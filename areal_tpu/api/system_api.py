@@ -49,6 +49,12 @@ class ModelWorkerConfig:
     experiment_name: str = ""
     trial_name: str = ""
     worker_index: int = 0
+    # Chips of this host the worker process owns (experiments/common.
+    # worker_chips, from allocation_mode). The controller puts them in
+    # the child's environment before its interpreter starts, so the
+    # process sees only these and shard device_ids index that local
+    # view. None = no assignment (the process sees the whole host).
+    chips: Optional[List[int]] = None
     shards: List[ModelShardSpec] = dataclasses.field(default_factory=list)
     # Dataset hosting (only on workers that serve the src MFC's model):
     datasets: List[DatasetAbstraction] = dataclasses.field(default_factory=list)
@@ -240,6 +246,10 @@ class GenerationServerConfig:
     # Shard the engine over this many local devices (megatron-style TP
     # via GSPMD; see engine/serving.serving_mesh).
     tensor_parallel: int = 1
+    # Chips of this host the server process owns: the server_index-th
+    # slice of allocation_mode's gen partition (see ModelWorkerConfig.
+    # chips). None = no assignment.
+    chips: Optional[List[int]] = None
     # Shard-aware weight plane (docs/weight_updates.md): this server's
     # coordinates in a FLEET-level tensor-parallel group. When set, the
     # server fetches only its slice of each weight version (a sliced

@@ -399,19 +399,6 @@ _KNOBS: List[Knob] = [
     _k("AREAL_BENCH_STATE_TTL_S", "float", 6 * 3600.0,
        "Age beyond which banked device state is stale for reporting "
        "(bench/bank.py, bench/report.py)."),
-    _k("AREAL_BENCH_POLL_S", "float", 10.0,
-       "Bench daemon device-poll interval seconds (bench/daemon.py)."),
-    _k("AREAL_BENCH_WINDOW_HINT_S", "float", 90.0,
-       "Optimistic device-window length hint the daemon plans phases "
-       "against (bench/daemon.py)."),
-    _k("AREAL_BENCH_MAX_ATTEMPTS", "int", 3,
-       "Attempts per bench phase before the daemon banks a failure "
-       "(bench/daemon.py)."),
-    _k("AREAL_BENCH_DEVICE_BUDGET_S", "float", 300.0,
-       "Per-phase device-seconds budget (bench/devices.py, "
-       "bench/workloads.py)."),
-    _k("AREAL_BENCH_INIT_BACKOFF_S", "float", 5.0,
-       "Backoff after a failed device grab (bench/devices.py)."),
     _k("AREAL_BENCH_PHASE_DEADLINE_S", "float", None,
        "Hard wall-clock deadline override for one phase subprocess "
        "(bench/phases.py); unset = per-phase default."),

@@ -120,8 +120,8 @@ _POINTS: List[FaultPoint] = [
        "The manager dies inside the update-weights fanout wave."),
     _p("bench.runner.phase",
        ("areal_tpu/bench/runner.py",), "sync",
-       "A bench phase subprocess dies or wedges (daemon "
-       "resume/attempt-budget machinery)."),
+       "A bench phase subprocess dies or wedges (the runner banks "
+       "the failure; a re-run resumes from the bank)."),
     _p("train.checkpoint",
        ("areal_tpu/engine/checkpoint.py",), "sync",
        "The trainer dies at the engine-checkpoint commit point, after "

@@ -35,8 +35,6 @@ from areal_tpu.bench._util import log, repo_root
 _CHILD = '''
 import os, sys
 sys.path.insert(0, %(repo)r)
-from areal_tpu.utils.jaxenv import apply_jax_platform_override
-apply_jax_platform_override()
 from areal_tpu.base import name_resolve
 name_resolve.reconfigure("nfs", record_root=%(nr)r)
 from areal_tpu.api.system_api import GenerationServerConfig

@@ -119,7 +119,10 @@ class JaxTrainBackend(ModelBackend):
         raw = model._raw
         model.module = JaxTrainEngine(
             model_cfg=raw["cfg"],
-            params=raw["params"],
+            # Handed over, not shared: on a mesh the engine reshards
+            # them, and a reference kept here would pin the unsharded
+            # originals on device 0.
+            params=raw.pop("params"),
             mesh=raw["mesh"],
             optimizer_config=self.optimizer,
             total_train_steps=max(1, spec.total_train_steps),
@@ -154,7 +157,10 @@ class JaxInferenceBackend(JaxTrainBackend):
         raw = model._raw
         model.module = JaxTrainEngine(
             model_cfg=raw["cfg"],
-            params=raw["params"],
+            # Handed over, not shared: on a mesh the engine reshards
+            # them, and a reference kept here would pin the unsharded
+            # originals on device 0.
+            params=raw.pop("params"),
             mesh=raw["mesh"],
             optimizer_config=None,
             attn_impl=self.attn_impl,

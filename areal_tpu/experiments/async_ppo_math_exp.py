@@ -116,9 +116,8 @@ def build_async_ppo_math_experiment(cfg: AsyncPPOMATHExpConfig) -> ExperimentCon
 
     workers = []
     for i in range(n_workers):
-        # The decoupled allocation's TRAIN partition (devices after the
-        # gen partition) drives the trainer mesh: fsdp/tensor axes from
-        # allocation_mode now reach the engine instead of being dropped.
+        # The decoupled allocation's TRAIN partition drives the trainer
+        # mesh: fsdp/tensor axes from allocation_mode reach the engine.
         t_mesh, t_devs = C.train_mesh_for_worker(cfg, i, n_workers)
         shards = [
             ModelShardSpec(
@@ -175,11 +174,23 @@ def build_async_ppo_math_experiment(cfg: AsyncPPOMATHExpConfig) -> ExperimentCon
     shards = parse_weight_shards(
         cfg.gen_weight_shards, cfg.n_generation_servers
     )
+    # One owner per chip: generation server i holds the i-th slice of
+    # the allocation's gen partition (the launcher hands it over in the
+    # process environment), and shards over exactly those chips.
+    chips = C.worker_chips(cfg, n_workers)
+    own = chips.get("generation_server/0")  # slices are equal
+    if own is not None and len(own) != cfg.gen_tensor_parallel:
+        raise ValueError(
+            f"allocation_mode={cfg.allocation_mode!r} gives each of "
+            f"{cfg.n_generation_servers} generation servers {len(own)} "
+            f"chip(s), but gen_tensor_parallel={cfg.gen_tensor_parallel}"
+        )
     gen_servers = [
         GenerationServerConfig(
             experiment_name=cfg.experiment_name,
             trial_name=cfg.trial_name,
             server_index=i,
+            chips=chips.get(f"generation_server/{i}"),
             model=C.model_abstraction(cfg.actor, cfg.tokenizer_path),
             tokenizer_path=cfg.tokenizer_path or cfg.actor.path,
             max_concurrent_requests=cfg.gen_max_concurrent_requests,

@@ -24,7 +24,10 @@ from areal_tpu.api.system_api import (
     ModelShardSpec,
     ModelWorkerConfig,
 )
+from areal_tpu.base import logging
 from areal_tpu.parallel.mesh import AllocationMode
+
+logger = logging.getLogger("experiments")
 
 
 def model_abstraction(m: ModelTrainEvalConfig, tokenizer_path: Optional[str],
@@ -82,21 +85,22 @@ def train_mesh_for_worker(
 ) -> Tuple[Optional[str], Optional[List[int]]]:
     """(mesh_spec, device_ids) for one model worker's slice of the
     allocation's TRAIN partition — the system-layer wiring that makes
-    `allocation_mode` actually drive sharded training (previously only
-    the data axis was consumed, as the worker count; fsdp/tensor/seq
-    axes were silently dropped).
+    `allocation_mode` actually drive sharded training.
+
+    ``device_ids`` index the worker's OWN devices: the launcher gives
+    each worker process only its chips (``worker_chips`` below), so the
+    slice always starts at local device 0.
 
     - Single-host (train_n_hosts == 1): the train data axis splits
-      across workers (each worker is one DP rank of the MFC layer, as
-      before); worker i gets a LOCAL (data/n_workers, fsdp, seq, tensor)
-      mesh over its contiguous device slice (offset past the gen
-      partition when the allocation is decoupled).
+      across workers (each worker is one DP rank of the MFC layer);
+      worker i gets a LOCAL (data/n_workers, fsdp, seq, tensor) mesh
+      over its first ``size`` devices.
     - Multi-host (train_n_hosts > 1): every worker-host builds the
       GLOBAL train mesh over the jax.distributed world's devices
       (device_ids None = all); DP happens inside the mesh.
     - Returns (None, None) for single-device allocations or when the
-      data axis doesn't divide the worker count (legacy behavior:
-      single-device mesh per worker).
+      data axis doesn't divide the worker count (single-device mesh per
+      worker).
     """
     try:
         alloc = AllocationMode.parse(cfg.allocation_mode)
@@ -113,9 +117,28 @@ def train_mesh_for_worker(
     if ts.data % max(1, n_workers) != 0:
         return None, None
     local = dataclasses.replace(ts, data=ts.data // max(1, n_workers))
-    offset = alloc.gen_spec.size if alloc.decoupled else 0
-    start = offset + worker_index * local.size
-    return str(local), list(range(start, start + local.size))
+    return str(local), list(range(local.size))
+
+
+def worker_chips(cfg: BaseExperimentConfig, n_workers: int) -> Dict[str, List[int]]:
+    """Chips of this host owned by each model worker and generation
+    server, by worker name (``AllocationMode.worker_chips``). Empty when
+    the allocation does not describe this launch: multi-host training
+    (each worker owns its whole host) or worker counts the allocation's
+    axes do not divide — on a TPU host the controller then refuses to
+    start more than one chip-holding process."""
+    if int(getattr(cfg, "train_n_hosts", 1) or 1) > 1:
+        return {}
+    try:
+        return AllocationMode.parse(cfg.allocation_mode).worker_chips(
+            int(getattr(cfg, "n_generation_servers", 0)), n_workers
+        )
+    except (ValueError, AttributeError) as e:
+        logger.warning(
+            f"allocation_mode={cfg.allocation_mode!r} gives no per-worker "
+            f"chip assignment: {e}"
+        )
+        return {}
 
 
 def backend_abstraction(m: ModelTrainEvalConfig, train: bool = True) -> ModelBackendAbstraction:
@@ -195,6 +218,7 @@ def base_model_worker(
         experiment_name=cfg.experiment_name,
         trial_name=cfg.trial_name,
         worker_index=index,
+        chips=worker_chips(cfg, n_workers).get(f"model_worker/{index}"),
         shards=shards,
         datasets=[dataset_abstraction(cfg.dataset)] if with_dataset else [],
         tokenizer_path=cfg.tokenizer_path,

@@ -268,9 +268,13 @@ def generate_tokens(
     gconfig,
     rng: jax.Array,
     eos_token_id: Optional[int] = None,
-    prompt_pad_multiple: int = 64,
+    prompt_pad_multiple: int = 128,
 ) -> List[Dict[str, Any]]:
     """Host-facing generation over a batch of prompts.
+
+    Prompts pad to a multiple of the TPU lane width, so the prefill's
+    rows qualify for the splash kernel (a shorter or unaligned row runs
+    the O(T^2) reference, ops/attention.resolve_attn_impl).
 
     Returns per-prompt dicts: output_ids, output_logprobs, no_eos.
     """

@@ -27,7 +27,7 @@ capacity buffer, exact at any router skew. On an expert-parallel mesh
 `shard_map` over the fsdp axis (`_moe_mlp_ep`): each shard holds only
 its E/ep experts, the (token, choice) streams are exchanged with an
 all-gather + psum_scatter pair (the static-shape stand-in for a ragged
-all-to-all; jax 0.4.x has none), and the per-shard grouped matmul runs
+all-to-all), and the per-shard grouped matmul runs
 local experts only — so the zero-drop guarantee and 1/ep expert HBM
 coexist. Tradeoff: the gather-side grouped matmul touches every
 exchanged row (dummy zero-weight groups absorb non-local rows), so
@@ -139,7 +139,6 @@ def _moe_mlp_ep(
     weights never all-gathered. The F dim stays column-parallel on
     `tensor` when divisible (psum over tensor closes the row-parallel
     w_down)."""
-    from areal_tpu.utils.jax_compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     moe = cfg.moe
@@ -234,7 +233,7 @@ def _moe_mlp_ep(
         }
         return y.reshape(xb.shape), aux
 
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(

@@ -172,7 +172,6 @@ def _attention_block(
     from areal_tpu.ops.attention import (
         resolve_attn_impl,
         sharded_splash_attention,
-        sharded_splash_ok,
     )
 
     R, T, D = x.shape
@@ -193,22 +192,14 @@ def _attention_block(
         q = apply_rotary(q, cos, sin, cfg.rotary_interleaved)
         k = apply_rotary(k, cos, sin, cfg.rotary_interleaved)
 
-    # 'auto' resolution is mesh-aware: a seq>1 mesh picks a CP scheme
-    # (Ulysses when heads divide the seq axis, ring otherwise) before
-    # the local-kernel choice. Explicit values pass through.
+    # Resolution is mesh-aware: a seq>1 mesh picks a CP scheme for
+    # 'auto' (Ulysses when heads divide the seq axis, ring otherwise)
+    # before the local-kernel choice, and a kernel with no shard_map
+    # layout for this mesh becomes the reference.
     impl = resolve_attn_impl(
         attn_impl, T, cfg.n_q_heads, cfg.n_kv_heads, mesh=mesh, r=R
     )
     sharded = mesh is not None and mesh.size > 1
-    if sharded and impl not in ("reference", "ring", "ulysses"):
-        # Never run a bare pallas_call inside a sharded jit — GSPMD
-        # cannot partition it (it replicates or fails). Only splash has a
-        # shard_map wrapping; anything else falls back to the einsum
-        # reference, which partitions cleanly.
-        if impl != "splash" or not sharded_splash_ok(
-            mesh, R, T, cfg.n_q_heads, cfg.n_kv_heads
-        ):
-            impl = "reference"
     if impl == "ring":
         # Context parallelism: KV chunks ring-rotate over the seq axis
         # (O(T/seq) per-device attention memory — the long-context path).

@@ -4,7 +4,8 @@ Counterpart of the reference's csrc/ extension loading
 (realhf/impl/model/nn/flatten_param.py:31,113,162 and
 realhf/impl/model/utils/ppo_functional.py:358-394): native fast path with
 pure-Python/numpy fallbacks, selected at import time. The library is
-compiled on first use with g++ (no pybind11 in the toolchain; plain C ABI).
+compiled on first use with g++ (no pybind11 in the toolchain; plain C ABI)
+into csrc/build/ under a name keyed by the source's content.
 
 Public API (all accept/return numpy arrays):
   - ffd_allocate_native(lengths, capacity, min_groups) -> List[List[int]]
@@ -18,6 +19,8 @@ Public API (all accept/return numpy arrays):
 from __future__ import annotations
 
 import ctypes
+import functools
+import hashlib
 import os
 import subprocess
 import threading
@@ -32,11 +35,13 @@ logger = areal_logging.getLogger("host_ops")
 _REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 _SRC = os.path.join(_REPO_ROOT, "csrc", "host_ops.cpp")
 _LIB_DIR = os.path.join(_REPO_ROOT, "csrc", "build")
-_LIB = os.path.join(_LIB_DIR, "libareal_host_ops.so")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _load_failed = False
+# Why the native library is unavailable (compiler output), for
+# require_native's error.
+_failure = ""
 
 _i64p = ctypes.POINTER(ctypes.c_int64)
 _f32p = ctypes.POINTER(ctypes.c_float)
@@ -44,19 +49,35 @@ _u8p = ctypes.POINTER(ctypes.c_uint8)
 _charp = ctypes.c_char_p
 
 
-def _build() -> bool:
+@functools.cache
+def _lib_path() -> str:
+    """Where the library built from THIS source lives: the file name
+    carries the source's content hash, so a library left behind by an
+    older source (csrc/build/ is git-ignored and survives checkouts) is
+    never loaded in its place."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_LIB_DIR, f"libareal_host_ops.{digest}.so")
+
+
+def _build(lib_path: str) -> bool:
+    global _failure
     # Compile to a process-unique temp path and rename into place: os.rename
-    # is atomic, so a concurrent worker either sees the old .so or the
-    # complete new one, never a half-written ELF.
+    # is atomic, so a concurrent worker either sees no library or the
+    # complete one, never a half-written ELF.
     os.makedirs(_LIB_DIR, exist_ok=True)
-    tmp = f"{_LIB}.tmp.{os.getpid()}"
+    tmp = f"{lib_path}.tmp.{os.getpid()}"
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17", _SRC, "-o", tmp]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.rename(tmp, _LIB)
+        os.rename(tmp, lib_path)
         return True
-    except Exception as e:  # pragma: no cover - toolchain-dependent
-        logger.warning(f"host_ops native build failed ({e}); using Python fallbacks")
+    except (OSError, subprocess.SubprocessError) as e:  # pragma: no cover
+        _failure = f"{e!r}: {getattr(e, 'stderr', b'') or b''!r}"
+        logger.warning(
+            f"host_ops native build failed ({_failure}); using Python "
+            f"fallbacks"
+        )
         try:
             os.unlink(tmp)
         except OSError:
@@ -64,31 +85,27 @@ def _build() -> bool:
         return False
 
 
-def _needs_build() -> bool:
-    if not os.path.exists(_LIB):
-        return True
-    try:
-        # Source may be absent (artifact-only deploy): use the .so as is.
-        return os.path.getmtime(_LIB) < os.path.getmtime(_SRC)
-    except OSError:
-        return False
-
-
 def _load() -> Optional[ctypes.CDLL]:
-    global _lib, _load_failed
+    global _lib, _load_failed, _failure
     if _lib is not None or _load_failed:
         return _lib
     with _lock:
         if _lib is not None or _load_failed:
             return _lib
-        if _needs_build():
-            if not os.path.exists(_SRC) or not _build():
-                _load_failed = True
-                return None
         try:
-            lib = ctypes.CDLL(_LIB)
+            lib_path = _lib_path()
+        except OSError as e:
+            _failure = f"no source to build from: {e!r}"
+            _load_failed = True
+            return None
+        if not os.path.exists(lib_path) and not _build(lib_path):
+            _load_failed = True
+            return None
+        try:
+            lib = ctypes.CDLL(lib_path)
         except OSError as e:  # pragma: no cover
-            logger.warning(f"host_ops load failed: {e}")
+            _failure = f"load failed: {e!r}"
+            logger.warning(f"host_ops {_failure}")
             _load_failed = True
             return None
         lib.ffd_allocate.restype = ctypes.c_int64
@@ -124,14 +141,22 @@ def native_available(wait: bool = True) -> bool:
         return True
     if _load_failed:
         return False
-    if wait:
-        return _load() is not None
-    if not _needs_build():
+    if wait or not os.path.exists(_SRC) or os.path.exists(_lib_path()):
         return _load() is not None
     if _bg_build is None or not _bg_build.is_alive():
         _bg_build = threading.Thread(target=_load, daemon=True, name="host_ops_build")
         _bg_build.start()
     return False
+
+
+def require_native() -> bool:
+    """Build/load the native library or raise with the compiler's
+    output. Workers call this on a TPU backend (utils/jaxenv.
+    report_devices): there a failed build is an error, not a warning and
+    a slower loop."""
+    if _load() is None:
+        raise RuntimeError(f"native host ops unavailable: {_failure}")
+    return True
 
 
 def _as_i64(x) -> np.ndarray:

@@ -10,7 +10,7 @@ streams the pool AS STORED:
   data   [Hkv, N, pg, hd] int8
   scales [Hkv, N, pg]     f32   (squeezed; pg is the lane axis)
 
-and dequantizes in VMEM, so HBM traffic per (kv head, page) is
+and applies the scales in VMEM, so HBM traffic per (kv head, page) is
 pg*(hd + 4) bytes vs 2*pg*hd for a bf16 pool — ~1.94x less at hd=128.
 
 Design (counterpart of the stock kernel's role, not its structure —
@@ -74,13 +74,17 @@ def _kernel(lengths_ref, pi_ref, q_ref, kd_ref, ks_ref, vd_ref, vs_ref,
 
     @pl.when(p * pg < length)
     def _compute():
+        # The per-token scales sit with pg on the lane axis ([1, pg]
+        # blocks), which is also the lane axis of the [g, pg] scores and
+        # probabilities — so they are applied THERE, as a sublane
+        # broadcast, instead of being turned into a [pg, 1] column to
+        # dequantize the [pg, hd] tiles (a lane->sublane relayout):
+        # q.(k*ks)^T == (q.k^T)*ks and p.(v*vs) == (p*vs).v exactly.
         q = q_ref[...].astype(jnp.float32)  # [g, hd], pre-scaled
-        k = kd_ref[0].astype(jnp.float32) * (
-            ks_ref[0] * (1.0 / KV_INT8_MAX))[:, None]  # [pg, hd]
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
+            q, kd_ref[0].astype(jnp.float32), (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )  # [g, pg]
+        ) * (ks_ref[...] * (1.0 / KV_INT8_MAX))  # [g, pg]
         pos = p * pg + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(pos < length, s, _NEG_INF)
 
@@ -89,11 +93,10 @@ def _kernel(lengths_ref, pi_ref, q_ref, kd_ref, ks_ref, vd_ref, vs_ref,
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)  # [g, 1]
         p_ij = jnp.exp(s - m_new)  # [g, pg]
-        v = vd_ref[0].astype(jnp.float32) * (
-            vs_ref[0] * (1.0 / KV_INT8_MAX))[:, None]  # [pg, hd]
         l_new = l_prev * alpha + jnp.sum(p_ij, axis=1, keepdims=True)
         acc_sc[...] = acc_sc[...] * alpha + jax.lax.dot_general(
-            p_ij, v, (((1,), (0,)), ((), ())),
+            p_ij * (vs_ref[...] * (1.0 / KV_INT8_MAX)),
+            vd_ref[0].astype(jnp.float32), (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         m_sc[...] = jnp.broadcast_to(m_new, m_sc.shape)
@@ -121,36 +124,36 @@ def int8_paged_decode_attention(
     P = page_indices.shape[1]
     g = Hq // Hkv
 
-    def page_map(extra):
-        # Block index (h-th kv head, pool page for (b, p)); extra 0s for
-        # the in-page dims.
-        def f(b, h, p, lr, pr):
-            return (h, pr[b, p]) + (0,) * extra
-
-        return f
+    # Mosaic wants the last two dims of every block to be (8, 128)
+    # multiples or the array's own: q/out go in as [B, Hkv, g, hd] (the
+    # group is a whole dim, g is rarely a multiple of 8) and the scales
+    # as [Hkv, N, 1, pg] — both free reshapes of the stored layouts.
+    def page_map(b, h, p, lr, pr):
+        # Block index: h-th kv head, pool page for (b, p).
+        return (h, pr[b, p], 0, 0)
 
     def head_map(b, h, p, lr, pr):
-        return (b, h, 0)
+        return (b, h, 0, 0)
 
-    return pl.pallas_call(
+    data_spec = pl.BlockSpec((None, 1, pg, hd), page_map)
+    scale_spec = pl.BlockSpec((None, None, 1, pg), page_map)
+    head_spec = pl.BlockSpec((None, None, g, hd), head_map)
+    out = pl.pallas_call(
         _kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(B, Hkv, P),
-            in_specs=[
-                pl.BlockSpec((None, g, hd), head_map),
-                pl.BlockSpec((None, 1, pg, hd), page_map(2)),
-                pl.BlockSpec((None, 1, pg), page_map(1)),
-                pl.BlockSpec((None, 1, pg, hd), page_map(2)),
-                pl.BlockSpec((None, 1, pg), page_map(1)),
-            ],
-            out_specs=pl.BlockSpec((None, g, hd), head_map),
+            in_specs=[head_spec, data_spec, scale_spec, data_spec,
+                      scale_spec],
+            out_specs=head_spec,
             scratch_shapes=[
                 pltpu.VMEM((g, _LANES), jnp.float32),  # running max
                 pltpu.VMEM((g, _LANES), jnp.float32),  # running sum
                 pltpu.VMEM((g, hd), jnp.float32),  # output accumulator
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, Hq, hd), qs.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, g, hd), qs.dtype),
         interpret=interpret,
-    )(lengths, page_indices, qs, kd, ks, vd, vs)
+    )(lengths, page_indices, qs.reshape(B, Hkv, g, hd),
+      kd, ks[:, :, None], vd, vs[:, :, None])
+    return out.reshape(B, Hq, hd)

@@ -70,7 +70,6 @@ def ring_packed_attention(
 ) -> jnp.ndarray:
     """Packed GQA attention with the KV stream ring-rotated over the
     mesh's `seq` axis. Callers must check `ring_ok` first."""
-    from areal_tpu.utils.jax_compat import shard_map
     from jax.sharding import PartitionSpec as P
 
     hd = q.shape[-1]
@@ -109,7 +108,7 @@ def ring_packed_attention(
         out = acc / jnp.maximum(l, 1e-30)[..., None]  # [R,Hkv,G,C,hd]
         return out.transpose(0, 3, 1, 2, 4).reshape(R, C, Hq, hd).astype(q.dtype)
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(

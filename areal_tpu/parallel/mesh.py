@@ -6,7 +6,8 @@ global_comm.py): parallelism is expressed as a `jax.sharding.Mesh` with
 axes (data, fsdp, seq, tensor) and GSPMD inserts the collectives. Device
 *partitions* (disjoint sets of chips for generation vs training, the
 reference's `sglang.dXpYmZ+dApBmC` decoupled allocation) are contiguous
-slices of the device list, each carrying its own mesh.
+slices of the host's chips, cut per worker process by
+`AllocationMode.worker_chips`: a chip has one owner.
 """
 
 from __future__ import annotations
@@ -44,22 +45,6 @@ def single_device_mesh(device: Optional[jax.Device] = None) -> Mesh:
 
 
 @dataclasses.dataclass
-class DevicePartition:
-    """A named slice of the global device list with its mesh spec."""
-
-    name: str
-    device_ids: List[int]  # indices into jax.devices()
-    mesh_spec: MeshSpec
-
-    def devices(self) -> List[jax.Device]:
-        all_devices = jax.devices()
-        return [all_devices[i] for i in self.device_ids]
-
-    def make_mesh(self) -> Mesh:
-        return make_mesh(self.mesh_spec, self.devices())
-
-
-@dataclasses.dataclass
 class AllocationMode:
     """Parsed allocation DSL (counterpart of the reference's
     `sglang.d4m1+d2m2`-style strings, realhf/experiments/common/utils.py:289).
@@ -89,19 +74,40 @@ class AllocationMode:
             )
         return cls(gen_spec=None, train_spec=MeshSpec.parse(s), decoupled=False)
 
-    def partitions(self, n_devices: Optional[int] = None) -> Dict[str, DevicePartition]:
-        n = n_devices if n_devices is not None else len(jax.devices())
-        need = self.train_spec.size + (self.gen_spec.size if self.decoupled else 0)
-        if need > n:
-            raise ValueError(f"allocation needs {need} devices, have {n}")
-        out: Dict[str, DevicePartition] = {}
-        cursor = 0
-        if self.decoupled:
-            out["gen"] = DevicePartition(
-                "gen", list(range(cursor, cursor + self.gen_spec.size)), self.gen_spec
-            )
-            cursor += self.gen_spec.size
-        out["train"] = DevicePartition(
-            "train", list(range(cursor, cursor + self.train_spec.size)), self.train_spec
+    def worker_chips(
+        self, n_gen_servers: int, n_train_workers: int
+    ) -> Dict[str, List[int]]:
+        """Host chip indices owned by each chip-holding worker process,
+        keyed by worker name.
+
+        A chip belongs to one process, so the allocation is cut into
+        per-process sets here, in the launcher, and never by indexing a
+        shared ``jax.devices()``: the gen partition comes first and
+        generation server i takes its i-th equal slice; the train
+        partition follows and model worker j takes its j-th equal slice.
+        The sets are disjoint and cover the allocation by construction
+        (whether they fit the host is the controller's check, where the
+        host is known). Inside a worker, ``device_ids`` index its own
+        (local) devices.
+
+        A colocated allocation ("d1f2") gives generation servers no
+        entry: they have no partition of their own.
+        """
+        gen_size = self.gen_spec.size if self.decoupled else 0
+        out: Dict[str, List[int]] = {}
+        if self.decoupled and n_gen_servers:
+            _slice_evenly(out, "generation_server", 0, gen_size, n_gen_servers)
+        _slice_evenly(
+            out, "model_worker", gen_size, self.train_spec.size, n_train_workers
         )
         return out
+
+
+def _slice_evenly(out, role: str, start: int, size: int, n: int) -> None:
+    if n < 1 or size % n:
+        raise ValueError(
+            f"{size} chips do not split evenly among {n} {role} processes"
+        )
+    per = size // n
+    for i in range(n):
+        out[f"{role}/{i}"] = list(range(start + i * per, start + (i + 1) * per))

@@ -14,6 +14,7 @@ import dataclasses
 import multiprocessing as mp
 import os
 import sys
+import threading
 import time
 import traceback
 from typing import Any, Dict, List, Optional, Set
@@ -33,21 +34,118 @@ RESTARTABLE_ROLES = frozenset(
     {"generation_server", "rollout_worker", "gserver_manager"}
 )
 
+# TPU_CHIPS_PER_PROCESS_BOUNDS for the chip-set shapes libtpu 0.0.34
+# accepted on a v5e 2x2 host (PR 21 chip run): one chip, or an aligned
+# pair (0,1) / (2,3). TPU_VISIBLE_CHIPS alone is refused: the second
+# process aborts on libtpu's multi-process lockfile.
+_CHIP_BOUNDS = {1: "1,1,1", 2: "1,2,1"}
+_MESH_CONTROLLER_PORT = 8476
+
+
+def host_tpu_chips(env: Dict[str, str]) -> int:
+    """TPU chips attached to this host, counted the way jax counts them
+    (PCI scan; touches no backend). 0 when the launch is held to the CPU
+    platform: virtual devices have no owner to assign."""
+    if env.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        return 0
+    from jax._src import hardware_utils
+
+    return hardware_utils.num_available_tpu_chips_and_device_id()[0]
+
+
+def chip_env(chips: List[int], n_host_chips: int) -> Dict[str, str]:
+    """The libtpu environment under which a process sees only `chips`
+    of this host (as local devices 0..n-1). Must be in the environment
+    before the interpreter starts: libtpu reads it when jax loads."""
+    chips = list(chips)
+    if not chips or chips[-1] >= n_host_chips:
+        raise ValueError(
+            f"chip set {chips} does not fit a host with {n_host_chips} chips"
+        )
+    if chips == list(range(n_host_chips)):
+        return {}  # the whole host: nothing to hide
+    n = len(chips)
+    if (
+        n not in _CHIP_BOUNDS
+        or chips != list(range(chips[0], chips[0] + n))
+        or chips[0] % n
+    ):
+        raise ValueError(
+            f"cannot give one process chips {chips}: supported sets are "
+            f"one chip, an aligned pair (0,1)/(2,3), or the whole host"
+        )
+    port = _MESH_CONTROLLER_PORT + chips[0]
+    return {
+        "TPU_VISIBLE_CHIPS": ",".join(str(c) for c in chips),
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": _CHIP_BOUNDS[n],
+        "TPU_PROCESS_BOUNDS": "1,1,1",
+        "TPU_MESH_CONTROLLER_ADDRESS": f"localhost:{port}",
+        "TPU_MESH_CONTROLLER_PORT": str(port),
+    }
+
+
+def plan_worker_envs(
+    exp_cfg: ExperimentConfig, worker_env: Dict[str, str], n_host_chips: int
+) -> Dict[str, Dict[str, str]]:
+    """Environment of every worker process, by worker name.
+
+    A chip belongs to one process. Model workers and generation servers
+    compute on an accelerator: each gets the shared `worker_env` plus
+    the libtpu variables for its own chips (its config's `chips`, cut
+    from allocation_mode by experiments/common.worker_chips). Every
+    other role is pinned to the CPU platform, so that a stray jnp call
+    in a rollout worker cannot take a chip from its owner. With
+    `n_host_chips` > 0 (a TPU host) the assignment is checked —
+    disjoint, inside the host, and no two processes left to fight over
+    unassigned chips; with 0 (held to the CPU) there is nothing to own."""
+    chip_cfgs = list(exp_cfg.model_workers) + list(exp_cfg.generation_servers)
+    envs: Dict[str, Dict[str, str]] = {}
+    owner: Dict[int, str] = {}
+    for cfg in chip_cfgs:
+        env = dict(worker_env)
+        if n_host_chips > 0:
+            if cfg.chips is None:
+                if len(chip_cfgs) > 1:
+                    raise ValueError(
+                        f"{cfg.worker_name} has no chips assigned, and "
+                        f"{len(chip_cfgs)} processes need an accelerator on "
+                        f"this {n_host_chips}-chip host: a chip has one "
+                        f"owner, so allocation_mode must name a set for each"
+                    )
+            else:
+                for c in cfg.chips:
+                    if c in owner:
+                        raise ValueError(
+                            f"chip {c} assigned to both {owner[c]} and "
+                            f"{cfg.worker_name}"
+                        )
+                    owner[c] = cfg.worker_name
+                env.update(chip_env(cfg.chips, n_host_chips))
+        envs[cfg.worker_name] = env
+    others = list(exp_cfg.rollout_workers)
+    if exp_cfg.gserver_manager is not None:
+        others.append(exp_cfg.gserver_manager)
+    for cfg in others:
+        envs[cfg.worker_name] = {**worker_env, "JAX_PLATFORMS": "cpu"}
+    return envs
+
+
+# Serializes the window in which a child's environment is staged in
+# os.environ around Process.start() (spawn inherits the parent's
+# environment at exec, the only way to reach the child's interpreter
+# start through multiprocessing).
+_spawn_env_lock = threading.Lock()
+
 
 def _run_worker_proc(
     worker_type: str,
     config: Any,
     name_resolve_cfg: Dict,
-    env: Dict[str, str],
     error_queue,
 ):
     """Subprocess entry: reconfigure name_resolve, build + run the worker."""
     worker_name = getattr(config, "worker_name", worker_type)
     try:
-        os.environ.update(env)
-        from areal_tpu.utils.jaxenv import apply_jax_platform_override
-
-        apply_jax_platform_override()
         name_resolve.reconfigure(**name_resolve_cfg)
         from areal_tpu.system import load_worker
 
@@ -101,9 +199,9 @@ class LocalController:
         # Guarded by _err_lock: appended by the supervisor thread while
         # the main thread drains/raises in run()'s teardown.
         self._pending_errors: List[str] = []
-        import threading
-
         self._err_lock = threading.Lock()
+        # Worker name -> process environment, planned in start_workers.
+        self._envs: Dict[str, Dict[str, str]] = {}
         self._ctx = mp.get_context("spawn")
         self._errors = self._ctx.Queue()
 
@@ -118,24 +216,32 @@ class LocalController:
         import areal_tpu
 
         repo_root = os.path.dirname(os.path.dirname(os.path.abspath(areal_tpu.__file__)))
-        existing = os.environ.get("PYTHONPATH", "")
+        name = getattr(config, "worker_name", worker_type)
+        env = dict(self._envs.get(name, self.worker_env))
+        existing = env.get("PYTHONPATH", os.environ.get("PYTHONPATH", ""))
         if repo_root not in existing.split(os.pathsep):
-            os.environ["PYTHONPATH"] = (
+            env["PYTHONPATH"] = (
                 repo_root + (os.pathsep + existing if existing else "")
             )
         p = self._ctx.Process(
             target=_run_worker_proc,
-            args=(
-                worker_type,
-                config,
-                self.name_resolve_cfg,
-                self.worker_env,
-                self._errors,
-            ),
+            args=(worker_type, config, self.name_resolve_cfg, self._errors),
             daemon=True,
         )
-        p.start()
-        name = getattr(config, "worker_name", worker_type)
+        # The child's environment must be complete BEFORE its
+        # interpreter starts: unpickling the target imports jax, and
+        # libtpu reads its chip assignment when it loads.
+        with _spawn_env_lock:
+            saved = {k: os.environ.get(k) for k in env}
+            os.environ.update(env)
+            try:
+                p.start()
+            finally:
+                for k, v in saved.items():
+                    if v is None:
+                        os.environ.pop(k, None)
+                    else:
+                        os.environ[k] = v
         rec = self._workers.get(name)
         if rec is None:
             self._workers[name] = _WorkerRecord(worker_type, config, p)
@@ -157,6 +263,16 @@ class LocalController:
             raise NotImplementedError(
                 f"async worker roles not available yet: {missing}"
             )
+        n_chips = host_tpu_chips({**os.environ, **self.worker_env})
+        if n_chips > 0:
+            from jax._src import xla_bridge
+
+            if xla_bridge.backends_are_initialized():
+                raise RuntimeError(
+                    "the launcher process has initialised a jax backend and "
+                    "so holds the chips its workers need; it must stay off jax"
+                )
+        self._envs = plan_worker_envs(self.exp_cfg, self.worker_env, n_chips)
         for cfg in self.exp_cfg.model_workers:
             self._spawn("model_worker", cfg)
         for cfg in self.exp_cfg.generation_servers:
@@ -423,6 +539,7 @@ class ClusterController:
                 "name_prefix",
                 f"{exp_cfg.experiment_name}-{exp_cfg.trial_name}",
             )
+        self._envs: Dict[str, Dict[str, str]] = {}
         self._sched = make_scheduler(
             scheduler_mode,
             log_dir=os.path.join(spool_dir, "logs"),
@@ -445,7 +562,7 @@ class ClusterController:
         repo_root = os.path.dirname(
             os.path.dirname(os.path.abspath(areal_tpu.__file__))
         )
-        env = dict(self.worker_env)
+        env = dict(self._envs.get(config.worker_name, self.worker_env))
         env["PYTHONPATH"] = (
             repo_root + os.pathsep + env.get(
                 "PYTHONPATH", os.environ.get("PYTHONPATH", "")
@@ -466,6 +583,13 @@ class ClusterController:
         return name
 
     def start_workers(self):
+        # Only local-mode workers share this host's chips; a cluster
+        # scheduler places each worker on a host of its own.
+        n_chips = (
+            host_tpu_chips({**os.environ, **self.worker_env})
+            if self.scheduler_mode == "local" else 0
+        )
+        self._envs = plan_worker_envs(self.exp_cfg, self.worker_env, n_chips)
         for cfg in self.exp_cfg.model_workers:
             self._submit("model_worker", cfg)
         for cfg in self.exp_cfg.generation_servers:

@@ -53,6 +53,7 @@ from areal_tpu.base import constants, logging, name_resolve, names, network, rpc
 from areal_tpu.base.fault_injection import faults
 from areal_tpu.engine.serving import GenRequest, ServingEngine
 from areal_tpu.engine.weight_client import ChunkStore, assemble_params
+from areal_tpu.utils import jaxenv
 from areal_tpu.system.weight_plane import (
     serve_store_chunk,
     serve_store_manifest,
@@ -123,11 +124,13 @@ class GenerationServer(Worker):
             mesh=mesh,
         )
         self.engine.start()
+        jaxenv.report_devices(config.worker_name)
         if config.warm_on_start:
             # Compile the serving programs before taking traffic (and
             # before discovery registration below): one bucket's worth
             # of prompt + the decode block covers the hot path.
             self.engine.warm([config.prompt_bucket])
+            jaxenv.report_usage(config.worker_name)
         self._n_interrupted = 0
         self._n_shed = 0
         self._last_load_info = None
@@ -2050,6 +2053,7 @@ class GenerationServer(Worker):
 
     def _exit_hook(self):
         try:
+            jaxenv.report_usage(self.cfg.worker_name)
             self.engine.stop()
             if self._handoff_session is not None:
                 asyncio.run_coroutine_threadsafe(

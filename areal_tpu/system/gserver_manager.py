@@ -1029,7 +1029,15 @@ class GserverManager(Worker):
             ),
             model or self.cfg.model_name,
         )
-        if os.path.exists(os.path.join(path, "engine_state.pkl")):
+        # A sharded trainer (mesh > 1) writes only the shard-local raw
+        # dump — no engine_state.pkl — which the servers assemble through
+        # weight_transfer.load_for_serving; gating on the pickle alone
+        # left that update forever pending.
+        from areal_tpu.system.weight_transfer import has_raw_dump
+
+        if has_raw_dump(path) or os.path.exists(
+            os.path.join(path, "engine_state.pkl")
+        ):
             return path
         return None
 

@@ -29,7 +29,7 @@ from areal_tpu.api import data_api
 from areal_tpu.api.config import ModelName
 from areal_tpu.api.data_api import MicroBatchSpec, SequenceSample
 from areal_tpu.base import monitor
-from areal_tpu.utils import profiling
+from areal_tpu.utils import jaxenv, profiling
 from areal_tpu.api.model_api import (
     FinetuneSpec,
     Model,
@@ -174,6 +174,7 @@ class ModelWorker(Worker):
             self.models[str(mn)] = model
             self.backends[str(mn)] = backend
             self.interfaces[str(mn)] = make_interface(shard.interface)
+        jaxenv.report_devices(config.worker_name)
         logger.info(
             f"{config.worker_name} configured: models={list(self.models)}, "
             f"dataset_size={dataset_size}"
@@ -311,6 +312,7 @@ class ModelWorker(Worker):
         # registry (metrics-registry lint checker).
         stats.update(metrics_registry.perf_mem_stats(mem))
         monitor.check_memory_kill_threshold(mem)
+        jaxenv.report_usage(self.config.worker_name)
         cfg = getattr(model.module, "model_cfg", None)
         if cfg is not None:
             in_lens = [

@@ -715,6 +715,12 @@ def _read_manifest(dump_dir: str) -> Optional[Dict[str, Any]]:
     return manifest
 
 
+def has_raw_dump(dump_dir: str) -> bool:
+    """Whether a raw dump (single bin or shard-local slabs) has been
+    published in ``dump_dir``."""
+    return _read_manifest(dump_dir) is not None
+
+
 def load_raw_params(dump_dir: str) -> Optional[Tuple[Any, int]]:
     """mmap the latest raw dump: (params pytree of memory-mapped arrays,
     dump version), or None if absent/torn (caller falls back).

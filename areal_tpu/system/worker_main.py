@@ -29,10 +29,6 @@ def main(argv=None):
                     help="JSON kwargs for name_resolve.reconfigure")
     args = ap.parse_args(argv)
 
-    from areal_tpu.utils.jaxenv import apply_jax_platform_override
-
-    apply_jax_platform_override()
-
     from areal_tpu.base import name_resolve
 
     name_resolve.reconfigure(**json.loads(args.name_resolve))

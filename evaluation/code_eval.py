@@ -23,12 +23,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-# Eval jobs are schedulable onto CPU workers: honor JAX_PLATFORMS before
-# any device use (utils/jaxenv.py explains the early-import dance).
-from areal_tpu.utils.jaxenv import apply_jax_platform_override
-
-apply_jax_platform_override()
-
 
 def evaluate_checkpoint(
     ckpt: str,

@@ -27,10 +27,6 @@ import time
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from areal_tpu.utils.jaxenv import apply_jax_platform_override
-
-apply_jax_platform_override()
-
 import jax
 import numpy as np
 

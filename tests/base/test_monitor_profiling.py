@@ -120,10 +120,13 @@ def test_time_marks():
 
 
 class _FakeDev:
-    def __init__(self, stats):
+    def __init__(self, stats, platform="cpu"):
         self._stats = stats
+        self.platform = platform
 
     def memory_stats(self):
+        if isinstance(self._stats, Exception):
+            raise self._stats
         return self._stats
 
 
@@ -146,6 +149,18 @@ def test_device_memory_stats_aggregates():
 def test_device_memory_stats_no_backend_support():
     s = monitor.device_memory_stats([_FakeDev(None)])
     assert s["mem_bytes_limit"] == 0 and s["mem_frac_in_use"] == 0.0
+
+
+def test_device_memory_stats_strict_on_tpu():
+    """Absent or failing stats are zeros off-TPU but an error on a TPU,
+    where they would turn the OOM guard into a no-op."""
+    assert monitor.device_peak_bytes([_FakeDev(RuntimeError("x"))]) == [0]
+    ok = _FakeDev({"peak_bytes_in_use": 7, "bytes_limit": 10}, "tpu")
+    assert monitor.device_peak_bytes([ok]) == [7]
+    with pytest.raises(RuntimeError, match="no memory_stats"):
+        monitor.device_memory_stats([_FakeDev(None, "tpu")])
+    with pytest.raises(RuntimeError, match="boom"):
+        monitor.device_memory_stats([_FakeDev(RuntimeError("boom"), "tpu")])
 
 
 def test_memory_kill_threshold(monkeypatch):

@@ -76,6 +76,8 @@ def test_train_batch_reduces_loss(mesh_spec):
         )
         losses.append(stats["sft/loss"])
         assert np.isfinite(stats["sft/grad_norm"])
+        # The realized parameter change: nonzero when the step took.
+        assert 0 < stats["sft/update_norm"] < np.inf
     assert losses[-1] < losses[0] * 0.9, losses
 
 
@@ -533,3 +535,21 @@ def test_offload_checkpoint_roundtrip(tmp_path):
     b = jax.tree_util.tree_leaves(eng2.get_params())
     for x, y in zip(a, b):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+def test_update_norm_is_the_change_that_survived_rounding():
+    """bf16 parameters under a step below their spacing do not move, and
+    `update_norm` says so (0), where the float32 update is plain."""
+    import jax.numpy as jnp
+
+    from areal_tpu.engine.jax_engine import apply_updates
+
+    u = {"w": jnp.ones((4,), jnp.float32)}
+    new, norm = apply_updates({"w": jnp.ones((4,), jnp.float32)}, u, 1e-3)
+    np.testing.assert_allclose(float(norm), 2e-3, rtol=1e-4)
+    # bf16 spacing at 1.0 is 2**-7: a 1e-3 step rounds away entirely.
+    p16 = {"w": jnp.ones((4,), jnp.bfloat16)}
+    new, norm = apply_updates(p16, u, 1e-3)
+    assert new["w"].dtype == jnp.bfloat16 and float(norm) == 0.0
+    new, norm = apply_updates(p16, u, 1e-2)
+    assert float(norm) > 0.0

@@ -6,6 +6,8 @@ test_cugae.py (CUDA-vs-Python parity), but the native side is the C++
 host library and the accelerator side is the lax.scan GAE.
 """
 
+import os
+
 import numpy as np
 import pytest
 
@@ -149,3 +151,42 @@ def test_gae_host_matches_jit_scan():
     )
     np.testing.assert_allclose(adv, np.asarray(jadv)[0], rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(ret, np.asarray(jret)[0], rtol=1e-4, atol=1e-4)
+
+
+def test_library_is_keyed_by_the_sources_content(tmp_path, monkeypatch):
+    """Built from what git would commit: the library's name carries the
+    source's hash, so an artifact left in the git-ignored build dir by
+    another source is never the one that loads."""
+    import hashlib
+
+    with open(host_ops._SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    path = host_ops._lib_path()
+    assert os.path.dirname(path) == host_ops._LIB_DIR
+    assert os.path.basename(path) == f"libareal_host_ops.{digest}.so"
+    assert host_ops.require_native() is True
+    assert os.path.exists(path)
+
+    # Another source maps to another file; a stale unkeyed artifact of
+    # the old scheme sits beside it untouched and unloaded.
+    other = tmp_path / "host_ops.cpp"
+    other.write_bytes(open(host_ops._SRC, "rb").read() + b"\n// changed\n")
+    monkeypatch.setattr(host_ops, "_SRC", str(other))
+    host_ops._lib_path.cache_clear()
+    try:
+        assert host_ops._lib_path() != path
+    finally:
+        monkeypatch.undo()
+        host_ops._lib_path.cache_clear()
+    assert host_ops._lib_path() == path
+
+
+def test_require_native_raises_with_the_reason(monkeypatch):
+    """On the chip path a failed build is an error (utils/jaxenv.
+    report_devices calls this on a TPU), with the compiler's output."""
+    monkeypatch.setattr(host_ops, "_lib", None)
+    monkeypatch.setattr(host_ops, "_load_failed", True)
+    monkeypatch.setattr(host_ops, "_failure", "g++: fatal error: boom")
+    assert host_ops.native_available() is False
+    with pytest.raises(RuntimeError, match="g\\+\\+: fatal error: boom"):
+        host_ops.require_native()

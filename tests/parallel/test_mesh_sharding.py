@@ -67,9 +67,7 @@ def test_sharded_forward_matches_single_device(spec_str):
     def f(p, i, s, pos):
         return forward(p, cfg, i, s, pos, attn_impl="reference")
 
-    from areal_tpu.utils.jax_compat import set_mesh
-
-    with set_mesh(mesh):
+    with jax.sharding.set_mesh(mesh):
         out = f(sharded, *args)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=2e-4, rtol=2e-4)
 
@@ -85,17 +83,49 @@ def test_reshard_between_meshes():
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
 
 
-def test_allocation_mode_partitions():
-    am = AllocationMode.parse("gen.d4t1+d2t2")
+def _assert_disjoint_cover(chips, n):
+    owned = sorted(c for cs in chips.values() for c in cs)
+    assert owned == list(range(n)), chips  # disjoint AND covering
+
+
+def test_allocation_mode_worker_chips():
+    """allocation_mode -> one chip set per chip-holding worker process:
+    gen slices first (server i = i-th slice), then train slices."""
+    chips = AllocationMode.parse("d1").worker_chips(0, 1)
+    assert chips == {"model_worker/0": [0]}
+
+    chips = AllocationMode.parse("gen.d1+d1").worker_chips(1, 1)
+    assert chips == {"generation_server/0": [0], "model_worker/0": [1]}
+    _assert_disjoint_cover(chips, 2)
+
+    # Two one-chip servers and an fsdp-2 trainer on a 2x2 host.
+    am = AllocationMode.parse("gen.d2t1+d1f2")
     assert am.decoupled
-    parts = am.partitions(8)
-    assert parts["gen"].device_ids == [0, 1, 2, 3]
-    assert parts["train"].device_ids == [4, 5, 6, 7]
-    am2 = AllocationMode.parse("d4t2")
-    assert not am2.decoupled
-    assert am2.partitions(8)["train"].mesh_spec.size == 8
-    with pytest.raises(ValueError):
-        AllocationMode.parse("gen.d8t1+d8t1").partitions(8)
+    chips = am.worker_chips(2, 1)
+    assert chips == {
+        "generation_server/0": [0],
+        "generation_server/1": [1],
+        "model_worker/0": [2, 3],
+    }
+    _assert_disjoint_cover(chips, 4)
+
+    # One TP-2 server, the train data axis spread over two workers.
+    chips = AllocationMode.parse("gen.d1t2+d2").worker_chips(1, 2)
+    assert chips == {
+        "generation_server/0": [0, 1],
+        "model_worker/0": [2],
+        "model_worker/1": [3],
+    }
+    _assert_disjoint_cover(chips, 4)
+
+    # Colocated: generation servers have no partition of their own.
+    assert AllocationMode.parse("d4t2").worker_chips(2, 1) == {
+        "model_worker/0": list(range(8))
+    }
+    # Not divisible among the processes: refused. (Larger than the host:
+    # refused where the host is known, tests/system/test_chip_assignment.py.)
+    with pytest.raises(ValueError, match="do not split evenly"):
+        AllocationMode.parse("gen.d3+d1").worker_chips(2, 1)
 
 
 def test_param_version_roundtrip(tmp_path):

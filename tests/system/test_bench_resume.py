@@ -50,11 +50,11 @@ def test_stale_record_not_banked(bank_env):
 
 def test_failed_record_not_banked(bank_env):
     bank.write_record(bank.make_record("gen_tps", "measure", "failed",
-                                       error="tunnel dropped"))
+                                       error="backend lost"))
     assert not bank.is_banked(None, "gen_tps", "measure", "cpu")
     # ...but it IS loadable evidence of the failure.
     rec = bank.load_record(bank.bank_dir(None), "gen_tps", "measure")
-    assert rec["error"] == "tunnel dropped"
+    assert rec["error"] == "backend lost"
 
 
 def test_cpu_record_never_clobbers_tpu_evidence(bank_env):

@@ -117,6 +117,21 @@ def test_build_sft_and_ppo_experiments(tmp_path):
     assert exp.generation_servers[0].tokenizer_path == tok_dir
     assert exp.model_workers[0].stream_dataset
     build_graph(exp.master.rpcs)
+    # Colocated default: servers have no chips of their own to be given.
+    assert exp.generation_servers[0].chips is None
+    assert exp.model_workers[0].chips == [0]
+
+    # Decoupled: server i owns the i-th slice of the gen partition, the
+    # trainer the chips after it; the slice must match the server's TP.
+    acfg.allocation_mode = "gen.d2t1+d1f2"
+    acfg.n_generation_servers = 2
+    exp = make_experiment("async-ppo-math", acfg)
+    assert [g.chips for g in exp.generation_servers] == [[0], [1]]
+    assert exp.model_workers[0].chips == [2, 3]
+    assert exp.model_workers[0].shards[0].model.args["device_ids"] == [0, 1]
+    acfg.gen_tensor_parallel = 2
+    with pytest.raises(ValueError, match="gen_tensor_parallel=2"):
+        make_experiment("async-ppo-math", acfg)
 
 
 def test_allocation_mode_drives_train_mesh(tmp_path):
@@ -137,17 +152,21 @@ def test_allocation_mode_drives_train_mesh(tmp_path):
     assert n == 2
     spec, devs = C.train_mesh_for_worker(cfg, 1, n)
     assert spec == "d1f2s1t2"
-    assert devs == [4, 5, 6, 7]  # worker 1's contiguous slice
+    # device_ids are LOCAL: the launcher shows worker 1 only its chips
+    # (4..7 of the host), which it sees as devices 0..3.
+    assert devs == [0, 1, 2, 3]
     exp = make_experiment("sft", cfg)
     m = exp.model_workers[1].shards[0].model
     assert m.args["mesh_spec"] == "d1f2s1t2"
-    assert m.args["device_ids"] == [4, 5, 6, 7]
+    assert m.args["device_ids"] == [0, 1, 2, 3]
+    assert [w.chips for w in exp.model_workers] == [[0, 1, 2, 3], [4, 5, 6, 7]]
 
-    # Decoupled: the train partition starts after the gen partition.
+    # Decoupled: the train partition's chips start after the gen partition.
     cfg.allocation_mode = "gen.d2t1+d1f2"
     spec, devs = C.train_mesh_for_worker(cfg, 0, 1)
     assert spec == "d1f2s1t1"
-    assert devs == [2, 3]
+    assert devs == [0, 1]
+    assert C.worker_chips(cfg, 1)["model_worker/0"] == [2, 3]
 
     # Multi-host: one worker per host, GLOBAL mesh, lockstep dataset.
     cfg.allocation_mode = "d2f2"
@@ -157,6 +176,7 @@ def test_allocation_mode_drives_train_mesh(tmp_path):
     assert spec == "d2f2s1t1" and devs is None
     exp = make_experiment("sft", cfg)
     for i, w in enumerate(exp.model_workers):
+        assert w.chips is None  # each worker owns its whole host
         assert (w.train_n_hosts, w.train_host_rank) == (2, i)
         assert (w.dataset_dp_rank, w.dataset_dp_size) == (0, 1)
 

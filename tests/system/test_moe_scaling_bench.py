@@ -13,11 +13,9 @@ evidence here; absolute step times only mean anything on-chip.
 
 The phase runs through the REAL bench runner (own subprocess +
 PhaseSpec.env 2-fake-device mesh + child-banked attested record) — the
-exact path the daemon takes, and the same jax 0.4.x
-suite-state-sensitivity sidestep test_train_sharded_bench.py documents.
+production path.
 
-Time budget: ~40 s (child imports + live compiles; the phase opts out
-of the persistent XLA cache)."""
+Time budget: ~40 s (child imports + compiles)."""
 
 import importlib.util
 import json

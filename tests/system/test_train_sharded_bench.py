@@ -10,15 +10,11 @@ independent, which is why a CPU-proxy record is real evidence here.
 
 The phase runs through the REAL bench runner (its own subprocess +
 PhaseSpec.env 2-fake-device mesh + child-banked attested record) — the
-exact path the daemon takes in production. Subprocess isolation is
-also load-bearing: in this container's jax 0.4.37, compiling the same
-tiny model on three meshes inside a process that already ran the full
-suite aborts natively in the XLA CPU client (suite-state sensitivity;
-standalone in-process runs pass) — the runner child sidesteps the
-whole class, exactly as it does for real TPU windows.
+production path. (On jax 0.9.0 the phase also runs in-process, with a
+cold or a warm persistent cache; the subprocess is the runner's
+contract, no longer a workaround.)
 
-Time budget: ~45 s (child imports + live compiles: the phase opts out
-of the persistent XLA cache, see workloads._without_persistent_xla_cache);
+Time budget: ~45 s (child imports + compiles);
 tier-1 headroom is tracked per PR 7's discipline."""
 
 import importlib.util

@@ -262,3 +262,38 @@ def test_param_realloc_dst_falls_back_to_raw_dump(tmp_path):
     got, v = wt.load_raw_params(d)
     assert v == 4
     assert_trees_bitwise_equal(tree, got)
+
+
+def test_manager_sees_a_sharded_trainers_dump_on_the_disk_path(
+    tmp_path, monkeypatch
+):
+    """A sharded trainer (mesh > 1) publishes only the shard-local raw
+    dump — no engine_state.pkl. The manager's disk path used to wait for
+    the pickle, so with an fsdp trainer and no weight plane no update
+    ever reached a server and the async loop stalled once the staleness
+    budget was spent (PR 21, found by chip_smoke's rehearsal)."""
+    from types import SimpleNamespace
+
+    from areal_tpu.base import constants
+    from areal_tpu.system.gserver_manager import GserverManager
+
+    monkeypatch.setattr(
+        constants, "get_param_realloc_path", lambda exp, trial: str(tmp_path)
+    )
+    mgr = GserverManager.__new__(GserverManager)
+    mgr.cfg = SimpleNamespace(
+        experiment_name="e", trial_name="t", model_name="actor"
+    )
+    d = tmp_path / "actor"
+    d.mkdir()
+    assert not wt.has_raw_dump(str(d))
+    assert mgr._current_param_path() is None  # nothing published yet
+    wt.dump_raw_params_sharded(
+        f2_sharded(make_tree()), str(d), version=1, chunk_bytes=CB
+    )
+    assert not (d / "engine_state.pkl").exists()
+    assert wt.has_raw_dump(str(d))
+    assert mgr._current_param_path() == str(d)
+    # ... and what it points the servers at loads at that version.
+    _, info = wt.load_for_serving(str(d), want_version=1, retries=1)
+    assert info["version"] == 1 and info["source"] == "disk_raw"

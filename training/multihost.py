@@ -59,10 +59,6 @@ def host_main(
     determinism of the dataloader (same seed, same files) keeps the hosts
     dispatching identical programs, which is the SPMD contract.
     """
-    from areal_tpu.utils.jaxenv import apply_jax_platform_override
-
-    apply_jax_platform_override()
-
     from areal_tpu.parallel.distributed import setup_host_group
 
     if cfg.name_resolve_root:
