@@ -1,19 +1,32 @@
-"""Request-scoped distributed tracing for the RL system plane.
+"""Request-scoped distributed tracing for the RL system plane, and the
+one runtime control over it and the device profiler.
 
 AReaL's headline claims (rollout/train overlap, staleness-gated
-admission, cheap interruption resumption) are timeline claims, but
-`utils/profiling.py` only captures per-worker XLA traces. This module
-records *RL-level* spans — one rollout's life across the rollout worker,
-gserver manager, generation server, reward verifier, buffer, and
-trainer — into per-worker JSONL shards that
-`areal_tpu/utils/rl_trace.py` merges into one Chrome-trace/Perfetto
-timeline with flow links per rollout.
+admission, cheap interruption resumption) are timeline claims. This
+module records *RL-level* spans — one rollout's life across the rollout
+worker, gserver manager, generation server, reward verifier, buffer, and
+trainer, and inside the trainer one PPO step down to each dispatch —
+into per-worker JSONL shards that `areal_tpu/utils/rl_trace.py` merges
+into one Chrome-trace/Perfetto timeline with flow links per rollout.
+
+Two switches turn it on: the environment (`AREAL_RL_TRACE`, on from the
+process's first call) and `start()` / `stop()`, callable in a running
+process from any thread. `start(profile_dir)` is also the program's one
+place that starts `jax.profiler` (`utils/profiling.py::maybe_profile`
+and the benchmark's traced window both come through it); while the
+profiler runs, every `span()` is mirrored into the device trace as a
+`TraceAnnotation("areal/<name>")`, so host spans and device ops share
+the trace's clock. `stop()` returns what was recorded since `start()`
+from memory.
 
 Design constraints:
 
 - Hard no-op by default: every public call starts with one cached
   boolean branch; the recorder object is never allocated unless
-  AREAL_RL_TRACE is truthy (pinned by tests/base/test_rl_tracing.py).
+  AREAL_RL_TRACE is truthy or `start()` was called (pinned by
+  tests/base/test_rl_tracing.py).
+- A span never synchronises with the device: it reads the host clock
+  twice and nothing else. Device time comes from the profiler.
 - Thread-safe: spans are appended to a bounded ring buffer under a lock
   and flushed to the shard in batches (overflow drops the OLDEST spans
   and counts them — tracing must never block or OOM the hot path).
@@ -31,7 +44,9 @@ Design constraints:
 Environment knobs:
 
 - AREAL_RL_TRACE=1          enable (anything not in {"", "0", "false"})
-- AREAL_RL_TRACE_DIR=<dir>  shard root (default /tmp/areal_tpu/rl_trace)
+- AREAL_RL_TRACE_DIR=<dir>  shard root (default /tmp/areal_tpu/rl_trace);
+                            a `start()`ed recorder writes a shard only
+                            when this is set or AREAL_RL_TRACE is on
 - AREAL_RL_TRACE_RING=<n>   ring-buffer capacity (default 65536 spans)
 
 See docs/observability.md for the span model and how to read the merged
@@ -74,10 +89,24 @@ _WORKER: Optional[str] = None
 # setting it own its freshness.
 _SCOPE: Optional[str] = None
 
+# The runtime control (start()/stop()). _SESSION is None outside a
+# session, else what stop() will add to the recorder's part of its answer.
+# _MIRROR is the profiler's TraceAnnotation class while this control has
+# the profiler running, else None: span() tests it once to decide whether
+# to mirror itself into the device trace.
+_CTL_LOCK = threading.Lock()
+_SESSION: Optional[Dict[str, Any]] = None
+_MIRROR: Optional[type] = None
+_MIRROR_PREFIX = "areal/"
+
 _CTX_KEY = "__rl_trace__"
 
 _current: contextvars.ContextVar[Optional["SpanContext"]] = (
     contextvars.ContextVar("areal_rl_trace_ctx", default=None)
+)
+# The attrs dict of the innermost open span(), for set_attrs().
+_live_attrs: contextvars.ContextVar[Optional[Dict[str, Any]]] = (
+    contextvars.ContextVar("areal_rl_trace_attrs", default=None)
 )
 
 
@@ -125,9 +154,10 @@ def configure_worker(
 
 def reconfigure() -> None:
     """Re-read the environment (tests flip AREAL_RL_TRACE in-process;
-    production workers inherit it at spawn and never need this). Flushes
-    and drops any live recorder."""
+    production workers inherit it at spawn and never need this). Ends a
+    `start()`ed session, flushes and drops any live recorder."""
     global _ENABLED, _REC
+    stop()
     with _REC_LOCK:
         if _REC is not None:
             _REC.flush()
@@ -148,25 +178,57 @@ def _new_id() -> str:
 
 
 class _Recorder:
-    """Bounded ring buffer of span dicts + batched JSONL shard writer."""
+    """Bounded ring buffer of span dicts + batched JSONL shard writer,
+    the counters, and between `start()` and `stop()` the session: the
+    same spans kept in memory (bounded like the ring) for `stop()` to
+    return. Without a shard (`to_file` false: started at run time with
+    no AREAL_RL_TRACE_DIR) the session is all there is."""
 
-    def __init__(self, worker: str):
+    def __init__(self, worker: str, to_file: bool = True):
         self.worker = worker
         self.capacity = env_registry.get_int(_ENV_RING)
         self._buf: List[Dict] = []
         self._lock = threading.Lock()
         self.n_dropped = 0
+        self.counters: Dict[str, float] = {}
+        self._session: Optional[List[Dict]] = None
+        self._session_dropped = 0
         self.anchor_wall_ns = time.time_ns()
         self.anchor_mono_ns = time.monotonic_ns()
-        d = trace_dir()
-        os.makedirs(d, exist_ok=True)
-        safe = worker.replace("/", "_").replace(os.sep, "_")
-        self.path = os.path.join(d, f"{safe}.{os.getpid()}.jsonl")
+        self.path: Optional[str] = None
+        if to_file:
+            d = trace_dir()
+            os.makedirs(d, exist_ok=True)
+            safe = worker.replace("/", "_").replace(os.sep, "_")
+            self.path = os.path.join(d, f"{safe}.{os.getpid()}.jsonl")
         self._header_written = False
+
+    def begin_session(self) -> None:
+        with self._lock:
+            self._session, self._session_dropped = [], 0
+            self.counters = {}
+
+    def end_session(self) -> Dict[str, Any]:
+        with self._lock:
+            spans, self._session = self._session or [], None
+            return {"spans": spans, "counters": dict(self.counters),
+                    "dropped": self._session_dropped}
+
+    def count(self, name: str, n: float) -> None:
+        with self._lock:
+            self.counters[name] = self.counters.get(name, 0) + n
 
     def append(self, rec: Dict) -> None:
         flush_now = False
         with self._lock:
+            if self._session is not None:
+                if len(self._session) >= self.capacity:
+                    drop = self.capacity // 2
+                    del self._session[:drop]
+                    self._session_dropped += drop
+                self._session.append(rec)
+            if self.path is None:
+                return
             if len(self._buf) >= self.capacity:
                 # Overflow: drop the oldest half rather than blocking the
                 # hot path or growing without bound.
@@ -184,6 +246,8 @@ class _Recorder:
         # interleave >8KB TextIOWrapper chunks mid-line and corrupt the
         # JSONL shard. Flushes are rare (every 512 spans), so briefly
         # blocking a concurrent append is the cheaper correctness.
+        if self.path is None:
+            return
         with self._lock:
             batch, self._buf = self._buf, []
             header = None
@@ -231,7 +295,10 @@ def _rec() -> _Recorder:
     if _REC is None:
         with _REC_LOCK:
             if _REC is None:
-                _REC = _Recorder(_WORKER or f"proc{os.getpid()}")
+                to_file = env_registry.get_bool(_ENV_ENABLE) or bool(
+                    env_registry.get_str(_ENV_DIR)
+                )
+                _REC = _Recorder(_WORKER or f"proc{os.getpid()}", to_file)
                 atexit.register(_REC.flush)
     return _REC
 
@@ -240,6 +307,101 @@ def recorder() -> Optional[_Recorder]:
     """The live recorder, or None when tracing never recorded (the
     disabled-mode test pins exactly this)."""
     return _REC
+
+
+# ---------------------------------------------------------------------------
+# The runtime control
+# ---------------------------------------------------------------------------
+
+
+def start(profile_dir: Optional[str] = None) -> bool:
+    """Turn span recording on in this running process, from any thread;
+    with `profile_dir` also start `jax.profiler` there (host tracer level
+    2, no Python tracer) and mirror every `span()` into its trace. The
+    first thing written to that trace is the marker annotation
+    `areal/clock_anchor`, whose span record carries the `monotonic_ns`
+    read inside it: the marker's time in the `.xplane.pb` minus that
+    value is the offset between the trace's clock and the spans'.
+
+    Idempotent: a second `start()` before `stop()` changes nothing and
+    returns False; True says this call started the session (and is the
+    one that should stop it)."""
+    global _ENABLED, _SESSION, _MIRROR
+    with _CTL_LOCK:
+        if _SESSION is not None:
+            return False
+        _ENABLED = True
+        session: Dict[str, Any] = {"profile_dir": None, "clock_anchor": None}
+        try:
+            _rec().begin_session()
+            if profile_dir is not None:
+                import jax
+
+                opts = jax.profiler.ProfileOptions()
+                opts.python_tracer_level = 0  # host spans are annotations
+                opts.host_tracer_level = 2
+                jax.profiler.start_trace(profile_dir, profiler_options=opts)
+                session["profile_dir"] = profile_dir
+                _MIRROR = jax.profiler.TraceAnnotation
+        except BaseException:
+            _ENABLED = env_registry.get_bool(_ENV_ENABLE)
+            if _REC is not None:
+                _REC.end_session()
+            raise
+        _SESSION = session
+        if _MIRROR is not None:
+            name = _MIRROR_PREFIX + "clock_anchor"
+            with _MIRROR(name):
+                mono = time.monotonic_ns()
+            session["clock_anchor"] = {"name": name, "monotonic_ns": mono}
+            record_span("clock_anchor", mono, monotonic_ns=mono)
+        return True
+
+
+def stop() -> Dict[str, Any]:
+    """End the session `start()` began: recording goes back to what the
+    environment says, the profiler stops if this control started it, and
+    what was recorded since `start()` comes back from memory:
+    `{"spans": [...], "counters": {...}, "dropped": n, "profile_dir": ...,
+    "clock_anchor": {"name", "monotonic_ns"} | None}`. The JSONL shard,
+    where there is one, is flushed too. Without a session: the same
+    dict, empty."""
+    global _ENABLED, _SESSION, _MIRROR
+    with _CTL_LOCK:
+        if _SESSION is None:
+            return {"spans": [], "counters": {}, "dropped": 0,
+                    "profile_dir": None, "clock_anchor": None}
+        session, _SESSION = _SESSION, None
+        _ENABLED = env_registry.get_bool(_ENV_ENABLE)
+        try:
+            if _MIRROR is not None:
+                _MIRROR = None
+                import jax
+
+                jax.profiler.stop_trace()
+        finally:
+            rec = _rec()
+            out = rec.end_session()
+            rec.flush()
+        return {**out, **session}
+
+
+def count(name: str, n: float = 1) -> None:
+    """Add to a counter kept in the recorder (`stop()` returns them)."""
+    if not enabled():
+        return
+    _rec().count(name, n)
+
+
+def set_attrs(**attrs: Any) -> None:
+    """Add attributes to the innermost open `span()` of this thread or
+    task: for what is only known once the work is under way. Decided by
+    the span, not by the switch: a span opened while recording was on
+    keeps its attributes when `stop()` falls in its middle (it may be
+    recorded by the next session)."""
+    live = _live_attrs.get()
+    if live is not None:
+        live.update(attrs)
 
 
 def flush() -> None:
@@ -370,10 +532,18 @@ def span(
     `ctx` overrides the parent (e.g. a context extracted from transport
     metadata). Without a parent, the span starts a NEW trace. Yields the
     span's own context (None when disabled) so callers can stash it.
+
+    While `start(profile_dir)` has the profiler running, the span is also
+    a `TraceAnnotation("areal/<name>")` on its thread's line of the device
+    trace. `record_span` and `ManualSpan` (ended later, perhaps on another
+    thread) are not mirrored: an annotation must open and close on one
+    thread.
     """
     if not enabled():
         yield None
         return
+    mirror = _MIRROR
+    ann = mirror(_MIRROR_PREFIX + name) if mirror is not None else None
     parent = ctx if ctx is not None else _current.get()
     if parent is not None:
         trace_id, parent_id = parent.trace_id, parent.span_id
@@ -381,11 +551,17 @@ def span(
         trace_id, parent_id = _new_id(), None
     me = SpanContext(trace_id=trace_id, span_id=_new_id())
     token = _current.set(me)
+    attrs_token = _live_attrs.set(attrs)
+    if ann is not None:
+        ann.__enter__()
     t0 = time.monotonic_ns()
     try:
         yield me
     finally:
         t1 = time.monotonic_ns()
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        _live_attrs.reset(attrs_token)
         _current.reset(token)
         _record(name, t0, t1, trace_id, me.span_id, parent_id, attrs)
 

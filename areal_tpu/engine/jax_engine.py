@@ -37,7 +37,7 @@ from areal_tpu.api.data_api import MicroBatchSpec, SequenceSample
 from areal_tpu.api.model_api import GenerationHyperparameters, TrainEngine
 from areal_tpu.base import env_registry
 from areal_tpu.base import logging as areal_logging
-from areal_tpu.base import stats_tracker
+from areal_tpu.base import stats_tracker, tracing
 from areal_tpu.models.config import TransformerConfig
 from areal_tpu.models.generation import generate_tokens
 from areal_tpu.models.packing import PackedBatch, pack_sequences
@@ -291,6 +291,12 @@ class JaxTrainEngine(TrainEngine):
             return p["embedding"]["weight"].T
         return p["head"]["weight"]
 
+    def _built(self, key, programs):
+        """A new entry of the jit cache, counted where it is made."""
+        self._jit_cache[key] = programs
+        tracing.count("train.programs_built")
+        return programs
+
     def _mb_loss_fn(self, loss_fn: PackedLossFn):
         """loss over one micro-batch's rows: (params, rows) -> (loss_sum, aux).
 
@@ -362,6 +368,17 @@ class JaxTrainEngine(TrainEngine):
 
         return compute
 
+    def _optimizer_apply(self, params, opt_state, grads, inv_denom, lr):
+        """The tail both step programs share, traced under one scope:
+        1/global_denom normalization, grad norm, optimizer update at a
+        unit LR, `p + lr * u` and the norm of what survived rounding."""
+        with jax.named_scope("optimizer_apply"):
+            grads = jax.tree_util.tree_map(lambda g: g * inv_denom, grads)
+            gnorm = optax_global_norm(grads)
+            updates, opt_state = self.optimizer.update(grads, opt_state, params)
+            params, unorm = apply_updates(params, updates, lr)
+        return params, opt_state, gnorm, unorm
+
     def _train_step_fn(self, loss_name: str, loss_fn: PackedLossFn,
                        row_keys: Tuple[str, ...], n_mbs: int):
         """One fused jitted program for the whole train step: micro-batch
@@ -386,9 +403,10 @@ class JaxTrainEngine(TrainEngine):
                     (loss, aux), g = jax.value_and_grad(mb_loss, has_aux=True)(
                         params, mb_rows
                     )
-                    grads_acc = jax.tree_util.tree_map(
-                        lambda a, b: a + b.astype(jnp.float32), grads_acc, g
-                    )
+                    with jax.named_scope("grad_accum"):
+                        grads_acc = jax.tree_util.tree_map(
+                            lambda a, b: a + b.astype(jnp.float32), grads_acc, g
+                        )
                     return grads_acc, (loss, aux)
 
                 grads0 = jax.tree_util.tree_map(
@@ -401,17 +419,18 @@ class JaxTrainEngine(TrainEngine):
                 (loss_sum, aux), grads = jax.value_and_grad(mb_loss, has_aux=True)(
                     params, rows
                 )
-                grads = jax.tree_util.tree_map(
-                    lambda g: g.astype(jnp.float32), grads
-                )
+                with jax.named_scope("grad_accum"):
+                    grads = jax.tree_util.tree_map(
+                        lambda g: g.astype(jnp.float32), grads
+                    )
 
-            grads = jax.tree_util.tree_map(lambda g: g * inv_denom, grads)
-            gnorm = optax_global_norm(grads)
-            updates, opt_state = self.optimizer.update(grads, opt_state, params)
-            # The optimizer ran with a unit LR; scale by the schedule
-            # value for this version (multiplication commutes bitwise,
-            # so the math equals an internal-schedule adamw at this lr).
-            params, unorm = apply_updates(params, updates, lr)
+            # The optimizer runs with a unit LR; `_optimizer_apply` scales
+            # by the schedule value for this version (multiplication
+            # commutes bitwise, so the math equals an internal-schedule
+            # adamw at this lr).
+            params, opt_state, gnorm, unorm = self._optimizer_apply(
+                params, opt_state, grads, inv_denom, lr
+            )
             params = jax.lax.with_sharding_constraint(params, self._param_shardings)
             opt_state = jax.lax.with_sharding_constraint(
                 opt_state, self._opt_shardings
@@ -428,8 +447,7 @@ class JaxTrainEngine(TrainEngine):
             )
             return params, opt_state, packed, aux
 
-        self._jit_cache[key] = jax.jit(step, donate_argnums=(0, 1))
-        return self._jit_cache[key]
+        return self._built(key, jax.jit(step, donate_argnums=(0, 1)))
 
     def _accum_step_fns(self, loss_name: str, loss_fn: PackedLossFn,
                         row_keys: Tuple[str, ...]):
@@ -446,9 +464,10 @@ class JaxTrainEngine(TrainEngine):
         mb_loss = self._mb_loss_fn(loss_fn)
 
         def to_f32(tree):
-            return jax.tree_util.tree_map(
-                lambda x: x.astype(jnp.float32), tree
-            )
+            with jax.named_scope("grad_accum"):
+                return jax.tree_util.tree_map(
+                    lambda x: x.astype(jnp.float32), tree
+                )
 
         def first(params, rows):
             (loss, aux), g = jax.value_and_grad(mb_loss, has_aux=True)(
@@ -461,33 +480,33 @@ class JaxTrainEngine(TrainEngine):
             (loss, aux), g = jax.value_and_grad(mb_loss, has_aux=True)(
                 params, rows
             )
-            g_acc = jax.tree_util.tree_map(
-                lambda a, b: a + b.astype(jnp.float32), g_acc, g
-            )
-            aux_acc = jax.tree_util.tree_map(
-                lambda a, b: a + b.astype(jnp.float32), aux_acc, aux
-            )
-            return g_acc, loss_acc + loss.astype(jnp.float32), aux_acc
+            with jax.named_scope("grad_accum"):
+                g_acc = jax.tree_util.tree_map(
+                    lambda a, b: a + b.astype(jnp.float32), g_acc, g
+                )
+                aux_acc = jax.tree_util.tree_map(
+                    lambda a, b: a + b.astype(jnp.float32), aux_acc, aux
+                )
+                loss_acc = loss_acc + loss.astype(jnp.float32)
+            return g_acc, loss_acc, aux_acc
 
-        fns = (jax.jit(first), jax.jit(nxt, donate_argnums=(1,)))
-        self._jit_cache[key] = fns
-        return fns
+        return self._built(
+            key, (jax.jit(first), jax.jit(nxt, donate_argnums=(1,)))
+        )
 
     def _apply_step_fn(self, loss_name: str):
-        """Optimizer apply for the pipelined path: 1/global_denom
-        normalization, grad norm, update, sharding constraints and the
-        single packed stats vector — line-for-line the tail of the fused
-        train program."""
+        """Optimizer apply for the pipelined path: `_optimizer_apply`,
+        sharding constraints and the single packed stats vector — the
+        tail of the fused train program."""
         key = ("apply", loss_name)
         if key in self._jit_cache:
             return self._jit_cache[key]
 
         def apply(params, opt_state, carry, inv_denom, lr):
             grads, loss_sum, aux = carry
-            grads = jax.tree_util.tree_map(lambda g: g * inv_denom, grads)
-            gnorm = optax_global_norm(grads)
-            updates, opt_state = self.optimizer.update(grads, opt_state, params)
-            params, unorm = apply_updates(params, updates, lr)
+            params, opt_state, gnorm, unorm = self._optimizer_apply(
+                params, opt_state, grads, inv_denom, lr
+            )
             params = jax.lax.with_sharding_constraint(
                 params, self._param_shardings
             )
@@ -501,8 +520,7 @@ class JaxTrainEngine(TrainEngine):
             )
             return params, opt_state, packed, aux
 
-        self._jit_cache[key] = jax.jit(apply, donate_argnums=(0, 1, 2))
-        return self._jit_cache[key]
+        return self._built(key, jax.jit(apply, donate_argnums=(0, 1, 2)))
 
     def warm(
         self,
@@ -730,80 +748,90 @@ class JaxTrainEngine(TrainEngine):
             raise ValueError(
                 f"unknown token_normalize_scope {token_normalize_scope!r}"
             )
-        lr_pos = self._lr_steps if version_steps is None else int(version_steps)
-        self._lr_steps += 1
-        lr = float(self._lr_schedule(lr_pos))
-        # The overlapped pipeline needs per-micro-batch programs; the
-        # fused path keeps the single donated executable. 'dp' scope stays
-        # fused (its per-shard denominators need every micro-batch's loss
-        # weights before the first dispatch) and so do serialized-dispatch
-        # CPU meshes (two collective-bearing executables must never be in
-        # flight there).
-        use_overlap = (
-            self.prefetch_depth > 0
-            and not self._serial_dispatch
-            and token_normalize_scope == "global"
-        )
-        if use_overlap:
-            mb_iter, groups, _, _ = input_.split_lazy(mb_spec)
-            if len(groups) > 1:
-                return self._train_batch_overlapped(
-                    mb_iter, len(groups), loss_fn, loss_weight_fn, loss_name,
-                    lr,
+        with tracing.span("train.batch"):
+            lr_pos = self._lr_steps if version_steps is None else int(version_steps)
+            self._lr_steps += 1
+            lr = float(self._lr_schedule(lr_pos))
+            # The overlapped pipeline needs per-micro-batch programs; the
+            # fused path keeps the single donated executable. 'dp' scope stays
+            # fused (its per-shard denominators need every micro-batch's loss
+            # weights before the first dispatch) and so do serialized-dispatch
+            # CPU meshes (two collective-bearing executables must never be in
+            # flight there).
+            use_overlap = (
+                self.prefetch_depth > 0
+                and not self._serial_dispatch
+                and token_normalize_scope == "global"
+            )
+            if use_overlap:
+                mb_iter, groups, _, _ = input_.split_lazy(mb_spec)
+                if len(groups) > 1:
+                    return self._train_batch_overlapped(
+                        mb_iter, len(groups), loss_fn, loss_weight_fn, loss_name,
+                        lr,
+                    )
+                # One micro-batch: nothing to pipeline against; run eagerly.
+                mbs = list(mb_iter)
+            else:
+                mbs, _, _ = input_.split(mb_spec)
+            global_denom = float(sum(loss_weight_fn(mb) for mb in mbs))
+            global_denom = max(global_denom, 1.0)
+
+            t_prep = time.monotonic_ns()
+            with tracing.span("train.pack"):
+                built = [self._build_rows(mb) for mb in mbs]
+                n_tok = sum(b.total_tokens for b, _ in built)
+                all_rows = [r for _, r in built]
+                if len(mbs) > 1:
+                    rows_np = self._stack_mb_rows(all_rows)
+                    sharding = jax.sharding.NamedSharding(
+                        self.mesh,
+                        jax.sharding.PartitionSpec(None, ("data", "fsdp"), "seq"),
+                    )
+                else:
+                    rows_np = all_rows[0]
+                    sharding = self._batch_sharding
+                if token_normalize_scope == "dp":
+                    rows_np = self._apply_dp_token_scale(
+                        rows_np, global_denom, dp_token_weights_fn
+                    )
+            with tracing.span("train.h2d"):
+                rows_dev = {
+                    k: jax.device_put(np.asarray(v), sharding)
+                    for k, v in rows_np.items()
+                }
+            prep_ms = (time.monotonic_ns() - t_prep) / 1e6
+            n_cells = int(np.prod(rows_np["input_ids"].shape))
+            # Eager-path telemetry: the whole pack+stack+H2D cost blocks the
+            # host before the single dispatch, so h2d_wait == dispatch gap ==
+            # the prep time (nothing is hidden).
+            self.last_overlap = {
+                "packing_efficiency": n_tok / max(n_cells, 1),
+                "h2d_wait_ms": prep_ms,
+                "dispatch_gap_ms": prep_ms,
+                "overlap_events": 0.0,
+            }
+            self._record_overlap_stats()
+            self._count_batch("fused", len(mbs), n_tok, n_cells)
+
+            step = self._train_step_fn(
+                loss_name, loss_fn, tuple(sorted(rows_np.keys())), len(mbs)
+            )
+            with tracing.span(
+                "train.dispatch", kind="fused",
+                rows=rows_np["input_ids"].shape[-2],
+                row_len=rows_np["input_ids"].shape[-1],
+            ):
+                self.params, self.opt_state, packed, aux = step(
+                    self.params, self.opt_state, rows_dev,
+                    jnp.asarray(1.0 / global_denom, jnp.float32),
+                    jnp.asarray(lr, jnp.float32),
                 )
-            # One micro-batch: nothing to pipeline against; run eagerly.
-            mbs = list(mb_iter)
-        else:
-            mbs, _, _ = input_.split(mb_spec)
-        global_denom = float(sum(loss_weight_fn(mb) for mb in mbs))
-        global_denom = max(global_denom, 1.0)
-
-        t_prep = time.perf_counter()
-        built = [self._build_rows(mb) for mb in mbs]
-        n_tok = sum(b.total_tokens for b, _ in built)
-        all_rows = [r for _, r in built]
-        if len(mbs) > 1:
-            rows_np = self._stack_mb_rows(all_rows)
-            sharding = jax.sharding.NamedSharding(
-                self.mesh,
-                jax.sharding.PartitionSpec(None, ("data", "fsdp"), "seq"),
+            if self._serial_dispatch:
+                jax.block_until_ready(self.params)
+            return self._fetch_train_stats(
+                packed, aux, loss_name, global_denom, len(mbs), lr
             )
-        else:
-            rows_np = all_rows[0]
-            sharding = self._batch_sharding
-        if token_normalize_scope == "dp":
-            rows_np = self._apply_dp_token_scale(
-                rows_np, global_denom, dp_token_weights_fn
-            )
-        rows_dev = {
-            k: jax.device_put(np.asarray(v), sharding) for k, v in rows_np.items()
-        }
-        prep_ms = (time.perf_counter() - t_prep) * 1e3
-        # Eager-path telemetry: the whole pack+stack+H2D cost blocks the
-        # host before the single dispatch, so h2d_wait == dispatch gap ==
-        # the prep time (nothing is hidden).
-        self.last_overlap = {
-            "packing_efficiency": n_tok
-            / max(int(np.prod(rows_np["input_ids"].shape)), 1),
-            "h2d_wait_ms": prep_ms,
-            "dispatch_gap_ms": prep_ms,
-            "overlap_events": 0.0,
-        }
-        self._record_overlap_stats()
-
-        step = self._train_step_fn(
-            loss_name, loss_fn, tuple(sorted(rows_np.keys())), len(mbs)
-        )
-        self.params, self.opt_state, packed, aux = step(
-            self.params, self.opt_state, rows_dev,
-            jnp.asarray(1.0 / global_denom, jnp.float32),
-            jnp.asarray(lr, jnp.float32),
-        )
-        if self._serial_dispatch:
-            jax.block_until_ready(self.params)
-        return self._fetch_train_stats(
-            packed, aux, loss_name, global_denom, len(mbs), lr
-        )
 
     def _train_batch_overlapped(
         self,
@@ -823,47 +851,63 @@ class JaxTrainEngine(TrainEngine):
         micro-batches stream through (it is only needed at the apply)."""
         from areal_tpu.engine.prefetch import HostPrefetcher
 
+        # The stage runs on the prefetcher's thread: hand it the batch's
+        # span so its spans stay in the step's trace.
+        parent = tracing.current()
+
         def stage(mb):
-            batch, rows = self._build_rows(mb)
-            denom = float(loss_weight_fn(mb))
-            rows_dev = {
-                k: jax.device_put(np.asarray(v), self._batch_sharding)
-                for k, v in rows.items()
-            }
-            return rows_dev, denom, batch.total_tokens, batch.n_rows * batch.row_len
+            with tracing.span("train.stage", ctx=parent):
+                with tracing.span("train.pack"):
+                    batch, rows = self._build_rows(mb)
+                denom = float(loss_weight_fn(mb))
+                with tracing.span("train.h2d"):
+                    rows_dev = {
+                        k: jax.device_put(np.asarray(v), self._batch_sharding)
+                        for k, v in rows.items()
+                    }
+                cells = batch.n_rows * batch.row_len
+                tracing.set_attrs(tokens=batch.total_tokens, cells=cells)
+            return rows_dev, denom, batch.total_tokens, cells
 
         pf = HostPrefetcher(
-            mb_iter, stage, depth=self.prefetch_depth, name=f"train/{loss_name}"
+            mb_iter, stage, depth=self.prefetch_depth, name=f"train/{loss_name}",
+            wait_span="train.wait_input",
         )
         carry = None
         nxt = None
         denom_sum, n_tok, n_cells = 0.0, 0, 0
         gaps_ms: List[float] = []
-        mark = time.perf_counter()
+        mark = time.monotonic_ns()
         try:
             for rows_dev, denom, tok, cells in pf:
-                now = time.perf_counter()
-                gaps_ms.append((now - mark) * 1e3)
+                gaps_ms.append((time.monotonic_ns() - mark) / 1e6)
                 denom_sum += denom
                 n_tok += tok
                 n_cells += cells
+                rows, row_len = rows_dev["input_ids"].shape
                 if carry is None:
                     first, nxt = self._accum_step_fns(
                         loss_name, loss_fn, tuple(sorted(rows_dev.keys()))
                     )
-                    carry = first(self.params, rows_dev)
+                    with tracing.span("train.dispatch", kind="first",
+                                      rows=rows, row_len=row_len):
+                        carry = first(self.params, rows_dev)
                 else:
-                    carry = nxt(self.params, carry, rows_dev)
-                mark = time.perf_counter()
+                    with tracing.span("train.dispatch", kind="next",
+                                      rows=rows, row_len=row_len):
+                        carry = nxt(self.params, carry, rows_dev)
+                mark = time.monotonic_ns()
         finally:
             pf.close()
         global_denom = max(denom_sum, 1.0)
         apply = self._apply_step_fn(loss_name)
-        self.params, self.opt_state, packed, aux = apply(
-            self.params, self.opt_state, carry,
-            jnp.asarray(1.0 / global_denom, jnp.float32),
-            jnp.asarray(lr, jnp.float32),
-        )
+        with tracing.span("train.apply"):
+            self.params, self.opt_state, packed, aux = apply(
+                self.params, self.opt_state, carry,
+                jnp.asarray(1.0 / global_denom, jnp.float32),
+                jnp.asarray(lr, jnp.float32),
+            )
+        self._count_batch("overlapped", n_mbs, n_tok, n_cells)
         self.last_overlap = {
             "packing_efficiency": n_tok / max(n_cells, 1),
             "h2d_wait_ms": pf.wait_ms,
@@ -874,6 +918,17 @@ class JaxTrainEngine(TrainEngine):
         return self._fetch_train_stats(
             packed, aux, loss_name, global_denom, n_mbs, lr
         )
+
+    @staticmethod
+    def _count_batch(path: str, n_mbs: int, n_tok: int, n_cells: int):
+        """What one train_batch did, on its `train.batch` span and in the
+        recorder's counters: real tokens and the cells (rows x row length)
+        they were padded to."""
+        tracing.set_attrs(path=path, n_mbs=n_mbs, tokens=n_tok, cells=n_cells)
+        tracing.count("train.batches")
+        tracing.count("train.micro_batches", n_mbs)
+        tracing.count("train.tokens", n_tok)
+        tracing.count("train.cells", n_cells)
 
     def _record_overlap_stats(self):
         """Ship the last pipeline's telemetry through the stats tracker so
@@ -958,10 +1013,13 @@ class JaxTrainEngine(TrainEngine):
             stats[f"{loss_name}/lr"] = lr  # host-side: exact even when stale
             stats[f"{loss_name}/stats_stale"] = 1.0
             self._record_moe_stats(stats, loss_name)
+            tracing.event("train.fetch_stats", stale=True)
             return stats
         aux_leaves, aux_treedef = jax.tree_util.tree_flatten(aux)
         del aux_leaves
-        p = np.asarray(packed)
+        # Where the host waits for the device: the step's one fetch.
+        with tracing.span("train.fetch_stats", stale=False):
+            p = np.asarray(packed)
         loss_sum, gnorm, unorm = float(p[0]), float(p[1]), float(p[2])
         aux_vals = jax.tree_util.tree_unflatten(aux_treedef, p[3:].tolist())
         stats = {
@@ -1012,7 +1070,7 @@ class JaxTrainEngine(TrainEngine):
                     )
                 return out  # [R, T] values or [R, T, V] logits
 
-            self._jit_cache[key] = jax.jit(fwd)
+            self._built(key, jax.jit(fwd))
         return self._jit_cache[key]
 
     def forward(
@@ -1052,17 +1110,16 @@ class JaxTrainEngine(TrainEngine):
             batches, outs = [], []
             n_tok = n_cells = 0
             gaps_ms: List[float] = []
-            mark = time.perf_counter()
+            mark = time.monotonic_ns()
             try:
                 for batch, rows_dev, sl in pf:
-                    now = time.perf_counter()
-                    gaps_ms.append((now - mark) * 1e3)
+                    gaps_ms.append((time.monotonic_ns() - mark) / 1e6)
                     outs.append(fn(self.params, rows_dev))  # not fetched
                     batches.append(batch)
                     mb_seqlens.append(sl)
                     n_tok += batch.total_tokens
                     n_cells += batch.n_rows * batch.row_len
-                    mark = time.perf_counter()
+                    mark = time.monotonic_ns()
             finally:
                 pf.close()
             fetched = jax.device_get(outs)  # one blocking drain per batch

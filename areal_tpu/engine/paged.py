@@ -195,8 +195,9 @@ def _paged_attention_xla(q, k_pages, v_pages, lengths, page_indices, scale):
             g = pool[:, page_indices]
         return g.transpose(1, 2, 3, 0, 4).reshape(B, P * pg, Hkv, hd)
 
-    k = gather(k_pages)
-    v = gather(v_pages)
+    with jax.named_scope("kv_read"):
+        k = gather(k_pages)
+        v = gather(v_pages)
     qg = q.reshape(B, Hkv, group, hd).astype(jnp.float32)
     scores = jnp.einsum("bhgd,bshd->bhgs", qg, k.astype(jnp.float32)) * scale
     pos = jnp.arange(P * pg)[None, :]
@@ -422,11 +423,14 @@ def _paged_decode_layer(
                     pool[1].at[:, w_pidx, w_off].set(s[..., 0]))
         return pool.at[:, w_pidx, w_off].set(val_t.astype(pool.dtype))
 
-    kp_l = scatter(kp_l, k.transpose(1, 0, 2))
-    vp_l = scatter(vp_l, v.transpose(1, 0, 2))
-    out = paged_decode_attention(
-        q, kp_l, vp_l, lengths + 1, page_indices, mesh=mesh, impl=attn_impl
-    )
+    with jax.named_scope("kv_write"):
+        kp_l = scatter(kp_l, k.transpose(1, 0, 2))
+        vp_l = scatter(vp_l, v.transpose(1, 0, 2))
+    with jax.named_scope("decode_kernel"):
+        out = paged_decode_attention(
+            q, kp_l, vp_l, lengths + 1, page_indices, mesh=mesh,
+            impl=attn_impl,
+        )
     attn_out = qmat(out.reshape(B, cfg.q_dim), a["wo"], cdt)
     if "bo" in a:
         attn_out = attn_out + a["bo"].astype(cdt)
@@ -523,6 +527,7 @@ def paged_decode_step(
 # ----------------------------------------------------------------------
 
 
+@jax.named_scope("prefill_chunk")
 def _chunk_prefill_body(
     params,
     cfg: TransformerConfig,

@@ -20,9 +20,14 @@ Telemetry contract (consumed by `JaxTrainEngine` and surfaced as
   pack+transfer latency NOT hidden behind compute. Eager pipelines
   pay the full stage cost here; a healthy prefetched loop shows ~0.
 - `stage_ms`: total time inside `stage_fn` (the work being hidden).
-- `spans`: per-item (stage_start, stage_end, consumed_at) perf_counter
-  timestamps, so tests can assert overlap structurally (stage i+1
+- `spans`: per-item (stage_start, stage_end, consumed_at) timestamps in
+  seconds, so tests can assert overlap structurally (stage i+1
   started before item i was consumed) instead of racing wall clocks.
+
+All of it is on the span recorder's clock (`time.monotonic_ns`), and
+with `wait_span` set the two reads around the queue wait that feed
+`wait_ms` are also recorded as a span of that name (base/tracing.py):
+one measurement, two readers.
 """
 
 from __future__ import annotations
@@ -31,6 +36,8 @@ import queue
 import threading
 import time
 from typing import Any, Callable, Iterable, List, Optional, Tuple
+
+from areal_tpu.base import tracing
 
 
 class _Done:
@@ -59,12 +66,14 @@ class HostPrefetcher:
         stage_fn: Callable[[Any], Any],
         depth: int = 2,
         name: str = "prefetch",
+        wait_span: Optional[str] = None,
     ):
         if depth < 1:
             raise ValueError(f"prefetch depth must be >= 1, got {depth}")
         self.depth = depth
         self._items = iter(items)
         self._stage = stage_fn
+        self._wait_span = wait_span
         self._q: "queue.Queue[Any]" = queue.Queue(maxsize=depth)
         self._stop = threading.Event()
         self.wait_ms = 0.0
@@ -101,15 +110,16 @@ class HostPrefetcher:
             except BaseException as e:  # iterator itself failed
                 self._put((None, e, 0.0, 0.0))
                 return
-            t0 = time.perf_counter()
+            t0 = time.monotonic_ns()
             try:
                 res = self._stage(item)
             except BaseException as e:
-                self._put((None, e, t0, time.perf_counter()))
+                self._put((None, e, t0 / 1e9, time.monotonic_ns() / 1e9))
                 return
-            self.stage_ms += (time.perf_counter() - t0) * 1e3
+            t1 = time.monotonic_ns()
+            self.stage_ms += (t1 - t0) / 1e6
             self.n_staged += 1
-            if not self._put((res, None, t0, time.perf_counter())):
+            if not self._put((res, None, t0 / 1e9, t1 / 1e9)):
                 return
 
     # -- consumer side -------------------------------------------------
@@ -118,10 +128,12 @@ class HostPrefetcher:
         """Next staged result in order; raises StopIteration when the
         stream is exhausted, or the original exception when the stage
         (or source iterator) failed at this position."""
-        t0 = time.perf_counter()
+        t0 = time.monotonic_ns()
         payload = self._q.get()
-        now = time.perf_counter()
-        self.wait_ms += (now - t0) * 1e3
+        now = time.monotonic_ns()
+        self.wait_ms += (now - t0) / 1e6
+        if self._wait_span is not None:
+            tracing.record_span(self._wait_span, t0, now)
         if payload is _Done:
             self.close()
             raise StopIteration
@@ -129,7 +141,7 @@ class HostPrefetcher:
         if exc is not None:
             self.close()
             raise exc
-        self.spans.append((s0, s1, now))
+        self.spans.append((s0, s1, now / 1e9))
         self.n_consumed += 1
         return res
 

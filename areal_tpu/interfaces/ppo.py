@@ -36,7 +36,7 @@ from areal_tpu.api.model_api import (
     register_interface,
 )
 from areal_tpu.base import logging as areal_logging
-from areal_tpu.base import stats_tracker
+from areal_tpu.base import stats_tracker, tracing
 from areal_tpu.interfaces import functional as F
 from areal_tpu.base import env_registry
 from areal_tpu.ops.gae import packed_gae
@@ -239,6 +239,7 @@ class PPOActorInterface(ModelInterface):
             # fallback, the Pallas kernel the measured opt-in).
             gae_impl = env_registry.get_str("AREAL_GAE_IMPL")
 
+            @jax.named_scope("ppo_prep")
             def prep(rows, kl_coef):
                 resp_mask = response_scoring_mask(
                     rows["segment_ids"], rows["prompt_mask"]
@@ -308,115 +309,123 @@ class PPOActorInterface(ModelInterface):
         self, model: Model, input_: SequenceSample, mb_spec: MicroBatchSpec
     ) -> Dict:
         engine = model.module
-        kl_coef = self.kl_controller.value
+        with tracing.span("ppo.train_step", version=model.version):
+            kl_coef = self.kl_controller.value
 
-        # 1) Whole-batch advantage computation on device.
-        batch, rows = engine._build_rows(input_)
-        rows_dev = engine._device_rows(rows)
-        adv_rows, ret_rows, resp_rows, kl_sum = self._prep_fn(engine)(
-            rows_dev, jnp.asarray(kl_coef, jnp.float32)
-        )
-        adv_flat = batch.gather_flat(np.asarray(adv_rows))
-        ret_flat = batch.gather_flat(np.asarray(ret_rows))
-        resp_flat = batch.gather_flat(np.asarray(resp_rows))
-
-        # 2) Optional group normalization (GRPO): per prompt-group over
-        #    response positions.
-        if self.adv_norm and self.group_adv_norm:
-            adv_flat = adv_flat.copy()
-            offset = 0
-            for sl in input_.seqlens["packed_input_ids"]:
-                glen = sum(sl)
-                idx = np.arange(offset, offset + glen)[resp_flat[offset : offset + glen] > 0]
-                if idx.size > 1:
-                    vals = adv_flat[idx]
-                    adv_flat[idx] = (vals - vals.mean()) / (vals.std() + 1e-5)
-                offset += glen
-        train_sample = input_
-        train_sample.update_(
-            SequenceSample(
-                ids=list(input_.ids),
-                keys={"advantages"},
-                data={"advantages": adv_flat.astype(np.float32)},
-                seqlens={
-                    "advantages": [list(sl) for sl in input_.seqlens["packed_input_ids"]]
-                },
+            # 1) Whole-batch advantage computation on device.
+            with tracing.span("ppo.prep"):
+                batch, rows = engine._build_rows(input_)
+                tracing.set_attrs(rows=batch.n_rows, row_len=batch.row_len)
+                rows_dev = engine._device_rows(rows)
+                adv_rows, ret_rows, resp_rows, kl_sum = self._prep_fn(engine)(
+                    rows_dev, jnp.asarray(kl_coef, jnp.float32)
+                )
+                adv_flat = batch.gather_flat(np.asarray(adv_rows))
+                ret_flat = batch.gather_flat(np.asarray(ret_rows))
+                resp_flat = batch.gather_flat(np.asarray(resp_rows))
+            tracing.set_attrs(
+                tokens=batch.total_tokens, sequences=len(batch.seq_lens)
             )
-        )
 
-        # 3) Minibatched PPO updates.
-        mb_inputs, *_ = train_sample.split(
-            MicroBatchSpec(n_mbs=self.n_minibatches)
-        )
-        use_decoupled = self.use_decoupled_loss and "logprobs" in train_sample.keys
-
-        def actor_loss(lp, rows):
-            # `lp` is the fused next-token logprobs [R, T] computed by the
-            # engine (logits never materialized).
-            mask = response_scoring_mask(rows["segment_ids"], rows["prompt_mask"])
-            # Engine-injected per-shard normalization scale applies to the
-            # LOSS weighting only (monitoring stats keep the raw mask).
-            loss_w = (
-                mask * rows["dp_loss_scale"] if "dp_loss_scale" in rows else mask
+            # 2) Optional group normalization (GRPO): per prompt-group over
+            #    response positions.
+            if self.adv_norm and self.group_adv_norm:
+                adv_flat = adv_flat.copy()
+                offset = 0
+                for sl in input_.seqlens["packed_input_ids"]:
+                    glen = sum(sl)
+                    idx = np.arange(offset, offset + glen)[resp_flat[offset : offset + glen] > 0]
+                    if idx.size > 1:
+                        vals = adv_flat[idx]
+                        adv_flat[idx] = (vals - vals.mean()) / (vals.std() + 1e-5)
+                    offset += glen
+            train_sample = input_
+            train_sample.update_(
+                SequenceSample(
+                    ids=list(input_.ids),
+                    keys={"advantages"},
+                    data={"advantages": adv_flat.astype(np.float32)},
+                    seqlens={
+                        "advantages": [list(sl) for sl in input_.seqlens["packed_input_ids"]]
+                    },
+                )
             )
-            prox = rows["logprobs"] if use_decoupled else None
-            loss_sum, st = F.actor_loss_fn(
-                logprobs=lp,
-                old_logprobs=rows["packed_logprobs"],
-                advantages=rows["advantages"],
-                eps_clip=self.eps_clip,
-                loss_mask=loss_w,
-                c_clip=self.c_clip,
-                proximal_logprobs=prox,
-                behav_imp_weight_cap=self.behav_imp_weight_cap if use_decoupled else None,
-                stats_mask=mask,
+
+            # 3) Minibatched PPO updates.
+            mb_inputs, *_ = train_sample.split(
+                MicroBatchSpec(n_mbs=self.n_minibatches)
             )
-            # Approx KL(new || behavior) for monitoring.
-            st["approx_kl"] = jnp.sum((rows["packed_logprobs"] - lp) * mask)
-            return loss_sum, st
+            use_decoupled = self.use_decoupled_loss and "logprobs" in train_sample.keys
 
-        def weight_fn(mb):
-            return _n_response_tokens(mb)
+            def actor_loss(lp, rows):
+                # `lp` is the fused next-token logprobs [R, T] computed by the
+                # engine (logits never materialized).
+                mask = response_scoring_mask(rows["segment_ids"], rows["prompt_mask"])
+                # Engine-injected per-shard normalization scale applies to the
+                # LOSS weighting only (monitoring stats keep the raw mask).
+                loss_w = (
+                    mask * rows["dp_loss_scale"] if "dp_loss_scale" in rows else mask
+                )
+                prox = rows["logprobs"] if use_decoupled else None
+                loss_sum, st = F.actor_loss_fn(
+                    logprobs=lp,
+                    old_logprobs=rows["packed_logprobs"],
+                    advantages=rows["advantages"],
+                    eps_clip=self.eps_clip,
+                    loss_mask=loss_w,
+                    c_clip=self.c_clip,
+                    proximal_logprobs=prox,
+                    behav_imp_weight_cap=self.behav_imp_weight_cap if use_decoupled else None,
+                    stats_mask=mask,
+                )
+                # Approx KL(new || behavior) for monitoring.
+                st["approx_kl"] = jnp.sum((rows["packed_logprobs"] - lp) * mask)
+                return loss_sum, st
 
-        all_stats = []
-        for mb in mb_inputs:
-            st = engine.train_batch(
-                mb, MicroBatchSpec(n_mbs=1, max_tokens_per_mb=mb_spec.max_tokens_per_mb),
-                loss_fn=actor_loss, loss_weight_fn=weight_fn,
-                token_normalize_scope=self.token_normalize_scope,
-                version_steps=model.version, loss_name="ppo_actor",
+            def weight_fn(mb):
+                return _n_response_tokens(mb)
+
+            all_stats = []
+            for i, mb in enumerate(mb_inputs):
+                with tracing.span("ppo.minibatch", index=i,
+                                  tokens=mb.total_seqlen()):
+                    st = engine.train_batch(
+                        mb, MicroBatchSpec(n_mbs=1, max_tokens_per_mb=mb_spec.max_tokens_per_mb),
+                        loss_fn=actor_loss, loss_weight_fn=weight_fn,
+                        token_normalize_scope=self.token_normalize_scope,
+                        version_steps=model.version, loss_name="ppo_actor",
+                    )
+                all_stats.append(st)
+            model.inc_version()
+
+            n_resp = float(np.sum(resp_flat))
+            mean_kl = float(kl_sum) / max(n_resp, 1.0)
+            self.kl_controller.update(mean_kl, int(n_resp))
+
+            agg = {k: float(np.mean([s[k] for s in all_stats])) for k in all_stats[0]}
+            agg.update(
+                {
+                    "ppo_actor/kl": mean_kl,
+                    "ppo_actor/kl_coef": kl_coef,
+                    "ppo_actor/adv_mean": float(
+                        np.sum(adv_flat * resp_flat) / max(n_resp, 1.0)
+                    ),
+                    "ppo_actor/ret_mean": float(
+                        np.sum(ret_flat * resp_flat) / max(n_resp, 1.0)
+                    ),
+                    "ppo_actor/reward_mean": float(np.mean(input_.data["rewards"]))
+                    if input_.data.get("rewards") is not None else 0.0,
+                    "ppo_actor/n_tokens": float(batch.total_tokens),
+                }
             )
-            all_stats.append(st)
-        model.inc_version()
-
-        n_resp = float(np.sum(resp_flat))
-        mean_kl = float(kl_sum) / max(n_resp, 1.0)
-        self.kl_controller.update(mean_kl, int(n_resp))
-
-        agg = {k: float(np.mean([s[k] for s in all_stats])) for k in all_stats[0]}
-        agg.update(
-            {
-                "ppo_actor/kl": mean_kl,
-                "ppo_actor/kl_coef": kl_coef,
-                "ppo_actor/adv_mean": float(
-                    np.sum(adv_flat * resp_flat) / max(n_resp, 1.0)
-                ),
-                "ppo_actor/ret_mean": float(
-                    np.sum(ret_flat * resp_flat) / max(n_resp, 1.0)
-                ),
-                "ppo_actor/reward_mean": float(np.mean(input_.data["rewards"]))
-                if input_.data.get("rewards") is not None else 0.0,
-                "ppo_actor/n_tokens": float(batch.total_tokens),
-            }
-        )
-        # Staleness accounting (reference: ppo_interface.py:752-762).
-        vs = input_.metadata.get("version_start")
-        ve = input_.metadata.get("version_end")
-        if vs:
-            agg["ppo_actor/head_offpolicyness"] = float(model.version - 1 - np.min(vs))
-            agg["ppo_actor/tail_offpolicyness"] = float(model.version - 1 - np.max(ve))
-        stats_tracker.scalar(**agg)
-        return agg
+            # Staleness accounting (reference: ppo_interface.py:752-762).
+            vs = input_.metadata.get("version_start")
+            ve = input_.metadata.get("version_end")
+            if vs:
+                agg["ppo_actor/head_offpolicyness"] = float(model.version - 1 - np.min(vs))
+                agg["ppo_actor/tail_offpolicyness"] = float(model.version - 1 - np.max(ve))
+            stats_tracker.scalar(**agg)
+            return agg
 
     def save(self, model: Model, save_dir: str):
         from areal_tpu.interfaces.sft import SFTInterface
@@ -487,69 +496,77 @@ class PPOCriticInterface(ModelInterface):
         self, model: Model, input_: SequenceSample, mb_spec: MicroBatchSpec
     ) -> Dict:
         engine = model.module
-        # Returns are recomputed exactly like the actor does.
-        batch, rows = engine._build_rows(input_)
-        rows_dev = engine._device_rows(rows)
-        _, ret_rows, resp_rows, kl_sum = self._helper._prep_fn(engine)(
-            rows_dev, jnp.asarray(self.kl_controller.value, jnp.float32)
-        )
-        ret_flat = batch.gather_flat(np.asarray(ret_rows))
-        resp_flat = batch.gather_flat(np.asarray(resp_rows))
-        if self.value_norm:
-            self.rms.update(ret_flat, mask=resp_flat > 0)
-            norm_ret = np.where(resp_flat > 0, self.rms.normalize(ret_flat), 0.0)
-            old_values = np.where(
-                resp_flat > 0,
-                self.rms.normalize(np.asarray(input_.data["values"])),
-                0.0,
+        with tracing.span("ppo.train_step", version=model.version):
+            # Returns are recomputed exactly like the actor does.
+            with tracing.span("ppo.prep"):
+                batch, rows = engine._build_rows(input_)
+                tracing.set_attrs(rows=batch.n_rows, row_len=batch.row_len)
+                rows_dev = engine._device_rows(rows)
+                _, ret_rows, resp_rows, kl_sum = self._helper._prep_fn(engine)(
+                    rows_dev, jnp.asarray(self.kl_controller.value, jnp.float32)
+                )
+                ret_flat = batch.gather_flat(np.asarray(ret_rows))
+                resp_flat = batch.gather_flat(np.asarray(resp_rows))
+            tracing.set_attrs(
+                tokens=batch.total_tokens, sequences=len(batch.seq_lens)
             )
-        else:
-            norm_ret = ret_flat
-            old_values = np.asarray(input_.data["values"])
+            if self.value_norm:
+                self.rms.update(ret_flat, mask=resp_flat > 0)
+                norm_ret = np.where(resp_flat > 0, self.rms.normalize(ret_flat), 0.0)
+                old_values = np.where(
+                    resp_flat > 0,
+                    self.rms.normalize(np.asarray(input_.data["values"])),
+                    0.0,
+                )
+            else:
+                norm_ret = ret_flat
+                old_values = np.asarray(input_.data["values"])
 
-        sl = [list(s) for s in input_.seqlens["packed_input_ids"]]
-        input_.update_(
-            SequenceSample(
-                ids=list(input_.ids), keys={"returns", "old_values_norm"},
-                data={
-                    "returns": norm_ret.astype(np.float32),
-                    "old_values_norm": old_values.astype(np.float32),
-                },
-                seqlens={"returns": sl, "old_values_norm": sl},
+            sl = [list(s) for s in input_.seqlens["packed_input_ids"]]
+            input_.update_(
+                SequenceSample(
+                    ids=list(input_.ids), keys={"returns", "old_values_norm"},
+                    data={
+                        "returns": norm_ret.astype(np.float32),
+                        "old_values_norm": old_values.astype(np.float32),
+                    },
+                    seqlens={"returns": sl, "old_values_norm": sl},
+                )
             )
-        )
 
-        def critic_loss(values, rows):
-            mask = response_scoring_mask(rows["segment_ids"], rows["prompt_mask"])
-            loss_w = (
-                mask * rows["dp_loss_scale"] if "dp_loss_scale" in rows else mask
-            )
-            loss_sum, st = F.critic_loss_fn(
-                value=values,
-                old_value=rows["old_values_norm"],
-                target_value=rows["returns"],
-                value_eps_clip=self.value_eps_clip,
-                loss_mask=loss_w,
-                stats_mask=mask,
-            )
-            return loss_sum, st
+            def critic_loss(values, rows):
+                mask = response_scoring_mask(rows["segment_ids"], rows["prompt_mask"])
+                loss_w = (
+                    mask * rows["dp_loss_scale"] if "dp_loss_scale" in rows else mask
+                )
+                loss_sum, st = F.critic_loss_fn(
+                    value=values,
+                    old_value=rows["old_values_norm"],
+                    target_value=rows["returns"],
+                    value_eps_clip=self.value_eps_clip,
+                    loss_mask=loss_w,
+                    stats_mask=mask,
+                )
+                return loss_sum, st
 
-        mb_inputs, *_ = input_.split(MicroBatchSpec(n_mbs=self.n_minibatches))
-        all_stats = []
-        for mb in mb_inputs:
-            st = engine.train_batch(
-                mb, MicroBatchSpec(n_mbs=1, max_tokens_per_mb=mb_spec.max_tokens_per_mb),
-                loss_fn=critic_loss, loss_weight_fn=_n_response_tokens,
-                token_normalize_scope=self.token_normalize_scope,
-                version_steps=model.version, loss_name="ppo_critic",
-            )
-            all_stats.append(st)
-        model.inc_version()
-        n_resp = float(np.sum(resp_flat))
-        self.kl_controller.update(float(kl_sum) / max(n_resp, 1.0), int(n_resp))
-        agg = {k: float(np.mean([s[k] for s in all_stats])) for k in all_stats[0]}
-        stats_tracker.scalar(**agg)
-        return agg
+            mb_inputs, *_ = input_.split(MicroBatchSpec(n_mbs=self.n_minibatches))
+            all_stats = []
+            for i, mb in enumerate(mb_inputs):
+                with tracing.span("ppo.minibatch", index=i,
+                                  tokens=mb.total_seqlen()):
+                    st = engine.train_batch(
+                        mb, MicroBatchSpec(n_mbs=1, max_tokens_per_mb=mb_spec.max_tokens_per_mb),
+                        loss_fn=critic_loss, loss_weight_fn=_n_response_tokens,
+                        token_normalize_scope=self.token_normalize_scope,
+                        version_steps=model.version, loss_name="ppo_critic",
+                    )
+                all_stats.append(st)
+            model.inc_version()
+            n_resp = float(np.sum(resp_flat))
+            self.kl_controller.update(float(kl_sum) / max(n_resp, 1.0), int(n_resp))
+            agg = {k: float(np.mean([s[k] for s in all_stats])) for k in all_stats[0]}
+            stats_tracker.scalar(**agg)
+            return agg
 
 
 register_interface("ppo_actor", PPOActorInterface)

@@ -168,29 +168,33 @@ def _mlp(h, lp, cfg, cdt):
 def _attention_block(
     x, lp, cfg, cos, sin, segment_ids, positions, attn_impl, cdt, mesh=None
 ):
-    """x: [R, T, D] -> attention output [R, T, D]."""
+    """x: [R, T, D] -> attention output [R, T, D]. Named scopes say in
+    the device trace which part an op belongs to: `attn_qkv`
+    (projections, rotary; the caller's input norm too), `attn_kernel`
+    (the attention call), `attn_out`."""
     from areal_tpu.ops.attention import (
         resolve_attn_impl,
         sharded_splash_attention,
     )
 
     R, T, D = x.shape
-    q = x @ lp["wq"].astype(cdt)
-    k = x @ lp["wk"].astype(cdt)
-    v = x @ lp["wv"].astype(cdt)
-    if "bq" in lp:
-        q = q + lp["bq"].astype(cdt)
-        k = k + lp["bk"].astype(cdt)
-        v = v + lp["bv"].astype(cdt)
-    q = q.reshape(R, T, cfg.n_q_heads, cfg.head_dim)
-    k = k.reshape(R, T, cfg.n_kv_heads, cfg.head_dim)
-    v = v.reshape(R, T, cfg.n_kv_heads, cfg.head_dim)
-    if cfg.qk_norm:
-        q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
-        k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
-    if cos is not None:  # rotary position encoding (None = learned pos emb)
-        q = apply_rotary(q, cos, sin, cfg.rotary_interleaved)
-        k = apply_rotary(k, cos, sin, cfg.rotary_interleaved)
+    with jax.named_scope("attn_qkv"):
+        q = x @ lp["wq"].astype(cdt)
+        k = x @ lp["wk"].astype(cdt)
+        v = x @ lp["wv"].astype(cdt)
+        if "bq" in lp:
+            q = q + lp["bq"].astype(cdt)
+            k = k + lp["bk"].astype(cdt)
+            v = v + lp["bv"].astype(cdt)
+        q = q.reshape(R, T, cfg.n_q_heads, cfg.head_dim)
+        k = k.reshape(R, T, cfg.n_kv_heads, cfg.head_dim)
+        v = v.reshape(R, T, cfg.n_kv_heads, cfg.head_dim)
+        if cfg.qk_norm:
+            q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
+            k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
+        if cos is not None:  # rotary position encoding (None = learned pos emb)
+            q = apply_rotary(q, cos, sin, cfg.rotary_interleaved)
+            k = apply_rotary(k, cos, sin, cfg.rotary_interleaved)
 
     # Resolution is mesh-aware: a seq>1 mesh picks a CP scheme for
     # 'auto' (Ulysses when heads divide the seq axis, ring otherwise)
@@ -200,52 +204,54 @@ def _attention_block(
         attn_impl, T, cfg.n_q_heads, cfg.n_kv_heads, mesh=mesh, r=R
     )
     sharded = mesh is not None and mesh.size > 1
-    if impl == "ring":
-        # Context parallelism: KV chunks ring-rotate over the seq axis
-        # (O(T/seq) per-device attention memory — the long-context path).
-        from areal_tpu.ops.ring_attention import ring_ok, ring_packed_attention
+    with jax.named_scope("attn_kernel"):
+        if impl == "ring":
+            # Context parallelism: KV chunks ring-rotate over the seq axis
+            # (O(T/seq) per-device attention memory — the long-context path).
+            from areal_tpu.ops.ring_attention import ring_ok, ring_packed_attention
 
-        if not (sharded and ring_ok(mesh, R, T, cfg.n_q_heads, cfg.n_kv_heads)):
-            raise ValueError(
-                "attn_impl='ring' needs a mesh with seq > 1 and divisible "
-                f"shapes (R={R}, T={T}, Hq={cfg.n_q_heads}, "
-                f"Hkv={cfg.n_kv_heads}, mesh={dict(mesh.shape) if mesh else None})"
+            if not (sharded and ring_ok(mesh, R, T, cfg.n_q_heads, cfg.n_kv_heads)):
+                raise ValueError(
+                    "attn_impl='ring' needs a mesh with seq > 1 and divisible "
+                    f"shapes (R={R}, T={T}, Hq={cfg.n_q_heads}, "
+                    f"Hkv={cfg.n_kv_heads}, mesh={dict(mesh.shape) if mesh else None})"
+                )
+            out = ring_packed_attention(q, k, v, segment_ids, positions, mesh)
+        elif impl == "ulysses":
+            # Context parallelism via all-to-alls (seq shard swaps onto
+            # heads; 4 a2a + 2 small gathers per layer vs ring's S ppermute
+            # steps) with a splash local kernel on TPU; pick ring vs ulysses
+            # by measurement per context length (ops/ulysses_attention.py).
+            from areal_tpu.ops.ulysses_attention import (
+                ulysses_ok,
+                ulysses_packed_attention,
             )
-        out = ring_packed_attention(q, k, v, segment_ids, positions, mesh)
-    elif impl == "ulysses":
-        # Context parallelism via all-to-alls (seq shard swaps onto
-        # heads; 4 a2a + 2 small gathers per layer vs ring's S ppermute
-        # steps) with a splash local kernel on TPU; pick ring vs ulysses
-        # by measurement per context length (ops/ulysses_attention.py).
-        from areal_tpu.ops.ulysses_attention import (
-            ulysses_ok,
-            ulysses_packed_attention,
-        )
 
-        if not (
-            sharded and ulysses_ok(mesh, R, T, cfg.n_q_heads, cfg.n_kv_heads)
-        ):
-            raise ValueError(
-                "attn_impl='ulysses' needs a mesh with seq > 1 and head "
-                f"counts divisible by seq*tensor (R={R}, T={T}, "
-                f"Hq={cfg.n_q_heads}, Hkv={cfg.n_kv_heads}, "
-                f"mesh={dict(mesh.shape) if mesh else None})"
+            if not (
+                sharded and ulysses_ok(mesh, R, T, cfg.n_q_heads, cfg.n_kv_heads)
+            ):
+                raise ValueError(
+                    "attn_impl='ulysses' needs a mesh with seq > 1 and head "
+                    f"counts divisible by seq*tensor (R={R}, T={T}, "
+                    f"Hq={cfg.n_q_heads}, Hkv={cfg.n_kv_heads}, "
+                    f"mesh={dict(mesh.shape) if mesh else None})"
+                )
+            out = ulysses_packed_attention(q, k, v, segment_ids, positions, mesh)
+        elif sharded and impl == "splash":
+            # pallas_call is opaque to GSPMD: run the kernel per shard under
+            # shard_map with the megatron-equivalent layout.
+            out = sharded_splash_attention(
+                q, k, v, segment_ids, positions, mesh
+            )  # [R, T, Hq, hd]
+        else:
+            attn_fn = lambda q1, k1, v1, s1, p1: packed_attention(
+                q1, k1, v1, s1, p1, impl=impl
             )
-        out = ulysses_packed_attention(q, k, v, segment_ids, positions, mesh)
-    elif sharded and impl == "splash":
-        # pallas_call is opaque to GSPMD: run the kernel per shard under
-        # shard_map with the megatron-equivalent layout.
-        out = sharded_splash_attention(
-            q, k, v, segment_ids, positions, mesh
-        )  # [R, T, Hq, hd]
-    else:
-        attn_fn = lambda q1, k1, v1, s1, p1: packed_attention(
-            q1, k1, v1, s1, p1, impl=impl
-        )
-        out = jax.vmap(attn_fn)(q, k, v, segment_ids, positions)
-    out = out.reshape(R, T, cfg.q_dim) @ lp["wo"].astype(cdt)
-    if "bo" in lp:
-        out = out + lp["bo"].astype(cdt)
+            out = jax.vmap(attn_fn)(q, k, v, segment_ids, positions)
+    with jax.named_scope("attn_out"):
+        out = out.reshape(R, T, cfg.q_dim) @ lp["wo"].astype(cdt)
+        if "bo" in lp:
+            out = out + lp["bo"].astype(cdt)
     return out, (k, v)
 
 
@@ -298,12 +304,14 @@ def forward(
         emb = jax.lax.with_sharding_constraint(
             emb, jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())
         )
-    x = act_c(emb[input_ids].astype(cdt))
-    if cfg.embedding_multiplier:
-        x = x * jnp.asarray(cfg.embedding_multiplier, cdt)
+    with jax.named_scope("embed"):
+        x = act_c(emb[input_ids].astype(cdt))
+        if cfg.embedding_multiplier:
+            x = x * jnp.asarray(cfg.embedding_multiplier, cdt)
+        if cfg.pos_emb == "learned":
+            x = x + params["pos_embedding"]["weight"][positions].astype(cdt)
 
     if cfg.pos_emb == "learned":
-        x = x + params["pos_embedding"]["weight"][positions].astype(cdt)
         cos = sin = None
     else:
         inv_freq = jnp.asarray(
@@ -359,18 +367,22 @@ def forward(
 
     def layer_body(carry, lp):
         x, aux_acc = carry
+        with jax.named_scope("attn_qkv"):
+            h = _norm(x, lp["ln1"], cfg)
         a, kv = _attention_block(
-            _norm(x, lp["ln1"], cfg), lp["attn"], cfg, cos, sin,
+            h, lp["attn"], cfg, cos, sin,
             segment_ids, positions, attn_impl, cdt, mesh=mesh,
         )
-        x = x + a
-        h = _norm(x, lp["ln2"], cfg)
-        if use_moe:
-            m, aux = mlp_fn(h, lp["mlp"])
-            aux_acc = {k: aux_acc[k] + aux[k] for k in aux_acc}
-        else:
-            m = mlp_fn(h, lp["mlp"])
-        x = act_c(x + m)
+        with jax.named_scope("attn_out"):
+            x = x + a
+        with jax.named_scope("mlp"):
+            h = _norm(x, lp["ln2"], cfg)
+            if use_moe:
+                m, aux = mlp_fn(h, lp["mlp"])
+                aux_acc = {k: aux_acc[k] + aux[k] for k in aux_acc}
+            else:
+                m = mlp_fn(h, lp["mlp"])
+            x = act_c(x + m)
         return (x, aux_acc), kv if return_kv else None
 
     aux0 = {
@@ -400,7 +412,8 @@ def forward(
     else:
         body = layer_body
     (x, moe_aux), kvs = jax.lax.scan(body, (x, aux0), params["layers"])
-    x = _norm(x, params["final_norm"], cfg)
+    with jax.named_scope("final_norm"):
+        x = _norm(x, params["final_norm"], cfg)
 
     if output == "hidden":
         out = x

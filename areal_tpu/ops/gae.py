@@ -203,6 +203,7 @@ def resolve_gae_impl(impl: str, r: int, t: int) -> str:
     return "assoc"
 
 
+@jax.named_scope("gae")
 def packed_gae(
     rewards: jnp.ndarray,
     values: jnp.ndarray,

@@ -101,6 +101,7 @@ def _ce_chunk_setting() -> Optional[int]:
     return _CE_CHUNK_SNAP[0]
 
 
+@jax.named_scope("xent")
 def fused_next_token_logprobs(
     hidden: jnp.ndarray,  # [R, T, D] compute dtype
     head_w: jnp.ndarray,  # [D, V]

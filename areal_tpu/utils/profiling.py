@@ -1,4 +1,6 @@
-"""Per-MFC profiling: jax.profiler trace capture + wall-time breakdown.
+"""Per-MFC profiling: jax.profiler trace capture (started through
+`base/tracing.py::start`, the program's one control over the profiler)
++ wall-time breakdown.
 
 TPU counterpart of the reference's env-gated per-MFC torch profiler
 (realhf/system/model_worker.py:136-139, __maybe_profile_rpc:828-909) and
@@ -20,7 +22,7 @@ import os
 import time
 from typing import Dict, Iterator, List, Optional
 
-from areal_tpu.base import env_registry
+from areal_tpu.base import env_registry, tracing
 from areal_tpu.base import logging as areal_logging
 
 logger = areal_logging.getLogger("profiling")
@@ -52,19 +54,25 @@ def maybe_profile(name: str, step: Optional[int] = None) -> Iterator[None]:
 
     The dump lands in `<AREAL_TRACE_DIR>/<name>/step<step>/` in the
     TensorBoard profile format (open with `tensorboard --logdir` or
-    xprof). No-op unless AREAL_DUMP_TRACE is set.
+    xprof), with the block's `tracing.span`s mirrored into it as
+    `areal/<name>` annotations. No-op unless AREAL_DUMP_TRACE is set.
     """
     if not trace_enabled() or not _step_selected(step):
         yield
         return
-    import jax
-
     sub = name if step is None else os.path.join(name, f"step{step}")
     path = os.path.join(_trace_dir(), sub)
     os.makedirs(path, exist_ok=True)
     logger.info(f"capturing jax.profiler trace for {name!r} -> {path}")
-    with jax.profiler.trace(path):
+    # Through the program's one control over the profiler, so the MFC's
+    # spans are in the dump too. A session someone else started (an
+    # operator's, an enclosing MFC's) is left to them.
+    started = tracing.start(profile_dir=path)
+    try:
         yield
+    finally:
+        if started:
+            tracing.stop()
 
 
 class TimeMarks:

@@ -85,7 +85,12 @@ class Spans:
 
 
 class TracedWindow:
-    """The profiler over a stretch of the measured window (`--trace 1`)."""
+    """The profiler and the program's own spans over a stretch of work:
+    part of the measured window (`--trace 1`) or a pass after it
+    (`--trace 2`). Both are switched by the program's one control,
+    `areal_tpu.base.tracing.start` / `stop`, which nothing here touches
+    unless `enabled`. `program` is what `stop()` returned (spans,
+    counters, the clock anchor)."""
 
     def __init__(self, out_dir: str, enabled: bool):
         self.dir = os.path.join(out_dir, "trace")
@@ -93,16 +98,16 @@ class TracedWindow:
         self.active = False
         self._ann = None
         self.t0 = self.t1 = None
+        self.program: Optional[Dict[str, Any]] = None
 
     def start(self):
         if not self.enabled or self.active:
             return
         import jax
 
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0  # host spans come from TraceAnnotation
-        opts.host_tracer_level = 2
-        jax.profiler.start_trace(self.dir, profiler_options=opts)
+        from areal_tpu.base import tracing
+
+        tracing.start(profile_dir=self.dir)
         self._ann = jax.profiler.TraceAnnotation(trace_reduce.WINDOW_ANNOTATION)
         self._ann.__enter__()
         self.t0 = time.monotonic()
@@ -111,11 +116,11 @@ class TracedWindow:
     def stop(self):
         if not self.active:
             return
-        import jax
+        from areal_tpu.base import tracing
 
         self.t1 = time.monotonic()
         self._ann.__exit__(None, None, None)
-        jax.profiler.stop_trace()
+        self.program = tracing.stop()
         self.active = False
 
     def reduce(self) -> Optional[Dict[str, Any]]:

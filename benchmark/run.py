@@ -1,13 +1,16 @@
 #!/usr/bin/env python3
 """The benchmark's one command.
 
-    python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
+    python3 benchmark/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1|2>
 
 Resolves the cell by name (benchmark/cells/<cell>.json -> configs/,
 traffic/, runners/, layer_metrics/ -> readers/), runs it on the chips this
 machine holds, and prints one JSON object as the last line of stdout:
 the cell's end-to-end metrics with `--trace 0`, its per-layer metrics
-with `--trace 1`. Everything before that line is the run's log.
+with `--trace 1`, and both with `--trace 2`: a `--trace 0` run that, once
+its measured window has closed and its numbers are taken, traces a few
+more seconds of the same traffic for the per-layer metrics. Everything
+before that line is the run's log.
 
 Without a TPU (or with fewer chips than the cell asks) it exits non-zero
 and prints no result. `--rehearse-on-cpu` walks the same path at toy
@@ -40,7 +43,7 @@ def main(argv=None) -> int:
     ap.add_argument("--workload", required=True)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--seconds", type=float, default=None)
-    ap.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    ap.add_argument("--trace", type=int, choices=(0, 1, 2), default=0)
     ap.add_argument("--out", default=None, help="directory for this run's files")
     ap.add_argument("--rehearse-on-cpu", action="store_true")
     args = ap.parse_args(argv)
@@ -92,7 +95,8 @@ def main(argv=None) -> int:
     hf = manifest.hf_config(cell["config_file"], rehearsal)
     ctx = dict(
         cell=cell, hf=hf, traffic=traffic_params, seed=args.seed, seconds=seconds,
-        trace=bool(args.trace), rehearsal=rehearsal, out_dir=out_dir,
+        trace=args.trace == 1, trace_after=args.trace == 2,
+        rehearsal=rehearsal, out_dir=out_dir,
         t_start=T_START, log=log, chips=int(cell["chips"]),
         compiles=common.CompileCounter(), work_dir=work_dir,
     )
@@ -104,18 +108,18 @@ def main(argv=None) -> int:
     ev = res["evidence"]
     ev.update(hf_config=hf, peaks=peaks, chips=ctx["chips"])
     problems = list(res["problems"])
-    if args.trace:
-        metrics = manifest.read_layer_metrics(cell["name"], ev)
-    else:
+    metrics = {}
+    if args.trace != 1:
         wanted = [m for m in man["end_to_end"]
                   if "workloads" not in m or cell["name"] in m["workloads"]]
-        metrics = {}
         for m in wanted:
             if m["name"] in res["end_to_end"]:
                 metrics[m["name"]] = {"value": float(res["end_to_end"][m["name"]]),
                                       "unit": m["unit"]}
             elif entry is not None:
                 problems.append(f"no value for {m['name']}")
+    if args.trace:
+        metrics.update(manifest.read_layer_metrics(cell["name"], ev))
     mem = ev.get("memory") or {}
     device["memory_peak_bytes"] = int(mem.get("peak_bytes_in_use", 0))
     tr = ev.get("trace")
@@ -127,14 +131,16 @@ def main(argv=None) -> int:
         log("device time by category: " + json.dumps(tr["category_s"]))
     elif args.trace and not rehearsal:  # the CPU's trace has no device plane
         problems.append("the traced window saw no operation on a device")
-        line["correct"] = False
+        # --trace 2 takes `correct` from the window that closed before the
+        # tracing began; the problem is listed all the same.
+        line["correct"] = line["correct"] and args.trace == 2
     if rehearsal:
         # Counts only: no time, rate or share from a CPU run under any name.
         line.update(metrics={}, rehearsal=True, counts=res["counts"],
                     would_report=sorted(metrics))
         line.pop("breakdown", None)
         device.pop("busy_s", None), device.pop("window_s", None)
-    line["problems"] = problems
+    line["problems"] = problems + res.get("trace_problems", [])
     line["compile_s"] = ctx["compiles"].compile_seconds()
     if not rehearsal:
         log(f"all end-to-end values: {json.dumps(res['end_to_end'])}")

@@ -8,7 +8,9 @@ behaviour logprobs with the engine's own forward, compares a few
 sequences with the plain reference, and runs one step over every batch
 of the pool (each shape the window uses is then compiled). The window
 runs whole passes over the pool in the seed's order, back to back, each
-step ending in `block_until_ready(params)`.
+step ending in `block_until_ready(params)`. With `--trace 2` the window
+runs exactly as with `--trace 0`; once it has closed and every number is
+taken, one more pass over the pool is traced (`_traced_pass`).
 """
 
 from __future__ import annotations
@@ -17,6 +19,7 @@ import json
 import math
 import os
 import time
+import traceback
 from typing import Any, Dict, List
 
 import numpy as np
@@ -57,6 +60,35 @@ def _scoring_mask(b) -> np.ndarray:
         m[off + pl - 1: off + l - 1] = 1.0
         off += l
     return m
+
+
+def _traced_pass(ctx, step, pool, extras, first_step: int):
+    """`--trace 2`, after the measured window has closed: trace one pass
+    over the pool in the seed's order (the pass `--trace 1` traces inside
+    the window). Its rows go to `traced_steps.jsonl` and enter no
+    end-to-end value. No throw-away profiler session comes first: on the
+    chip a process's first start costs what its second does, and both
+    fall before the traced window opens (PERF.md section 6, PR 26)."""
+    log = ctx["log"]
+    tracer = common.TracedWindow(ctx["out_dir"], True)
+    began = time.monotonic()
+    tracer.start()
+    rows = [step(first_step + i, b, extra)
+            for i, (b, extra) in enumerate(zip(pool, extras))]
+    tracer.stop()
+    ended = time.monotonic()
+    built = ctx["compiles"].between(began, ended)
+    log(f"traced pass: {len(rows)} steps in {rows[-1]['end'] - rows[0]['start']:.3f}s"
+        f" ({ended - began:.3f}s with the profiler's start and stop), "
+        f"{len(built)} program(s) built {sorted(set(built))}")
+    with open(os.path.join(ctx["out_dir"], "traced_steps.jsonl"), "w") as f:
+        for r in rows:
+            f.write(json.dumps(r) + "\n")
+    with open(os.path.join(ctx["out_dir"], "program.json"), "w") as f:
+        json.dump(tracer.program, f, default=str)
+    trace = tracer.reduce()
+    log(f"trace reduced {time.monotonic() - ended:.1f}s after the traced pass")
+    return tracer.program, trace, common.peak_memory(ctx["chips"])  # of the whole run
 
 
 def run(ctx: Dict[str, Any]) -> Dict[str, Any]:
@@ -207,13 +239,31 @@ def run(ctx: Dict[str, Any]) -> Dict[str, Any]:
     tokens = float(sum(r["tokens"] for r in rows))
     sum_sq = float(len(rows) // len(pool)
                    * sum(l * l for b in pool for l in b["seqlens"]))
+    memory = common.peak_memory(ctx["chips"])
+    window_spans = list(spans.rows)
+    trace = tracer.reduce()
+    end_to_end = dict(setup_s=setup_s, train_tokens_per_s=tokens / elapsed)
+    program = tracer.program
+    # Everything above is the measured window's; nothing of the profiler
+    # or the program's tracing existed until here in modes 0 and 2. What
+    # follows can only add per-layer metrics: if it fails, the window's
+    # numbers and its `correct` still go out, the failure beside them.
+    trace_problems: List[str] = []
+    if ctx.get("trace_after"):
+        log(f"measured window closed: {json.dumps(end_to_end)}")
+        try:
+            program, trace, memory = _traced_pass(ctx, step, pool, extras, len(rows))
+        except Exception as e:
+            log("the traced pass failed:\n" + traceback.format_exc())
+            trace_problems.append(f"the traced pass failed: {type(e).__name__}: {e}")
     return dict(
-        problems=problems, attempted=len(rows), failed=len(bad),
-        end_to_end=dict(setup_s=setup_s, train_tokens_per_s=tokens / elapsed),
+        problems=problems, trace_problems=trace_problems,
+        attempted=len(rows), failed=len(bad),
+        end_to_end=end_to_end,
         evidence=dict(
-            spans=spans.rows, trace=tracer.reduce(),
-            memory=common.peak_memory(ctx["chips"]),
+            spans=window_spans, trace=trace, memory=memory,
             work=dict(tokens=tokens, sum_len_sq=sum_sq, elapsed_s=elapsed),
+            program=program,
         ),
         counts=dict(steps=len(rows), tokens=tokens, compiles_in_window=len(in_window)),
     )

@@ -103,6 +103,35 @@ def test_maybe_profile_noop_and_capture(tmp_path, monkeypatch):
     assert (tmp_path / "mfc_x" / "step3").exists()
 
 
+@pytest.mark.parametrize("selected,already_started", [
+    (True, False), (False, False), (True, True)])
+def test_maybe_profile_goes_through_the_tracing_control(
+    tmp_path, monkeypatch, selected, already_started
+):
+    """The program has one place that starts the profiler: maybe_profile
+    asks `tracing.start(profile_dir=...)` and stops only what it started."""
+    from areal_tpu.base import tracing
+
+    calls = []
+    monkeypatch.setattr(
+        tracing, "start",
+        lambda profile_dir=None: calls.append(("start", profile_dir))
+        or not already_started)
+    monkeypatch.setattr(tracing, "stop", lambda: calls.append(("stop",)) or {})
+    monkeypatch.setenv("AREAL_DUMP_TRACE", "1")
+    monkeypatch.setenv("AREAL_TRACE_DIR", str(tmp_path))
+    monkeypatch.setenv("AREAL_TRACE_STEPS", "3")
+    with profiling.maybe_profile("mfc_x", step=3 if selected else 2):
+        calls.append(("body",))
+    path = str(tmp_path / "mfc_x" / "step3")
+    if not selected:
+        assert calls == [("body",)]
+    elif already_started:
+        assert calls == [("start", path), ("body",)]
+    else:
+        assert calls == [("start", path), ("body",), ("stop",)]
+
+
 def test_time_marks():
     tm = profiling.TimeMarks()
     with tm.record("fwd"):

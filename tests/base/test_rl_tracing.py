@@ -3,7 +3,8 @@
 Pins the two hard contracts:
 
 - DISABLED is a true no-op: span calls cost one branch, no recorder is
-  ever allocated, no shard files appear (the acceptance criterion).
+  ever allocated, no shard files appear (the acceptance criterion),
+  unless the environment or `start()` says so.
 - ENABLED records parent-linked spans into per-worker JSONL shards that
   the aggregator merges with intact flow links, and the trace context
   survives both transports' metadata (request_reply_stream Payload,
@@ -69,8 +70,11 @@ def test_disabled_is_true_noop(untraced):
         assert tracing.start_span("d") is None
         assert tracing.inject() is None
         assert tracing.current() is None
+        tracing.set_attrs(late=1)
+        tracing.count("n")
     tracing.flush()
-    # The acceptance pin: no recorder allocation, no shard files.
+    # The acceptance pin: no recorder allocation, no shard files, as long
+    # as neither the environment nor start() has switched tracing on.
     assert tracing.recorder() is None
     assert not os.path.exists(untraced) or not os.listdir(untraced)
 
@@ -79,6 +83,241 @@ def test_disabled_inject_into_returns_same_dict(untraced):
     d = {"x": 1}
     assert tracing.inject_into(d) is d
     assert tracing.extract_from({"x": 1}) is None
+
+
+# ---------------------------------------------------------------------------
+# The runtime control: start() / stop() in a live process
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(params=["no_dir", "dir"])
+def live(request, tmp_path, monkeypatch):
+    """A process whose environment says off; with and without a shard dir."""
+    d = str(tmp_path / "rl_trace_live")
+    monkeypatch.setenv("AREAL_RL_TRACE", "0")
+    if request.param == "dir":
+        monkeypatch.setenv("AREAL_RL_TRACE_DIR", d)
+    else:
+        monkeypatch.delenv("AREAL_RL_TRACE_DIR", raising=False)
+    tracing.reconfigure()
+    yield d if request.param == "dir" else None
+    tracing.reconfigure()
+
+
+def test_start_stop_switch_a_live_process(live):
+    with tracing.span("before") as ctx:
+        assert ctx is None
+    assert tracing.recorder() is None
+    assert tracing.start() is True
+    assert tracing.enabled() and tracing.recorder() is not None
+    with tracing.span("outer", k=1) as outer:
+        tracing.set_attrs(late=2)
+        tracing.count("things", 3)
+        tracing.count("things")
+        with tracing.span("inner"):
+            tracing.set_attrs(only_inner=True)
+        t0 = tracing.now_ns()
+        tracing.record_span("explicit", t0, t0 + 5)
+    got = tracing.stop()
+    # back to what the environment says, and nothing lost to a file
+    assert not tracing.enabled()
+    with tracing.span("after") as ctx:
+        assert ctx is None
+    spans = {s["name"]: s for s in got["spans"]}
+    assert set(spans) == {"outer", "inner", "explicit"}
+    assert spans["inner"]["parent"] == spans["outer"]["span"] == outer.span_id
+    assert spans["outer"]["attrs"] == {"k": 1, "late": 2}
+    assert spans["inner"]["attrs"] == {"only_inner": True}
+    assert got["counters"] == {"things": 4} and got["dropped"] == 0
+    assert got["profile_dir"] is None and got["clock_anchor"] is None
+    # the shard is written only where AREAL_RL_TRACE_DIR is in use
+    if live is None:
+        assert tracing.recorder().path is None
+    else:
+        assert {s["name"] for s in _load_spans(live)} == set(spans)
+
+
+def test_start_and_stop_are_idempotent_and_sessions_do_not_leak(live):
+    assert tracing.stop()["spans"] == []  # no session: the same dict, empty
+    assert tracing.start() is True
+    assert tracing.start() is False  # a second start changes nothing
+    tracing.event("first")
+    tracing.count("c")
+    assert [s["name"] for s in tracing.stop()["spans"]] == ["first"]
+    assert tracing.stop() == {"spans": [], "counters": {}, "dropped": 0,
+                              "profile_dir": None, "clock_anchor": None}
+    assert tracing.start() is True
+    tracing.event("second")
+    got = tracing.stop()
+    assert [s["name"] for s in got["spans"]] == ["second"]
+    assert got["counters"] == {}
+
+
+def test_control_works_from_any_thread(live):
+    import threading
+
+    t = threading.Thread(target=tracing.start)
+    t.start()
+    t.join(timeout=10)
+    assert not t.is_alive() and tracing.enabled()
+    with tracing.span("main_thread"):
+        pass
+    box = {}
+    t = threading.Thread(target=lambda: box.update(got=tracing.stop()))
+    t.start()
+    t.join(timeout=10)
+    assert not t.is_alive()
+    assert [s["name"] for s in box["got"]["spans"]] == ["main_thread"]
+
+
+def test_environment_on_stays_on_after_stop(traced):
+    assert tracing.start() is True
+    tracing.event("in_session")
+    assert [s["name"] for s in tracing.stop()["spans"]] == ["in_session"]
+    assert tracing.enabled()  # "on from the first call" still holds
+    tracing.event("after_session")
+    tracing.flush()
+    assert {s["name"] for s in _load_spans(traced)} == {"in_session", "after_session"}
+
+
+def test_session_memory_is_bounded_like_the_ring(monkeypatch, live):
+    monkeypatch.setenv("AREAL_RL_TRACE_RING", "8")
+    tracing.reconfigure()
+    tracing.start()
+    for i in range(20):
+        tracing.event(f"e{i}")
+    got = tracing.stop()
+    assert len(got["spans"]) <= 8 and got["dropped"] > 0
+    assert got["spans"][-1]["name"] == "e19"  # the oldest went
+
+
+def test_sessions_toggled_under_concurrent_spans_lose_nothing_they_own(live):
+    """More threads than cores record spans and counts while the control
+    is toggled: every span a session returns is whole, counters never
+    exceed what was counted, and the threads end."""
+    import sys
+    import threading
+
+    stop_flag, errors, emitted = threading.Event(), [], [0] * 16
+
+    def work(i):
+        try:
+            while not stop_flag.is_set():
+                with tracing.span("w", i=i):
+                    tracing.count("c")
+                    tracing.set_attrs(done=True)
+                emitted[i] += 1
+        except Exception as e:  # pragma: no cover - the failure this test is for
+            errors.append(e)
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    threads = [threading.Thread(target=work, args=(i,)) for i in range(16)]
+    try:
+        for t in threads:
+            t.start()
+        got = []
+        for _ in range(20):
+            assert tracing.start() is True
+            got.append(tracing.stop())
+    finally:
+        stop_flag.set()
+        for t in threads:
+            t.join(timeout=10)
+        sys.setswitchinterval(old)
+    assert not errors and not any(t.is_alive() for t in threads)
+    spans = [s for g in got for s in g["spans"]]
+    assert all(s["name"] == "w" and s["end_ns"] >= s["start_ns"] for s in spans)
+    assert all(s["attrs"] == {"i": s["attrs"]["i"], "done": True} for s in spans)
+    assert len(spans) <= sum(emitted) and sum(g["counters"].get("c", 0) for g in got) <= sum(emitted) + 16
+    assert not tracing.enabled()
+
+
+class _FakeProfiler:
+    """Stands in for jax.profiler: records what the control asks of it."""
+
+    def __init__(self):
+        self.calls = []
+        fake = self
+
+        class TraceAnnotation:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                fake.calls.append(("enter", self.name))
+
+            def __exit__(self, *a):
+                fake.calls.append(("exit", self.name))
+
+        self.TraceAnnotation = TraceAnnotation
+
+    def start_trace(self, log_dir, profiler_options=None):
+        self.calls.append(("start_trace", log_dir,
+                           profiler_options.python_tracer_level,
+                           profiler_options.host_tracer_level))
+
+    def stop_trace(self):
+        self.calls.append(("stop_trace",))
+
+
+@pytest.fixture
+def fake_profiler(monkeypatch):
+    import jax
+
+    fake = _FakeProfiler()
+    for name in ("start_trace", "stop_trace", "TraceAnnotation"):
+        monkeypatch.setattr(jax.profiler, name, getattr(fake, name))
+    return fake
+
+
+def test_profile_dir_starts_the_profiler_anchors_the_clocks_and_mirrors_spans(
+    live, fake_profiler, tmp_path
+):
+    d = str(tmp_path / "prof")
+    before = tracing.now_ns()
+    assert tracing.start(profile_dir=d) is True
+    # the options the benchmark's traced window has always used, then the anchor
+    assert fake_profiler.calls[:3] == [
+        ("start_trace", d, 0, 2),
+        ("enter", "areal/clock_anchor"), ("exit", "areal/clock_anchor")]
+    with tracing.span("train.dispatch"):
+        t0 = tracing.now_ns()
+        tracing.record_span("train.wait_input", t0, t0 + 1)  # not mirrored
+        ms = tracing.start_span("manual")  # not mirrored
+        ms.end()
+    got = tracing.stop()
+    assert fake_profiler.calls[3:] == [
+        ("enter", "areal/train.dispatch"), ("exit", "areal/train.dispatch"),
+        ("stop_trace",)]
+    assert got["profile_dir"] == d
+    anchor = got["clock_anchor"]
+    assert anchor["name"] == "areal/clock_anchor"
+    assert before <= anchor["monotonic_ns"] <= tracing.now_ns()
+    rec = next(s for s in got["spans"] if s["name"] == "clock_anchor")
+    assert rec["attrs"]["monotonic_ns"] == rec["start_ns"] == anchor["monotonic_ns"]
+    assert {s["name"] for s in got["spans"]} == {
+        "clock_anchor", "train.dispatch", "train.wait_input", "manual"}
+    # with the profiler off again a span is not mirrored
+    tracing.start()
+    with tracing.span("plain"):
+        pass
+    tracing.stop()
+    assert fake_profiler.calls[-1] == ("stop_trace",)
+
+
+def test_a_profiler_that_fails_to_start_leaves_tracing_off(live, monkeypatch):
+    import jax
+
+    def boom(*a, **k):
+        raise RuntimeError("profiler busy")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", boom)
+    with pytest.raises(RuntimeError, match="profiler busy"):
+        tracing.start(profile_dir="/nonexistent")
+    assert not tracing.enabled()
+    assert tracing.start() is True  # and the control is still usable
+    assert tracing.stop()["spans"] == []
 
 
 # ---------------------------------------------------------------------------

@@ -38,7 +38,8 @@ def cells_of(metric):
 
 def test_manifest_has_exactly_the_contract_keys():
     assert set(MAN) == {"command", "paths", "run_seconds", "configs",
-                        "workloads", "end_to_end", "per_layer"}
+                        "workloads", "end_to_end", "per_layer", "trace_in_run"}
+    assert MAN["trace_in_run"] is True  # the harness takes --trace 2
     assert MAN["command"] == ["python3", "benchmark/run.py"]
     assert MAN["paths"] == ["benchmark", "tests/benchmark"]
     assert isinstance(MAN["run_seconds"], int) and 1 <= MAN["run_seconds"] <= 51

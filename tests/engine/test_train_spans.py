@@ -1,0 +1,145 @@
+"""The trainer engine's own spans and counters (`train.*`, recorded
+through base/tracing.py where the work happens), on both input paths:
+one `train.batch` a call, one `train.dispatch` a program, the stage on
+the prefetcher's thread under the batch's trace, tokens <= cells, and
+`perf/*` telemetry the same whether tracing is on or off."""
+
+import threading
+
+import jax
+import pytest
+
+from areal_tpu.api.data_api import MicroBatchSpec
+from areal_tpu.base import stats_tracker, tracing
+from areal_tpu.models.transformer import init_params
+
+from tests.engine.test_prefetch import (
+    loss_weight, make_batch, mk_engine, packed_loss, small_cfg,
+)
+
+N_MBS = 3
+# path -> prefetch depth, and per train_batch the count of each span kind
+PATHS = {
+    "overlapped": dict(depth=2, counts={
+        "train.batch": 1, "train.stage": N_MBS, "train.pack": N_MBS,
+        "train.h2d": N_MBS, "train.wait_input": N_MBS + 1,
+        "train.dispatch": N_MBS, "train.apply": 1, "train.fetch_stats": 1}),
+    "fused": dict(depth=0, counts={
+        "train.batch": 1, "train.pack": 1, "train.h2d": 1,
+        "train.dispatch": 1, "train.fetch_stats": 1}),
+}
+
+
+@pytest.fixture(autouse=True)
+def _tracing_off(monkeypatch):
+    monkeypatch.setenv("AREAL_RL_TRACE", "0")
+    monkeypatch.delenv("AREAL_RL_TRACE_DIR", raising=False)
+    tracing.reconfigure()
+    yield
+    tracing.reconfigure()
+
+
+@pytest.fixture(scope="module", params=sorted(PATHS))
+def recorded(request):
+    """One traced train_batch on each path (after an untraced warm one):
+    (path, what stop() returned, the engine's telemetry, the main thread)."""
+    path = request.param
+    tracing.reconfigure()
+    eng = mk_engine(init_params(small_cfg(), jax.random.PRNGKey(5)),
+                    depth=PATHS[path]["depth"])
+    batch = make_batch(n=9, seed=5)
+    args = (batch, MicroBatchSpec(n_mbs=N_MBS), packed_loss, loss_weight)
+    eng.train_batch(*args, loss_name="t")  # warm, untraced
+    assert tracing.recorder() is None
+    off = dict(eng.last_overlap)
+    tracing.start()
+    try:
+        eng.train_batch(*args, loss_name="t")
+    finally:
+        got = tracing.stop()
+    return path, got, off, dict(eng.last_overlap), threading.get_ident() & 0xFFFF
+
+
+@pytest.mark.parametrize("kind", sorted({k for p in PATHS.values() for k in p["counts"]}))
+def test_each_span_kind_is_recorded_the_right_number_of_times(recorded, kind):
+    path, got, *_ = recorded
+    n = sum(s["name"] == kind for s in got["spans"])
+    assert n == PATHS[path]["counts"].get(kind, 0), (path, kind, n)
+
+
+def test_the_tree_hangs_under_one_train_batch(recorded):
+    path, got, _, _, main_tid = recorded
+    spans = got["spans"]
+    by_id = {s["span"]: s for s in spans}
+    [batch] = [s for s in spans if s["name"] == "train.batch"]
+    assert {s["trace"] for s in spans} == {batch["trace"]}
+    parent_of = {s["name"]: by_id[s["parent"]]["name"] for s in spans if s["parent"]}
+    want = {"train.dispatch": "train.batch", "train.fetch_stats": "train.batch"}
+    if path == "overlapped":
+        want.update({"train.stage": "train.batch", "train.pack": "train.stage",
+                     "train.h2d": "train.stage", "train.wait_input": "train.batch",
+                     "train.apply": "train.batch"})
+    else:
+        want.update({"train.pack": "train.batch", "train.h2d": "train.batch"})
+    assert parent_of == want
+    # the stage runs on the prefetcher's thread, everything else on ours
+    for s in spans:
+        on_worker = path == "overlapped" and s["name"] in (
+            "train.stage", "train.pack", "train.h2d")
+        assert (s["tid"] != main_tid) == on_worker, s
+
+
+def test_attributes_and_counters_count_tokens_and_cells(recorded):
+    path, got, *_ = recorded
+    spans = got["spans"]
+    [batch] = [s for s in spans if s["name"] == "train.batch"]
+    a = batch["attrs"]
+    assert a["path"] == path and a["n_mbs"] == N_MBS
+    assert 0 < a["tokens"] <= a["cells"]
+    assert got["counters"] == {
+        "train.batches": 1, "train.micro_batches": N_MBS,
+        "train.tokens": a["tokens"], "train.cells": a["cells"]}
+    kinds = [s["attrs"]["kind"] for s in spans if s["name"] == "train.dispatch"]
+    assert kinds == (["first"] + ["next"] * (N_MBS - 1) if path == "overlapped"
+                     else ["fused"])
+    for s in spans:
+        if s["name"] == "train.dispatch":
+            assert s["attrs"]["rows"] >= 1 and s["attrs"]["row_len"] % 32 == 0
+        if s["name"] == "train.stage":
+            assert 0 < s["attrs"]["tokens"] <= s["attrs"]["cells"]
+        if s["name"] == "train.fetch_stats":
+            assert s["attrs"] == {"stale": False}
+    if path == "overlapped":
+        stages = [s["attrs"] for s in spans if s["name"] == "train.stage"]
+        assert sum(x["tokens"] for x in stages) == a["tokens"]
+        assert sum(x["cells"] for x in stages) == a["cells"]
+
+
+def test_perf_telemetry_is_the_same_with_tracing_on_and_off(recorded):
+    _, _, off, on, _ = recorded
+    assert set(on) == set(off) == {
+        "packing_efficiency", "h2d_wait_ms", "dispatch_gap_ms", "overlap_events"}
+    assert on["packing_efficiency"] == off["packing_efficiency"]
+    assert on["h2d_wait_ms"] >= 0.0 and on["dispatch_gap_ms"] >= 0.0
+
+
+def test_programs_built_counts_new_jit_cache_entries_and_stale_fetches_are_marked():
+    stats_tracker.export()
+    tracing.start()
+    try:
+        eng = mk_engine(init_params(small_cfg(), jax.random.PRNGKey(6)),
+                        depth=2, stats_fetch_interval=2)
+        batch = make_batch(n=9, seed=6)
+        for _ in range(3):
+            eng.train_batch(batch, MicroBatchSpec(n_mbs=N_MBS), packed_loss,
+                            loss_weight, loss_name="t")
+    finally:
+        got = tracing.stop()
+    # one entry for first+next, one for the apply; built once, run three times
+    assert got["counters"]["train.programs_built"] == len(eng._jit_cache) == 2
+    assert got["counters"]["train.batches"] == 3
+    stale = [s["attrs"]["stale"] for s in got["spans"] if s["name"] == "train.fetch_stats"]
+    assert stale == [False, False, True]
+    out = stats_tracker.export()
+    assert {"perf/packing_efficiency", "perf/h2d_wait_ms",
+            "perf/dispatch_gap_ms", "perf/overlap_events"} <= set(out)
