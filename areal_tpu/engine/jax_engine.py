@@ -42,6 +42,7 @@ from areal_tpu.models.config import TransformerConfig
 from areal_tpu.models.generation import generate_tokens
 from areal_tpu.models.packing import PackedBatch, pack_sequences
 from areal_tpu.models.transformer import forward as model_forward
+from areal_tpu.ops.attention import attn_run_len
 from areal_tpu.ops.loss import fused_next_token_logprobs
 from areal_tpu.engine.optimizer import (
     OptimizerConfig,
@@ -812,15 +813,17 @@ class JaxTrainEngine(TrainEngine):
                 "overlap_events": 0.0,
             }
             self._record_overlap_stats()
-            self._count_batch("fused", len(mbs), n_tok, n_cells)
+            rows, row_len = rows_np["input_ids"].shape[-2:]
+            attn_row_len = self._attn_row_len(rows, row_len)
+            self._count_batch("fused", len(mbs), n_tok, n_cells,
+                              len(mbs) * rows * attn_row_len)
 
             step = self._train_step_fn(
                 loss_name, loss_fn, tuple(sorted(rows_np.keys())), len(mbs)
             )
             with tracing.span(
-                "train.dispatch", kind="fused",
-                rows=rows_np["input_ids"].shape[-2],
-                row_len=rows_np["input_ids"].shape[-1],
+                "train.dispatch", kind="fused", rows=rows, row_len=row_len,
+                attn_row_len=attn_row_len,
             ):
                 self.params, self.opt_state, packed, aux = step(
                     self.params, self.opt_state, rows_dev,
@@ -875,7 +878,7 @@ class JaxTrainEngine(TrainEngine):
         )
         carry = None
         nxt = None
-        denom_sum, n_tok, n_cells = 0.0, 0, 0
+        denom_sum, n_tok, n_cells, n_attn_cells = 0.0, 0, 0, 0
         gaps_ms: List[float] = []
         mark = time.monotonic_ns()
         try:
@@ -885,16 +888,20 @@ class JaxTrainEngine(TrainEngine):
                 n_tok += tok
                 n_cells += cells
                 rows, row_len = rows_dev["input_ids"].shape
+                attn_row_len = self._attn_row_len(rows, row_len)
+                n_attn_cells += rows * attn_row_len
                 if carry is None:
                     first, nxt = self._accum_step_fns(
                         loss_name, loss_fn, tuple(sorted(rows_dev.keys()))
                     )
                     with tracing.span("train.dispatch", kind="first",
-                                      rows=rows, row_len=row_len):
+                                      rows=rows, row_len=row_len,
+                                      attn_row_len=attn_row_len):
                         carry = first(self.params, rows_dev)
                 else:
                     with tracing.span("train.dispatch", kind="next",
-                                      rows=rows, row_len=row_len):
+                                      rows=rows, row_len=row_len,
+                                      attn_row_len=attn_row_len):
                         carry = nxt(self.params, carry, rows_dev)
                 mark = time.monotonic_ns()
         finally:
@@ -907,7 +914,7 @@ class JaxTrainEngine(TrainEngine):
                 jnp.asarray(1.0 / global_denom, jnp.float32),
                 jnp.asarray(lr, jnp.float32),
             )
-        self._count_batch("overlapped", n_mbs, n_tok, n_cells)
+        self._count_batch("overlapped", n_mbs, n_tok, n_cells, n_attn_cells)
         self.last_overlap = {
             "packing_efficiency": n_tok / max(n_cells, 1),
             "h2d_wait_ms": pf.wait_ms,
@@ -919,16 +926,30 @@ class JaxTrainEngine(TrainEngine):
             packed, aux, loss_name, global_denom, n_mbs, lr
         )
 
+    def _attn_row_len(self, rows: int, row_len: int) -> int:
+        """The length the attention kernel runs this micro-batch's rows
+        at (ops/attention.attn_run_len: splash pads a row to a length
+        whose blocks are large; any other implementation runs it as it
+        is)."""
+        return attn_run_len(
+            self.attn_impl, row_len, self.model_cfg.n_q_heads,
+            self.model_cfg.n_kv_heads,
+            mesh=self.mesh if self.mesh.size > 1 else None, r=rows,
+        )
+
     @staticmethod
-    def _count_batch(path: str, n_mbs: int, n_tok: int, n_cells: int):
+    def _count_batch(path: str, n_mbs: int, n_tok: int, n_cells: int,
+                     n_attn_cells: int):
         """What one train_batch did, on its `train.batch` span and in the
-        recorder's counters: real tokens and the cells (rows x row length)
-        they were padded to."""
+        recorder's counters: real tokens, the cells (rows x row length)
+        they were padded to, and the cells the attention kernel ran (rows
+        x the length it ran them at)."""
         tracing.set_attrs(path=path, n_mbs=n_mbs, tokens=n_tok, cells=n_cells)
         tracing.count("train.batches")
         tracing.count("train.micro_batches", n_mbs)
         tracing.count("train.tokens", n_tok)
         tracing.count("train.cells", n_cells)
+        tracing.count("train.attn_cells", n_attn_cells)
 
     def _record_overlap_stats(self):
         """Ship the last pipeline's telemetry through the stats tracker so

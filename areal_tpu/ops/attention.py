@@ -16,6 +16,7 @@ and per-token positions; attention is masked to (same segment) AND
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -127,22 +128,106 @@ def _splash_block_targets():
     return _SPLASH_SNAP
 
 
-def _largest_block(n: int, cap: int) -> int:
-    """Largest multiple of 128 that divides n and is <= cap (splash
-    requires lane-aligned blocks that divide the sequence length)."""
+def _blocks_dividing(n: int, cap: int) -> list:
+    """Multiples of 128 that divide n and are <= cap, largest first
+    (splash requires lane-aligned blocks that divide the sequence)."""
     if n % LANES:
         raise ValueError(
             f"splash attention needs seq len a multiple of {LANES}, got {n}"
         )
-    d = (min(cap, n) // LANES) * LANES
-    while n % d:
-        d -= LANES
-    return d
+    top = min(cap, n) // LANES
+    return [d * LANES for d in range(top, 0, -1) if n % (d * LANES) == 0]
 
 
-def _splash_kernel(t: int, group: int, interpret: bool = False):
-    """Build a tuned splash-attention kernel for seq len `t` (the mask
-    object is cached; the kernel itself is rebuilt per trace).
+# How far a row is padded at most to find large blocks: the next
+# multiple of this always has q and kv blocks of 512.
+_SPLASH_PAD_TO = 512
+
+# splash_cost's constants, ns per q head of one row over the three
+# kernel passes a layer runs under full remat (forward, re-forward with
+# residuals, fused backward), one per term of _splash_cost_terms: least
+# squares over 229 timed run shapes of 19 row lengths on one v5e, 12 / 2
+# heads of 128 (scripts/splash_shape_sweep.py; docs/perf_notes.md, "How
+# the row length picks the splash blocks"). Median error 6 %; the shape
+# picked is within 3 % of the fastest measured one for every length of
+# 896 and above but 3840, which stays as it is (_SPLASH_MIN_GAIN).
+_SPLASH_NS = (980.0, 0.0115, 1.13, 1.25)
+
+# The estimate's own median error: a smaller estimated gain is no reason
+# to leave the row as it is at the largest blocks that divide it.
+_SPLASH_MIN_GAIN = 0.05
+
+
+def _splash_cost_terms(t: int, bq: int, bkv: int, bkvc: int) -> tuple:
+    """What splash_cost prices, a q head of one causal row: grid steps
+    (each a fixed overhead whether or not the mask leaves it any work:
+    at 128 x 128 blocks nearly all of the time), and over the block
+    pairs the mask leaves active their bq x bkv cells, their bq rows of
+    softmax bookkeeping once per compute sub-block, and the bq + bkv
+    rows of q and k/v they load."""
+    nq, nkv = t // bq, t // bkv
+    # kv blocks j that q block i sees: j * bkv <= (i + 1) * bq - 1
+    active = sum(min(nkv, ((i + 1) * bq - 1) // bkv + 1) for i in range(nq))
+    return (nq * nkv, active * bq * bkv, active * (bkv // bkvc) * bq,
+            active * (bq + bkv))
+
+
+def splash_cost(t: int, bq: int, bkv: int, bkvc: int) -> float:
+    """Estimated kernel time of a causal row of length `t` at the given
+    blocks, in ns per q head."""
+    terms = _splash_cost_terms(t, bq, bkv, bkvc)
+    return sum(ns * x for ns, x in zip(_SPLASH_NS, terms))
+
+
+def _run_lengths(t: int) -> range:
+    """`t, t+128, ..` up to the next multiple of _SPLASH_PAD_TO."""
+    return range(t, -(-t // _SPLASH_PAD_TO) * _SPLASH_PAD_TO + 1, LANES)
+
+
+def _plain_run_shape(t: int, tq: int, tkv: int, tkvc: int) -> tuple:
+    """The row as it is, at the largest blocks that divide it."""
+    bkv = _blocks_dividing(t, tkv)[0]
+    return (t, _blocks_dividing(t, tq)[0], bkv, _blocks_dividing(bkv, tkvc)[0])
+
+
+@functools.lru_cache(maxsize=None)
+def _cheapest_run_shape(t: int, tq: int, tkv: int, tkvc: int) -> tuple:
+    plain = _plain_run_shape(t, tq, tkv, tkvc)
+    shapes = [
+        (t_run, bq, bkv, bkvc)
+        for t_run in _run_lengths(t)
+        for bq in _blocks_dividing(t_run, tq)
+        for bkv in _blocks_dividing(t_run, tkv)
+        for bkvc in _blocks_dividing(bkv, tkvc)
+    ]
+    # min keeps the first of equals: the shorter run, the larger blocks.
+    best = min(shapes, key=lambda s: splash_cost(*s))
+    if splash_cost(*best) > (1.0 - _SPLASH_MIN_GAIN) * splash_cost(*plain):
+        return plain
+    return best
+
+
+def splash_run_shape(t: int):
+    """(t', bq, bkv, bkvc): the length the splash kernel runs a row of
+    length `t` at, and its blocks. A pure function of `t` (and the
+    AREAL_SPLASH_* upper targets), decided at trace time.
+
+    Blocks must divide the length, so a row whose count of 128-blocks
+    is prime (packed rows are multiples of 128: 3712 = 29 x 128) would
+    run 128 x 128 tiles, where per-grid-step overhead, not arithmetic,
+    sets the time. Among `t, t+128, ..` up to the next multiple of 512
+    and the blocks that divide each, this takes the cheapest by
+    `splash_cost`, if that is cheaper than the row as it is at its
+    largest dividing blocks by more than the estimate's error; else the
+    row stays as it is. So a padded length is never priced above `t`."""
+    return _cheapest_run_shape(t, *_splash_block_targets())
+
+
+def _splash_kernel(t: int, bq: int, bkv: int, bkvc: int, group: int,
+                   interpret: bool = False):
+    """Build the splash-attention kernel for rows of `t` at the given
+    blocks (the mask object is cached; the kernel itself is rebuilt per
+    trace).
 
     jax's splash attention (jax.experimental.pallas.ops.tpu.splash_attention,
     the production TPU flash kernel — same role as the flash-attn package
@@ -150,7 +235,8 @@ def _splash_kernel(t: int, group: int, interpret: bool = False):
     per kv head: q carries the GQA group as its head axis. Global causal
     mask + segment ids equals our (same segment) & (position causal) mask
     because packed segments are contiguous with ascending positions.
-    Block sizes were tuned on v5e (fused bwd, 512/1024 tiles).
+    Length and blocks come from `splash_run_shape`; the backward is the
+    fused dq/dkv kernel at the same blocks.
     """
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk,
@@ -167,16 +253,6 @@ def _splash_kernel(t: int, group: int, interpret: bool = False):
         mask = sm.MultiHeadMask([sm.CausalMask((t, t)) for _ in range(group)])
         _SPLASH_MASK_CACHE[key] = mask
 
-    # Block sizes must divide the sequence length (packed rows are
-    # padded to multiples of 128, so t is often e.g. 640 or 1536).
-    # Targets are overridable for on-chip tuning (scripts/mfu_sweep.py),
-    # validated + pinned at engine construction (snapshot_splash_blocks)
-    # so a mid-run retrace cannot mix settings; sweeps re-pin by
-    # constructing a fresh engine per setting.
-    tq, tkv, tkvc = _splash_block_targets()
-    bq = _largest_block(t, tq)
-    bkv = _largest_block(t, tkv)
-    bkvc = _largest_block(bkv, tkvc)
     bs = sk.BlockSizes(
         block_q=bq, block_kv=bkv, block_kv_compute=bkvc,
         block_q_dkv=bq, block_kv_dkv=bkv, block_kv_dkv_compute=bkvc,
@@ -200,11 +276,17 @@ def splash_packed_attention(
     positions: jnp.ndarray,  # [T] int32 (unused: causality via stream order)
     softmax_scale: Optional[float] = None,
     interpret: Optional[bool] = None,
+    _run_shape: Optional[tuple] = None,
 ) -> jnp.ndarray:
     """Packed GQA attention on jax's splash kernel (one MQA call per kv
     head, GQA group as the q-head axis). Pad tokens (segment 0) attend
     only among themselves, so outputs there are finite garbage — masked
-    by downstream losses exactly like the other impls."""
+    by downstream losses exactly like the other impls.
+
+    The kernel runs at `splash_run_shape(T)`: the row is padded with
+    zeros in segment 0 up to a length whose blocks are large, and the
+    first T positions come back. `_run_shape` overrides that choice
+    (tests, scripts/splash_shape_sweep.py)."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk,
     )
@@ -215,18 +297,26 @@ def splash_packed_attention(
     scale = float(softmax_scale) if softmax_scale is not None else hd ** -0.5
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
-    kernel = _splash_kernel(t, group, interpret=bool(interpret))
+    t_run, bq, bkv, bkvc = _run_shape or splash_run_shape(t)
+    kernel = _splash_kernel(t_run, bq, bkv, bkvc, group,
+                            interpret=bool(interpret))
 
-    # [T, Hq, hd] -> [Hkv, group, T, hd]; k/v -> [Hkv, T, hd]
-    qh = (q * jnp.asarray(scale, q.dtype)).transpose(1, 0, 2).reshape(
-        hkv, group, t, hd
-    )
+    q = q * jnp.asarray(scale, q.dtype)
+    if t_run > t:
+        # Segment 0 is already the padding segment: real tokens never see
+        # the new positions, which attend among themselves.
+        pad = ((0, t_run - t), (0, 0), (0, 0))
+        q, k, v = jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad)
+        segment_ids = jnp.pad(segment_ids, (0, t_run - t))
+    # [T', Hq, hd] -> [Hkv, group, T', hd]; k/v -> [Hkv, T', hd]
+    qh = q.transpose(1, 0, 2).reshape(hkv, group, t_run, hd)
     kh = k.transpose(1, 0, 2)
     vh = v.transpose(1, 0, 2)
     ids = sk.SegmentIds(q=segment_ids, kv=segment_ids)
     out = jax.vmap(lambda qq, kk, vv: kernel(qq, kk, vv, ids))(qh, kh, vh)
-    # [Hkv, group, T, hd] -> [T, Hq, hd]
-    return out.reshape(hq, t, hd).transpose(1, 0, 2).astype(q.dtype)
+    # [Hkv, group, T', hd] -> [T, Hq, hd]
+    out = out.reshape(hq, t_run, hd).transpose(1, 0, 2)
+    return out[:t].astype(q.dtype)
 
 
 def sharded_splash_attention(
@@ -376,6 +466,17 @@ def resolve_attn_impl(
     ran, why = _choose_attn_impl(impl, t, hq, hkv, mesh, r)
     say_dispatch("attn_impl", impl, ran, why, rows=r, t=t, hq=hq, hkv=hkv)
     return ran
+
+
+def attn_run_len(
+    impl: str, t: int, hq: int, hkv: int, mesh=None, r: Optional[int] = None,
+) -> int:
+    """Length the attention kernel runs rows of `t` at: splash's padded
+    `t'` (splash_run_shape) where splash is what runs, else `t`. For
+    host-side counters; says nothing (resolve_attn_impl does, in the
+    trace)."""
+    ran, _ = _choose_attn_impl(impl, t, hq, hkv, mesh, r)
+    return splash_run_shape(t)[0] if ran == "splash" else t
 
 
 def packed_attention(q, k, v, segment_ids, positions, softmax_scale=None, impl="auto"):

@@ -1,0 +1,202 @@
+#!/usr/bin/env python3
+"""Time the splash kernel alone, per row shape and per candidate run
+shape, and fit `ops/attention.splash_cost` to what the chip says.
+
+    python scripts/splash_shape_sweep.py --out chiprun_out/splash_sweep.jsonl
+    python scripts/splash_shape_sweep.py --fit chiprun_out/splash_sweep.jsonl
+    python scripts/splash_shape_sweep.py --chosen --out chiprun_out/splash_chosen.jsonl
+
+The first form needs a TPU: for every `rows x t` of `--shapes` (default:
+the sixteen training micro-batches and the forward-only lengths of the
+benchmark's `ppo-packed` pool) it runs `splash_packed_attention` under
+`vmap` over rows at each candidate `(t', bq, bkv, bkvc)` — `t, t+128, ..`
+up to the next multiple of 512, a few dividing blocks each — through
+`--layers` chained calls in one program, forward alone and forward plus
+the fused backward, and writes one JSON line a candidate. `--chosen`
+times only what `splash_run_shape` picks beside today's `t' = t` at the
+largest dividing blocks. `--fit` needs no device: least squares of
+`splash_cost`'s constants on fwd + (fwd + bwd), the kernels one layer
+runs under full remat.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+POOL = ("3x6144,1x896,3x5504,6x3200,2x768,6x3072,2x640,3x5760,2x384,5x3840,"
+        "1x512,4x4480,1x1152,5x3712,1x1280,9x2432,9x2048,10x1920,3x1024")
+
+
+def candidates(t):
+    """Run shapes to time for a row of `t`: each length up to the next
+    multiple of 512 at its two largest q blocks x three largest kv
+    blocks with the largest compute block, and the top pair once more
+    at the next compute block down."""
+    from areal_tpu.ops import attention as A
+
+    tq, tkv, tkvc = A._splash_block_targets()
+    out = []
+    for t_run in A._run_lengths(t):
+        bqs = A._blocks_dividing(t_run, tq)[:2]
+        bkvs = A._blocks_dividing(t_run, tkv)[:3]
+        out += [(t_run, bq, bkv, A._blocks_dividing(bkv, tkvc)[0])
+                for bq in bqs for bkv in bkvs]
+        out += [(t_run, bqs[0], bkvs[0], c)
+                for c in A._blocks_dividing(bkvs[0], tkvc)[1:2]]
+    return out
+
+
+def today(t):
+    """What the parent ran: t' = t at the largest dividing blocks."""
+    from areal_tpu.ops import attention as A
+
+    return A._plain_run_shape(t, *A._splash_block_targets())
+
+
+def time_shape(rows, t, run_shape, hq, hkv, hd, layers):
+    """(fwd ms, fwd+bwd ms) of `layers` chained attention calls."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from areal_tpu.ops.attention import splash_packed_attention
+
+    rng = np.random.RandomState(0)
+    q = jnp.asarray(rng.randn(rows, t, hq, hd), jnp.bfloat16)
+    k = jnp.asarray(rng.randn(rows, t, hkv, hd), jnp.bfloat16)
+    v = jnp.asarray(rng.randn(rows, t, hkv, hd), jnp.bfloat16)
+    # Three sequences and a padded tail a row, as the packer leaves them.
+    seg = np.zeros((rows, t), np.int32)
+    for r in range(rows):
+        cuts = [0, t // 5, t // 2, t - 1 - (r * 37) % 100]
+        for i in range(3):
+            seg[r, cuts[i]: cuts[i + 1]] = i + 1
+    seg = jnp.asarray(seg)
+    pos = jnp.zeros((rows, t), jnp.int32)
+
+    def attn(q1, k1, v1, s1, p1):
+        return splash_packed_attention(q1, k1, v1, s1, p1,
+                                       _run_shape=run_shape)
+
+    def chain(q, k, v):
+        def body(x, _):
+            out = jax.vmap(attn)(x, k, v, seg, pos)
+            return x + out * jnp.asarray(1e-3, x.dtype), None
+
+        x, _ = jax.lax.scan(body, q, None, length=layers)
+        return jnp.sum(x.astype(jnp.float32))
+
+    def clock(fn):
+        jax.block_until_ready(fn(q, k, v))  # compiles
+        t0 = time.perf_counter()
+        jax.block_until_ready(fn(q, k, v))
+        reps = max(1, int(0.05 / (time.perf_counter() - t0)))
+        best = float("inf")
+        for _ in range(3):
+            t0 = time.perf_counter()
+            for _ in range(reps):
+                out = fn(q, k, v)
+            jax.block_until_ready(out)
+            best = min(best, (time.perf_counter() - t0) / reps)
+        return best * 1e3
+
+    return clock(jax.jit(chain)), clock(jax.jit(jax.grad(chain, (0, 1, 2))))
+
+
+def sweep(args):
+    import jax
+
+    if jax.default_backend() != "tpu":
+        sys.exit("splash_shape_sweep times the compiled kernel: needs a TPU")
+    from areal_tpu.ops.attention import splash_run_shape
+
+    shapes = [tuple(int(x) for x in s.split("x")) for s in args.shapes.split(",")]
+    plan, seen = [], set()
+    for rows, t in shapes:
+        cands = [today(t)]
+        cands += [splash_run_shape(t)] if args.chosen else candidates(t)
+        for c in cands:
+            if (rows, t, c) not in seen:
+                seen.add((rows, t, c))
+                plan.append((rows, t, c))
+    # Today's shapes first, so a run cut short still has the baseline.
+    plan.sort(key=lambda p: p[2] != today(p[1]))
+    began = time.monotonic()
+    os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
+    with open(args.out, "w") as f:
+        for i, (rows, t, c) in enumerate(plan):
+            if time.monotonic() - began > args.max_seconds:
+                print(f"stopped at {i} of {len(plan)}: --max-seconds", flush=True)
+                break
+            row = dict(rows=rows, t=t, t_run=c[0], bq=c[1], bkv=c[2], bkvc=c[3],
+                       hq=args.hq, hkv=args.hkv, hd=args.hd, layers=args.layers,
+                       device=jax.devices()[0].device_kind)
+            try:
+                row["fwd_ms"], row["grad_ms"] = time_shape(
+                    rows, t, c, args.hq, args.hkv, args.hd, args.layers)
+            except Exception as e:  # a block the compiler refuses is a result
+                row["error"] = f"{type(e).__name__}: {str(e)[:300]}"
+            f.write(json.dumps(row) + "\n")
+            f.flush()
+            print(json.dumps(row), flush=True)
+
+
+def fit(path):
+    """Least squares of splash_cost's constants (`_SPLASH_NS`) on a
+    sweep's file: (constants, the model's relative error a run, the runs)."""
+    import numpy as np
+
+    from areal_tpu.ops.attention import _splash_cost_terms
+
+    rows = [json.loads(l) for l in open(path)]
+    rows = [r for r in rows if "error" not in r]
+    x = np.asarray([_splash_cost_terms(r["t_run"], r["bq"], r["bkv"], r["bkvc"])
+                    for r in rows], float)
+    y = np.asarray([(r["fwd_ms"] + r["grad_ms"]) * 1e6
+                    / (r["rows"] * r["hq"] * r["layers"]) for r in rows])
+    # Relative error matters (the choice compares costs of one row), so
+    # weigh each run by 1 / measured.
+    coef, *_ = np.linalg.lstsq(x / y[:, None], np.ones_like(y), rcond=None)
+    return tuple(map(float, coef)), x @ coef / y - 1.0, rows
+
+
+def print_fit(path):
+    import numpy as np
+
+    coef, err, rows = fit(path)
+    print(json.dumps(dict(runs=len(rows), _SPLASH_NS=coef,
+                          rel_err_median=float(np.median(np.abs(err))),
+                          rel_err_max=float(np.max(np.abs(err))))))
+    for r, e in sorted(zip(rows, err), key=lambda z: -abs(z[1]))[:12]:
+        print(f"  {r['rows']}x{r['t']} at {r['t_run']} {r['bq']}/{r['bkv']}/"
+              f"{r['bkvc']}: fwd {r['fwd_ms']:.2f} + grad {r['grad_ms']:.2f} ms,"
+              f" model {e:+.1%}")
+
+
+def main():
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--shapes", default=POOL, help="rows x t, comma-separated")
+    ap.add_argument("--out", default="chiprun_out/splash_sweep.jsonl")
+    ap.add_argument("--fit", metavar="JSONL", help="fit splash_cost to a sweep")
+    ap.add_argument("--chosen", action="store_true",
+                    help="time only today's shape and splash_run_shape's")
+    ap.add_argument("--hq", type=int, default=12)
+    ap.add_argument("--hkv", type=int, default=2)
+    ap.add_argument("--hd", type=int, default=128)
+    ap.add_argument("--layers", type=int, default=12)
+    ap.add_argument("--max-seconds", type=float, default=3000.0)
+    args = ap.parse_args()
+    if args.fit:
+        print_fit(args.fit)
+    else:
+        sweep(args)
+
+
+if __name__ == "__main__":
+    main()

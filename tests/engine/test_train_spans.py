@@ -7,9 +7,10 @@ the prefetcher's thread under the batch's trace, tokens <= cells, and
 import threading
 
 import jax
+import numpy as np
 import pytest
 
-from areal_tpu.api.data_api import MicroBatchSpec
+from areal_tpu.api.data_api import MicroBatchSpec, SequenceSample
 from areal_tpu.base import stats_tracker, tracing
 from areal_tpu.models.transformer import init_params
 
@@ -98,13 +99,16 @@ def test_attributes_and_counters_count_tokens_and_cells(recorded):
     assert 0 < a["tokens"] <= a["cells"]
     assert got["counters"] == {
         "train.batches": 1, "train.micro_batches": N_MBS,
-        "train.tokens": a["tokens"], "train.cells": a["cells"]}
+        "train.tokens": a["tokens"], "train.cells": a["cells"],
+        # the reference runs on the CPU, at the rows' own length
+        "train.attn_cells": a["cells"]}
     kinds = [s["attrs"]["kind"] for s in spans if s["name"] == "train.dispatch"]
     assert kinds == (["first"] + ["next"] * (N_MBS - 1) if path == "overlapped"
                      else ["fused"])
     for s in spans:
         if s["name"] == "train.dispatch":
             assert s["attrs"]["rows"] >= 1 and s["attrs"]["row_len"] % 32 == 0
+            assert s["attrs"]["attn_row_len"] == s["attrs"]["row_len"]
         if s["name"] == "train.stage":
             assert 0 < s["attrs"]["tokens"] <= s["attrs"]["cells"]
         if s["name"] == "train.fetch_stats":
@@ -143,3 +147,36 @@ def test_programs_built_counts_new_jit_cache_entries_and_stale_fetches_are_marke
     out = stats_tracker.export()
     assert {"perf/packing_efficiency", "perf/h2d_wait_ms",
             "perf/dispatch_gap_ms", "perf/overlap_events"} <= set(out)
+
+
+@pytest.mark.parametrize("impl", ["splash", "reference"])
+def test_attn_cells_count_the_length_the_kernel_runs_at(impl):
+    """Rows of 640 (5 blocks of 128): splash pads them to a length whose
+    blocks are large and `train.attn_cells` counts rows x that length;
+    the reference runs them as they are."""
+    from areal_tpu.ops.attention import splash_run_shape
+
+    eng = mk_engine(init_params(small_cfg(), jax.random.PRNGKey(7)), depth=0,
+                    attn_impl=impl)
+    eng.row_len_multiple = 128
+    rng = np.random.RandomState(7)
+    seqlens = [600, 590, 300]
+    total = sum(seqlens)
+    batch = SequenceSample.from_default(
+        ids=[f"a{i}" for i in range(3)], seqlens=seqlens,
+        data={"packed_input_ids": rng.randint(0, 64, size=total),
+              "loss_mask": np.ones(total, np.float32)})
+    tracing.start()
+    try:
+        eng.train_batch(batch, MicroBatchSpec(n_mbs=1), packed_loss, loss_weight,
+                        loss_name="t")
+    finally:
+        got = tracing.stop()
+    [d] = [s["attrs"] for s in got["spans"] if s["name"] == "train.dispatch"]
+    assert d["row_len"] == 640
+    want = splash_run_shape(640)[0] if impl == "splash" else 640
+    assert (want > 640) == (impl == "splash")
+    assert d["attn_row_len"] == want
+    c = got["counters"]
+    assert c["train.cells"] == d["rows"] * 640
+    assert c["train.attn_cells"] == d["rows"] * want
