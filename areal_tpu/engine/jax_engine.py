@@ -42,7 +42,7 @@ from areal_tpu.models.config import TransformerConfig
 from areal_tpu.models.generation import generate_tokens
 from areal_tpu.models.packing import PackedBatch, pack_sequences
 from areal_tpu.models.transformer import forward as model_forward
-from areal_tpu.ops.attention import attn_run_len
+from areal_tpu.ops.attention import attn_block_cells, attn_run_len
 from areal_tpu.ops.loss import fused_next_token_logprobs
 from areal_tpu.engine.optimizer import (
     OptimizerConfig,
@@ -86,6 +86,49 @@ def opt_state_shardings(opt_state, params, mesh):
     return walk(opt_state)
 
 
+# Leaves of the parameter tree that are buffers, not weights: the
+# forward pass reads them, no gradient reaches them, and the optimizer
+# neither updates them nor keeps moments for them. `expert_bias` is the
+# router's selection bias, which pre-training balances by a rule of its
+# own and an RL step leaves as it is.
+BUFFER_LEAVES = ("expert_bias",)
+
+
+def trainable(tree):
+    """`tree` (parameters, or gradients of the same structure) without
+    its buffer leaves: what the optimizer sees. A tree without buffers
+    comes back with the same structure."""
+    if isinstance(tree, dict):
+        return {k: trainable(v) for k, v in tree.items()
+                if k not in BUFFER_LEAVES}
+    return tree
+
+
+def with_buffers(new, old):
+    """`old` with every leaf that `new` has replaced by it: the updated
+    weights put back beside the buffers they were taken from."""
+    if isinstance(old, dict):
+        return {k: with_buffers(new[k], v) if k in new else v
+                for k, v in old.items()}
+    return new
+
+
+def _kinds_label(cfg: TransformerConfig) -> str:
+    """The stack's layer kinds in order, runs of equal kinds folded:
+    `dense.w2048.rope,moe.w2048.rope x2,moe.full.nope`."""
+    names = [
+        f"{k.mlp}.{'full' if k.window is None else 'w%d' % k.window}."
+        f"{'rope' if k.rotary else 'nope'}" for k in cfg.kinds()
+    ]
+    out = []
+    for n in names:
+        if out and out[-1][0] == n:
+            out[-1][1] += 1
+        else:
+            out.append([n, 1])
+    return ",".join(n if c == 1 else f"{n} x{c}" for n, c in out)
+
+
 @dataclasses.dataclass
 class EngineStats:
     """Host-side per-train_batch summary."""
@@ -123,6 +166,14 @@ class JaxTrainEngine(TrainEngine):
                 moe=dataclasses.replace(model_cfg.moe, dispatch=env_dispatch),
             )
         self.model_cfg = model_cfg
+        # What `train.dispatch` says of the stack it runs: nothing for a
+        # stack of one plain kind.
+        self._stack_attrs: Dict[str, Any] = {}
+        if model_cfg.layer_kinds is not None:
+            windows = sorted({k.window for k in model_cfg.kinds()
+                              if k.window is not None})
+            self._stack_attrs = dict(window=windows[0] if windows else None,
+                                     kinds=_kinds_label(model_cfg))
         # Pin AREAL_CE_CHUNK / AREAL_SPLASH_* now: retraces mid-run must
         # not mix tuning settings, and bad values must fail at init.
         from areal_tpu.ops import snapshot_env_tuning
@@ -208,11 +259,24 @@ class JaxTrainEngine(TrainEngine):
             self._lr_schedule = make_lr_schedule(
                 optimizer_config, total_train_steps
             )
-            opt_shape = jax.eval_shape(self.optimizer.init, self.params)
-            self._opt_shardings = opt_state_shardings(opt_shape, self.params, self.mesh)
+            weights = trainable(self.params)  # no moments for buffers
+
+            def init_state(w):
+                # Adam's moments in float32 from the start, as the first
+                # update leaves them whatever the parameters' dtype
+                # (optax makes them zeros_like the parameters, and the
+                # update promotes them): moments that change dtype once
+                # make every program that takes the optimizer state
+                # twice, and the second build of the first one falls
+                # wherever its shape comes round again.
+                return self.optimizer.init(jax.tree_util.tree_map(
+                    lambda x: x.astype(jnp.float32), w))
+
+            opt_shape = jax.eval_shape(init_state, weights)
+            self._opt_shardings = opt_state_shardings(opt_shape, weights, self.mesh)
             self.opt_state = jax.jit(
-                self.optimizer.init, out_shardings=self._opt_shardings
-            )(self.params)
+                init_state, out_shardings=self._opt_shardings
+            )(weights)
             if self._serial_dispatch:
                 jax.block_until_ready(self.opt_state)
         # jit caches keyed by (kind, loss name, row shape, extra)
@@ -345,9 +409,8 @@ class JaxTrainEngine(TrainEngine):
                 # tokens while global_denom counts loss-weight (response)
                 # tokens, so the n_tok scaling used by the loss-like
                 # stats would inflate a fraction.
-                aux["mean:moe_drop_rate"] = (
-                    moe_aux["drop_rate"] / self.model_cfg.n_layers
-                )
+                n_layers = self.model_cfg.n_moe_layers
+                aux["mean:moe_drop_rate"] = moe_aux["drop_rate"] / n_layers
                 # Router telemetry (PR 17): layer-mean router entropy,
                 # expert overload factor (E * max_e layer-mean routing
                 # fraction; 1.0 = perfectly balanced), and EP-exchange
@@ -355,7 +418,6 @@ class JaxTrainEngine(TrainEngine):
                 # expert-parallel meshes). Same "mean:" convention as
                 # drop_rate — these are ratios/volumes, not loss-like
                 # token-scaled sums.
-                n_layers = self.model_cfg.n_layers
                 aux["mean:moe_router_entropy"] = (
                     moe_aux["router_entropy"] / n_layers
                 )
@@ -365,6 +427,12 @@ class JaxTrainEngine(TrainEngine):
                     * moe_cfg.num_experts
                 )
                 aux["mean:moe_a2a_bytes"] = moe_aux["a2a_bytes"]
+                if "pairs_held" in moe_aux:
+                    # A share of the experts (MoEConfig.experts_held):
+                    # counts summed over expert layers and micro-batches,
+                    # for the counters train.moe_pairs_held / train.moe_rows.
+                    aux["sum:moe_pairs_held"] = moe_aux["pairs_held"]
+                    aux["sum:moe_rows"] = moe_aux["rows_run"]
             return loss_sum, aux
 
         return compute
@@ -374,11 +442,13 @@ class JaxTrainEngine(TrainEngine):
         1/global_denom normalization, grad norm, optimizer update at a
         unit LR, `p + lr * u` and the norm of what survived rounding."""
         with jax.named_scope("optimizer_apply"):
-            grads = jax.tree_util.tree_map(lambda g: g * inv_denom, grads)
+            weights = trainable(params)
+            grads = jax.tree_util.tree_map(
+                lambda g: g * inv_denom, trainable(grads))
             gnorm = optax_global_norm(grads)
-            updates, opt_state = self.optimizer.update(grads, opt_state, params)
-            params, unorm = apply_updates(params, updates, lr)
-        return params, opt_state, gnorm, unorm
+            updates, opt_state = self.optimizer.update(grads, opt_state, weights)
+            weights, unorm = apply_updates(weights, updates, lr)
+        return with_buffers(weights, params), opt_state, gnorm, unorm
 
     def _train_step_fn(self, loss_name: str, loss_fn: PackedLossFn,
                        row_keys: Tuple[str, ...], n_mbs: int):
@@ -814,16 +884,16 @@ class JaxTrainEngine(TrainEngine):
             }
             self._record_overlap_stats()
             rows, row_len = rows_np["input_ids"].shape[-2:]
-            attn_row_len = self._attn_row_len(rows, row_len)
+            attn = self._attn_counts(rows, row_len)
             self._count_batch("fused", len(mbs), n_tok, n_cells,
-                              len(mbs) * rows * attn_row_len)
+                              *(len(mbs) * rows * c for c in attn))
 
             step = self._train_step_fn(
                 loss_name, loss_fn, tuple(sorted(rows_np.keys())), len(mbs)
             )
             with tracing.span(
                 "train.dispatch", kind="fused", rows=rows, row_len=row_len,
-                attn_row_len=attn_row_len,
+                attn_row_len=attn[0], **self._stack_attrs,
             ):
                 self.params, self.opt_state, packed, aux = step(
                     self.params, self.opt_state, rows_dev,
@@ -878,7 +948,8 @@ class JaxTrainEngine(TrainEngine):
         )
         carry = None
         nxt = None
-        denom_sum, n_tok, n_cells, n_attn_cells = 0.0, 0, 0, 0
+        denom_sum, n_tok, n_cells = 0.0, 0, 0
+        n_attn = [0, 0, 0]  # cells at the run length, active, causal
         gaps_ms: List[float] = []
         mark = time.monotonic_ns()
         try:
@@ -888,20 +959,23 @@ class JaxTrainEngine(TrainEngine):
                 n_tok += tok
                 n_cells += cells
                 rows, row_len = rows_dev["input_ids"].shape
-                attn_row_len = self._attn_row_len(rows, row_len)
-                n_attn_cells += rows * attn_row_len
+                attn = self._attn_counts(rows, row_len)
+                attn_row_len = attn[0]
+                n_attn = [n + rows * c for n, c in zip(n_attn, attn)]
                 if carry is None:
                     first, nxt = self._accum_step_fns(
                         loss_name, loss_fn, tuple(sorted(rows_dev.keys()))
                     )
                     with tracing.span("train.dispatch", kind="first",
                                       rows=rows, row_len=row_len,
-                                      attn_row_len=attn_row_len):
+                                      attn_row_len=attn_row_len,
+                                      **self._stack_attrs):
                         carry = first(self.params, rows_dev)
                 else:
                     with tracing.span("train.dispatch", kind="next",
                                       rows=rows, row_len=row_len,
-                                      attn_row_len=attn_row_len):
+                                      attn_row_len=attn_row_len,
+                                      **self._stack_attrs):
                         carry = nxt(self.params, carry, rows_dev)
                 mark = time.monotonic_ns()
         finally:
@@ -914,7 +988,7 @@ class JaxTrainEngine(TrainEngine):
                 jnp.asarray(1.0 / global_denom, jnp.float32),
                 jnp.asarray(lr, jnp.float32),
             )
-        self._count_batch("overlapped", n_mbs, n_tok, n_cells, n_attn_cells)
+        self._count_batch("overlapped", n_mbs, n_tok, n_cells, *n_attn)
         self.last_overlap = {
             "packing_efficiency": n_tok / max(n_cells, 1),
             "h2d_wait_ms": pf.wait_ms,
@@ -926,30 +1000,42 @@ class JaxTrainEngine(TrainEngine):
             packed, aux, loss_name, global_denom, n_mbs, lr
         )
 
-    def _attn_row_len(self, rows: int, row_len: int) -> int:
-        """The length the attention kernel runs this micro-batch's rows
-        at (ops/attention.attn_run_len: splash pads a row to a length
-        whose blocks are large; any other implementation runs it as it
-        is)."""
-        return attn_run_len(
-            self.attn_impl, row_len, self.model_cfg.n_q_heads,
-            self.model_cfg.n_kv_heads,
+    def _attn_counts(self, rows: int, row_len: int) -> Tuple[int, int, int]:
+        """What the attention kernels do with one of this micro-batch's
+        rows, from the shapes alone: (the length they run it at:
+        ops/attention.attn_run_len, splash pads a row to a length whose
+        blocks are large and any other implementation runs it as it is;
+        the cells of the block pairs they run, summed over the layers;
+        the cells a causal mask alone would make them run)."""
+        cfg = self.model_cfg
+        shape = dict(
+            impl=self.attn_impl, t=row_len, hq=cfg.n_q_heads, hkv=cfg.n_kv_heads,
             mesh=self.mesh if self.mesh.size > 1 else None, r=rows,
         )
+        cells = [attn_block_cells(window=k.window, **shape) for k in cfg.kinds()]
+        return (attn_run_len(**shape), sum(a for a, _ in cells),
+                sum(c for _, c in cells))
 
-    @staticmethod
-    def _count_batch(path: str, n_mbs: int, n_tok: int, n_cells: int,
-                     n_attn_cells: int):
+    def _count_batch(self, path: str, n_mbs: int, n_tok: int, n_cells: int,
+                     n_attn_cells: int, n_attn_active: int, n_attn_causal: int):
         """What one train_batch did, on its `train.batch` span and in the
         recorder's counters: real tokens, the cells (rows x row length)
-        they were padded to, and the cells the attention kernel ran (rows
-        x the length it ran them at)."""
+        they were padded to, the cells the attention kernel ran (rows
+        x the length it ran them at), the cells of the block pairs it ran
+        against those of a causal mask alone, and the (token, expert)
+        pairs the routers of the expert layers made."""
         tracing.set_attrs(path=path, n_mbs=n_mbs, tokens=n_tok, cells=n_cells)
         tracing.count("train.batches")
         tracing.count("train.micro_batches", n_mbs)
         tracing.count("train.tokens", n_tok)
         tracing.count("train.cells", n_cells)
         tracing.count("train.attn_cells", n_attn_cells)
+        tracing.count("train.attn_active_cells", n_attn_active)
+        tracing.count("train.attn_causal_cells", n_attn_causal)
+        moe = self.model_cfg.moe
+        if moe is not None:
+            tracing.count("train.moe_pairs",
+                          moe.top_k * n_tok * self.model_cfg.n_moe_layers)
 
     def _record_overlap_stats(self):
         """Ship the last pipeline's telemetry through the stats tracker so
@@ -1057,6 +1143,12 @@ class JaxTrainEngine(TrainEngine):
                 # sum across the accumulation scan, so dividing by the
                 # micro-batch count recovers the mean.
                 stats[f"{loss_name}/{k[len('mean:'):]}"] = float(v) / n_mbs
+            elif k.startswith("sum:"):
+                # Counts over the step's micro-batches, as they are: also
+                # the recorder's counter `train.<name>`.
+                name = k[len("sum:"):]
+                stats[f"{loss_name}/{name}"] = float(v)
+                tracing.count(f"train.{name}", float(v))
             else:
                 stats[f"{loss_name}/{k}"] = float(v) / global_denom
         if self.stats_fetch_interval > 1:

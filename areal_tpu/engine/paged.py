@@ -470,6 +470,7 @@ def paged_decode_step(
     writes are routed to the trash page). Returns (logits, pools); with
     return_moe_stats, also a dict of layer-mean router scalars
     (moe_drop_rate / moe_router_entropy; empty for dense models)."""
+    cfg.require_plain_stack("engine/paged.py paged_decode_step")
     cdt = jnp.dtype(cfg.compute_dtype)
     pg = kv_pool_data(k_pages).shape[3]
     B = tokens.shape[0]

@@ -114,6 +114,7 @@ def decode_step(params, cfg: TransformerConfig, tokens, k_cache, v_cache, length
     tokens: [B] the tokens just sampled (to be fed in); lengths: [B] cache
     fill BEFORE this token. Returns (logits [B, V], k_cache, v_cache).
     """
+    cfg.require_plain_stack("models/generation.py decode_step")
     cdt = jnp.dtype(cfg.compute_dtype)
     x = params["embedding"]["weight"][tokens].astype(cdt)  # [B, D]
     if cfg.embedding_multiplier:
@@ -153,6 +154,7 @@ def prefill(params, cfg: TransformerConfig, input_ids, prompt_lens, cache_len: i
     Returns (last_logits [B, V], k_cache, v_cache) with caches sized
     [L, B, cache_len, Hkv, hd].
     """
+    cfg.require_plain_stack("models/generation.py prefill")
     B, P = input_ids.shape
     pos = jnp.arange(P)[None, :]
     seg = (pos < prompt_lens[:, None]).astype(jnp.int32)

@@ -153,3 +153,4 @@ from areal_tpu.models.hf import mistral as _mistral  # noqa: E402,F401
 from areal_tpu.models.hf import mixtral as _mixtral  # noqa: E402,F401
 from areal_tpu.models.hf import gemma as _gemma  # noqa: E402,F401
 from areal_tpu.models.hf import gpt2 as _gpt2  # noqa: E402,F401
+from areal_tpu.models.hf import afmoe as _afmoe  # noqa: E402,F401

@@ -1,10 +1,11 @@
 """Mistral HF conversion: llama layout, silu, GQA.
 Reference parity: realhf/api/from_hf/mistral.py.
 
-Sliding-window attention is intentionally NOT replicated: the TPU build
-always attends over the full (packed) context — a superset of the
-sliding window, matching how the reference treats mistral weights in its
-own flash-attn path for training.
+A published `sliding_window` becomes the window of every layer
+(`TransformerConfig.layer_kinds`): the trainer's attention masks and
+skips what lies behind it (ops/attention.py), and the KV-cache paths,
+which hold no window yet, refuse the configuration
+(`TransformerConfig.require_plain_stack`).
 """
 
 from __future__ import annotations
@@ -12,7 +13,7 @@ from __future__ import annotations
 from typing import Any, Dict
 
 from areal_tpu.api.model_api import register_hf_family
-from areal_tpu.models.config import TransformerConfig
+from areal_tpu.models.config import LayerKind, TransformerConfig
 from areal_tpu.models.hf import HFFamily
 from areal_tpu.models.hf.llama import (
     _config_from_hf as llama_config_from_hf,
@@ -23,13 +24,18 @@ from areal_tpu.models.hf.llama import (
 
 
 def _config_from_hf(hf: Dict[str, Any], is_critic: bool = False) -> TransformerConfig:
-    return llama_config_from_hf(hf, is_critic)
+    cfg = llama_config_from_hf(hf, is_critic)
+    window = hf.get("sliding_window")
+    if window is not None and window < cfg.max_position_embeddings:
+        cfg.layer_kinds = (LayerKind(window=int(window)),) * cfg.n_layers
+    return cfg
 
 
 def _config_to_hf(cfg: TransformerConfig) -> Dict[str, Any]:
     hf = llama_config_to_hf(cfg)
     hf["architectures"] = ["MistralForCausalLM"]
     hf["model_type"] = "mistral"
+    hf["sliding_window"] = cfg.kinds()[0].window
     hf.pop("attention_bias", None)
     return hf
 

@@ -35,10 +35,22 @@ dropless-EP spends up to ep x the expert-FFN FLOPs of capacity
 dispatch for its zero drops and 1/ep weight memory — measured, not
 assumed, by the `moe_scaling` bench phase (docs/perf_notes.md Round
 17), with capacity dispatch kept as the FLOPs-optimal EP baseline.
+
+`MoEConfig.experts_held` makes the layer one share of an expert-parallel
+layer on a chip of its own (`_held_experts`): it routes over all
+experts, computes the experts it holds and adds nothing for the rest,
+with no exchange and nothing that stands in for the absent chips. The
+(token, choice) pairs of held experts are compacted to the front of one
+sort and run through the grouped matmuls in passes over a buffer sized
+for an even share: the first pass always, further ones only while pairs
+are left, so no pair is dropped at any skew and the work follows the
+pairs held, not all k x T. The router's published sigmoid form
+(`score_func`), the selection bias and the shared expert live here too.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -96,9 +108,24 @@ def decode_moe_overrides(cfg: TransformerConfig) -> Tuple[str, Optional[float]]:
     return dispatch, cap
 
 
-def _router(xt, router_w, moe):
-    """fp32 router: probs, renormalized top-k gates, expert choices."""
+def _router(xt, router_w, moe, expert_bias=None):
+    """fp32 router: probs, top-k gates, expert choices. "softmax":
+    probabilities over all experts, the k largest, renormalised.
+    "sigmoid": scores s = sigmoid(logits); the k experts with the largest
+    s + expert_bias (a buffer: no gradient reaches it), weighted by the
+    bare s over their sum (`route_norm`) times the scaling factor."""
     logits = xt.astype(jnp.float32) @ router_w.astype(jnp.float32)  # [T, E]
+    if moe.score_func == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        select = scores
+        if expert_bias is not None:
+            select = scores + jax.lax.stop_gradient(
+                expert_bias.astype(jnp.float32))
+        _, top_e = jax.lax.top_k(select, moe.top_k)  # [T, k]
+        top_p = jnp.take_along_axis(scores, top_e, axis=-1)
+        if moe.route_norm:
+            top_p = top_p / (top_p.sum(-1, keepdims=True) + 1e-20)
+        return logits, scores, top_p * moe.routed_scaling_factor, top_e
     probs = jax.nn.softmax(logits, axis=-1)
     top_p, top_e = jax.lax.top_k(probs, moe.top_k)  # [T, k]
     if moe.routed_scaling_factor != 1.0:
@@ -245,14 +272,195 @@ def _moe_mlp_ep(
         ),
         out_specs=(
             P(rows, "seq", None),
-            {k_: P() for k_ in (
-                "load_balance_loss", "z_loss", "drop_rate",
-                "router_entropy", "expert_load", "a2a_bytes",
-            )},
+            {k_: P() for k_ in AUX_KEYS},
         ),
         check_vma=False,
     )(x, mp["router"], mp["w_gate"], mp["w_up"], mp["w_down"])
     return y, aux
+
+
+AUX_KEYS = ("load_balance_loss", "z_loss", "drop_rate", "router_entropy",
+            "expert_load", "a2a_bytes")
+# What a share of the experts adds (`experts_held`): the real (token,
+# choice) pairs routed to experts held here, and the buffer rows of the
+# passes that ran over them.
+HELD_AUX_KEYS = ("pairs_held", "rows_run")
+
+
+def moe_aux_zeros(cfg: TransformerConfig) -> Dict[str, jnp.ndarray]:
+    """The sums `forward` carries through its layers, at zero: a dense
+    model carries them too (one entry of `expert_load`)."""
+    moe = cfg.moe
+    aux = {k: jnp.zeros((), jnp.float32) for k in AUX_KEYS}
+    aux["expert_load"] = jnp.zeros((moe.num_experts if moe else 1,), jnp.float32)
+    if moe is not None and moe.experts_held is not None:
+        aux.update({k: jnp.zeros((), jnp.float32) for k in HELD_AUX_KEYS})
+    return aux
+
+
+# The buffer of one pass over the held pairs, as a multiple of an even
+# share (k x T x held / experts), rounded up to the grouped matmul's row
+# tile: with routing near even the first pass takes every pair.
+_HELD_BUFFER_FACTOR = 1.25
+_HELD_ROW_TILE = 512
+
+
+def held_buffer_rows(n_tokens: int, moe) -> int:
+    """Rows of the buffer one pass of `_held_experts` runs the grouped
+    matmuls over: static, from the shapes alone."""
+    pairs = n_tokens * moe.top_k
+    even = pairs * moe.n_held / moe.num_experts
+    rows = -(-int(_HELD_BUFFER_FACTOR * even) // _HELD_ROW_TILE) * _HELD_ROW_TILE
+    return max(1, min(pairs, rows))
+
+
+def _add_rows_impl(rows, tok, n_tok: int):
+    b = rows.shape[0]
+    assert n_tok * b < 2**31
+    # By token, then by row: one sort of distinct keys (token x rows +
+    # row), the rows taken in that order, and a scatter-add that is told
+    # its indices are sorted. Left to itself the chip's compiler sorts
+    # the indices of a scatter with the rows as a second operand, and
+    # takes 8 s over that sort at 20k rows (1.8 s over this scatter, 1.5
+    # over this sort).
+    keys = jax.lax.sort(
+        tok.astype(jnp.int32) * b + jnp.arange(b, dtype=jnp.int32), is_stable=False)
+    return jnp.zeros((n_tok, rows.shape[1]), rows.dtype).at[keys // b].add(
+        rows[keys % b], indices_are_sorted=True)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _add_rows(rows, tok, n_tok: int):
+    """[n_tok, D]: row r of `rows` added to token `tok[r]`."""
+    return _add_rows_impl(rows, tok, n_tok)
+
+
+def _add_rows_fwd(rows, tok, n_tok):
+    return _add_rows_impl(rows, tok, n_tok), tok
+
+
+def _add_rows_bwd(n_tok, tok, dy):
+    return dy[tok], None
+
+
+_add_rows.defvjp(_add_rows_fwd, _add_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
+def _take_rows(x, tok, n_tok: int):
+    """x[tok] for x of `n_tok` rows, whose gradient is `_add_rows` (each
+    the other's transpose: neither pass leaves the compiler a scatter to
+    sort)."""
+    return x[tok]
+
+
+def _take_rows_fwd(x, tok, n_tok):
+    return x[tok], tok
+
+
+def _take_rows_bwd(n_tok, tok, d):
+    return _add_rows_impl(d, tok, n_tok), None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+def _held_experts(xt, mp, moe, act, cdt, choice_e, gate, tok_idx, token_mask):
+    """The held experts' part of sum_e w_e Expert_e(x): [T, D], and
+    (pairs held, buffer rows run). `choice_e`, `gate`, `tok_idx`: the
+    k x T (token, choice) pairs' expert, weight and token, choice-major.
+
+    Of the k x T (token, choice) pairs only those of real tokens whose
+    expert is held here take part. One argsort puts them first, ordered
+    by expert; pass c takes rows [c B, (c + 1) B) of that order into a
+    buffer of B rows (`held_buffer_rows`), gathers their tokens, runs
+    the three grouped matmuls with the group sizes that fall into the
+    pass, and adds the weighted results to their tokens. The first pass
+    always runs; pass c > 0 runs only if more than c B pairs are held
+    (`lax.cond` inside a scan: one traced body, static shapes; the
+    backward pass recomputes such a pass instead of keeping it), so no
+    pair is dropped whatever the imbalance and nothing is computed for
+    the pairs of absent experts. Rows of a buffer beyond the pairs left
+    are in no group: the grouped matmul skips them, and what it leaves
+    there is masked on the way in and on the way out."""
+    T, D = xt.shape
+    k = moe.top_k
+    first, n_held = moe.experts_held
+    with jax.named_scope("moe_dispatch"):
+        here = (choice_e >= first) & (choice_e < first + n_held)
+        if token_mask is not None:
+            here &= jnp.tile(token_mask.reshape(-1), k)
+        local_e = jnp.where(here, choice_e - first, n_held).astype(jnp.int32)
+        # Held first, by expert, then by pair: the order of a stable
+        # argsort, as one sort of distinct keys (expert x pairs + pair).
+        # The chip's compiler takes 12 s over a stable or two-operand
+        # sort of this length and 1.5 s over this one.
+        n = k * T
+        assert (n_held + 1) * n < 2**31
+        keys = local_e * n + jnp.arange(n, dtype=jnp.int32)
+        order = jax.lax.sort(keys, is_stable=False) % n
+        sizes = jnp.bincount(local_e, length=n_held + 1)[:n_held].astype(jnp.int32)
+        ends = jnp.cumsum(sizes)
+        n_pairs = ends[-1]
+        tok_sorted = tok_idx[order]
+        gate_sorted = gate[order]
+    B = held_buffer_rows(T, moe)
+    n_pass = -(-k * T // B)
+    pad = n_pass * B - k * T
+    if pad:  # the last pass's slice stays inside the arrays
+        tok_sorted = jnp.pad(tok_sorted, (0, pad))
+        gate_sorted = jnp.pad(gate_sorted, (0, pad))
+    wg, wu, wd = (mp[n].astype(cdt) for n in ("w_gate", "w_up", "w_down"))
+    xc = xt.astype(cdt)
+
+    def one_pass(c):
+        lo = c * B
+        with jax.named_scope("moe_dispatch"):
+            tok = jax.lax.dynamic_slice(tok_sorted, (lo,), (B,))
+            w = jax.lax.dynamic_slice(gate_sorted, (lo,), (B,))
+            valid = (lo + jnp.arange(B, dtype=jnp.int32)) < n_pairs
+            # group sizes of this pass: each expert's rows cut to [lo, lo + B)
+            gs = jnp.diff(jnp.clip(ends, lo, lo + B), prepend=lo).astype(jnp.int32)
+            xs = jnp.where(valid[:, None], _take_rows(xc, tok, T), 0)
+        with jax.named_scope("moe_experts"):
+            h = act(jax.lax.ragged_dot(xs, wg, gs))
+            h = h * jax.lax.ragged_dot(xs, wu, gs)
+            ys = jax.lax.ragged_dot(h, wd, gs)  # [B, D]
+        with jax.named_scope("moe_combine"):
+            # Mask before weighing: what the grouped matmul leaves in rows
+            # of no group need not be finite, and 0 x it (the weight's
+            # gradient, were the mask applied after) would not be 0.
+            ys = w.astype(cdt)[:, None] * jnp.where(valid[:, None], ys, 0)
+            return _add_rows(ys, tok, T)
+
+    y = one_pass(jnp.int32(0))
+    rows_run = jnp.float32(B)
+    if n_pass > 1:
+        # The whole guarded pass is what the backward pass recomputes:
+        # with the `cond` outside the checkpoint its residuals (the
+        # tokens and the expert weights) would leave it as outputs and
+        # the scan would keep a copy of them for every pass, run or not.
+        @jax.checkpoint
+        def guarded_pass(c):
+            return jax.lax.cond(
+                c * B < n_pairs, one_pass, lambda c: jnp.zeros((T, D), cdt), c)
+
+        def overflow(carry, c):
+            y, rows = carry
+            rows = rows + jnp.where(c * B < n_pairs, jnp.float32(B), 0.0)
+            return (y + guarded_pass(c), rows), None
+
+        (y, rows_run), _ = jax.lax.scan(
+            overflow, (y, rows_run), jnp.arange(1, n_pass, dtype=jnp.int32))
+    return y, n_pairs.astype(jnp.float32), rows_run
+
+
+def _shared_expert(xt, sp, act, cdt):
+    """The gated MLP every token passes through."""
+    with jax.named_scope("moe_shared"):
+        xc = xt.astype(cdt)
+        h = act(xc @ sp["w_gate"].astype(cdt)) * (xc @ sp["w_up"].astype(cdt))
+        return h @ sp["w_down"].astype(cdt)
 
 
 def moe_mlp(
@@ -279,7 +487,20 @@ def moe_mlp(
         capacity_factor = moe.capacity_factor
     if dispatch is None:
         dispatch = moe.dispatch
-    if dispatch == "dropless" and moe_ep_degree(cfg, mesh, x.shape) > 1:
+    if moe.experts_held is not None and mesh is not None and mesh.size > 1:
+        raise NotImplementedError(
+            "experts_held is one chip's share of an expert-parallel layer: "
+            "across chips the pairs must be exchanged (models/moe.py has "
+            "_moe_mlp_ep for a whole layer on an fsdp mesh, and no exchange "
+            "for a share)"
+        )
+    if (dispatch == "dropless" and moe.experts_held is None
+            and moe_ep_degree(cfg, mesh, x.shape) > 1):
+        if moe.score_func != "softmax" or "shared" in mp:
+            raise NotImplementedError(
+                "_moe_mlp_ep routes with the softmax router and has no "
+                "shared expert"
+            )
         return _moe_mlp_ep(x, mp, cfg, cdt, mesh)
 
     E, k = moe.num_experts, moe.top_k
@@ -288,14 +509,22 @@ def moe_mlp(
     xt = x.reshape(-1, D)
     T = xt.shape[0]
 
-    logits, probs, top_p, top_e = _router(xt, mp["router"], moe)
+    with jax.named_scope("moe_router"):
+        logits, probs, top_p, top_e = _router(
+            xt, mp["router"], moe, mp.get("expert_bias"))
     choice_e = top_e.T.reshape(-1)  # [k*T] expert ids, choice-major
     gate = top_p.T.reshape(-1)  # [kT], aligned with choice_e
     tok_idx = jnp.tile(jnp.arange(T), k)
     act = jax.nn.silu if cfg.activation == "silu" else jax.nn.gelu
     a2a_bytes = jnp.zeros((), jnp.float32)
+    held_aux = {}
 
-    if dispatch == "dropless":
+    if moe.experts_held is not None:
+        y, pairs_held, rows_run = _held_experts(
+            xt, mp, moe, act, cdt, choice_e, gate, tok_idx, token_mask)
+        held_aux = dict(pairs_held=pairs_held, rows_run=rows_run)
+        drop_rate = jnp.zeros((), jnp.float32)
+    elif dispatch == "dropless":
         # Sort (token, choice) pairs by expert; the expert FFN becomes
         # ragged grouped matmuls with per-expert group sizes. Static
         # shapes (kT rows regardless of skew), zero drops.
@@ -364,6 +593,8 @@ def moe_mlp(
                 jnp.float32,
             )
 
+    if "shared" in mp:
+        y = y + _shared_expert(xt, mp["shared"], act, cdt)
     f_e, P_e, z, entropy = _router_stats(logits, probs, top_e, E)
     return y.reshape(*lead_shape, D), {
         "load_balance_loss": E * jnp.sum(f_e * P_e),
@@ -372,17 +603,32 @@ def moe_mlp(
         "router_entropy": entropy,
         "expert_load": f_e,
         "a2a_bytes": a2a_bytes,
+        **held_aux,
     }
 
 
-def init_moe_params(cfg: TransformerConfig, dense_fn, keys) -> Dict[str, Any]:
-    """Stacked per-layer MoE params (L leading dim, matching the scan)."""
+def init_moe_params(cfg: TransformerConfig, dense_fn, keys, n_layers: int,
+                    shared_key=None) -> Dict[str, Any]:
+    """Stacked per-layer MoE params (L leading dim, matching the scan):
+    the router over all experts, the weights of the experts held here,
+    and where the config has them the selection bias (zeros) and the
+    shared expert."""
     moe = cfg.moe
-    L, D, E = cfg.n_layers, cfg.hidden_dim, moe.num_experts
+    L, D, E, H = n_layers, cfg.hidden_dim, moe.num_experts, moe.n_held
     F = moe.expert_intermediate_dim or cfg.intermediate_dim
-    return {
+    mp = {
         "router": dense_fn(keys[0], (L, D, E)),
-        "w_gate": dense_fn(keys[1], (L, E, D, F)),
-        "w_up": dense_fn(keys[2], (L, E, D, F)),
-        "w_down": dense_fn(keys[3], (L, E, F, D)),
+        "w_gate": dense_fn(keys[1], (L, H, D, F)),
+        "w_up": dense_fn(keys[2], (L, H, D, F)),
+        "w_down": dense_fn(keys[3], (L, H, F, D)),
     }
+    if moe.router_bias:
+        mp["expert_bias"] = jnp.zeros((L, E), jnp.float32)
+    if moe.n_shared_experts:
+        Fs, ks = F * moe.n_shared_experts, jax.random.split(shared_key, 3)
+        mp["shared"] = {
+            "w_gate": dense_fn(ks[0], (L, D, Fs)),
+            "w_up": dense_fn(ks[1], (L, D, Fs)),
+            "w_down": dense_fn(ks[2], (L, Fs, D)),
+        }
+    return mp

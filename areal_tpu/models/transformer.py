@@ -7,6 +7,14 @@ real_llm_base.py (blocks) — redesigned for XLA rather than translated:
   one pytree with a leading layer axis, and the forward pass scans over it.
   One layer gets traced/compiled regardless of depth, and XLA pipelines
   HBM weight streaming across layers.
+- **Layers of several kinds in one stack** (`config.LayerKind`: dense or
+  expert MLP, an attention window or none, rotary or none, all static):
+  leading layers whose MLP differs from the rest are a stack of their own
+  (`params["lead_layers"]`) and run before the scan; the scanned layers
+  share one MLP kind, and which (window, rotary) attention each has is an
+  index scanned beside its parameters that switches the attention call
+  alone. One layer body is traced whatever the pattern of kinds; a stack
+  of one kind is the plain scan.
 - **Packed rows**: a batch is [R, T] token streams; each row packs several
   variable-length sequences tagged by segment ids (0 = padding). No pad
   waste beyond the row tail, matching the reference's packed varlen
@@ -46,16 +54,11 @@ Params = Dict[str, Any]
 # ---------------------------------------------------------------------------
 
 
-def init_params(cfg: TransformerConfig, rng: jax.Array) -> Params:
-    """Random-init parameter pytree with stacked layers."""
+def _init_layer_stack(cfg: TransformerConfig, keys, n: int, mlp_kind: str,
+                      dense) -> Dict[str, Any]:
+    """`n` layers of one MLP kind, stacked on a leading axis."""
     pdt = jnp.dtype(cfg.param_dtype)
-    D, F, V, L = cfg.hidden_dim, cfg.intermediate_dim, cfg.vocab_size, cfg.n_layers
-    keys = jax.random.split(rng, 16)
-
-    def dense(key, shape, scale=None):
-        scale = scale if scale is not None else (1.0 / math.sqrt(shape[-2]))
-        return (jax.random.normal(key, shape, dtype=jnp.float32) * scale).astype(pdt)
-
+    D, F, L = cfg.hidden_dim, cfg.intermediate_dim, n
     attn: Dict[str, Any] = {
         "wq": dense(keys[0], (L, D, cfg.q_dim)),
         "wk": dense(keys[1], (L, D, cfg.kv_dim)),
@@ -71,16 +74,15 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Params:
     if cfg.qk_norm:
         attn["q_norm"] = jnp.ones((L, cfg.head_dim), pdt)
         attn["k_norm"] = jnp.ones((L, cfg.head_dim), pdt)
+    if cfg.attn_gate:
+        attn["wg"] = dense(keys[10], (L, D, cfg.q_dim))
 
-    if cfg.moe is not None:
+    if mlp_kind == "moe":
         from areal_tpu.models.moe import init_moe_params
 
-        if cfg.moe.first_k_dense:
-            raise NotImplementedError(
-                "first_k_dense breaks the homogeneous layer scan; "
-                "interleaved dense layers are not supported yet"
-            )
-        mlp = init_moe_params(cfg, dense, jax.random.split(keys[4], 4))
+        mlp = init_moe_params(
+            cfg, dense, jax.random.split(keys[4], 4), L, shared_key=keys[11]
+        )
     elif cfg.mlp_type == "gated":
         mlp = {
             "w_gate": dense(keys[4], (L, D, F)),
@@ -92,7 +94,7 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Params:
             "w_in": dense(keys[4], (L, D, F)),
             "w_out": dense(keys[6], (L, F, D)),
         }
-    if cfg.mlp_bias and cfg.moe is None:
+    if cfg.mlp_bias and mlp_kind == "dense":
         if cfg.mlp_type == "gated":
             mlp["b_gate"] = jnp.zeros((L, F), pdt)
             mlp["b_up"] = jnp.zeros((L, F), pdt)
@@ -101,21 +103,40 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Params:
             mlp["b_in"] = jnp.zeros((L, F), pdt)
             mlp["b_out"] = jnp.zeros((L, D), pdt)
 
-    layers = {
-        "ln1": {"weight": jnp.ones((L, D), pdt)},
-        "ln2": {"weight": jnp.ones((L, D), pdt)},
-        "attn": attn,
-        "mlp": mlp,
-    }
+    norms = ["ln1", "ln2"] + (["ln1_post", "ln2_post"] if cfg.post_norms else [])
+    layers = {name: {"weight": jnp.ones((L, D), pdt)} for name in norms}
+    layers.update(attn=attn, mlp=mlp)
     if cfg.norm_type == "layer":
-        layers["ln1"]["bias"] = jnp.zeros((L, D), pdt)
-        layers["ln2"]["bias"] = jnp.zeros((L, D), pdt)
+        for name in norms:
+            layers[name]["bias"] = jnp.zeros((L, D), pdt)
+    return layers
 
+
+def init_params(cfg: TransformerConfig, rng: jax.Array) -> Params:
+    """Random-init parameter pytree with stacked layers: `layers`, the
+    stack the forward pass scans, and before it `lead_layers` where the
+    first layers have another MLP than the rest (leading dense layers of
+    an expert model)."""
+    pdt = jnp.dtype(cfg.param_dtype)
+    D, V = cfg.hidden_dim, cfg.vocab_size
+    keys = jax.random.split(rng, 16)
+
+    def dense(key, shape, scale=None):
+        scale = scale if scale is not None else (1.0 / math.sqrt(shape[-2]))
+        return (jax.random.normal(key, shape, dtype=jnp.float32) * scale).astype(pdt)
+
+    kinds, n_lead = cfg.kinds(), cfg.n_lead_layers
     params: Params = {
         "embedding": {"weight": dense(keys[7], (V, D), scale=0.02)},
-        "layers": layers,
+        "layers": _init_layer_stack(
+            cfg, keys, cfg.n_layers - n_lead, kinds[-1].mlp, dense
+        ),
         "final_norm": {"weight": jnp.ones((D,), pdt)},
     }
+    if n_lead:
+        params["lead_layers"] = _init_layer_stack(
+            cfg, jax.random.split(keys[12], 16), n_lead, kinds[0].mlp, dense
+        )
     if cfg.pos_emb == "learned":
         params["pos_embedding"] = {
             "weight": dense(keys[9], (cfg.max_position_embeddings, D), scale=0.02)
@@ -165,17 +186,80 @@ def _mlp(h, lp, cfg, cdt):
     return out
 
 
+def _attention_kernel(q, k, v, segment_ids, positions, impl, cfg, mesh, window):
+    """The attention call itself, by the resolved implementation:
+    q [R, T, Hq, hd], k and v [R, T, Hkv, hd] -> [R, T, Hq, hd]."""
+    from areal_tpu.ops.attention import sharded_splash_attention
+
+    R, T = q.shape[:2]
+    sharded = mesh is not None and mesh.size > 1
+    if window is not None and impl in ("ring", "ulysses"):
+        raise NotImplementedError(
+            f"attn_impl={impl!r} (context parallelism over the mesh's seq "
+            "axis) has no window in its mask: ops/ring_attention.py and "
+            "ops/ulysses_attention.py build a causal mask only"
+        )
+    if impl == "ring":
+        # Context parallelism: KV chunks ring-rotate over the seq axis
+        # (O(T/seq) per-device attention memory — the long-context path).
+        from areal_tpu.ops.ring_attention import ring_ok, ring_packed_attention
+
+        if not (sharded and ring_ok(mesh, R, T, cfg.n_q_heads, cfg.n_kv_heads)):
+            raise ValueError(
+                "attn_impl='ring' needs a mesh with seq > 1 and divisible "
+                f"shapes (R={R}, T={T}, Hq={cfg.n_q_heads}, "
+                f"Hkv={cfg.n_kv_heads}, mesh={dict(mesh.shape) if mesh else None})"
+            )
+        out = ring_packed_attention(q, k, v, segment_ids, positions, mesh)
+    elif impl == "ulysses":
+        # Context parallelism via all-to-alls (seq shard swaps onto
+        # heads; 4 a2a + 2 small gathers per layer vs ring's S ppermute
+        # steps) with a splash local kernel on TPU; pick ring vs ulysses
+        # by measurement per context length (ops/ulysses_attention.py).
+        from areal_tpu.ops.ulysses_attention import (
+            ulysses_ok,
+            ulysses_packed_attention,
+        )
+
+        if not (
+            sharded and ulysses_ok(mesh, R, T, cfg.n_q_heads, cfg.n_kv_heads)
+        ):
+            raise ValueError(
+                "attn_impl='ulysses' needs a mesh with seq > 1 and head "
+                f"counts divisible by seq*tensor (R={R}, T={T}, "
+                f"Hq={cfg.n_q_heads}, Hkv={cfg.n_kv_heads}, "
+                f"mesh={dict(mesh.shape) if mesh else None})"
+            )
+        out = ulysses_packed_attention(q, k, v, segment_ids, positions, mesh)
+    elif sharded and impl == "splash":
+        # pallas_call is opaque to GSPMD: run the kernel per shard under
+        # shard_map with the megatron-equivalent layout.
+        out = sharded_splash_attention(
+            q, k, v, segment_ids, positions, mesh, window=window
+        )  # [R, T, Hq, hd]
+    else:
+        attn_fn = lambda q1, k1, v1, s1, p1: packed_attention(
+            q1, k1, v1, s1, p1, impl=impl, window=window
+        )
+        out = jax.vmap(attn_fn)(q, k, v, segment_ids, positions)
+    return out
+
+
 def _attention_block(
-    x, lp, cfg, cos, sin, segment_ids, positions, attn_impl, cdt, mesh=None
+    x, lp, cfg, cos, sin, segment_ids, positions, attn_impl, cdt, mesh=None,
+    variants=((None, True),), variant_index=None,
 ):
     """x: [R, T, D] -> attention output [R, T, D]. Named scopes say in
     the device trace which part an op belongs to: `attn_qkv`
     (projections, rotary; the caller's input norm too), `attn_kernel`
-    (the attention call), `attn_out`."""
-    from areal_tpu.ops.attention import (
-        resolve_attn_impl,
-        sharded_splash_attention,
-    )
+    (the attention call), `attn_out`, and inside the first and the last
+    `attn_gate` (the gate's projection; its sigmoid times the kernel's
+    output). `variants` are the (window, rotary) pairs the layers of
+    this stack have: a window limits a token to the `window` positions
+    ending at it, no rotary leaves q and k without a position encoding.
+    One variant is called as it is; of several, `variant_index` (traced,
+    scanned beside the layer's parameters) picks the one that runs."""
+    from areal_tpu.ops.attention import resolve_attn_impl
 
     R, T, D = x.shape
     with jax.named_scope("attn_qkv"):
@@ -192,9 +276,9 @@ def _attention_block(
         if cfg.qk_norm:
             q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
             k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
-        if cos is not None:  # rotary position encoding (None = learned pos emb)
-            q = apply_rotary(q, cos, sin, cfg.rotary_interleaved)
-            k = apply_rotary(k, cos, sin, cfg.rotary_interleaved)
+        if "wg" in lp:
+            with jax.named_scope("attn_gate"):
+                gate = x @ lp["wg"].astype(cdt)
 
     # Resolution is mesh-aware: a seq>1 mesh picks a CP scheme for
     # 'auto' (Ulysses when heads divide the seq axis, ring otherwise)
@@ -203,56 +287,47 @@ def _attention_block(
     impl = resolve_attn_impl(
         attn_impl, T, cfg.n_q_heads, cfg.n_kv_heads, mesh=mesh, r=R
     )
-    sharded = mesh is not None and mesh.size > 1
-    with jax.named_scope("attn_kernel"):
-        if impl == "ring":
-            # Context parallelism: KV chunks ring-rotate over the seq axis
-            # (O(T/seq) per-device attention memory — the long-context path).
-            from areal_tpu.ops.ring_attention import ring_ok, ring_packed_attention
 
-            if not (sharded and ring_ok(mesh, R, T, cfg.n_q_heads, cfg.n_kv_heads)):
-                raise ValueError(
-                    "attn_impl='ring' needs a mesh with seq > 1 and divisible "
-                    f"shapes (R={R}, T={T}, Hq={cfg.n_q_heads}, "
-                    f"Hkv={cfg.n_kv_heads}, mesh={dict(mesh.shape) if mesh else None})"
-                )
-            out = ring_packed_attention(q, k, v, segment_ids, positions, mesh)
-        elif impl == "ulysses":
-            # Context parallelism via all-to-alls (seq shard swaps onto
-            # heads; 4 a2a + 2 small gathers per layer vs ring's S ppermute
-            # steps) with a splash local kernel on TPU; pick ring vs ulysses
-            # by measurement per context length (ops/ulysses_attention.py).
-            from areal_tpu.ops.ulysses_attention import (
-                ulysses_ok,
-                ulysses_packed_attention,
-            )
+    def attend(window, rotary):
+        """q, k, v -> (attention output [R, T, Hq, hd], k as attended)."""
 
-            if not (
-                sharded and ulysses_ok(mesh, R, T, cfg.n_q_heads, cfg.n_kv_heads)
-            ):
-                raise ValueError(
-                    "attn_impl='ulysses' needs a mesh with seq > 1 and head "
-                    f"counts divisible by seq*tensor (R={R}, T={T}, "
-                    f"Hq={cfg.n_q_heads}, Hkv={cfg.n_kv_heads}, "
-                    f"mesh={dict(mesh.shape) if mesh else None})"
-                )
-            out = ulysses_packed_attention(q, k, v, segment_ids, positions, mesh)
-        elif sharded and impl == "splash":
-            # pallas_call is opaque to GSPMD: run the kernel per shard under
-            # shard_map with the megatron-equivalent layout.
-            out = sharded_splash_attention(
-                q, k, v, segment_ids, positions, mesh
-            )  # [R, T, Hq, hd]
-        else:
-            attn_fn = lambda q1, k1, v1, s1, p1: packed_attention(
-                q1, k1, v1, s1, p1, impl=impl
-            )
-            out = jax.vmap(attn_fn)(q, k, v, segment_ids, positions)
+        def run(q, k, v):
+            if rotary and cos is not None:  # None = learned pos emb
+                with jax.named_scope("attn_qkv"):
+                    q = apply_rotary(q, cos, sin, cfg.rotary_interleaved)
+                    k = apply_rotary(k, cos, sin, cfg.rotary_interleaved)
+            with jax.named_scope("attn_kernel"):
+                return _attention_kernel(
+                    q, k, v, segment_ids, positions, impl, cfg, mesh, window), k
+
+        return run
+
+    if len(variants) == 1:
+        out, k = attend(*variants[0])(q, k, v)
+    else:
+        out, k = jax.lax.switch(
+            variant_index, [attend(*vt) for vt in variants], q, k, v)
     with jax.named_scope("attn_out"):
-        out = out.reshape(R, T, cfg.q_dim) @ lp["wo"].astype(cdt)
+        out = out.reshape(R, T, cfg.q_dim)
+        if "wg" in lp:
+            with jax.named_scope("attn_gate"):
+                out = out * jax.nn.sigmoid(gate)
+        out = out @ lp["wo"].astype(cdt)
         if "bo" in lp:
             out = out + lp["bo"].astype(cdt)
     return out, (k, v)
+
+
+def _unstack(stack, n: int):
+    """The `n` layers of a stack, one pytree each, by a split whose
+    transpose is one concatenation: indexing's would be a zero-padded
+    copy of the whole stack for every layer."""
+    if not n:
+        return []
+    leaves, treedef = jax.tree_util.tree_flatten(stack)
+    cut = [[p.reshape(p.shape[1:]) for p in jax.lax.split(a, (1,) * n, axis=0)]
+           for a in leaves]
+    return [treedef.unflatten([c[i] for c in cut]) for i in range(n)]
 
 
 def forward(
@@ -351,67 +426,98 @@ def forward(
                 stacklevel=2,
             )
             remat_mode = "full"
+    kinds, n_lead = cfg.kinds(), cfg.n_lead_layers
+    if return_kv and not all(k == kinds[0] for k in kinds):
+        raise NotImplementedError(
+            "return_kv with layers of different kinds: the KV cache "
+            "(models/generation.py) holds one kind of layer"
+        )
     if use_moe:
         from areal_tpu.models.moe import moe_mlp
 
         moe_token_mask = segment_ids > 0  # real-token drop accounting
         # mesh enables the expert-parallel dropless path (moe.py
         # _moe_mlp_ep) when the fsdp axis divides num_experts.
-        mlp_fn = lambda h, mp: moe_mlp(
+        moe_fn = lambda h, mp: moe_mlp(
             h, mp, cfg, cdt, token_mask=moe_token_mask, mesh=mesh
         )
-    else:
-        mlp_fn = lambda h, mp: _mlp(h, mp, cfg, cdt)
+    dense_fn = lambda h, mp: _mlp(h, mp, cfg, cdt)
     if remat_mode == "mlp":
-        mlp_fn = jax.checkpoint(mlp_fn)
+        dense_fn = jax.checkpoint(dense_fn)
+        if use_moe:
+            moe_fn = jax.checkpoint(moe_fn)
 
-    def layer_body(carry, lp):
-        x, aux_acc = carry
-        with jax.named_scope("attn_qkv"):
-            h = _norm(x, lp["ln1"], cfg)
-        a, kv = _attention_block(
-            h, lp["attn"], cfg, cos, sin,
-            segment_ids, positions, attn_impl, cdt, mesh=mesh,
-        )
-        with jax.named_scope("attn_out"):
-            x = x + a
-        with jax.named_scope("mlp"):
-            h = _norm(x, lp["ln2"], cfg)
-            if use_moe:
-                m, aux = mlp_fn(h, lp["mlp"])
-                aux_acc = {k: aux_acc[k] + aux[k] for k in aux_acc}
-            else:
-                m = mlp_fn(h, lp["mlp"])
-            x = act_c(x + m)
-        return (x, aux_acc), kv if return_kv else None
+    def layer_body(mlp_kind, variants):
+        """carry, (one layer's parameters, which of `variants` it is) ->
+        carry, its (k, v): a layer with this MLP ("dense" or "moe") whose
+        attention is one of the (window, rotary) `variants`, under the
+        remat mode. Layers that differ only in their attention share the
+        one traced body: the switch is around the attention call alone
+        (`_attention_block`), so what the backward pass keeps of a layer
+        is one layer's, whatever its kind."""
+        moe_layer = mlp_kind == "moe"
 
-    aux0 = {
-        "load_balance_loss": jnp.zeros((), jnp.float32),
-        "z_loss": jnp.zeros((), jnp.float32),
-        "drop_rate": jnp.zeros((), jnp.float32),  # summed; /n_layers = mean
-        # Router telemetry (summed over layers like drop_rate):
-        # per-expert routing-fraction histogram, router entropy, and
-        # EP-exchange bytes per device (0 off expert-parallel meshes).
-        "router_entropy": jnp.zeros((), jnp.float32),
-        "expert_load": jnp.zeros(
-            (cfg.moe.num_experts if use_moe else 1,), jnp.float32
-        ),
-        "a2a_bytes": jnp.zeros((), jnp.float32),
-    }
-    if remat_mode == "full":
-        body = jax.checkpoint(layer_body)
-    elif remat_mode == "save_attn":
-        from areal_tpu.ops.attention import SPLASH_RESIDUAL_NAME
+        def body(carry, xs):
+            lp, variant_index = xs
+            x, aux_acc = carry
+            with jax.named_scope("attn_qkv"):
+                h = _norm(x, lp["ln1"], cfg)
+            a, kv = _attention_block(
+                h, lp["attn"], cfg, cos, sin,
+                segment_ids, positions, attn_impl, cdt, mesh=mesh,
+                variants=variants, variant_index=variant_index,
+            )
+            with jax.named_scope("attn_out"):
+                if "ln1_post" in lp:
+                    a = _norm(a, lp["ln1_post"], cfg)
+                x = x + a
+            with jax.named_scope("mlp"):
+                h = _norm(x, lp["ln2"], cfg)
+                if moe_layer:
+                    m, aux = moe_fn(h, lp["mlp"])
+                    aux_acc = {k: aux_acc[k] + aux[k] for k in aux_acc}
+                else:
+                    m = dense_fn(h, lp["mlp"])
+                if "ln2_post" in lp:
+                    m = _norm(m, lp["ln2_post"], cfg)
+                x = act_c(x + m)
+            return (x, aux_acc), kv if return_kv else None
 
-        body = jax.checkpoint(
-            layer_body,
-            policy=jax.checkpoint_policies.save_only_these_names(
-                SPLASH_RESIDUAL_NAME
-            ),
-        )
+        if remat_mode == "full":
+            return jax.checkpoint(body)
+        if remat_mode == "save_attn":
+            from areal_tpu.ops.attention import SPLASH_RESIDUAL_NAME
+
+            return jax.checkpoint(
+                body,
+                policy=jax.checkpoint_policies.save_only_these_names(
+                    SPLASH_RESIDUAL_NAME
+                ),
+            )
+        return body
+
+    from areal_tpu.models.moe import moe_aux_zeros
+
+    attn_of = lambda k: (k.window, k.rotary)
+    carry = (x, moe_aux_zeros(cfg))
+    # Leading layers of another MLP kind run before the scan, one by one.
+    for kind, lp in zip(kinds, _unstack(params.get("lead_layers"), n_lead)):
+        carry, _ = layer_body(kind.mlp, (attn_of(kind),))(carry, (lp, None))
+    # The rest is one scan over `params["layers"]`: its layers have one
+    # MLP kind, and which attention each has is scanned beside its
+    # parameters (nothing, where all have the same).
+    rest = [attn_of(k) for k in kinds[n_lead:]]
+    variants = tuple(sorted(set(rest), key=rest.index))
+    which = None
+    if len(variants) > 1:
+        which = jnp.asarray([variants.index(v) for v in rest], jnp.int32)
+    body = layer_body(kinds[-1].mlp, variants)
+    if which is None:
+        carry, kvs = jax.lax.scan(
+            lambda c, lp: body(c, (lp, None)), carry, params["layers"])
     else:
-        body = layer_body
-    (x, moe_aux), kvs = jax.lax.scan(body, (x, aux0), params["layers"])
+        carry, kvs = jax.lax.scan(body, carry, (params["layers"], which))
+    x, moe_aux = carry
     with jax.named_scope("final_norm"):
         x = _norm(x, params["final_norm"], cfg)
 

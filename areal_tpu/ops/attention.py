@@ -32,13 +32,18 @@ SPLASH_RESIDUAL_NAME = "splash_attn_residuals"
 
 
 def segment_causal_mask(
-    q_seg: jnp.ndarray, kv_seg: jnp.ndarray, q_pos: jnp.ndarray, kv_pos: jnp.ndarray
+    q_seg: jnp.ndarray, kv_seg: jnp.ndarray, q_pos: jnp.ndarray, kv_pos: jnp.ndarray,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
-    """Boolean [Tq, Tk]: token i may attend to token j."""
+    """Boolean [Tq, Tk]: token i may attend to token j. With a `window`,
+    only to the `window` positions that end at its own."""
     same = q_seg[:, None] == kv_seg[None, :]
     causal = q_pos[:, None] >= kv_pos[None, :]
     valid = (q_seg[:, None] > 0) & (kv_seg[None, :] > 0)
-    return same & causal & valid
+    mask = same & causal & valid
+    if window is not None:
+        mask &= q_pos[:, None] - kv_pos[None, :] < window
+    return mask
 
 
 def reference_packed_attention(
@@ -48,6 +53,7 @@ def reference_packed_attention(
     segment_ids: jnp.ndarray,  # [T] int32, 0 = pad
     positions: jnp.ndarray,  # [T] int32 within-sequence positions
     softmax_scale: Optional[float] = None,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     T, Hq, hd = q.shape
     Hkv = k.shape[1]
@@ -58,7 +64,9 @@ def reference_packed_attention(
     vf = v.astype(jnp.float32)
     # scores: [Hkv, group, Tq, Tk]
     scores = jnp.einsum("qhgd,khd->hgqk", qg, kf) * scale
-    mask = segment_causal_mask(segment_ids, segment_ids, positions, positions)
+    mask = segment_causal_mask(
+        segment_ids, segment_ids, positions, positions, window=window
+    )
     scores = jnp.where(mask[None, None], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     # Fully-masked (padding) rows: zero out.
@@ -158,18 +166,40 @@ _SPLASH_NS = (980.0, 0.0115, 1.13, 1.25)
 _SPLASH_MIN_GAIN = 0.05
 
 
-def _splash_cost_terms(t: int, bq: int, bkv: int, bkvc: int) -> tuple:
-    """What splash_cost prices, a q head of one causal row: grid steps
-    (each a fixed overhead whether or not the mask leaves it any work:
-    at 128 x 128 blocks nearly all of the time), and over the block
-    pairs the mask leaves active their bq x bkv cells, their bq rows of
-    softmax bookkeeping once per compute sub-block, and the bq + bkv
-    rows of q and k/v they load."""
+def _active_block_pairs(t: int, bq: int, bkv: int,
+                        window: Optional[int] = None) -> tuple:
+    """(active pairs, widest q row): the (q block, kv block) pairs of a
+    row of length `t` that the mask leaves any work in. Causal: kv block
+    j is active for q block i when j * bkv <= (i + 1) * bq - 1; with a
+    window also when block j ends at or after the first column that
+    q block i's first row sees, i * bq - (window - 1). The forward
+    kernel's grid is nq x the widest row: splash shrinks the kv axis to
+    the most active blocks any q block has, and skips the rest (the
+    fused backward kernel keeps its whole grid and skips the work)."""
     nq, nkv = t // bq, t // bkv
-    # kv blocks j that q block i sees: j * bkv <= (i + 1) * bq - 1
-    active = sum(min(nkv, ((i + 1) * bq - 1) // bkv + 1) for i in range(nq))
-    return (nq * nkv, active * bq * bkv, active * (bkv // bkvc) * bq,
-            active * (bq + bkv))
+    active = widest = 0
+    for i in range(nq):
+        hi = min(nkv - 1, ((i + 1) * bq - 1) // bkv)
+        lo = 0 if window is None else max(0, (i * bq - (window - 1)) // bkv)
+        active += hi - lo + 1
+        widest = max(widest, hi - lo + 1)
+    return active, widest
+
+
+def _splash_cost_terms(t: int, bq: int, bkv: int, bkvc: int,
+                       window: Optional[int] = None) -> tuple:
+    """What splash_cost prices, a q head of one row: grid steps (each a
+    fixed overhead whether or not the mask leaves it any work: at
+    128 x 128 blocks nearly all of the time), and over the block pairs
+    the mask leaves active their bq x bkv cells, their bq rows of
+    softmax bookkeeping once per compute sub-block, and the bq + bkv
+    rows of q and k/v they load. `window` counts a window layer's pairs
+    (for a fit of a `scripts/splash_shape_sweep.py --window` sweep: the
+    constants in the tree are fitted on causal masks, and `splash_cost`
+    prices those alone)."""
+    active, widest = _active_block_pairs(t, bq, bkv, window)
+    return ((t // bq) * widest, active * bq * bkv,
+            active * (bkv // bkvc) * bq, active * (bq + bkv))
 
 
 def splash_cost(t: int, bq: int, bkv: int, bkvc: int) -> float:
@@ -207,10 +237,22 @@ def _cheapest_run_shape(t: int, tq: int, tkv: int, tkvc: int) -> tuple:
     return best
 
 
+def _row_window(t: int, window: Optional[int]) -> Optional[int]:
+    """A window that covers the whole row masks nothing: the same kernel
+    as no window."""
+    return None if window is None or window >= t else int(window)
+
+
 def splash_run_shape(t: int):
     """(t', bq, bkv, bkvc): the length the splash kernel runs a row of
     length `t` at, and its blocks. A pure function of `t` (and the
-    AREAL_SPLASH_* upper targets), decided at trace time.
+    AREAL_SPLASH_* upper targets), decided at trace time: a window layer
+    runs its rows at the same shape as a causal one. Its mask leaves
+    fewer block pairs active, but on a v5e the shape picked here is
+    also a window layer's fastest at four of five row lengths timed and
+    12 % behind at the fifth, while the causal constants over the
+    window's pairs would pick a shape 16 % slower at one
+    (tests/model/data/splash_window_sweep_v5e.jsonl).
 
     Blocks must divide the length, so a row whose count of 128-blocks
     is prime (packed rows are multiples of 128: 3712 = 29 x 128) would
@@ -224,7 +266,7 @@ def splash_run_shape(t: int):
 
 
 def _splash_kernel(t: int, bq: int, bkv: int, bkvc: int, group: int,
-                   interpret: bool = False):
+                   interpret: bool = False, window: Optional[int] = None):
     """Build the splash-attention kernel for rows of `t` at the given
     blocks (the mask object is cached; the kernel itself is rebuilt per
     trace).
@@ -234,8 +276,10 @@ def _splash_kernel(t: int, bq: int, bkv: int, bkvc: int, group: int,
     the reference installs, realhf Dockerfile) is used as an MQA problem
     per kv head: q carries the GQA group as its head axis. Global causal
     mask + segment ids equals our (same segment) & (position causal) mask
-    because packed segments are contiguous with ascending positions.
-    Length and blocks come from `splash_run_shape`; the backward is the
+    because packed segments are contiguous with ascending positions; a
+    `window` is splash's LocalMask (window - 1 to the left, none to the
+    right) for the same reason, and the kernels skip the block pairs
+    wholly behind it. Length and blocks come from `splash_run_shape`; the backward is the
     fused dq/dkv kernel at the same blocks.
     """
     from jax.experimental.pallas.ops.tpu.splash_attention import (
@@ -247,10 +291,12 @@ def _splash_kernel(t: int, bq: int, bkv: int, bkvc: int, group: int,
     # mask-info buffers, and reusing it across jit traces leaks tracers
     # (UnexpectedTracerError). Rebuilding per trace is cheap — tracing
     # happens once per compiled program, not per step.
-    key = (t, group)
+    key = (t, group, window)
     mask = _SPLASH_MASK_CACHE.get(key)
     if mask is None:
-        mask = sm.MultiHeadMask([sm.CausalMask((t, t)) for _ in range(group)])
+        one = (sm.CausalMask((t, t)) if window is None else
+               sm.LocalMask((t, t), window_size=(window - 1, 0), offset=0))
+        mask = sm.MultiHeadMask([one for _ in range(group)])
         _SPLASH_MASK_CACHE[key] = mask
 
     bs = sk.BlockSizes(
@@ -277,6 +323,7 @@ def splash_packed_attention(
     softmax_scale: Optional[float] = None,
     interpret: Optional[bool] = None,
     _run_shape: Optional[tuple] = None,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """Packed GQA attention on jax's splash kernel (one MQA call per kv
     head, GQA group as the q-head axis). Pad tokens (segment 0) attend
@@ -297,9 +344,10 @@ def splash_packed_attention(
     scale = float(softmax_scale) if softmax_scale is not None else hd ** -0.5
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    window = _row_window(t, window)
     t_run, bq, bkv, bkvc = _run_shape or splash_run_shape(t)
     kernel = _splash_kernel(t_run, bq, bkv, bkvc, group,
-                            interpret=bool(interpret))
+                            interpret=bool(interpret), window=window)
 
     q = q * jnp.asarray(scale, q.dtype)
     if t_run > t:
@@ -328,6 +376,7 @@ def sharded_splash_attention(
     mesh,
     softmax_scale: Optional[float] = None,
     interpret: Optional[bool] = None,
+    window: Optional[int] = None,
 ) -> jnp.ndarray:
     """splash attention under `shard_map` for GSPMD programs.
 
@@ -355,6 +404,7 @@ def sharded_splash_attention(
             lambda q1, k1, v1, s1, p1: splash_packed_attention(
                 q1, k1, v1, s1, p1,
                 softmax_scale=softmax_scale, interpret=interpret,
+                window=window,
             )
         )(q, k, v, seg, pos)
 
@@ -469,7 +519,7 @@ def resolve_attn_impl(
 
 
 def attn_run_len(
-    impl: str, t: int, hq: int, hkv: int, mesh=None, r: Optional[int] = None,
+    impl: str, t: int, hq: int, hkv: int, mesh=None, r: Optional[int] = None
 ) -> int:
     """Length the attention kernel runs rows of `t` at: splash's padded
     `t'` (splash_run_shape) where splash is what runs, else `t`. For
@@ -479,21 +529,47 @@ def attn_run_len(
     return splash_run_shape(t)[0] if ran == "splash" else t
 
 
-def packed_attention(q, k, v, segment_ids, positions, softmax_scale=None, impl="auto"):
+def attn_block_cells(
+    impl: str, t: int, hq: int, hkv: int, mesh=None, r: Optional[int] = None,
+    window: Optional[int] = None,
+) -> tuple:
+    """(cells the attention kernel runs for one row of `t`, cells it
+    would run under the causal mask alone), per q head: those of the
+    active block pairs at the shape splash runs the row at; an
+    implementation without blocks (the einsum reference) runs all
+    t x t cells whatever the mask. For host-side counters."""
+    ran, _ = _choose_attn_impl(impl, t, hq, hkv, mesh, r)
+    if ran != "splash":
+        return t * t, t * t
+    t_run, bq, bkv, _ = splash_run_shape(t)
+    pairs = lambda w: _active_block_pairs(t_run, bq, bkv, w)[0] * bq * bkv
+    return pairs(_row_window(t, window)), pairs(None)
+
+
+def packed_attention(q, k, v, segment_ids, positions, softmax_scale=None,
+                     impl="auto", window=None):
     """Dispatch between implementations. Static decision (trace-time): `impl`
     is 'reference', 'flash' (our Pallas kernel), 'splash' (jax's tuned TPU
-    kernel), or 'auto' (see resolve_attn_impl)."""
+    kernel), or 'auto' (see resolve_attn_impl). `window` limits a token
+    to the `window` positions that end at its own."""
     impl = resolve_attn_impl(impl, q.shape[0], q.shape[1], k.shape[1])
     if impl == "splash":
         return splash_packed_attention(
-            q, k, v, segment_ids, positions, softmax_scale=softmax_scale
+            q, k, v, segment_ids, positions, softmax_scale=softmax_scale,
+            window=window,
         )
     if impl == "flash":
+        if window is not None:
+            raise NotImplementedError(
+                "attn_impl='flash' (ops/pallas/flash_attn.py) has no window "
+                "in its mask; use 'splash' or 'reference' for a window layer"
+            )
         from areal_tpu.ops.pallas.flash_attn import flash_packed_attention
 
         return flash_packed_attention(
             q, k, v, segment_ids, positions, softmax_scale=softmax_scale
         )
     return reference_packed_attention(
-        q, k, v, segment_ids, positions, softmax_scale=softmax_scale
+        q, k, v, segment_ids, positions, softmax_scale=softmax_scale,
+        window=window,
     )

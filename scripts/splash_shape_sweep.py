@@ -59,7 +59,7 @@ def today(t):
     return A._plain_run_shape(t, *A._splash_block_targets())
 
 
-def time_shape(rows, t, run_shape, hq, hkv, hd, layers):
+def time_shape(rows, t, run_shape, hq, hkv, hd, layers, window=None):
     """(fwd ms, fwd+bwd ms) of `layers` chained attention calls."""
     import jax
     import jax.numpy as jnp
@@ -82,7 +82,7 @@ def time_shape(rows, t, run_shape, hq, hkv, hd, layers):
 
     def attn(q1, k1, v1, s1, p1):
         return splash_packed_attention(q1, k1, v1, s1, p1,
-                                       _run_shape=run_shape)
+                                       _run_shape=run_shape, window=window)
 
     def chain(q, k, v):
         def body(x, _):
@@ -136,10 +136,11 @@ def sweep(args):
                 break
             row = dict(rows=rows, t=t, t_run=c[0], bq=c[1], bkv=c[2], bkvc=c[3],
                        hq=args.hq, hkv=args.hkv, hd=args.hd, layers=args.layers,
-                       device=jax.devices()[0].device_kind)
+                       window=args.window, device=jax.devices()[0].device_kind)
             try:
                 row["fwd_ms"], row["grad_ms"] = time_shape(
-                    rows, t, c, args.hq, args.hkv, args.hd, args.layers)
+                    rows, t, c, args.hq, args.hkv, args.hd, args.layers,
+                    args.window)
             except Exception as e:  # a block the compiler refuses is a result
                 row["error"] = f"{type(e).__name__}: {str(e)[:300]}"
             f.write(json.dumps(row) + "\n")
@@ -156,7 +157,8 @@ def fit(path):
 
     rows = [json.loads(l) for l in open(path)]
     rows = [r for r in rows if "error" not in r]
-    x = np.asarray([_splash_cost_terms(r["t_run"], r["bq"], r["bkv"], r["bkvc"])
+    x = np.asarray([_splash_cost_terms(r["t_run"], r["bq"], r["bkv"], r["bkvc"],
+                                       r.get("window"))
                     for r in rows], float)
     y = np.asarray([(r["fwd_ms"] + r["grad_ms"]) * 1e6
                     / (r["rows"] * r["hq"] * r["layers"]) for r in rows])
@@ -190,6 +192,8 @@ def main():
     ap.add_argument("--hkv", type=int, default=2)
     ap.add_argument("--hd", type=int, default=128)
     ap.add_argument("--layers", type=int, default=12)
+    ap.add_argument("--window", type=int, default=None,
+                    help="time a window layer (LocalMask), not a causal one")
     ap.add_argument("--max-seconds", type=float, default=3000.0)
     args = ap.parse_args()
     if args.fit:

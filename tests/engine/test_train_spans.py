@@ -19,6 +19,7 @@ from tests.engine.test_prefetch import (
 )
 
 N_MBS = 3
+N_LAYERS = small_cfg().n_layers
 # path -> prefetch depth, and per train_batch the count of each span kind
 PATHS = {
     "overlapped": dict(depth=2, counts={
@@ -97,11 +98,19 @@ def test_attributes_and_counters_count_tokens_and_cells(recorded):
     a = batch["attrs"]
     assert a["path"] == path and a["n_mbs"] == N_MBS
     assert 0 < a["tokens"] <= a["cells"]
+    dispatches = [s["attrs"] for s in spans if s["name"] == "train.dispatch"]
+    # the reference runs on the CPU, at the rows' own length and over
+    # every cell of a row, in each layer, whatever the mask
+    all_cells = sum(
+        (N_MBS if d["kind"] == "fused" else 1) * d["rows"] * d["row_len"] ** 2
+        for d in dispatches) * N_LAYERS
     assert got["counters"] == {
         "train.batches": 1, "train.micro_batches": N_MBS,
         "train.tokens": a["tokens"], "train.cells": a["cells"],
-        # the reference runs on the CPU, at the rows' own length
-        "train.attn_cells": a["cells"]}
+        "train.attn_cells": a["cells"],
+        "train.attn_active_cells": all_cells,
+        "train.attn_causal_cells": all_cells}
+    assert all("window" not in d and "kinds" not in d for d in dispatches)
     kinds = [s["attrs"]["kind"] for s in spans if s["name"] == "train.dispatch"]
     assert kinds == (["first"] + ["next"] * (N_MBS - 1) if path == "overlapped"
                      else ["fused"])

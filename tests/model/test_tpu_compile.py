@@ -1,0 +1,91 @@
+"""The new kernels' call sites compiled for a v5e that is described, not
+attached, at the widths `trinity-d5e16-train-ppo-long` runs them (the
+on-chip-measurement guide's third rehearsal, kept as tests): what the
+chip's compiler would refuse, it refuses here. Nothing runs, so nothing
+here says a result or a time. All such compiles live in this one file:
+only one process at a time may load the TPU's library."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler here, or another process holds it
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_compile_cache():
+    """A compile for a described chip is written to the persistent cache
+    but cannot be read back without the chip."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    before = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", before)
+    cc.reset_cache()
+
+
+def _shape(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+@pytest.mark.parametrize("window", [2048, None], ids=["window", "full"])
+def test_splash_row_of_16k_at_32_over_4_heads_compiles_forward_and_backward(one_chip, window):
+    from areal_tpu.ops.attention import splash_packed_attention
+
+    t, hq, hkv, hd = 8192, 32, 4, 128  # half the longest row: a quicker compile
+    q = _shape((t, hq, hd), jnp.bfloat16, one_chip)
+    kv = _shape((t, hkv, hd), jnp.bfloat16, one_chip)
+    ids = _shape((t,), jnp.int32, one_chip)
+
+    def loss(q, k, v, seg, pos):
+        out = splash_packed_attention(q, k, v, seg, pos, window=window, interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv, ids, ids).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2  # the forward and the fused backward kernel
+
+
+def test_held_experts_pass_compiles_at_the_published_widths(one_chip):
+    """16 of 128 experts of width 1024 over 16,384 tokens top-8: the
+    sort, the passes over a 20,480-row buffer, the grouped matmuls
+    (`lax.ragged_dot` is a kernel on the chip) and the overflow scan."""
+    from areal_tpu.models import moe as moe_lib
+    from areal_tpu.models.config import MoEConfig
+
+    moe = MoEConfig(num_experts=128, top_k=8, dispatch="dropless", score_func="sigmoid",
+                    routed_scaling_factor=2.826, experts_held=(0, 16))
+    T, D, F = 16384, 2048, 1024
+    assert moe_lib.held_buffer_rows(T, moe) == 20480
+    mp = {"w_gate": _shape((16, D, F), jnp.bfloat16, one_chip),
+          "w_up": _shape((16, D, F), jnp.bfloat16, one_chip),
+          "w_down": _shape((16, F, D), jnp.bfloat16, one_chip)}
+    x = _shape((T, D), jnp.bfloat16, one_chip)
+    gate = _shape((8 * T,), jnp.float32, one_chip)
+    choice = _shape((8 * T,), jnp.int32, one_chip)
+    mask = _shape((T,), jnp.bool_, one_chip)
+
+    def loss(x, mp, gate, choice, mask):
+        tok = jnp.tile(jnp.arange(T, dtype=jnp.int32), 8)
+        y, pairs, rows = moe_lib._held_experts(
+            x, mp, moe, jax.nn.silu, jnp.bfloat16, choice, gate, tok, mask)
+        return y.astype(jnp.float32).sum() + pairs + rows
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(x, mp, gate, choice, mask).compile()
+    text = compiled.as_text()
+    assert "ragged-dot" in text and "tpu_custom_call" in text
+    assert " conditional(" in text  # the overflow passes run under a condition
+    # a pass's buffers (1.6 GB by the compiler's count, backward
+    # included), not k x T = 131,072 rows of them (six times that)
+    assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
