@@ -884,9 +884,8 @@ class JaxTrainEngine(TrainEngine):
             }
             self._record_overlap_stats()
             rows, row_len = rows_np["input_ids"].shape[-2:]
-            attn = self._attn_counts(rows, row_len)
-            self._count_batch("fused", len(mbs), n_tok, n_cells,
-                              *(len(mbs) * rows * c for c in attn))
+            attn = self._attn_counts(rows_np["segment_ids"])
+            self._count_batch("fused", len(mbs), n_tok, n_cells, *attn[1:])
 
             step = self._train_step_fn(
                 loss_name, loss_fn, tuple(sorted(rows_np.keys())), len(mbs)
@@ -940,7 +939,8 @@ class JaxTrainEngine(TrainEngine):
                     }
                 cells = batch.n_rows * batch.row_len
                 tracing.set_attrs(tokens=batch.total_tokens, cells=cells)
-            return rows_dev, denom, batch.total_tokens, cells
+                attn = self._attn_counts(rows["segment_ids"])
+            return rows_dev, denom, batch.total_tokens, cells, attn
 
         pf = HostPrefetcher(
             mb_iter, stage, depth=self.prefetch_depth, name=f"train/{loss_name}",
@@ -949,19 +949,18 @@ class JaxTrainEngine(TrainEngine):
         carry = None
         nxt = None
         denom_sum, n_tok, n_cells = 0.0, 0, 0
-        n_attn = [0, 0, 0]  # cells at the run length, active, causal
+        n_attn = [0, 0, 0]  # cells at the run length, run, causal
         gaps_ms: List[float] = []
         mark = time.monotonic_ns()
         try:
-            for rows_dev, denom, tok, cells in pf:
+            for rows_dev, denom, tok, cells, attn in pf:
                 gaps_ms.append((time.monotonic_ns() - mark) / 1e6)
                 denom_sum += denom
                 n_tok += tok
                 n_cells += cells
                 rows, row_len = rows_dev["input_ids"].shape
-                attn = self._attn_counts(rows, row_len)
                 attn_row_len = attn[0]
-                n_attn = [n + rows * c for n, c in zip(n_attn, attn)]
+                n_attn = [n + c for n, c in zip(n_attn, attn[1:])]
                 if carry is None:
                     first, nxt = self._accum_step_fns(
                         loss_name, loss_fn, tuple(sorted(rows_dev.keys()))
@@ -1000,21 +999,33 @@ class JaxTrainEngine(TrainEngine):
             packed, aux, loss_name, global_denom, n_mbs, lr
         )
 
-    def _attn_counts(self, rows: int, row_len: int) -> Tuple[int, int, int]:
-        """What the attention kernels do with one of this micro-batch's
-        rows, from the shapes alone: (the length they run it at:
+    def _attn_counts(self, segment_ids: np.ndarray) -> Tuple[int, int, int, int]:
+        """What the attention kernels do with packed rows (on the host,
+        before the transfer), `segment_ids` [R, T] of one micro-batch or
+        [n, R, T] of several: (the length they run a row at:
         ops/attention.attn_run_len, splash pads a row to a length whose
         blocks are large and any other implementation runs it as it is;
-        the cells of the block pairs they run, summed over the layers;
-        the cells a causal mask alone would make them run)."""
+        rows x that length; the cells of the block pairs they run, summed
+        over rows and layers: by the rows' own segment ids,
+        ops/attention.attn_block_cells; the cells a causal mask alone
+        would make them run)."""
         cfg = self.model_cfg
+        segment_ids = np.asarray(segment_ids)
+        rows, row_len = segment_ids.shape[-2:]
+        mbs = segment_ids.reshape(-1, rows, row_len)
         shape = dict(
-            impl=self.attn_impl, t=row_len, hq=cfg.n_q_heads, hkv=cfg.n_kv_heads,
-            mesh=self.mesh if self.mesh.size > 1 else None, r=rows,
+            impl=self.attn_impl, hq=cfg.n_q_heads, hkv=cfg.n_kv_heads,
+            mesh=self.mesh if self.mesh.size > 1 else None,
         )
-        cells = [attn_block_cells(window=k.window, **shape) for k in cfg.kinds()]
-        return (attn_run_len(**shape), sum(a for a, _ in cells),
-                sum(c for _, c in cells))
+        run_len = attn_run_len(t=row_len, r=rows, **shape)
+        windows = [k.window for k in cfg.kinds()]
+        # One count a window, not one a layer.
+        cells = {w: np.sum([attn_block_cells(segment_ids=mb, window=w, **shape)
+                            for mb in mbs], axis=0)
+                 for w in set(windows)}
+        return (run_len, len(mbs) * rows * run_len,
+                int(sum(cells[w][0] for w in windows)),
+                int(sum(cells[w][1] for w in windows)))
 
     def _count_batch(self, path: str, n_mbs: int, n_tok: int, n_cells: int,
                      n_attn_cells: int, n_attn_active: int, n_attn_causal: int):

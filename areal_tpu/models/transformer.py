@@ -189,7 +189,10 @@ def _mlp(h, lp, cfg, cdt):
 def _attention_kernel(q, k, v, segment_ids, positions, impl, cfg, mesh, window):
     """The attention call itself, by the resolved implementation:
     q [R, T, Hq, hd], k and v [R, T, Hkv, hd] -> [R, T, Hq, hd]."""
-    from areal_tpu.ops.attention import sharded_splash_attention
+    from areal_tpu.ops.attention import (
+        sharded_splash_attention,
+        splash_packed_attention,
+    )
 
     R, T = q.shape[:2]
     sharded = mesh is not None and mesh.size > 1
@@ -237,6 +240,10 @@ def _attention_kernel(q, k, v, segment_ids, positions, impl, cfg, mesh, window):
         out = sharded_splash_attention(
             q, k, v, segment_ids, positions, mesh, window=window
         )  # [R, T, Hq, hd]
+    elif impl == "splash":
+        # Rows go in whole: the wrapper sees how many share the call.
+        out = splash_packed_attention(
+            q, k, v, segment_ids, positions, window=window)
     else:
         attn_fn = lambda q1, k1, v1, s1, p1: packed_attention(
             q1, k1, v1, s1, p1, impl=impl, window=window

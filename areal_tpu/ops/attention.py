@@ -21,6 +21,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from areal_tpu.base import env_registry
 
@@ -166,24 +167,63 @@ _SPLASH_NS = (980.0, 0.0115, 1.13, 1.25)
 _SPLASH_MIN_GAIN = 0.05
 
 
+def _static_block_pairs(t: int, bq: int, bkv: int,
+                        window: Optional[int] = None):
+    """bool [nq, nkv]: the (q block, kv block) pairs of a row of length
+    `t` that the row's own mask leaves any work in, whatever the row
+    holds. Causal: kv block j for q block i when j * bkv <= (i + 1) * bq
+    - 1; with a window also when block j ends at or after the first
+    column that q block i's first row sees, i * bq - (window - 1)."""
+    i = np.arange(t // bq)[:, None]
+    j = np.arange(t // bkv)[None, :]
+    pairs = j * bkv <= (i + 1) * bq - 1
+    if window is not None:
+        pairs &= (j + 1) * bkv - 1 >= i * bq - (window - 1)
+    return pairs
+
+
 def _active_block_pairs(t: int, bq: int, bkv: int,
                         window: Optional[int] = None) -> tuple:
-    """(active pairs, widest q row): the (q block, kv block) pairs of a
-    row of length `t` that the mask leaves any work in. Causal: kv block
-    j is active for q block i when j * bkv <= (i + 1) * bq - 1; with a
-    window also when block j ends at or after the first column that
-    q block i's first row sees, i * bq - (window - 1). The forward
-    kernel's grid is nq x the widest row: splash shrinks the kv axis to
-    the most active blocks any q block has, and skips the rest (the
-    fused backward kernel keeps its whole grid and skips the work)."""
-    nq, nkv = t // bq, t // bkv
-    active = widest = 0
-    for i in range(nq):
-        hi = min(nkv - 1, ((i + 1) * bq - 1) // bkv)
-        lo = 0 if window is None else max(0, (i * bq - (window - 1)) // bkv)
-        active += hi - lo + 1
-        widest = max(widest, hi - lo + 1)
-    return active, widest
+    """(active pairs, widest q row) of `_static_block_pairs`. The
+    forward kernel's grid is nq x the widest row: splash shrinks the kv
+    axis to the most active blocks any q block has, and skips the rest
+    (the fused backward kernel keeps its whole grid and skips the
+    work)."""
+    pairs = _static_block_pairs(t, bq, bkv, window)
+    return int(pairs.sum()), int(pairs.sum(axis=1).max())
+
+
+def live_block_pairs(segment_ids, bq: int, bkv: int):
+    """bool [..., nq, nkv]: the (q block, kv block) pairs of packed rows
+    `segment_ids` [..., T] that may hold a q token and a kv token of one
+    sequence. A block's sequences span [lowest, highest non-zero id];
+    a pair is live when both blocks hold a real token and the two spans
+    meet. A span covers every id in its block, so no pair with two
+    tokens of one sequence is ever dropped; where the packer numbers a
+    row's sequences 1, 2, .. in order, each contiguous, none is kept in
+    vain either. The pairs on a q block's own diagonal are always live:
+    a q block with no kv block at all would divide by a softmax
+    denominator of 0, and the NaN it leaves at padded positions reaches
+    the weights' gradients as 0 x NaN.
+
+    One rule for the device (traced ids: `_block_tables`) and the host
+    (numpy ids: `attn_block_cells`)."""
+    xp = jnp if isinstance(segment_ids, jax.Array) else np
+    t = segment_ids.shape[-1]
+
+    def spans(b):
+        ids = segment_ids.reshape(*segment_ids.shape[:-1], t // b, b)
+        lo = xp.where(ids > 0, ids, np.iinfo(np.int32).max).min(axis=-1)
+        return lo, ids.max(axis=-1)
+
+    # A block with no real token spans [largest int, 0]: it meets nothing.
+    (lo_q, hi_q), (lo_k, hi_k) = spans(bq), spans(bkv)
+    meet = ((lo_q[..., :, None] <= hi_k[..., None, :])
+            & (lo_k[..., None, :] <= hi_q[..., :, None]))
+    i = np.arange(t // bq)[:, None]
+    j = np.arange(t // bkv)[None, :]
+    diagonal = (j * bkv < (i + 1) * bq) & (i * bq < (j + 1) * bkv)
+    return meet | diagonal
 
 
 def _splash_cost_terms(t: int, bq: int, bkv: int, bkvc: int,
@@ -314,8 +354,85 @@ def _splash_kernel(t: int, bq: int, bkv: int, bkvc: int, group: int,
     )
 
 
+def _next_live(live, block):
+    """For every step of a kernel's walk ([a, b], row by row): `block`
+    at this step if it is live, else at the next live one (what the
+    pipeline fetches meanwhile), 0 after the last."""
+    n = live.size
+    at = jnp.where(live.reshape(n), jnp.arange(n), n)
+    nxt = jax.lax.cummin(at, reverse=True)
+    return jnp.append(block.reshape(n), 0)[nxt].reshape(live.shape)
+
+
+@functools.partial(jax.jit, static_argnums=(5, 6))
+def _block_tables(segment_ids, fwd_mask, fwd_next, dkv_mask, dkv_next, bq, bkv):
+    """The block tables the splash kernels skip by, for one packed row
+    `segment_ids` [T]: a kernel's static tables (`block_mask` says
+    whether a grid step runs, `data_next` which block it loads; [nq,
+    steps] each, the head axis taken off) with the pairs outside
+    `live_block_pairs` skipped as well. Returns (forward block_mask,
+    data_next, fused backward block_mask, data_next), [1, nq, steps] in
+    the static arrays' own dtypes. Jitted: a program's call sites (each
+    kind of layer, the forward pass and remat's) share one traced copy."""
+    live = live_block_pairs(segment_ids, bq, bkv)  # [nq, nkv]
+
+    def masked(mask, dtype, live, block, walk):
+        """`walk` puts [nq, steps] in the order the kernel's grid goes."""
+        run = live & (mask > 0)
+        return (jnp.where(run, mask, 0)[None],
+                walk(_next_live(walk(run), walk(block))).astype(dtype)[None])
+
+    # Forward, a q block's steps one after another: under a window the kv
+    # axis is shrunk to the widest q row, and the static data_next says
+    # which kv block a step loads.
+    kv_of_step = fwd_next.astype(jnp.int32)
+    fwd = masked(fwd_mask, fwd_next.dtype,
+                 jnp.take_along_axis(live, kv_of_step, axis=1), kv_of_step,
+                 lambda a: a)
+    # Fused backward, not shrunk, a kv block's q blocks one after
+    # another; data_next names the q block.
+    q_of_step = jnp.broadcast_to(jnp.arange(live.shape[0])[:, None], live.shape)
+    dkv = masked(dkv_mask, dkv_next.dtype, live, q_of_step, jnp.transpose)
+    return (*fwd, *dkv)
+
+
+def _with_tables(kernel, tables):
+    """`kernel` skipping by the run-time `tables` of one row
+    (`_block_tables`) in place of its static ones. Both are
+    scalar-prefetch operands, so they may be traced (jax's own dynamic
+    masks are). Inside a pair that runs nothing changes: the segment ids
+    still mask cell by cell."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk,
+    )
+
+    fwd_mask, fwd_next, dkv_mask, dkv_next = tables
+    return sk.SplashAttentionKernel(
+        kernel.fwd_mask_info._replace(block_mask=fwd_mask, data_next=fwd_next),
+        None,
+        kernel.dkv_mask_info._replace(block_mask=dkv_mask, data_next=dkv_next),
+        **kernel.kwargs)
+
+
+# A row shorter than this (at the length it runs at) keeps the static
+# kernel: its kernels take tens of microseconds, and a program whose block
+# tables are values of the run takes 0.2-0.3 s longer to trace and lower.
+_SKIP_MIN_LEN = 2048
+
+
+def _rows_skip(rows: int, t_run: int) -> bool:
+    """Whether the kernels of `rows` packed rows in one call skip by the
+    rows' own segment ids: a long row alone. Several rows keep the static
+    kernel and share one grid: a table a row needs a loop over the rows,
+    which under `vmap` (pallas's own, around the kernel calls alone) is
+    slower than the static kernel and under `lax.map` gained 3-4 % of
+    `q15d12-train-ppo`'s tokens/s for 19 % of its `setup_s`, each program
+    0.35 s longer to trace and lower (PERF.md section 6, PR 29)."""
+    return rows == 1 and t_run >= _SKIP_MIN_LEN
+
+
 def splash_packed_attention(
-    q: jnp.ndarray,  # [T, Hq, hd]
+    q: jnp.ndarray,  # [T, Hq, hd], or packed rows: [R, T, Hq, hd]
     k: jnp.ndarray,  # [T, Hkv, hd]
     v: jnp.ndarray,  # [T, Hkv, hd]
     segment_ids: jnp.ndarray,  # [T] int32, 0 = pad
@@ -333,7 +450,38 @@ def splash_packed_attention(
     The kernel runs at `splash_run_shape(T)`: the row is padded with
     zeros in segment 0 up to a length whose blocks are large, and the
     first T positions come back. `_run_shape` overrides that choice
-    (tests, scripts/splash_shape_sweep.py)."""
+    (tests, scripts/splash_shape_sweep.py).
+
+    Of the block pairs the row's causal or window mask leaves, the
+    kernels of a long row alone (`_rows_skip`) run those that
+    `live_block_pairs` finds in the row's segment ids: not the pairs
+    between two sequences, nor those between a sequence and the padding.
+    What comes back at a real position is the same to the bit.
+
+    Packed rows go in whole, every array with a leading axis, so that
+    the wrapper sees how many share the call; a row given alone (which
+    may be under a caller's `vmap`) keeps the static kernel."""
+    del positions
+    t, hd = q.shape[-3], q.shape[-1]
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    run_shape = _run_shape or splash_run_shape(t)
+    one = functools.partial(
+        _splash_row, run_shape=run_shape, interpret=bool(interpret),
+        window=_row_window(t, window),
+        skip=q.ndim == 4 and _rows_skip(q.shape[0], run_shape[0]),
+        scale=float(softmax_scale) if softmax_scale is not None else hd ** -0.5)
+    rows = (q, k, v, segment_ids)
+    if q.ndim == 3:
+        return one(*rows)
+    if q.shape[0] == 1:
+        return one(*(a[0] for a in rows))[None]
+    return jax.vmap(one)(*rows)
+
+
+def _splash_row(q, k, v, segment_ids, *, run_shape, interpret, window, skip,
+                scale):
+    """One packed row of `splash_packed_attention`, its choices made."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk,
     )
@@ -341,13 +489,9 @@ def splash_packed_attention(
     t, hq, hd = q.shape
     hkv = k.shape[1]
     group = hq // hkv
-    scale = float(softmax_scale) if softmax_scale is not None else hd ** -0.5
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-    window = _row_window(t, window)
-    t_run, bq, bkv, bkvc = _run_shape or splash_run_shape(t)
+    t_run, bq, bkv, bkvc = run_shape
     kernel = _splash_kernel(t_run, bq, bkv, bkvc, group,
-                            interpret=bool(interpret), window=window)
+                            interpret=interpret, window=window)
 
     q = q * jnp.asarray(scale, q.dtype)
     if t_run > t:
@@ -356,6 +500,11 @@ def splash_packed_attention(
         pad = ((0, t_run - t), (0, 0), (0, 0))
         q, k, v = jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad)
         segment_ids = jnp.pad(segment_ids, (0, t_run - t))
+    if skip:
+        fwd, dkv = kernel.fwd_mask_info, kernel.dkv_mask_info
+        kernel = _with_tables(kernel, _block_tables(
+            segment_ids, fwd.block_mask[0], fwd.data_next[0],
+            dkv.block_mask[0], dkv.data_next[0], bq, bkv))
     # [T', Hq, hd] -> [Hkv, group, T', hd]; k/v -> [Hkv, T', hd]
     qh = q.transpose(1, 0, 2).reshape(hkv, group, t_run, hd)
     kh = k.transpose(1, 0, 2)
@@ -400,13 +549,10 @@ def sharded_splash_attention(
         interpret = jax.default_backend() != "tpu"
 
     def local_attn(q, k, v, seg, pos):
-        return jax.vmap(
-            lambda q1, k1, v1, s1, p1: splash_packed_attention(
-                q1, k1, v1, s1, p1,
-                softmax_scale=softmax_scale, interpret=interpret,
-                window=window,
-            )
-        )(q, k, v, seg, pos)
+        return splash_packed_attention(
+            q, k, v, seg, pos, softmax_scale=softmax_scale,
+            interpret=interpret, window=window,
+        )
 
     rows = ("data", "fsdp")
     return jax.shard_map(
@@ -530,20 +676,32 @@ def attn_run_len(
 
 
 def attn_block_cells(
-    impl: str, t: int, hq: int, hkv: int, mesh=None, r: Optional[int] = None,
+    impl: str, segment_ids: np.ndarray, hq: int, hkv: int, mesh=None,
     window: Optional[int] = None,
 ) -> tuple:
-    """(cells the attention kernel runs for one row of `t`, cells it
-    would run under the causal mask alone), per q head: those of the
-    active block pairs at the shape splash runs the row at; an
-    implementation without blocks (the einsum reference) runs all
-    t x t cells whatever the mask. For host-side counters."""
+    """(cells the attention kernels run for the packed rows
+    `segment_ids` [R, T] of one micro-batch, cells they would run under
+    the causal mask alone), per q head: those of the block pairs splash
+    runs at the shape it runs the rows at, which are the pairs its
+    static mask leaves AND, where the kernels skip by the rows' own ids
+    (`_rows_skip`), `live_block_pairs` of each row; an implementation
+    without blocks (the einsum reference) runs all t x t cells whatever
+    the mask. For host-side counters."""
+    r, t = segment_ids.shape
     ran, _ = _choose_attn_impl(impl, t, hq, hkv, mesh, r)
     if ran != "splash":
-        return t * t, t * t
+        return r * t * t, r * t * t
     t_run, bq, bkv, _ = splash_run_shape(t)
-    pairs = lambda w: _active_block_pairs(t_run, bq, bkv, w)[0] * bq * bkv
-    return pairs(_row_window(t, window)), pairs(None)
+    static = _static_block_pairs(t_run, bq, bkv, _row_window(t, window))
+    causal = r * int(_static_block_pairs(t_run, bq, bkv).sum())
+    # A sharded mesh runs each shard's rows in a call of their own.
+    if _rows_skip(r // (cp_axes(mesh)[0] if mesh is not None else 1), t_run):
+        live = live_block_pairs(
+            np.pad(segment_ids, ((0, 0), (0, t_run - t))), bq, bkv)
+        pairs = int((live & static).sum())
+    else:
+        pairs = r * int(static.sum())
+    return pairs * bq * bkv, causal * bq * bkv
 
 
 def packed_attention(q, k, v, segment_ids, positions, softmax_scale=None,

@@ -8,8 +8,8 @@ shape, and fit `ops/attention.splash_cost` to what the chip says.
 
 The first form needs a TPU: for every `rows x t` of `--shapes` (default:
 the sixteen training micro-batches and the forward-only lengths of the
-benchmark's `ppo-packed` pool) it runs `splash_packed_attention` under
-`vmap` over rows at each candidate `(t', bq, bkv, bkvc)` — `t, t+128, ..`
+benchmark's `ppo-packed` pool) it runs `splash_packed_attention` over
+the rows at each candidate `(t', bq, bkv, bkvc)` — `t, t+128, ..`
 up to the next multiple of 512, a few dividing blocks each — through
 `--layers` chained calls in one program, forward alone and forward plus
 the fused backward, and writes one JSON line a candidate. `--chosen`
@@ -80,13 +80,11 @@ def time_shape(rows, t, run_shape, hq, hkv, hd, layers, window=None):
     seg = jnp.asarray(seg)
     pos = jnp.zeros((rows, t), jnp.int32)
 
-    def attn(q1, k1, v1, s1, p1):
-        return splash_packed_attention(q1, k1, v1, s1, p1,
-                                       _run_shape=run_shape, window=window)
-
     def chain(q, k, v):
         def body(x, _):
-            out = jax.vmap(attn)(x, k, v, seg, pos)
+            # rows whole, as the model gives them
+            out = splash_packed_attention(x, k, v, seg, pos,
+                                          _run_shape=run_shape, window=window)
             return x + out * jnp.asarray(1e-3, x.dtype), None
 
         x, _ = jax.lax.scan(body, q, None, length=layers)

@@ -163,20 +163,26 @@ def test_splash_block_sizes_divide_odd_row_lengths():
     jax.default_backend() != "tpu",
     reason="real-TPU compiled-kernel parity (CPU runs interpret mode above)",
 )
-def test_splash_compiled_matches_reference_on_tpu():
+@pytest.mark.parametrize("window", [None, 700], ids=["causal", "window"])
+def test_splash_compiled_matches_reference_on_tpu(window):
+    """Three sequences and a padded tail in a row of several block pairs:
+    the block tables made from the segment ids at run time, compiled."""
     from areal_tpu.ops.attention import splash_packed_attention
 
-    T, hq, hkv, hd = 512, 4, 2, 64
+    T, hq, hkv, hd = 2048, 4, 2, 64
     q, k, v, seg, pos = make_packed(T, 3, hq, hkv, hd, seed=21)
+    seg[T - 600:] = 0
     qb = jnp.asarray(q, jnp.bfloat16)
     kb = jnp.asarray(k, jnp.bfloat16)
     vb = jnp.asarray(v, jnp.bfloat16)
     ref = reference_packed_attention(
-        qb, kb, vb, jnp.asarray(seg), jnp.asarray(pos)
+        qb, kb, vb, jnp.asarray(seg), jnp.asarray(pos), window=window
     )
     got = splash_packed_attention(
-        qb, kb, vb, jnp.asarray(seg), jnp.asarray(pos), interpret=False
-    )
+        qb[None], kb[None], vb[None], jnp.asarray(seg)[None], jnp.asarray(pos)[None],
+        interpret=False, window=window,
+    )[0]
+    assert np.isfinite(np.asarray(got, np.float32)).all()
     valid = seg > 0
     np.testing.assert_allclose(
         np.asarray(got, np.float32)[valid],
@@ -286,6 +292,207 @@ def test_splash_padded_run_matches_reference_forward_and_grads(run_shape):
         assert a.shape == b.shape and a.shape[1] == T, name
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    atol=5e-2, rtol=5e-2, err_msg=name)
+
+
+# ---------------------------------------------------------------------------
+# the block pairs the kernels run: the row's mask AND the row's segment ids
+# ---------------------------------------------------------------------------
+
+
+def _row(t, lens):
+    """Sequences of `lens` one after another from the row's start,
+    numbered from 1 as the packer does; the rest is padding."""
+    seg, at = np.zeros(t, np.int32), 0
+    for i, n in enumerate(lens):
+        seg[at:at + n] = i + 1
+        at += n
+    return seg
+
+
+LAYOUTS = {
+    "three_and_a_padded_tail": _row(1024, [300, 150, 200]),
+    "all_one_sequence": _row(1024, [1024]),
+    "last_block_part_padding": _row(1024, [500, 470]),
+    "many_short": _row(1024, [60, 70, 130, 10, 200, 128, 128, 40]),
+    "all_padding": _row(1024, []),
+    "one_token": _row(1024, [1]),
+}
+
+
+@pytest.mark.parametrize("window", [None, 200, 513], ids=["causal", "w200", "w513"])
+@pytest.mark.parametrize("bq,bkv", [(128, 128), (128, 256), (256, 128), (512, 256)])
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_live_block_pairs_keep_every_pair_with_an_unmasked_cell(layout, bq, bkv, window):
+    """Against the dense mask, cell by cell: no block pair that holds an
+    unmasked cell is dropped, every q block keeps a pair (its diagonal),
+    and under a causal mask nothing else is kept; numpy ids (the host's
+    count) and jax ids (the device's tables) give one answer."""
+    from areal_tpu.ops import attention as A
+
+    seg = LAYOUTS[layout]
+    t = len(seg)
+    at = np.arange(t)  # splash masks by place in the row, not by position
+    dense = np.asarray(A.segment_causal_mask(seg, seg, at, at, window=window))
+    dense &= (seg > 0)[:, None]
+    holds = dense.reshape(t // bq, bq, t // bkv, bkv).any(axis=(1, 3))
+    live = A.live_block_pairs(seg, bq, bkv)
+    assert live.dtype == bool and live.shape == holds.shape
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(lambda s: A.live_block_pairs(s, bq, bkv))(jnp.asarray(seg))), live)
+    runs = live & A._static_block_pairs(t, bq, bkv, window)
+    assert not (holds & ~runs).any()
+    assert runs.any(axis=1).all()
+    i, j = np.arange(t // bq)[:, None], np.arange(t // bkv)[None, :]
+    diagonal = (j * bkv < (i + 1) * bq) & (i * bq < (j + 1) * bkv)
+    if window is None:
+        np.testing.assert_array_equal(runs, holds | diagonal)
+    if layout == "all_one_sequence":  # the static mask, back from the rule itself
+        np.testing.assert_array_equal(runs, A._static_block_pairs(t, bq, bkv, window))
+    # rows at once, as the host counts them
+    both = A.live_block_pairs(np.stack([seg, seg[::-1].copy()]), bq, bkv)
+    np.testing.assert_array_equal(both[0], live)
+    np.testing.assert_array_equal(both[1], A.live_block_pairs(seg[::-1].copy(), bq, bkv))
+
+
+def _layout_rows(T, hq, hkv, hd):
+    """Three packed rows of different layouts: three sequences and a tail
+    of padding several blocks long; all one sequence; two sequences, the
+    last block part padding."""
+    segs = [_row(T, [T // 3 - 20, T // 8, T // 6 + 5]), _row(T, [T]),
+            _row(T, [T // 2 - 7, T // 2 - 30])]
+    rng = np.random.RandomState(41)
+    q, k, v = (jnp.asarray(rng.randn(len(segs), T, h, hd).astype(np.float32))
+               for h in (hq, hkv, hkv))
+    seg = np.stack(segs)
+    pos = np.zeros_like(seg)
+    for r, row in enumerate(seg):
+        for s in range(1, row.max() + 1):
+            pos[r, row == s] = np.arange((row == s).sum())
+    return q, k, v, jnp.asarray(seg), jnp.asarray(pos)
+
+
+@pytest.mark.parametrize("rows", ["one_by_one", "vmap"])
+@pytest.mark.parametrize("window", [None, 200], ids=["causal", "window"])
+@pytest.mark.parametrize("run_shape", [
+    (768, 128, 128, 128), (768, 128, 256, 128), (768, 384, 128, 128)])
+def test_splash_run_time_block_mask_is_the_static_kernel_at_real_positions(
+        run_shape, window, rows, monkeypatch):
+    """Rows of different layouts, each given alone as `[1, T, ..]` (one
+    after another, or under a caller's `vmap`: a table a row), with the
+    block pairs of no sequence skipped: outputs at real positions and dq,
+    dk, dv equal to the static-mask kernel's, not to a tolerance; finite
+    where the row is padding; and the reference's."""
+    from areal_tpu.ops import attention as A
+
+    R, T, hq, hkv, hd = 3, 768, 4, 2, 32
+    q, k, v, seg, pos = _layout_rows(T, hq, hkv, hd)
+    real = np.asarray(seg) > 0
+    dout = jnp.asarray(np.random.RandomState(2).randn(R, T, hq, hd).astype(np.float32)
+                       * real[..., None, None])
+
+    def run(fn, rows="vmap"):
+        def loss(q, k, v):
+            if rows == "vmap":
+                out = jax.vmap(fn)(q, k, v, seg, pos)
+            else:
+                out = jnp.stack([fn(q[r], k[r], v[r], seg[r], pos[r]) for r in range(R)])
+            return jnp.sum(out * dout), out
+
+        (_, out), grads = jax.value_and_grad(loss, (0, 1, 2), has_aux=True)(q, k, v)
+        return np.asarray(out), [np.asarray(g) for g in grads]
+
+    splash = lambda *a: A.splash_packed_attention(
+        *(x[None] for x in a), interpret=True, _run_shape=run_shape, window=window)[0]
+    monkeypatch.setattr(A, "_SKIP_MIN_LEN", 0)  # a row this short would not skip
+    skipped = []
+    keep = A._with_tables
+    monkeypatch.setattr(A, "_with_tables",
+                        lambda kernel, tables: skipped.append(tables) or keep(kernel, tables))
+    got, g_got = run(splash, rows)
+    assert skipped  # the run-time tables are what ran
+    monkeypatch.setattr(A, "_with_tables", lambda kernel, tables: kernel)
+    static, g_static = run(splash)
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got[real], static[real])
+    for a, b, name in zip(g_got, g_static, "qkv"):
+        np.testing.assert_array_equal(a, b, err_msg=name)
+    ref, g_ref = run(lambda *a: A.reference_packed_attention(*a, window=window))
+    np.testing.assert_allclose(got[real], ref[real], atol=2e-5, rtol=2e-5)
+    for a, b, name in zip(g_got, g_ref, "qkv"):
+        np.testing.assert_allclose(a, b, atol=2e-4, rtol=2e-4, err_msg=name)
+
+
+def test_only_a_long_row_alone_skips_by_its_segment_ids(monkeypatch):
+    """Several rows in one call, a row given without its leading axis (it
+    may be one of many under a caller's `vmap`) and a short row keep the
+    static kernel: the parent's program."""
+    from areal_tpu.ops import attention as A
+
+    assert A._rows_skip(1, 16384) and A._rows_skip(1, 2048)
+    assert not A._rows_skip(1, 1536) and not A._rows_skip(3, 6144)
+    called = []
+    keep = A._with_tables
+    monkeypatch.setattr(A, "_with_tables",
+                        lambda kernel, tables: called.append(tables) or keep(kernel, tables))
+    q, k, v, seg, pos = _layout_rows(768, 4, 2, 32)
+    run = lambda *a: A.splash_packed_attention(*a, interpret=True)
+    short = run(q[:1], k[:1], v[:1], seg[:1], pos[:1])
+    assert not called
+    monkeypatch.setattr(A, "_SKIP_MIN_LEN", 512)
+    rows = run(q, k, v, seg, pos)
+    alone = run(q[0], k[0], v[0], seg[0], pos[0])
+    assert not called
+    np.testing.assert_array_equal(np.asarray(rows[0]), np.asarray(alone))
+    np.testing.assert_array_equal(np.asarray(rows[0]), np.asarray(short[0]))
+    one = run(q[:1], k[:1], v[:1], seg[:1], pos[:1])
+    assert len(called) == 1
+    real = np.asarray(seg[0]) > 0
+    np.testing.assert_array_equal(np.asarray(one[0])[real], np.asarray(rows[0])[real])
+
+
+@pytest.mark.parametrize("window", [None, 2048], ids=["causal", "window"])
+@pytest.mark.parametrize("t,lens", [
+    (2048, [700, 500, 300]), (3712, [1500, 900, 1000]), (8192, [3000, 900]),
+    (8192, [8192]), (16384, [9000])])
+def test_host_count_of_cells_run_is_the_device_block_mask(t, lens, window):
+    """`attn_block_cells` (the engine's `train.attn_active_cells`) counts
+    on the host what the kernels skip by on the device: the non-zero
+    entries of the forward and of the fused backward `block_mask`, times
+    a block pair's cells."""
+    from areal_tpu.ops import attention as A
+
+    seg = _row(t, lens)
+    t_run, bq, bkv, bkvc = A.splash_run_shape(t)
+    assert A._rows_skip(1, t_run)
+    padded = np.pad(seg, (0, t_run - t))
+    static = A._splash_kernel(t_run, bq, bkv, bkvc, 1, interpret=True,
+                              window=A._row_window(t, window))
+    kernel = A._with_tables(static, A._block_tables(
+        jnp.asarray(padded), static.fwd_mask_info.block_mask[0],
+        static.fwd_mask_info.data_next[0], static.dkv_mask_info.block_mask[0],
+        static.dkv_mask_info.data_next[0], bq, bkv))
+    fwd = int((np.asarray(kernel.fwd_mask_info.block_mask) > 0).sum())
+    dkv = int((np.asarray(kernel.dkv_mask_info.block_mask) > 0).sum())
+    ran, causal = A.attn_block_cells("splash", seg[None], 4, 2, window=window)
+    assert ran == fwd * bq * bkv == dkv * bq * bkv
+    assert causal == A._active_block_pairs(t_run, bq, bkv)[0] * bq * bkv
+    skips = len(lens) > 1 or lens[0] < t or A._row_window(t, window) is not None
+    assert (ran < causal) if skips else (ran == causal)
+    # several rows in one call keep the static kernel; so does a short row
+    pairs = A._active_block_pairs(t_run, bq, bkv, A._row_window(t, window))[0]
+    assert A.attn_block_cells("splash", np.stack([seg, seg]), 4, 2, window=window) == (
+        2 * pairs * bq * bkv, 2 * causal)
+    assert A.attn_block_cells("splash", _row(1024, [10])[None], 4, 2) == (
+        A._active_block_pairs(1024, 512, 512)[0] * 512 * 512,) * 2
+    for name, block in (("fwd_mask_info", bkv), ("dkv_mask_info", bq)):
+        info, was = getattr(kernel, name), getattr(static, name)
+        nxt = np.asarray(info.data_next)
+        assert nxt.dtype == was.data_next.dtype and nxt.shape == was.data_next.shape
+        assert info.block_mask.dtype == was.block_mask.dtype
+        assert nxt.min() >= 0 and nxt.max() < t_run // block
+        if not skips:  # a step the static kernel runs loads what it loaded
+            run = np.asarray(was.block_mask) > 0
+            np.testing.assert_array_equal(nxt[run], np.asarray(was.data_next)[run])
 
 
 @pytest.mark.parametrize("impl,t,want", [

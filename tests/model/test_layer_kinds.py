@@ -356,18 +356,21 @@ def test_window_layers_skip_block_pairs_behind_the_window():
             int((kept > 0).sum()), kept.shape[1]), (t, bq, bkv, window)
     assert A._splash_cost_terms(3072, 512, 1024, 512) == A._splash_cost_terms(
         3072, 512, 1024, 512, window=None)
-    # the counters: a window layer runs its rows at the causal layers'
-    # shape (chosen from the row length alone) and skips pairs there
+    # the counters, for a row that is all one sequence (nothing else to
+    # skip): a window layer runs its rows at the causal layers' shape
+    # (chosen from the row length alone) and skips pairs there
     splash = dict(impl="splash", hq=32, hkv=4)
-    ran, causal = A.attn_block_cells(t=16384, window=2048, **splash)
-    assert ran < 0.4 * causal == 0.4 * A.attn_block_cells(t=16384, **splash)[0]
-    assert A.attn_block_cells(t=1024, window=2048, **splash) == A.attn_block_cells(
-        t=1024, **splash)
+    one = lambda t: np.ones((1, t), np.int32)
+    ran, causal = A.attn_block_cells(segment_ids=one(16384), window=2048, **splash)
+    assert ran < 0.4 * causal == 0.4 * A.attn_block_cells(segment_ids=one(16384), **splash)[0]
+    assert A.attn_block_cells(segment_ids=one(1024), window=2048, **splash) == (
+        A.attn_block_cells(segment_ids=one(1024), **splash))
     t_run, bq, bkv, _ = A.splash_run_shape(3712)  # padded to 4096
-    assert A.attn_block_cells(t=3712, window=2048, **splash) == (
+    assert A.attn_block_cells(segment_ids=one(3712), window=2048, **splash) == (
         A._active_block_pairs(t_run, bq, bkv, 2048)[0] * bq * bkv,
         A._active_block_pairs(t_run, bq, bkv)[0] * bq * bkv)
-    assert A.attn_block_cells("reference", 640, 32, 4, window=128) == (640 * 640, 640 * 640)
+    assert A.attn_block_cells("reference", np.ones((3, 640), np.int32), 32, 4,
+                              window=128) == (3 * 640 * 640, 3 * 640 * 640)
 
 
 # A window layer (LocalMask 2048) alone on one v5e, 32 / 4 heads of 128,

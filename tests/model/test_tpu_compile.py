@@ -40,21 +40,36 @@ def _shape(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+@pytest.mark.parametrize("rows", [1, 3, "vmap"], ids=["row", "rows", "rows_vmap"])
 @pytest.mark.parametrize("window", [2048, None], ids=["window", "full"])
-def test_splash_row_of_16k_at_32_over_4_heads_compiles_forward_and_backward(one_chip, window):
+def test_splash_row_of_16k_at_32_over_4_heads_compiles_forward_and_backward(one_chip, window, rows):
+    """The segment ids are arguments, so the block tables a long row
+    alone skips by (`ops/attention._block_tables`) are values of the run:
+    scalar-prefetch operands that are traced. Three rows in one call keep
+    the static kernel; a caller's `vmap` over rows each given alone is a
+    table a row and pallas's own loop over the kernel calls."""
     from areal_tpu.ops.attention import splash_packed_attention
 
     t, hq, hkv, hd = 8192, 32, 4, 128  # half the longest row: a quicker compile
-    q = _shape((t, hq, hd), jnp.bfloat16, one_chip)
-    kv = _shape((t, hkv, hd), jnp.bfloat16, one_chip)
-    ids = _shape((t,), jnp.int32, one_chip)
+    lead = (1,) if rows == 1 else (3,)
+    q = _shape((*lead, t, hq, hd), jnp.bfloat16, one_chip)
+    kv = _shape((*lead, t, hkv, hd), jnp.bfloat16, one_chip)
+    ids = _shape((*lead, t), jnp.int32, one_chip)
+
+    def attend(q, k, v, seg, pos):
+        return splash_packed_attention(q, k, v, seg, pos, window=window, interpret=False)
 
     def loss(q, k, v, seg, pos):
-        out = splash_packed_attention(q, k, v, seg, pos, window=window, interpret=False)
+        if rows == "vmap":
+            out = jax.vmap(lambda *a: attend(*(x[None] for x in a))[0])(q, k, v, seg, pos)
+        else:
+            out = attend(q, k, v, seg, pos)
         return out.astype(jnp.float32).sum()
 
     text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv, ids, ids).compile().as_text()
     assert text.count("tpu_custom_call") >= 2  # the forward and the fused backward kernel
+    assert (" while(" in text) == (rows == "vmap")
+    assert (" reduce-window(" in text) == (rows != 3)  # the tables' running minimum
 
 
 def test_held_experts_pass_compiles_at_the_published_widths(one_chip):
