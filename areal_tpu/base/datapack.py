@@ -109,7 +109,7 @@ def packing_density(
     divided by the [R, T] cells shipped to the device. 1.0 = no pad
     waste; every (1 - density) fraction of the step's FLOPs is spent on
     padding. This is the `packing_efficiency` series surfaced in the
-    master's perf history and bench.py output."""
+    master's perf history."""
     n_rows, row_len = pack_shape(
         lengths, row_len_multiple, n_rows_multiple, max_row_len
     )

@@ -142,9 +142,9 @@ _KNOBS: List[Knob] = [
        "edits land as donated per-slot row scatters and chunk-prefill "
        "control crosses as ONE fused array, so only admission/eviction "
        "DELTAS pay H2D between decode blocks. False restores the "
-       "legacy full-table restage + per-scalar staging (the "
-       "kernel_micro_decode_state A/B arm; greedy-token parity between "
-       "the modes is pinned in tests).", snapshot=True),
+       "legacy full-table restage + per-scalar staging (greedy-token "
+       "parity between the modes is pinned in "
+       "tests/engine/test_decode_resident.py).", snapshot=True),
     # -- base ------------------------------------------------------------
     _k("AREAL_FILEROOT", "str", None,
        "Filesystem root for logs/checkpoints/realloc params; unset = "
@@ -209,8 +209,8 @@ _KNOBS: List[Knob] = [
        "Trainer GAE implementation (ops/gae.packed_gae): 'auto' "
        "(associative scan), 'scan' (the serial lax.scan oracle), "
        "'assoc', or 'pallas' (blocked Pallas scan kernel, shape-gated; "
-       "opt-in until kernel_micro_gae banks device crossover "
-       "evidence). Pinned when the PPO prep program is first traced.",
+       "opt-in: not timed on the chip against the associative scan). "
+       "Pinned when the PPO prep program is first traced.",
        snapshot=True),
     # -- MoE dispatch (models/moe.py, engine/jax_engine.py) --------------
     _k("AREAL_MOE_DISPATCH", "str", None,

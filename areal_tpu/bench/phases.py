@@ -2,10 +2,7 @@
 
 A phase is the unit of banking. Each one declares:
 
-- ``priority``       lower runs first — headline evidence (train
-                     TFLOP/s, gen tok/s) outranks secondary probes, so
-                     a short flap window is spent on what the round is
-                     actually gated on
+- ``priority``       lower runs first
 - ``est_compile_s``  estimated on-chip cost of the *compile pass*:
                      trace + XLA-compile every program the phase needs,
                      populating the persistent compilation cache. Banked
@@ -129,69 +126,6 @@ def load_extra_modules(spec: Optional[str] = None) -> None:
 # ----------------------------------------------------------------------
 
 register(PhaseSpec(
-    name="train_tflops",
-    entrypoint="areal_tpu.bench.workloads:train_phase",
-    priority=0,
-    est_compile_s=180.0,
-    est_measure_s=45.0,
-    min_window_s=25.0,
-    headline=True,
-    description="Full train step (fwd+bwd+sharded optimizer) TFLOP/s per "
-                "chip on the flagship packed-varlen model",
-))
-
-register(PhaseSpec(
-    name="gen_tps",
-    entrypoint="areal_tpu.bench.workloads:gen_phase",
-    priority=1,
-    est_compile_s=120.0,
-    est_measure_s=60.0,
-    min_window_s=40.0,
-    headline=True,
-    description="ServingEngine sustained output tok/s/chip, 32x512+512",
-))
-
-register(PhaseSpec(
-    name="train_tflops_scaling",
-    entrypoint="areal_tpu.bench.workloads:train_tflops_scaling_phase",
-    priority=2,
-    est_compile_s=300.0,
-    est_measure_s=180.0,
-    min_window_s=60.0,
-    # Harmless on TPU (the flag only shapes the HOST platform); makes a
-    # CPU round bank a labeled 2-point sanity curve instead of nothing.
-    env={"XLA_FLAGS": "--xla_force_host_platform_device_count=2"},
-    description="Weak-scaling train curve 1->N chips: per-chip TFLOP/s "
-                "per power-of-2 FSDP mesh (batch grows with the mesh), "
-                "banked as points so scaling curves assemble across "
-                "rounds — the daemon spends the next real multi-chip "
-                "window here unattended",
-))
-
-register(PhaseSpec(
-    name="gen_long_tps",
-    entrypoint="areal_tpu.bench.workloads:gen_long_phase",
-    priority=2,
-    est_compile_s=120.0,
-    est_measure_s=420.0,
-    min_window_s=180.0,
-    description="Long-form serving: 8 requests x 8192 new tokens through "
-                "chunked prefill + the paged pool",
-))
-
-register(PhaseSpec(
-    name="serving_http",
-    entrypoint="areal_tpu.bench.workloads:serving_http_phase",
-    priority=3,
-    est_compile_s=120.0,
-    est_measure_s=90.0,
-    min_window_s=60.0,
-    default=False,
-    description="System-layer serving: GenerationServer worker behind "
-                "HTTP (the SGLang-contract path the RL system drives)",
-))
-
-register(PhaseSpec(
     name="serving_openloop",
     entrypoint="areal_tpu.bench.workloads:serving_openloop_phase",
     priority=4,
@@ -291,76 +225,6 @@ register(PhaseSpec(
                 "to-first-token) solo vs fair-share ON vs FIFO, with the "
                 "aggressor shed against its own stream cap and the DRR "
                 "queue demonstrably engaged (CPU-proxy)",
-))
-
-# kernel_micro family (ROADMAP item 3): per-kernel parity + timing
-# evidence for the hot-path kernels, DEFAULT phases so the daemon
-# spends the next unattended TPU window banking all of it. Off-TPU the
-# records self-label cpu_proxy (validate_bench refuses unlabeled ones);
-# they are NOT proxy=True phases — that would pin the subprocess to
-# JAX_PLATFORMS=cpu and the device window would never measure them.
-
-register(PhaseSpec(
-    name="kernel_micro_gae",
-    entrypoint="areal_tpu.bench.workloads:kernel_micro_gae_phase",
-    priority=8,
-    est_compile_s=30.0,
-    est_measure_s=40.0,
-    min_window_s=10.0,
-    description="Trainer GAE kernels: serial lax.scan baseline vs the "
-                "associative scan 'auto' dispatches vs the blocked "
-                "Pallas scan + host loop, parity per case "
-                "(packed multi-segment rows, misaligned starts)",
-))
-
-register(PhaseSpec(
-    name="kernel_micro_paged_decode",
-    entrypoint="areal_tpu.bench.workloads:kernel_micro_paged_decode_phase",
-    priority=8,
-    est_compile_s=60.0,
-    est_measure_s=60.0,
-    min_window_s=15.0,
-    description="Paged decode attention across the scheduler's pow2 "
-                "admit batches: XLA gather baseline vs the 'auto'-"
-                "resolved kernel for float AND int8 pools, parity + "
-                "quant error per case",
-))
-
-register(PhaseSpec(
-    name="kernel_micro_splash",
-    entrypoint="areal_tpu.bench.workloads:kernel_micro_splash_phase",
-    priority=9,
-    est_compile_s=60.0,
-    est_measure_s=40.0,
-    min_window_s=10.0,
-    description="Splash prefill attention vs the reference einsum "
-                "oracle on a packed multi-segment stream (parity-only "
-                "interpret case off-TPU)",
-))
-
-register(PhaseSpec(
-    name="kernel_micro_decode_state",
-    entrypoint="areal_tpu.bench.workloads:kernel_micro_decode_state_phase",
-    priority=9,
-    est_compile_s=90.0,
-    est_measure_s=90.0,
-    min_window_s=20.0,
-    description="Device-resident decode-state A/B "
-                "(AREAL_DECODE_RESIDENT on vs off): per-decode-block "
-                "H2D transfers/bytes + throughput for both arms with "
-                "greedy token parity asserted in-phase",
-))
-
-register(PhaseSpec(
-    name="pack_density",
-    entrypoint="areal_tpu.bench.workloads:pack_density_phase",
-    priority=10,
-    est_compile_s=0.0,  # host-only: nothing to compile, no compile pass
-    est_measure_s=20.0,
-    min_window_s=0.0,
-    proxy=True,
-    description="FFD packing density on realistic length mixes "
-                "(host-side; CPU-proxy evidence)",
 ))
 
 register(PhaseSpec(
@@ -487,15 +351,3 @@ register(PhaseSpec(
                 "(CPU-proxy)",
 ))
 
-register(PhaseSpec(
-    name="prefetch_overlap",
-    entrypoint="areal_tpu.bench.workloads:prefetch_overlap_phase",
-    priority=11,
-    est_compile_s=30.0,
-    est_measure_s=40.0,
-    min_window_s=0.0,
-    proxy=True,
-    description="Input-pipeline overlap telemetry (packing_efficiency / "
-                "h2d_wait / dispatch_gap) on the virtual-mesh engine "
-                "(CPU-proxy evidence)",
-))

@@ -1,17 +1,17 @@
-"""Phase bodies: the actual benchmark workloads.
+"""Phase bodies: the old benchmark's workloads.
 
-Moved out of the old monolithic ``bench.py``. Every function here is a
-phase entrypoint ``fn(pass_) -> value dict`` run inside its own runner
-subprocess (see :mod:`areal_tpu.bench.runner`):
+Every function here is a phase entrypoint ``fn(pass_) -> value dict``
+run inside its own runner subprocess (see :mod:`areal_tpu.bench.runner`):
 
 - ``pass_ == "compile"``: build the workload and compile every program
-  it needs — via the engines' AOT warm hooks — so the persistent XLA
-  cache holds them. Returns compile timings.
-- ``pass_ == "measure"``: warm briefly (cache hits), then time the
-  steady state and return the metrics.
+  it needs, so the persistent XLA cache holds them. Returns compile
+  timings.
+- ``pass_ == "measure"``: warm briefly (cache hits), then run the
+  workload and return what it counted.
 
-The split is the point: measuring never waits on compiling what the
-persistent cache already holds.
+Every phase is a CPU proxy over the control plane: the runner pins its
+subprocess to ``JAX_PLATFORMS=cpu``, and none of them times the chip.
+Speed on the chip is ``benchmark/run.py``'s to measure.
 """
 
 from __future__ import annotations
@@ -24,438 +24,9 @@ import numpy as np
 
 from areal_tpu.base import env_registry
 from areal_tpu.base import metrics_registry as mreg
-from areal_tpu.bench._util import log, repo_root
+from areal_tpu.bench._util import log
 
 BASELINE_TFLOPS = 198.0
-
-
-def flagship_cfg(max_pos: int = 40960, attn_bias: bool = True):
-    """The benchmark model shape: R1-Distill-Qwen-1.5B-class layers
-    (hidden 1536, 12 q / 2 kv heads, head_dim 128, ffn 8960 — the family
-    the reference's headline benchmark trains,
-    benchmark/verl_v0_3_0_post1_76084d3/README.md:38-44), trimmed to 16
-    layers / 32k vocab so params + fp32 Adam moments + activations fit
-    one v5e chip's 16 GB HBM. Shared by every bench phase and the perf
-    scripts (mfu_sweep, long_context_probe) so every banked number
-    measures the SAME model."""
-    from areal_tpu.models.config import TransformerConfig
-
-    return TransformerConfig(
-        n_layers=16, hidden_dim=1536, n_q_heads=12, n_kv_heads=2,
-        head_dim=128, intermediate_dim=8960, vocab_size=32768,
-        attn_bias=attn_bias, compute_dtype="bfloat16",
-        param_dtype="bfloat16", max_position_embeddings=max_pos,
-    )
-
-
-def smoke_cfg():
-    """CPU smoke shape so dev runs terminate quickly."""
-    from areal_tpu.models.config import TransformerConfig
-
-    return TransformerConfig(
-        n_layers=2, hidden_dim=64, n_q_heads=4, n_kv_heads=2, head_dim=16,
-        intermediate_dim=128, vocab_size=256, compute_dtype="float32",
-    )
-
-
-def train_step_flops(cfg, n_params: int, seqlens) -> float:
-    """Analytic fwd+bwd FLOPs for a packed batch (llama-formula style:
-    6*N per token for matmuls, plus causal attention score/context terms)."""
-    total = 0.0
-    q_dim = cfg.n_q_heads * cfg.head_dim
-    for l in seqlens:
-        total += 6.0 * n_params * l
-        # QK^T + AV: 2 * (2 * l^2 * q_dim) * 0.5 (causal) per layer, x3 for bwd.
-        total += 6.0 * cfg.n_layers * q_dim * float(l) * l
-    return total
-
-
-# ----------------------------------------------------------------------
-# train_tflops
-# ----------------------------------------------------------------------
-
-
-def _train_setup():
-    import jax
-
-    from areal_tpu.api.data_api import MicroBatchSpec, SequenceSample
-    from areal_tpu.engine.jax_engine import JaxTrainEngine
-    from areal_tpu.engine.optimizer import OptimizerConfig
-    from areal_tpu.models.transformer import count_params, init_params
-    from areal_tpu.ops.loss import sft_loss_from_logprobs
-
-    devices = jax.devices()
-    platform = devices[0].platform
-    on_tpu = platform == "tpu"
-    log(f"bench: platform={platform} n_devices={len(devices)}")
-
-    if on_tpu:
-        # flagship_cfg: params in bf16 with fp32 optimizer moments
-        # (weights stream at half the bytes; update math stays fp32 —
-        # measured +18 TFLOP/s over fp32 params, scripts/perf_probe.py).
-        cfg = flagship_cfg()
-        seqlen, n_seqs, n_warmup, n_steps = 2048, 16, 2, 5
-    else:
-        cfg = smoke_cfg()
-        seqlen, n_seqs, n_warmup, n_steps = 128, 4, 1, 2
-
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    n_params = count_params(params)
-    log(f"bench: n_params={n_params/1e6:.1f}M")
-
-    eng = JaxTrainEngine(
-        cfg, params,
-        optimizer_config=OptimizerConfig(lr=1e-4, warmup_steps_proportion=0.0),
-        total_train_steps=1000, row_len_multiple=seqlen, max_row_len=seqlen,
-        # save_attn: keep the flash kernel's residuals, recompute the rest
-        # in backward — the best single-chip throughput/memory point for
-        # this model size (see scripts/perf_probe.py measurements).
-        remat="save_attn" if on_tpu else "full",
-    )
-
-    rng = np.random.RandomState(0)
-    seqlens = [seqlen] * n_seqs
-    total = sum(seqlens)
-    batch = SequenceSample.from_default(
-        ids=[f"b{i}" for i in range(n_seqs)],
-        seqlens=seqlens,
-        data={
-            "packed_input_ids": rng.randint(0, cfg.vocab_size, size=total),
-            "loss_mask": np.ones(total, np.float32),
-        },
-    )
-
-    def packed_loss(lp, rows):
-        tot, n = sft_loss_from_logprobs(lp, rows["loss_mask"])
-        return tot, {}
-
-    def weight(mb):
-        return float(np.sum(mb.data["loss_mask"]))
-
-    mb_spec = MicroBatchSpec(n_mbs=1)
-    return eng, batch, mb_spec, packed_loss, weight, dict(
-        cfg=cfg, n_params=n_params, seqlens=seqlens, total=total,
-        n_warmup=n_warmup, n_steps=n_steps, on_tpu=on_tpu,
-    )
-
-
-def train_phase(pass_: str) -> dict:
-    import jax
-
-    eng, batch, mb_spec, loss_fn, weight, meta = _train_setup()
-
-    def one_step(i):
-        return eng.train_batch(batch, mb_spec, loss_fn, weight,
-                               version_steps=i, loss_name="bench")
-
-    if pass_ == "compile":
-        t0 = time.perf_counter()
-        aot_s = eng.warm(batch, mb_spec, loss_fn, loss_name="bench")
-        # One executed step on top of the AOT pass: covers whatever the
-        # lowered program does not (stats fetch path, eager helpers) and
-        # proves the compiled program actually runs on this device.
-        one_step(0)
-        jax.block_until_ready(eng.params)
-        dt = time.perf_counter() - t0
-        log(f"bench: train compile pass {dt:.1f}s (aot {aot_s:.1f}s)")
-        return {"compile_s": dt, "aot_compile_s": aot_s}
-
-    for i in range(meta["n_warmup"]):
-        t = time.perf_counter()
-        one_step(i)
-        log(f"bench: warmup step {i} {time.perf_counter() - t:.2f}s")
-
-    # Drain warmup-recorded pipeline stats so the exported overlap
-    # telemetry below covers ONLY the timed steps.
-    from areal_tpu.base import stats_tracker
-
-    stats_tracker.export(key="perf")
-
-    t0 = time.perf_counter()
-    for i in range(meta["n_steps"]):
-        one_step(meta["n_warmup"] + i)
-    jax.block_until_ready(eng.params)
-    dt = (time.perf_counter() - t0) / meta["n_steps"]
-
-    flops = train_step_flops(meta["cfg"], meta["n_params"], meta["seqlens"])
-    # Mesh shape + device count live in the VALUES (not just record
-    # attestation) so scaling curves assemble across bench rounds
-    # without re-parsing attestation blobs; "train_tflops" stays the
-    # PER-CHIP number report.py has always derived its headline from.
-    n_devices = int(eng.mesh.size)
-    tflops_total = flops / dt / 1e12
-    tflops = tflops_total / n_devices
-    tokens_per_sec = meta["total"] / dt
-    log(f"bench: {dt:.3f}s/step {tokens_per_sec:.0f} tok/s "
-        f"{tflops:.1f} TFLOP/s/chip x{n_devices}")
-    perf = stats_tracker.export(key="perf")
-    overlap = {
-        k[len("perf/"):]: float(v) for k, v in perf.items()
-        if k in (mreg.PERF_PACKING_EFFICIENCY, mreg.PERF_H2D_WAIT_MS,
-                 mreg.PERF_DISPATCH_GAP_MS)
-    }
-    log(f"bench: overlap telemetry {overlap}")
-    return {
-        "train_tflops": tflops,
-        "train_tflops_total": tflops_total,
-        "n_devices": float(n_devices),
-        "mesh_shape": {k: int(v) for k, v in dict(eng.mesh.shape).items()},
-        "tokens_per_sec": tokens_per_sec,
-        "step_s": dt,
-        "vs_baseline": tflops / BASELINE_TFLOPS,
-        "overlap": overlap,
-    }
-
-
-# ----------------------------------------------------------------------
-# gen_tps / gen_long_tps
-# ----------------------------------------------------------------------
-
-
-def _gen_run(pass_: str, long_form: bool) -> dict:
-    """Generation throughput on the ServingEngine (paged KV, batched
-    prefill, jitted decode blocks): sustained output tokens/sec/chip at a
-    realistic batch + context. The reference's headline gains are
-    generation-side (async RL is generation-bound, blog/AReaL_v0_3.md:125)
-    but it publishes only relative deltas, so this is reported as an
-    absolute alongside the train metric.
-
-    long_form=True is the 8k-new-tokens-class workload (the reference's
-    headline benchmark generates ~31k tokens/sample): moderate batch,
-    fixed-shape chunked prefill, and sustained long decode through the
-    paged pool — the regime the async design is supposed to win on,
-    which the 512+512 short mode does not speak to."""
-    import threading
-
-    import jax
-
-    from areal_tpu.engine.serving import GenRequest, ServingEngine
-    from areal_tpu.models.transformer import init_params
-
-    devices = jax.devices()
-    on_tpu = devices[0].platform == "tpu"
-
-    if on_tpu:
-        cfg = flagship_cfg()
-        if long_form:
-            # ~1.2 GB of paged KV at bf16 alongside the 3.5 GB params.
-            n_reqs, plen, max_new, page, block = 8, 1024, 8192, 128, 32
-            chunk = 512
-        else:
-            n_reqs, plen, max_new, page, block = 32, 512, 512, 128, 32
-            chunk = None
-    else:
-        cfg = smoke_cfg()
-        if long_form:
-            n_reqs, plen, max_new, page, block = 2, 32, 64, 8, 4
-            chunk = 16
-        else:
-            n_reqs, plen, max_new, page, block = 2, 16, 8, 8, 4
-            chunk = None
-
-    params = init_params(cfg, jax.random.PRNGKey(1))
-    eng = ServingEngine(
-        cfg, params,
-        max_batch_size=n_reqs,
-        max_seq_len=plen + max_new + page,
-        decode_block_steps=block,
-        prompt_bucket=page,
-        eos_token_id=None,  # budget-bound: every request emits max_new
-        page_size=page,
-        kv_pool_tokens=n_reqs * (plen + max_new + page),
-        prefill_chunk=chunk,
-    )
-    eng.start()
-    try:
-        tag = "gen-long" if long_form else "gen"
-        if pass_ == "compile":
-            t0 = time.perf_counter()
-            eng.warm([plen] * min(n_reqs, 8))
-            dt = time.perf_counter() - t0
-            log(f"bench: {tag} compile pass {dt:.1f}s")
-            return {"compile_s": dt}
-
-        rng = np.random.RandomState(1)
-
-        def run(n, new_tokens, req_tag):
-            done = threading.Event()
-            got = []
-
-            def cb(res):
-                got.append(len(res.output_ids))
-                if len(got) == n:
-                    done.set()
-
-            t0 = time.perf_counter()
-            for i in range(n):
-                eng.submit(GenRequest(
-                    qid=f"{req_tag}{i}",
-                    input_ids=rng.randint(
-                        0, cfg.vocab_size, size=plen
-                    ).tolist(),
-                    max_new_tokens=new_tokens,
-                    done_cb=cb,
-                ))
-            assert done.wait(1800), f"gen bench stalled: {len(got)}/{n}"
-            return sum(got), time.perf_counter() - t0
-
-        # Warmup compiles (or cache-loads) prefill buckets + the decode
-        # block; cheap when the compile pass already banked them.
-        _, wdt = run(min(n_reqs, 8), 2 * block, "w")
-        log(f"bench: {tag} warmup {wdt:.2f}s")
-        h0, b0, d0 = eng.h2d_transfers, eng.h2d_bytes, eng.decode_blocks
-        toks, dt = run(n_reqs, max_new, "g")
-        tps = toks / dt
-        log(f"bench: {tag} {toks} tokens in {dt:.2f}s -> {tps:.0f} tok/s/chip")
-        key = "gen_long_tps" if long_form else "gen_tps"
-        blocks = max(1, eng.decode_blocks - d0)
-        return {
-            key: tps, "tokens": toks, "wall_s": dt,
-            # Decode-dispatch staging telemetry over the measured window
-            # (device-resident decode state, docs/perf_notes.md Round
-            # 15; the kernel_micro_decode_state phase banks the A/B).
-            "h2d_per_decode_block": (eng.h2d_transfers - h0) / blocks,
-            "h2d_bytes_per_decode_block": (eng.h2d_bytes - b0) / blocks,
-            "decode_resident": 1.0 if eng.decode_resident else 0.0,
-        }
-    finally:
-        eng.stop()
-
-
-def gen_phase(pass_: str) -> dict:
-    return _gen_run(pass_, long_form=False)
-
-
-def gen_long_phase(pass_: str) -> dict:
-    return _gen_run(pass_, long_form=True)
-
-
-# ----------------------------------------------------------------------
-# serving_http: the system-layer serving path (GenerationServer worker
-# behind the SGLang-contract HTTP endpoints) — what the RL system
-# actually drives, including HTTP + JSON + engine-thread handoff costs.
-# ----------------------------------------------------------------------
-
-
-def serving_http_phase(pass_: str) -> dict:
-    import json
-    import subprocess
-    import tempfile
-    import urllib.request
-    import uuid
-
-    # Platform via a PROBE subprocess, never an in-process backend init:
-    # this phase spawns a second jax process (the server), and a TPU
-    # client acquired here would be exclusive — the server child would
-    # fail 'device busy' on the one platform the phase exists to measure.
-    from areal_tpu.bench.devices import probe_devices
-
-    on_tpu = probe_devices()["platform"] == "tpu"
-    if on_tpu:
-        import dataclasses as _dc
-
-        # Same flagship shape as the train/gen phases — derived, not
-        # duplicated, so a retune keeps every banked number comparable.
-        model_cfg = _dc.asdict(flagship_cfg())
-        n_reqs, plen, max_new = 16, 256, 256
-        srv = dict(max_concurrent_requests=16, max_seq_len=1024,
-                   kv_page_size=128, decode_block_steps=32, prompt_bucket=128)
-    else:
-        model_cfg = dict(
-            n_layers=2, hidden_dim=32, n_q_heads=2, n_kv_heads=2, head_dim=16,
-            intermediate_dim=64, vocab_size=64, compute_dtype="float32",
-            param_dtype="float32",
-        )
-        n_reqs, plen, max_new = 4, 8, 8
-        srv = dict(max_concurrent_requests=4, max_seq_len=64,
-                   kv_page_size=8, decode_block_steps=4, prompt_bucket=8)
-
-    repo = repo_root()
-    tmp = tempfile.mkdtemp(prefix="areal_bench_http_")
-    nr = os.path.join(tmp, "nr")
-    exp, trial = f"bench-http-{uuid.uuid4().hex[:6]}", "t0"
-    child = (
-        "import os, sys\n"
-        f"sys.path.insert(0, {repo!r})\n"
-        "from areal_tpu.base import name_resolve\n"
-        f"name_resolve.reconfigure('nfs', record_root={nr!r})\n"
-        "from areal_tpu.api.system_api import GenerationServerConfig\n"
-        "from areal_tpu.api.config import ModelAbstraction\n"
-        "from areal_tpu.system.generation_server import GenerationServer\n"
-        "import areal_tpu.engine.factories\n"
-        "cfg = GenerationServerConfig(\n"
-        f"    experiment_name={exp!r}, trial_name={trial!r}, server_index=0,\n"
-        "    model=ModelAbstraction('tpu_transformer',\n"
-        f"        args=dict(config={model_cfg!r})),\n"
-        f"    warm_on_start=True, seed=0, **{srv!r})\n"
-        "w = GenerationServer()\n"
-        "w.configure(cfg, experiment_name=cfg.experiment_name,\n"
-        "            trial_name=cfg.trial_name, worker_name=cfg.worker_name)\n"
-        "w.run()\n"
-    )
-    env = dict(os.environ)
-    env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
-    log_path = os.path.join(tmp, "server.log")
-    t_spawn = time.monotonic()
-    with open(log_path, "w") as log_f:
-        proc = subprocess.Popen(
-            [sys.executable, "-c", child], env=env, cwd=repo,
-            stdout=log_f, stderr=subprocess.STDOUT,
-        )
-    try:
-        from areal_tpu.base import name_resolve, names
-
-        name_resolve.reconfigure("nfs", record_root=nr)
-        url = None
-        deadline = time.monotonic() + 600
-        while url is None:
-            if proc.poll() is not None:
-                with open(log_path) as f:
-                    tail = f.read()[-3000:]
-                raise RuntimeError(f"serving_http server died:\n{tail}")
-            try:
-                url = name_resolve.get(names.gen_server_url(exp, trial, "0"))
-            except Exception:
-                if time.monotonic() > deadline:
-                    raise TimeoutError("serving_http server never registered")
-                time.sleep(0.5)
-
-        def generate(i, new_tokens):
-            body = json.dumps({
-                "qid": f"h{i}",
-                "input_ids": list(range(1, plen + 1)),
-                "gconfig": {"max_new_tokens": new_tokens, "greedy": True},
-            }).encode()
-            req = urllib.request.Request(
-                f"{url}/generate", data=body,
-                headers={"Content-Type": "application/json"},
-            )
-            with urllib.request.urlopen(req, timeout=600) as resp:
-                return json.loads(resp.read())
-
-        if pass_ == "compile":
-            generate(0, srv["decode_block_steps"])
-            # From spawn, not from registration: with warm_on_start the
-            # XLA compiles happen BEFORE the server registers, and the
-            # banked compile_s must not hide them.
-            dt = time.monotonic() - t_spawn
-            log(f"bench: serving_http compile pass {dt:.1f}s")
-            return {"compile_s": dt}
-
-        generate(0, srv["decode_block_steps"])  # warm
-        t0 = time.monotonic()
-        toks = 0
-        for i in range(1, n_reqs + 1):
-            out = generate(i, max_new)
-            toks += len(out.get("output_ids", []))
-        dt = time.monotonic() - t0
-        tps = toks / dt
-        log(f"bench: serving_http {toks} tokens in {dt:.2f}s "
-            f"-> {tps:.0f} tok/s (serial HTTP)")
-        return {"serving_http_tps": tps, "tokens": toks, "wall_s": dt}
-    finally:
-        proc.kill()
-        proc.wait()
 
 
 # ----------------------------------------------------------------------
@@ -463,10 +34,9 @@ def serving_http_phase(pass_: str) -> dict:
 # over a REAL multi-process fleet (bench/fleet.py): GenerationServer
 # worker subprocesses behind a real GserverManager, load routed through
 # /schedule_request — the path production rollout workers take (the
-# ROADMAP item-2 "not in-process engines" gap). Closed-loop throughput
-# (gen_tps, serving_http) cannot see overload behavior — an open-loop
-# generator keeps submitting at the offered rate regardless of
-# completions, which is what "millions of users" do. Sweeps arrival
+# ROADMAP item-2 "not in-process engines" gap). A closed loop cannot
+# see overload behavior — an open-loop generator keeps submitting at
+# the offered rate regardless of completions. Sweeps arrival
 # rates against measured capacity and A/Bs server-side admission
 # control (429 watermark shedding) against a no-backpressure baseline
 # at deliberate overload: with admission, p99 TTFT stays bounded by the
@@ -1050,115 +620,6 @@ def sessions_resident_phase(pass_: str) -> dict:
         "fleet": "process",
         "wall_s": time.monotonic() - t_start,
     }
-
-
-# ----------------------------------------------------------------------
-# CPU-proxy phases (never driver-verified; the runner pins them to
-# JAX_PLATFORMS=cpu and the report labels them proxy evidence).
-# ----------------------------------------------------------------------
-
-
-def pack_density_phase(pass_: str) -> dict:
-    """FFD packing density on realistic length mixes — the host-side
-    fraction of shipped device cells that hold real tokens. Pure-host
-    evidence for the input pipeline; pairs with the on-chip
-    packing_efficiency telemetry the train phase exports."""
-    from areal_tpu.base.datapack import packing_density
-
-    if pass_ == "compile":
-        return {"compile_s": 0.0}  # nothing to compile: host-only
-    rng = np.random.RandomState(7)
-    mixes = {
-        # Short chat-style responses with a long tail.
-        "chat_tail": np.clip(
-            rng.lognormal(5.5, 0.8, size=512), 16, 4096
-        ).astype(int),
-        # Reasoning-style long generations (the reference's ~31k regime,
-        # scaled to the flagship bench context).
-        "reasoning": np.clip(
-            rng.lognormal(7.8, 0.5, size=256), 256, 16384
-        ).astype(int),
-        # Uniform mid-length SFT corpus.
-        "sft_uniform": rng.randint(128, 2048, size=512),
-    }
-    t0 = time.perf_counter()
-    out = {}
-    for name, lengths in mixes.items():
-        out[f"density_{name}"] = packing_density(
-            lengths.tolist(), row_len_multiple=128, max_row_len=16384
-        )
-    out["wall_s"] = time.perf_counter() - t0
-    log(f"bench: pack_density {out}")
-    return out
-
-
-def prefetch_overlap_phase(pass_: str) -> dict:
-    """Input-pipeline overlap telemetry on the 1-device CPU engine: the
-    packing_efficiency / h2d_wait_ms / dispatch_gap_ms series from a
-    multi-microbatch train loop through the prefetched pipeline. Proxy
-    evidence that the overlap path engages and its telemetry is sane —
-    absolute numbers only mean anything on-chip."""
-    import jax
-
-    from areal_tpu.api.data_api import MicroBatchSpec, SequenceSample
-    from areal_tpu.base import stats_tracker
-    from areal_tpu.engine.jax_engine import JaxTrainEngine
-    from areal_tpu.engine.optimizer import OptimizerConfig
-    from areal_tpu.models.transformer import init_params
-    from areal_tpu.ops.loss import sft_loss_from_logprobs
-
-    cfg = smoke_cfg()
-    seqlen, n_seqs = 128, 8
-    params = init_params(cfg, jax.random.PRNGKey(0))
-    eng = JaxTrainEngine(
-        cfg, params,
-        optimizer_config=OptimizerConfig(lr=1e-4, warmup_steps_proportion=0.0),
-        total_train_steps=100, row_len_multiple=seqlen, max_row_len=seqlen,
-        remat="full", prefetch_depth=2,
-    )
-    rng = np.random.RandomState(0)
-    total = seqlen * n_seqs
-    batch = SequenceSample.from_default(
-        ids=[f"b{i}" for i in range(n_seqs)],
-        seqlens=[seqlen] * n_seqs,
-        data={
-            "packed_input_ids": rng.randint(0, cfg.vocab_size, size=total),
-            "loss_mask": np.ones(total, np.float32),
-        },
-    )
-
-    def packed_loss(lp, rows):
-        tot, n = sft_loss_from_logprobs(lp, rows["loss_mask"])
-        return tot, {}
-
-    def weight(mb):
-        return float(np.sum(mb.data["loss_mask"]))
-
-    spec = MicroBatchSpec(n_mbs=4)
-    if pass_ == "compile":
-        t0 = time.perf_counter()
-        eng.train_batch(batch, spec, packed_loss, weight, loss_name="bench")
-        jax.block_until_ready(eng.params)
-        return {"compile_s": time.perf_counter() - t0}
-
-    eng.train_batch(batch, spec, packed_loss, weight, loss_name="bench")
-    stats_tracker.export(key="perf")  # drain warmup telemetry
-    n_steps = 3
-    t0 = time.perf_counter()
-    for i in range(n_steps):
-        eng.train_batch(batch, spec, packed_loss, weight,
-                        version_steps=i + 1, loss_name="bench")
-    jax.block_until_ready(eng.params)
-    dt = (time.perf_counter() - t0) / n_steps
-    perf = stats_tracker.export(key="perf")
-    out = {
-        k[len("perf/"):]: float(v) for k, v in perf.items()
-        if k in (mreg.PERF_PACKING_EFFICIENCY, mreg.PERF_H2D_WAIT_MS,
-                 mreg.PERF_DISPATCH_GAP_MS, mreg.PERF_OVERLAP_EVENTS)
-    }
-    out["step_s"] = dt
-    log(f"bench: prefetch_overlap {out}")
-    return out
 
 
 def weight_plane_sharded_phase(pass_: str) -> dict:
@@ -1866,128 +1327,6 @@ def _moe_scaling_measure() -> dict:
     }
     log(f"bench: moe_scaling {out}")
     return out
-
-
-def train_tflops_scaling_phase(pass_: str) -> dict:
-    """Train-throughput scaling curve, 1 -> N chips (weak scaling: the
-    global batch grows with the FSDP mesh so per-chip work is constant
-    — the regime ROADMAP item 1's reference system runs in). Registered
-    as a default driver phase so the daemon spends the next real TPU
-    window producing the curve unattended; on a CPU host the phase env
-    forces 2 virtual devices, so proxy rounds still bank a (labeled)
-    2-point sanity curve."""
-    import jax
-
-    from areal_tpu.api.data_api import MicroBatchSpec, SequenceSample
-    from areal_tpu.base.topology import MeshSpec
-    from areal_tpu.engine.jax_engine import JaxTrainEngine
-    from areal_tpu.engine.optimizer import OptimizerConfig
-    from areal_tpu.models.transformer import count_params, init_params
-    from areal_tpu.ops.loss import sft_loss_from_logprobs
-    from areal_tpu.parallel.mesh import make_mesh
-
-    devices = jax.devices()
-    on_tpu = devices[0].platform == "tpu"
-    ns = [1]
-    while ns[-1] * 2 <= len(devices):
-        ns.append(ns[-1] * 2)
-    if on_tpu:
-        cfg = flagship_cfg()
-        seqlen, base_seqs, n_warmup, n_steps = 2048, 8, 2, 4
-        remat = "save_attn"
-    else:
-        cfg = smoke_cfg()
-        seqlen, base_seqs, n_warmup, n_steps = 128, 2, 1, 2
-        remat = "full"
-
-    def packed_loss(lp, rows):
-        tot, _ = sft_loss_from_logprobs(lp, rows["loss_mask"])
-        return tot, {}
-
-    def weight(mb):
-        return float(np.sum(mb.data["loss_mask"]))
-
-    t_start = time.monotonic()
-    points = []
-    compile_s = 0.0
-    for n in ns:
-        mesh = make_mesh(MeshSpec(data=1, fsdp=n), devices[:n])
-        params = init_params(cfg, jax.random.PRNGKey(0))
-        n_params = count_params(params)
-        eng = JaxTrainEngine(
-            cfg, params, mesh=mesh,
-            optimizer_config=OptimizerConfig(
-                lr=1e-4, warmup_steps_proportion=0.0
-            ),
-            total_train_steps=1000, row_len_multiple=seqlen,
-            max_row_len=seqlen, remat=remat,
-        )
-        rng = np.random.RandomState(0)
-        n_seqs = base_seqs * n  # weak scaling
-        seqlens = [seqlen] * n_seqs
-        total = seqlen * n_seqs
-        batch = SequenceSample.from_default(
-            ids=[f"b{n}-{i}" for i in range(n_seqs)],
-            seqlens=seqlens,
-            data={
-                "packed_input_ids": rng.randint(
-                    0, cfg.vocab_size, size=total
-                ),
-                "loss_mask": np.ones(total, np.float32),
-            },
-        )
-        mb_spec = MicroBatchSpec(n_mbs=1)
-        if pass_ == "compile":
-            t0 = time.perf_counter()
-            compile_s += eng.warm(batch, mb_spec, packed_loss,
-                                  loss_name="bench")
-            eng.train_batch(batch, mb_spec, packed_loss, weight,
-                            version_steps=0, loss_name="bench")
-            jax.block_until_ready(eng.params)
-            log(f"bench: scaling compile n={n} "
-                f"{time.perf_counter() - t0:.1f}s")
-            del eng
-            continue
-        for i in range(n_warmup):
-            eng.train_batch(batch, mb_spec, packed_loss, weight,
-                            version_steps=i, loss_name="bench")
-        jax.block_until_ready(eng.params)
-        t0 = time.perf_counter()
-        for i in range(n_steps):
-            eng.train_batch(batch, mb_spec, packed_loss, weight,
-                            version_steps=n_warmup + i, loss_name="bench")
-        jax.block_until_ready(eng.params)
-        dt = (time.perf_counter() - t0) / n_steps
-        flops = train_step_flops(cfg, n_params, seqlens)
-        per_chip = flops / dt / 1e12 / n
-        points.append({
-            "n_devices": float(n),
-            "mesh": str(MeshSpec(data=1, fsdp=n)),
-            "step_s": dt,
-            "tokens_per_sec": total / dt,
-            "train_tflops_total": flops / dt / 1e12,
-            "train_tflops_per_chip": per_chip,
-        })
-        log(f"bench: scaling n={n} {dt:.3f}s/step "
-            f"{per_chip:.1f} TFLOP/s/chip")
-        del eng  # free params+moments before the next (larger) mesh
-
-    if pass_ == "compile":
-        return {"compile_s": compile_s or (time.monotonic() - t_start)}
-    eff = (
-        points[-1]["train_tflops_per_chip"]
-        / max(points[0]["train_tflops_per_chip"], 1e-9)
-        if points else 0.0
-    )
-    return {
-        "points": points,
-        "n_devices_max": float(ns[-1]),
-        "scaling_efficiency": eff,
-        "train_tflops_per_chip_at_max": (
-            points[-1]["train_tflops_per_chip"] if points else 0.0
-        ),
-        "wall_s": time.monotonic() - t_start,
-    }
 
 
 def rpc_resilience_phase(pass_: str) -> dict:
@@ -3253,509 +2592,6 @@ def tenant_fairness_phase(pass_: str) -> dict:
         import shutil
 
         shutil.rmtree(tmp, ignore_errors=True)
-
-
-# ----------------------------------------------------------------------
-# kernel_micro family: banked per-kernel evidence for the serving/train
-# hot-path kernels (ROADMAP item 3). Every case carries its parity
-# number next to its timing — a fast kernel that diverged is refused by
-# validate_bench, not published — and CPU rounds label themselves
-# cpu_proxy so the report can never conflate them with chip numbers.
-# ----------------------------------------------------------------------
-
-
-def _time_ms(fn, iters: int = 20, warmup: int = 2) -> float:
-    """Median of per-iteration wall times: robust to the load spikes a
-    2-core CI host throws at a mean (one preempted iteration would
-    otherwise flip a close A/B)."""
-    import jax
-
-    for _ in range(warmup):
-        jax.block_until_ready(fn())
-    times = []
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        jax.block_until_ready(fn())
-        times.append(time.perf_counter() - t0)
-    return float(np.median(times)) * 1e3
-
-
-def _kmicro_case(name, baseline_impl, optimized_impl, baseline_ms,
-                 optimized_ms, parity_max_rel, timed=True, **extra):
-    """One microbench case. ``optimized`` is what the production 'auto'
-    dispatcher resolves to on THIS platform — when that IS the baseline,
-    the same measurement is banked for both (speedup exactly 1.0, never
-    a noise artifact the slower-than-baseline tooth would refuse).
-    ``timed=False`` cases are parity-only: the optimized impl exists
-    here only in interpret mode, and timing an interpreter would be
-    fiction."""
-    case = {
-        "name": name,
-        "baseline_impl": baseline_impl,
-        "optimized_impl": optimized_impl,
-        "parity_max_rel": float(parity_max_rel),
-        "timed": 1.0 if timed else 0.0,
-    }
-    if timed:
-        case["baseline_ms"] = float(baseline_ms)
-        case["optimized_ms"] = float(optimized_ms)
-        case["speedup"] = float(baseline_ms) / max(float(optimized_ms), 1e-9)
-    case.update(extra)
-    return case
-
-
-def _kmicro_value(cases, on_tpu: bool, **extra) -> dict:
-    timed = [c["speedup"] for c in cases if c["timed"]]
-    val = {
-        "cases": cases,
-        "n_cases": float(len(cases)),
-        "cpu_proxy": 0.0 if on_tpu else 1.0,
-        "best_speedup": float(max(timed)) if timed else 1.0,
-    }
-    val.update(extra)
-    if not on_tpu:
-        val["evidence"] = "proxy"
-    return val
-
-
-def _rel_err(got, want) -> float:
-    """max |got - want| normalized by the result scale: float32 eps at
-    O(20) magnitudes is ~2.4e-6, so an absolute tolerance would judge
-    reassociated sums by their input scale, not their arithmetic."""
-    import numpy as _np
-
-    g, w = _np.asarray(got, _np.float64), _np.asarray(want, _np.float64)
-    return float(
-        _np.max(_np.abs(g - w)) / max(1.0, float(_np.max(_np.abs(w))))
-    )
-
-
-def _gae_pack(R: int, T: int, seed: int = 0):
-    """Packed multi-segment rows with misaligned starts, inter-segment
-    padding gaps, and a bootstrap at every segment's final token — the
-    case family the reference ships three CUDA GAE variants for."""
-    rng = np.random.RandomState(seed)
-    seg = np.zeros((R, T), np.int32)
-    boot = np.zeros((R, T), np.float32)
-    for r in range(R):
-        t = int(rng.randint(0, 5))
-        s = 1
-        while t < T - 4:
-            length = int(rng.randint(3, max(4, T // 12)))
-            end = min(t + length, T)
-            seg[r, t:end] = s
-            boot[r, end - 1] = rng.randn()
-            s += 1
-            t = end + int(rng.randint(0, 3))
-    rew = (rng.randn(R, T) * (seg > 0)).astype(np.float32)
-    val = (rng.randn(R, T) * (seg > 0)).astype(np.float32)
-    return rew, val, seg, boot
-
-
-def kernel_micro_gae_phase(pass_: str) -> dict:
-    """Trainer GAE: serial lax.scan (baseline oracle) vs the
-    associative scan 'auto' dispatches to vs the blocked Pallas kernel,
-    plus the host numpy loop for scale. Parity is mandatory per case."""
-    import functools
-
-    import jax
-    import jax.numpy as jnp
-
-    from areal_tpu.ops.gae import (
-        gae_rows, gae_rows_assoc, gae_rows_pallas, resolve_gae_impl,
-    )
-
-    devices = jax.devices()
-    on_tpu = devices[0].platform == "tpu"
-    R, T = (16, 8192) if on_tpu else (8, 1024)
-    gamma, lam = 0.97, 0.95
-    rew, val, seg, boot = _gae_pack(R, T)
-    args = tuple(jnp.asarray(x) for x in (rew, val, seg, boot))
-
-    impls = {
-        "scan": jax.jit(functools.partial(gae_rows, gamma=gamma, lam=lam)),
-        "assoc": jax.jit(
-            functools.partial(gae_rows_assoc, gamma=gamma, lam=lam)
-        ),
-        "pallas": jax.jit(
-            functools.partial(gae_rows_pallas, gamma=gamma, lam=lam)
-        ),
-    }
-    # Pallas arm: full shape on TPU (native kernel, timed); a small
-    # parity-only shape off-TPU — the interpreter executes per-block,
-    # and timing (or warming) it at full size would be pure waste.
-    if on_tpu:
-        pallas_args = args
-    else:
-        prew, pval, pseg, pboot = _gae_pack(8, 256, seed=1)
-        pallas_args = tuple(
-            jnp.asarray(x) for x in (prew, pval, pseg, pboot)
-        )
-    if pass_ == "compile":
-        t0 = time.perf_counter()
-        for name, fn in impls.items():
-            jax.block_until_ready(
-                fn(*(pallas_args if name == "pallas" else args))
-            )
-        return {"compile_s": time.perf_counter() - t0}
-
-    base_adv = impls["scan"](*args)[0]
-    auto = resolve_gae_impl("auto", R, T)
-    scan_ms = _time_ms(lambda: impls["scan"](*args)[0])
-    assoc_ms = _time_ms(lambda: impls["assoc"](*args)[0])
-    by_impl = {"scan": scan_ms, "assoc": assoc_ms}
-    if auto not in by_impl:
-        # Future-proof the dispatcher flip (e.g. auto -> 'pallas' once
-        # device evidence lands): time whatever auto resolves to at its
-        # own measurement shape instead of KeyError-ing the phase out
-        # of every subsequent window.
-        auto_args = pallas_args if auto == "pallas" else args
-        by_impl[auto] = _time_ms(lambda: impls[auto](*auto_args)[0])
-
-    # Host loop (the reference's python fallback): one reverse pass per
-    # row on numpy scalars — the scale bar the device scans are judged
-    # against.
-    def host_gae():
-        adv = np.zeros((R, T), np.float64)
-        nxt_a = np.zeros(R)
-        nxt_v = np.zeros(R)
-        nxt_s = np.zeros(R, np.int64)
-        for t in range(T - 1, -1, -1):
-            for r in range(R):
-                s = seg[r, t]
-                if s == 0:
-                    adv[r, t] = 0.0
-                else:
-                    same = s == nxt_s[r]
-                    v1 = nxt_v[r] if same else boot[r, t]
-                    d = rew[r, t] + gamma * v1 - val[r, t]
-                    adv[r, t] = d + gamma * lam * (
-                        nxt_a[r] if same else 0.0
-                    )
-                nxt_a[r] = adv[r, t]
-                nxt_v[r] = val[r, t]
-                nxt_s[r] = s
-        return adv
-
-    t0 = time.perf_counter()
-    host_adv = host_gae()
-    host_ms = (time.perf_counter() - t0) * 1e3
-
-    cases = [
-        _kmicro_case(
-            f"gae_{R}x{T}", "scan", auto, scan_ms, by_impl[auto],
-            _rel_err(impls[auto](*args)[0], base_adv),
-            host_ms=host_ms,
-            host_parity_max_rel=_rel_err(host_adv, base_adv),
-            scan_depth=float(T),
-            assoc_depth=float(int(np.ceil(np.log2(max(T, 2))))),
-        ),
-    ]
-    # Pallas: timed only where it compiles natively; interpret-mode
-    # timings are fiction, but parity is parity everywhere.
-    if on_tpu:
-        cases.append(_kmicro_case(
-            f"gae_pallas_{R}x{T}", "scan", "pallas", scan_ms,
-            _time_ms(lambda: impls["pallas"](*pallas_args)[0]),
-            _rel_err(impls["pallas"](*pallas_args)[0], base_adv),
-        ))
-    else:
-        cases.append(_kmicro_case(
-            "gae_pallas_8x256", "scan", "pallas", None, None,
-            _rel_err(
-                impls["pallas"](*pallas_args)[0],
-                impls["scan"](*pallas_args)[0],
-            ),
-            timed=False,
-        ))
-    out = _kmicro_value(cases, on_tpu, gae_auto_impl=auto)
-    log(f"bench: kernel_micro_gae scan {scan_ms:.2f}ms assoc "
-        f"{assoc_ms:.2f}ms host {host_ms:.0f}ms auto={auto}")
-    return out
-
-
-def kernel_micro_paged_decode_phase(pass_: str) -> dict:
-    """Paged decode attention across the scheduler's pow2 admit batch
-    shapes: XLA gather (baseline) vs what 'auto' resolves to, for the
-    float pool AND the int8 (data, scales) pool. On TPU that is the
-    stock Pallas kernel / our int8 kernel; on CPU both resolve to the
-    XLA path and the record is an honest speedup-1.0 parity anchor."""
-    import jax
-    import jax.numpy as jnp
-
-    from areal_tpu.engine.paged import (
-        paged_decode_attention, quantize_kv, resolve_paged_decode_impl,
-    )
-
-    devices = jax.devices()
-    on_tpu = devices[0].platform == "tpu"
-    if on_tpu:
-        Hq, Hkv, hd, pg, P, batches = 12, 2, 128, 128, 16, (8, 16, 32)
-    else:
-        Hq, Hkv, hd, pg, P, batches = 4, 2, 16, 8, 4, (2, 4, 8)
-    N = max(batches) * P + 1
-    rng = np.random.RandomState(0)
-    kf = jnp.asarray(rng.randn(Hkv, N, pg, hd).astype(np.float32))
-    vf = jnp.asarray(rng.randn(Hkv, N, pg, hd).astype(np.float32))
-    kq_d, kq_s = quantize_kv(kf)
-    vq_d, vq_s = quantize_kv(vf)
-    kq = (kq_d, kq_s[..., 0])
-    vq = (vq_d, vq_s[..., 0])
-
-    def shapes(B, seed):
-        r = np.random.RandomState(seed)
-        q = jnp.asarray(r.randn(B, Hq, hd).astype(np.float32))
-        lengths = jnp.asarray(
-            r.randint(1, P * pg + 1, size=B).astype(np.int32)
-        )
-        pages = jnp.asarray(
-            (1 + r.permutation(N - 1)[: B * P]).reshape(B, P).astype(
-                np.int32
-            )
-        )
-        return q, lengths, pages
-
-    def run(B, pool_k, pool_v, impl, seed=0):
-        q, lengths, pages = shapes(B, seed)
-        fn = jax.jit(
-            lambda q, lg, pi: paged_decode_attention(
-                q, pool_k, pool_v, lg, pi, impl=impl
-            )
-        )
-        return fn, (q, lengths, pages)
-
-    if pass_ == "compile":
-        t0 = time.perf_counter()
-        for B in batches:
-            for pool_k, pool_v, quant in ((kf, vf, False), (kq, vq, True)):
-                for impl in {"xla", resolve_paged_decode_impl(
-                    "auto", quant, pg, hd, P
-                )}:
-                    fn, a = run(B, pool_k, pool_v, impl)
-                    jax.block_until_ready(fn(*a))
-        return {"compile_s": time.perf_counter() - t0}
-
-    cases = []
-    for B in batches:
-        float_base_out = None  # float arm's XLA result, reused below
-        for enc, pool_k, pool_v, quant in (
-            ("float", kf, vf, False), ("int8", kq, vq, True),
-        ):
-            auto = resolve_paged_decode_impl("auto", quant, pg, hd, P)
-            base_fn, a = run(B, pool_k, pool_v, "xla", seed=B)
-            base_out = base_fn(*a)
-            base_ms = _time_ms(lambda: base_fn(*a))
-            if auto == "xla":
-                opt_ms, rel = base_ms, 0.0
-            else:
-                opt_fn, _ = run(B, pool_k, pool_v, auto, seed=B)
-                rel = _rel_err(opt_fn(*a), base_out)
-                opt_ms = _time_ms(lambda: opt_fn(*a))
-            extra = {}
-            if quant:
-                # Quantization error vs the float pool — context for the
-                # parity number, which compares SAME-encoding paths. The
-                # float arm's result for this B is reused as-is (same
-                # seed, same shapes — rebuilding it would re-trace and
-                # re-run the identical program).
-                extra["quant_max_rel_vs_float"] = _rel_err(
-                    base_out, float_base_out
-                )
-            else:
-                float_base_out = base_out
-            cases.append(_kmicro_case(
-                f"decode_b{B}_{enc}", "xla", auto, base_ms, opt_ms, rel,
-                admit_batch=float(B), **extra,
-            ))
-    out = _kmicro_value(cases, on_tpu, pages_per_seq=float(P),
-                        page_size=float(pg), head_dim=float(hd))
-    log(f"bench: kernel_micro_paged_decode {len(cases)} cases, best "
-        f"speedup {out['best_speedup']:.2f}")
-    return out
-
-
-def kernel_micro_splash_phase(pass_: str) -> dict:
-    """Splash prefill attention vs the reference einsum oracle on a
-    packed multi-segment stream. Timed natively on TPU; on CPU the
-    kernel only exists interpreted, so the case is parity-only and the
-    reference timing anchors the scale."""
-    import jax
-    import jax.numpy as jnp
-
-    from areal_tpu.ops.attention import (
-        reference_packed_attention, splash_packed_attention,
-    )
-
-    devices = jax.devices()
-    on_tpu = devices[0].platform == "tpu"
-    if on_tpu:
-        T, Hq, Hkv, hd, n_seg = 1536, 12, 2, 128, 4
-    else:
-        # hd must be 128 even interpreted (this jax's splash kernel
-        # gates head_dim before dispatching to the interpreter).
-        T, Hq, Hkv, hd, n_seg = 256, 4, 2, 128, 3
-    rng = np.random.RandomState(0)
-    bounds = np.sort(rng.choice(np.arange(1, T // 8), n_seg - 1,
-                                replace=False)) * 8
-    seg = np.zeros((T,), np.int32)
-    pos = np.zeros((T,), np.int32)
-    start = 0
-    for i, end in enumerate(list(bounds) + [T]):
-        seg[start:end] = i + 1
-        pos[start:end] = np.arange(end - start)
-        start = end
-    q = jnp.asarray(rng.randn(T, Hq, hd).astype(np.float32) * 0.1)
-    k = jnp.asarray(rng.randn(T, Hkv, hd).astype(np.float32) * 0.1)
-    v = jnp.asarray(rng.randn(T, Hkv, hd).astype(np.float32) * 0.1)
-    segj, posj = jnp.asarray(seg), jnp.asarray(pos)
-
-    ref_fn = jax.jit(
-        lambda q, k, v: reference_packed_attention(q, k, v, segj, posj)
-    )
-    splash_fn = jax.jit(
-        lambda q, k, v: splash_packed_attention(
-            q, k, v, segj, posj, interpret=not on_tpu
-        )
-    )
-    if pass_ == "compile":
-        t0 = time.perf_counter()
-        jax.block_until_ready(ref_fn(q, k, v))
-        if on_tpu:
-            jax.block_until_ready(splash_fn(q, k, v))
-        return {"compile_s": time.perf_counter() - t0}
-
-    ref_out = np.asarray(ref_fn(q, k, v))
-    splash_out = np.asarray(splash_fn(q, k, v))
-    mask = seg > 0
-    rel = _rel_err(splash_out[mask], ref_out[mask])
-    base_ms = _time_ms(lambda: ref_fn(q, k, v))
-    if on_tpu:
-        case = _kmicro_case(
-            f"splash_t{T}", "reference", "splash", base_ms,
-            _time_ms(lambda: splash_fn(q, k, v)), rel,
-        )
-    else:
-        case = _kmicro_case(
-            f"splash_t{T}", "reference", "splash", None, None, rel,
-            timed=False, reference_ms=base_ms,
-        )
-    out = _kmicro_value([case], on_tpu, seq_len=float(T))
-    log(f"bench: kernel_micro_splash T={T} parity {rel:.2e} "
-        f"ref {base_ms:.2f}ms")
-    return out
-
-
-def kernel_micro_decode_state_phase(pass_: str) -> dict:
-    """Device-resident decode-state A/B (AREAL_DECODE_RESIDENT): the
-    SAME greedy workload through a resident and a legacy engine —
-    token parity is asserted in-phase, and the banked evidence is the
-    measured per-decode-block H2D transfer/byte reduction plus the
-    throughput of both arms. Prompts are sized to exercise the chunked
-    prefill (where the fused control array saves 2 transfers per chunk)
-    and multi-slot admission (where the row scatter replaces the
-    full-table restage)."""
-    import threading
-
-    import jax
-
-    from areal_tpu.engine.serving import GenRequest, ServingEngine
-    from areal_tpu.models.transformer import init_params
-
-    devices = jax.devices()
-    on_tpu = devices[0].platform == "tpu"
-    if on_tpu:
-        cfg = flagship_cfg()
-        n_reqs, plen, max_new, page, block, chunk = 8, 512, 128, 128, 32, 256
-    else:
-        cfg = smoke_cfg()
-        n_reqs, plen, max_new, page, block, chunk = 4, 40, 24, 8, 4, 16
-    params = init_params(cfg, jax.random.PRNGKey(1))
-    rng = np.random.RandomState(3)
-    prompts = [
-        rng.randint(0, cfg.vocab_size, size=plen - (i % 3)).tolist()
-        for i in range(n_reqs)
-    ]
-
-    def run(resident: bool):
-        eng = ServingEngine(
-            cfg, params,
-            max_batch_size=max(2, n_reqs // 2),  # forces multi-round admits
-            max_seq_len=plen + max_new + page,
-            decode_block_steps=block,
-            prompt_bucket=page,
-            page_size=page,
-            kv_pool_tokens=n_reqs * (plen + max_new + page),
-            prefill_chunk=chunk,
-            decode_resident=resident,
-            seed=5,
-        )
-        eng.start()
-        try:
-            def drive(reqs, tag):
-                done = threading.Event()
-                out = {}
-
-                def cb(res):
-                    out[res.qid] = list(res.output_ids)
-                    if len(out) == len(reqs):
-                        done.set()
-
-                for i, p in enumerate(reqs):
-                    eng.submit(GenRequest(
-                        qid=f"{tag}{i}", input_ids=p,
-                        max_new_tokens=max_new, greedy=True, done_cb=cb,
-                    ))
-                assert done.wait(1800), (
-                    f"decode_state arm stalled: {len(out)}/{len(reqs)}"
-                )
-                return out
-
-            # Per-arm warmup: each arm compiles ITS OWN staging programs
-            # (packed vs legacy chunk prefill) but shares the decode
-            # block — without this the first arm eats the shared
-            # compiles inside its timed window and the A/B throughput
-            # is fiction. Counters are snapshot-diffed past it too.
-            drive(prompts[:2], "w")
-            h0, b0, d0 = eng.h2d_transfers, eng.h2d_bytes, eng.decode_blocks
-            t0 = time.perf_counter()
-            out = drive(prompts, "q")
-            wall = time.perf_counter() - t0
-            blocks = max(1, eng.decode_blocks - d0)
-            return out, {
-                "h2d_per_block": (eng.h2d_transfers - h0) / blocks,
-                "h2d_bytes_per_block": (eng.h2d_bytes - b0) / blocks,
-                "tps": sum(len(v) for v in out.values()) / wall,
-            }
-        finally:
-            eng.stop()
-
-    if pass_ == "compile":
-        t0 = time.perf_counter()
-        run(True)
-        run(False)
-        return {"compile_s": time.perf_counter() - t0}
-
-    out_res, st_res = run(True)
-    out_leg, st_leg = run(False)
-    parity = all(out_res[k] == out_leg[k] for k in out_res)
-    val = {
-        "token_parity_ok": 1.0 if parity else 0.0,
-        "h2d_per_block_resident": st_res["h2d_per_block"],
-        "h2d_per_block_legacy": st_leg["h2d_per_block"],
-        "h2d_bytes_per_block_resident": st_res["h2d_bytes_per_block"],
-        "h2d_bytes_per_block_legacy": st_leg["h2d_bytes_per_block"],
-        "gen_tps_resident": st_res["tps"],
-        "gen_tps_legacy": st_leg["tps"],
-        "n_requests": float(n_reqs),
-        "cpu_proxy": 0.0 if on_tpu else 1.0,
-    }
-    if not on_tpu:
-        val["evidence"] = "proxy"
-    log(f"bench: kernel_micro_decode_state parity={parity} h2d/block "
-        f"{st_res['h2d_per_block']:.1f} vs {st_leg['h2d_per_block']:.1f} "
-        f"bytes/block {st_res['h2d_bytes_per_block']:.0f} vs "
-        f"{st_leg['h2d_bytes_per_block']:.0f}")
-    return val
 
 
 def recovery_slo_phase(pass_: str) -> dict:

@@ -593,97 +593,6 @@ class JaxTrainEngine(TrainEngine):
 
         return self._built(key, jax.jit(apply, donate_argnums=(0, 1, 2)))
 
-    def warm(
-        self,
-        input_: SequenceSample,
-        mb_spec: MicroBatchSpec,
-        loss_fn: PackedLossFn,
-        loss_name: str = "loss",
-    ) -> float:
-        """AOT warm hook: trace + XLA-compile every program a
-        `train_batch` of this shape would run, WITHOUT executing a step —
-        params and optimizer state are untouched. With a persistent
-        compilation cache configured, the compiled executables outlive
-        this process: the bench compile pass calls this in a throwaway
-        subprocess so a later (possibly one-minute) measure window pays
-        zero compile time. Returns seconds spent compiling.
-
-        Best-effort by design: shapes are lowered abstractly
-        (jax.ShapeDtypeStruct), and any lowering the running jax version
-        cannot express is skipped with a log line — the measure path
-        then compiles live, exactly as before."""
-        assert self.optimizer is not None, "engine built without optimizer"
-        self._ensure_loaded()
-        t0 = time.perf_counter()
-        mbs, _, _ = input_.split(mb_spec)
-        built = [self._build_rows(mb) for mb in mbs]
-        all_rows = [r for _, r in built]
-        if len(mbs) > 1:
-            rows_np = self._stack_mb_rows(all_rows)
-            rows_sharding = jax.sharding.NamedSharding(
-                self.mesh,
-                jax.sharding.PartitionSpec(None, ("data", "fsdp"), "seq"),
-            )
-        else:
-            rows_np = all_rows[0]
-            rows_sharding = self._batch_sharding
-
-        def sds(x, sharding=None):
-            a = np.asarray(x) if not hasattr(x, "dtype") else x
-            return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
-
-        rows_sds = {k: sds(np.asarray(v), rows_sharding)
-                    for k, v in rows_np.items()}
-        params_sds = jax.tree_util.tree_map(
-            sds, self.params, self._param_shardings
-        )
-        opt_sds = jax.tree_util.tree_map(
-            sds, self.opt_state, self._opt_shardings
-        )
-        scalar_sds = jax.ShapeDtypeStruct((), jnp.float32)
-        row_keys = tuple(sorted(rows_np.keys()))
-        compiled = 0
-        use_overlap = (
-            self.prefetch_depth > 0
-            and not self._serial_dispatch
-            and len(mbs) > 1
-        )
-        try:
-            if use_overlap:
-                # The pipelined path's three programs. The carry avals
-                # come from eval_shape of the first-mb program; their
-                # shardings are XLA-derived at runtime, so on multi-chip
-                # meshes these cache entries may not match — acceptable
-                # for a best-effort warm (the bench measures single-chip).
-                mb_sds = {k: sds(np.asarray(v), self._batch_sharding)
-                          for k, v in all_rows[0].items()}
-                first, nxt = self._accum_step_fns(loss_name, loss_fn, row_keys)
-                carry_sds = jax.eval_shape(first, params_sds, mb_sds)
-                first.lower(params_sds, mb_sds).compile()
-                nxt.lower(params_sds, carry_sds, mb_sds).compile()
-                apply = self._apply_step_fn(loss_name)
-                apply.lower(
-                    params_sds, opt_sds, carry_sds, scalar_sds, scalar_sds
-                ).compile()
-                compiled = 3
-            else:
-                step = self._train_step_fn(
-                    loss_name, loss_fn, row_keys, len(mbs)
-                )
-                step.lower(
-                    params_sds, opt_sds, rows_sds, scalar_sds, scalar_sds
-                ).compile()
-                compiled = 1
-        except Exception as e:
-            logger.warning(f"AOT warm skipped ({e!r}); the first executed "
-                           "step will compile live")
-        dt = time.perf_counter() - t0
-        logger.info(
-            f"AOT warm: {compiled} program(s) compiled in {dt:.1f}s "
-            f"(n_mbs={len(mbs)}, overlap={use_overlap})"
-        )
-        return dt
-
     def _stack_mb_rows(
         self, mbs_rows: List[Dict[str, np.ndarray]]
     ) -> Dict[str, np.ndarray]:
@@ -1051,7 +960,7 @@ class JaxTrainEngine(TrainEngine):
     def _record_overlap_stats(self):
         """Ship the last pipeline's telemetry through the stats tracker so
         model workers export it per MFC (`perf/*` keys reach the master's
-        perf history) and bench.py reads it after the timed loop.
+        perf history).
         h2d_wait/dispatch_gap merge as MAX across DP workers — the step
         blocks on the slowest worker, so averaging would understate it."""
         ov = self.last_overlap
@@ -1065,11 +974,7 @@ class JaxTrainEngine(TrainEngine):
                 "perf/dispatch_gap_ms": ov["dispatch_gap_ms"],
             },
         )
-        # Regression note: the prefetch_overlap bench has parsed
-        # perf/overlap_events since it landed, but this method never
-        # shipped it — the engagement proof silently read as absent.
-        # Found by the metrics-registry lint checker (parsed-but-never-
-        # emitted); SUM so multi-step windows accumulate.
+        # SUM so multi-step windows accumulate.
         stats_tracker.scalar(
             reduce_type=stats_tracker.ReduceType.SUM,
             **{"perf/overlap_events": ov["overlap_events"]},
@@ -1078,7 +983,7 @@ class JaxTrainEngine(TrainEngine):
     def _record_moe_stats(self, stats: Dict[str, float], loss_name: str):
         """Ship router telemetry through the stats tracker so model
         workers export it per MFC (perf/moe_* keys reach the master's
-        perf_summary and the bench JSON passthrough). No-op for dense
+        perf_summary). No-op for dense
         models — keyed off the moe aux stats the loss fetch surfaced."""
         if f"{loss_name}/moe_drop_rate" not in stats:
             return

@@ -239,8 +239,7 @@ def resolve_paged_decode_impl(
     tp_ok: bool = True,
 ) -> str:
     """The paged-decode implementation that runs (trace-time static
-    decision, mirroring ops/attention.resolve_attn_impl — and the
-    dispatch table kernel_micro_paged_decode measures case by case).
+    decision, mirroring ops/attention.resolve_attn_impl).
     Explicit impls pass through untouched.
 
     int8 pools use OUR kernel (ops/pallas/paged_decode_int8) on TPU:

@@ -446,8 +446,8 @@ class ServingEngine:
 
         # Decode-dispatch H2D telemetry (engine-thread writers; metrics()
         # reads the plain ints off-thread like total_generated). Counts
-        # every host->device staging on the admit/decode hot path — the
-        # per-block evidence the kernel_micro_decode_state A/B banks.
+        # every host->device staging on the admit/decode hot path
+        # (tests/engine/test_decode_resident.py reads them per block).
         self.h2d_transfers = 0
         self.h2d_bytes = 0
         self.decode_blocks = 0
@@ -1290,8 +1290,7 @@ class ServingEngine:
             # Decode-dispatch H2D accounting (device-resident decode
             # state, docs/perf_notes.md Round 15): stagings + bytes on
             # the admit/decode hot path, and the decode-block count they
-            # amortize over. The kernel_micro_decode_state A/B banks the
-            # per-block ratio resident-vs-legacy.
+            # amortize over.
             "h2d_transfers_total": float(self.h2d_transfers),
             "h2d_bytes_total": float(self.h2d_bytes),
             "decode_blocks_total": float(self.decode_blocks),

@@ -36,7 +36,6 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from areal_tpu.models.config import TransformerConfig
 from areal_tpu.ops.attention import packed_attention, reference_packed_attention
@@ -148,10 +147,6 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Params:
     elif not cfg.tied_embeddings:
         params["head"] = {"weight": dense(keys[8], (D, V), scale=0.02)}
     return params
-
-
-def count_params(params: Params) -> int:
-    return sum(int(np.prod(x.shape)) for x in jax.tree_util.tree_leaves(params))
 
 
 # ---------------------------------------------------------------------------

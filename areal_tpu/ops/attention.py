@@ -108,8 +108,7 @@ def snapshot_splash_blocks():
     """Parse + validate the AREAL_SPLASH_BQ/BKV/BKVC block-size targets
     and pin them for subsequent traces. Called at engine construction so
     a mid-run retrace can't silently mix tuning settings and a bad value
-    fails at init instead of inside a jit trace; sweeps re-pin by
-    constructing a fresh engine per setting (scripts/mfu_sweep.py)."""
+    fails at init instead of inside a jit trace; a fresh engine re-pins."""
     global _SPLASH_SNAP
 
     def check(name, v):
@@ -595,9 +594,8 @@ def resolve_cp_impl(mesh, r: int, t: int, hq: int, hkv: int) -> Optional[str]:
     """Default context-parallel scheme for an 'auto' impl on a seq>1
     mesh (trace-time static decision).
 
-    Policy (analytic default, pending on-ICI measurement — see
-    docs/perf_notes.md "ring vs Ulysses" and
-    scripts/long_context_probe.py cp mode, which A/Bs this choice):
+    Policy (analytic default, not measured on the chip's interconnect —
+    see docs/perf_notes.md "ring vs Ulysses"):
     prefer Ulysses when the head counts divide the seq axis — its
     per-layer communication is 4 all-to-alls + 2 small gathers
     regardless of the seq size, each moving 1/seq of the activations,

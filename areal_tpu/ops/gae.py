@@ -12,10 +12,9 @@ TPU-native implementations over one shared formulation:
   the GAE recursion A_t = delta_t + (gamma*lam)*[same-seg]*A_{t+1} is a
   first-order linear recurrence, i.e. a reverse scan of affine maps
   f_t(x) = a_t*x + b_t under composition — associative, so XLA runs it
-  in O(log T) depth instead of T serial dispatches. Measured 2x faster
-  than the serial scan on CPU at [8, 4096] (kernel_micro_gae banks the
-  ongoing evidence); on TPU the win is the whole point: the serial scan
-  is T tiny dependent ops.
+  in O(log T) depth instead of T serial dispatches (the serial scan
+  is T tiny dependent ops). Not timed alone on the chip: the whole
+  PPO prep is 8.6 ms of a 2.0 s step (`ppo_prep_ms`; ledger, PR 29).
 - ``gae_rows_pallas`` — the same affine scan as a blocked Pallas kernel
   (ops/pallas/gae_scan.py): ONE HBM read of (a, b) + one write of the
   result vs associative_scan's log T full-array passes. Shape-gated
@@ -24,9 +23,8 @@ TPU-native implementations over one shared formulation:
 
 ``packed_gae`` dispatches (``impl='auto'|'scan'|'assoc'|'pallas'``,
 mirroring ops/attention.resolve_attn_impl): 'auto' resolves to the
-associative scan everywhere — Pallas stays opt-in until a device
-window banks kernel_micro_gae evidence for the crossover
-(docs/perf_notes.md "Round 15").
+associative scan everywhere — Pallas stays opt-in: no chip run has
+timed one against the other (docs/perf_notes.md "Round 15").
 
 Inputs are [R, T] row-packed (multiple sequences per row, segment ids,
 0 = padding). Bootstrapping for truncated (no-EOS) sequences is expressed
@@ -192,12 +190,11 @@ def resolve_gae_impl(impl: str, r: int, t: int) -> str:
     (trace-time static decision, mirroring ops/attention.
     resolve_attn_impl). Explicit values pass through untouched.
 
-    'auto' is the associative scan everywhere: it beats the serial scan
-    on CPU (measured 2x at [8, 4096]) and avoids T dependent dispatches
-    on TPU. The Pallas kernel stays opt-in (impl='pallas') until a
-    device window banks kernel_micro_gae crossover evidence — flipping
-    a default on unmeasured kernel timings is how CPU-proxy numbers get
-    conflated with chip numbers."""
+    'auto' is the associative scan everywhere: O(log T) depth where the
+    serial scan is T dependent dispatches. The Pallas kernel stays
+    opt-in (impl='pallas'): no chip run has timed it against the
+    associative scan, and a default is not flipped on an unmeasured
+    kernel."""
     if impl != "auto":
         return impl
     return "assoc"

@@ -82,9 +82,7 @@ def snapshot_ce_chunk() -> Optional[int]:
     Called at engine construction (engine/jax_engine.py): a mid-run
     retrace then reuses the pinned value instead of silently picking up
     a mutated environment, and an unparseable value fails HERE — at
-    init — rather than deep inside a jit trace. Sweeps that mutate the
-    env between settings (scripts/mfu_sweep.py) re-pin simply by
-    constructing a fresh engine."""
+    init — rather than deep inside a jit trace. A fresh engine re-pins."""
     global _CE_CHUNK_SNAP
     # ValueError (unparseable value) surfaces at snapshot time.
     val: Optional[int] = env_registry.get_int("AREAL_CE_CHUNK")
@@ -126,8 +124,8 @@ def fused_next_token_logprobs(
     R, T, D = hidden.shape
     V = head_w.shape[-1]
     if chunk_size is None:
-        # Sweep override (scripts/mfu_sweep.py), validated + pinned at
-        # engine construction (snapshot_ce_chunk) so retraces can't mix
+        # AREAL_CE_CHUNK override, validated + pinned at engine
+        # construction (snapshot_ce_chunk) so retraces can't mix
         # settings mid-run.
         chunk_size = _ce_chunk_setting()
         if chunk_size is None:

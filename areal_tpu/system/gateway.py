@@ -1789,8 +1789,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         action="store_true",
         help="serve against an in-process stub fleet, run one "
         "completion + one chat completion through the full tenant "
-        "path, check the ledger; exit 0 iff healthy (chip_runbook "
-        "preflight)",
+        "path, check the ledger; exit 0 iff healthy",
     )
     args = p.parse_args(argv)
     if args.name_resolve_root:

@@ -569,7 +569,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         "--selftest",
         action="store_true",
         help="spawn the pool, probe /metrics + one sandboxed job, "
-        "tear down; exit 0 iff healthy (chip_runbook preflight)",
+        "tear down; exit 0 iff healthy",
     )
     args = p.parse_args(argv)
     if args.name_resolve_root:

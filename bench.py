@@ -1,19 +1,10 @@
-"""Benchmark CLI: thin front-end over areal_tpu/bench/.
+"""Benchmark CLI: thin front-end over areal_tpu/bench/ (the old benchmark).
 
 Prints ONE JSON line:
-  {"metric": ..., "value": N, "unit": ..., "vs_baseline": N, ...}
+  {"metric": ..., "value": N, "unit": ..., ...}
 
-Metric: achieved model TFLOP/s per chip for the full training step
-(fwd + bwd + sharded optimizer) on a Qwen2.5-style packed-varlen model in
-bfloat16, plus serving tok/s phases. FLOPs are computed analytically from
-the model dims (the reference does the same for its TFLOP/s logs —
-realhf/base/monitor.py:288 llama formulas).
-
-vs_baseline: ratio against 198 TFLOP/s/GPU — the reference's efficiency
-class on its H800 benchmark hardware (~40% MFU of H800 dense bf16
-~495 TFLOP/s; its headline runs are throughput-bound on exactly this
-train path, benchmark/verl_v0_3_0_post1_76084d3/README.md). >1.0 means a
-chip running this framework outruns an H800 running the reference.
+Every phase is a CPU proxy over the control plane (`--list-phases`);
+none of them times the chip. Speed on the chip is `benchmark/run.py`'s.
 
 Modes:
   python bench.py                 run every unbanked default phase
@@ -43,14 +34,6 @@ sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
 from areal_tpu.bench import bank, phases, report, runner  # noqa: E402
 from areal_tpu.bench.devices import probe_devices  # noqa: E402
-
-# Shared with scripts/mfu_sweep.py and scripts/long_context_probe.py so
-# every probe measures the SAME model and formula as the banked numbers.
-from areal_tpu.bench.workloads import (  # noqa: E402,F401
-    BASELINE_TFLOPS,
-    flagship_cfg,
-    train_step_flops,
-)
 
 
 def log(*a):

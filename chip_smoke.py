@@ -49,6 +49,7 @@ import sys
 import tempfile
 import time
 import urllib.request
+import uuid
 from concurrent.futures import ThreadPoolExecutor
 
 REPO = os.path.dirname(os.path.abspath(__file__))
@@ -108,10 +109,56 @@ def train_depth(cfg: dict) -> int:
 
 
 def make_workload(root: str):
-    """Tokenizer + math prompts from a seed (no network on the machine)."""
-    from scripts.async_speedup_bench import _make_synthetic_workload
+    """Tokenizer + \\boxed math prompts from a seed (no network on the
+    machine): a WordPiece tokenizer trained on the prompts themselves,
+    small enough for the toy model's vocabulary."""
+    from tokenizers import Tokenizer
+    from tokenizers.models import WordPiece
+    from tokenizers.pre_tokenizers import Whitespace
+    from tokenizers.trainers import WordPieceTrainer
+    from transformers import PreTrainedTokenizerFast
 
-    return _make_synthetic_workload(root, n_rows=64, seed=SEED)
+    rng = random.Random(SEED)
+    words = [
+        "prove", "that", "the", "sum", "of", "two", "odd", "numbers",
+        "is", "even", "find", "x", "such", "integral", "matrix", "prime",
+        "graph", "vertex", "angle", "triangle", "circle", "radius",
+    ]
+    rows = []
+    texts = []
+    for _ in range(64):
+        prompt = " ".join(rng.choice(words) for _ in range(rng.randint(6, 14)))
+        rows.append(
+            dict(
+                query_id=str(uuid.uuid4()),
+                task="math",
+                prompt=prompt,
+                solutions=["\\boxed{42}"],
+            )
+        )
+        texts.append(prompt)
+
+    tok = Tokenizer(WordPiece(unk_token="[UNK]"))
+    tok.pre_tokenizer = Whitespace()
+    trainer = WordPieceTrainer(
+        vocab_size=TOY_HF["vocab_size"] - 2,
+        min_frequency=0,
+        special_tokens=["[UNK]", "[EOS]"],
+    )
+    tok.train_from_iterator(texts, trainer)
+    tok_file = os.path.join(root, "tokenizer.json")
+    tok.save(tok_file)
+    tok_dir = os.path.join(root, "tokenizer")
+    PreTrainedTokenizerFast(
+        tokenizer_file=tok_file, eos_token="[EOS]", pad_token="[EOS]",
+        unk_token="[UNK]",
+    ).save_pretrained(tok_dir)
+
+    data_path = os.path.join(root, "math.jsonl")
+    with open(data_path, "w") as f:
+        for r in rows:
+            f.write(json.dumps(r) + "\n")
+    return tok_dir, data_path
 
 
 # ----------------------------------------------------------------------

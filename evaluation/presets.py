@@ -120,8 +120,7 @@ PROMPT_TEMPLATES = {
         demo_format="Question: {question}\n{answer}",
         demo_sep="\n---\n",
     ),
-    # DeepSeek-R1-Distill family markup with an opened think block (the
-    # flagship bench model family; see docs/perf_notes.md).
+    # DeepSeek-R1-Distill family markup with an opened think block.
     "r1-distill": PromptTemplate(
         name="r1-distill",
         question_format=(

@@ -16,8 +16,7 @@
 #              a renamed point must fail HERE, not silently no-op on
 #              a chip window.
 #
-# Exit nonzero if any gate fails. Used by chip_runbook.sh preflight
-# and intended as the single command future PRs/CI wire in.
+# Exit nonzero if any gate fails: the single command PRs/CI wire in.
 
 set -u
 cd "$(dirname "$0")/.."

@@ -35,10 +35,6 @@ from areal_tpu.bench import bank  # noqa: E402
 # carry every listed numeric key. Catches a phase body drifting away
 # from what the report/readers consume without anything failing loudly.
 PHASE_VALUE_KEYS: Dict[str, tuple] = {
-    # Mesh shape + device count ride the VALUES (not just record
-    # attestation) so scaling curves assemble across bench rounds;
-    # train_tflops stays the per-chip headline number.
-    "train_tflops": ("train_tflops", "n_devices"),
     # Sharded-training evidence without its parity/high-water/roundtrip
     # fields is not evidence: a record could bank mesh step times off a
     # run whose sharded math silently diverged.
@@ -46,7 +42,6 @@ PHASE_VALUE_KEYS: Dict[str, tuple] = {
         "fsdp2_parity_ok", "tp2_parity_ok", "loss_parity_max_rel_err",
         "dump_highwater_frac", "dump_roundtrip_ok", "n_devices",
     ),
-    "train_tflops_scaling": ("n_devices_max", "scaling_efficiency"),
     "weight_update": (
         "weight_update_ms", "weight_transfer_ms", "weight_cutover_ms",
         "origin_full_payloads",
@@ -173,22 +168,6 @@ PHASE_VALUE_KEYS: Dict[str, tuple] = {
         "affinity_prefix_hits",
         "exec_jobs_total", "exec_warm_hits", "exec_workers_alive",
         "sat_peak_jobs_per_s", "sat_failed", "sat_shed_total",
-    ),
-    # kernel_micro family: per-kernel timing is only evidence NEXT TO
-    # its parity number, and a CPU round must label itself proxy
-    # (enforced against the record's own attestation below).
-    "kernel_micro_gae": ("n_cases", "best_speedup", "cpu_proxy"),
-    "kernel_micro_paged_decode": ("n_cases", "best_speedup", "cpu_proxy"),
-    "kernel_micro_splash": ("n_cases", "best_speedup", "cpu_proxy"),
-    "kernel_micro_decode_state": (
-        "token_parity_ok",
-        "h2d_per_block_resident",
-        "h2d_per_block_legacy",
-        "h2d_bytes_per_block_resident",
-        "h2d_bytes_per_block_legacy",
-        "gen_tps_resident",
-        "gen_tps_legacy",
-        "cpu_proxy",
     ),
     # The disaggregation A/B is only evidence as a PAIR: a record
     # carrying one arm's tail latency without the other cannot show the
@@ -352,51 +331,6 @@ def _validate_train_sharded(val: Dict) -> List[str]:
             f"train_sharded: dump host high-water frac {frac:.3f} does "
             f"not show the ~1/mesh_size reduction (expected <= 0.75 on "
             f"a 2-device mesh)"
-        )
-    return problems
-
-
-# Numeric keys every train_tflops_scaling curve point must carry: a
-# record without per-point per-chip throughput is not a scaling curve.
-SCALING_POINT_KEYS = ("n_devices", "step_s", "train_tflops_per_chip")
-
-
-def _validate_scaling_points(val: Dict) -> List[str]:
-    problems: List[str] = []
-    points = val.get("points")
-    if not isinstance(points, list) or not points:
-        return [
-            "train_tflops_scaling: measure value must carry a "
-            "non-empty 'points' curve"
-        ]
-    prev_n = 0.0
-    for i, pt in enumerate(points):
-        if not isinstance(pt, dict):
-            problems.append(
-                f"train_tflops_scaling: points[{i}] is not an object"
-            )
-            continue
-        for k in SCALING_POINT_KEYS:
-            if not isinstance(pt.get(k), (int, float)) or isinstance(
-                pt.get(k), bool
-            ):
-                problems.append(
-                    f"train_tflops_scaling: points[{i}] missing "
-                    f"numeric {k!r}"
-                )
-        n = pt.get("n_devices")
-        if isinstance(n, (int, float)):
-            if n <= prev_n:
-                problems.append(
-                    f"train_tflops_scaling: points[{i}] n_devices "
-                    f"{n} not increasing (curve must run 1 -> N)"
-                )
-            prev_n = float(n)
-    first_n = (points[0] or {}).get("n_devices")
-    if isinstance(first_n, (int, float)) and first_n != 1:
-        problems.append(
-            "train_tflops_scaling: curve must start at n_devices == 1 "
-            "(the per-chip baseline every other point is judged against)"
         )
     return problems
 
@@ -845,133 +779,6 @@ def _validate_recovery_slo(val: Dict) -> List[str]:
     return problems
 
 
-# Parity ceiling for kernel_micro cases: impls reassociate float32
-# sums, so agreement is ~1e-7..1e-6 relative (ops/gae docstring); a
-# case past this diverged, it didn't round.
-KMICRO_PARITY_MAX = 1e-4
-# Noise allowance on the optimized-not-slower tooth. When 'auto'
-# resolves to the baseline impl the phase banks the SAME measurement
-# for both arms (speedup exactly 1.0), so this margin only ever absorbs
-# genuine run-to-run jitter of a genuinely different kernel.
-KMICRO_SLOWDOWN_MAX = 1.10
-
-KMICRO_CASE_PHASES = (
-    "kernel_micro_gae", "kernel_micro_paged_decode", "kernel_micro_splash",
-)
-
-
-def _validate_kmicro_cases(name: str, val: Dict) -> List[str]:
-    """The kernel_micro contract: every case carries its parity number,
-    and a case timed as evidence must not show the optimized path
-    SLOWER than its baseline — that record is a regression, not
-    evidence (the tooth the tentpole issue mandates)."""
-    problems: List[str] = []
-    cases = val.get("cases")
-    if not isinstance(cases, list) or not cases:
-        return [f"{name}: measure value must carry a non-empty 'cases' list"]
-    for i, c in enumerate(cases):
-        if not isinstance(c, dict):
-            problems.append(f"{name}: cases[{i}] is not an object")
-            continue
-        tag = c.get("name", f"cases[{i}]")
-        for k in ("baseline_impl", "optimized_impl"):
-            if not isinstance(c.get(k), str):
-                problems.append(f"{name}: {tag} missing {k!r}")
-        par = _num(c, "parity_max_rel")
-        if par is None:
-            problems.append(
-                f"{name}: {tag} lacks numeric parity_max_rel — a timing "
-                f"without its parity check is not kernel evidence"
-            )
-        elif par > KMICRO_PARITY_MAX:
-            problems.append(
-                f"{name}: {tag} parity_max_rel {par:.2e} exceeds "
-                f"{KMICRO_PARITY_MAX:.0e} — the optimized kernel diverged"
-            )
-        timed = _num(c, "timed")
-        if timed is None:
-            problems.append(f"{name}: {tag} missing numeric 'timed' flag")
-            continue
-        if timed:
-            base, opt = _num(c, "baseline_ms"), _num(c, "optimized_ms")
-            if base is None or opt is None or _num(c, "speedup") is None:
-                problems.append(
-                    f"{name}: {tag} is timed but lacks "
-                    f"baseline_ms/optimized_ms/speedup"
-                )
-            elif opt > base * KMICRO_SLOWDOWN_MAX:
-                problems.append(
-                    f"{name}: {tag} optimized path ({opt:.3f} ms) is "
-                    f"slower than its baseline ({base:.3f} ms) — refusing "
-                    f"a regression as evidence"
-                )
-    return problems
-
-
-def _validate_kmicro_labeling(name: str, rec: Dict) -> List[str]:
-    """CPU-proxy labeling, cross-checked against the record's OWN
-    attestation: a non-driver-verified kernel_micro record must stamp
-    itself cpu_proxy/evidence=proxy, and a driver-verified one must
-    not — the round-6 conflation mandate applied per record."""
-    att = rec.get("attestation")
-    if not isinstance(att, dict):
-        return []  # bare value dicts (unit tests); bank records always attest
-    val = rec.get("value") or {}
-    dv = bool(att.get("driver_verified"))
-    proxy = _num(val, "cpu_proxy")
-    problems: List[str] = []
-    if not dv:
-        if proxy != 1:
-            problems.append(
-                f"{name}: non-driver-verified record lacks cpu_proxy=1"
-            )
-        if val.get("evidence") != "proxy":
-            problems.append(
-                f"{name}: non-driver-verified record is not labeled "
-                f"evidence: proxy"
-            )
-    else:
-        if proxy not in (None, 0):
-            problems.append(
-                f"{name}: driver-verified record claims cpu_proxy"
-            )
-        if val.get("evidence") == "proxy":
-            problems.append(
-                f"{name}: driver-verified record mislabeled evidence: proxy"
-            )
-    return problems
-
-
-def _validate_decode_state(val: Dict) -> List[str]:
-    """The decode-state A/B contract: token parity is non-negotiable
-    (a faster engine emitting different tokens is a broken engine), and
-    the resident arm must actually reduce per-block host staging —
-    that reduction IS the phase's claim."""
-    problems: List[str] = []
-    if _num(val, "token_parity_ok") != 1:
-        problems.append(
-            "kernel_micro_decode_state: resident/legacy greedy tokens "
-            "diverged (or parity missing) — refusing"
-        )
-    res = _num(val, "h2d_per_block_resident")
-    leg = _num(val, "h2d_per_block_legacy")
-    if res is not None and leg is not None and res >= leg:
-        problems.append(
-            f"kernel_micro_decode_state: resident arm stages "
-            f"{res:.2f} transfers/block, not below the legacy "
-            f"{leg:.2f} — the optimization is not engaged"
-        )
-    bres = _num(val, "h2d_bytes_per_block_resident")
-    bleg = _num(val, "h2d_bytes_per_block_legacy")
-    if bres is not None and bleg is not None and bres > bleg * 1.10:
-        problems.append(
-            f"kernel_micro_decode_state: resident arm stages "
-            f"{bres:.0f} bytes/block vs legacy {bleg:.0f} — the delta "
-            f"path is moving MORE data than the full restage"
-        )
-    return problems
-
-
 def _validate_moe_scaling(val: Dict) -> List[str]:
     """The MoE fast-path contract: EP and no-drop-capacity loss
     trajectories must MATCH dropless-EP1 (parity-missing records are
@@ -1051,16 +858,8 @@ def validate_phase_value(name: str, rec: Dict) -> List[str]:
         )
     if name == "train_sharded":
         problems.extend(_validate_train_sharded(val))
-    if name == "train_tflops_scaling":
-        problems.extend(_validate_scaling_points(val))
     if name == "moe_scaling":
         problems.extend(_validate_moe_scaling(val))
-    if name == "train_tflops" and not isinstance(
-        val.get("mesh_shape"), dict
-    ):
-        problems.append(
-            "train_tflops: measure value missing the 'mesh_shape' dict"
-        )
     if name == "weight_plane_sharded":
         problems.extend(_validate_sharded_plane(val))
     if name == "serving_openloop":
@@ -1079,12 +878,6 @@ def validate_phase_value(name: str, rec: Dict) -> List[str]:
         problems.extend(_validate_agentic_rollout(val))
     if name == "recovery_slo":
         problems.extend(_validate_recovery_slo(val))
-    if name in KMICRO_CASE_PHASES:
-        problems.extend(_validate_kmicro_cases(name, val))
-    if name == "kernel_micro_decode_state":
-        problems.extend(_validate_decode_state(val))
-    if name.startswith("kernel_micro_"):
-        problems.extend(_validate_kmicro_labeling(name, rec))
     if name == "serving_disagg":
         failed = val.get("disagg_failed")
         if isinstance(failed, (int, float)) and failed > 0:

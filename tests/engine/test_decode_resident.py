@@ -10,8 +10,7 @@ admission/eviction deltas pay H2D. These tests pin:
   kept verbatim behind the knob) across chunked prefill, prefix-cache
   resubmission, and multi-round admission;
 - the measured reduction itself: per-decode-block H2D transfer count
-  strictly below legacy on a chunked workload (the evidence the
-  kernel_micro_decode_state phase banks);
+  strictly below legacy on a chunked workload;
 - unit semantics of the fused row scatter and the packed chunk-prefill
   entry point against their legacy equivalents.
 

@@ -2,8 +2,8 @@
 engine with a reduced KV pool (VERDICT r3 missing #4 — the reference's
 headline workload generates ~31k-token sequences,
 benchmark/verl_v0_3_0_post1_76084d3/README.md:38-44; this CPU test keeps
-the >=16k path from rotting while the on-chip numbers live in
-docs/perf_notes.md)."""
+the >=16k path from rotting; no cell measures it on the chip yet:
+PERF.md section 7)."""
 
 import threading
 

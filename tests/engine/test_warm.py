@@ -1,19 +1,12 @@
-"""AOT warm hooks: compile without perturbing state (train engine) /
-compile the serving programs through the live loop (serving engine).
-These back the bench compile pass (docs/benchmarking.md) and
-`warm_on_start` serving pods."""
+"""The serving engine's warm hook: compile the serving programs through
+the live loop. Backs `warm_on_start` serving pods."""
 
-import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
 
-from areal_tpu.api.data_api import MicroBatchSpec, SequenceSample
-from areal_tpu.engine.jax_engine import JaxTrainEngine
-from areal_tpu.engine.optimizer import OptimizerConfig
 from areal_tpu.models.config import TransformerConfig
 from areal_tpu.models.transformer import init_params
-from areal_tpu.ops.loss import sft_loss_from_logprobs
 
 
 def _tiny_cfg():
@@ -21,69 +14,6 @@ def _tiny_cfg():
         n_layers=2, hidden_dim=64, n_q_heads=4, n_kv_heads=2, head_dim=16,
         intermediate_dim=128, vocab_size=256, compute_dtype="float32",
     )
-
-
-def _batch(cfg, seqlen=64, n_seqs=4, seed=0):
-    rng = np.random.RandomState(seed)
-    total = seqlen * n_seqs
-    return SequenceSample.from_default(
-        ids=[f"b{i}" for i in range(n_seqs)],
-        seqlens=[seqlen] * n_seqs,
-        data={
-            "packed_input_ids": rng.randint(0, cfg.vocab_size, size=total),
-            "loss_mask": np.ones(total, np.float32),
-        },
-    )
-
-
-def _loss(lp, rows):
-    tot, n = sft_loss_from_logprobs(lp, rows["loss_mask"])
-    return tot, {}
-
-
-def _weight(mb):
-    return float(np.sum(mb.data["loss_mask"]))
-
-
-def test_train_warm_compiles_without_touching_state():
-    cfg = _tiny_cfg()
-    eng = JaxTrainEngine(
-        cfg, init_params(cfg, jax.random.PRNGKey(0)),
-        optimizer_config=OptimizerConfig(lr=1e-3, warmup_steps_proportion=0.0),
-        total_train_steps=10, row_len_multiple=64, max_row_len=64,
-    )
-    batch = _batch(cfg)
-    before = jax.tree_util.tree_map(np.asarray, eng.params)
-    dt = eng.warm(batch, MicroBatchSpec(n_mbs=1), _loss, loss_name="bench")
-    assert dt >= 0.0
-    after = jax.tree_util.tree_map(np.asarray, eng.params)
-    for a, b in zip(jax.tree_util.tree_leaves(before),
-                    jax.tree_util.tree_leaves(after)):
-        np.testing.assert_array_equal(a, b)  # AOT: no step executed
-    # The warmed engine trains normally (and identically to a cold one).
-    cold = JaxTrainEngine(
-        cfg, init_params(cfg, jax.random.PRNGKey(0)),
-        optimizer_config=OptimizerConfig(lr=1e-3, warmup_steps_proportion=0.0),
-        total_train_steps=10, row_len_multiple=64, max_row_len=64,
-    )
-    s_warm = eng.train_batch(batch, MicroBatchSpec(n_mbs=1), _loss, _weight,
-                             loss_name="bench")
-    s_cold = cold.train_batch(batch, MicroBatchSpec(n_mbs=1), _loss, _weight,
-                              loss_name="bench")
-    assert s_warm["bench/loss"] == pytest.approx(s_cold["bench/loss"],
-                                                 rel=1e-5)
-
-
-def test_train_warm_multi_microbatch_shapes():
-    cfg = _tiny_cfg()
-    eng = JaxTrainEngine(
-        cfg, init_params(cfg, jax.random.PRNGKey(0)),
-        optimizer_config=OptimizerConfig(lr=1e-3, warmup_steps_proportion=0.0),
-        total_train_steps=10, row_len_multiple=64, max_row_len=64,
-    )
-    eng.warm(_batch(cfg, n_seqs=8), MicroBatchSpec(n_mbs=4), _loss)
-    eng.train_batch(_batch(cfg, n_seqs=8), MicroBatchSpec(n_mbs=4),
-                    _loss, _weight)
 
 
 def test_serving_warm_compiles_then_serves():

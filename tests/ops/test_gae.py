@@ -178,8 +178,7 @@ def test_pallas_shape_gate():
 
 
 def test_dispatcher_resolution_and_knob_default():
-    """'auto' resolves to the associative scan (the measured default;
-    kernel_micro_gae banks the ongoing evidence), explicit impls pass
+    """'auto' resolves to the associative scan, explicit impls pass
     through, unknown ones are refused, and the registered knob default
     is 'auto' so the PPO interface dispatches without env plumbing."""
     from areal_tpu.base import env_registry

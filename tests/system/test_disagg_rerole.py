@@ -202,12 +202,14 @@ def test_disagg_pairing_handoff_and_trace(tmp_path, monkeypatch):
         assert dec.engine.kv_imports == 2
 
         # Manager /status: pools surface with roles, pool membership,
-        # and the fleet handoff totals (after a metrics poll cycle).
-        _wait_until(
-            lambda: _get_json(mgr.address + "/status")["pools"][
-                "kv_handoff"]["imports"] >= 1,
-            30, "kv handoff totals on /status",
-        )
+        # and the fleet handoff totals (after a metrics poll of BOTH
+        # servers: imports come from the decode server's /metrics,
+        # export bytes from the prefill server's).
+        def _totals_in():
+            kv = _get_json(mgr.address + "/status")["pools"]["kv_handoff"]
+            return kv["imports"] >= 1 and kv["export_bytes"] > 0
+
+        _wait_until(_totals_in, 30, "kv handoff totals on /status")
         st = _get_json(mgr.address + "/status")
         assert st["pools"]["roles"][pre.address] == "prefill"
         assert st["pools"]["roles"][dec.address] == "decode"
