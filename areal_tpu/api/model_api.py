@@ -81,10 +81,13 @@ class TrainEngine(abc.ABC):
         token_normalize_scope: str = "global",
         version_steps: Optional[int] = None,
         loss_name: str = "loss",
+        scored_fn: Any = None,
     ) -> Dict[str, float]:
         """Run forward+backward+update over micro-batches; returns host
         stats. `version_steps` positions the LR schedule (None = the
-        engine's own step count); see JaxTrainEngine.train_batch."""
+        engine's own step count); `scored_fn(rows)` names the positions
+        whose logprob `loss_fn` reads (None = all); see
+        JaxTrainEngine.train_batch."""
 
     @abc.abstractmethod
     def forward(

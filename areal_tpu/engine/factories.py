@@ -192,7 +192,7 @@ class MockEngine(TrainEngine):
 
     def train_batch(self, input_, mb_spec, loss_fn, loss_weight_fn,
                     token_normalize_scope="global", version_steps=0,
-                    loss_name="loss"):
+                    loss_name="loss", scored_fn=None):
         self.n_train_calls += 1
         self.version += 1
         return {

@@ -43,7 +43,11 @@ from areal_tpu.models.generation import generate_tokens
 from areal_tpu.models.packing import PackedBatch, pack_sequences
 from areal_tpu.models.transformer import forward as model_forward
 from areal_tpu.ops.attention import attn_block_cells, attn_run_len
-from areal_tpu.ops.loss import fused_next_token_logprobs
+from areal_tpu.ops.loss import (
+    fused_next_token_logprobs,
+    head_cells_run,
+    response_scoring_mask,
+)
 from areal_tpu.engine.optimizer import (
     OptimizerConfig,
     make_lr_schedule,
@@ -55,6 +59,9 @@ from areal_tpu.parallel.sharding import batch_sharding, param_shardings
 logger = areal_logging.getLogger("jax_engine")
 
 PackedLossFn = Callable[[jnp.ndarray, Dict[str, jnp.ndarray]], Tuple[jnp.ndarray, Dict]]
+# rows -> [R, T], nonzero at the positions whose logprob the loss reads;
+# on device rows inside the step and on numpy rows for the host's counts.
+ScoredFn = Callable[[Dict[str, Any]], Any]
 
 
 def opt_state_shardings(opt_state, params, mesh):
@@ -362,13 +369,18 @@ class JaxTrainEngine(TrainEngine):
         tracing.count("train.programs_built")
         return programs
 
-    def _mb_loss_fn(self, loss_fn: PackedLossFn):
+    def _mb_loss_fn(self, loss_fn: PackedLossFn, scored_fn: Optional[ScoredFn]):
         """loss over one micro-batch's rows: (params, rows) -> (loss_sum, aux).
 
         Non-critic models run the forward to hidden states only and feed
         the loss the fused next-token logprobs; the [R, T, V] logits are
         never materialized (reference analogue: vocab-parallel fused CE,
         realhf/impl/model/parallelism/tensor_parallel/modules.py:1180).
+
+        `scored_fn(rows)` is `train_batch`'s: the caller of `train_batch`
+        names the positions whose logprob `loss_fn` reads, and the head
+        computes those alone (zeros elsewhere), each shard of the rows
+        over its own. None: the head runs over every valid position.
         """
         is_critic = self.model_cfg.is_critic
 
@@ -387,6 +399,8 @@ class JaxTrainEngine(TrainEngine):
                 out = fused_next_token_logprobs(
                     out, self._head_weight(p),
                     rows["input_ids"], rows["segment_ids"],
+                    scored=None if scored_fn is None else scored_fn(rows),
+                    mesh=self.mesh if self.mesh.size > 1 else None,
                 )
             loss_sum, aux = loss_fn(out, rows)
             if self.model_cfg.moe is not None:
@@ -451,7 +465,8 @@ class JaxTrainEngine(TrainEngine):
         return with_buffers(weights, params), opt_state, gnorm, unorm
 
     def _train_step_fn(self, loss_name: str, loss_fn: PackedLossFn,
-                       row_keys: Tuple[str, ...], n_mbs: int):
+                       row_keys: Tuple[str, ...], n_mbs: int,
+                       scored_fn: Optional[ScoredFn] = None):
         """One fused jitted program for the whole train step: micro-batch
         gradient accumulation (lax.scan over stacked rows), global-denom
         normalization, grad norm, optimizer update — with params and
@@ -461,11 +476,11 @@ class JaxTrainEngine(TrainEngine):
         fwd/bwd launches + separate optimizer step) keeps XLA free to
         overlap collectives and avoids any host round-trip inside a step.
         """
-        key = ("train", loss_name, row_keys, n_mbs > 1)
+        key = ("train", loss_name, row_keys, n_mbs > 1, scored_fn is not None)
         if key in self._jit_cache:
             return self._jit_cache[key]
 
-        mb_loss = self._mb_loss_fn(loss_fn)
+        mb_loss = self._mb_loss_fn(loss_fn, scored_fn)
 
         def step(params, opt_state, rows, inv_denom, lr):
             if n_mbs > 1:
@@ -521,18 +536,19 @@ class JaxTrainEngine(TrainEngine):
         return self._built(key, jax.jit(step, donate_argnums=(0, 1)))
 
     def _accum_step_fns(self, loss_name: str, loss_fn: PackedLossFn,
-                        row_keys: Tuple[str, ...]):
+                        row_keys: Tuple[str, ...],
+                        scored_fn: Optional[ScoredFn] = None):
         """Two jitted programs for the pipelined accumulation path:
         `first` computes micro-batch 0's fp32 (grads, loss_sum, aux)
         carry, `next` adds one micro-batch into a donated carry. Same
         per-mb math and left-to-right fp32 addition order as the fused
         scan body — the step's numerics must not depend on which path
         ran (see tests/engine/test_prefetch.py equivalence)."""
-        key = ("accum", loss_name, row_keys)
+        key = ("accum", loss_name, row_keys, scored_fn is not None)
         if key in self._jit_cache:
             return self._jit_cache[key]
 
-        mb_loss = self._mb_loss_fn(loss_fn)
+        mb_loss = self._mb_loss_fn(loss_fn, scored_fn)
 
         def to_f32(tree):
             with jax.named_scope("grad_accum"):
@@ -616,20 +632,11 @@ class JaxTrainEngine(TrainEngine):
         """Host-side per-token loss weights used to build the per-shard
         denominators for 'dp' normalization. Mirrors what the standard
         losses weight by: the shifted response mask for SFT/PPO batches
-        (interfaces/ppo.response_scoring_mask), or an explicit loss_mask."""
-        seg = np.asarray(rows_np["segment_ids"])
+        (ops/loss.response_scoring_mask), or an explicit loss_mask."""
         pm = rows_np.get("prompt_mask")
         if pm is not None:
-            pm = np.asarray(pm)
-            next_seg = np.concatenate(
-                [seg[..., 1:], np.zeros_like(seg[..., :1])], axis=-1
-            )
-            next_pm = np.concatenate(
-                [pm[..., 1:], np.ones_like(pm[..., :1])], axis=-1
-            )
-            return ((next_seg == seg) & (seg > 0) & (next_pm == 0)).astype(
-                np.float32
-            )
+            return response_scoring_mask(
+                np.asarray(rows_np["segment_ids"]), np.asarray(pm))
         lm = rows_np.get("loss_mask")
         if lm is None:
             raise ValueError(
@@ -688,6 +695,7 @@ class JaxTrainEngine(TrainEngine):
         version_steps: Optional[int] = None,
         loss_name: str = "loss",
         dp_token_weights_fn=None,
+        scored_fn: Optional[ScoredFn] = None,
     ) -> Dict[str, float]:
         """Forward+backward over micro-batches, one optimizer step, no
         host sync until the single packed-stats fetch at the end. Two
@@ -721,6 +729,17 @@ class JaxTrainEngine(TrainEngine):
         because every loss is linear in its per-token weights). D_s comes
         from `dp_token_weights_fn(rows)` when given, else from the
         standard response mask / loss_mask (_dp_token_weights).
+
+        `scored_fn(rows) -> [R, T]` (nonzero = read) is how the caller,
+        who wrote `loss_fn`, names the positions whose logprob it reads;
+        the loss head then computes those alone and hands `loss_fn`
+        zeros at the others, which it must give weight 0 (the PPO and
+        SFT interfaces pass `ops/loss.response_positions`, the mask
+        their losses multiply by). It is called on device rows inside
+        the step and on numpy rows for the counters
+        `train.scored_cells` / `train.head_cells`. None (the critic, any
+        caller that says nothing): every valid position, the program
+        this engine built before the argument existed.
         """
         assert self.optimizer is not None, "engine built without optimizer"
         self._ensure_loaded()
@@ -748,7 +767,7 @@ class JaxTrainEngine(TrainEngine):
                 if len(groups) > 1:
                     return self._train_batch_overlapped(
                         mb_iter, len(groups), loss_fn, loss_weight_fn, loss_name,
-                        lr,
+                        lr, scored_fn,
                     )
                 # One micro-batch: nothing to pipeline against; run eagerly.
                 mbs = list(mb_iter)
@@ -794,10 +813,12 @@ class JaxTrainEngine(TrainEngine):
             self._record_overlap_stats()
             rows, row_len = rows_np["input_ids"].shape[-2:]
             attn = self._attn_counts(rows_np["segment_ids"])
-            self._count_batch("fused", len(mbs), n_tok, n_cells, *attn[1:])
+            self._count_batch("fused", len(mbs), n_tok, n_cells, *attn[1:],
+                              *self._head_counts(rows_np, scored_fn))
 
             step = self._train_step_fn(
-                loss_name, loss_fn, tuple(sorted(rows_np.keys())), len(mbs)
+                loss_name, loss_fn, tuple(sorted(rows_np.keys())), len(mbs),
+                scored_fn,
             )
             with tracing.span(
                 "train.dispatch", kind="fused", rows=rows, row_len=row_len,
@@ -822,6 +843,7 @@ class JaxTrainEngine(TrainEngine):
         loss_weight_fn: Callable[[SequenceSample], float],
         loss_name: str,
         lr: float,
+        scored_fn: Optional[ScoredFn] = None,
     ) -> Dict[str, float]:
         """Pipelined gradient accumulation: a background thread FFD-packs,
         pads-to-bucket and `device_put`s micro-batch i+1 while micro-batch
@@ -848,8 +870,9 @@ class JaxTrainEngine(TrainEngine):
                     }
                 cells = batch.n_rows * batch.row_len
                 tracing.set_attrs(tokens=batch.total_tokens, cells=cells)
-                attn = self._attn_counts(rows["segment_ids"])
-            return rows_dev, denom, batch.total_tokens, cells, attn
+                counts = (self._attn_counts(rows["segment_ids"])
+                          + self._head_counts(rows, scored_fn))
+            return rows_dev, denom, batch.total_tokens, cells, counts
 
         pf = HostPrefetcher(
             mb_iter, stage, depth=self.prefetch_depth, name=f"train/{loss_name}",
@@ -858,21 +881,24 @@ class JaxTrainEngine(TrainEngine):
         carry = None
         nxt = None
         denom_sum, n_tok, n_cells = 0.0, 0, 0
-        n_attn = [0, 0, 0]  # cells at the run length, run, causal
+        # attention's cells at the run length, run, causal; the head's
+        # positions read, cells run
+        n_counts = [0, 0, 0, 0, 0]
         gaps_ms: List[float] = []
         mark = time.monotonic_ns()
         try:
-            for rows_dev, denom, tok, cells, attn in pf:
+            for rows_dev, denom, tok, cells, counts in pf:
                 gaps_ms.append((time.monotonic_ns() - mark) / 1e6)
                 denom_sum += denom
                 n_tok += tok
                 n_cells += cells
                 rows, row_len = rows_dev["input_ids"].shape
-                attn_row_len = attn[0]
-                n_attn = [n + c for n, c in zip(n_attn, attn[1:])]
+                attn_row_len = counts[0]
+                n_counts = [n + c for n, c in zip(n_counts, counts[1:])]
                 if carry is None:
                     first, nxt = self._accum_step_fns(
-                        loss_name, loss_fn, tuple(sorted(rows_dev.keys()))
+                        loss_name, loss_fn, tuple(sorted(rows_dev.keys())),
+                        scored_fn,
                     )
                     with tracing.span("train.dispatch", kind="first",
                                       rows=rows, row_len=row_len,
@@ -896,7 +922,7 @@ class JaxTrainEngine(TrainEngine):
                 jnp.asarray(1.0 / global_denom, jnp.float32),
                 jnp.asarray(lr, jnp.float32),
             )
-        self._count_batch("overlapped", n_mbs, n_tok, n_cells, *n_attn)
+        self._count_batch("overlapped", n_mbs, n_tok, n_cells, *n_counts)
         self.last_overlap = {
             "packing_efficiency": n_tok / max(n_cells, 1),
             "h2d_wait_ms": pf.wait_ms,
@@ -936,14 +962,36 @@ class JaxTrainEngine(TrainEngine):
                 int(sum(cells[w][0] for w in windows)),
                 int(sum(cells[w][1] for w in windows)))
 
+    def _head_counts(self, rows_np: Dict[str, np.ndarray],
+                     scored_fn: Optional[ScoredFn]) -> Tuple[int, int]:
+        """What the loss head does with packed rows (on the host, before
+        the transfer; [R, T] arrays of one micro-batch or [n, R, T] of
+        several): (the positions whose logprob the loss reads, the cells
+        of the chunks the head runs its logits tile over), by the
+        device's own rule (ops/loss.head_cells_run). A critic has no
+        such head."""
+        if self.model_cfg.is_critic:
+            return 0, 0
+        seg = np.asarray(rows_np["segment_ids"])
+        mbs = seg.reshape((-1,) + seg.shape[-2:])
+        scored = ([None] * len(mbs) if scored_fn is None
+                  else np.asarray(scored_fn(rows_np)).reshape(mbs.shape))
+        counts = [head_cells_run(mb, s, self.model_cfg.vocab_size,
+                                 self._n_row_multiple)
+                  for mb, s in zip(mbs, scored)]
+        return tuple(int(x) for x in np.sum(counts, axis=0))
+
     def _count_batch(self, path: str, n_mbs: int, n_tok: int, n_cells: int,
-                     n_attn_cells: int, n_attn_active: int, n_attn_causal: int):
+                     n_attn_cells: int, n_attn_active: int, n_attn_causal: int,
+                     n_scored: int, n_head_cells: int):
         """What one train_batch did, on its `train.batch` span and in the
         recorder's counters: real tokens, the cells (rows x row length)
         they were padded to, the cells the attention kernel ran (rows
         x the length it ran them at), the cells of the block pairs it ran
-        against those of a causal mask alone, and the (token, expert)
-        pairs the routers of the expert layers made."""
+        against those of a causal mask alone, the positions whose logprob
+        the loss reads and the cells of the chunks the loss head ran for
+        them, and the (token, expert) pairs the routers of the expert
+        layers made."""
         tracing.set_attrs(path=path, n_mbs=n_mbs, tokens=n_tok, cells=n_cells)
         tracing.count("train.batches")
         tracing.count("train.micro_batches", n_mbs)
@@ -952,6 +1000,9 @@ class JaxTrainEngine(TrainEngine):
         tracing.count("train.attn_cells", n_attn_cells)
         tracing.count("train.attn_active_cells", n_attn_active)
         tracing.count("train.attn_causal_cells", n_attn_causal)
+        if not self.model_cfg.is_critic:
+            tracing.count("train.scored_cells", n_scored)
+            tracing.count("train.head_cells", n_head_cells)
         moe = self.model_cfg.moe
         if moe is not None:
             tracing.count("train.moe_pairs",

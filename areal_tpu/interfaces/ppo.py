@@ -40,19 +40,13 @@ from areal_tpu.base import stats_tracker, tracing
 from areal_tpu.interfaces import functional as F
 from areal_tpu.base import env_registry
 from areal_tpu.ops.gae import packed_gae
-from areal_tpu.ops.loss import masked_normalization
+from areal_tpu.ops.loss import (
+    masked_normalization,
+    response_positions,
+    response_scoring_mask,
+)
 
 logger = areal_logging.getLogger("ppo")
-
-
-def response_scoring_mask(segment_ids, prompt_mask):
-    """[R, T] 1.0 where position t scores a response token (t+1)."""
-    seg = segment_ids
-    next_seg = jnp.concatenate([seg[:, 1:], jnp.zeros_like(seg[:, :1])], axis=1)
-    next_pm = jnp.concatenate(
-        [prompt_mask[:, 1:], jnp.ones_like(prompt_mask[:, :1])], axis=1
-    )
-    return ((next_seg == seg) & (seg > 0) & (next_pm == 0)).astype(jnp.float32)
 
 
 def last_response_position_mask(resp_mask):
@@ -394,6 +388,7 @@ class PPOActorInterface(ModelInterface):
                         loss_fn=actor_loss, loss_weight_fn=weight_fn,
                         token_normalize_scope=self.token_normalize_scope,
                         version_steps=model.version, loss_name="ppo_actor",
+                        scored_fn=response_positions,
                     )
                 all_stats.append(st)
             model.inc_version()

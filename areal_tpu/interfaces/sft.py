@@ -11,17 +11,14 @@ import numpy as np
 from areal_tpu.api.data_api import MicroBatchSpec, SequenceSample
 from areal_tpu.api.model_api import Model, ModelInterface, register_interface
 from areal_tpu.base import stats_tracker
+from areal_tpu.ops.loss import response_positions
 
 
 def sft_row_loss(lp, rows):
     """Next-token CE over response tokens (prompt_mask == 1 marks prompts).
 
     `lp` is the engine-supplied fused next-token logprobs [R, T]."""
-    seg = rows["segment_ids"]
-    pm = rows["prompt_mask"]
-    next_seg = jnp.concatenate([seg[:, 1:], jnp.zeros_like(seg[:, :1])], axis=1)
-    next_pm = jnp.concatenate([pm[:, 1:], jnp.ones_like(pm[:, :1])], axis=1)
-    mask = ((next_seg == seg) & (seg > 0) & (next_pm == 0)).astype(jnp.float32)
+    mask = response_positions(rows)
     n_tokens = jnp.sum(mask)
     if "dp_loss_scale" in rows:
         # Engine-injected per-shard normalization scale
@@ -61,6 +58,7 @@ class SFTInterface(ModelInterface):
             token_normalize_scope=self.token_normalize_scope,
             version_steps=model.version,
             loss_name="sft",
+            scored_fn=response_positions,
         )
         model.inc_version()
         stats_tracker.scalar(**stats)

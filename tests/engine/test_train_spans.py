@@ -109,7 +109,10 @@ def test_attributes_and_counters_count_tokens_and_cells(recorded):
         "train.tokens": a["tokens"], "train.cells": a["cells"],
         "train.attn_cells": a["cells"],
         "train.attn_active_cells": all_cells,
-        "train.attn_causal_cells": all_cells}
+        "train.attn_causal_cells": all_cells,
+        # no `scored_fn`: the head reads every token but a sequence's
+        # last (9 sequences) and runs over every cell
+        "train.scored_cells": a["tokens"] - 9, "train.head_cells": a["cells"]}
     assert all("window" not in d and "kinds" not in d for d in dispatches)
     kinds = [s["attrs"]["kind"] for s in spans if s["name"] == "train.dispatch"]
     assert kinds == (["first"] + ["next"] * (N_MBS - 1) if path == "overlapped"
