@@ -122,11 +122,17 @@ def with_buffers(new, old):
 
 def _kinds_label(cfg: TransformerConfig) -> str:
     """The stack's layer kinds in order, runs of equal kinds folded:
-    `dense.w2048.rope,moe.w2048.rope x2,moe.full.nope`."""
-    names = [
-        f"{k.mlp}.{'full' if k.window is None else 'w%d' % k.window}."
-        f"{'rope' if k.rotary else 'nope'}" for k in cfg.kinds()
-    ]
+    `dense.w2048.rope,moe.w2048.rope x2,moe.full.nope` for transformer
+    blocks (named by their MLP); a layer of one part is `ssm`, `moe`,
+    `dense` or `attn.full.nope`."""
+    def name(k):
+        attn = (f"{'full' if k.window is None else 'w%d' % k.window}."
+                f"{'rope' if k.rotary else 'nope'}")
+        if k.block:
+            return f"{k.mlp}.{attn}"
+        return f"attn.{attn}" if k.mixer == "attention" else k.parts
+
+    names = [name(k) for k in cfg.kinds()]
     out = []
     for n in names:
         if out and out[-1][0] == n:
@@ -814,7 +820,8 @@ class JaxTrainEngine(TrainEngine):
             rows, row_len = rows_np["input_ids"].shape[-2:]
             attn = self._attn_counts(rows_np["segment_ids"])
             self._count_batch("fused", len(mbs), n_tok, n_cells, *attn[1:],
-                              *self._head_counts(rows_np, scored_fn))
+                              *self._head_counts(rows_np, scored_fn),
+                              *self._ssm_counts(rows_np["segment_ids"]))
 
             step = self._train_step_fn(
                 loss_name, loss_fn, tuple(sorted(rows_np.keys())), len(mbs),
@@ -871,7 +878,8 @@ class JaxTrainEngine(TrainEngine):
                 cells = batch.n_rows * batch.row_len
                 tracing.set_attrs(tokens=batch.total_tokens, cells=cells)
                 counts = (self._attn_counts(rows["segment_ids"])
-                          + self._head_counts(rows, scored_fn))
+                          + self._head_counts(rows, scored_fn)
+                          + self._ssm_counts(rows["segment_ids"]))
             return rows_dev, denom, batch.total_tokens, cells, counts
 
         pf = HostPrefetcher(
@@ -882,8 +890,9 @@ class JaxTrainEngine(TrainEngine):
         nxt = None
         denom_sum, n_tok, n_cells = 0.0, 0, 0
         # attention's cells at the run length, run, causal; the head's
-        # positions read, cells run
-        n_counts = [0, 0, 0, 0, 0]
+        # positions read, cells run; the state-space scan's chunks, live,
+        # mixed, and its resets
+        n_counts = [0] * 9
         gaps_ms: List[float] = []
         mark = time.monotonic_ns()
         try:
@@ -953,7 +962,7 @@ class JaxTrainEngine(TrainEngine):
             mesh=self.mesh if self.mesh.size > 1 else None,
         )
         run_len = attn_run_len(t=row_len, r=rows, **shape)
-        windows = [k.window for k in cfg.kinds()]
+        windows = [k.window for k in cfg.kinds() if k.mixer == "attention"]
         # One count a window, not one a layer.
         cells = {w: np.sum([attn_block_cells(segment_ids=mb, window=w, **shape)
                             for mb in mbs], axis=0)
@@ -961,6 +970,21 @@ class JaxTrainEngine(TrainEngine):
         return (run_len, len(mbs) * rows * run_len,
                 int(sum(cells[w][0] for w in windows)),
                 int(sum(cells[w][1] for w in windows)))
+
+    def _ssm_counts(self, segment_ids: np.ndarray) -> Tuple[int, int, int, int]:
+        """What the state-space layers' chunked scan does with packed rows
+        (on the host, before the transfer; `segment_ids` [R, T] of one
+        micro-batch or [n, R, T] of several), by the device's own rule
+        (ops/ssm.chunk_counts), summed over those layers: (the chunks it
+        runs, those that hold a token, those that hold a sequence start
+        after their first cell, sequence starts)."""
+        n = self.model_cfg.n_ssm_layers
+        if not n:
+            return 0, 0, 0, 0
+        from areal_tpu.ops.ssm import chunk_counts
+
+        return tuple(n * c for c in chunk_counts(
+            segment_ids, self.model_cfg.ssm.chunk_size))
 
     def _head_counts(self, rows_np: Dict[str, np.ndarray],
                      scored_fn: Optional[ScoredFn]) -> Tuple[int, int]:
@@ -983,15 +1007,16 @@ class JaxTrainEngine(TrainEngine):
 
     def _count_batch(self, path: str, n_mbs: int, n_tok: int, n_cells: int,
                      n_attn_cells: int, n_attn_active: int, n_attn_causal: int,
-                     n_scored: int, n_head_cells: int):
+                     n_scored: int, n_head_cells: int, n_ssm_chunks: int = 0,
+                     n_ssm_live: int = 0, n_ssm_mixed: int = 0, n_ssm_resets: int = 0):
         """What one train_batch did, on its `train.batch` span and in the
         recorder's counters: real tokens, the cells (rows x row length)
         they were padded to, the cells the attention kernel ran (rows
         x the length it ran them at), the cells of the block pairs it ran
         against those of a causal mask alone, the positions whose logprob
         the loss reads and the cells of the chunks the loss head ran for
-        them, and the (token, expert) pairs the routers of the expert
-        layers made."""
+        them, the (token, expert) pairs the routers of the expert
+        layers made, and the chunks the state-space layers' scan ran."""
         tracing.set_attrs(path=path, n_mbs=n_mbs, tokens=n_tok, cells=n_cells)
         tracing.count("train.batches")
         tracing.count("train.micro_batches", n_mbs)
@@ -1007,6 +1032,11 @@ class JaxTrainEngine(TrainEngine):
         if moe is not None:
             tracing.count("train.moe_pairs",
                           moe.top_k * n_tok * self.model_cfg.n_moe_layers)
+        if self.model_cfg.n_ssm_layers:
+            tracing.count("train.ssm_chunks", n_ssm_chunks)
+            tracing.count("train.ssm_chunks_live", n_ssm_live)
+            tracing.count("train.ssm_chunks_mixed", n_ssm_mixed)
+            tracing.count("train.ssm_resets", n_ssm_resets)
 
     def _record_overlap_stats(self):
         """Ship the last pipeline's telemetry through the stats tracker so

@@ -51,11 +51,20 @@ def make_lr_schedule(cfg: OptimizerConfig, total_train_steps: int):
     )
 
 
+# A state-space mixer's per-head and per-channel parameters (ops/ssm.py):
+# stacked on a layer axis they have two dimensions and are no matrices.
+NO_DECAY_LEAVES = ("A_log", "D", "dt_bias", "conv_b")
+
+
 def _decay_mask(params):
-    """No weight decay on 1D params (norms, biases) — standard practice."""
+    """No weight decay on 1D params (norms, biases) — standard practice —
+    nor, by leaf name, on a state-space mixer's decay rates, skip
+    weights, step biases and convolution bias."""
     import jax
 
-    return jax.tree_util.tree_map(lambda p: p.ndim > 1, params)
+    return jax.tree_util.tree_map_with_path(
+        lambda path, p: p.ndim > 1 and getattr(path[-1], "key", None) not in NO_DECAY_LEAVES,
+        params)
 
 
 def make_optimizer(

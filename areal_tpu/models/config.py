@@ -4,15 +4,17 @@ Counterpart of the reference's ReaLModelConfig (realhf/api/core/model_api.py:340
 covering the same architecture space: GQA attention, rotary variants,
 RMS/LayerNorm, gated MLPs, optional MoE, actor (LM head) or critic (scalar
 head) outputs, tied embeddings, and qk-norm (qwen3); and, beyond it, a
-kind per layer (`LayerKind`: MLP, attention window, rotary), an attention
-output gate, post-norms, a sigmoid router with a selection bias, shared
-experts and a share of the experts held here (afmoe).
+kind per layer (`LayerKind`: the parts a layer has: a mixer, attention
+with its window and rotary or a state-space mixer, and an MLP, dense or
+expert, either of which may be absent), an attention output gate,
+post-norms, a sigmoid router with a selection bias, shared experts and a
+share of the experts held here.
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 
 @dataclasses.dataclass
@@ -56,6 +58,9 @@ class MoEConfig:
     # expert width that every token passes through, added to the routed
     # result.
     n_shared_experts: int = 0
+    # The shared MLP's width where the config states it; None = the
+    # expert width times n_shared_experts.
+    shared_intermediate_dim: Optional[int] = None
     # (first, count): the contiguous range of experts whose weights this
     # chip holds, as one share of an expert-parallel layer. The router
     # keeps all `num_experts` outputs and its top-k; the layer computes
@@ -96,23 +101,121 @@ class MoEConfig:
         return self.experts_held[1] if self.experts_held else self.num_experts
 
 
+@dataclasses.dataclass
+class SSMConfig:
+    """A state-space mixer (Mamba-2 form, ops/ssm.py): `n_heads` heads of
+    `head_dim` channels, each with a state of `state_dim`, whose B and C
+    are shared by the heads of one of `n_groups` groups; a causal
+    depthwise convolution of `conv_kernel` taps before it; computed in
+    chunks of `chunk_size` positions."""
+
+    n_heads: int = 8
+    head_dim: int = 16
+    n_groups: int = 1
+    state_dim: int = 16
+    conv_kernel: int = 4
+    chunk_size: int = 128
+    conv_bias: bool = True
+    # The step size's initial range: dt_bias is the inverse softplus of a
+    # log-uniform draw from [dt_min, dt_max], floored at dt_floor.
+    dt_min: float = 0.001
+    dt_max: float = 0.1
+    dt_floor: float = 1e-4
+
+    def __post_init__(self):
+        if self.n_heads % self.n_groups != 0:
+            raise ValueError("SSMConfig.n_heads must be a multiple of n_groups")
+
+    @property
+    def d_inner(self) -> int:
+        return self.n_heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels the convolution runs over: x, B and C."""
+        return self.d_inner + 2 * self.n_groups * self.state_dim
+
+    @property
+    def in_proj_dim(self) -> int:
+        """[z | xBC | dt]."""
+        return self.d_inner + self.conv_dim + self.n_heads
+
+
 @dataclasses.dataclass(frozen=True)
 class LayerKind:
     """What one layer of the stack is, known when the program is traced:
-    its MLP ("dense" or "moe": different parameter shapes), its attention
-    mask (`window` = how many positions back a token sees, itself
-    included; None = all of its sequence) and whether q and k get the
-    rotary embedding (False = no position encoding in this layer)."""
+    the parts it has, each under its own norm with its own residual. A
+    mixer (`mixer`: "attention", "ssm" or None) and an MLP (`mlp`:
+    "dense", "moe" or None); a transformer block has both, a layer may
+    have one. For attention: its mask (`window` = how many positions
+    back a token sees, itself included; None = all of its sequence) and
+    whether q and k get the rotary embedding (False = no position
+    encoding in this layer)."""
 
-    mlp: str = "dense"
+    mlp: Optional[str] = "dense"
     window: Optional[int] = None
     rotary: bool = True
+    mixer: Optional[str] = "attention"
 
     def __post_init__(self):
-        if self.mlp not in ("dense", "moe"):
-            raise ValueError(f"LayerKind.mlp must be 'dense' or 'moe', got {self.mlp!r}")
+        if self.mlp not in ("dense", "moe", None):
+            raise ValueError(
+                f"LayerKind.mlp must be 'dense', 'moe' or None, got {self.mlp!r}")
+        if self.mixer not in ("attention", "ssm", None):
+            raise ValueError(
+                f"LayerKind.mixer must be 'attention', 'ssm' or None, got {self.mixer!r}")
+        if self.mixer is None and self.mlp is None:
+            raise ValueError("a LayerKind needs a mixer or an MLP")
         if self.window is not None and self.window < 1:
             raise ValueError(f"LayerKind.window must be >= 1, got {self.window}")
+        if self.mixer != "attention" and (self.window is not None or not self.rotary):
+            # one spelling a kind: layers without attention compare equal
+            raise ValueError("window and rotary describe an attention mixer")
+
+    @property
+    def parts(self) -> str:
+        """The layer's parts, which decide its parameters' structure:
+        layers with the same parts share a stack. "attention+moe",
+        "ssm", "moe", ..."""
+        return "+".join(p for p in (self.mixer, self.mlp) if p)
+
+    @property
+    def block(self) -> bool:
+        """A transformer block: attention, then an MLP."""
+        return self.mixer == "attention" and self.mlp is not None
+
+
+@dataclasses.dataclass(frozen=True)
+class Segment:
+    """A stretch of the stack that `forward` runs as one scan: `repeats`
+    times a unit of `len(unit)` layers (their `LayerKind.parts`),
+    starting at layer `start`. One repeat is run as it is, layer by
+    layer."""
+
+    start: int
+    unit: Tuple[str, ...]
+    repeats: int
+
+
+def segments_of(parts: Tuple[str, ...]) -> Tuple[Segment, ...]:
+    """The pattern of a stack cut into segments, greedily from the front:
+    at each layer the unit whose immediate repeats cover most layers (the
+    shortest such), or the layer alone when nothing repeats. `M E M E M *
+    E M E` is (M E) x 2, then M, *, E, M, E one by one; twelve equal
+    layers are one unit x 12; a different first layer stands alone. What
+    is traced grows with the runs of the pattern, not with the depth."""
+    out, i, n = [], 0, len(parts)
+    while i < n:
+        best_p, best_r = 1, 1
+        for p in range(1, (n - i) // 2 + 1):
+            r = 1
+            while parts[i + r * p: i + (r + 1) * p] == parts[i: i + p]:
+                r += 1
+            if r > 1 and r * p > best_r * best_p:
+                best_p, best_r = p, r
+        out.append(Segment(i, tuple(parts[i: i + best_p]), best_r))
+        i += best_p * best_r
+    return tuple(out)
 
 
 @dataclasses.dataclass(eq=False)  # eq=False keeps it hashable (by id) for jit static args
@@ -126,7 +229,7 @@ class TransformerConfig:
     vocab_size: int = 128
     max_position_embeddings: int = 2048
 
-    activation: str = "silu"  # silu | gelu
+    activation: str = "silu"  # silu | gelu | relu2 (squared ReLU)
     mlp_type: str = "gated"  # gated | plain
     norm_type: str = "rms"  # rms | layer
     norm_eps: float = 1e-6
@@ -156,6 +259,7 @@ class TransformerConfig:
 
     is_critic: bool = False
     moe: Optional[MoEConfig] = None
+    ssm: Optional[SSMConfig] = None
     # One LayerKind a layer, filled by the family from the published
     # config; None = every layer the same (moe or dense by `moe`, full
     # causal attention, rotary by `pos_emb`).
@@ -174,6 +278,11 @@ class TransformerConfig:
             # TransformerConfig(**config)); coerce the nested MoE block
             # so `model.config.moe.num_experts=8` works end-to-end.
             self.moe = MoEConfig(**self.moe)
+        if isinstance(self.ssm, dict):
+            self.ssm = SSMConfig(**self.ssm)
+        if self.activation not in ("silu", "gelu", "relu2"):
+            raise ValueError(
+                f"activation must be 'silu', 'gelu' or 'relu2', got {self.activation!r}")
         if self.layer_kinds is not None:
             self.layer_kinds = tuple(
                 LayerKind(**k) if isinstance(k, dict) else k
@@ -187,14 +296,8 @@ class TransformerConfig:
         kinds = self.kinds()
         if any(k.mlp == "moe" for k in kinds) and self.moe is None:
             raise ValueError("a layer of kind 'moe' needs TransformerConfig.moe")
-        rest = kinds[self.n_lead_layers:]
-        if any(k.mlp != rest[0].mlp for k in rest):
-            raise NotImplementedError(
-                "MLP kinds that alternate after the leading layers need a "
-                "parameter stack per position of the period "
-                "(models/transformer.py scans one stack): "
-                f"{[k.mlp for k in kinds]}"
-            )
+        if any(k.mixer == "ssm" for k in kinds) and self.ssm is None:
+            raise ValueError("a layer with an 'ssm' mixer needs TransformerConfig.ssm")
 
     @property
     def q_dim(self) -> int:
@@ -217,24 +320,54 @@ class TransformerConfig:
 
     @property
     def n_lead_layers(self) -> int:
-        """Leading layers whose MLP differs from the last layer's: their
-        parameters are a stack of their own (`params["lead_layers"]`) and
-        they run before the scan over `params["layers"]`."""
+        """Leading layers whose parts differ from the last layer's: in a
+        stack of transformer blocks their parameters are a stack of
+        their own (`params["lead_layers"]`)."""
         kinds = self.kinds()
         n = 0
-        while n < len(kinds) and kinds[n].mlp != kinds[-1].mlp:
+        while n < len(kinds) and kinds[n].parts != kinds[-1].parts:
             n += 1
         return n
+
+    def stack_paths(self) -> Dict[str, Tuple[Tuple[str, ...], Tuple[int, ...]]]:
+        """Where the parameters of each kind of layer live: `parts` ->
+        (path into the parameter tree, the layers it holds in order).
+        Layers with the same parts share one stack, on a leading axis. A
+        stack of transformer blocks that is leading layers of one kind
+        and then layers of another keeps its names, `lead_layers` and
+        `layers`; any other pattern has `stacks/<parts>`."""
+        kinds = self.kinds()
+        by_parts: Dict[str, list] = {}
+        for i, k in enumerate(kinds):
+            by_parts.setdefault(k.parts, []).append(i)
+        n_lead = self.n_lead_layers
+        lead, rest = kinds[:n_lead], kinds[n_lead:]
+        if (all(k.block for k in kinds)
+                and all(k.parts == rest[0].parts for k in rest)
+                and all(k.parts == lead[0].parts for k in lead)):
+            out = {rest[0].parts: (("layers",), tuple(range(n_lead, len(kinds))))}
+            if lead:
+                out[lead[0].parts] = (("lead_layers",), tuple(range(n_lead)))
+            return out
+        return {parts: (("stacks", parts), tuple(idx))
+                for parts, idx in by_parts.items()}
+
+    def segments(self) -> Tuple[Segment, ...]:
+        return segments_of(tuple(k.parts for k in self.kinds()))
 
     @property
     def n_moe_layers(self) -> int:
         return sum(k.mlp == "moe" for k in self.kinds())
 
     @property
+    def n_ssm_layers(self) -> int:
+        return sum(k.mixer == "ssm" for k in self.kinds())
+
+    @property
     def one_kind(self) -> bool:
-        """Every layer the same, full causal attention, rotary as
-        `pos_emb` says: what the KV-cache paths (generation, serving,
-        paged) can run."""
+        """Every layer the same transformer block, full causal
+        attention, rotary as `pos_emb` says: what the KV-cache paths
+        (generation, serving, paged) can run."""
         kinds = self.kinds()
         plain = LayerKind(mlp=kinds[0].mlp, rotary=self.pos_emb == "rotary")
         return all(k == plain for k in kinds)
@@ -245,12 +378,27 @@ class TransformerConfig:
         block: refuse what they would otherwise compute wrongly, naming
         what is missing."""
         missing = []
-        if not self.one_kind:
+        kinds = self.kinds()
+        if any(k.mixer == "ssm" for k in kinds):
+            missing.append(
+                "a recurrent state beside the KV pages: a state-space layer "
+                "keeps, a sequence, its state [heads, head_dim, state_dim] and "
+                "the last conv_kernel - 1 inputs of its convolution, which the "
+                "cache manager has no slot for, no snapshot of for an "
+                "interrupted rollout to resume from, and no decode step"
+            )
+        if not all(k.block for k in kinds):
+            missing.append(
+                "a decode layer per kind of layer: the layers here have the "
+                f"parts {sorted({k.parts for k in kinds})}, the cache paths run "
+                "attention and an MLP in every layer"
+            )
+        elif not self.one_kind:
             missing.append(
                 "a cache manager with a kind per layer (window layers keep "
                 "the last `window` positions, full layers all; rotary or "
                 "none per layer): the layers here are "
-                f"{sorted({(k.mlp, k.window or 0, k.rotary) for k in self.kinds()})}"
+                f"{sorted({(k.mlp, k.window or 0, k.rotary) for k in kinds})}"
             )
         if self.attn_gate or self.post_norms:
             missing.append(
@@ -265,6 +413,8 @@ class TransformerConfig:
                 "the sigmoid router, shared expert and held-experts share in "
                 "the decode layer's expert MLP"
             )
+        if self.activation == "relu2":
+            missing.append("the squared-ReLU activation in the decode layer's MLP")
         if missing:
             raise NotImplementedError(
                 f"{where} cannot run this configuration; it lacks "

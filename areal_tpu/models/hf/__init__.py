@@ -154,3 +154,4 @@ from areal_tpu.models.hf import mixtral as _mixtral  # noqa: E402,F401
 from areal_tpu.models.hf import gemma as _gemma  # noqa: E402,F401
 from areal_tpu.models.hf import gpt2 as _gpt2  # noqa: E402,F401
 from areal_tpu.models.hf import afmoe as _afmoe  # noqa: E402,F401
+from areal_tpu.models.hf import nemotron_h as _nemotron_h  # noqa: E402,F401

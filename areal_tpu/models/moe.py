@@ -46,6 +46,8 @@ for an even share: the first pass always, further ones only while pairs
 are left, so no pair is dropped at any skew and the work follows the
 pairs held, not all k x T. The router's published sigmoid form
 (`score_func`), the selection bias and the shared expert live here too.
+Experts, routed and shared, are gated (`w_gate`, `w_up`, `w_down`) or
+plain (`w_in`, `w_out`) by `cfg.mlp_type`, under `cfg.activation`.
 """
 
 from __future__ import annotations
@@ -58,6 +60,31 @@ import jax.numpy as jnp
 
 from areal_tpu.base import env_registry
 from areal_tpu.models.config import TransformerConfig
+
+
+def activation_fn(name: str):
+    """silu, gelu, or relu2: the squared ReLU."""
+    if name == "relu2":
+        return lambda x: jnp.square(jax.nn.relu(x))
+    return {"silu": jax.nn.silu, "gelu": jax.nn.gelu}[name]
+
+
+def expert_mats(cfg: TransformerConfig) -> Tuple[str, ...]:
+    """The matrices of one expert MLP (routed, shared or dense), by
+    `mlp_type`: gated `act(x w_gate) * (x w_up) w_down`, plain
+    `act(x w_in) w_out`."""
+    return ("w_gate", "w_up", "w_down") if cfg.mlp_type == "gated" else ("w_in", "w_out")
+
+
+def _grouped_ffn(xs, weights, gs, act):
+    """The experts' MLP over rows sorted by expert, `gs` rows a group:
+    `lax.ragged_dot` against the two (plain) or three (gated) stacks."""
+    if len(weights) == 3:
+        wg, wu, wd = weights
+        h = act(jax.lax.ragged_dot(xs, wg, gs)) * jax.lax.ragged_dot(xs, wu, gs)
+        return jax.lax.ragged_dot(h, wd, gs)
+    w_in, w_out = weights
+    return jax.lax.ragged_dot(act(jax.lax.ragged_dot(xs, w_in, gs)), w_out, gs)
 
 
 def moe_ep_degree(cfg: TransformerConfig, mesh, x_shape=None) -> int:
@@ -187,7 +214,7 @@ def _moe_mlp_ep(
     a2a_bytes = float(
         (ep - 1) * n_local * (2 * D * jnp.dtype(cdt).itemsize + k * 12)
     )
-    act = jax.nn.silu if cfg.activation == "silu" else jax.nn.gelu
+    act = activation_fn(cfg.activation)
     f_spec = "tensor" if tp_shards > 1 else None
     red = ("data", "fsdp", "seq")  # equal-count shards: pmean is exact
 
@@ -365,7 +392,8 @@ def _take_rows_bwd(n_tok, tok, d):
 _take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
 
 
-def _held_experts(xt, mp, moe, act, cdt, choice_e, gate, tok_idx, token_mask):
+def _held_experts(xt, mp, moe, act, cdt, choice_e, gate, tok_idx, token_mask,
+                  mats=("w_gate", "w_up", "w_down")):
     """The held experts' part of sum_e w_e Expert_e(x): [T, D], and
     (pairs held, buffer rows run). `choice_e`, `gate`, `tok_idx`: the
     k x T (token, choice) pairs' expert, weight and token, choice-major.
@@ -374,7 +402,7 @@ def _held_experts(xt, mp, moe, act, cdt, choice_e, gate, tok_idx, token_mask):
     expert is held here take part. One argsort puts them first, ordered
     by expert; pass c takes rows [c B, (c + 1) B) of that order into a
     buffer of B rows (`held_buffer_rows`), gathers their tokens, runs
-    the three grouped matmuls with the group sizes that fall into the
+    the grouped matmuls (`mats`) with the group sizes that fall into the
     pass, and adds the weighted results to their tokens. The first pass
     always runs; pass c > 0 runs only if more than c B pairs are held
     (`lax.cond` inside a scan: one traced body, static shapes; the
@@ -410,7 +438,7 @@ def _held_experts(xt, mp, moe, act, cdt, choice_e, gate, tok_idx, token_mask):
     if pad:  # the last pass's slice stays inside the arrays
         tok_sorted = jnp.pad(tok_sorted, (0, pad))
         gate_sorted = jnp.pad(gate_sorted, (0, pad))
-    wg, wu, wd = (mp[n].astype(cdt) for n in ("w_gate", "w_up", "w_down"))
+    weights = tuple(mp[n].astype(cdt) for n in mats)
     xc = xt.astype(cdt)
 
     def one_pass(c):
@@ -423,9 +451,7 @@ def _held_experts(xt, mp, moe, act, cdt, choice_e, gate, tok_idx, token_mask):
             gs = jnp.diff(jnp.clip(ends, lo, lo + B), prepend=lo).astype(jnp.int32)
             xs = jnp.where(valid[:, None], _take_rows(xc, tok, T), 0)
         with jax.named_scope("moe_experts"):
-            h = act(jax.lax.ragged_dot(xs, wg, gs))
-            h = h * jax.lax.ragged_dot(xs, wu, gs)
-            ys = jax.lax.ragged_dot(h, wd, gs)  # [B, D]
+            ys = _grouped_ffn(xs, weights, gs, act)  # [B, D]
         with jax.named_scope("moe_combine"):
             # Mask before weighing: what the grouped matmul leaves in rows
             # of no group need not be finite, and 0 x it (the weight's
@@ -456,9 +482,11 @@ def _held_experts(xt, mp, moe, act, cdt, choice_e, gate, tok_idx, token_mask):
 
 
 def _shared_expert(xt, sp, act, cdt):
-    """The gated MLP every token passes through."""
+    """The MLP every token passes through, gated or plain."""
     with jax.named_scope("moe_shared"):
         xc = xt.astype(cdt)
+        if "w_in" in sp:
+            return act(xc @ sp["w_in"].astype(cdt)) @ sp["w_out"].astype(cdt)
         h = act(xc @ sp["w_gate"].astype(cdt)) * (xc @ sp["w_up"].astype(cdt))
         return h @ sp["w_down"].astype(cdt)
 
@@ -496,10 +524,12 @@ def moe_mlp(
         )
     if (dispatch == "dropless" and moe.experts_held is None
             and moe_ep_degree(cfg, mesh, x.shape) > 1):
-        if moe.score_func != "softmax" or "shared" in mp:
+        if moe.score_func != "softmax" or "shared" in mp or cfg.mlp_type != "gated":
             raise NotImplementedError(
-                "_moe_mlp_ep routes with the softmax router and has no "
-                "shared expert"
+                "_moe_mlp_ep routes with the softmax router, has no shared "
+                "expert and runs gated experts (w_gate, w_up, w_down) only: "
+                "plain experts (w_in, w_out) have no grouped matmul in its "
+                "shard_map body"
             )
         return _moe_mlp_ep(x, mp, cfg, cdt, mesh)
 
@@ -515,13 +545,13 @@ def moe_mlp(
     choice_e = top_e.T.reshape(-1)  # [k*T] expert ids, choice-major
     gate = top_p.T.reshape(-1)  # [kT], aligned with choice_e
     tok_idx = jnp.tile(jnp.arange(T), k)
-    act = jax.nn.silu if cfg.activation == "silu" else jax.nn.gelu
+    act, mats = activation_fn(cfg.activation), expert_mats(cfg)
     a2a_bytes = jnp.zeros((), jnp.float32)
     held_aux = {}
 
     if moe.experts_held is not None:
         y, pairs_held, rows_run = _held_experts(
-            xt, mp, moe, act, cdt, choice_e, gate, tok_idx, token_mask)
+            xt, mp, moe, act, cdt, choice_e, gate, tok_idx, token_mask, mats)
         held_aux = dict(pairs_held=pairs_held, rows_run=rows_run)
         drop_rate = jnp.zeros((), jnp.float32)
     elif dispatch == "dropless":
@@ -531,12 +561,8 @@ def moe_mlp(
         order = jnp.argsort(choice_e)  # stable: keeps priority order
         group_sizes = jnp.bincount(choice_e, length=E)
         xs = xt[tok_idx[order]].astype(cdt)  # [kT, D] sorted by expert
-        wg = mp["w_gate"].astype(cdt)
-        wu = mp["w_up"].astype(cdt)
-        wd = mp["w_down"].astype(cdt)
-        h = act(jax.lax.ragged_dot(xs, wg, group_sizes))
-        h = h * jax.lax.ragged_dot(xs, wu, group_sizes)
-        ys = jax.lax.ragged_dot(h, wd, group_sizes)  # [kT, D]
+        ys = _grouped_ffn(xs, tuple(mp[n].astype(cdt) for n in mats),
+                          group_sizes, act)  # [kT, D]
         y = (
             jnp.zeros((T, D), cdt)
             .at[tok_idx[order]]
@@ -563,9 +589,10 @@ def moe_mlp(
         )
 
         xe = jnp.einsum("tec,td->ecd", disp.astype(cdt), xt.astype(cdt))  # [E, C, D]
-        h = act(jnp.einsum("ecd,edf->ecf", xe, mp["w_gate"].astype(cdt)))
-        h = h * jnp.einsum("ecd,edf->ecf", xe, mp["w_up"].astype(cdt))
-        ye = jnp.einsum("ecf,efd->ecd", h, mp["w_down"].astype(cdt))  # [E, C, D]
+        h = act(jnp.einsum("ecd,edf->ecf", xe, mp[mats[0]].astype(cdt)))
+        if len(mats) == 3:
+            h = h * jnp.einsum("ecd,edf->ecf", xe, mp["w_up"].astype(cdt))
+        ye = jnp.einsum("ecf,efd->ecd", h, mp[mats[-1]].astype(cdt))  # [E, C, D]
         y = jnp.einsum("tec,ecd->td", comb.astype(cdt), ye)  # [T, D]
         # Realized drop rate: fraction of REAL (token, choice) routings
         # that exceeded their expert's capacity this step. The quality
@@ -616,19 +643,18 @@ def init_moe_params(cfg: TransformerConfig, dense_fn, keys, n_layers: int,
     moe = cfg.moe
     L, D, E, H = n_layers, cfg.hidden_dim, moe.num_experts, moe.n_held
     F = moe.expert_intermediate_dim or cfg.intermediate_dim
-    mp = {
-        "router": dense_fn(keys[0], (L, D, E)),
-        "w_gate": dense_fn(keys[1], (L, H, D, F)),
-        "w_up": dense_fn(keys[2], (L, H, D, F)),
-        "w_down": dense_fn(keys[3], (L, H, F, D)),
-    }
+    mats = expert_mats(cfg)
+    # (gate, up, down) or (in, out): the last maps back to the hidden size
+    mp = {"router": dense_fn(keys[0], (L, D, E))}
+    for name, key in zip(mats[:-1], keys[1:]):
+        mp[name] = dense_fn(key, (L, H, D, F))
+    mp[mats[-1]] = dense_fn(keys[3], (L, H, F, D))
     if moe.router_bias:
         mp["expert_bias"] = jnp.zeros((L, E), jnp.float32)
     if moe.n_shared_experts:
-        Fs, ks = F * moe.n_shared_experts, jax.random.split(shared_key, 3)
-        mp["shared"] = {
-            "w_gate": dense_fn(ks[0], (L, D, Fs)),
-            "w_up": dense_fn(ks[1], (L, D, Fs)),
-            "w_down": dense_fn(ks[2], (L, Fs, D)),
-        }
+        Fs = moe.shared_intermediate_dim or F * moe.n_shared_experts
+        ks = jax.random.split(shared_key, 3)
+        mp["shared"] = {name: dense_fn(key, (L, D, Fs))
+                        for name, key in zip(mats[:-1], ks)}
+        mp["shared"][mats[-1]] = dense_fn(ks[2], (L, Fs, D))
     return mp

@@ -7,14 +7,21 @@ real_llm_base.py (blocks) — redesigned for XLA rather than translated:
   one pytree with a leading layer axis, and the forward pass scans over it.
   One layer gets traced/compiled regardless of depth, and XLA pipelines
   HBM weight streaming across layers.
-- **Layers of several kinds in one stack** (`config.LayerKind`: dense or
-  expert MLP, an attention window or none, rotary or none, all static):
-  leading layers whose MLP differs from the rest are a stack of their own
-  (`params["lead_layers"]`) and run before the scan; the scanned layers
-  share one MLP kind, and which (window, rotary) attention each has is an
-  index scanned beside its parameters that switches the attention call
-  alone. One layer body is traced whatever the pattern of kinds; a stack
-  of one kind is the plain scan.
+- **Layers of several kinds in one stack** (`config.LayerKind`: the
+  parts a layer has, each under its own norm with its own residual: a
+  mixer, attention with a window or none and rotary or none, or a
+  state-space mixer, `ops/ssm.py`; and an MLP, dense or expert; all
+  static). Layers with the same parts share a parameter stack
+  (`config.stack_paths`: `layers` and, for leading blocks of another
+  kind, `lead_layers`; `stacks/<parts>` for any other pattern), and the
+  forward pass walks the pattern in segments (`config.segments_of`): a
+  run of a repeated unit of one or more layers is one scan over the
+  unit, whose stacks are cut from their kinds' by `lax.split`; a layer
+  that repeats nothing runs as it is. Layers of a scan that differ only
+  in their attention share one traced body: which (window, rotary) each
+  has is an index scanned beside its parameters that switches the
+  attention call alone. What is traced grows with the runs of the
+  pattern, not the depth; a stack of one kind is the plain scan.
 - **Packed rows**: a batch is [R, T] token streams; each row packs several
   variable-length sequences tagged by segment ids (0 = padding). No pad
   waste beyond the row tail, matching the reference's packed varlen
@@ -37,7 +44,8 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from areal_tpu.models.config import TransformerConfig
+from areal_tpu.models.config import LayerKind, TransformerConfig
+from areal_tpu.models.moe import activation_fn
 from areal_tpu.ops.attention import packed_attention, reference_packed_attention
 from areal_tpu.ops.norms import layer_norm, rms_norm
 from areal_tpu.ops.rotary import apply_rotary, rotary_cos_sin, rotary_inv_freq
@@ -53,47 +61,58 @@ Params = Dict[str, Any]
 # ---------------------------------------------------------------------------
 
 
-def _init_layer_stack(cfg: TransformerConfig, keys, n: int, mlp_kind: str,
+def _init_layer_stack(cfg: TransformerConfig, keys, n: int, kind: LayerKind,
                       dense) -> Dict[str, Any]:
-    """`n` layers of one MLP kind, stacked on a leading axis."""
+    """`n` layers with the parts of `kind`, stacked on a leading axis:
+    the mixer (`attn` or `ssm`) under `ln1`, the MLP under `ln2`."""
     pdt = jnp.dtype(cfg.param_dtype)
     D, F, L = cfg.hidden_dim, cfg.intermediate_dim, n
-    attn: Dict[str, Any] = {
-        "wq": dense(keys[0], (L, D, cfg.q_dim)),
-        "wk": dense(keys[1], (L, D, cfg.kv_dim)),
-        "wv": dense(keys[2], (L, D, cfg.kv_dim)),
-        "wo": dense(keys[3], (L, cfg.q_dim, D)),
-    }
-    if cfg.attn_bias:
-        attn["bq"] = jnp.zeros((L, cfg.q_dim), pdt)
-        attn["bk"] = jnp.zeros((L, cfg.kv_dim), pdt)
-        attn["bv"] = jnp.zeros((L, cfg.kv_dim), pdt)
-    if cfg.attn_out_bias:
-        attn["bo"] = jnp.zeros((L, D), pdt)
-    if cfg.qk_norm:
-        attn["q_norm"] = jnp.ones((L, cfg.head_dim), pdt)
-        attn["k_norm"] = jnp.ones((L, cfg.head_dim), pdt)
-    if cfg.attn_gate:
-        attn["wg"] = dense(keys[10], (L, D, cfg.q_dim))
+    layers: Dict[str, Any] = {}
+    norms = []
+    if kind.mixer == "attention":
+        attn: Dict[str, Any] = {
+            "wq": dense(keys[0], (L, D, cfg.q_dim)),
+            "wk": dense(keys[1], (L, D, cfg.kv_dim)),
+            "wv": dense(keys[2], (L, D, cfg.kv_dim)),
+            "wo": dense(keys[3], (L, cfg.q_dim, D)),
+        }
+        if cfg.attn_bias:
+            attn["bq"] = jnp.zeros((L, cfg.q_dim), pdt)
+            attn["bk"] = jnp.zeros((L, cfg.kv_dim), pdt)
+            attn["bv"] = jnp.zeros((L, cfg.kv_dim), pdt)
+        if cfg.attn_out_bias:
+            attn["bo"] = jnp.zeros((L, D), pdt)
+        if cfg.qk_norm:
+            attn["q_norm"] = jnp.ones((L, cfg.head_dim), pdt)
+            attn["k_norm"] = jnp.ones((L, cfg.head_dim), pdt)
+        if cfg.attn_gate:
+            attn["wg"] = dense(keys[10], (L, D, cfg.q_dim))
+        layers["attn"] = attn
+    elif kind.mixer == "ssm":
+        from areal_tpu.ops.ssm import init_ssm_params
 
-    if mlp_kind == "moe":
+        layers["ssm"] = init_ssm_params(cfg.ssm, D, dense, keys[13], L, pdt)
+    if kind.mixer is not None:
+        norms += ["ln1"] + (["ln1_post"] if cfg.post_norms else [])
+
+    if kind.mlp == "moe":
         from areal_tpu.models.moe import init_moe_params
 
         mlp = init_moe_params(
             cfg, dense, jax.random.split(keys[4], 4), L, shared_key=keys[11]
         )
-    elif cfg.mlp_type == "gated":
+    elif kind.mlp == "dense" and cfg.mlp_type == "gated":
         mlp = {
             "w_gate": dense(keys[4], (L, D, F)),
             "w_up": dense(keys[5], (L, D, F)),
             "w_down": dense(keys[6], (L, F, D)),
         }
-    else:
+    elif kind.mlp == "dense":
         mlp = {
             "w_in": dense(keys[4], (L, D, F)),
             "w_out": dense(keys[6], (L, F, D)),
         }
-    if cfg.mlp_bias and mlp_kind == "dense":
+    if cfg.mlp_bias and kind.mlp == "dense":
         if cfg.mlp_type == "gated":
             mlp["b_gate"] = jnp.zeros((L, F), pdt)
             mlp["b_up"] = jnp.zeros((L, F), pdt)
@@ -101,21 +120,23 @@ def _init_layer_stack(cfg: TransformerConfig, keys, n: int, mlp_kind: str,
         else:
             mlp["b_in"] = jnp.zeros((L, F), pdt)
             mlp["b_out"] = jnp.zeros((L, D), pdt)
+    if kind.mlp is not None:
+        layers["mlp"] = mlp
+        norms += ["ln2"] + (["ln2_post"] if cfg.post_norms else [])
 
-    norms = ["ln1", "ln2"] + (["ln1_post", "ln2_post"] if cfg.post_norms else [])
-    layers = {name: {"weight": jnp.ones((L, D), pdt)} for name in norms}
-    layers.update(attn=attn, mlp=mlp)
-    if cfg.norm_type == "layer":
-        for name in norms:
+    for name in norms:
+        layers[name] = {"weight": jnp.ones((L, D), pdt)}
+        if cfg.norm_type == "layer":
             layers[name]["bias"] = jnp.zeros((L, D), pdt)
     return layers
 
 
 def init_params(cfg: TransformerConfig, rng: jax.Array) -> Params:
-    """Random-init parameter pytree with stacked layers: `layers`, the
-    stack the forward pass scans, and before it `lead_layers` where the
-    first layers have another MLP than the rest (leading dense layers of
-    an expert model)."""
+    """Random-init parameter pytree with stacked layers, one stack a
+    kind of layer (`cfg.stack_paths`): `layers`, and before it
+    `lead_layers` where the first blocks have another MLP than the rest
+    (leading dense layers of an expert model); `stacks/<parts>` for a
+    pattern of kinds that is not that."""
     pdt = jnp.dtype(cfg.param_dtype)
     D, V = cfg.hidden_dim, cfg.vocab_size
     keys = jax.random.split(rng, 16)
@@ -124,18 +145,20 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Params:
         scale = scale if scale is not None else (1.0 / math.sqrt(shape[-2]))
         return (jax.random.normal(key, shape, dtype=jnp.float32) * scale).astype(pdt)
 
-    kinds, n_lead = cfg.kinds(), cfg.n_lead_layers
+    kinds = cfg.kinds()
     params: Params = {
         "embedding": {"weight": dense(keys[7], (V, D), scale=0.02)},
-        "layers": _init_layer_stack(
-            cfg, keys, cfg.n_layers - n_lead, kinds[-1].mlp, dense
-        ),
         "final_norm": {"weight": jnp.ones((D,), pdt)},
     }
-    if n_lead:
-        params["lead_layers"] = _init_layer_stack(
-            cfg, jax.random.split(keys[12], 16), n_lead, kinds[0].mlp, dense
-        )
+    for i, (path, idx) in enumerate(cfg.stack_paths().values()):
+        if path == ("layers",):
+            stack_keys = keys
+        elif path == ("lead_layers",):
+            stack_keys = jax.random.split(keys[12], 16)
+        else:
+            stack_keys = jax.random.split(jax.random.fold_in(keys[14], i), 16)
+        _stack_at(params, path, _init_layer_stack(
+            cfg, stack_keys, len(idx), kinds[idx[0]], dense))
     if cfg.pos_emb == "learned":
         params["pos_embedding"] = {
             "weight": dense(keys[9], (cfg.max_position_embeddings, D), scale=0.02)
@@ -160,8 +183,19 @@ def _norm(x, p, cfg):
     return layer_norm(x, p["weight"], p.get("bias"), cfg.norm_eps)
 
 
+def _stack_at(params, path, new=None):
+    """The stack at `path` of the parameter tree (`cfg.stack_paths`);
+    with `new`, put it there."""
+    node = params
+    for key in path[:-1]:
+        node = node.setdefault(key, {}) if new is not None else node[key]
+    if new is not None:
+        node[path[-1]] = new
+    return node[path[-1]]
+
+
 def _mlp(h, lp, cfg, cdt):
-    act = jax.nn.silu if cfg.activation == "silu" else jax.nn.gelu
+    act = activation_fn(cfg.activation)
     if cfg.mlp_type == "gated":
         g = qmat(h, lp["w_gate"], cdt)
         u = qmat(h, lp["w_up"], cdt)
@@ -320,16 +354,55 @@ def _attention_block(
     return out, (k, v)
 
 
-def _unstack(stack, n: int):
-    """The `n` layers of a stack, one pytree each, by a split whose
-    transpose is one concatenation: indexing's would be a zero-padded
-    copy of the whole stack for every layer."""
-    if not n:
-        return []
-    leaves, treedef = jax.tree_util.tree_flatten(stack)
-    cut = [[p.reshape(p.shape[1:]) for p in jax.lax.split(a, (1,) * n, axis=0)]
-           for a in leaves]
-    return [treedef.unflatten([c[i] for c in cut]) for i in range(n)]
+def _segment_stacks(params, cfg: TransformerConfig):
+    """The stack's segments (`cfg.segments`), each with the parameters of
+    its unit's positions: one layer's pytree a position where the
+    segment runs once, a stack of `repeats` layers a position where it
+    is scanned. Each kind's stack is cut along its leading axis into
+    what the segments take of it, in order, by one `lax.split` a leaf
+    (its transpose is one concatenation; indexing's would be a
+    zero-padded copy of the stack for every piece). A stack that one
+    scan takes whole is handed over as it is."""
+    segments = cfg.segments()
+    # per kind: (segment, how many layers a repeat) in the stack's order
+    takes: Dict[str, list] = {}
+    for si, seg in enumerate(segments):
+        for parts in dict.fromkeys(seg.unit):
+            takes.setdefault(parts, []).append((si, seg.unit.count(parts)))
+    pieces: Dict[Tuple[int, str], list] = {}
+    for parts, (path, _) in cfg.stack_paths().items():
+        stack = _stack_at(params, path)
+        leaves, treedef = jax.tree_util.tree_flatten(stack)
+        (s0, c0), whole = takes[parts][0], len(takes[parts]) == 1
+        if whole and c0 == 1 and segments[s0].repeats > 1:
+            pieces[s0, parts] = [stack]
+            continue
+        sizes = tuple(segments[si].repeats * c for si, c in takes[parts])
+
+        def cut(a):
+            """One leaf -> per segment, the arrays of its unit's positions."""
+            out = []
+            for piece, (si, c) in zip(jax.lax.split(a, sizes, axis=0), takes[parts]):
+                r = segments[si].repeats
+                if c > 1:  # c layers of this kind a repeat: [r c, ..] -> c of [r, ..]
+                    piece = piece.reshape((r, c) + a.shape[1:])
+                    out.append([b.reshape((r,) + a.shape[1:])
+                                for b in jax.lax.split(piece, (1,) * c, axis=1)])
+                else:
+                    out.append([piece])
+                if r == 1:  # run as they are: no leading axis
+                    out[-1] = [b.reshape(a.shape[1:]) for b in out[-1]]
+            return out
+
+        per_leaf = [cut(a) for a in leaves]
+        for n, (si, c) in enumerate(takes[parts]):
+            pieces[si, parts] = [treedef.unflatten([l[n][i] for l in per_leaf])
+                                 for i in range(c)]
+    out = []
+    for si, seg in enumerate(segments):
+        nth = {parts: iter(pieces[si, parts]) for parts in dict.fromkeys(seg.unit)}
+        out.append((seg, [next(nth[parts]) for parts in seg.unit]))
+    return out
 
 
 def forward(
@@ -428,11 +501,19 @@ def forward(
                 stacklevel=2,
             )
             remat_mode = "full"
-    kinds, n_lead = cfg.kinds(), cfg.n_lead_layers
-    if return_kv and not all(k == kinds[0] for k in kinds):
+    kinds = cfg.kinds()
+    if return_kv and not all(k == kinds[0] and k.block for k in kinds):
         raise NotImplementedError(
             "return_kv with layers of different kinds: the KV cache "
-            "(models/generation.py) holds one kind of layer"
+            "(models/generation.py) holds one kind of layer, attention and an "
+            "MLP in each, and has no recurrent state for a state-space layer"
+        )
+    if cfg.n_ssm_layers and mesh is not None and mesh.shape.get("seq", 1) > 1:
+        raise NotImplementedError(
+            "a state-space layer on a mesh that splits the sequence (ring / "
+            "ulysses context parallelism): ops/ssm.py scans a row's chunks on "
+            "one device and has no hand-over of the state and of the "
+            "convolution's last inputs from one sequence shard to the next"
         )
     if use_moe:
         from areal_tpu.models.moe import moe_mlp
@@ -449,40 +530,54 @@ def forward(
         if use_moe:
             moe_fn = jax.checkpoint(moe_fn)
 
-    def layer_body(mlp_kind, variants):
+    def layer_body(kind, variants):
         """carry, (one layer's parameters, which of `variants` it is) ->
-        carry, its (k, v): a layer with this MLP ("dense" or "moe") whose
+        carry, its (k, v): a layer with the parts of `kind` whose
         attention is one of the (window, rotary) `variants`, under the
         remat mode. Layers that differ only in their attention share the
         one traced body: the switch is around the attention call alone
         (`_attention_block`), so what the backward pass keeps of a layer
         is one layer's, whatever its kind."""
-        moe_layer = mlp_kind == "moe"
 
         def body(carry, xs):
             lp, variant_index = xs
             x, aux_acc = carry
-            with jax.named_scope("attn_qkv"):
-                h = _norm(x, lp["ln1"], cfg)
-            a, kv = _attention_block(
-                h, lp["attn"], cfg, cos, sin,
-                segment_ids, positions, attn_impl, cdt, mesh=mesh,
-                variants=variants, variant_index=variant_index,
-            )
-            with jax.named_scope("attn_out"):
-                if "ln1_post" in lp:
-                    a = _norm(a, lp["ln1_post"], cfg)
-                x = x + a
-            with jax.named_scope("mlp"):
-                h = _norm(x, lp["ln2"], cfg)
-                if moe_layer:
-                    m, aux = moe_fn(h, lp["mlp"])
-                    aux_acc = {k: aux_acc[k] + aux[k] for k in aux_acc}
-                else:
-                    m = dense_fn(h, lp["mlp"])
-                if "ln2_post" in lp:
-                    m = _norm(m, lp["ln2_post"], cfg)
-                x = act_c(x + m)
+            kv = None
+            if kind.mixer == "attention":
+                with jax.named_scope("attn_qkv"):
+                    h = _norm(x, lp["ln1"], cfg)
+                a, kv = _attention_block(
+                    h, lp["attn"], cfg, cos, sin,
+                    segment_ids, positions, attn_impl, cdt, mesh=mesh,
+                    variants=variants, variant_index=variant_index,
+                )
+                with jax.named_scope("attn_out"):
+                    if "ln1_post" in lp:
+                        a = _norm(a, lp["ln1_post"], cfg)
+                    x = x + a
+            elif kind.mixer == "ssm":
+                from areal_tpu.ops.ssm import ssm_mixer
+
+                with jax.named_scope("ssm_in_proj"):
+                    h = _norm(x, lp["ln1"], cfg)
+                a = ssm_mixer(h, lp["ssm"], cfg.ssm, segment_ids, cdt, cfg.norm_eps)
+                with jax.named_scope("ssm_out_proj"):
+                    if "ln1_post" in lp:
+                        a = _norm(a, lp["ln1_post"], cfg)
+                    x = x + a
+            if kind.mlp is None:
+                x = act_c(x)
+            else:
+                with jax.named_scope("mlp"):
+                    h = _norm(x, lp["ln2"], cfg)
+                    if kind.mlp == "moe":
+                        m, aux = moe_fn(h, lp["mlp"])
+                        aux_acc = {k: aux_acc[k] + aux[k] for k in aux_acc}
+                    else:
+                        m = dense_fn(h, lp["mlp"])
+                    if "ln2_post" in lp:
+                        m = _norm(m, lp["ln2_post"], cfg)
+                    x = act_c(x + m)
             return (x, aux_acc), kv if return_kv else None
 
         if remat_mode == "full":
@@ -500,25 +595,35 @@ def forward(
 
     from areal_tpu.models.moe import moe_aux_zeros
 
-    attn_of = lambda k: (k.window, k.rotary)
-    carry = (x, moe_aux_zeros(cfg))
-    # Leading layers of another MLP kind run before the scan, one by one.
-    for kind, lp in zip(kinds, _unstack(params.get("lead_layers"), n_lead)):
-        carry, _ = layer_body(kind.mlp, (attn_of(kind),))(carry, (lp, None))
-    # The rest is one scan over `params["layers"]`: its layers have one
-    # MLP kind, and which attention each has is scanned beside its
-    # parameters (nothing, where all have the same).
-    rest = [attn_of(k) for k in kinds[n_lead:]]
-    variants = tuple(sorted(set(rest), key=rest.index))
-    which = None
-    if len(variants) > 1:
-        which = jnp.asarray([variants.index(v) for v in rest], jnp.int32)
-    body = layer_body(kinds[-1].mlp, variants)
-    if which is None:
-        carry, kvs = jax.lax.scan(
-            lambda c, lp: body(c, (lp, None)), carry, params["layers"])
-    else:
-        carry, kvs = jax.lax.scan(body, carry, (params["layers"], which))
+    carry, kvs = (x, moe_aux_zeros(cfg)), None
+    for seg, stacks in _segment_stacks(params, cfg):
+        # One body a position of the unit: its layers, one a repeat,
+        # differ at most in their attention, and which each has is
+        # scanned beside its parameters (nothing, where all have the same).
+        p, bodies, which = len(seg.unit), [], []
+        for j in range(p):
+            of_j = kinds[seg.start + j: seg.start + p * seg.repeats: p]
+            rest = [(k.window, k.rotary) for k in of_j]
+            variants = tuple(sorted(set(rest), key=rest.index))
+            bodies.append(layer_body(of_j[0], variants))
+            which.append(None if len(variants) == 1 else jnp.asarray(
+                [variants.index(v) for v in rest], jnp.int32))
+        if seg.repeats == 1:  # as they are, one by one
+            for body, lp in zip(bodies, stacks):
+                carry, _ = body(carry, (lp, None))
+        elif p > 1:
+            def unit(c, xs, bodies=bodies):
+                for body, layer in zip(bodies, xs):
+                    c, _ = body(c, layer)
+                return c, None
+
+            carry, _ = jax.lax.scan(unit, carry, tuple(zip(stacks, which)))
+        elif which[0] is None:
+            body = bodies[0]
+            carry, kvs = jax.lax.scan(
+                lambda c, lp: body(c, (lp, None)), carry, stacks[0])
+        else:
+            carry, kvs = jax.lax.scan(bodies[0], carry, (stacks[0], which[0]))
     x, moe_aux = carry
     with jax.named_scope("final_norm"):
         x = _norm(x, params["final_norm"], cfg)

@@ -1,0 +1,218 @@
+"""A state-space mixer (Mamba-2 form) over packed rows.
+
+For a layer's normalised input `h` [R, T, D], with H heads of P channels,
+a state of N a channel, B and C shared by the H / G heads of a group:
+
+    [z | xBC | dt] = h W_in
+    xBC_t <- silu(b_c + sum_l w_c[K-1-l] * xBC_{t-l}),  l = 0 .. K-1, depthwise,
+             a term dropped unless position t-l lies in t's own sequence
+    xBC -> x [H, P], B [G, N], C [G, N];  dt_t = softplus(dt_t + dt_bias) [H]
+    S_t = exp(dt_t A) S_{t-1} + dt_t x_t (x) B_t,  S = 0 before a sequence's
+          first token;  A = -exp(A_log) [H]
+    y_t = S_t C_t + D x_t;  y <- RMSNorm_groups(y * silu(z)) * w;  out = y W_out
+
+A packed row holds several sequences (segment ids, 0 = padding): the
+state and the convolution start afresh at every sequence start, and a
+padding cell has dt = 0 and x = 0, so it adds nothing to any state and
+its own result is 0. The input is masked at padding cells on the way in:
+whatever they hold (the residual stream carries them along) reaches
+neither a result nor a gradient.
+
+Computed in chunks of `chunk_size` positions (the SSD form), in einsums
+and one scan. With `a_t = dt_t A` and `cum` its running sum inside a
+chunk: within a chunk `y_i = sum_{j<=i} L_ij (C_i . B_j) dt_j x_j`, where
+`L_ij = exp(cum_i - cum_j)` if i and j are of one sequence, else 0
+(`ssm_intra`); between chunks a scan over the chunks' states
+(`ssm_states`): a chunk hands on the state of the sequence its last cell
+belongs to, `sum_j exp(cum_last - cum_j) dt_j x_j (x) B_j` over that
+sequence's cells, plus `exp(cum_last)` times the state it received if
+that same sequence crossed the whole chunk; the state a chunk receives
+reaches, decayed by `exp(cum_i)`, only the cells of the sequence that
+crossed into it. Decays, softplus and running sums are float32; the
+matrix products run in the compute dtype and accumulate in float32.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any, Dict
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from areal_tpu.models.config import SSMConfig
+
+
+def init_ssm_params(ssm: SSMConfig, hidden_dim: int, dense_fn, key, n_layers: int,
+                    pdt) -> Dict[str, Any]:
+    """`n_layers` mixers stacked on a leading axis. `A_log` = log of a
+    uniform draw from 1..16, `dt_bias` the inverse softplus of a
+    log-uniform step in [dt_min, dt_max] floored at dt_floor, `D` = 1:
+    a head then forgets at a rate of exp(-dt A) a token, between 0.9990
+    (dt 0.001, A 1) and 0.20 (dt 0.1, A 16)."""
+    L, H = n_layers, ssm.n_heads
+    k_in, k_conv, k_out, k_a, k_dt = jax.random.split(key, 5)
+    dt = jnp.exp(jax.random.uniform(k_dt, (L, H), jnp.float32)
+                 * (math.log(ssm.dt_max) - math.log(ssm.dt_min))
+                 + math.log(ssm.dt_min))
+    dt = jnp.maximum(dt, ssm.dt_floor)
+    sp: Dict[str, Any] = {
+        "in_proj": dense_fn(k_in, (L, hidden_dim, ssm.in_proj_dim)),
+        # [K, channels]: tap K-1 multiplies the position itself
+        "conv_w": dense_fn(k_conv, (L, ssm.conv_kernel, ssm.conv_dim),
+                           1.0 / math.sqrt(ssm.conv_kernel)),
+        "A_log": jnp.log(jax.random.uniform(
+            k_a, (L, H), jnp.float32, 1.0, 16.0)).astype(pdt),
+        "D": jnp.ones((L, H), pdt),
+        "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(pdt),
+        "norm": jnp.ones((L, ssm.d_inner), pdt),
+        "out_proj": dense_fn(k_out, (L, ssm.d_inner, hidden_dim)),
+    }
+    if ssm.conv_bias:
+        sp["conv_b"] = jnp.zeros((L, ssm.conv_dim), pdt)
+    return sp
+
+
+def causal_conv(xbc, w, b, segment_ids):
+    """xbc [R, T, C], w [K, C], b [C] or None, segment_ids [R, T] ->
+    silu(b + sum_l w[K-1-l] xbc[t-l]) over the taps whose position lies
+    in t's own sequence; 0 at padding cells."""
+    K, T = w.shape[0], xbc.shape[1]
+    before = jnp.pad(xbc, ((0, 0), (K - 1, 0), (0, 0)))  # position t at t + K - 1
+    seg_before = jnp.pad(segment_ids, ((0, 0), (K - 1, 0)))
+    acc = xbc * w[K - 1]
+    for lag in range(1, K):
+        lo = K - 1 - lag
+        same = seg_before[:, lo: lo + T] == segment_ids
+        acc = acc + jnp.where(same[..., None], before[:, lo: lo + T], 0) * w[lo]
+    if b is not None:
+        acc = acc + b
+    return jnp.where((segment_ids > 0)[..., None], jax.nn.silu(acc), 0)
+
+
+def chunked_scan(x, dt, A, B, C, segment_ids, chunk: int):
+    """The recurrence over packed rows, in chunks. x [R, T, H, P] (0 at
+    padding), dt [R, T, H] float32 (0 at padding), A [H] float32 (< 0),
+    B and C [R, T, G, N], segment_ids [R, T] -> y [R, T, H, P] float32,
+    without the `D x` term."""
+    R, T, H, P = x.shape
+    G, N = B.shape[2:]
+    Q, K = chunk, H // G
+    pad = -T % Q
+    if pad:
+        grow = lambda a: jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+        x, dt, B, C, segment_ids = (grow(a) for a in (x, dt, B, C, segment_ids))
+    nc = (T + pad) // Q
+    cdt = x.dtype
+    f32 = jnp.float32
+    # [R, c, Q, ...]; heads as (group, head of the group). The decays
+    # are held [R, c, G, K, Q]: the chunk's positions on the minor axis.
+    x = x.reshape(R, nc, Q, G, K, P)
+    dt = dt.reshape(R, nc, Q, G, K)
+    B, C = B.reshape(R, nc, Q, G, N), C.reshape(R, nc, Q, G, N)
+    seg = segment_ids.reshape(R, nc, Q)
+    by_head = lambda a: jnp.moveaxis(a, 2, -1)  # [R, c, Q, G, K] -> [R, c, G, K, Q]
+    by_cell = lambda a: jnp.moveaxis(a, -1, 2)[..., None]  # -> [R, c, Q, G, K, 1]
+    cum = jnp.cumsum(by_head(dt * A.reshape(G, K)), axis=-1)  # <= 0
+    dtx = dt[..., None] * x.astype(f32)  # [R, c, Q, G, K, P]
+
+    with jax.named_scope("ssm_intra"):
+        # L_ij = exp(cum_i - cum_j) for j <= i of i's sequence, else 0
+        # (masked before the exp: above the diagonal the difference is
+        # positive and may overflow).
+        seen = (seg[:, :, :, None] == seg[:, :, None, :]) & jnp.tril(
+            jnp.ones((Q, Q), bool))  # [R, c, i, j]
+        diff = cum[..., :, None] - cum[..., None, :]  # [R, c, G, K, i, j]
+        decay = jnp.exp(jnp.where(seen[:, :, None, None], diff, -jnp.inf))
+        cb = jnp.einsum("rcign,rcjgn->rcgij", C, B, preferred_element_type=f32)
+        scores = (cb[:, :, :, None] * decay).astype(cdt)
+        y = jnp.einsum("rcgkij,rcjgkp->rcigkp", scores, dtx.astype(cdt),
+                       preferred_element_type=f32)
+
+    with jax.named_scope("ssm_states"):
+        last = seg[:, :, -1]  # [R, c] the sequence a chunk hands on
+        # what the chunk's own cells add to the state at its end
+        to_end = jnp.exp(jnp.where(
+            (seg == last[..., None])[:, :, None, None],
+            cum[..., -1:] - cum, -jnp.inf))  # [R, c, G, K, Q]
+        local = jnp.einsum(
+            "rcjgn,rcjgkp->rcgkpn", B, (by_cell(to_end) * dtx).astype(cdt),
+            preferred_element_type=f32)  # [R, c, G, K, P, N]
+        # the state received is handed on, decayed over the whole chunk,
+        # if the sequence that crossed in is the one handed on
+        before = jnp.pad(last, ((0, 0), (1, 0)))[:, :-1]  # [R, c]
+        carry_on = jnp.where(((last == before) & (last > 0))[..., None, None],
+                             jnp.exp(cum[..., -1]), 0.0)  # [R, c, G, K]
+
+        def step(state, inp):
+            keep, add = inp
+            return keep[..., None, None] * state + add, state
+
+        _, received = jax.lax.scan(
+            step, jnp.zeros((R, G, K, P, N), f32),
+            (jnp.moveaxis(carry_on, 1, 0), jnp.moveaxis(local, 1, 0)))
+        received = jnp.moveaxis(received, 0, 1)  # [R, c, G, K, P, N]
+        # ... and reaches the cells of the sequence that crossed in
+        from_start = jnp.where(
+            ((seg == before[..., None]) & (seg > 0))[:, :, None, None],
+            jnp.exp(cum), 0.0)  # [R, c, G, K, Q]
+        y = y + by_cell(from_start) * jnp.einsum(
+            "rcign,rcgkpn->rcigkp", C, received.astype(cdt),
+            preferred_element_type=f32)
+    return y.reshape(R, nc * Q, H, P)[:, :T]
+
+
+def ssm_mixer(h, sp, ssm: SSMConfig, segment_ids, cdt, eps: float,
+              scan=chunked_scan):
+    """h [R, T, D] (the layer's input after its norm) -> the mixer's
+    output [R, T, D]; `sp` one layer's parameters (`init_ssm_params`
+    without the leading axis)."""
+    R, T, _ = h.shape
+    H, P, G, N = ssm.n_heads, ssm.head_dim, ssm.n_groups, ssm.state_dim
+    d_in = ssm.d_inner
+    valid = segment_ids > 0
+    f32 = jnp.float32
+    with jax.named_scope("ssm_in_proj"):
+        h = jnp.where(valid[..., None], h, 0).astype(cdt)
+        zxbcdt = h @ sp["in_proj"].astype(cdt)
+        z, xbc, dt = jnp.split(zxbcdt, [d_in, d_in + ssm.conv_dim], axis=-1)
+    with jax.named_scope("ssm_taps"):
+        xbc = causal_conv(
+            xbc, sp["conv_w"].astype(cdt),
+            sp["conv_b"].astype(cdt) if "conv_b" in sp else None, segment_ids)
+        x, B, C = jnp.split(xbc, [d_in, d_in + G * N], axis=-1)
+        x = x.reshape(R, T, H, P)
+    with jax.named_scope("ssm_scan"):
+        dt = jax.nn.softplus(dt.astype(f32) + sp["dt_bias"].astype(f32))
+        dt = jnp.where(valid[..., None], dt, 0.0)
+        A = -jnp.exp(sp["A_log"].astype(f32))
+        y = scan(x, dt, A, B.reshape(R, T, G, N), C.reshape(R, T, G, N),
+                 segment_ids, ssm.chunk_size)
+        y = y + sp["D"].astype(f32)[:, None] * x.astype(f32)
+    with jax.named_scope("ssm_gate_norm"):
+        y = y.reshape(R, T, d_in) * jax.nn.silu(z.astype(f32))
+        # RMSNorm over each group's channels, after the gate
+        yg = y.reshape(R, T, G, d_in // G)
+        yg = yg * jax.lax.rsqrt(jnp.mean(yg * yg, axis=-1, keepdims=True) + eps)
+        y = (yg.reshape(R, T, d_in) * sp["norm"].astype(f32)).astype(cdt)
+    with jax.named_scope("ssm_out_proj"):
+        return y @ sp["out_proj"].astype(cdt)
+
+
+def chunk_counts(segment_ids: np.ndarray, chunk: int):
+    """What `chunked_scan` does with packed rows, counted on the host by
+    its own rule; `segment_ids` [..., T]: (chunks it runs, those that
+    hold a token, those that hold a sequence start after their first
+    cell, sequence starts)."""
+    seg = np.asarray(segment_ids)
+    seg = seg.reshape(-1, seg.shape[-1])
+    pad = -seg.shape[1] % chunk
+    seg = np.pad(seg, ((0, 0), (0, pad)))
+    start = (seg != np.pad(seg, ((0, 0), (1, 0)))[:, :-1]) & (seg > 0)
+    chunks = seg.reshape(seg.shape[0], -1, chunk)
+    starts = start.reshape(chunks.shape)
+    return (int(chunks.shape[0] * chunks.shape[1]),
+            int((chunks > 0).any(-1).sum()),
+            int(starts[:, :, 1:].any(-1).sum()),
+            int(start.sum()))

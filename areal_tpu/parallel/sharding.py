@@ -69,6 +69,15 @@ def param_partition_spec(path: str, ndim: int) -> P:
         return P(None, "tensor", "fsdp")  # [L, in, D]: row parallel
     if name in ("bq", "bk", "bv", "b_gate", "b_up", "b_in"):
         return P(None, "tensor")  # [L, out]
+    # A state-space mixer (ops/ssm.py): its two projections ZeRO-sharded
+    # on the hidden dim; [z | xBC | dt] and the heads stay whole on every
+    # device (the scan over a row's chunks has no tensor-parallel form
+    # here), so `tensor` shards nothing of it.
+    if name == "in_proj":
+        return P(None, "fsdp", None)  # [L, D, z + xBC + dt]
+    if name == "out_proj":
+        return P(None, None, "fsdp")  # [L, d_inner, D]
+    # conv_w, conv_b, A_log, D, dt_bias, the gated norm,
     # norms, small biases (b_down/b_out [L, D]), router [L, D, E],
     # q_norm/k_norm: replicated.
     return P(*([None] * ndim))

@@ -104,3 +104,54 @@ def test_held_experts_pass_compiles_at_the_published_widths(one_chip):
     # a pass's buffers (1.6 GB by the compiler's count, backward
     # included), not k x T = 131,072 rows of them (six times that)
     assert compiled.memory_analysis().temp_size_in_bytes < 2.5e9
+
+
+def test_state_space_mixer_and_plain_experts_compile_at_the_published_widths(one_chip):
+    """What `nemotron3n-d9e8-train-ppo-long` adds to a step, forward and
+    backward at a row of 8,192 (half the cell's: a quicker compile): the
+    state-space mixer at 64 heads of 64, state 128, 8 groups, chunks of
+    128 (einsums and one scan, no kernel), and a held-experts pass over
+    8 of 128 plain squared-ReLU experts of 1856 top-6."""
+    from areal_tpu.models import moe as moe_lib
+    from areal_tpu.models.config import MoEConfig, SSMConfig
+    from areal_tpu.ops import ssm as ssm_lib
+
+    T, D = 8192, 2688
+    ssm = SSMConfig(n_heads=64, head_dim=64, n_groups=8, state_dim=128, chunk_size=128)
+    sp = jax.eval_shape(lambda k: jax.tree_util.tree_map(
+        lambda a: a[0], ssm_lib.init_ssm_params(
+            ssm, D, lambda k, s, scale=None: jnp.zeros(s, jnp.bfloat16), k, 1, jnp.bfloat16)),
+        jax.random.PRNGKey(0))
+    sp = jax.tree_util.tree_map(lambda a: _shape(a.shape, a.dtype, one_chip), sp)
+    h, seg = _shape((1, T, D), jnp.bfloat16, one_chip), _shape((1, T), jnp.int32, one_chip)
+
+    def mixer_loss(h, sp, seg):
+        return ssm_lib.ssm_mixer(h, sp, ssm, seg, jnp.bfloat16, 1e-5).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(mixer_loss, (0, 1))).lower(h, sp, seg).compile()
+    text = compiled.as_text()
+    assert " while(" in text and "tpu_custom_call" not in text  # the scan over chunk states; no kernel
+    # the float32 decay block is [64 chunks, 64 heads, 128, 128] = 268 MB: a few of them, not a row's worth a head
+    assert compiled.memory_analysis().temp_size_in_bytes < 4e9
+
+    moe = MoEConfig(num_experts=128, top_k=6, dispatch="dropless", score_func="sigmoid",
+                    routed_scaling_factor=2.5, experts_held=(0, 8))
+    F = 1856
+    assert moe_lib.held_buffer_rows(T, moe) == 4096
+    mp = {"w_in": _shape((8, D, F), jnp.bfloat16, one_chip),
+          "w_out": _shape((8, F, D), jnp.bfloat16, one_chip)}
+    x = _shape((T, D), jnp.bfloat16, one_chip)
+    gate = _shape((6 * T,), jnp.float32, one_chip)
+    choice = _shape((6 * T,), jnp.int32, one_chip)
+    mask = _shape((T,), jnp.bool_, one_chip)
+
+    def experts_loss(x, mp, gate, choice, mask):
+        tok = jnp.tile(jnp.arange(T, dtype=jnp.int32), 6)
+        y, pairs, rows = moe_lib._held_experts(
+            x, mp, moe, moe_lib.activation_fn("relu2"), jnp.bfloat16, choice, gate, tok, mask,
+            mats=("w_in", "w_out"))
+        return y.astype(jnp.float32).sum() + pairs + rows
+
+    text = jax.jit(jax.grad(experts_loss, (0, 1, 2))).lower(
+        x, mp, gate, choice, mask).compile().as_text()
+    assert "ragged-dot" in text and " conditional(" in text
