@@ -294,7 +294,7 @@ class SequenceSample:
 
         Returns (mb_iterator, groups, forward_indices, backward_indices);
         `groups[j]` holds micro-batch j's sample indices, so callers can
-        do per-mb pad-waste accounting (`datapack.packing_density` over
+        do per-mb pad-waste accounting (`datapack.ladder_density` over
         the group's lengths) before the data is ever touched.
         """
         lens = self.seqlens_of()

@@ -105,15 +105,92 @@ def packing_density(
     n_rows_multiple: int = 1,
     max_row_len: int = None,
 ) -> float:
-    """Tokens per padded token of the FFD pack of `lengths`: real tokens
-    divided by the [R, T] cells shipped to the device. 1.0 = no pad
-    waste; every (1 - density) fraction of the step's FLOPs is spent on
-    padding. This is the `packing_efficiency` series surfaced in the
-    master's perf history."""
+    """Tokens per padded token of the FFD pack of `lengths` into rows as
+    long as the longest sequence (`pack_shape`): real tokens divided by
+    the [R, T] cells. What the trainer engine ships is `ladder_density`."""
     n_rows, row_len = pack_shape(
         lengths, row_len_multiple, n_rows_multiple, max_row_len
     )
     return float(sum(int(l) for l in lengths)) / float(n_rows * row_len)
+
+
+def ladder_rung(n_tokens: int, row_len_multiple: int = 128) -> int:
+    """The shortest row length of the engine's ladder that holds
+    `n_tokens`. The rungs are multiples of `row_len_multiple` whose step
+    doubles with the length, `row_len_multiple` x 2^(floor(log2(n /
+    row_len_multiple)) - 3) and never under `row_len_multiple` (at 128:
+    steps of 128 to 2048, 256 to 4096, 512 to 8192, 1024 to 16,384), so
+    a rung wastes under an eighth of the row, or under one multiple, and
+    a doubling of the length adds eight compiled shapes."""
+    n_tokens = max(int(n_tokens), 1)
+    step = row_len_multiple << max(
+        (n_tokens // row_len_multiple).bit_length() - 4, 0)
+    return _round_up(n_tokens, step)
+
+
+def ladder_shape(
+    lengths: Sequence[int],
+    row_len_multiple: int = 128,
+    n_rows_multiple: int = 1,
+    max_row_len: int = None,
+) -> tuple:
+    """(n_rows, row_len) the trainer engine packs a micro-batch of these
+    sequence lengths into: as few rows as hold its tokens, at a row
+    length from a short ladder.
+
+    n_rows is `n_rows_multiple` (1 on one chip; the data x fsdp shards of
+    a mesh), and more, in multiples of it, only where `max_row_len`
+    (rounded up to `row_len_multiple`) forbids rows that long; the
+    sequences are balanced over the rows by FFD. row_len is the
+    shortest rung (`ladder_rung`) that holds the fullest row, or the
+    cap. A function of the lengths and the three settings alone: hand
+    its result to `models.packing.pack_sequences(row_len=, n_rows=)`,
+    which lays the sequences out by the same FFD. Raises, as that does,
+    for a sequence longer than the cap."""
+    lengths = [int(l) for l in lengths]
+    if not lengths:
+        raise ValueError("cannot compute pack shape of zero sequences")
+    cap = None
+    if max_row_len is not None:
+        cap = _round_up(max_row_len, row_len_multiple)
+        if max(lengths) > cap:
+            raise ValueError(
+                f"sequence of length {max(lengths)} exceeds row_len {cap}")
+    n_rows = n_rows_multiple
+    capacity = sum(lengths) if cap is None else cap
+    while True:
+        # min_groups: FFD hands each sequence to the emptiest row that
+        # has room, so the rows come out balanced.
+        groups = ffd_allocate(lengths, capacity=capacity, min_groups=n_rows)
+        if len(groups) <= n_rows:
+            break
+        n_rows = _round_up(len(groups), n_rows_multiple)
+    fullest = max(sum(lengths[i] for i in g) for g in groups)
+    row_len = ladder_rung(fullest, row_len_multiple)
+    return n_rows, row_len if cap is None else min(row_len, cap)
+
+
+def ladder_density(
+    lengths: Sequence[int],
+    row_len_multiple: int = 128,
+    n_rows_multiple: int = 1,
+    max_row_len: int = None,
+) -> float:
+    """Tokens per padded token of `ladder_shape`'s pack: real tokens
+    divided by the [R, T] cells the engine ships to the device. 1.0 = no
+    pad waste; the projections, MLP and norms spend a (1 - density)
+    share of their time on padding. The estimate behind the
+    `packing_efficiency` series of the master's perf history where the
+    engine recorded none: it is used on inputs the caller may not
+    control, so where the engine would raise (a sequence longer than
+    `max_row_len`) it widens the cap to fit and never returns a density
+    above 1.0."""
+    lengths = [int(l) for l in lengths]
+    if max_row_len is not None and lengths:
+        max_row_len = max(max_row_len, max(lengths))
+    n_rows, row_len = ladder_shape(
+        lengths, row_len_multiple, n_rows_multiple, max_row_len)
+    return float(sum(lengths)) / float(n_rows * row_len)
 
 
 def min_abs_diff_partition(nums: Sequence[int], k: int) -> List[List[int]]:

@@ -427,8 +427,9 @@ _METRICS: List[Metric] = [
        "gen_tokens/elapsed, computed at aggregation.",
        reduce="derived"),
     _m("perf/packing_efficiency", "scalar", "engine/jax_engine.py",
-       "Realized token/cell density of what shipped to HBM (FFD "
-       "fallback for non-packed paths).", reduce="avg"),
+       "Realized token/cell density of what shipped to HBM (the "
+       "packer's own estimate, datapack.ladder_density, for non-packed "
+       "paths).", reduce="avg"),
     _m("perf/h2d_wait_ms", "scalar", "engine/jax_engine.py",
        "Host-to-device staging wait per step; MAX across DP workers "
        "— the step blocks on the slowest, averaging understates.",

@@ -26,6 +26,8 @@ broadcast across their span).
 from __future__ import annotations
 
 import dataclasses
+import functools
+import operator
 import time
 from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
 
@@ -35,7 +37,7 @@ import numpy as np
 
 from areal_tpu.api.data_api import MicroBatchSpec, SequenceSample
 from areal_tpu.api.model_api import GenerationHyperparameters, TrainEngine
-from areal_tpu.base import env_registry
+from areal_tpu.base import datapack, env_registry
 from areal_tpu.base import logging as areal_logging
 from areal_tpu.base import stats_tracker, tracing
 from areal_tpu.models.config import TransformerConfig
@@ -307,7 +309,12 @@ class JaxTrainEngine(TrainEngine):
     def _build_rows(
         self, sample: SequenceSample, keys: Optional[List[str]] = None
     ) -> Tuple[PackedBatch, Dict[str, np.ndarray]]:
-        """Pack the main token key into rows; scatter/broadcast other keys."""
+        """Pack the main token key into rows; scatter/broadcast other keys.
+
+        The one rule by which this engine picks a micro-batch's (rows,
+        row length), for train steps, forward passes and the interfaces'
+        whole-batch prep alike: `datapack.ladder_shape`, as few rows as
+        hold its tokens at a row length from a short ladder."""
         main_key = sample._main_key()
         flat_main = sample.data[main_key]
         lens_per_seq: List[int] = []
@@ -318,12 +325,13 @@ class JaxTrainEngine(TrainEngine):
                 seqs.append(np.asarray(flat_main[offset : offset + l]))
                 lens_per_seq.append(l)
                 offset += l
-        batch = pack_sequences(
-            seqs,
+        n_rows, row_len = datapack.ladder_shape(
+            lens_per_seq,
             row_len_multiple=self.row_len_multiple,
             n_rows_multiple=self._n_row_multiple,
             max_row_len=self.max_row_len,
         )
+        batch = pack_sequences(seqs, row_len=row_len, n_rows=n_rows)
         rows: Dict[str, np.ndarray] = {
             "input_ids": batch.input_ids,
             "segment_ids": batch.segment_ids,
@@ -474,7 +482,8 @@ class JaxTrainEngine(TrainEngine):
                        row_keys: Tuple[str, ...], n_mbs: int,
                        scored_fn: Optional[ScoredFn] = None):
         """One fused jitted program for the whole train step: micro-batch
-        gradient accumulation (lax.scan over stacked rows), global-denom
+        gradient accumulation (a lax.scan over each stack of equal-shaped
+        micro-batches, `_stack_mb_rows`), global-denom
         normalization, grad norm, optimizer update — with params and
         optimizer state donated.
 
@@ -490,7 +499,8 @@ class JaxTrainEngine(TrainEngine):
 
         def step(params, opt_state, rows, inv_denom, lr):
             if n_mbs > 1:
-                # rows: [n_mbs, R, T]; accumulate grads in fp32.
+                # rows: [n, R, T] stacks, one a micro-batch shape;
+                # accumulate grads in fp32.
                 def body(grads_acc, mb_rows):
                     (loss, aux), g = jax.value_and_grad(mb_loss, has_aux=True)(
                         params, mb_rows
@@ -501,12 +511,15 @@ class JaxTrainEngine(TrainEngine):
                         )
                     return grads_acc, (loss, aux)
 
-                grads0 = jax.tree_util.tree_map(
+                grads = jax.tree_util.tree_map(
                     lambda p: jnp.zeros(p.shape, jnp.float32), params
                 )
-                grads, (losses, auxs) = jax.lax.scan(body, grads0, rows)
-                loss_sum = jnp.sum(losses)
-                aux = jax.tree_util.tree_map(jnp.sum, auxs)
+                sums = []
+                for stack in rows:
+                    grads, per_mb = jax.lax.scan(body, grads, stack)
+                    sums.append(jax.tree_util.tree_map(jnp.sum, per_mb))
+                loss_sum, aux = jax.tree_util.tree_map(
+                    lambda *x: functools.reduce(operator.add, x), *sums)
             else:
                 (loss_sum, aux), grads = jax.value_and_grad(mb_loss, has_aux=True)(
                     params, rows
@@ -615,23 +628,20 @@ class JaxTrainEngine(TrainEngine):
 
         return self._built(key, jax.jit(apply, donate_argnums=(0, 1, 2)))
 
+    @staticmethod
     def _stack_mb_rows(
-        self, mbs_rows: List[Dict[str, np.ndarray]]
-    ) -> Dict[str, np.ndarray]:
-        """Stack per-microbatch row dicts into [n_mbs, R_max, T_max] (pad
-        rows/tails with zeros = segment id 0 = ignored)."""
-        r_max = max(r["input_ids"].shape[0] for r in mbs_rows)
-        t_max = max(r["input_ids"].shape[1] for r in mbs_rows)
-        stacked: Dict[str, np.ndarray] = {}
-        for k in mbs_rows[0]:
-            arrs = []
-            for r in mbs_rows:
-                a = r[k]
-                pad = [(0, r_max - a.shape[0]), (0, t_max - a.shape[1])]
-                pad += [(0, 0)] * (a.ndim - 2)
-                arrs.append(np.pad(a, pad))
-            stacked[k] = np.stack(arrs, axis=0)
-        return stacked
+        mbs_rows: List[Dict[str, np.ndarray]]
+    ) -> List[Dict[str, np.ndarray]]:
+        """The fused step's input: the micro-batches' row dicts stacked
+        into one [n, R, T] dict a shape, in the order the shapes first
+        appear. The step scans each stack, so no micro-batch is padded
+        to another's shape (the packer gives a large micro-batch one row
+        of 16,384 and a small one beside it one of 1,024)."""
+        by_shape: Dict[Tuple[int, int], List[Dict[str, np.ndarray]]] = {}
+        for r in mbs_rows:
+            by_shape.setdefault(r["input_ids"].shape, []).append(r)
+        return [{k: np.stack([r[k] for r in same]) for k in same[0]}
+                for same in by_shape.values()]
 
     @staticmethod
     def _dp_token_weights(rows_np: Dict[str, np.ndarray]) -> np.ndarray:
@@ -654,41 +664,39 @@ class JaxTrainEngine(TrainEngine):
 
     def _apply_dp_token_scale(
         self,
-        rows_np: Dict[str, np.ndarray],
+        stacks: List[Dict[str, np.ndarray]],
         global_denom: float,
         dp_token_weights_fn=None,
-    ) -> Dict[str, np.ndarray]:
+    ) -> List[Dict[str, np.ndarray]]:
         """Inject a 'dp_loss_scale' rows key so global normalization equals
-        per-dp-shard normalization (see train_batch docstring). Rows are
-        sharded over (data, fsdp) in contiguous chunks; shard s's
-        denominator D_s sums its loss weights across every micro-batch
-        (the reference's per-rank denominator spans the rank's whole
-        step). Losses multiply this scale into their token mask."""
+        per-dp-shard normalization (see train_batch docstring). `stacks`
+        hold the step's micro-batches, [R, T] or [n, R, T] arrays each.
+        Rows are sharded over (data, fsdp) in contiguous chunks; shard
+        s's denominator D_s sums its loss weights across every
+        micro-batch (the reference's per-rank denominator spans the
+        rank's whole step). Losses multiply this scale into their token
+        mask."""
         n = self._n_row_multiple
         if n <= 1:
-            return rows_np  # one shard: 'dp' == 'global'
-        w = (
-            dp_token_weights_fn(rows_np)
-            if dp_token_weights_fn is not None
-            else self._dp_token_weights(rows_np)
-        ).astype(np.float32)
-        r_axis = w.ndim - 2  # [R, T] or [n_mbs, R, T]
-        R = w.shape[r_axis]
-        per_shard = w.reshape(
-            w.shape[:r_axis] + (n, R // n) + w.shape[r_axis + 1:]
-        )
+            return stacks  # one shard: 'dp' == 'global'
+        weights_fn = dp_token_weights_fn or self._dp_token_weights
+
+        def by_shard(a):  # [.., R, T] -> [.., n, R // n, T]
+            return a.reshape(a.shape[:-2] + (n, a.shape[-2] // n, a.shape[-1]))
+
+        ws = [by_shard(weights_fn(rows).astype(np.float32)) for rows in stacks]
         # D_s: sum over everything except the shard axis.
-        axes = tuple(i for i in range(per_shard.ndim) if i != r_axis)
-        d_s = np.maximum(per_shard.sum(axis=axes), 1.0)  # [n]
-        scale = global_denom / (n * d_s)  # [n]
-        shape = [1] * per_shard.ndim
-        shape[r_axis] = n
-        scale_rows = np.broadcast_to(
-            scale.reshape(shape),
-            per_shard.shape,
-        ).reshape(w.shape).astype(np.float32)
-        out = dict(rows_np)
-        out["dp_loss_scale"] = np.ascontiguousarray(scale_rows)
+        d_s = np.maximum(sum(
+            w.sum(axis=tuple(i for i in range(w.ndim) if i != w.ndim - 3))
+            for w in ws), 1.0)  # [n]
+        scale = (global_denom / (n * d_s)).astype(np.float32)
+        out = []
+        for rows, w in zip(stacks, ws):
+            rows = dict(rows)
+            rows["dp_loss_scale"] = np.ascontiguousarray(np.broadcast_to(
+                scale[:, None, None], w.shape
+            ).reshape(rows["input_ids"].shape))
+            out.append(rows)
         return out
 
     def train_batch(
@@ -788,25 +796,26 @@ class JaxTrainEngine(TrainEngine):
                 n_tok = sum(b.total_tokens for b, _ in built)
                 all_rows = [r for _, r in built]
                 if len(mbs) > 1:
-                    rows_np = self._stack_mb_rows(all_rows)
+                    stacks = self._stack_mb_rows(all_rows)
                     sharding = jax.sharding.NamedSharding(
                         self.mesh,
                         jax.sharding.PartitionSpec(None, ("data", "fsdp"), "seq"),
                     )
                 else:
-                    rows_np = all_rows[0]
+                    stacks = all_rows
                     sharding = self._batch_sharding
                 if token_normalize_scope == "dp":
-                    rows_np = self._apply_dp_token_scale(
-                        rows_np, global_denom, dp_token_weights_fn
+                    stacks = self._apply_dp_token_scale(
+                        stacks, global_denom, dp_token_weights_fn
                     )
             with tracing.span("train.h2d"):
-                rows_dev = {
-                    k: jax.device_put(np.asarray(v), sharding)
-                    for k, v in rows_np.items()
-                }
+                rows_dev = [
+                    {k: jax.device_put(np.asarray(v), sharding)
+                     for k, v in rows.items()}
+                    for rows in stacks
+                ]
             prep_ms = (time.monotonic_ns() - t_prep) / 1e6
-            n_cells = int(np.prod(rows_np["input_ids"].shape))
+            n_cells = sum(b.n_rows * b.row_len for b, _ in built)
             # Eager-path telemetry: the whole pack+stack+H2D cost blocks the
             # host before the single dispatch, so h2d_wait == dispatch gap ==
             # the prep time (nothing is hidden).
@@ -817,22 +826,30 @@ class JaxTrainEngine(TrainEngine):
                 "overlap_events": 0.0,
             }
             self._record_overlap_stats()
-            rows, row_len = rows_np["input_ids"].shape[-2:]
-            attn = self._attn_counts(rows_np["segment_ids"])
-            self._count_batch("fused", len(mbs), n_tok, n_cells, *attn[1:],
-                              *self._head_counts(rows_np, scored_fn),
-                              *self._ssm_counts(rows_np["segment_ids"]))
+            attn = [self._attn_counts(rows["segment_ids"]) for rows in stacks]
+            counts = [a[1:] + self._head_counts(rows, scored_fn)
+                      + self._ssm_counts(rows["segment_ids"])
+                      for a, rows in zip(attn, stacks)]
+            self._count_batch(
+                "fused", len(mbs), sum(b.n_rows == 1 for b, _ in built),
+                n_tok, n_cells, *(sum(c) for c in zip(*counts)))
 
             step = self._train_step_fn(
-                loss_name, loss_fn, tuple(sorted(rows_np.keys())), len(mbs),
+                loss_name, loss_fn, tuple(sorted(stacks[0].keys())), len(mbs),
                 scored_fn,
             )
+            # One program for all the micro-batches: the span says the
+            # shape of the largest.
+            big = max(range(len(stacks)),
+                      key=lambda i: np.prod(stacks[i]["input_ids"].shape[-2:]))
+            rows, row_len = stacks[big]["input_ids"].shape[-2:]
             with tracing.span(
                 "train.dispatch", kind="fused", rows=rows, row_len=row_len,
-                attn_row_len=attn[0], **self._stack_attrs,
+                attn_row_len=attn[big][0], **self._stack_attrs,
             ):
                 self.params, self.opt_state, packed, aux = step(
-                    self.params, self.opt_state, rows_dev,
+                    self.params, self.opt_state,
+                    rows_dev if len(mbs) > 1 else rows_dev[0],
                     jnp.asarray(1.0 / global_denom, jnp.float32),
                     jnp.asarray(lr, jnp.float32),
                 )
@@ -888,7 +905,7 @@ class JaxTrainEngine(TrainEngine):
         )
         carry = None
         nxt = None
-        denom_sum, n_tok, n_cells = 0.0, 0, 0
+        denom_sum, n_tok, n_cells, n_one_row = 0.0, 0, 0, 0
         # attention's cells at the run length, run, causal; the head's
         # positions read, cells run; the state-space scan's chunks, live,
         # mixed, and its resets
@@ -902,6 +919,7 @@ class JaxTrainEngine(TrainEngine):
                 n_tok += tok
                 n_cells += cells
                 rows, row_len = rows_dev["input_ids"].shape
+                n_one_row += int(rows == 1)
                 attn_row_len = counts[0]
                 n_counts = [n + c for n, c in zip(n_counts, counts[1:])]
                 if carry is None:
@@ -931,7 +949,8 @@ class JaxTrainEngine(TrainEngine):
                 jnp.asarray(1.0 / global_denom, jnp.float32),
                 jnp.asarray(lr, jnp.float32),
             )
-        self._count_batch("overlapped", n_mbs, n_tok, n_cells, *n_counts)
+        self._count_batch("overlapped", n_mbs, n_one_row, n_tok, n_cells,
+                          *n_counts)
         self.last_overlap = {
             "packing_efficiency": n_tok / max(n_cells, 1),
             "h2d_wait_ms": pf.wait_ms,
@@ -1005,14 +1024,18 @@ class JaxTrainEngine(TrainEngine):
                   for mb, s in zip(mbs, scored)]
         return tuple(int(x) for x in np.sum(counts, axis=0))
 
-    def _count_batch(self, path: str, n_mbs: int, n_tok: int, n_cells: int,
+    def _count_batch(self, path: str, n_mbs: int, n_one_row: int, n_tok: int,
+                     n_cells: int,
                      n_attn_cells: int, n_attn_active: int, n_attn_causal: int,
                      n_scored: int, n_head_cells: int, n_ssm_chunks: int = 0,
                      n_ssm_live: int = 0, n_ssm_mixed: int = 0, n_ssm_resets: int = 0):
         """What one train_batch did, on its `train.batch` span and in the
-        recorder's counters: real tokens, the cells (rows x row length)
-        they were padded to, the cells the attention kernel ran (rows
-        x the length it ran them at), the cells of the block pairs it ran
+        recorder's counters: the micro-batches and how many of them the
+        packer made one row (what lets attention skip the block pairs
+        between sequences, from 2048 cells up: ops/attention._rows_skip),
+        real tokens, the cells (rows x row length) they were padded to,
+        the cells the attention kernel ran (rows x the length it ran
+        them at), the cells of the block pairs it ran
         against those of a causal mask alone, the positions whose logprob
         the loss reads and the cells of the chunks the loss head ran for
         them, the (token, expert) pairs the routers of the expert
@@ -1020,6 +1043,7 @@ class JaxTrainEngine(TrainEngine):
         tracing.set_attrs(path=path, n_mbs=n_mbs, tokens=n_tok, cells=n_cells)
         tracing.count("train.batches")
         tracing.count("train.micro_batches", n_mbs)
+        tracing.count("train.one_row_batches", n_one_row)
         tracing.count("train.tokens", n_tok)
         tracing.count("train.cells", n_cells)
         tracing.count("train.attn_cells", n_attn_cells)

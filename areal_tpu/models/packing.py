@@ -4,6 +4,8 @@ The bridge between `SequenceSample` (packed 1D, fully dynamic) and what XLA
 wants (static shapes): sequences are FFD-packed into R rows of T tokens
 with segment ids, T bucketed (multiple of `row_len_multiple`, default 128 —
 the TPU lane width) so the number of distinct compiled shapes stays small.
+The trainer engine picks (R, T) itself (`base.datapack.ladder_shape`: as
+few rows as hold the tokens) and hands both to `pack_sequences`.
 
 Counterpart of the reference's packed varlen layout + cu_seqlens handling
 (realhf/api/core/data_api.py SequenceSample + flash-attn varlen); on TPU
@@ -55,7 +57,7 @@ class PackedBatch:
     def density(self) -> float:
         """Tokens per padded token: real tokens / the [R, T] cells this
         pack ships to the device (the realized packing efficiency; the
-        estimator counterpart is `base.datapack.packing_density`)."""
+        estimator counterpart is `base.datapack.ladder_density`)."""
         return self.total_tokens / float(self.n_rows * self.row_len)
 
     def scatter_per_token(self, values: Sequence[np.ndarray]) -> np.ndarray:
@@ -94,6 +96,7 @@ def pack_sequences(
     row_len_multiple: int = 128,
     n_rows_multiple: int = 1,
     max_row_len: Optional[int] = None,
+    n_rows: Optional[int] = None,
 ) -> PackedBatch:
     """FFD-pack sequences into rows.
 
@@ -101,6 +104,10 @@ def pack_sequences(
     `row_len_multiple` (bucketing keeps recompiles bounded).
     n_rows_multiple: pad the row count (empty rows) so R divides evenly
     across data-parallel shards.
+    n_rows: exactly this many rows, the sequences balanced over them
+    (FFD's emptiest row with room); with `row_len`, the shape
+    `base.datapack.ladder_shape` chose, which is how the trainer engine
+    packs. Raises if the rows cannot hold the sequences.
     """
     lens = [int(len(s)) for s in seqs]
     if not lens:
@@ -113,8 +120,12 @@ def pack_sequences(
     if longest > row_len:
         raise ValueError(f"sequence of length {longest} exceeds row_len {row_len}")
 
-    groups = datapack.ffd_allocate(lens, capacity=row_len, min_groups=1)
-    n_rows = _round_up(len(groups), n_rows_multiple)
+    groups = datapack.ffd_allocate(lens, capacity=row_len, min_groups=n_rows or 1)
+    if n_rows is None:
+        n_rows = _round_up(len(groups), n_rows_multiple)
+    elif len(groups) > n_rows:
+        raise ValueError(
+            f"{n_rows} rows of {row_len} cannot hold sequences of lengths {lens}")
 
     input_ids = np.zeros((n_rows, row_len), dtype=np.int32)
     segment_ids = np.zeros((n_rows, row_len), dtype=np.int32)

@@ -322,7 +322,8 @@ class ModelWorker(Worker):
             # engine records the REALIZED density of what it shipped to
             # HBM (tracked export above); when it did not run a packed
             # path (mock engines, custom interfaces) fall back to the
-            # analytic FFD estimate over this MFC's input lengths.
+            # estimate of the engine's own rule over this MFC's input
+            # lengths (`datapack.ladder_density`).
             # Generate MFCs are deliberately excluded — the serving
             # engine admits prompts into a paged pool, so a row-pack
             # density over its inputs would be a made-up number.
@@ -335,9 +336,10 @@ class ModelWorker(Worker):
             ):
                 from areal_tpu.base import datapack
 
-                stats["perf/packing_efficiency"] = datapack.packing_density(
+                stats["perf/packing_efficiency"] = datapack.ladder_density(
                     in_lens,
                     row_len_multiple=row_mult,
+                    n_rows_multiple=getattr(model.module, "_n_row_multiple", 1),
                     max_row_len=getattr(model.module, "max_row_len", None),
                 )
             out_lens = None

@@ -59,6 +59,9 @@ def recorded(request):
         eng.train_batch(*args, loss_name="t")
     finally:
         got = tracing.stop()
+    # the (rows, row length) the engine's packer gives each micro-batch
+    got["shapes"] = [eng._build_rows(mb)[0].input_ids.shape
+                     for mb in batch.split(MicroBatchSpec(n_mbs=N_MBS))[0]]
     return path, got, off, dict(eng.last_overlap), threading.get_ident() & 0xFFFF
 
 
@@ -99,13 +102,18 @@ def test_attributes_and_counters_count_tokens_and_cells(recorded):
     assert a["path"] == path and a["n_mbs"] == N_MBS
     assert 0 < a["tokens"] <= a["cells"]
     dispatches = [s["attrs"] for s in spans if s["name"] == "train.dispatch"]
+    shapes = got["shapes"]
+    # one program a micro-batch, or one for all of them that says the
+    # shape of the largest
+    assert [(d["rows"], d["row_len"]) for d in dispatches] == (
+        shapes if path == "overlapped" else [max(shapes, key=np.prod)])
     # the reference runs on the CPU, at the rows' own length and over
     # every cell of a row, in each layer, whatever the mask
-    all_cells = sum(
-        (N_MBS if d["kind"] == "fused" else 1) * d["rows"] * d["row_len"] ** 2
-        for d in dispatches) * N_LAYERS
+    all_cells = sum(r * t ** 2 for r, t in shapes) * N_LAYERS
+    assert a["cells"] == sum(r * t for r, t in shapes)
     assert got["counters"] == {
         "train.batches": 1, "train.micro_batches": N_MBS,
+        "train.one_row_batches": sum(r == 1 for r, _ in shapes),
         "train.tokens": a["tokens"], "train.cells": a["cells"],
         "train.attn_cells": a["cells"],
         "train.attn_active_cells": all_cells,
@@ -163,16 +171,16 @@ def test_programs_built_counts_new_jit_cache_entries_and_stale_fetches_are_marke
 
 @pytest.mark.parametrize("impl", ["splash", "reference"])
 def test_attn_cells_count_the_length_the_kernel_runs_at(impl):
-    """Rows of 640 (5 blocks of 128): splash pads them to a length whose
+    """A row of 640 (5 blocks of 128): splash pads it to a length whose
     blocks are large and `train.attn_cells` counts rows x that length;
-    the reference runs them as they are."""
+    the reference runs it as it is."""
     from areal_tpu.ops.attention import splash_run_shape
 
     eng = mk_engine(init_params(small_cfg(), jax.random.PRNGKey(7)), depth=0,
                     attn_impl=impl)
     eng.row_len_multiple = 128
     rng = np.random.RandomState(7)
-    seqlens = [600, 590, 300]
+    seqlens = [300, 200, 100]
     total = sum(seqlens)
     batch = SequenceSample.from_default(
         ids=[f"a{i}" for i in range(3)], seqlens=seqlens,
