@@ -126,13 +126,17 @@ def _kinds_label(cfg: TransformerConfig) -> str:
     """The stack's layer kinds in order, runs of equal kinds folded:
     `dense.w2048.rope,moe.w2048.rope x2,moe.full.nope` for transformer
     blocks (named by their MLP); a layer of one part is `ssm`, `moe`,
-    `dense` or `attn.full.nope`."""
+    `dense` or `attn.full.nope`. Differential attention says `diff.`, a
+    layer that reads layer n's tensor `<n`, one that keeps its own `^`:
+    `ssm+dense^`, `dense.diff.full.nope<5`, `gmu+dense<4`."""
     def name(k):
-        attn = (f"{'full' if k.window is None else 'w%d' % k.window}."
+        attn = (f"{'diff.' if k.diff else ''}"
+                f"{'full' if k.window is None else 'w%d' % k.window}."
                 f"{'rope' if k.rotary else 'nope'}")
+        keeps, reads = "^" if k.keeps else "", "" if k.reads is None else f"<{k.reads}"
         if k.block:
-            return f"{k.mlp}.{attn}"
-        return f"attn.{attn}" if k.mixer == "attention" else k.parts
+            return f"{k.mlp}.{attn}{keeps}{reads}"
+        return (f"attn.{attn}{keeps}" if k.mixer == "attention" else k.parts) + reads
 
     names = [name(k) for k in cfg.kinds()]
     out = []
@@ -994,7 +998,8 @@ class JaxTrainEngine(TrainEngine):
         """What the state-space layers' chunked scan does with packed rows
         (on the host, before the transfer; `segment_ids` [R, T] of one
         micro-batch or [n, R, T] of several), by the device's own rule
-        (ops/ssm.chunk_counts), summed over those layers: (the chunks it
+        (ops/ssm.chunk_counts; for the selective scan a chunk is the
+        kernel's block of time), summed over those layers: (the chunks it
         runs, those that hold a token, those that hold a sequence start
         after their first cell, sequence starts)."""
         n = self.model_cfg.n_ssm_layers
@@ -1039,7 +1044,8 @@ class JaxTrainEngine(TrainEngine):
         against those of a causal mask alone, the positions whose logprob
         the loss reads and the cells of the chunks the loss head ran for
         them, the (token, expert) pairs the routers of the expert
-        layers made, and the chunks the state-space layers' scan ran."""
+        layers made, and the chunks the state-space layers' scan ran
+        (the selective scan's also as positions: chunks x their length)."""
         tracing.set_attrs(path=path, n_mbs=n_mbs, tokens=n_tok, cells=n_cells)
         tracing.count("train.batches")
         tracing.count("train.micro_batches", n_mbs)
@@ -1057,6 +1063,9 @@ class JaxTrainEngine(TrainEngine):
             tracing.count("train.moe_pairs",
                           moe.top_k * n_tok * self.model_cfg.n_moe_layers)
         if self.model_cfg.n_ssm_layers:
+            ssm = self.model_cfg.ssm
+            if ssm.form == "mamba1":  # positions the scan kernel walks
+                tracing.count("train.sscan_cells", n_ssm_chunks * ssm.chunk_size)
             tracing.count("train.ssm_chunks", n_ssm_chunks)
             tracing.count("train.ssm_chunks_live", n_ssm_live)
             tracing.count("train.ssm_chunks_mixed", n_ssm_mixed)

@@ -51,15 +51,19 @@ def make_lr_schedule(cfg: OptimizerConfig, total_train_steps: int):
     )
 
 
-# A state-space mixer's per-head and per-channel parameters (ops/ssm.py):
+# A state-space mixer's per-head and per-channel parameters (ops/ssm.py,
+# ops/selective_scan.py: there `A_log` is [channels, states], rates all
+# the same) and differential attention's lambda vectors and inner norm:
 # stacked on a layer axis they have two dimensions and are no matrices.
-NO_DECAY_LEAVES = ("A_log", "D", "dt_bias", "conv_b")
+NO_DECAY_LEAVES = ("A_log", "D", "dt_bias", "conv_b", "lambda_q1", "lambda_k1",
+                   "lambda_q2", "lambda_k2", "sub_norm")
 
 
 def _decay_mask(params):
     """No weight decay on 1D params (norms, biases) — standard practice —
     nor, by leaf name, on a state-space mixer's decay rates, skip
-    weights, step biases and convolution bias."""
+    weights, step biases and convolution bias, nor on differential
+    attention's lambda vectors and inner norm."""
     import jax
 
     return jax.tree_util.tree_map_with_path(

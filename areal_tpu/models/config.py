@@ -5,8 +5,10 @@ covering the same architecture space: GQA attention, rotary variants,
 RMS/LayerNorm, gated MLPs, optional MoE, actor (LM head) or critic (scalar
 head) outputs, tied embeddings, and qk-norm (qwen3); and, beyond it, a
 kind per layer (`LayerKind`: the parts a layer has: a mixer, attention
-with its window and rotary or a state-space mixer, and an MLP, dense or
-expert, either of which may be absent), an attention output gate,
+with its window and rotary, differential or not, a state-space mixer in
+one of two forms or a gated memory unit, and an MLP, dense or expert,
+either of which may be absent; a layer may keep a tensor that later
+layers read), an attention output gate,
 post-norms, a sigmoid router with a selection bias, shared experts and a
 share of the experts held here.
 """
@@ -103,12 +105,20 @@ class MoEConfig:
 
 @dataclasses.dataclass
 class SSMConfig:
-    """A state-space mixer (Mamba-2 form, ops/ssm.py): `n_heads` heads of
-    `head_dim` channels, each with a state of `state_dim`, whose B and C
-    are shared by the heads of one of `n_groups` groups; a causal
-    depthwise convolution of `conv_kernel` taps before it; computed in
-    chunks of `chunk_size` positions."""
+    """A state-space mixer, in one of two forms. "mamba2" (ops/ssm.py):
+    `n_heads` heads of `head_dim` channels, each with a state of
+    `state_dim`, whose B and C are shared by the heads of one of
+    `n_groups` groups, and a scalar decay a head, so a chunk is matrix
+    products. "mamba1" (ops/selective_scan.py): `channels` channels with
+    a state of `state_dim` each and a decay for every channel and state,
+    B and C shared by all channels, the step size through a projection
+    of rank `dt_rank`; walked token by token. Both: a causal depthwise
+    convolution of `conv_kernel` taps before it; computed in chunks of
+    `chunk_size` positions (mamba1: the kernel's block of time)."""
 
+    form: str = "mamba2"
+    channels: Optional[int] = None  # mamba1
+    dt_rank: Optional[int] = None  # mamba1
     n_heads: int = 8
     head_dim: int = 16
     n_groups: int = 1
@@ -123,11 +133,18 @@ class SSMConfig:
     dt_floor: float = 1e-4
 
     def __post_init__(self):
+        if self.form not in ("mamba2", "mamba1"):
+            raise ValueError(
+                f"SSMConfig.form must be 'mamba2' or 'mamba1', got {self.form!r}")
+        if self.form == "mamba1" and not (self.channels and self.dt_rank):
+            raise ValueError("SSMConfig.form 'mamba1' needs channels and dt_rank")
         if self.n_heads % self.n_groups != 0:
             raise ValueError("SSMConfig.n_heads must be a multiple of n_groups")
 
     @property
     def d_inner(self) -> int:
+        if self.form == "mamba1":
+            return self.channels
         return self.n_heads * self.head_dim
 
     @property
@@ -145,39 +162,73 @@ class SSMConfig:
 class LayerKind:
     """What one layer of the stack is, known when the program is traced:
     the parts it has, each under its own norm with its own residual. A
-    mixer (`mixer`: "attention", "ssm" or None) and an MLP (`mlp`:
+    mixer (`mixer`: "attention", "ssm", "gmu" or None) and an MLP (`mlp`:
     "dense", "moe" or None); a transformer block has both, a layer may
     have one. For attention: its mask (`window` = how many positions
-    back a token sees, itself included; None = all of its sequence) and
+    back a token sees, itself included; None = all of its sequence),
     whether q and k get the rotary embedding (False = no position
-    encoding in this layer)."""
+    encoding in this layer), and `diff`: differential attention, two
+    softmaxes a pair of heads, the second subtracted from the first
+    (`transformer._diff_combine`).
+
+    Two relations between layers. `keeps`: the layer hands a tensor on
+    to later layers: an "ssm" mixer its scan's output before the gate,
+    attention its k and v. `reads`: the index of the keeping layer whose
+    tensor this layer reads: attention then has no k and v of its own
+    (cross-attention over that layer's), and a "gmu" mixer (a gated
+    memory unit: `(silu(u W_1) * m) W_2`, no recurrence of its own)
+    always reads a scan's output m."""
 
     mlp: Optional[str] = "dense"
     window: Optional[int] = None
     rotary: bool = True
     mixer: Optional[str] = "attention"
+    diff: bool = False
+    keeps: bool = False
+    reads: Optional[int] = None
 
     def __post_init__(self):
         if self.mlp not in ("dense", "moe", None):
             raise ValueError(
                 f"LayerKind.mlp must be 'dense', 'moe' or None, got {self.mlp!r}")
-        if self.mixer not in ("attention", "ssm", None):
+        if self.mixer not in ("attention", "ssm", "gmu", None):
             raise ValueError(
-                f"LayerKind.mixer must be 'attention', 'ssm' or None, got {self.mixer!r}")
+                "LayerKind.mixer must be 'attention', 'ssm', 'gmu' or None, "
+                f"got {self.mixer!r}")
         if self.mixer is None and self.mlp is None:
             raise ValueError("a LayerKind needs a mixer or an MLP")
         if self.window is not None and self.window < 1:
             raise ValueError(f"LayerKind.window must be >= 1, got {self.window}")
-        if self.mixer != "attention" and (self.window is not None or not self.rotary):
+        if self.mixer != "attention" and (
+                self.window is not None or not self.rotary or self.diff):
             # one spelling a kind: layers without attention compare equal
-            raise ValueError("window and rotary describe an attention mixer")
+            raise ValueError("window, rotary and diff describe an attention mixer")
+        if self.keeps and (self.mixer not in ("attention", "ssm")
+                           or self.reads is not None):
+            raise ValueError(
+                "keeps: an 'ssm' mixer keeps its scan's output, attention with "
+                "k and v of its own keeps them; nothing else has what to keep")
+        if self.mixer != "attention" and (self.reads is not None) != (self.mixer == "gmu"):
+            raise ValueError(
+                "reads: a 'gmu' mixer always reads a kept scan output, attention "
+                "may read a kept k and v, no other mixer reads")
 
     @property
     def parts(self) -> str:
-        """The layer's parts, which decide its parameters' structure:
-        layers with the same parts share a stack. "attention+moe",
-        "ssm", "moe", ..."""
-        return "+".join(p for p in (self.mixer, self.mlp) if p)
+        """The layer's parts, which decide its parameters' structure and
+        the stack they live in: layers with the same parts share a
+        stack. "attention+moe", "ssm", "moe", "diffattention+dense",
+        "xdiffattention+dense" (x: q and the output projection only),
+        "gmu+dense", ...; a layer that `keeps` adds "^" ("ssm+dense^"):
+        it runs on its own, never as a repeat of a scan, so its
+        parameters are a stack of their own, which `forward` takes
+        whole (a stack cut between segments is copied, and so are the
+        gradient's pieces joined)."""
+        mixer = self.mixer
+        if mixer == "attention":
+            mixer = ("x" if self.reads is not None else "") + (
+                "diff" if self.diff else "") + "attention"
+        return "+".join(p for p in (mixer, self.mlp) if p) + ("^" if self.keeps else "")
 
     @property
     def block(self) -> bool:
@@ -197,10 +248,11 @@ class Segment:
     repeats: int
 
 
-def segments_of(parts: Tuple[str, ...]) -> Tuple[Segment, ...]:
+def segments_of(parts: Tuple[str, ...], min_repeats: int = 2) -> Tuple[Segment, ...]:
     """The pattern of a stack cut into segments, greedily from the front:
-    at each layer the unit whose immediate repeats cover most layers (the
-    shortest such), or the layer alone when nothing repeats. `M E M E M *
+    at each layer the unit whose immediate repeats (`min_repeats` of them
+    or more) cover most layers (the shortest such), or the layer alone
+    when nothing repeats that often. `M E M E M *
     E M E` is (M E) x 2, then M, *, E, M, E one by one; twelve equal
     layers are one unit x 12; a different first layer stands alone. What
     is traced grows with the runs of the pattern, not with the depth."""
@@ -211,7 +263,7 @@ def segments_of(parts: Tuple[str, ...]) -> Tuple[Segment, ...]:
             r = 1
             while parts[i + r * p: i + (r + 1) * p] == parts[i: i + p]:
                 r += 1
-            if r > 1 and r * p > best_r * best_p:
+            if r >= max(min_repeats, 2) and r * p > best_r * best_p:
                 best_p, best_r = p, r
         out.append(Segment(i, tuple(parts[i: i + best_p]), best_r))
         i += best_p * best_r
@@ -264,6 +316,12 @@ class TransformerConfig:
     # config; None = every layer the same (moe or dense by `moe`, full
     # causal attention, rotary by `pos_emb`).
     layer_kinds: Optional[Tuple[LayerKind, ...]] = None
+    # A unit of layers that repeats fewer times than this runs layer by
+    # layer, not as a scan. A scan's weight gradients are whole stacks
+    # that live until its backward pass ends; a layer's own are added to
+    # the gradient sums at once. Two repeats of wide layers save little
+    # tracing and may cost memory a chip does not have.
+    scan_min_repeats: int = 2
 
     # Numerics: params kept in param_dtype, compute in compute_dtype.
     param_dtype: str = "float32"
@@ -296,8 +354,24 @@ class TransformerConfig:
         kinds = self.kinds()
         if any(k.mlp == "moe" for k in kinds) and self.moe is None:
             raise ValueError("a layer of kind 'moe' needs TransformerConfig.moe")
-        if any(k.mixer == "ssm" for k in kinds) and self.ssm is None:
-            raise ValueError("a layer with an 'ssm' mixer needs TransformerConfig.ssm")
+        if any(k.mixer in ("ssm", "gmu") for k in kinds) and self.ssm is None:
+            raise ValueError(
+                "a layer with an 'ssm' or 'gmu' mixer needs TransformerConfig.ssm")
+        for i, k in enumerate(kinds):
+            if k.reads is None:
+                continue
+            kept = kinds[k.reads] if 0 <= k.reads < i else None
+            want = "ssm" if k.mixer == "gmu" else "attention"
+            if kept is None or not kept.keeps or kept.mixer != want:
+                raise ValueError(
+                    f"layer {i} reads layer {k.reads}, which is no earlier "
+                    f"{want!r} layer that keeps its tensor")
+            if k.diff != kept.diff and want == "attention":
+                raise ValueError(
+                    f"layer {i} reads the k and v of layer {k.reads}: both "
+                    "differential or neither")
+        if any(k.diff for k in kinds) and (self.n_q_heads % 2 or self.n_kv_heads % 2):
+            raise ValueError("differential attention pairs the heads: even counts")
 
     @property
     def q_dim(self) -> int:
@@ -353,7 +427,7 @@ class TransformerConfig:
                 for parts, idx in by_parts.items()}
 
     def segments(self) -> Tuple[Segment, ...]:
-        return segments_of(tuple(k.parts for k in self.kinds()))
+        return segments_of(tuple(k.parts for k in self.kinds()), self.scan_min_repeats)
 
     @property
     def n_moe_layers(self) -> int:
@@ -380,12 +454,29 @@ class TransformerConfig:
         missing = []
         kinds = self.kinds()
         if any(k.mixer == "ssm" for k in kinds):
+            state = ("[channels, state_dim], a decay for every channel and state"
+                     if self.ssm.form == "mamba1" else "[heads, head_dim, state_dim]")
             missing.append(
                 "a recurrent state beside the KV pages: a state-space layer "
-                "keeps, a sequence, its state [heads, head_dim, state_dim] and "
+                f"keeps, a sequence, its state {state} and "
                 "the last conv_kernel - 1 inputs of its convolution, which the "
                 "cache manager has no slot for, no snapshot of for an "
                 "interrupted rollout to resume from, and no decode step"
+            )
+        if any(k.reads is not None or k.keeps for k in kinds):
+            missing.append(
+                "one layer's tensors shared by many: the layers "
+                f"{[i for i, k in enumerate(kinds) if k.reads is not None]} read "
+                "the k and v, or the scan output, that layers "
+                f"{sorted({k.reads for k in kinds if k.reads is not None})} keep; "
+                "the cache paths give every layer KV pages of its own and have "
+                "no slot for a kept scan output a position"
+            )
+        if any(k.diff for k in kinds):
+            missing.append(
+                "the differential combine in the decode layer: two softmaxes a "
+                "pair of heads over q, k of one size and v of twice it, their "
+                "difference under a norm of its own"
             )
         if not all(k.block for k in kinds):
             missing.append(

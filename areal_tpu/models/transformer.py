@@ -9,9 +9,14 @@ real_llm_base.py (blocks) — redesigned for XLA rather than translated:
   HBM weight streaming across layers.
 - **Layers of several kinds in one stack** (`config.LayerKind`: the
   parts a layer has, each under its own norm with its own residual: a
-  mixer, attention with a window or none and rotary or none, or a
-  state-space mixer, `ops/ssm.py`; and an MLP, dense or expert; all
-  static). Layers with the same parts share a parameter stack
+  mixer, attention with a window or none and rotary or none,
+  differential or not, a state-space mixer, `ops/ssm.py` or
+  `ops/selective_scan.py`, or a gated memory unit; and an MLP, dense or
+  expert; all static). A layer may keep a tensor (its scan's output, its
+  k and v) that later layers read: it travels beside the residual
+  stream, an input of each reader's checkpointed body and a constant of
+  a scan over readers, so the backward pass holds it once. Layers with
+  the same parts share a parameter stack
   (`config.stack_paths`: `layers` and, for leading blocks of another
   kind, `lead_layers`; `stacks/<parts>` for any other pattern), and the
   forward pass walks the pattern in segments (`config.segments_of`): a
@@ -80,6 +85,14 @@ def _init_layer_stack(cfg: TransformerConfig, keys, n: int, kind: LayerKind,
             attn["bq"] = jnp.zeros((L, cfg.q_dim), pdt)
             attn["bk"] = jnp.zeros((L, cfg.kv_dim), pdt)
             attn["bv"] = jnp.zeros((L, cfg.kv_dim), pdt)
+        if kind.reads is not None:  # another layer's k and v
+            for name in ("wk", "wv", "bk", "bv"):
+                attn.pop(name, None)
+        if kind.diff:
+            for n, name in enumerate(DIFF_LAMBDAS):
+                attn[name] = dense(jax.random.fold_in(keys[15], n),
+                                   (L, cfg.head_dim), 0.1)
+            attn["sub_norm"] = jnp.ones((L, 2 * cfg.head_dim), pdt)
         if cfg.attn_out_bias:
             attn["bo"] = jnp.zeros((L, D), pdt)
         if cfg.qk_norm:
@@ -88,10 +101,18 @@ def _init_layer_stack(cfg: TransformerConfig, keys, n: int, kind: LayerKind,
         if cfg.attn_gate:
             attn["wg"] = dense(keys[10], (L, D, cfg.q_dim))
         layers["attn"] = attn
+    elif kind.mixer == "ssm" and cfg.ssm.form == "mamba1":
+        from areal_tpu.ops.selective_scan import init_sscan_params
+
+        layers["ssm"] = init_sscan_params(cfg.ssm, D, dense, keys[13], L, pdt)
     elif kind.mixer == "ssm":
         from areal_tpu.ops.ssm import init_ssm_params
 
         layers["ssm"] = init_ssm_params(cfg.ssm, D, dense, keys[13], L, pdt)
+    elif kind.mixer == "gmu":
+        k_in, k_out = jax.random.split(jax.random.fold_in(keys[15], 8))
+        layers["gmu"] = {"w_in": dense(k_in, (L, D, cfg.ssm.d_inner)),
+                         "w_out": dense(k_out, (L, cfg.ssm.d_inner, D))}
     if kind.mixer is not None:
         norms += ["ln1"] + (["ln1_post"] if cfg.post_norms else [])
 
@@ -129,6 +150,16 @@ def _init_layer_stack(cfg: TransformerConfig, keys, n: int, kind: LayerKind,
         if cfg.norm_type == "layer":
             layers[name]["bias"] = jnp.zeros((L, D), pdt)
     return layers
+
+
+# A differential attention layer's four vectors of head_dim:
+# lambda = exp(q1 . k1) - exp(q2 . k2) + lambda_init(depth).
+DIFF_LAMBDAS = ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2")
+
+
+def diff_lambda_init(layer_idx):
+    """0.8 - 0.6 exp(-0.3 depth): 0.2 at the first layer, towards 0.8."""
+    return 0.8 - 0.6 * math.exp(-0.3 * layer_idx)
 
 
 def init_params(cfg: TransformerConfig, rng: jax.Array) -> Params:
@@ -281,9 +312,39 @@ def _attention_kernel(q, k, v, segment_ids, positions, impl, cfg, mesh, window):
     return out
 
 
+def _diff_split(q, k, v):
+    """Differential attention's heads, for one kernel call. The heads
+    come in consecutive pairs: q1, q2 the even and odd q heads, k1, k2
+    likewise, v the pairs joined to heads of twice the size. Returns q
+    as [q1 heads | q2 heads], k as [k1 heads | k2 heads] and v twice:
+    under the kernel's own grouping (q head h on kv head h // group) q1's
+    pair p then meets k1 of kv pair p // group, and q2's the k2 of it."""
+    R, T, hq, hd = q.shape
+    hkv = k.shape[2]
+    q = q.reshape(R, T, hq // 2, 2, hd)
+    k = k.reshape(R, T, hkv // 2, 2, hd)
+    v = v.reshape(R, T, hkv // 2, 2 * hd)
+    return (jnp.concatenate([q[:, :, :, 0], q[:, :, :, 1]], axis=2),
+            jnp.concatenate([k[:, :, :, 0], k[:, :, :, 1]], axis=2),
+            jnp.concatenate([v, v], axis=2))
+
+
+def _diff_combine(out, lp, l0, eps):
+    """out [R, T, Hq, 2 hd], the q1 heads' softmax(q1 k1) v then the q2
+    heads' softmax(q2 k2) v -> RMSNorm_2hd(A1 - lambda A2) * (1 - l0),
+    [R, T, Hq / 2, 2 hd]; lambda = exp(lq1 . lk1) - exp(lq2 . lk2) + l0."""
+    f32 = jnp.float32
+    lq1, lk1, lq2, lk2 = (lp[name].astype(f32) for name in DIFF_LAMBDAS)
+    lam = jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2)) + l0
+    half = out.shape[2] // 2
+    a = out[:, :, :half].astype(f32) - lam * out[:, :, half:].astype(f32)
+    a = rms_norm(a, lp["sub_norm"], eps) * (1.0 - l0)
+    return a.astype(out.dtype)
+
+
 def _attention_block(
     x, lp, cfg, cos, sin, segment_ids, positions, attn_impl, cdt, mesh=None,
-    variants=((None, True),), variant_index=None,
+    variants=((None, True),), variant_index=None, l0=None, kv=None,
 ):
     """x: [R, T, D] -> attention output [R, T, D]. Named scopes say in
     the device trace which part an op belongs to: `attn_qkv`
@@ -294,21 +355,31 @@ def _attention_block(
     this stack have: a window limits a token to the `window` positions
     ending at it, no rotary leaves q and k without a position encoding.
     One variant is called as it is; of several, `variant_index` (traced,
-    scanned beside the layer's parameters) picks the one that runs."""
+    scanned beside the layer's parameters) picks the one that runs.
+    `l0` (the layer's lambda_init; None = plain attention) makes it
+    differential: `_diff_split` before the kernel, `_diff_combine`
+    (scope `attn_diff`) after. `kv` = another layer's k and v, as that
+    layer returned them: this layer then projects q only."""
     from areal_tpu.ops.attention import resolve_attn_impl
 
     R, T, D = x.shape
     with jax.named_scope("attn_qkv"):
         q = x @ lp["wq"].astype(cdt)
-        k = x @ lp["wk"].astype(cdt)
-        v = x @ lp["wv"].astype(cdt)
+        if kv is None:
+            k = x @ lp["wk"].astype(cdt)
+            v = x @ lp["wv"].astype(cdt)
         if "bq" in lp:
             q = q + lp["bq"].astype(cdt)
-            k = k + lp["bk"].astype(cdt)
-            v = v + lp["bv"].astype(cdt)
+            if kv is None:
+                k = k + lp["bk"].astype(cdt)
+                v = v + lp["bv"].astype(cdt)
         q = q.reshape(R, T, cfg.n_q_heads, cfg.head_dim)
-        k = k.reshape(R, T, cfg.n_kv_heads, cfg.head_dim)
-        v = v.reshape(R, T, cfg.n_kv_heads, cfg.head_dim)
+        if kv is None:
+            k = k.reshape(R, T, cfg.n_kv_heads, cfg.head_dim)
+            v = v.reshape(R, T, cfg.n_kv_heads, cfg.head_dim)
+        else:
+            k, v = kv
+        own_kv = (k, v)
         if cfg.qk_norm:
             q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
             k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
@@ -338,11 +409,18 @@ def _attention_block(
 
         return run
 
+    if l0 is not None:
+        with jax.named_scope("attn_qkv"):
+            q, k, v = _diff_split(q, k, v)
     if len(variants) == 1:
         out, k = attend(*variants[0])(q, k, v)
     else:
         out, k = jax.lax.switch(
             variant_index, [attend(*vt) for vt in variants], q, k, v)
+    if l0 is not None:
+        with jax.named_scope("attn_diff"):
+            out = _diff_combine(out, lp, l0, cfg.norm_eps)
+        k, v = own_kv
     with jax.named_scope("attn_out"):
         out = out.reshape(R, T, cfg.q_dim)
         if "wg" in lp:
@@ -531,18 +609,21 @@ def forward(
             moe_fn = jax.checkpoint(moe_fn)
 
     def layer_body(kind, variants):
-        """carry, (one layer's parameters, which of `variants` it is) ->
-        carry, its (k, v): a layer with the parts of `kind` whose
+        """carry, (one layer's parameters, which of `variants` it is),
+        the tensor it reads -> carry, its (k, v) (for a layer that keeps:
+        what it keeps): a layer with the parts of `kind` whose
         attention is one of the (window, rotary) `variants`, under the
         remat mode. Layers that differ only in their attention share the
         one traced body: the switch is around the attention call alone
         (`_attention_block`), so what the backward pass keeps of a layer
         is one layer's, whatever its kind."""
 
-        def body(carry, xs):
+        def body(carry, xs, kept=None):
             lp, variant_index = xs
             x, aux_acc = carry
-            kv = None
+            kv = l0 = None
+            if kind.diff:  # beside the variant, the layer's lambda_init
+                variant_index, l0 = variant_index["variant"], variant_index["l0"]
             if kind.mixer == "attention":
                 with jax.named_scope("attn_qkv"):
                     h = _norm(x, lp["ln1"], cfg)
@@ -550,11 +631,25 @@ def forward(
                     h, lp["attn"], cfg, cos, sin,
                     segment_ids, positions, attn_impl, cdt, mesh=mesh,
                     variants=variants, variant_index=variant_index,
+                    l0=l0, kv=kept,
                 )
                 with jax.named_scope("attn_out"):
                     if "ln1_post" in lp:
                         a = _norm(a, lp["ln1_post"], cfg)
                     x = x + a
+            elif kind.mixer == "ssm" and cfg.ssm.form == "mamba1":
+                from areal_tpu.ops.selective_scan import sscan_mixer
+
+                with jax.named_scope("sscan_in_proj"):
+                    h = _norm(x, lp["ln1"], cfg)
+                a, kv = sscan_mixer(h, lp["ssm"], cfg.ssm, segment_ids, cdt, mesh=mesh)
+                with jax.named_scope("sscan_out_proj"):
+                    x = x + a
+            elif kind.mixer == "gmu":
+                with jax.named_scope("gmu"):
+                    h = _norm(x, lp["ln1"], cfg)
+                    g = jax.nn.silu(h @ lp["gmu"]["w_in"].astype(cdt))
+                    x = x + (g * kept) @ lp["gmu"]["w_out"].astype(cdt)
             elif kind.mixer == "ssm":
                 from areal_tpu.ops.ssm import ssm_mixer
 
@@ -578,7 +673,7 @@ def forward(
                     if "ln2_post" in lp:
                         m = _norm(m, lp["ln2_post"], cfg)
                     x = act_c(x + m)
-            return (x, aux_acc), kv if return_kv else None
+            return (x, aux_acc), kv if return_kv or kind.keeps else None
 
         if remat_mode == "full":
             return jax.checkpoint(body)
@@ -596,34 +691,51 @@ def forward(
     from areal_tpu.models.moe import moe_aux_zeros
 
     carry, kvs = (x, moe_aux_zeros(cfg)), None
+    kept: Dict[int, Any] = {}  # keeping layer -> its tensor, for its readers
     for seg, stacks in _segment_stacks(params, cfg):
         # One body a position of the unit: its layers, one a repeat,
         # differ at most in their attention, and which each has is
         # scanned beside its parameters (nothing, where all have the same).
-        p, bodies, which = len(seg.unit), [], []
+        p, bodies, which, reads = len(seg.unit), [], [], []
         for j in range(p):
-            of_j = kinds[seg.start + j: seg.start + p * seg.repeats: p]
+            idx = range(seg.start + j, seg.start + p * seg.repeats, p)
+            of_j = [kinds[i] for i in idx]
             rest = [(k.window, k.rotary) for k in of_j]
             variants = tuple(sorted(set(rest), key=rest.index))
             bodies.append(layer_body(of_j[0], variants))
-            which.append(None if len(variants) == 1 else jnp.asarray(
-                [variants.index(v) for v in rest], jnp.int32))
+            w = None if len(variants) == 1 else jnp.asarray(
+                [variants.index(v) for v in rest], jnp.int32)
+            if of_j[0].diff:
+                l0 = jnp.asarray([diff_lambda_init(i) for i in idx], jnp.float32)
+                w = dict(variant=w, l0=l0 if seg.repeats > 1 else l0[0])
+            which.append(w)
+            if len({k.reads for k in of_j}) > 1 or (
+                    seg.repeats > 1 and any(k.keeps for k in of_j)):
+                raise NotImplementedError(
+                    "layers of one scan that read different layers' tensors, or "
+                    "one that keeps its own: a kept tensor is a constant of the "
+                    "scan over its readers, and a keeping layer runs on its own")
+            reads.append(None if of_j[0].reads is None else kept[of_j[0].reads])
         if seg.repeats == 1:  # as they are, one by one
-            for body, lp in zip(bodies, stacks):
-                carry, _ = body(carry, (lp, None))
+            for j, (body, lp) in enumerate(zip(bodies, stacks)):
+                carry, out = body(carry, (lp, which[j]), reads[j])
+                if kinds[seg.start + j].keeps:
+                    kept[seg.start + j] = out
         elif p > 1:
-            def unit(c, xs, bodies=bodies):
-                for body, layer in zip(bodies, xs):
-                    c, _ = body(c, layer)
+            def unit(c, xs, bodies=bodies, reads=reads):
+                for body, layer, read in zip(bodies, xs, reads):
+                    c, _ = body(c, layer, read)
                 return c, None
 
             carry, _ = jax.lax.scan(unit, carry, tuple(zip(stacks, which)))
         elif which[0] is None:
-            body = bodies[0]
+            body, read = bodies[0], reads[0]
             carry, kvs = jax.lax.scan(
-                lambda c, lp: body(c, (lp, None)), carry, stacks[0])
+                lambda c, lp: body(c, (lp, None), read), carry, stacks[0])
         else:
-            carry, kvs = jax.lax.scan(bodies[0], carry, (stacks[0], which[0]))
+            body, read = bodies[0], reads[0]
+            carry, kvs = jax.lax.scan(
+                lambda c, xs: body(c, xs, read), carry, (stacks[0], which[0]))
     x, moe_aux = carry
     with jax.named_scope("final_norm"):
         x = _norm(x, params["final_norm"], cfg)

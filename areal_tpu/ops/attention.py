@@ -73,7 +73,7 @@ def reference_packed_attention(
     # Fully-masked (padding) rows: zero out.
     probs = jnp.where(mask.any(axis=-1)[None, None, :, None], probs, 0.0)
     out = jnp.einsum("hgqk,khd->qhgd", probs, vf)
-    return out.reshape(T, Hq, hd).astype(q.dtype)
+    return out.reshape(T, Hq, v.shape[-1]).astype(q.dtype)
 
 
 def decode_attention(
@@ -510,8 +510,8 @@ def _splash_row(q, k, v, segment_ids, *, run_shape, interpret, window, skip,
     vh = v.transpose(1, 0, 2)
     ids = sk.SegmentIds(q=segment_ids, kv=segment_ids)
     out = jax.vmap(lambda qq, kk, vv: kernel(qq, kk, vv, ids))(qh, kh, vh)
-    # [Hkv, group, T', hd] -> [T, Hq, hd]
-    out = out.reshape(hq, t_run, hd).transpose(1, 0, 2)
+    # [Hkv, group, T', hd of v] -> [T, Hq, hd of v]
+    out = out.reshape(hq, t_run, v.shape[-1]).transpose(1, 0, 2)
     return out[:t].astype(q.dtype)
 
 

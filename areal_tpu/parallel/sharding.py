@@ -77,7 +77,11 @@ def param_partition_spec(path: str, ndim: int) -> P:
         return P(None, "fsdp", None)  # [L, D, z + xBC + dt]
     if name == "out_proj":
         return P(None, None, "fsdp")  # [L, d_inner, D]
-    # conv_w, conv_b, A_log, D, dt_bias, the gated norm,
+    # conv_w, conv_b, A_log, D, dt_bias, the gated norm; the selective
+    # scan's x_proj [L, d_inner, rank + 2 N] and dt_proj [L, rank, d_inner]
+    # (small, and the scan has every channel on every device); differential
+    # attention's lambda vectors and sub_norm; a gated memory unit's two
+    # matrices are `w_in` / `w_out` above;
     # norms, small biases (b_down/b_out [L, D]), router [L, D, E],
     # q_norm/k_norm: replicated.
     return P(*([None] * ndim))

@@ -155,3 +155,49 @@ def test_state_space_mixer_and_plain_experts_compile_at_the_published_widths(one
     text = jax.jit(jax.grad(experts_loss, (0, 1, 2))).lower(
         x, mp, gate, choice, mask).compile().as_text()
     assert "ragged-dot" in text and " conditional(" in text
+
+
+def test_selective_scan_kernels_compile_at_the_published_widths(one_chip):
+    """What `phi4flash-d8-train-ppo-8k` adds to a step: the selective
+    scan's forward and backward kernels over a packed row of 8,192 at
+    5,120 channels of 16 states in bf16 (blocks of 128 positions x 512
+    channels, the state and a chunk's 129 states in VMEM), and nothing of
+    [T, d_in, N] outside them."""
+    from areal_tpu.ops import selective_scan as ss
+
+    T, Dn, N = 8192, 5120, 16
+    x, dt = _shape((1, T, Dn), jnp.bfloat16, one_chip), _shape((1, T, Dn), jnp.float32, one_chip)
+    A, bc = _shape((Dn, N), jnp.float32, one_chip), _shape((1, T, N), jnp.bfloat16, one_chip)
+    seg = _shape((1, T), jnp.int32, one_chip)
+
+    def loss(x, dt, A, B, C, seg):
+        return ss.kernel_scan(x, dt, A, B, C, seg, 128, interpret=False).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2, 3, 4))).lower(x, dt, A, bc, bc, seg).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2 and "sscan_fwd" in text and "sscan_bwd" in text
+    # x, dt and their gradients are 0.08-0.17 GB each; [T, d_in, N] float32 would be 2.7 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.6e9
+
+
+@pytest.mark.parametrize("window", [512, None], ids=["window", "full"])
+def test_splash_takes_q_and_k_at_64_against_v_at_128(one_chip, window):
+    """Differential attention's one call: 40 q heads and 20 k heads of 64
+    against 20 v heads of 128 (`head_dim_v`), a row of 8,192 alone in
+    its call, so the block tables are values of the run. Mosaic takes
+    the head size of 64 as it is: nothing is padded."""
+    from areal_tpu.ops.attention import splash_packed_attention
+
+    t = 8192
+    q = _shape((1, t, 40, 64), jnp.bfloat16, one_chip)
+    k = _shape((1, t, 20, 64), jnp.bfloat16, one_chip)
+    v = _shape((1, t, 20, 128), jnp.bfloat16, one_chip)
+    ids = _shape((1, t), jnp.int32, one_chip)
+
+    def loss(q, k, v, seg, pos):
+        out = splash_packed_attention(q, k, v, seg, pos, window=window, interpret=False)
+        assert out.shape == (1, t, 40, 128)
+        return out.astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, k, v, ids, ids).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2
