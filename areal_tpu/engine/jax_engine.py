@@ -44,7 +44,11 @@ from areal_tpu.models.config import TransformerConfig
 from areal_tpu.models.generation import generate_tokens
 from areal_tpu.models.packing import PackedBatch, pack_sequences
 from areal_tpu.models.transformer import forward as model_forward
-from areal_tpu.ops.attention import attn_block_cells, attn_run_len
+from areal_tpu.ops.attention import (
+    attn_block_cells,
+    attn_grid_steps,
+    attn_run_len,
+)
 from areal_tpu.ops.loss import (
     fused_next_token_logprobs,
     head_cells_run,
@@ -831,7 +835,7 @@ class JaxTrainEngine(TrainEngine):
             }
             self._record_overlap_stats()
             attn = [self._attn_counts(rows["segment_ids"]) for rows in stacks]
-            counts = [a[1:] + self._head_counts(rows, scored_fn)
+            counts = [a[1:-1] + self._head_counts(rows, scored_fn)
                       + self._ssm_counts(rows["segment_ids"])
                       for a, rows in zip(attn, stacks)]
             self._count_batch(
@@ -849,7 +853,8 @@ class JaxTrainEngine(TrainEngine):
             rows, row_len = stacks[big]["input_ids"].shape[-2:]
             with tracing.span(
                 "train.dispatch", kind="fused", rows=rows, row_len=row_len,
-                attn_row_len=attn[big][0], **self._stack_attrs,
+                attn_row_len=attn[big][0], width=attn[big][-1],
+                **self._stack_attrs,
             ):
                 self.params, self.opt_state, packed, aux = step(
                     self.params, self.opt_state,
@@ -898,10 +903,11 @@ class JaxTrainEngine(TrainEngine):
                     }
                 cells = batch.n_rows * batch.row_len
                 tracing.set_attrs(tokens=batch.total_tokens, cells=cells)
-                counts = (self._attn_counts(rows["segment_ids"])
-                          + self._head_counts(rows, scored_fn)
-                          + self._ssm_counts(rows["segment_ids"]))
-            return rows_dev, denom, batch.total_tokens, cells, counts
+                run_len, *attn, width = self._attn_counts(rows["segment_ids"])
+                counts = (*attn, *self._head_counts(rows, scored_fn),
+                          *self._ssm_counts(rows["segment_ids"]))
+            return (rows_dev, denom, batch.total_tokens, cells,
+                    dict(attn_row_len=run_len, width=width), counts)
 
         pf = HostPrefetcher(
             mb_iter, stage, depth=self.prefetch_depth, name=f"train/{loss_name}",
@@ -910,22 +916,21 @@ class JaxTrainEngine(TrainEngine):
         carry = None
         nxt = None
         denom_sum, n_tok, n_cells, n_one_row = 0.0, 0, 0, 0
-        # attention's cells at the run length, run, causal; the head's
-        # positions read, cells run; the state-space scan's chunks, live,
-        # mixed, and its resets
-        n_counts = [0] * 9
+        # attention's cells at the run length, run, causal, its grid steps
+        # walked, live; the head's positions read, cells run; the
+        # state-space scan's chunks, live, mixed, and its resets
+        n_counts = [0] * 11
         gaps_ms: List[float] = []
         mark = time.monotonic_ns()
         try:
-            for rows_dev, denom, tok, cells, counts in pf:
+            for rows_dev, denom, tok, cells, attn_attrs, counts in pf:
                 gaps_ms.append((time.monotonic_ns() - mark) / 1e6)
                 denom_sum += denom
                 n_tok += tok
                 n_cells += cells
                 rows, row_len = rows_dev["input_ids"].shape
                 n_one_row += int(rows == 1)
-                attn_row_len = counts[0]
-                n_counts = [n + c for n, c in zip(n_counts, counts[1:])]
+                n_counts = [n + c for n, c in zip(n_counts, counts)]
                 if carry is None:
                     first, nxt = self._accum_step_fns(
                         loss_name, loss_fn, tuple(sorted(rows_dev.keys())),
@@ -933,14 +938,12 @@ class JaxTrainEngine(TrainEngine):
                     )
                     with tracing.span("train.dispatch", kind="first",
                                       rows=rows, row_len=row_len,
-                                      attn_row_len=attn_row_len,
-                                      **self._stack_attrs):
+                                      **attn_attrs, **self._stack_attrs):
                         carry = first(self.params, rows_dev)
                 else:
                     with tracing.span("train.dispatch", kind="next",
                                       rows=rows, row_len=row_len,
-                                      attn_row_len=attn_row_len,
-                                      **self._stack_attrs):
+                                      **attn_attrs, **self._stack_attrs):
                         carry = nxt(self.params, carry, rows_dev)
                 mark = time.monotonic_ns()
         finally:
@@ -966,7 +969,7 @@ class JaxTrainEngine(TrainEngine):
             packed, aux, loss_name, global_denom, n_mbs, lr
         )
 
-    def _attn_counts(self, segment_ids: np.ndarray) -> Tuple[int, int, int, int]:
+    def _attn_counts(self, segment_ids: np.ndarray) -> Tuple[int, ...]:
         """What the attention kernels do with packed rows (on the host,
         before the transfer), `segment_ids` [R, T] of one micro-batch or
         [n, R, T] of several: (the length they run a row at:
@@ -975,7 +978,11 @@ class JaxTrainEngine(TrainEngine):
         rows x that length; the cells of the block pairs they run, summed
         over rows and layers: by the rows' own segment ids,
         ops/attention.attn_block_cells; the cells a causal mask alone
-        would make them run)."""
+        would make them run; the grid steps the forward, dq and dkv
+        kernels walk and those whose pair runs, summed over rows, q heads
+        and layers: ops/attention.attn_grid_steps; the widest forward
+        grid, kv steps a q block, of any layer and row: a row alone
+        takes the narrowest width that holds it)."""
         cfg = self.model_cfg
         segment_ids = np.asarray(segment_ids)
         rows, row_len = segment_ids.shape[-2:]
@@ -986,13 +993,16 @@ class JaxTrainEngine(TrainEngine):
         )
         run_len = attn_run_len(t=row_len, r=rows, **shape)
         windows = [k.window for k in cfg.kinds() if k.mixer == "attention"]
-        # One count a window, not one a layer.
-        cells = {w: np.sum([attn_block_cells(segment_ids=mb, window=w, **shape)
-                            for mb in mbs], axis=0)
-                 for w in set(windows)}
-        return (run_len, len(mbs) * rows * run_len,
-                int(sum(cells[w][0] for w in windows)),
-                int(sum(cells[w][1] for w in windows)))
+        # One count a window, not one a layer: (cells run, causal cells,
+        # steps walked, live steps, width) a micro-batch.
+        per = {w: [attn_block_cells(segment_ids=mb, window=w, **shape)
+                   + attn_grid_steps(segment_ids=mb, window=w, **shape)
+                   for mb in mbs]
+               for w in set(windows)}
+        total = lambda i: int(sum(mb[i] for w in windows for mb in per[w]))
+        return (run_len, len(mbs) * rows * run_len, total(0), total(1),
+                cfg.n_q_heads * total(2), cfg.n_q_heads * total(3),
+                max(mb[4] for counts in per.values() for mb in counts))
 
     def _ssm_counts(self, segment_ids: np.ndarray) -> Tuple[int, int, int, int]:
         """What the state-space layers' chunked scan does with packed rows
@@ -1032,6 +1042,7 @@ class JaxTrainEngine(TrainEngine):
     def _count_batch(self, path: str, n_mbs: int, n_one_row: int, n_tok: int,
                      n_cells: int,
                      n_attn_cells: int, n_attn_active: int, n_attn_causal: int,
+                     n_attn_steps: int, n_attn_live: int,
                      n_scored: int, n_head_cells: int, n_ssm_chunks: int = 0,
                      n_ssm_live: int = 0, n_ssm_mixed: int = 0, n_ssm_resets: int = 0):
         """What one train_batch did, on its `train.batch` span and in the
@@ -1041,7 +1052,8 @@ class JaxTrainEngine(TrainEngine):
         real tokens, the cells (rows x row length) they were padded to,
         the cells the attention kernel ran (rows x the length it ran
         them at), the cells of the block pairs it ran
-        against those of a causal mask alone, the positions whose logprob
+        against those of a causal mask alone, the grid steps its kernels
+        walked and those whose block pair ran, the positions whose logprob
         the loss reads and the cells of the chunks the loss head ran for
         them, the (token, expert) pairs the routers of the expert
         layers made, and the chunks the state-space layers' scan ran
@@ -1055,6 +1067,8 @@ class JaxTrainEngine(TrainEngine):
         tracing.count("train.attn_cells", n_attn_cells)
         tracing.count("train.attn_active_cells", n_attn_active)
         tracing.count("train.attn_causal_cells", n_attn_causal)
+        tracing.count("train.attn_grid_steps", n_attn_steps)
+        tracing.count("train.attn_live_steps", n_attn_live)
         if not self.model_cfg.is_critic:
             tracing.count("train.scored_cells", n_scored)
             tracing.count("train.head_cells", n_head_cells)

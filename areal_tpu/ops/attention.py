@@ -159,6 +159,9 @@ _SPLASH_PAD_TO = 512
 # the row length picks the splash blocks"). Median error 6 %; the shape
 # picked is within 3 % of the fastest measured one for every length of
 # 896 and above but 3840, which stays as it is (_SPLASH_MIN_GAIN).
+# Fitted on the fused backward over static grids: a row alone has run
+# the dq and dkv kernels over compacted tables since PR 35, at blocks
+# these constants still pick (a refit is its own change).
 _SPLASH_NS = (980.0, 0.0115, 1.13, 1.25)
 
 # The estimate's own median error: a smaller estimated gain is no reason
@@ -187,9 +190,18 @@ def _active_block_pairs(t: int, bq: int, bkv: int,
     forward kernel's grid is nq x the widest row: splash shrinks the kv
     axis to the most active blocks any q block has, and skips the rest
     (the fused backward kernel keeps its whole grid and skips the
-    work)."""
+    work; the dq and dkv kernels of a row alone are shrunk as the
+    forward is: `_table_widths`)."""
     pairs = _static_block_pairs(t, bq, bkv, window)
     return int(pairs.sum()), int(pairs.sum(axis=1).max())
+
+
+def _diagonal_block_pairs(t: int, bq: int, bkv: int):
+    """bool [nq, nkv]: the pairs whose q block and kv block share a place
+    in the row. Every token sees itself there."""
+    i = np.arange(t // bq)[:, None]
+    j = np.arange(t // bkv)[None, :]
+    return (j * bkv < (i + 1) * bq) & (i * bq < (j + 1) * bkv)
 
 
 def live_block_pairs(segment_ids, bq: int, bkv: int):
@@ -219,23 +231,25 @@ def live_block_pairs(segment_ids, bq: int, bkv: int):
     (lo_q, hi_q), (lo_k, hi_k) = spans(bq), spans(bkv)
     meet = ((lo_q[..., :, None] <= hi_k[..., None, :])
             & (lo_k[..., None, :] <= hi_q[..., :, None]))
-    i = np.arange(t // bq)[:, None]
-    j = np.arange(t // bkv)[None, :]
-    diagonal = (j * bkv < (i + 1) * bq) & (i * bq < (j + 1) * bkv)
-    return meet | diagonal
+    return meet | _diagonal_block_pairs(t, bq, bkv)
 
 
 def _splash_cost_terms(t: int, bq: int, bkv: int, bkvc: int,
                        window: Optional[int] = None) -> tuple:
     """What splash_cost prices, a q head of one row: grid steps (each a
     fixed overhead whether or not the mask leaves it any work: at
-    128 x 128 blocks nearly all of the time), and over the block pairs
+    128 x 128 blocks nearly all of the time; a walked step whose pair
+    does not run costs 0.29 us in the forward kernel, 0.31 in dq, 0.41
+    in dkv and 0.85 in the fused backward, which also writes a block of
+    zeros into its partial of dq: one v5e, 512 x 1024 blocks, PERF.md
+    section 6, PR 29 and PR 35), and over the block pairs
     the mask leaves active their bq x bkv cells, their bq rows of
     softmax bookkeeping once per compute sub-block, and the bq + bkv
     rows of q and k/v they load. `window` counts a window layer's pairs
     (for a fit of a `scripts/splash_shape_sweep.py --window` sweep: the
     constants in the tree are fitted on causal masks, and `splash_cost`
-    prices those alone)."""
+    prices those alone). The steps are the static forward grid's: what a
+    row alone walks at run time (`_table_widths`) is not priced."""
     active, widest = _active_block_pairs(t, bq, bkv, window)
     return ((t // bq) * widest, active * bq * bkv,
             active * (bkv // bkvc) * bq, active * (bq + bkv))
@@ -243,7 +257,10 @@ def _splash_cost_terms(t: int, bq: int, bkv: int, bkvc: int,
 
 def splash_cost(t: int, bq: int, bkv: int, bkvc: int) -> float:
     """Estimated kernel time of a causal row of length `t` at the given
-    blocks, in ns per q head."""
+    blocks, in ns per q head: forward, remat's forward and the fused
+    backward over the static grids, which is what the constants were
+    fitted on. A row alone runs less (its live pairs' steps, dq and dkv
+    in kernels of their own); the blocks picked are the same."""
     terms = _splash_cost_terms(t, bq, bkv, bkvc)
     return sum(ns * x for ns, x in zip(_SPLASH_NS, terms))
 
@@ -305,7 +322,8 @@ def splash_run_shape(t: int):
 
 
 def _splash_kernel(t: int, bq: int, bkv: int, bkvc: int, group: int,
-                   interpret: bool = False, window: Optional[int] = None):
+                   interpret: bool = False, window: Optional[int] = None,
+                   fused_bwd: bool = True):
     """Build the splash-attention kernel for rows of `t` at the given
     blocks (the mask object is cached; the kernel itself is rebuilt per
     trace).
@@ -318,8 +336,12 @@ def _splash_kernel(t: int, bq: int, bkv: int, bkvc: int, group: int,
     because packed segments are contiguous with ascending positions; a
     `window` is splash's LocalMask (window - 1 to the left, none to the
     right) for the same reason, and the kernels skip the block pairs
-    wholly behind it. Length and blocks come from `splash_run_shape`; the backward is the
-    fused dq/dkv kernel at the same blocks.
+    wholly behind it. Length and blocks come from `splash_run_shape`; the
+    backward is the fused dq/dkv kernel at the same blocks, or with
+    `fused_bwd=False` (a row alone: `_rows_skip`) splash's dq kernel and
+    dkv kernel, each over a grid of its own: dq summed in the kernel's
+    float32 scratch, not in `[kv blocks, heads, t, hd]` partials and a
+    reduce.
     """
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk,
@@ -341,7 +363,8 @@ def _splash_kernel(t: int, bq: int, bkv: int, bkvc: int, group: int,
     bs = sk.BlockSizes(
         block_q=bq, block_kv=bkv, block_kv_compute=bkvc,
         block_q_dkv=bq, block_kv_dkv=bkv, block_kv_dkv_compute=bkvc,
-        use_fused_bwd_kernel=True,
+        use_fused_bwd_kernel=fused_bwd,
+        **({} if fused_bwd else dict(block_q_dq=bq, block_kv_dq=bkv)),
     )
     # Residuals are checkpoint-named so the "save_attn" remat policy
     # (models/transformer.py) can pin them: backward then runs the
@@ -353,63 +376,110 @@ def _splash_kernel(t: int, bq: int, bkv: int, bkvc: int, group: int,
     )
 
 
-def _next_live(live, block):
-    """For every step of a kernel's walk ([a, b], row by row): `block`
-    at this step if it is live, else at the next live one (what the
-    pipeline fetches meanwhile), 0 after the last."""
-    n = live.size
-    at = jnp.where(live.reshape(n), jnp.arange(n), n)
-    nxt = jax.lax.cummin(at, reverse=True)
-    return jnp.append(block.reshape(n), 0)[nxt].reshape(live.shape)
+@functools.lru_cache(maxsize=None)
+def _table_widths(t: int, bq: int, bkv: int, window: Optional[int]) -> tuple:
+    """((W, Wq), ..), each no narrower than the one before: the widths
+    the compacted tables of a row of `t` may take, W kv blocks a q block
+    (forward and dq grids: nq x W) and Wq q blocks a kv block (dkv: Wq x
+    nkv). A quarter, a half and the whole of the row's blocks, none wider
+    than the static mask's widest row or column (a window layer's is a
+    few blocks, so it has one width) nor narrower than the diagonal. A
+    pure function of the run shape and the window: the widths are
+    branches inside one program, never a program each."""
+    static = _static_block_pairs(t, bq, bkv, window)
+    diagonal = _diagonal_block_pairs(t, bq, bkv)
+
+    def width(axis, part):
+        most = static.sum(axis).max()  # blocks any row (or column) can need
+        return int(min(most, max(diagonal.sum(axis).max(),
+                                 -(-static.shape[axis] // part))))
+
+    widths = []
+    for part in (4, 2, 1):
+        if (pair := (width(1, part), width(0, part))) not in widths:
+            widths.append(pair)
+    return tuple(widths)
 
 
-@functools.partial(jax.jit, static_argnums=(5, 6))
-def _block_tables(segment_ids, fwd_mask, fwd_next, dkv_mask, dkv_next, bq, bkv):
-    """The block tables the splash kernels skip by, for one packed row
-    `segment_ids` [T]: a kernel's static tables (`block_mask` says
-    whether a grid step runs, `data_next` which block it loads; [nq,
-    steps] each, the head axis taken off) with the pairs outside
-    `live_block_pairs` skipped as well. Returns (forward block_mask,
-    data_next, fused backward block_mask, data_next), [1, nq, steps] in
-    the static arrays' own dtypes. Jitted: a program's call sites (each
-    kind of layer, the forward pass and remat's) share one traced copy."""
-    live = live_block_pairs(segment_ids, bq, bkv)  # [nq, nkv]
+def _width_index(pairs, widths: tuple):
+    """Which of `widths` the rows whose pairs `pairs` [..., nq, nkv] run
+    take: the narrowest that holds the most pairs any q block and any kv
+    block has. One rule for the device (`_block_tables`) and the host
+    (`attn_grid_steps`)."""
+    need_w = pairs.sum(axis=-1).max(axis=-1)
+    need_wq = pairs.sum(axis=-2).max(axis=-1)
+    return sum(((need_w > w) | (need_wq > wq)).astype(np.int32)
+               for w, wq in widths[:-1])
 
-    def masked(mask, dtype, live, block, walk):
-        """`walk` puts [nq, steps] in the order the kernel's grid goes."""
-        run = live & (mask > 0)
-        return (jnp.where(run, mask, 0)[None],
-                walk(_next_live(walk(run), walk(block))).astype(dtype)[None])
 
-    # Forward, a q block's steps one after another: under a window the kv
-    # axis is shrunk to the widest q row, and the static data_next says
-    # which kv block a step loads.
-    kv_of_step = fwd_next.astype(jnp.int32)
-    fwd = masked(fwd_mask, fwd_next.dtype,
-                 jnp.take_along_axis(live, kv_of_step, axis=1), kv_of_step,
-                 lambda a: a)
-    # Fused backward, not shrunk, a kv block's q blocks one after
-    # another; data_next names the q block.
-    q_of_step = jnp.broadcast_to(jnp.arange(live.shape[0])[:, None], live.shape)
-    dkv = masked(dkv_mask, dkv_next.dtype, live, q_of_step, jnp.transpose)
-    return (*fwd, *dkv)
+def _compacted(pairs, width: int, after):
+    """(block_mask, data_next), [1, n, width] each: for every row of
+    `pairs` [n, m] its blocks that run, packed to the front in their own
+    order, as splash's static shrunk tables are: `block_mask` 1 where a
+    step runs and 0 on the tail, `data_next` the block a step loads; on
+    the tail, the block that `after` [n] names (what the pipeline
+    fetches meanwhile)."""
+    first = jnp.argsort(~pairs, axis=1, stable=True)[:, :width]
+    runs = jnp.arange(width)[None, :] < pairs.sum(axis=1)[:, None]
+    return (runs.astype(jnp.int32)[None],
+            jnp.where(runs, first, after(first[:, 0])[:, None])[None])
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _block_tables(segment_ids, bq, bkv, window):
+    """The block tables the splash kernels of one packed row
+    `segment_ids` [T] walk: of the pairs its static mask leaves
+    (`_static_block_pairs`) those that `live_block_pairs` finds in the
+    row, compacted. Returns (which of `_table_widths` the row takes, and
+    for every width (forward and dq block_mask, data_next: [1, nq, W];
+    dkv block_mask, data_next: [1, Wq, nkv])). A width that does not
+    hold the row gets the diagonal alone: no program without a caller's
+    `vmap` runs it, and under one (every branch runs, a select picks)
+    its softmax still has a denominator. Jitted: a program's call sites
+    (each kind of layer, the forward pass and remat's) share one traced
+    copy."""
+    t = segment_ids.shape[-1]
+    widths = _table_widths(t, bq, bkv, window)
+    live = (live_block_pairs(segment_ids, bq, bkv)
+            & _static_block_pairs(t, bq, bkv, window))
+    index = _width_index(live, widths)
+    diagonal = _diagonal_block_pairs(t, bq, bkv)
+    tables = []
+    for at, (w, wq) in enumerate(widths):
+        pairs = live if at == len(widths) - 1 else jnp.where(
+            index <= at, live, diagonal)
+        # Forward and dq, a q block's steps one after another, then the
+        # next q block's (the last one's tail: the next head's first).
+        fwd = _compacted(pairs, w, lambda first: jnp.roll(first, -1))
+        # dkv, a kv block's q blocks one after another, then the same kv
+        # block again for the next q head.
+        dkv = _compacted(pairs.T, wq, lambda first: first)
+        tables.append((*fwd, *(a.transpose(0, 2, 1) for a in dkv)))
+    return index, tuple(tables)
 
 
 def _with_tables(kernel, tables):
-    """`kernel` skipping by the run-time `tables` of one row
-    (`_block_tables`) in place of its static ones. Both are
+    """`kernel` walking the run-time `tables` of one row and one width
+    (`_block_tables`) in place of its static ones. All are
     scalar-prefetch operands, so they may be traced (jax's own dynamic
-    masks are). Inside a pair that runs nothing changes: the segment ids
-    still mask cell by cell."""
+    masks are), and each kernel's grid is as wide as its table. Inside a
+    pair that runs nothing changes: the mask function and the segment
+    ids still mask cell by cell, at the place `data_next` names."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk,
     )
 
+    def put(info, block_mask, data_next):
+        assert info.partial_mask_blocks is None and info.mask_next is None
+        return info._replace(
+            block_mask=block_mask.astype(info.block_mask.dtype),
+            data_next=data_next.astype(info.data_next.dtype))
+
     fwd_mask, fwd_next, dkv_mask, dkv_next = tables
     return sk.SplashAttentionKernel(
-        kernel.fwd_mask_info._replace(block_mask=fwd_mask, data_next=fwd_next),
-        None,
-        kernel.dkv_mask_info._replace(block_mask=dkv_mask, data_next=dkv_next),
+        put(kernel.fwd_mask_info, fwd_mask, fwd_next),
+        put(kernel.dq_mask_info, fwd_mask, fwd_next),
+        put(kernel.dkv_mask_info, dkv_mask, dkv_next),
         **kernel.kwargs)
 
 
@@ -420,9 +490,11 @@ _SKIP_MIN_LEN = 2048
 
 
 def _rows_skip(rows: int, t_run: int) -> bool:
-    """Whether the kernels of `rows` packed rows in one call skip by the
-    rows' own segment ids: a long row alone. Several rows keep the static
-    kernel and share one grid: a table a row needs a loop over the rows,
+    """Whether the kernels of `rows` packed rows in one call walk the
+    rows' own live block pairs (`_block_tables`: compacted tables, dq and
+    dkv in kernels of their own): a long row alone. Several rows keep the
+    static kernels, the fused backward among them, and share one grid: a
+    table a row needs a loop over the rows,
     which under `vmap` (pallas's own, around the kernel calls alone) is
     slower than the static kernel and under `lax.map` gained 3-4 % of
     `q15d12-train-ppo`'s tokens/s for 19 % of its `setup_s`, each program
@@ -452,10 +524,12 @@ def splash_packed_attention(
     (tests, scripts/splash_shape_sweep.py).
 
     Of the block pairs the row's causal or window mask leaves, the
-    kernels of a long row alone (`_rows_skip`) run those that
-    `live_block_pairs` finds in the row's segment ids: not the pairs
-    between two sequences, nor those between a sequence and the padding.
-    What comes back at a real position is the same to the bit.
+    kernels of a long row alone (`_rows_skip`) walk those that
+    `live_block_pairs` finds in the row's segment ids, and no others:
+    not the pairs between two sequences, nor those between a sequence
+    and the padding, and their grids are as wide as the row's live
+    pairs need (`_table_widths`: a branch taken at run time). What comes
+    back at a real position is the same to the bit.
 
     Packed rows go in whole, every array with a leading axis, so that
     the wrapper sees how many share the call; a row given alone (which
@@ -489,8 +563,8 @@ def _splash_row(q, k, v, segment_ids, *, run_shape, interpret, window, skip,
     hkv = k.shape[1]
     group = hq // hkv
     t_run, bq, bkv, bkvc = run_shape
-    kernel = _splash_kernel(t_run, bq, bkv, bkvc, group,
-                            interpret=interpret, window=window)
+    kernel = _splash_kernel(t_run, bq, bkv, bkvc, group, interpret=interpret,
+                            window=window, fused_bwd=not skip)
 
     q = q * jnp.asarray(scale, q.dtype)
     if t_run > t:
@@ -499,17 +573,24 @@ def _splash_row(q, k, v, segment_ids, *, run_shape, interpret, window, skip,
         pad = ((0, t_run - t), (0, 0), (0, 0))
         q, k, v = jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad)
         segment_ids = jnp.pad(segment_ids, (0, t_run - t))
-    if skip:
-        fwd, dkv = kernel.fwd_mask_info, kernel.dkv_mask_info
-        kernel = _with_tables(kernel, _block_tables(
-            segment_ids, fwd.block_mask[0], fwd.data_next[0],
-            dkv.block_mask[0], dkv.data_next[0], bq, bkv))
     # [T', Hq, hd] -> [Hkv, group, T', hd]; k/v -> [Hkv, T', hd]
     qh = q.transpose(1, 0, 2).reshape(hkv, group, t_run, hd)
     kh = k.transpose(1, 0, 2)
     vh = v.transpose(1, 0, 2)
     ids = sk.SegmentIds(q=segment_ids, kv=segment_ids)
-    out = jax.vmap(lambda qq, kk, vv: kernel(qq, kk, vv, ids))(qh, kh, vh)
+
+    def attend(kernel):
+        return jax.vmap(lambda qq, kk, vv: kernel(qq, kk, vv, ids))(qh, kh, vh)
+
+    if skip:
+        # One program whatever the row holds: the width is a branch taken
+        # at run time, outside the loop over kv heads.
+        index, tables = _block_tables(segment_ids, bq, bkv, window)
+        walks = [functools.partial(attend, _with_tables(kernel, one))
+                 for one in tables]
+        out = walks[0]() if len(walks) == 1 else jax.lax.switch(index, walks)
+    else:
+        out = attend(kernel)
     # [Hkv, group, T', hd of v] -> [T, Hq, hd of v]
     out = out.reshape(hq, t_run, v.shape[-1]).transpose(1, 0, 2)
     return out[:t].astype(q.dtype)
@@ -673,6 +754,28 @@ def attn_run_len(
     return splash_run_shape(t)[0] if ran == "splash" else t
 
 
+def _host_block_pairs(impl, segment_ids, hq, hkv, mesh, window):
+    """What the host's counters count of the packed rows `segment_ids`
+    [R, T] of one micro-batch: None where an implementation without
+    blocks runs them, else (run shape, the window if it cuts the row,
+    static pairs [nq, nkv], and where the kernels walk the rows' own
+    pairs (`_rows_skip`) those pairs, [R, nq, nkv])."""
+    r, t = segment_ids.shape
+    ran, _ = _choose_attn_impl(impl, t, hq, hkv, mesh, r)
+    if ran != "splash":
+        return None
+    shape = splash_run_shape(t)
+    t_run, bq, bkv, _ = shape
+    window = _row_window(t, window)
+    static = _static_block_pairs(t_run, bq, bkv, window)
+    # A sharded mesh runs each shard's rows in a call of their own.
+    if not _rows_skip(r // (cp_axes(mesh)[0] if mesh is not None else 1), t_run):
+        return shape, window, static, None
+    live = live_block_pairs(
+        np.pad(segment_ids, ((0, 0), (0, t_run - t))), bq, bkv)
+    return shape, window, static, live & static
+
+
 def attn_block_cells(
     impl: str, segment_ids: np.ndarray, hq: int, hkv: int, mesh=None,
     window: Optional[int] = None,
@@ -686,20 +789,39 @@ def attn_block_cells(
     without blocks (the einsum reference) runs all t x t cells whatever
     the mask. For host-side counters."""
     r, t = segment_ids.shape
-    ran, _ = _choose_attn_impl(impl, t, hq, hkv, mesh, r)
-    if ran != "splash":
+    found = _host_block_pairs(impl, segment_ids, hq, hkv, mesh, window)
+    if found is None:
         return r * t * t, r * t * t
-    t_run, bq, bkv, _ = splash_run_shape(t)
-    static = _static_block_pairs(t_run, bq, bkv, _row_window(t, window))
+    (t_run, bq, bkv, _), _, static, live = found
     causal = r * int(_static_block_pairs(t_run, bq, bkv).sum())
-    # A sharded mesh runs each shard's rows in a call of their own.
-    if _rows_skip(r // (cp_axes(mesh)[0] if mesh is not None else 1), t_run):
-        live = live_block_pairs(
-            np.pad(segment_ids, ((0, 0), (0, t_run - t))), bq, bkv)
-        pairs = int((live & static).sum())
-    else:
-        pairs = r * int(static.sum())
+    pairs = r * int(static.sum()) if live is None else int(live.sum())
     return pairs * bq * bkv, causal * bq * bkv
+
+
+def attn_grid_steps(
+    impl: str, segment_ids: np.ndarray, hq: int, hkv: int, mesh=None,
+    window: Optional[int] = None,
+) -> tuple:
+    """(grid steps the attention kernels walk for the packed rows
+    `segment_ids` [R, T] of one micro-batch, those whose block pair
+    runs, the widest forward grid: kv steps a q block), per q head, the
+    forward kernel once. A row alone (`_rows_skip`) walks compacted
+    tables at the width it takes (`_table_widths`, `_width_index`: the
+    device's rule): nq x W forward, the same in dq, Wq x nkv in dkv.
+    Other rows walk the static grids: nq x the mask's widest row
+    forward, and the fused backward's whole nq x nkv. An implementation
+    without blocks has no grid: zeros. For host-side counters."""
+    found = _host_block_pairs(impl, segment_ids, hq, hkv, mesh, window)
+    if found is None:
+        return 0, 0, 0
+    (t_run, bq, bkv, _), window, static, live = found
+    nq, nkv = static.shape
+    if live is None:
+        r, widest = len(segment_ids), int(static.sum(axis=1).max())
+        return r * (nq * widest + nq * nkv), r * 2 * int(static.sum()), widest
+    widths = _table_widths(t_run, bq, bkv, window)
+    w, wq = np.asarray(widths)[_width_index(live, widths)].T
+    return int((2 * nq * w + wq * nkv).sum()), 3 * int(live.sum()), int(w.max())
 
 
 def packed_attention(q, k, v, segment_ids, positions, softmax_scale=None,

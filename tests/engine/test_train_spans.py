@@ -118,6 +118,8 @@ def test_attributes_and_counters_count_tokens_and_cells(recorded):
         "train.attn_cells": a["cells"],
         "train.attn_active_cells": all_cells,
         "train.attn_causal_cells": all_cells,
+        # the einsum reference has no grid to walk
+        "train.attn_grid_steps": 0, "train.attn_live_steps": 0,
         # no `scored_fn`: the head reads every token but a sequence's
         # last (9 sequences) and runs over every cell
         "train.scored_cells": a["tokens"] - 9, "train.head_cells": a["cells"]}
@@ -129,6 +131,7 @@ def test_attributes_and_counters_count_tokens_and_cells(recorded):
         if s["name"] == "train.dispatch":
             assert s["attrs"]["rows"] >= 1 and s["attrs"]["row_len"] % 32 == 0
             assert s["attrs"]["attn_row_len"] == s["attrs"]["row_len"]
+            assert s["attrs"]["width"] == 0
         if s["name"] == "train.stage":
             assert 0 < s["attrs"]["tokens"] <= s["attrs"]["cells"]
         if s["name"] == "train.fetch_stats":
@@ -200,3 +203,17 @@ def test_attn_cells_count_the_length_the_kernel_runs_at(impl):
     c = got["counters"]
     assert c["train.cells"] == d["rows"] * 640
     assert c["train.attn_cells"] == d["rows"] * want
+    # the grids the kernels walk, by q head and layer: a short row keeps
+    # the static ones (forward nq x widest, the fused backward nq x nkv);
+    # the reference has none
+    if impl == "splash":
+        from areal_tpu.ops.attention import attn_grid_steps
+
+        cfg = eng.model_cfg
+        seg = np.zeros((d["rows"], 640), np.int32)
+        steps, live, width = attn_grid_steps("splash", seg, cfg.n_q_heads, cfg.n_kv_heads)
+        assert 0 < live < steps and d["width"] == width > 0
+        assert c["train.attn_grid_steps"] == steps * cfg.n_q_heads * cfg.n_layers
+        assert c["train.attn_live_steps"] == live * cfg.n_q_heads * cfg.n_layers
+    else:
+        assert c["train.attn_grid_steps"] == c["train.attn_live_steps"] == d["width"] == 0

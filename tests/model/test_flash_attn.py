@@ -453,46 +453,190 @@ def test_only_a_long_row_alone_skips_by_its_segment_ids(monkeypatch):
 @pytest.mark.parametrize("window", [None, 2048], ids=["causal", "window"])
 @pytest.mark.parametrize("t,lens", [
     (2048, [700, 500, 300]), (3712, [1500, 900, 1000]), (8192, [3000, 900]),
-    (8192, [8192]), (16384, [9000])])
-def test_host_count_of_cells_run_is_the_device_block_mask(t, lens, window):
-    """`attn_block_cells` (the engine's `train.attn_active_cells`) counts
-    on the host what the kernels skip by on the device: the non-zero
-    entries of the forward and of the fused backward `block_mask`, times
-    a block pair's cells."""
+    (8192, [8192]), (16384, [9000]), (16384, [1100] * 14), (16384, [3000, 5000, 4000])])
+def test_host_counts_are_the_device_block_tables(t, lens, window):
+    """`attn_block_cells` and `attn_grid_steps` (the engine's
+    `train.attn_active_cells`, `train.attn_grid_steps`,
+    `train.attn_live_steps` and the span's `width`) count on the host
+    what the kernels walk on the device: the tables `_block_tables` makes
+    at the width it picks, their sizes the steps, their non-zero
+    `block_mask` the pairs that run; and the tables name just the pairs
+    the static mask and the row's sequences leave, each once, in order."""
     from areal_tpu.ops import attention as A
 
     seg = _row(t, lens)
     t_run, bq, bkv, bkvc = A.splash_run_shape(t)
+    nq, nkv = t_run // bq, t_run // bkv
     assert A._rows_skip(1, t_run)
     padded = np.pad(seg, (0, t_run - t))
-    static = A._splash_kernel(t_run, bq, bkv, bkvc, 1, interpret=True,
-                              window=A._row_window(t, window))
-    kernel = A._with_tables(static, A._block_tables(
-        jnp.asarray(padded), static.fwd_mask_info.block_mask[0],
-        static.fwd_mask_info.data_next[0], static.dkv_mask_info.block_mask[0],
-        static.dkv_mask_info.data_next[0], bq, bkv))
-    fwd = int((np.asarray(kernel.fwd_mask_info.block_mask) > 0).sum())
-    dkv = int((np.asarray(kernel.dkv_mask_info.block_mask) > 0).sum())
+    win = A._row_window(t, window)
+    widths = A._table_widths(t_run, bq, bkv, win)
+    index, tables = A._block_tables(jnp.asarray(padded), bq, bkv, win)
+    index = int(index)
+    assert len(tables) == len(widths) <= 3 and sorted(widths) == list(widths)
+    pairs = A.live_block_pairs(padded, bq, bkv) & A._static_block_pairs(t_run, bq, bkv, win)
+    need = (pairs.sum(axis=1).max(), pairs.sum(axis=0).max())
+    # the narrowest width that holds the row
+    assert all(n <= w for n, w in zip(need, widths[index]))
+    assert index == 0 or any(n > w for n, w in zip(need, widths[index - 1]))
+    for (w, wq), (fwd_mask, fwd_next, dkv_mask, dkv_next) in zip(widths, tables):
+        assert fwd_mask.shape == fwd_next.shape == (1, nq, w)
+        assert dkv_mask.shape == dkv_next.shape == (1, wq, nkv)
+        assert 0 <= int(fwd_next.min()) and int(fwd_next.max()) < nkv
+        assert 0 <= int(dkv_next.min()) and int(dkv_next.max()) < nq
+    fwd_mask, fwd_next, dkv_mask, dkv_next = (np.asarray(a)[0] for a in tables[index])
+    for runs, named in ((fwd_mask > 0, fwd_next), (dkv_mask.T > 0, dkv_next.T)):
+        # a row's live blocks at the front, ascending, each once
+        assert (runs[:, :-1] >= runs[:, 1:]).all()
+        assert all((np.diff(n[r]) > 0).all() for r, n in zip(runs, named))
+    got = np.zeros_like(pairs)
+    got[np.nonzero(fwd_mask)[0], fwd_next[fwd_mask > 0]] = True
+    np.testing.assert_array_equal(got, pairs)
+    got = np.zeros_like(pairs)
+    got[dkv_next[dkv_mask > 0], np.nonzero(dkv_mask)[1]] = True
+    np.testing.assert_array_equal(got, pairs)
+
     ran, causal = A.attn_block_cells("splash", seg[None], 4, 2, window=window)
-    assert ran == fwd * bq * bkv == dkv * bq * bkv
+    assert ran == int(pairs.sum()) * bq * bkv
     assert causal == A._active_block_pairs(t_run, bq, bkv)[0] * bq * bkv
-    skips = len(lens) > 1 or lens[0] < t or A._row_window(t, window) is not None
+    skips = len(lens) > 1 or lens[0] < t or win is not None
     assert (ran < causal) if skips else (ran == causal)
-    # several rows in one call keep the static kernel; so does a short row
-    pairs = A._active_block_pairs(t_run, bq, bkv, A._row_window(t, window))[0]
+    steps, live, width = A.attn_grid_steps("splash", seg[None], 4, 2, window=window)
+    assert steps == 2 * fwd_mask.size + dkv_mask.size  # forward, dq, dkv
+    assert live == 2 * int((fwd_mask > 0).sum()) + int((dkv_mask > 0).sum()) == 3 * pairs.sum()
+    assert width == widths[index][0]
+    # several rows in one call keep the static kernels; so does a short row
+    active, widest = A._active_block_pairs(t_run, bq, bkv, win)
     assert A.attn_block_cells("splash", np.stack([seg, seg]), 4, 2, window=window) == (
-        2 * pairs * bq * bkv, 2 * causal)
+        2 * active * bq * bkv, 2 * causal)
+    assert A.attn_grid_steps("splash", np.stack([seg, seg]), 4, 2, window=window) == (
+        2 * (nq * widest + nq * nkv), 2 * 2 * active, widest)  # forward, fused backward
     assert A.attn_block_cells("splash", _row(1024, [10])[None], 4, 2) == (
         A._active_block_pairs(1024, 512, 512)[0] * 512 * 512,) * 2
-    for name, block in (("fwd_mask_info", bkv), ("dkv_mask_info", bq)):
-        info, was = getattr(kernel, name), getattr(static, name)
-        nxt = np.asarray(info.data_next)
-        assert nxt.dtype == was.data_next.dtype and nxt.shape == was.data_next.shape
-        assert info.block_mask.dtype == was.block_mask.dtype
-        assert nxt.min() >= 0 and nxt.max() < t_run // block
-        if not skips:  # a step the static kernel runs loads what it loaded
+    assert A.attn_grid_steps("splash", _row(1024, [10])[None], 4, 2) == (2 * 2 + 2 * 2, 6, 2)
+    assert A.attn_grid_steps("reference", seg[None], 4, 2, window=window) == (0, 0, 0)
+
+
+def test_compacted_tables_of_a_whole_row_are_the_static_shrunk_ones():
+    """One sequence from end to end leaves every pair of the static
+    mask: at the widest width the compacted tables run the steps splash's
+    own shrunk static tables run and load the blocks they load, forward,
+    dq and dkv, causal and under a window."""
+    from areal_tpu.ops import attention as A
+
+    t, bq, bkv = 4096, 256, 512
+    for window in (None, 700):
+        static = A._splash_kernel(t, bq, bkv, bkv, 1, interpret=True, window=window,
+                                  fused_bwd=False)
+        index, tables = A._block_tables(jnp.asarray(_row(t, [t])), bq, bkv, window)
+        assert int(index) == len(tables) - 1
+        kernel = A._with_tables(static, tables[-1])
+        for name in ("fwd_mask_info", "dq_mask_info"):
+            was, info = getattr(static, name), getattr(kernel, name)
+            assert info.block_mask.dtype == was.block_mask.dtype
+            assert info.data_next.dtype == was.data_next.dtype
+            assert info.block_mask.shape == was.block_mask.shape == info.data_next.shape
             run = np.asarray(was.block_mask) > 0
-            np.testing.assert_array_equal(nxt[run], np.asarray(was.data_next)[run])
+            np.testing.assert_array_equal(np.asarray(info.block_mask) > 0, run)
+            np.testing.assert_array_equal(np.asarray(info.data_next)[run],
+                                          np.asarray(was.data_next)[run])
+        # dkv: splash leaves a kv block's q blocks where they stand and
+        # cuts the steps no kv block uses; compacted, they start at step 0
+        was, info = static.dkv_mask_info, kernel.dkv_mask_info
+        assert info.block_mask.dtype == was.block_mask.dtype
+        assert info.data_next.dtype == was.data_next.dtype
+        assert info.block_mask.shape == was.block_mask.shape == info.data_next.shape
+        pairs = []
+        for one in (was, info):
+            run = np.asarray(one.block_mask)[0] > 0
+            pairs.append(sorted(zip(np.asarray(one.data_next)[0][run], np.nonzero(run)[1])))
+        assert pairs[0] == pairs[1]
+
+
+# lens, window, (hq, hkv, head size of q and k, of v), the width's index
+COMPACTED = {
+    "one_sequence": ([2048], None, (4, 2, 32, 32), 2),
+    "many_short": ([100] * 18, None, (4, 2, 32, 32), 0),
+    "wider_than_the_narrowest": ([700, 300, 600], None, (4, 2, 32, 32), 1),
+    "padded_tail": ([500, 400], None, (4, 2, 32, 32), 1),
+    "window": ([900, 700], 300, (4, 2, 32, 32), 1),
+    "window_many_short": ([100] * 18, 300, (4, 2, 32, 32), 0),
+    "heads_of_64_against_128": ([700, 300, 600], None, (4, 2, 64, 128), 1),
+    "gqa_group_of_16": ([100] * 18, None, (16, 1, 32, 32), 0),
+}
+
+
+@pytest.mark.parametrize("case", COMPACTED)
+def test_a_row_alone_walks_its_live_pairs(case, monkeypatch):
+    """A row of 2,048 alone in its call, dq and dkv in kernels of their
+    own over compacted tables, the width a branch taken at run time (the
+    case says which): the output at real positions is the static
+    kernel's to the bit, and the output and the q, k, v gradients are
+    the einsum reference's to 2e-5 in float32."""
+    from areal_tpu.ops import attention as A
+
+    lens, window, (hq, hkv, hd, hd_v), want = COMPACTED[case]
+    t, run_shape = 2048, (2048, 128, 256, 128)
+    seg = _row(t, lens)
+    index, tables = A._block_tables(jnp.asarray(seg), 128, 256, window)
+    assert int(index) == want and (len(tables) == 3 if window is None else 2)
+    rng = np.random.RandomState(7)
+    q, k, v = (jnp.asarray(rng.randn(1, t, h, d).astype(np.float32))
+               for h, d in ((hq, hd), (hkv, hd), (hkv, hd_v)))
+    real = seg > 0
+    dout = jnp.asarray(rng.randn(1, t, hq, hd_v).astype(np.float32) * real[None, :, None, None])
+    ids, at = jnp.asarray(seg)[None], jnp.arange(t)[None]
+
+    def run(fn):
+        def loss(q, k, v):
+            out = fn(q, k, v)
+            return jnp.sum(out * dout), out
+
+        (_, out), grads = jax.value_and_grad(loss, (0, 1, 2), has_aux=True)(q, k, v)
+        return np.asarray(out)[0], [np.asarray(g) for g in grads]
+
+    splash = lambda q, k, v: A.splash_packed_attention(
+        q, k, v, ids, at, interpret=True, _run_shape=run_shape, window=window)
+    got, g_got = run(splash)
+    assert got.shape == (t, hq, hd_v) and np.isfinite(got).all()
+    ref, g_ref = run(lambda q, k, v: A.reference_packed_attention(
+        q[0], k[0], v[0], ids[0], at[0], window=window)[None])
+    np.testing.assert_allclose(got[real], ref[real], atol=2e-5, rtol=2e-5)
+    for a, b, name in zip(g_got, g_ref, "qkv"):
+        np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5, err_msg=name)
+    monkeypatch.setattr(A, "_with_tables", lambda kernel, tables: kernel)
+    static, _ = run(splash)
+    np.testing.assert_array_equal(got[real], static[real])
+
+
+# sha256 of str(jaxpr) of the backward pass of one call, taken at the
+# commit before the compacted tables (PR 34): (rows, t, window)
+STATIC_JAXPR = {
+    (1, 1024, None): "7fc849574ba150c4f3b9f5f8f0311417d7949c09d52ba1c9a1292bdc0d302d3d",
+    (3, 2048, None): "68f17527c86c1846ef97da91460e93444b55e03d9e5256b090757715a9f55702",
+    (3, 2048, 512): "174e14466af1ee5d5bec27f916b4d5188efb24f087e0fe1320fb0030aad29956",
+}
+
+
+@pytest.mark.parametrize("rows,t,window", sorted(STATIC_JAXPR, key=str))
+def test_short_rows_and_rows_together_trace_the_program_they_did(rows, t, window):
+    """A row under 2,048 and three rows in one call keep the static
+    kernels and the fused backward: the jaxpr of the backward pass is
+    the parent commit's to the character."""
+    import hashlib
+
+    from areal_tpu.ops.attention import splash_packed_attention
+
+    q = jax.ShapeDtypeStruct((rows, t, 4, 32), jnp.float32)
+    kv = jax.ShapeDtypeStruct((rows, t, 2, 32), jnp.float32)
+    ids = jax.ShapeDtypeStruct((rows, t), jnp.int32)
+
+    def loss(q, k, v, seg, pos):
+        return splash_packed_attention(q, k, v, seg, pos, interpret=True, window=window).sum()
+
+    text = str(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, kv, kv, ids, ids))
+    assert hashlib.sha256(text.encode()).hexdigest() == STATIC_JAXPR[rows, t, window]
+    assert "splash_mqa_dkv" in text and "splash_mqa_dq" not in text
 
 
 @pytest.mark.parametrize("impl,t,want", [

@@ -44,10 +44,14 @@ def _shape(shape, dtype, sharding):
 @pytest.mark.parametrize("window", [2048, None], ids=["window", "full"])
 def test_splash_row_of_16k_at_32_over_4_heads_compiles_forward_and_backward(one_chip, window, rows):
     """The segment ids are arguments, so the block tables a long row
-    alone skips by (`ops/attention._block_tables`) are values of the run:
-    scalar-prefetch operands that are traced. Three rows in one call keep
-    the static kernel; a caller's `vmap` over rows each given alone is a
-    table a row and pallas's own loop over the kernel calls."""
+    alone walks (`ops/attention._block_tables`) are values of the run:
+    scalar-prefetch operands that are traced, compacted to one of a few
+    widths, each a branch of one program, and its backward is splash's
+    dq kernel and dkv kernel: no `[kv blocks, heads, t, hd]` partials of
+    dq (537 MB here) and no sum over them. Three rows in one call keep
+    the static kernels and the fused backward; a caller's `vmap` over
+    rows each given alone is a table a row, pallas's own loop over the
+    kernel calls and every width run (a select picks)."""
     from areal_tpu.ops.attention import splash_packed_attention
 
     t, hq, hkv, hd = 8192, 32, 4, 128  # half the longest row: a quicker compile
@@ -66,10 +70,54 @@ def test_splash_row_of_16k_at_32_over_4_heads_compiles_forward_and_backward(one_
             out = attend(q, k, v, seg, pos)
         return out.astype(jnp.float32).sum()
 
-    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv, ids, ids).compile().as_text()
-    assert text.count("tpu_custom_call") >= 2  # the forward and the fused backward kernel
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv, ids, ids).compile()
+    text = compiled.as_text()
+    # the forward and the fused backward kernel, or forward, dq and dkv
+    assert text.count("tpu_custom_call") >= (2 if rows == 3 else 3)
+    assert ("splash_mqa_dq" in text) == (rows != 3)
     assert (" while(" in text) == (rows == "vmap")
-    assert (" reduce-window(" in text) == (rows != 3)  # the tables' running minimum
+    assert (" sort(" in text) == (rows != 3)  # the tables' compaction
+    assert (" conditional(" in text) == (rows == 1)  # the widths
+    if rows == 1:
+        assert f"bf16[{hkv},8,{hq // hkv},{t},{hd}]" not in text  # dq a kv block
+        assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9  # the parent's: 0.61e9
+
+
+def test_a_train_step_of_one_row_holds_no_partial_of_dq(one_chip, monkeypatch):
+    """A forward-backward micro-batch of `q15d12-train-ppo`'s model (12
+    layers, 12 / 2 heads of 128, full remat, the masked loss head) at
+    one row of 8,192, as the engine's accumulate step runs it: twelve
+    kernels (forward, remat's forward, dq and dkv at three widths), the
+    widths branches of the one program, and nowhere the fused
+    backward's `[2, 8, 6, 8192, 128]` partials of dq nor the sum over
+    them that followed the kernel."""
+    import json
+    import re
+
+    from areal_tpu.models.transformer import forward, init_params
+    from areal_tpu.ops.loss import fused_next_token_logprobs
+    from benchmark.model import transformer_config
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernels, not interpret mode
+    with open("benchmark/configs/qwen2.5-1.5b-d12.json") as f:
+        cfg = transformer_config(json.load(f), "bfloat16")
+    assert (cfg.n_layers, cfg.n_q_heads, cfg.n_kv_heads) == (12, 12, 2)
+    params = jax.tree_util.tree_map(
+        lambda a: _shape(a.shape, a.dtype, one_chip),
+        jax.eval_shape(lambda k: init_params(cfg, k), jax.random.PRNGKey(0)))
+    ids = _shape((1, 8192), jnp.int32, one_chip)
+
+    def loss(p, input_ids, seg, pos):
+        hidden = forward(p, cfg, input_ids, seg, pos, attn_impl="splash", remat=True,
+                         output="hidden")
+        return fused_next_token_logprobs(hidden, p["embedding"]["weight"].T, input_ids, seg,
+                                         scored=seg > 0).sum()
+
+    text = jax.jit(jax.value_and_grad(loss)).lower(params, ids, ids, ids).compile().as_text()
+    assert text.count("tpu_custom_call") == 12 and "splash_mqa_dq" in text
+    assert " conditional(" in text
+    assert "bf16[2,8,6,8192,128]" not in text
+    assert not re.search(r"attn_kernel/[^\n\"]*_splash_attention[^\n\"]*/reduce_sum", text)
 
 
 def test_held_experts_pass_compiles_at_the_published_widths(one_chip):
@@ -200,4 +248,4 @@ def test_splash_takes_q_and_k_at_64_against_v_at_128(one_chip, window):
         return out.astype(jnp.float32).sum()
 
     text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, k, v, ids, ids).compile().as_text()
-    assert text.count("tpu_custom_call") >= 2
+    assert text.count("tpu_custom_call") >= 3 and "splash_mqa_dq" in text
