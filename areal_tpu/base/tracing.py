@@ -19,12 +19,19 @@ profiler runs, every `span()` is mirrored into the device trace as a
 the trace's clock. `stop()` returns what was recorded since `start()`
 from memory.
 
+Apart from the spans, and whether or not they are on, the module keeps
+the process's build records (`watch_builds()`, `builds()`): every
+program jax traced, lowered, compiled or loaded from its persistent
+cache, by name and shape, on the spans' clock.
+
 Design constraints:
 
 - Hard no-op by default: every public call starts with one cached
   boolean branch; the recorder object is never allocated unless
   AREAL_RL_TRACE is truthy or `start()` was called (pinned by
-  tests/base/test_rl_tracing.py).
+  tests/base/test_rl_tracing.py). The build records need no recorder:
+  jax calls their listener only when it builds a program, which a
+  steady step never does.
 - A span never synchronises with the device: it reads the host clock
   twice and nothing else. Device time comes from the profiler.
 - Thread-safe: spans are appended to a bounded ring buffer under a lock
@@ -363,14 +370,18 @@ def stop() -> Dict[str, Any]:
     environment says, the profiler stops if this control started it, and
     what was recorded since `start()` comes back from memory:
     `{"spans": [...], "counters": {...}, "dropped": n, "profile_dir": ...,
-    "clock_anchor": {"name", "monotonic_ns"} | None}`. The JSONL shard,
-    where there is one, is flushed too. Without a session: the same
-    dict, empty."""
+    "clock_anchor": {"name", "monotonic_ns"} | None, "builds": [...],
+    "builds_dropped": n}`. The JSONL shard, where there is one, is
+    flushed too. `builds` is `builds()`: every build record of the
+    process so far, those from before `start()` too (set-up's; a reader
+    tells them from the session's by the clock). Without a session: the
+    same dict, empty but for the builds."""
     global _ENABLED, _SESSION, _MIRROR
     with _CTL_LOCK:
         if _SESSION is None:
             return {"spans": [], "counters": {}, "dropped": 0,
-                    "profile_dir": None, "clock_anchor": None}
+                    "profile_dir": None, "clock_anchor": None,
+                    "builds": builds(), "builds_dropped": _BUILDS_DROPPED}
         session, _SESSION = _SESSION, None
         _ENABLED = env_registry.get_bool(_ENV_ENABLE)
         try:
@@ -383,7 +394,8 @@ def stop() -> Dict[str, Any]:
             rec = _rec()
             out = rec.end_session()
             rec.flush()
-        return {**out, **session}
+        return {**out, **session, "builds": builds(),
+                "builds_dropped": _BUILDS_DROPPED}
 
 
 def count(name: str, n: float = 1) -> None:
@@ -643,3 +655,149 @@ def event(name: str, ctx: Optional[SpanContext] = None, **attrs: Any) -> None:
         return
     t = time.monotonic_ns()
     record_span(name, t, t, ctx=ctx, **attrs)
+
+
+# ---------------------------------------------------------------------------
+# Builds: the programs jax traces, lowers, compiles or loads from its cache
+# ---------------------------------------------------------------------------
+
+# jax.monitoring's names (jax 0.9.0). Each of the three is a scalar event
+# when its stage starts and a duration event when it ends;
+# `backend_compile_duration` wraps the look-up in the persistent cache, so
+# a hit ends as that event too, after `cache_hits` (and after
+# `/jax/compilation_cache/cache_retrieval_time_sec`, the read and
+# deserialisation alone, which lies inside it and makes no record).
+_BUILD_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+_CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+
+# The records, for the life of the process; overflow drops the oldest
+# half and counts them, like the ring.
+_BUILDS_CAP = 8192
+_BUILDS: List[Dict[str, Any]] = []
+_BUILDS_DROPPED = 0
+_BUILDS_LOCK = threading.Lock()
+_WATCHING = False
+
+
+class _BuildThread(threading.local):
+    """What the listeners keep between two events of one thread."""
+
+    # Stages of a build open on this thread: jax traces the jitted
+    # functions a function calls (every `jnp.where`) inside its trace,
+    # an event each, and only the outermost is a record: its seconds
+    # hold theirs.
+    depth = 0
+    # What the engine said its next dispatch runs (`build_site`).
+    site: Optional[tuple] = None
+    # The persistent cache's answer since the last backend compile:
+    # True after a hit, False after a miss that wrote the entry.
+    cache_hit: Optional[bool] = None
+
+
+_BT = _BuildThread()
+
+
+def watch_builds() -> None:
+    """Start keeping build records (idempotent). Imports jax: call it
+    where jax is in use anyway (the engine's module, `report_devices`)."""
+    global _WATCHING
+    with _BUILDS_LOCK:
+        if _WATCHING:
+            return
+        from jax import monitoring
+
+        monitoring.register_scalar_listener(_on_build_start)
+        monitoring.register_event_listener(_on_cache_event)
+        monitoring.register_event_duration_secs_listener(_on_build_end)
+        _WATCHING = True
+
+
+def build_site(program: str, fun: Any, rows: Optional[int] = None,
+               row_len: Optional[int] = None) -> None:
+    """Say what this thread dispatches next: the engine's name for the
+    program, the jitted function (a record takes the site only if jax
+    reports that function's name) and the micro-batch's shape. One
+    store; nothing reads it unless jax builds."""
+    _BT.site = (program, fun.__name__, rows, row_len)
+
+
+def builds() -> List[Dict[str, Any]]:
+    """Every build record of the process so far, oldest first: `{"kind":
+    "build", "phase": "trace" | "lower" | "compile" | "cache_load", "fun",
+    "program", "rows", "row_len", "start_ns", "end_ns", "tid",
+    "cache_hit"}` on `time.monotonic_ns`. Which step recompiled: the
+    records whose clock falls inside it."""
+    with _BUILDS_LOCK:
+        return list(_BUILDS)
+
+
+def builds_dropped() -> int:
+    return _BUILDS_DROPPED
+
+
+def _on_build_start(event: str, value: float, **_: Any) -> None:
+    if event in _BUILD_PHASES:
+        _BT.depth += 1
+
+
+def _on_cache_event(event: str, **_: Any) -> None:
+    if event == _CACHE_HIT_EVENT:
+        _BT.cache_hit = True
+    elif event == _CACHE_MISS_EVENT:
+        _BT.cache_hit = False
+
+
+def _on_build_end(event: str, duration_secs: float, **kw: Any) -> None:
+    global _BUILDS_DROPPED
+    end_ns = time.monotonic_ns()
+    bt = _BT
+    phase = _BUILD_PHASES.get(event)
+    if phase is None:
+        return
+    bt.depth = max(bt.depth - 1, 0)
+    fun, cache_hit = kw.get("fun_name"), None
+    if phase == "compile":
+        cache_hit, bt.cache_hit = bt.cache_hit, None
+        if cache_hit:
+            phase = "cache_load"
+    if bt.depth:  # inside another stage of this thread, whose seconds hold these
+        return
+    program = rows = row_len = None
+    site = bt.site
+    if site is not None and fun in (site[1], f"jit({site[1]})"):
+        program, _, rows, row_len = site
+    rec = {
+        "kind": "build", "phase": phase, "fun": fun, "program": program,
+        "rows": rows, "row_len": row_len,
+        "start_ns": end_ns - int(duration_secs * 1e9), "end_ns": end_ns,
+        "tid": threading.get_ident() & 0xFFFF, "cache_hit": cache_hit,
+    }
+    with _BUILDS_LOCK:
+        if len(_BUILDS) >= _BUILDS_CAP:
+            drop = _BUILDS_CAP // 2
+            del _BUILDS[:drop]
+            _BUILDS_DROPPED += drop
+        _BUILDS.append(rec)
+    if not enabled():
+        return
+    # While recording is on the record is also a span under the span that
+    # paid for it (`train.dispatch`, `fwd.dispatch`, ...), which says how
+    # many programs it built.
+    record_span(
+        "jit." + phase, rec["start_ns"], end_ns,
+        **{k: rec[k] for k in ("fun", "program", "rows", "row_len", "cache_hit")
+           if rec[k] is not None},
+    )
+    count("jit.build_s", duration_secs)
+    if phase in ("compile", "cache_load"):
+        live = _live_attrs.get()
+        if live is not None:
+            live["built"] = live.get("built", 0) + 1
+        count("jit.programs_compiled")
+        if cache_hit is not None:
+            count("jit.cache_hits" if cache_hit else "jit.cache_misses")

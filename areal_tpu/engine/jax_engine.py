@@ -64,6 +64,12 @@ from areal_tpu.parallel.sharding import batch_sharding, param_shardings
 
 logger = areal_logging.getLogger("jax_engine")
 
+# From this module's import on (jax is in use anyway), every program the
+# process builds is a record of `tracing.builds()`: the engine's own by
+# name and shape (`tracing.build_site` before each dispatch), and what is
+# built beside it (a caller's jitted weights, eager ops).
+tracing.watch_builds()
+
 PackedLossFn = Callable[[jnp.ndarray, Dict[str, jnp.ndarray]], Tuple[jnp.ndarray, Dict]]
 # rows -> [R, T], nonzero at the positions whose logprob the loss reads;
 # on device rows inside the step and on numpy rows for the host's counts.
@@ -386,7 +392,10 @@ class JaxTrainEngine(TrainEngine):
         return p["head"]["weight"]
 
     def _built(self, key, programs):
-        """A new entry of the jit cache, counted where it is made."""
+        """A new entry of the jit cache, counted where it is made
+        (`train.programs_built`: jitted functions; the programs jax
+        builds from them, one a micro-batch shape, are `tracing.builds`
+        and the counter `jit.programs_compiled`)."""
         self._jit_cache[key] = programs
         tracing.count("train.programs_built")
         return programs
@@ -834,27 +843,30 @@ class JaxTrainEngine(TrainEngine):
                 "overlap_events": 0.0,
             }
             self._record_overlap_stats()
-            attn = [self._attn_counts(rows["segment_ids"]) for rows in stacks]
-            counts = [a[1:-1] + self._head_counts(rows, scored_fn)
-                      + self._ssm_counts(rows["segment_ids"])
-                      for a, rows in zip(attn, stacks)]
-            self._count_batch(
-                "fused", len(mbs), sum(b.n_rows == 1 for b, _ in built),
-                n_tok, n_cells, *(sum(c) for c in zip(*counts)))
-
-            step = self._train_step_fn(
-                loss_name, loss_fn, tuple(sorted(stacks[0].keys())), len(mbs),
-                scored_fn,
-            )
             # One program for all the micro-batches: the span says the
             # shape of the largest.
             big = max(range(len(stacks)),
                       key=lambda i: np.prod(stacks[i]["input_ids"].shape[-2:]))
             rows, row_len = stacks[big]["input_ids"].shape[-2:]
+            attn_attrs = {}
+            if tracing.enabled():  # host passes whose only readers are spans and counters
+                attn = [self._attn_counts(rows["segment_ids"]) for rows in stacks]
+                counts = [a[1:-1] + self._head_counts(rows, scored_fn)
+                          + self._ssm_counts(rows["segment_ids"])
+                          for a, rows in zip(attn, stacks)]
+                self._count_batch(
+                    "fused", len(mbs), sum(b.n_rows == 1 for b, _ in built),
+                    n_tok, n_cells, *(sum(c) for c in zip(*counts)))
+                attn_attrs = dict(attn_row_len=attn[big][0], width=attn[big][-1])
+
+            step = self._train_step_fn(
+                loss_name, loss_fn, tuple(sorted(stacks[0].keys())), len(mbs),
+                scored_fn,
+            )
+            tracing.build_site("fused_step", step, rows, row_len)
             with tracing.span(
                 "train.dispatch", kind="fused", rows=rows, row_len=row_len,
-                attn_row_len=attn[big][0], width=attn[big][-1],
-                **self._stack_attrs,
+                **attn_attrs, **self._stack_attrs,
             ):
                 self.params, self.opt_state, packed, aux = step(
                     self.params, self.opt_state,
@@ -903,11 +915,13 @@ class JaxTrainEngine(TrainEngine):
                     }
                 cells = batch.n_rows * batch.row_len
                 tracing.set_attrs(tokens=batch.total_tokens, cells=cells)
-                run_len, *attn, width = self._attn_counts(rows["segment_ids"])
-                counts = (*attn, *self._head_counts(rows, scored_fn),
-                          *self._ssm_counts(rows["segment_ids"]))
-            return (rows_dev, denom, batch.total_tokens, cells,
-                    dict(attn_row_len=run_len, width=width), counts)
+                attn_attrs, counts = {}, None
+                if tracing.enabled():  # their only readers are spans and counters
+                    run_len, *attn, width = self._attn_counts(rows["segment_ids"])
+                    counts = (*attn, *self._head_counts(rows, scored_fn),
+                              *self._ssm_counts(rows["segment_ids"]))
+                    attn_attrs = dict(attn_row_len=run_len, width=width)
+            return (rows_dev, denom, batch.total_tokens, cells, attn_attrs, counts)
 
         pf = HostPrefetcher(
             mb_iter, stage, depth=self.prefetch_depth, name=f"train/{loss_name}",
@@ -918,8 +932,9 @@ class JaxTrainEngine(TrainEngine):
         denom_sum, n_tok, n_cells, n_one_row = 0.0, 0, 0, 0
         # attention's cells at the run length, run, causal, its grid steps
         # walked, live; the head's positions read, cells run; the
-        # state-space scan's chunks, live, mixed, and its resets
-        n_counts = [0] * 11
+        # state-space scan's chunks, live, mixed, and its resets; counted
+        # while tracing is on (`n_counted` of the micro-batches)
+        n_counts, n_counted = [0] * 11, 0
         gaps_ms: List[float] = []
         mark = time.monotonic_ns()
         try:
@@ -930,17 +945,21 @@ class JaxTrainEngine(TrainEngine):
                 n_cells += cells
                 rows, row_len = rows_dev["input_ids"].shape
                 n_one_row += int(rows == 1)
-                n_counts = [n + c for n, c in zip(n_counts, counts)]
+                if counts is not None:
+                    n_counted += 1
+                    n_counts = [n + c for n, c in zip(n_counts, counts)]
                 if carry is None:
                     first, nxt = self._accum_step_fns(
                         loss_name, loss_fn, tuple(sorted(rows_dev.keys())),
                         scored_fn,
                     )
+                    tracing.build_site("accum_first", first, rows, row_len)
                     with tracing.span("train.dispatch", kind="first",
                                       rows=rows, row_len=row_len,
                                       **attn_attrs, **self._stack_attrs):
                         carry = first(self.params, rows_dev)
                 else:
+                    tracing.build_site("accum_next", nxt, rows, row_len)
                     with tracing.span("train.dispatch", kind="next",
                                       rows=rows, row_len=row_len,
                                       **attn_attrs, **self._stack_attrs):
@@ -950,14 +969,16 @@ class JaxTrainEngine(TrainEngine):
             pf.close()
         global_denom = max(denom_sum, 1.0)
         apply = self._apply_step_fn(loss_name)
+        tracing.build_site("apply", apply)
         with tracing.span("train.apply"):
             self.params, self.opt_state, packed, aux = apply(
                 self.params, self.opt_state, carry,
                 jnp.asarray(1.0 / global_denom, jnp.float32),
                 jnp.asarray(lr, jnp.float32),
             )
-        self._count_batch("overlapped", n_mbs, n_one_row, n_tok, n_cells,
-                          *n_counts)
+        if n_counted == n_mbs:  # tracing was on for the whole batch
+            self._count_batch("overlapped", n_mbs, n_one_row, n_tok, n_cells,
+                              *n_counts)
         self.last_overlap = {
             "packing_efficiency": n_tok / max(n_cells, 1),
             "h2d_wait_ms": pf.wait_ms,
@@ -1252,53 +1273,67 @@ class JaxTrainEngine(TrainEngine):
         fn = self._forward_fn(output)
         per_mb_flat: List[np.ndarray] = []
         mb_seqlens: List[List[int]] = []
-        if self.prefetch_depth > 0 and not self._serial_dispatch:
-            from areal_tpu.engine.prefetch import HostPrefetcher
+        n_tok = n_cells = 0
+        with tracing.span("fwd.batch"):
+            if self.prefetch_depth > 0 and not self._serial_dispatch:
+                from areal_tpu.engine.prefetch import HostPrefetcher
 
-            mb_iter, _, _, bwd_indices = input_.split_lazy(mb_spec)
+                mb_iter, _, _, bwd_indices = input_.split_lazy(mb_spec)
 
-            def stage(mb):
-                batch, rows = self._build_rows(mb, keys=[main_key])
-                return batch, self._device_rows(rows), mb.seqlens_of()
+                def stage(mb):
+                    batch, rows = self._build_rows(mb, keys=[main_key])
+                    return batch, self._device_rows(rows), mb.seqlens_of()
 
-            pf = HostPrefetcher(
-                mb_iter, stage, depth=self.prefetch_depth, name="forward"
-            )
-            batches, outs = [], []
-            n_tok = n_cells = 0
-            gaps_ms: List[float] = []
-            mark = time.monotonic_ns()
-            try:
-                for batch, rows_dev, sl in pf:
-                    gaps_ms.append((time.monotonic_ns() - mark) / 1e6)
-                    outs.append(fn(self.params, rows_dev))  # not fetched
-                    batches.append(batch)
-                    mb_seqlens.append(sl)
+                pf = HostPrefetcher(
+                    mb_iter, stage, depth=self.prefetch_depth, name="forward",
+                    wait_span="fwd.wait_input",
+                )
+                batches, outs = [], []
+                gaps_ms: List[float] = []
+                mark = time.monotonic_ns()
+                try:
+                    for batch, rows_dev, sl in pf:
+                        gaps_ms.append((time.monotonic_ns() - mark) / 1e6)
+                        tracing.build_site("forward", fn, batch.n_rows, batch.row_len)
+                        with tracing.span("fwd.dispatch", rows=batch.n_rows,
+                                          row_len=batch.row_len):
+                            outs.append(fn(self.params, rows_dev))  # not fetched
+                        batches.append(batch)
+                        mb_seqlens.append(sl)
+                        n_tok += batch.total_tokens
+                        n_cells += batch.n_rows * batch.row_len
+                        mark = time.monotonic_ns()
+                finally:
+                    pf.close()
+                with tracing.span("fwd.fetch"):
+                    fetched = jax.device_get(outs)  # one blocking drain per batch
+                per_mb_flat = [
+                    b.gather_flat(np.asarray(o, np.float32))
+                    for b, o in zip(batches, fetched)
+                ]
+                self.last_overlap = {
+                    "packing_efficiency": n_tok / max(n_cells, 1),
+                    "h2d_wait_ms": pf.wait_ms,
+                    "dispatch_gap_ms": float(np.mean(gaps_ms)) if gaps_ms else 0.0,
+                    "overlap_events": float(pf.overlap_count()),
+                }
+                self._record_overlap_stats()
+            else:
+                mbs, _, bwd_indices = input_.split(mb_spec)
+                for mb in mbs:
+                    batch, rows = self._build_rows(mb, keys=[main_key])
+                    rows_dev = self._device_rows(rows)
+                    tracing.build_site("forward", fn, batch.n_rows, batch.row_len)
+                    with tracing.span("fwd.dispatch", rows=batch.n_rows,
+                                      row_len=batch.row_len):
+                        out_dev = fn(self.params, rows_dev)
+                    with tracing.span("fwd.fetch"):
+                        out_rows = np.asarray(out_dev, np.float32)
+                    per_mb_flat.append(batch.gather_flat(out_rows))
+                    mb_seqlens.append(mb.seqlens_of())
                     n_tok += batch.total_tokens
                     n_cells += batch.n_rows * batch.row_len
-                    mark = time.monotonic_ns()
-            finally:
-                pf.close()
-            fetched = jax.device_get(outs)  # one blocking drain per batch
-            per_mb_flat = [
-                b.gather_flat(np.asarray(o, np.float32))
-                for b, o in zip(batches, fetched)
-            ]
-            self.last_overlap = {
-                "packing_efficiency": n_tok / max(n_cells, 1),
-                "h2d_wait_ms": pf.wait_ms,
-                "dispatch_gap_ms": float(np.mean(gaps_ms)) if gaps_ms else 0.0,
-                "overlap_events": float(pf.overlap_count()),
-            }
-            self._record_overlap_stats()
-        else:
-            mbs, _, bwd_indices = input_.split(mb_spec)
-            for mb in mbs:
-                batch, rows = self._build_rows(mb, keys=[main_key])
-                rows_dev = self._device_rows(rows)
-                out_rows = np.asarray(fn(self.params, rows_dev), np.float32)
-                per_mb_flat.append(batch.gather_flat(out_rows))
-                mb_seqlens.append(mb.seqlens_of())
+            tracing.set_attrs(n_mbs=len(mb_seqlens), tokens=n_tok, cells=n_cells)
         merged = SequenceSample.reorder_output(
             np.concatenate(per_mb_flat, axis=0),
             mb_seqlens,

@@ -311,7 +311,9 @@ class PPOActorInterface(ModelInterface):
                 batch, rows = engine._build_rows(input_)
                 tracing.set_attrs(rows=batch.n_rows, row_len=batch.row_len)
                 rows_dev = engine._device_rows(rows)
-                adv_rows, ret_rows, resp_rows, kl_sum = self._prep_fn(engine)(
+                prep = self._prep_fn(engine)
+                tracing.build_site("ppo_prep", prep, batch.n_rows, batch.row_len)
+                adv_rows, ret_rows, resp_rows, kl_sum = prep(
                     rows_dev, jnp.asarray(kl_coef, jnp.float32)
                 )
                 adv_flat = batch.gather_flat(np.asarray(adv_rows))
@@ -497,7 +499,9 @@ class PPOCriticInterface(ModelInterface):
                 batch, rows = engine._build_rows(input_)
                 tracing.set_attrs(rows=batch.n_rows, row_len=batch.row_len)
                 rows_dev = engine._device_rows(rows)
-                _, ret_rows, resp_rows, kl_sum = self._helper._prep_fn(engine)(
+                prep = self._helper._prep_fn(engine)
+                tracing.build_site("ppo_prep", prep, batch.n_rows, batch.row_len)
+                _, ret_rows, resp_rows, kl_sum = prep(
                     rows_dev, jnp.asarray(self.kl_controller.value, jnp.float32)
                 )
                 ret_flat = batch.gather_flat(np.asarray(ret_rows))

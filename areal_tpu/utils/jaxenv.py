@@ -15,7 +15,7 @@ import os
 import threading
 from typing import Any, Dict, List
 
-from areal_tpu.base import logging
+from areal_tpu.base import logging, tracing
 
 logger = logging.getLogger("ran")
 
@@ -23,7 +23,6 @@ RAN_TAG = "areal-ran"
 
 _lock = threading.Lock()
 _said: set = set()
-_compile_s = 0.0
 
 
 def say(kind: str, **fields: Any) -> None:
@@ -61,21 +60,15 @@ def parse_ran(text: str) -> List[Dict[str, Any]]:
     return out
 
 
-def _on_compile_event(event: str, duration_secs: float, **_) -> None:
-    global _compile_s
-    if event.startswith("/jax/core/compile/"):
-        with _lock:
-            _compile_s += duration_secs
-
-
 def report_devices(worker: str) -> None:
     """Say which devices this process owns (its whole local view: the
-    launcher shows a worker only its own chips), and start counting the
-    seconds jax spends tracing, lowering and compiling. On a TPU the
-    native host ops must be in use, not their Python fallbacks."""
+    launcher shows a worker only its own chips), and start keeping the
+    records of what jax traces, lowers and compiles (`tracing.builds`).
+    On a TPU the native host ops must be in use, not their Python
+    fallbacks."""
     import jax
 
-    jax.monitoring.register_event_duration_secs_listener(_on_compile_event)
+    tracing.watch_builds()
     from areal_tpu.ops import host_ops
 
     devs = jax.local_devices()
@@ -98,15 +91,15 @@ def report_devices(worker: str) -> None:
 
 
 def report_usage(worker: str) -> None:
-    """Say the peak HBM of every local device and the compile seconds
-    so far (call after work that should be accounted)."""
+    """Say the peak HBM of every local device and the seconds jax spent
+    building programs so far: tracing, lowering, compiling and loading
+    from its cache (call after work that should be accounted)."""
     from areal_tpu.base import monitor
 
-    with _lock:
-        compile_s = round(_compile_s, 1)
+    build_ns = sum(b["end_ns"] - b["start_ns"] for b in tracing.builds())
     say(
         "usage",
         worker=worker,
         peak_hbm_bytes=monitor.device_peak_bytes(),
-        compile_s=compile_s,
+        compile_s=round(build_ns / 1e9, 1),
     )

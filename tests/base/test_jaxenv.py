@@ -69,9 +69,27 @@ def test_report_devices_and_usage_on_the_cpu(ran_log):
     assert use["compile_s"] >= 0
 
 
-def test_compile_seconds_accumulate(monkeypatch):
-    monkeypatch.setattr(jaxenv, "_compile_s", 0.0)
-    jaxenv._on_compile_event("/jax/core/compile/backend_compile_duration", 1.5)
-    jaxenv._on_compile_event("/jax/core/compile/jaxpr_trace_duration", 0.25)
-    jaxenv._on_compile_event("/jax/compilation_cache/cache_retrieval_time_sec", 9.0)
-    assert jaxenv._compile_s == 1.75
+def test_compile_seconds_are_the_build_records(ran_log, monkeypatch):
+    """`compile_s` is read off `tracing.builds()` (the program's one
+    jax.monitoring listener): every stage's seconds, a cache load's too,
+    and a trace inside a trace once."""
+    from jax import monitoring
+
+    from areal_tpu.base import tracing
+
+    tracing.watch_builds()
+    monkeypatch.setattr(tracing, "_BUILDS", [])
+    trace = "/jax/core/compile/jaxpr_trace_duration"
+    backend = "/jax/core/compile/backend_compile_duration"
+    monitoring.record_scalar(trace, 0.0, fun_name="outer")
+    monitoring.record_scalar(trace, 0.0, fun_name="inner")
+    monitoring.record_event_duration_secs(trace, 0.125, fun_name="inner")
+    monitoring.record_event_duration_secs(trace, 0.25, fun_name="outer")
+    monitoring.record_scalar(backend, 0.0, fun_name="jit(outer)")
+    monitoring.record_event_duration_secs(
+        "/jax/compilation_cache/cache_retrieval_time_sec", 9.0)
+    monitoring.record_event_duration_secs(backend, 1.5, fun_name="jit(outer)")
+    assert [b["fun"] for b in tracing.builds()] == ["outer", "jit(outer)"]
+    jaxenv.report_usage("w")
+    [use] = _facts(ran_log)
+    assert use["compile_s"] == 1.8  # 1.75 to a tenth, as the line prints it

@@ -86,6 +86,191 @@ def test_disabled_inject_into_returns_same_dict(untraced):
 
 
 # ---------------------------------------------------------------------------
+# Build records: what jax traces, lowers, compiles or loads from its cache
+# ---------------------------------------------------------------------------
+
+
+def _jitted(name):
+    """A jitted function of that name that calls a jitted function (and
+    `jnp.where`, jitted too): jax traces both inside the outer trace."""
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def helper(x):
+        return jnp.where(x > 0, x, 0.0) * 2
+
+    def f(x):
+        return jnp.sum(helper(x))
+
+    f.__name__ = f.__qualname__ = name
+    return jax.jit(f)
+
+
+def _since(n, fun=None):
+    return [b for b in tracing.builds()[n:]
+            if fun is None or b["fun"] in (fun, f"jit({fun})")]
+
+
+def _phases(recs):
+    """The records' phases; the suite runs under a persistent cache
+    (tests/conftest.py), so a program is compiled (a miss that writes the
+    entry) the first time a machine sees it and loaded ever after."""
+    for b in recs:
+        if b["phase"] in ("compile", "cache_load"):
+            assert b["cache_hit"] is (b["phase"] == "cache_load")
+        else:
+            assert b["cache_hit"] is None
+    return [{"cache_load": "compile"}.get(b["phase"], b["phase"]) for b in recs]
+
+
+def test_a_first_call_leaves_trace_lower_and_compile_and_a_second_none(untraced):
+    import jax.numpy as jnp
+
+    tracing.watch_builds()
+    tracing.watch_builds()  # idempotent: one listener, one record an event
+    f, x = _jitted("builds_probe_a"), jnp.ones((4, 8))
+    n, t0 = len(tracing.builds()), tracing.now_ns()
+    f(x)
+    t1 = tracing.now_ns()
+    recs = _since(n, "builds_probe_a")
+    assert _phases(recs) == ["trace", "lower", "compile"]
+    # the functions traced inside the outer trace are no records of their
+    # own: the outer's seconds hold theirs
+    assert {b["fun"] for b in _since(n)} == {"builds_probe_a", "jit(builds_probe_a)"}
+    for b in recs:
+        assert b["kind"] == "build" and t0 <= b["start_ns"] <= b["end_ns"] <= t1
+        assert b["program"] is None and b["rows"] is None  # nobody named a site
+    assert [a["end_ns"] <= b["start_ns"] + 1_000_000 for a, b in zip(recs, recs[1:])]
+    n = len(tracing.builds())
+    f(x)
+    assert _since(n) == []
+    f(jnp.ones((16, 8)))  # a new shape is a new program
+    assert _phases(_since(n, "builds_probe_a")) == ["trace", "lower", "compile"]
+    # all of it with spans off: no recorder
+    assert tracing.recorder() is None
+
+
+def test_a_record_takes_the_site_only_of_the_function_it_names(untraced):
+    import jax.numpy as jnp
+
+    tracing.watch_builds()
+    f, g = _jitted("builds_probe_b"), _jitted("builds_probe_c")
+    n = len(tracing.builds())
+    tracing.build_site("accum_first", f, 2, 128)
+    f(jnp.ones((2, 128)))
+    g(jnp.ones((2, 128)))  # built after the site was named, by another function
+    mine, other = _since(n, "builds_probe_b"), _since(n, "builds_probe_c")
+    assert len(mine) == len(other) == 3
+    assert all((b["program"], b["rows"], b["row_len"]) == ("accum_first", 2, 128)
+               for b in mine)
+    assert all(b["program"] is None and b["row_len"] is None for b in other)
+
+
+def test_the_list_of_builds_is_bounded_and_counts_what_it_drops(untraced, monkeypatch):
+    import jax.numpy as jnp
+
+    tracing.watch_builds()
+    monkeypatch.setattr(tracing, "_BUILDS_CAP", 8)
+    dropped = tracing.builds_dropped()
+    f = _jitted("builds_probe_d")
+    for i in range(1, 8):
+        f(jnp.ones((i, 3)))
+    assert len(tracing.builds()) <= 8
+    assert tracing.builds_dropped() > dropped
+    assert tracing.builds()[-1]["fun"] == "jit(builds_probe_d)"  # the oldest went
+    assert tracing.stop()["builds_dropped"] == tracing.builds_dropped()
+
+
+def test_stop_returns_builds_made_before_start_and_spans_for_those_inside(live):
+    import jax.numpy as jnp
+
+    tracing.watch_builds()
+    f, x = _jitted("builds_probe_e"), jnp.ones((3, 2))
+    tracing.build_site("apply", print)  # this thread's last dispatch was another's
+    n = len(tracing.builds())
+    f(jnp.ones((2, 2)))  # set-up: before any session
+    assert tracing.recorder() is None
+    tracing.start()
+    tracing.build_site("forward", f, 3, 2)
+    with tracing.span("fwd.dispatch") as ctx:
+        f(x)
+    with tracing.span("fwd.dispatch"):
+        f(x)  # builds nothing
+    got = tracing.stop()
+    recs = [b for b in got["builds"][n:] if "builds_probe_e" in b["fun"]]
+    assert _phases(recs) == ["trace", "lower", "compile"] * 2
+    assert [b["rows"] for b in recs] == [None] * 3 + [3] * 3
+    first, second = [s for s in got["spans"] if s["name"] == "fwd.dispatch"]
+    assert first["attrs"] == {"built": 1} and "attrs" not in second
+    jit = [s for s in got["spans"] if s["name"].startswith("jit.")]
+    assert [s["name"] for s in jit] == ["jit." + b["phase"] for b in recs[3:]]
+    for s, b in zip(jit, recs[3:]):
+        assert s["parent"] == ctx.span_id and s["trace"] == ctx.trace_id
+        assert (s["start_ns"], s["end_ns"]) == (b["start_ns"], b["end_ns"])
+        assert s["attrs"] == {
+            "fun": b["fun"], "program": "forward", "rows": 3, "row_len": 2,
+            **({} if b["cache_hit"] is None else {"cache_hit": b["cache_hit"]})}
+    c = got["counters"]
+    assert c["jit.programs_compiled"] == 1
+    assert c.get("jit.cache_hits", 0) + c.get("jit.cache_misses", 0) == 1
+    assert c["jit.build_s"] == pytest.approx(
+        sum(b["end_ns"] - b["start_ns"] for b in recs[3:]) / 1e9, rel=1e-3)
+
+
+def test_a_program_no_cache_has_seen_is_a_miss_and_its_twin_a_load(untraced):
+    import jax
+    import jax.numpy as jnp
+
+    tracing.watch_builds()
+    c = float(int.from_bytes(os.urandom(4), "big"))  # in no cache yet
+
+    def twin():
+        def f(x):
+            return jnp.sum(x * c)
+
+        f.__name__ = f.__qualname__ = "builds_probe_f"
+        return jax.jit(f)
+
+    n = len(tracing.builds())
+    twin()(jnp.ones((3, 3)))
+    twin()(jnp.ones((3, 3)))  # traced anew, and the same program
+    got = [(b["phase"], b["cache_hit"]) for b in _since(n, "builds_probe_f")]
+    assert got == [("trace", None), ("lower", None), ("compile", False),
+                   ("trace", None), ("lower", None), ("cache_load", True)]
+
+
+def test_jaxs_events_in_its_order_on_a_hit_a_miss_and_without_a_cache(untraced):
+    """jax's own events, fired by hand in its order on a hit (compiler.py:
+    `cache_hits`, `cache_retrieval_time_sec`, then the
+    `backend_compile_duration` that wraps the look-up) and on a miss."""
+    from jax import monitoring
+
+    tracing.watch_builds()
+    compile_ev = "/jax/core/compile/backend_compile_duration"
+
+    def backend_compile(hit):
+        monitoring.record_scalar(compile_ev, 0.0, fun_name="jit(p)")
+        if hit:
+            monitoring.record_event("/jax/compilation_cache/cache_hits")
+            monitoring.record_event_duration_secs(
+                "/jax/compilation_cache/cache_retrieval_time_sec", 0.25)
+        else:
+            monitoring.record_event("/jax/compilation_cache/cache_misses")
+        monitoring.record_event_duration_secs(compile_ev, 0.5, fun_name="jit(p)")
+
+    n = len(tracing.builds())
+    backend_compile(hit=True)
+    backend_compile(hit=False)
+    monitoring.record_scalar(compile_ev, 0.0, fun_name="jit(p)")
+    monitoring.record_event_duration_secs(compile_ev, 0.5, fun_name="jit(p)")
+    got = [(b["phase"], b["cache_hit"]) for b in tracing.builds()[n:]]
+    assert got == [("cache_load", True), ("compile", False), ("compile", None)]
+    assert all(b["end_ns"] - b["start_ns"] == 500_000_000
+               for b in tracing.builds()[n:])
+
+
+# ---------------------------------------------------------------------------
 # The runtime control: start() / stop() in a live process
 # ---------------------------------------------------------------------------
 
@@ -144,8 +329,11 @@ def test_start_and_stop_are_idempotent_and_sessions_do_not_leak(live):
     tracing.event("first")
     tracing.count("c")
     assert [s["name"] for s in tracing.stop()["spans"]] == ["first"]
+    # empty but for the process's build records, which outlive sessions
     assert tracing.stop() == {"spans": [], "counters": {}, "dropped": 0,
-                              "profile_dir": None, "clock_anchor": None}
+                              "profile_dir": None, "clock_anchor": None,
+                              "builds": tracing.builds(),
+                              "builds_dropped": tracing.builds_dropped()}
     assert tracing.start() is True
     tracing.event("second")
     got = tracing.stop()

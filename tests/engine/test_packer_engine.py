@@ -128,6 +128,9 @@ def test_the_fused_step_takes_micro_batches_of_unequal_row_length_unpadded():
     assert pf == ["fused"] * 2 and pp == ["overlapped"] * 2
     # no micro-batch is padded to another's shape: both paths ship the same cells
     built = cf.pop("train.programs_built"), cp.pop("train.programs_built")
+    # what jax built for them (`jit.*`) is each path's own
+    cf, cp = ({k: v for k, v in c.items() if not k.startswith("jit.")}
+              for c in (cf, cp))
     assert cf == cp and cf["train.cells"] == 2 * sum(r * t for r, t in shapes)
     assert cf["train.one_row_batches"] == cf["train.micro_batches"] == 2 * len(shapes)
     assert built[0] == 1  # one program, a scan a shape

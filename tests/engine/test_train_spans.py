@@ -51,7 +51,11 @@ def recorded(request):
                     depth=PATHS[path]["depth"])
     batch = make_batch(n=9, seed=5)
     args = (batch, MicroBatchSpec(n_mbs=N_MBS), packed_loss, loss_weight)
-    eng.train_batch(*args, loss_name="t")  # warm, untraced
+    # warm, untraced; twice: the second call hands the programs updated
+    # parameters, and jax looks its cached trace up again (a `jit.trace`
+    # of microseconds), the third and every later one build nothing
+    eng.train_batch(*args, loss_name="t")
+    eng.train_batch(*args, loss_name="t")
     assert tracing.recorder() is None
     off = dict(eng.last_overlap)
     tracing.start()
@@ -217,3 +221,158 @@ def test_attn_cells_count_the_length_the_kernel_runs_at(impl):
         assert c["train.attn_live_steps"] == live * cfg.n_q_heads * cfg.n_layers
     else:
         assert c["train.attn_grid_steps"] == c["train.attn_live_steps"] == d["width"] == 0
+
+
+# ---------------------------------------------------------------------------
+# What the engine builds (tracing.builds, jit.*), the forward-only path's
+# spans, and what tracing costs when it is off
+# ---------------------------------------------------------------------------
+
+
+def _long_batch(seed, n=6, lo=40, hi=60):
+    """Sequences long enough that a micro-batch's row is a rung above
+    `make_batch`'s."""
+    rng = np.random.RandomState(seed)
+    seqlens = rng.randint(lo, hi, size=n).tolist()
+    total = sum(seqlens)
+    return SequenceSample.from_default(
+        ids=[f"long{seed}-{i}" for i in range(n)], seqlens=seqlens,
+        data={"packed_input_ids": rng.randint(0, 64, size=total),
+              "loss_mask": np.ones(total, np.float32)})
+
+
+def _children(spans, parent):
+    return [s for s in spans if s["parent"] == parent["span"]]
+
+
+def test_a_new_shape_through_an_old_jit_entry_is_built_under_its_dispatch():
+    eng = mk_engine(init_params(small_cfg(), jax.random.PRNGKey(8)), depth=2)
+    spec = MicroBatchSpec(n_mbs=N_MBS)
+    tracing.start()
+    try:
+        for _ in range(2):  # the second call looks its traces up again
+            eng.train_batch(make_batch(n=9, seed=8), spec, packed_loss,
+                            loss_weight, loss_name="t")
+        warm = tracing.stop()
+        n_builds = len(warm["builds"])
+        tracing.start()
+        eng.train_batch(_long_batch(8), spec, packed_loss, loss_weight,
+                        loss_name="t")
+    finally:
+        got = tracing.stop()
+    # jit-cache entries: first+next and the apply, made by the first call
+    assert warm["counters"]["train.programs_built"] == len(eng._jit_cache) == 2
+    assert "train.programs_built" not in got["counters"]
+    dispatches = [s for s in got["spans"] if s["name"] == "train.dispatch"]
+    shapes = {(s["attrs"]["rows"], s["attrs"]["row_len"]) for s in dispatches}
+    old = {(s["attrs"]["rows"], s["attrs"]["row_len"])
+           for s in warm["spans"] if s["name"] == "train.dispatch"}
+    assert shapes and not shapes & old  # every micro-batch a new shape
+    paid = [s for s in dispatches if "built" in s["attrs"]]
+    assert paid and got["counters"]["jit.programs_compiled"] == sum(
+        s["attrs"]["built"] for s in paid)
+    assert got["counters"]["jit.build_s"] > 0
+    for s in paid:
+        a = s["attrs"]
+        kids = _children(got["spans"], s)
+        assert [k["name"] for k in kids][:2] == ["jit.trace", "jit.lower"]
+        assert kids[2]["name"] in ("jit.compile", "jit.cache_load")
+        program = {"first": "accum_first", "next": "accum_next"}[a["kind"]]
+        for k in kids:
+            assert k["attrs"]["program"] == program
+            assert (k["attrs"]["rows"], k["attrs"]["row_len"]) == (a["rows"], a["row_len"])
+            assert s["start_ns"] <= k["start_ns"] + 1_000_000 and k["end_ns"] <= s["end_ns"]
+    # the records say the same without a session: which step recompiled
+    new = [b for b in got["builds"][n_builds:] if b["program"] is not None]
+    assert {b["program"] for b in new} <= {"accum_first", "accum_next", "apply"}
+    assert {(b["rows"], b["row_len"]) for b in new if b["rows"]} == shapes
+    assert all(b["fun"] in ("first", "jit(first)", "nxt", "jit(nxt)", "apply",
+                            "jit(apply)") for b in new)
+
+
+def test_the_fused_step_and_the_apply_are_named_too():
+    eng = mk_engine(init_params(small_cfg(), jax.random.PRNGKey(9)), depth=0)
+    n = len(tracing.builds())
+    eng.train_batch(make_batch(n=9, seed=9), MicroBatchSpec(n_mbs=N_MBS),
+                    packed_loss, loss_weight, loss_name="t")
+    assert tracing.recorder() is None  # records without spans
+    named = [b for b in tracing.builds()[n:] if b["program"]]
+    assert {b["program"] for b in named} == {"fused_step"}
+    assert {b["fun"] for b in named} == {"step", "jit(step)"}
+    assert all(b["rows"] >= 1 and b["row_len"] % 32 == 0 for b in named)
+    eng2 = mk_engine(init_params(small_cfg(), jax.random.PRNGKey(9)), depth=2)
+    n = len(tracing.builds())
+    eng2.train_batch(make_batch(n=9, seed=9), MicroBatchSpec(n_mbs=N_MBS),
+                     packed_loss, loss_weight, loss_name="t")
+    by_program = {}
+    for b in tracing.builds()[n:]:
+        by_program.setdefault(b["program"], []).append(b)
+    assert set(by_program) >= {"accum_first", "accum_next", "apply"}
+    assert all(b["rows"] is None for b in by_program["apply"])
+
+
+@pytest.mark.parametrize("depth", [2, 0])
+def test_one_forward_records_the_fwd_spans(depth):
+    eng = mk_engine(init_params(small_cfg(), jax.random.PRNGKey(10)), depth=depth)
+    batch = make_batch(n=9, seed=10)
+    spec = MicroBatchSpec(n_mbs=N_MBS)
+    want = eng.forward(batch, spec).data["logprobs"]  # untraced, and builds
+    tracing.start()
+    try:
+        out = eng.forward(batch, spec).data["logprobs"]
+        eng.forward(_long_batch(10), spec)
+    finally:
+        got = tracing.stop()
+    np.testing.assert_array_equal(out, want)
+    roots = [s for s in got["spans"] if s["name"] == "fwd.batch"]
+    assert len(roots) == 2 and all(s["parent"] is None for s in roots)
+    kids = _children(got["spans"], roots[0])
+    names = [k["name"] for k in kids]
+    assert names.count("fwd.dispatch") == N_MBS
+    # one drain of every output, or one fetch a micro-batch without the prefetcher
+    assert names.count("fwd.fetch") == (1 if depth else N_MBS)
+    assert names.count("fwd.wait_input") == (N_MBS + 1 if depth else 0)
+    assert set(names) <= {"fwd.dispatch", "fwd.fetch", "fwd.wait_input"}
+    a = roots[0]["attrs"]
+    shapes = [(k["attrs"]["rows"], k["attrs"]["row_len"])
+              for k in kids if k["name"] == "fwd.dispatch"]
+    assert a["n_mbs"] == N_MBS and a["cells"] == sum(r * t for r, t in shapes)
+    assert 0 < a["tokens"] == sum(sum(sl) for sl in batch.seqlens["packed_input_ids"])
+    assert a["tokens"] <= a["cells"]
+    assert all("built" not in k.get("attrs", {}) for k in kids)  # warm
+    # the second forward's shapes are new: built under their dispatches
+    paid = [k for k in _children(got["spans"], roots[1])
+            if k["name"] == "fwd.dispatch" and "built" in k["attrs"]]
+    assert paid
+    for s in paid:
+        for k in _children(got["spans"], s):
+            assert k["name"].startswith("jit.") and k["attrs"]["program"] == "forward"
+            assert k["attrs"]["row_len"] == s["attrs"]["row_len"]
+
+
+@pytest.mark.parametrize("path", sorted(PATHS))
+def test_tracing_off_runs_no_host_count_and_the_stats_are_bit_equal(path, monkeypatch):
+    from areal_tpu.engine.jax_engine import JaxTrainEngine
+
+    params = init_params(small_cfg(), jax.random.PRNGKey(11))
+    batch = make_batch(n=9, seed=11)
+    args = (batch, MicroBatchSpec(n_mbs=N_MBS), packed_loss, loss_weight)
+    on_eng = mk_engine(params, depth=PATHS[path]["depth"])
+    tracing.start()
+    try:
+        on = [on_eng.train_batch(*args, loss_name="t") for _ in range(2)]
+    finally:
+        counters = tracing.stop()["counters"]
+    assert counters["train.attn_cells"] > 0 and counters["train.head_cells"] > 0
+
+    called = []
+    for name in ("_attn_counts", "_head_counts", "_ssm_counts", "_count_batch"):
+        monkeypatch.setattr(
+            JaxTrainEngine, name,
+            lambda self, *a, _n=name, **k: called.append(_n) or (0,) * 7)
+    off_eng = mk_engine(params, depth=PATHS[path]["depth"])
+    off = [off_eng.train_batch(*args, loss_name="t") for _ in range(2)]
+    assert called == [] and not tracing.enabled()
+    assert on == off  # every float, to the bit
+    assert off_eng.last_overlap["packing_efficiency"] == \
+        on_eng.last_overlap["packing_efficiency"] > 0

@@ -130,3 +130,27 @@ def test_attributes_count_what_the_step_did(step_spans):
     assert c["train.batches"] == N_MINIBATCHES
     assert c["train.micro_batches"] == N_MINIBATCHES * MBS_PER_MINIBATCH
     assert c["train.tokens"] == 8 * 24 <= c["train.cells"]
+
+
+@pytest.mark.parametrize("critic", [False, True], ids=["actor", "critic"])
+def test_a_first_step_builds_its_prep_under_ppo_prep_by_name_and_shape(critic):
+    tracing.reconfigure()
+    model = _model(critic)
+    itf = (PPOCriticInterface if critic else PPOActorInterface)(
+        n_minibatches=N_MINIBATCHES)
+    tracing.start()
+    try:
+        itf.train_step(model, _sample(values=critic), MicroBatchSpec(max_tokens_per_mb=48))
+    finally:
+        got = tracing.stop()
+    [prep] = [s for s in got["spans"] if s["name"] == "ppo.prep"]
+    named = [s for s in got["spans"] if s["parent"] == prep["span"]
+             and s["attrs"].get("program") == "ppo_prep"]
+    assert [s["name"] for s in named][:2] == ["jit.trace", "jit.lower"]
+    assert named[2]["name"] in ("jit.compile", "jit.cache_load") and len(named) == 3
+    a = prep["attrs"]
+    assert all((s["attrs"]["rows"], s["attrs"]["row_len"]) == (a["rows"], a["row_len"])
+               for s in named)
+    assert a["built"] >= 1  # the prep, and whatever eager op was new beside it
+    programs = {b["program"] for b in got["builds"]}
+    assert {"ppo_prep", "accum_first", "accum_next", "apply"} <= programs
