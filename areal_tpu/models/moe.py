@@ -41,10 +41,10 @@ layer on a chip of its own (`_held_experts`): it routes over all
 experts, computes the experts it holds and adds nothing for the rest,
 with no exchange and nothing that stands in for the absent chips. The
 (token, choice) pairs of held experts are compacted to the front of one
-sort and run through the grouped matmuls in passes over a buffer sized
-for an even share: the first pass always, further ones only while pairs
-are left, so no pair is dropped at any skew and the work follows the
-pairs held, not all k x T. The router's published sigmoid form
+sort and walked in row tiles of one expert each by a loop whose trip
+count is the number of tiles the run holds, forward and backward, each
+tile's products dense: no pair is dropped at any skew and the work
+follows the pairs held, not all k x T. The router's published sigmoid form
 (`score_func`), the selection bias and the shared expert live here too.
 Experts, routed and shared, are gated (`w_gate`, `w_up`, `w_down`) or
 plain (`w_in`, `w_out`) by `cfg.mlp_type`, under `cfg.activation`.
@@ -309,8 +309,8 @@ def _moe_mlp_ep(
 AUX_KEYS = ("load_balance_loss", "z_loss", "drop_rate", "router_entropy",
             "expert_load", "a2a_bytes")
 # What a share of the experts adds (`experts_held`): the real (token,
-# choice) pairs routed to experts held here, and the buffer rows of the
-# passes that ran over them.
+# choice) pairs routed to experts held here, and the rows of the tiles
+# that ran over them.
 HELD_AUX_KEYS = ("pairs_held", "rows_run")
 
 
@@ -325,94 +325,199 @@ def moe_aux_zeros(cfg: TransformerConfig) -> Dict[str, jnp.ndarray]:
     return aux
 
 
-# The buffer of one pass over the held pairs, as a multiple of an even
-# share (k x T x held / experts), rounded up to the grouped matmul's row
-# tile: with routing near even the first pass takes every pair.
-_HELD_BUFFER_FACTOR = 1.25
-_HELD_ROW_TILE = 512
+# Rows of one tile of the held pairs, and rows of one chunk of tiles. A
+# tile belongs to one expert, so its products are dense and every tile
+# re-reads that expert's matrices and adds into its float32 gradients:
+# the smaller the tile, the fewer rows an expert's short last tile runs
+# empty and the more often the matrices are read. A chunk's rows go to
+# their tokens in one scatter-add, and on the chip a scatter-add reads
+# and writes the whole [T, D] it adds into (0.44 ms at 16,384 x 2048 in
+# float32) whatever it adds (0.14 us a row of the chunk, held or not): a
+# tile at a time that pass cost ten times the tile's products. Measured
+# (PERF.md section 6, PR 37): the held part alone under mildly skewed
+# routing is fastest at tiles of 512, by 6-12 % over 256; in the trinity
+# cell, whose routers skew more, 256 and 384 come before 512 (15.90,
+# 15.85 and 16.00 s of device time a pass) and 256 runs the fewest empty
+# rows (137 % of the pairs held, against 158 and 179). Chunks of 4,096
+# to 12,288 rows are within a few per cent of each other.
+_HELD_ROW_TILE = 256
+_HELD_CHUNK_ROWS = 8192
 
 
-def held_buffer_rows(n_tokens: int, moe) -> int:
-    """Rows of the buffer one pass of `_held_experts` runs the grouped
-    matmuls over: static, from the shapes alone."""
-    pairs = n_tokens * moe.top_k
-    even = pairs * moe.n_held / moe.num_experts
-    rows = -(-int(_HELD_BUFFER_FACTOR * even) // _HELD_ROW_TILE) * _HELD_ROW_TILE
-    return max(1, min(pairs, rows))
+def _expert_ffn(xs, ws, act):
+    """One expert's MLP over its rows: gated `act(x w_gate) * (x w_up)
+    w_down` (three matrices) or plain `act(x w_in) w_out` (two)."""
+    if len(ws) == 3:
+        wg, wu, wd = ws
+        return (act(xs @ wg) * (xs @ wu)) @ wd
+    w_in, w_out = ws
+    return act(xs @ w_in) @ w_out
 
 
-def _add_rows_impl(rows, tok, n_tok: int):
-    b = rows.shape[0]
-    assert n_tok * b < 2**31
-    # By token, then by row: one sort of distinct keys (token x rows +
-    # row), the rows taken in that order, and a scatter-add that is told
-    # its indices are sorted. Left to itself the chip's compiler sorts
-    # the indices of a scatter with the rows as a second operand, and
-    # takes 8 s over that sort at 20k rows (1.8 s over this scatter, 1.5
-    # over this sort).
-    keys = jax.lax.sort(
-        tok.astype(jnp.int32) * b + jnp.arange(b, dtype=jnp.int32), is_stable=False)
-    return jnp.zeros((n_tok, rows.shape[1]), rows.dtype).at[keys // b].add(
-        rows[keys % b], indices_are_sorted=True)
+def _held_tiles(sizes, n_rows: int):
+    """The held pairs, sorted by expert, cut into tiles of
+    `_HELD_ROW_TILE` rows that hold one expert's pairs each (an expert's
+    last tile is short). `sizes`: pairs an expert. Returns the number of
+    tiles, a value of the run, and for every tile up to the static bound
+    (`n_rows` pairs in all) its expert, its first row and the pairs it
+    holds (0 past the tiles there are)."""
+    R, n_held = _HELD_ROW_TILE, sizes.shape[0]
+    ends = jnp.cumsum(sizes)
+    per = -(-sizes // R)  # tiles an expert
+    tile_ends = jnp.cumsum(per)
+    i = jnp.arange(-(-n_rows // R) + n_held, dtype=jnp.int32)
+    e = jnp.minimum(jnp.sum(tile_ends[None, :] <= i[:, None], axis=1), n_held - 1)
+    lo = (ends - sizes)[e] + (i - (tile_ends - per)[e]) * R
+    return tile_ends[-1], (e.astype(jnp.int32), lo.astype(jnp.int32),
+                           jnp.clip(ends[e] - lo, 0, R).astype(jnp.int32))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _add_rows(rows, tok, n_tok: int):
-    """[n_tok, D]: row r of `rows` added to token `tok[r]`."""
-    return _add_rows_impl(rows, tok, n_tok)
+def _tile_rows(i, tiles, pairs, gate, n_tok: int):
+    """Tile i: its expert, its rows' pairs, tokens and weights, and which
+    rows hold a pair of its own. A row past them (another expert's pair,
+    or one not held) is sent to the last token: what it adds there is a
+    zero."""
+    R = _HELD_ROW_TILE
+    e, lo, count = (a[i] for a in tiles)
+    valid = jnp.arange(R, dtype=jnp.int32) < count
+    pair = jax.lax.dynamic_slice(pairs, (lo,), (R,))
+    return e, pair, jnp.where(valid, pair % n_tok, n_tok - 1), gate[pair], valid
 
 
-def _add_rows_fwd(rows, tok, n_tok):
-    return _add_rows_impl(rows, tok, n_tok), tok
+def _tile_out(act, xs, ws, w, valid):
+    """A tile's weighted results, [R, D]. Masked on the way in and on the
+    way out, and before weighing: what a row past the tile's pairs holds
+    need not be finite, and 0 x it (the weight's gradient, were the mask
+    applied after) would not be 0."""
+    with jax.named_scope("moe_experts"):
+        ys = _expert_ffn(jnp.where(valid[:, None], xs, 0), ws, act)
+    with jax.named_scope("moe_combine"):
+        return w.astype(ys.dtype)[:, None] * jnp.where(valid[:, None], ys, 0)
 
 
-def _add_rows_bwd(n_tok, tok, dy):
-    return dy[tok], None
+def _expert_of(weights, e):
+    """Expert e's matrices, cut from the stacks."""
+    return tuple(jax.lax.dynamic_index_in_dim(m, e, keepdims=False) for m in weights)
 
 
-_add_rows.defvjp(_add_rows_fwd, _add_rows_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
-def _take_rows(x, tok, n_tok: int):
-    """x[tok] for x of `n_tok` rows, whose gradient is `_add_rows` (each
-    the other's transpose: neither pass leaves the compiler a scatter to
+def _add_rows(y, rows, tok, n_rows):
+    """y [T, D] with row r of `rows` added to token `tok[r]`, for the
+    first `n_rows` rows (the rest hold what an earlier chunk left). By
+    token, then by row: one sort of distinct keys (token x rows + row),
+    the rows taken in that order, and a scatter-add that is told its
+    indices are sorted. Left to itself the chip's compiler sorts the
+    indices of a scatter with the rows as a second operand, and takes 8 s
+    over that sort at 20k rows (1.8 s over this scatter, 1.5 over this
     sort)."""
-    return x[tok]
+    b = rows.shape[0]
+    assert y.shape[0] * b < 2**31
+    row = jnp.arange(b, dtype=jnp.int32)
+    keys = jax.lax.sort(jnp.where(row < n_rows, tok, y.shape[0] - 1) * b + row, is_stable=False)
+    row = keys % b
+    return y.at[keys // b].add(
+        jnp.where((row < n_rows)[:, None], rows[row], 0).astype(y.dtype), indices_are_sorted=True)
 
 
-def _take_rows_fwd(x, tok, n_tok):
-    return x[tok], tok
+def _chunk_loop(n_tiles, tile, carry, dtype):
+    """Walk the tiles a chunk at a time: `tile(i, carry) -> (carry, rows,
+    tok)` runs tile i and hands back the [R, D] rows of `dtype` it has
+    for tokens `tok`; a chunk's rows are added to `carry[0]`, [T, D],
+    together. Both loops' trip counts are values of the run: the chunks
+    that hold a tile, and the tiles a chunk holds."""
+    R = _HELD_ROW_TILE
+    G = max(1, _HELD_CHUNK_ROWS // R)
+
+    def chunk(c, state):
+        def one(j, state):
+            carry, buf, toks = state
+            carry, rows, tok = tile(c * G + j, carry)
+            return (carry, jax.lax.dynamic_update_slice(buf, rows, (j * R, 0)),
+                    jax.lax.dynamic_update_slice(toks, tok, (j * R,)))
+
+        held = jnp.minimum(G, n_tiles - c * G)
+        carry, buf, toks = jax.lax.fori_loop(0, held, one, state)
+        with jax.named_scope("moe_combine"):
+            return (_add_rows(carry[0], buf, toks, held * R),) + carry[1:], buf, toks
+
+    # a chunk's rows and their tokens: made once, written a tile at a time
+    return jax.lax.fori_loop(0, -(-n_tiles // G), chunk, (
+        carry, jnp.zeros((G * R, carry[0].shape[1]), dtype),
+        jnp.zeros((G * R,), jnp.int32)))[0]
 
 
-def _take_rows_bwd(n_tok, tok, d):
-    return _add_rows_impl(d, tok, n_tok), None
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
+def _run_tiles(act, xc, weights, gate, pairs, n_tiles, tiles):
+    """[T, D] float32: every tile's weighted results added to their
+    tokens. The gradient is a second walk over the tiles that computes a
+    tile again (`_run_tiles_bwd`): what is kept for it is the inputs,
+    nothing a tile made."""
+    T = xc.shape[0]
+
+    def tile(i, carry):
+        with jax.named_scope("moe_dispatch"):
+            e, _, tok, w, valid = _tile_rows(i, tiles, pairs, gate, T)
+            xs = xc[tok]
+        return carry, _tile_out(act, xs, _expert_of(weights, e), w, valid), tok
+
+    return _chunk_loop(n_tiles, tile, (jnp.zeros(xc.shape, jnp.float32),), xc.dtype)[0]
 
 
-_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+def _run_tiles_fwd(act, xc, weights, gate, pairs, n_tiles, tiles):
+    y = _run_tiles(act, xc, weights, gate, pairs, n_tiles, tiles)
+    return y, (xc, weights, gate, pairs, n_tiles, tiles)
 
 
-def _held_experts(xt, mp, moe, act, cdt, choice_e, gate, tok_idx, token_mask,
+def _run_tiles_bwd(act, res, dy):
+    xc, weights, gate, pairs, n_tiles, tiles = res
+    T = xc.shape[0]
+    dyc = dy.astype(xc.dtype)
+
+    def tile(i, carry):
+        dx, dws, dg = carry
+        with jax.named_scope("moe_dispatch"):
+            e, pair, tok, w, valid = _tile_rows(i, tiles, pairs, gate, T)
+            xs, d = xc[tok], dyc[tok]
+        _, vjp = jax.vjp(lambda xs, ws, w: _tile_out(act, xs, ws, w, valid),
+                         xs, _expert_of(weights, e), w)
+        dxs, dw_e, dw = vjp(d)
+        with jax.named_scope("moe_dispatch"):
+            dg = dg.at[pair].add(dw.astype(dg.dtype))
+        with jax.named_scope("moe_experts"):
+            dws = tuple(a.at[e].add(g.astype(a.dtype)) for a, g in zip(dws, dw_e))
+        return (dx, dws, dg), dxs, tok
+
+    # float32 sums: a token's rows come from up to k tiles, an expert's
+    # weight gradient from every tile it has
+    dx, dws, dg = _chunk_loop(n_tiles, tile, (
+        jnp.zeros(xc.shape, jnp.float32),
+        tuple(jnp.zeros(m.shape, jnp.float32) for m in weights),
+        jnp.zeros(gate.shape, jnp.float32)), xc.dtype)
+    return (dx.astype(xc.dtype), tuple(a.astype(m.dtype) for a, m in zip(dws, weights)),
+            dg.astype(gate.dtype), None, None, None)
+
+
+_run_tiles.defvjp(_run_tiles_fwd, _run_tiles_bwd)
+
+
+def _held_experts(xt, mp, moe, act, cdt, choice_e, gate, token_mask,
                   mats=("w_gate", "w_up", "w_down")):
     """The held experts' part of sum_e w_e Expert_e(x): [T, D], and
-    (pairs held, buffer rows run). `choice_e`, `gate`, `tok_idx`: the
-    k x T (token, choice) pairs' expert, weight and token, choice-major.
+    (pairs held, rows run). `choice_e`, `gate`: the k x T (token, choice)
+    pairs' expert and weight, choice-major (pair c T + t is token t's
+    choice c).
 
-    Of the k x T (token, choice) pairs only those of real tokens whose
-    expert is held here take part. One argsort puts them first, ordered
-    by expert; pass c takes rows [c B, (c + 1) B) of that order into a
-    buffer of B rows (`held_buffer_rows`), gathers their tokens, runs
-    the grouped matmuls (`mats`) with the group sizes that fall into the
-    pass, and adds the weighted results to their tokens. The first pass
-    always runs; pass c > 0 runs only if more than c B pairs are held
-    (`lax.cond` inside a scan: one traced body, static shapes; the
-    backward pass recomputes such a pass instead of keeping it), so no
-    pair is dropped whatever the imbalance and nothing is computed for
-    the pairs of absent experts. Rows of a buffer beyond the pairs left
-    are in no group: the grouped matmul skips them, and what it leaves
-    there is masked on the way in and on the way out."""
-    T, D = xt.shape
-    k = moe.top_k
+    Of the k x T pairs only those of real tokens whose expert is held
+    here take part. One sort puts them first, by expert; each expert's
+    pairs are cut into tiles of `_HELD_ROW_TILE` rows (`_held_tiles`),
+    and a loop whose trip count is the number of tiles the run has
+    (`_run_tiles`, `_chunk_loop`) gathers a tile's tokens, runs its
+    expert's MLP (`mats`) on them as dense products and adds the weighted
+    rows to their tokens, a chunk of tiles at a time; the backward pass
+    is a loop of the same count. So no pair is dropped whatever the
+    imbalance, and the work follows the pairs held: nothing runs, and
+    nothing is zeroed, for the pairs of absent experts or for tiles there
+    are not."""
+    T, k, R = xt.shape[0], moe.top_k, _HELD_ROW_TILE
     first, n_held = moe.experts_held
     with jax.named_scope("moe_dispatch"):
         here = (choice_e >= first) & (choice_e < first + n_held)
@@ -426,69 +531,22 @@ def _held_experts(xt, mp, moe, act, cdt, choice_e, gate, tok_idx, token_mask,
         n = k * T
         assert (n_held + 1) * n < 2**31
         keys = local_e * n + jnp.arange(n, dtype=jnp.int32)
-        order = jax.lax.sort(keys, is_stable=False) % n
-        sizes = jnp.bincount(local_e, length=n_held + 1)[:n_held].astype(jnp.int32)
-        ends = jnp.cumsum(sizes)
-        n_pairs = ends[-1]
-        tok_sorted = tok_idx[order]
-        gate_sorted = gate[order]
-    B = held_buffer_rows(T, moe)
-    n_pass = -(-k * T // B)
-    pad = n_pass * B - k * T
-    if pad:  # the last pass's slice stays inside the arrays
-        tok_sorted = jnp.pad(tok_sorted, (0, pad))
-        gate_sorted = jnp.pad(gate_sorted, (0, pad))
-    weights = tuple(mp[n].astype(cdt) for n in mats)
-    xc = xt.astype(cdt)
-
-    def one_pass(c):
-        lo = c * B
-        with jax.named_scope("moe_dispatch"):
-            tok = jax.lax.dynamic_slice(tok_sorted, (lo,), (B,))
-            w = jax.lax.dynamic_slice(gate_sorted, (lo,), (B,))
-            valid = (lo + jnp.arange(B, dtype=jnp.int32)) < n_pairs
-            # group sizes of this pass: each expert's rows cut to [lo, lo + B)
-            gs = jnp.diff(jnp.clip(ends, lo, lo + B), prepend=lo).astype(jnp.int32)
-            xs = jnp.where(valid[:, None], _take_rows(xc, tok, T), 0)
-        with jax.named_scope("moe_experts"):
-            ys = _grouped_ffn(xs, weights, gs, act)  # [B, D]
-        with jax.named_scope("moe_combine"):
-            # Mask before weighing: what the grouped matmul leaves in rows
-            # of no group need not be finite, and 0 x it (the weight's
-            # gradient, were the mask applied after) would not be 0.
-            ys = w.astype(cdt)[:, None] * jnp.where(valid[:, None], ys, 0)
-            return _add_rows(ys, tok, T)
-
-    y = one_pass(jnp.int32(0))
-    rows_run = jnp.float32(B)
-    if n_pass > 1:
-        # The whole guarded pass is what the backward pass recomputes:
-        # with the `cond` outside the checkpoint its residuals (the
-        # tokens and the expert weights) would leave it as outputs and
-        # the scan would keep a copy of them for every pass, run or not.
-        @jax.checkpoint
-        def guarded_pass(c):
-            return jax.lax.cond(
-                c * B < n_pairs, one_pass, lambda c: jnp.zeros((T, D), cdt), c)
-
-        def overflow(carry, c):
-            y, rows = carry
-            rows = rows + jnp.where(c * B < n_pairs, jnp.float32(B), 0.0)
-            return (y + guarded_pass(c), rows), None
-
-        (y, rows_run), _ = jax.lax.scan(
-            overflow, (y, rows_run), jnp.arange(1, n_pass, dtype=jnp.int32))
-    return y, n_pairs.astype(jnp.float32), rows_run
+        # a tile's slice of R rows stays inside the array
+        pairs = jnp.pad(jax.lax.sort(keys, is_stable=False) % n, (0, R))
+        sizes = jnp.sum(local_e[None, :] == jnp.arange(n_held, dtype=jnp.int32)[:, None],
+                        axis=1, dtype=jnp.int32)
+        n_tiles, tiles = _held_tiles(sizes, n)
+    y = _run_tiles(act, xt.astype(cdt), tuple(mp[m].astype(cdt) for m in mats),
+                   gate, pairs, n_tiles, tiles)
+    return (y.astype(cdt), jnp.sum(sizes).astype(jnp.float32),
+            (n_tiles * R).astype(jnp.float32))
 
 
 def _shared_expert(xt, sp, act, cdt):
     """The MLP every token passes through, gated or plain."""
     with jax.named_scope("moe_shared"):
-        xc = xt.astype(cdt)
-        if "w_in" in sp:
-            return act(xc @ sp["w_in"].astype(cdt)) @ sp["w_out"].astype(cdt)
-        h = act(xc @ sp["w_gate"].astype(cdt)) * (xc @ sp["w_up"].astype(cdt))
-        return h @ sp["w_down"].astype(cdt)
+        mats = ("w_in", "w_out") if "w_in" in sp else ("w_gate", "w_up", "w_down")
+        return _expert_ffn(xt.astype(cdt), tuple(sp[m].astype(cdt) for m in mats), act)
 
 
 def moe_mlp(
@@ -551,7 +609,7 @@ def moe_mlp(
 
     if moe.experts_held is not None:
         y, pairs_held, rows_run = _held_experts(
-            xt, mp, moe, act, cdt, choice_e, gate, tok_idx, token_mask, mats)
+            xt, mp, moe, act, cdt, choice_e, gate, token_mask, mats)
         held_aux = dict(pairs_held=pairs_held, rows_run=rows_run)
         drop_rate = jnp.zeros((), jnp.float32)
     elif dispatch == "dropless":

@@ -105,7 +105,7 @@ def test_the_stack_matches_the_reference_through_a_ppo_step(remat, monkeypatch):
     """`M E M E M * E M E`: a scan over two (M, E) units and five layers
     one by one, three parameter stacks; logprobs, the PPO loss and every
     parameter's gradient."""
-    monkeypatch.setattr(moe_lib, "_HELD_ROW_TILE", 8)  # several passes at toy size
+    monkeypatch.setattr(moe_lib, "_HELD_ROW_TILE", 8)  # several tiles an expert at toy size
     cfg = _cfg()
     assert [s.repeats for s in cfg.segments()] == [2, 1, 1, 1, 1, 1]
     params = _params(cfg)
@@ -323,6 +323,41 @@ def test_the_sixteen_shares_add_up_to_the_uncut_layer(monkeypatch):
     assert pairs == h.shape[0] * cfg.moe.top_k  # every pair is held by one share
 
 
+@pytest.mark.parametrize("tile", [4, 8, 512])
+def test_plain_experts_run_whole_tiles_and_rows_past_their_pairs_reach_nothing(tile, monkeypatch):
+    """A share of 4 plain squared-ReLU experts at tiles of 4, 8 and 512
+    rows: the rows run are tiles x the tile, at least the pairs held;
+    with NaN left in every row past a tile's pairs (masked on the way in)
+    the result and every gradient, the router's too, are the clean
+    run's."""
+    monkeypatch.setattr(moe_lib, "_HELD_ROW_TILE", tile)
+    hf, cfg, mlp, h = _expert_layer_inputs()
+    c = dataclasses.replace(cfg, moe=dataclasses.replace(cfg.moe, experts_held=(8, 4)))
+    mp = {k: (v[8:12] if k in ("w_in", "w_out") else v) for k, v in mlp.items()}
+    mask = jnp.arange(h.shape[0]) < 80  # the last tokens are padding
+
+    def run(mp, h):
+        y, aux = moe_lib.moe_mlp(h, mp, c, jnp.float32, token_mask=mask)
+        return (y * jnp.cos(y)).sum(), (aux["rows_run"], aux["pairs_held"])
+
+    (clean, (rows, pairs)), g_clean = jax.value_and_grad(run, (0, 1), has_aux=True)(mp, h)
+    real = moe_lib._expert_ffn
+
+    def dirty(xs, ws, act):
+        if xs.shape[0] != tile:  # the shared expert, over every token
+            return real(xs, ws, act)
+        return jnp.where((xs == 0).all(-1)[:, None], jnp.nan, real(xs, ws, act))
+
+    monkeypatch.setattr(moe_lib, "_expert_ffn", dirty)
+    (got, _), g = jax.value_and_grad(run, (0, 1), has_aux=True)(mp, h)
+    assert float(rows) % tile == 0 and float(rows) >= float(pairs) > 0
+    assert float(rows) < float(pairs) + 4 * tile  # under a tile an expert is empty
+    np.testing.assert_allclose(float(got), float(clean), rtol=1e-6)
+    for a, b in zip(jax.tree_util.tree_leaves(g), jax.tree_util.tree_leaves(g_clean)):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
+
+
 @pytest.mark.parametrize("dispatch", ["dropless", "capacity"])
 def test_plain_squared_relu_experts_are_a_loop_over_experts(dispatch):
     """`relu(x W_in)^2 W_out` for each chosen expert, weighted, plus the
@@ -424,12 +459,14 @@ def test_nemotron_h_config_and_names_round_trip():
         fam.config_from_hf(dict(HF, n_group=2))
 
 
-# sha256 of str(jaxpr) of the backward pass, taken at the commit before
-# this refactor (PR 31), by remat mode
+# sha256 of str(jaxpr) of the backward pass, by remat mode: taken at the
+# commit before that refactor (PR 31) and held until PR 37, which changed
+# what the held experts trace to (loops over tiles each way) and took
+# them again with nothing else changed
 AFMOE_JAXPR = {
-    "full": "9457132a71ff6fe4dbb750ccb0e442326e7c3e8fd9e3708135a880507a98f835",
-    "none": "f091a1f5ccb700f05d6b8312fe2200100bb8558127217dc335405044c3becef8",
-    "mlp": "adf4d8ac7b5a5edaaf3b1c293e5448446cf5eb947b530b8ef716a4c616c8d044",
+    "full": "c88f2f1b1f15a3c61e74860d6d403da242ae2cbcfd8bde713d9bb38ab5528c17",
+    "none": "78743c87aed401bfd9d038288a0c6ec9d416b026e46f4b32cfdf7973548355e8",
+    "mlp": "2e2614437332c271c3b53c232aeaa8eedd3316c713c87557b6b3dff57f41f09c",
 }
 
 

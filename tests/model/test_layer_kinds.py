@@ -107,7 +107,7 @@ def _assert_trees_close(got, want, rtol=2e-4):
 def test_each_layer_kind_matches_the_reference(window, mlp, rotary, monkeypatch):
     """Two layers of one kind: logprobs and the gradients of their sum,
     on packed rows whose sequences cross the window."""
-    monkeypatch.setattr(moe_lib, "_HELD_ROW_TILE", 8)  # several passes at toy size
+    monkeypatch.setattr(moe_lib, "_HELD_ROW_TILE", 8)  # several tiles an expert at toy size
     kind = LayerKind(mlp=mlp, window=window, rotary=rotary)
     hf = dict(HF, num_hidden_layers=2, num_dense_layers=2 if mlp == "dense" else 0,
               layer_types=["sliding_attention"] * 2)
@@ -196,12 +196,13 @@ def test_the_shares_add_up_to_the_uncut_layer(monkeypatch):
     assert pairs == h2.shape[0] * cfg.moe.top_k  # every pair is held by one share
 
 
-@pytest.mark.parametrize("tile", [8, 512], ids=["7-passes", "1-pass"])
+@pytest.mark.parametrize("tile", [8, 512], ids=["12-tiles-an-expert", "1-tile-an-expert"])
 def test_no_pair_is_dropped_when_every_token_goes_to_the_held_experts(tile, monkeypatch):
     """A skew that sends all k choices of every token to the experts held
-    here: 8 times an even share, so the overflow passes run; the answer
-    is the reference's and every pair is counted."""
+    here: 8 times an even share, so the loop runs as many tiles as that
+    takes; the answer is the reference's and every pair is counted."""
     monkeypatch.setattr(moe_lib, "_HELD_ROW_TILE", tile)
+    monkeypatch.setattr(moe_lib, "_HELD_CHUNK_ROWS", 4 * tile)  # 12 chunks of 4 tiles, or one
     hf, cfg, mlp, h2 = _expert_layer_inputs()
     held = (4, 4)  # k = 4 of the 4 held
     mlp = dict(mlp, expert_bias=jnp.zeros(16).at[4:8].set(10.0))
@@ -213,21 +214,21 @@ def test_no_pair_is_dropped_when_every_token_goes_to_the_held_experts(tile, monk
         want = ref.expert_layer(h2, mp, dict(hf, num_experts=4, num_experts_routed=16,
                                              experts_held_first=4))
         shared = ref._swiglu(h2, mp["shared"])
-    n, k = h2.shape[0], cfg.moe.top_k
+    k = cfg.moe.top_k
     np.testing.assert_allclose(np.asarray(y[:90]), np.asarray(want[:90]), atol=5e-5)
     # padding is routed nowhere: only the shared expert's part is left
     np.testing.assert_allclose(np.asarray(y[90:]), np.asarray(shared[90:]), atol=5e-5)
     assert float(aux["pairs_held"]) == 90 * k and float(aux["drop_rate"]) == 0.0
-    buf = moe_lib.held_buffer_rows(n, c.moe)
-    assert float(aux["rows_run"]) == -(-90 * k // buf) * buf
+    # each of the 4 held experts has 90 pairs: whole tiles of them
+    assert float(aux["rows_run"]) == 4 * -(-90 // tile) * tile
 
 
-def test_rows_of_no_group_never_reach_a_result_or_a_gradient(monkeypatch):
-    """On the chip the grouped matmul skips the rows of a pass's buffer
-    that lie in no group and leaves there whatever was in memory. Here
-    every such row is made NaN: the layer's result and every gradient
-    (the router's too, through the pairs' weights) stay finite and equal
-    to the clean run's."""
+def test_rows_past_a_tiles_pairs_never_reach_a_result_or_a_gradient(monkeypatch):
+    """An expert's last tile is short: its rows past the pairs hold other
+    experts' pairs, masked on the way in. Here the expert's MLP leaves
+    NaN in every such row: the layer's result and every gradient (the
+    router's too, through the pairs' weights) stay finite and equal to
+    the clean run's."""
     monkeypatch.setattr(moe_lib, "_HELD_ROW_TILE", 8)
     hf, cfg, mlp, h2 = _expert_layer_inputs()
     c = dataclasses.replace(cfg, moe=dataclasses.replace(cfg.moe, experts_held=(4, 4)))
@@ -235,55 +236,140 @@ def test_rows_of_no_group_never_reach_a_result_or_a_gradient(monkeypatch):
 
     def run(mp, h2):
         y, aux = moe_lib.moe_mlp(h2, mp, c, jnp.float32)
-        return (y * jnp.cos(y)).sum(), aux["rows_run"]
+        return (y * jnp.cos(y)).sum(), (aux["rows_run"], aux["pairs_held"])
 
-    (clean, rows), g_clean = jax.value_and_grad(run, (0, 1), has_aux=True)(mp, h2)
-    real = jax.lax.ragged_dot
+    (clean, (rows, pairs)), g_clean = jax.value_and_grad(run, (0, 1), has_aux=True)(mp, h2)
+    real = moe_lib._expert_ffn
 
-    def dirty(lhs, rhs, group_sizes, **kw):
-        out = real(lhs, rhs, group_sizes, **kw)
-        in_group = jnp.arange(lhs.shape[0]) < jnp.sum(group_sizes)
-        return jnp.where(in_group[:, None], out, jnp.nan)
+    def dirty(xs, ws, act):
+        if xs.shape[0] != 8:  # the shared expert, over every token
+            return real(xs, ws, act)
+        masked = (xs == 0).all(-1)  # what `_tile_out` zeroed on the way in
+        return jnp.where(masked[:, None], jnp.nan, real(xs, ws, act))
 
-    monkeypatch.setattr(jax.lax, "ragged_dot", dirty)
+    monkeypatch.setattr(moe_lib, "_expert_ffn", dirty)
     (got, _), g = jax.value_and_grad(run, (0, 1), has_aux=True)(mp, h2)
-    assert float(rows) > 96 * 4 / 4  # buffers hold rows beyond the pairs held
+    assert float(rows) > float(pairs) > 0  # tiles hold rows beyond the pairs held
     np.testing.assert_allclose(float(got), float(clean), rtol=1e-6)
     for a, b in zip(jax.tree_util.tree_leaves(g), jax.tree_util.tree_leaves(g_clean)):
         assert np.isfinite(np.asarray(a)).all()
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
 
 
-def test_rows_move_to_and_from_their_tokens_without_a_scatter_left_to_sort():
-    """`_take_rows` and `_add_rows` (the pairs' tokens into a pass's
-    buffer, and its results back) are a gather and a scatter-add, each
-    the other's gradient; every scatter in either pass is handed sorted
-    indices, because the chip's compiler otherwise sorts them with the
-    rows as a second operand and takes 8 s a program over it."""
-    rng = np.random.default_rng(0)
-    x = jnp.asarray(rng.standard_normal((12, 5)), jnp.float32)
-    rows = jnp.asarray(rng.standard_normal((40, 5)), jnp.float32)
-    tok = jnp.asarray(rng.integers(0, 12, 40), jnp.int32)  # tokens repeat, some get no row
-    want_add = np.zeros((12, 5), np.float32)
-    np.add.at(want_add, np.asarray(tok), np.asarray(rows))
-    np.testing.assert_allclose(moe_lib._add_rows(rows, tok, 12), want_add, atol=1e-6)
-    np.testing.assert_array_equal(moe_lib._take_rows(x, tok, 12), np.asarray(x)[np.asarray(tok)])
-    w = jnp.asarray(rng.standard_normal((12, 5)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((40, 5)), jnp.float32)
-    g_add = jax.grad(lambda r: (moe_lib._add_rows(r, tok, 12) * w).sum())(rows)
-    np.testing.assert_allclose(g_add, np.asarray(w)[np.asarray(tok)], atol=1e-6)
-    g_take = jax.grad(lambda x: (moe_lib._take_rows(x, tok, 12) * v).sum())(x)
-    want = np.zeros((12, 5), np.float32)
-    np.add.at(want, np.asarray(tok), np.asarray(v))
-    np.testing.assert_allclose(g_take, want, atol=1e-6)
+_HELD = (4, 3)  # experts 4, 5, 6 of 16
+_TILE = 8
 
-    def both(x, rows):
-        return (moe_lib._add_rows(moe_lib._take_rows(x, tok, 12) * rows, tok, 12) ** 2).sum()
 
-    text = jax.jit(jax.grad(both, (0, 1))).lower(x, rows).as_text()
-    scatters = re.findall(r'"stablehlo\.scatter"\([^\n]*', text)
-    assert len(scatters) == 2  # one a pass
-    assert all("indices_are_sorted = true" in op for op in scatters)
+def _held_case(case, T=40, k=2, seed=0):
+    """(choice_e, token_mask) for T tokens of k choices, choice-major,
+    and the pairs each held expert gets."""
+    rng = np.random.default_rng(seed)
+    out = np.array([0, 1, 2, 3, 7, 8, 9, 10, 11, 12, 13, 14, 15])  # experts not held
+    choice = rng.choice(out, (k, T))
+    mask = np.ones(T, bool)
+    if case == "one-pair":
+        choice[1, 17] = 5
+    elif case == "R-pairs":
+        choice[0, rng.choice(T, _TILE, replace=False)] = 4
+    elif case == "R+1-pairs":
+        choice[1, rng.choice(T, _TILE + 1, replace=False)] = 6
+    elif case == "all-to-one":  # every token's first choice: 5 tiles of one expert
+        choice[0] = 5
+    elif case in ("random", "padding"):
+        choice = np.stack([rng.permutation(16)[:k] for _ in range(T)], 1)
+        choice[:, ::3] = [[4], [6]]  # a skew: a third of the tokens to two held experts
+        if case == "padding":
+            mask[T - 10:] = False
+    else:
+        assert case == "no-pair"
+    sizes = [int(((choice == e) & mask).sum()) for e in range(_HELD[0], sum(_HELD))]
+    return jnp.asarray(choice.reshape(-1), jnp.int32), jnp.asarray(mask), sizes
+
+
+def _plain_sum_over_pairs(x, ws, act, choice, gate, mask):
+    """sum over the held pairs of w_pair Expert(x_token): every expert's
+    MLP over every token, the pairs picked by a mask."""
+    T = x.shape[0]
+    y = jnp.zeros_like(x)
+    for h in range(_HELD[1]):
+        out = moe_lib._expert_ffn(x, tuple(m[h] for m in ws), act)
+        for c in range(choice.shape[0] // T):
+            sel = (choice[c * T:(c + 1) * T] == _HELD[0] + h) & mask
+            y = y + jnp.where(sel[:, None], gate[c * T:(c + 1) * T, None] * out, 0)
+    return y
+
+
+@pytest.mark.parametrize("case", ["no-pair", "one-pair", "R-pairs", "R+1-pairs",
+                                  "all-to-one", "random", "padding"])
+@pytest.mark.parametrize("mats", [("w_gate", "w_up", "w_down"), ("w_in", "w_out")],
+                         ids=["gated", "plain"])
+def test_the_held_part_is_the_plain_sum_over_its_pairs(mats, case, monkeypatch):
+    """`_held_experts` against a sum over pairs that knows no tile:
+    values and the gradients of x, of the pairs' weights and of every
+    expert matrix, float32, for gated silu and plain squared-ReLU
+    experts, at chunks of two tiles (the last chunk of `all-to-one` holds
+    one); and the rows run are whole tiles of every expert's pairs."""
+    monkeypatch.setattr(moe_lib, "_HELD_ROW_TILE", _TILE)
+    monkeypatch.setattr(moe_lib, "_HELD_CHUNK_ROWS", 2 * _TILE)
+    T, k, D, F = 40, 2, 16, 24
+    moe = MoEConfig(num_experts=16, top_k=k, dispatch="dropless", score_func="sigmoid",
+                    experts_held=_HELD)
+    act = moe_lib.activation_fn("silu" if len(mats) == 3 else "relu2")
+    ks = jax.random.split(jax.random.PRNGKey(1), 5)
+    x = jax.random.normal(ks[0], (T, D))
+    mp = {m: 0.3 * jax.random.normal(key, (_HELD[1], F, D) if m == mats[-1] else (_HELD[1], D, F))
+          for m, key in zip(mats, ks[1:])}
+    gate = jax.random.uniform(ks[4], (k * T,), minval=0.1, maxval=1.0)
+    choice, mask, sizes = _held_case(case)
+
+    def tiled(x, mp, gate):
+        y, pairs, rows = moe_lib._held_experts(
+            x, mp, moe, act, jnp.float32, choice, gate, mask, mats)
+        return (y * jnp.cos(y)).sum(), (y, pairs, rows)
+
+    def plain(x, mp, gate):
+        y = _plain_sum_over_pairs(x, tuple(mp[m] for m in mats), act, choice, gate, mask)
+        return (y * jnp.cos(y)).sum(), y
+
+    with jax.default_matmul_precision("highest"):
+        (_, (y, pairs, rows)), g = jax.value_and_grad(tiled, (0, 1, 2), has_aux=True)(x, mp, gate)
+        (_, want), g_want = jax.value_and_grad(plain, (0, 1, 2), has_aux=True)(x, mp, gate)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=1e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(g), jax.tree_util.tree_leaves(g_want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
+    assert float(pairs) == sum(sizes)
+    assert float(rows) == sum(-(-n // _TILE) for n in sizes) * _TILE
+    if case == "no-pair":
+        assert float(rows) == 0 and not np.asarray(y).any()
+
+
+def test_the_held_part_is_loops_of_a_run_time_count_and_its_row_scatters_are_sorted():
+    """What the held part lowers to, forward and backward: a loop over
+    chunks around a loop over a chunk's tiles, each way (the sort's own
+    aside), no conditional, no grouped matmul; the two scatters of rows
+    into tokens, one a chunk, are handed sorted indices, because the
+    chip's compiler otherwise sorts them with the rows as a second
+    operand and takes 8 s a program over it."""
+    T, k, D, F = 64, 2, 16, 24
+    moe = MoEConfig(num_experts=16, top_k=k, dispatch="dropless", score_func="sigmoid",
+                    experts_held=_HELD)
+    mp = {m: jnp.ones((3, F, D) if m == "w_down" else (3, D, F)) for m in
+          ("w_gate", "w_up", "w_down")}
+    choice, mask, _ = _held_case("random", T=T)
+
+    def loss(x, mp, gate):
+        y, pairs, rows = moe_lib._held_experts(
+            x, mp, moe, jax.nn.silu, jnp.float32, choice, gate, mask)
+        return (y ** 2).sum()
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        jnp.ones((T, D)), mp, jnp.ones((k * T,))).as_text()
+    assert "ragged_dot" not in text and "stablehlo.case" not in text and "stablehlo.if" not in text
+    assert text.count("stablehlo.while") == 4
+    rows = [op for op in re.findall(r'"stablehlo\.scatter"\([^\n]*', text)
+            if "update_window_dims = [1]" in op]  # rows of [D] into a [T, D]
+    assert len(rows) == 2  # y in the forward's chunks, dx in the backward's
+    assert all("indices_are_sorted = true" in op for op in rows)
 
 
 def test_the_router_is_the_published_form():
