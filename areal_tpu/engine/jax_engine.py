@@ -53,6 +53,7 @@ from areal_tpu.ops.loss import (
     fused_next_token_logprobs,
     head_cells_run,
     response_scoring_mask,
+    two_on,
 )
 from areal_tpu.engine.optimizer import (
     OptimizerConfig,
@@ -138,9 +139,11 @@ def _kinds_label(cfg: TransformerConfig) -> str:
     blocks (named by their MLP); a layer of one part is `ssm`, `moe`,
     `dense` or `attn.full.nope`. Differential attention says `diff.`, a
     layer that reads layer n's tensor `<n`, one that keeps its own `^`:
-    `ssm+dense^`, `dense.diff.full.nope<5`, `gmu+dense<4`."""
+    `ssm+dense^`, `dense.diff.full.nope<5`, `gmu+dense<4`; latent
+    attention says `latent.`, and a prediction module after the stack
+    ends the label with `+mtp`."""
     def name(k):
-        attn = (f"{'diff.' if k.diff else ''}"
+        attn = (f"{'diff.' if k.diff else ''}{'latent.' if k.latent else ''}"
                 f"{'full' if k.window is None else 'w%d' % k.window}."
                 f"{'rope' if k.rotary else 'nope'}")
         keeps, reads = "^" if k.keeps else "", "" if k.reads is None else f"<{k.reads}"
@@ -155,7 +158,8 @@ def _kinds_label(cfg: TransformerConfig) -> str:
             out[-1][1] += 1
         else:
             out.append([n, 1])
-    return ",".join(n if c == 1 else f"{n} x{c}" for n, c in out)
+    return ",".join(n if c == 1 else f"{n} x{c}" for n, c in out) + (
+        "+mtp" if cfg.mtp is not None else "")
 
 
 @dataclasses.dataclass
@@ -198,11 +202,20 @@ class JaxTrainEngine(TrainEngine):
         # What `train.dispatch` says of the stack it runs: nothing for a
         # stack of one plain kind.
         self._stack_attrs: Dict[str, Any] = {}
-        if model_cfg.layer_kinds is not None:
+        if model_cfg.layer_kinds is not None or model_cfg.mla is not None:
             windows = sorted({k.window for k in model_cfg.kinds()
                               if k.window is not None})
             self._stack_attrs = dict(window=windows[0] if windows else None,
                                      kinds=_kinds_label(model_cfg))
+        # The prediction module's share of a train step (models/config
+        # MTPConfig): the weight of its loss, 0 = the step skips its pass,
+        # and the layers a step then runs beside the stack's.
+        self._mtp_weight = (
+            float(model_cfg.mtp.loss_weight) if model_cfg.mtp is not None else 0.0)
+        last = model_cfg.kinds()[-1]
+        mtp_on = self._mtp_weight > 0
+        self._n_moe_layers = model_cfg.n_moe_layers + (mtp_on and last.mlp == "moe")
+        self._mtp_attn = mtp_on and last.mixer == "attention"
         # Pin AREAL_CE_CHUNK / AREAL_SPLASH_* now: retraces mid-run must
         # not mix tuning settings, and bad values must fail at init.
         from areal_tpu.ops import snapshot_env_tuning
@@ -414,6 +427,8 @@ class JaxTrainEngine(TrainEngine):
         over its own. None: the head runs over every valid position.
         """
         is_critic = self.model_cfg.is_critic
+        mtp = self._mtp_weight > 0
+        mesh = self.mesh if self.mesh.size > 1 else None
 
         def compute(p, rows):
             out = model_forward(
@@ -422,18 +437,40 @@ class JaxTrainEngine(TrainEngine):
                 attn_impl=self.attn_impl, remat=self.remat,
                 output="logits" if is_critic else "hidden",
                 return_aux=self.model_cfg.moe is not None,
-                mesh=self.mesh if self.mesh.size > 1 else None,
+                mesh=mesh, mtp=mtp,
             )
             if self.model_cfg.moe is not None:
                 out, moe_aux = out
+            if mtp:
+                out, mtp_hidden = out
             if not is_critic:
+                scored = None if scored_fn is None else scored_fn(rows)
                 out = fused_next_token_logprobs(
                     out, self._head_weight(p),
                     rows["input_ids"], rows["segment_ids"],
-                    scored=None if scored_fn is None else scored_fn(rows),
-                    mesh=self.mesh if self.mesh.size > 1 else None,
+                    scored=scored, mesh=mesh,
                 )
             loss_sum, aux = loss_fn(out, rows)
+            if mtp:
+                # The prediction module's loss beside the caller's: the
+                # head again, over the module's hidden states and the
+                # tokens two on, at the positions whose such token the
+                # caller's loss scores; summed here and divided by the
+                # step's denominator with the rest (the caller's count of
+                # scored tokens, which is the module's count of targets
+                # wherever no sequence's second token is scored). The
+                # head's weight is the model's and stays the caller's
+                # loss's to move (models/config.MTPConfig).
+                with jax.named_scope("mtp_head"):
+                    keep = (jnp.ones(rows["segment_ids"].shape, jnp.float32)
+                            if scored is None else two_on(scored))
+                    logp, hit = fused_next_token_logprobs(
+                        mtp_hidden, jax.lax.stop_gradient(self._head_weight(p)),
+                        rows["input_ids"], rows["segment_ids"],
+                        scored=keep, mesh=mesh, shift=2, top=True,
+                    )
+                aux = dict(aux, mtp_loss=-jnp.sum(logp), mtp_accept=jnp.sum(hit))
+                loss_sum = loss_sum + self._mtp_weight * aux["mtp_loss"]
             if self.model_cfg.moe is not None:
                 # MoE aux losses scale with token count so they
                 # survive the 1/global_denom normalization applied
@@ -454,7 +491,7 @@ class JaxTrainEngine(TrainEngine):
                 # tokens while global_denom counts loss-weight (response)
                 # tokens, so the n_tok scaling used by the loss-like
                 # stats would inflate a fraction.
-                n_layers = self.model_cfg.n_moe_layers
+                n_layers = self._n_moe_layers
                 aux["mean:moe_drop_rate"] = moe_aux["drop_rate"] / n_layers
                 # Router telemetry (PR 17): layer-mean router entropy,
                 # expert overload factor (E * max_e layer-mean routing
@@ -852,6 +889,7 @@ class JaxTrainEngine(TrainEngine):
             if tracing.enabled():  # host passes whose only readers are spans and counters
                 attn = [self._attn_counts(rows["segment_ids"]) for rows in stacks]
                 counts = [a[1:-1] + self._head_counts(rows, scored_fn)
+                          + self._mtp_counts(rows, scored_fn)
                           + self._ssm_counts(rows["segment_ids"])
                           for a, rows in zip(attn, stacks)]
                 self._count_batch(
@@ -919,6 +957,7 @@ class JaxTrainEngine(TrainEngine):
                 if tracing.enabled():  # their only readers are spans and counters
                     run_len, *attn, width = self._attn_counts(rows["segment_ids"])
                     counts = (*attn, *self._head_counts(rows, scored_fn),
+                              *self._mtp_counts(rows, scored_fn),
                               *self._ssm_counts(rows["segment_ids"]))
                     attn_attrs = dict(attn_row_len=run_len, width=width)
             return (rows_dev, denom, batch.total_tokens, cells, attn_attrs, counts)
@@ -931,10 +970,11 @@ class JaxTrainEngine(TrainEngine):
         nxt = None
         denom_sum, n_tok, n_cells, n_one_row = 0.0, 0, 0, 0
         # attention's cells at the run length, run, causal, its grid steps
-        # walked, live; the head's positions read, cells run; the
-        # state-space scan's chunks, live, mixed, and its resets; counted
-        # while tracing is on (`n_counted` of the micro-batches)
-        n_counts, n_counted = [0] * 11, 0
+        # walked, live; the head's positions read, cells run, and the
+        # prediction module's; the state-space scan's chunks, live, mixed,
+        # and its resets; counted while tracing is on (`n_counted` of the
+        # micro-batches)
+        n_counts, n_counted = [0] * 13, 0
         gaps_ms: List[float] = []
         mark = time.monotonic_ns()
         try:
@@ -1014,6 +1054,8 @@ class JaxTrainEngine(TrainEngine):
         )
         run_len = attn_run_len(t=row_len, r=rows, **shape)
         windows = [k.window for k in cfg.kinds() if k.mixer == "attention"]
+        if self._mtp_attn:  # the prediction module's block, the last kind's
+            windows.append(cfg.kinds()[-1].window)
         # One count a window, not one a layer: (cells run, causal cells,
         # steps walked, live steps, width) a micro-batch.
         per = {w: [attn_block_cells(segment_ids=mb, window=w, **shape)
@@ -1042,13 +1084,14 @@ class JaxTrainEngine(TrainEngine):
             segment_ids, self.model_cfg.ssm.chunk_size))
 
     def _head_counts(self, rows_np: Dict[str, np.ndarray],
-                     scored_fn: Optional[ScoredFn]) -> Tuple[int, int]:
+                     scored_fn: Optional[ScoredFn], shift: int = 1) -> Tuple[int, int]:
         """What the loss head does with packed rows (on the host, before
         the transfer; [R, T] arrays of one micro-batch or [n, R, T] of
         several): (the positions whose logprob the loss reads, the cells
         of the chunks the head runs its logits tile over), by the
         device's own rule (ops/loss.head_cells_run). A critic has no
-        such head."""
+        such head. `shift`: how many tokens on the labels lie (the
+        prediction module's run of the head: `_mtp_counts`)."""
         if self.model_cfg.is_critic:
             return 0, 0
         seg = np.asarray(rows_np["segment_ids"])
@@ -1056,15 +1099,30 @@ class JaxTrainEngine(TrainEngine):
         scored = ([None] * len(mbs) if scored_fn is None
                   else np.asarray(scored_fn(rows_np)).reshape(mbs.shape))
         counts = [head_cells_run(mb, s, self.model_cfg.vocab_size,
-                                 self._n_row_multiple)
+                                 self._n_row_multiple, shift)
                   for mb, s in zip(mbs, scored)]
         return tuple(int(x) for x in np.sum(counts, axis=0))
+
+    def _mtp_counts(self, rows_np: Dict[str, np.ndarray],
+                    scored_fn: Optional[ScoredFn]) -> Tuple[int, int]:
+        """`_head_counts` of the prediction module's run of the head, over
+        the tokens two on that the caller's loss scores (`_mb_loss_fn`):
+        zeros where the step runs none."""
+        if not self._mtp_weight > 0:
+            return 0, 0
+        if scored_fn is None:
+            reads = lambda rows: np.ones(np.shape(rows["segment_ids"]), np.float32)
+        else:
+            reads = lambda rows: two_on(np.asarray(scored_fn(rows)))
+        return self._head_counts(rows_np, reads, shift=2)
 
     def _count_batch(self, path: str, n_mbs: int, n_one_row: int, n_tok: int,
                      n_cells: int,
                      n_attn_cells: int, n_attn_active: int, n_attn_causal: int,
                      n_attn_steps: int, n_attn_live: int,
-                     n_scored: int, n_head_cells: int, n_ssm_chunks: int = 0,
+                     n_scored: int, n_head_cells: int,
+                     n_mtp_targets: int, n_mtp_head_cells: int,
+                     n_ssm_chunks: int = 0,
                      n_ssm_live: int = 0, n_ssm_mixed: int = 0, n_ssm_resets: int = 0):
         """What one train_batch did, on its `train.batch` span and in the
         recorder's counters: the micro-batches and how many of them the
@@ -1076,7 +1134,8 @@ class JaxTrainEngine(TrainEngine):
         against those of a causal mask alone, the grid steps its kernels
         walked and those whose block pair ran, the positions whose logprob
         the loss reads and the cells of the chunks the loss head ran for
-        them, the (token, expert) pairs the routers of the expert
+        them, the same two of the prediction module's run of the head,
+        the (token, expert) pairs the routers of the expert
         layers made, and the chunks the state-space layers' scan ran
         (the selective scan's also as positions: chunks x their length)."""
         tracing.set_attrs(path=path, n_mbs=n_mbs, tokens=n_tok, cells=n_cells)
@@ -1093,10 +1152,13 @@ class JaxTrainEngine(TrainEngine):
         if not self.model_cfg.is_critic:
             tracing.count("train.scored_cells", n_scored)
             tracing.count("train.head_cells", n_head_cells)
+        if self._mtp_weight > 0:
+            tracing.count("train.mtp_targets", n_mtp_targets)
+            tracing.count("train.mtp_head_cells", n_mtp_head_cells)
         moe = self.model_cfg.moe
         if moe is not None:
             tracing.count("train.moe_pairs",
-                          moe.top_k * n_tok * self.model_cfg.n_moe_layers)
+                          moe.top_k * n_tok * self._n_moe_layers)
         if self.model_cfg.n_ssm_layers:
             ssm = self.model_cfg.ssm
             if ssm.form == "mamba1":  # positions the scan kernel walks

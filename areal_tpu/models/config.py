@@ -5,12 +5,13 @@ covering the same architecture space: GQA attention, rotary variants,
 RMS/LayerNorm, gated MLPs, optional MoE, actor (LM head) or critic (scalar
 head) outputs, tied embeddings, and qk-norm (qwen3); and, beyond it, a
 kind per layer (`LayerKind`: the parts a layer has: a mixer, attention
-with its window and rotary, differential or not, a state-space mixer in
-one of two forms or a gated memory unit, and an MLP, dense or expert,
-either of which may be absent; a layer may keep a tensor that later
-layers read), an attention output gate,
-post-norms, a sigmoid router with a selection bias, shared experts and a
-share of the experts held here.
+with its window and rotary, differential or latent or neither, a
+state-space mixer in one of two forms or a gated memory unit, and an MLP,
+dense or expert, either of which may be absent; a layer may keep a tensor
+that later layers read), an attention output gate,
+post-norms, a sigmoid router with a selection bias, shared experts, a
+share of the experts held here, and a multi-token-prediction module
+after the stack (`MTPConfig`).
 """
 
 from __future__ import annotations
@@ -158,6 +159,52 @@ class SSMConfig:
         return self.d_inner + self.conv_dim + self.n_heads
 
 
+@dataclasses.dataclass
+class MLAConfig:
+    """Latent attention: q and k, v come through low-rank projections
+    with an RMSNorm inside each. With x the layer's normed input,
+    `c_q = RMSNorm(x W_qa)` [q_rank], `q = c_q W_qb`: a head
+    `[nope_dim | rope_dim]`; `[c_kv | k_r] = x W_kva` [kv_rank |
+    rope_dim], `c_kv = RMSNorm(c_kv)`, `c_kv W_kvb`: a head `[k_nope
+    nope_dim | v v_dim]`. Rotary goes over the rope part alone, and the
+    one `k_r` a token is every head's. A head's q and k are `nope_dim +
+    rope_dim` wide (`TransformerConfig.head_dim`, the softmax scale's),
+    its v and output `v_dim`."""
+
+    q_rank: int = 24
+    kv_rank: int = 16
+    nope_dim: int = 8
+    rope_dim: int = 8
+    v_dim: int = 8
+
+    @property
+    def qk_dim(self) -> int:
+        return self.nope_dim + self.rope_dim
+
+
+@dataclasses.dataclass
+class MTPConfig:
+    """A multi-token-prediction module after the stack (DeepSeek-V3's):
+    with h the stack's output after `final_norm` and e the embedding
+    table, position i of a sequence gives `u_i = W_eh [RMSNorm_e(e[t_{i+1}])
+    ; RMSNorm_h(h_i)]`, one more layer of the stack's last kind, an
+    RMSNorm, and the model's own head: a prediction of `t_{i+2}`.
+    `loss_weight` is what the training step gives its loss beside the
+    caller's (0 = the step skips the module's pass). In that step the
+    module's inputs h and e and the head are under `stop_gradient`: the
+    caller's loss alone moves the model, and the module follows it."""
+
+    n_modules: int = 1
+    loss_weight: float = 0.1
+
+    def __post_init__(self):
+        if self.n_modules != 1:
+            raise NotImplementedError(
+                f"MTPConfig.n_modules={self.n_modules}: models/transformer.py "
+                "runs one prediction module (what the published models have), "
+                "not a chain of them")
+
+
 @dataclasses.dataclass(frozen=True)
 class LayerKind:
     """What one layer of the stack is, known when the program is traced:
@@ -169,7 +216,8 @@ class LayerKind:
     whether q and k get the rotary embedding (False = no position
     encoding in this layer), and `diff`: differential attention, two
     softmaxes a pair of heads, the second subtracted from the first
-    (`transformer._diff_combine`).
+    (`transformer._diff_combine`), and `latent`: q and k, v through
+    low-rank projections (`MLAConfig`, `transformer._latent_attention_block`).
 
     Two relations between layers. `keeps`: the layer hands a tensor on
     to later layers: an "ssm" mixer its scan's output before the gate,
@@ -186,6 +234,7 @@ class LayerKind:
     diff: bool = False
     keeps: bool = False
     reads: Optional[int] = None
+    latent: bool = False
 
     def __post_init__(self):
         if self.mlp not in ("dense", "moe", None):
@@ -200,9 +249,17 @@ class LayerKind:
         if self.window is not None and self.window < 1:
             raise ValueError(f"LayerKind.window must be >= 1, got {self.window}")
         if self.mixer != "attention" and (
-                self.window is not None or not self.rotary or self.diff):
+                self.window is not None or not self.rotary or self.diff
+                or self.latent):
             # one spelling a kind: layers without attention compare equal
-            raise ValueError("window, rotary and diff describe an attention mixer")
+            raise ValueError(
+                "window, rotary, diff and latent describe an attention mixer")
+        if self.latent and (self.window is not None or not self.rotary or self.diff
+                            or self.keeps or self.reads is not None):
+            raise NotImplementedError(
+                "latent attention is causal over the whole sequence with its "
+                "rotary part: no window, no differential pairing, and its k and "
+                "v are no other layer's")
         if self.keeps and (self.mixer not in ("attention", "ssm")
                            or self.reads is not None):
             raise ValueError(
@@ -219,7 +276,8 @@ class LayerKind:
         the stack they live in: layers with the same parts share a
         stack. "attention+moe", "ssm", "moe", "diffattention+dense",
         "xdiffattention+dense" (x: q and the output projection only),
-        "gmu+dense", ...; a layer that `keeps` adds "^" ("ssm+dense^"):
+        "latentattention+moe", "gmu+dense", ...; a layer that `keeps` adds
+        "^" ("ssm+dense^"):
         it runs on its own, never as a repeat of a scan, so its
         parameters are a stack of their own, which `forward` takes
         whole (a stack cut between segments is copied, and so are the
@@ -227,7 +285,8 @@ class LayerKind:
         mixer = self.mixer
         if mixer == "attention":
             mixer = ("x" if self.reads is not None else "") + (
-                "diff" if self.diff else "") + "attention"
+                "diff" if self.diff else "") + (
+                "latent" if self.latent else "") + "attention"
         return "+".join(p for p in (mixer, self.mlp) if p) + ("^" if self.keeps else "")
 
     @property
@@ -312,6 +371,11 @@ class TransformerConfig:
     is_critic: bool = False
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
+    # Latent attention's sizes; with them and no `layer_kinds`, every
+    # layer's attention is latent. `head_dim` is then a head's q and k.
+    mla: Optional[MLAConfig] = None
+    # The prediction module after the stack, or None.
+    mtp: Optional[MTPConfig] = None
     # One LayerKind a layer, filled by the family from the published
     # config; None = every layer the same (moe or dense by `moe`, full
     # causal attention, rotary by `pos_emb`).
@@ -338,6 +402,10 @@ class TransformerConfig:
             self.moe = MoEConfig(**self.moe)
         if isinstance(self.ssm, dict):
             self.ssm = SSMConfig(**self.ssm)
+        if isinstance(self.mla, dict):
+            self.mla = MLAConfig(**self.mla)
+        if isinstance(self.mtp, dict):
+            self.mtp = MTPConfig(**self.mtp)
         if self.activation not in ("silu", "gelu", "relu2"):
             raise ValueError(
                 f"activation must be 'silu', 'gelu' or 'relu2', got {self.activation!r}")
@@ -357,6 +425,19 @@ class TransformerConfig:
         if any(k.mixer in ("ssm", "gmu") for k in kinds) and self.ssm is None:
             raise ValueError(
                 "a layer with an 'ssm' or 'gmu' mixer needs TransformerConfig.ssm")
+        if any(k.latent for k in kinds):
+            if self.mla is None:
+                raise ValueError("a latent attention layer needs TransformerConfig.mla")
+            if self.head_dim != self.mla.qk_dim or self.n_q_heads != self.n_kv_heads:
+                raise ValueError(
+                    "latent attention: head_dim is a head's q and k, nope_dim + "
+                    f"rope_dim = {self.mla.qk_dim}, and k and v are a head each "
+                    f"(got head_dim {self.head_dim}, {self.n_q_heads} / "
+                    f"{self.n_kv_heads} heads)")
+        if self.mtp is not None and (self.is_critic or not kinds[-1].block):
+            raise ValueError(
+                "the prediction module is one more transformer block of the "
+                "stack's last kind under an actor's head")
         for i, k in enumerate(kinds):
             if k.reads is None:
                 continue
@@ -381,6 +462,12 @@ class TransformerConfig:
     def kv_dim(self) -> int:
         return self.n_kv_heads * self.head_dim
 
+    @property
+    def rotary_dim(self) -> int:
+        """The width the rotary embedding turns: a whole head, or latent
+        attention's rope part."""
+        return self.mla.rope_dim if self.mla is not None else self.head_dim
+
     def kinds(self) -> Tuple[LayerKind, ...]:
         """The kind of every layer, in order."""
         if self.layer_kinds is not None:
@@ -388,7 +475,8 @@ class TransformerConfig:
         rotary = self.pos_emb == "rotary"
         dense_first = self.moe.first_k_dense if self.moe is not None else self.n_layers
         return tuple(
-            LayerKind(mlp="dense" if i < dense_first else "moe", rotary=rotary)
+            LayerKind(mlp="dense" if i < dense_first else "moe", rotary=rotary,
+                      latent=self.mla is not None)
             for i in range(self.n_layers)
         )
 
@@ -453,6 +541,19 @@ class TransformerConfig:
         what is missing."""
         missing = []
         kinds = self.kinds()
+        if any(k.latent for k in kinds):
+            missing.append(
+                "a latent cache: latent attention keeps, a token, one row of "
+                f"kv_rank + rope_dim = {self.mla.kv_rank + self.mla.rope_dim} "
+                "values that every head reads (its projections absorbed into q "
+                "and the output in decode), where the KV pages hold k and v a "
+                "head; models/transformer.py runs the materialised form, which "
+                "a decode step has no use for")
+        if self.mtp is not None:
+            missing.append(
+                "the multi-token-prediction module: the cache paths have no "
+                "pages for its layer, no draft from it (engine/spec_decode.py "
+                "drafts by n-gram) and a weight update that does not carry it")
         if any(k.mixer == "ssm" for k in kinds):
             state = ("[channels, state_dim], a decay for every channel and state"
                      if self.ssm.form == "mamba1" else "[heads, head_dim, state_dim]")
@@ -484,7 +585,7 @@ class TransformerConfig:
                 f"parts {sorted({k.parts for k in kinds})}, the cache paths run "
                 "attention and an MLP in every layer"
             )
-        elif not self.one_kind:
+        elif not self.one_kind and not any(k.latent for k in kinds):
             missing.append(
                 "a cache manager with a kind per layer (window layers keep "
                 "the last `window` positions, full layers all; rotary or "

@@ -10,9 +10,11 @@ real_llm_base.py (blocks) — redesigned for XLA rather than translated:
 - **Layers of several kinds in one stack** (`config.LayerKind`: the
   parts a layer has, each under its own norm with its own residual: a
   mixer, attention with a window or none and rotary or none,
-  differential or not, a state-space mixer, `ops/ssm.py` or
-  `ops/selective_scan.py`, or a gated memory unit; and an MLP, dense or
-  expert; all static). A layer may keep a tensor (its scan's output, its
+  differential or latent (low-rank q and kv projections,
+  `_latent_attention_block`) or neither, a state-space mixer,
+  `ops/ssm.py` or `ops/selective_scan.py`, or a gated memory unit; and
+  an MLP, dense or expert; all static). A layer may keep a tensor (its
+  scan's output, its
   k and v) that later layers read: it travels beside the residual
   stream, an input of each reader's checkpointed body and a constant of
   a scan over readers, so the backward pass holds it once. Layers with
@@ -36,6 +38,11 @@ real_llm_base.py (blocks) — redesigned for XLA rather than translated:
   megatron-equivalent collectives.
 - Mixed precision: params in fp32 (or bf16), compute in bf16, logits and
   softmax in fp32.
+
+- **A prediction module after the stack** (`config.MTPConfig`):
+  `forward(mtp=True)` also hands out the hidden states
+  of one more block that reads the stack's output and the next token's
+  embedding, for a loss over the token after that.
 
 The KV-cache decode path lives in areal_tpu/models/generation.py.
 """
@@ -74,7 +81,9 @@ def _init_layer_stack(cfg: TransformerConfig, keys, n: int, kind: LayerKind,
     D, F, L = cfg.hidden_dim, cfg.intermediate_dim, n
     layers: Dict[str, Any] = {}
     norms = []
-    if kind.mixer == "attention":
+    if kind.mixer == "attention" and kind.latent:
+        layers["attn"] = _init_latent_attention(cfg, keys, L, dense)
+    elif kind.mixer == "attention":
         attn: Dict[str, Any] = {
             "wq": dense(keys[0], (L, D, cfg.q_dim)),
             "wk": dense(keys[1], (L, D, cfg.kv_dim)),
@@ -152,6 +161,43 @@ def _init_layer_stack(cfg: TransformerConfig, keys, n: int, kind: LayerKind,
     return layers
 
 
+# What the seeded draw of a latent attention layer departs by from
+# `dense`'s 1 / sqrt(fan-in), so that a check against a reference sees
+# every part of the layer (benchmark/configs/joyai-llm-flash-*.json
+# `assumed`): q is drawn `_LATENT_Q_GAIN` times as large, so a head's
+# scores have that standard deviation and its softmax is peaked, as a
+# trained model's, not the near-uniform average over a sequence that
+# unit scores give (whose output is a hundredth of the MLP's beside it);
+# the two down-projections' latent columns are drawn `_LATENT_DOWN_GAIN`
+# times as large, so the RMSNorms inside the projections rescale what
+# they are given instead of passing on a vector that is unit already.
+_LATENT_Q_GAIN = 3.0
+_LATENT_DOWN_GAIN = 0.25
+
+
+def _init_latent_attention(cfg: TransformerConfig, keys, L: int, dense) -> Dict[str, Any]:
+    """`L` latent attention layers (`config.MLAConfig`): the two
+    down-projections (the kv one with the shared rope key beside its
+    latent), the norms inside them, the two up-projections to heads, and
+    the output projection from heads of `v_dim`."""
+    pdt = jnp.dtype(cfg.param_dtype)
+    m, D, H = cfg.mla, cfg.hidden_dim, cfg.n_q_heads
+    k_kvb, k_rope = jax.random.split(jax.random.fold_in(keys[15], 16))
+    down = _LATENT_DOWN_GAIN / math.sqrt(D)
+    return {
+        "wq_a": dense(keys[0], (L, D, m.q_rank), down),
+        "q_a_norm": jnp.ones((L, m.q_rank), pdt),
+        "wq_b": dense(keys[1], (L, m.q_rank, H * m.qk_dim),
+                      _LATENT_Q_GAIN / math.sqrt(m.q_rank)),
+        "wkv_a": jnp.concatenate(
+            [dense(keys[2], (L, D, m.kv_rank), down),
+             dense(k_rope, (L, D, m.rope_dim))], axis=-1),
+        "kv_a_norm": jnp.ones((L, m.kv_rank), pdt),
+        "wkv_b": dense(k_kvb, (L, m.kv_rank, H * (m.nope_dim + m.v_dim))),
+        "wo": dense(keys[3], (L, H * m.v_dim, D)),
+    }
+
+
 # A differential attention layer's four vectors of head_dim:
 # lambda = exp(q1 . k1) - exp(q2 . k2) + lambda_init(depth).
 DIFF_LAMBDAS = ("lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2")
@@ -190,6 +236,17 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Params:
             stack_keys = jax.random.split(jax.random.fold_in(keys[14], i), 16)
         _stack_at(params, path, _init_layer_stack(
             cfg, stack_keys, len(idx), kinds[idx[0]], dense))
+    if cfg.mtp is not None:
+        k_eh, k_block = jax.random.split(jax.random.fold_in(keys[14], 1 << 16))
+        params["mtp"] = {
+            "enorm": {"weight": jnp.ones((D,), pdt)},
+            "hnorm": {"weight": jnp.ones((D,), pdt)},
+            "eh_proj": {"weight": dense(k_eh, (2 * D, D))},
+            # one layer of the stack's last kind, on a leading axis of 1
+            "block": _init_layer_stack(
+                cfg, jax.random.split(k_block, 16), 1, kinds[-1], dense),
+            "norm": {"weight": jnp.ones((D,), pdt)},
+        }
     if cfg.pos_emb == "learned":
         params["pos_embedding"] = {
             "weight": dense(keys[9], (cfg.max_position_embeddings, D), scale=0.02)
@@ -432,6 +489,45 @@ def _attention_block(
     return out, (k, v)
 
 
+def _latent_attention_block(x, lp, cfg, cos, sin, segment_ids, positions,
+                            attn_impl, cdt, mesh=None):
+    """x: [R, T, D] -> latent attention's output [R, T, D] and its (k, v)
+    (`config.MLAConfig` has the equations): the materialised form, k and
+    v a head, as a training or prefill pass runs it. Scopes `mla_q_proj`
+    and `mla_kv_proj` inside `attn_qkv` hold the low-rank projections
+    with their norms; rotary (`cos`, `sin` of `rope_dim / 2`) turns q's
+    rope part a head and the one rope key a token, which every head's k
+    ends with. The kernel is the plain block's, called with q and k of
+    `nope_dim + rope_dim` against v of `v_dim`; its softmax scale is
+    that q and k size's."""
+    from areal_tpu.ops.attention import resolve_attn_impl
+
+    R, T, _ = x.shape
+    m, H = cfg.mla, cfg.n_q_heads
+    with jax.named_scope("attn_qkv"):
+        with jax.named_scope("mla_q_proj"):
+            c_q = rms_norm(x @ lp["wq_a"].astype(cdt), lp["q_a_norm"], cfg.norm_eps)
+            q = (c_q @ lp["wq_b"].astype(cdt)).reshape(R, T, H, m.qk_dim)
+        with jax.named_scope("mla_kv_proj"):
+            c_kv, k_r = jnp.split(x @ lp["wkv_a"].astype(cdt), [m.kv_rank], axis=-1)
+            c_kv = rms_norm(c_kv, lp["kv_a_norm"], cfg.norm_eps)
+            kv = (c_kv @ lp["wkv_b"].astype(cdt)).reshape(
+                R, T, H, m.nope_dim + m.v_dim)
+        q_nope, q_r = jnp.split(q, [m.nope_dim], axis=-1)
+        k_nope, v = jnp.split(kv, [m.nope_dim], axis=-1)
+        q_r = apply_rotary(q_r, cos, sin, cfg.rotary_interleaved)
+        k_r = apply_rotary(k_r[:, :, None, :], cos, sin, cfg.rotary_interleaved)
+        q = jnp.concatenate([q_nope, q_r], axis=-1)
+        k = jnp.concatenate(
+            [k_nope, jnp.broadcast_to(k_r, (R, T, H, m.rope_dim))], axis=-1)
+    impl = resolve_attn_impl(attn_impl, T, H, H, mesh=mesh, r=R)
+    with jax.named_scope("attn_kernel"):
+        out = _attention_kernel(q, k, v, segment_ids, positions, impl, cfg, mesh, None)
+    with jax.named_scope("attn_out"):
+        out = out.reshape(R, T, H * m.v_dim) @ lp["wo"].astype(cdt)
+    return out, (k, v)
+
+
 def _segment_stacks(params, cfg: TransformerConfig):
     """The stack's segments (`cfg.segments`), each with the parameters of
     its unit's positions: one layer's pytree a position where the
@@ -495,12 +591,17 @@ def forward(
     return_aux: bool = False,  # also return MoE aux losses (zeros if dense)
     remat: Any = False,  # False/"none" | True/"full" | "save_attn" | "mlp"
     mesh=None,  # jax.sharding.Mesh: anchor activation/logits shardings
+    mtp: bool = False,  # also run the prediction module (cfg.mtp)
 ) -> Any:
     """Packed-rows forward pass.
 
     Returns logits [R, T, V] (fp32), critic values [R, T] when
     cfg.is_critic, or hidden states; optionally also per-layer (k, v)
-    stacked as [L, R, T, Hkv, hd] for generation prefill.
+    stacked as [L, R, T, Hkv, hd] for generation prefill. With `mtp`
+    the result is a pair: what it would be without, and the prediction
+    module's hidden states [R, T, D] after its norm (its expert layer's
+    sums are in the aux losses), which the model's head
+    turns into a prediction of the token two on.
 
     When `mesh` is given, activations are pinned to
     P((data, fsdp), seq, None) and logits to P((data, fsdp), seq, tensor)
@@ -544,11 +645,11 @@ def forward(
     else:
         inv_freq = jnp.asarray(
             rotary_inv_freq(
-                cfg.head_dim, cfg.rotary_base, cfg.rotary_scaling,
+                cfg.rotary_dim, cfg.rotary_base, cfg.rotary_scaling,
                 cfg.rotary_scaling_type, cfg.rotary_scaling_params,
             )
         )
-        cos, sin = rotary_cos_sin(positions, inv_freq)  # [R, T, hd/2]
+        cos, sin = rotary_cos_sin(positions, inv_freq)  # [R, T, rotary_dim/2]
 
     use_moe = cfg.moe is not None
     # remat policy: "full" recomputes the whole layer in backward (least
@@ -580,12 +681,16 @@ def forward(
             )
             remat_mode = "full"
     kinds = cfg.kinds()
-    if return_kv and not all(k == kinds[0] and k.block for k in kinds):
+    if return_kv and not all(
+            k == kinds[0] and k.block and not k.latent for k in kinds):
         raise NotImplementedError(
-            "return_kv with layers of different kinds: the KV cache "
-            "(models/generation.py) holds one kind of layer, attention and an "
-            "MLP in each, and has no recurrent state for a state-space layer"
+            "return_kv with layers of different kinds or latent attention: the "
+            "KV cache (models/generation.py) holds one kind of layer, plain "
+            "attention and an MLP in each, has no recurrent state for a "
+            "state-space layer and no latent row for latent attention"
         )
+    if mtp and (cfg.mtp is None or return_kv):
+        raise ValueError("mtp=True needs cfg.mtp, and hands out no KV cache")
     if cfg.n_ssm_layers and mesh is not None and mesh.shape.get("seq", 1) > 1:
         raise NotImplementedError(
             "a state-space layer on a mesh that splits the sequence (ring / "
@@ -627,12 +732,17 @@ def forward(
             if kind.mixer == "attention":
                 with jax.named_scope("attn_qkv"):
                     h = _norm(x, lp["ln1"], cfg)
-                a, kv = _attention_block(
-                    h, lp["attn"], cfg, cos, sin,
-                    segment_ids, positions, attn_impl, cdt, mesh=mesh,
-                    variants=variants, variant_index=variant_index,
-                    l0=l0, kv=kept,
-                )
+                if kind.latent:
+                    a, kv = _latent_attention_block(
+                        h, lp["attn"], cfg, cos, sin, segment_ids, positions,
+                        attn_impl, cdt, mesh=mesh)
+                else:
+                    a, kv = _attention_block(
+                        h, lp["attn"], cfg, cos, sin,
+                        segment_ids, positions, attn_impl, cdt, mesh=mesh,
+                        variants=variants, variant_index=variant_index,
+                        l0=l0, kv=kept,
+                    )
                 with jax.named_scope("attn_out"):
                     if "ln1_post" in lp:
                         a = _norm(a, lp["ln1_post"], cfg)
@@ -739,6 +849,24 @@ def forward(
     x, moe_aux = carry
     with jax.named_scope("final_norm"):
         x = _norm(x, params["final_norm"], cfg)
+    if mtp:
+        # The module reads the stack's output and the embedding table and
+        # moves neither: the caller's loss alone trains the model.
+        mp = params["mtp"]
+        with jax.named_scope("mtp_in"):
+            # (a row's last position wraps round: it has no target to read)
+            e = jax.lax.stop_gradient(emb)[jnp.roll(input_ids, -1, axis=1)].astype(cdt)
+            if cfg.embedding_multiplier:
+                e = e * jnp.asarray(cfg.embedding_multiplier, cdt)
+            u = jnp.concatenate(
+                [_norm(e, mp["enorm"], cfg),
+                 _norm(jax.lax.stop_gradient(x), mp["hnorm"], cfg)], axis=-1)
+            u = act_c(u @ mp["eh_proj"]["weight"].astype(cdt))
+        with jax.named_scope("mtp_block"):
+            block = jax.tree_util.tree_map(lambda a: a[0], mp["block"])
+            (u, moe_aux), _ = layer_body(kinds[-1], ((None, True),))(
+                (u, moe_aux), (block, None))
+            x_mtp = _norm(u, mp["norm"], cfg)
 
     if output == "hidden":
         out = x
@@ -753,6 +881,8 @@ def forward(
                 else params["head"]["weight"]
             )
             out = log_c((x @ head_w.astype(cdt)).astype(jnp.float32))  # [R, T, V]
+    if mtp:
+        out = (out, x_mtp)
     if return_kv and return_aux:
         return out, kvs, moe_aux
     if return_kv:

@@ -53,15 +53,17 @@ def next_token_entropy(
     return jnp.where(segment_ids > 0, ent, 0.0)
 
 
-def _next_token_targets(input_ids, segment_ids):
+def _next_token_targets(input_ids, segment_ids, shift: int = 1):
     """(next_ids, valid) in the shifted frame shared by all logprob ops;
-    on device rows, or on numpy rows for the host's counts."""
+    on device rows, or on numpy rows for the host's counts. `shift`:
+    how many tokens on the target lies, inside the position's own
+    sequence (a prediction module's is 2)."""
     xp = np if isinstance(segment_ids, np.ndarray) else jnp
     next_ids = xp.concatenate(
-        [input_ids[:, 1:], xp.zeros_like(input_ids[:, :1])], axis=1
+        [input_ids[:, shift:], xp.zeros_like(input_ids[:, :shift])], axis=1
     )
     next_seg = xp.concatenate(
-        [segment_ids[:, 1:], xp.zeros_like(segment_ids[:, :1])], axis=1
+        [segment_ids[:, shift:], xp.zeros_like(segment_ids[:, :shift])], axis=1
     )
     valid = (segment_ids > 0) & (next_seg == segment_ids)
     return next_ids, valid
@@ -103,11 +105,14 @@ def _ce_chunk_setting() -> Optional[int]:
     return _CE_CHUNK_SNAP[0]
 
 
-def _chunk_logprobs(h_c, y_c, head_w):
-    """[C] fp32: log P(y_c) from one [C, V] logits tile."""
+def _chunk_logprobs(h_c, y_c, head_w, top: bool = False):
+    """[C] fp32: log P(y_c) from one [C, V] logits tile; with `top` a
+    pair, the second 1.0 where y_c is the tile's largest logit."""
     logits = (h_c @ head_w.astype(h_c.dtype)).astype(jnp.float32)
     lse = jax.nn.logsumexp(logits, axis=-1)
     picked = jnp.take_along_axis(logits, y_c[:, None], axis=-1)[:, 0]
+    if top:
+        return picked - lse, (picked >= jnp.max(logits, axis=-1)).astype(jnp.float32)
     return picked - lse
 
 
@@ -143,6 +148,16 @@ def response_positions(rows):
     """`train_batch`'s `scored_fn` of the losses that weigh every
     position by `response_scoring_mask`."""
     return response_scoring_mask(rows["segment_ids"], rows["prompt_mask"])
+
+
+def two_on(scored):
+    """What a prediction module's loss reads, of a next-token loss's
+    `scored` [..., T] (nonzero at t = the loss reads token t + 1): nonzero
+    at t where it reads token t + 2, the token that position t + 1
+    scores. `_next_token_targets(shift=2)` keeps those whose t + 2 lies
+    in t's own sequence. On numpy or device rows."""
+    xp = np if isinstance(scored, np.ndarray) else jnp
+    return xp.concatenate([scored[..., 1:], xp.zeros_like(scored[..., :1])], axis=-1)
 
 
 def _kept_first(keep):
@@ -200,8 +215,8 @@ def _scored_layout(keep, c: int):
     return src, jnp.maximum(csum - 1, 0), live, chunk_ids[0], n_run[0, -1]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _scored_logprobs(hidden, next_ids, head_w, keep, c: int, mesh):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _scored_logprobs(hidden, next_ids, head_w, keep, c: int, mesh, top: bool = False):
     """[G, m] fp32: log P(next_ids) at the positions `keep` [G, m], zero
     at the others, from `hidden` [G, m, D] through chunks of `c`
     positions: the kept positions' rows move to the front of their
@@ -217,8 +232,10 @@ def _scored_logprobs(hidden, next_ids, head_w, keep, c: int, mesh):
     add from the chunks that run (the head alone on a v5e: PERF.md
     section 6, PR 31). Moving the rows there and back is a gather each
     way in either pass, each the other's transpose, so neither leaves
-    the compiler a scatter whose indices it would sort (models/moe.py)."""
-    return _scored_logprobs_fwd(hidden, next_ids, head_w, keep, c, mesh)[0]
+    the compiler a scatter whose indices it would sort (models/moe.py).
+    With `top` a pair: beside the logprobs, 1.0 at the kept positions
+    whose label is the largest logit (nothing flows back through it)."""
+    return _scored_logprobs_fwd(hidden, next_ids, head_w, keep, c, mesh, top)[0]
 
 
 def _group_shardings(mesh):
@@ -230,7 +247,7 @@ def _group_shardings(mesh):
             jax.sharding.NamedSharding(mesh, spec()))
 
 
-def _scored_logprobs_fwd(hidden, next_ids, head_w, keep, c, mesh):
+def _scored_logprobs_fwd(hidden, next_ids, head_w, keep, c, mesh, top=False):
     g, m, d = hidden.shape
     by_group, everywhere = _group_shardings(mesh)
     src, rank, live, chunk_ids, n_run = _scored_layout(keep, c)
@@ -243,17 +260,21 @@ def _scored_logprobs_fwd(hidden, next_ids, head_w, keep, c, mesh):
 
     def body(k, out):
         i = chunk_ids[k]
-        return out.at[i].set(_chunk_logprobs(h[i], y[i], head_w))
+        return jax.tree_util.tree_map(
+            lambda o, new: o.at[i].set(new), out,
+            _chunk_logprobs(h[i], y[i], head_w, top))
 
-    logp = jax.lax.fori_loop(0, n_run, body, jnp.zeros(y.shape, jnp.float32))
-    out = _take(logp.reshape(g, m), rank, keep, by_group)
+    zeros = jnp.zeros(y.shape, jnp.float32)
+    out = jax.lax.fori_loop(0, n_run, body, (zeros, zeros) if top else zeros)
+    out = jax.tree_util.tree_map(
+        lambda a: _take(a.reshape(g, m), rank, keep, by_group), out)
     return out, (h, y, head_w, src, rank, live, keep, chunk_ids, n_run)
 
 
-def _scored_logprobs_bwd(c, mesh, res, d_out):
+def _scored_logprobs_bwd(c, mesh, top, res, d_out):
     h, y, head_w, src, rank, live, keep, chunk_ids, n_run = res
     by_group, _ = _group_shardings(mesh)
-    d_logp = _take(d_out, src, live).reshape(-1, c)
+    d_logp = _take(d_out[0] if top else d_out, src, live).reshape(-1, c)
 
     def body(k, grads):
         dh, dw = grads
@@ -273,13 +294,13 @@ _scored_logprobs.defvjp(_scored_logprobs_fwd, _scored_logprobs_bwd)
 
 
 def head_cells_run(segment_ids: np.ndarray, scored: Optional[np.ndarray],
-                   vocab: int, row_groups: int = 1) -> Tuple[int, int]:
+                   vocab: int, row_groups: int = 1, shift: int = 1) -> Tuple[int, int]:
     """(positions whose logprob is read, cells the head runs its logits
     tile over) for one micro-batch's packed rows [R, T], counted on the
     host by the rule `fused_next_token_logprobs` runs by on the device.
     `scored` None: every valid position is read and every chunk runs."""
     seg = np.asarray(segment_ids)
-    _, keep = _next_token_targets(seg, seg)
+    _, keep = _next_token_targets(seg, seg, shift)
     if scored is None:
         return int(keep.sum()), seg.size
     keep = (keep & (np.asarray(scored) > 0)).reshape(row_groups, -1)
@@ -297,6 +318,8 @@ def fused_next_token_logprobs(
     chunk_size: Optional[int] = None,
     scored: Optional[jnp.ndarray] = None,  # [R, T], nonzero = read
     mesh=None,
+    shift: int = 1,
+    top: bool = False,
 ) -> jnp.ndarray:
     """next_token_logprobs computed straight from hidden states without
     ever materializing the [R, T, V] logits tensor.
@@ -323,18 +346,27 @@ def fused_next_token_logprobs(
     valid position is computed: the forward-only path and any caller
     that says nothing.
 
+    `shift` is how many tokens on the label lies (1: the next token; a
+    prediction module's hidden states are read with 2), always inside
+    the position's own sequence of a packed row. `top` (with `scored`)
+    makes the result a pair: the logprobs, and 1.0 where the label is
+    the head's argmax.
+
     Returns [R, T] fp32, zeros at invalid (sequence-final / pad) slots.
     """
     R, T, D = hidden.shape
-    next_ids, valid = _next_token_targets(input_ids, segment_ids)
+    next_ids, valid = _next_token_targets(input_ids, segment_ids, shift)
     n = R * T
     c = head_chunk_len(n, head_w.shape[-1], chunk_size)
     if scored is not None:
         # one group of rows a shard of the mesh's data axes
         g = 1 if mesh is None else mesh.shape["data"] * mesh.shape["fsdp"]
-        return _scored_logprobs(
+        out = _scored_logprobs(
             hidden.reshape(g, n // g, D), next_ids.reshape(g, n // g), head_w,
-            (valid & (scored > 0)).reshape(g, n // g), c, mesh).reshape(R, T)
+            (valid & (scored > 0)).reshape(g, n // g), c, mesh, top)
+        return jax.tree_util.tree_map(lambda a: a.reshape(R, T), out)
+    if top:
+        raise NotImplementedError("top is the masked head's: pass `scored`")
     flat_h = hidden.reshape(n // c, c, D)
     flat_y = next_ids.reshape(n // c, c)
 

@@ -53,7 +53,12 @@ def param_partition_spec(path: str, ndim: int) -> P:
         return P("tensor", "fsdp")  # [V, D]
     if path.startswith("head") or "/head/" in path or path == "head/weight":
         return P("fsdp", "tensor")  # [D, V] or [D, 1]
-    if name in ("wq", "wk", "wv", "w_gate", "w_up", "w_in"):
+    # latent attention's up-projections [L, rank, heads x size] go with
+    # the column-parallel matrices; its down-projections, whose outputs
+    # an RMSNorm reads whole, are ZeRO-sharded on the hidden dim alone
+    if name in ("wq_a", "wkv_a"):
+        return P(None, "fsdp", None)
+    if name in ("wq", "wk", "wv", "w_gate", "w_up", "w_in", "wq_b", "wkv_b"):
         if ndim == 4:
             # MoE stacked experts [L, E, D, F]: expert parallelism —
             # E shards over the ZeRO/fsdp axis (the einsum dispatch

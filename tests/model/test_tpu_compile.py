@@ -255,3 +255,25 @@ def test_splash_takes_q_and_k_at_64_against_v_at_128(one_chip, window):
 
     text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, k, v, ids, ids).compile().as_text()
     assert text.count("tpu_custom_call") >= 3 and "splash_mqa_dq" in text
+
+
+def test_splash_takes_q_and_k_at_192_against_v_at_128_and_a_group_of_one(one_chip):
+    """Latent attention's call (PR 40): 32 q and 32 kv heads, q and k of
+    128 + 64 = 192 (no multiple of the 128 lanes) against v of 128, a row
+    of 8,192 alone in its call. Mosaic takes 192 as it is: nothing is
+    padded to 256, and the backward is the dq and dkv kernels."""
+    from areal_tpu.ops.attention import splash_packed_attention
+
+    t = 8192
+    qk = _shape((1, t, 32, 192), jnp.bfloat16, one_chip)
+    v = _shape((1, t, 32, 128), jnp.bfloat16, one_chip)
+    ids = _shape((1, t), jnp.int32, one_chip)
+
+    def loss(q, k, v, seg, pos):
+        out = splash_packed_attention(q, k, v, seg, pos, interpret=False)
+        assert out.shape == (1, t, 32, 128)
+        return out.astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(qk, qk, v, ids, ids).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3 and "splash_mqa_dq" in text
+    assert "bf16[32,1,8192,256]" not in text  # no padded copy of q
