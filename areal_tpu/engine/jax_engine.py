@@ -1042,8 +1042,8 @@ class JaxTrainEngine(TrainEngine):
         would make them run; the grid steps the forward, dq and dkv
         kernels walk and those whose pair runs, summed over rows, q heads
         and layers: ops/attention.attn_grid_steps; the widest forward
-        grid, kv steps a q block, of any layer and row: a row alone
-        takes the narrowest width that holds it)."""
+        grid, kv steps a q block, of any layer and row: of a row alone
+        the pairs of its fullest q block)."""
         cfg = self.model_cfg
         segment_ids = np.asarray(segment_ids)
         rows, row_len = segment_ids.shape[-2:]

@@ -159,9 +159,10 @@ _SPLASH_PAD_TO = 512
 # the row length picks the splash blocks"). Median error 6 %; the shape
 # picked is within 3 % of the fastest measured one for every length of
 # 896 and above but 3840, which stays as it is (_SPLASH_MIN_GAIN).
-# Fitted on the fused backward over static grids: a row alone has run
-# the dq and dkv kernels over compacted tables since PR 35, at blocks
-# these constants still pick (a refit is its own change).
+# Fitted on the fused backward over static grids: a row alone runs the
+# repo's own forward, dq and dkv kernels over its list of live pairs
+# (ops/pallas/splash_pairs.py), at blocks these constants still pick (a
+# refit is its own change).
 _SPLASH_NS = (980.0, 0.0115, 1.13, 1.25)
 
 # The estimate's own median error: a smaller estimated gain is no reason
@@ -187,11 +188,11 @@ def _static_block_pairs(t: int, bq: int, bkv: int,
 def _active_block_pairs(t: int, bq: int, bkv: int,
                         window: Optional[int] = None) -> tuple:
     """(active pairs, widest q row) of `_static_block_pairs`. The
-    forward kernel's grid is nq x the widest row: splash shrinks the kv
-    axis to the most active blocks any q block has, and skips the rest
-    (the fused backward kernel keeps its whole grid and skips the
-    work; the dq and dkv kernels of a row alone are shrunk as the
-    forward is: `_table_widths`)."""
+    static forward kernel's grid is nq x the widest row: splash shrinks
+    the kv axis to the most active blocks any q block has, and skips the
+    rest (the fused backward kernel keeps its whole grid and skips the
+    work; a row alone walks a list of its live pairs instead:
+    `_pair_lists`)."""
     pairs = _static_block_pairs(t, bq, bkv, window)
     return int(pairs.sum()), int(pairs.sum(axis=1).max())
 
@@ -217,7 +218,7 @@ def live_block_pairs(segment_ids, bq: int, bkv: int):
     denominator of 0, and the NaN it leaves at padded positions reaches
     the weights' gradients as 0 x NaN.
 
-    One rule for the device (traced ids: `_block_tables`) and the host
+    One rule for the device (traced ids: `_pair_lists`) and the host
     (numpy ids: `attn_block_cells`)."""
     xp = jnp if isinstance(segment_ids, jax.Array) else np
     t = segment_ids.shape[-1]
@@ -248,8 +249,9 @@ def _splash_cost_terms(t: int, bq: int, bkv: int, bkvc: int,
     rows of q and k/v they load. `window` counts a window layer's pairs
     (for a fit of a `scripts/splash_shape_sweep.py --window` sweep: the
     constants in the tree are fitted on causal masks, and `splash_cost`
-    prices those alone). The steps are the static forward grid's: what a
-    row alone walks at run time (`_table_widths`) is not priced."""
+    prices those alone). The steps are the static forward grid's: a row
+    alone walks its live pairs and no step besides (`_pair_lists`),
+    which is not priced."""
     active, widest = _active_block_pairs(t, bq, bkv, window)
     return ((t // bq) * widest, active * bq * bkv,
             active * (bkv // bkvc) * bq, active * (bq + bkv))
@@ -322,11 +324,10 @@ def splash_run_shape(t: int):
 
 
 def _splash_kernel(t: int, bq: int, bkv: int, bkvc: int, group: int,
-                   interpret: bool = False, window: Optional[int] = None,
-                   fused_bwd: bool = True):
-    """Build the splash-attention kernel for rows of `t` at the given
-    blocks (the mask object is cached; the kernel itself is rebuilt per
-    trace).
+                   interpret: bool = False, window: Optional[int] = None):
+    """Build the static splash-attention kernel for rows of `t` at the
+    given blocks: what short rows and rows together run (the mask object
+    is cached; the kernel itself is rebuilt per trace).
 
     jax's splash attention (jax.experimental.pallas.ops.tpu.splash_attention,
     the production TPU flash kernel — same role as the flash-attn package
@@ -337,11 +338,7 @@ def _splash_kernel(t: int, bq: int, bkv: int, bkvc: int, group: int,
     `window` is splash's LocalMask (window - 1 to the left, none to the
     right) for the same reason, and the kernels skip the block pairs
     wholly behind it. Length and blocks come from `splash_run_shape`; the
-    backward is the fused dq/dkv kernel at the same blocks, or with
-    `fused_bwd=False` (a row alone: `_rows_skip`) splash's dq kernel and
-    dkv kernel, each over a grid of its own: dq summed in the kernel's
-    float32 scratch, not in `[kv blocks, heads, t, hd]` partials and a
-    reduce.
+    backward is the fused dq/dkv kernel at the same blocks.
     """
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk,
@@ -363,8 +360,7 @@ def _splash_kernel(t: int, bq: int, bkv: int, bkvc: int, group: int,
     bs = sk.BlockSizes(
         block_q=bq, block_kv=bkv, block_kv_compute=bkvc,
         block_q_dkv=bq, block_kv_dkv=bkv, block_kv_dkv_compute=bkvc,
-        use_fused_bwd_kernel=fused_bwd,
-        **({} if fused_bwd else dict(block_q_dq=bq, block_kv_dq=bkv)),
+        use_fused_bwd_kernel=True,
     )
     # Residuals are checkpoint-named so the "save_attn" remat policy
     # (models/transformer.py) can pin them: backward then runs the
@@ -376,125 +372,60 @@ def _splash_kernel(t: int, bq: int, bkv: int, bkvc: int, group: int,
     )
 
 
-@functools.lru_cache(maxsize=None)
-def _table_widths(t: int, bq: int, bkv: int, window: Optional[int]) -> tuple:
-    """((W, Wq), ..), each no narrower than the one before: the widths
-    the compacted tables of a row of `t` may take, W kv blocks a q block
-    (forward and dq grids: nq x W) and Wq q blocks a kv block (dkv: Wq x
-    nkv). A quarter, a half and the whole of the row's blocks, none wider
-    than the static mask's widest row or column (a window layer's is a
-    few blocks, so it has one width) nor narrower than the diagonal. A
-    pure function of the run shape and the window: the widths are
-    branches inside one program, never a program each."""
-    static = _static_block_pairs(t, bq, bkv, window)
-    diagonal = _diagonal_block_pairs(t, bq, bkv)
+def _pair_list(pairs, capacity: int):
+    """(`pairs` [a, b] in row-major order as (major, minor, flags), int32
+    [capacity] each, and how many there are): a major block's pairs in a
+    run, its first flagged FIRST and its last LAST; past the count, the
+    last pair again (a step never walked: the grid is as long as the
+    count)."""
+    from areal_tpu.ops.pallas.splash_pairs import FIRST, LAST
 
-    def width(axis, part):
-        most = static.sum(axis).max()  # blocks any row (or column) can need
-        return int(min(most, max(diagonal.sum(axis).max(),
-                                 -(-static.shape[axis] // part))))
-
-    widths = []
-    for part in (4, 2, 1):
-        if (pair := (width(1, part), width(0, part))) not in widths:
-            widths.append(pair)
-    return tuple(widths)
-
-
-def _width_index(pairs, widths: tuple):
-    """Which of `widths` the rows whose pairs `pairs` [..., nq, nkv] run
-    take: the narrowest that holds the most pairs any q block and any kv
-    block has. One rule for the device (`_block_tables`) and the host
-    (`attn_grid_steps`)."""
-    need_w = pairs.sum(axis=-1).max(axis=-1)
-    need_wq = pairs.sum(axis=-2).max(axis=-1)
-    return sum(((need_w > w) | (need_wq > wq)).astype(np.int32)
-               for w, wq in widths[:-1])
-
-
-def _compacted(pairs, width: int, after):
-    """(block_mask, data_next), [1, n, width] each: for every row of
-    `pairs` [n, m] its blocks that run, packed to the front in their own
-    order, as splash's static shrunk tables are: `block_mask` 1 where a
-    step runs and 0 on the tail, `data_next` the block a step loads; on
-    the tail, the block that `after` [n] names (what the pipeline
-    fetches meanwhile)."""
-    first = jnp.argsort(~pairs, axis=1, stable=True)[:, :width]
-    runs = jnp.arange(width)[None, :] < pairs.sum(axis=1)[:, None]
-    return (runs.astype(jnp.int32)[None],
-            jnp.where(runs, first, after(first[:, 0])[:, None])[None])
+    n = pairs.sum(dtype=jnp.int32)
+    at = jnp.nonzero(pairs.reshape(-1), size=capacity, fill_value=0)[0]
+    at = jnp.where(jnp.arange(capacity) < n, at, at[n - 1]).astype(jnp.int32)
+    major, minor = at // pairs.shape[1], at % pairs.shape[1]
+    edge = major[1:] != major[:-1]
+    first = jnp.concatenate([jnp.ones(1, bool), edge])
+    last = jnp.concatenate([edge, jnp.ones(1, bool)]) | (jnp.arange(capacity) == n - 1)
+    return major, minor, (FIRST * first + LAST * last).astype(jnp.int32), n
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2, 3))
-def _block_tables(segment_ids, bq, bkv, window):
-    """The block tables the splash kernels of one packed row
-    `segment_ids` [T] walk: of the pairs its static mask leaves
-    (`_static_block_pairs`) those that `live_block_pairs` finds in the
-    row, compacted. Returns (which of `_table_widths` the row takes, and
-    for every width (forward and dq block_mask, data_next: [1, nq, W];
-    dkv block_mask, data_next: [1, Wq, nkv])). A width that does not
-    hold the row gets the diagonal alone: no program without a caller's
-    `vmap` runs it, and under one (every branch runs, a select picks)
-    its softmax still has a denominator. Jitted: a program's call sites
-    (each kind of layer, the forward pass and remat's) share one traced
-    copy."""
+def _pair_lists(segment_ids, bq, bkv, window):
+    """The block pairs the kernels of one packed row `segment_ids` [T]
+    walk (`ops/pallas/splash_pairs.PairLists`): of the pairs its static
+    mask leaves (`_static_block_pairs`) those that `live_block_pairs`
+    finds in the row, each once, q-major for the forward and dq kernels
+    and kv-major for dkv, in lists as long as the static mask has pairs
+    with `n`, the row's own count, beside them. Every q block's diagonal
+    pair is live, so `n` is never under the number of q blocks and every
+    output block is written. Jitted: a program's call sites (each kind of
+    layer, the forward pass and remat's) share one traced copy."""
+    from areal_tpu.ops.pallas.splash_pairs import PairList, PairLists
+
     t = segment_ids.shape[-1]
-    widths = _table_widths(t, bq, bkv, window)
-    live = (live_block_pairs(segment_ids, bq, bkv)
-            & _static_block_pairs(t, bq, bkv, window))
-    index = _width_index(live, widths)
-    diagonal = _diagonal_block_pairs(t, bq, bkv)
-    tables = []
-    for at, (w, wq) in enumerate(widths):
-        pairs = live if at == len(widths) - 1 else jnp.where(
-            index <= at, live, diagonal)
-        # Forward and dq, a q block's steps one after another, then the
-        # next q block's (the last one's tail: the next head's first).
-        fwd = _compacted(pairs, w, lambda first: jnp.roll(first, -1))
-        # dkv, a kv block's q blocks one after another, then the same kv
-        # block again for the next q head.
-        dkv = _compacted(pairs.T, wq, lambda first: first)
-        tables.append((*fwd, *(a.transpose(0, 2, 1) for a in dkv)))
-    return index, tuple(tables)
-
-
-def _with_tables(kernel, tables):
-    """`kernel` walking the run-time `tables` of one row and one width
-    (`_block_tables`) in place of its static ones. All are
-    scalar-prefetch operands, so they may be traced (jax's own dynamic
-    masks are), and each kernel's grid is as wide as its table. Inside a
-    pair that runs nothing changes: the mask function and the segment
-    ids still mask cell by cell, at the place `data_next` names."""
-    from jax.experimental.pallas.ops.tpu.splash_attention import (
-        splash_attention_kernel as sk,
-    )
-
-    def put(info, block_mask, data_next):
-        assert info.partial_mask_blocks is None and info.mask_next is None
-        return info._replace(
-            block_mask=block_mask.astype(info.block_mask.dtype),
-            data_next=data_next.astype(info.data_next.dtype))
-
-    fwd_mask, fwd_next, dkv_mask, dkv_next = tables
-    return sk.SplashAttentionKernel(
-        put(kernel.fwd_mask_info, fwd_mask, fwd_next),
-        put(kernel.dq_mask_info, fwd_mask, fwd_next),
-        put(kernel.dkv_mask_info, dkv_mask, dkv_next),
-        **kernel.kwargs)
+    static = _static_block_pairs(t, bq, bkv, window)
+    live = live_block_pairs(segment_ids, bq, bkv) & static
+    capacity = int(static.sum())
+    qi, ki, q_flags, n = _pair_list(live, capacity)
+    kj, qj, kv_flags, _ = _pair_list(live.T, capacity)
+    return PairLists(PairList(qi, ki, q_flags), PairList(qj, kj, kv_flags), n)
 
 
 # A row shorter than this (at the length it runs at) keeps the static
-# kernel: its kernels take tens of microseconds, and a program whose block
-# tables are values of the run takes 0.2-0.3 s longer to trace and lower.
+# kernel: its kernels take tens of microseconds, and its static kernels
+# with the fused backward are 8-25 % faster there (PERF.md section 6,
+# PR 35).
 _SKIP_MIN_LEN = 2048
 
 
 def _rows_skip(rows: int, t_run: int) -> bool:
     """Whether the kernels of `rows` packed rows in one call walk the
-    rows' own live block pairs (`_block_tables`: compacted tables, dq and
-    dkv in kernels of their own): a long row alone. Several rows keep the
-    static kernels, the fused backward among them, and share one grid: a
-    table a row needs a loop over the rows,
+    rows' own live block pairs (`_pair_lists`: a list of pairs whose
+    length is a value of the run, forward, dq and dkv in the repo's own
+    kernels): a long row alone. Several rows keep the static kernels,
+    the fused backward among them, and share one grid: a list a row
+    needs a loop over the rows,
     which under `vmap` (pallas's own, around the kernel calls alone) is
     slower than the static kernel and under `lax.map` gained 3-4 % of
     `q15d12-train-ppo`'s tokens/s for 19 % of its `setup_s`, each program
@@ -527,9 +458,9 @@ def splash_packed_attention(
     kernels of a long row alone (`_rows_skip`) walk those that
     `live_block_pairs` finds in the row's segment ids, and no others:
     not the pairs between two sequences, nor those between a sequence
-    and the padding, and their grids are as wide as the row's live
-    pairs need (`_table_widths`: a branch taken at run time). What comes
-    back at a real position is the same to the bit.
+    and the padding, and their grids are as long as the row's live pairs
+    (`_pair_lists`, `ops/pallas/splash_pairs.py`: no step without a
+    pair). What comes back at a real position is the same to the bit.
 
     Packed rows go in whole, every array with a leading axis, so that
     the wrapper sees how many share the call; a row given alone (which
@@ -563,8 +494,8 @@ def _splash_row(q, k, v, segment_ids, *, run_shape, interpret, window, skip,
     hkv = k.shape[1]
     group = hq // hkv
     t_run, bq, bkv, bkvc = run_shape
-    kernel = _splash_kernel(t_run, bq, bkv, bkvc, group, interpret=interpret,
-                            window=window, fused_bwd=not skip)
+    kernel = None if skip else _splash_kernel(
+        t_run, bq, bkv, bkvc, group, interpret=interpret, window=window)
 
     q = q * jnp.asarray(scale, q.dtype)
     if t_run > t:
@@ -573,25 +504,23 @@ def _splash_row(q, k, v, segment_ids, *, run_shape, interpret, window, skip,
         pad = ((0, t_run - t), (0, 0), (0, 0))
         q, k, v = jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad)
         segment_ids = jnp.pad(segment_ids, (0, t_run - t))
-    # [T', Hq, hd] -> [Hkv, group, T', hd]; k/v -> [Hkv, T', hd]
-    qh = q.transpose(1, 0, 2).reshape(hkv, group, t_run, hd)
+    # [T', H, hd] -> [H, T', hd]; the static kernel takes q as
+    # [Hkv, group, T', hd], an MQA problem a kv head
+    qh = q.transpose(1, 0, 2)
+    if not skip:
+        qh = qh.reshape(hkv, group, t_run, hd)
     kh = k.transpose(1, 0, 2)
     vh = v.transpose(1, 0, 2)
-    ids = sk.SegmentIds(q=segment_ids, kv=segment_ids)
-
-    def attend(kernel):
-        return jax.vmap(lambda qq, kk, vv: kernel(qq, kk, vv, ids))(qh, kh, vh)
-
     if skip:
-        # One program whatever the row holds: the width is a branch taken
-        # at run time, outside the loop over kv heads.
-        index, tables = _block_tables(segment_ids, bq, bkv, window)
-        walks = [functools.partial(attend, _with_tables(kernel, one))
-                 for one in tables]
-        out = walks[0]() if len(walks) == 1 else jax.lax.switch(index, walks)
+        from areal_tpu.ops.pallas.splash_pairs import Blocks, pair_attention
+
+        out = pair_attention(
+            qh, kh, vh, segment_ids, _pair_lists(segment_ids, bq, bkv, window),
+            Blocks(bq, bkv, bkvc), window, SPLASH_RESIDUAL_NAME, interpret)
     else:
-        out = attend(kernel)
-    # [Hkv, group, T', hd of v] -> [T, Hq, hd of v]
+        ids = sk.SegmentIds(q=segment_ids, kv=segment_ids)
+        out = jax.vmap(lambda qq, kk, vv: kernel(qq, kk, vv, ids))(qh, kh, vh)
+    # [Hq, T', hd of v] -> [T, Hq, hd of v]
     out = out.reshape(hq, t_run, v.shape[-1]).transpose(1, 0, 2)
     return out[:t].astype(q.dtype)
 
@@ -757,23 +686,22 @@ def attn_run_len(
 def _host_block_pairs(impl, segment_ids, hq, hkv, mesh, window):
     """What the host's counters count of the packed rows `segment_ids`
     [R, T] of one micro-batch: None where an implementation without
-    blocks runs them, else (run shape, the window if it cuts the row,
-    static pairs [nq, nkv], and where the kernels walk the rows' own
-    pairs (`_rows_skip`) those pairs, [R, nq, nkv])."""
+    blocks runs them, else (run shape, static pairs [nq, nkv], and
+    where the kernels walk the rows' own pairs (`_rows_skip`) those
+    pairs, [R, nq, nkv])."""
     r, t = segment_ids.shape
     ran, _ = _choose_attn_impl(impl, t, hq, hkv, mesh, r)
     if ran != "splash":
         return None
     shape = splash_run_shape(t)
     t_run, bq, bkv, _ = shape
-    window = _row_window(t, window)
-    static = _static_block_pairs(t_run, bq, bkv, window)
+    static = _static_block_pairs(t_run, bq, bkv, _row_window(t, window))
     # A sharded mesh runs each shard's rows in a call of their own.
     if not _rows_skip(r // (cp_axes(mesh)[0] if mesh is not None else 1), t_run):
-        return shape, window, static, None
+        return shape, static, None
     live = live_block_pairs(
         np.pad(segment_ids, ((0, 0), (0, t_run - t))), bq, bkv)
-    return shape, window, static, live & static
+    return shape, static, live & static
 
 
 def attn_block_cells(
@@ -792,7 +720,7 @@ def attn_block_cells(
     found = _host_block_pairs(impl, segment_ids, hq, hkv, mesh, window)
     if found is None:
         return r * t * t, r * t * t
-    (t_run, bq, bkv, _), _, static, live = found
+    (t_run, bq, bkv, _), static, live = found
     causal = r * int(_static_block_pairs(t_run, bq, bkv).sum())
     pairs = r * int(static.sum()) if live is None else int(live.sum())
     return pairs * bq * bkv, causal * bq * bkv
@@ -805,23 +733,22 @@ def attn_grid_steps(
     """(grid steps the attention kernels walk for the packed rows
     `segment_ids` [R, T] of one micro-batch, those whose block pair
     runs, the widest forward grid: kv steps a q block), per q head, the
-    forward kernel once. A row alone (`_rows_skip`) walks compacted
-    tables at the width it takes (`_table_widths`, `_width_index`: the
-    device's rule): nq x W forward, the same in dq, Wq x nkv in dkv.
-    Other rows walk the static grids: nq x the mask's widest row
-    forward, and the fused backward's whole nq x nkv. An implementation
-    without blocks has no grid: zeros. For host-side counters."""
+    forward kernel once. A row alone (`_rows_skip`) walks its list of
+    live pairs (`_pair_lists`: the device's rule) in the forward, dq and
+    dkv kernels and no step besides; its widest grid is its fullest q
+    block's pairs. Other rows walk the static grids: nq x the mask's
+    widest row forward, and the fused backward's whole nq x nkv. An
+    implementation without blocks has no grid: zeros. For host-side
+    counters."""
     found = _host_block_pairs(impl, segment_ids, hq, hkv, mesh, window)
     if found is None:
         return 0, 0, 0
-    (t_run, bq, bkv, _), window, static, live = found
-    nq, nkv = static.shape
+    _, static, live = found
     if live is None:
+        nq, nkv = static.shape
         r, widest = len(segment_ids), int(static.sum(axis=1).max())
         return r * (nq * widest + nq * nkv), r * 2 * int(static.sum()), widest
-    widths = _table_widths(t_run, bq, bkv, window)
-    w, wq = np.asarray(widths)[_width_index(live, widths)].T
-    return int((2 * nq * w + wq * nkv).sum()), 3 * int(live.sum()), int(w.max())
+    return 3 * int(live.sum()), 3 * int(live.sum()), int(live.sum(axis=-1).max())
 
 
 def packed_attention(q, k, v, segment_ids, positions, softmax_scale=None,

@@ -19,16 +19,20 @@ largest dividing blocks. `--fit` needs no device: least squares of
 runs under full remat.
 
 `--check` times nothing: the call's output and gradients against the
-einsum reference's. The backward is what the call runs: splash's dq and
-dkv kernels over compacted tables for a row of 2048 or more alone in its
-call, the fused kernel over the static grid otherwise. `--unfused` gives every call the
-dq and dkv kernels, `--fused` the fused one (a row alone then keeps its
-static tables: the two cannot be told apart otherwise), `--static` a row
-alone its static tables, `--width W,Wq` one width for its compacted
-tables in place of the branch `_table_widths` offers (the row must fit:
-`--seq-len` packs it with sequences that short), so that two widths
-price a walked dead step: forward by `fwd_ms`, dq by `grad_ms` less
-that at two `W`, dkv at two `Wq`.
+einsum reference's. The kernels are what the call runs: for a row of
+2048 or more alone in its call the repo's own over the row's list of
+live block pairs (`ops/pallas/splash_pairs.py`), splash's static ones
+and the fused backward otherwise. `--static` gives a row alone the
+static kernels too, `--seq-len`
+packs the rows with sequences that short and `--rows-from TRAFFIC` with
+the micro-batches of one of the benchmark's pools, a line each.
+`--group` and `--v-dim` set the kv heads (hq // group) and v's head
+size (192 / 128: `--hd 192 --v-dim 128`). `--ops` times by a trace, not
+the host's clock: from a traced call of forward + backward, the
+milliseconds a layer of each attention kernel by name, with the grid
+steps a q head walks and those whose pair runs;
+`--parent DIR` runs every line for the checkout at DIR as well (`git
+archive` of the commit to compare with), on the same inputs.
 """
 
 from __future__ import annotations
@@ -71,21 +75,57 @@ def today(t):
     return A._plain_run_shape(t, *A._splash_block_targets())
 
 
-def variant(args):
-    """Steer `ops/attention` to the kernels `--unfused`, `--fused`,
-    `--static` and `--width` name."""
-    from areal_tpu.ops import attention as A
+def attention_module(parent=None):
+    """This tree's `ops/attention`, or that of the checkout at
+    `parent`, loaded beside it."""
+    if parent is None:
+        from areal_tpu.ops import attention
 
-    if args.unfused or args.fused:
-        build = A._splash_kernel
-        A._splash_kernel = lambda *a, fused_bwd=True, **kw: build(
-            *a, fused_bwd=not args.unfused, **kw)
-    if args.fused:
-        A._rows_skip = lambda rows, t_run: False
+        return attention
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "parent_attention", os.path.join(parent, "areal_tpu", "ops", "attention.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def variant(args, A):
+    """Steer `A` to the kernels `--static` names."""
     if args.static:
-        A._with_tables = lambda kernel, tables: kernel
-    if args.width:
-        A._table_widths = lambda *a: (tuple(args.width),)
+        A._rows_skip = lambda rows, t_run: False
+
+
+def pool_rows(traffic_name, t):
+    """(segment ids, positions), [n, 1, t] each: the train micro-batches
+    of the benchmark's pool `traffic_name`, each packed into one row of
+    `t` in the order the batch has them."""
+    import numpy as np
+
+    from areal_tpu.api.data_api import MicroBatchSpec, SequenceSample
+    from benchmark import manifest, traffic
+
+    with open(os.path.join(manifest.BENCH_DIR, "traffic", f"{traffic_name}.json")) as f:
+        p = traffic.effective(json.load(f), rehearsal=False)
+    budget = MicroBatchSpec(n_mbs=1, max_tokens_per_mb=int(p["ppo"]["max_tokens_per_mb"]))
+    rows = []
+    for i, seqs in enumerate(traffic.ppo_batch_lengths(p)):
+        lens = [s["prompt_len"] + s["resp_len"] for s in seqs]
+        batch = SequenceSample.from_default(
+            ids=[f"{i}/{j}" for j in range(len(lens))], seqlens=lens,
+            data={"packed_input_ids": np.zeros(sum(lens), np.int32)})
+        for mini in batch.split(MicroBatchSpec(n_mbs=int(p["ppo"]["n_minibatches"])))[0]:
+            rows += [mb.seqlens_of() for mb in mini.split(budget)[0]]
+    seg = np.zeros((len(rows), 1, t), np.int32)
+    pos = np.zeros((len(rows), 1, t), np.int32)
+    for r, lens in enumerate(rows):
+        at = 0
+        for i, n in enumerate(lens):
+            seg[r, 0, at:at + n] = i + 1
+            pos[r, 0, at:at + n] = np.arange(n)
+            at += n
+    return seg, pos
 
 
 def packed_rows(rows, t, seq_len=None):
@@ -99,48 +139,59 @@ def packed_rows(rows, t, seq_len=None):
     for r in range(rows):
         cuts = [0, t // 5, t // 2, t - 1 - (r * 37) % 100]
         if seq_len:
-            cuts = list(range(0, t - (r * 37) % 100, seq_len))
+            cuts = list(range(0, t - (r * 37) % 100 + 1, seq_len))
         for i in range(len(cuts) - 1):
             seg[r, cuts[i]: cuts[i + 1]] = i + 1
             pos[r, cuts[i]: cuts[i + 1]] = np.arange(cuts[i + 1] - cuts[i])
     return seg, pos
 
 
-def time_shape(rows, t, run_shape, hq, hkv, hd, layers, window=None,
-               seq_len=None):
-    """(fwd ms, fwd+bwd ms) of `layers` chained attention calls."""
-    import jax
+def inputs(rows, t, hq, hkv, hd, v_dim, seed):
     import jax.numpy as jnp
     import numpy as np
 
-    from areal_tpu.ops.attention import splash_packed_attention
+    rng = np.random.RandomState(seed)
+    return tuple(jnp.asarray(rng.randn(rows, t, h, d), jnp.bfloat16)
+                 for h, d in ((hq, hd), (hkv, hd), (hkv, v_dim)))
 
-    rng = np.random.RandomState(0)
-    q = jnp.asarray(rng.randn(rows, t, hq, hd), jnp.bfloat16)
-    k = jnp.asarray(rng.randn(rows, t, hkv, hd), jnp.bfloat16)
-    v = jnp.asarray(rng.randn(rows, t, hkv, hd), jnp.bfloat16)
-    seg, pos = (jnp.asarray(a) for a in packed_rows(rows, t, seq_len))
 
-    def chain(q, k, v):
+def chained(A, run_shape, layers, window):
+    """(q, k, v, seg, pos) -> a scalar through `layers` chained calls of
+    `A.splash_packed_attention`, rows whole as the model gives them."""
+    import jax
+    import jax.numpy as jnp
+
+    def chain(q, k, v, seg, pos):
         def body(x, _):
-            # rows whole, as the model gives them
-            out = splash_packed_attention(x, k, v, seg, pos,
-                                          _run_shape=run_shape, window=window)
+            out = A.splash_packed_attention(x, k, v, seg, pos,
+                                            _run_shape=run_shape, window=window)
+            # v's head size may differ from q's
+            d = x.shape[-1] - out.shape[-1]
+            out = jnp.pad(out, ((0, 0),) * 3 + ((0, d),)) if d > 0 else out[..., :x.shape[-1]]
             return x + out * jnp.asarray(1e-3, x.dtype), None
 
         x, _ = jax.lax.scan(body, q, None, length=layers)
         return jnp.sum(x.astype(jnp.float32))
 
+    return chain
+
+
+def time_shape(A, qkv, ids, run_shape, layers, window=None):
+    """(fwd ms, fwd+bwd ms) of `layers` chained attention calls."""
+    import jax
+
+    chain = chained(A, run_shape, layers, window)
+
     def clock(fn):
-        jax.block_until_ready(fn(q, k, v))  # compiles
+        jax.block_until_ready(fn(*qkv, *ids))  # compiles
         t0 = time.perf_counter()
-        jax.block_until_ready(fn(q, k, v))
+        jax.block_until_ready(fn(*qkv, *ids))
         reps = max(1, int(0.05 / (time.perf_counter() - t0)))
         best = float("inf")
         for _ in range(3):
             t0 = time.perf_counter()
             for _ in range(reps):
-                out = fn(q, k, v)
+                out = fn(*qkv, *ids)
             jax.block_until_ready(out)
             best = min(best, (time.perf_counter() - t0) / reps)
         return best * 1e3
@@ -148,7 +199,43 @@ def time_shape(rows, t, run_shape, hq, hkv, hd, layers, window=None,
     return clock(jax.jit(chain)), clock(jax.jit(jax.grad(chain, (0, 1, 2))))
 
 
-def check_shape(rows, t, run_shape, hq, hkv, hd, window=None, seq_len=None):
+def kernel_ops(A, qkv, run_shape, layers, window=None, reps=3):
+    """ids -> {attention kernel's name: ms a layer} from a trace of
+    `reps` calls of forward + backward through `layers` chained calls;
+    one program for every (segment ids, positions)."""
+    import tempfile
+
+    import jax
+
+    from benchmark import trace_reduce
+
+    fn = jax.jit(jax.grad(chained(A, run_shape, layers, window), (0, 1, 2)))
+
+    def traced(ids):
+        jax.block_until_ready(fn(*qkv, *ids))
+        with tempfile.TemporaryDirectory() as d:
+            with jax.profiler.trace(d):
+                for _ in range(reps):
+                    jax.block_until_ready(fn(*qkv, *ids))
+            got = trace_reduce.reduce_trace(
+                trace_reduce.load_xplane(trace_reduce.find_xplane(d)), top=40)
+        return {name: 1e3 * s / (reps * layers) for name, s in got["device_ops"]
+                if trace_reduce.categorize(name) == "attention"}
+
+    return traced
+
+
+def walked(A, ids, hq, hkv, window):
+    """(grid steps, live steps) a q head, the forward kernel once, by
+    the tree's own host rule."""
+    import numpy as np
+
+    steps, live = A.attn_grid_steps("splash", np.asarray(ids[0]), hq, hkv,
+                                    window=window)[:2]
+    return int(steps), int(live)
+
+
+def check_shape(A, qkv, ids, run_shape, window=None):
     """Largest error of the call's output and of its q, k, v gradients
     against `reference_packed_attention`'s (float32 from the same bf16
     inputs), each as a share of the reference's largest value."""
@@ -156,18 +243,10 @@ def check_shape(rows, t, run_shape, hq, hkv, hd, window=None, seq_len=None):
     import jax.numpy as jnp
     import numpy as np
 
-    from areal_tpu.ops.attention import (
-        reference_packed_attention,
-        splash_packed_attention,
-    )
-
-    rng = np.random.RandomState(1)
-    q, k, v = (jnp.asarray(rng.randn(rows, t, h, hd), jnp.bfloat16)
-               for h in (hq, hkv, hkv))
-    seg, pos = packed_rows(rows, t, seq_len)
-    real = jnp.asarray(seg > 0)[..., None, None]
-    seg, pos = jnp.asarray(seg), jnp.asarray(pos)
-    dout = jnp.asarray(rng.randn(rows, t, hq, hd), jnp.float32) * real
+    seg, pos = ids
+    real = (seg > 0)[..., None, None]
+    dout = jnp.asarray(np.random.RandomState(1).randn(
+        *qkv[0].shape[:-1], qkv[2].shape[-1]), jnp.float32) * real
 
     def run(attend):
         def loss(q, k, v):
@@ -175,13 +254,13 @@ def check_shape(rows, t, run_shape, hq, hkv, hd, window=None, seq_len=None):
             return jnp.sum(out * dout), out
 
         (_, out), grads = jax.jit(jax.value_and_grad(
-            loss, (0, 1, 2), has_aux=True))(q, k, v)
+            loss, (0, 1, 2), has_aux=True))(*qkv)
         return [np.asarray(a, np.float32) for a in (out, *grads)]
 
-    got = run(lambda q, k, v: splash_packed_attention(
+    got = run(lambda q, k, v: A.splash_packed_attention(
         q, k, v, seg, pos, _run_shape=run_shape, window=window))
     want = run(lambda q, k, v: jax.vmap(
-        lambda q, k, v, s, p: reference_packed_attention(
+        lambda q, k, v, s, p: A.reference_packed_attention(
             q, k, v, s, p, window=window))(q, k, v, seg, pos))
     return {name: float(np.abs(a - b).max() / np.abs(b).max())
             for name, a, b in zip(("out", "dq", "dk", "dv"), got, want)}
@@ -189,11 +268,14 @@ def check_shape(rows, t, run_shape, hq, hkv, hd, window=None, seq_len=None):
 
 def sweep(args):
     import jax
+    import jax.numpy as jnp
 
     if jax.default_backend() != "tpu":
         sys.exit("splash_shape_sweep times the compiled kernel: needs a TPU")
     from areal_tpu.ops.attention import splash_run_shape
 
+    hkv = args.hq // args.group if args.group else args.hkv
+    v_dim = args.v_dim or args.hd
     shapes = [tuple(int(x) for x in s.split("x")) for s in args.shapes.split(",")]
     plan, seen = [], set()
     for rows, t in shapes:
@@ -205,7 +287,11 @@ def sweep(args):
                 plan.append((rows, t, c))
     # Today's shapes first, so a run cut short still has the baseline.
     plan.sort(key=lambda p: p[2] != today(p[1]))
-    variant(args)
+    trees = [("here", attention_module())]
+    if args.parent:
+        trees.append(("parent", attention_module(args.parent)))
+    for _, A in trees:
+        variant(args, A)
     began = time.monotonic()
     os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
     with open(args.out, "a" if args.append else "w") as f:
@@ -213,26 +299,37 @@ def sweep(args):
             if time.monotonic() - began > args.max_seconds:
                 print(f"stopped at {i} of {len(plan)}: --max-seconds", flush=True)
                 break
-            row = dict(rows=rows, t=t, t_run=c[0], bq=c[1], bkv=c[2], bkvc=c[3],
-                       hq=args.hq, hkv=args.hkv, hd=args.hd, layers=args.layers,
-                       window=args.window, device=jax.devices()[0].device_kind,
-                       unfused=args.unfused, fused=args.fused,
-                       static=args.static, width=args.width,
-                       seq_len=args.seq_len)
-            try:
-                if args.check:
-                    row["rel_err"] = check_shape(
-                        rows, t, c, args.hq, args.hkv, args.hd, args.window,
-                        args.seq_len)
-                else:
-                    row["fwd_ms"], row["grad_ms"] = time_shape(
-                        rows, t, c, args.hq, args.hkv, args.hd, args.layers,
-                        args.window, args.seq_len)
-            except Exception as e:  # a block the compiler refuses is a result
-                row["error"] = f"{type(e).__name__}: {str(e)[:300]}"
-            f.write(json.dumps(row) + "\n")
-            f.flush()
-            print(json.dumps(row), flush=True)
+            qkv = inputs(rows, t, args.hq, hkv, args.hd, v_dim, seed=0)
+            if args.rows_from:
+                assert rows == 1, "--rows-from packs one row a micro-batch"
+                pool = [(a, b) for a, b in zip(*pool_rows(args.rows_from, t))]
+            else:
+                pool = [packed_rows(rows, t, args.seq_len)]
+            pool = [tuple(jnp.asarray(a) for a in ids) for ids in pool]
+            for tree, A in trees:
+                ops = kernel_ops(A, qkv, c, args.layers, args.window) if args.ops else None
+                for at, ids in enumerate(pool):
+                    row = dict(rows=rows, t=t, t_run=c[0], bq=c[1], bkv=c[2], bkvc=c[3],
+                               hq=args.hq, hkv=hkv, hd=args.hd, v_dim=v_dim,
+                               layers=args.layers, window=args.window,
+                               device=jax.devices()[0].device_kind, tree=tree,
+                               static=args.static,
+                               seq_len=args.seq_len, rows_from=args.rows_from, row=at)
+                    try:
+                        if args.check:
+                            row["rel_err"] = check_shape(A, qkv, ids, c, args.window)
+                        elif args.ops:
+                            row["steps"], row["live"] = walked(
+                                A, ids, args.hq, hkv, args.window)
+                            row["ops_ms"] = ops(ids)
+                        else:
+                            row["fwd_ms"], row["grad_ms"] = time_shape(
+                                A, qkv, ids, c, args.layers, args.window)
+                    except Exception as e:  # a block the compiler refuses is a result
+                        row["error"] = f"{type(e).__name__}: {str(e)[:300]}"
+                    f.write(json.dumps(row) + "\n")
+                    f.flush()
+                    print(json.dumps(row), flush=True)
 
 
 def fit(path):
@@ -281,14 +378,18 @@ def main():
     ap.add_argument("--layers", type=int, default=12)
     ap.add_argument("--window", type=int, default=None,
                     help="time a window layer (LocalMask), not a causal one")
-    ap.add_argument("--unfused", action="store_true",
-                    help="splash's dq and dkv kernels in every call")
-    ap.add_argument("--fused", action="store_true",
-                    help="the fused backward (static tables) in every call")
+    ap.add_argument("--group", type=int, default=None,
+                    help="q heads a kv head (sets the kv heads: hq // group)")
+    ap.add_argument("--v-dim", type=int, default=None,
+                    help="v's head size, where it is not q's and k's")
     ap.add_argument("--static", action="store_true",
-                    help="a row alone keeps its static tables")
-    ap.add_argument("--width", type=lambda s: [int(x) for x in s.split(",")],
-                    help="W,Wq: one width for a row's compacted tables")
+                    help="a row alone keeps splash's static kernels")
+    ap.add_argument("--rows-from", metavar="TRAFFIC", default=None,
+                    help="pack the rows as a benchmark pool's micro-batches")
+    ap.add_argument("--ops", action="store_true",
+                    help="a traced call's attention kernels, ms a layer each")
+    ap.add_argument("--parent", metavar="DIR", default=None,
+                    help="time the checkout at DIR as well")
     ap.add_argument("--seq-len", type=int, default=None,
                     help="pack the rows with sequences this long")
     ap.add_argument("--check", action="store_true",

@@ -371,6 +371,49 @@ def _layout_rows(T, hq, hkv, hd):
     return q, k, v, jnp.asarray(seg), jnp.asarray(pos)
 
 
+def _static_unfused(t, bq, bkv, bkvc, group, window):
+    """jax's own splash kernel over its static tables with the dq and
+    dkv kernels (not the fused backward): what a step of the run-time
+    kernels computes, pair for pair."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import (
+        splash_attention_kernel as sk,
+        splash_attention_mask as sm,
+    )
+
+    one = (sm.CausalMask((t, t)) if window is None else
+           sm.LocalMask((t, t), window_size=(window - 1, 0), offset=0))
+    return sk.make_splash_mqa_single_device(
+        mask=sm.MultiHeadMask([one] * group), interpret=True,
+        block_sizes=sk.BlockSizes(
+            block_q=bq, block_kv=bkv, block_kv_compute=bkvc, block_q_dkv=bq,
+            block_kv_dkv=bkv, block_kv_dkv_compute=bkvc, block_q_dq=bq,
+            block_kv_dq=bkv, use_fused_bwd_kernel=False))
+
+
+def _static_unfused_attention(q, k, v, seg, run_shape, window):
+    """One row [T, H, hd] through `_static_unfused`, as `_splash_row`
+    lays it out."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import splash_attention_kernel as sk
+
+    t, hq, hd = q.shape
+    hkv = k.shape[1]
+    kernel = _static_unfused(*run_shape, hq // hkv, window)
+    assert run_shape[0] == t
+    q = q * jnp.asarray(hd ** -0.5, q.dtype)
+    qh = q.transpose(1, 0, 2).reshape(hkv, hq // hkv, t, hd)
+    ids = sk.SegmentIds(q=seg, kv=seg)
+    out = jax.vmap(lambda qq, kk, vv: kernel(qq, kk, vv, ids))(
+        qh, k.transpose(1, 0, 2), v.transpose(1, 0, 2))
+    return out.reshape(hq, t, v.shape[-1]).transpose(1, 0, 2)
+
+
+def _watch_pair_lists(A, monkeypatch):
+    """The calls of `_pair_lists`, as they are made."""
+    calls, keep = [], A._pair_lists
+    monkeypatch.setattr(A, "_pair_lists", lambda *a: calls.append(a) or keep(*a))
+    return calls
+
+
 @pytest.mark.parametrize("rows", ["one_by_one", "vmap"])
 @pytest.mark.parametrize("window", [None, 200], ids=["causal", "window"])
 @pytest.mark.parametrize("run_shape", [
@@ -378,10 +421,13 @@ def _layout_rows(T, hq, hkv, hd):
 def test_splash_run_time_block_mask_is_the_static_kernel_at_real_positions(
         run_shape, window, rows, monkeypatch):
     """Rows of different layouts, each given alone as `[1, T, ..]` (one
-    after another, or under a caller's `vmap`: a table a row), with the
-    block pairs of no sequence skipped: outputs at real positions and dq,
-    dk, dv equal to the static-mask kernel's, not to a tolerance; finite
-    where the row is padding; and the reference's."""
+    after another, or under a caller's `vmap`: a list a row, pallas's
+    own loop over the kernel calls), walking the live pairs of their
+    lists and no others: outputs at real positions and dq equal to those
+    of splash's static kernels (forward, dq, dkv), not to a tolerance,
+    dk and dv to float32's rounding (a kv block's q heads are summed
+    pair by pair, not head by head); finite where the row is padding;
+    and the reference's."""
     from areal_tpu.ops import attention as A
 
     R, T, hq, hkv, hd = 3, 768, 4, 2, 32
@@ -404,18 +450,16 @@ def test_splash_run_time_block_mask_is_the_static_kernel_at_real_positions(
     splash = lambda *a: A.splash_packed_attention(
         *(x[None] for x in a), interpret=True, _run_shape=run_shape, window=window)[0]
     monkeypatch.setattr(A, "_SKIP_MIN_LEN", 0)  # a row this short would not skip
-    skipped = []
-    keep = A._with_tables
-    monkeypatch.setattr(A, "_with_tables",
-                        lambda kernel, tables: skipped.append(tables) or keep(kernel, tables))
+    walked = _watch_pair_lists(A, monkeypatch)
     got, g_got = run(splash, rows)
-    assert skipped  # the run-time tables are what ran
-    monkeypatch.setattr(A, "_with_tables", lambda kernel, tables: kernel)
-    static, g_static = run(splash)
+    assert walked  # the run-time lists are what ran
+    static, g_static = run(lambda q, k, v, seg, pos: _static_unfused_attention(
+        q, k, v, seg, run_shape, window))
     assert np.isfinite(got).all()
     np.testing.assert_array_equal(got[real], static[real])
-    for a, b, name in zip(g_got, g_static, "qkv"):
-        np.testing.assert_array_equal(a, b, err_msg=name)
+    np.testing.assert_array_equal(g_got[0], g_static[0], err_msg="q")
+    for a, b, name in zip(g_got[1:], g_static[1:], "kv"):
+        np.testing.assert_allclose(a, b, atol=2e-6, rtol=2e-6, err_msg=name)
     ref, g_ref = run(lambda *a: A.reference_packed_attention(*a, window=window))
     np.testing.assert_allclose(got[real], ref[real], atol=2e-5, rtol=2e-5)
     for a, b, name in zip(g_got, g_ref, "qkv"):
@@ -430,10 +474,7 @@ def test_only_a_long_row_alone_skips_by_its_segment_ids(monkeypatch):
 
     assert A._rows_skip(1, 16384) and A._rows_skip(1, 2048)
     assert not A._rows_skip(1, 1536) and not A._rows_skip(3, 6144)
-    called = []
-    keep = A._with_tables
-    monkeypatch.setattr(A, "_with_tables",
-                        lambda kernel, tables: called.append(tables) or keep(kernel, tables))
+    called = _watch_pair_lists(A, monkeypatch)
     q, k, v, seg, pos = _layout_rows(768, 4, 2, 32)
     run = lambda *a: A.splash_packed_attention(*a, interpret=True)
     short = run(q[:1], k[:1], v[:1], seg[:1], pos[:1])
@@ -450,6 +491,27 @@ def test_only_a_long_row_alone_skips_by_its_segment_ids(monkeypatch):
     np.testing.assert_array_equal(np.asarray(one[0])[real], np.asarray(rows[0])[real])
 
 
+def _check_pair_list(lst, n, pairs, major):
+    """`lst` walks just `pairs` [nq, nkv], each once, `major`-major in
+    ascending order, its flags right, and past `n` nothing new."""
+    from areal_tpu.ops.pallas.splash_pairs import FIRST, LAST
+
+    qi, ki, flags = (np.asarray(a) for a in lst)
+    assert qi.dtype == ki.dtype == flags.dtype == np.int32
+    assert n == pairs.sum() and qi.shape == ki.shape == flags.shape and n <= len(qi)
+    want = np.argwhere(pairs if major == "q" else pairs.T)  # row-major: ascending
+    want = want if major == "q" else want[:, ::-1]
+    np.testing.assert_array_equal(np.stack([qi[:n], ki[:n]], 1), want)
+    assert (qi[n:] == qi[n - 1]).all() and (ki[n:] == ki[n - 1]).all()  # in bounds
+    run = (qi if major == "q" else ki)[:n]
+    edge = np.flatnonzero(np.diff(run)) + 1
+    first = np.zeros(n, bool)
+    first[np.r_[0, edge]] = True
+    last = np.zeros(n, bool)
+    last[np.r_[edge - 1, n - 1]] = True
+    np.testing.assert_array_equal(flags[:n], FIRST * first + LAST * last)
+
+
 @pytest.mark.parametrize("window", [None, 2048], ids=["causal", "window"])
 @pytest.mark.parametrize("t,lens", [
     (2048, [700, 500, 300]), (3712, [1500, 900, 1000]), (8192, [3000, 900]),
@@ -458,10 +520,11 @@ def test_host_counts_are_the_device_block_tables(t, lens, window):
     """`attn_block_cells` and `attn_grid_steps` (the engine's
     `train.attn_active_cells`, `train.attn_grid_steps`,
     `train.attn_live_steps` and the span's `width`) count on the host
-    what the kernels walk on the device: the tables `_block_tables` makes
-    at the width it picks, their sizes the steps, their non-zero
-    `block_mask` the pairs that run; and the tables name just the pairs
-    the static mask and the row's sequences leave, each once, in order."""
+    what the kernels walk on the device: the lists `_pair_lists` makes,
+    `n` steps in each of the forward, dq and dkv kernels, every one a
+    pair that runs; and the lists name just the pairs the static mask
+    and the row's sequences leave, each once, in order, their capacity
+    the static mask's pairs."""
     from areal_tpu.ops import attention as A
 
     seg = _row(t, lens)
@@ -470,43 +533,23 @@ def test_host_counts_are_the_device_block_tables(t, lens, window):
     assert A._rows_skip(1, t_run)
     padded = np.pad(seg, (0, t_run - t))
     win = A._row_window(t, window)
-    widths = A._table_widths(t_run, bq, bkv, win)
-    index, tables = A._block_tables(jnp.asarray(padded), bq, bkv, win)
-    index = int(index)
-    assert len(tables) == len(widths) <= 3 and sorted(widths) == list(widths)
+    lists = A._pair_lists(jnp.asarray(padded), bq, bkv, win)
+    n = int(lists.n)
     pairs = A.live_block_pairs(padded, bq, bkv) & A._static_block_pairs(t_run, bq, bkv, win)
-    need = (pairs.sum(axis=1).max(), pairs.sum(axis=0).max())
-    # the narrowest width that holds the row
-    assert all(n <= w for n, w in zip(need, widths[index]))
-    assert index == 0 or any(n > w for n, w in zip(need, widths[index - 1]))
-    for (w, wq), (fwd_mask, fwd_next, dkv_mask, dkv_next) in zip(widths, tables):
-        assert fwd_mask.shape == fwd_next.shape == (1, nq, w)
-        assert dkv_mask.shape == dkv_next.shape == (1, wq, nkv)
-        assert 0 <= int(fwd_next.min()) and int(fwd_next.max()) < nkv
-        assert 0 <= int(dkv_next.min()) and int(dkv_next.max()) < nq
-    fwd_mask, fwd_next, dkv_mask, dkv_next = (np.asarray(a)[0] for a in tables[index])
-    for runs, named in ((fwd_mask > 0, fwd_next), (dkv_mask.T > 0, dkv_next.T)):
-        # a row's live blocks at the front, ascending, each once
-        assert (runs[:, :-1] >= runs[:, 1:]).all()
-        assert all((np.diff(n[r]) > 0).all() for r, n in zip(runs, named))
-    got = np.zeros_like(pairs)
-    got[np.nonzero(fwd_mask)[0], fwd_next[fwd_mask > 0]] = True
-    np.testing.assert_array_equal(got, pairs)
-    got = np.zeros_like(pairs)
-    got[dkv_next[dkv_mask > 0], np.nonzero(dkv_mask)[1]] = True
-    np.testing.assert_array_equal(got, pairs)
+    active, widest = A._active_block_pairs(t_run, bq, bkv, win)
+    assert nq <= n <= active == len(lists.q_major.q) == len(lists.kv_major.kv)
+    _check_pair_list(lists.q_major, n, pairs, "q")
+    _check_pair_list(lists.kv_major, n, pairs, "kv")
 
     ran, causal = A.attn_block_cells("splash", seg[None], 4, 2, window=window)
-    assert ran == int(pairs.sum()) * bq * bkv
+    assert ran == n * bq * bkv
     assert causal == A._active_block_pairs(t_run, bq, bkv)[0] * bq * bkv
     skips = len(lens) > 1 or lens[0] < t or win is not None
     assert (ran < causal) if skips else (ran == causal)
     steps, live, width = A.attn_grid_steps("splash", seg[None], 4, 2, window=window)
-    assert steps == 2 * fwd_mask.size + dkv_mask.size  # forward, dq, dkv
-    assert live == 2 * int((fwd_mask > 0).sum()) + int((dkv_mask > 0).sum()) == 3 * pairs.sum()
-    assert width == widths[index][0]
+    assert steps == live == 3 * n  # forward, dq, dkv: no step without a pair
+    assert width == pairs.sum(axis=1).max()
     # several rows in one call keep the static kernels; so does a short row
-    active, widest = A._active_block_pairs(t_run, bq, bkv, win)
     assert A.attn_block_cells("splash", np.stack([seg, seg]), 4, 2, window=window) == (
         2 * active * bq * bkv, 2 * causal)
     assert A.attn_grid_steps("splash", np.stack([seg, seg]), 4, 2, window=window) == (
@@ -517,69 +560,84 @@ def test_host_counts_are_the_device_block_tables(t, lens, window):
     assert A.attn_grid_steps("reference", seg[None], 4, 2, window=window) == (0, 0, 0)
 
 
-def test_compacted_tables_of_a_whole_row_are_the_static_shrunk_ones():
+@pytest.mark.parametrize("window", [None, 200, 513], ids=["causal", "w200", "w513"])
+@pytest.mark.parametrize("bq,bkv", [(128, 256), (256, 128)])
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_pair_lists_walk_every_live_pair_once_in_order(layout, bq, bkv, window):
+    """Over every layout of the rule's own test: the lists against
+    `live_block_pairs` AND the static mask; `n` at least the q blocks
+    (an all-padding row: just its diagonal)."""
+    from areal_tpu.ops import attention as A
+
+    seg = LAYOUTS[layout]
+    t = len(seg)
+    lists = A._pair_lists(jnp.asarray(seg), bq, bkv, window)
+    pairs = A.live_block_pairs(seg, bq, bkv) & A._static_block_pairs(t, bq, bkv, window)
+    n = int(lists.n)
+    assert n >= t // bq and (layout != "all_padding" or n == A._diagonal_block_pairs(
+        t, bq, bkv).sum())
+    _check_pair_list(lists.q_major, n, pairs, "q")
+    _check_pair_list(lists.kv_major, n, pairs, "kv")
+
+
+@pytest.mark.parametrize("window", [None, 700], ids=["causal", "window"])
+def test_pair_lists_of_a_whole_row_are_the_static_tables(window):
     """One sequence from end to end leaves every pair of the static
-    mask: at the widest width the compacted tables run the steps splash's
-    own shrunk static tables run and load the blocks they load, forward,
-    dq and dkv, causal and under a window."""
+    mask: the lists are full (`n` their capacity) and name the steps
+    splash's own shrunk static tables run and the blocks they load,
+    forward, dq and dkv."""
     from areal_tpu.ops import attention as A
 
     t, bq, bkv = 4096, 256, 512
-    for window in (None, 700):
-        static = A._splash_kernel(t, bq, bkv, bkv, 1, interpret=True, window=window,
-                                  fused_bwd=False)
-        index, tables = A._block_tables(jnp.asarray(_row(t, [t])), bq, bkv, window)
-        assert int(index) == len(tables) - 1
-        kernel = A._with_tables(static, tables[-1])
-        for name in ("fwd_mask_info", "dq_mask_info"):
-            was, info = getattr(static, name), getattr(kernel, name)
-            assert info.block_mask.dtype == was.block_mask.dtype
-            assert info.data_next.dtype == was.data_next.dtype
-            assert info.block_mask.shape == was.block_mask.shape == info.data_next.shape
-            run = np.asarray(was.block_mask) > 0
-            np.testing.assert_array_equal(np.asarray(info.block_mask) > 0, run)
-            np.testing.assert_array_equal(np.asarray(info.data_next)[run],
-                                          np.asarray(was.data_next)[run])
-        # dkv: splash leaves a kv block's q blocks where they stand and
-        # cuts the steps no kv block uses; compacted, they start at step 0
-        was, info = static.dkv_mask_info, kernel.dkv_mask_info
-        assert info.block_mask.dtype == was.block_mask.dtype
-        assert info.data_next.dtype == was.data_next.dtype
-        assert info.block_mask.shape == was.block_mask.shape == info.data_next.shape
-        pairs = []
-        for one in (was, info):
-            run = np.asarray(one.block_mask)[0] > 0
-            pairs.append(sorted(zip(np.asarray(one.data_next)[0][run], np.nonzero(run)[1])))
-        assert pairs[0] == pairs[1]
+    static = _static_unfused(t, bq, bkv, bkv, 1, window)
+    lists = A._pair_lists(jnp.asarray(_row(t, [t])), bq, bkv, window)
+    assert int(lists.n) == len(lists.q_major.q)
+    for info, lst, axes in ((static.fwd_mask_info, lists.q_major, (0, 1)),
+                            (static.dq_mask_info, lists.q_major, (0, 1)),
+                            (static.dkv_mask_info, lists.kv_major, (1, 0))):
+        run = np.asarray(info.block_mask)[0] > 0
+        named = np.asarray(info.data_next)[0][run]  # the kv block; in dkv the q block
+        at = np.nonzero(run)[axes[0]]  # the q block the step belongs to; in dkv the kv block
+        mine = (lst.q, lst.kv) if axes == (0, 1) else (lst.kv, lst.q)
+        assert sorted(zip(at, named)) == sorted(zip(*(np.asarray(a) for a in mine)))
 
 
-# lens, window, (hq, hkv, head size of q and k, of v), the width's index
-COMPACTED = {
-    "one_sequence": ([2048], None, (4, 2, 32, 32), 2),
-    "many_short": ([100] * 18, None, (4, 2, 32, 32), 0),
-    "wider_than_the_narrowest": ([700, 300, 600], None, (4, 2, 32, 32), 1),
-    "padded_tail": ([500, 400], None, (4, 2, 32, 32), 1),
-    "window": ([900, 700], 300, (4, 2, 32, 32), 1),
-    "window_many_short": ([100] * 18, 300, (4, 2, 32, 32), 0),
-    "heads_of_64_against_128": ([700, 300, 600], None, (4, 2, 64, 128), 1),
-    "gqa_group_of_16": ([100] * 18, None, (16, 1, 32, 32), 0),
+# lens, window, (hq, hkv, head size of q and k, of v)
+PAIRS = {
+    "one_sequence": ([2048], None, (4, 2, 32, 32)),
+    "128_short": ([16] * 128, None, (4, 2, 32, 32)),
+    "all_padding": ([], None, (4, 2, 32, 32)),
+    "padded_tail": ([500, 400], None, (4, 2, 32, 32)),
+    "three_sequences": ([700, 300, 600], None, (4, 2, 32, 32)),
+    "one_token": ([1], None, (2, 2, 32, 32)),
+    "window": ([900, 700], 300, (4, 2, 32, 32)),
+    "window_one_sequence": ([2048], 300, (4, 2, 32, 32)),
+    "window_128_short": ([16] * 128, 300, (4, 2, 32, 32)),
+    "window_all_padding": ([], 300, (2, 1, 32, 32)),
+    "heads_of_128": ([700, 300, 600], None, (2, 1, 128, 128)),
+    "heads_of_64_against_128": ([700, 300, 600], None, (4, 2, 64, 128)),
+    "heads_of_192_against_128": ([700, 300, 600], None, (2, 2, 192, 128)),
+    "window_heads_of_192_against_128": ([900, 700], 300, (2, 2, 192, 128)),
+    "gqa_group_of_16": ([100] * 18, None, (16, 1, 32, 32)),
+    "gqa_group_of_8": ([100] * 18, None, (8, 1, 32, 32)),
+    "gqa_group_of_6": ([700, 300, 600], None, (12, 2, 32, 32)),
+    "gqa_group_of_1": ([100] * 18, None, (3, 3, 32, 32)),
 }
 
 
-@pytest.mark.parametrize("case", COMPACTED)
+@pytest.mark.parametrize("case", PAIRS)
 def test_a_row_alone_walks_its_live_pairs(case, monkeypatch):
-    """A row of 2,048 alone in its call, dq and dkv in kernels of their
-    own over compacted tables, the width a branch taken at run time (the
-    case says which): the output at real positions is the static
-    kernel's to the bit, and the output and the q, k, v gradients are
-    the einsum reference's to 2e-5 in float32."""
+    """A row of 2,048 alone in its call through the repo's own forward,
+    dq and dkv kernels over the row's lists of pairs, the grid as long
+    as the row's live pairs: the output at real positions is the static
+    fused-backward kernel's to the bit, and the output and the q, k, v
+    gradients are the einsum reference's to 2e-5 in float32; finite
+    everywhere, padding too (every q block has its diagonal pair)."""
     from areal_tpu.ops import attention as A
 
-    lens, window, (hq, hkv, hd, hd_v), want = COMPACTED[case]
+    lens, window, (hq, hkv, hd, hd_v) = PAIRS[case]
     t, run_shape = 2048, (2048, 128, 256, 128)
     seg = _row(t, lens)
-    index, tables = A._block_tables(jnp.asarray(seg), 128, 256, window)
-    assert int(index) == want and (len(tables) == 3 if window is None else 2)
     rng = np.random.RandomState(7)
     q, k, v = (jnp.asarray(rng.randn(1, t, h, d).astype(np.float32))
                for h, d in ((hq, hd), (hkv, hd), (hkv, hd_v)))
@@ -597,16 +655,54 @@ def test_a_row_alone_walks_its_live_pairs(case, monkeypatch):
 
     splash = lambda q, k, v: A.splash_packed_attention(
         q, k, v, ids, at, interpret=True, _run_shape=run_shape, window=window)
+    walked = _watch_pair_lists(A, monkeypatch)
     got, g_got = run(splash)
-    assert got.shape == (t, hq, hd_v) and np.isfinite(got).all()
+    assert walked and got.shape == (t, hq, hd_v) and np.isfinite(got).all()
+    assert all(np.isfinite(g).all() for g in g_got)
     ref, g_ref = run(lambda q, k, v: A.reference_packed_attention(
         q[0], k[0], v[0], ids[0], at[0], window=window)[None])
     np.testing.assert_allclose(got[real], ref[real], atol=2e-5, rtol=2e-5)
     for a, b, name in zip(g_got, g_ref, "qkv"):
         np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5, err_msg=name)
-    monkeypatch.setattr(A, "_with_tables", lambda kernel, tables: kernel)
+    monkeypatch.setattr(A, "_rows_skip", lambda rows, t_run: False)
     static, _ = run(splash)
     np.testing.assert_array_equal(got[real], static[real])
+
+
+def test_a_shard_with_one_long_row_walks_its_live_pairs(monkeypatch):
+    """`sharded_splash_attention` over a mesh of two: each shard's call
+    holds one row of 2,048, so each walks its own list of pairs (the
+    two rows' layouts differ), forward and backward, inside `shard_map`:
+    the einsum reference's output and gradients."""
+    from areal_tpu.base.topology import MeshSpec
+    from areal_tpu.ops import attention as A
+    from areal_tpu.parallel.mesh import make_mesh
+
+    t, hq, hkv, hd = 2048, 4, 2, 32
+    seg = np.stack([_row(t, [700, 300, 600]), _row(t, [2048])])
+    rng = np.random.RandomState(11)
+    q, k, v = (jnp.asarray(rng.randn(2, t, h, hd).astype(np.float32)) for h in (hq, hkv, hkv))
+    real = seg > 0
+    dout = jnp.asarray(rng.randn(2, t, hq, hd).astype(np.float32) * real[..., None, None])
+    ids, at = jnp.asarray(seg), jnp.broadcast_to(jnp.arange(t), (2, t))
+    mesh = make_mesh(MeshSpec(data=2), jax.devices()[:2])
+    walked = _watch_pair_lists(A, monkeypatch)
+
+    def run(fn):
+        loss = lambda q, k, v: (lambda out: (jnp.sum(out * dout), out))(fn(q, k, v))
+        (_, out), grads = jax.value_and_grad(loss, (0, 1, 2), has_aux=True)(q, k, v)
+        return np.asarray(out), [np.asarray(g) for g in grads]
+
+    got, g_got = run(lambda q, k, v: A.sharded_splash_attention(
+        q, k, v, ids, at, mesh, interpret=True))
+    assert walked
+    ref, g_ref = run(lambda q, k, v: jax.vmap(A.reference_packed_attention)(q, k, v, ids, at))
+    np.testing.assert_allclose(got[real], ref[real], atol=2e-5, rtol=2e-5)
+    for a, b, name in zip(g_got, g_ref, "qkv"):
+        np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5, err_msg=name)
+    assert A.attn_grid_steps("splash", seg, hq, hkv, mesh=mesh)[:2] == (
+        A.attn_grid_steps("splash", seg[:1], hq, hkv)[0]
+        + A.attn_grid_steps("splash", seg[1:], hq, hkv)[0],) * 2
 
 
 # sha256 of str(jaxpr) of the backward pass of one call, taken at the

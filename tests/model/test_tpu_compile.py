@@ -43,15 +43,16 @@ def _shape(shape, dtype, sharding):
 @pytest.mark.parametrize("rows", [1, 3, "vmap"], ids=["row", "rows", "rows_vmap"])
 @pytest.mark.parametrize("window", [2048, None], ids=["window", "full"])
 def test_splash_row_of_16k_at_32_over_4_heads_compiles_forward_and_backward(one_chip, window, rows):
-    """The segment ids are arguments, so the block tables a long row
-    alone walks (`ops/attention._block_tables`) are values of the run:
-    scalar-prefetch operands that are traced, compacted to one of a few
-    widths, each a branch of one program, and its backward is splash's
-    dq kernel and dkv kernel: no `[kv blocks, heads, t, hd]` partials of
-    dq (537 MB here) and no sum over them. Three rows in one call keep
-    the static kernels and the fused backward; a caller's `vmap` over
-    rows each given alone is a table a row, pallas's own loop over the
-    kernel calls and every width run (a select picks)."""
+    """The segment ids are arguments, so the list of block pairs a long
+    row alone walks (`ops/attention._pair_lists`) and its length are
+    values of the run: scalar-prefetch operands that are traced, and a
+    grid dimension that is dynamic, in the repo's own forward, dq and
+    dkv kernels (`ops/pallas/splash_pairs.py`): three custom calls, no
+    branch over widths, no `[kv blocks, heads, t, hd]` partials of dq
+    (537 MB here) and no sum over them. Three rows in one call keep
+    splash's static kernels and the fused backward; a caller's `vmap`
+    over rows each given alone is a list a row: pallas's own loop over
+    the kernel calls."""
     from areal_tpu.ops.attention import splash_packed_attention
 
     t, hq, hkv, hd = 8192, 32, 4, 128  # half the longest row: a quicker compile
@@ -73,24 +74,47 @@ def test_splash_row_of_16k_at_32_over_4_heads_compiles_forward_and_backward(one_
     compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv, ids, ids).compile()
     text = compiled.as_text()
     # the forward and the fused backward kernel, or forward, dq and dkv
-    assert text.count("tpu_custom_call") >= (2 if rows == 3 else 3)
-    assert ("splash_mqa_dq" in text) == (rows != 3)
+    assert text.count("tpu_custom_call") == (2 if rows == 3 else 3)
+    assert ("splash_pairs_dq" in text) == (rows != 3) == ("splash_mqa" not in text)
     assert (" while(" in text) == (rows == "vmap")
-    assert (" sort(" in text) == (rows != 3)  # the tables' compaction
-    assert (" conditional(" in text) == (rows == 1)  # the widths
+    assert " conditional(" not in text  # no widths: the grid is as long as the list
     if rows == 1:
         assert f"bf16[{hkv},8,{hq // hkv},{t},{hd}]" not in text  # dq a kv block
-        assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9  # the parent's: 0.61e9
+        assert compiled.memory_analysis().temp_size_in_bytes < 0.3e9  # PR 34's: 0.61e9
+
+
+@pytest.mark.parametrize("hq,hkv,hd,hd_v,window", [
+    (32, 32, 192, 128, None), (16, 1, 128, 128, 2048), (12, 2, 128, 128, None)],
+    ids=["joyai_192_128", "group_16_window", "group_6"])
+def test_pair_kernels_compile_at_a_row_of_16k(one_chip, hq, hkv, hd, hd_v, window):
+    """The list-walking kernels at the longest row, 16,384 at the blocks
+    it runs at (512 x 1024): q and k of 192 against v of 128 (the joyai
+    cell's call), a group of 16 under a window, a group of 6."""
+    from areal_tpu.ops.attention import splash_packed_attention
+
+    t = 16384
+    q = _shape((1, t, hq, hd), jnp.bfloat16, one_chip)
+    k = _shape((1, t, hkv, hd), jnp.bfloat16, one_chip)
+    v = _shape((1, t, hkv, hd_v), jnp.bfloat16, one_chip)
+    ids = _shape((1, t), jnp.int32, one_chip)
+
+    def loss(q, k, v, seg, pos):
+        out = splash_packed_attention(q, k, v, seg, pos, window=window, interpret=False)
+        return out.astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, k, v, ids, ids).compile().as_text()
+    assert text.count("tpu_custom_call") == 3 and " conditional(" not in text
+    assert all(f"splash_pairs_{name}" in text for name in ("fwd", "dq", "dkv"))
 
 
 def test_a_train_step_of_one_row_holds_no_partial_of_dq(one_chip, monkeypatch):
     """A forward-backward micro-batch of `q15d12-train-ppo`'s model (12
     layers, 12 / 2 heads of 128, full remat, the masked loss head) at
-    one row of 8,192, as the engine's accumulate step runs it: twelve
-    kernels (forward, remat's forward, dq and dkv at three widths), the
-    widths branches of the one program, and nowhere the fused
-    backward's `[2, 8, 6, 8192, 128]` partials of dq nor the sum over
-    them that followed the kernel."""
+    one row of 8,192, as the engine's accumulate step runs it: four
+    kernels (forward, remat's forward, dq and dkv over the row's list
+    of pairs: twelve before PR 41, three widths each), no branch over
+    widths, and nowhere the fused backward's `[2, 8, 6, 8192, 128]`
+    partials of dq nor the sum over them that followed the kernel."""
     import json
     import re
 
@@ -114,8 +138,8 @@ def test_a_train_step_of_one_row_holds_no_partial_of_dq(one_chip, monkeypatch):
                                          scored=seg > 0).sum()
 
     text = jax.jit(jax.value_and_grad(loss)).lower(params, ids, ids, ids).compile().as_text()
-    assert text.count("tpu_custom_call") == 12 and "splash_mqa_dq" in text
-    assert " conditional(" in text
+    assert text.count("tpu_custom_call") == 4 and "splash_pairs_dq" in text
+    assert "splash_mqa" not in text and " conditional(" not in text
     assert "bf16[2,8,6,8192,128]" not in text
     assert not re.search(r"attn_kernel/[^\n\"]*_splash_attention[^\n\"]*/reduce_sum", text)
 
@@ -238,8 +262,8 @@ def test_selective_scan_kernels_compile_at_the_published_widths(one_chip):
 def test_splash_takes_q_and_k_at_64_against_v_at_128(one_chip, window):
     """Differential attention's one call: 40 q heads and 20 k heads of 64
     against 20 v heads of 128 (`head_dim_v`), a row of 8,192 alone in
-    its call, so the block tables are values of the run. Mosaic takes
-    the head size of 64 as it is: nothing is padded."""
+    its call, so it walks its list of pairs in the repo's own kernels.
+    Mosaic takes the head size of 64 as it is: nothing is padded."""
     from areal_tpu.ops.attention import splash_packed_attention
 
     t = 8192
@@ -254,7 +278,7 @@ def test_splash_takes_q_and_k_at_64_against_v_at_128(one_chip, window):
         return out.astype(jnp.float32).sum()
 
     text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, k, v, ids, ids).compile().as_text()
-    assert text.count("tpu_custom_call") >= 3 and "splash_mqa_dq" in text
+    assert text.count("tpu_custom_call") == 3 and "splash_pairs_dq" in text
 
 
 def test_splash_takes_q_and_k_at_192_against_v_at_128_and_a_group_of_one(one_chip):
@@ -275,5 +299,5 @@ def test_splash_takes_q_and_k_at_192_against_v_at_128_and_a_group_of_one(one_chi
         return out.astype(jnp.float32).sum()
 
     text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(qk, qk, v, ids, ids).compile().as_text()
-    assert text.count("tpu_custom_call") >= 3 and "splash_mqa_dq" in text
-    assert "bf16[32,1,8192,256]" not in text  # no padded copy of q
+    assert text.count("tpu_custom_call") == 3 and "splash_pairs_dq" in text
+    assert "bf16[32,8192,256]" not in text  # no padded copy of q
