@@ -140,10 +140,12 @@ def _kinds_label(cfg: TransformerConfig) -> str:
     `dense` or `attn.full.nope`. Differential attention says `diff.`, a
     layer that reads layer n's tensor `<n`, one that keeps its own `^`:
     `ssm+dense^`, `dense.diff.full.nope<5`, `gmu+dense<4`; latent
-    attention says `latent.`, and a prediction module after the stack
-    ends the label with `+mtp`."""
+    attention says `latent.`, attention over the keys an indexer chooses
+    `indexed.`, and a prediction module after the stack ends the label
+    with `+mtp`."""
     def name(k):
         attn = (f"{'diff.' if k.diff else ''}{'latent.' if k.latent else ''}"
+                f"{'indexed.' if k.indexed else ''}"
                 f"{'full' if k.window is None else 'w%d' % k.window}."
                 f"{'rope' if k.rotary else 'nope'}")
         keeps, reads = "^" if k.keeps else "", "" if k.reads is None else f"<{k.reads}"
@@ -202,7 +204,8 @@ class JaxTrainEngine(TrainEngine):
         # What `train.dispatch` says of the stack it runs: nothing for a
         # stack of one plain kind.
         self._stack_attrs: Dict[str, Any] = {}
-        if model_cfg.layer_kinds is not None or model_cfg.mla is not None:
+        if (model_cfg.layer_kinds is not None or model_cfg.mla is not None
+                or model_cfg.indexer is not None):
             windows = sorted({k.window for k in model_cfg.kinds()
                               if k.window is not None})
             self._stack_attrs = dict(window=windows[0] if windows else None,
@@ -216,6 +219,11 @@ class JaxTrainEngine(TrainEngine):
         mtp_on = self._mtp_weight > 0
         self._n_moe_layers = model_cfg.n_moe_layers + (mtp_on and last.mlp == "moe")
         self._mtp_attn = mtp_on and last.mixer == "attention"
+        # The indexers' share of a train step (models/config IndexerConfig):
+        # the weight of their KL loss, 0 = the step skips its pass.
+        self._n_indexed = model_cfg.n_indexed_layers
+        self._index_weight = (
+            float(model_cfg.indexer.loss_weight) if self._n_indexed else 0.0)
         # Pin AREAL_CE_CHUNK / AREAL_SPLASH_* now: retraces mid-run must
         # not mix tuning settings, and bad values must fail at init.
         from areal_tpu.ops import snapshot_env_tuning
@@ -429,6 +437,7 @@ class JaxTrainEngine(TrainEngine):
         is_critic = self.model_cfg.is_critic
         mtp = self._mtp_weight > 0
         mesh = self.mesh if self.mesh.size > 1 else None
+        sums = self.model_cfg.moe is not None or self._n_indexed > 0
 
         def compute(p, rows):
             out = model_forward(
@@ -436,10 +445,10 @@ class JaxTrainEngine(TrainEngine):
                 rows["input_ids"], rows["segment_ids"], rows["positions"],
                 attn_impl=self.attn_impl, remat=self.remat,
                 output="logits" if is_critic else "hidden",
-                return_aux=self.model_cfg.moe is not None,
-                mesh=mesh, mtp=mtp,
+                return_aux=sums,
+                mesh=mesh, mtp=mtp, index_loss=self._index_weight > 0,
             )
-            if self.model_cfg.moe is not None:
+            if sums:
                 out, moe_aux = out
             if mtp:
                 out, mtp_hidden = out
@@ -515,6 +524,23 @@ class JaxTrainEngine(TrainEngine):
                     # for the counters train.moe_pairs_held / train.moe_rows.
                     aux["sum:moe_pairs_held"] = moe_aux["pairs_held"]
                     aux["sum:moe_rows"] = moe_aux["rows_run"]
+            if self._n_indexed:
+                # The indexers' KL beside the caller's loss: the layers'
+                # mean of a sum over this micro-batch's real tokens. Its
+                # denominator is the step's count of real tokens, not the
+                # caller's of scored ones, and only the indexers'
+                # parameters have a gradient in it: `_optimizer_apply`
+                # divides their gradients by that count and the rest by
+                # the caller's (`inv_denom` holds both), and
+                # `_fetch_train_stats` reports the loss the same way.
+                kl = moe_aux["index_kl"] / self._n_indexed
+                aux = dict(aux)
+                aux["num:indexer_kl"] = kl
+                aux["den:indexer_kl"] = jnp.sum(
+                    rows["segment_ids"] > 0).astype(jnp.float32)
+                aux["num:indexer_selected"] = moe_aux["index_chosen"]
+                aux["den:indexer_selected"] = moe_aux["index_cells"]
+                loss_sum = loss_sum + self._index_weight * kl
             return loss_sum, aux
 
         return compute
@@ -525,8 +551,16 @@ class JaxTrainEngine(TrainEngine):
         unit LR, `p + lr * u` and the norm of what survived rounding."""
         with jax.named_scope("optimizer_apply"):
             weights = trainable(params)
-            grads = jax.tree_util.tree_map(
-                lambda g: g * inv_denom, trainable(grads))
+            if inv_denom.ndim:
+                # (the caller's loss's, the indexers' KL's): the two sets
+                # of parameters are disjoint (`_mb_loss_fn`)
+                grads = jax.tree_util.tree_map_with_path(
+                    lambda path, g: g * inv_denom[int(any(
+                        getattr(k, "key", None) == "indexer" for k in path))],
+                    trainable(grads))
+            else:
+                grads = jax.tree_util.tree_map(
+                    lambda g: g * inv_denom, trainable(grads))
             gnorm = optax_global_norm(grads)
             updates, opt_state = self.optimizer.update(grads, opt_state, weights)
             weights, unorm = apply_updates(weights, updates, lr)
@@ -891,6 +925,7 @@ class JaxTrainEngine(TrainEngine):
                 counts = [a[1:-1] + self._head_counts(rows, scored_fn)
                           + self._mtp_counts(rows, scored_fn)
                           + self._ssm_counts(rows["segment_ids"])
+                          + self._index_counts(rows)
                           for a, rows in zip(attn, stacks)]
                 self._count_batch(
                     "fused", len(mbs), sum(b.n_rows == 1 for b, _ in built),
@@ -909,7 +944,7 @@ class JaxTrainEngine(TrainEngine):
                 self.params, self.opt_state, packed, aux = step(
                     self.params, self.opt_state,
                     rows_dev if len(mbs) > 1 else rows_dev[0],
-                    jnp.asarray(1.0 / global_denom, jnp.float32),
+                    self._inv_denom(global_denom, n_tok),
                     jnp.asarray(lr, jnp.float32),
                 )
             if self._serial_dispatch:
@@ -958,7 +993,8 @@ class JaxTrainEngine(TrainEngine):
                     run_len, *attn, width = self._attn_counts(rows["segment_ids"])
                     counts = (*attn, *self._head_counts(rows, scored_fn),
                               *self._mtp_counts(rows, scored_fn),
-                              *self._ssm_counts(rows["segment_ids"]))
+                              *self._ssm_counts(rows["segment_ids"]),
+                              *self._index_counts(rows))
                     attn_attrs = dict(attn_row_len=run_len, width=width)
             return (rows_dev, denom, batch.total_tokens, cells, attn_attrs, counts)
 
@@ -972,9 +1008,10 @@ class JaxTrainEngine(TrainEngine):
         # attention's cells at the run length, run, causal, its grid steps
         # walked, live; the head's positions read, cells run, and the
         # prediction module's; the state-space scan's chunks, live, mixed,
-        # and its resets; counted while tracing is on (`n_counted` of the
+        # and its resets; the indexers' cells scored, kept, and queries that
+        # choose; counted while tracing is on (`n_counted` of the
         # micro-batches)
-        n_counts, n_counted = [0] * 13, 0
+        n_counts, n_counted = [0] * 16, 0
         gaps_ms: List[float] = []
         mark = time.monotonic_ns()
         try:
@@ -1013,7 +1050,7 @@ class JaxTrainEngine(TrainEngine):
         with tracing.span("train.apply"):
             self.params, self.opt_state, packed, aux = apply(
                 self.params, self.opt_state, carry,
-                jnp.asarray(1.0 / global_denom, jnp.float32),
+                self._inv_denom(global_denom, n_tok),
                 jnp.asarray(lr, jnp.float32),
             )
         if n_counted == n_mbs:  # tracing was on for the whole batch
@@ -1029,6 +1066,28 @@ class JaxTrainEngine(TrainEngine):
         return self._fetch_train_stats(
             packed, aux, loss_name, global_denom, n_mbs, lr
         )
+
+    def _inv_denom(self, global_denom: float, n_tok: int):
+        """What a step's gradient sums are divided by: one over the
+        caller's count of loss-weighted tokens, and, where indexers train
+        beside the caller's loss, one over the step's real tokens for
+        their parameters' (`_optimizer_apply`)."""
+        if self._index_weight > 0:
+            return jnp.asarray([1.0 / global_denom, 1.0 / max(n_tok, 1)], jnp.float32)
+        return jnp.asarray(1.0 / global_denom, jnp.float32)
+
+    def _index_counts(self, rows_np: Dict[str, np.ndarray]) -> Tuple[int, int, int]:
+        """What the indexers do with packed rows (on the host, before the
+        transfer), summed over indexed layers: (cells scored, cells an
+        exact choice keeps, queries with more keys than `top_k`), by the
+        device's own rule (ops/indexer.index_counts)."""
+        if not self._n_indexed:
+            return 0, 0, 0
+        from areal_tpu.ops.indexer import index_counts
+
+        return tuple(self._n_indexed * int(c) for c in index_counts(
+            np.asarray(rows_np["positions"]).astype(np.int64),
+            np.asarray(rows_np["segment_ids"]), self.model_cfg.indexer.top_k))
 
     def _attn_counts(self, segment_ids: np.ndarray) -> Tuple[int, ...]:
         """What the attention kernels do with packed rows (on the host,
@@ -1123,7 +1182,9 @@ class JaxTrainEngine(TrainEngine):
                      n_scored: int, n_head_cells: int,
                      n_mtp_targets: int, n_mtp_head_cells: int,
                      n_ssm_chunks: int = 0,
-                     n_ssm_live: int = 0, n_ssm_mixed: int = 0, n_ssm_resets: int = 0):
+                     n_ssm_live: int = 0, n_ssm_mixed: int = 0, n_ssm_resets: int = 0,
+                     n_index_cells: int = 0, n_index_selected: int = 0,
+                     n_index_choosing: int = 0):
         """What one train_batch did, on its `train.batch` span and in the
         recorder's counters: the micro-batches and how many of them the
         packer made one row (what lets attention skip the block pairs
@@ -1136,8 +1197,10 @@ class JaxTrainEngine(TrainEngine):
         the loss reads and the cells of the chunks the loss head ran for
         them, the same two of the prediction module's run of the head,
         the (token, expert) pairs the routers of the expert
-        layers made, and the chunks the state-space layers' scan ran
-        (the selective scan's also as positions: chunks x their length)."""
+        layers made, the chunks the state-space layers' scan ran
+        (the selective scan's also as positions: chunks x their length),
+        and the cells the indexers scored, those an exact choice keeps and
+        the queries that had more keys than they keep."""
         tracing.set_attrs(path=path, n_mbs=n_mbs, tokens=n_tok, cells=n_cells)
         tracing.count("train.batches")
         tracing.count("train.micro_batches", n_mbs)
@@ -1167,6 +1230,10 @@ class JaxTrainEngine(TrainEngine):
             tracing.count("train.ssm_chunks_live", n_ssm_live)
             tracing.count("train.ssm_chunks_mixed", n_ssm_mixed)
             tracing.count("train.ssm_resets", n_ssm_resets)
+        if self._n_indexed:
+            tracing.count("train.index_cells", n_index_cells)
+            tracing.count("train.index_selected", n_index_selected)
+            tracing.count("train.index_queries_choosing", n_index_choosing)
 
     def _record_overlap_stats(self):
         """Ship the last pipeline's telemetry through the stats tracker so
@@ -1276,8 +1343,20 @@ class JaxTrainEngine(TrainEngine):
                 name = k[len("sum:"):]
                 stats[f"{loss_name}/{name}"] = float(v)
                 tracing.count(f"train.{name}", float(v))
-            else:
+            elif k.startswith("num:"):
+                # A ratio of two sums over the step's micro-batches.
+                name = k[len("num:"):]
+                stats[f"{loss_name}/{name}"] = float(v) / max(
+                    float(aux_vals[f"den:{name}"]), 1.0)
+            elif not k.startswith("den:"):
                 stats[f"{loss_name}/{k}"] = float(v) / global_denom
+        if self._index_weight > 0:
+            # The loss sum holds the indexers' KL sum, whose denominator
+            # is its own (`_mb_loss_fn`).
+            stats[f"{loss_name}/loss"] = (
+                (loss_sum - self._index_weight * float(aux_vals["num:indexer_kl"]))
+                / global_denom
+                + self._index_weight * stats[f"{loss_name}/indexer_kl"])
         if self.stats_fetch_interval > 1:
             stats[f"{loss_name}/stats_stale"] = 0.0
         self._last_train_stats = dict(stats)

@@ -10,8 +10,9 @@ state-space mixer in one of two forms or a gated memory unit, and an MLP,
 dense or expert, either of which may be absent; a layer may keep a tensor
 that later layers read), an attention output gate,
 post-norms, a sigmoid router with a selection bias, shared experts, a
-share of the experts held here, and a multi-token-prediction module
-after the stack (`MTPConfig`).
+share of the experts held here, a multi-token-prediction module
+after the stack (`MTPConfig`), and an indexer beside attention that
+chooses each query's keys (`IndexerConfig`).
 """
 
 from __future__ import annotations
@@ -205,6 +206,39 @@ class MTPConfig:
                 "not a chain of them")
 
 
+@dataclasses.dataclass
+class IndexerConfig:
+    """A learned indexer beside attention (DeepSeek sparse attention): it
+    scores every key of a query's causal prefix and attention reads the
+    `top_k` best alone. With x the layer's normed input under
+    `stop_gradient`: `qI = x W_Iq` (`n_heads` heads of `head_dim`),
+    `kI = LayerNorm(x W_Ik)` (one head for all), `wI = x W_Iw`
+    (`n_heads`), rotary over the whole `head_dim` of qI and kI, and
+    `I[t, s] = sum_j wI[t, j] relu(qI[t, j] . kI[s]) head_dim^-0.5
+    n_heads^-0.5` in float32. A query with more than `top_k` keys in its
+    sequence keeps those whose score is at least its `top_k`-th largest
+    (ties at the threshold all kept); one with fewer keeps them all.
+    Attention is dense attention under that mask, which is a constant of
+    the backward pass. `loss_weight` is what a training step gives the
+    indexer's own loss beside the caller's: the KL from the attention
+    probabilities averaged over the q heads (under `stop_gradient`) to
+    the softmax of `I` over the chosen keys, a mean over real tokens and
+    over layers; 0 = the step skips that pass and no gradient reaches
+    the indexer (an optimizer's weight decay still does). So the
+    caller's loss alone moves the model and the KL alone moves the
+    indexer; under global-norm clipping the two share the one norm."""
+
+    n_heads: int = 4
+    head_dim: int = 16
+    top_k: int = 16
+    loss_weight: float = 1.0
+    norm_eps: float = 1e-6
+
+    @property
+    def scale(self) -> float:
+        return self.head_dim ** -0.5 * self.n_heads ** -0.5
+
+
 @dataclasses.dataclass(frozen=True)
 class LayerKind:
     """What one layer of the stack is, known when the program is traced:
@@ -217,7 +251,9 @@ class LayerKind:
     encoding in this layer), and `diff`: differential attention, two
     softmaxes a pair of heads, the second subtracted from the first
     (`transformer._diff_combine`), and `latent`: q and k, v through
-    low-rank projections (`MLAConfig`, `transformer._latent_attention_block`).
+    low-rank projections (`MLAConfig`, `transformer._latent_attention_block`),
+    and `indexed`: an indexer of its own parameters chooses the keys
+    each query reads (`IndexerConfig`, `ops/indexer.py`).
 
     Two relations between layers. `keeps`: the layer hands a tensor on
     to later layers: an "ssm" mixer its scan's output before the gate,
@@ -235,6 +271,7 @@ class LayerKind:
     keeps: bool = False
     reads: Optional[int] = None
     latent: bool = False
+    indexed: bool = False
 
     def __post_init__(self):
         if self.mlp not in ("dense", "moe", None):
@@ -250,10 +287,17 @@ class LayerKind:
             raise ValueError(f"LayerKind.window must be >= 1, got {self.window}")
         if self.mixer != "attention" and (
                 self.window is not None or not self.rotary or self.diff
-                or self.latent):
+                or self.latent or self.indexed):
             # one spelling a kind: layers without attention compare equal
             raise ValueError(
-                "window, rotary, diff and latent describe an attention mixer")
+                "window, rotary, diff, latent and indexed describe an attention "
+                "mixer")
+        if self.indexed and (self.window is not None or self.diff or self.latent
+                             or self.keeps or self.reads is not None):
+            raise NotImplementedError(
+                "an indexer chooses among the keys of plain causal attention: "
+                "no window, no differential pairing, no latent projections, "
+                "and its k and v are no other layer's")
         if self.latent and (self.window is not None or not self.rotary or self.diff
                             or self.keeps or self.reads is not None):
             raise NotImplementedError(
@@ -276,7 +320,9 @@ class LayerKind:
         the stack they live in: layers with the same parts share a
         stack. "attention+moe", "ssm", "moe", "diffattention+dense",
         "xdiffattention+dense" (x: q and the output projection only),
-        "latentattention+moe", "gmu+dense", ...; a layer that `keeps` adds
+        "latentattention+moe", "indexedattention+moe" (the indexer's
+        parameters beside attention's), "gmu+dense", ...; a layer that
+        `keeps` adds
         "^" ("ssm+dense^"):
         it runs on its own, never as a repeat of a scan, so its
         parameters are a stack of their own, which `forward` takes
@@ -286,7 +332,8 @@ class LayerKind:
         if mixer == "attention":
             mixer = ("x" if self.reads is not None else "") + (
                 "diff" if self.diff else "") + (
-                "latent" if self.latent else "") + "attention"
+                "latent" if self.latent else "") + (
+                "indexed" if self.indexed else "") + "attention"
         return "+".join(p for p in (mixer, self.mlp) if p) + ("^" if self.keeps else "")
 
     @property
@@ -376,6 +423,9 @@ class TransformerConfig:
     mla: Optional[MLAConfig] = None
     # The prediction module after the stack, or None.
     mtp: Optional[MTPConfig] = None
+    # The indexer's sizes; with them and no `layer_kinds`, every layer's
+    # attention reads the keys its indexer chooses.
+    indexer: Optional[IndexerConfig] = None
     # One LayerKind a layer, filled by the family from the published
     # config; None = every layer the same (moe or dense by `moe`, full
     # causal attention, rotary by `pos_emb`).
@@ -406,6 +456,8 @@ class TransformerConfig:
             self.mla = MLAConfig(**self.mla)
         if isinstance(self.mtp, dict):
             self.mtp = MTPConfig(**self.mtp)
+        if isinstance(self.indexer, dict):
+            self.indexer = IndexerConfig(**self.indexer)
         if self.activation not in ("silu", "gelu", "relu2"):
             raise ValueError(
                 f"activation must be 'silu', 'gelu' or 'relu2', got {self.activation!r}")
@@ -434,6 +486,8 @@ class TransformerConfig:
                     f"rope_dim = {self.mla.qk_dim}, and k and v are a head each "
                     f"(got head_dim {self.head_dim}, {self.n_q_heads} / "
                     f"{self.n_kv_heads} heads)")
+        if any(k.indexed for k in kinds) and self.indexer is None:
+            raise ValueError("an indexed attention layer needs TransformerConfig.indexer")
         if self.mtp is not None and (self.is_critic or not kinds[-1].block):
             raise ValueError(
                 "the prediction module is one more transformer block of the "
@@ -476,7 +530,8 @@ class TransformerConfig:
         dense_first = self.moe.first_k_dense if self.moe is not None else self.n_layers
         return tuple(
             LayerKind(mlp="dense" if i < dense_first else "moe", rotary=rotary,
-                      latent=self.mla is not None)
+                      latent=self.mla is not None,
+                      indexed=self.indexer is not None)
             for i in range(self.n_layers)
         )
 
@@ -522,6 +577,10 @@ class TransformerConfig:
         return sum(k.mlp == "moe" for k in self.kinds())
 
     @property
+    def n_indexed_layers(self) -> int:
+        return sum(k.indexed for k in self.kinds())
+
+    @property
     def n_ssm_layers(self) -> int:
         return sum(k.mixer == "ssm" for k in self.kinds())
 
@@ -549,6 +608,14 @@ class TransformerConfig:
                 "and the output in decode), where the KV pages hold k and v a "
                 "head; models/transformer.py runs the materialised form, which "
                 "a decode step has no use for")
+        if any(k.indexed for k in kinds):
+            missing.append(
+                "the indexer: a cache of indexer keys (one row of "
+                f"{self.indexer.head_dim} values a token a layer) beside the KV "
+                "pages, the scores of a new token against it and the choice of "
+                f"{self.indexer.top_k} pages' rows inside paged decode; "
+                "models/transformer.py scores and chooses over a whole packed "
+                "row, as a training or prefill pass does")
         if self.mtp is not None:
             missing.append(
                 "the multi-token-prediction module: the cache paths have no "
@@ -585,7 +652,7 @@ class TransformerConfig:
                 f"parts {sorted({k.parts for k in kinds})}, the cache paths run "
                 "attention and an MLP in every layer"
             )
-        elif not self.one_kind and not any(k.latent for k in kinds):
+        elif not self.one_kind and not any(k.latent or k.indexed for k in kinds):
             missing.append(
                 "a cache manager with a kind per layer (window layers keep "
                 "the last `window` positions, full layers all; rotary or "

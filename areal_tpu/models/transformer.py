@@ -39,6 +39,13 @@ real_llm_base.py (blocks) — redesigned for XLA rather than translated:
 - Mixed precision: params in fp32 (or bf16), compute in bf16, logits and
   softmax in fp32.
 
+- **An indexer beside attention** (`config.IndexerConfig`, a layer whose
+  kind is `indexed`): three projections of the layer's normed input
+  under `stop_gradient` (`_index_proj`) score every key of a query's
+  causal prefix, and attention reads the `top_k` best alone
+  (`ops/indexer.py`); `forward(index_loss=True)` also sums the layers'
+  KL terms into the aux sums, as the expert layers' are.
+
 - **A prediction module after the stack** (`config.MTPConfig`):
   `forward(mtp=True)` also hands out the hidden states
   of one more block that reads the stack's output and the next token's
@@ -51,7 +58,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -109,6 +116,18 @@ def _init_layer_stack(cfg: TransformerConfig, keys, n: int, kind: LayerKind,
             attn["k_norm"] = jnp.ones((L, cfg.head_dim), pdt)
         if cfg.attn_gate:
             attn["wg"] = dense(keys[10], (L, D, cfg.q_dim))
+        if kind.indexed:
+            ix = cfg.indexer
+            k_iq, k_ik, k_iw = jax.random.split(jax.random.fold_in(keys[15], 32), 3)
+            attn["indexer"] = {
+                "iq_proj": dense(k_iq, (L, D, ix.n_heads * ix.head_dim)),
+                "ik_proj": dense(k_ik, (L, D, ix.head_dim)),
+                "iw_proj": dense(k_iw, (L, D, ix.n_heads)),
+                "ik_norm": {"weight": jnp.ones((L, ix.head_dim), pdt),
+                            "bias": jnp.zeros((L, ix.head_dim), pdt)},
+            }
+            if cfg.qk_norm:
+                attn["q_norm"] = attn["q_norm"] * _INDEXED_Q_GAIN
         layers["attn"] = attn
     elif kind.mixer == "ssm" and cfg.ssm.form == "mamba1":
         from areal_tpu.ops.selective_scan import init_sscan_params
@@ -160,6 +179,25 @@ def _init_layer_stack(cfg: TransformerConfig, keys, n: int, kind: LayerKind,
             layers[name]["bias"] = jnp.zeros((L, D), pdt)
     return layers
 
+
+# What the seeded draw of an indexed attention layer departs by from
+# ones in its q norm (benchmark/configs/keye-vl-2.0-*.json `assumed`): a
+# head's scores then have this standard deviation and its softmax is
+# peaked, as a trained model's; at 1 a head is a near-uniform average
+# over thousands of keys, and no check of logprobs would see which of
+# them the indexer chose.
+_INDEXED_Q_GAIN = 3.0
+# And the embedding of a stack with such layers is drawn at this scale,
+# not 0.02: a position's state is then its own token's first, as a
+# trained model's. At 0.02 the residual stream is what attention wrote
+# there, an average over the sequence's values that every later layer
+# averages again: by the sixth layer half of a normed state's energy is
+# its sequence's mean, a router sends a whole sequence the same way, and
+# the (token, expert) pairs a share of the experts holds swing by a
+# third from one sequence, seed or step to the next (PERF.md section 6,
+# PR 42), a step's seconds with them. (Where the head is the embedding
+# too, the usual draw stays: logits of that scale would be no model's.)
+_INDEXED_EMBED_SCALE = 2.0
 
 # What the seeded draw of a latent attention layer departs by from
 # `dense`'s 1 / sqrt(fan-in), so that a check against a reference sees
@@ -224,7 +262,9 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Params:
 
     kinds = cfg.kinds()
     params: Params = {
-        "embedding": {"weight": dense(keys[7], (V, D), scale=0.02)},
+        "embedding": {"weight": dense(keys[7], (V, D), scale=(
+            _INDEXED_EMBED_SCALE if any(k.indexed for k in kinds)
+            and not cfg.tied_embeddings else 0.02))},
         "final_norm": {"weight": jnp.ones((D,), pdt)},
     }
     for i, (path, idx) in enumerate(cfg.stack_paths().values()):
@@ -399,9 +439,39 @@ def _diff_combine(out, lp, l0, eps):
     return a.astype(out.dtype)
 
 
+class _Index(NamedTuple):
+    """What `forward` hands an indexed layer: the indexer's rotary tables
+    (`cos`, `sin` of its head size / 2), whether the KL is wanted, and
+    where to leave each layer's (choice, tau) for a caller that asked
+    (None: nowhere)."""
+    cos: jnp.ndarray
+    sin: jnp.ndarray
+    want_kl: bool
+    choices: Optional[list]
+
+
+def _index_proj(x, ip, cfg, cos, sin, cdt):
+    """The indexer's projections of the layer's normed input x `[R, T,
+    D]`, which they do not move (`stop_gradient`): (iq `[R, T, H, d]`, ik
+    `[R, T, d]` under its LayerNorm, iw `[R, T, H]` float32 with both
+    scales folded in), rotary (`cos`, `sin` of `d / 2`) over the whole
+    of iq's heads and of the one ik."""
+    ix = cfg.indexer
+    R, T, _ = x.shape
+    with jax.named_scope("index_proj"):
+        x = jax.lax.stop_gradient(x)
+        iq = (x @ ip["iq_proj"].astype(cdt)).reshape(R, T, ix.n_heads, ix.head_dim)
+        ik = layer_norm(x @ ip["ik_proj"].astype(cdt), ip["ik_norm"]["weight"],
+                        ip["ik_norm"]["bias"], ix.norm_eps)
+        iw = (x @ ip["iw_proj"].astype(cdt)).astype(jnp.float32) * ix.scale
+        iq = apply_rotary(iq, cos, sin, cfg.rotary_interleaved)
+        ik = apply_rotary(ik[:, :, None, :], cos, sin, cfg.rotary_interleaved)[:, :, 0]
+    return iq, ik, iw
+
+
 def _attention_block(
     x, lp, cfg, cos, sin, segment_ids, positions, attn_impl, cdt, mesh=None,
-    variants=((None, True),), variant_index=None, l0=None, kv=None,
+    variants=((None, True),), variant_index=None, l0=None, kv=None, index=None,
 ):
     """x: [R, T, D] -> attention output [R, T, D]. Named scopes say in
     the device trace which part an op belongs to: `attn_qkv`
@@ -416,7 +486,10 @@ def _attention_block(
     `l0` (the layer's lambda_init; None = plain attention) makes it
     differential: `_diff_split` before the kernel, `_diff_combine`
     (scope `attn_diff`) after. `kv` = another layer's k and v, as that
-    layer returned them: this layer then projects q only."""
+    layer returned them: this layer then projects q only. With `index`
+    (`_Index`) the layer's indexer (`lp["indexer"]`, `_index_proj`)
+    chooses the keys each query reads (`ops/indexer.indexed_attention`),
+    and the layer's sums come back as a third result (empty without)."""
     from areal_tpu.ops.attention import resolve_attn_impl
 
     R, T, D = x.shape
@@ -452,6 +525,18 @@ def _attention_block(
         attn_impl, T, cfg.n_q_heads, cfg.n_kv_heads, mesh=mesh, r=R
     )
 
+    sums = {}
+    if index is not None:
+        if impl in ("ring", "ulysses"):
+            raise NotImplementedError(
+                f"attn_impl={impl!r} (context parallelism over the mesh's seq "
+                "axis) with an indexer: ops/ring_attention.py and "
+                "ops/ulysses_attention.py hold a shard of a query's keys each "
+                "and have no threshold over all of them, nor a mask operand")
+        if mesh is not None and mesh.size > 1 and impl == "splash":
+            impl = "reference"  # the plain form partitions; the kernels are one chip's
+        iq, ik, iw = _index_proj(x, lp["indexer"], cfg, index.cos, index.sin, cdt)
+
     def attend(window, rotary):
         """q, k, v -> (attention output [R, T, Hq, hd], k as attended)."""
 
@@ -460,6 +545,17 @@ def _attention_block(
                 with jax.named_scope("attn_qkv"):
                     q = apply_rotary(q, cos, sin, cfg.rotary_interleaved)
                     k = apply_rotary(k, cos, sin, cfg.rotary_interleaved)
+            if index is not None:
+                from areal_tpu.ops.indexer import indexed_attention
+
+                out, got = indexed_attention(
+                    q, k, v, iq, ik, iw, segment_ids, positions,
+                    cfg.indexer.top_k, impl, index.want_kl)
+                choice = (got.pop("choice"), got.pop("tau"))
+                if index.choices is not None:
+                    index.choices.append(choice)
+                sums.update(got)
+                return out, k
             with jax.named_scope("attn_kernel"):
                 return _attention_kernel(
                     q, k, v, segment_ids, positions, impl, cfg, mesh, window), k
@@ -486,7 +582,7 @@ def _attention_block(
         out = out @ lp["wo"].astype(cdt)
         if "bo" in lp:
             out = out + lp["bo"].astype(cdt)
-    return out, (k, v)
+    return out, (k, v), sums
 
 
 def _latent_attention_block(x, lp, cfg, cos, sin, segment_ids, positions,
@@ -592,6 +688,8 @@ def forward(
     remat: Any = False,  # False/"none" | True/"full" | "save_attn" | "mlp"
     mesh=None,  # jax.sharding.Mesh: anchor activation/logits shardings
     mtp: bool = False,  # also run the prediction module (cfg.mtp)
+    index_loss: bool = False,  # also sum the indexers' KL terms (cfg.indexer)
+    index_choice: bool = False,  # also return what the indexers chose
 ) -> Any:
     """Packed-rows forward pass.
 
@@ -601,7 +699,13 @@ def forward(
     the result is a pair: what it would be without, and the prediction
     module's hidden states [R, T, D] after its norm (its expert layer's
     sums are in the aux losses), which the model's head
-    turns into a prediction of the token two on.
+    turns into a prediction of the token two on. With `index_loss` the
+    indexed layers' KL terms (`config.IndexerConfig`) are summed into the
+    aux sums under `index_kl`; without it those layers score and choose
+    all the same and the sum stays 0. With `index_choice` (a check's,
+    not a step's: the layers then run one by one, without remat) the
+    last result is (choice bool `[L, R, T, T]`, tau `[L, R, T]`) of the
+    indexed layers in order.
 
     When `mesh` is given, activations are pinned to
     P((data, fsdp), seq, None) and logits to P((data, fsdp), seq, tensor)
@@ -650,6 +754,18 @@ def forward(
             )
         )
         cos, sin = rotary_cos_sin(positions, inv_freq)  # [R, T, rotary_dim/2]
+    index = None
+    if cfg.indexer is not None:
+        # the indexer's own tables: the same base over its head size
+        index = _Index(*rotary_cos_sin(positions, jnp.asarray(rotary_inv_freq(
+            cfg.indexer.head_dim, cfg.rotary_base, cfg.rotary_scaling,
+            cfg.rotary_scaling_type, cfg.rotary_scaling_params))), bool(index_loss),
+            [] if index_choice else None)
+    if index_choice:
+        if index is None or remat not in (False, "none"):
+            raise ValueError("index_choice=True needs cfg.indexer, and no remat")
+        # one by one: a scan's body would hold the choices as tracers
+        cfg = dataclasses.replace(cfg, scan_min_repeats=cfg.n_layers + 1)
 
     use_moe = cfg.moe is not None
     # remat policy: "full" recomputes the whole layer in backward (least
@@ -682,12 +798,14 @@ def forward(
             remat_mode = "full"
     kinds = cfg.kinds()
     if return_kv and not all(
-            k == kinds[0] and k.block and not k.latent for k in kinds):
+            k == kinds[0] and k.block and not k.latent and not k.indexed
+            for k in kinds):
         raise NotImplementedError(
-            "return_kv with layers of different kinds or latent attention: the "
-            "KV cache (models/generation.py) holds one kind of layer, plain "
-            "attention and an MLP in each, has no recurrent state for a "
-            "state-space layer and no latent row for latent attention"
+            "return_kv with layers of different kinds, latent attention or an "
+            "indexer: the KV cache (models/generation.py) holds one kind of "
+            "layer, plain attention and an MLP in each, has no recurrent state "
+            "for a state-space layer, no latent row for latent attention and no "
+            "indexer keys beside k and v"
         )
     if mtp and (cfg.mtp is None or return_kv):
         raise ValueError("mtp=True needs cfg.mtp, and hands out no KV cache")
@@ -737,12 +855,15 @@ def forward(
                         h, lp["attn"], cfg, cos, sin, segment_ids, positions,
                         attn_impl, cdt, mesh=mesh)
                 else:
-                    a, kv = _attention_block(
+                    a, kv, sums = _attention_block(
                         h, lp["attn"], cfg, cos, sin,
                         segment_ids, positions, attn_impl, cdt, mesh=mesh,
                         variants=variants, variant_index=variant_index,
-                        l0=l0, kv=kept,
+                        l0=l0, kv=kept, index=index if kind.indexed else None,
                     )
+                    if sums:
+                        aux_acc = {**aux_acc, **{k: aux_acc[k] + v
+                                                 for k, v in sums.items()}}
                 with jax.named_scope("attn_out"):
                     if "ln1_post" in lp:
                         a = _norm(a, lp["ln1_post"], cfg)
@@ -777,7 +898,8 @@ def forward(
                     h = _norm(x, lp["ln2"], cfg)
                     if kind.mlp == "moe":
                         m, aux = moe_fn(h, lp["mlp"])
-                        aux_acc = {k: aux_acc[k] + aux[k] for k in aux_acc}
+                        aux_acc = {k: aux_acc[k] + aux[k] if k in aux else aux_acc[k]
+                                   for k in aux_acc}
                     else:
                         m = dense_fn(h, lp["mlp"])
                     if "ln2_post" in lp:
@@ -800,7 +922,12 @@ def forward(
 
     from areal_tpu.models.moe import moe_aux_zeros
 
-    carry, kvs = (x, moe_aux_zeros(cfg)), None
+    sums0 = moe_aux_zeros(cfg)
+    if cfg.indexer is not None:
+        from areal_tpu.ops.indexer import INDEX_SUMS
+
+        sums0.update({k: jnp.zeros((), jnp.float32) for k in INDEX_SUMS})
+    carry, kvs = (x, sums0), None
     kept: Dict[int, Any] = {}  # keeping layer -> its tensor, for its readers
     for seg, stacks in _segment_stacks(params, cfg):
         # One body a position of the unit: its layers, one a repeat,
@@ -883,6 +1010,9 @@ def forward(
             out = log_c((x @ head_w.astype(cdt)).astype(jnp.float32))  # [R, T, V]
     if mtp:
         out = (out, x_mtp)
+    if index_choice:
+        chosen = tuple(jnp.stack(a) for a in zip(*index.choices))
+        return (out, moe_aux, chosen) if return_aux else (out, chosen)
     if return_kv and return_aux:
         return out, kvs, moe_aux
     if return_kv:

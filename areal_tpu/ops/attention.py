@@ -55,6 +55,7 @@ def reference_packed_attention(
     positions: jnp.ndarray,  # [T] int32 within-sequence positions
     softmax_scale: Optional[float] = None,
     window: Optional[int] = None,
+    chosen: Optional[jnp.ndarray] = None,  # [T, T] bool: a choice of keys a query
 ) -> jnp.ndarray:
     T, Hq, hd = q.shape
     Hkv = k.shape[1]
@@ -68,6 +69,8 @@ def reference_packed_attention(
     mask = segment_causal_mask(
         segment_ids, segment_ids, positions, positions, window=window
     )
+    if chosen is not None:
+        mask &= chosen
     scores = jnp.where(mask[None, None], scores, NEG_INF)
     probs = jax.nn.softmax(scores, axis=-1)
     # Fully-masked (padding) rows: zero out.

@@ -27,27 +27,30 @@ KEEP = ("loss", "mtp_loss", "mtp_accept", "grad_norm")
 SEEN = []
 
 
-def main() -> int:
+def main(keep=KEEP, label="mtp", a_pass="mtp_loss") -> int:
+    """Run the cell, printing `<label> step <n>: {<the stats in keep>}` a
+    train step and, on stderr, the mean of `a_pass` a pass over the pool
+    (`scripts/indexer_step_stats.py` calls it with the indexers' names)."""
     inner = ppo.PPOActorInterface.train_step
 
     def train_step(self, *a, **k):
         stats = inner(self, *a, **k)
         row = {key.split("/")[-1]: float(v) for key, v in stats.items()
-               if key.split("/")[-1] in KEEP}
+               if key.split("/")[-1] in keep}
         SEEN.append(row)
-        print(f"mtp step {len(SEEN) - 1}: {json.dumps(row)}", flush=True)
+        print(f"{label} step {len(SEEN) - 1}: {json.dumps(row)}", flush=True)
         return stats
 
     ppo.PPOActorInterface.train_step = train_step
     rc = run.main()
-    if SEEN and "mtp_loss" in SEEN[0]:
+    if SEEN and a_pass in SEEN[0]:
         from benchmark import manifest
 
         cell = sys.argv[sys.argv.index("--workload") + 1]
         pool = int(manifest.load_cell(cell)["traffic_file"]["pool_batches"])
-        loss = [r["mtp_loss"] for r in SEEN]
+        loss = [r[a_pass] for r in SEEN]
         # on stderr: the contract's line stays the last of stdout
-        print("mtp passes (mean mtp_loss, warm pass first): " + json.dumps(
+        print(f"{label} passes (mean {a_pass}, warm pass first): " + json.dumps(
             [sum(loss[i:i + pool]) / len(loss[i:i + pool]) for i in range(0, len(loss), pool)]),
             file=sys.stderr, flush=True)
     return rc

@@ -107,6 +107,36 @@ def test_pair_kernels_compile_at_a_row_of_16k(one_chip, hq, hkv, hd, hd_v, windo
     assert all(f"splash_pairs_{name}" in text for name in ("fwd", "dq", "dkv"))
 
 
+def test_the_indexers_kernels_compile_at_a_row_of_16k(one_chip):
+    """The keye cell's attention at its longest row: `index_select` (8 MB
+    of scores in VMEM a step, 32 halvings), the pair kernels under the
+    int8 mask operand, `index_kl_bwd` (the heads innermost, a transposed
+    product into a block that stays in VMEM); the backward pass needs no
+    `index_kl_fwd` (the KL's value) and the compiler drops it."""
+    from areal_tpu.ops.indexer import indexed_attention
+
+    t, hq, hkv, hd, hi, d = 16384, 32, 4, 128, 16, 64
+    bf = jnp.bfloat16
+    args = (_shape((1, t, hq, hd), bf, one_chip), _shape((1, t, hkv, hd), bf, one_chip),
+            _shape((1, t, hkv, hd), bf, one_chip), _shape((1, t, hi, d), bf, one_chip),
+            _shape((1, t, d), bf, one_chip), _shape((1, t, hi), jnp.float32, one_chip))
+    ids = _shape((1, t), jnp.int32, one_chip)
+
+    def loss(q, k, v, iq, ik, iw, seg, pos):
+        out, sums = indexed_attention(q, k, v, iq, ik, iw, seg, pos, 2048, "splash", True,
+                                      interpret=False)
+        return out.astype(jnp.float32).sum() + sums["index_kl"]
+
+    text = jax.jit(jax.grad(loss, tuple(range(6)))).lower(*args, ids, ids).compile().as_text()
+    assert text.count("tpu_custom_call") == 5 and " conditional(" not in text
+    for name in ("index_select", "splash_pairs_fwd", "splash_pairs_dq", "splash_pairs_dkv",
+                 "index_kl_bwd"):
+        assert name in text, name
+    assert "index_kl_fwd" not in text
+    text = jax.jit(loss).lower(*args, ids, ids).compile().as_text()
+    assert text.count("tpu_custom_call") == 3 and "index_kl_fwd" in text
+
+
 def test_a_train_step_of_one_row_holds_no_partial_of_dq(one_chip, monkeypatch):
     """A forward-backward micro-batch of `q15d12-train-ppo`'s model (12
     layers, 12 / 2 heads of 128, full remat, the masked loss head) at
