@@ -521,9 +521,11 @@ class JaxTrainEngine(TrainEngine):
                 if "pairs_held" in moe_aux:
                     # A share of the experts (MoEConfig.experts_held):
                     # counts summed over expert layers and micro-batches,
-                    # for the counters train.moe_pairs_held / train.moe_rows.
+                    # for the counters train.moe_pairs_held / train.moe_rows
+                    # / train.moe_chunks.
                     aux["sum:moe_pairs_held"] = moe_aux["pairs_held"]
                     aux["sum:moe_rows"] = moe_aux["rows_run"]
+                    aux["sum:moe_chunks"] = moe_aux["chunks_run"]
             if self._n_indexed:
                 # The indexers' KL beside the caller's loss: the layers'
                 # mean of a sum over this micro-batch's real tokens. Its

@@ -309,9 +309,10 @@ def _moe_mlp_ep(
 AUX_KEYS = ("load_balance_loss", "z_loss", "drop_rate", "router_entropy",
             "expert_load", "a2a_bytes")
 # What a share of the experts adds (`experts_held`): the real (token,
-# choice) pairs routed to experts held here, and the rows of the tiles
-# that ran over them.
-HELD_AUX_KEYS = ("pairs_held", "rows_run")
+# choice) pairs routed to experts held here, the rows of the tiles that
+# ran over them, and the chunks of tiles whose rows went to their tokens
+# together (`_add_rows` ran once a chunk forward and once backward).
+HELD_AUX_KEYS = ("pairs_held", "rows_run", "chunks_run")
 
 
 def moe_aux_zeros(cfg: TransformerConfig) -> Dict[str, jnp.ndarray]:
@@ -329,19 +330,25 @@ def moe_aux_zeros(cfg: TransformerConfig) -> Dict[str, jnp.ndarray]:
 # tile belongs to one expert, so its products are dense and every tile
 # re-reads that expert's matrices and adds into its float32 gradients:
 # the smaller the tile, the fewer rows an expert's short last tile runs
-# empty and the more often the matrices are read. A chunk's rows go to
-# their tokens in one scatter-add, and on the chip a scatter-add reads
-# and writes the whole [T, D] it adds into (0.44 ms at 16,384 x 2048 in
-# float32) whatever it adds (0.14 us a row of the chunk, held or not): a
-# tile at a time that pass cost ten times the tile's products. Measured
-# (PERF.md section 6, PR 37): the held part alone under mildly skewed
-# routing is fastest at tiles of 512, by 6-12 % over 256; in the trinity
-# cell, whose routers skew more, 256 and 384 come before 512 (15.90,
-# 15.85 and 16.00 s of device time a pass) and 256 runs the fewest empty
-# rows (137 % of the pairs held, against 158 and 179). Chunks of 4,096
-# to 12,288 rows are within a few per cent of each other.
+# empty and the more often the matrices are read. Measured (PERF.md
+# section 6, PR 37): the held part alone under mildly skewed routing is
+# fastest at tiles of 512, by 6-12 % over 256; in the trinity cell, whose
+# routers skew more, 256 and 384 come before 512 (15.90, 15.85 and 16.00
+# s of device time a pass) and 256 runs the fewest empty rows (137 % of
+# the pairs held, against 158 and 179). A chunk's rows go to their tokens
+# together (`_add_rows`): a tile at a time that pass cost ten times the
+# tile's products. Each call sorts and gathers a whole chunk, rows held
+# or not (0.05 ms at 8,192 rows of 2,048, 0.07 at 12,288, 0.11 at
+# 16,384), and reads and writes every band of tokens a row lands in (0.25
+# ms for the 8.5k real tokens of a row of 16,384, few rows or many), so a
+# layer's rows should fit one chunk and the chunk be no larger than that
+# takes. Measured with the kernel (PERF.md section 6, PR 43: the held
+# part alone, forward and backward, ms at chunks of 8,192 / 12,288 /
+# 16,384): 12.44 / 11.96 / 11.94 at 8.7k rows, 12.23 / 11.68 / 11.88 at
+# 10.8k, 8.55 / 8.72 / 8.82 at 6.4k, 9.52 / 9.46 / 9.66 at 3.8k rows of
+# 2,688.
 _HELD_ROW_TILE = 256
-_HELD_CHUNK_ROWS = 8192
+_HELD_CHUNK_ROWS = 12288
 
 
 def _expert_ffn(xs, ws, act):
@@ -403,19 +410,35 @@ def _expert_of(weights, e):
 def _add_rows(y, rows, tok, n_rows):
     """y [T, D] with row r of `rows` added to token `tok[r]`, for the
     first `n_rows` rows (the rest hold what an earlier chunk left). By
-    token, then by row: one sort of distinct keys (token x rows + row),
-    the rows taken in that order, and a scatter-add that is told its
-    indices are sorted. Left to itself the chip's compiler sorts the
-    indices of a scatter with the rows as a second operand, and takes 8 s
-    over that sort at 20k rows (1.8 s over this scatter, 1.5 over this
-    sort)."""
+    token, then by row: one sort of distinct keys (token x rows + row)
+    and the rows taken in that order, nothing past `n_rows`. On the chip
+    those go to a kernel that sums them band of tokens by band
+    (`ops/pallas/segment_add.py`: only the bands a row lands in are read
+    and written); elsewhere, and at shapes the kernel does not take (the
+    tests' toy tiles), to a scatter-add that is told its indices are
+    sorted, which on the chip reads and writes all of `y` and walks the
+    rows one at a time (1.55 ms for 8,192 rows into [16384, 2048]). Left
+    to itself the chip's compiler sorts the indices of a scatter with the
+    rows as a second operand, and takes 8 s over that sort at 20k rows
+    (1.8 s over this scatter, 1.5 over this sort)."""
+    from areal_tpu.ops.pallas import segment_add
+
     b = rows.shape[0]
     assert y.shape[0] * b < 2**31
     row = jnp.arange(b, dtype=jnp.int32)
     keys = jax.lax.sort(jnp.where(row < n_rows, tok, y.shape[0] - 1) * b + row, is_stable=False)
-    row = keys % b
-    return y.at[keys // b].add(
-        jnp.where((row < n_rows)[:, None], rows[row], 0).astype(y.dtype), indices_are_sorted=True)
+    row = keys % b  # the first n_rows rows stay the first n_rows
+    tok, rows = keys // b, rows[row]
+    if jax.default_backend() == "tpu" and segment_add.kernel_ok(*y.shape, b):
+        return segment_add.add_sorted_rows(y, rows, tok, n_rows)
+    return y.at[tok].add(
+        jnp.where((row < n_rows)[:, None], rows, 0).astype(y.dtype), indices_are_sorted=True)
+
+
+def _chunks(n_tiles):
+    """(tiles a chunk, chunks that hold one of `n_tiles` tiles)."""
+    G = max(1, _HELD_CHUNK_ROWS // _HELD_ROW_TILE)
+    return G, -(-n_tiles // G)
 
 
 def _chunk_loop(n_tiles, tile, carry, dtype):
@@ -425,7 +448,7 @@ def _chunk_loop(n_tiles, tile, carry, dtype):
     together. Both loops' trip counts are values of the run: the chunks
     that hold a tile, and the tiles a chunk holds."""
     R = _HELD_ROW_TILE
-    G = max(1, _HELD_CHUNK_ROWS // R)
+    G, n_chunks = _chunks(n_tiles)
 
     def chunk(c, state):
         def one(j, state):
@@ -440,7 +463,7 @@ def _chunk_loop(n_tiles, tile, carry, dtype):
             return (_add_rows(carry[0], buf, toks, held * R),) + carry[1:], buf, toks
 
     # a chunk's rows and their tokens: made once, written a tile at a time
-    return jax.lax.fori_loop(0, -(-n_tiles // G), chunk, (
+    return jax.lax.fori_loop(0, n_chunks, chunk, (
         carry, jnp.zeros((G * R, carry[0].shape[1]), dtype),
         jnp.zeros((G * R,), jnp.int32)))[0]
 
@@ -502,7 +525,7 @@ _run_tiles.defvjp(_run_tiles_fwd, _run_tiles_bwd)
 def _held_experts(xt, mp, moe, act, cdt, choice_e, gate, token_mask,
                   mats=("w_gate", "w_up", "w_down")):
     """The held experts' part of sum_e w_e Expert_e(x): [T, D], and
-    (pairs held, rows run). `choice_e`, `gate`: the k x T (token, choice)
+    (pairs held, rows run, chunks run). `choice_e`, `gate`: the k x T (token, choice)
     pairs' expert and weight, choice-major (pair c T + t is token t's
     choice c).
 
@@ -539,7 +562,7 @@ def _held_experts(xt, mp, moe, act, cdt, choice_e, gate, token_mask,
     y = _run_tiles(act, xt.astype(cdt), tuple(mp[m].astype(cdt) for m in mats),
                    gate, pairs, n_tiles, tiles)
     return (y.astype(cdt), jnp.sum(sizes).astype(jnp.float32),
-            (n_tiles * R).astype(jnp.float32))
+            (n_tiles * R).astype(jnp.float32), _chunks(n_tiles)[1].astype(jnp.float32))
 
 
 def _shared_expert(xt, sp, act, cdt):
@@ -608,9 +631,9 @@ def moe_mlp(
     held_aux = {}
 
     if moe.experts_held is not None:
-        y, pairs_held, rows_run = _held_experts(
+        y, *counts = _held_experts(
             xt, mp, moe, act, cdt, choice_e, gate, token_mask, mats)
-        held_aux = dict(pairs_held=pairs_held, rows_run=rows_run)
+        held_aux = dict(zip(HELD_AUX_KEYS, counts))
         drop_rate = jnp.zeros((), jnp.float32)
     elif dispatch == "dropless":
         # Sort (token, choice) pairs by expert; the expert FFN becomes

@@ -1,20 +1,28 @@
 #!/usr/bin/env python3
 """The held experts' part of an expert layer alone, on the chip, at the
-shapes of the two cells that run it: one row of 16,384 tokens of which
+shapes of the four cells that run it: one row of 16,384 tokens of which
 about half are real, bf16,
 
     trinity   D 2048, 16 of 128 gated silu experts of 1024, top-8, about 6,750 pairs held
     nemotron  D 2688,  8 of 128 plain squared-ReLU experts of 1856, top-6, about 2,600 pairs held
+    joyai     D 2048, 16 of 256 gated silu experts of 768, top-8, about 4,300 pairs held
+    keye      D 2048, 16 of 128 gated silu experts of 768, top-8, about 8,600 pairs held
 
 with seeded random routing skewed to those counts. A line a (cell, row
 tile x chunk rows): forward and forward + backward milliseconds (the
 median of `--reps` calls, each ended by `block_until_ready`), the pairs
 held, the tiles and the rows they run. `--parent DIR` times `_held_experts` of the
-checkout at DIR (the form before PR 37: passes over a static buffer) on
-the same inputs and compares values and gradients; `--ops` adds the
-heaviest device ops of a traced call, by HLO base name.
+checkout at DIR on the same inputs and compares values and gradients;
+`--ops` adds the heaviest device ops of a traced call, by HLO base name.
+`--combine` adds a line a (cell, chunk rows) for the combine alone
+(`_add_rows`: sort, gather, add) over the first chunk's rows and tokens
+as the tiles hand them over: milliseconds a call (a scan over sixteen
+calls whose rows are rotated tile by tile, so that nothing is hoisted)
+for the scatter-add beside the kernel (`ops/pallas/segment_add.py`, at
+each `--kernel-blocks` band x block), the largest difference between
+the two, and with `--ops` each form's device ops.
 
-    python scripts/held_experts_probe.py [--tiles 128x4096,256x4096,512x4096] [--parent _parent] [--out chiprun_out/x.jsonl]
+    python scripts/held_experts_probe.py [--tiles 128x4096,256x4096,512x4096] [--parent _parent] [--combine] [--out chiprun_out/x.jsonl]
 
 `--toy` walks it on the CPU at a small size: the plumbing, no time.
 """
@@ -38,12 +46,18 @@ import numpy as np
 
 from areal_tpu.models import moe as moe_lib
 from areal_tpu.models.config import MoEConfig
+from areal_tpu.ops.pallas import segment_add
 
+_GATED = ("w_gate", "w_up", "w_down")
 CELLS = {
     "trinity": dict(D=2048, F=1024, held=16, k=8, act="silu", scale=2.826,
-                    mats=("w_gate", "w_up", "w_down"), real=0.521, pairs=6750),
+                    mats=_GATED, real=0.521, pairs=6750),
     "nemotron": dict(D=2688, F=1856, held=8, k=6, act="relu2", scale=2.5,
                      mats=("w_in", "w_out"), real=0.526, pairs=2600),
+    "joyai": dict(D=2048, F=768, held=16, k=8, act="silu", scale=2.5,
+                  mats=_GATED, real=0.526, pairs=4300, experts=256),
+    "keye": dict(D=2048, F=768, held=16, k=8, act="silu", scale=1.0,
+                 mats=_GATED, real=0.526, pairs=8600),
 }
 
 
@@ -91,6 +105,78 @@ def timed(fn, args, reps):
     return statistics.median(out)
 
 
+def first_chunk(choice, mask, held, k, T):
+    """The tokens of the first chunk's rows as `_run_tiles` hands them to
+    `_add_rows` (a row past its tile's pairs is sent to the last token),
+    and the rows the chunk holds."""
+    R, G = moe_lib._HELD_ROW_TILE, moe_lib._chunks(0)[0]
+    here = (choice < held) & jnp.tile(mask, k)
+    local_e = jnp.where(here, choice, held).astype(jnp.int32)
+    n = k * T
+    pairs = jnp.pad(jax.lax.sort(local_e * n + jnp.arange(n, dtype=jnp.int32)) % n, (0, R))
+    sizes = jnp.sum(local_e[None, :] == jnp.arange(held, dtype=jnp.int32)[:, None], axis=1,
+                    dtype=jnp.int32)
+    n_tiles, tiles = moe_lib._held_tiles(sizes, n)
+    in_chunk = int(min(G, int(n_tiles)))
+    toks = [moe_lib._tile_rows(i, tiles, pairs, jnp.zeros((n + R,)), T)[2] for i in range(in_chunk)]
+    tok = jnp.concatenate(toks + [jnp.zeros(((G - in_chunk) * R,), jnp.int32)])
+    return tok, in_chunk * R
+
+
+def combine_lines(name, c, choice, mask, T, dtype, a):
+    """The combine alone: `_add_rows` as a scatter-add and as the kernel."""
+    R = moe_lib._HELD_ROW_TILE
+    tok, n_rows = first_chunk(choice, mask, c["held"], c["k"], T)
+    B, reps = tok.shape[0], 16
+    key = jax.random.PRNGKey(a.seed + 1)
+    rows = jax.random.normal(key, (B, c["D"])).astype(dtype)
+    y0 = jax.random.normal(jax.random.fold_in(key, 1), (T, c["D"]), jnp.float32)
+    # call i sees the chunk's first n_rows rows rotated by i tiles
+    at = (jnp.arange(n_rows)[None, :] + R * jnp.arange(reps)[:, None]) % max(n_rows, 1)
+    toks = jnp.concatenate([tok[:n_rows][at], jnp.broadcast_to(tok[n_rows:], (reps, B - n_rows))], 1)
+
+    def program(blocks):
+        """The sixteen calls compiled as the scatter-add (no `blocks`) or
+        as the kernel at that band x block: traced here, under the form
+        asked for."""
+        def calls(y, rows, toks):
+            return jax.lax.scan(
+                lambda y, t: (moe_lib._add_rows(y, rows, t, n_rows), None), y, toks)[0]
+
+        was = segment_add.kernel_ok, segment_add.BAND, segment_add.BLOCK
+        if blocks:
+            segment_add.BAND, segment_add.BLOCK = blocks
+        else:
+            segment_add.kernel_ok = lambda *_: False
+        try:
+            return jax.jit(calls).lower(y0, rows, toks).compile()
+        finally:
+            segment_add.kernel_ok, segment_add.BAND, segment_add.BLOCK = was
+
+    lines, want = [], None
+    forms = [("scatter", None)] + [("kernel", tuple(map(int, b.split("x"))))
+                                   for b in a.kernel_blocks.split(",")]
+    for form, blocks in forms:
+        fn = program(blocks)
+        got = fn(y0, rows, toks)
+        line = dict(cell=name, combine=form, blocks=blocks, T=T, D=c["D"], chunk_rows=B,
+                    n_rows=n_rows, ms_a_call=None if a.toy else timed(fn, (y0, rows, toks), a.reps) / reps)
+        if want is None:
+            want = got
+        else:
+            line["vs_scatter"] = float(jnp.abs(got - want).max() / jnp.abs(want).max())
+        if a.ops and not a.toy:
+            line["device_ops_ms_a_call"] = [[n_, round(ms / reps, 4)] for n_, ms in
+                                            device_ops(fn, (y0, rows, toks))]
+        lines.append(line)
+        print(json.dumps(line), flush=True)
+    if not a.toy:  # a chunk without a row: a grid of no step, y as it was to the bit
+        same = jax.jit(lambda y, rows, tok: moe_lib._add_rows(y, rows, tok, 0))(y0, rows, tok)
+        print(json.dumps(dict(cell=name, combine="kernel", n_rows=0,
+                              y_kept=bool((same == y0).all()))), flush=True)
+    return lines
+
+
 def device_ops(fn, args, top=12):
     from benchmark import trace_reduce
 
@@ -105,13 +191,16 @@ def device_ops(fn, args, top=12):
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--cells", default="trinity,nemotron")
+    ap.add_argument("--cells", default=",".join(CELLS))
     ap.add_argument("--tiles", default="128x4096,256x4096,512x4096",
                     help="row tile x chunk rows, comma-separated")
     ap.add_argument("--parent", default=None)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--reps", type=int, default=20)
     ap.add_argument("--ops", action="store_true")
+    ap.add_argument("--combine", action="store_true")
+    ap.add_argument("--kernel-blocks", default=f"{segment_add.BAND}x{segment_add.BLOCK}",
+                    help="with --combine: the kernel's band x block, comma-separated")
     ap.add_argument("--toy", action="store_true")
     ap.add_argument("--out", default=None)
     a = ap.parse_args()
@@ -130,10 +219,11 @@ def main():
         if a.toy:
             c.update(D=32, F=48, pairs=c["pairs"] * T // 16384)
         k, held, mats = c["k"], c["held"], c["mats"]
-        moe = MoEConfig(num_experts=128, top_k=k, dispatch="dropless", score_func="sigmoid",
+        experts = c.get("experts", 128)
+        moe = MoEConfig(num_experts=experts, top_k=k, dispatch="dropless", score_func="sigmoid",
                         routed_scaling_factor=c["scale"], experts_held=(0, held))
         act = moe_lib.activation_fn(c["act"])
-        choice, mask, pairs = routing(a.seed, T, k, held, c["real"], c["pairs"])
+        choice, mask, pairs = routing(a.seed, T, k, held, c["real"], c["pairs"], experts)
         ks = jax.random.split(jax.random.PRNGKey(a.seed), 6)
         x = jax.random.normal(ks[0], (T, c["D"])).astype(dtype)
         r = jax.random.normal(ks[1], (T, c["D"])).astype(dtype)
@@ -146,7 +236,7 @@ def main():
             call = held_call(lib, moe, act, mats, choice, mask, T, k)
 
             def loss(x, mp, gate, r):
-                y, n_pairs, rows = call(x, mp, gate)
+                y, n_pairs, rows, *_ = call(x, mp, gate)
                 return (y.astype(jnp.float32) * r).sum(), (y, n_pairs, rows)
 
             return jax.jit(call), jax.jit(jax.value_and_grad(loss, (0, 1, 2), has_aux=True))
@@ -176,6 +266,13 @@ def main():
                 line["device_ops_ms"] = device_ops(both, (x, mp, gate, r))
             lines.append(line)
             print(json.dumps(line), flush=True)
+        if a.combine:
+            seen = set()
+            for t in a.tiles.split(","):
+                moe_lib._HELD_ROW_TILE, moe_lib._HELD_CHUNK_ROWS = map(int, t.split("x"))
+                if moe_lib._HELD_CHUNK_ROWS not in seen:
+                    seen.add(moe_lib._HELD_CHUNK_ROWS)
+                    lines += combine_lines(name, c, choice, mask, T, dtype, a)
     if a.out:
         os.makedirs(os.path.dirname(os.path.abspath(a.out)), exist_ok=True)
         with open(a.out, "w") as f:

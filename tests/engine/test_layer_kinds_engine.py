@@ -91,6 +91,8 @@ def test_a_train_step_updates_the_weights_and_leaves_the_buffer(depth):
     assert 0 < c["train.moe_pairs_held"] < c["train.moe_pairs"]
     assert c["train.moe_pairs_held"] == stats["t/moe_pairs_held"]
     assert c["train.moe_rows"] == stats["t/moe_rows"] >= c["train.moe_pairs_held"]
+    # a layer's held pairs of a toy micro-batch fit one chunk, and each holds some
+    assert c["train.moe_chunks"] == stats["t/moe_chunks"] == expert_layers * N_MBS
     assert stats["t/moe_drop_rate"] == 0.0
     # the einsum reference runs every cell of a row whatever the mask
     assert c["train.attn_active_cells"] == c["train.attn_causal_cells"] > 0

@@ -462,11 +462,13 @@ def test_nemotron_h_config_and_names_round_trip():
 # sha256 of str(jaxpr) of the backward pass, by remat mode: taken at the
 # commit before that refactor (PR 31) and held until PR 37, which changed
 # what the held experts trace to (loops over tiles each way) and took
-# them again with nothing else changed
+# them again with nothing else changed; PR 43 took them again for the
+# same reason (a chunk of 12,288 rows, the count of chunks carried beside
+# the pairs and rows)
 AFMOE_JAXPR = {
-    "full": "c88f2f1b1f15a3c61e74860d6d403da242ae2cbcfd8bde713d9bb38ab5528c17",
-    "none": "78743c87aed401bfd9d038288a0c6ec9d416b026e46f4b32cfdf7973548355e8",
-    "mlp": "2e2614437332c271c3b53c232aeaa8eedd3316c713c87557b6b3dff57f41f09c",
+    "full": "a4b57b2950075c9d633a53ff1356421bf8c4969ba45096d17a7ad717f93ba0fc",
+    "none": "134a6214d02f81e3f7840479ae81f0aef7ede7163c581b09f861298fd7cd8f49",
+    "mlp": "f031d7ac4ced30aec1c025c256db0a648ca0a70f18dec2b7cc2b5e654254c026",
 }
 
 

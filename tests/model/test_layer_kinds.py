@@ -323,22 +323,24 @@ def test_the_held_part_is_the_plain_sum_over_its_pairs(mats, case, monkeypatch):
     choice, mask, sizes = _held_case(case)
 
     def tiled(x, mp, gate):
-        y, pairs, rows = moe_lib._held_experts(
+        y, pairs, rows, chunks = moe_lib._held_experts(
             x, mp, moe, act, jnp.float32, choice, gate, mask, mats)
-        return (y * jnp.cos(y)).sum(), (y, pairs, rows)
+        return (y * jnp.cos(y)).sum(), (y, pairs, rows, chunks)
 
     def plain(x, mp, gate):
         y = _plain_sum_over_pairs(x, tuple(mp[m] for m in mats), act, choice, gate, mask)
         return (y * jnp.cos(y)).sum(), y
 
     with jax.default_matmul_precision("highest"):
-        (_, (y, pairs, rows)), g = jax.value_and_grad(tiled, (0, 1, 2), has_aux=True)(x, mp, gate)
+        (_, (y, pairs, rows, chunks)), g = jax.value_and_grad(
+            tiled, (0, 1, 2), has_aux=True)(x, mp, gate)
         (_, want), g_want = jax.value_and_grad(plain, (0, 1, 2), has_aux=True)(x, mp, gate)
     np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=1e-5)
     for a, b in zip(jax.tree_util.tree_leaves(g), jax.tree_util.tree_leaves(g_want)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-5)
     assert float(pairs) == sum(sizes)
     assert float(rows) == sum(-(-n // _TILE) for n in sizes) * _TILE
+    assert float(chunks) == -(-float(rows) // (2 * _TILE))  # chunks of two tiles
     if case == "no-pair":
         assert float(rows) == 0 and not np.asarray(y).any()
 
@@ -358,7 +360,7 @@ def test_the_held_part_is_loops_of_a_run_time_count_and_its_row_scatters_are_sor
     choice, mask, _ = _held_case("random", T=T)
 
     def loss(x, mp, gate):
-        y, pairs, rows = moe_lib._held_experts(
+        y, pairs, rows, _ = moe_lib._held_experts(
             x, mp, moe, jax.nn.silu, jnp.float32, choice, gate, mask)
         return (y ** 2).sum()
 

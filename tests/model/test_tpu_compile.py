@@ -174,13 +174,15 @@ def test_a_train_step_of_one_row_holds_no_partial_of_dq(one_chip, monkeypatch):
     assert not re.search(r"attn_kernel/[^\n\"]*_splash_attention[^\n\"]*/reduce_sum", text)
 
 
-def _held_experts_compile(one_chip, T, D, F, n_held, k, act, mats):
+def _held_experts_compile(one_chip, T, D, F, n_held, k, act, mats, kernels=0):
     """`_held_experts` forward and backward for a described v5e, and what
     the held part must look like there: each way a loop over chunks
     around a loop over a chunk's tiles, their trip counts values of the
     run, no conditional and no grouped matmul, and in no loop body a
     `broadcast` as large as a tile's rows, the tokens or a weight stack
-    (a block of zeros for what did not run). Returns the compiler's
+    (a block of zeros for what did not run). `kernels`: the custom calls
+    the program holds (none where `_add_rows` is the scatter-add, as it is
+    unless a test steers `jax.default_backend`). Returns the compiler's
     temporaries in bytes."""
     import math
     import re
@@ -198,7 +200,7 @@ def _held_experts_compile(one_chip, T, D, F, n_held, k, act, mats):
     mask = _shape((T,), jnp.bool_, one_chip)
 
     def loss(x, mp, gate, choice, mask):
-        y, pairs, rows = moe_lib._held_experts(
+        y, pairs, rows, _ = moe_lib._held_experts(
             x, mp, moe, act, jnp.bfloat16, choice, gate, mask, mats)
         # a cotangent that is an array: a constant one would be made in the loop
         return (y.astype(jnp.float32) * x).sum() + pairs + rows
@@ -207,7 +209,7 @@ def _held_experts_compile(one_chip, T, D, F, n_held, k, act, mats):
         x, mp, gate, choice, mask).compile()
     text = compiled.as_text()
     assert " conditional(" not in text and "ragged-dot" not in text
-    assert "tpu_custom_call" not in text  # dense products, no kernel
+    assert text.count("tpu_custom_call") == kernels  # dense products, no kernel of their own
     bodies = set(re.findall(r" while\(.*?body=(%[\w.\-]+)", text))
     assert len(bodies) == 4  # chunks and a chunk's tiles, forward and backward
     comps = {m.group(1): c for c in text.split("\n\n")
@@ -263,6 +265,32 @@ def test_state_space_mixer_and_plain_experts_compile_at_the_published_widths(one
     # 0.85 GB where the parent's passes over a 7,680-row buffer took 0.93
     assert _held_experts_compile(one_chip, 16384, D, 1856, 8, 6, moe_lib.activation_fn("relu2"),
                                  ("w_in", "w_out")) < 0.9e9
+
+
+@pytest.mark.parametrize("d", [2048, 2688])
+def test_the_chunk_rows_kernel_compiles_at_the_published_widths(one_chip, d, monkeypatch):
+    """`_add_rows` as the chip runs it (`ops/pallas/segment_add.py`): a
+    chunk's rows in bf16 added into `[16384, d]` float32 at the chunk the
+    program runs, `d` of 16 and of 21 lane tiles (the trinity, joyai and
+    keye cells' and the nemotron cell's): one sort, one kernel, no
+    scatter; and float32 rows (an engine run in float32), whose block and
+    `Precision.HIGHEST` product must fit VMEM too."""
+    from areal_tpu.models import moe as moe_lib
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernel, not the scatter-add
+    b = moe_lib._HELD_CHUNK_ROWS
+    for dtype in (jnp.bfloat16, jnp.float32):
+        text = jax.jit(lambda *args: moe_lib._add_rows(*args)).lower(
+            _shape((16384, d), jnp.float32, one_chip), _shape((b, d), dtype, one_chip),
+            _shape((b,), jnp.int32, one_chip), _shape((), jnp.int32, one_chip)).compile().as_text()
+        assert text.count("tpu_custom_call") == 1 and "moe_rows_add" in text
+        assert " scatter(" not in text and text.count(" sort(") == 1
+    if d == 2048:
+        # the held part whole, as the trinity cell runs it on the chip: the
+        # kernel once in the forward's loop over chunks and once in the
+        # backward's, the float32 sums aliased through both (no copy of them)
+        _held_experts_compile(one_chip, 16384, d, 1024, 16, 8, jax.nn.silu,
+                              ("w_gate", "w_up", "w_down"), kernels=2)
 
 
 def test_selective_scan_kernels_compile_at_the_published_widths(one_chip):
