@@ -123,9 +123,21 @@ def ladder_rung(n_tokens: int, row_len_multiple: int = 128) -> int:
     a rung wastes under an eighth of the row, or under one multiple, and
     a doubling of the length adds eight compiled shapes."""
     n_tokens = max(int(n_tokens), 1)
-    step = row_len_multiple << max(
-        (n_tokens // row_len_multiple).bit_length() - 4, 0)
-    return _round_up(n_tokens, step)
+    return _round_up(n_tokens, _step_at(n_tokens, row_len_multiple))
+
+
+def _step_at(n_tokens: int, row_len_multiple: int) -> int:
+    """The ladder's step at a length of `n_tokens`."""
+    return row_len_multiple << max((n_tokens // row_len_multiple).bit_length() - 4, 0)
+
+
+def ladder_step(row_len: int, row_len_multiple: int = 128) -> int:
+    """The ladder's step up to the rung `row_len`: the fullest row of a
+    micro-batch packed that long holds more than `row_len - step` tokens
+    (`ladder_rung` would have taken a shorter rung otherwise), so its
+    padding is under one step. At a multiple of 128 a row of 16,384 has
+    a step of 1,024; at a multiple of 16,384 the step is the row."""
+    return _step_at(max(int(row_len), 1) - 1, row_len_multiple)
 
 
 def ladder_shape(

@@ -447,6 +447,7 @@ class JaxTrainEngine(TrainEngine):
                 output="logits" if is_critic else "hidden",
                 return_aux=sums,
                 mesh=mesh, mtp=mtp, index_loss=self._index_weight > 0,
+                bands=self._dead_bands(rows["input_ids"].shape[-1]),
             )
             if sums:
                 out, moe_aux = out
@@ -928,6 +929,7 @@ class JaxTrainEngine(TrainEngine):
                           + self._mtp_counts(rows, scored_fn)
                           + self._ssm_counts(rows["segment_ids"])
                           + self._index_counts(rows)
+                          + self._band_counts(rows["segment_ids"])
                           for a, rows in zip(attn, stacks)]
                 self._count_batch(
                     "fused", len(mbs), sum(b.n_rows == 1 for b, _ in built),
@@ -996,7 +998,8 @@ class JaxTrainEngine(TrainEngine):
                     counts = (*attn, *self._head_counts(rows, scored_fn),
                               *self._mtp_counts(rows, scored_fn),
                               *self._ssm_counts(rows["segment_ids"]),
-                              *self._index_counts(rows))
+                              *self._index_counts(rows),
+                              *self._band_counts(rows["segment_ids"]))
                     attn_attrs = dict(attn_row_len=run_len, width=width)
             return (rows_dev, denom, batch.total_tokens, cells, attn_attrs, counts)
 
@@ -1011,9 +1014,9 @@ class JaxTrainEngine(TrainEngine):
         # walked, live; the head's positions read, cells run, and the
         # prediction module's; the state-space scan's chunks, live, mixed,
         # and its resets; the indexers' cells scored, kept, and queries that
-        # choose; counted while tracing is on (`n_counted` of the
-        # micro-batches)
-        n_counts, n_counted = [0] * 16, 0
+        # choose; the cells the layers' token-wise stretches run; counted
+        # while tracing is on (`n_counted` of the micro-batches)
+        n_counts, n_counted = [0] * 17, 0
         gaps_ms: List[float] = []
         mark = time.monotonic_ns()
         try:
@@ -1090,6 +1093,37 @@ class JaxTrainEngine(TrainEngine):
         return tuple(self._n_indexed * int(c) for c in index_counts(
             np.asarray(rows_np["positions"]).astype(np.int64),
             np.asarray(rows_np["segment_ids"]), self.model_cfg.indexer.top_k))
+
+    def _dead_bands(self, row_len: int) -> bool:
+        """Whether a row of `row_len` cells, as `_build_rows` packs it, may
+        hold a band no token is in (what `forward(bands=)` is told): the
+        ladder's step up to that rung is longer than a band. At the
+        launcher's `row_len_multiple` of 128 a row of 16,384 pads under
+        1,024 cells and every band of it is live; at a multiple that is
+        the row, half of it may be empty."""
+        from areal_tpu.ops.band_loop import _BAND
+
+        return datapack.ladder_step(row_len, self.row_len_multiple) > _BAND
+
+    def _band_counts(self, segment_ids: np.ndarray) -> Tuple[int]:
+        """The cells the layers' token-wise stretches run (on the host,
+        before the transfer; `segment_ids` [R, T] of one micro-batch or [n,
+        R, T] of several), by the device's own rule
+        (`ops/band_loop.band_cells_run`: the bands of `_BAND` cells up to a
+        row's last token, for one row alone of two bands or more), a mean
+        over the stack's layers: one whose kind keeps the whole row
+        (`models/transformer.looping_layers`) counts every cell, and so
+        does a row the packer fills to the last band (`_dead_bands`)."""
+        from areal_tpu.models.transformer import looping_layers
+        from areal_tpu.ops.band_loop import band_cells_run
+
+        seg = np.asarray(segment_ids)
+        mbs = seg.reshape((-1,) + seg.shape[-2:])
+        n = self.model_cfg.n_layers
+        loop = looping_layers(
+            self.model_cfg, *mbs.shape[1:], sharded=self.mesh.size > 1
+        ) if self._dead_bands(mbs.shape[2]) else 0
+        return (sum((loop * band_cells_run(mb) + (n - loop) * mb.size) // n for mb in mbs),)
 
     def _attn_counts(self, segment_ids: np.ndarray) -> Tuple[int, ...]:
         """What the attention kernels do with packed rows (on the host,
@@ -1186,7 +1220,7 @@ class JaxTrainEngine(TrainEngine):
                      n_ssm_chunks: int = 0,
                      n_ssm_live: int = 0, n_ssm_mixed: int = 0, n_ssm_resets: int = 0,
                      n_index_cells: int = 0, n_index_selected: int = 0,
-                     n_index_choosing: int = 0):
+                     n_index_choosing: int = 0, n_band_cells: int = 0):
         """What one train_batch did, on its `train.batch` span and in the
         recorder's counters: the micro-batches and how many of them the
         packer made one row (what lets attention skip the block pairs
@@ -1201,14 +1235,16 @@ class JaxTrainEngine(TrainEngine):
         the (token, expert) pairs the routers of the expert
         layers made, the chunks the state-space layers' scan ran
         (the selective scan's also as positions: chunks x their length),
-        and the cells the indexers scored, those an exact choice keeps and
-        the queries that had more keys than they keep."""
+        the cells the indexers scored, those an exact choice keeps and
+        the queries that had more keys than they keep, and the cells the
+        layers' token-wise stretches ran (`_band_counts`)."""
         tracing.set_attrs(path=path, n_mbs=n_mbs, tokens=n_tok, cells=n_cells)
         tracing.count("train.batches")
         tracing.count("train.micro_batches", n_mbs)
         tracing.count("train.one_row_batches", n_one_row)
         tracing.count("train.tokens", n_tok)
         tracing.count("train.cells", n_cells)
+        tracing.count("train.band_cells", n_band_cells)
         tracing.count("train.attn_cells", n_attn_cells)
         tracing.count("train.attn_active_cells", n_attn_active)
         tracing.count("train.attn_causal_cells", n_attn_causal)
@@ -1383,6 +1419,7 @@ class JaxTrainEngine(TrainEngine):
                     attn_impl=self.attn_impl,
                     output="hidden" if fuse else "logits",
                     mesh=self.mesh if self.mesh.size > 1 else None,
+                    bands=self._dead_bands(rows["input_ids"].shape[-1]),
                 )
                 if fuse:
                     return fused_next_token_logprobs(

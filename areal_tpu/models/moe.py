@@ -162,16 +162,25 @@ def _router(xt, router_w, moe, expert_bias=None):
     return logits, probs, top_p, top_e
 
 
-def _router_stats(logits, probs, top_e, E):
-    """(f_e, P_e, load_balance, z, entropy) over this shard's tokens.
+def _router_stats(logits, probs, top_e, E, token_mask=None):
+    """(f_e, P_e, z, entropy) over this shard's tokens: the real ones
+    where `token_mask` says which they are (a padding cell's routing, or
+    the zeros a band no token is in reads, `ops/band_loop.stretch`, is no
+    one's to balance), every cell without.
 
     f_e is the per-expert fraction of (token, choice) routings — the
-    expert-load histogram surfaced in telemetry; load_balance is the
-    Switch loss E * sum_e f_e * P_e."""
-    f_e = jnp.mean(jax.nn.one_hot(top_e, E, dtype=jnp.float32), axis=(0, 1))
-    P_e = jnp.mean(probs, axis=0)
-    z = jnp.mean(jax.scipy.special.logsumexp(logits, axis=-1) ** 2)
-    entropy = jnp.mean(-jnp.sum(probs * jnp.log(probs + 1e-9), axis=-1))
+    expert-load histogram surfaced in telemetry; the Switch loss is
+    E * sum_e f_e * P_e."""
+    if token_mask is None:
+        mean = lambda a: jnp.mean(a, axis=tuple(range(a.ndim - 1)) if a.ndim > 1 else None)
+    else:
+        w = token_mask.reshape(-1).astype(jnp.float32)
+        w = w / jnp.maximum(jnp.sum(w), 1.0)
+        mean = lambda a: w @ (a if a.ndim < 3 else jnp.mean(a, axis=1))
+    f_e = mean(jax.nn.one_hot(top_e, E, dtype=jnp.float32))
+    P_e = mean(probs)
+    z = mean(jax.scipy.special.logsumexp(logits, axis=-1) ** 2)
+    entropy = mean(-jnp.sum(probs * jnp.log(probs + 1e-9), axis=-1))
     return f_e, P_e, z, entropy
 
 
@@ -614,15 +623,32 @@ def moe_mlp(
             )
         return _moe_mlp_ep(x, mp, cfg, cdt, mesh)
 
-    E, k = moe.num_experts, moe.top_k
     lead_shape = x.shape[:-1]
     D = x.shape[-1]
     xt = x.reshape(-1, D)
-    T = xt.shape[0]
-
     with jax.named_scope("moe_router"):
-        logits, probs, top_p, top_e = _router(
-            xt, mp["router"], moe, mp.get("expert_bias"))
+        routed = _router(xt, mp["router"], moe, mp.get("expert_bias"))
+    y, aux = after_router(xt, mp, cfg, cdt, routed, token_mask, mesh, dispatch, capacity_factor)
+    return y.reshape(*lead_shape, D), aux
+
+
+def after_router(xt, mp, cfg, cdt, routed, token_mask=None, mesh=None,
+                 dispatch=None, capacity_factor=None, shared=None):
+    """The layer from the router's `routed` (`_router`'s four) on, for the
+    tokens xt `[T, D]`: the routed experts' weighted sum plus the shared
+    expert's result, `[T, D]`, and the aux dict. `shared`: that result
+    where the caller has it (a stretch of the layer made it a band at a
+    time beside the router, `models/transformer._after_mixer`); made here
+    otherwise. A cell `token_mask` calls padding takes no part in what
+    crosses tokens: no expert's capacity, no held expert's tile, none of
+    the router's statistics; under dropless dispatch it is routed like a
+    token (static shapes) and read by no one."""
+    moe = cfg.moe
+    capacity_factor = moe.capacity_factor if capacity_factor is None else capacity_factor
+    dispatch = dispatch or moe.dispatch
+    E, k = moe.num_experts, moe.top_k
+    T, D = xt.shape
+    _, _, top_p, top_e = routed
     choice_e = top_e.T.reshape(-1)  # [k*T] expert ids, choice-major
     gate = top_p.T.reshape(-1)  # [kT], aligned with choice_e
     tok_idx = jnp.tile(jnp.arange(T), k)
@@ -657,9 +683,13 @@ def moe_mlp(
         # flattened (k, T) priority order (choice 0 of every token
         # first).
         onehot = jax.nn.one_hot(choice_e, E, dtype=jnp.int32)  # [kT, E]
+        if token_mask is not None:  # padding takes no expert's capacity
+            mask_k = jnp.tile(token_mask.reshape(-1), k)  # aligns choice_e
+            onehot = onehot * mask_k[:, None].astype(jnp.int32)
         pos_in_e = jnp.cumsum(onehot, axis=0) - onehot  # exclusive
         pos = jnp.sum(pos_in_e * onehot, axis=-1)  # [kT]
-        keep = pos < C
+        over = pos >= C
+        keep = ~over if token_mask is None else ~over & mask_k
 
         # dispatch [T, E, C] / combine [T, E, C]
         disp = jnp.zeros((T, E, C), bool)
@@ -680,9 +710,8 @@ def moe_mlp(
         # risk of the einsum formulation under router skew — surfaced in
         # train stats so it is measured, not assumed.
         if token_mask is not None:
-            mask_k = jnp.tile(token_mask.reshape(-1), k)  # aligns choice_e
             real = jnp.sum(mask_k.astype(jnp.float32))
-            dropped = jnp.sum((~keep & mask_k).astype(jnp.float32))
+            dropped = jnp.sum(over.astype(jnp.float32))  # padding is never over
             drop_rate = dropped / jnp.maximum(real, 1.0)
         else:
             # Clamp: XLA's mean (sum * approx-reciprocal) can round an
@@ -701,10 +730,12 @@ def moe_mlp(
                 jnp.float32,
             )
 
-    if "shared" in mp:
-        y = y + _shared_expert(xt, mp["shared"], act, cdt)
-    f_e, P_e, z, entropy = _router_stats(logits, probs, top_e, E)
-    return y.reshape(*lead_shape, D), {
+    if shared is None and "shared" in mp:
+        shared = _shared_expert(xt, mp["shared"], act, cdt)
+    if shared is not None:
+        y = y + shared
+    f_e, P_e, z, entropy = _router_stats(*routed[:2], top_e, E, token_mask)
+    return y, {
         "load_balance_loss": E * jnp.sum(f_e * P_e),
         "z_loss": z,
         "drop_rate": drop_rate,

@@ -65,6 +65,7 @@ import jax.numpy as jnp
 
 from areal_tpu.models.config import LayerKind, TransformerConfig
 from areal_tpu.models.moe import activation_fn
+from areal_tpu.ops import band_loop
 from areal_tpu.ops.attention import packed_attention, reference_packed_attention
 from areal_tpu.ops.norms import layer_norm, rms_norm
 from areal_tpu.ops.rotary import apply_rotary, rotary_cos_sin, rotary_inv_freq
@@ -469,30 +470,14 @@ def _index_proj(x, ip, cfg, cos, sin, cdt):
     return iq, ik, iw
 
 
-def _attention_block(
-    x, lp, cfg, cos, sin, segment_ids, positions, attn_impl, cdt, mesh=None,
-    variants=((None, True),), variant_index=None, l0=None, kv=None, index=None,
-):
-    """x: [R, T, D] -> attention output [R, T, D]. Named scopes say in
-    the device trace which part an op belongs to: `attn_qkv`
-    (projections, rotary; the caller's input norm too), `attn_kernel`
-    (the attention call), `attn_out`, and inside the first and the last
-    `attn_gate` (the gate's projection; its sigmoid times the kernel's
-    output). `variants` are the (window, rotary) pairs the layers of
-    this stack have: a window limits a token to the `window` positions
-    ending at it, no rotary leaves q and k without a position encoding.
-    One variant is called as it is; of several, `variant_index` (traced,
-    scanned beside the layer's parameters) picks the one that runs.
-    `l0` (the layer's lambda_init; None = plain attention) makes it
-    differential: `_diff_split` before the kernel, `_diff_combine`
-    (scope `attn_diff`) after. `kv` = another layer's k and v, as that
-    layer returned them: this layer then projects q only. With `index`
-    (`_Index`) the layer's indexer (`lp["indexer"]`, `_index_proj`)
-    chooses the keys each query reads (`ops/indexer.indexed_attention`),
-    and the layer's sums come back as a third result (empty without)."""
-    from areal_tpu.ops.attention import resolve_attn_impl
-
+def _attn_in(x, lp, cfg, cdt, kv=None):
+    """An attention layer's projections of its normed input x `[R, T, D]`
+    (scope `attn_qkv`): q `[R, T, Hq, hd]`, k and v `[R, T, Hkv, hd]` (or
+    `kv`, another layer's), q and k under their norms where the layer
+    has them, the gate's projection (scope `attn_gate`; None without),
+    and (k, v) as they were before the norm."""
     R, T, D = x.shape
+    gate = None
     with jax.named_scope("attn_qkv"):
         q = x @ lp["wq"].astype(cdt)
         if kv is None:
@@ -516,7 +501,37 @@ def _attention_block(
         if "wg" in lp:
             with jax.named_scope("attn_gate"):
                 gate = x @ lp["wg"].astype(cdt)
+    return q, k, v, gate, own_kv
 
+
+def _attn_out(out, gate, lp, cfg, cdt, l0=None):
+    """The attention call's output `[R, T, Hq, hd]` -> the layer's `[R,
+    T, D]`: differential attention's combine (`l0`), the gate, the output
+    projection (scopes `attn_diff`, `attn_out` > `attn_gate`)."""
+    if l0 is not None:
+        with jax.named_scope("attn_diff"):
+            out = _diff_combine(out, lp, l0, cfg.norm_eps)
+    with jax.named_scope("attn_out"):
+        out = out.reshape(out.shape[:2] + (cfg.q_dim,))
+        if gate is not None:
+            with jax.named_scope("attn_gate"):
+                out = out * jax.nn.sigmoid(gate)
+        out = out @ lp["wo"].astype(cdt)
+        if "bo" in lp:
+            out = out + lp["bo"].astype(cdt)
+    return out
+
+
+def _attn_core(q, k, v, cfg, cos, sin, segment_ids, positions, attn_impl, mesh,
+               variants, variant_index, diff, index, ix, rotated=False):
+    """What of an attention layer crosses tokens: rotary (unless q and k
+    come `rotated`) and the attention call of the (window, rotary)
+    variant that runs, differential attention's split before it, the
+    indexer's choice (`ix`: its three projections) inside it. Returns
+    the call's output `[R, T, Hq, hd]`, k as attended, the layer's sums."""
+    from areal_tpu.ops.attention import resolve_attn_impl
+
+    R, T = q.shape[:2]
     # Resolution is mesh-aware: a seq>1 mesh picks a CP scheme for
     # 'auto' (Ulysses when heads divide the seq axis, ring otherwise)
     # before the local-kernel choice, and a kernel with no shard_map
@@ -535,13 +550,13 @@ def _attention_block(
                 "and have no threshold over all of them, nor a mask operand")
         if mesh is not None and mesh.size > 1 and impl == "splash":
             impl = "reference"  # the plain form partitions; the kernels are one chip's
-        iq, ik, iw = _index_proj(x, lp["indexer"], cfg, index.cos, index.sin, cdt)
+        iq, ik, iw = ix
 
     def attend(window, rotary):
         """q, k, v -> (attention output [R, T, Hq, hd], k as attended)."""
 
         def run(q, k, v):
-            if rotary and cos is not None:  # None = learned pos emb
+            if rotary and cos is not None and not rotated:  # None = learned pos emb
                 with jax.named_scope("attn_qkv"):
                     q = apply_rotary(q, cos, sin, cfg.rotary_interleaved)
                     k = apply_rotary(k, cos, sin, cfg.rotary_interleaved)
@@ -562,7 +577,7 @@ def _attention_block(
 
         return run
 
-    if l0 is not None:
+    if diff:
         with jax.named_scope("attn_qkv"):
             q, k, v = _diff_split(q, k, v)
     if len(variants) == 1:
@@ -570,34 +585,45 @@ def _attention_block(
     else:
         out, k = jax.lax.switch(
             variant_index, [attend(*vt) for vt in variants], q, k, v)
+    return out, k, sums
+
+
+def _attention_block(
+    x, lp, cfg, cos, sin, segment_ids, positions, attn_impl, cdt, mesh=None,
+    variants=((None, True),), variant_index=None, l0=None, kv=None, index=None,
+):
+    """x: [R, T, D] -> attention output [R, T, D]. Named scopes say in
+    the device trace which part an op belongs to: `attn_qkv`
+    (projections, rotary; the caller's input norm too), `attn_kernel`
+    (the attention call), `attn_out`, and inside the first and the last
+    `attn_gate` (the gate's projection; its sigmoid times the kernel's
+    output). `variants` are the (window, rotary) pairs the layers of
+    this stack have: a window limits a token to the `window` positions
+    ending at it, no rotary leaves q and k without a position encoding.
+    One variant is called as it is; of several, `variant_index` (traced,
+    scanned beside the layer's parameters) picks the one that runs.
+    `l0` (the layer's lambda_init; None = plain attention) makes it
+    differential: `_diff_split` before the kernel, `_diff_combine`
+    (scope `attn_diff`) after. `kv` = another layer's k and v, as that
+    layer returned them: this layer then projects q only. With `index`
+    (`_Index`) the layer's indexer (`lp["indexer"]`, `_index_proj`)
+    chooses the keys each query reads (`ops/indexer.indexed_attention`),
+    and the layer's sums come back as a third result (empty without)."""
+    q, k, v, gate, own_kv = _attn_in(x, lp, cfg, cdt, kv)
+    ix = None if index is None else _index_proj(
+        x, lp["indexer"], cfg, index.cos, index.sin, cdt)
+    out, k, sums = _attn_core(
+        q, k, v, cfg, cos, sin, segment_ids, positions, attn_impl, mesh,
+        variants, variant_index, l0 is not None, index, ix)
     if l0 is not None:
-        with jax.named_scope("attn_diff"):
-            out = _diff_combine(out, lp, l0, cfg.norm_eps)
         k, v = own_kv
-    with jax.named_scope("attn_out"):
-        out = out.reshape(R, T, cfg.q_dim)
-        if "wg" in lp:
-            with jax.named_scope("attn_gate"):
-                out = out * jax.nn.sigmoid(gate)
-        out = out @ lp["wo"].astype(cdt)
-        if "bo" in lp:
-            out = out + lp["bo"].astype(cdt)
+    out = _attn_out(out, gate, lp, cfg, cdt, l0)
     return out, (k, v), sums
 
 
-def _latent_attention_block(x, lp, cfg, cos, sin, segment_ids, positions,
-                            attn_impl, cdt, mesh=None):
-    """x: [R, T, D] -> latent attention's output [R, T, D] and its (k, v)
-    (`config.MLAConfig` has the equations): the materialised form, k and
-    v a head, as a training or prefill pass runs it. Scopes `mla_q_proj`
-    and `mla_kv_proj` inside `attn_qkv` hold the low-rank projections
-    with their norms; rotary (`cos`, `sin` of `rope_dim / 2`) turns q's
-    rope part a head and the one rope key a token, which every head's k
-    ends with. The kernel is the plain block's, called with q and k of
-    `nope_dim + rope_dim` against v of `v_dim`; its softmax scale is
-    that q and k size's."""
-    from areal_tpu.ops.attention import resolve_attn_impl
-
+def _latent_in(x, lp, cfg, cos, sin, cdt):
+    """Latent attention's q and k `[R, T, H, nope + rope]` and v `[R, T,
+    H, v_dim]` of the layer's normed input x (scope `attn_qkv`)."""
     R, T, _ = x.shape
     m, H = cfg.mla, cfg.n_q_heads
     with jax.named_scope("attn_qkv"):
@@ -616,12 +642,159 @@ def _latent_attention_block(x, lp, cfg, cos, sin, segment_ids, positions,
         q = jnp.concatenate([q_nope, q_r], axis=-1)
         k = jnp.concatenate(
             [k_nope, jnp.broadcast_to(k_r, (R, T, H, m.rope_dim))], axis=-1)
-    impl = resolve_attn_impl(attn_impl, T, H, H, mesh=mesh, r=R)
-    with jax.named_scope("attn_kernel"):
-        out = _attention_kernel(q, k, v, segment_ids, positions, impl, cfg, mesh, None)
+    return q, k, v
+
+
+def _latent_out(out, lp, cdt):
+    """The attention call's output `[R, T, H, v_dim]` through latent
+    attention's output projection (scope `attn_out`)."""
     with jax.named_scope("attn_out"):
-        out = out.reshape(R, T, H * m.v_dim) @ lp["wo"].astype(cdt)
-    return out, (k, v)
+        return out.reshape(out.shape[:2] + (-1,)) @ lp["wo"].astype(cdt)
+
+
+def _latent_attention_block(x, lp, cfg, cos, sin, segment_ids, positions,
+                            attn_impl, cdt, mesh=None):
+    """x: [R, T, D] -> latent attention's output [R, T, D] and its (k, v)
+    (`config.MLAConfig` has the equations): the materialised form, k and
+    v a head, as a training or prefill pass runs it. Scopes `mla_q_proj`
+    and `mla_kv_proj` inside `attn_qkv` hold the low-rank projections
+    with their norms; rotary (`cos`, `sin` of `rope_dim / 2`) turns q's
+    rope part a head and the one rope key a token, which every head's k
+    ends with. The kernel is the plain block's, called with q and k of
+    `nope_dim + rope_dim` against v of `v_dim`; its softmax scale is
+    that q and k size's."""
+    q, k, v = _latent_in(x, lp, cfg, cos, sin, cdt)
+    out = _latent_core(q, k, v, cfg, segment_ids, positions, attn_impl, mesh)
+    return _latent_out(out, lp, cdt), (k, v)
+
+
+def _latent_core(q, k, v, cfg, segment_ids, positions, attn_impl, mesh):
+    """Latent attention's kernel call (scope `attn_kernel`): every head
+    has its own k and v."""
+    from areal_tpu.ops.attention import resolve_attn_impl
+
+    H = cfg.n_q_heads
+    impl = resolve_attn_impl(attn_impl, q.shape[1], H, H, mesh=mesh, r=q.shape[0])
+    with jax.named_scope("attn_kernel"):
+        return _attention_kernel(q, k, v, segment_ids, positions, impl, cfg, mesh, None)
+
+
+class _Stretch(NamedTuple):
+    """What a layer's stretches (`ops/band_loop.stretch`) do not trace,
+    hashable: the configuration (by identity), the layer's kind, the
+    compute dtype, and whether rotary turns q and k inside the first
+    stretch (every layer of the scan has it)."""
+    cfg: TransformerConfig
+    kind: LayerKind
+    cdt: Any
+    rot_in: bool = False
+
+
+# Of a plain attention layer's parameters, those its first stretch reads;
+# its second reads the rest (latent attention: all but `wo`, and `wo`).
+_ATTN_IN = ("wq", "wk", "wv", "bq", "bk", "bv", "q_norm", "k_norm", "wg", "indexer")
+
+
+def _mixer_weights(st: _Stretch, mp, first: bool):
+    """The part of an attention layer's parameters `mp` that its first
+    stretch reads, or its second."""
+    first_names = set(mp) - {"wo"} if st.kind.latent else _ATTN_IN
+    return {n: w for n, w in mp.items() if (n in first_names) == first}
+
+
+def _before_mixer(st: _Stretch, w, xs, side):
+    """A layer's first stretch: its input x under `ln1` and attention's
+    projections of that, with what is token-wise after them (q/k norm,
+    the gate's and the indexer's projections, rotary where every layer
+    of the scan has it). `side`: the rotary tables, then the indexer's.
+    Returns q, k, v, (the gate's projection), (the indexer's three)."""
+    cfg, kind, cdt = st.cfg, st.kind, st.cdt
+    (x,), mp = xs, w["mixer"]
+    h = _norm(x, w["ln1"], cfg)
+    if kind.latent:
+        return _latent_in(h, mp, cfg, side[0], side[1], cdt)
+    q, k, v, gate, _ = _attn_in(h, mp, cfg, cdt)
+    if st.rot_in:
+        q = apply_rotary(q, side[0], side[1], cfg.rotary_interleaved)
+        k = apply_rotary(k, side[0], side[1], cfg.rotary_interleaved)
+    out = (q, k, v) if gate is None else (q, k, v, gate)
+    if kind.indexed:
+        out += _index_proj(h, mp["indexer"], cfg, side[-2], side[-1], cdt)
+    return out
+
+
+def _after_mixer(st: _Stretch, w, xs, side):
+    """A layer's second stretch, from the attention call's output (then
+    the gate's projection; the layer's input last) to the MLP's product
+    or the routed experts' doorstep: the output projection under the
+    gate, the residual, `ln2`, and the dense MLP with its residual, or
+    the router and the shared expert. Returns the stream, then for an
+    expert layer the experts' input, the router's four and the shared
+    expert's result."""
+    cfg, kind, cdt = st.cfg, st.kind, st.cdt
+    *got, x = xs
+    if kind.latent:
+        a = _latent_out(got[0], w["mixer"], cdt)
+    else:
+        a = _attn_out(got[0], got[1] if len(got) > 1 else None, w["mixer"], cfg, cdt)
+    if "ln1_post" in w:
+        a = _norm(a, w["ln1_post"], cfg)
+    x = x + a
+    with jax.named_scope("mlp"):
+        h = _norm(x, w["ln2"], cfg)
+        if kind.mlp == "dense":
+            m = _mlp(h, w["mlp"], cfg, cdt)
+            if "ln2_post" in w:
+                m = _norm(m, w["ln2_post"], cfg)
+            return (x + m,)
+        from areal_tpu.models.moe import _router, _shared_expert
+
+        ht = h.reshape(-1, h.shape[-1])
+        with jax.named_scope("moe_router"):
+            routed = _router(ht, w["mlp"]["router"], cfg.moe, w["mlp"].get("expert_bias"))
+        out = (x, h) + tuple(a.reshape(h.shape[:2] + a.shape[1:]) for a in routed)
+        if "shared" in w["mlp"]:
+            out += (_shared_expert(ht, w["mlp"]["shared"], activation_fn(cfg.activation),
+                                   cdt).reshape(h.shape),)
+        return out
+
+
+def _kind_loops(kind: LayerKind) -> bool:
+    """Whether a layer of `kind` runs as stretches over live bands where
+    the call's shape allows: a plain, latent or indexed attention mixer
+    with an MLP beside it. By the probe (`scripts/band_loop_probe.py`;
+    PERF.md section 6, PR 45) such a layer takes 21-32 % off a half-empty
+    row and loses 0-3.5 % of a full one; a layer of one part (a
+    state-space mixer, experts or attention alone) and a scan or memory
+    unit with its MLP lose 7-14 % of a full row for 9-22 % off a
+    half-empty one, and differential attention's three kinds, each walked
+    outside a scan, are the most to trace in the stack with the least
+    set-up to spare. (`looping_layers`: and only inside a scan.) The
+    figures of the kinds ruled out were taken with looping bodies that
+    went with this rule: the probe in the tree re-measures the kinds that
+    loop."""
+    return (kind.mixer == "attention" and kind.mlp is not None
+            and not kind.diff and kind.reads is None)
+
+
+def looping_layers(cfg: TransformerConfig, n_rows: int, row_len: int,
+                   sharded: bool = False) -> int:
+    """How many of the stack's layers walk their live bands in a call of
+    `n_rows` rows of `row_len` cells: none on a mesh that splits rows or
+    the sequence (`sharded`), none for rows together or a row under two
+    bands (`ops/band_loop.loops`), else the layers of a scan
+    (`cfg.segments()`: a unit that repeats) whose kind takes the loop. A
+    layer that runs once (a leading dense layer, a prediction module's)
+    keeps the whole row: outside a scan a looping layer cost 3 s of
+    tracing and lowering a cell on the chip's host (trinity's one +3.6,
+    joyai's two +5.2 to +6.7 s of warm `setup_build_s`, keye's none -0.3
+    to -0.9: PERF.md section 6, PR 45), where a scan's one traced body
+    serves every repeat."""
+    if sharded or not band_loop.loops(n_rows, row_len):
+        return 0
+    kinds = cfg.kinds()
+    return sum(_kind_loops(kinds[i]) for seg in cfg.segments() if seg.repeats > 1
+               for i in range(seg.start, seg.start + len(seg.unit) * seg.repeats))
 
 
 def _segment_stacks(params, cfg: TransformerConfig):
@@ -690,6 +863,7 @@ def forward(
     mtp: bool = False,  # also run the prediction module (cfg.mtp)
     index_loss: bool = False,  # also sum the indexers' KL terms (cfg.indexer)
     index_choice: bool = False,  # also return what the indexers chose
+    bands: bool = False,  # True: the caller's packer may leave a band of a row empty
 ) -> Any:
     """Packed-rows forward pass.
 
@@ -706,6 +880,13 @@ def forward(
     not a step's: the layers then run one by one, without remat) the
     last result is (choice bool `[L, R, T, T]`, tau `[L, R, T]`) of the
     indexed layers in order.
+
+    With `bands` one row alone of two bands or more (`ops/band_loop.loops`)
+    runs its layers' token-wise stretches over the bands its tokens reach
+    (`looping_layers`). For the caller to say, who knows its packer: one
+    whose ladder steps by a band or less at this row length
+    (`base/datapack.ladder_step`) fills every band of such a row, and the
+    loop would only cost (0 to 6 % of a full row).
 
     When `mesh` is given, activations are pinned to
     P((data, fsdp), seq, None) and logits to P((data, fsdp), seq, tensor)
@@ -826,12 +1007,18 @@ def forward(
             h, mp, cfg, cdt, token_mask=moe_token_mask, mesh=mesh
         )
     dense_fn = lambda h, mp: _mlp(h, mp, cfg, cdt)
+    # One row of two bands or more, alone on its chip, walks the bands its
+    # tokens reach in every token-wise stretch of its layers.
+    n_live = None
+    if bands and not return_kv and looping_layers(
+            cfg, *input_ids.shape, sharded=mesh is not None and mesh.size > 1):
+        n_live = band_loop.live_bands(segment_ids)
     if remat_mode == "mlp":
         dense_fn = jax.checkpoint(dense_fn)
         if use_moe:
             moe_fn = jax.checkpoint(moe_fn)
 
-    def layer_body(kind, variants):
+    def layer_body(kind, variants, scanned=False):
         """carry, (one layer's parameters, which of `variants` it is),
         the tensor it reads -> carry, its (k, v) (for a layer that keeps:
         what it keeps): a layer with the parts of `kind` whose
@@ -907,6 +1094,62 @@ def forward(
                     x = act_c(x + m)
             return (x, aux_acc), kv if return_kv or kind.keeps else None
 
+        def looped(carry, xs, kept=None):
+            """`body` for a row that walks its live bands (`_kind_loops`: an
+            attention mixer and an MLP): the same layer as two stretches
+            (`_before_mixer`, `_after_mixer`) around what crosses tokens."""
+            lp, variant_index = xs
+            x, aux_acc = carry
+            mp = lp["attn"]
+            st = _Stretch(cfg, kind, cdt, rot_in=not kind.latent and cos is not None
+                          and all(rotary for _, rotary in variants))
+            run = lambda fn, w, xs, side=(): band_loop.stretch(fn, st, w, xs, side, n_live)
+            side = (() if cos is None else (cos, sin)) + (
+                (index.cos, index.sin) if kind.indexed else ())
+            q, k, v, *mid = run(
+                _before_mixer, {"ln1": lp["ln1"], "mixer": _mixer_weights(st, mp, True)},
+                (x,), side)
+            if kind.latent:
+                got = (_latent_core(q, k, v, cfg, segment_ids, positions, attn_impl, mesh),)
+            else:
+                gate = (mid.pop(0),) if "wg" in mp else ()
+                out, k, sums = _attn_core(
+                    q, k, v, cfg, cos, sin, segment_ids, positions, attn_impl, mesh,
+                    variants, variant_index, False, index if kind.indexed else None,
+                    tuple(mid), rotated=st.rot_in)
+                if sums:
+                    aux_acc = {**aux_acc, **{n: aux_acc[n] + a for n, a in sums.items()}}
+                got = (out,) + gate
+            after = {n: lp[n] for n in ("ln1_post", "ln2", "ln2_post") if n in lp}
+            after["mixer"] = _mixer_weights(st, mp, False)
+            if kind.mlp == "dense":
+                after["mlp"] = lp["mlp"]
+            else:  # its post-norm follows the experts' sum
+                post = after.pop("ln2_post", None)
+                after["mlp"] = {n: lp["mlp"][n] for n in ("router", "expert_bias", "shared")
+                                if n in lp["mlp"]}
+            x, *rest = run(_after_mixer, after, got + (x,))
+            if kind.mlp == "moe":
+                from areal_tpu.models.moe import after_router
+
+                h, *routed = (a.reshape(a.shape[1:]) for a in rest)
+                shared = routed.pop() if "shared" in lp["mlp"] else None
+                with jax.named_scope("mlp"):
+                    # the shared expert's result joins the experts' over the
+                    # whole row: a sum, of zeros where no token is (no pair,
+                    # and the stream and that result are zeros there); a
+                    # third loop would cost set-up more than the sum costs
+                    # the chip
+                    m, aux = after_router(h, lp["mlp"], cfg, cdt, tuple(routed),
+                                          moe_token_mask, mesh, shared=shared)
+                    aux_acc = {n: aux_acc[n] + aux[n] if n in aux else aux_acc[n]
+                               for n in aux_acc}
+                    m = m.reshape(x.shape)
+                    x = x + (m if post is None else _norm(m, post, cfg))
+            return (x, aux_acc), (k, v) if kind.keeps else None
+
+        if n_live is not None and scanned and _kind_loops(kind):
+            body = looped
         if remat_mode == "full":
             return jax.checkpoint(body)
         if remat_mode == "save_attn":
@@ -939,7 +1182,7 @@ def forward(
             of_j = [kinds[i] for i in idx]
             rest = [(k.window, k.rotary) for k in of_j]
             variants = tuple(sorted(set(rest), key=rest.index))
-            bodies.append(layer_body(of_j[0], variants))
+            bodies.append(layer_body(of_j[0], variants, seg.repeats > 1))
             w = None if len(variants) == 1 else jnp.asarray(
                 [variants.index(v) for v in rest], jnp.int32)
             if of_j[0].diff:

@@ -1,0 +1,160 @@
+"""A token-wise stretch of a layer over the bands of a row that hold a token.
+
+A packed row's tokens are a prefix (`models/packing.pack_sequences` fills
+a row from cell 0 and leaves its padding at the tail), and what a layer
+does to every cell alone (a norm, a projection, an MLP, a router, a
+residual: row `i` of the result depends on row `i` of the inputs and on
+the weights) does the same work for a padding cell as for a token.
+`stretch` runs such a function over *bands* of `_BAND` consecutive
+cells, the first `live_bands` of them and no others: one `fori_loop`
+whose trip count is a value of the run, as the loss head
+(`ops/loss._scored_logprobs`), the held experts (`models/moe._run_tiles`)
+and attention (`ops/pallas/splash_pairs.py`) already walk what they run.
+A band past them costs nothing and reads zero on the way out: what
+crosses tokens afterwards (a scan, a convolution's taps) finds zeros
+there, not what memory held.
+
+The backward pass is a second loop of the same count, written by hand
+(`jax.custom_vjp`). The forward rule keeps the stretch's inputs and
+nothing of a band; the backward loop makes a band's `jax.vjp` again from
+its cells, pulls the band's cotangents back and adds the band's weight
+gradients into float32 sums (the compiler fuses the add into the product
+that makes them), cast once where the loop ends. (A forward rule that kept
+each band's products a band a slot, for the backward loop to start from,
+bought 0.25-0.46 % of `train_tokens_per_s` in the three cells that loop,
+every one of them under `remat` full, for a hundred lines that walked the
+band's jaxpr: PERF.md section 6, PR 45, after review.)
+
+One function, jitted at module level with the stretch's function and
+its static description as static arguments: the second layer of a kind,
+and the second and third program of a process (a train step is traced
+for the first micro-batch, the next, and a forward pass beside them),
+find the stretch traced, differentiated and transposed in jax's own
+caches.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Any, Callable, Sequence, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+# Cells a band. A shorter band runs fewer empty cells in the last band a
+# row's tokens reach (8.6k tokens of 16,384 are 9 bands of 1,024 = 9.2k
+# cells, 5 of 2,048 = 10.2k) and reads the stretch's weights, and adds
+# into their float32 gradient sums, once more a band. Measured on the
+# chip (`scripts/band_loop_probe.py`; PERF.md section 6, PR 45), one layer
+# at 8,600 tokens of 16,384, forward + backward ms at bands of 512 / 1,024
+# / 2,048: trinity's expert layer 60.6 / 61.6 / 64.4, its dense layer 58.1
+# / 55.9 / 59.2; Qwen's layer 30.7 at 1,024 against 32.6 at 2,048; a full
+# row reads the same at all three. One constant for every stack.
+_BAND = 1024
+
+
+def loops(n_rows: int, row_len: int) -> bool:
+    """Whether a call of `n_rows` rows of `row_len` cells walks its live
+    bands: one row (several rows' tokens are no prefix of the call's
+    cells) of two bands or more, as `ops/attention._rows_skip` says for
+    the pair kernels."""
+    return n_rows == 1 and row_len >= 2 * _BAND and row_len % _BAND == 0
+
+
+def live_bands(segment_ids) -> jnp.ndarray:
+    """Bands of the one row `segment_ids` `[1, T]` up to its last token
+    (segment id > 0), int32: `ceil(tokens / _BAND)` for a packed row."""
+    T = segment_ids.shape[-1]
+    last = jnp.max(jnp.where(segment_ids.reshape(-1) > 0,
+                             jnp.arange(1, T + 1, dtype=jnp.int32), 0))
+    return (last + _BAND - 1) // _BAND
+
+
+def band_cells_run(segment_ids: np.ndarray) -> int:
+    """Cells the stretches of one micro-batch `[R, T]` run, on the host by
+    the device's rule: live bands x `_BAND` where the call loops, every
+    cell where it runs whole."""
+    seg = np.asarray(segment_ids)
+    if seg.ndim != 2 or not loops(*seg.shape):
+        return int(seg.size)
+    (live,) = np.nonzero(seg[0] > 0)
+    return int(-(-(live[-1] + 1) // _BAND) * _BAND) if live.size else 0
+
+
+def _cut(arrays, i):
+    """Band i of each `[1, T, ...]` array."""
+    return tuple(jax.lax.dynamic_slice_in_dim(a, i * _BAND, _BAND, axis=1) for a in arrays)
+
+
+def _put(bufs, bands, i):
+    """Each `[1, T, ...]` buffer with its band written as band i."""
+    return tuple(jax.lax.dynamic_update_slice_in_dim(b, a, i * _BAND, axis=1)
+                 for b, a in zip(bufs, bands))
+
+
+def _row_zeros(avals, T):
+    """`[1, T, ...]` zeros for bands of `avals`: what a band that does not
+    run reads. (Zeros written into the dead bands alone, band by band
+    after the loop, cost the chip as much as zeros over the whole row and
+    a second loop a stretch to trace, lower and compile.)"""
+    return tuple(jnp.zeros((1, T) + a.shape[2:], a.dtype) for a in avals)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _stretch(fn, static, weights, xs, side, n_live):
+    band = lambda i: tuple(fn(static, weights, _cut(xs, i), _cut(side, i)))
+    return jax.lax.fori_loop(
+        0, n_live, lambda i, outs: _put(outs, band(i), i),
+        _row_zeros(jax.eval_shape(band, 0), xs[0].shape[1]))
+
+
+def _stretch_fwd(fn, static, weights, xs, side, n_live):
+    return _stretch(fn, static, weights, xs, side, n_live), (weights, xs, side, n_live)
+
+
+def _stretch_bwd(fn, static, res, d_outs):
+    weights, xs, side, n_live = res
+    # an integer output's cotangent is float0: no one's to hand in
+    d_outs = tuple(d for d in d_outs if d.dtype != jax.dtypes.float0)
+
+    def body(i, carry):
+        dws, dxs = carry
+        s = _cut(side, i)
+        outs, vjp = jax.vjp(lambda w, *x: tuple(fn(static, w, x, s)), weights, *_cut(xs, i))
+        d_band = iter(_cut(d_outs, i))
+        dw, *dx = vjp(tuple(
+            next(d_band) if jnp.issubdtype(a.dtype, jnp.inexact)
+            else np.zeros(a.shape, jax.dtypes.float0) for a in outs))
+        dws = jax.tree_util.tree_map(lambda a, g: a + g.astype(a.dtype), dws, dw)
+        return dws, _put(dxs, dx, i)
+
+    dws, dxs = jax.lax.fori_loop(0, n_live, body, (
+        jax.tree_util.tree_map(lambda w: jnp.zeros(w.shape, jnp.float32), weights),
+        tuple(jnp.zeros_like(x) for x in xs)))
+    # Cast here and now, before anything reads the cells' cotangents: left
+    # to the compiler the cast joins whatever reads the gradient last (a
+    # layer that runs outside a scan hands it to the step's end), and a
+    # stretch's float32 sums stay until then.
+    dws, dxs = jax.lax.optimization_barrier(
+        (jax.tree_util.tree_map(lambda a, w: a.astype(w.dtype), dws, weights), dxs))
+    return dws, dxs, None, None
+
+
+_stretch.defvjp(_stretch_fwd, _stretch_bwd)
+
+_stretch_jit = jax.jit(_stretch, static_argnums=(0, 1))
+
+
+def stretch(fn: Callable, static: Any, weights: Any, xs: Sequence[jnp.ndarray],
+            side: Sequence[jnp.ndarray], n_live) -> Tuple[jnp.ndarray, ...]:
+    """`fn(static, weights, xs, side)` over the first `n_live` bands of the
+    one row: `xs` and `side` are tuples of `[1, T, ...]` arrays of which
+    `fn` sees a band `[1, _BAND, ...]` each, and returns a tuple of such
+    arrays; the result is those as `[1, T, ...]`, zero past the live
+    bands. `fn` and `static` are hashable (a module-level function and a
+    tuple of what it does not trace): they key the one trace. Gradients
+    flow to `weights` (a pytree of floating arrays, the same for every
+    band; summed in float32 across bands) and to `xs`; `side` gets none
+    (positions' tables)."""
+    return _stretch_jit(fn, static, weights, tuple(xs), tuple(side), n_live)

@@ -7,6 +7,7 @@ the prefetcher's thread under the batch's trace, tokens <= cells, and
 import threading
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -126,7 +127,9 @@ def test_attributes_and_counters_count_tokens_and_cells(recorded):
         "train.attn_grid_steps": 0, "train.attn_live_steps": 0,
         # no `scored_fn`: the head reads every token but a sequence's
         # last (9 sequences) and runs over every cell
-        "train.scored_cells": a["tokens"] - 9, "train.head_cells": a["cells"]}
+        "train.scored_cells": a["tokens"] - 9, "train.head_cells": a["cells"],
+        # rows under two bands: the layers' token-wise stretches run whole
+        "train.band_cells": a["cells"]}
     assert all("window" not in d and "kinds" not in d for d in dispatches)
     kinds = [s["attrs"]["kind"] for s in spans if s["name"] == "train.dispatch"]
     assert kinds == (["first"] + ["next"] * (N_MBS - 1) if path == "overlapped"
@@ -221,6 +224,54 @@ def test_attn_cells_count_the_length_the_kernel_runs_at(impl):
         assert c["train.attn_live_steps"] == live * cfg.n_q_heads * cfg.n_layers
     else:
         assert c["train.attn_grid_steps"] == c["train.attn_live_steps"] == d["width"] == 0
+
+
+def test_band_cells_are_the_cells_the_devices_loops_run(monkeypatch):
+    """A row of 640 at bands of 128 holding 300 tokens: the host counts
+    three bands of five (`train.band_cells` 384 beside `train.cells` 640)
+    by the rule the device's trip count is made from, and two rows
+    together count every cell."""
+    from areal_tpu.ops import band_loop
+
+    monkeypatch.setattr(band_loop, "_BAND", 128)
+    jax.clear_caches()
+    eng = mk_engine(init_params(small_cfg(), jax.random.PRNGKey(7)), depth=0,
+                    attn_impl="reference")
+    eng.row_len_multiple = 640
+    rng = np.random.RandomState(7)
+
+    def counters(seqlens):
+        total = sum(seqlens)
+        batch = SequenceSample.from_default(
+            ids=[f"b{i}" for i in range(len(seqlens))], seqlens=seqlens,
+            data={"packed_input_ids": rng.randint(0, 64, size=total),
+                  "loss_mask": np.ones(total, np.float32)})
+        tracing.start()
+        try:
+            eng.train_batch(batch, MicroBatchSpec(n_mbs=1), packed_loss, loss_weight,
+                            loss_name="t")
+        finally:
+            got = tracing.stop()
+        [d] = [s["attrs"] for s in got["spans"] if s["name"] == "train.dispatch"]
+        return d, got["counters"]
+
+    d, c = counters([200, 100])
+    assert (d["rows"], d["row_len"]) == (1, 640)
+    seg = np.zeros((1, 640), np.int32)
+    seg[0, :300] = 1
+    assert c["train.band_cells"] == 128 * int(band_loop.live_bands(jnp.asarray(seg))) == 384
+    assert c["train.cells"] == 640 and c["train.tokens"] == 300
+    # a ladder that steps by a band or less fills a row's every band: at a
+    # multiple of 16 a row of 512 pads under 32 cells, and runs whole
+    eng.row_len_multiple = 16
+    d, c = counters([300, 190])
+    assert (d["rows"], d["row_len"]) == (1, 512) and band_loop.loops(1, 512)
+    assert not eng._dead_bands(512) and c["train.band_cells"] == c["train.cells"] == 512
+    eng.row_len_multiple = 640
+    # rows together, a row under two bands and micro-batches stacked: by shape
+    assert eng._band_counts(np.tile(seg, (2, 1))) == (1280,)
+    assert eng._band_counts(seg[:, :128]) == (128,)
+    assert eng._band_counts(np.stack([seg, np.roll(seg, 200, axis=1)])) == (384 + 512,)
 
 
 # ---------------------------------------------------------------------------

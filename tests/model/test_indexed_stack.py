@@ -93,6 +93,44 @@ def test_logprobs_are_the_plain_references(remat):
         assert np.abs(other - want).max() > 1e-3, control
 
 
+@pytest.mark.parametrize("remat", ["none", "full", "mlp"])
+def test_a_half_empty_row_of_the_indexed_stack_walks_its_live_bands(remat, monkeypatch):
+    """One row alone, 100 tokens in 256 cells at bands of 16: both layers
+    (one scan, one traced body of two stretches) run their projections
+    (the indexer's among them), their router and their output over seven
+    bands of sixteen; the logprobs and the KL are the plain reference's,
+    and every gradient is the whole row's."""
+    from areal_tpu.models.transformer import looping_layers
+    from tests.model.test_layer_kinds import small_bands
+
+    ran = small_bands(monkeypatch)
+    cfg = _cfg()
+    params = _params(cfg)
+    ids, seg, pos = _row([100], 256)
+    got = _logprobs(params, cfg, ids, seg, pos, remat=remat, bands=True)
+    assert looping_layers(cfg, 1, 256) == 2 and ran == ["_before_mixer", "_after_mixer"]
+    want = ref.next_token_logprobs(params, HF, np.asarray(ids[0, :100]), pad_to=256)
+    np.testing.assert_allclose(got[:99], want, atol=5e-5)
+    _, sums = forward(params, cfg, ids, seg, pos, output="hidden", return_aux=True,
+                      index_loss=True, remat=remat, bands=True)
+    np.testing.assert_allclose(float(sums["index_kl"]), ref.indexer_kl(
+        params, HF, np.asarray(ids[0, :100]), pad_to=256).sum(), rtol=2e-5)
+
+    def loss(p, rows):  # rows together keep the whole row
+        tile = lambda a: jnp.tile(a, (rows, 1))
+        hidden, sums = forward(p, cfg, tile(ids), tile(seg), tile(pos), output="hidden",
+                               return_aux=True, index_loss=True, remat=remat, bands=True)
+        lp = fused_next_token_logprobs(hidden, p["head"]["weight"], tile(ids), tile(seg))
+        return (lp.sum() + sums["index_kl"]) / rows
+
+    g_loop, g_whole = jax.grad(loss)(params, 1), jax.grad(loss)(params, 2)
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(g_loop),
+                            jax.tree_util.tree_leaves(g_whole)):
+        scale = float(jnp.abs(b).max())
+        np.testing.assert_allclose(a, b, atol=2e-4 * scale + 1e-6,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
 def test_the_choice_is_the_references_in_float32():
     cfg = _cfg()
     params = _params(cfg)

@@ -112,6 +112,36 @@ def test_gradients_are_the_plain_references(hf):
     """Of the sum of the logprobs, and with the module of its own too:
     every leaf is reached, the module's and the stack's. `forward` hands
     the module the stack's output and the embedding table as constants."""
+    _assert_gradients_are_the_references(hf, "full")
+
+
+@pytest.mark.parametrize("remat", ["none", "full", "mlp"])
+def test_a_half_empty_row_of_the_latent_stack_walks_its_live_bands(remat, monkeypatch):
+    """One row alone, 40 tokens in 256 cells at bands of 16: the two
+    scanned expert layers (one traced body, two stretches) run over three
+    bands of sixteen, the dense layer and the module's layer, which run
+    once each, over the whole row; logprobs, the module's, and every
+    gradient are the plain reference's."""
+    from areal_tpu.models.transformer import looping_layers
+
+    from tests.model.test_layer_kinds import small_bands
+
+    monkeypatch.setattr(moe_lib, "_HELD_ROW_TILE", 8)
+    ran = small_bands(monkeypatch)
+    cfg = _cfg(HF)
+    params = _params(cfg)
+    n = 40
+    ids, seg, pos = _row([n], 256)
+    lp, lp2 = _logprobs(params, cfg, ids, seg, pos, remat=remat, bands=True)
+    assert looping_layers(cfg, 1, 256) == 2 and ran == ["_before_mixer", "_after_mixer"]
+    np.testing.assert_allclose(lp[: n - 1], ref.next_token_logprobs(
+        params, HF, np.asarray(ids[0, :n]), pad_to=256), atol=3e-5)
+    np.testing.assert_allclose(lp2[: n - 2], ref.mtp_logprobs(
+        params, HF, np.asarray(ids[0, :n]), pad_to=256), atol=3e-5)
+    _assert_gradients_are_the_references(HF, remat, bands=True)
+
+
+def _assert_gradients_are_the_references(hf, remat, **kw):
     cfg = _cfg(hf)
     params = _params(cfg)
     n, T = 40, 256
@@ -129,7 +159,7 @@ def test_gradients_are_the_plain_references(hf):
         return total
 
     def got_fn(p):
-        lp, lp2 = _logprobs(p, cfg, ids, seg, pos, remat="full")
+        lp, lp2 = _logprobs(p, cfg, ids, seg, pos, remat=remat, **kw)
         return lp.sum() + (lp2.sum() if hf is HF else 0.0)
 
     want, got = _flat(jax.grad(want_fn)(params)), _flat(jax.grad(got_fn)(params))

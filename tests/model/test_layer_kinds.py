@@ -20,6 +20,7 @@ from areal_tpu.models.config import LayerKind, MoEConfig, TransformerConfig
 from areal_tpu.models.hf import family_from_hf_config, get_family
 from areal_tpu.models.transformer import forward, init_params
 from areal_tpu.ops import attention as A
+from areal_tpu.ops import band_loop
 from benchmark.reference import afmoe as ref
 
 WINDOW = 8
@@ -54,12 +55,12 @@ def _params(cfg, seed=0, bias_scale=0.0):
     return params
 
 
-def _packed(seed=0, vocab=64):
+def _packed(seed=0, vocab=64, rows=ROWS, row_len=ROW_LEN):
     rng = np.random.default_rng(seed)
-    ids = np.zeros((len(ROWS), ROW_LEN), np.int32)
+    ids = np.zeros((len(rows), row_len), np.int32)
     seg, pos = np.zeros_like(ids), np.zeros_like(ids)
     seqs = []
-    for r, lens in enumerate(ROWS):
+    for r, lens in enumerate(rows):
         o = 0
         for j, n in enumerate(lens):
             t = rng.integers(0, vocab, n)
@@ -147,6 +148,123 @@ def test_the_whole_stack_matches_the_reference(remat, monkeypatch):
     g_ref = jax.grad(lambda p: _reference_logprob_sum(p, HF, seqs)[0])(params)
     g_ref["layers"]["mlp"]["expert_bias"] = g_prog["layers"]["mlp"]["expert_bias"]
     _assert_trees_close(g_prog, g_ref)
+
+
+def small_bands(monkeypatch, band=16):
+    """Bands of `band` cells, so that a toy row walks them, and a list
+    that grows by one for every stretch a program runs through the
+    loop (`ops/band_loop.stretch`)."""
+    monkeypatch.setattr(band_loop, "_BAND", band)
+    jax.clear_caches()  # a trace made at another band length is no one's to find
+    ran, stretch = [], band_loop.stretch
+    monkeypatch.setattr(band_loop, "stretch", lambda fn, *a: ran.append(fn.__name__) or stretch(fn, *a))
+    return ran
+
+
+@pytest.mark.parametrize("remat", ["none", "full", "mlp"])
+def test_a_half_empty_row_walks_its_live_bands_and_matches_the_reference(remat, monkeypatch):
+    """One row alone, 37 tokens in 96 cells: the four scanned expert
+    layers of the stack (window and full; one traced body, two stretches)
+    run over three bands of six, the leading dense layer, which runs
+    once, over the whole row, and the logprobs and every gradient are the
+    plain reference's."""
+    from areal_tpu.models.transformer import looping_layers
+
+    monkeypatch.setattr(moe_lib, "_HELD_ROW_TILE", 8)
+    ran = small_bands(monkeypatch)
+    cfg = _cfg()
+    params = _params(cfg, bias_scale=0.1)
+    ids, seg, pos, seqs = _packed(rows=[[24, 13]], row_len=96)
+    assert int(band_loop.live_bands(seg)) == 3 and band_loop.band_cells_run(np.asarray(seg)) == 48
+    _, got = _program_logprob_sum(params, cfg, ids, seg, pos, seqs, remat=remat, bands=True)
+    assert looping_layers(cfg, 1, 96) == 4 and ran == ["_before_mixer", "_after_mixer"]
+    _, want = _reference_logprob_sum(params, HF, seqs)
+    for g, w in zip(got, want):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=2e-5)
+    prog = lambda p: _program_logprob_sum(
+        p, cfg, ids, seg, pos, seqs, remat=remat, bands=True)[0]
+    g_prog = jax.grad(prog)(params)
+    g_ref = jax.grad(lambda p: _reference_logprob_sum(p, HF, seqs)[0])(params)
+    g_ref["layers"]["mlp"]["expert_bias"] = g_prog["layers"]["mlp"]["expert_bias"]
+    _assert_trees_close(g_prog, g_ref)
+
+
+def test_a_dead_bands_cells_leave_the_stack_as_zeros(monkeypatch):
+    """What no token is in reads zero after every layer, not what memory
+    held: the hidden states past the last live band, and the stream's
+    gradient there."""
+    monkeypatch.setattr(moe_lib, "_HELD_ROW_TILE", 8)
+    small_bands(monkeypatch)
+    cfg = _cfg()
+    params = _params(cfg)
+    ids, seg, pos, _ = _packed(rows=[[24, 13]], row_len=96)
+    hidden = forward(params, cfg, ids, seg, pos, attn_impl="reference", output="hidden",
+                     bands=True)
+    assert np.asarray(hidden[:, :37]).any() and not np.asarray(hidden[:, 48:]).any()
+    whole = forward(params, cfg, jnp.tile(ids, (2, 1)), jnp.tile(seg, (2, 1)),
+                    jnp.tile(pos, (2, 1)), attn_impl="reference", output="hidden")
+    np.testing.assert_allclose(hidden[0, :37], whole[0, :37], atol=2e-5)
+
+
+def test_what_keeps_the_whole_row(monkeypatch):
+    """Rows together, a row under two bands, a caller that wants the KV
+    cache and one that does not ask for bands run no loop: the parent's
+    program."""
+    ran = small_bands(monkeypatch)
+    kind = LayerKind(mlp="dense", window=None, rotary=True)
+    hf = dict(HF, num_hidden_layers=2, num_dense_layers=2, layer_types=["full_attention"] * 2)
+    cfg = _cfg(hf, layer_kinds=(kind, kind))
+    params = _params(cfg)
+    ids, seg, pos, _ = _packed()  # two rows
+    run = lambda *a, **kw: forward(params, cfg, *a, attn_impl="reference", **kw)
+    run(ids, seg, pos, bands=True)
+    run(ids[:1, :24], seg[:1, :24], pos[:1, :24], bands=True)
+    run(ids[:1], seg[:1], pos[:1], return_kv=True, bands=True)
+    run(ids[:1], seg[:1], pos[:1])
+    assert not ran
+    run(ids[:1], seg[:1], pos[:1], bands=True)
+    assert ran == ["_before_mixer", "_after_mixer"]
+
+
+def test_a_second_program_finds_its_stretches_traced(monkeypatch):
+    """What the set-up budget rests on: a cell's second and third program
+    (`accum_next` and `forward` after `accum_first`) hand
+    `ops/band_loop.stretch` the same functions, static description and
+    shapes, so the stretch's Python runs for the first program alone (its
+    plain loop, its forward rule and its backward loop) and for no later
+    one; and a layer that runs once, outside a scan, keeps the whole
+    row."""
+    import collections
+
+    from areal_tpu.models import transformer as tf
+
+    ran = small_bands(monkeypatch)
+    calls = []
+    for name in ("_before_mixer", "_after_mixer"):
+        def counted(st, w, xs, side, fn=getattr(tf, name), name=name):
+            calls.append(name)
+            return fn(st, w, xs, side)
+
+        monkeypatch.setattr(tf, name, counted)
+    kind = LayerKind(mlp="dense", window=None, rotary=True)
+    ids, seg, pos, seqs = _packed(rows=[[24, 13]], row_len=96)
+    hf = dict(HF, num_hidden_layers=3, num_dense_layers=3, layer_types=["full_attention"] * 3)
+    cfg = _cfg(hf, layer_kinds=(kind,) * 3)
+    assert [s.repeats for s in cfg.segments()] == [3] and tf.looping_layers(cfg, 1, 96) == 3
+    params = _params(cfg)
+    total = lambda p: _program_logprob_sum(
+        p, cfg, ids, seg, pos, seqs, remat="full", bands=True)[0]
+    jax.jit(jax.grad(total))(params)
+    first = collections.Counter(calls)
+    assert set(first) == {"_before_mixer", "_after_mixer"} and len(ran) == 2
+    jax.jit(jax.value_and_grad(lambda p: total(p)))(params)  # a second program
+    jax.jit(lambda p: total(p))(params)  # a third, forward only
+    assert collections.Counter(calls) == first and len(ran) == 6
+    alone = _cfg(hf, layer_kinds=(kind,) * 3, scan_min_repeats=4)  # one by one
+    assert tf.looping_layers(alone, 1, 96) == 0
+    del ran[:]
+    _program_logprob_sum(params, alone, ids, seg, pos, seqs, bands=True)
+    assert not ran
 
 
 def test_next_token_logprobs_pads_and_blocks():

@@ -279,8 +279,9 @@ def test_moe_dispatch_validated():
 
 
 def test_moe_drop_rate_counts_real_tokens_only(params):
-    """Padding rows route too (static shapes) but must not dilute the
-    reported drop rate: with token_mask, the rate is over real routings."""
+    """Padding must not dilute the reported drop rate, nor take a real
+    token's place: with token_mask, the rate is over real routings, and
+    only they fill an expert's capacity."""
     x, lp = _skewed_input(params, n_tokens=32)
     # Second half of the tokens are padding.
     mask = jnp.asarray(np.r_[np.ones(16, bool), np.zeros(16, bool)])
@@ -290,11 +291,9 @@ def test_moe_drop_rate_counts_real_tokens_only(params):
         else jnp.broadcast_to(mask, x.shape[:-1]),
     )
     _, aux_unmasked = moe_mlp(x, lp, CFG, jnp.float32, capacity_factor=1.0)
-    # All tokens (real + pad) fight for the same capacity. Under full
-    # skew the capacity buffer keeps the EARLIEST routings in priority
-    # order — the real (first-half) tokens — so the real-token rate is
-    # strictly below the all-token rate. Equal rates would mean the
-    # mask was ignored.
+    # Unmasked, all 32 tokens fight for the same capacity; masked, the 16
+    # real ones alone do, so the real-token rate is strictly below the
+    # all-token rate. Equal rates would mean the mask was ignored.
     assert 0.0 <= float(aux_masked["drop_rate"]) <= 1.0
     assert 0.0 <= float(aux_unmasked["drop_rate"]) <= 1.0
     assert float(aux_masked["drop_rate"]) < float(aux_unmasked["drop_rate"])
@@ -428,3 +427,76 @@ def test_moe_cli_overrides_end_to_end():
             ),
             tokenizer_path=None,
         )
+
+
+@pytest.mark.parametrize("dispatch,factor", [("dropless", 2.5), ("capacity", 0.25)])
+@pytest.mark.parametrize("remat", ["none", "full", "mlp"])
+def test_a_half_empty_row_walks_its_live_bands_through_whole_expert_layers(
+        remat, dispatch, factor, monkeypatch):
+    """One row alone, 37 tokens in 96 cells at bands of 16: the router runs
+    inside the second stretch of both layers (three bands of six) and the
+    experts take its choices, all held and dropless or at a capacity that
+    drops pairs. The cells of a band that did not run read
+    zeros for a routing and are no token's: they take no expert's
+    capacity and none of the router's statistics, so the logits, every
+    aux sum (the balance and z losses the step adds to its loss among
+    them) and every gradient of a loss made of all of them are those of
+    the same row run whole."""
+    import dataclasses
+
+    from tests.model.test_layer_kinds import small_bands
+
+    ran = small_bands(monkeypatch)
+    cfg = dataclasses.replace(CFG, moe=dataclasses.replace(
+        CFG.moe, dispatch=dispatch, capacity_factor=factor))
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    T, n = 96, 37
+    ids = jnp.asarray(np.random.RandomState(0).randint(0, 64, (1, T)), jnp.int32)
+    seg = (jnp.arange(T) < n).astype(jnp.int32)[None] * (1 + (jnp.arange(T) >= 24))
+    pos = jnp.where(jnp.arange(T) < 24, jnp.arange(T), jnp.arange(T) - 24)[None] * (seg > 0)
+
+    def loss(p, bands):
+        logits, aux = forward(p, cfg, ids, seg, pos, attn_impl="reference", remat=remat,
+                              return_aux=True, bands=bands)
+        total = jnp.sum(jnp.where(seg[..., None] > 0, jnp.sin(logits), 0))
+        return total + 3.0 * aux["load_balance_loss"] + 0.5 * aux["z_loss"], (logits, aux)
+
+    (l1, (out1, aux1)), g1 = jax.value_and_grad(loss, has_aux=True)(params, True)
+    assert set(ran) == {"_before_mixer", "_after_mixer"}
+    n_ran = len(ran)
+    (l2, (out2, aux2)), g2 = jax.value_and_grad(loss, has_aux=True)(params, False)
+    assert len(ran) == n_ran
+    np.testing.assert_allclose(out1[0, :n], out2[0, :n], atol=2e-5)
+    np.testing.assert_allclose(float(l1), float(l2), rtol=1e-5)
+    assert set(aux1) == set(aux2)
+    for name in aux1:
+        np.testing.assert_allclose(aux1[name], aux2[name], rtol=1e-5, atol=1e-6, err_msg=name)
+    assert (float(aux1["drop_rate"]) > 0.2) == (dispatch == "capacity")  # of two layers' sum
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(g1), jax.tree_util.tree_leaves(g2)):
+        np.testing.assert_allclose(a, b, atol=2e-4 * float(jnp.abs(b).max()) + 1e-7,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("dispatch,factor", [("dropless", 2.5), ("capacity", 0.5)])
+def test_padding_takes_no_capacity_and_none_of_the_routers_statistics(dispatch, factor, params):
+    """What `token_mask` calls padding is no token's routing: the real
+    tokens' outputs, the drop rate and the router's statistics (the expert
+    load, the balance and z losses, the entropy) are what the layer gives
+    for the real tokens alone at the same capacity a expert, whatever the
+    padding cells hold (here: every one of them wants expert 0 first)."""
+    import dataclasses
+
+    cfg = dataclasses.replace(CFG, moe=dataclasses.replace(CFG.moe, dispatch=dispatch))
+    x, lp = _skewed_input(params, n_tokens=32)
+    x = x.reshape(-1, x.shape[-1])
+    real = jax.random.normal(jax.random.PRNGKey(3), (20, x.shape[-1]), x.dtype)
+    padded = jnp.concatenate([real, x[:12]])
+    mask = jnp.arange(32) < 20
+    y, aux = moe_mlp(padded, lp, cfg, jnp.float32, capacity_factor=factor, token_mask=mask)
+    y_real, aux_real = moe_mlp(real, lp, cfg, jnp.float32, capacity_factor=factor * 32 / 20)
+    np.testing.assert_allclose(y[:20], y_real, atol=1e-5)
+    for name in aux:
+        np.testing.assert_allclose(aux[name], aux_real[name], rtol=1e-5, atol=1e-6, err_msg=name)
+    assert (float(aux["drop_rate"]) > 0) == (dispatch == "capacity")
+    _, unmasked = moe_mlp(padded, lp, cfg, jnp.float32, capacity_factor=factor)
+    assert float(unmasked["expert_load"][0]) > float(aux["expert_load"][0]) + 0.05

@@ -244,3 +244,22 @@ def test_shapes_over_a_thousand_batches_of_the_ppo_distribution():
           f"new {density(datapack.ladder_shape):.2f}")
     assert len(old) > 200 and len(new) <= 40  # the ladder has 40 rungs to 16,384
     assert density(datapack.ladder_shape) >= 96.0 > density(datapack.pack_shape)
+
+
+@pytest.mark.parametrize("multiple", [16, 128, 8192, 16384])
+def test_a_rungs_step_bounds_what_its_row_pads(multiple):
+    """`ladder_step(rung)` is the step `ladder_rung` took up to that rung:
+    every count of tokens that lands on a rung is within one step of it,
+    and some count is a whole step short but one. What the engine reads a
+    row's chance of an empty band from (`JaxTrainEngine._dead_bands`)."""
+    from areal_tpu.base.datapack import ladder_rung, ladder_step
+
+    worst = {}
+    for n in range(1, 40000, 7):
+        rung = ladder_rung(n, multiple)
+        assert rung - ladder_step(rung, multiple) < n <= rung
+        worst[rung] = max(worst.get(rung, 0), rung - n)
+    assert ladder_step(16384, 128) == 1024 and ladder_step(16384, 16384) == 16384
+    assert ladder_step(8192, 8192) == 8192 and ladder_step(1280, 128) == 128
+    full = [r for r in worst if r > 8 * multiple and r < 30000]
+    assert all(worst[r] > ladder_step(r, multiple) - 8 for r in full)

@@ -174,6 +174,47 @@ def test_a_train_step_of_one_row_holds_no_partial_of_dq(one_chip, monkeypatch):
     assert not re.search(r"attn_kernel/[^\n\"]*_splash_attention[^\n\"]*/reduce_sum", text)
 
 
+@pytest.mark.parametrize("config,loops", [
+    ("trinity-mini-d5-e16", 4), ("nemotron-3-nano-d9-e8", 0)])
+def test_an_accumulate_step_of_16k_compiles_with_its_stretches_looped(
+        one_chip, monkeypatch, config, loops):
+    """A forward-backward micro-batch of the trinity and the nemotron cells'
+    models at their one shape `(1, 16384)`, full remat, the masked loss
+    head. The trinity stack's four scanned expert layers run their two
+    token-wise stretches as loops whose trip count is read from the segment ids
+    (`ops/band_loop.py`): a known forward, remat's and a backward one a
+    stretch a kind of layer, beside the held experts' and the head's own;
+    the nemotron stack's layers have one part each and keep the whole
+    row, the parent's program. The chip's compiler takes both."""
+    import json
+    import re
+
+    from areal_tpu.models.transformer import forward, init_params, looping_layers
+    from areal_tpu.ops.loss import fused_next_token_logprobs
+    from benchmark.model import transformer_config
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernels, not interpret mode
+    with open(f"benchmark/configs/{config}.json") as f:
+        cfg = transformer_config(json.load(f), "bfloat16")
+    assert looping_layers(cfg, 1, 16384) == loops
+    params = jax.tree_util.tree_map(
+        lambda a: _shape(a.shape, a.dtype, one_chip),
+        jax.eval_shape(lambda k: init_params(cfg, k), jax.random.PRNGKey(0)))
+    ids = _shape((1, 16384), jnp.int32, one_chip)
+
+    def loss(p, input_ids, seg, pos):
+        hidden, _ = forward(p, cfg, input_ids, seg, pos, attn_impl="splash", remat=True,
+                            output="hidden", return_aux=True, bands=True)
+        return fused_next_token_logprobs(hidden, p["head"]["weight"], input_ids, seg,
+                                         scored=seg > 0).sum()
+
+    lowered = jax.jit(jax.value_and_grad(loss)).lower(params, ids, ids, ids)
+    # the jitted stretch is a function of the module where a layer loops
+    assert ("@_stretch" in lowered.as_text()) == bool(loops)
+    whiles = len(re.findall(r" while\(", lowered.compile().as_text()))
+    assert whiles >= 16 or not loops
+
+
 def _held_experts_compile(one_chip, T, D, F, n_held, k, act, mats, kernels=0):
     """`_held_experts` forward and backward for a described v5e, and what
     the held part must look like there: each way a loop over chunks
