@@ -34,7 +34,7 @@ class ModelTrainEvalConfig:
     attn_impl: str = dataclasses.field(
         default="auto",
         metadata={
-            "help": "attention impl: auto | splash | flash | reference | "
+            "help": "attention impl: auto | splash | reference | "
             "ring | ulysses (ring/ulysses = context parallelism over the "
             "seq mesh axis)"
         },

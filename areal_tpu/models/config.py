@@ -251,7 +251,7 @@ class LayerKind:
     encoding in this layer), and `diff`: differential attention, two
     softmaxes a pair of heads, the second subtracted from the first
     (`transformer._diff_combine`), and `latent`: q and k, v through
-    low-rank projections (`MLAConfig`, `transformer._latent_attention_block`),
+    low-rank projections (`MLAConfig`, `transformer._latent_in`),
     and `indexed`: an indexer of its own parameters chooses the keys
     each query reads (`IndexerConfig`, `ops/indexer.py`).
 

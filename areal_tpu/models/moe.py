@@ -590,6 +590,8 @@ def moe_mlp(
     token_mask: jnp.ndarray = None,  # [...] bool, True = real token
     mesh=None,
     dispatch: Optional[str] = None,
+    routed=None,
+    shared=None,
 ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """Returns (y with x's shape, aux dict: load_balance_loss, z_loss,
     drop_rate, router_entropy, expert_load [E], a2a_bytes).
@@ -599,7 +601,11 @@ def moe_mlp(
     shapes) and would otherwise dilute the rate. `mesh` enables the
     expert-parallel dropless path (`_moe_mlp_ep`) when the fsdp axis
     divides num_experts; `dispatch` overrides cfg.moe.dispatch (the
-    decode path passes decode_moe_overrides)."""
+    decode path passes decode_moe_overrides). `routed`, `shared`: the
+    router's four (`_router`) and the shared expert's product, each of
+    x's leading shape, where the caller has them (a stretch of the layer
+    made them a band at a time, `models/transformer._mlp_part`: one chip,
+    no mesh's path); made here otherwise."""
     moe = cfg.moe
     if capacity_factor is None:
         capacity_factor = moe.capacity_factor
@@ -626,9 +632,14 @@ def moe_mlp(
     lead_shape = x.shape[:-1]
     D = x.shape[-1]
     xt = x.reshape(-1, D)
-    with jax.named_scope("moe_router"):
-        routed = _router(xt, mp["router"], moe, mp.get("expert_bias"))
-    y, aux = after_router(xt, mp, cfg, cdt, routed, token_mask, mesh, dispatch, capacity_factor)
+    if routed is None:
+        with jax.named_scope("moe_router"):
+            routed = _router(xt, mp["router"], moe, mp.get("expert_bias"))
+    else:
+        flat = lambda a: a.reshape((-1,) + a.shape[len(lead_shape):])
+        routed, shared = tuple(map(flat, routed)), None if shared is None else flat(shared)
+    y, aux = after_router(xt, mp, cfg, cdt, routed, token_mask, mesh, dispatch,
+                          capacity_factor, shared)
     return y.reshape(*lead_shape, D), aux
 
 
@@ -637,12 +648,11 @@ def after_router(xt, mp, cfg, cdt, routed, token_mask=None, mesh=None,
     """The layer from the router's `routed` (`_router`'s four) on, for the
     tokens xt `[T, D]`: the routed experts' weighted sum plus the shared
     expert's result, `[T, D]`, and the aux dict. `shared`: that result
-    where the caller has it (a stretch of the layer made it a band at a
-    time beside the router, `models/transformer._after_mixer`); made here
-    otherwise. A cell `token_mask` calls padding takes no part in what
-    crosses tokens: no expert's capacity, no held expert's tile, none of
-    the router's statistics; under dropless dispatch it is routed like a
-    token (static shapes) and read by no one."""
+    where `moe_mlp`'s caller had it; made here otherwise. A cell
+    `token_mask` calls padding takes no part in what crosses tokens: no
+    expert's capacity, no held expert's tile, none of the router's
+    statistics; under dropless dispatch it is routed like a token (static
+    shapes) and read by no one."""
     moe = cfg.moe
     capacity_factor = moe.capacity_factor if capacity_factor is None else capacity_factor
     dispatch = dispatch or moe.dispatch

@@ -588,39 +588,6 @@ def _attn_core(q, k, v, cfg, cos, sin, segment_ids, positions, attn_impl, mesh,
     return out, k, sums
 
 
-def _attention_block(
-    x, lp, cfg, cos, sin, segment_ids, positions, attn_impl, cdt, mesh=None,
-    variants=((None, True),), variant_index=None, l0=None, kv=None, index=None,
-):
-    """x: [R, T, D] -> attention output [R, T, D]. Named scopes say in
-    the device trace which part an op belongs to: `attn_qkv`
-    (projections, rotary; the caller's input norm too), `attn_kernel`
-    (the attention call), `attn_out`, and inside the first and the last
-    `attn_gate` (the gate's projection; its sigmoid times the kernel's
-    output). `variants` are the (window, rotary) pairs the layers of
-    this stack have: a window limits a token to the `window` positions
-    ending at it, no rotary leaves q and k without a position encoding.
-    One variant is called as it is; of several, `variant_index` (traced,
-    scanned beside the layer's parameters) picks the one that runs.
-    `l0` (the layer's lambda_init; None = plain attention) makes it
-    differential: `_diff_split` before the kernel, `_diff_combine`
-    (scope `attn_diff`) after. `kv` = another layer's k and v, as that
-    layer returned them: this layer then projects q only. With `index`
-    (`_Index`) the layer's indexer (`lp["indexer"]`, `_index_proj`)
-    chooses the keys each query reads (`ops/indexer.indexed_attention`),
-    and the layer's sums come back as a third result (empty without)."""
-    q, k, v, gate, own_kv = _attn_in(x, lp, cfg, cdt, kv)
-    ix = None if index is None else _index_proj(
-        x, lp["indexer"], cfg, index.cos, index.sin, cdt)
-    out, k, sums = _attn_core(
-        q, k, v, cfg, cos, sin, segment_ids, positions, attn_impl, mesh,
-        variants, variant_index, l0 is not None, index, ix)
-    if l0 is not None:
-        k, v = own_kv
-    out = _attn_out(out, gate, lp, cfg, cdt, l0)
-    return out, (k, v), sums
-
-
 def _latent_in(x, lp, cfg, cos, sin, cdt):
     """Latent attention's q and k `[R, T, H, nope + rope]` and v `[R, T,
     H, v_dim]` of the layer's normed input x (scope `attn_qkv`)."""
@@ -652,22 +619,6 @@ def _latent_out(out, lp, cdt):
         return out.reshape(out.shape[:2] + (-1,)) @ lp["wo"].astype(cdt)
 
 
-def _latent_attention_block(x, lp, cfg, cos, sin, segment_ids, positions,
-                            attn_impl, cdt, mesh=None):
-    """x: [R, T, D] -> latent attention's output [R, T, D] and its (k, v)
-    (`config.MLAConfig` has the equations): the materialised form, k and
-    v a head, as a training or prefill pass runs it. Scopes `mla_q_proj`
-    and `mla_kv_proj` inside `attn_qkv` hold the low-rank projections
-    with their norms; rotary (`cos`, `sin` of `rope_dim / 2`) turns q's
-    rope part a head and the one rope key a token, which every head's k
-    ends with. The kernel is the plain block's, called with q and k of
-    `nope_dim + rope_dim` against v of `v_dim`; its softmax scale is
-    that q and k size's."""
-    q, k, v = _latent_in(x, lp, cfg, cos, sin, cdt)
-    out = _latent_core(q, k, v, cfg, segment_ids, positions, attn_impl, mesh)
-    return _latent_out(out, lp, cdt), (k, v)
-
-
 def _latent_core(q, k, v, cfg, segment_ids, positions, attn_impl, mesh):
     """Latent attention's kernel call (scope `attn_kernel`): every head
     has its own k and v."""
@@ -680,73 +631,91 @@ def _latent_core(q, k, v, cfg, segment_ids, positions, attn_impl, mesh):
 
 
 class _Stretch(NamedTuple):
-    """What a layer's stretches (`ops/band_loop.stretch`) do not trace,
-    hashable: the configuration (by identity), the layer's kind, the
-    compute dtype, and whether rotary turns q and k inside the first
-    stretch (every layer of the scan has it)."""
+    """What a layer's token-wise steps do not trace, hashable (it keys
+    the trace of a stretch, `ops/band_loop.stretch`): the configuration
+    (by identity), the layer's kind, the compute dtype, and where the row
+    walks its bands, which ops stand inside a step that the whole row has
+    elsewhere."""
     cfg: TransformerConfig
     kind: LayerKind
     cdt: Any
+    # Rotary turns q and k inside the first step, where every layer of
+    # the scan has it: in the attention call's switch (the whole row's
+    # place, `_attn_core`) it would turn every cell of the row.
     rot_in: bool = False
+    # The router and the shared expert run inside the second step, a band
+    # at a time. The whole row leaves both to `moe.moe_mlp`, which picks
+    # a mesh's expert-parallel path before the router and makes the
+    # shared expert's product after the experts'.
+    route_in: bool = False
+    # remat "mlp": the dense MLP under a checkpoint of its own. (No loop
+    # has it: a stretch's backward rule keeps nothing of a band.)
+    mlp_ckpt: bool = False
 
 
-# Of a plain attention layer's parameters, those its first stretch reads;
+# Of a plain attention layer's parameters, those its first step reads;
 # its second reads the rest (latent attention: all but `wo`, and `wo`).
 _ATTN_IN = ("wq", "wk", "wv", "bq", "bk", "bv", "q_norm", "k_norm", "wg", "indexer")
 
 
 def _mixer_weights(st: _Stretch, mp, first: bool):
     """The part of an attention layer's parameters `mp` that its first
-    stretch reads, or its second."""
+    step reads, or its second."""
     first_names = set(mp) - {"wo"} if st.kind.latent else _ATTN_IN
     return {n: w for n, w in mp.items() if (n in first_names) == first}
 
 
 def _before_mixer(st: _Stretch, w, xs, side):
-    """A layer's first stretch: its input x under `ln1` and attention's
-    projections of that, with what is token-wise after them (q/k norm,
-    the gate's and the indexer's projections, rotary where every layer
-    of the scan has it). `side`: the rotary tables, then the indexer's.
-    Returns q, k, v, (the gate's projection), (the indexer's three)."""
+    """An attention layer up to what crosses tokens: its input x (then
+    the k and v of the layer it reads, where it reads one) under `ln1`
+    and attention's projections of that (scope `attn_qkv`: `_attn_in`,
+    `_latent_in`), with what is token-wise after them (q/k norm, the
+    gate's and the indexer's projections, rotary where `st.rot_in`).
+    `side`: the rotary tables, then the indexer's. Returns q, k, v, (the
+    gate's projection), (the indexer's three), (differential attention:
+    k and v as projected, which a reader of this layer takes)."""
     cfg, kind, cdt = st.cfg, st.kind, st.cdt
-    (x,), mp = xs, w["mixer"]
-    h = _norm(x, w["ln1"], cfg)
+    (x, *kept), mp = xs, w["mixer"]
+    with jax.named_scope("attn_qkv"):
+        h = _norm(x, w["ln1"], cfg)
     if kind.latent:
         return _latent_in(h, mp, cfg, side[0], side[1], cdt)
-    q, k, v, gate, _ = _attn_in(h, mp, cfg, cdt)
+    q, k, v, gate, own_kv = _attn_in(h, mp, cfg, cdt, tuple(kept) or None)
     if st.rot_in:
-        q = apply_rotary(q, side[0], side[1], cfg.rotary_interleaved)
-        k = apply_rotary(k, side[0], side[1], cfg.rotary_interleaved)
+        with jax.named_scope("attn_qkv"):
+            q = apply_rotary(q, side[0], side[1], cfg.rotary_interleaved)
+            k = apply_rotary(k, side[0], side[1], cfg.rotary_interleaved)
     out = (q, k, v) if gate is None else (q, k, v, gate)
     if kind.indexed:
         out += _index_proj(h, mp["indexer"], cfg, side[-2], side[-1], cdt)
-    return out
+    return out + own_kv if kind.diff else out
 
 
-def _after_mixer(st: _Stretch, w, xs, side):
-    """A layer's second stretch, from the attention call's output (then
-    the gate's projection; the layer's input last) to the MLP's product
-    or the routed experts' doorstep: the output projection under the
-    gate, the residual, `ln2`, and the dense MLP with its residual, or
-    the router and the shared expert. Returns the stream, then for an
-    expert layer the experts' input, the router's four and the shared
-    expert's result."""
+def _mlp_joins(x, m, w, cfg):
+    """The stream x plus the MLP's product m under the layer's `ln2_post`."""
+    if "ln2_post" in w:
+        m = _norm(m, w["ln2_post"], cfg)
+    return x + m
+
+
+def _mlp_part(st: _Stretch, w, xs, side=()):
+    """What of a layer's MLP is token-wise, from the stream x (scope
+    `mlp`): `ln2` and the dense MLP with its residual, returned alone; or
+    for an expert layer the stream and the experts' input, with the
+    router's four and the shared expert's product where `st.route_in`. A
+    layer of a mixer alone hands x back."""
     cfg, kind, cdt = st.cfg, st.kind, st.cdt
-    *got, x = xs
-    if kind.latent:
-        a = _latent_out(got[0], w["mixer"], cdt)
-    else:
-        a = _attn_out(got[0], got[1] if len(got) > 1 else None, w["mixer"], cfg, cdt)
-    if "ln1_post" in w:
-        a = _norm(a, w["ln1_post"], cfg)
-    x = x + a
+    (x,) = xs
+    if kind.mlp is None:
+        return (x,)
     with jax.named_scope("mlp"):
         h = _norm(x, w["ln2"], cfg)
         if kind.mlp == "dense":
-            m = _mlp(h, w["mlp"], cfg, cdt)
-            if "ln2_post" in w:
-                m = _norm(m, w["ln2_post"], cfg)
-            return (x + m,)
+            mlp = jax.checkpoint(_mlp, static_argnums=(2, 3)) if st.mlp_ckpt else _mlp
+            m = mlp(h, w["mlp"], cfg, cdt)
+            return (_mlp_joins(x, m, w, cfg),)
+        if not st.route_in:
+            return x, h
         from areal_tpu.models.moe import _router, _shared_expert
 
         ht = h.reshape(-1, h.shape[-1])
@@ -757,6 +726,28 @@ def _after_mixer(st: _Stretch, w, xs, side):
             out += (_shared_expert(ht, w["mlp"]["shared"], activation_fn(cfg.activation),
                                    cdt).reshape(h.shape),)
         return out
+
+
+def _after_mixer(st: _Stretch, w, xs, side):
+    """An attention layer from the attention call's output (then the
+    gate's projection; the layer's input last) to the MLP's product or
+    the routed experts' doorstep: the output projection under the gate
+    (`_attn_out`, with differential attention's combine under `w["l0"]`;
+    `_latent_out`), `ln1_post` and the residual (scope `attn_out`), then
+    `_mlp_part`: two steps composed, because a third loop would cost
+    set-up more than it saves the chip."""
+    cfg, kind, cdt = st.cfg, st.kind, st.cdt
+    *got, x = xs
+    if kind.latent:
+        a = _latent_out(got[0], w["mixer"], cdt)
+    else:
+        a = _attn_out(got[0], got[1] if len(got) > 1 else None, w["mixer"], cfg, cdt,
+                      w.get("l0"))
+    with jax.named_scope("attn_out"):
+        if "ln1_post" in w:
+            a = _norm(a, w["ln1_post"], cfg)
+        x = x + a
+    return _mlp_part(st, w, (x,))
 
 
 def _kind_loops(kind: LayerKind) -> bool:
@@ -1003,20 +994,17 @@ def forward(
         moe_token_mask = segment_ids > 0  # real-token drop accounting
         # mesh enables the expert-parallel dropless path (moe.py
         # _moe_mlp_ep) when the fsdp axis divides num_experts.
-        moe_fn = lambda h, mp: moe_mlp(
-            h, mp, cfg, cdt, token_mask=moe_token_mask, mesh=mesh
-        )
-    dense_fn = lambda h, mp: _mlp(h, mp, cfg, cdt)
+        moe_fn = lambda h, mp, routed=None, shared=None: moe_mlp(
+            h, mp, cfg, cdt, token_mask=moe_token_mask, mesh=mesh,
+            routed=routed, shared=shared)
+        if remat_mode == "mlp":
+            moe_fn = jax.checkpoint(moe_fn)
     # One row of two bands or more, alone on its chip, walks the bands its
     # tokens reach in every token-wise stretch of its layers.
     n_live = None
     if bands and not return_kv and looping_layers(
             cfg, *input_ids.shape, sharded=mesh is not None and mesh.size > 1):
         n_live = band_loop.live_bands(segment_ids)
-    if remat_mode == "mlp":
-        dense_fn = jax.checkpoint(dense_fn)
-        if use_moe:
-            moe_fn = jax.checkpoint(moe_fn)
 
     def layer_body(kind, variants, scanned=False):
         """carry, (one layer's parameters, which of `variants` it is),
@@ -1025,36 +1013,54 @@ def forward(
         attention is one of the (window, rotary) `variants`, under the
         remat mode. Layers that differ only in their attention share the
         one traced body: the switch is around the attention call alone
-        (`_attention_block`), so what the backward pass keeps of a layer
-        is one layer's, whatever its kind."""
+        (`_attn_core`), so what the backward pass keeps of a layer is one
+        layer's, whatever its kind.
+
+        A layer is steps, token-wise (`_before_mixer`, `_after_mixer`,
+        `_mlp_part`) or across tokens (the attention call, a scan, the
+        routed experts), and `run` is how a token-wise step runs: over the
+        row's live bands (`ops/band_loop.stretch`) for a layer of a scan
+        whose kind takes the loop (`_kind_loops`) where the call walks
+        bands at all (`n_live`), a plain call otherwise."""
+        banded = n_live is not None and scanned and _kind_loops(kind)
+        st = _Stretch(
+            cfg, kind, cdt, route_in=banded, mlp_ckpt=remat_mode == "mlp" and not banded,
+            rot_in=banded and not kind.latent and cos is not None
+            and all(rotary for _, rotary in variants))
+        if banded:
+            run = lambda fn, w, xs, side=(): band_loop.stretch(fn, st, w, xs, side, n_live)
+        else:
+            run = lambda fn, w, xs, side=(): fn(st, w, xs, side)
 
         def body(carry, xs, kept=None):
             lp, variant_index = xs
             x, aux_acc = carry
-            kv = l0 = None
-            if kind.diff:  # beside the variant, the layer's lambda_init
-                variant_index, l0 = variant_index["variant"], variant_index["l0"]
+            kv, terms, step, w = None, {}, _mlp_part, {}
             if kind.mixer == "attention":
-                with jax.named_scope("attn_qkv"):
-                    h = _norm(x, lp["ln1"], cfg)
+                mp = lp["attn"]
+                side = (() if cos is None else (cos, sin)) + (
+                    (index.cos, index.sin) if kind.indexed else ())
+                q, k, v, *mid = run(
+                    _before_mixer, {"ln1": lp["ln1"], "mixer": _mixer_weights(st, mp, True)},
+                    (x,) + (kept or ()), side)
+                step, w = _after_mixer, {"mixer": _mixer_weights(st, mp, False)}
                 if kind.latent:
-                    a, kv = _latent_attention_block(
-                        h, lp["attn"], cfg, cos, sin, segment_ids, positions,
-                        attn_impl, cdt, mesh=mesh)
+                    got = (_latent_core(q, k, v, cfg, segment_ids, positions, attn_impl, mesh),)
                 else:
-                    a, kv, sums = _attention_block(
-                        h, lp["attn"], cfg, cos, sin,
-                        segment_ids, positions, attn_impl, cdt, mesh=mesh,
-                        variants=variants, variant_index=variant_index,
-                        l0=l0, kv=kept, index=index if kind.indexed else None,
-                    )
-                    if sums:
-                        aux_acc = {**aux_acc, **{k: aux_acc[k] + v
-                                                 for k, v in sums.items()}}
-                with jax.named_scope("attn_out"):
-                    if "ln1_post" in lp:
-                        a = _norm(a, lp["ln1_post"], cfg)
-                    x = x + a
+                    if kind.diff:  # beside the variant, the layer's lambda_init
+                        variant_index, w["l0"] = variant_index["variant"], variant_index["l0"]
+                        *mid, k0, v0 = mid
+                    gate = (mid.pop(0),) if "wg" in mp else ()
+                    out, k, terms = _attn_core(
+                        q, k, v, cfg, cos, sin, segment_ids, positions, attn_impl, mesh,
+                        variants, variant_index, kind.diff, index if kind.indexed else None,
+                        tuple(mid), rotated=st.rot_in)
+                    got = (out,) + gate
+                    if kind.diff:  # a reader takes k and v as projected
+                        k, v = k0, v0
+                kv, got = (k, v), got + (x,)
+                if "ln1_post" in lp:
+                    w["ln1_post"] = lp["ln1_post"]
             elif kind.mixer == "ssm" and cfg.ssm.form == "mamba1":
                 from areal_tpu.ops.selective_scan import sscan_mixer
 
@@ -1062,12 +1068,12 @@ def forward(
                     h = _norm(x, lp["ln1"], cfg)
                 a, kv = sscan_mixer(h, lp["ssm"], cfg.ssm, segment_ids, cdt, mesh=mesh)
                 with jax.named_scope("sscan_out_proj"):
-                    x = x + a
+                    got = (x + a,)
             elif kind.mixer == "gmu":
                 with jax.named_scope("gmu"):
                     h = _norm(x, lp["ln1"], cfg)
                     g = jax.nn.silu(h @ lp["gmu"]["w_in"].astype(cdt))
-                    x = x + (g * kept) @ lp["gmu"]["w_out"].astype(cdt)
+                    got = (x + (g * kept) @ lp["gmu"]["w_out"].astype(cdt),)
             elif kind.mixer == "ssm":
                 from areal_tpu.ops.ssm import ssm_mixer
 
@@ -1077,79 +1083,31 @@ def forward(
                 with jax.named_scope("ssm_out_proj"):
                     if "ln1_post" in lp:
                         a = _norm(a, lp["ln1_post"], cfg)
-                    x = x + a
-            if kind.mlp is None:
-                x = act_c(x)
-            else:
-                with jax.named_scope("mlp"):
-                    h = _norm(x, lp["ln2"], cfg)
-                    if kind.mlp == "moe":
-                        m, aux = moe_fn(h, lp["mlp"])
-                        aux_acc = {k: aux_acc[k] + aux[k] if k in aux else aux_acc[k]
-                                   for k in aux_acc}
-                    else:
-                        m = dense_fn(h, lp["mlp"])
-                    if "ln2_post" in lp:
-                        m = _norm(m, lp["ln2_post"], cfg)
-                    x = act_c(x + m)
-            return (x, aux_acc), kv if return_kv or kind.keeps else None
-
-        def looped(carry, xs, kept=None):
-            """`body` for a row that walks its live bands (`_kind_loops`: an
-            attention mixer and an MLP): the same layer as two stretches
-            (`_before_mixer`, `_after_mixer`) around what crosses tokens."""
-            lp, variant_index = xs
-            x, aux_acc = carry
-            mp = lp["attn"]
-            st = _Stretch(cfg, kind, cdt, rot_in=not kind.latent and cos is not None
-                          and all(rotary for _, rotary in variants))
-            run = lambda fn, w, xs, side=(): band_loop.stretch(fn, st, w, xs, side, n_live)
-            side = (() if cos is None else (cos, sin)) + (
-                (index.cos, index.sin) if kind.indexed else ())
-            q, k, v, *mid = run(
-                _before_mixer, {"ln1": lp["ln1"], "mixer": _mixer_weights(st, mp, True)},
-                (x,), side)
-            if kind.latent:
-                got = (_latent_core(q, k, v, cfg, segment_ids, positions, attn_impl, mesh),)
-            else:
-                gate = (mid.pop(0),) if "wg" in mp else ()
-                out, k, sums = _attn_core(
-                    q, k, v, cfg, cos, sin, segment_ids, positions, attn_impl, mesh,
-                    variants, variant_index, False, index if kind.indexed else None,
-                    tuple(mid), rotated=st.rot_in)
-                if sums:
-                    aux_acc = {**aux_acc, **{n: aux_acc[n] + a for n, a in sums.items()}}
-                got = (out,) + gate
-            after = {n: lp[n] for n in ("ln1_post", "ln2", "ln2_post") if n in lp}
-            after["mixer"] = _mixer_weights(st, mp, False)
+                    got = (x + a,)
+            else:  # an MLP alone
+                got = (x,)
             if kind.mlp == "dense":
-                after["mlp"] = lp["mlp"]
-            else:  # its post-norm follows the experts' sum
-                post = after.pop("ln2_post", None)
-                after["mlp"] = {n: lp["mlp"][n] for n in ("router", "expert_bias", "shared")
-                                if n in lp["mlp"]}
-            x, *rest = run(_after_mixer, after, got + (x,))
+                w.update({n: lp[n] for n in ("ln2", "ln2_post", "mlp") if n in lp})
+            elif kind.mlp == "moe":  # the experts' own weights stay out of a stretch
+                w.update(ln2=lp["ln2"], mlp={n: lp["mlp"][n] for n in (
+                    "router", "expert_bias", "shared") if n in lp["mlp"]})
+            x, *rest = run(step, w, got)
             if kind.mlp == "moe":
-                from areal_tpu.models.moe import after_router
-
-                h, *routed = (a.reshape(a.shape[1:]) for a in rest)
-                shared = routed.pop() if "shared" in lp["mlp"] else None
+                # the shared expert's product, where a stretch made it,
+                # joins the experts' over the whole row: a sum, of zeros
+                # where no token is (no pair, and the stream and that
+                # product are zeros there)
                 with jax.named_scope("mlp"):
-                    # the shared expert's result joins the experts' over the
-                    # whole row: a sum, of zeros where no token is (no pair,
-                    # and the stream and that result are zeros there); a
-                    # third loop would cost set-up more than the sum costs
-                    # the chip
-                    m, aux = after_router(h, lp["mlp"], cfg, cdt, tuple(routed),
-                                          moe_token_mask, mesh, shared=shared)
-                    aux_acc = {n: aux_acc[n] + aux[n] if n in aux else aux_acc[n]
-                               for n in aux_acc}
-                    m = m.reshape(x.shape)
-                    x = x + (m if post is None else _norm(m, post, cfg))
-            return (x, aux_acc), (k, v) if kind.keeps else None
+                    m, aux = moe_fn(rest[0], lp["mlp"], tuple(rest[1:5]) or None,
+                                    rest[5] if len(rest) > 5 else None)
+                terms = {**terms, **aux}
+            aux_acc = {n: aux_acc[n] + terms[n] if n in terms else aux_acc[n]
+                       for n in aux_acc}
+            if kind.mlp == "moe":  # its post-norm follows the experts' sum
+                with jax.named_scope("mlp"):
+                    x = _mlp_joins(x, m, lp, cfg)
+            return (act_c(x), aux_acc), kv if return_kv or kind.keeps else None
 
-        if n_live is not None and scanned and _kind_loops(kind):
-            body = looped
         if remat_mode == "full":
             return jax.checkpoint(body)
         if remat_mode == "save_attn":

@@ -8,8 +8,10 @@ and per-token positions; attention is masked to (same segment) AND
 
 - `reference_packed_attention`: dense jnp einsum + mask. O(T^2) memory;
   used on CPU tests and as the numerical oracle.
-- `flash_packed_attention` (areal_tpu.ops.pallas.flash_attn): blocked
-  Pallas kernel, online softmax, segment-aware block skipping.
+- `splash_packed_attention`: jax's Pallas TPU kernel under a static
+  block mask, or this repo's pair kernels over a row's live block pairs
+  (areal_tpu.ops.pallas.splash_pairs); what a training layer runs on the
+  chip.
 
 `packed_attention` dispatches on platform/size.
 """
@@ -629,6 +631,9 @@ def resolve_cp_impl(mesh, r: int, t: int, hq: int, hkv: int) -> Optional[str]:
 
 def _choose_attn_impl(impl, t, hq, hkv, mesh, r):
     """(implementation that will run, why)."""
+    known = ("auto", "splash", "reference", "ring", "ulysses")
+    if impl not in known:
+        raise ValueError(f"attn_impl={impl!r}: expected one of {', '.join(known)}")
     sharded = mesh is not None and mesh.size > 1
     why = "requested"
     if impl == "auto":
@@ -643,17 +648,13 @@ def _choose_attn_impl(impl, t, hq, hkv, mesh, r):
                 f"splash needs rows a multiple of {LANES} and hkv | hq"
             )
         impl, why = "splash", f"tpu backend, rows a multiple of {LANES}"
-    if sharded and impl not in ("reference", "ring", "ulysses"):
+    if sharded and impl == "splash" and (
+            r is None or not sharded_splash_ok(mesh, r, t, hq, hkv)):
         # Never run a bare pallas_call inside a sharded jit — GSPMD
-        # cannot partition it (it replicates or fails). Only splash has a
-        # shard_map wrapping; anything else runs the einsum reference,
-        # which partitions cleanly.
-        if impl != "splash" or r is None or not sharded_splash_ok(
-            mesh, r, t, hq, hkv
-        ):
-            return "reference", (
-                f"{impl} has no shard_map layout for mesh {dict(mesh.shape)}"
-            )
+        # cannot partition it (it replicates or fails). Where splash's
+        # shard_map wrapping does not fit the mesh, the einsum reference
+        # runs, which partitions cleanly.
+        return "reference", f"splash has no shard_map layout for mesh {dict(mesh.shape)}"
     return impl, why
 
 
@@ -757,25 +758,14 @@ def attn_grid_steps(
 def packed_attention(q, k, v, segment_ids, positions, softmax_scale=None,
                      impl="auto", window=None):
     """Dispatch between implementations. Static decision (trace-time): `impl`
-    is 'reference', 'flash' (our Pallas kernel), 'splash' (jax's tuned TPU
-    kernel), or 'auto' (see resolve_attn_impl). `window` limits a token
-    to the `window` positions that end at its own."""
+    is 'reference', 'splash' (jax's tuned TPU kernel), or 'auto' (see
+    resolve_attn_impl). `window` limits a token to the `window` positions
+    that end at its own."""
     impl = resolve_attn_impl(impl, q.shape[0], q.shape[1], k.shape[1])
     if impl == "splash":
         return splash_packed_attention(
             q, k, v, segment_ids, positions, softmax_scale=softmax_scale,
             window=window,
-        )
-    if impl == "flash":
-        if window is not None:
-            raise NotImplementedError(
-                "attn_impl='flash' (ops/pallas/flash_attn.py) has no window "
-                "in its mask; use 'splash' or 'reference' for a window layer"
-            )
-        from areal_tpu.ops.pallas.flash_attn import flash_packed_attention
-
-        return flash_packed_attention(
-            q, k, v, segment_ids, positions, softmax_scale=softmax_scale
         )
     return reference_packed_attention(
         q, k, v, segment_ids, positions, softmax_scale=softmax_scale,

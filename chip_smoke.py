@@ -311,7 +311,6 @@ def child_kernels(payload):
     from areal_tpu.engine import paged
     from areal_tpu.ops import attention as A
     from areal_tpu.ops import gae as G
-    from areal_tpu.ops.pallas.flash_attn import flash_packed_attention
 
     hq, hkv, hd = payload["hq"], payload["hkv"], payload["hd"]
     t, pg = payload["t"], payload["page"]
@@ -451,7 +450,6 @@ def child_kernels(payload):
         "paged_attention (decode, jax stock kernel)": lambda: paged_case(False, "kernel"),
         "paged_decode_int8": lambda: paged_case(True, "int8_kernel"),
         "gae_scan": gae_case,
-        "flash_attn": lambda: attn_case(flash_packed_attention, 0.05),
     }
     if jax.default_backend() != "tpu":
         # The stock kernel has no interpreted form to rehearse.

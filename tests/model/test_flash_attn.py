@@ -1,6 +1,7 @@
-"""Pallas flash attention vs the dense reference oracle (forward + grads).
+"""The training attention kernels (splash, the pair kernels) vs the dense
+reference oracle (forward + grads).
 
-Runs the kernel in interpreter mode on the CPU test platform; the same
+Runs the kernels in interpreter mode on the CPU test platform; the same
 code path compiles on TPU (dispatched by areal_tpu/ops/attention.py).
 """
 
@@ -14,7 +15,6 @@ import numpy as np
 import pytest
 
 from areal_tpu.ops.attention import reference_packed_attention
-from areal_tpu.ops.pallas.flash_attn import flash_packed_attention
 
 
 def make_packed(T, n_seqs, hq, hkv, hd, seed=0):
@@ -32,65 +32,6 @@ def make_packed(T, n_seqs, hq, hkv, hd, seed=0):
     k = rng.randn(T, hkv, hd).astype(np.float32)
     v = rng.randn(T, hkv, hd).astype(np.float32)
     return q, k, v, seg, pos
-
-
-@pytest.mark.parametrize("hq,hkv,hd", [(4, 4, 64), (4, 2, 64), (8, 2, 32)])
-def test_flash_forward_matches_reference(hq, hkv, hd):
-    T = 256
-    q, k, v, seg, pos = make_packed(T, n_seqs=3, hq=hq, hkv=hkv, hd=hd)
-    ref = reference_packed_attention(q, k, v, seg, pos)
-    got = flash_packed_attention(q, k, v, seg, pos, interpret=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5, rtol=2e-5)
-
-
-def test_flash_padding_rows_zero():
-    T = 128
-    q, k, v, seg, pos = make_packed(T, n_seqs=2, hq=4, hkv=2, hd=32, seed=3)
-    seg[100:] = 0  # force a padded tail
-    got = np.asarray(flash_packed_attention(q, k, v, seg, pos, interpret=True))
-    np.testing.assert_allclose(got[100:], 0.0, atol=1e-6)
-
-
-def test_flash_grads_match_reference():
-    T = 256
-    q, k, v, seg, pos = make_packed(T, n_seqs=3, hq=4, hkv=2, hd=32, seed=7)
-    dout = np.random.RandomState(9).randn(T, 4, 32).astype(np.float32)
-
-    def loss_ref(q, k, v):
-        return jnp.vdot(reference_packed_attention(q, k, v, seg, pos), dout)
-
-    def loss_flash(q, k, v):
-        return jnp.vdot(
-            flash_packed_attention(q, k, v, seg, pos, interpret=True), dout
-        )
-
-    gr = jax.grad(loss_ref, argnums=(0, 1, 2))(q, k, v)
-    gf = jax.grad(loss_flash, argnums=(0, 1, 2))(q, k, v)
-    for a, b, name in zip(gf, gr, "qkv"):
-        np.testing.assert_allclose(
-            np.asarray(a), np.asarray(b), atol=3e-4, rtol=3e-4, err_msg=name
-        )
-
-
-def test_flash_vmap_rows():
-    # The model vmaps attention over packed rows; exercise the batching rule.
-    R, T = 2, 128
-    packs = [make_packed(T, 2, 4, 2, 32, seed=10 + r) for r in range(R)]
-    q = np.stack([p[0] for p in packs])
-    k = np.stack([p[1] for p in packs])
-    v = np.stack([p[2] for p in packs])
-    seg = np.stack([p[3] for p in packs])
-    pos = np.stack([p[4] for p in packs])
-    got = jax.vmap(
-        lambda q1, k1, v1, s1, p1: flash_packed_attention(
-            q1, k1, v1, s1, p1, interpret=True
-        )
-    )(q, k, v, seg, pos)
-    for r in range(R):
-        ref = reference_packed_attention(q[r], k[r], v[r], seg[r], pos[r])
-        np.testing.assert_allclose(
-            np.asarray(got[r]), np.asarray(ref), atol=2e-5, rtol=2e-5
-        )
 
 
 # ---------------------------------------------------------------------------

@@ -527,6 +527,67 @@ def test_a_stack_of_blocks_traces_the_program_it_did_before_segments(remat):
     assert AFMOE_HF["num_hidden_layers"] == 5
 
 
+# sha256 of str(jaxpr) of the backward pass of a stack whose scanned layers
+# walk their live bands (one row alone of 96 or 256 cells at bands of 16,
+# `bands=True`, remat full): taken at the commit before PR 46 (869f2e1), where
+# `forward.layer_body` held such a layer a second time (`looped`). "dense"
+# is that commit's. The three with experts were taken again at PR 46 for
+# ops that stand where the whole row has them and compute what they did:
+# the reshape of the experts' sum from `[T, D]` to the row's `[1, T, D]`
+# is `moe.moe_mlp`'s, before the layer's aux sums are added and not after
+# them (afmoe 8994ddb7..., latent a5937252... at 869f2e1: no other line of
+# the jaxpr differs); and the indexed layer's three sums join the carry in
+# the layer's one sum with the experts', after the routed experts, not on
+# their own after the attention call (indexed aa430367... at 869f2e1: those
+# adds and the names after them).
+LOOPING_JAXPR = {
+    "afmoe": "f23332a142a1ca645ad827303ab15225f01b25639a65ec9d9a5b04c598304fa6",
+    "latent": "371185f2b21532005e8040a8ebf21979086edc433d8a53e62a1fad8dec84e605",
+    "indexed": "f8414a0388ce27ce05d56210d5cb72a45b59e3d2341255a35b89c0772c8d8774",
+    "dense": "0154f30c09a2fff80afea6c6d94735dadef447ee9a2428d4a3193e05505657dc",
+}
+
+
+@pytest.mark.parametrize("stack", sorted(LOOPING_JAXPR))
+def test_a_stack_that_walks_its_bands_traces_the_program_it_did_with_two_bodies(
+        stack, monkeypatch):
+    """The looping program of each kind that loops: the stack of blocks
+    above (a leading dense layer over the whole row, expert layers
+    `s s f s` with a shared expert, gate and four norms in one scan), the
+    latent stack with its prediction module, the indexed stack with its KL
+    and a one-kind dense stack."""
+    from areal_tpu.models.transformer import looping_layers
+    from tests.model import test_indexed_stack, test_latent_stack
+
+    ran = small_bands(monkeypatch)
+    kw, T, want = {}, 256, 2
+    if stack == "afmoe":
+        cfg, T, want = _afmoe_cfg(), 96, 4
+    elif stack == "latent":
+        cfg, kw = test_latent_stack._cfg(), dict(mtp=True)
+    elif stack == "indexed":
+        cfg, kw = test_indexed_stack._cfg(), dict(index_loss=True)
+    else:
+        kind = LayerKind(mlp="dense", window=None, rotary=True)
+        hf = dict(AFMOE_HF, num_hidden_layers=2, num_dense_layers=2,
+                  layer_types=["full_attention"] * 2)
+        cfg = _afmoe_cfg(hf, layer_kinds=(kind, kind))
+    assert looping_layers(cfg, 1, T) == want
+    params = jax.eval_shape(lambda k: init_params(cfg, k), jax.random.PRNGKey(0))
+    ids = jnp.zeros((1, T), jnp.int32)
+
+    def loss(p, ids):
+        out, aux = forward(p, cfg, ids, jnp.ones_like(ids), jnp.arange(T)[None],
+                           attn_impl="reference", remat="full", return_aux=True,
+                           output="hidden", bands=True, **kw)
+        return sum(o.sum() for o in jax.tree_util.tree_leaves(out)) + sum(
+            a.sum() for a in jax.tree_util.tree_leaves(aux))
+
+    sha = hashlib.sha256(str(jax.make_jaxpr(jax.grad(loss))(params, ids)).encode()).hexdigest()
+    assert ran == ["_before_mixer", "_after_mixer"]
+    assert sha == LOOPING_JAXPR[stack], sha
+
+
 def test_seeded_weights_of_a_stack_of_blocks_are_the_ones_they_were():
     """`init_params` draws an accepted configuration's weights from the
     same keys as before: a checksum taken at the parent commit."""

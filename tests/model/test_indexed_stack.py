@@ -387,9 +387,9 @@ def test_what_the_stack_cannot_run_is_refused_by_mechanism():
         TransformerConfig(n_layers=1, layer_kinds=(LayerKind(indexed=True),))
     # the context-parallel paths refuse the indexer by name
     lp = jax.tree_util.tree_map(lambda a: a[0], params["layers"]["attn"])
-    x = jnp.zeros((1, 32, 32))
+    q, k, v, _, _ = tf._attn_in(jnp.zeros((1, 32, 32)), lp, cfg, jnp.float32)
     cos = sin = jnp.zeros((1, 32, 4))
     for impl in ("ring", "ulysses"):
         with pytest.raises(NotImplementedError, match="with an indexer"):
-            tf._attention_block(x, lp, cfg, cos, sin, seg, pos, impl, jnp.float32,
-                                index=tf._Index(cos, sin, False, None))
+            tf._attn_core(q, k, v, cfg, cos, sin, seg, pos, impl, None, ((None, True),), None,
+                          False, tf._Index(cos, sin, False, None), None)

@@ -17,7 +17,8 @@ import pytest
 from areal_tpu.models import moe as moe_lib
 from areal_tpu.models.config import LayerKind, MLAConfig, MTPConfig, TransformerConfig
 from areal_tpu.models.hf import family_from_hf_config, get_family
-from areal_tpu.models.transformer import _latent_attention_block, forward, init_params
+from areal_tpu.models import transformer as tf
+from areal_tpu.models.transformer import forward, init_params
 from areal_tpu.ops.loss import fused_next_token_logprobs, head_cells_run, two_on
 from areal_tpu.ops.rotary import rotary_cos_sin, rotary_inv_freq
 from benchmark.reference import joyai_llm_flash as ref
@@ -186,8 +187,9 @@ def test_the_latent_block_is_the_references_materialised_form():
     cos, sin = rotary_cos_sin(pos, jnp.asarray(rotary_inv_freq(4, cfg.rotary_base)))
     assert cfg.rotary_dim == 4 and cos.shape == (1, T, 2)
     with jax.default_matmul_precision("highest"):
-        got, (k, v) = _latent_attention_block(
-            h, at, cfg, cos, sin, jnp.ones((1, T), jnp.int32), pos, "reference", jnp.float32)
+        q, k, v = tf._latent_in(h, at, cfg, cos, sin, jnp.float32)
+        got = tf._latent_out(tf._latent_core(
+            q, k, v, cfg, jnp.ones((1, T), jnp.int32), pos, "reference", None), at, jnp.float32)
         want = ref.latent_attention(h[0], at, HF)
     assert k.shape == (1, T, 4, 12) and v.shape == (1, T, 4, 16)
     # every head's k ends in the same rope key

@@ -33,14 +33,19 @@ def test_attn_on_a_sharded_mesh_needs_a_shard_map_layout(on_tpu):
     fsdp2 = make_mesh(MeshSpec.parse("d1f2"), jax.devices()[:2])
     # Rows split over fsdp=2: splash has a layout ...
     assert A._choose_attn_impl("auto", 1024, 12, 2, fsdp2, 4)[0] == "splash"
-    # ... but not for an odd row count, and flash has none at all: both
-    # run the partitionable reference, and the reason says so.
-    for impl, r in (("auto", 3), ("flash", 4)):
-        ran, why = A._choose_attn_impl(impl, 1024, 12, 2, fsdp2, r)
-        assert ran == "reference" and "no shard_map layout" in why
-    # Explicit requests pass through on one device.
-    assert A._choose_attn_impl("flash", 1024, 12, 2, None, None) == (
-        "flash", "requested")
+    # ... but not for an odd row count: that runs the partitionable
+    # reference, and the reason says so.
+    ran, why = A._choose_attn_impl("auto", 1024, 12, 2, fsdp2, 3)
+    assert ran == "reference" and "no shard_map layout" in why
+    # Explicit requests pass through on one device ...
+    assert A._choose_attn_impl("splash", 1024, 12, 2, None, None) == ("splash", "requested")
+    # ... and a name that is no implementation's (the kernel that left with
+    # PR 46, one no one ever wrote) is refused with the names that are, on
+    # one device and on the mesh, where it used to run the reference.
+    for impl in ("flash", "blocked"):
+        for mesh, r in ((None, None), (fsdp2, 4)):
+            with pytest.raises(ValueError, match="auto, splash, reference, ring, ulysses"):
+                A._choose_attn_impl(impl, 1024, 12, 2, mesh, r)
 
 
 def test_paged_decode_auto_off_tpu_is_the_gather_path():
