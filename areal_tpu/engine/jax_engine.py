@@ -141,8 +141,10 @@ def _kinds_label(cfg: TransformerConfig) -> str:
     layer that reads layer n's tensor `<n`, one that keeps its own `^`:
     `ssm+dense^`, `dense.diff.full.nope<5`, `gmu+dense<4`; latent
     attention says `latent.`, attention over the keys an indexer chooses
-    `indexed.`, and a prediction module after the stack ends the label
-    with `+mtp`."""
+    `indexed.`, a layer over four residual streams starts with `hc4.`, and
+    a prediction module after the stack ends the label with `+mtp`."""
+    streams = f"hc{cfg.hyper.n}." if cfg.hyper is not None else ""
+
     def name(k):
         attn = (f"{'diff.' if k.diff else ''}{'latent.' if k.latent else ''}"
                 f"{'indexed.' if k.indexed else ''}"
@@ -150,7 +152,7 @@ def _kinds_label(cfg: TransformerConfig) -> str:
                 f"{'rope' if k.rotary else 'nope'}")
         keeps, reads = "^" if k.keeps else "", "" if k.reads is None else f"<{k.reads}"
         if k.block:
-            return f"{k.mlp}.{attn}{keeps}{reads}"
+            return f"{streams}{k.mlp}.{attn}{keeps}{reads}"
         return (f"attn.{attn}{keeps}" if k.mixer == "attention" else k.parts) + reads
 
     names = [name(k) for k in cfg.kinds()]
@@ -205,7 +207,7 @@ class JaxTrainEngine(TrainEngine):
         # stack of one plain kind.
         self._stack_attrs: Dict[str, Any] = {}
         if (model_cfg.layer_kinds is not None or model_cfg.mla is not None
-                or model_cfg.indexer is not None):
+                or model_cfg.indexer is not None or model_cfg.hyper is not None):
             windows = sorted({k.window for k in model_cfg.kinds()
                               if k.window is not None})
             self._stack_attrs = dict(window=windows[0] if windows else None,
@@ -437,7 +439,8 @@ class JaxTrainEngine(TrainEngine):
         is_critic = self.model_cfg.is_critic
         mtp = self._mtp_weight > 0
         mesh = self.mesh if self.mesh.size > 1 else None
-        sums = self.model_cfg.moe is not None or self._n_indexed > 0
+        hyper = self.model_cfg.hyper is not None
+        sums = self.model_cfg.moe is not None or self._n_indexed > 0 or hyper
 
         def compute(p, rows):
             out = model_forward(
@@ -544,6 +547,14 @@ class JaxTrainEngine(TrainEngine):
                 aux["num:indexer_selected"] = moe_aux["index_chosen"]
                 aux["den:indexer_selected"] = moe_aux["index_cells"]
                 loss_sum = loss_sum + self._index_weight * kl
+            if hyper:
+                # What Sinkhorn left undone of H_res (the largest distance of
+                # a row or column sum from 1), a mean over the step's real
+                # tokens and the stack's sublayers: 0 where it converged.
+                aux = dict(aux)
+                aux["num:mhc_res_err"] = moe_aux["mhc_res_err"]
+                aux["den:mhc_res_err"] = 2.0 * self.model_cfg.n_layers * jnp.sum(
+                    rows["segment_ids"] > 0).astype(jnp.float32)
             return loss_sum, aux
 
         return compute
@@ -930,6 +941,7 @@ class JaxTrainEngine(TrainEngine):
                           + self._ssm_counts(rows["segment_ids"])
                           + self._index_counts(rows)
                           + self._band_counts(rows["segment_ids"])
+                          + self._mhc_counts(rows["segment_ids"])
                           for a, rows in zip(attn, stacks)]
                 self._count_batch(
                     "fused", len(mbs), sum(b.n_rows == 1 for b, _ in built),
@@ -999,7 +1011,8 @@ class JaxTrainEngine(TrainEngine):
                               *self._mtp_counts(rows, scored_fn),
                               *self._ssm_counts(rows["segment_ids"]),
                               *self._index_counts(rows),
-                              *self._band_counts(rows["segment_ids"]))
+                              *self._band_counts(rows["segment_ids"]),
+                              *self._mhc_counts(rows["segment_ids"]))
                     attn_attrs = dict(attn_row_len=run_len, width=width)
             return (rows_dev, denom, batch.total_tokens, cells, attn_attrs, counts)
 
@@ -1016,7 +1029,7 @@ class JaxTrainEngine(TrainEngine):
         # and its resets; the indexers' cells scored, kept, and queries that
         # choose; the cells the layers' token-wise stretches run; counted
         # while tracing is on (`n_counted` of the micro-batches)
-        n_counts, n_counted = [0] * 17, 0
+        n_counts, n_counted = [0] * 19, 0
         gaps_ms: List[float] = []
         mark = time.monotonic_ns()
         try:
@@ -1105,15 +1118,18 @@ class JaxTrainEngine(TrainEngine):
 
         return datapack.ladder_step(row_len, self.row_len_multiple) > _BAND
 
-    def _band_counts(self, segment_ids: np.ndarray) -> Tuple[int]:
-        """The cells the layers' token-wise stretches run (on the host,
-        before the transfer; `segment_ids` [R, T] of one micro-batch or [n,
-        R, T] of several), by the device's own rule
-        (`ops/band_loop.band_cells_run`: the bands of `_BAND` cells up to a
-        row's last token, for one row alone of two bands or more), a mean
-        over the stack's layers: one whose kind keeps the whole row
+    def _stretch_cells(self, segment_ids: np.ndarray) -> List[Tuple[int, int]]:
+        """A micro-batch (`segment_ids` [R, T] of one or [n, R, T] of
+        several): the cells its layers' token-wise steps run, summed over
+        the stack's layers, on the host before the transfer by the
+        device's own rule, as (those of the layers that walk bands, those
+        of the layers that run the row whole):
+        `ops/band_loop.band_cells_run` (the bands of `_BAND` cells up to
+        a row's last token, for one row alone of two bands or more) for
+        the first; a layer whose kind keeps the whole row
         (`models/transformer.looping_layers`) counts every cell, and so
-        does a row the packer fills to the last band (`_dead_bands`)."""
+        does every layer of a row the packer fills to the last band
+        (`_dead_bands`)."""
         from areal_tpu.models.transformer import looping_layers
         from areal_tpu.ops.band_loop import band_cells_run
 
@@ -1123,7 +1139,25 @@ class JaxTrainEngine(TrainEngine):
         loop = looping_layers(
             self.model_cfg, *mbs.shape[1:], sharded=self.mesh.size > 1
         ) if self._dead_bands(mbs.shape[2]) else 0
-        return (sum((loop * band_cells_run(mb) + (n - loop) * mb.size) // n for mb in mbs),)
+        return [(loop * band_cells_run(mb), (n - loop) * mb.size) for mb in mbs]
+
+    def _band_counts(self, segment_ids: np.ndarray) -> Tuple[int]:
+        """The cells the layers' token-wise stretches run
+        (`_stretch_cells`), a mean over the stack's layers."""
+        n = self.model_cfg.n_layers
+        return (sum((bands + whole) // n for bands, whole in self._stretch_cells(segment_ids)),)
+
+    def _mhc_counts(self, segment_ids: np.ndarray) -> Tuple[int, int]:
+        """The cells the stream steps of hyper-connections run
+        (`models/transformer._hc_read`, `_hc_write`), summed over the two
+        sublayers of every layer by `_stretch_cells`' rule, and those of
+        them inside a layer that walks bands, whose backward loop makes a
+        band's forward once more (`ops/band_loop.py`); 0 for one stream."""
+        if self.model_cfg.hyper is None:
+            return (0, 0)
+        cells = self._stretch_cells(segment_ids)
+        return (2 * sum(bands + whole for bands, whole in cells),
+                2 * sum(bands for bands, _ in cells))
 
     def _attn_counts(self, segment_ids: np.ndarray) -> Tuple[int, ...]:
         """What the attention kernels do with packed rows (on the host,
@@ -1220,7 +1254,8 @@ class JaxTrainEngine(TrainEngine):
                      n_ssm_chunks: int = 0,
                      n_ssm_live: int = 0, n_ssm_mixed: int = 0, n_ssm_resets: int = 0,
                      n_index_cells: int = 0, n_index_selected: int = 0,
-                     n_index_choosing: int = 0, n_band_cells: int = 0):
+                     n_index_choosing: int = 0, n_band_cells: int = 0,
+                     n_mhc_cells: int = 0, n_mhc_loop_cells: int = 0):
         """What one train_batch did, on its `train.batch` span and in the
         recorder's counters: the micro-batches and how many of them the
         packer made one row (what lets attention skip the block pairs
@@ -1236,8 +1271,10 @@ class JaxTrainEngine(TrainEngine):
         layers made, the chunks the state-space layers' scan ran
         (the selective scan's also as positions: chunks x their length),
         the cells the indexers scored, those an exact choice keeps and
-        the queries that had more keys than they keep, and the cells the
-        layers' token-wise stretches ran (`_band_counts`)."""
+        the queries that had more keys than they keep, the cells the
+        layers' token-wise stretches ran, the cells the stream steps of
+        hyper-connections ran over the stack's sublayers and those of them
+        inside a band loop (`_band_counts`, `_mhc_counts`)."""
         tracing.set_attrs(path=path, n_mbs=n_mbs, tokens=n_tok, cells=n_cells)
         tracing.count("train.batches")
         tracing.count("train.micro_batches", n_mbs)
@@ -1245,6 +1282,9 @@ class JaxTrainEngine(TrainEngine):
         tracing.count("train.tokens", n_tok)
         tracing.count("train.cells", n_cells)
         tracing.count("train.band_cells", n_band_cells)
+        if self.model_cfg.hyper is not None:
+            tracing.count("train.mhc_cells", n_mhc_cells)
+            tracing.count("train.mhc_loop_cells", n_mhc_loop_cells)
         tracing.count("train.attn_cells", n_attn_cells)
         tracing.count("train.attn_active_cells", n_attn_active)
         tracing.count("train.attn_causal_cells", n_attn_causal)

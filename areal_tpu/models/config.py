@@ -11,8 +11,9 @@ dense or expert, either of which may be absent; a layer may keep a tensor
 that later layers read), an attention output gate,
 post-norms, a sigmoid router with a selection bias, shared experts, a
 share of the experts held here, a multi-token-prediction module
-after the stack (`MTPConfig`), and an indexer beside attention that
-chooses each query's keys (`IndexerConfig`).
+after the stack (`MTPConfig`), an indexer beside attention that
+chooses each query's keys (`IndexerConfig`), and a residual path of
+several streams a token (`HyperConnConfig`).
 """
 
 from __future__ import annotations
@@ -177,10 +178,21 @@ class MLAConfig:
     nope_dim: int = 8
     rope_dim: int = 8
     v_dim: int = 8
+    # What the softmax scale `qk_dim^-0.5` is multiplied by: YaRN's
+    # `mscale(factor, mscale_all_dim)^2` where the rope part's table is
+    # scaled (DeepSeek-V3's `softmax_scale`), 1 otherwise.
+    softmax_scale_factor: float = 1.0
 
     @property
     def qk_dim(self) -> int:
         return self.nope_dim + self.rope_dim
+
+    @property
+    def softmax_scale(self) -> Optional[float]:
+        """The attention call's scale, or None for its own `qk_dim^-0.5`."""
+        if self.softmax_scale_factor == 1.0:
+            return None
+        return self.qk_dim ** -0.5 * self.softmax_scale_factor
 
 
 @dataclasses.dataclass
@@ -239,6 +251,43 @@ class IndexerConfig:
         return self.head_dim ** -0.5 * self.n_heads ** -0.5
 
 
+@dataclasses.dataclass
+class HyperConnConfig:
+    """Manifold-constrained hyper-connections (mHC, arXiv:2512.24880, on
+    Hyper-Connections, arXiv:2409.19606): a token has `n` residual
+    streams `X` `[n, D]`, and each sublayer `F` (a layer's mixer, then its
+    MLP) has `Phi` `[n D, n^2 + 2 n]`, `b` `[n^2 + 2 n]` and three scalars
+    `a` = (pre, post, res). In float32, with `x~ = vec(X)`:
+
+        m      = rsqrt(mean(x~^2) + norm_eps) * (x~ Phi)
+        H_pre  = sigmoid(a_pre m[:n] + b[:n])                    [1, n]
+        H_post = 2 sigmoid(a_post m[n:2n] + b[n:2n])             [1, n]
+        M_0    = exp(clip(a_res mat(m[2n:]) + mat(b[2n:]), clamp))  [n, n]
+        M_k    = cols(rows(M_{k-1})), rows(M) = M / (sum_j M + eps), cols
+                 alike; H_res = M_{sinkhorn_iters}: doubly stochastic
+        h = H_pre X;  y = F(norm(h));  X' = H_res X + H_post^T y
+
+    The stack starts from `n` copies of the embedding and ends in the sum
+    of the streams (`models/transformer._hc_read`, `_hc_write`)."""
+
+    n: int = 4
+    sinkhorn_iters: int = 20
+    eps: float = 1e-6
+    clamp: Tuple[float, float] = (-30.0, 30.0)
+
+    def __post_init__(self):
+        self.clamp = tuple(float(c) for c in self.clamp)
+        if self.n < 2 or self.sinkhorn_iters < 1 or len(self.clamp) != 2:
+            raise ValueError(
+                f"HyperConnConfig: n >= 2 streams, sinkhorn_iters >= 1 and a clamp "
+                f"of (min, max), got {self}")
+
+    @property
+    def n_coef(self) -> int:
+        """Columns of `Phi`: H_pre's n, H_post's n, H_res's n^2."""
+        return self.n * (self.n + 2)
+
+
 @dataclasses.dataclass(frozen=True)
 class LayerKind:
     """What one layer of the stack is, known when the program is traced:
@@ -253,7 +302,9 @@ class LayerKind:
     (`transformer._diff_combine`), and `latent`: q and k, v through
     low-rank projections (`MLAConfig`, `transformer._latent_in`),
     and `indexed`: an indexer of its own parameters chooses the keys
-    each query reads (`IndexerConfig`, `ops/indexer.py`).
+    each query reads (`IndexerConfig`, `ops/indexer.py`). (The residual
+    streams a layer's parts read and write are the stack's, not a layer's:
+    `TransformerConfig.hyper`.)
 
     Two relations between layers. `keeps`: the layer hands a tensor on
     to later layers: an "ssm" mixer its scan's output before the gate,
@@ -426,6 +477,10 @@ class TransformerConfig:
     # The indexer's sizes; with them and no `layer_kinds`, every layer's
     # attention reads the keys its indexer chooses.
     indexer: Optional[IndexerConfig] = None
+    # The residual path: `n` streams a token under hyper-connections, or
+    # None for the one plain stream; every layer's parts read and write
+    # them, and carry a sublayer's `phi`, `b`, `a` beside their own.
+    hyper: Optional[HyperConnConfig] = None
     # One LayerKind a layer, filled by the family from the published
     # config; None = every layer the same (moe or dense by `moe`, full
     # causal attention, rotary by `pos_emb`).
@@ -458,6 +513,8 @@ class TransformerConfig:
             self.mtp = MTPConfig(**self.mtp)
         if isinstance(self.indexer, dict):
             self.indexer = IndexerConfig(**self.indexer)
+        if isinstance(self.hyper, dict):
+            self.hyper = HyperConnConfig(**self.hyper)
         if self.activation not in ("silu", "gelu", "relu2"):
             raise ValueError(
                 f"activation must be 'silu', 'gelu' or 'relu2', got {self.activation!r}")
@@ -488,6 +545,21 @@ class TransformerConfig:
                     f"{self.n_kv_heads} heads)")
         if any(k.indexed for k in kinds) and self.indexer is None:
             raise ValueError("an indexed attention layer needs TransformerConfig.indexer")
+        if self.hyper is not None:
+            if self.mtp is not None:
+                raise NotImplementedError(
+                    "a prediction module after a stack of several residual streams: "
+                    "how the module reads the streams and hands them on is in no "
+                    "published config or paper; models/transformer.py runs the "
+                    "module over one stream")
+            if self.is_critic or self.norm_type != "rms" or not all(
+                    k.block and not k.diff and k.reads is None and not k.keeps
+                    for k in kinds):
+                raise NotImplementedError(
+                    "hyper-connections are computed around an attention mixer and "
+                    "an MLP under RMSNorms in every layer of an actor: no "
+                    "state-space or memory mixer, no differential pairing, no kept "
+                    "tensor, no LayerNorm, no critic head")
         if self.mtp is not None and (self.is_critic or not kinds[-1].block):
             raise ValueError(
                 "the prediction module is one more transformer block of the "
@@ -586,12 +658,12 @@ class TransformerConfig:
 
     @property
     def one_kind(self) -> bool:
-        """Every layer the same transformer block, full causal
-        attention, rotary as `pos_emb` says: what the KV-cache paths
-        (generation, serving, paged) can run."""
+        """Every layer the same transformer block over one residual
+        stream, full causal attention, rotary as `pos_emb` says: what the
+        KV-cache paths (generation, serving, paged) can run."""
         kinds = self.kinds()
         plain = LayerKind(mlp=kinds[0].mlp, rotary=self.pos_emb == "rotary")
-        return all(k == plain for k in kinds)
+        return self.hyper is None and all(k == plain for k in kinds)
 
     def require_plain_stack(self, where: str) -> None:
         """The KV-cache paths (models/generation.py, engine/paged.py,
@@ -616,6 +688,11 @@ class TransformerConfig:
                 f"{self.indexer.top_k} pages' rows inside paged decode; "
                 "models/transformer.py scores and chooses over a whole packed "
                 "row, as a training or prefill pass does")
+        if self.hyper is not None:
+            missing.append(
+                f"{self.hyper.n} residual streams a token: the decode layer carries "
+                "one stream and has no read, write or Sinkhorn step of "
+                "hyper-connections (models/transformer._hc_read, _hc_write)")
         if self.mtp is not None:
             missing.append(
                 "the multi-token-prediction module: the cache paths have no "

@@ -4,8 +4,10 @@ of DeepSeek-V3's shape.
 Every layer attends through latent attention (`models/config.MLAConfig`:
 `q_lora_rank`, `kv_lora_rank`, `qk_nope_head_dim`, `qk_rope_head_dim`,
 `v_head_dim`; the config's `head_dim` is the rope part again), rotary
-interleaved over the rope part alone (`rope_interleave`), no rope
-scaling. The first `first_k_dense_replace` layers have a dense SwiGLU of
+interleaved over the rope part alone (`rope_interleave`), its table
+plain (`rope_scaling` null, the published file's) or YaRN's
+(`_rope_scaling`: DeepSeek-V3's, the softmax scale times `mscale^2`). The
+first `first_k_dense_replace` layers have a dense SwiGLU of
 `intermediate_size`, the rest (`moe_layer_freq` 1) a sigmoid-routed
 expert layer: `n_routed_experts` experts of `moe_intermediate_size`,
 `num_experts_per_tok` a token chosen on score + `e_score_correction_bias`
@@ -45,32 +47,59 @@ from areal_tpu.models.hf import HFFamily
 MODEL_TYPE = "joyai_llm_flash"
 
 
-def _config_from_hf(hf: Dict[str, Any], is_critic: bool = False) -> TransformerConfig:
+def _rope_scaling(hf: Dict[str, Any], family: str):
+    """`rope_scaling` of a DeepSeek-V3-shaped config -> (TransformerConfig's
+    three rotary-scaling fields, the softmax scale's factor): none and 1
+    for a plain table; YaRN (`ops/rotary.py`) over the rope part with the
+    scale times `mscale(factor, mscale_all_dim)^2`. cos and sin carry
+    `mscale(factor, mscale) / mscale(factor, mscale_all_dim)`, which is 1
+    in every published config of this shape; another ratio is refused."""
+    from areal_tpu.ops.rotary import yarn_mscale
+
+    rs = hf.get("rope_scaling")
+    if not rs:
+        return {}, 1.0
+    kind = rs.get("type", rs.get("rope_type"))
+    if kind != "yarn":
+        raise NotImplementedError(
+            f"{family}: rope_scaling type {kind!r}: latent attention's rope "
+            "part has a plain table or YaRN's (ops/rotary.py), no other")
+    if rs.get("mscale", 1.0) != rs.get("mscale_all_dim", 0.0):
+        raise NotImplementedError(
+            f"{family}: rope_scaling mscale {rs.get('mscale')} != mscale_all_dim "
+            f"{rs.get('mscale_all_dim')}: cos and sin would carry their ratio, which "
+            "ops/rotary.py's tables do not")
+    factor = float(rs["factor"])
+    params = {k: v for k, v in rs.items() if k not in ("type", "rope_type", "factor")}
+    return (dict(rotary_scaling=factor, rotary_scaling_type="yarn",
+                 rotary_scaling_params=params),
+            yarn_mscale(factor, float(rs["mscale_all_dim"])) ** 2)
+
+
+def _config_from_hf(hf: Dict[str, Any], is_critic: bool = False,
+                    family: str = MODEL_TYPE) -> TransformerConfig:
     for key in ("n_group", "topk_group"):
         if hf.get(key, 1) not in (None, 1):
             raise NotImplementedError(
-                f"{MODEL_TYPE}: {key}={hf[key]}: group-limited routing is not "
+                f"{family}: {key}={hf[key]}: group-limited routing is not "
                 "in models/moe.py's router")
-    if hf.get("rope_scaling"):
-        raise NotImplementedError(
-            f"{MODEL_TYPE}: rope_scaling={hf['rope_scaling']}: latent attention "
-            "here has no scaled rotary table and no softmax scale correction")
+    scaling, scale_factor = _rope_scaling(hf, family)
     if hf.get("moe_layer_freq", 1) != 1 or hf.get("scoring_func", "sigmoid") != "sigmoid":
         raise NotImplementedError(
-            f"{MODEL_TYPE}: an expert layer every layer after the dense ones, "
+            f"{family}: an expert layer every layer after the dense ones, "
             "sigmoid scores: got moe_layer_freq "
             f"{hf.get('moe_layer_freq')}, scoring_func {hf.get('scoring_func')!r}")
     if not hf.get("q_lora_rank"):
         raise NotImplementedError(
-            f"{MODEL_TYPE}: q_lora_rank is absent: a full-rank q beside a "
+            f"{family}: q_lora_rank is absent: a full-rank q beside a "
             "low-rank kv is not in models/transformer.py's latent block")
     mla = MLAConfig(
         q_rank=int(hf["q_lora_rank"]), kv_rank=int(hf["kv_lora_rank"]),
         nope_dim=int(hf["qk_nope_head_dim"]), rope_dim=int(hf["qk_rope_head_dim"]),
-        v_dim=int(hf["v_head_dim"]))
+        v_dim=int(hf["v_head_dim"]), softmax_scale_factor=scale_factor)
     heads = int(hf["num_attention_heads"])
     if int(hf.get("num_key_value_heads", heads)) != heads:
-        raise ValueError(f"{MODEL_TYPE}: latent attention has k and v a head")
+        raise ValueError(f"{family}: latent attention has k and v a head")
     held = int(hf["n_routed_experts"])
     routed = int(hf.get("num_experts_routed", held))
     first = int(hf.get("experts_held_first", 0))
@@ -102,6 +131,7 @@ def _config_from_hf(hf: Dict[str, Any], is_critic: bool = False) -> TransformerC
         norm_eps=float(hf.get("rms_norm_eps", 1e-6)),
         rotary_base=float(hf.get("rope_theta", 10000.0)),
         rotary_interleaved=bool(hf.get("rope_interleave", True)),
+        **scaling,
         attn_bias=bool(hf.get("attention_bias", False)),
         tied_embeddings=bool(hf.get("tie_word_embeddings", False)),
         is_critic=is_critic,
@@ -130,7 +160,8 @@ def _config_to_hf(cfg: TransformerConfig) -> Dict[str, Any]:
         hidden_act="silu",
         rms_norm_eps=cfg.norm_eps,
         rope_theta=cfg.rotary_base, rope_interleave=cfg.rotary_interleaved,
-        rope_scaling=None,
+        rope_scaling=None if cfg.rotary_scaling_type != "yarn" else dict(
+            cfg.rotary_scaling_params, factor=cfg.rotary_scaling, type="yarn"),
         attention_bias=cfg.attn_bias,
         tie_word_embeddings=cfg.tied_embeddings,
         first_k_dense_replace=moe.first_k_dense, moe_layer_freq=1,

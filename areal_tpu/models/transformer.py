@@ -51,6 +51,18 @@ real_llm_base.py (blocks) — redesigned for XLA rather than translated:
   of one more block that reads the stack's output and the next token's
   embedding, for a loss over the token after that.
 
+- **Several residual streams a token** (`config.HyperConnConfig`,
+  manifold-constrained hyper-connections): the carry of the stack is a
+  token's `n` streams one after the other, `[R, T, n D]` (`vec(X)` a
+  row: a stream of a tile of tokens is then a lane-aligned slab, which
+  `[.., n, D]` with `n` = 4 on the chip's sublanes is not). Every
+  sublayer reads its input from them through `_hc_read` and writes its
+  output back through `_hc_write`, both token-wise: they run inside the
+  steps a row walks band by band and in the join after the routed
+  experts. The stack starts from `n` copies of the embedding and ends in
+  the sum of the streams. With `cfg.hyper` None both are the plain `x`
+  and `x + y`.
+
 The KV-cache decode path lives in areal_tpu/models/generation.py.
 """
 
@@ -65,9 +77,10 @@ import jax.numpy as jnp
 
 from areal_tpu.models.config import LayerKind, TransformerConfig
 from areal_tpu.models.moe import activation_fn
-from areal_tpu.ops import band_loop
+from areal_tpu.ops import band_loop, hyper_conn
 from areal_tpu.ops.attention import packed_attention, reference_packed_attention
 from areal_tpu.ops.norms import layer_norm, rms_norm
+from areal_tpu.ops.pallas import stream_mix
 from areal_tpu.ops.rotary import apply_rotary, rotary_cos_sin, rotary_inv_freq
 # qmat == `h @ w.astype(cdt)` for plain weights; the serving decode path
 # may pass (int8, scale) pairs instead (ops/wquant.py W8A16).
@@ -174,6 +187,18 @@ def _init_layer_stack(cfg: TransformerConfig, keys, n: int, kind: LayerKind,
         layers["mlp"] = mlp
         norms += ["ln2"] + (["ln2_post"] if cfg.post_norms else [])
 
+    hy = cfg.hyper
+    if hy is not None:  # a sublayer's hyper-connections: the mixer's, the MLP's
+        for j, name in enumerate(("hc1", "hc2")):
+            if (kind.mixer, kind.mlp)[j] is None:
+                continue
+            k_phi, k_b = jax.random.split(jax.random.fold_in(keys[15], 64 + j))
+            layers[name] = {
+                "phi": dense(k_phi, (L, hy.n * D, hy.n_coef)),
+                "b": dense(k_b, (L, hy.n_coef), _HC_BIAS_SCALE),
+                "a": jnp.full((L, 3), _HC_GATE, pdt),  # pre, post, res
+            }
+
     for name in norms:
         layers[name] = {"weight": jnp.ones((L, D), pdt)}
         if cfg.norm_type == "layer":
@@ -200,6 +225,24 @@ _INDEXED_Q_GAIN = 3.0
 # too, the usual draw stays: logits of that scale would be no model's.)
 _INDEXED_EMBED_SCALE = 2.0
 
+# The seeded draw of a sublayer's hyper-connections
+# (benchmark/configs/xing4.0-*.json `assumed`): the paper starts the three
+# gates at 0.01, which makes every coefficient a constant of the layer
+# (sigmoid(b), Sinkhorn of exp(b)), and a check of logprobs would then
+# see neither `phi` nor the token-dependent Sinkhorn. Here the gates
+# start at 1, `phi` is drawn at 1 / sqrt(n D) (`dense`'s: `m` has unit
+# variance a column) and `b` from N(0, 1): coefficients differ by token
+# and H_res is a generic doubly stochastic matrix.
+_HC_GATE = 1.0
+_HC_BIAS_SCALE = 1.0
+# A stack of several streams draws its embedding at `_INDEXED_EMBED_SCALE`
+# too, for that constant's reason: the streams start as `n` copies of the
+# embedding, and under unit scores (the YaRN draw below) attention writes
+# a sequence's running mean over them. At 0.02 the routers then follow the
+# sequence, not the token: the pairs that 8 of 64 experts hold swing from
+# 8.7 to 12.3 % by seed and fall through a run as the router trains, and
+# a step's seconds with them (PERF.md section 6, PR 47, third hand-in).
+
 # What the seeded draw of a latent attention layer departs by from
 # `dense`'s 1 / sqrt(fan-in), so that a check against a reference sees
 # every part of the layer (benchmark/configs/joyai-llm-flash-*.json
@@ -210,6 +253,16 @@ _INDEXED_EMBED_SCALE = 2.0
 # the two down-projections' latent columns are drawn `_LATENT_DOWN_GAIN`
 # times as large, so the RMSNorms inside the projections rescale what
 # they are given instead of passing on a vector that is unit already.
+# Where the rope part's table is scaled (YaRN: the softmax scale carries
+# `mscale^2`, `MLAConfig.softmax_scale_factor`) q keeps `dense`'s own
+# scale, over that factor, so that a head's scores have unit standard
+# deviation: dividing the low frequencies by 64 moves the scores enough
+# for a check to see the table without a peaked softmax (the unscaled
+# table moves a sequence's mean logprob by 0.56-0.65), and bf16's rounding
+# of peaked scores is most of what separates the program from its float32
+# reference: 0.15 on that mean at a gain of 3, 0.027 at 1, where the
+# subtlest control (one Sinkhorn iteration for twenty) reads 0.23 and 0.09
+# (PERF.md section 6, PR 47).
 _LATENT_Q_GAIN = 3.0
 _LATENT_DOWN_GAIN = 0.25
 
@@ -223,11 +276,12 @@ def _init_latent_attention(cfg: TransformerConfig, keys, L: int, dense) -> Dict[
     m, D, H = cfg.mla, cfg.hidden_dim, cfg.n_q_heads
     k_kvb, k_rope = jax.random.split(jax.random.fold_in(keys[15], 16))
     down = _LATENT_DOWN_GAIN / math.sqrt(D)
+    q_gain = _LATENT_Q_GAIN if m.softmax_scale_factor == 1.0 else 1.0 / m.softmax_scale_factor
     return {
         "wq_a": dense(keys[0], (L, D, m.q_rank), down),
         "q_a_norm": jnp.ones((L, m.q_rank), pdt),
         "wq_b": dense(keys[1], (L, m.q_rank, H * m.qk_dim),
-                      _LATENT_Q_GAIN / math.sqrt(m.q_rank)),
+                      q_gain / math.sqrt(m.q_rank)),
         "wkv_a": jnp.concatenate(
             [dense(keys[2], (L, D, m.kv_rank), down),
              dense(k_rope, (L, D, m.rope_dim))], axis=-1),
@@ -264,7 +318,8 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Params:
     kinds = cfg.kinds()
     params: Params = {
         "embedding": {"weight": dense(keys[7], (V, D), scale=(
-            _INDEXED_EMBED_SCALE if any(k.indexed for k in kinds)
+            _INDEXED_EMBED_SCALE
+            if (cfg.hyper is not None or any(k.indexed for k in kinds))
             and not cfg.tied_embeddings else 0.02))},
         "final_norm": {"weight": jnp.ones((D,), pdt)},
     }
@@ -344,9 +399,11 @@ def _mlp(h, lp, cfg, cdt):
     return out
 
 
-def _attention_kernel(q, k, v, segment_ids, positions, impl, cfg, mesh, window):
+def _attention_kernel(q, k, v, segment_ids, positions, impl, cfg, mesh, window,
+                      softmax_scale=None):
     """The attention call itself, by the resolved implementation:
-    q [R, T, Hq, hd], k and v [R, T, Hkv, hd] -> [R, T, Hq, hd]."""
+    q [R, T, Hq, hd], k and v [R, T, Hkv, hd] -> [R, T, Hq, hd].
+    `softmax_scale`: None = the head size's `hd^-0.5`."""
     from areal_tpu.ops.attention import (
         sharded_splash_attention,
         splash_packed_attention,
@@ -354,11 +411,12 @@ def _attention_kernel(q, k, v, segment_ids, positions, impl, cfg, mesh, window):
 
     R, T = q.shape[:2]
     sharded = mesh is not None and mesh.size > 1
-    if window is not None and impl in ("ring", "ulysses"):
+    if (window is not None or softmax_scale is not None) and impl in ("ring", "ulysses"):
         raise NotImplementedError(
             f"attn_impl={impl!r} (context parallelism over the mesh's seq "
-            "axis) has no window in its mask: ops/ring_attention.py and "
-            "ops/ulysses_attention.py build a causal mask only"
+            "axis) has no window in its mask and no softmax scale but the head "
+            "size's: ops/ring_attention.py and ops/ulysses_attention.py build a "
+            "causal mask only"
         )
     if impl == "ring":
         # Context parallelism: KV chunks ring-rotate over the seq axis
@@ -396,15 +454,16 @@ def _attention_kernel(q, k, v, segment_ids, positions, impl, cfg, mesh, window):
         # pallas_call is opaque to GSPMD: run the kernel per shard under
         # shard_map with the megatron-equivalent layout.
         out = sharded_splash_attention(
-            q, k, v, segment_ids, positions, mesh, window=window
+            q, k, v, segment_ids, positions, mesh, window=window,
+            softmax_scale=softmax_scale,
         )  # [R, T, Hq, hd]
     elif impl == "splash":
         # Rows go in whole: the wrapper sees how many share the call.
         out = splash_packed_attention(
-            q, k, v, segment_ids, positions, window=window)
+            q, k, v, segment_ids, positions, window=window, softmax_scale=softmax_scale)
     else:
         attn_fn = lambda q1, k1, v1, s1, p1: packed_attention(
-            q1, k1, v1, s1, p1, impl=impl, window=window
+            q1, k1, v1, s1, p1, softmax_scale=softmax_scale, impl=impl, window=window
         )
         out = jax.vmap(attn_fn)(q, k, v, segment_ids, positions)
     return out
@@ -621,13 +680,15 @@ def _latent_out(out, lp, cdt):
 
 def _latent_core(q, k, v, cfg, segment_ids, positions, attn_impl, mesh):
     """Latent attention's kernel call (scope `attn_kernel`): every head
-    has its own k and v."""
+    has its own k and v; the softmax scale is `MLAConfig.softmax_scale`
+    (the head size's times YaRN's `mscale^2` where the table is scaled)."""
     from areal_tpu.ops.attention import resolve_attn_impl
 
     H = cfg.n_q_heads
     impl = resolve_attn_impl(attn_impl, q.shape[1], H, H, mesh=mesh, r=q.shape[0])
     with jax.named_scope("attn_kernel"):
-        return _attention_kernel(q, k, v, segment_ids, positions, impl, cfg, mesh, None)
+        return _attention_kernel(q, k, v, segment_ids, positions, impl, cfg, mesh, None,
+                                 cfg.mla.softmax_scale)
 
 
 class _Stretch(NamedTuple):
@@ -651,6 +712,10 @@ class _Stretch(NamedTuple):
     # remat "mlp": the dense MLP under a checkpoint of its own. (No loop
     # has it: a stretch's backward rule keeps nothing of a band.)
     mlp_ckpt: bool = False
+    # The stream steps' kernels (`ops/pallas/stream_mix.py`, `sinkhorn.py`):
+    # None = on the chip where the shapes allow; False = the plain forms,
+    # on a mesh of several devices (a kernel is opaque to the partitioner).
+    hc_kernel: Optional[bool] = None
 
 
 # Of a plain attention layer's parameters, those its first step reads;
@@ -665,6 +730,34 @@ def _mixer_weights(st: _Stretch, mp, first: bool):
     return {n: w for n, w in mp.items() if (n in first_names) == first}
 
 
+def _hc_read(st: _Stretch, hp, x):
+    """A sublayer's input from the streams x `[R, T, n D]` under its
+    hyper-connections `hp` (`ops/hyper_conn.coefficients`, scope
+    `mhc_coef`): `h = H_pre X` `[R, T, D]` (scope `mhc_read`) and the
+    (H_post `[R, T, n]`, H_res `[R, T, n, n]`), float32, that
+    `_hc_write` puts its output back by. One stream (`cfg.hyper` None):
+    x itself, and nothing."""
+    hy = st.cfg.hyper
+    if hy is None:
+        return x, ()
+    with jax.named_scope("mhc_coef"):
+        h_pre, h_post, h_res = hyper_conn.coefficients(
+            hp, x, hy, st.cfg.norm_eps, st.hc_kernel)
+    with jax.named_scope("mhc_read"):
+        h = stream_mix.mhc_mix(h_pre[..., None, :], (x,), st.hc_kernel)
+    return h, (h_post, h_res)
+
+
+def _hc_write(st: _Stretch, x, y, h_post=None, h_res=None):
+    """The streams after a sublayer's output y `[R, T, D]`: `X' = H_res X
+    + H_post^T y` (scope `mhc_write`); one stream: `x + y`."""
+    if h_res is None:
+        return x + y
+    with jax.named_scope("mhc_write"):
+        a = jnp.concatenate([h_res, h_post[..., None]], axis=-1)  # [R, T, n, n + 1]
+        return stream_mix.mhc_mix(a, (x, y), st.hc_kernel)
+
+
 def _before_mixer(st: _Stretch, w, xs, side):
     """An attention layer up to what crosses tokens: its input x (then
     the k and v of the layer it reads, where it reads one) under `ln1`
@@ -673,13 +766,15 @@ def _before_mixer(st: _Stretch, w, xs, side):
     gate's and the indexer's projections, rotary where `st.rot_in`).
     `side`: the rotary tables, then the indexer's. Returns q, k, v, (the
     gate's projection), (the indexer's three), (differential attention:
-    k and v as projected, which a reader of this layer takes)."""
+    k and v as projected, which a reader of this layer takes), (several
+    streams: H_post and H_res, `_hc_read`'s, for `_after_mixer`)."""
     cfg, kind, cdt = st.cfg, st.kind, st.cdt
     (x, *kept), mp = xs, w["mixer"]
+    h, coefs = _hc_read(st, w.get("hc"), x)
     with jax.named_scope("attn_qkv"):
-        h = _norm(x, w["ln1"], cfg)
+        h = _norm(h, w["ln1"], cfg)
     if kind.latent:
-        return _latent_in(h, mp, cfg, side[0], side[1], cdt)
+        return _latent_in(h, mp, cfg, side[0], side[1], cdt) + coefs
     q, k, v, gate, own_kv = _attn_in(h, mp, cfg, cdt, tuple(kept) or None)
     if st.rot_in:
         with jax.named_scope("attn_qkv"):
@@ -688,14 +783,15 @@ def _before_mixer(st: _Stretch, w, xs, side):
     out = (q, k, v) if gate is None else (q, k, v, gate)
     if kind.indexed:
         out += _index_proj(h, mp["indexer"], cfg, side[-2], side[-1], cdt)
-    return out + own_kv if kind.diff else out
+    return (out + own_kv if kind.diff else out) + coefs
 
 
-def _mlp_joins(x, m, w, cfg):
-    """The stream x plus the MLP's product m under the layer's `ln2_post`."""
+def _mlp_joins(st: _Stretch, x, m, w, coefs=()):
+    """The stream x plus the MLP's product m under the layer's
+    `ln2_post`; several streams: m written to them by `coefs`."""
     if "ln2_post" in w:
-        m = _norm(m, w["ln2_post"], cfg)
-    return x + m
+        m = _norm(m, w["ln2_post"], st.cfg)
+    return _hc_write(st, x, m, *coefs)
 
 
 def _mlp_part(st: _Stretch, w, xs, side=()):
@@ -703,19 +799,23 @@ def _mlp_part(st: _Stretch, w, xs, side=()):
     `mlp`): `ln2` and the dense MLP with its residual, returned alone; or
     for an expert layer the stream and the experts' input, with the
     router's four and the shared expert's product where `st.route_in`. A
-    layer of a mixer alone hands x back."""
+    layer of a mixer alone hands x back. Several streams: x is they, the
+    MLP reads them through `_hc_read`, and the result ends in H_res
+    (the dense MLP's, its write done) or H_post and H_res (the experts',
+    for the join after them)."""
     cfg, kind, cdt = st.cfg, st.kind, st.cdt
     (x,) = xs
     if kind.mlp is None:
         return (x,)
+    h, coefs = _hc_read(st, w.get("hc"), x)
     with jax.named_scope("mlp"):
-        h = _norm(x, w["ln2"], cfg)
+        h = _norm(h, w["ln2"], cfg)
         if kind.mlp == "dense":
             mlp = jax.checkpoint(_mlp, static_argnums=(2, 3)) if st.mlp_ckpt else _mlp
             m = mlp(h, w["mlp"], cfg, cdt)
-            return (_mlp_joins(x, m, w, cfg),)
+            return (_mlp_joins(st, x, m, w, coefs),) + coefs[1:]
         if not st.route_in:
-            return x, h
+            return (x, h) + coefs
         from areal_tpu.models.moe import _router, _shared_expert
 
         ht = h.reshape(-1, h.shape[-1])
@@ -725,12 +825,13 @@ def _mlp_part(st: _Stretch, w, xs, side=()):
         if "shared" in w["mlp"]:
             out += (_shared_expert(ht, w["mlp"]["shared"], activation_fn(cfg.activation),
                                    cdt).reshape(h.shape),)
-        return out
+        return out + coefs
 
 
 def _after_mixer(st: _Stretch, w, xs, side):
     """An attention layer from the attention call's output (then the
-    gate's projection; the layer's input last) to the MLP's product or
+    gate's projection; several streams: `_before_mixer`'s H_post and
+    H_res; the layer's input last) to the MLP's product or
     the routed experts' doorstep: the output projection under the gate
     (`_attn_out`, with differential attention's combine under `w["l0"]`;
     `_latent_out`), `ln1_post` and the residual (scope `attn_out`), then
@@ -738,6 +839,10 @@ def _after_mixer(st: _Stretch, w, xs, side):
     set-up more than it saves the chip."""
     cfg, kind, cdt = st.cfg, st.kind, st.cdt
     *got, x = xs
+    coefs = ()
+    if cfg.hyper is not None:  # `_before_mixer`'s H_post and H_res
+        *got, h_post, h_res = got
+        coefs = (h_post, h_res)
     if kind.latent:
         a = _latent_out(got[0], w["mixer"], cdt)
     else:
@@ -746,7 +851,7 @@ def _after_mixer(st: _Stretch, w, xs, side):
     with jax.named_scope("attn_out"):
         if "ln1_post" in w:
             a = _norm(a, w["ln1_post"], cfg)
-        x = x + a
+        x = _hc_write(st, x, a, *coefs)
     return _mlp_part(st, w, (x,))
 
 
@@ -915,6 +1020,8 @@ def forward(
             x = x * jnp.asarray(cfg.embedding_multiplier, cdt)
         if cfg.pos_emb == "learned":
             x = x + params["pos_embedding"]["weight"][positions].astype(cdt)
+        if cfg.hyper is not None:  # every stream starts as the embedding
+            x = act_c(jnp.concatenate([x] * cfg.hyper.n, axis=-1))
 
     if cfg.pos_emb == "learned":
         cos = sin = None
@@ -969,13 +1076,15 @@ def forward(
             )
             remat_mode = "full"
     kinds = cfg.kinds()
-    if return_kv and not all(
+    if return_kv and (cfg.hyper is not None or not all(
             k == kinds[0] and k.block and not k.latent and not k.indexed
-            for k in kinds):
+            for k in kinds)):
         raise NotImplementedError(
-            "return_kv with layers of different kinds, latent attention or an "
-            "indexer: the KV cache (models/generation.py) holds one kind of "
-            "layer, plain attention and an MLP in each, has no recurrent state "
+            "return_kv with layers of different kinds, latent attention, an "
+            "indexer or several residual streams: the KV cache "
+            "(models/generation.py) holds one kind of "
+            "layer, plain attention and an MLP in each over one stream, has no "
+            "recurrent state "
             "for a state-space layer, no latent row for latent attention and no "
             "indexer keys beside k and v"
         )
@@ -1026,7 +1135,14 @@ def forward(
         st = _Stretch(
             cfg, kind, cdt, route_in=banded, mlp_ckpt=remat_mode == "mlp" and not banded,
             rot_in=banded and not kind.latent and cos is not None
-            and all(rotary for _, rotary in variants))
+            and all(rotary for _, rotary in variants),
+            hc_kernel=False if mesh is not None and mesh.size > 1 else None)
+        hyper = cfg.hyper is not None
+
+        def res_err(h_res):
+            """What Sinkhorn left undone of H_res, summed over real tokens."""
+            return jnp.sum(jnp.where(segment_ids > 0, hyper_conn.res_err(h_res), 0.0))
+
         if banded:
             run = lambda fn, w, xs, side=(): band_loop.stretch(fn, st, w, xs, side, n_live)
         else:
@@ -1041,9 +1157,15 @@ def forward(
                 side = (() if cos is None else (cos, sin)) + (
                     (index.cos, index.sin) if kind.indexed else ())
                 q, k, v, *mid = run(
-                    _before_mixer, {"ln1": lp["ln1"], "mixer": _mixer_weights(st, mp, True)},
+                    _before_mixer,
+                    {"ln1": lp["ln1"], "mixer": _mixer_weights(st, mp, True),
+                     **({"hc": lp["hc1"]} if hyper else {})},
                     (x,) + (kept or ()), side)
                 step, w = _after_mixer, {"mixer": _mixer_weights(st, mp, False)}
+                coefs = ()
+                if hyper:  # H_post and H_res of the mixer's read, for its write
+                    *mid, h_post, h_res = mid
+                    coefs, terms = (h_post, h_res), {"mhc_res_err": res_err(h_res)}
                 if kind.latent:
                     got = (_latent_core(q, k, v, cfg, segment_ids, positions, attn_impl, mesh),)
                 else:
@@ -1051,14 +1173,14 @@ def forward(
                         variant_index, w["l0"] = variant_index["variant"], variant_index["l0"]
                         *mid, k0, v0 = mid
                     gate = (mid.pop(0),) if "wg" in mp else ()
-                    out, k, terms = _attn_core(
+                    out, k, sums = _attn_core(
                         q, k, v, cfg, cos, sin, segment_ids, positions, attn_impl, mesh,
                         variants, variant_index, kind.diff, index if kind.indexed else None,
                         tuple(mid), rotated=st.rot_in)
-                    got = (out,) + gate
+                    got, terms = (out,) + gate, {**terms, **sums}
                     if kind.diff:  # a reader takes k and v as projected
                         k, v = k0, v0
-                kv, got = (k, v), got + (x,)
+                kv, got = (k, v), got + coefs + (x,)
                 if "ln1_post" in lp:
                     w["ln1_post"] = lp["ln1_post"]
             elif kind.mixer == "ssm" and cfg.ssm.form == "mamba1":
@@ -1091,7 +1213,16 @@ def forward(
             elif kind.mlp == "moe":  # the experts' own weights stay out of a stretch
                 w.update(ln2=lp["ln2"], mlp={n: lp["mlp"][n] for n in (
                     "router", "expert_bias", "shared") if n in lp["mlp"]})
+            if hyper and kind.mlp is not None:
+                w["hc"] = lp["hc2"]
             x, *rest = run(step, w, got)
+            coefs = ()
+            if hyper and kind.mlp is not None:  # the MLP's read: H_res last, H_post before
+                *rest, h_res = rest
+                terms = {**terms, "mhc_res_err": terms["mhc_res_err"] + res_err(h_res)}
+                if kind.mlp == "moe":
+                    *rest, h_post = rest
+                    coefs = (h_post, h_res)
             if kind.mlp == "moe":
                 # the shared expert's product, where a stretch made it,
                 # joins the experts' over the whole row: a sum, of zeros
@@ -1105,7 +1236,7 @@ def forward(
                        for n in aux_acc}
             if kind.mlp == "moe":  # its post-norm follows the experts' sum
                 with jax.named_scope("mlp"):
-                    x = _mlp_joins(x, m, lp, cfg)
+                    x = _mlp_joins(st, x, m, lp, coefs)
             return (act_c(x), aux_acc), kv if return_kv or kind.keeps else None
 
         if remat_mode == "full":
@@ -1128,6 +1259,9 @@ def forward(
         from areal_tpu.ops.indexer import INDEX_SUMS
 
         sums0.update({k: jnp.zeros((), jnp.float32) for k in INDEX_SUMS})
+    if cfg.hyper is not None:
+        # over sublayers and real tokens: what Sinkhorn left undone of H_res
+        sums0["mhc_res_err"] = jnp.zeros((), jnp.float32)
     carry, kvs = (x, sums0), None
     kept: Dict[int, Any] = {}  # keeping layer -> its tensor, for its readers
     for seg, stacks in _segment_stacks(params, cfg):
@@ -1175,6 +1309,10 @@ def forward(
             carry, kvs = jax.lax.scan(
                 lambda c, xs: body(c, xs, read), carry, (stacks[0], which[0]))
     x, moe_aux = carry
+    if cfg.hyper is not None:  # the stack's output: the sum of the streams
+        d = cfg.hidden_dim
+        x = sum(x[..., k * d:(k + 1) * d].astype(jnp.float32)
+                for k in range(cfg.hyper.n)).astype(cdt)
     with jax.named_scope("final_norm"):
         x = _norm(x, params["final_norm"], cfg)
     if mtp:

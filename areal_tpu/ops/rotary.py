@@ -8,13 +8,20 @@ embedding is gathered per token rather than sliced per sequence.
 
 from __future__ import annotations
 
+import math
 from typing import Optional
 
 import jax.numpy as jnp
 import numpy as np
 
 
-SUPPORTED_ROPE_TYPES = (None, "default", "linear", "llama3")
+SUPPORTED_ROPE_TYPES = (None, "default", "linear", "llama3", "yarn")
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature: `0.1 mscale ln(factor) + 1` for a
+    factor over 1, else 1."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
 
 
 def rotary_inv_freq(
@@ -51,6 +58,20 @@ def rotary_inv_freq(
         inv_freq = np.where(
             wavelen < high_wl, inv_freq, np.where(wavelen > low_wl, scaled, smoothed)
         )
+    elif scaling_type == "yarn" and scaling:
+        # YaRN (arXiv:2309.00071) as DeepSeek-V3 runs it: the dimensions
+        # that turn more than `beta_fast` times over the original context
+        # keep their frequency, those that turn fewer than `beta_slow`
+        # times are divided by the factor, a linear ramp between.
+        p = scaling_params or {}
+        orig_ctx = p.get("original_max_position_embeddings", 4096)
+        turns = lambda beta: head_dim * math.log(orig_ctx / (beta * 2 * math.pi)) / (
+            2 * math.log(base))
+        low = max(math.floor(turns(p.get("beta_fast", 32))), 0)
+        high = min(math.ceil(turns(p.get("beta_slow", 1))), head_dim // 2 - 1)
+        ramp = np.clip((np.arange(head_dim // 2, dtype=np.float64) - low)
+                       / max(high - low, 1e-3), 0.0, 1.0)
+        inv_freq = inv_freq / scaling * ramp + inv_freq * (1.0 - ramp)
     return inv_freq.astype(np.float32)
 
 

@@ -400,3 +400,84 @@ def test_splash_takes_q_and_k_at_192_against_v_at_128_and_a_group_of_one(one_chi
     text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(qk, qk, v, ids, ids).compile().as_text()
     assert text.count("tpu_custom_call") == 3 and "splash_pairs_dq" in text
     assert "bf16[32,8192,256]" not in text  # no padded copy of q
+
+
+@pytest.mark.parametrize("use", ["read", "write"])
+def test_the_stream_kernels_compile_at_the_published_widths(one_chip, use):
+    """`xing4-d5e8-train-ppo-8k`'s two stream steps over a band of 1,024
+    tokens of four streams of 3,584 (`ops/pallas/stream_mix.py`): the mix
+    forward, and in its backward the mix under the transposed
+    coefficients and the coefficients' gradient: three custom calls, the
+    streams never copied (`[X; y]` is two operands, not a concatenation)."""
+    from areal_tpu.ops.pallas import stream_mix
+
+    t, n, d = 1024, 4, 3584
+    x = _shape((1, t, n * d), jnp.bfloat16, one_chip)
+    if use == "read":
+        a, ins = _shape((1, t, 1, n), jnp.float32, one_chip), (x,)
+    else:
+        a, ins = (_shape((1, t, n, n + 1), jnp.float32, one_chip),
+                  (x, _shape((1, t, d), jnp.bfloat16, one_chip)))
+
+    def loss(a, ins):
+        out = stream_mix.mhc_mix(a, ins, True)
+        assert out.shape == (1, t, a.shape[-2] * d) and out.dtype == jnp.bfloat16
+        return (out.astype(jnp.float32) ** 2).sum()
+
+    text = jax.jit(jax.grad(loss, (0, 1))).lower(a, ins).compile().as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert "mhc_mix" in text and "mhc_coef_grad" in text
+    assert f"bf16[{t},{(n + 1) * d}]" not in text and f"bf16[1,{t},{(n + 1) * d}]" not in text
+
+
+def test_the_sinkhorn_kernels_compile_for_a_band_of_tokens(one_chip):
+    """Twenty iterations over a band's 1,024 four-by-four matrices: one
+    kernel forward and one backward (`ops/pallas/sinkhorn.py`), where the
+    plain form is forty reductions and forty divisions each way."""
+    from areal_tpu.ops import hyper_conn
+
+    m = _shape((1, 1024, 4, 4), jnp.float32, one_chip)
+    loss = lambda m: (hyper_conn.sinkhorn(m, 20, 1e-6, kernel=True) ** 2).sum()
+    text = jax.jit(jax.value_and_grad(loss)).lower(m).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "mhc_sinkhorn_bwd" in text and " reduce(" not in text.split("ENTRY")[1]
+
+
+def test_an_accumulate_step_of_the_four_stream_stack_compiles_at_8k(one_chip, monkeypatch):
+    """A forward-backward micro-batch of `xing4-d5e8-train-ppo-8k`'s model
+    at its one shape `(1, 8192)`, full remat, the masked loss head: the
+    four scanned expert layers walk their live bands with the streams
+    `[1, T, 4 x 3584]` among a stretch's inputs, every stream step a
+    kernel (`mhc_mix`, `mhc_coef_grad`, the Sinkhorn pair) beside the pair
+    kernels and the experts' row adds. The compiler's temporaries: 6.6 GB
+    beside 10.63 GB of weights, gradient sums and moments (more than the
+    allocator's 15.75 by this count, which the chip runs all the same:
+    PERF.md section 7, S4)."""
+    import json
+
+    from areal_tpu.models.transformer import forward, init_params, looping_layers
+    from areal_tpu.ops.loss import fused_next_token_logprobs
+    from benchmark.model import transformer_config
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernels, not interpret mode
+    with open("benchmark/configs/xing4.0-d5-e8.json") as f:
+        hf = {k: v for k, v in json.load(f).items() if k != "benchmark"}
+    cfg = transformer_config(hf, "bfloat16")
+    assert looping_layers(cfg, 1, 8192) == 4
+    params = jax.tree_util.tree_map(
+        lambda a: _shape(a.shape, a.dtype, one_chip),
+        jax.eval_shape(lambda k: init_params(cfg, k), jax.random.PRNGKey(0)))
+    ids = _shape((1, 8192), jnp.int32, one_chip)
+
+    def loss(p, input_ids, seg, pos):
+        hidden, _ = forward(p, cfg, input_ids, seg, pos, attn_impl="splash", remat=True,
+                            output="hidden", return_aux=True, bands=True)
+        return fused_next_token_logprobs(hidden, p["head"]["weight"], input_ids, seg,
+                                         scored=seg > 0).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss)).lower(params, ids, ids, ids).compile()
+    text = compiled.as_text()
+    for name in ("mhc_mix", "mhc_coef_grad", "mhc_sinkhorn", "mhc_sinkhorn_bwd",
+                 "splash_pairs_dq", "moe_rows_add"):
+        assert name in text, name
+    assert compiled.memory_analysis().temp_size_in_bytes < 7.0e9
