@@ -219,6 +219,7 @@ class JaxTrainEngine(TrainEngine):
             float(model_cfg.mtp.loss_weight) if model_cfg.mtp is not None else 0.0)
         last = model_cfg.kinds()[-1]
         mtp_on = self._mtp_weight > 0
+        self._n_step_layers = model_cfg.n_layers + mtp_on  # the module's block is one
         self._n_moe_layers = model_cfg.n_moe_layers + (mtp_on and last.mlp == "moe")
         self._mtp_attn = mtp_on and last.mixer == "attention"
         # The indexers' share of a train step (models/config IndexerConfig):
@@ -1121,9 +1122,10 @@ class JaxTrainEngine(TrainEngine):
     def _stretch_cells(self, segment_ids: np.ndarray) -> List[Tuple[int, int]]:
         """A micro-batch (`segment_ids` [R, T] of one or [n, R, T] of
         several): the cells its layers' token-wise steps run, summed over
-        the stack's layers, on the host before the transfer by the
-        device's own rule, as (those of the layers that walk bands, those
-        of the layers that run the row whole):
+        the layers a step runs (the stack's, and the prediction module's
+        block where the step runs the module), on the host before the
+        transfer by the device's own rule, as (those of the layers that
+        walk bands, those of the layers that run the row whole):
         `ops/band_loop.band_cells_run` (the bands of `_BAND` cells up to
         a row's last token, for one row alone of two bands or more) for
         the first; a layer whose kind keeps the whole row
@@ -1135,17 +1137,18 @@ class JaxTrainEngine(TrainEngine):
 
         seg = np.asarray(segment_ids)
         mbs = seg.reshape((-1,) + seg.shape[-2:])
-        n = self.model_cfg.n_layers
+        n = self._n_step_layers
         loop = looping_layers(
-            self.model_cfg, *mbs.shape[1:], sharded=self.mesh.size > 1
+            self.model_cfg, *mbs.shape[1:], sharded=self.mesh.size > 1,
+            mtp=n > self.model_cfg.n_layers,
         ) if self._dead_bands(mbs.shape[2]) else 0
         return [(loop * band_cells_run(mb), (n - loop) * mb.size) for mb in mbs]
 
     def _band_counts(self, segment_ids: np.ndarray) -> Tuple[int]:
         """The cells the layers' token-wise stretches run
-        (`_stretch_cells`), a mean over the stack's layers."""
-        n = self.model_cfg.n_layers
-        return (sum((bands + whole) // n for bands, whole in self._stretch_cells(segment_ids)),)
+        (`_stretch_cells`), a mean over those layers."""
+        return (sum((bands + whole) // self._n_step_layers
+                    for bands, whole in self._stretch_cells(segment_ids)),)
 
     def _mhc_counts(self, segment_ids: np.ndarray) -> Tuple[int, int]:
         """The cells the stream steps of hyper-connections run

@@ -700,8 +700,8 @@ class _Stretch(NamedTuple):
     cfg: TransformerConfig
     kind: LayerKind
     cdt: Any
-    # Rotary turns q and k inside the first step, where every layer of
-    # the scan has it: in the attention call's switch (the whole row's
+    # Rotary turns q and k inside the first step, where every layer that
+    # shares the body has it: in the attention call's switch (the whole row's
     # place, `_attn_core`) it would turn every cell of the row.
     rot_in: bool = False
     # The router and the shared expert run inside the second step, a band
@@ -865,7 +865,11 @@ def _kind_loops(kind: LayerKind) -> bool:
     unit with its MLP lose 7-14 % of a full row for 9-22 % off a
     half-empty one, and differential attention's three kinds, each walked
     outside a scan, are the most to trace in the stack with the least
-    set-up to spare. (`looping_layers`: and only inside a scan.) The
+    set-up to spare. Where such a layer stands does not matter
+    (`looping_layers`): a leading dense layer and a prediction module's
+    block, which run once outside a scan, loop as the scanned layers do
+    since PR 48, but in a stack of several streams (`_lone_layer_loops`;
+    the set-up they cost is PERF.md section 6's, PR 48). The
     figures of the kinds ruled out were taken with looping bodies that
     went with this rule: the probe in the tree re-measures the kinds that
     loop."""
@@ -874,23 +878,37 @@ def _kind_loops(kind: LayerKind) -> bool:
 
 
 def looping_layers(cfg: TransformerConfig, n_rows: int, row_len: int,
-                   sharded: bool = False) -> int:
-    """How many of the stack's layers walk their live bands in a call of
-    `n_rows` rows of `row_len` cells: none on a mesh that splits rows or
-    the sequence (`sharded`), none for rows together or a row under two
-    bands (`ops/band_loop.loops`), else the layers of a scan
-    (`cfg.segments()`: a unit that repeats) whose kind takes the loop. A
-    layer that runs once (a leading dense layer, a prediction module's)
-    keeps the whole row: outside a scan a looping layer cost 3 s of
-    tracing and lowering a cell on the chip's host (trinity's one +3.6,
-    joyai's two +5.2 to +6.7 s of warm `setup_build_s`, keye's none -0.3
-    to -0.9: PERF.md section 6, PR 45), where a scan's one traced body
-    serves every repeat."""
+                   sharded: bool = False, mtp: bool = False) -> int:
+    """How many layers walk their live bands in a call of `n_rows` rows of
+    `row_len` cells: none on a mesh that splits rows or the sequence
+    (`sharded`), none for rows together or a row under two bands
+    (`ops/band_loop.loops`), else every layer whose kind takes the loop
+    (`_kind_loops`), in a scan or alone (a leading dense layer), and with
+    `mtp` the prediction module's block, a layer of the stack's last kind
+    alone. One exception (`_lone_layer_loops`): a layer alone in a stack
+    of several residual streams keeps the whole row."""
     if sharded or not band_loop.loops(n_rows, row_len):
         return 0
     kinds = cfg.kinds()
-    return sum(_kind_loops(kinds[i]) for seg in cfg.segments() if seg.repeats > 1
-               for i in range(seg.start, seg.start + len(seg.unit) * seg.repeats))
+    scanned = {i for seg in cfg.segments() if seg.repeats > 1
+               for i in range(seg.start, seg.start + len(seg.unit) * seg.repeats)}
+    return sum(_kind_loops(k) and (i in scanned or _lone_layer_loops(cfg))
+               for i, k in enumerate(kinds + kinds[-1:] * mtp))
+
+
+def _lone_layer_loops(cfg: TransformerConfig) -> bool:
+    """Whether a layer that runs once outside a scan (a leading dense
+    layer, a prediction module's block) walks its live bands where its
+    kind does: yes, but for a stack of several residual streams
+    (`cfg.hyper`). Such a layer's stretches hold the stream steps' kernels
+    and their hand-written backward rules, the most of any kind to trace
+    and lower once more outside the scan's one body, for rows 69 % full:
+    in `xing4-d5e8-train-ppo-8k` it bought +1.7 to +1.9 % of
+    `train_tokens_per_s` for +6.7 to +8.3 s of warm `setup_s` (a bound of
+    5.9 s), where one stream's lone layers cost +1.0 to +2.6 s (trinity)
+    and +2.3 to +5.9 s (joyai, two of them) for +5.8 to +6.4 % (PERF.md
+    section 6, PR 48)."""
+    return cfg.hyper is None
 
 
 def _segment_stacks(params, cfg: TransformerConfig):
@@ -978,9 +996,12 @@ def forward(
     indexed layers in order.
 
     With `bands` one row alone of two bands or more (`ops/band_loop.loops`)
-    runs its layers' token-wise stretches over the bands its tokens reach
-    (`looping_layers`). For the caller to say, who knows its packer: one
-    whose ladder steps by a band or less at this row length
+    runs the token-wise stretches of every layer whose kind takes the loop
+    (`looping_layers`: the stack's, in a scan or not, and the prediction
+    module's block; a layer alone among several residual streams keeps the
+    whole row) over the bands its tokens reach. For the caller to
+    say, who knows its packer: one whose ladder steps by a band or less at
+    this row length
     (`base/datapack.ladder_step`) fills every band of such a row, and the
     loop would only cost (0 to 6 % of a full row).
 
@@ -1128,10 +1149,12 @@ def forward(
         A layer is steps, token-wise (`_before_mixer`, `_after_mixer`,
         `_mlp_part`) or across tokens (the attention call, a scan, the
         routed experts), and `run` is how a token-wise step runs: over the
-        row's live bands (`ops/band_loop.stretch`) for a layer of a scan
-        whose kind takes the loop (`_kind_loops`) where the call walks
-        bands at all (`n_live`), a plain call otherwise."""
-        banded = n_live is not None and scanned and _kind_loops(kind)
+        row's live bands (`ops/band_loop.stretch`) for a layer whose kind
+        takes the loop (`_kind_loops`), in a scan or outside one
+        (`_lone_layer_loops`), where the call walks bands at all
+        (`n_live`), a plain call otherwise."""
+        banded = (n_live is not None and _kind_loops(kind)
+                  and (scanned or _lone_layer_loops(cfg)))
         st = _Stretch(
             cfg, kind, cdt, route_in=banded, mlp_ckpt=remat_mode == "mlp" and not banded,
             rot_in=banded and not kind.latent and cos is not None
@@ -1144,7 +1167,14 @@ def forward(
             return jnp.sum(jnp.where(segment_ids > 0, hyper_conn.res_err(h_res), 0.0))
 
         if banded:
-            run = lambda fn, w, xs, side=(): band_loop.stretch(fn, st, w, xs, side, n_live)
+            # `_before_mixer` reads nothing of the layer's MLP, and its key
+            # says so: a leading dense layer's first stretch is then the
+            # trace the expert layers' first stretch made, where the two
+            # have the same mixer (PERF.md section 6, PR 48: 1.5 s of the
+            # joyai stack's tracing on the chip's host).
+            st_in = st._replace(kind=dataclasses.replace(kind, mlp=None))
+            run = lambda fn, w, xs, side=(): band_loop.stretch(
+                fn, st_in if fn is _before_mixer else st, w, xs, side, n_live)
         else:
             run = lambda fn, w, xs, side=(): fn(st, w, xs, side)
 

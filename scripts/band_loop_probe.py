@@ -1,6 +1,8 @@
 #!/usr/bin/env python3
-"""Two layers of each kind a configuration's stack holds, in a scan as a
-cell's stack runs them, their token-wise
+"""Each kind of layer a configuration's stack holds, as the stack runs it
+(two of a kind in a scan where the kind's first layer stands in a
+repeating segment; a layer alone where it runs once: a leading dense
+layer, a prediction module's block), its token-wise
 stretches over the whole row against over the row's live bands
 (`areal_tpu/ops/band_loop.py`), on the chip at the cell's widths: one
 packed row of `--row-len` cells of which `--tokens` hold a token
@@ -13,8 +15,16 @@ A kind is a layer with the parts of one `LayerKind` of the stack (window
 and rotary aside); a layer that keeps a tensor is timed alone, and one
 that reads another's with the layer it reads before it. A kind that keeps
 the whole row (`models/transformer._kind_loops`) reads the same in both
-modes: this script re-measures the kinds that loop and the band's length.
-**It cannot regenerate the rows of PERF.md section 6, PR 45's table for
+modes. **Which rows of PERF.md section 6's tables it regenerates**: PR 45's
+for the kinds that loop (trinity's attention + dense and attention +
+experts, joyai's latent + dense and latent + experts, keye's indexed +
+experts, Qwen's attention + dense; since PR 48 a leading dense layer and a
+prediction module's block are probed alone, as they run, where PR 45
+probed every kind alone) and PR 48's own (the leading dense layers of the
+trinity and joyai stacks and joyai's module block, with the seconds to
+trace and lower each; the xing4 stack's row was taken before
+`_lone_layer_loops` ruled that layer out, and reads the same in both modes
+now). **It cannot regenerate PR 45's rows for
 the kinds that were ruled out** (nemotron's `M`, `E`, `*`, phi4flash's
 scan, memory unit and differential layers): those were taken one layer a
 kind by the code of that PR's calls 1 and 2, which had a looping body for
@@ -69,24 +79,31 @@ _BAND = band_loop._BAND  # the program's own
 
 
 def unit_stacks(cfg):
-    """[(name, a configuration of two layers of a kind of `cfg`'s stack, of
-    a keeping layer alone, or of the layer a layer reads and that layer)],
-    a kind once."""
+    """[(name, a configuration of a kind of `cfg`'s stack as the stack
+    runs its first layer of that kind: two of it, a scan, where that layer
+    stands in a repeating segment, the layer alone where it runs once (a
+    leading dense layer, a layer that keeps a tensor), the layer it reads
+    and that layer where it reads one)], a kind once; and where the stack
+    has a prediction module, its block: a layer of the last kind alone."""
     kinds, seen, out = cfg.kinds(), set(), []
-    for kind in kinds:
+    scanned = {i for seg in cfg.segments() if seg.repeats > 1
+               for i in range(seg.start, seg.start + len(seg.unit) * seg.repeats)}
+    units = []
+    for i, kind in enumerate(kinds):
         key = (kind.mixer, kind.mlp, kind.diff, kind.latent, kind.indexed,
                kind.reads is not None)
         if key in seen:
             continue
         seen.add(key)
-        # two of a kind make a scan, where a layer may loop (`looping_layers`)
-        unit = (kind, kind) if kind.reads is None and not kind.keeps else (
-            (kind,) if kind.reads is None else
-            (kinds[kind.reads], dataclasses.replace(kind, reads=0)))
+        units.append(((kind,) * (1 + (i in scanned)) if kind.reads is None else
+                      (kinds[kind.reads], dataclasses.replace(kind, reads=0)), ""))
+    if cfg.mtp is not None:
+        units.append(((kinds[-1],), "@module"))
+    for unit, suffix in units:
         name = "+".join(
             f"{k.mixer or '-'}{'.latent' if k.latent else ''}{'.indexed' if k.indexed else ''}"
             f"{'.diff' if k.diff else ''}{'<' if k.reads is not None else ''}/{k.mlp or '-'}"
-            for k in unit)
+            for k in unit) + suffix
         moe = cfg.moe
         if moe is not None and not any(k.mlp == "moe" for k in unit):
             moe = None

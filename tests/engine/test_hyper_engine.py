@@ -103,8 +103,9 @@ def test_one_sinkhorn_iteration_shows_in_the_steps_stat():
 def test_the_host_counts_the_stream_steps_cells_by_the_devices_rule(monkeypatch):
     """Where the scanned layers loop (one row alone of two bands or more
     that the packer may leave half empty), their four stream steps run
-    the live bands and the dense layer's the whole row; one stream counts
-    nothing."""
+    the live bands and the dense layer's, a layer alone among several
+    streams (`transformer._lone_layer_loops`), the whole row; one stream
+    counts nothing, and its leading dense layer walks bands too."""
     monkeypatch.setattr(band_loop, "_BAND", 16)
     cfg, eng = engine(0, row_len_multiple=128)
     seg = np.zeros((1, 128), np.int32)
@@ -116,7 +117,10 @@ def test_the_host_counts_the_stream_steps_cells_by_the_devices_rule(monkeypatch)
     assert eng._mhc_counts(seg) == (2 * (2 * 48 + 128), 2 * 2 * 48)
     assert eng._mhc_counts(np.stack([seg, seg])) == (4 * (2 * 48 + 128), 4 * 2 * 48)
     _, plain = engine(0, hc_mult=1)
-    assert plain._band_counts(seg) == eng._band_counts(seg) and plain._mhc_counts(seg) == (0, 0)
+    assert plain._band_counts(seg) == (48,) and plain._mhc_counts(seg) == (0, 0)
+    # a row the packer fills to the last band runs whole in every layer
+    eng.row_len_multiple = 16
+    assert not eng._dead_bands(128) and eng._mhc_counts(seg) == (2 * 3 * 128, 0)
     assert _kinds_label(plain.model_cfg) == "dense.latent.full.rope,moe.latent.full.rope x2"
 
 

@@ -250,3 +250,53 @@ def test_the_ppo_interface_reports_the_modules_loss_and_acceptance():
     stats = PPOActorInterface(n_minibatches=1).train_step(
         Model(name=ModelName("actor"), module=eng, tokenizer=None), batch, MicroBatchSpec())
     assert np.isfinite(stats["ppo_actor/mtp_loss"]) and 0 <= stats["ppo_actor/mtp_accept"] <= 1
+
+
+@pytest.mark.parametrize("stack", ["leading_dense", "module", "afmoe_leading_dense"])
+def test_a_layer_that_runs_once_walks_its_live_bands_as_the_scanned_ones_do(stack, monkeypatch):
+    """One row alone, 40 tokens in 128 cells at bands of 16, as the engine
+    packs and runs it: a leading dense layer outside the scan (the latent
+    stack's with its module off, the stack of blocks') and the prediction
+    module's block run their two stretches over the three live bands as
+    the scanned layers do; the loss and every gradient are the whole
+    row's program's, and the host counts what `live_bands` runs."""
+    from areal_tpu.models.transformer import looping_layers
+    from areal_tpu.ops import band_loop
+
+    from tests.model.test_layer_kinds import small_bands
+
+    ran = small_bands(monkeypatch)
+    if stack == "afmoe_leading_dense":
+        from tests.engine.test_layer_kinds_engine import engine as afmoe_engine
+
+        cfg, eng = afmoe_engine(0)
+    else:
+        cfg, eng = engine(0, mtp_weight=0.1 if stack == "module" else 0.0)
+    eng.row_len_multiple = 128
+    _, rows = eng._build_rows(ppo_like_batch([24, 16], [10, 5]))
+    assert rows["input_ids"].shape == (1, 128) and eng._dead_bands(128)
+    seg = np.asarray(rows["segment_ids"])
+    live = int(band_loop.live_bands(jnp.asarray(seg)))
+    # every layer a step runs is of a kind that loops, and all of them do
+    layers = cfg.n_layers + (stack == "module")
+    assert [s.repeats for s in cfg.segments()][0] == 1  # the leading layer: no scan
+    assert looping_layers(cfg, 1, 128, mtp=stack == "module") == layers
+    assert live == 3 and eng._stretch_cells(seg) == [(layers * 16 * live, 0)]
+    assert eng._band_counts(seg) == (16 * live,)
+    rows = {k: jnp.asarray(v) for k, v in rows.items()}
+    step = lambda: jax.jit(jax.value_and_grad(
+        eng._mb_loss_fn(response_loss, response_positions), has_aux=True))(eng.params, rows)
+    (got, _), g_got = step()
+    # the leading layer, the scan's one body (and the module's block), two stretches each
+    assert set(ran) == {"_before_mixer", "_after_mixer"} and len(ran) >= 2 * (2 + (stack == "module"))
+    del ran[:]
+    monkeypatch.setattr(eng, "_dead_bands", lambda row_len: False)  # the whole row's program
+    (want, _), g_want = step()
+    assert not ran
+    np.testing.assert_allclose(got, want, rtol=2e-5)
+    g_got, g_want = _flat(g_got), _flat(g_want)
+    assert g_got.keys() == g_want.keys()
+    for name in g_want:
+        scale = float(jnp.abs(g_want[name]).max())
+        np.testing.assert_allclose(g_got[name], g_want[name], atol=2e-4 * scale + 1e-6,
+                                   err_msg=name)

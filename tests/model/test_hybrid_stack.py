@@ -135,7 +135,7 @@ def test_a_half_empty_row_of_one_part_layers_keeps_the_whole_row(remat, monkeypa
     monkeypatch.setattr(moe_lib, "_HELD_ROW_TILE", 8)
     ran = small_bands(monkeypatch)
     cfg = _cfg()
-    assert looping_layers(cfg, 1, 96) == 0 and looping_layers(_afmoe_cfg(), 1, 96) == 4
+    assert looping_layers(cfg, 1, 96) == 0 and looping_layers(_afmoe_cfg(), 1, 96) == 5
     params = _params(cfg)
     ids, seg, pos, seqs = _packed(rows=[[24, 13]], row_len=96)
     got = _program_logprobs(params, cfg, ids, seg, pos, seqs, remat=remat, bands=True)
@@ -539,10 +539,18 @@ def test_a_stack_of_blocks_traces_the_program_it_did_before_segments(remat):
 # the jaxpr differs); and the indexed layer's three sums join the carry in
 # the layer's one sum with the experts', after the routed experts, not on
 # their own after the attention call (indexed aa430367... at 869f2e1: those
-# adds and the names after them).
+# adds and the names after them). "afmoe" and "latent" were taken again at
+# PR 48, which drops the rule "only inside a scan": the afmoe stack's
+# leading dense layer, and the latent stack's leading dense layer and its
+# prediction module's block, now run their two steps as stretches where
+# they ran them as plain calls (PR 46: afmoe f23332a1..., latent
+# 371185f2..., which this tree still gives with `banded` asking `scanned`
+# again: nothing else of the program moved). "indexed" (every layer in one
+# scan) and "dense" (one kind, one scan) have no layer outside a scan and
+# are kept.
 LOOPING_JAXPR = {
-    "afmoe": "f23332a142a1ca645ad827303ab15225f01b25639a65ec9d9a5b04c598304fa6",
-    "latent": "371185f2b21532005e8040a8ebf21979086edc433d8a53e62a1fad8dec84e605",
+    "afmoe": "8579ee6c452e5a5184b9f3ceb217dfc27a19c3775d9e1d51cb2fa2b05899b852",
+    "latent": "405ab41b217f922375a5abf54cc7220ccd538f05ce40f4bcc89f5d8473dacfbd",
     "indexed": "f8414a0388ce27ce05d56210d5cb72a45b59e3d2341255a35b89c0772c8d8774",
     "dense": "0154f30c09a2fff80afea6c6d94735dadef447ee9a2428d4a3193e05505657dc",
 }
@@ -552,19 +560,19 @@ LOOPING_JAXPR = {
 def test_a_stack_that_walks_its_bands_traces_the_program_it_did_with_two_bodies(
         stack, monkeypatch):
     """The looping program of each kind that loops: the stack of blocks
-    above (a leading dense layer over the whole row, expert layers
-    `s s f s` with a shared expert, gate and four norms in one scan), the
-    latent stack with its prediction module, the indexed stack with its KL
-    and a one-kind dense stack."""
+    above (a leading dense layer that runs once, expert layers `s s f s`
+    with a shared expert, gate and four norms in one scan), the latent
+    stack with its leading dense layer and its prediction module, the
+    indexed stack with its KL and a one-kind dense stack."""
     from areal_tpu.models.transformer import looping_layers
     from tests.model import test_indexed_stack, test_latent_stack
 
     ran = small_bands(monkeypatch)
-    kw, T, want = {}, 256, 2
-    if stack == "afmoe":
-        cfg, T, want = _afmoe_cfg(), 96, 4
-    elif stack == "latent":
-        cfg, kw = test_latent_stack._cfg(), dict(mtp=True)
+    kw, T, want, bodies = {}, 256, 2, 1
+    if stack == "afmoe":  # the leading layer's body, then the scan's
+        cfg, T, want, bodies = _afmoe_cfg(), 96, 5, 2
+    elif stack == "latent":  # and the module's block
+        cfg, kw, want, bodies = test_latent_stack._cfg(), dict(mtp=True), 4, 3
     elif stack == "indexed":
         cfg, kw = test_indexed_stack._cfg(), dict(index_loss=True)
     else:
@@ -572,7 +580,7 @@ def test_a_stack_that_walks_its_bands_traces_the_program_it_did_with_two_bodies(
         hf = dict(AFMOE_HF, num_hidden_layers=2, num_dense_layers=2,
                   layer_types=["full_attention"] * 2)
         cfg = _afmoe_cfg(hf, layer_kinds=(kind, kind))
-    assert looping_layers(cfg, 1, T) == want
+    assert looping_layers(cfg, 1, T, mtp="mtp" in kw) == want
     params = jax.eval_shape(lambda k: init_params(cfg, k), jax.random.PRNGKey(0))
     ids = jnp.zeros((1, T), jnp.int32)
 
@@ -584,7 +592,7 @@ def test_a_stack_that_walks_its_bands_traces_the_program_it_did_with_two_bodies(
             a.sum() for a in jax.tree_util.tree_leaves(aux))
 
     sha = hashlib.sha256(str(jax.make_jaxpr(jax.grad(loss))(params, ids)).encode()).hexdigest()
-    assert ran == ["_before_mixer", "_after_mixer"]
+    assert ran == ["_before_mixer", "_after_mixer"] * bodies
     assert sha == LOOPING_JAXPR[stack], sha
 
 

@@ -141,8 +141,9 @@ def test_a_half_empty_row_of_streams_walks_its_live_bands(monkeypatch):
     scanned expert layers run their two stretches over three bands (the
     streams cut band by band as any input, the coefficients handed from
     the first stretch to the second), thirteen bands stay empty and read
-    zeros, the dense layer runs the whole row; logprobs and every
-    gradient are the plain reference's."""
+    zeros, the dense layer, alone among several streams
+    (`transformer._lone_layer_loops`), runs the whole row; logprobs and
+    every gradient are the plain reference's."""
     from tests.model.test_layer_kinds import small_bands
 
     monkeypatch.setattr(moe_lib, "_HELD_ROW_TILE", 8)

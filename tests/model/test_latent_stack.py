@@ -118,11 +118,11 @@ def test_gradients_are_the_plain_references(hf):
 
 @pytest.mark.parametrize("remat", ["none", "full", "mlp"])
 def test_a_half_empty_row_of_the_latent_stack_walks_its_live_bands(remat, monkeypatch):
-    """One row alone, 40 tokens in 256 cells at bands of 16: the two
-    scanned expert layers (one traced body, two stretches) run over three
-    bands of sixteen, the dense layer and the module's layer, which run
-    once each, over the whole row; logprobs, the module's, and every
-    gradient are the plain reference's."""
+    """One row alone, 40 tokens in 256 cells at bands of 16: the dense
+    layer, the two scanned expert layers (one traced body) and the
+    module's block, which runs once after the stack, run their two
+    stretches each over three bands of sixteen; logprobs, the module's,
+    and every gradient are the plain reference's."""
     from areal_tpu.models.transformer import looping_layers
 
     from tests.model.test_layer_kinds import small_bands
@@ -134,7 +134,8 @@ def test_a_half_empty_row_of_the_latent_stack_walks_its_live_bands(remat, monkey
     n = 40
     ids, seg, pos = _row([n], 256)
     lp, lp2 = _logprobs(params, cfg, ids, seg, pos, remat=remat, bands=True)
-    assert looping_layers(cfg, 1, 256) == 2 and ran == ["_before_mixer", "_after_mixer"]
+    assert looping_layers(cfg, 1, 256) == 3 and looping_layers(cfg, 1, 256, mtp=True) == 4
+    assert ran == ["_before_mixer", "_after_mixer"] * 3
     np.testing.assert_allclose(lp[: n - 1], ref.next_token_logprobs(
         params, HF, np.asarray(ids[0, :n]), pad_to=256), atol=3e-5)
     np.testing.assert_allclose(lp2[: n - 2], ref.mtp_logprobs(

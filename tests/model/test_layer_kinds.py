@@ -163,11 +163,11 @@ def small_bands(monkeypatch, band=16):
 
 @pytest.mark.parametrize("remat", ["none", "full", "mlp"])
 def test_a_half_empty_row_walks_its_live_bands_and_matches_the_reference(remat, monkeypatch):
-    """One row alone, 37 tokens in 96 cells: the four scanned expert
-    layers of the stack (window and full; one traced body, two stretches)
-    run over three bands of six, the leading dense layer, which runs
-    once, over the whole row, and the logprobs and every gradient are the
-    plain reference's."""
+    """One row alone, 37 tokens in 96 cells: the leading dense layer,
+    which runs once outside a scan, and the four scanned expert layers of
+    the stack (window and full; one traced body) run their two stretches
+    each over three bands of six, and the logprobs and every gradient are
+    the plain reference's."""
     from areal_tpu.models.transformer import looping_layers
 
     monkeypatch.setattr(moe_lib, "_HELD_ROW_TILE", 8)
@@ -177,7 +177,7 @@ def test_a_half_empty_row_walks_its_live_bands_and_matches_the_reference(remat, 
     ids, seg, pos, seqs = _packed(rows=[[24, 13]], row_len=96)
     assert int(band_loop.live_bands(seg)) == 3 and band_loop.band_cells_run(np.asarray(seg)) == 48
     _, got = _program_logprob_sum(params, cfg, ids, seg, pos, seqs, remat=remat, bands=True)
-    assert looping_layers(cfg, 1, 96) == 4 and ran == ["_before_mixer", "_after_mixer"]
+    assert looping_layers(cfg, 1, 96) == 5 and ran == ["_before_mixer", "_after_mixer"] * 2
     _, want = _reference_logprob_sum(params, HF, seqs)
     for g, w in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=2e-5)
@@ -232,8 +232,9 @@ def test_a_second_program_finds_its_stretches_traced(monkeypatch):
     `ops/band_loop.stretch` the same functions, static description and
     shapes, so the stretch's Python runs for the first program alone (its
     plain loop, its forward rule and its backward loop) and for no later
-    one; and a layer that runs once, outside a scan, keeps the whole
-    row."""
+    one; and layers that run one by one, outside a scan, loop as well,
+    the second and third finding the first's trace: three cost the
+    stretches' Python what one does."""
     import collections
 
     from areal_tpu.models import transformer as tf
@@ -261,10 +262,17 @@ def test_a_second_program_finds_its_stretches_traced(monkeypatch):
     jax.jit(lambda p: total(p))(params)  # a third, forward only
     assert collections.Counter(calls) == first and len(ran) == 6
     alone = _cfg(hf, layer_kinds=(kind,) * 3, scan_min_repeats=4)  # one by one
-    assert tf.looping_layers(alone, 1, 96) == 0
-    del ran[:]
+    assert [s.repeats for s in alone.segments()] == [1] * 3
+    assert tf.looping_layers(alone, 1, 96) == 3
+    del ran[:], calls[:]
     _program_logprob_sum(params, alone, ids, seg, pos, seqs, bands=True)
-    assert not ran
+    three = collections.Counter(calls)
+    assert len(ran) == 6 and set(three) == {"_before_mixer", "_after_mixer"}
+    one = _cfg(dict(hf, num_hidden_layers=1, num_dense_layers=1,
+                    layer_types=["full_attention"]), layer_kinds=(kind,))
+    del ran[:], calls[:]
+    _program_logprob_sum(_params(one), one, ids, seg, pos, seqs, bands=True)
+    assert len(ran) == 2 and collections.Counter(calls) == three
 
 
 def test_next_token_logprobs_pads_and_blocks():

@@ -175,13 +175,13 @@ def test_a_train_step_of_one_row_holds_no_partial_of_dq(one_chip, monkeypatch):
 
 
 @pytest.mark.parametrize("config,loops", [
-    ("trinity-mini-d5-e16", 4), ("nemotron-3-nano-d9-e8", 0)])
+    ("trinity-mini-d5-e16", 5), ("nemotron-3-nano-d9-e8", 0)])
 def test_an_accumulate_step_of_16k_compiles_with_its_stretches_looped(
         one_chip, monkeypatch, config, loops):
     """A forward-backward micro-batch of the trinity and the nemotron cells'
     models at their one shape `(1, 16384)`, full remat, the masked loss
-    head. The trinity stack's four scanned expert layers run their two
-    token-wise stretches as loops whose trip count is read from the segment ids
+    head. The trinity stack's leading dense layer and its four scanned
+    expert layers run their two token-wise stretches as loops whose trip count is read from the segment ids
     (`ops/band_loop.py`): a known forward, remat's and a backward one a
     stretch a kind of layer, beside the held experts' and the head's own;
     the nemotron stack's layers have one part each and keep the whole
@@ -463,7 +463,7 @@ def test_an_accumulate_step_of_the_four_stream_stack_compiles_at_8k(one_chip, mo
     with open("benchmark/configs/xing4.0-d5-e8.json") as f:
         hf = {k: v for k, v in json.load(f).items() if k != "benchmark"}
     cfg = transformer_config(hf, "bfloat16")
-    assert looping_layers(cfg, 1, 8192) == 4
+    assert looping_layers(cfg, 1, 8192) == 4  # the leading dense layer, alone among streams, does not
     params = jax.tree_util.tree_map(
         lambda a: _shape(a.shape, a.dtype, one_chip),
         jax.eval_shape(lambda k: init_params(cfg, k), jax.random.PRNGKey(0)))
