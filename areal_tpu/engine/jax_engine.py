@@ -11,8 +11,10 @@ fused path (one donated jitted program, lax.scan accumulation — used for
 'dp' normalization and serialized-dispatch CPU meshes) and the default
 overlapped path, where a bounded prefetch thread packs + device_puts
 micro-batch i+1 while micro-batch i's accumulate program runs
-(engine/prefetch.py), with per-mb accumulate programs and one optimizer
-apply — no host fetch until the single packed-stats transfer per batch.
+(engine/prefetch.py), with one accumulate program a micro-batch shape
+(a minibatch's first micro-batch and the rest run the same one) and one
+optimizer apply — no host fetch until the single packed-stats transfer
+per batch.
 
 Loss functions are pure jit-able callables
 `loss_fn(model_out, rows) -> (loss_sum, aux_dict)` where `model_out` is
@@ -44,6 +46,7 @@ from areal_tpu.models.config import TransformerConfig
 from areal_tpu.models.generation import generate_tokens
 from areal_tpu.models.packing import PackedBatch, pack_sequences
 from areal_tpu.models.transformer import forward as model_forward
+from areal_tpu.models.transformer import scan_stacked
 from areal_tpu.ops.attention import (
     attn_block_cells,
     attn_grid_steps,
@@ -339,6 +342,11 @@ class JaxTrainEngine(TrainEngine):
         self._offloaded = False
         self._host_params = None
         self._host_opt_state = None
+        # The overlapped path's fp32 gradient sums, kept from one
+        # minibatch to the next (`_accum_step_fn`) and dropped when the
+        # engine is asked for anything but another (`forward`,
+        # `generate`, `offload`, `set_params`); None until the first.
+        self._grad_sums = None
 
     # ------------------------------------------------------------------
     # Batch building
@@ -657,51 +665,98 @@ class JaxTrainEngine(TrainEngine):
 
         return self._built(key, jax.jit(step, donate_argnums=(0, 1)))
 
-    def _accum_step_fns(self, loss_name: str, loss_fn: PackedLossFn,
-                        row_keys: Tuple[str, ...],
-                        scored_fn: Optional[ScoredFn] = None):
-        """Two jitted programs for the pipelined accumulation path:
-        `first` computes micro-batch 0's fp32 (grads, loss_sum, aux)
-        carry, `next` adds one micro-batch into a donated carry. Same
+    def _accum_step_fn(self, loss_name: str, loss_fn: PackedLossFn,
+                       row_keys: Tuple[str, ...],
+                       scored_fn: Optional[ScoredFn] = None):
+        """The one program a micro-batch shape of the pipelined
+        accumulation path: `(params, g_acc, rows, first) -> (g_acc,
+        (loss_sum, aux))`, one micro-batch's gradient added into the
+        donated fp32 sums, or, where `first` (a value of the run, not of
+        the trace) says so, put in their place whatever they held. A
+        minibatch's first micro-batch and every later one run this same
+        program, so a shape is traced, lowered and compiled once. Same
         per-mb math and left-to-right fp32 addition order as the fused
         scan body — the step's numerics must not depend on which path
-        ran (see tests/engine/test_prefetch.py equivalence)."""
+        ran (see tests/engine/test_prefetch.py equivalence).
+
+        How `first` is obeyed is decided a leaf, by where its gradient
+        comes from (`transformer.scan_stacked`; PERF.md section 6, PR 49,
+        each form measured on the other's leaves and read in the
+        compiled text). A leaf whose layers all run in a scan has its
+        gradient written as a stack when the scan ends and added by a
+        fusion of its own: those leaves go through one `lax.cond`
+        between converting and adding, which costs nothing, and the
+        first branch reads no sum. Any other weight's gradient is a
+        product the compiler fuses with the add into its sum, or reaches
+        the add in another layout than the sum's; a branch wants its
+        operands whole and in one layout, so it would part the product
+        from the add or copy the gradient (either way written and read
+        once more, 4 bytes a parameter a micro-batch or more). There the
+        add stays unconditional over `where(first, 0, sum)`, and a
+        minibatch's first micro-batch reads a sum it discards (4 bytes a
+        parameter a minibatch, however many micro-batches follow)."""
         key = ("accum", loss_name, row_keys, scored_fn is not None)
         if key in self._jit_cache:
             return self._jit_cache[key]
 
         mb_loss = self._mb_loss_fn(loss_fn, scored_fn)
+        # the leaves (in the tree's flat order) that a scan stacks
+        stacked = [i for i, s in enumerate(jax.tree_util.tree_leaves(
+            scan_stacked(self.model_cfg, self.params))) if s]
 
         def to_f32(tree):
-            with jax.named_scope("grad_accum"):
-                return jax.tree_util.tree_map(
-                    lambda x: x.astype(jnp.float32), tree
-                )
+            return jax.tree_util.tree_map(lambda x: x.astype(jnp.float32), tree)
 
-        def first(params, rows):
-            (loss, aux), g = jax.value_and_grad(mb_loss, has_aux=True)(
-                params, rows
-            )
-            return to_f32(g), loss.astype(jnp.float32), to_f32(aux)
-
-        def nxt(params, carry, rows):
-            g_acc, loss_acc, aux_acc = carry
+        def mb_accum(params, g_acc, rows, first):
             (loss, aux), g = jax.value_and_grad(mb_loss, has_aux=True)(
                 params, rows
             )
             with jax.named_scope("grad_accum"):
-                g_acc = jax.tree_util.tree_map(
-                    lambda a, b: a + b.astype(jnp.float32), g_acc, g
-                )
-                aux_acc = jax.tree_util.tree_map(
-                    lambda a, b: a + b.astype(jnp.float32), aux_acc, aux
-                )
-                loss_acc = loss_acc + loss.astype(jnp.float32)
-            return g_acc, loss_acc, aux_acc
+                sums, treedef = jax.tree_util.tree_flatten(g_acc)
+                g = treedef.flatten_up_to(g)
+                branch = dict(zip(stacked, jax.lax.cond(
+                    first,
+                    lambda acc, g: to_f32(g),
+                    lambda acc, g: [a + b.astype(jnp.float32)
+                                    for a, b in zip(acc, g)],
+                    [sums[i] for i in stacked], [g[i] for i in stacked],
+                ))) if stacked else {}
+                out = [branch[i] if i in branch
+                       else jnp.where(first, 0.0, a) + b.astype(jnp.float32)
+                       for i, (a, b) in enumerate(zip(sums, g))]
+                g_acc = treedef.unflatten(out)
+                stats = to_f32((loss, aux))
+            # the donated sums come back where they were
+            g_acc = jax.lax.with_sharding_constraint(g_acc, self._param_shardings)
+            return g_acc, stats
 
-        return self._built(
-            key, (jax.jit(first), jax.jit(nxt, donate_argnums=(1,)))
-        )
+        return self._built(key, jax.jit(mb_accum, donate_argnums=(1,)))
+
+    def _accum_sum_fns(self):
+        """Two programs beside `_accum_step_fn`'s that see no row and no
+        model, one build an engine: `zero_sums`, the fp32 sums' buffers
+        where the engine holds none (later minibatches take the last
+        one's, whose content `first` discards: a fill a minibatch was
+        3-6 ms of a step in `q15d12-train-short`, PERF.md section 6, PR
+        49, call F), and `sum_add`, a later micro-batch's `(loss_sum,
+        aux)` scalars added to the minibatch's, left to right."""
+        key = ("accum_sums",)
+        if key in self._jit_cache:
+            return self._jit_cache[key]
+
+        def zero_sums(params):
+            return jax.tree_util.tree_map(
+                lambda p: jnp.zeros(p.shape, jnp.float32), params
+            )
+
+        def sum_add(stats, mb_stats):
+            with jax.named_scope("grad_accum"):
+                return jax.tree_util.tree_map(operator.add, stats, mb_stats)
+
+        return self._built(key, (
+            jax.jit(zero_sums, out_shardings=self._param_shardings),
+            jax.jit(sum_add),
+        ))
 
     def _apply_step_fn(self, loss_name: str):
         """Optimizer apply for the pipelined path: `_optimizer_apply`,
@@ -729,7 +784,9 @@ class JaxTrainEngine(TrainEngine):
             )
             return params, opt_state, packed, aux
 
-        return self._built(key, jax.jit(apply, donate_argnums=(0, 1, 2)))
+        # the sums are not donated: the engine hands their buffers to the
+        # next minibatch (`_train_batch_overlapped`)
+        return self._built(key, jax.jit(apply, donate_argnums=(0, 1)))
 
     @staticmethod
     def _stack_mb_rows(
@@ -816,9 +873,9 @@ class JaxTrainEngine(TrainEngine):
     ) -> Dict[str, float]:
         """Forward+backward over micro-batches, one optimizer step, no
         host sync until the single packed-stats fetch at the end. Two
-        equivalent input paths: the default overlapped pipeline (per-mb
-        accumulate programs; pack+H2D of mb i+1 hidden behind mb i's
-        compute — _train_batch_overlapped) and the fused path (one
+        equivalent input paths: the default overlapped pipeline (one
+        accumulate program a micro-batch shape; pack+H2D of mb i+1 hidden
+        behind mb i's compute — _train_batch_overlapped) and the fused path (one
         donated jitted program, lax.scan accumulation), which 'dp'
         normalization and serialized-dispatch CPU meshes use.
 
@@ -1021,8 +1078,16 @@ class JaxTrainEngine(TrainEngine):
             mb_iter, stage, depth=self.prefetch_depth, name=f"train/{loss_name}",
             wait_span="train.wait_input",
         )
-        carry = None
-        nxt = None
+        mb_accum = None
+        zero_sums, sum_add = self._accum_sum_fns()
+        # The fp32 gradient sums: the last minibatch's buffers (donated
+        # from micro-batch to micro-batch, so nothing else holds them
+        # while a minibatch runs), or zeros where the engine holds none.
+        g_acc, self._grad_sums = self._grad_sums, None
+        if g_acc is None:
+            tracing.build_site("accum_zeros", zero_sums)
+            g_acc = zero_sums(self.params)
+        stats = None
         denom_sum, n_tok, n_cells, n_one_row = 0.0, 0, 0, 0
         # attention's cells at the run length, run, causal, its grid steps
         # walked, live; the head's positions read, cells run, and the
@@ -1044,22 +1109,26 @@ class JaxTrainEngine(TrainEngine):
                 if counts is not None:
                     n_counted += 1
                     n_counts = [n + c for n, c in zip(n_counts, counts)]
-                if carry is None:
-                    first, nxt = self._accum_step_fns(
+                if mb_accum is None:
+                    mb_accum = self._accum_step_fn(
                         loss_name, loss_fn, tuple(sorted(rows_dev.keys())),
                         scored_fn,
                     )
-                    tracing.build_site("accum_first", first, rows, row_len)
-                    with tracing.span("train.dispatch", kind="first",
-                                      rows=rows, row_len=row_len,
-                                      **attn_attrs, **self._stack_attrs):
-                        carry = first(self.params, rows_dev)
+                tracing.build_site("accum_step", mb_accum, rows, row_len)
+                # `kind`: whether the program starts the sums (`first`) or
+                # adds into them (`next`); one program either way
+                with tracing.span("train.dispatch",
+                                  kind="first" if stats is None else "next",
+                                  rows=rows, row_len=row_len,
+                                  **attn_attrs, **self._stack_attrs):
+                    g_acc, mb_stats = mb_accum(
+                        self.params, g_acc, rows_dev, np.asarray(stats is None)
+                    )
+                if stats is None:
+                    stats = mb_stats
                 else:
-                    tracing.build_site("accum_next", nxt, rows, row_len)
-                    with tracing.span("train.dispatch", kind="next",
-                                      rows=rows, row_len=row_len,
-                                      **attn_attrs, **self._stack_attrs):
-                        carry = nxt(self.params, carry, rows_dev)
+                    tracing.build_site("accum_stats", sum_add)
+                    stats = sum_add(stats, mb_stats)
                 mark = time.monotonic_ns()
         finally:
             pf.close()
@@ -1068,10 +1137,11 @@ class JaxTrainEngine(TrainEngine):
         tracing.build_site("apply", apply)
         with tracing.span("train.apply"):
             self.params, self.opt_state, packed, aux = apply(
-                self.params, self.opt_state, carry,
+                self.params, self.opt_state, (g_acc, *stats),
                 self._inv_denom(global_denom, n_tok),
                 jnp.asarray(lr, jnp.float32),
             )
+        self._grad_sums = g_acc  # the next minibatch's buffers
         if n_counted == n_mbs:  # tracing was on for the whole batch
             self._count_batch("overlapped", n_mbs, n_one_row, n_tok, n_cells,
                               *n_counts)
@@ -1492,6 +1562,7 @@ class JaxTrainEngine(TrainEngine):
         the packed-stats single-fetch discipline applied to forward."""
         output = output or ("values" if self.model_cfg.is_critic else "logprobs")
         self._ensure_loaded()
+        self._grad_sums = None  # a train step's, not a forward phase's to hold
         main_key = input_._main_key()
         fn = self._forward_fn(output)
         per_mb_flat: List[np.ndarray] = []
@@ -1602,6 +1673,7 @@ class JaxTrainEngine(TrainEngine):
         # calls draw independent sampling streams.
         self._gen_calls += 1
         self._ensure_loaded()
+        self._grad_sums = None  # the KV cache's room
         rng = rng if rng is not None else jax.random.PRNGKey(self._gen_calls)
         eos = getattr(tokenizer, "eos_token_id", None) if tokenizer is not None else None
         with jax.sharding.set_mesh(self.mesh):
@@ -1636,6 +1708,7 @@ class JaxTrainEngine(TrainEngine):
         )
         self.params = None
         self.opt_state = None
+        self._grad_sums = None
         self._offloaded = True
         logger.info("engine params offloaded to host")
 
@@ -1701,6 +1774,7 @@ class JaxTrainEngine(TrainEngine):
                 self._host_opt_state, self._opt_shardings
             )
         self.drop_offloaded_state()
+        self._grad_sums = None
         self.params = jax.device_put(params, param_shardings(params, self.mesh))
 
 

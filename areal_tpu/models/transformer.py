@@ -877,6 +877,12 @@ def _kind_loops(kind: LayerKind) -> bool:
             and not kind.diff and kind.reads is None)
 
 
+def _scanned_layers(cfg: TransformerConfig) -> set:
+    """The layers `forward` runs inside a `lax.scan` (`cfg.segments`)."""
+    return {i for seg in cfg.segments() if seg.repeats > 1
+            for i in range(seg.start, seg.start + len(seg.unit) * seg.repeats)}
+
+
 def looping_layers(cfg: TransformerConfig, n_rows: int, row_len: int,
                    sharded: bool = False, mtp: bool = False) -> int:
     """How many layers walk their live bands in a call of `n_rows` rows of
@@ -890,10 +896,28 @@ def looping_layers(cfg: TransformerConfig, n_rows: int, row_len: int,
     if sharded or not band_loop.loops(n_rows, row_len):
         return 0
     kinds = cfg.kinds()
-    scanned = {i for seg in cfg.segments() if seg.repeats > 1
-               for i in range(seg.start, seg.start + len(seg.unit) * seg.repeats)}
+    scanned = _scanned_layers(cfg)
     return sum(_kind_loops(k) and (i in scanned or _lone_layer_loops(cfg))
                for i, k in enumerate(kinds + kinds[-1:] * mtp))
+
+
+def scan_stacked(cfg: TransformerConfig, params: Params) -> Any:
+    """A bool a leaf of `params`: whether every layer that holds it runs
+    inside a `lax.scan` of `forward` (`cfg.segments`). Such a leaf's
+    gradient is a scan's stacked output: it stands in memory, whole and
+    in the leaf's own layout, before anything can consume it. No other
+    leaf's does: a layer run once hands its weights' gradients over as
+    products that a consumer can fuse with, a kind's stack with a layer
+    outside a scan is joined from pieces, and the embedding's, the
+    head's and the prediction module's come in whatever layout their
+    last product chose (`engine/jax_engine._accum_step_fn` asks)."""
+    scanned = _scanned_layers(cfg)
+    out = jax.tree_util.tree_map(lambda _: False, params)
+    for path, idx in cfg.stack_paths().values():
+        if scanned.issuperset(idx):
+            _stack_at(out, path, jax.tree_util.tree_map(
+                lambda _: True, _stack_at(params, path)))
+    return out
 
 
 def _lone_layer_loops(cfg: TransformerConfig) -> bool:

@@ -1,8 +1,9 @@
 """The trainer engine's own spans and counters (`train.*`, recorded
 through base/tracing.py where the work happens), on both input paths:
-one `train.batch` a call, one `train.dispatch` a program, the stage on
-the prefetcher's thread under the batch's trace, tokens <= cells, and
-`perf/*` telemetry the same whether tracing is on or off."""
+one `train.batch` a call, one `train.dispatch` a micro-batch (the fused
+step: one for all of them), the stage on the prefetcher's thread under
+the batch's trace, tokens <= cells, and `perf/*` telemetry the same
+whether tracing is on or off."""
 
 import threading
 
@@ -169,8 +170,9 @@ def test_programs_built_counts_new_jit_cache_entries_and_stale_fetches_are_marke
                             loss_weight, loss_name="t")
     finally:
         got = tracing.stop()
-    # one entry for first+next, one for the apply; built once, run three times
-    assert got["counters"]["train.programs_built"] == len(eng._jit_cache) == 2
+    # one entry for the accumulate program, one for the two beside it
+    # that see no row, one for the apply; built once, run three times
+    assert got["counters"]["train.programs_built"] == len(eng._jit_cache) == 3
     assert got["counters"]["train.batches"] == 3
     stale = [s["attrs"]["stale"] for s in got["spans"] if s["name"] == "train.fetch_stats"]
     assert stale == [False, False, True]
@@ -311,8 +313,9 @@ def test_a_new_shape_through_an_old_jit_entry_is_built_under_its_dispatch():
                         loss_name="t")
     finally:
         got = tracing.stop()
-    # jit-cache entries: first+next and the apply, made by the first call
-    assert warm["counters"]["train.programs_built"] == len(eng._jit_cache) == 2
+    # jit-cache entries: the accumulate program, the two beside it that
+    # see no row and the apply, made by the first call
+    assert warm["counters"]["train.programs_built"] == len(eng._jit_cache) == 3
     assert "train.programs_built" not in got["counters"]
     dispatches = [s for s in got["spans"] if s["name"] == "train.dispatch"]
     shapes = {(s["attrs"]["rows"], s["attrs"]["row_len"]) for s in dispatches}
@@ -328,17 +331,19 @@ def test_a_new_shape_through_an_old_jit_entry_is_built_under_its_dispatch():
         kids = _children(got["spans"], s)
         assert [k["name"] for k in kids][:2] == ["jit.trace", "jit.lower"]
         assert kids[2]["name"] in ("jit.compile", "jit.cache_load")
-        program = {"first": "accum_first", "next": "accum_next"}[a["kind"]]
+        # a new shape builds one program, whether the micro-batch is a
+        # minibatch's first or a later one
+        assert len(kids) == 3 and a["kind"] in ("first", "next")
         for k in kids:
-            assert k["attrs"]["program"] == program
+            assert k["attrs"]["program"] == "accum_step"
             assert (k["attrs"]["rows"], k["attrs"]["row_len"]) == (a["rows"], a["row_len"])
             assert s["start_ns"] <= k["start_ns"] + 1_000_000 and k["end_ns"] <= s["end_ns"]
     # the records say the same without a session: which step recompiled
     new = [b for b in got["builds"][n_builds:] if b["program"] is not None]
-    assert {b["program"] for b in new} <= {"accum_first", "accum_next", "apply"}
+    assert {b["program"] for b in new} <= {"accum_step", "apply"}
     assert {(b["rows"], b["row_len"]) for b in new if b["rows"]} == shapes
-    assert all(b["fun"] in ("first", "jit(first)", "nxt", "jit(nxt)", "apply",
-                            "jit(apply)") for b in new)
+    assert all(b["fun"] in ("mb_accum", "jit(mb_accum)", "apply", "jit(apply)")
+               for b in new)
 
 
 def test_the_fused_step_and_the_apply_are_named_too():
@@ -358,8 +363,9 @@ def test_the_fused_step_and_the_apply_are_named_too():
     by_program = {}
     for b in tracing.builds()[n:]:
         by_program.setdefault(b["program"], []).append(b)
-    assert set(by_program) >= {"accum_first", "accum_next", "apply"}
-    assert all(b["rows"] is None for b in by_program["apply"])
+    assert set(by_program) >= {"accum_step", "accum_zeros", "accum_stats", "apply"}
+    assert all(b["rows"] is None for p in ("accum_zeros", "accum_stats", "apply")
+               for b in by_program[p])
 
 
 @pytest.mark.parametrize("depth", [2, 0])

@@ -153,4 +153,4 @@ def test_a_first_step_builds_its_prep_under_ppo_prep_by_name_and_shape(critic):
                for s in named)
     assert a["built"] >= 1  # the prep, and whatever eager op was new beside it
     programs = {b["program"] for b in got["builds"]}
-    assert {"ppo_prep", "accum_first", "accum_next", "apply"} <= programs
+    assert {"ppo_prep", "accum_step", "accum_zeros", "accum_stats", "apply"} <= programs

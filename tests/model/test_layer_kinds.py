@@ -227,8 +227,9 @@ def test_what_keeps_the_whole_row(monkeypatch):
 
 
 def test_a_second_program_finds_its_stretches_traced(monkeypatch):
-    """What the set-up budget rests on: a cell's second and third program
-    (`accum_next` and `forward` after `accum_first`) hand
+    """What the set-up budget rests on: a cell's later programs
+    (`forward` after `accum_step`; until PR 49 a second accumulate
+    program too) hand
     `ops/band_loop.stretch` the same functions, static description and
     shapes, so the stretch's Python runs for the first program alone (its
     plain loop, its forward rule and its backward loop) and for no later
