@@ -143,7 +143,8 @@ def _kinds_label(cfg: TransformerConfig) -> str:
     `dense` or `attn.full.nope`. Differential attention says `diff.`, a
     layer that reads layer n's tensor `<n`, one that keeps its own `^`:
     `ssm+dense^`, `dense.diff.full.nope<5`, `gmu+dense<4`; latent
-    attention says `latent.`, attention over the keys an indexer chooses
+    attention says `latent.`, a delta-rule mixer beside an MLP `kda.` and
+    its chunk (`moe.kda.c64`), attention over the keys an indexer chooses
     `indexed.`, a layer over four residual streams starts with `hc4.`, and
     a prediction module after the stack ends the label with `+mtp`."""
     streams = f"hc{cfg.hyper.n}." if cfg.hyper is not None else ""
@@ -156,6 +157,8 @@ def _kinds_label(cfg: TransformerConfig) -> str:
         keeps, reads = "^" if k.keeps else "", "" if k.reads is None else f"<{k.reads}"
         if k.block:
             return f"{streams}{k.mlp}.{attn}{keeps}{reads}"
+        if k.mixer == "kda" and k.mlp is not None:
+            return f"{k.mlp}.kda.c{cfg.kda.chunk_size}"
         return (f"attn.{attn}{keeps}" if k.mixer == "attention" else k.parts) + reads
 
     names = [name(k) for k in cfg.kinds()]
@@ -1000,6 +1003,7 @@ class JaxTrainEngine(TrainEngine):
                           + self._index_counts(rows)
                           + self._band_counts(rows["segment_ids"])
                           + self._mhc_counts(rows["segment_ids"])
+                          + self._kda_counts(rows["segment_ids"])
                           for a, rows in zip(attn, stacks)]
                 self._count_batch(
                     "fused", len(mbs), sum(b.n_rows == 1 for b, _ in built),
@@ -1070,7 +1074,8 @@ class JaxTrainEngine(TrainEngine):
                               *self._ssm_counts(rows["segment_ids"]),
                               *self._index_counts(rows),
                               *self._band_counts(rows["segment_ids"]),
-                              *self._mhc_counts(rows["segment_ids"]))
+                              *self._mhc_counts(rows["segment_ids"]),
+                              *self._kda_counts(rows["segment_ids"]))
                     attn_attrs = dict(attn_row_len=run_len, width=width)
             return (rows_dev, denom, batch.total_tokens, cells, attn_attrs, counts)
 
@@ -1095,7 +1100,7 @@ class JaxTrainEngine(TrainEngine):
         # and its resets; the indexers' cells scored, kept, and queries that
         # choose; the cells the layers' token-wise stretches run; counted
         # while tracing is on (`n_counted` of the micro-batches)
-        n_counts, n_counted = [0] * 19, 0
+        n_counts, n_counted = None, 0  # as many as a stage counts: summed as they come
         gaps_ms: List[float] = []
         mark = time.monotonic_ns()
         try:
@@ -1108,7 +1113,7 @@ class JaxTrainEngine(TrainEngine):
                 n_one_row += int(rows == 1)
                 if counts is not None:
                     n_counted += 1
-                    n_counts = [n + c for n, c in zip(n_counts, counts)]
+                    n_counts = [n + c for n, c in zip(n_counts or [0] * len(counts), counts)]
                 if mb_accum is None:
                     mb_accum = self._accum_step_fn(
                         loss_name, loss_fn, tuple(sorted(rows_dev.keys())),
@@ -1142,7 +1147,7 @@ class JaxTrainEngine(TrainEngine):
                 jnp.asarray(lr, jnp.float32),
             )
         self._grad_sums = g_acc  # the next minibatch's buffers
-        if n_counted == n_mbs:  # tracing was on for the whole batch
+        if n_counts and n_counted == n_mbs:  # tracing was on for the whole batch
             self._count_batch("overlapped", n_mbs, n_one_row, n_tok, n_cells,
                               *n_counts)
         self.last_overlap = {
@@ -1285,6 +1290,21 @@ class JaxTrainEngine(TrainEngine):
         return tuple(n * c for c in chunk_counts(
             segment_ids, self.model_cfg.ssm.chunk_size))
 
+    def _kda_counts(self, segment_ids: np.ndarray) -> Tuple[int, int, int, int]:
+        """What the delta-rule layers' chunked rule does with packed rows
+        (on the host, before the transfer; `segment_ids` [R, T] of one
+        micro-batch or [n, R, T] of several), by the device's own rule
+        (ops/kda.chunk_counts), summed over those layers: (the positions it
+        walks, the chunks it runs, those that hold a token, sequence
+        starts)."""
+        n = self.model_cfg.n_kda_layers
+        if not n:
+            return 0, 0, 0, 0
+        from areal_tpu.ops.kda import chunk_counts
+
+        return tuple(n * c for c in chunk_counts(
+            segment_ids, self.model_cfg.kda.chunk_size))
+
     def _head_counts(self, rows_np: Dict[str, np.ndarray],
                      scored_fn: Optional[ScoredFn], shift: int = 1) -> Tuple[int, int]:
         """What the loss head does with packed rows (on the host, before
@@ -1328,7 +1348,9 @@ class JaxTrainEngine(TrainEngine):
                      n_ssm_live: int = 0, n_ssm_mixed: int = 0, n_ssm_resets: int = 0,
                      n_index_cells: int = 0, n_index_selected: int = 0,
                      n_index_choosing: int = 0, n_band_cells: int = 0,
-                     n_mhc_cells: int = 0, n_mhc_loop_cells: int = 0):
+                     n_mhc_cells: int = 0, n_mhc_loop_cells: int = 0,
+                     n_kda_cells: int = 0, n_kda_chunks: int = 0,
+                     n_kda_live: int = 0, n_kda_resets: int = 0):
         """What one train_batch did, on its `train.batch` span and in the
         recorder's counters: the micro-batches and how many of them the
         packer made one row (what lets attention skip the block pairs
@@ -1347,7 +1369,9 @@ class JaxTrainEngine(TrainEngine):
         the queries that had more keys than they keep, the cells the
         layers' token-wise stretches ran, the cells the stream steps of
         hyper-connections ran over the stack's sublayers and those of them
-        inside a band loop (`_band_counts`, `_mhc_counts`)."""
+        inside a band loop (`_band_counts`, `_mhc_counts`), the positions
+        the delta-rule layers' chunked rule walked, its chunks, those with
+        a token and the sequence starts (`_kda_counts`)."""
         tracing.set_attrs(path=path, n_mbs=n_mbs, tokens=n_tok, cells=n_cells)
         tracing.count("train.batches")
         tracing.count("train.micro_batches", n_mbs)
@@ -1381,6 +1405,11 @@ class JaxTrainEngine(TrainEngine):
             tracing.count("train.ssm_chunks_live", n_ssm_live)
             tracing.count("train.ssm_chunks_mixed", n_ssm_mixed)
             tracing.count("train.ssm_resets", n_ssm_resets)
+        if self.model_cfg.n_kda_layers:
+            tracing.count("train.kda_cells", n_kda_cells)
+            tracing.count("train.kda_chunks", n_kda_chunks)
+            tracing.count("train.kda_chunks_live", n_kda_live)
+            tracing.count("train.kda_resets", n_kda_resets)
         if self._n_indexed:
             tracing.count("train.index_cells", n_index_cells)
             tracing.count("train.index_selected", n_index_selected)

@@ -6,7 +6,8 @@ RMS/LayerNorm, gated MLPs, optional MoE, actor (LM head) or critic (scalar
 head) outputs, tied embeddings, and qk-norm (qwen3); and, beyond it, a
 kind per layer (`LayerKind`: the parts a layer has: a mixer, attention
 with its window and rotary, differential or latent or neither, a
-state-space mixer in one of two forms or a gated memory unit, and an MLP,
+state-space mixer in one of two forms, a delta-rule mixer (`KDAConfig`)
+or a gated memory unit, and an MLP,
 dense or expert, either of which may be absent; a layer may keep a tensor
 that later layers read), an attention output gate,
 post-norms, a sigmoid router with a selection bias, shared experts, a
@@ -162,18 +163,52 @@ class SSMConfig:
 
 
 @dataclasses.dataclass
+class KDAConfig:
+    """A delta-rule mixer with a decay for every channel (Kimi Delta
+    Attention, arXiv:2510.26692; `ops/kda.py` has the equations):
+    `n_heads` heads whose keys and values are `head_dim` wide, a state of
+    `head_dim x head_dim` a head, q, k and v each through a causal
+    depthwise convolution of `conv_kernel` taps, the decay and the output
+    gate through low-rank products of `gate_rank`, the recurrence computed
+    in chunks of `chunk_size` positions. `dt_min`, `dt_max`, `dt_floor`:
+    the seeded draw of `dt_bias`, as `SSMConfig`'s."""
+
+    n_heads: int = 2
+    head_dim: int = 16
+    conv_kernel: int = 4
+    gate_rank: int = 16
+    chunk_size: int = 64
+    dt_min: float = 0.001
+    dt_max: float = 0.1
+    dt_floor: float = 1e-4
+
+    def __post_init__(self):
+        if self.chunk_size % 16:
+            raise ValueError(
+                f"KDAConfig.chunk_size must be a multiple of 16 (the sub-blocks "
+                f"inside which decays are taken cell by cell), got {self.chunk_size}")
+
+    @property
+    def d_inner(self) -> int:
+        return self.n_heads * self.head_dim
+
+
+@dataclasses.dataclass
 class MLAConfig:
     """Latent attention: q and k, v come through low-rank projections
     with an RMSNorm inside each. With x the layer's normed input,
     `c_q = RMSNorm(x W_qa)` [q_rank], `q = c_q W_qb`: a head
-    `[nope_dim | rope_dim]`; `[c_kv | k_r] = x W_kva` [kv_rank |
+    `[nope_dim | rope_dim]` (`q_rank` None: a full-rank `q = x W_q`, no
+    norm); `[c_kv | k_r] = x W_kva` [kv_rank |
     rope_dim], `c_kv = RMSNorm(c_kv)`, `c_kv W_kvb`: a head `[k_nope
-    nope_dim | v v_dim]`. Rotary goes over the rope part alone, and the
+    nope_dim | v v_dim]`. Rotary goes over the rope part alone (a kind
+    with `rotary=False`: over nothing, the rope part is then `rope_dim`
+    more columns of q and of the shared key), and the
     one `k_r` a token is every head's. A head's q and k are `nope_dim +
     rope_dim` wide (`TransformerConfig.head_dim`, the softmax scale's),
     its v and output `v_dim`."""
 
-    q_rank: int = 24
+    q_rank: Optional[int] = 24
     kv_rank: int = 16
     nope_dim: int = 8
     rope_dim: int = 8
@@ -292,7 +327,7 @@ class HyperConnConfig:
 class LayerKind:
     """What one layer of the stack is, known when the program is traced:
     the parts it has, each under its own norm with its own residual. A
-    mixer (`mixer`: "attention", "ssm", "gmu" or None) and an MLP (`mlp`:
+    mixer (`mixer`: "attention", "ssm", "kda", "gmu" or None) and an MLP (`mlp`:
     "dense", "moe" or None); a transformer block has both, a layer may
     have one. For attention: its mask (`window` = how many positions
     back a token sees, itself included; None = all of its sequence),
@@ -328,9 +363,9 @@ class LayerKind:
         if self.mlp not in ("dense", "moe", None):
             raise ValueError(
                 f"LayerKind.mlp must be 'dense', 'moe' or None, got {self.mlp!r}")
-        if self.mixer not in ("attention", "ssm", "gmu", None):
+        if self.mixer not in ("attention", "ssm", "kda", "gmu", None):
             raise ValueError(
-                "LayerKind.mixer must be 'attention', 'ssm', 'gmu' or None, "
+                "LayerKind.mixer must be 'attention', 'ssm', 'kda', 'gmu' or None, "
                 f"got {self.mixer!r}")
         if self.mixer is None and self.mlp is None:
             raise ValueError("a LayerKind needs a mixer or an MLP")
@@ -349,12 +384,12 @@ class LayerKind:
                 "an indexer chooses among the keys of plain causal attention: "
                 "no window, no differential pairing, no latent projections, "
                 "and its k and v are no other layer's")
-        if self.latent and (self.window is not None or not self.rotary or self.diff
+        if self.latent and (self.window is not None or self.diff
                             or self.keeps or self.reads is not None):
             raise NotImplementedError(
-                "latent attention is causal over the whole sequence with its "
-                "rotary part: no window, no differential pairing, and its k and "
-                "v are no other layer's")
+                "latent attention is causal over the whole sequence, its rope "
+                "part rotated or (rotary=False) left as it is: no window, no "
+                "differential pairing, and its k and v are no other layer's")
         if self.keeps and (self.mixer not in ("attention", "ssm")
                            or self.reads is not None):
             raise ValueError(
@@ -369,7 +404,7 @@ class LayerKind:
     def parts(self) -> str:
         """The layer's parts, which decide its parameters' structure and
         the stack they live in: layers with the same parts share a
-        stack. "attention+moe", "ssm", "moe", "diffattention+dense",
+        stack. "attention+moe", "ssm", "moe", "kda+moe", "diffattention+dense",
         "xdiffattention+dense" (x: q and the output projection only),
         "latentattention+moe", "indexedattention+moe" (the indexer's
         parameters beside attention's), "gmu+dense", ...; a layer that
@@ -469,6 +504,8 @@ class TransformerConfig:
     is_critic: bool = False
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
+    # The delta-rule mixer's sizes, for layers whose mixer is "kda".
+    kda: Optional[KDAConfig] = None
     # Latent attention's sizes; with them and no `layer_kinds`, every
     # layer's attention is latent. `head_dim` is then a head's q and k.
     mla: Optional[MLAConfig] = None
@@ -507,6 +544,8 @@ class TransformerConfig:
             self.moe = MoEConfig(**self.moe)
         if isinstance(self.ssm, dict):
             self.ssm = SSMConfig(**self.ssm)
+        if isinstance(self.kda, dict):
+            self.kda = KDAConfig(**self.kda)
         if isinstance(self.mla, dict):
             self.mla = MLAConfig(**self.mla)
         if isinstance(self.mtp, dict):
@@ -534,6 +573,8 @@ class TransformerConfig:
         if any(k.mixer in ("ssm", "gmu") for k in kinds) and self.ssm is None:
             raise ValueError(
                 "a layer with an 'ssm' or 'gmu' mixer needs TransformerConfig.ssm")
+        if any(k.mixer == "kda" for k in kinds) and self.kda is None:
+            raise ValueError("a layer with a 'kda' mixer needs TransformerConfig.kda")
         if any(k.latent for k in kinds):
             if self.mla is None:
                 raise ValueError("a latent attention layer needs TransformerConfig.mla")
@@ -657,6 +698,10 @@ class TransformerConfig:
         return sum(k.mixer == "ssm" for k in self.kinds())
 
     @property
+    def n_kda_layers(self) -> int:
+        return sum(k.mixer == "kda" for k in self.kinds())
+
+    @property
     def one_kind(self) -> bool:
         """Every layer the same transformer block over one residual
         stream, full causal attention, rotary as `pos_emb` says: what the
@@ -707,6 +752,15 @@ class TransformerConfig:
                 "the last conv_kernel - 1 inputs of its convolution, which the "
                 "cache manager has no slot for, no snapshot of for an "
                 "interrupted rollout to resume from, and no decode step"
+            )
+        if any(k.mixer == "kda" for k in kinds):
+            missing.append(
+                "a delta-rule state beside the KV pages: such a layer keeps, a "
+                f"sequence, its state [{self.kda.n_heads}, {self.kda.head_dim}, "
+                f"{self.kda.head_dim}] and the last conv_kernel - 1 inputs of its "
+                "three convolutions, which the cache manager has no slot for, no "
+                "snapshot of for an interrupted rollout to resume from, and no "
+                "decode step"
             )
         if any(k.reads is not None or k.keeps for k in kinds):
             missing.append(

@@ -91,8 +91,10 @@ def _config_from_hf(hf: Dict[str, Any], is_critic: bool = False,
             f"{hf.get('moe_layer_freq')}, scoring_func {hf.get('scoring_func')!r}")
     if not hf.get("q_lora_rank"):
         raise NotImplementedError(
-            f"{family}: q_lora_rank is absent: a full-rank q beside a "
-            "low-rank kv is not in models/transformer.py's latent block")
+            f"{family}: q_lora_rank is absent: models/transformer.py's latent "
+            "block takes a full-rank q (MLAConfig.q_rank None, as the kimi_linear "
+            "family has it), but this family's checkpoint names and its round trip "
+            "are written for the low-rank pair every published config of it has")
     mla = MLAConfig(
         q_rank=int(hf["q_lora_rank"]), kv_rank=int(hf["kv_lora_rank"]),
         nope_dim=int(hf["qk_nope_head_dim"]), rope_dim=int(hf["qk_rope_head_dim"]),

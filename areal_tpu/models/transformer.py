@@ -12,7 +12,8 @@ real_llm_base.py (blocks) — redesigned for XLA rather than translated:
   mixer, attention with a window or none and rotary or none,
   differential or latent (low-rank q and kv projections,
   `_latent_attention_block`) or neither, a state-space mixer,
-  `ops/ssm.py` or `ops/selective_scan.py`, or a gated memory unit; and
+  `ops/ssm.py` or `ops/selective_scan.py`, a delta-rule mixer,
+  `ops/kda.py`, or a gated memory unit; and
   an MLP, dense or expert; all static). A layer may keep a tensor (its
   scan's output, its
   k and v) that later layers read: it travels beside the residual
@@ -151,6 +152,10 @@ def _init_layer_stack(cfg: TransformerConfig, keys, n: int, kind: LayerKind,
         from areal_tpu.ops.ssm import init_ssm_params
 
         layers["ssm"] = init_ssm_params(cfg.ssm, D, dense, keys[13], L, pdt)
+    elif kind.mixer == "kda":
+        from areal_tpu.ops.kda import init_kda_params
+
+        layers["kda"] = init_kda_params(cfg.kda, D, dense, keys[13], L, pdt)
     elif kind.mixer == "gmu":
         k_in, k_out = jax.random.split(jax.random.fold_in(keys[15], 8))
         layers["gmu"] = {"w_in": dense(k_in, (L, D, cfg.ssm.d_inner)),
@@ -271,17 +276,22 @@ def _init_latent_attention(cfg: TransformerConfig, keys, L: int, dense) -> Dict[
     """`L` latent attention layers (`config.MLAConfig`): the two
     down-projections (the kv one with the shared rope key beside its
     latent), the norms inside them, the two up-projections to heads, and
-    the output projection from heads of `v_dim`."""
+    the output projection from heads of `v_dim`; with `q_rank` None, q's
+    one projection `wq` in place of its three leaves."""
     pdt = jnp.dtype(cfg.param_dtype)
     m, D, H = cfg.mla, cfg.hidden_dim, cfg.n_q_heads
     k_kvb, k_rope = jax.random.split(jax.random.fold_in(keys[15], 16))
     down = _LATENT_DOWN_GAIN / math.sqrt(D)
     q_gain = _LATENT_Q_GAIN if m.softmax_scale_factor == 1.0 else 1.0 / m.softmax_scale_factor
+    if m.q_rank is None:  # a full-rank q: one product, no norm
+        q = {"wq": dense(keys[1], (L, D, H * m.qk_dim), q_gain / math.sqrt(D))}
+    else:
+        q = {"wq_a": dense(keys[0], (L, D, m.q_rank), down),
+             "q_a_norm": jnp.ones((L, m.q_rank), pdt),
+             "wq_b": dense(keys[1], (L, m.q_rank, H * m.qk_dim),
+                           q_gain / math.sqrt(m.q_rank))}
     return {
-        "wq_a": dense(keys[0], (L, D, m.q_rank), down),
-        "q_a_norm": jnp.ones((L, m.q_rank), pdt),
-        "wq_b": dense(keys[1], (L, m.q_rank, H * m.qk_dim),
-                      q_gain / math.sqrt(m.q_rank)),
+        **q,
         "wkv_a": jnp.concatenate(
             [dense(keys[2], (L, D, m.kv_rank), down),
              dense(k_rope, (L, D, m.rope_dim))], axis=-1),
@@ -319,7 +329,7 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Params:
     params: Params = {
         "embedding": {"weight": dense(keys[7], (V, D), scale=(
             _INDEXED_EMBED_SCALE
-            if (cfg.hyper is not None or any(k.indexed for k in kinds))
+            if (cfg.hyper is not None or any(k.indexed or k.mixer == "kda" for k in kinds))
             and not cfg.tied_embeddings else 0.02))},
         "final_norm": {"weight": jnp.ones((D,), pdt)},
     }
@@ -647,15 +657,22 @@ def _attn_core(q, k, v, cfg, cos, sin, segment_ids, positions, attn_impl, mesh,
     return out, k, sums
 
 
-def _latent_in(x, lp, cfg, cos, sin, cdt):
+def _latent_in(x, lp, cfg, cos, sin, cdt, rotary=True):
     """Latent attention's q and k `[R, T, H, nope + rope]` and v `[R, T,
-    H, v_dim]` of the layer's normed input x (scope `attn_qkv`)."""
+    H, v_dim]` of the layer's normed input x (scope `attn_qkv`). q through
+    its low-rank pair, or through the one `wq` of a full-rank q
+    (`MLAConfig.q_rank` None); the rope parts turned unless the kind has
+    no `rotary`."""
     R, T, _ = x.shape
     m, H = cfg.mla, cfg.n_q_heads
     with jax.named_scope("attn_qkv"):
         with jax.named_scope("mla_q_proj"):
-            c_q = rms_norm(x @ lp["wq_a"].astype(cdt), lp["q_a_norm"], cfg.norm_eps)
-            q = (c_q @ lp["wq_b"].astype(cdt)).reshape(R, T, H, m.qk_dim)
+            if "wq" in lp:
+                c_q, wq = x, lp["wq"]
+            else:
+                c_q, wq = rms_norm(x @ lp["wq_a"].astype(cdt), lp["q_a_norm"],
+                                   cfg.norm_eps), lp["wq_b"]
+            q = (c_q @ wq.astype(cdt)).reshape(R, T, H, m.qk_dim)
         with jax.named_scope("mla_kv_proj"):
             c_kv, k_r = jnp.split(x @ lp["wkv_a"].astype(cdt), [m.kv_rank], axis=-1)
             c_kv = rms_norm(c_kv, lp["kv_a_norm"], cfg.norm_eps)
@@ -663,8 +680,11 @@ def _latent_in(x, lp, cfg, cos, sin, cdt):
                 R, T, H, m.nope_dim + m.v_dim)
         q_nope, q_r = jnp.split(q, [m.nope_dim], axis=-1)
         k_nope, v = jnp.split(kv, [m.nope_dim], axis=-1)
-        q_r = apply_rotary(q_r, cos, sin, cfg.rotary_interleaved)
-        k_r = apply_rotary(k_r[:, :, None, :], cos, sin, cfg.rotary_interleaved)
+        if rotary:
+            q_r = apply_rotary(q_r, cos, sin, cfg.rotary_interleaved)
+            k_r = apply_rotary(k_r[:, :, None, :], cos, sin, cfg.rotary_interleaved)
+        else:  # its columns as they are: that many more of the one shared key
+            k_r = k_r[:, :, None, :]
         q = jnp.concatenate([q_nope, q_r], axis=-1)
         k = jnp.concatenate(
             [k_nope, jnp.broadcast_to(k_r, (R, T, H, m.rope_dim))], axis=-1)
@@ -721,11 +741,18 @@ class _Stretch(NamedTuple):
 # Of a plain attention layer's parameters, those its first step reads;
 # its second reads the rest (latent attention: all but `wo`, and `wo`).
 _ATTN_IN = ("wq", "wk", "wv", "bq", "bk", "bv", "q_norm", "k_norm", "wg", "indexer")
+# Of a delta-rule mixer's, those its first step reads (the projections)
+# and its second (the head norm, the output projection); the rest are
+# what crosses tokens reads (`ops/kda.kda_mixer`).
+_KDA_IN = ("wq", "wk", "wv", "w_fa", "w_fb", "w_b", "w_ga", "w_gb")
+_KDA_OUT = ("o_norm", "wo")
 
 
 def _mixer_weights(st: _Stretch, mp, first: bool):
-    """The part of an attention layer's parameters `mp` that its first
-    step reads, or its second."""
+    """The part of a mixer's parameters `mp` that its first step reads, or
+    its second."""
+    if st.kind.mixer == "kda":
+        return {n: mp[n] for n in (_KDA_IN if first else _KDA_OUT)}
     first_names = set(mp) - {"wo"} if st.kind.latent else _ATTN_IN
     return {n: w for n, w in mp.items() if (n in first_names) == first}
 
@@ -758,8 +785,32 @@ def _hc_write(st: _Stretch, x, y, h_post=None, h_res=None):
         return stream_mix.mhc_mix(a, (x, y), st.hc_kernel)
 
 
+def _kda_in(h, mp, cdt):
+    """A delta-rule mixer's projections of its normed input h `[R, T, D]`
+    (scope `kda_proj`; the two low-rank products under `kda_gate`): q, k,
+    v `[R, T, H K]` before their convolutions, the decay's `(h W_fa) W_fb`
+    `[R, T, H K]`, beta's `h W_b` `[R, T, H]`, the output gate's
+    `(h W_ga) W_gb` `[R, T, H K]`."""
+    with jax.named_scope("kda_proj"):
+        q, k, v, b = (h @ mp[n].astype(cdt) for n in ("wq", "wk", "wv", "w_b"))
+        with jax.named_scope("kda_gate"):
+            f = (h @ mp["w_fa"].astype(cdt)) @ mp["w_fb"].astype(cdt)
+            gate = (h @ mp["w_ga"].astype(cdt)) @ mp["w_gb"].astype(cdt)
+    return q, k, v, f, b, gate
+
+
+def _kda_out(o, gate, mp, cfg, cdt):
+    """The rule's output o `[R, T, H, K]` -> the mixer's `[R, T, D]`: an
+    RMSNorm a head, the sigmoid gate, the output projection (scope
+    `kda_out`)."""
+    with jax.named_scope("kda_out"):
+        o = rms_norm(o, mp["o_norm"], cfg.norm_eps).reshape(gate.shape)
+        return (o * jax.nn.sigmoid(gate)) @ mp["wo"].astype(cdt)
+
+
 def _before_mixer(st: _Stretch, w, xs, side):
-    """An attention layer up to what crosses tokens: its input x (then
+    """A delta-rule mixer's layer up to what crosses tokens: `ln1` and
+    `_kda_in`. An attention layer up to what crosses tokens: its input x (then
     the k and v of the layer it reads, where it reads one) under `ln1`
     and attention's projections of that (scope `attn_qkv`: `_attn_in`,
     `_latent_in`), with what is token-wise after them (q/k norm, the
@@ -770,11 +821,15 @@ def _before_mixer(st: _Stretch, w, xs, side):
     streams: H_post and H_res, `_hc_read`'s, for `_after_mixer`)."""
     cfg, kind, cdt = st.cfg, st.kind, st.cdt
     (x, *kept), mp = xs, w["mixer"]
+    if kind.mixer == "kda":
+        with jax.named_scope("kda_proj"):
+            h = _norm(x, w["ln1"], cfg)
+        return _kda_in(h, mp, cdt)
     h, coefs = _hc_read(st, w.get("hc"), x)
     with jax.named_scope("attn_qkv"):
         h = _norm(h, w["ln1"], cfg)
     if kind.latent:
-        return _latent_in(h, mp, cfg, side[0], side[1], cdt) + coefs
+        return _latent_in(h, mp, cfg, *side[:2], cdt, kind.rotary) + coefs
     q, k, v, gate, own_kv = _attn_in(h, mp, cfg, cdt, tuple(kept) or None)
     if st.rot_in:
         with jax.named_scope("attn_qkv"):
@@ -829,7 +884,8 @@ def _mlp_part(st: _Stretch, w, xs, side=()):
 
 
 def _after_mixer(st: _Stretch, w, xs, side):
-    """An attention layer from the attention call's output (then the
+    """A delta-rule mixer's layer from the rule's output and the gate's
+    product (`_kda_out`), an attention layer from the attention call's output (then the
     gate's projection; several streams: `_before_mixer`'s H_post and
     H_res; the layer's input last) to the MLP's product or
     the routed experts' doorstep: the output projection under the gate
@@ -843,12 +899,14 @@ def _after_mixer(st: _Stretch, w, xs, side):
     if cfg.hyper is not None:  # `_before_mixer`'s H_post and H_res
         *got, h_post, h_res = got
         coefs = (h_post, h_res)
-    if kind.latent:
+    if kind.mixer == "kda":
+        a = _kda_out(got[0], got[1], w["mixer"], cfg, cdt)
+    elif kind.latent:
         a = _latent_out(got[0], w["mixer"], cdt)
     else:
         a = _attn_out(got[0], got[1] if len(got) > 1 else None, w["mixer"], cfg, cdt,
                       w.get("l0"))
-    with jax.named_scope("attn_out"):
+    with jax.named_scope("kda_out" if kind.mixer == "kda" else "attn_out"):
         if "ln1_post" in w:
             a = _norm(a, w["ln1_post"], cfg)
         x = _hc_write(st, x, a, *coefs)
@@ -857,8 +915,11 @@ def _after_mixer(st: _Stretch, w, xs, side):
 
 def _kind_loops(kind: LayerKind) -> bool:
     """Whether a layer of `kind` runs as stretches over live bands where
-    the call's shape allows: a plain, latent or indexed attention mixer
-    with an MLP beside it. By the probe (`scripts/band_loop_probe.py`;
+    the call's shape allows: a plain, latent or indexed attention mixer,
+    or a delta-rule mixer, with an MLP beside it (the delta-rule kinds by
+    memory first: a whole row of their stretches' float32 and MLP
+    temporaries is 1.8 GB of a 16,384-token step's 7.4, PERF.md section 6,
+    PR 50). By the probe (`scripts/band_loop_probe.py`;
     PERF.md section 6, PR 45) such a layer takes 21-32 % off a half-empty
     row and loses 0-3.5 % of a full one; a layer of one part (a
     state-space mixer, experts or attention alone) and a scan or memory
@@ -873,7 +934,7 @@ def _kind_loops(kind: LayerKind) -> bool:
     figures of the kinds ruled out were taken with looping bodies that
     went with this rule: the probe in the tree re-measures the kinds that
     loop."""
-    return (kind.mixer == "attention" and kind.mlp is not None
+    return (kind.mixer in ("attention", "kda") and kind.mlp is not None
             and not kind.diff and kind.reads is None)
 
 
@@ -1135,6 +1196,13 @@ def forward(
         )
     if mtp and (cfg.mtp is None or return_kv):
         raise ValueError("mtp=True needs cfg.mtp, and hands out no KV cache")
+    if cfg.n_kda_layers and mesh is not None and mesh.shape.get("seq", 1) > 1:
+        raise NotImplementedError(
+            "a delta-rule layer on a mesh that splits the sequence (ring / "
+            "ulysses context parallelism): ops/kda.py walks a row's chunks on one "
+            "device and has no hand-over of the state and of the convolutions' "
+            "last inputs from one sequence shard to the next"
+        )
     if cfg.n_ssm_layers and mesh is not None and mesh.shape.get("seq", 1) > 1:
         raise NotImplementedError(
             "a state-space layer on a mesh that splits the sequence (ring / "
@@ -1235,6 +1303,18 @@ def forward(
                     if kind.diff:  # a reader takes k and v as projected
                         k, v = k0, v0
                 kv, got = (k, v), got + coefs + (x,)
+                if "ln1_post" in lp:
+                    w["ln1_post"] = lp["ln1_post"]
+            elif kind.mixer == "kda":
+                from areal_tpu.ops.kda import kda_mixer
+
+                mp = lp["kda"]
+                *qkvfb, gate = run(
+                    _before_mixer,
+                    {"ln1": lp["ln1"], "mixer": _mixer_weights(st, mp, True)}, (x,))
+                o = kda_mixer(*qkvfb, mp, cfg.kda, segment_ids, cdt, mesh=mesh)
+                step, w = _after_mixer, {"mixer": _mixer_weights(st, mp, False)}
+                got = (o, gate, x)
                 if "ln1_post" in lp:
                     w["ln1_post"] = lp["ln1_post"]
             elif kind.mixer == "ssm" and cfg.ssm.form == "mamba1":

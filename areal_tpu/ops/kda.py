@@ -1,0 +1,511 @@
+"""A delta-rule mixer with a decay for every channel (Kimi Delta
+Attention, arXiv:2510.26692) over packed rows.
+
+For a layer's normalised input `h` [R, T, D], with H heads whose keys and
+values are K = V = `head_dim` wide:
+
+    q, k, v = silu(conv(h W_q)), silu(conv(h W_k)), silu(conv(h W_v))   [T, H, K]
+              conv: causal, depthwise, `conv_kernel` taps, no bias; a tap is
+              dropped unless its position lies in the token's own sequence
+    q, k    = q * rsqrt(sum q^2 + 1e-6), k likewise, a head;  q <- q * K^-0.5
+    g       = -exp(A_log)[H] * softplus((h W_fa) W_fb + dt_bias)   [T, H, K] float32, <= 0
+    b       = sigmoid(h W_b)                                        [T, H]
+    S_t     = (I - b_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + b_t k_t v_t^T   [K, V] a head,
+              float32, S = 0 before a sequence's first token
+    o_t     = S_t^T q_t
+    out     = (RMSNorm_head(o) * sigmoid((h W_ga) W_gb)) W_o
+
+What is token-wise (the projections before, the head norm, gate and `W_o`
+after) is `models/transformer.py`'s (`_before_mixer`, `_after_mixer`);
+here is what crosses tokens (`kda_mixer`): the convolutions, the decay,
+and the rule (`delta_rule`, which makes q and k unit a head on its way in).
+
+A packed row holds several sequences (segment ids, 0 = padding): state and
+convolution start afresh at every sequence start; a padding cell has
+b = 0, g = 0 and q = k = v = 0, so it adds nothing to any state and its own
+result is 0.
+
+**The rule in chunks** of `chunk_size` C positions (`delta_rule`). With
+`G_i` the running sum of `g` inside a chunk (restarting nowhere: the masks
+do the restarting), a chunk that receives the state `S_0`:
+
+    A[i, j] = b_i sum_d k_i[d] k_j[d] exp(G_i[d] - G_j[d])   j < i of one sequence, else 0
+    T = (I + A)^-1 Diag(b);   W = T (K * exp(G));   U = T V
+    O = (Q * exp(G)) S_0' + tril(P) (U - W S_0'),
+        P[i, j] = sum_d q_i[d] k_j[d] exp(G_i[d] - G_j[d]),  j <= i of one sequence
+    S_C = Diag(exp(G_C)) S_0' + (K * exp(G_C - G))^T (U - W S_0')
+
+`S_0'` is `S_0` for the cells of the sequence that crossed into the chunk
+and 0 for the rest; a chunk hands on the state of the sequence its last
+cell belongs to (as `ops/ssm.chunked_scan`). Two steps: `intra` makes, for
+every chunk of a group at once, what does not depend on the state (W, U,
+Q exp(G), K exp(G_C - G), tril(P), exp(G_C), each with its mask folded
+in); the walk carries `S` over the group's chunks (the kernels of
+`ops/pallas/kda_chunk.py` on the chip, a `lax.scan` elsewhere:
+`states_scan`). `delta_rule` takes a row a group of chunks at a time, up
+to the group of its last token.
+
+**No exponential of a positive number.** `exp(G_i - G_j)` is never split
+into `exp(G_i) exp(-G_j)` across a chunk (a decay of 0.2 a token over 64
+positions is 1e-45): a chunk is sub-blocks of 16; an off-diagonal
+sub-block is taken relative to the later sub-block's first position r
+(`exp(G_i - G_r)` and `exp(G_r - G_j)`, both at most 1) and is a matrix
+product; a diagonal sub-block is taken cell by cell. `A` is strictly
+lower triangular, so `(I + A)^-1 = (I - A)(I + A^2)(I + A^4)...`, `log2 C`
+squarings in float32 (backwards the inverse's own rule, two products). Decays, running sums, A, P and the inverse are
+float32; the other matrix products take operands in the compute dtype and
+accumulate in float32.
+
+The backward pass (`delta_rule`'s `custom_vjp`) keeps the rule's inputs
+and the state each group received; group by group from the last, it makes
+`intra` and the chunks' states again, walks the chunks backwards for the
+state's part, and differentiates `intra` for the rest.
+"""
+
+from __future__ import annotations
+
+import functools
+import math
+from typing import Any, Dict
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from areal_tpu.models.config import KDAConfig
+from areal_tpu.ops.ssm import causal_conv
+
+SUB = 16  # a chunk's sub-blocks: decays inside one are taken cell by cell
+L2_EPS = 1e-6
+# The rule takes a call's rows this many cells at a time, `intra` and the
+# walk, forward and backward: what it holds at once is a group's, not a row's.
+GROUP_CELLS = 1024
+
+
+_mm32 = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
+
+
+def init_kda_params(kda: KDAConfig, hidden_dim: int, dense_fn, key, n_layers: int,
+                    pdt) -> Dict[str, Any]:
+    """`n_layers` mixers stacked on a leading axis. `A_log` (a head) and
+    `dt_bias` (a channel) as `ops/ssm.init_ssm_params` draws them: log of
+    a uniform draw from 1..16, the inverse softplus of a log-uniform step
+    in [dt_min, dt_max]: before the low-rank product moves it, a channel
+    forgets at 0.999 to 0.2 a token."""
+    L, D, d_in, r = n_layers, hidden_dim, kda.d_inner, kda.gate_rank
+    ks = jax.random.split(key, 13)
+    dt = jnp.exp(jax.random.uniform(ks[0], (L, d_in), jnp.float32)
+                 * (math.log(kda.dt_max) - math.log(kda.dt_min))
+                 + math.log(kda.dt_min))
+    dt = jnp.maximum(dt, kda.dt_floor)
+    taps = lambda k: dense_fn(k, (L, kda.conv_kernel, d_in),
+                              1.0 / math.sqrt(kda.conv_kernel))
+    return {
+        "wq": dense_fn(ks[1], (L, D, d_in)),
+        "wk": dense_fn(ks[2], (L, D, d_in)),
+        "wv": dense_fn(ks[3], (L, D, d_in)),
+        # [taps, channels]: the last tap multiplies the position itself
+        "conv_q": taps(ks[4]), "conv_k": taps(ks[5]), "conv_v": taps(ks[6]),
+        "w_fa": dense_fn(ks[7], (L, D, r)), "w_fb": dense_fn(ks[8], (L, r, d_in)),
+        "w_b": dense_fn(ks[9], (L, D, kda.n_heads)),
+        "w_ga": dense_fn(ks[10], (L, D, r)), "w_gb": dense_fn(ks[11], (L, r, d_in)),
+        "A_log": jnp.log(jax.random.uniform(
+            jax.random.fold_in(ks[0], 1), (L, kda.n_heads), jnp.float32, 1.0, 16.0)
+        ).astype(pdt),
+        "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(pdt),
+        "o_norm": jnp.ones((L, kda.head_dim), pdt),
+        "wo": dense_fn(ks[12], (L, d_in, D)),
+    }
+
+
+@jax.custom_vjp
+def _inverse_unit_lower(a):
+    """(I + a)^-1 for strictly lower triangular `a` [..., C, C] float32:
+    (I - a)(I + a^2)(I + a^4)..., exact once the power reaches C. Its
+    backward rule is the inverse's own, `da = -Y^T dY Y^T`: two products
+    where the squarings' transposes are twenty."""
+    C = a.shape[-1]
+    eye = jnp.eye(C, dtype=a.dtype)
+    inv, power, n = eye - a, a, 2
+    while n < C:
+        power = _mm32(power, power)
+        inv = _mm32(inv, eye + power)
+        n *= 2
+    return inv
+
+
+def _inverse_fwd(a):
+    y = _inverse_unit_lower(a)
+    return y, y
+
+
+def _inverse_bwd(y, dy):
+    yt = jnp.swapaxes(y, -1, -2)
+    return (-_mm32(_mm32(yt, dy), yt),)
+
+
+_inverse_unit_lower.defvjp(_inverse_fwd, _inverse_bwd)
+
+
+@jax.checkpoint
+def _diagonal_blocks(q, k, G, same):
+    """The diagonal sub-blocks cell by cell: q, k, G [..., n, SUB, K]
+    float32, same [..., n, SUB, SUB] (i, j of one sequence, j <= i) ->
+    sum_d x_i[d] k_j[d] exp(G_i[d] - G_j[d]) [..., n, SUB, SUB] for x = k
+    and for x = q, the one exponential under both. (Under a checkpoint:
+    its backward recomputes the [SUB, SUB, K] terms, which are the largest
+    tensor of the rule by K / 4, and keeps none.)"""
+    diff = G[..., :, None, :] - G[..., None, :, :]
+    ke = k[..., None, :, :] * jnp.exp(jnp.where(same[..., None], diff, -jnp.inf))
+    return (jnp.sum(k[..., :, None, :] * ke, axis=-1),
+            jnp.sum(q[..., :, None, :] * ke, axis=-1))
+
+
+def unit(x):
+    """x a head over its norm, float32: `x * rsqrt(sum x^2 + L2_EPS)`."""
+    x = x.astype(jnp.float32)
+    return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + L2_EPS)
+
+
+def decay(f, A, dt_bias, seg):
+    """The log-decay a channel: f [N, C, H, K] (the low-rank product), A
+    [H] float32 (`-exp(A_log)`), dt_bias [H, K] float32, seg [N, C] ->
+    `g = A softplus(f + dt_bias)` float32 <= 0, 0 at padding."""
+    g = A[:, None] * jax.nn.softplus(f.astype(jnp.float32) + dt_bias)
+    return jnp.where((seg > 0)[..., None, None], g, 0.0)
+
+
+def intra(q, k, v, g, b, seg, before, cdt):
+    """What of a chunk does not depend on the state it receives. q, k
+    [N, C, H, K] (as their convolutions left them: made unit a head, and q
+    scaled by K^-0.5, here, in float32, a group of chunks at a time and
+    not a row) and v [N, C, H, V] (N chunks of C cells), g [N, C, H, K]
+    float32, b [N, C, H] float32, seg [N, C], before [N] (the sequence
+    the chunk before handed on, 0 = none) -> heads first, masks folded in:
+    Wm [N, H, C, K], U [N, H, C, V], Qg [N, H, C, K], Kd [N, H, C, K],
+    Pm [N, H, C, C] in `cdt`, dec [N, H, K] float32."""
+    f32 = jnp.float32
+    N, C, H, K = q.shape
+    n = C // SUB
+    heads_first = lambda a: jnp.moveaxis(a, 2, 1)  # [N, C, H, ..] -> [N, H, C, ..]
+    q, k, v, g = (heads_first(a) for a in (q, k, v, g))
+    qf, kf = unit(q) * K ** -0.5, unit(k)
+    b = jnp.moveaxis(b, 2, 1)  # [N, H, C]
+    G = jnp.cumsum(g, axis=2)  # [N, H, C, K] <= 0, falling
+
+    same = seg[:, :, None] == seg[:, None, :]  # [N, i, j]
+    causal = jnp.tril(jnp.ones((C, C), bool))
+    # off-diagonal sub-blocks: relative to the later sub-block's first cell
+    blocks = lambda a: a.reshape(a.shape[:2] + (n, SUB) + a.shape[3:])
+    Gb = blocks(G)  # [N, H, n, SUB, K]
+    Gr = Gb[:, :, :, :1]  # [N, H, n, 1, K] the running sum at a sub-block's first cell
+    rows = jnp.exp(Gb - Gr)  # <= 1: a cell against its own sub-block's first
+    # every cell before sub-block I against I's first cell, 0 from there on
+    earlier = (jnp.arange(C)[None, :] < (jnp.arange(n) * SUB)[:, None])  # [n, C]
+    cols = jnp.where(earlier[..., None], jnp.exp(jnp.minimum(
+        Gr - G[:, :, None], 0.0)), 0.0)  # [N, H, n, C, K]
+    k_cols = (kf[:, :, None] * cols).astype(cdt)
+    off = lambda x: jnp.einsum(
+        "nhbik,nhbjk->nhbij", (blocks(x) * rows).astype(cdt), k_cols,
+        preferred_element_type=f32).reshape(N, H, C, C)
+    # diagonal sub-blocks: cell by cell
+    seen = same & causal  # [N, i, j]
+    same_b = jnp.moveaxis(jnp.diagonal(
+        seen.reshape(N, n, SUB, n, SUB), axis1=1, axis2=3), -1, 1)[:, None]
+    on_k, on_q = (jnp.einsum("nhbij,bc->nhbicj", d, jnp.eye(n, dtype=f32)).reshape(N, H, C, C)
+                  for d in _diagonal_blocks(blocks(qf), blocks(kf), Gb, same_b))
+
+    seen = seen[:, None]  # [N, 1, i, j]
+    kk = jnp.where(seen, off(kf) + on_k, 0.0)
+    P = jnp.where(seen, off(qf) + on_q, 0.0)
+    A = jnp.where(jnp.eye(C, dtype=bool), 0.0, kk) * b[..., None]  # row i by b_i
+    T = _inverse_unit_lower(A) * b[:, :, None, :]  # (I + A)^-1 Diag(b)
+    Tc = T.astype(cdt)
+    eG = jnp.exp(G)
+    W = jnp.einsum("nhij,nhjk->nhik", Tc, (kf * eG).astype(cdt),
+                   preferred_element_type=f32)
+    U = jnp.einsum("nhij,nhjv->nhiv", Tc, v.astype(cdt), preferred_element_type=f32)
+
+    last = seg[:, -1]
+    cross = ((seg == before[:, None]) & (seg > 0))[:, None, :, None]  # [N, 1, C, 1]
+    to_end = (seg == last[:, None])[:, None, :, None]
+    carry = ((last == before) & (last > 0))[:, None, None]  # [N, 1, 1]
+    G_end = G[:, :, -1:]  # [N, H, 1, K]
+    Wm = jnp.where(cross, W, 0.0).astype(cdt)
+    Qg = jnp.where(cross, qf * eG, 0.0).astype(cdt)
+    Kd = jnp.where(to_end, kf * jnp.exp(G_end - G), 0.0).astype(cdt)
+    dec = jnp.where(carry, jnp.exp(G_end[:, :, 0]), 0.0)
+    return Wm, U.astype(cdt), Qg, Kd, P.astype(cdt), dec
+
+
+def states_scan(Wm, U, Qg, Kd, Pm, dec, S_in):
+    """The walk over a row's chunks, plain: each [R, N, H, ...] as `intra`
+    makes them, `S_in` [R, H, V, K] float32 the state the first of them
+    receives (held transposed, as the kernels hold it) -> O [R, N, H, C,
+    V] and the state every chunk received [R, N, H, V, K] in the operands'
+    dtype, and the state the last hands on, float32."""
+    f32 = jnp.float32
+    cdt = Wm.dtype
+    mm = functools.partial(jnp.einsum, preferred_element_type=f32)
+
+    def step(S, x):
+        wm, u, qg, kd, pm, de = x
+        Sc = S.astype(cdt)
+        vc = (u.astype(f32) - mm("rhck,rhvk->rhcv", wm, Sc)).astype(cdt)
+        o = mm("rhck,rhvk->rhcv", qg, Sc) + mm("rhij,rhjv->rhiv", pm, vc)
+        return de[..., None, :] * S + mm("rhcv,rhck->rhvk", vc, kd), (o.astype(cdt), Sc)
+
+    by_chunk = lambda a: jnp.moveaxis(a, 1, 0)
+    S_out, (O, S_all) = jax.lax.scan(
+        step, S_in, tuple(by_chunk(a) for a in (Wm, U, Qg, Kd, Pm, dec)))
+    return jnp.moveaxis(O, 0, 1), jnp.moveaxis(S_all, 0, 1), S_out
+
+
+def states_scan_bwd(Wm, U, Qg, Kd, Pm, dec, S_all, dO, dS_in):
+    """`states_scan`'s transpose, the chunks walked backwards with the
+    state's cotangent carried from `dS_in` (that of the state the last
+    chunk hands on): -> the cotangents of Wm, U, Qg, Kd, Pm (in their
+    dtype) and dec, and that of the state the first chunk received."""
+    f32 = jnp.float32
+    cdt = Wm.dtype
+    mm = functools.partial(jnp.einsum, preferred_element_type=f32)
+
+    def step(dS, x):
+        wm, u, qg, kd, pm, de, Sc, doc = x
+        S, dSc = Sc.astype(f32), dS.astype(cdt)
+        vn = (u.astype(f32) - mm("rhck,rhvk->rhcv", wm, Sc)).astype(cdt)
+        dvn = mm("rhij,rhiv->rhjv", pm, doc) + mm("rhck,rhvk->rhcv", kd, dSc)
+        dvc = dvn.astype(cdt)
+        outs = (-mm("rhcv,rhvk->rhck", dvc, Sc), dvn, mm("rhcv,rhvk->rhck", doc, Sc),
+                mm("rhcv,rhvk->rhck", vn, dSc), mm("rhiv,rhjv->rhij", doc, vn))
+        dS_new = (mm("rhcv,rhck->rhvk", doc, qg) + de[..., None, :] * dS
+                  - mm("rhcv,rhck->rhvk", dvc, wm))
+        return dS_new, tuple(a.astype(cdt) for a in outs) + (jnp.sum(dS * S, axis=-2),)
+
+    by_chunk = lambda a: jnp.moveaxis(a, 1, 0)
+    dS_out, outs = jax.lax.scan(
+        step, dS_in, tuple(by_chunk(a) for a in (Wm, U, Qg, Kd, Pm, dec, S_all, dO)),
+        reverse=True)
+    return tuple(jnp.moveaxis(a, 0, 1) for a in outs) + (dS_out,)
+
+
+def _group(R: int, N: int, C: int, cells: int) -> int:
+    """Chunks of every row the rule takes at a time: the largest divisor
+    of N whose cells, over the R rows, are at most `cells`."""
+    g = max(1, min(N, cells // (R * C)))
+    while N % g:
+        g -= 1
+    return g
+
+
+def _live_chunks(seg, C: int):
+    """[R] the chunks of each row up to its last token's (0 = an empty row)."""
+    T = seg.shape[1]
+    last = jnp.max(jnp.where(seg > 0, jnp.arange(1, T + 1), 0), axis=1)
+    return (last + C - 1) // C
+
+
+def _walk(parts, S_in, n_live, kernel):
+    if kernel:
+        from areal_tpu.ops.pallas import kda_chunk
+
+        return kda_chunk.states_fwd(*parts, S_in, n_live, interpret=kernel == "interpret")
+    return states_scan(*parts, S_in)
+
+
+def _walk_bwd(parts, S_all, dO, dS_in, n_live, kernel):
+    if kernel:
+        from areal_tpu.ops.pallas import kda_chunk
+
+        return kda_chunk.states_bwd(*parts, S_all, dO, dS_in, n_live,
+                                    interpret=kernel == "interpret")
+    return states_scan_bwd(*parts, S_all, dO, dS_in)
+
+
+class _Groups:
+    """A call's rows cut into groups of chunks: `args` [R, N, ...] a group
+    `i` of every row at a time (`take`), and a result put back (`put`)."""
+
+    def __init__(self, segment_ids, C: int, cells: int):
+        R, T = segment_ids.shape
+        self.R, self.N, self.C = R, T // C, C
+        self.gs = _group(R, self.N, C, cells)
+        self.seg = segment_ids.reshape(R, self.N, C)
+        # the sequence the chunk before each handed on (0 for a row's first)
+        self.before = jnp.pad(self.seg[:, :, -1], ((0, 0), (1, 0)))[:, :-1]
+        self.n_live = _live_chunks(segment_ids, C)
+        # groups up to the one that holds the fullest row's last token
+        self.live = (jnp.max(self.n_live) + self.gs - 1) // self.gs
+
+    def chunked(self, a):
+        return a.reshape((self.R, self.N, self.C) + a.shape[2:])
+
+    def take(self, a, i):
+        return jax.lax.dynamic_slice_in_dim(a, i * self.gs, self.gs, axis=1)
+
+    def put(self, buf, a, i):
+        return jax.lax.dynamic_update_slice_in_dim(buf, a.astype(buf.dtype), i * self.gs, axis=1)
+
+    def live_in(self, i):
+        """[R] a row's live chunks inside group i."""
+        return jnp.clip(self.n_live - i * self.gs, 0, self.gs)
+
+    def intra(self, cdt, i):
+        """`decay` and `intra` of group i's chunks, as a function of (q, k,
+        v, f, b) `[R, gs, C, ...]`, A and dt_bias -> parts `[R, gs, H,
+        ...]`: the float32 decays are a group's, never a row's."""
+        R, gs = self.R, self.gs
+        flat = lambda a: a.reshape((R * gs,) + a.shape[2:])
+        seg, before = flat(self.take(self.seg, i)), flat(self.take(self.before, i))
+
+        def fn(q, k, v, f, b, A, dt_bias):
+            g = decay(flat(f), A, dt_bias, seg)
+            parts = intra(flat(q), flat(k), flat(v), g, flat(b), seg, before, cdt)
+            return tuple(a.reshape((R, gs) + a.shape[1:]) for a in parts)
+
+        return fn
+
+
+def delta_rule(q, k, v, f, b, A, dt_bias, segment_ids, chunk: int, kernel):
+    """The recurrence over packed rows, in chunks, of `unit(q) K^-0.5` and
+    `unit(k)` under the decay `exp(A softplus(f + dt_bias))`: q, k, f [R,
+    T, H, K], v [R, T, H, V], all 0 at padding; b [R, T, H] float32, 0 at
+    padding; A [H] and dt_bias [H, K] float32; segment_ids [R, T]; T a
+    multiple of `chunk` -> o [R, T, H, V] in q's dtype. `kernel`: the walk over
+    chunks by the kernels of `ops/pallas/kda_chunk.py` (True; "interpret":
+    in interpret mode, a test's), or by `states_scan` (False).
+
+    A group of every row's chunks at a time (`_Groups`), up to the group
+    of the fullest row's last token (a loop whose trip count is a value of
+    the run): `intra` of the group, then the walk over its chunks from the
+    state the group before handed on. What stands in memory at once is a
+    group's; the forward rule keeps its inputs and the state each group
+    received, and the backward loop makes a group's `intra` and its
+    chunks' states again before it walks them backwards. One function
+    jitted at module level (as `ops/band_loop.stretch`): the layers of a
+    stack that call it at one shape share a trace and a lowering of each
+    loop."""
+    return _rule_jit(q, k, v, f, b, A, dt_bias, segment_ids, chunk, kernel, GROUP_CELLS)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
+def _rule(q, k, v, f, b, A, dt_bias, segment_ids, chunk, kernel, cells):
+    return _rule_fwd(q, k, v, f, b, A, dt_bias, segment_ids, chunk, kernel, cells)[0]
+
+
+def _rule_fwd(q, k, v, f, b, A, dt_bias, segment_ids, chunk, kernel, cells):
+    R, T, H, K = q.shape
+    V, cdt = v.shape[-1], q.dtype
+    gr = _Groups(segment_ids, chunk, cells)
+    args = tuple(gr.chunked(a) for a in (q, k, v, f, b))
+
+    def body(i, carry):
+        S, O, bounds = carry
+        with jax.named_scope("kda_intra"):
+            parts = gr.intra(cdt, i)(*(gr.take(a, i) for a in args), A, dt_bias)
+        with jax.named_scope("kda_states"):
+            O_g, _, S_out = _walk(parts, S, gr.live_in(i), kernel)
+        return (S_out, gr.put(O, O_g, i),
+                jax.lax.dynamic_update_slice_in_dim(bounds, S[None], i, axis=0))
+
+    _, O, bounds = jax.lax.fori_loop(0, gr.live, body, (
+        jnp.zeros((R, H, V, K), jnp.float32), jnp.zeros((R, gr.N, H, chunk, V), cdt),
+        jnp.zeros((gr.N // gr.gs, R, H, V, K), jnp.float32)))
+    o = jnp.moveaxis(O, 2, 3).reshape(R, T, H, V)  # [R, N, H, C, V] -> cells
+    return o, (q, k, v, f, b, A, dt_bias, segment_ids, bounds)
+
+
+def _rule_bwd(chunk, kernel, cells, res, do):
+    q, k, v, f, b, A, dt_bias, segment_ids, bounds = res
+    R, T, H, K = q.shape
+    V, cdt = v.shape[-1], q.dtype
+    gr = _Groups(segment_ids, chunk, cells)
+    args = tuple(gr.chunked(a) for a in (q, k, v, f, b))
+    dO = jnp.moveaxis(gr.chunked(do.astype(cdt)), 3, 2)  # [R, N, H, C, V]
+
+    def body(j, carry):
+        dS, grads, consts = carry
+        i = gr.live - 1 - j
+        with jax.named_scope("kda_intra"):
+            parts, pull = jax.vjp(gr.intra(cdt, i), *(gr.take(a, i) for a in args),
+                                  A, dt_bias)
+        with jax.named_scope("kda_states"):
+            S_in = jax.lax.dynamic_index_in_dim(bounds, i, 0, keepdims=False)
+            _, S_all, _ = _walk(parts, S_in, gr.live_in(i), kernel)
+            *d_parts, dS = _walk_bwd(parts, S_all, gr.take(dO, i), dS, gr.live_in(i), kernel)
+        with jax.named_scope("kda_intra"):
+            *got, dA, d_bias = pull(tuple(d.astype(p.dtype) for d, p in zip(d_parts, parts)))
+        return (dS, tuple(gr.put(buf, a, i) for buf, a in zip(grads, got)),
+                (consts[0] + dA, consts[1] + d_bias))
+
+    _, grads, consts = jax.lax.fori_loop(0, gr.live, body, (
+        jnp.zeros((R, H, V, K), jnp.float32), tuple(jnp.zeros_like(a) for a in args),
+        (jnp.zeros_like(A), jnp.zeros_like(dt_bias))))
+    return tuple(a.reshape((R, T) + a.shape[3:]) for a in grads) + consts + (None,)
+
+
+_rule.defvjp(_rule_fwd, _rule_bwd)
+_rule_jit = jax.jit(_rule, static_argnums=(8, 9, 10))
+
+
+def _use_kernel(K: int, mesh) -> bool:
+    """The kernels walk the chunks on the chip, one device's rows, heads of
+    whole lane tiles; the plain walk elsewhere (the CPU, a toy head, a
+    mesh of several devices: a kernel is opaque to the partitioner)."""
+    return (jax.default_backend() == "tpu" and (mesh is None or mesh.size == 1)
+            and K % 128 == 0)
+
+
+def kda_mixer(q, k, v, f, b, kp, kda: KDAConfig, segment_ids, cdt, mesh=None,
+              kernel=None):
+    """What of the mixer crosses tokens. q, k, v [R, T, H K] (the three
+    projections), f [R, T, H K] (the decay's low-rank product), b [R, T,
+    H] (beta's projection), `kp` the layer's `conv_*`, `A_log`, `dt_bias`
+    -> o [R, T, H, K] in `cdt`, before the head norm."""
+    R, T, _ = q.shape
+    H, K, C = kda.n_heads, kda.head_dim, kda.chunk_size
+    f32 = jnp.float32
+    valid = segment_ids > 0
+    # masked on the way in: whatever padding cells hold (the residual
+    # stream carries them along) reaches neither a result nor a gradient
+    q, k, v, f, b = (jnp.where(valid[..., None], a, 0) for a in (q, k, v, f, b))
+    with jax.named_scope("kda_taps"):
+        conv = lambda x, w: causal_conv(x.astype(cdt), w.astype(cdt), None, segment_ids)
+        q, k, v = (conv(x, kp[n]).reshape(R, T, H, K)
+                   for x, n in ((q, "conv_q"), (k, "conv_k"), (v, "conv_v")))
+    with jax.named_scope("kda_gate"):
+        A = -jnp.exp(kp["A_log"].astype(f32))  # [H]
+        dt_bias = kp["dt_bias"].astype(f32).reshape(H, K)
+        f = f.astype(cdt).reshape(R, T, H, K)
+        beta = jnp.where(valid[..., None], jax.nn.sigmoid(b.astype(f32)), 0.0)
+    with jax.named_scope("kda_chunk"):
+        pad = -T % C
+        if pad:
+            grow = lambda a: jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
+            q, k, v, f, beta, segment_ids = (
+                grow(a) for a in (q, k, v, f, beta, segment_ids))
+        if kernel is None:
+            kernel = _use_kernel(K, mesh)
+        o = delta_rule(q, k, v, f, beta, A, dt_bias, segment_ids, C, kernel)
+    return o[:, :T]
+
+
+def chunk_counts(segment_ids: np.ndarray, chunk: int):
+    """What `delta_rule` does with packed rows, counted on the host by its
+    own rule; `segment_ids` [R, T] of one call or [n, R, T] of several:
+    (the positions it walks: the chunks it runs times their length; the
+    chunks it runs: every row's, a group at a time up to the group of the
+    fullest row's last token, `_Groups`; those that hold a token; sequence
+    starts)."""
+    seg = np.asarray(segment_ids)
+    seg = seg.reshape((-1,) + seg.shape[-2:])
+    pad = -seg.shape[-1] % chunk
+    seg = np.pad(seg, ((0, 0), (0, 0), (0, pad)))
+    start = (seg != np.pad(seg, ((0, 0), (0, 0), (1, 0)))[..., :-1]) & (seg > 0)
+    n_calls, R, T = seg.shape
+    N = T // chunk
+    live = (seg.reshape(n_calls, R, N, chunk) > 0).any(-1)  # [n, R, N]
+    last = np.where(live.any(-1), N - np.argmax(live[..., ::-1], axis=-1), 0).max(-1)
+    gs = _group(R, N, chunk, GROUP_CELLS)
+    run = R * int((-(-last // gs) * gs).sum())
+    return run * chunk, run, int(live.sum()), int(start.sum())

@@ -1,0 +1,97 @@
+#!/usr/bin/env python3
+"""The delta rule on the chip (`areal_tpu/ops/kda.py`): milliseconds a call
+of `delta_rule`, forward and forward + backward, at the shapes
+`kimilinear-d5e8-train-ppo-long` runs it (a row of 16,384 cells, 32 heads
+of 128, bf16) with a given share of the row holding tokens, the walk over
+chunks by the kernels of `ops/pallas/kda_chunk.py` and by the plain scan,
+at each chunk size, group of chunks and heads a grid step asked for.
+
+    python scripts/kda_probe.py [--out chiprun_out/x.jsonl] [--chunks 64 128]
+"""
+
+import argparse
+import functools
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from areal_tpu.ops import kda
+
+
+def timed(fn, args, reps=5):
+    out = fn(*args)
+    jax.block_until_ready(out)
+    t0 = time.perf_counter()
+    for _ in range(reps):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / reps
+
+
+def inputs(T, H, K, tokens, seed=0):
+    rng = np.random.default_rng(seed)
+    seg = np.zeros((1, T), np.int32)
+    at, i = 0, 1
+    while at < tokens:  # sequences of 1-6k tokens
+        n = min(int(rng.integers(1000, 6000)), tokens - at)
+        seg[0, at:at + n] = i
+        at, i = at + n, i + 1
+    valid = seg > 0
+    q, k, v = (rng.normal(size=(1, T, H, K)) for _ in range(3))
+    f = rng.normal(size=(1, T, H, K)) - 4.0  # with A of 1-16: a decay of 0.2-0.999 a token
+    b = rng.uniform(0.1, 0.95, size=(1, T, H))
+    m = valid[..., None, None]
+    bf = lambda a: jnp.asarray(np.where(m, a, 0), jnp.bfloat16)
+    return (bf(q), bf(k), bf(v), bf(f),
+            jnp.asarray(np.where(valid[..., None], b, 0), jnp.float32),
+            -jnp.asarray(rng.uniform(1, 16, size=(H,)), jnp.float32),
+            jnp.zeros((H, K), jnp.float32), jnp.asarray(seg))
+
+
+def main():
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--out", default=None)
+    ap.add_argument("--chunks", type=int, nargs="+", default=[64])
+    ap.add_argument("--fill", type=float, nargs="+", default=[0.53, 1.0])
+    ap.add_argument("--heads", type=int, nargs="+", default=[4])
+    ap.add_argument("--groups", type=int, nargs="+", default=[1024])
+    args = ap.parse_args()
+    T, H, K = 16384, 32, 128
+    rows = []
+    for fill in args.fill:
+        *xs, seg = inputs(T, H, K, int(T * fill))
+        w = jnp.asarray(np.random.default_rng(1).normal(size=(1, T, H, K)), jnp.float32)
+        for chunk in args.chunks:
+            for group in args.groups:
+                kda.GROUP_CELLS = group
+                for name, kernel, heads in [("plain", False, 0)] + [
+                        ("kernel", True, h) for h in args.heads]:
+                    if heads:
+                        from areal_tpu.ops.pallas import kda_chunk
+                        kda_chunk.HEADS = heads
+                    rule = functools.partial(kda.delta_rule, chunk=chunk, kernel=kernel)
+                    fwd = jax.jit(lambda *a: rule(*a, seg))
+                    both = jax.jit(jax.grad(
+                        lambda *a: jnp.sum(rule(*a, seg) * w), tuple(range(7))))
+                    row = dict(fill=fill, chunk=chunk, group=group, walk=name, heads=heads,
+                               fwd_ms=timed(fwd, xs) * 1e3,
+                               fwd_bwd_ms=timed(both, xs) * 1e3)
+                    rows.append(row)
+                    print(json.dumps(row), flush=True)
+                    jax.clear_caches()
+    if args.out:
+        os.makedirs(os.path.dirname(args.out), exist_ok=True)
+        with open(args.out, "w") as f:
+            for r in rows:
+                f.write(json.dumps(r) + "\n")
+
+
+if __name__ == "__main__":
+    main()

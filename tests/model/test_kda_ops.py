@@ -1,0 +1,245 @@
+"""The delta rule over packed rows (`areal_tpu/ops/kda.py`): the chunked
+form and its hand-written backward against the recurrence token by token
+(`benchmark/reference/kimi_linear.delta_rule`), decays small enough to
+underflow a chunk, the kernels of `ops/pallas/kda_chunk.py` in interpret
+mode, a packed row against each of its sequences alone, and the host's
+counts. CPU, float32, toy widths."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from areal_tpu.models.config import KDAConfig
+from areal_tpu.ops import kda
+from areal_tpu.ops.pallas import kda_chunk
+from benchmark.reference import kimi_linear as ref
+
+H, K = 2, 16
+ROWS = ((50, 77, 30), (100, 64))  # sequences no chunk of 16 or 64 divides evenly
+
+
+def _segments(rows, T):
+    seg = np.zeros((len(rows), T), np.int32)
+    for r, lens in enumerate(rows):
+        o = 0
+        for j, n in enumerate(lens):
+            seg[r, o:o + n] = j + 1
+            o += n
+    return seg
+
+
+def _inputs(T=192, rows=ROWS, g_max=0.5, g_min=0.001, seed=0):
+    """q, k, v, g in [-g_max, -g_min], b in (0.1, 0.95), all 0 at padding,
+    and the rows' segment ids."""
+    rng = np.random.default_rng(seed)
+    seg = _segments(rows, T)
+    R = len(rows)
+    q, k, v = (rng.normal(size=(R, T, H, K)) for _ in range(3))
+    g = -rng.uniform(g_min, g_max, size=(R, T, H, K))
+    b = rng.uniform(0.1, 0.95, size=(R, T, H))
+    valid = seg > 0
+    m = valid[..., None, None]
+    arrays = [np.where(m, a, 0) for a in (q, k, v, g)] + [
+        np.where(valid[..., None], b, 0)]
+    return tuple(jnp.asarray(a, jnp.float32) for a in arrays) + (jnp.asarray(seg),)
+
+
+def recurrence(q, k, v, g, b, seg):
+    """The reference's token-by-token rule over q and k made unit a head
+    (q scaled), a sequence of a packed row at a time, zeros at padding."""
+    q, k = kda.unit(q) * K ** -0.5, kda.unit(k)
+    out = jnp.zeros(v.shape, jnp.float32)
+    seg = np.asarray(seg)
+    for r in range(seg.shape[0]):
+        for s in np.unique(seg[r][seg[r] > 0]):
+            (at,) = np.nonzero(seg[r] == s)
+            cut = slice(at[0], at[-1] + 1)
+            out = out.at[r, cut].set(ref.delta_rule(
+                q[r, cut], k[r, cut], v[r, cut], g[r, cut], b[r, cut]))
+    return out
+
+
+def _rule(q, k, v, g, b, seg, chunk, kernel):
+    """`kda.delta_rule` given the log-decays g themselves: A = -1, no bias,
+    and f the inverse softplus of -g (anything at padding, where g is 0)."""
+    f = jnp.where(g < 0, jnp.log(jnp.expm1(-jnp.where(g < 0, g, -1.0))), 0.0)
+    H, K = q.shape[2:]
+    return kda.delta_rule(q, k, v, f, b, -jnp.ones((H,)), jnp.zeros((H, K)), seg, chunk, kernel)
+
+
+def _grads(fn, args, w):
+    return jax.grad(lambda *a: jnp.sum(fn(*a) * w), argnums=(0, 1, 2, 3, 4))(*args)
+
+
+def _assert_close(got, want, tol):
+    for name, a, b in zip("qkvgb", got, want):
+        scale = float(jnp.abs(b).max()) + 1e-6
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=tol * scale, rtol=0,
+                                   err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("chunk", [16, 64])
+@pytest.mark.parametrize("group_cells", [128, 1 << 20], ids=["groups", "whole"])
+def test_the_chunked_rule_is_the_recurrence_and_so_is_its_backward(chunk, group_cells,
+                                                                   monkeypatch):
+    """Outputs and every gradient, with `intra` run a group of chunks at a
+    time (a loop whose trip count is the row's live groups) and whole."""
+    monkeypatch.setattr(kda, "GROUP_CELLS", group_cells)
+    *args, seg = _inputs()
+    w = jnp.asarray(np.random.default_rng(1).normal(size=args[2].shape), jnp.float32)
+    chunked = lambda *a: _rule(*a, seg, chunk, False)
+    plain = lambda *a: recurrence(*a, seg)
+    with jax.default_matmul_precision("highest"):
+        np.testing.assert_allclose(np.asarray(chunked(*args)), np.asarray(plain(*args)),
+                                   atol=2e-5)
+        _assert_close(_grads(chunked, args, w), _grads(plain, args, w), 2e-5)
+
+
+@pytest.mark.parametrize("chunk", [16, 64])
+def test_decays_that_underflow_a_chunk_stay_finite_and_right(chunk):
+    """The sub-block rule: a decay of 0.01 a token (g = -4.6) in every
+    channel over whole chunks, where `exp(-G_j)` across a chunk of 64
+    would be 1e128: no exponential of a positive number is taken, and the
+    result is the recurrence's to 1e-4."""
+    *args, seg = _inputs(g_max=4.7, g_min=4.5)
+    w = jnp.asarray(np.random.default_rng(1).normal(size=args[2].shape), jnp.float32)
+    chunked = lambda *a: _rule(*a, seg, chunk, False)
+    with jax.default_matmul_precision("highest"):
+        got, want = chunked(*args), recurrence(*args, seg)
+        assert np.isfinite(np.asarray(got)).all()
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-4)
+        grads = _grads(chunked, args, w)
+        assert all(np.isfinite(np.asarray(a)).all() for a in grads)
+        _assert_close(grads, _grads(lambda *a: recurrence(*a, seg), args, w), 1e-4)
+
+
+def test_intra_takes_no_exponential_of_a_positive_number(monkeypatch):
+    """Every `exp` of `intra`'s trace gets an argument that is at most 0
+    (or -inf where a mask stands)."""
+    seen = []
+    exp = jnp.exp
+    monkeypatch.setattr(kda.jnp, "exp", lambda x: seen.append(float(jnp.max(x))) or exp(x))
+    q, k, v, g, b, seg = _inputs(g_max=4.7)
+    C = 64
+    cut = lambda a: a.reshape((-1, C) + a.shape[2:])
+    # the cell-by-cell blocks without their checkpoint, which would trace
+    monkeypatch.setattr(kda, "_diagonal_blocks", kda._diagonal_blocks.__wrapped__)
+    with jax.disable_jit():
+        kda.intra(cut(q), cut(k), cut(v), cut(g), cut(b), cut(seg),
+                  jnp.zeros((seg.size // C,), jnp.int32), jnp.float32)
+    assert len(seen) >= 5 and max(seen) <= 0.0
+
+
+@pytest.mark.parametrize("chunk", [16, 64])
+def test_the_kernels_walk_is_the_plain_walk(chunk, monkeypatch):
+    """`kda_fwd_states` and `kda_bwd_states` in interpret mode against
+    `states_scan` and its transpose, through the whole rule: a row with
+    an empty tail (its dead chunks read zero) beside a full one."""
+    ran = []
+    for name in ("states_fwd", "states_bwd"):
+        fn = getattr(kda_chunk, name)
+        monkeypatch.setattr(kda_chunk, name, lambda *a, _fn=fn, _n=name, **kw: (
+            ran.append(_n) or _fn(*a, **kw)))
+    jax.clear_caches()  # `delta_rule` is jitted at module level: trace it through the patches
+    *args, seg = _inputs(rows=((50, 40), (100, 92)))
+    w = jnp.asarray(np.random.default_rng(1).normal(size=args[2].shape), jnp.float32)
+    kernel = lambda *a: _rule(*a, seg, chunk, "interpret")
+    plain = lambda *a: _rule(*a, seg, chunk, False)
+    with jax.default_matmul_precision("highest"):
+        got = kernel(*args)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(plain(*args)), atol=1e-6)
+        assert not np.asarray(got[0, 90:]).any()
+        _assert_close(_grads(kernel, args, w), _grads(plain, args, w), 1e-6)
+    # a group a call: the forward, then the forward with its backward, which
+    # walks a group's chunks forwards again for their states
+    groups = 192 // chunk // kda._group(2, 192 // chunk, chunk, kda.GROUP_CELLS)
+    assert ran.count("states_fwd") == 3 * groups and ran.count("states_bwd") == groups
+
+
+def _mixer_inputs(D=32, T=64, lens=((20, 30, 10), (45, 11)), seed=0):
+    cfg = KDAConfig(n_heads=H, head_dim=K, gate_rank=8, chunk_size=16)
+    dense = lambda k, s, scale=None: jax.random.normal(k, s) * (scale or s[-2] ** -0.5)
+    kp = jax.tree_util.tree_map(lambda a: a[0], kda.init_kda_params(
+        cfg, D, dense, jax.random.PRNGKey(seed), 1, jnp.float32))
+    proj = lambda key, n: jax.random.normal(jax.random.PRNGKey(key), (len(lens), T, n))
+    xs = tuple(proj(i, H * K) for i in range(4)) + (proj(4, H),)
+    return cfg, kp, xs, jnp.asarray(_segments(lens, T)), lens
+
+
+def test_a_packed_row_is_each_of_its_sequences_alone():
+    """State and the three convolutions start afresh at every sequence
+    start, and cells of padding, filled with NaN, reach neither a result
+    nor a gradient."""
+    cfg, kp, xs, seg, lens = _mixer_inputs()
+    T = seg.shape[1]
+    mixer = lambda xs, kp, seg: kda.kda_mixer(*xs, kp, cfg, seg, jnp.float32)
+    w = jax.random.normal(jax.random.PRNGKey(9), (len(lens), T, H, K)) * (seg > 0)[..., None, None]
+    loss = lambda xs, kp: (mixer(xs, kp, seg) * w).sum()
+    with jax.default_matmul_precision("highest"):
+        packed = mixer(xs, kp, seg)
+        g_xs, g_kp = jax.grad(loss, (0, 1))(xs, kp)
+        for r, ls in enumerate(lens):
+            o = 0
+            for n in ls:  # the sequence alone in a row of its own, chunks from its start
+                cut = lambda a: a[r:r + 1, o:o + n]
+                alone_seg = jnp.ones((1, n), jnp.int32)
+                alone = mixer(tuple(cut(a) for a in xs), kp, alone_seg)
+                np.testing.assert_allclose(np.asarray(packed[r, o:o + n]),
+                                           np.asarray(alone[0]), atol=2e-5)
+                g_alone = jax.grad(lambda xs: (mixer(xs, kp, alone_seg) * cut(w)).sum())(
+                    tuple(cut(a) for a in xs))
+                for a, b in zip(g_xs, g_alone):
+                    np.testing.assert_allclose(np.asarray(cut(a)), np.asarray(b), atol=5e-5)
+                o += n
+            assert not np.asarray(packed[r, o:]).any()  # padding gets nothing
+        nan = lambda a: jnp.where((seg > 0).reshape(seg.shape + (1,) * (a.ndim - 2)), a, jnp.nan)
+        xs_nan = tuple(nan(a) for a in xs)
+        np.testing.assert_array_equal(np.asarray(mixer(xs_nan, kp, seg)), np.asarray(packed))
+        n_xs, n_kp = jax.grad(loss, (0, 1))(xs_nan, kp)
+    for a, b in zip(jax.tree_util.tree_leaves((n_xs, n_kp)),
+                    jax.tree_util.tree_leaves((g_xs, g_kp))):
+        assert np.isfinite(np.asarray(a)).all()
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_a_row_no_chunk_divides_is_padded_and_cut_back():
+    cfg, kp, xs, seg, _ = _mixer_inputs(T=50, lens=((20, 25), (7, 43)))
+    with jax.default_matmul_precision("highest"):
+        got = kda.kda_mixer(*xs, kp, cfg, seg, jnp.float32)
+        longer = kda.kda_mixer(*(jnp.pad(a, ((0, 0), (0, 14), (0, 0))) for a in xs), kp, cfg,
+                               jnp.pad(seg, ((0, 0), (0, 14))), jnp.float32)
+    assert got.shape == (2, 50, H, K)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(longer[:, :50]), atol=1e-6)
+
+
+def test_seeded_parameters_neither_freeze_nor_blow_up():
+    """`A_log` a head and `dt_bias` a channel as `init_ssm_params` draws
+    them: before the low-rank product moves it a channel forgets at 0.999
+    to 0.2 a token."""
+    cfg = KDAConfig(n_heads=32, head_dim=128, gate_rank=128)
+    dense = lambda k, s, scale=None: jax.random.normal(k, s) * (scale or s[-2] ** -0.5)
+    kp = kda.init_kda_params(cfg, 64, dense, jax.random.PRNGKey(0), 2, jnp.float32)
+    assert kp["A_log"].shape == (2, 32) and kp["dt_bias"].shape == (2, 4096)
+    dt = jax.nn.softplus(kp["dt_bias"]).reshape(2, 32, 128)
+    decay = np.asarray(jnp.exp(-jnp.exp(kp["A_log"])[..., None] * dt))
+    assert 0.19 < decay.min() < 0.5 and 0.99 < decay.max() < 1.0
+    assert kp["conv_q"].shape == (2, 4, 4096) and "conv_b" not in kp
+
+
+def test_the_host_counts_chunks_by_the_devices_rule(monkeypatch):
+    """Positions walked, chunks run (a group of every row's chunks at a
+    time, up to the fullest row's last token), chunks with a token,
+    sequence starts; several micro-batches are summed."""
+    seg = _segments(((50, 40), (100, 92)), 192)
+    monkeypatch.setattr(kda, "GROUP_CELLS", 1 << 20)
+    assert kda.chunk_counts(seg, 64) == (2 * 192, 6, 5, 4)  # one group: every chunk
+    monkeypatch.setattr(kda, "GROUP_CELLS", 128)  # a chunk of each row a group
+    assert kda.chunk_counts(seg, 64) == (2 * 192, 6, 5, 4)
+    half = _segments(((50, 40), (60,)), 192)
+    assert kda.chunk_counts(half, 64) == (2 * 128, 4, 3, 3)
+    assert kda.chunk_counts(np.stack([seg, half]), 64) == (2 * 192 + 2 * 128, 10, 8, 7)
+    one = _segments(((70,),), 256)
+    assert kda.chunk_counts(one, 16) == (128, 8, 5, 1)  # groups of 8 chunks
+    live = np.asarray(kda._live_chunks(jnp.asarray(half), 64))
+    assert live.tolist() == [2, 1]

@@ -481,3 +481,70 @@ def test_an_accumulate_step_of_the_four_stream_stack_compiles_at_8k(one_chip, mo
                  "splash_pairs_dq", "moe_rows_add"):
         assert name in text, name
     assert compiled.memory_analysis().temp_size_in_bytes < 7.0e9
+
+
+def test_the_delta_rules_kernels_compile_at_the_published_widths(one_chip):
+    """`kimilinear-d5e8-train-ppo-long`'s delta rule over a row of 16,384
+    at 32 heads of 128 x 128, bf16 (`ops/kda.py`): a loop over groups of
+    16 chunks each way, its trip count a value of the run; the walk over a
+    group's chunks is a custom call, `kda_fwd_states` in the forward loop,
+    that again and `kda_bwd_states` in the backward loop, each with the
+    group's live chunks as a scalar the index maps read. What stands in
+    memory beside the inputs and their cotangents is a group's."""
+    from areal_tpu.ops import kda
+
+    t, h, k = 16384, 32, 128
+    q = _shape((1, t, h, k), jnp.bfloat16, one_chip)
+    b = _shape((1, t, h), jnp.float32, one_chip)
+    a, bias = _shape((h,), jnp.float32, one_chip), _shape((h, k), jnp.float32, one_chip)
+    seg = _shape((1, t), jnp.int32, one_chip)
+
+    def loss(q, k_, v, f, b, a, bias, seg):
+        return kda.delta_rule(q, k_, v, f, b, a, bias, seg, 64, True).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, tuple(range(7)))).lower(
+        q, q, q, q, b, a, bias, seg).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    assert "kda_fwd_states" in text and "kda_bwd_states" in text
+    assert text.count(" while(") >= 2  # the groups, each way
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+def test_an_accumulate_step_of_the_delta_rule_stack_compiles_at_16k(one_chip, monkeypatch):
+    """A forward-backward micro-batch of `kimilinear-d5e8-train-ppo-long`'s
+    model at its one shape `(1, 16384)`, full remat, the masked loss head:
+    four delta-rule layers (one alone, a scan of two, one alone) and the
+    latent layer between them, every layer's token-wise stretches over the
+    row's live bands, the rule's two kernels beside the pair kernels at
+    192 against 128 and the experts' row adds. The compiler's temporaries:
+    6.1 GB beside 8.43 GB of weights, gradient sums and moments (10.7 GB
+    with the rule's parts and decays held a row at a time and the
+    stretches over the whole row: PERF.md section 6, PR 50)."""
+    import json
+
+    from areal_tpu.models.transformer import forward, init_params, looping_layers
+    from areal_tpu.ops.loss import fused_next_token_logprobs
+    from benchmark.model import transformer_config
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernels, not interpret mode
+    with open("benchmark/configs/kimi-linear-d5-e8.json") as f:
+        hf = {k: v for k, v in json.load(f).items() if k != "benchmark"}
+    cfg = transformer_config(hf, "bfloat16")
+    assert looping_layers(cfg, 1, 16384) == 5
+    params = jax.tree_util.tree_map(
+        lambda a: _shape(a.shape, a.dtype, one_chip),
+        jax.eval_shape(lambda k: init_params(cfg, k), jax.random.PRNGKey(0)))
+    ids = _shape((1, 16384), jnp.int32, one_chip)
+
+    def loss(p, input_ids, seg, pos):
+        hidden, _ = forward(p, cfg, input_ids, seg, pos, attn_impl="splash", remat=True,
+                            output="hidden", return_aux=True, bands=True)
+        return fused_next_token_logprobs(hidden, p["head"]["weight"], input_ids, seg,
+                                         scored=seg > 0).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss)).lower(params, ids, ids, ids).compile()
+    text = compiled.as_text()
+    for name in ("kda_fwd_states", "kda_bwd_states", "splash_pairs_dq", "moe_rows_add"):
+        assert name in text, name
+    assert compiled.memory_analysis().temp_size_in_bytes < 6.5e9
