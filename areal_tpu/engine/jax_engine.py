@@ -1246,11 +1246,13 @@ class JaxTrainEngine(TrainEngine):
         rows x that length; the cells of the block pairs they run, summed
         over rows and layers: by the rows' own segment ids,
         ops/attention.attn_block_cells; the cells a causal mask alone
-        would make them run; the grid steps the forward, dq and dkv
-        kernels walk and those whose pair runs, summed over rows, q heads
-        and layers: ops/attention.attn_grid_steps; the widest forward
-        grid, kv steps a q block, of any layer and row: of a row alone
-        the pairs of its fullest q block)."""
+        would make them run; the grid steps the forward and backward
+        kernels walk, those whose pair runs, and the steps of the first
+        that the backward walks (a row alone: its live pairs, once, in
+        one kernel), summed over rows, q heads and layers:
+        ops/attention.attn_grid_steps; the widest forward grid, kv steps
+        a q block, of any layer and row: of a row alone the pairs of its
+        fullest q block)."""
         cfg = self.model_cfg
         segment_ids = np.asarray(segment_ids)
         rows, row_len = segment_ids.shape[-2:]
@@ -1264,7 +1266,8 @@ class JaxTrainEngine(TrainEngine):
         if self._mtp_attn:  # the prediction module's block, the last kind's
             windows.append(cfg.kinds()[-1].window)
         # One count a window, not one a layer: (cells run, causal cells,
-        # steps walked, live steps, width) a micro-batch.
+        # steps walked, live steps, width, the backward's steps) a
+        # micro-batch.
         per = {w: [attn_block_cells(segment_ids=mb, window=w, **shape)
                    + attn_grid_steps(segment_ids=mb, window=w, **shape)
                    for mb in mbs]
@@ -1272,6 +1275,7 @@ class JaxTrainEngine(TrainEngine):
         total = lambda i: int(sum(mb[i] for w in windows for mb in per[w]))
         return (run_len, len(mbs) * rows * run_len, total(0), total(1),
                 cfg.n_q_heads * total(2), cfg.n_q_heads * total(3),
+                cfg.n_q_heads * total(5),
                 max(mb[4] for counts in per.values() for mb in counts))
 
     def _ssm_counts(self, segment_ids: np.ndarray) -> Tuple[int, int, int, int]:
@@ -1341,7 +1345,7 @@ class JaxTrainEngine(TrainEngine):
     def _count_batch(self, path: str, n_mbs: int, n_one_row: int, n_tok: int,
                      n_cells: int,
                      n_attn_cells: int, n_attn_active: int, n_attn_causal: int,
-                     n_attn_steps: int, n_attn_live: int,
+                     n_attn_steps: int, n_attn_live: int, n_attn_bwd_steps: int,
                      n_scored: int, n_head_cells: int,
                      n_mtp_targets: int, n_mtp_head_cells: int,
                      n_ssm_chunks: int = 0,
@@ -1359,7 +1363,9 @@ class JaxTrainEngine(TrainEngine):
         the cells the attention kernel ran (rows x the length it ran
         them at), the cells of the block pairs it ran
         against those of a causal mask alone, the grid steps its kernels
-        walked and those whose block pair ran, the positions whose logprob
+        walked, those whose block pair ran and those the backward walked
+        (half the steps where one backward kernel walks a row's live
+        pairs once), the positions whose logprob
         the loss reads and the cells of the chunks the loss head ran for
         them, the same two of the prediction module's run of the head,
         the (token, expert) pairs the routers of the expert
@@ -1387,6 +1393,7 @@ class JaxTrainEngine(TrainEngine):
         tracing.count("train.attn_causal_cells", n_attn_causal)
         tracing.count("train.attn_grid_steps", n_attn_steps)
         tracing.count("train.attn_live_steps", n_attn_live)
+        tracing.count("train.attn_bwd_steps", n_attn_bwd_steps)
         if not self.model_cfg.is_critic:
             tracing.count("train.scored_cells", n_scored)
             tracing.count("train.head_cells", n_head_cells)

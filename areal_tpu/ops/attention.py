@@ -165,7 +165,7 @@ _SPLASH_PAD_TO = 512
 # picked is within 3 % of the fastest measured one for every length of
 # 896 and above but 3840, which stays as it is (_SPLASH_MIN_GAIN).
 # Fitted on the fused backward over static grids: a row alone runs the
-# repo's own forward, dq and dkv kernels over its list of live pairs
+# repo's own forward and backward kernels over its lists of live pairs
 # (ops/pallas/splash_pairs.py), at blocks these constants still pick (a
 # refit is its own change).
 _SPLASH_NS = (980.0, 0.0115, 1.13, 1.25)
@@ -266,8 +266,8 @@ def splash_cost(t: int, bq: int, bkv: int, bkvc: int) -> float:
     """Estimated kernel time of a causal row of length `t` at the given
     blocks, in ns per q head: forward, remat's forward and the fused
     backward over the static grids, which is what the constants were
-    fitted on. A row alone runs less (its live pairs' steps, dq and dkv
-    in kernels of their own); the blocks picked are the same."""
+    fitted on. A row alone runs less (its live pairs' steps and no
+    others, forward and backward); the blocks picked are the same."""
     terms = _splash_cost_terms(t, bq, bkv, bkvc)
     return sum(ns * x for ns, x in zip(_SPLASH_NS, terms))
 
@@ -380,10 +380,14 @@ def _splash_kernel(t: int, bq: int, bkv: int, bkvc: int, group: int,
 def _pair_list(pairs, capacity: int):
     """(`pairs` [a, b] in row-major order as (major, minor, flags), int32
     [capacity] each, and how many there are): a major block's pairs in a
-    run, its first flagged FIRST and its last LAST; past the count, the
-    last pair again (a step never walked: the grid is as long as the
-    count)."""
-    from areal_tpu.ops.pallas.splash_pairs import FIRST, LAST
+    run, its first flagged FIRST and its last LAST; NEW the pair at which
+    the walk first comes to its minor block and DONE that at which it
+    leaves it (in the kv-major list: where the backward kernel writes a
+    q block's sum and does not read it, and where it writes the block of
+    dq), NEXT a pair whose minor block is the next pair's too (there the
+    kernel keeps the sum for the next step); past the count, the last
+    pair again (a step never walked: the grid is as long as the count)."""
+    from areal_tpu.ops.pallas.splash_pairs import DONE, FIRST, LAST, NEW, NEXT
 
     n = pairs.sum(dtype=jnp.int32)
     at = jnp.nonzero(pairs.reshape(-1), size=capacity, fill_value=0)[0]
@@ -392,7 +396,12 @@ def _pair_list(pairs, capacity: int):
     edge = major[1:] != major[:-1]
     first = jnp.concatenate([jnp.ones(1, bool), edge])
     last = jnp.concatenate([edge, jnp.ones(1, bool)]) | (jnp.arange(capacity) == n - 1)
-    return major, minor, (FIRST * first + LAST * last).astype(jnp.int32), n
+    new = major == jnp.argmax(pairs, axis=0)[minor]
+    done = major == (pairs.shape[0] - 1 - jnp.argmax(pairs[::-1], axis=0))[minor]
+    again = jnp.concatenate([minor[1:] == minor[:-1], jnp.zeros(1, bool)])
+    again &= jnp.arange(capacity) < n - 1
+    flags = FIRST * first + LAST * last + NEW * new + NEXT * again + DONE * done
+    return major, minor, flags.astype(jnp.int32), n
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2, 3))
@@ -400,8 +409,8 @@ def _pair_lists(segment_ids, bq, bkv, window):
     """The block pairs the kernels of one packed row `segment_ids` [T]
     walk (`ops/pallas/splash_pairs.PairLists`): of the pairs its static
     mask leaves (`_static_block_pairs`) those that `live_block_pairs`
-    finds in the row, each once, q-major for the forward and dq kernels
-    and kv-major for dkv, in lists as long as the static mask has pairs
+    finds in the row, each once, q-major for the forward kernel and
+    kv-major for the backward, in lists as long as the static mask has pairs
     with `n`, the row's own count, beside them. Every q block's diagonal
     pair is live, so `n` is never under the number of q blocks and every
     output block is written. Jitted: a program's call sites (each kind of
@@ -427,8 +436,8 @@ _SKIP_MIN_LEN = 2048
 def _rows_skip(rows: int, t_run: int) -> bool:
     """Whether the kernels of `rows` packed rows in one call walk the
     rows' own live block pairs (`_pair_lists`: a list of pairs whose
-    length is a value of the run, forward, dq and dkv in the repo's own
-    kernels): a long row alone. Several rows keep the static kernels,
+    length is a value of the run, forward and backward each one of the
+    repo's own kernels): a long row alone. Several rows keep the static kernels,
     the fused backward among them, and share one grid: a list a row
     needs a loop over the rows,
     which under `vmap` (pallas's own, around the kernel calls alone) is
@@ -736,23 +745,26 @@ def attn_grid_steps(
 ) -> tuple:
     """(grid steps the attention kernels walk for the packed rows
     `segment_ids` [R, T] of one micro-batch, those whose block pair
-    runs, the widest forward grid: kv steps a q block), per q head, the
-    forward kernel once. A row alone (`_rows_skip`) walks its list of
-    live pairs (`_pair_lists`: the device's rule) in the forward, dq and
-    dkv kernels and no step besides; its widest grid is its fullest q
-    block's pairs. Other rows walk the static grids: nq x the mask's
-    widest row forward, and the fused backward's whole nq x nkv. An
-    implementation without blocks has no grid: zeros. For host-side
-    counters."""
+    runs, the widest forward grid: kv steps a q block, and the steps of
+    the two that the backward walks), per q head, the forward kernel
+    once. A row alone (`_rows_skip`) walks its list of live pairs
+    (`_pair_lists`: the device's rule) once in the forward kernel and
+    once in the one backward kernel, and no step besides; its widest
+    grid is its fullest q block's pairs. Other rows walk the static
+    grids: nq x the mask's widest row forward, and the fused backward's
+    whole nq x nkv. An implementation without blocks has no grid: zeros.
+    For host-side counters."""
     found = _host_block_pairs(impl, segment_ids, hq, hkv, mesh, window)
     if found is None:
-        return 0, 0, 0
+        return 0, 0, 0, 0
     _, static, live = found
     if live is None:
         nq, nkv = static.shape
         r, widest = len(segment_ids), int(static.sum(axis=1).max())
-        return r * (nq * widest + nq * nkv), r * 2 * int(static.sum()), widest
-    return 3 * int(live.sum()), 3 * int(live.sum()), int(live.sum(axis=-1).max())
+        return (r * (nq * widest + nq * nkv), r * 2 * int(static.sum()), widest,
+                r * nq * nkv)
+    n = int(live.sum())
+    return 2 * n, 2 * n, int(live.sum(axis=-1).max()), n
 
 
 def packed_attention(q, k, v, segment_ids, positions, softmax_scale=None,

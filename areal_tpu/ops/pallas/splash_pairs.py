@@ -5,33 +5,72 @@ jax's splash kernels walk a grid of `q blocks x W kv blocks`, W static;
 a step whose pair holds nothing is skipped but still walked (0.3-0.4 us
 on a v5e). These walk a flat list instead: `ops/attention._pair_lists`
 names the row's live (q block, kv block) pairs, q-major for the forward
-and dq kernels and kv-major for dkv, with `n`, how many there are, and
+kernel and kv-major for the backward, with `n`, how many there are, and
 the grid is `(q heads, n)`, `n` a dynamic grid dimension: no step without
 a pair. A step's flags say whether it is the first or the last of its q
-block (kv block in dkv): scratch is initialised on the first, the output
-block written on the last.
+block (kv block in the backward): scratch is initialised on the first,
+the output block written on the last.
 
 Inside a step the arithmetic is splash's
 (jax.experimental.pallas.ops.tpu.splash_attention.splash_attention_kernel:
-`flash_attention_kernel`, `_flash_attention_dq_kernel`,
-`_flash_attention_dkv_kernel` without the fused dq): online softmax over
-`bkvc` sub-blocks, float32 sums in scratch, seven products a pair in the
-backward, the mask by place in the row and segment id. Operands are
-head-first: q `[Hq, T, hd]`, k `[Hkv, T, hd]`, v `[Hkv, T, hd_v]`; the kv
-head of a q head is in the index maps (`h // group`), and dkv's grid is
+`flash_attention_kernel`, `_flash_attention_dkv_kernel` with the fused
+dq): online softmax over `bkvc` sub-blocks, float32 sums, the mask by
+place in the row and segment id. Operands are head-first: q `[Hq, T,
+hd]`, k `[Hkv, T, hd]`, v `[Hkv, T, hd_v]`; the kv head of a q head is in
+the index maps (`h // group`).
+
+The backward is one kernel, five products a pair (`s = k q^T`, `dp = v
+do^T`, `dv = p^T do`, `dk = ds^T q`, `dq^T = k^T ds`), where a dq kernel
+beside a dkv kernel made `s`, `dp` and the exponential twice. Its grid is
 `(kv heads, n, group)`: a kv block's q blocks for every q head of the
-group before dk and dv leave scratch.
+group before dk and dv leave scratch. dq has no such run: a q block is
+visited once a kv block it pairs with, in steps that are not consecutive,
+so its float32 sum lives in HBM between visits, in an output of the
+kernel's own, `[Hq, hd, T]` (transposed: `k^T ds` needs the transpose of
+the kv block's k, made once a run of steps, where `ds^T k` would need
+that of every step's `ds`; and 192 rows of sublanes pad nothing where 192
+lanes pad to 256). A step reads the block's sum, adds its own and writes
+it back by copies of its own making, not the pipeline's: splash's fused
+backward avoids the read by writing partials `[kv blocks, heads, T, hd]`
+and summing them outside (805 MB a layer at 16,384), and an output block
+the pipeline writes back may still be on its way when the block's next
+visit, as little as one step later, is fetched. The order is kept by the
+grid running in order on one core (every axis "arbitrary": a chip with
+two cores must not split the heads either, since a step's write is
+waited for two steps on, whichever head that is) and by the copies'
+semaphores. The sums pass through the two halves of one buffer in turn:
+step j, at its start, waits for step j - 2's write out of its half (the
+whole of step j - 1 has hidden it), then starts the read of its block
+into that half; at its end it waits for the read, adds, and starts its
+write, which step j + 1 hides. So the only write that can still be on
+its way when a read starts is step j - 1's, and that is never to the
+same block: where the walk visits a block in two steps running (a group
+of 1: a kv block's last q block is the next's first; NEXT in the first
+step's flags) the first step writes nothing and the second takes the
+sum from the other half. A q block's first visit in the walk (NEW)
+writes and does not read, so nothing is set to zero first and nothing
+uninitialised is read; its last (DONE) writes no sum but the block of dq
+itself: transposed back to `[bq, hd]` and rounded to q's dtype, once,
+when the sum is complete (a minor dimension that is no multiple of the
+lanes, 192, goes out in 256 and is sliced outside). Every q block has a
+pair (its diagonal), so every block of dq is written. What the steps
+must know of each other (a write nobody has waited for, a sum kept for
+the next step) is four scalars in SMEM. On a v5e a step a head costs
+4.4 us at heads of 128 and 6.4 at 192 / 128 where a dq and a dkv kernel
+took 5.5 and 8.4; the products alone would take 3.8 and 5.7: the read of
+the sum costs half a microsecond wherever it is started and waited for,
+the writes nothing (PERF.md section 6, PR 51).
 
 A mask operand (`pair_attention_chosen`) is a choice of keys a query
 that is a value of the run, the same for every q head of the row: int8,
 non-zero = the query may read the key, ANDed with the mask by place and
-segment a cell at a time. Forward and dq read it as `[T / bkvc, T, bkvc]`
-(kv sub-block c of a pair's tile is `[c]` of its block), dkv as its
-transpose by q blocks, `[T / bq, T, bq]`. The walk stays the row's live
-pairs; a query must keep a key in its q block's pairs (its own place:
-the indexer always chooses a query's best-scored key, and a query alone
-in its prefix has itself). A row without such a choice passes no mask
-and its kernels read none: the operand is not there.
+segment a cell at a time. The forward reads it as `[T / bkvc, T, bkvc]`
+(kv sub-block c of a pair's tile is `[c]` of its block), the backward as
+its transpose by q blocks, `[T / bq, T, bq]`. The walk stays the row's
+live pairs; a query must keep a key in its q block's pairs (its own
+place: the indexer always chooses a query's best-scored key, and a query
+alone in its prefix has itself). A row without such a choice passes no
+mask and its kernels read none: the operand is not there.
 """
 
 from __future__ import annotations
@@ -54,22 +93,33 @@ _MASK_VALUE = -0.7 * float(np.finfo(np.dtype("float32")).max)
 _NN = (((1,), (0,)), ((), ()))  # standard matmul
 _NT = (((1,), (1,)), ((), ()))  # right-hand side transposed
 
-# A step's flags (`PairList.flags`).
-FIRST, LAST = 1, 2
+# A step's flags (`PairList.flags`): FIRST and LAST of its major block's
+# run of steps; NEW at its minor block's first step of the whole walk and
+# DONE at its last, NEXT where the walk's next step is at the same minor
+# block.
+FIRST, LAST, NEW, NEXT, DONE = 1, 2, 4, 8, 16
+
+# What the backward kernel's steps tell each other, int32 in SMEM: [0] and
+# [1] a write out of that half of `dq_io` that nobody has waited for, then
+# a block out of `dq_out` likewise, and that the step before kept its sum
+# in its half for this one.
+_OUT, _KEPT = 2, 3
 
 
 class PairList(NamedTuple):
     """Block pairs in the order a kernel walks them, int32 `[capacity]`
     each; past `PairLists.n` the last pair again. `flags`: FIRST / LAST
-    of the run of steps that share the list's major block."""
+    of the run of steps that share the list's major block, NEW where
+    the walk comes to the minor block for the first time, DONE for the
+    last, NEXT where its next step is at the same minor block."""
     q: jax.Array
     kv: jax.Array
     flags: jax.Array
 
 
 class PairLists(NamedTuple):
-    q_major: PairList  # forward and dq: a q block's kv blocks in a run
-    kv_major: PairList  # dkv: a kv block's q blocks in a run
+    q_major: PairList  # forward: a q block's kv blocks in a run
+    kv_major: PairList  # backward: a kv block's q blocks in a run
     n: jax.Array  # int32 scalar: the pairs that are there
 
 
@@ -148,53 +198,60 @@ def _fwd_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, qseg_ref,
             lse_ref[...] = (jnp.log(l) + m_sc[...]).T[:1]
 
 
-def _dq_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, qseg_ref,
-               kvseg_ref, *rest, blocks, window, masked=False):
+def _bwd_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, qseg_ref,
+                kvseg_ref, *rest, blocks, window, group, masked=False):
     mask_ref, rest = (rest[0], rest[1:]) if masked else (None, rest)
-    lse_ref, do_ref, di_ref, dq_ref, dq_sc = rest
-    bq, bkv, _ = blocks
-    s = pl.program_id(1)
-    flags = flags_ref[s]
-
-    @pl.when(flags & FIRST != 0)
-    def init():
-        dq_sc[...] = jnp.zeros_like(dq_sc)
-
-    k, v = k_ref[...], v_ref[...]
-    qk = lax.dot_general(q_ref[...], k, _NT, preferred_element_type=jnp.float32)
-    chosen = None if mask_ref is None else jnp.concatenate(
-        [mask_ref[c] for c in range(mask_ref.shape[0])], axis=1)
-    keep = _keep(qi_ref[s] * bq, ki_ref[s] * bkv, qk.shape,
-                 jnp.tile(qseg_ref[...], (1, bkv // _LANES)), kvseg_ref[:1, :],
-                 window, True, chosen)
-    p = jnp.exp(jnp.where(keep, qk, _MASK_VALUE) - jnp.expand_dims(lse_ref[0], -1))
-    dp = lax.dot_general(do_ref[...].astype(v.dtype), v, _NT,
-                         preferred_element_type=jnp.float32)
-    ds = (dp - jnp.expand_dims(di_ref[0], -1)) * p
-    dq_sc[...] += lax.dot_general(ds.astype(k.dtype), k, _NN,
-                                  preferred_element_type=jnp.float32)
-
-    @pl.when(flags & LAST != 0)
-    def end():
-        dq_ref[...] = dq_sc[...].astype(dq_ref.dtype)
-
-
-def _dkv_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, qseg_ref,
-                kvseg_ref, *rest, blocks, window, masked=False):
-    mask_ref, rest = (rest[0], rest[1:]) if masked else (None, rest)
-    lse_ref, do_ref, di_ref, dk_ref, dv_ref, dk_sc, dv_sc = rest
+    (lse_ref, do_ref, di_ref, dq_ref, sum_ref, dk_ref, dv_ref,
+     dk_sc, dv_sc, kt_sc, dq_sc, dq_io, dq_out, sems, on_its_way) = rest
     bq, bkv, bkvc = blocks
-    s, g = pl.program_id(1), pl.program_id(2)
+    h, s, g = (pl.program_id(i) for i in range(3))
+    kv_heads, n = pl.num_programs(0), pl.num_programs(1)
     flags = flags_ref[s]
+    step = (h * n + s) * group + g
+
+    @pl.when(step == 0)
+    def nothing_yet():
+        for i in range(4):
+            on_its_way[i] = 0
 
     @pl.when((flags & FIRST != 0) & (g == 0))
     def init():
         dk_sc[...] = jnp.zeros_like(dk_sc)
         dv_sc[...] = jnp.zeros_like(dv_sc)
+        # dq is summed transposed, `[hd, bq]` = k^T ds: the transpose is
+        # of the kv block's k, once a run of steps, not of a step's ds.
+        kt_sc[...] = k_ref[...].T
 
+    # The q block's sum lives in HBM between its visits (`sum_ref`, the
+    # whole `[Hq, hd, T]`, float32): in at the step's start, added to at
+    # its end, and out behind the next step, through the two halves of
+    # `dq_io` in turn; its last visit writes the block of dq itself.
     q_at, k_at = qi_ref[s] * bq, ki_ref[s] * bkv
+    half = lax.rem(step, 2)
+    mine, other = dq_io.at[half], dq_io.at[1 - half]
+    rows_of_q = pl.ds(pl.multiple_of(q_at, bq), bq)
+    sum_at = sum_ref.at[h * group + g, :, rows_of_q]
+    read = pltpu.make_async_copy(sum_at, mine, sems.at[2])
+    write = pltpu.make_async_copy(mine, sum_at, sems.at[half])
+    out = pltpu.make_async_copy(dq_out, dq_ref.at[h * group + g, rows_of_q], sems.at[3])
+    handed = on_its_way[_KEPT] != 0
+    summed = flags & NEW == 0  # an earlier step left this block a sum
+    fetched = summed & jnp.logical_not(handed)
+    last_visit = flags & DONE != 0
+    # A group of 1 visits a block in two steps running where a kv block's
+    # last q block is the next's first: the sum stays in VMEM.
+    keeps = (flags & NEXT != 0) if group == 1 else jnp.bool_(False)
 
-    def sub_block(c, _):
+    @pl.when(on_its_way[half] != 0)
+    def half_free():  # step j - 2's write: step j - 1 has hidden it
+        write.wait()
+        on_its_way[half] = 0
+
+    @pl.when(fetched)
+    def sum_in():
+        read.start()
+
+    for c in range(bkv // bkvc):
         rows = pl.ds(c * bkvc, bkvc)
         q, k, v, do = q_ref[...], k_ref[rows, :], v_ref[rows, :], do_ref[...]
         qk = lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32)
@@ -205,17 +262,55 @@ def _dkv_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, qseg_ref,
         dv = lax.dot(p.astype(do.dtype), do, preferred_element_type=jnp.float32)
         dv_sc[rows, :] = dv + dv_sc[rows, :]
         dp = lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)
-        ds = (dp - di_ref[:1, :]) * p
-        dk = lax.dot_general(ds.astype(do.dtype), q, _NN,
-                             preferred_element_type=jnp.float32)
+        ds = ((dp - di_ref[:1, :]) * p).astype(do.dtype)
+        dk = lax.dot_general(ds, q, _NN, preferred_element_type=jnp.float32)
         dk_sc[rows, :] = dk + dk_sc[rows, :]
+        dq = lax.dot(kt_sc[:, rows], ds, preferred_element_type=jnp.float32)
+        dq_sc[...] = dq if c == 0 else dq + dq_sc[...]
 
-    lax.fori_loop(0, bkv // bkvc, sub_block, None, unroll=True)
+    @pl.when(fetched)
+    def add():
+        read.wait()
+        mine[...] += dq_sc[...]
 
-    @pl.when((flags & LAST != 0) & (g == pl.num_programs(2) - 1))
+    @pl.when(handed)
+    def take_over():
+        mine[...] = other[...] + dq_sc[...]
+
+    @pl.when(jnp.logical_not(summed))
+    def begin():
+        mine[...] = dq_sc[...]
+
+    on_its_way[_KEPT] = keeps.astype(jnp.int32)
+
+    @pl.when(last_visit)
+    def block_out():  # the one rounding, and dq as the model has it: [bq, hd]
+        @pl.when(on_its_way[_OUT] != 0)
+        def stage_free():
+            out.wait()
+
+        dq_out[:, :mine.shape[0]] = mine[...].T.astype(dq_out.dtype)
+        out.start()
+        on_its_way[_OUT] = 1
+
+    @pl.when(jnp.logical_not(last_visit | keeps))
+    def sum_out():
+        write.start()
+        on_its_way[half] = 1
+
+    @pl.when((flags & LAST != 0) & (g == group - 1))
     def end():
         dk_ref[...] = dk_sc[...].astype(dk_ref.dtype)
         dv_ref[...] = dv_sc[...].astype(dv_ref.dtype)
+
+    @pl.when((h == kv_heads - 1) & (s == n - 1) & (g == group - 1))
+    def drain():
+        for i in (0, 1):
+            @pl.when(on_its_way[i] != 0)
+            def landed():
+                pltpu.make_async_copy(dq_io.at[i], sum_at, sems.at[i]).wait()
+
+        out.wait()  # the walk's last step is its block's last visit
 
 
 def _call(kernel, name, grid, lists, in_specs, out_specs, out_shape, scratch,
@@ -266,7 +361,7 @@ def _q_major(q, k, v, blocks, mask=None):
 
 def _masked(*operands):
     """The operands that are there: a kernel's mask is its last input
-    before the backward's, and absent for a row without one."""
+    before the backward's own, and absent for a row without one."""
     return tuple(x for x in operands if x is not None)
 
 
@@ -295,31 +390,16 @@ def _forward(q, k, v, segment_ids, lists, blocks, window, interpret, residuals,
     return out[0], (out[1][:, 0] if residuals else None)
 
 
-def _backward_dq(q, k, v, segment_ids, lists, lse, do, di, blocks, window,
-                 interpret, mask=None):
-    hq, _, hd = q.shape
-    bq, hd_v = blocks.bq, v.shape[-1]
-    on_q, in_specs = _q_major(q, k, v, blocks, mask)
-    q_row = pl.BlockSpec((None, 1, bq), lambda h, s, qi, ki, fl: (h, 0, qi[s]))
-    return _call(
-        functools.partial(_dq_kernel, blocks=blocks, window=window,
-                          masked=mask is not None),
-        "splash_pairs_dq", (hq, lists.n), lists.q_major,
-        in_specs=[*in_specs, q_row, pl.BlockSpec((None, bq, hd_v), on_q), q_row],
-        out_specs=pl.BlockSpec((None, bq, hd), on_q),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        scratch=[pltpu.VMEM((bq, hd), jnp.float32)],
-        semantics=("parallel", "arbitrary"), interpret=interpret,
-        operands=_masked(q, k, v, *_segment_operands(segment_ids, q_in_lanes=False),
-                         mask, lse[:, None, :], do, di[:, None, :]))
-
-
-def _backward_dkv(q, k, v, segment_ids, lists, lse, do, di, blocks, window,
-                  interpret, mask_t=None):
+def _backward(q, k, v, segment_ids, lists, lse, do, di, blocks, window,
+              interpret, mask_t=None):
+    """(dq, dk, dv) from one kernel over the kv-major list. dq's minor
+    dimension is a multiple of the lanes in the kernel's own copies (192
+    in 256): sliced here."""
     hq, t, hd = q.shape
     hkv, hd_v = k.shape[0], v.shape[-1]
     group = hq // hkv
     bq, bkv, _ = blocks
+    hd_out = -(-hd // _LANES) * _LANES
     on_q = lambda h, s, g, qi, ki, fl: (h * group + g, qi[s], 0)
     on_kv = lambda h, s, g, qi, ki, fl: (h, ki[s], 0)
     # Sublane-broadcast, as splash does it: Mosaic has no retiling of a
@@ -331,10 +411,10 @@ def _backward_dkv(q, k, v, segment_ids, lists, lse, do, di, blocks, window,
     # with its kv rows along sublanes, as this kernel computes.
     mask_spec = () if mask_t is None else (pl.BlockSpec(
         (None, bkv, bq), lambda h, s, g, qi, ki, fl: (qi[s], ki[s], 0)),)
-    return _call(
-        functools.partial(_dkv_kernel, blocks=blocks, window=window,
+    dq, _, dk, dv = _call(
+        functools.partial(_bwd_kernel, blocks=blocks, window=window, group=group,
                           masked=mask_t is not None),
-        "splash_pairs_dkv", (hkv, lists.n, group), lists.kv_major,
+        "splash_pairs_bwd", (hkv, lists.n, group), lists.kv_major,
         in_specs=[
             pl.BlockSpec((None, bq, hd), on_q),
             pl.BlockSpec((None, bkv, hd), on_kv),
@@ -346,15 +426,25 @@ def _backward_dkv(q, k, v, segment_ids, lists, lse, do, di, blocks, window,
             pl.BlockSpec((None, bq, hd_v), on_q),
             q_rows,
         ],
-        out_specs=[pl.BlockSpec((None, bkv, hd), on_kv),
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                   pl.BlockSpec(memory_space=pl.ANY),
+                   pl.BlockSpec((None, bkv, hd), on_kv),
                    pl.BlockSpec((None, bkv, hd_v), on_kv)],
-        out_shape=[jax.ShapeDtypeStruct(k.shape, k.dtype),
+        out_shape=[jax.ShapeDtypeStruct((hq, t, hd_out), q.dtype),
+                   jax.ShapeDtypeStruct((hq, hd, t), jnp.float32),  # the sums, in passing
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch=[pltpu.VMEM((bkv, hd), jnp.float32),
-                 pltpu.VMEM((bkv, hd_v), jnp.float32)],
-        semantics=("parallel", "arbitrary", "arbitrary"), interpret=interpret,
+                 pltpu.VMEM((bkv, hd_v), jnp.float32),
+                 pltpu.VMEM((hd, bkv), k.dtype),
+                 pltpu.VMEM((hd, bq), jnp.float32),
+                 pltpu.VMEM((2, hd, bq), jnp.float32),
+                 pltpu.VMEM((bq, hd_out), q.dtype),
+                 pltpu.SemaphoreType.DMA((4,)), pltpu.SMEM((4,), jnp.int32)],
+        semantics=("arbitrary", "arbitrary", "arbitrary"), interpret=interpret,
         operands=_masked(q, k, v, *_segment_operands(segment_ids, q_in_lanes=True),
                          mask_t, rows(lse), do, rows(di)))
+    return dq[..., :hd], dk, dv
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
@@ -384,18 +474,18 @@ def _pair_attention_bwd(blocks, window, residual_name, interpret, res, do):
     del residual_name
     q, k, v, segment_ids, lists, out, lse = res
     di = jnp.einsum("hsd,hsd->hs", out.astype(jnp.float32), do.astype(jnp.float32))
-    args = (q, k, v, segment_ids, lists, lse, do, di, blocks, window, interpret)
-    dk, dv = _backward_dkv(*args)
-    return _backward_dq(*args), dk, dv, None, None
+    dq, dk, dv = _backward(q, k, v, segment_ids, lists, lse, do, di, blocks, window,
+                           interpret)
+    return dq, dk, dv, None, None
 
 
 pair_attention.defvjp(_pair_attention_fwd, _pair_attention_bwd)
 
 
 def transpose_mask(mask, bq: int):
-    """`[T / bkvc, T, bkvc]` (forward and dq's layout of a mask operand)
-    -> `[T / bq, T, bq]` (dkv's): `out[i, s, r] = mask[s // bkvc, i bq +
-    r, s % bkvc]`."""
+    """`[T / bkvc, T, bkvc]` (the forward's layout of a mask operand) ->
+    `[T / bq, T, bq]` (the backward's): `out[i, s, r] = mask[s // bkvc,
+    i bq + r, s % bkvc]`."""
     n, t, bkvc = mask.shape
     return mask.reshape(n, t // bq, bq, bkvc).transpose(1, 0, 3, 2).reshape(
         t // bq, t, bq)
@@ -419,17 +509,17 @@ def _pair_attention_chosen_fwd(q, k, v, segment_ids, lists, mask, mask_t, blocks
     out, lse = _forward(q, k, v, segment_ids, lists, blocks, None, interpret,
                         residuals=True, mask=mask)
     out, lse = (checkpoint_name(x, residual_name) for x in (out, lse))
-    return (out, lse), (q, k, v, segment_ids, lists, mask, mask_t, out, lse)
+    return (out, lse), (q, k, v, segment_ids, lists, mask_t, out, lse)
 
 
 def _pair_attention_chosen_bwd(blocks, residual_name, interpret, res, cts):
     del residual_name
-    q, k, v, segment_ids, lists, mask, mask_t, out, lse = res
+    q, k, v, segment_ids, lists, mask_t, out, lse = res
     do, _ = cts
     di = jnp.einsum("hsd,hsd->hs", out.astype(jnp.float32), do.astype(jnp.float32))
-    args = (q, k, v, segment_ids, lists, lse, do, di, blocks, None, interpret)
-    dk, dv = _backward_dkv(*args, mask_t=mask_t)
-    return _backward_dq(*args, mask=mask), dk, dv, None, None, None, None
+    dq, dk, dv = _backward(q, k, v, segment_ids, lists, lse, do, di, blocks, None,
+                           interpret, mask_t=mask_t)
+    return dq, dk, dv, None, None, None, None
 
 
 pair_attention_chosen.defvjp(_pair_attention_chosen_fwd, _pair_attention_chosen_bwd)

@@ -102,7 +102,7 @@ def main():
         (lk, sk), gk = got["splash"]
         real = np.asarray(seg[0] > 0)
         line = dict(check=t, loss=[float(lp), float(lk)],
-                    sums={k: [float(sp[k]), float(sk[k])] for k in sp},
+                    sums={k: [float(sp[k]), float(sk[k])] for k in sp if jnp.ndim(sp[k]) == 0},
                     grad_err={n: [float(np.abs(np.asarray(u - w)[0][real]).max()),
                                   float(np.abs(np.asarray(u)[0][real]).max())]
                               for n, u, w in zip("q k v iq ik iw".split(), gp, gk)})

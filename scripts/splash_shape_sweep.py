@@ -29,8 +29,12 @@ the micro-batches of one of the benchmark's pools, a line each.
 `--group` and `--v-dim` set the kv heads (hq // group) and v's head
 size (192 / 128: `--hd 192 --v-dim 128`). `--ops` times by a trace, not
 the host's clock: from a traced call of forward + backward, the
-milliseconds a layer of each attention kernel by name, with the grid
-steps a q head walks and those whose pair runs;
+milliseconds a layer of each attention kernel by name (`ops_ms`: a row
+alone `splash_pairs_fwd` and `splash_pairs_bwd`), the backward's kernels
+summed (`backward_ms`: the parent's dq + dkv beside the one kernel's),
+the device's busy ms a layer (`busy_ms`: the kernels and what XLA runs
+around them) and the heaviest other ops, with the grid steps a q head
+walks and those whose pair runs;
 `--parent DIR` runs every line for the checkout at DIR as well (`git
 archive` of the commit to compare with), on the same inputs.
 """
@@ -75,20 +79,36 @@ def today(t):
     return A._plain_run_shape(t, *A._splash_block_targets())
 
 
+_PAIRS = "areal_tpu.ops.pallas.splash_pairs"
+
+
 def attention_module(parent=None):
-    """This tree's `ops/attention`, or that of the checkout at
-    `parent`, loaded beside it."""
+    """(`ops/attention`, `ops/pallas/splash_pairs`) of this tree, or of
+    the checkout at `parent`, loaded beside them. `ops/attention` imports
+    the pair kernels by name where it calls them: `use_tree` puts a
+    tree's own under that name."""
     if parent is None:
         from areal_tpu.ops import attention
+        from areal_tpu.ops.pallas import splash_pairs
 
-        return attention
+        return attention, splash_pairs
     import importlib.util
 
-    spec = importlib.util.spec_from_file_location(
-        "parent_attention", os.path.join(parent, "areal_tpu", "ops", "attention.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
+    def load(name, *path):
+        spec = importlib.util.spec_from_file_location(
+            name, os.path.join(parent, "areal_tpu", "ops", *path))
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        return mod
+
+    return (load("parent_attention", "attention.py"),
+            load("parent_splash_pairs", "pallas", "splash_pairs.py"))
+
+
+def use_tree(pairs):
+    """What a tree's `ops/attention` traces from here on are its own
+    pair kernels."""
+    sys.modules[_PAIRS] = pairs
 
 
 def variant(args, A):
@@ -199,10 +219,19 @@ def time_shape(A, qkv, ids, run_shape, layers, window=None):
     return clock(jax.jit(chain)), clock(jax.jit(jax.grad(chain, (0, 1, 2))))
 
 
+def backward_ms(ops_ms):
+    """The backward kernels' ms of `kernel_ops`' names: the one kernel
+    over the kv-major list, the parent's dq + dkv, or the static fused
+    backward."""
+    return sum(ms for name, ms in ops_ms.items() if not name.endswith("fwd"))
+
+
 def kernel_ops(A, qkv, run_shape, layers, window=None, reps=3):
-    """ids -> {attention kernel's name: ms a layer} from a trace of
-    `reps` calls of forward + backward through `layers` chained calls;
-    one program for every (segment ids, positions)."""
+    """ids -> ({attention kernel's name: ms a layer}, the device's busy
+    ms a layer: the kernels and what XLA runs around them, {the six
+    heaviest other ops: ms a layer}) from a trace of `reps` calls of
+    forward + backward through `layers` chained calls; one program for
+    every (segment ids, positions)."""
     import tempfile
 
     import jax
@@ -219,8 +248,11 @@ def kernel_ops(A, qkv, run_shape, layers, window=None, reps=3):
                     jax.block_until_ready(fn(*qkv, *ids))
             got = trace_reduce.reduce_trace(
                 trace_reduce.load_xplane(trace_reduce.find_xplane(d)), top=40)
-        return {name: 1e3 * s / (reps * layers) for name, s in got["device_ops"]
+        ms = {name: 1e3 * s / (reps * layers) for name, s in got["device_ops"]}
+        mine = {name: x for name, x in ms.items()
                 if trace_reduce.categorize(name) == "attention"}
+        rest = [(name, x) for name, x in ms.items() if name not in mine]
+        return mine, 1e3 * got["busy_s"] / (reps * layers), dict(rest[:6])
 
     return traced
 
@@ -287,10 +319,10 @@ def sweep(args):
                 plan.append((rows, t, c))
     # Today's shapes first, so a run cut short still has the baseline.
     plan.sort(key=lambda p: p[2] != today(p[1]))
-    trees = [("here", attention_module())]
+    trees = [("here", *attention_module())]
     if args.parent:
-        trees.append(("parent", attention_module(args.parent)))
-    for _, A in trees:
+        trees.append(("parent", *attention_module(args.parent)))
+    for _, A, _ in trees:
         variant(args, A)
     began = time.monotonic()
     os.makedirs(os.path.dirname(os.path.abspath(args.out)), exist_ok=True)
@@ -306,7 +338,8 @@ def sweep(args):
             else:
                 pool = [packed_rows(rows, t, args.seq_len)]
             pool = [tuple(jnp.asarray(a) for a in ids) for ids in pool]
-            for tree, A in trees:
+            for tree, A, pairs in trees:
+                use_tree(pairs)
                 ops = kernel_ops(A, qkv, c, args.layers, args.window) if args.ops else None
                 for at, ids in enumerate(pool):
                     row = dict(rows=rows, t=t, t_run=c[0], bq=c[1], bkv=c[2], bkvc=c[3],
@@ -321,7 +354,8 @@ def sweep(args):
                         elif args.ops:
                             row["steps"], row["live"] = walked(
                                 A, ids, args.hq, hkv, args.window)
-                            row["ops_ms"] = ops(ids)
+                            row["ops_ms"], row["busy_ms"], row["other_ms"] = ops(ids)
+                            row["backward_ms"] = backward_ms(row["ops_ms"])
                         else:
                             row["fwd_ms"], row["grad_ms"] = time_shape(
                                 A, qkv, ids, c, args.layers, args.window)

@@ -5,6 +5,12 @@ by where the compiled program keeps them.
 
     python scripts/trace_op_events.py <dir with an .xplane.pb> --op mhc_mix mhc_coef_grad
 
+`--op splash_pairs` lists a row alone's attention kernels, an instruction a
+call site: `splash_pairs_fwd.N` and, since PR 51, the one backward kernel
+`splash_pairs_bwd.N` (of whose results the float32 `[Hq, hd, T]` is the sums
+of dq in passing, the other three dq, dk and dv; `splash_pairs_dq` and
+`splash_pairs_dkv` in a trace from before).
+
 On a TPU a device event's name is the whole HLO instruction, types and
 layouts included, so the trace itself says which operands of a call live
 in VMEM (`S(1)` in the layout) and which in HBM. A line:

@@ -126,6 +126,7 @@ def test_attributes_and_counters_count_tokens_and_cells(recorded):
         "train.attn_causal_cells": all_cells,
         # the einsum reference has no grid to walk
         "train.attn_grid_steps": 0, "train.attn_live_steps": 0,
+        "train.attn_bwd_steps": 0,
         # no `scored_fn`: the head reads every token but a sequence's
         # last (9 sequences) and runs over every cell
         "train.scored_cells": a["tokens"] - 9, "train.head_cells": a["cells"],
@@ -220,12 +221,15 @@ def test_attn_cells_count_the_length_the_kernel_runs_at(impl):
 
         cfg = eng.model_cfg
         seg = np.zeros((d["rows"], 640), np.int32)
-        steps, live, width = attn_grid_steps("splash", seg, cfg.n_q_heads, cfg.n_kv_heads)
-        assert 0 < live < steps and d["width"] == width > 0
+        steps, live, width, backward = attn_grid_steps(
+            "splash", seg, cfg.n_q_heads, cfg.n_kv_heads)
+        assert 0 < live < steps and d["width"] == width > 0 < backward < steps
         assert c["train.attn_grid_steps"] == steps * cfg.n_q_heads * cfg.n_layers
         assert c["train.attn_live_steps"] == live * cfg.n_q_heads * cfg.n_layers
+        assert c["train.attn_bwd_steps"] == backward * cfg.n_q_heads * cfg.n_layers
     else:
         assert c["train.attn_grid_steps"] == c["train.attn_live_steps"] == d["width"] == 0
+        assert c["train.attn_bwd_steps"] == 0
 
 
 def test_band_cells_are_the_cells_the_devices_loops_run(monkeypatch):
@@ -426,7 +430,7 @@ def test_tracing_off_runs_no_host_count_and_the_stats_are_bit_equal(path, monkey
     for name in ("_attn_counts", "_head_counts", "_ssm_counts", "_count_batch"):
         monkeypatch.setattr(
             JaxTrainEngine, name,
-            lambda self, *a, _n=name, **k: called.append(_n) or (0,) * 7)
+            lambda self, *a, _n=name, **k: called.append(_n) or (0,) * 8)
     off_eng = mk_engine(params, depth=PATHS[path]["depth"])
     off = [off_eng.train_batch(*args, loss_name="t") for _ in range(2)]
     assert called == [] and not tracing.enabled()

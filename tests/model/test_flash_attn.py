@@ -364,11 +364,13 @@ def test_splash_run_time_block_mask_is_the_static_kernel_at_real_positions(
     """Rows of different layouts, each given alone as `[1, T, ..]` (one
     after another, or under a caller's `vmap`: a list a row, pallas's
     own loop over the kernel calls), walking the live pairs of their
-    lists and no others: outputs at real positions and dq equal to those
-    of splash's static kernels (forward, dq, dkv), not to a tolerance,
+    lists and no others: outputs at real positions equal to those of
+    splash's static kernels (forward, dq, dkv), not to a tolerance, dq,
     dk and dv to float32's rounding (a kv block's q heads are summed
-    pair by pair, not head by head); finite where the row is padding;
-    and the reference's."""
+    pair by pair, not head by head, and dq is summed a sub-block at a
+    time as `k^T ds`, where splash's dq kernel sums `ds k` over a whole
+    kv block in one product); finite where the row is padding; and the
+    reference's."""
     from areal_tpu.ops import attention as A
 
     R, T, hq, hkv, hd = 3, 768, 4, 2, 32
@@ -398,8 +400,7 @@ def test_splash_run_time_block_mask_is_the_static_kernel_at_real_positions(
         q, k, v, seg, run_shape, window))
     assert np.isfinite(got).all()
     np.testing.assert_array_equal(got[real], static[real])
-    np.testing.assert_array_equal(g_got[0], g_static[0], err_msg="q")
-    for a, b, name in zip(g_got[1:], g_static[1:], "kv"):
+    for a, b, name in zip(g_got, g_static, "qkv"):
         np.testing.assert_allclose(a, b, atol=2e-6, rtol=2e-6, err_msg=name)
     ref, g_ref = run(lambda *a: A.reference_packed_attention(*a, window=window))
     np.testing.assert_allclose(got[real], ref[real], atol=2e-5, rtol=2e-5)
@@ -434,8 +435,10 @@ def test_only_a_long_row_alone_skips_by_its_segment_ids(monkeypatch):
 
 def _check_pair_list(lst, n, pairs, major):
     """`lst` walks just `pairs` [nq, nkv], each once, `major`-major in
-    ascending order, its flags right, and past `n` nothing new."""
-    from areal_tpu.ops.pallas.splash_pairs import FIRST, LAST
+    ascending order, its flags right (NEW at a minor block's first step
+    of the walk and DONE at its last, NEXT where the next step's minor
+    block is the same), and past `n` nothing new."""
+    from areal_tpu.ops.pallas.splash_pairs import DONE, FIRST, LAST, NEW, NEXT
 
     qi, ki, flags = (np.asarray(a) for a in lst)
     assert qi.dtype == ki.dtype == flags.dtype == np.int32
@@ -450,7 +453,14 @@ def _check_pair_list(lst, n, pairs, major):
     first[np.r_[0, edge]] = True
     last = np.zeros(n, bool)
     last[np.r_[edge - 1, n - 1]] = True
-    np.testing.assert_array_equal(flags[:n], FIRST * first + LAST * last)
+    minor = (ki if major == "q" else qi)[:n]
+    new = np.zeros(n, bool)
+    new[np.unique(minor, return_index=True)[1]] = True
+    done = np.zeros(n, bool)
+    done[n - 1 - np.unique(minor[::-1], return_index=True)[1]] = True
+    again = np.r_[minor[1:] == minor[:-1], False]
+    np.testing.assert_array_equal(
+        flags[:n], FIRST * first + LAST * last + NEW * new + NEXT * again + DONE * done)
 
 
 @pytest.mark.parametrize("window", [None, 2048], ids=["causal", "window"])
@@ -462,8 +472,8 @@ def test_host_counts_are_the_device_block_tables(t, lens, window):
     `train.attn_active_cells`, `train.attn_grid_steps`,
     `train.attn_live_steps` and the span's `width`) count on the host
     what the kernels walk on the device: the lists `_pair_lists` makes,
-    `n` steps in each of the forward, dq and dkv kernels, every one a
-    pair that runs; and the lists name just the pairs the static mask
+    `n` steps in the forward kernel and `n` in the one backward kernel
+    (`train.attn_bwd_steps`), every one a pair that runs; and the lists name just the pairs the static mask
     and the row's sequences leave, each once, in order, their capacity
     the static mask's pairs."""
     from areal_tpu.ops import attention as A
@@ -487,18 +497,21 @@ def test_host_counts_are_the_device_block_tables(t, lens, window):
     assert causal == A._active_block_pairs(t_run, bq, bkv)[0] * bq * bkv
     skips = len(lens) > 1 or lens[0] < t or win is not None
     assert (ran < causal) if skips else (ran == causal)
-    steps, live, width = A.attn_grid_steps("splash", seg[None], 4, 2, window=window)
-    assert steps == live == 3 * n  # forward, dq, dkv: no step without a pair
-    assert width == pairs.sum(axis=1).max()
+    steps, live, width, backward = A.attn_grid_steps(
+        "splash", seg[None], 4, 2, window=window)
+    assert steps == live == 2 * n  # forward and backward: no step without a pair
+    assert width == pairs.sum(axis=1).max() and backward == n
     # several rows in one call keep the static kernels; so does a short row
     assert A.attn_block_cells("splash", np.stack([seg, seg]), 4, 2, window=window) == (
         2 * active * bq * bkv, 2 * causal)
     assert A.attn_grid_steps("splash", np.stack([seg, seg]), 4, 2, window=window) == (
-        2 * (nq * widest + nq * nkv), 2 * 2 * active, widest)  # forward, fused backward
+        2 * (nq * widest + nq * nkv), 2 * 2 * active, widest,
+        2 * nq * nkv)  # forward, fused backward
     assert A.attn_block_cells("splash", _row(1024, [10])[None], 4, 2) == (
         A._active_block_pairs(1024, 512, 512)[0] * 512 * 512,) * 2
-    assert A.attn_grid_steps("splash", _row(1024, [10])[None], 4, 2) == (2 * 2 + 2 * 2, 6, 2)
-    assert A.attn_grid_steps("reference", seg[None], 4, 2, window=window) == (0, 0, 0)
+    assert A.attn_grid_steps("splash", _row(1024, [10])[None], 4, 2) == (
+        2 * 2 + 2 * 2, 6, 2, 2 * 2)
+    assert A.attn_grid_steps("reference", seg[None], 4, 2, window=window) == (0, 0, 0, 0)
 
 
 @pytest.mark.parametrize("window", [None, 200, 513], ids=["causal", "w200", "w513"])
@@ -568,8 +581,8 @@ PAIRS = {
 
 @pytest.mark.parametrize("case", PAIRS)
 def test_a_row_alone_walks_its_live_pairs(case, monkeypatch):
-    """A row of 2,048 alone in its call through the repo's own forward,
-    dq and dkv kernels over the row's lists of pairs, the grid as long
+    """A row of 2,048 alone in its call through the repo's own forward
+    and backward kernels over the row's lists of pairs, the grid as long
     as the row's live pairs: the output at real positions is the static
     fused-backward kernel's to the bit, and the output and the q, k, v
     gradients are the einsum reference's to 2e-5 in float32; finite
@@ -608,6 +621,114 @@ def test_a_row_alone_walks_its_live_pairs(case, monkeypatch):
     monkeypatch.setattr(A, "_rows_skip", lambda rows, t_run: False)
     static, _ = run(splash)
     np.testing.assert_array_equal(got[real], static[real])
+
+
+# lens, (bq, bkv, bkvc), window, (hq, hkv, head size of q and k, of v), a
+# mask operand, the fewest grid steps between two visits of one q block's
+# sum (0: no q block has two) and the most steps running at one q block
+BACKWARD = {
+    "group_8_at_128": ([400, 300, 200], (128, 256, 128), None, (8, 1, 128, 128), False, 16, 1),
+    "group_1_at_192_128": ([400, 300, 200], (128, 256, 128), None, (2, 2, 192, 128), False, 2, 1),
+    "window": ([900], (128, 256, 128), 300, (4, 2, 32, 32), False, 4, 1),
+    "mask_operand": ([400, 300, 200], (128, 256, 128), None, (4, 2, 32, 32), True, 4, 1),
+    # kv block 0 ends at q block 1, where kv block 1 begins; and kv block
+    # 1 (of 256) ends at q block 4, where kv block 2 begins
+    "revisit_one_step_later": ([200, 400, 300], (128, 128, 128), None, (1, 1, 32, 32), False, 1, 2),
+    "revisit_one_step_later_at_256": ([640], (128, 256, 128), None, (2, 2, 32, 32), False, 1, 2),
+    # the same under a group of 2: the two visits are not two steps running
+    "revisit_one_pair_later_in_a_group": ([640], (128, 256, 128), None, (2, 1, 32, 32), False, 2, 2),
+    # (kv 3, q 2), (kv 4, q 2), (kv 5, q 2): kv block 4 has one pair
+    "revisit_twice_running": ([384, 316, 324], (256, 128, 128), None, (1, 1, 32, 32), False, 1, 3),
+    # (kv 0, q 2), (kv 0, q 3), (kv 1, q 2): a sequence that ends with a kv block
+    "revisit_two_steps_later": ([512, 512], (128, 256, 128), None, (1, 1, 32, 32), False, 2, 1),
+    # all padding: a q block's diagonal pair and no other, a column one pair
+    "a_single_pair": ([], (128, 128, 128), None, (1, 1, 32, 32), False, 0, 1),
+}
+
+
+@pytest.mark.parametrize("case", BACKWARD)
+def test_the_one_backward_kernel_sums_dq_where_it_lives(case):
+    """`splash_pairs_bwd` alone over the kv-major list (interpret mode):
+    dq, whose block's float32 sum lives in HBM between the block's
+    visits and is read, added to and written back a step (or, where two
+    visits are two steps running, kept in VMEM for the second), dk and
+    dv against the einsum reference's to the tolerance of
+    `test_a_row_alone_walks_its_live_pairs`; over a group of 8 at heads
+    of 128, a group of 1 at 192 / 128, a window, a mask operand, and the
+    layouts that bring a q block's visits closest."""
+    from areal_tpu.ops import attention as A
+    from areal_tpu.ops.pallas.splash_pairs import (
+        NEW, Blocks, pair_attention, pair_attention_chosen, transpose_mask,
+    )
+
+    lens, blocks, window, (hq, hkv, hd, hd_v), masked, gap, running = BACKWARD[case]
+    t, blocks = 1024, Blocks(*blocks)
+    seg = jnp.asarray(_row(t, lens))
+    pos = jnp.arange(t)
+    lists = A._pair_lists(seg, blocks.bq, blocks.bkv, window)
+    n, walk = int(lists.n), np.asarray(lists.kv_major.q)
+    visits = [np.flatnonzero(walk[:n] == b) for b in range(t // blocks.bq)]
+    assert all(len(v) for v in visits)  # every block of dq is written
+    gaps = [int(np.diff(v).min()) * (hq // hkv) for v in visits if len(v) > 1]
+    assert min(gaps, default=0) == gap
+    edges = np.flatnonzero(np.diff(walk[:n])) + 1
+    assert np.diff(np.r_[0, edges, n]).max() == running
+    firsts = np.flatnonzero(np.asarray(lists.kv_major.flags)[:n] & NEW)
+    assert sorted(firsts) == sorted(v[0] for v in visits)
+
+    rng = np.random.RandomState(3)
+    q, k, v = (jnp.asarray(rng.randn(t, h, d).astype(np.float32))
+               for h, d in ((hq, hd), (hkv, hd), (hkv, hd_v)))
+    real = np.asarray(seg) > 0
+    dout = jnp.asarray(rng.randn(t, hq, hd_v).astype(np.float32) * real[:, None, None])
+    chosen = mask = None
+    if masked:  # a random choice that always keeps a query's own place
+        chosen = A.segment_causal_mask(seg, seg, pos, pos) & jnp.asarray(
+            (rng.rand(t, t) < 0.3) | np.eye(t, dtype=bool))
+        mask = chosen.astype(jnp.int8).reshape(t, t // blocks.bkvc, blocks.bkvc).transpose(1, 0, 2)
+
+    def kernels(q, k, v):
+        heads_first = ((q * hd ** -0.5).transpose(1, 0, 2), k.transpose(1, 0, 2),
+                       v.transpose(1, 0, 2), seg, lists)
+        if masked:
+            out, _ = pair_attention_chosen(
+                *heads_first, mask, transpose_mask(mask, blocks.bq), blocks, "x", True)
+        else:
+            out = pair_attention(*heads_first, blocks, window, "x", True)
+        return out.transpose(1, 0, 2)
+
+    def plain(q, k, v):
+        return A.reference_packed_attention(q, k, v, seg, pos, window=window, chosen=chosen)
+
+    loss = lambda fn: lambda *a: jnp.sum(fn(*a) * dout)
+    got = jax.grad(loss(kernels), (0, 1, 2))(q, k, v)
+    want = jax.grad(loss(plain), (0, 1, 2))(q, k, v)
+    for a, b, name in zip(got, want, "qkv"):
+        assert np.isfinite(np.asarray(a)).all(), name
+        np.testing.assert_allclose(a, b, atol=2e-5, rtol=2e-5, err_msg=name)
+
+
+@pytest.mark.parametrize("window", [None, 200], ids=["causal", "w200"])
+@pytest.mark.parametrize("bq,bkv", [(128, 128), (128, 256), (256, 128)])
+@pytest.mark.parametrize("layout", LAYOUTS)
+def test_a_minor_blocks_first_visit_is_flagged_once(layout, bq, bkv, window):
+    """`_pair_list`'s NEW marks, of the steps up to `n`, just one a minor
+    block that has a pair, and that step is the block's first in the
+    walk: in the kv-major list the step of the backward kernel that
+    writes a q block's dq and does not read it; every q block has one."""
+    from areal_tpu.ops import attention as A
+    from areal_tpu.ops.pallas.splash_pairs import NEW
+
+    seg = LAYOUTS[layout]
+    lists = A._pair_lists(jnp.asarray(seg), bq, bkv, window)
+    n = int(lists.n)
+    for lst, minor, blocks in ((lists.kv_major, lists.kv_major.q, len(seg) // bq),
+                               (lists.q_major, lists.q_major.kv, len(seg) // bkv)):
+        minor, new = np.asarray(minor)[:n], (np.asarray(lst.flags)[:n] & NEW) != 0
+        for b in range(blocks):
+            at = np.flatnonzero(minor == b)
+            assert new[at].sum() == (len(at) > 0) and (not len(at) or new[at[0]])
+        assert lst is lists.q_major or new.sum() == blocks
 
 
 def test_a_shard_with_one_long_row_walks_its_live_pairs(monkeypatch):

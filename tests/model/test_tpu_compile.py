@@ -40,16 +40,60 @@ def _shape(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
+def _no_partial_of_dq(text, hq, t, hd):
+    """No array of the compiled program `text` has an axis (of pairs, of
+    kv blocks) in front of dq's `[heads, t, hd]`, as the backward kernel
+    sums it (`[heads, hd, t]`) or as the model takes it: the sum of a q
+    block lives in one place."""
+    import math
+    import re
+
+    for dims in set(re.findall(r"\b(?:bf16|f32)\[([\d,]+)\]", text)):
+        dims = [int(d) for d in dims.split(",")]
+        if tuple(dims[-3:]) in ((hq, t, hd), (hq, hd, t)):
+            assert math.prod(dims[:-3]) == 1, f"a partial of dq: {dims}"
+
+
+def _accumulate_step(one_chip, monkeypatch, config, t):
+    """(the configuration, the compiled forward-backward micro-batch of
+    its model at one row of `t`: full remat, the masked loss head, the
+    stretches over the live bands, as the engine's accumulate step runs
+    it)."""
+    import json
+
+    from areal_tpu.models.transformer import forward, init_params
+    from areal_tpu.ops.loss import fused_next_token_logprobs
+    from benchmark.model import transformer_config
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernels, not interpret mode
+    with open(f"benchmark/configs/{config}.json") as f:
+        hf = {k: v for k, v in json.load(f).items() if k != "benchmark"}
+    cfg = transformer_config(hf, "bfloat16")
+    params = jax.tree_util.tree_map(
+        lambda a: _shape(a.shape, a.dtype, one_chip),
+        jax.eval_shape(lambda k: init_params(cfg, k), jax.random.PRNGKey(0)))
+    ids = _shape((1, t), jnp.int32, one_chip)
+
+    def loss(p, input_ids, seg, pos):
+        hidden, _ = forward(p, cfg, input_ids, seg, pos, attn_impl="splash", remat=True,
+                            output="hidden", return_aux=True, bands=True)
+        head = p["head"]["weight"] if "head" in p else p["embedding"]["weight"].T
+        return fused_next_token_logprobs(hidden, head, input_ids, seg, scored=seg > 0).sum()
+
+    return cfg, jax.jit(jax.value_and_grad(loss)).lower(params, ids, ids, ids).compile()
+
+
 @pytest.mark.parametrize("rows", [1, 3, "vmap"], ids=["row", "rows", "rows_vmap"])
 @pytest.mark.parametrize("window", [2048, None], ids=["window", "full"])
 def test_splash_row_of_16k_at_32_over_4_heads_compiles_forward_and_backward(one_chip, window, rows):
     """The segment ids are arguments, so the list of block pairs a long
     row alone walks (`ops/attention._pair_lists`) and its length are
     values of the run: scalar-prefetch operands that are traced, and a
-    grid dimension that is dynamic, in the repo's own forward, dq and
-    dkv kernels (`ops/pallas/splash_pairs.py`): three custom calls, no
+    grid dimension that is dynamic, in the repo's own forward and
+    backward kernels (`ops/pallas/splash_pairs.py`): two custom calls, no
     branch over widths, no `[kv blocks, heads, t, hd]` partials of dq
-    (537 MB here) and no sum over them. Three rows in one call keep
+    (537 MB here) and no sum over them: dq is one float32 `[heads, hd,
+    t]` that the backward kernel sums in place. Three rows in one call keep
     splash's static kernels and the fused backward; a caller's `vmap`
     over rows each given alone is a list a row: pallas's own loop over
     the kernel calls."""
@@ -73,9 +117,10 @@ def test_splash_row_of_16k_at_32_over_4_heads_compiles_forward_and_backward(one_
 
     compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, kv, kv, ids, ids).compile()
     text = compiled.as_text()
-    # the forward and the fused backward kernel, or forward, dq and dkv
-    assert text.count("tpu_custom_call") == (2 if rows == 3 else 3)
-    assert ("splash_pairs_dq" in text) == (rows != 3) == ("splash_mqa" not in text)
+    # the forward and the fused backward kernel, or the forward and the
+    # backward over the row's lists
+    assert text.count("tpu_custom_call") == 2 and "splash_pairs_dq" not in text
+    assert ("splash_pairs_bwd" in text) == (rows != 3) == ("splash_mqa" not in text)
     assert (" while(" in text) == (rows == "vmap")
     assert " conditional(" not in text  # no widths: the grid is as long as the list
     if rows == 1:
@@ -103,8 +148,9 @@ def test_pair_kernels_compile_at_a_row_of_16k(one_chip, hq, hkv, hd, hd_v, windo
         return out.astype(jnp.float32).sum()
 
     text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, k, v, ids, ids).compile().as_text()
-    assert text.count("tpu_custom_call") == 3 and " conditional(" not in text
-    assert all(f"splash_pairs_{name}" in text for name in ("fwd", "dq", "dkv"))
+    assert text.count("tpu_custom_call") == 2 and " conditional(" not in text
+    assert all(f"splash_pairs_{name}" in text for name in ("fwd", "bwd"))
+    assert not any(f"splash_pairs_{name}" in text for name in ("dq", "dkv"))
 
 
 def test_the_indexers_kernels_compile_at_a_row_of_16k(one_chip):
@@ -128,11 +174,10 @@ def test_the_indexers_kernels_compile_at_a_row_of_16k(one_chip):
         return out.astype(jnp.float32).sum() + sums["index_kl"]
 
     text = jax.jit(jax.grad(loss, tuple(range(6)))).lower(*args, ids, ids).compile().as_text()
-    assert text.count("tpu_custom_call") == 5 and " conditional(" not in text
-    for name in ("index_select", "splash_pairs_fwd", "splash_pairs_dq", "splash_pairs_dkv",
-                 "index_kl_bwd"):
+    assert text.count("tpu_custom_call") == 4 and " conditional(" not in text
+    for name in ("index_select", "splash_pairs_fwd", "splash_pairs_bwd", "index_kl_bwd"):
         assert name in text, name
-    assert "index_kl_fwd" not in text
+    assert "index_kl_fwd" not in text and "splash_pairs_dq" not in text
     text = jax.jit(loss).lower(*args, ids, ids).compile().as_text()
     assert text.count("tpu_custom_call") == 3 and "index_kl_fwd" in text
 
@@ -140,11 +185,14 @@ def test_the_indexers_kernels_compile_at_a_row_of_16k(one_chip):
 def test_a_train_step_of_one_row_holds_no_partial_of_dq(one_chip, monkeypatch):
     """A forward-backward micro-batch of `q15d12-train-ppo`'s model (12
     layers, 12 / 2 heads of 128, full remat, the masked loss head) at
-    one row of 8,192, as the engine's accumulate step runs it: four
-    kernels (forward, remat's forward, dq and dkv over the row's list
-    of pairs: twelve before PR 41, three widths each), no branch over
-    widths, and nowhere the fused backward's `[2, 8, 6, 8192, 128]`
-    partials of dq nor the sum over them that followed the kernel."""
+    one row of 8,192, as the engine's accumulate step runs it: three
+    kernels (forward, remat's forward and the one backward over the
+    row's list of pairs: twelve before PR 41, three widths each; four
+    before PR 51, dq in a kernel of its own), no branch over widths, and
+    nowhere the fused backward's `[2, 8, 6, 8192, 128]` partials of dq,
+    nor any array with an axis of pairs or kv blocks in front of dq's
+    `[heads, hd, t]` (`_no_partial_of_dq`), nor the sum over them that
+    followed the kernel."""
     import json
     import re
 
@@ -168,9 +216,11 @@ def test_a_train_step_of_one_row_holds_no_partial_of_dq(one_chip, monkeypatch):
                                          scored=seg > 0).sum()
 
     text = jax.jit(jax.value_and_grad(loss)).lower(params, ids, ids, ids).compile().as_text()
-    assert text.count("tpu_custom_call") == 4 and "splash_pairs_dq" in text
+    assert text.count("tpu_custom_call") == 3 and "splash_pairs_bwd" in text
+    assert "splash_pairs_dq" not in text and "splash_pairs_dkv" not in text
     assert "splash_mqa" not in text and " conditional(" not in text
     assert "bf16[2,8,6,8192,128]" not in text
+    _no_partial_of_dq(text, cfg.n_q_heads, 8192, cfg.head_dim)
     assert not re.search(r"attn_kernel/[^\n\"]*_splash_attention[^\n\"]*/reduce_sum", text)
 
 
@@ -377,14 +427,18 @@ def test_splash_takes_q_and_k_at_64_against_v_at_128(one_chip, window):
         return out.astype(jnp.float32).sum()
 
     text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(q, k, v, ids, ids).compile().as_text()
-    assert text.count("tpu_custom_call") == 3 and "splash_pairs_dq" in text
+    assert text.count("tpu_custom_call") == 2 and "splash_pairs_bwd" in text
 
 
 def test_splash_takes_q_and_k_at_192_against_v_at_128_and_a_group_of_one(one_chip):
     """Latent attention's call (PR 40): 32 q and 32 kv heads, q and k of
     128 + 64 = 192 (no multiple of the 128 lanes) against v of 128, a row
-    of 8,192 alone in its call. Mosaic takes 192 as it is: nothing is
-    padded to 256, and the backward is the dq and dkv kernels."""
+    of 8,192 alone in its call. Mosaic takes 192 as it is: no operand is
+    padded to 256 (dq's float32 sums are `[heads, 192, t]`, the 192 along
+    sublanes; only the blocks of dq the kernel copies out itself are 256
+    wide, sliced after it), and the backward is one kernel."""
+    import re
+
     from areal_tpu.ops.attention import splash_packed_attention
 
     t = 8192
@@ -398,8 +452,9 @@ def test_splash_takes_q_and_k_at_192_against_v_at_128_and_a_group_of_one(one_chi
         return out.astype(jnp.float32).sum()
 
     text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(qk, qk, v, ids, ids).compile().as_text()
-    assert text.count("tpu_custom_call") == 3 and "splash_pairs_dq" in text
-    assert "bf16[32,8192,256]" not in text  # no padded copy of q
+    assert text.count("tpu_custom_call") == 2 and "splash_pairs_bwd" in text
+    assert not re.search(r"= bf16\[32,8192,256\]\S* pad\(", text)  # no padded copy of q
+    assert "f32[32,192,8192]" in text and "f32[32,8192,256]" not in text
 
 
 @pytest.mark.parametrize("use", ["read", "write"])
@@ -453,32 +508,13 @@ def test_an_accumulate_step_of_the_four_stream_stack_compiles_at_8k(one_chip, mo
     beside 10.63 GB of weights, gradient sums and moments (more than the
     allocator's 15.75 by this count, which the chip runs all the same:
     PERF.md section 7, S4)."""
-    import json
+    from areal_tpu.models.transformer import looping_layers
 
-    from areal_tpu.models.transformer import forward, init_params, looping_layers
-    from areal_tpu.ops.loss import fused_next_token_logprobs
-    from benchmark.model import transformer_config
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernels, not interpret mode
-    with open("benchmark/configs/xing4.0-d5-e8.json") as f:
-        hf = {k: v for k, v in json.load(f).items() if k != "benchmark"}
-    cfg = transformer_config(hf, "bfloat16")
+    cfg, compiled = _accumulate_step(one_chip, monkeypatch, "xing4.0-d5-e8", 8192)
     assert looping_layers(cfg, 1, 8192) == 4  # the leading dense layer, alone among streams, does not
-    params = jax.tree_util.tree_map(
-        lambda a: _shape(a.shape, a.dtype, one_chip),
-        jax.eval_shape(lambda k: init_params(cfg, k), jax.random.PRNGKey(0)))
-    ids = _shape((1, 8192), jnp.int32, one_chip)
-
-    def loss(p, input_ids, seg, pos):
-        hidden, _ = forward(p, cfg, input_ids, seg, pos, attn_impl="splash", remat=True,
-                            output="hidden", return_aux=True, bands=True)
-        return fused_next_token_logprobs(hidden, p["head"]["weight"], input_ids, seg,
-                                         scored=seg > 0).sum()
-
-    compiled = jax.jit(jax.value_and_grad(loss)).lower(params, ids, ids, ids).compile()
     text = compiled.as_text()
     for name in ("mhc_mix", "mhc_coef_grad", "mhc_sinkhorn", "mhc_sinkhorn_bwd",
-                 "splash_pairs_dq", "moe_rows_add"):
+                 "splash_pairs_bwd", "moe_rows_add"):
         assert name in text, name
     assert compiled.memory_analysis().temp_size_in_bytes < 7.0e9
 
@@ -511,6 +547,37 @@ def test_the_delta_rules_kernels_compile_at_the_published_widths(one_chip):
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
+# The step's temporaries at PR 50 (two kernels in the backward, dq bf16
+# `[heads, T, hd]` out of scratch), bytes, by this file's own compile:
+# the two cells with the least room beside their state.
+_TEMPORARIES_WITH_TWO_BACKWARD_KERNELS = {
+    "phi-4-mini-flash-d8": 2_161_756_672, "kimi-linear-d5-e8": 5_229_632_512}
+
+
+def _holds_dq_once(cfg, compiled, config, t):
+    """The one backward kernel's float32 sums of dq `[hq, hd, t]` are
+    there, no array has an axis in front of them or of dq, and the step's
+    temporaries are the parent's or less, or more by no more than that
+    one array."""
+    hq, hd = cfg.n_q_heads, cfg.head_dim
+    text = compiled.as_text()
+    assert "splash_pairs_bwd" in text and "splash_pairs_dq" not in text
+    assert f"f32[{hq},{hd},{t}]" in text
+    _no_partial_of_dq(text, hq, t, hd)
+    assert compiled.memory_analysis().temp_size_in_bytes <= (
+        _TEMPORARIES_WITH_TWO_BACKWARD_KERNELS[config] + 4 * hq * hd * t)
+
+
+def test_an_accumulate_step_of_the_sambay_stack_holds_dq_once(one_chip, monkeypatch):
+    """`phi4flash-d8-train-ppo-8k`'s forward-backward micro-batch at its
+    one shape `(1, 8192)` (2.16 GB of temporaries beside 11.9 GB of
+    state: the cell with the least room): differential attention's one
+    call is 40 q heads of 64 over the rearranged heads, its backward one
+    kernel that sums dq in one float32 `[40, 64, 8192]`."""
+    cfg, compiled = _accumulate_step(one_chip, monkeypatch, "phi-4-mini-flash-d8", 8192)
+    _holds_dq_once(cfg, compiled, "phi-4-mini-flash-d8", 8192)
+
+
 def test_an_accumulate_step_of_the_delta_rule_stack_compiles_at_16k(one_chip, monkeypatch):
     """A forward-backward micro-batch of `kimilinear-d5e8-train-ppo-long`'s
     model at its one shape `(1, 16384)`, full remat, the masked loss head:
@@ -521,30 +588,12 @@ def test_an_accumulate_step_of_the_delta_rule_stack_compiles_at_16k(one_chip, mo
     6.1 GB beside 8.43 GB of weights, gradient sums and moments (10.7 GB
     with the rule's parts and decays held a row at a time and the
     stretches over the whole row: PERF.md section 6, PR 50)."""
-    import json
+    from areal_tpu.models.transformer import looping_layers
 
-    from areal_tpu.models.transformer import forward, init_params, looping_layers
-    from areal_tpu.ops.loss import fused_next_token_logprobs
-    from benchmark.model import transformer_config
-
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")  # the kernels, not interpret mode
-    with open("benchmark/configs/kimi-linear-d5-e8.json") as f:
-        hf = {k: v for k, v in json.load(f).items() if k != "benchmark"}
-    cfg = transformer_config(hf, "bfloat16")
+    cfg, compiled = _accumulate_step(one_chip, monkeypatch, "kimi-linear-d5-e8", 16384)
     assert looping_layers(cfg, 1, 16384) == 5
-    params = jax.tree_util.tree_map(
-        lambda a: _shape(a.shape, a.dtype, one_chip),
-        jax.eval_shape(lambda k: init_params(cfg, k), jax.random.PRNGKey(0)))
-    ids = _shape((1, 16384), jnp.int32, one_chip)
-
-    def loss(p, input_ids, seg, pos):
-        hidden, _ = forward(p, cfg, input_ids, seg, pos, attn_impl="splash", remat=True,
-                            output="hidden", return_aux=True, bands=True)
-        return fused_next_token_logprobs(hidden, p["head"]["weight"], input_ids, seg,
-                                         scored=seg > 0).sum()
-
-    compiled = jax.jit(jax.value_and_grad(loss)).lower(params, ids, ids, ids).compile()
     text = compiled.as_text()
-    for name in ("kda_fwd_states", "kda_bwd_states", "splash_pairs_dq", "moe_rows_add"):
+    for name in ("kda_fwd_states", "kda_bwd_states", "splash_pairs_bwd", "moe_rows_add"):
         assert name in text, name
     assert compiled.memory_analysis().temp_size_in_bytes < 6.5e9
+    _holds_dq_once(cfg, compiled, "kimi-linear-d5-e8", 16384)
