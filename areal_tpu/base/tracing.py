@@ -35,8 +35,12 @@ Design constraints:
 - A span never synchronises with the device: it reads the host clock
   twice and nothing else. Device time comes from the profiler.
 - Thread-safe: spans are appended to a bounded ring buffer under a lock
-  and flushed to the shard in batches (overflow drops the OLDEST spans
-  and counts them — tracing must never block or OOM the hot path).
+  (overflow drops the OLDEST spans and counts them — tracing must never
+  block or OOM the hot path). The thread that appends never writes: a
+  writer thread of the recorder's own takes the buffer at 512 spans and
+  at least once a second, so a process that leaves by `os._exit` loses
+  its last second and no more; `flush()` and `stop()` return with
+  everything on disk.
 - Clock model: span timestamps are `time.monotonic_ns()` (immune to NTP
   steps within a process); the shard header carries one
   (wall_ns, monotonic_ns) anchor pair so the merger maps every shard
@@ -79,7 +83,9 @@ _ENV_ENABLE = "AREAL_RL_TRACE"
 _ENV_DIR = "AREAL_RL_TRACE_DIR"
 _ENV_RING = "AREAL_RL_TRACE_RING"
 _DEFAULT_DIR = "/tmp/areal_tpu/rl_trace"
+# The shard's writer takes the buffer at this many spans, and at least this often.
 _FLUSH_EVERY = 512
+_WRITE_EVERY_S = 1.0
 
 # Cached enablement: None = not yet read from the environment. The hot
 # path pays exactly one branch once this is a bool.
@@ -105,6 +111,12 @@ _CTL_LOCK = threading.Lock()
 _SESSION: Optional[Dict[str, Any]] = None
 _MIRROR: Optional[type] = None
 _MIRROR_PREFIX = "areal/"
+
+# `drained()`'s pending mark: (monotonic_ns, the blocking span's name) from
+# the moment this process last emptied the device until `fed()` ends the
+# stretch as a `device.starved` span. One for the process: the device is.
+_DRAINED: Optional[tuple] = None
+_DRAINED_LOCK = threading.Lock()
 
 _CTX_KEY = "__rl_trace__"
 
@@ -167,7 +179,7 @@ def reconfigure() -> None:
     stop()
     with _REC_LOCK:
         if _REC is not None:
-            _REC.flush()
+            _REC.close()
             # Drop the exit hook with the recorder: repeated reconfigure
             # cycles (tests) must not accumulate callbacks that try to
             # flush into deleted tmp dirs at interpreter exit.
@@ -185,11 +197,15 @@ def _new_id() -> str:
 
 
 class _Recorder:
-    """Bounded ring buffer of span dicts + batched JSONL shard writer,
-    the counters, and between `start()` and `stop()` the session: the
-    same spans kept in memory (bounded like the ring) for `stop()` to
-    return. Without a shard (`to_file` false: started at run time with
-    no AREAL_RL_TRACE_DIR) the session is all there is."""
+    """Bounded ring buffer of span dicts + the JSONL shard's writer, the
+    counters, and between `start()` and `stop()` the session: the same
+    spans kept in memory (bounded like the ring) for `stop()` to return.
+    Without a shard (`to_file` false: started at run time with no
+    AREAL_RL_TRACE_DIR) the session is all there is, and no thread.
+
+    With a shard, `append` only appends: a daemon thread takes the buffer
+    when it holds `_FLUSH_EVERY` spans and at least every `_WRITE_EVERY_S`
+    seconds, and serialises and writes it outside the buffer's lock."""
 
     def __init__(self, worker: str, to_file: bool = True):
         self.worker = worker
@@ -203,12 +219,22 @@ class _Recorder:
         self.anchor_wall_ns = time.time_ns()
         self.anchor_mono_ns = time.monotonic_ns()
         self.path: Optional[str] = None
+        self._header_written = False
+        # The writer's alone: whoever writes the shard (the writer thread,
+        # a caller of `flush()`) holds it from the buffer's swap to the
+        # write's end, so batches reach the file whole and in order.
+        self._write_lock = threading.Lock()
+        self._wake = threading.Event()
+        self._closed = False
+        self._writer: Optional[threading.Thread] = None
         if to_file:
             d = trace_dir()
             os.makedirs(d, exist_ok=True)
             safe = worker.replace("/", "_").replace(os.sep, "_")
             self.path = os.path.join(d, f"{safe}.{os.getpid()}.jsonl")
-        self._header_written = False
+            self._writer = threading.Thread(
+                target=self._write_loop, name="rl-trace-writer", daemon=True)
+            self._writer.start()
 
     def begin_session(self) -> None:
         with self._lock:
@@ -226,7 +252,6 @@ class _Recorder:
             self.counters[name] = self.counters.get(name, 0) + n
 
     def append(self, rec: Dict) -> None:
-        flush_now = False
         with self._lock:
             if self._session is not None:
                 if len(self._session) >= self.capacity:
@@ -243,20 +268,32 @@ class _Recorder:
                 del self._buf[:drop]
                 self.n_dropped += drop
             self._buf.append(rec)
-            flush_now = len(self._buf) >= _FLUSH_EVERY
-        if flush_now:
+            wake = len(self._buf) % _FLUSH_EVERY == 0
+        if wake:
+            self._wake.set()
+
+    def _write_loop(self) -> None:
+        while not self._closed:
+            self._wake.wait(_WRITE_EVERY_S)
+            self._wake.clear()
             self.flush()
 
+    def close(self) -> None:
+        """Write what is held and end the writer thread."""
+        self._closed = True
+        self._wake.set()
+        if self._writer is not None:
+            self._writer.join(timeout=5.0)
+        self.flush()
+
     def flush(self) -> None:
-        # The file write stays under the lock: concurrent flushes from
-        # two threads (engine loop + HTTP loop) would otherwise
-        # interleave >8KB TextIOWrapper chunks mid-line and corrupt the
-        # JSONL shard. Flushes are rare (every 512 spans), so briefly
-        # blocking a concurrent append is the cheaper correctness.
+        """Everything appended so far is on disk when this returns."""
         if self.path is None:
             return
-        with self._lock:
-            batch, self._buf = self._buf, []
+        with self._write_lock:
+            with self._lock:
+                batch, self._buf = self._buf, []
+                dropped, self.n_dropped = self.n_dropped, 0
             header = None
             if not self._header_written:
                 header = {
@@ -266,8 +303,6 @@ class _Recorder:
                     "anchor_wall_ns": self.anchor_wall_ns,
                     "anchor_mono_ns": self.anchor_mono_ns,
                 }
-                self._header_written = True
-            dropped, self.n_dropped = self.n_dropped, 0
             if header is None and not batch and not dropped:
                 return
             lines = []
@@ -285,16 +320,19 @@ class _Recorder:
                     json.dumps(rec, separators=(",", ":"), default=str)
                 )
             try:
-                with open(self.path, "a") as f:
-                    f.write("\n".join(lines) + "\n")
+                self._write_lines(lines)
+                self._header_written = True
             except OSError:
                 # Tracing must never take down the hot path: a full or
-                # vanished /tmp loses this batch (counted as dropped);
-                # if the header was in it, rewrite it with the next
-                # successful flush so the shard stays parseable.
-                self.n_dropped += len(batch)
-                if header is not None:
-                    self._header_written = False
+                # vanished /tmp loses this batch (counted as dropped); a
+                # header that was in it goes out with the next batch that
+                # is written, so the shard stays parseable.
+                with self._lock:
+                    self.n_dropped += dropped + len(batch)
+
+    def _write_lines(self, lines: List[str]) -> None:
+        with open(self.path, "a") as f:
+            f.write("\n".join(lines) + "\n")
 
 
 def _rec() -> _Recorder:
@@ -333,11 +371,12 @@ def start(profile_dir: Optional[str] = None) -> bool:
     Idempotent: a second `start()` before `stop()` changes nothing and
     returns False; True says this call started the session (and is the
     one that should stop it)."""
-    global _ENABLED, _SESSION, _MIRROR
+    global _ENABLED, _SESSION, _MIRROR, _DRAINED
     with _CTL_LOCK:
         if _SESSION is not None:
             return False
         _ENABLED = True
+        _DRAINED = None  # a stretch that began before the session is not its
         session: Dict[str, Any] = {"profile_dir": None, "clock_anchor": None}
         try:
             _rec().begin_session()
@@ -368,7 +407,8 @@ def start(profile_dir: Optional[str] = None) -> bool:
 def stop() -> Dict[str, Any]:
     """End the session `start()` began: recording goes back to what the
     environment says, the profiler stops if this control started it, and
-    what was recorded since `start()` comes back from memory:
+    a pending `drained()` mark is dropped (the stretch would end in no
+    session), and what was recorded since `start()` comes back from memory:
     `{"spans": [...], "counters": {...}, "dropped": n, "profile_dir": ...,
     "clock_anchor": {"name", "monotonic_ns"} | None, "builds": [...],
     "builds_dropped": n}`. The JSONL shard, where there is one, is
@@ -376,8 +416,9 @@ def stop() -> Dict[str, Any]:
     process so far, those from before `start()` too (set-up's; a reader
     tells them from the session's by the clock). Without a session: the
     same dict, empty but for the builds."""
-    global _ENABLED, _SESSION, _MIRROR
+    global _ENABLED, _SESSION, _MIRROR, _DRAINED
     with _CTL_LOCK:
+        _DRAINED = None
         if _SESSION is None:
             return {"spans": [], "counters": {}, "dropped": 0,
                     "profile_dir": None, "clock_anchor": None,
@@ -655,6 +696,45 @@ def event(name: str, ctx: Optional[SpanContext] = None, **attrs: Any) -> None:
         return
     t = time.monotonic_ns()
     record_span(name, t, t, ctx=ctx, **attrs)
+
+
+def drained(after: str) -> None:
+    """The caller has just returned from a blocking read of the newest
+    thing it enqueued, so nothing of its own is on the device: note the
+    clock and `after`, the name of the span that blocked
+    (`train.fetch_stats`, `ppo.prep`, `fwd.fetch`). A second call before
+    a `fed()` keeps the first mark: the device has been empty since then.
+    """
+    global _DRAINED
+    if not enabled():
+        return
+    if _DRAINED is None:
+        _DRAINED = (time.monotonic_ns(), after)
+
+
+def fed(program: str) -> None:
+    """An enqueue has just returned. If a `drained()` mark is pending,
+    record the span `device.starved` from the mark to now under the
+    current context (so it lands in the trace of the step that fed the
+    device), attrs `after` (the mark's) and `until` = `program`, the
+    engine's name for what was enqueued (`build_site`'s names), and clear
+    the mark. Without a mark: nothing, after two branches.
+
+    What the span is: the stretch in which this process had nothing
+    queued on the device, as the host saw it. It starts a device-to-host
+    copy and a thread's wake-up after the device truly ran dry, and ends
+    at most one enqueue after it truly started again; neither call
+    touches the device. A pause of the machine inside the blocking read
+    is the device's idle time and no part of the span (nothing told the
+    host the device was dry); an enqueue that returns late is the span's
+    and not the device's."""
+    global _DRAINED
+    if not enabled() or _DRAINED is None:
+        return
+    with _DRAINED_LOCK:
+        mark, _DRAINED = _DRAINED, None
+    if mark is not None:
+        record_span("device.starved", mark[0], after=mark[1], until=program)
 
 
 # ---------------------------------------------------------------------------

@@ -426,15 +426,6 @@ class JaxTrainEngine(TrainEngine):
             return p["embedding"]["weight"].T
         return p["head"]["weight"]
 
-    def _built(self, key, programs):
-        """A new entry of the jit cache, counted where it is made
-        (`train.programs_built`: jitted functions; the programs jax
-        builds from them, one a micro-batch shape, are `tracing.builds`
-        and the counter `jit.programs_compiled`)."""
-        self._jit_cache[key] = programs
-        tracing.count("train.programs_built")
-        return programs
-
     def _mb_loss_fn(self, loss_fn: PackedLossFn, scored_fn: Optional[ScoredFn]):
         """loss over one micro-batch's rows: (params, rows) -> (loss_sum, aux).
 
@@ -666,7 +657,7 @@ class JaxTrainEngine(TrainEngine):
             )
             return params, opt_state, packed, aux
 
-        return self._built(key, jax.jit(step, donate_argnums=(0, 1)))
+        return self._jit_cache.setdefault(key, jax.jit(step, donate_argnums=(0, 1)))
 
     def _accum_step_fn(self, loss_name: str, loss_fn: PackedLossFn,
                        row_keys: Tuple[str, ...],
@@ -733,7 +724,7 @@ class JaxTrainEngine(TrainEngine):
             g_acc = jax.lax.with_sharding_constraint(g_acc, self._param_shardings)
             return g_acc, stats
 
-        return self._built(key, jax.jit(mb_accum, donate_argnums=(1,)))
+        return self._jit_cache.setdefault(key, jax.jit(mb_accum, donate_argnums=(1,)))
 
     def _accum_sum_fns(self):
         """Two programs beside `_accum_step_fn`'s that see no row and no
@@ -756,7 +747,7 @@ class JaxTrainEngine(TrainEngine):
             with jax.named_scope("grad_accum"):
                 return jax.tree_util.tree_map(operator.add, stats, mb_stats)
 
-        return self._built(key, (
+        return self._jit_cache.setdefault(key, (
             jax.jit(zero_sums, out_shardings=self._param_shardings),
             jax.jit(sum_add),
         ))
@@ -789,7 +780,7 @@ class JaxTrainEngine(TrainEngine):
 
         # the sums are not donated: the engine hands their buffers to the
         # next minibatch (`_train_batch_overlapped`)
-        return self._built(key, jax.jit(apply, donate_argnums=(0, 1)))
+        return self._jit_cache.setdefault(key, jax.jit(apply, donate_argnums=(0, 1)))
 
     @staticmethod
     def _stack_mb_rows(
@@ -925,6 +916,9 @@ class JaxTrainEngine(TrainEngine):
                 f"unknown token_normalize_scope {token_normalize_scope!r}"
             )
         with tracing.span("train.batch"):
+            # The host's work before the first micro-batch is asked for: a
+            # leaf of its own, ended where the path's input begins.
+            begin = tracing.start_span("train.begin")
             lr_pos = self._lr_steps if version_steps is None else int(version_steps)
             self._lr_steps += 1
             lr = float(self._lr_schedule(lr_pos))
@@ -944,7 +938,7 @@ class JaxTrainEngine(TrainEngine):
                 if len(groups) > 1:
                     return self._train_batch_overlapped(
                         mb_iter, len(groups), loss_fn, loss_weight_fn, loss_name,
-                        lr, scored_fn,
+                        lr, scored_fn, begin,
                     )
                 # One micro-batch: nothing to pipeline against; run eagerly.
                 mbs = list(mb_iter)
@@ -952,6 +946,8 @@ class JaxTrainEngine(TrainEngine):
                 mbs, _, _ = input_.split(mb_spec)
             global_denom = float(sum(loss_weight_fn(mb) for mb in mbs))
             global_denom = max(global_denom, 1.0)
+            if begin is not None:
+                begin.end()
 
             t_prep = time.monotonic_ns()
             with tracing.span("train.pack"):
@@ -1025,6 +1021,7 @@ class JaxTrainEngine(TrainEngine):
                     self._inv_denom(global_denom, n_tok),
                     jnp.asarray(lr, jnp.float32),
                 )
+                tracing.fed("fused_step")
             if self._serial_dispatch:
                 jax.block_until_ready(self.params)
             return self._fetch_train_stats(
@@ -1040,6 +1037,7 @@ class JaxTrainEngine(TrainEngine):
         loss_name: str,
         lr: float,
         scored_fn: Optional[ScoredFn] = None,
+        begin: Optional[tracing.ManualSpan] = None,
     ) -> Dict[str, float]:
         """Pipelined gradient accumulation: a background thread FFD-packs,
         pads-to-bucket and `device_put`s micro-batch i+1 while micro-batch
@@ -1102,6 +1100,8 @@ class JaxTrainEngine(TrainEngine):
         # while tracing is on (`n_counted` of the micro-batches)
         n_counts, n_counted = None, 0  # as many as a stage counts: summed as they come
         gaps_ms: List[float] = []
+        if begin is not None:  # `train.begin`: up to the first `train.wait_input`
+            begin.end()
         mark = time.monotonic_ns()
         try:
             for rows_dev, denom, tok, cells, attn_attrs, counts in pf:
@@ -1129,6 +1129,7 @@ class JaxTrainEngine(TrainEngine):
                     g_acc, mb_stats = mb_accum(
                         self.params, g_acc, rows_dev, np.asarray(stats is None)
                     )
+                    tracing.fed("accum_step")
                 if stats is None:
                     stats = mb_stats
                 else:
@@ -1146,6 +1147,7 @@ class JaxTrainEngine(TrainEngine):
                 self._inv_denom(global_denom, n_tok),
                 jnp.asarray(lr, jnp.float32),
             )
+            tracing.fed("apply")
         self._grad_sums = g_acc  # the next minibatch's buffers
         if n_counts and n_counted == n_mbs:  # tracing was on for the whole batch
             self._count_batch("overlapped", n_mbs, n_one_row, n_tok, n_cells,
@@ -1508,6 +1510,7 @@ class JaxTrainEngine(TrainEngine):
         # Where the host waits for the device: the step's one fetch.
         with tracing.span("train.fetch_stats", stale=False):
             p = np.asarray(packed)
+        tracing.drained("train.fetch_stats")
         loss_sum, gnorm, unorm = float(p[0]), float(p[1]), float(p[2])
         aux_vals = jax.tree_util.tree_unflatten(aux_treedef, p[3:].tolist())
         stats = {
@@ -1577,7 +1580,7 @@ class JaxTrainEngine(TrainEngine):
                     )
                 return out  # [R, T] values or [R, T, V] logits
 
-            self._built(key, jax.jit(fwd))
+            self._jit_cache[key] = jax.jit(fwd)
         return self._jit_cache[key]
 
     def forward(
@@ -1628,6 +1631,7 @@ class JaxTrainEngine(TrainEngine):
                         with tracing.span("fwd.dispatch", rows=batch.n_rows,
                                           row_len=batch.row_len):
                             outs.append(fn(self.params, rows_dev))  # not fetched
+                            tracing.fed("forward")
                         batches.append(batch)
                         mb_seqlens.append(sl)
                         n_tok += batch.total_tokens
@@ -1637,6 +1641,7 @@ class JaxTrainEngine(TrainEngine):
                     pf.close()
                 with tracing.span("fwd.fetch"):
                     fetched = jax.device_get(outs)  # one blocking drain per batch
+                tracing.drained("fwd.fetch")
                 per_mb_flat = [
                     b.gather_flat(np.asarray(o, np.float32))
                     for b, o in zip(batches, fetched)
@@ -1657,8 +1662,10 @@ class JaxTrainEngine(TrainEngine):
                     with tracing.span("fwd.dispatch", rows=batch.n_rows,
                                       row_len=batch.row_len):
                         out_dev = fn(self.params, rows_dev)
+                        tracing.fed("forward")
                     with tracing.span("fwd.fetch"):
                         out_rows = np.asarray(out_dev, np.float32)
+                    tracing.drained("fwd.fetch")
                     per_mb_flat.append(batch.gather_flat(out_rows))
                     mb_seqlens.append(mb.seqlens_of())
                     n_tok += batch.total_tokens

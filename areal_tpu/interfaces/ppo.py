@@ -308,49 +308,49 @@ class PPOActorInterface(ModelInterface):
 
             # 1) Whole-batch advantage computation on device.
             with tracing.span("ppo.prep"):
-                batch, rows = engine._build_rows(input_)
-                tracing.set_attrs(rows=batch.n_rows, row_len=batch.row_len)
-                rows_dev = engine._device_rows(rows)
-                prep = self._prep_fn(engine)
-                tracing.build_site("ppo_prep", prep, batch.n_rows, batch.row_len)
-                adv_rows, ret_rows, resp_rows, kl_sum = prep(
-                    rows_dev, jnp.asarray(kl_coef, jnp.float32)
-                )
-                adv_flat = batch.gather_flat(np.asarray(adv_rows))
-                ret_flat = batch.gather_flat(np.asarray(ret_rows))
-                resp_flat = batch.gather_flat(np.asarray(resp_rows))
+                batch, (adv_rows, ret_rows, resp_rows, kl_sum) = _run_prep(
+                    engine, self._prep_fn(engine), input_, kl_coef)
+                # What is left of `ppo.prep` outside its children: the
+                # step's first wait for the device.
+                adv_rows = np.asarray(adv_rows)
+                tracing.drained("ppo.prep")
+                with tracing.span("ppo.prep.gather"):
+                    adv_flat = batch.gather_flat(adv_rows)
+                    ret_flat = batch.gather_flat(np.asarray(ret_rows))
+                    resp_flat = batch.gather_flat(np.asarray(resp_rows))
             tracing.set_attrs(
                 tokens=batch.total_tokens, sequences=len(batch.seq_lens)
             )
 
-            # 2) Optional group normalization (GRPO): per prompt-group over
-            #    response positions.
-            if self.adv_norm and self.group_adv_norm:
-                adv_flat = adv_flat.copy()
-                offset = 0
-                for sl in input_.seqlens["packed_input_ids"]:
-                    glen = sum(sl)
-                    idx = np.arange(offset, offset + glen)[resp_flat[offset : offset + glen] > 0]
-                    if idx.size > 1:
-                        vals = adv_flat[idx]
-                        adv_flat[idx] = (vals - vals.mean()) / (vals.std() + 1e-5)
-                    offset += glen
-            train_sample = input_
-            train_sample.update_(
-                SequenceSample(
-                    ids=list(input_.ids),
-                    keys={"advantages"},
-                    data={"advantages": adv_flat.astype(np.float32)},
-                    seqlens={
-                        "advantages": [list(sl) for sl in input_.seqlens["packed_input_ids"]]
-                    },
+            with tracing.span("ppo.advantages"):
+                # 2) Optional group normalization (GRPO): per prompt-group over
+                #    response positions.
+                if self.adv_norm and self.group_adv_norm:
+                    adv_flat = adv_flat.copy()
+                    offset = 0
+                    for sl in input_.seqlens["packed_input_ids"]:
+                        glen = sum(sl)
+                        idx = np.arange(offset, offset + glen)[resp_flat[offset : offset + glen] > 0]
+                        if idx.size > 1:
+                            vals = adv_flat[idx]
+                            adv_flat[idx] = (vals - vals.mean()) / (vals.std() + 1e-5)
+                        offset += glen
+                train_sample = input_
+                train_sample.update_(
+                    SequenceSample(
+                        ids=list(input_.ids),
+                        keys={"advantages"},
+                        data={"advantages": adv_flat.astype(np.float32)},
+                        seqlens={
+                            "advantages": [list(sl) for sl in input_.seqlens["packed_input_ids"]]
+                        },
+                    )
                 )
-            )
 
-            # 3) Minibatched PPO updates.
-            mb_inputs, *_ = train_sample.split(
-                MicroBatchSpec(n_mbs=self.n_minibatches)
-            )
+                # 3) Minibatched PPO updates.
+                mb_inputs, *_ = train_sample.split(
+                    MicroBatchSpec(n_mbs=self.n_minibatches)
+                )
             use_decoupled = self.use_decoupled_loss and "logprobs" in train_sample.keys
 
             def actor_loss(lp, rows):
@@ -393,41 +393,59 @@ class PPOActorInterface(ModelInterface):
                         scored_fn=response_positions,
                     )
                 all_stats.append(st)
-            model.inc_version()
+            with tracing.span("ppo.stats"):
+                model.inc_version()
 
-            n_resp = float(np.sum(resp_flat))
-            mean_kl = float(kl_sum) / max(n_resp, 1.0)
-            self.kl_controller.update(mean_kl, int(n_resp))
+                n_resp = float(np.sum(resp_flat))
+                mean_kl = float(kl_sum) / max(n_resp, 1.0)
+                self.kl_controller.update(mean_kl, int(n_resp))
 
-            agg = {k: float(np.mean([s[k] for s in all_stats])) for k in all_stats[0]}
-            agg.update(
-                {
-                    "ppo_actor/kl": mean_kl,
-                    "ppo_actor/kl_coef": kl_coef,
-                    "ppo_actor/adv_mean": float(
-                        np.sum(adv_flat * resp_flat) / max(n_resp, 1.0)
-                    ),
-                    "ppo_actor/ret_mean": float(
-                        np.sum(ret_flat * resp_flat) / max(n_resp, 1.0)
-                    ),
-                    "ppo_actor/reward_mean": float(np.mean(input_.data["rewards"]))
-                    if input_.data.get("rewards") is not None else 0.0,
-                    "ppo_actor/n_tokens": float(batch.total_tokens),
-                }
-            )
-            # Staleness accounting (reference: ppo_interface.py:752-762).
-            vs = input_.metadata.get("version_start")
-            ve = input_.metadata.get("version_end")
-            if vs:
-                agg["ppo_actor/head_offpolicyness"] = float(model.version - 1 - np.min(vs))
-                agg["ppo_actor/tail_offpolicyness"] = float(model.version - 1 - np.max(ve))
-            stats_tracker.scalar(**agg)
+                agg = {k: float(np.mean([s[k] for s in all_stats])) for k in all_stats[0]}
+                agg.update(
+                    {
+                        "ppo_actor/kl": mean_kl,
+                        "ppo_actor/kl_coef": kl_coef,
+                        "ppo_actor/adv_mean": float(
+                            np.sum(adv_flat * resp_flat) / max(n_resp, 1.0)
+                        ),
+                        "ppo_actor/ret_mean": float(
+                            np.sum(ret_flat * resp_flat) / max(n_resp, 1.0)
+                        ),
+                        "ppo_actor/reward_mean": float(np.mean(input_.data["rewards"]))
+                        if input_.data.get("rewards") is not None else 0.0,
+                        "ppo_actor/n_tokens": float(batch.total_tokens),
+                    }
+                )
+                # Staleness accounting (reference: ppo_interface.py:752-762).
+                vs = input_.metadata.get("version_start")
+                ve = input_.metadata.get("version_end")
+                if vs:
+                    agg["ppo_actor/head_offpolicyness"] = float(model.version - 1 - np.min(vs))
+                    agg["ppo_actor/tail_offpolicyness"] = float(model.version - 1 - np.max(ve))
+                stats_tracker.scalar(**agg)
             return agg
 
     def save(self, model: Model, save_dir: str):
         from areal_tpu.interfaces.sft import SFTInterface
 
         SFTInterface.save(self, model, save_dir)  # same HF export path
+
+
+def _run_prep(engine, prep, input_: SequenceSample, kl_coef: float):
+    """The host's side of `ppo.prep` up to the enqueue, a leaf span each
+    part: pack the whole batch into rows, put them on the device, call the
+    prep program. Returns the packed batch and the program's outputs,
+    still on the device."""
+    with tracing.span("ppo.prep.pack"):
+        batch, rows = engine._build_rows(input_)
+    tracing.set_attrs(rows=batch.n_rows, row_len=batch.row_len)
+    with tracing.span("ppo.prep.h2d"):
+        rows_dev = engine._device_rows(rows)
+    tracing.build_site("ppo_prep", prep, batch.n_rows, batch.row_len)
+    with tracing.span("ppo.prep.dispatch"):
+        out = prep(rows_dev, jnp.asarray(kl_coef, jnp.float32))
+        tracing.fed("ppo_prep")
+    return batch, out
 
 
 def _n_response_tokens(mb: SequenceSample) -> float:
@@ -496,42 +514,42 @@ class PPOCriticInterface(ModelInterface):
         with tracing.span("ppo.train_step", version=model.version):
             # Returns are recomputed exactly like the actor does.
             with tracing.span("ppo.prep"):
-                batch, rows = engine._build_rows(input_)
-                tracing.set_attrs(rows=batch.n_rows, row_len=batch.row_len)
-                rows_dev = engine._device_rows(rows)
-                prep = self._helper._prep_fn(engine)
-                tracing.build_site("ppo_prep", prep, batch.n_rows, batch.row_len)
-                _, ret_rows, resp_rows, kl_sum = prep(
-                    rows_dev, jnp.asarray(self.kl_controller.value, jnp.float32)
-                )
-                ret_flat = batch.gather_flat(np.asarray(ret_rows))
-                resp_flat = batch.gather_flat(np.asarray(resp_rows))
+                batch, (_, ret_rows, resp_rows, kl_sum) = _run_prep(
+                    engine, self._helper._prep_fn(engine), input_,
+                    self.kl_controller.value)
+                ret_rows = np.asarray(ret_rows)  # the step's first wait for the device
+                tracing.drained("ppo.prep")
+                with tracing.span("ppo.prep.gather"):
+                    ret_flat = batch.gather_flat(ret_rows)
+                    resp_flat = batch.gather_flat(np.asarray(resp_rows))
             tracing.set_attrs(
                 tokens=batch.total_tokens, sequences=len(batch.seq_lens)
             )
-            if self.value_norm:
-                self.rms.update(ret_flat, mask=resp_flat > 0)
-                norm_ret = np.where(resp_flat > 0, self.rms.normalize(ret_flat), 0.0)
-                old_values = np.where(
-                    resp_flat > 0,
-                    self.rms.normalize(np.asarray(input_.data["values"])),
-                    0.0,
-                )
-            else:
-                norm_ret = ret_flat
-                old_values = np.asarray(input_.data["values"])
+            with tracing.span("ppo.advantages"):
+                if self.value_norm:
+                    self.rms.update(ret_flat, mask=resp_flat > 0)
+                    norm_ret = np.where(resp_flat > 0, self.rms.normalize(ret_flat), 0.0)
+                    old_values = np.where(
+                        resp_flat > 0,
+                        self.rms.normalize(np.asarray(input_.data["values"])),
+                        0.0,
+                    )
+                else:
+                    norm_ret = ret_flat
+                    old_values = np.asarray(input_.data["values"])
 
-            sl = [list(s) for s in input_.seqlens["packed_input_ids"]]
-            input_.update_(
-                SequenceSample(
-                    ids=list(input_.ids), keys={"returns", "old_values_norm"},
-                    data={
-                        "returns": norm_ret.astype(np.float32),
-                        "old_values_norm": old_values.astype(np.float32),
-                    },
-                    seqlens={"returns": sl, "old_values_norm": sl},
+                sl = [list(s) for s in input_.seqlens["packed_input_ids"]]
+                input_.update_(
+                    SequenceSample(
+                        ids=list(input_.ids), keys={"returns", "old_values_norm"},
+                        data={
+                            "returns": norm_ret.astype(np.float32),
+                            "old_values_norm": old_values.astype(np.float32),
+                        },
+                        seqlens={"returns": sl, "old_values_norm": sl},
+                    )
                 )
-            )
+                mb_inputs, *_ = input_.split(MicroBatchSpec(n_mbs=self.n_minibatches))
 
             def critic_loss(values, rows):
                 mask = response_scoring_mask(rows["segment_ids"], rows["prompt_mask"])
@@ -548,7 +566,6 @@ class PPOCriticInterface(ModelInterface):
                 )
                 return loss_sum, st
 
-            mb_inputs, *_ = input_.split(MicroBatchSpec(n_mbs=self.n_minibatches))
             all_stats = []
             for i, mb in enumerate(mb_inputs):
                 with tracing.span("ppo.minibatch", index=i,
@@ -560,11 +577,12 @@ class PPOCriticInterface(ModelInterface):
                         version_steps=model.version, loss_name="ppo_critic",
                     )
                 all_stats.append(st)
-            model.inc_version()
-            n_resp = float(np.sum(resp_flat))
-            self.kl_controller.update(float(kl_sum) / max(n_resp, 1.0), int(n_resp))
-            agg = {k: float(np.mean([s[k] for s in all_stats])) for k in all_stats[0]}
-            stats_tracker.scalar(**agg)
+            with tracing.span("ppo.stats"):
+                model.inc_version()
+                n_resp = float(np.sum(resp_flat))
+                self.kl_controller.update(float(kl_sum) / max(n_resp, 1.0), int(n_resp))
+                agg = {k: float(np.mean([s[k] for s in all_stats])) for k in all_stats[0]}
+                stats_tracker.scalar(**agg)
             return agg
 
 

@@ -5,7 +5,7 @@
 
 Runs `benchmark/run.py` with the arguments after `<out dir>` in this process,
 with the program's spans on (`AREAL_RL_TRACE`; the shard is flushed before
-`run.py` leaves by `os._exit`, which would lose its last 512 spans), and two
+`run.py` leaves by `os._exit`, which would lose its last second of spans), and two
 clocks that do nothing but sleep 20 ms and note when they woke late:
 
 - a thread of this process: it is late when the interpreter's lock was held
@@ -36,8 +36,10 @@ import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TICK, LATE, OVER = 0.02, 0.3, 0.1
+# `device.starved` beside `train.fetch_stats`: a pause inside the blocking
+# read grows the second alone, one between a read and the next enqueue the first
 SPANS = ("ppo.prep", "train.wait_input", "train.dispatch", "train.apply",
-         "train.fetch_stats")
+         "train.fetch_stats", "device.starved")
 
 
 def _clock(note):

@@ -72,7 +72,10 @@ def test_disabled_is_true_noop(untraced):
         assert tracing.current() is None
         tracing.set_attrs(late=1)
         tracing.count("n")
+        tracing.drained("train.fetch_stats")
+        tracing.fed("accum_step")
     tracing.flush()
+    assert tracing._DRAINED is None  # off: not even a mark
     # The acceptance pin: no recorder allocation, no shard files, as long
     # as neither the environment nor start() has switched tracing on.
     assert tracing.recorder() is None
@@ -172,6 +175,9 @@ def test_the_list_of_builds_is_bounded_and_counts_what_it_drops(untraced, monkey
 
     tracing.watch_builds()
     monkeypatch.setattr(tracing, "_BUILDS_CAP", 8)
+    # whatever this process built before (other tests of the worker) is
+    # not this test's to drop four at a time
+    monkeypatch.setattr(tracing, "_BUILDS", [])
     dropped = tracing.builds_dropped()
     f = _jitted("builds_probe_d")
     for i in range(1, 8):
@@ -339,6 +345,40 @@ def test_start_and_stop_are_idempotent_and_sessions_do_not_leak(live):
     got = tracing.stop()
     assert [s["name"] for s in got["spans"]] == ["second"]
     assert got["counters"] == {}
+
+
+def test_a_starved_stretch_runs_from_the_first_mark_to_the_next_enqueue(live):
+    tracing.start()
+    with tracing.span("step") as step:
+        tracing.fed("accum_step")  # nothing drained yet: nothing to end
+        t0 = tracing.now_ns()
+        tracing.drained("train.fetch_stats")
+        t1 = tracing.now_ns()
+        tracing.drained("ppo.prep")  # the device has been empty since the first
+        with tracing.span("train.dispatch") as enqueue:
+            tracing.fed("accum_step")
+            t2 = tracing.now_ns()
+            tracing.fed("accum_step")  # a later enqueue ends nothing
+    got = tracing.stop()
+    [s] = [x for x in got["spans"] if x["name"] == "device.starved"]
+    assert t0 <= s["start_ns"] <= t1 <= s["end_ns"] <= t2
+    assert s["attrs"] == {"after": "train.fetch_stats", "until": "accum_step"}
+    # under the span that enqueued, in its trace
+    assert (s["trace"], s["parent"]) == (step.trace_id, enqueue.span_id)
+    assert tracing._DRAINED is None
+
+
+def test_a_mark_from_before_start_or_left_at_stop_ends_in_no_span(traced):
+    tracing.drained("train.fetch_stats")  # the environment's recording is on
+    assert tracing._DRAINED is not None
+    tracing.start()  # a session records no stretch that began before it
+    tracing.fed("ppo_prep")
+    tracing.drained("ppo.prep")
+    got = tracing.stop()  # nor leaves one that would end after it
+    tracing.fed("accum_step")
+    tracing.flush()
+    assert got["spans"] == [] and tracing._DRAINED is None
+    assert "device.starved" not in {s["name"] for s in _load_spans(traced)}
 
 
 def test_control_works_from_any_thread(live):
@@ -577,6 +617,116 @@ def test_ring_buffer_overflow_drops_oldest(tmp_path, monkeypatch):
         assert len(shard.spans) <= 8
     finally:
         tracing.reconfigure()
+
+
+def _writer_threads():
+    import threading
+
+    return [t for t in threading.enumerate() if t.name == "rl-trace-writer"]
+
+
+def test_the_thread_that_appends_the_512th_span_writes_nothing(traced, monkeypatch):
+    import threading
+    import time
+
+    tracing.event("first")
+    rec = tracing.recorder()
+    real, wrote = rec._write_lines, []
+
+    def slow(lines):
+        wrote.append((threading.get_ident(), len(lines)))
+        time.sleep(0.4)
+        real(lines)
+
+    monkeypatch.setattr(rec, "_write_lines", slow)
+    t0 = time.monotonic()
+    for i in range(600):  # past the writer's batch of 512
+        tracing.event(f"e{i}")
+    took = time.monotonic() - t0
+    # the 512th span woke the writer's thread, which is inside its slow
+    # write (or on its way there) while we are already done
+    assert took < 0.3, took
+    deadline = time.monotonic() + 5.0
+    while not wrote and time.monotonic() < deadline:
+        time.sleep(0.01)
+    assert wrote and wrote[0][0] != threading.get_ident() and wrote[0][1] >= 512
+    tracing.flush()  # waits for that write, then writes the rest itself
+    names = [s["name"] for s in _load_spans(traced)]
+    assert names == ["first"] + [f"e{i}" for i in range(600)]
+
+
+def test_flush_leaves_every_span_on_disk_in_whole_lines_under_two_appenders(traced):
+    import sys
+    import threading
+
+    n = 3000
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        def work(tag):
+            for i in range(n):
+                tracing.event(f"{tag}{i}", payload="x" * 200)
+
+        threads = [threading.Thread(target=work, args=(t,)) for t in "ab"]
+        for t in threads:
+            t.start()
+        while any(t.is_alive() for t in threads):
+            tracing.flush()  # a third writer beside the recorder's own
+        for t in threads:
+            t.join(timeout=30)
+            assert not t.is_alive()
+    finally:
+        sys.setswitchinterval(old)
+    tracing.flush()
+    [shard] = os.listdir(traced)
+    with open(os.path.join(traced, shard)) as f:
+        recs = [json.loads(line) for line in f]  # a torn line would not parse
+    assert recs[0]["kind"] == "header"
+    assert sum(r["kind"] == "header" for r in recs) == 1
+    for tag in "ab":  # nothing lost, each thread's spans in its order
+        assert [r["name"] for r in recs[1:] if r["name"][0] == tag] == [
+            f"{tag}{i}" for i in range(n)]
+
+
+def test_reconfigure_ends_the_writer_and_a_session_without_a_shard_has_none(
+        tmp_path, monkeypatch):
+    monkeypatch.setenv("AREAL_RL_TRACE", "0")
+    monkeypatch.delenv("AREAL_RL_TRACE_DIR", raising=False)
+    tracing.reconfigure()
+    assert not _writer_threads()
+    tracing.start()
+    tracing.event("in memory")
+    assert tracing.recorder().path is None and not _writer_threads()
+    tracing.stop()
+    monkeypatch.setenv("AREAL_RL_TRACE_DIR", str(tmp_path / "w"))
+    tracing.reconfigure()
+    tracing.start()
+    tracing.event("to a shard")
+    assert len(_writer_threads()) == 1
+    tracing.stop()
+    tracing.reconfigure()
+    assert not _writer_threads()
+
+
+def test_a_process_that_leaves_by_os_exit_loses_at_most_its_last_second(tmp_path):
+    import subprocess
+    import sys
+
+    d = str(tmp_path / "exit")
+    code = (
+        "import os, time\n"
+        "from areal_tpu.base import tracing\n"
+        "for i in range(20): tracing.event(f'early{i}')\n"
+        "time.sleep(1.6)\n"
+        "tracing.event('late')\n"
+        "os._exit(0)\n"
+    )
+    env = dict(os.environ, AREAL_RL_TRACE="1", AREAL_RL_TRACE_DIR=d)
+    subprocess.run([sys.executable, "-c", code], env=env, check=True, timeout=60,
+                   cwd=os.path.dirname(os.path.dirname(os.path.dirname(__file__))))
+    names = [s["name"] for s in _load_spans(d)]
+    # no atexit hook ran; what was older than a second is there
+    assert names == [f"early{i}" for i in range(20)]
 
 
 # ---------------------------------------------------------------------------

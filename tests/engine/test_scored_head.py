@@ -191,7 +191,7 @@ def test_no_mask_counts_every_chunk_and_a_critic_counts_no_head():
                                   "trinity-d5e16-train-ppo-long"])
 def test_a_cells_traffic_builds_the_programs_the_unmasked_step_builds(cell, monkeypatch):
     """A pass over the cell's pool at the rehearsal's toy widths, with
-    the mask and without: `train.programs_built` is the same number, the
+    the mask and without: the engine's jit cache holds as many entries, the
     mask adds no compiled shape."""
     from benchmark import manifest, model, traffic
     from benchmark.runners.train import _sample as pool_sample
@@ -229,7 +229,7 @@ def test_a_cells_traffic_builds_the_programs_the_unmasked_step_builds(cell, monk
                 assert np.isfinite(stats["ppo_actor/loss"])
         finally:
             got = tracing.stop()["counters"]
-        built[masked] = got["train.programs_built"]
+        built[masked] = len(engine._jit_cache)
         # (at toy widths a micro-batch is one chunk, and it runs)
         assert 0 < got["train.scored_cells"] < got["train.head_cells"] <= got["train.cells"]
         assert masked or got["train.head_cells"] == got["train.cells"]

@@ -25,11 +25,11 @@ N_LAYERS = small_cfg().n_layers
 # path -> prefetch depth, and per train_batch the count of each span kind
 PATHS = {
     "overlapped": dict(depth=2, counts={
-        "train.batch": 1, "train.stage": N_MBS, "train.pack": N_MBS,
+        "train.batch": 1, "train.begin": 1, "train.stage": N_MBS, "train.pack": N_MBS,
         "train.h2d": N_MBS, "train.wait_input": N_MBS + 1,
         "train.dispatch": N_MBS, "train.apply": 1, "train.fetch_stats": 1}),
     "fused": dict(depth=0, counts={
-        "train.batch": 1, "train.pack": 1, "train.h2d": 1,
+        "train.batch": 1, "train.begin": 1, "train.pack": 1, "train.h2d": 1,
         "train.dispatch": 1, "train.fetch_stats": 1}),
 }
 
@@ -85,7 +85,8 @@ def test_the_tree_hangs_under_one_train_batch(recorded):
     [batch] = [s for s in spans if s["name"] == "train.batch"]
     assert {s["trace"] for s in spans} == {batch["trace"]}
     parent_of = {s["name"]: by_id[s["parent"]]["name"] for s in spans if s["parent"]}
-    want = {"train.dispatch": "train.batch", "train.fetch_stats": "train.batch"}
+    want = {"train.dispatch": "train.batch", "train.fetch_stats": "train.batch",
+            "train.begin": "train.batch"}
     if path == "overlapped":
         want.update({"train.stage": "train.batch", "train.pack": "train.stage",
                      "train.h2d": "train.stage", "train.wait_input": "train.batch",
@@ -93,6 +94,11 @@ def test_the_tree_hangs_under_one_train_batch(recorded):
     else:
         want.update({"train.pack": "train.batch", "train.h2d": "train.batch"})
     assert parent_of == want
+    # `train.begin`: the host's work before the path's input begins
+    [begin] = [s for s in spans if s["name"] == "train.begin"]
+    first_input = min(s["start_ns"] for s in spans if s["name"] == (
+        "train.wait_input" if path == "overlapped" else "train.pack"))
+    assert batch["start_ns"] <= begin["start_ns"] < begin["end_ns"] <= first_input
     # the stage runs on the prefetcher's thread, everything else on ours
     for s in spans:
         on_worker = path == "overlapped" and s["name"] in (
@@ -159,24 +165,33 @@ def test_perf_telemetry_is_the_same_with_tracing_on_and_off(recorded):
     assert on["h2d_wait_ms"] >= 0.0 and on["dispatch_gap_ms"] >= 0.0
 
 
-def test_programs_built_counts_new_jit_cache_entries_and_stale_fetches_are_marked():
+def test_the_jit_cache_holds_three_entries_and_a_stale_fetch_is_marked_and_drains_nothing():
     stats_tracker.export()
     tracing.start()
     try:
         eng = mk_engine(init_params(small_cfg(), jax.random.PRNGKey(6)),
                         depth=2, stats_fetch_interval=2)
         batch = make_batch(n=9, seed=6)
-        for _ in range(3):
+        for _ in range(4):
             eng.train_batch(batch, MicroBatchSpec(n_mbs=N_MBS), packed_loss,
                             loss_weight, loss_name="t")
     finally:
         got = tracing.stop()
     # one entry for the accumulate program, one for the two beside it
-    # that see no row, one for the apply; built once, run three times
-    assert got["counters"]["train.programs_built"] == len(eng._jit_cache) == 3
-    assert got["counters"]["train.batches"] == 3
-    stale = [s["attrs"]["stale"] for s in got["spans"] if s["name"] == "train.fetch_stats"]
-    assert stale == [False, False, True]
+    # that see no row, one for the apply; built once, run four times
+    assert len(eng._jit_cache) == 3
+    assert got["counters"]["train.batches"] == 4
+    fetches = [s for s in got["spans"] if s["name"] == "train.fetch_stats"]
+    assert [s["attrs"]["stale"] for s in fetches] == [False, False, True, False]
+    # a fetch that blocked leaves a mark for the next batch's first
+    # enqueue; the stale one did not block, and the fourth batch starts
+    # with the third's programs still queued: no stretch
+    batches = [s for s in got["spans"] if s["name"] == "train.batch"]
+    starved = [s for s in got["spans"] if s["name"] == "device.starved"]
+    assert [s["trace"] for s in starved] == [b["trace"] for b in batches[1:3]]
+    for s, fetch in zip(starved, fetches):
+        assert s["attrs"] == {"after": "train.fetch_stats", "until": "accum_step"}
+        assert fetch["end_ns"] <= s["start_ns"] < s["end_ns"]
     out = stats_tracker.export()
     assert {"perf/packing_efficiency", "perf/h2d_wait_ms",
             "perf/dispatch_gap_ms", "perf/overlap_events"} <= set(out)
@@ -311,16 +326,15 @@ def test_a_new_shape_through_an_old_jit_entry_is_built_under_its_dispatch():
             eng.train_batch(make_batch(n=9, seed=8), spec, packed_loss,
                             loss_weight, loss_name="t")
         warm = tracing.stop()
-        n_builds = len(warm["builds"])
+        n_builds, n_entries = len(warm["builds"]), len(eng._jit_cache)
         tracing.start()
         eng.train_batch(_long_batch(8), spec, packed_loss, loss_weight,
                         loss_name="t")
     finally:
         got = tracing.stop()
     # jit-cache entries: the accumulate program, the two beside it that
-    # see no row and the apply, made by the first call
-    assert warm["counters"]["train.programs_built"] == len(eng._jit_cache) == 3
-    assert "train.programs_built" not in got["counters"]
+    # see no row and the apply, made by the first call; a new shape adds none
+    assert len(eng._jit_cache) == n_entries == 3
     dispatches = [s for s in got["spans"] if s["name"] == "train.dispatch"]
     shapes = {(s["attrs"]["rows"], s["attrs"]["row_len"]) for s in dispatches}
     old = {(s["attrs"]["rows"], s["attrs"]["row_len"])
@@ -407,8 +421,20 @@ def test_one_forward_records_the_fwd_spans(depth):
     assert paid
     for s in paid:
         for k in _children(got["spans"], s):
+            if k["name"] == "device.starved":
+                continue
             assert k["name"].startswith("jit.") and k["attrs"]["program"] == "forward"
             assert k["attrs"]["row_len"] == s["attrs"]["row_len"]
+    # every fetch blocks and the dispatch after it ends the stretch: one
+    # drain a forward with the prefetcher, one a micro-batch without; the
+    # session's first dispatch follows no drain, its last fetch feeds none
+    starved = [s for s in got["spans"] if s["name"] == "device.starved"]
+    by_id = {s["span"]: s for s in got["spans"]}
+    assert len(starved) == (1 if depth else 2 * N_MBS - 1)
+    for s in starved:
+        assert s["attrs"] == {"after": "fwd.fetch", "until": "forward"}
+        assert by_id[s["parent"]]["name"] == "fwd.dispatch"
+        assert s["end_ns"] <= by_id[s["parent"]]["end_ns"]
 
 
 @pytest.mark.parametrize("path", sorted(PATHS))

@@ -1,7 +1,10 @@
 """A PPO step as the program's own tracing records it: one
-`ppo.train_step` root a step, `ppo.prep` and one `ppo.minibatch` a
-minibatch under it, each minibatch's `train.batch` tree under that, the
-prefetcher's `train.stage` under the step's trace id. Actor and critic."""
+`ppo.train_step` root a step, `ppo.prep` (its host parts as children),
+`ppo.advantages`, one `ppo.minibatch` a minibatch and `ppo.stats` under
+it, each minibatch's `train.batch` tree under that, the prefetcher's
+`train.stage` under the step's trace id, and a `device.starved` span for
+every stretch between a blocking read and the next enqueue. Actor and
+critic."""
 
 import threading
 
@@ -25,8 +28,15 @@ N_MINIBATCHES, MBS_PER_MINIBATCH = 2, 2
 TREE = {
     "ppo.train_step": (1, None),
     "ppo.prep": (1, "ppo.train_step"),
+    "ppo.prep.pack": (1, "ppo.prep"),
+    "ppo.prep.h2d": (1, "ppo.prep"),
+    "ppo.prep.dispatch": (1, "ppo.prep"),
+    "ppo.prep.gather": (1, "ppo.prep"),
+    "ppo.advantages": (1, "ppo.train_step"),
+    "ppo.stats": (1, "ppo.train_step"),
     "ppo.minibatch": (N_MINIBATCHES, "ppo.train_step"),
     "train.batch": (N_MINIBATCHES, "ppo.minibatch"),
+    "train.begin": (N_MINIBATCHES, "train.batch"),
     "train.stage": (N_MINIBATCHES * MBS_PER_MINIBATCH, "train.batch"),
     "train.pack": (N_MINIBATCHES * MBS_PER_MINIBATCH, "train.stage"),
     "train.h2d": (N_MINIBATCHES * MBS_PER_MINIBATCH, "train.stage"),
@@ -34,6 +44,10 @@ TREE = {
     "train.dispatch": (N_MINIBATCHES * MBS_PER_MINIBATCH, "train.batch"),
     "train.apply": (N_MINIBATCHES, "train.batch"),
     "train.fetch_stats": (N_MINIBATCHES, "train.batch"),
+    # a session's first step: after the prep's read and after each
+    # minibatch's fetch but the last, each ended by a minibatch's first
+    # dispatch
+    "device.starved": (N_MINIBATCHES, "train.dispatch"),
 }
 
 
@@ -144,13 +158,64 @@ def test_a_first_step_builds_its_prep_under_ppo_prep_by_name_and_shape(critic):
     finally:
         got = tracing.stop()
     [prep] = [s for s in got["spans"] if s["name"] == "ppo.prep"]
-    named = [s for s in got["spans"] if s["parent"] == prep["span"]
+    [enqueue] = [s for s in got["spans"] if s["name"] == "ppo.prep.dispatch"]
+    # the child that enqueues pays the build
+    named = [s for s in got["spans"] if s["parent"] == enqueue["span"]
              and s["attrs"].get("program") == "ppo_prep"]
     assert [s["name"] for s in named][:2] == ["jit.trace", "jit.lower"]
     assert named[2]["name"] in ("jit.compile", "jit.cache_load") and len(named) == 3
     a = prep["attrs"]
     assert all((s["attrs"]["rows"], s["attrs"]["row_len"]) == (a["rows"], a["row_len"])
                for s in named)
-    assert a["built"] >= 1  # the prep, and whatever eager op was new beside it
+    assert enqueue["attrs"]["built"] >= 1  # the prep, and whatever eager op was new beside it
     programs = {b["program"] for b in got["builds"]}
     assert {"ppo_prep", "accum_step", "accum_zeros", "accum_stats", "apply"} <= programs
+
+
+@pytest.mark.parametrize("critic", [False, True], ids=["actor", "critic"])
+def test_every_stretch_after_a_blocking_read_is_one_starved_span_of_the_step_that_fed(critic):
+    """Three steps of one session: the first records a stretch after the
+    prep's read and after each minibatch's fetch but the last, whose mark
+    waits for the next step's prep; every later step one more."""
+    tracing.reconfigure()
+    model = _model(critic)
+    itf = (PPOCriticInterface if critic else PPOActorInterface)(
+        n_minibatches=N_MINIBATCHES)
+    mb_spec = MicroBatchSpec(max_tokens_per_mb=48)
+    itf.train_step(model, _sample(values=critic), mb_spec)  # warm, and marks nothing
+    tracing.start()
+    try:
+        for seed in (1, 2, 3):
+            itf.train_step(model, _sample(seed=seed, values=critic), mb_spec)
+    finally:
+        got = tracing.stop()
+    spans = sorted(got["spans"], key=lambda s: s["start_ns"])
+    by_id = {s["span"]: s for s in spans}
+    roots = [s for s in spans if s["name"] == "ppo.train_step"]
+    main = [s for s in spans if s["tid"] == roots[0]["tid"] and s["name"] != "device.starved"]
+    assert len(roots) == 3
+    for i, root in enumerate(roots):
+        mine = [s for s in spans if s["name"] == "device.starved"
+                and s["trace"] == root["trace"]]
+        untils = [s["attrs"]["until"] for s in mine]
+        afters = [s["attrs"]["after"] for s in mine]
+        tail = [] if i == 0 else [("train.fetch_stats", "ppo_prep")]
+        assert list(zip(afters, untils)) == tail + [("ppo.prep", "accum_step")] + [
+            ("train.fetch_stats", "accum_step")] * (N_MINIBATCHES - 1)
+        for s in mine:
+            # it ends as an enqueue returns, inside the span that enqueued
+            parent = by_id[s["parent"]]
+            assert parent["name"] == ("ppo.prep.dispatch" if s["attrs"]["until"] == "ppo_prep"
+                                      else "train.dispatch")
+            assert parent["start_ns"] <= s["end_ns"] <= parent["end_ns"]
+            # and starts where the blocking read returned: after the span
+            # that blocked (the prep's read: between its enqueue and its
+            # gather) with no other span of the thread begun in between
+            before = [m for m in main if m["end_ns"] <= s["start_ns"]]
+            last = max(before, key=lambda m: m["end_ns"])
+            assert last["name"] == ("ppo.prep.dispatch" if s["attrs"]["after"] == "ppo.prep"
+                                    else "train.fetch_stats")
+            assert not [m for m in main if last["end_ns"] < m["start_ns"] < s["start_ns"]]
+        if tail:  # the waiting mark was set in the step before, after its last fetch
+            assert roots[i - 1]["start_ns"] < mine[0]["start_ns"] < roots[i - 1]["end_ns"]
+    assert got["dropped"] == 0
