@@ -1378,8 +1378,10 @@ class JaxTrainEngine(TrainEngine):
         layers' token-wise stretches ran, the cells the stream steps of
         hyper-connections ran over the stack's sublayers and those of them
         inside a band loop (`_band_counts`, `_mhc_counts`), the positions
-        the delta-rule layers' chunked rule walked, its chunks, those with
-        a token and the sequence starts (`_kda_counts`)."""
+        the delta-rule layers' chunked rule walked (and, of them, those
+        whose forward the one kernel ran: `ops/kda._use_kernel`), its
+        chunks, those with a token and the sequence starts
+        (`_kda_counts`)."""
         tracing.set_attrs(path=path, n_mbs=n_mbs, tokens=n_tok, cells=n_cells)
         tracing.count("train.batches")
         tracing.count("train.micro_batches", n_mbs)
@@ -1416,6 +1418,12 @@ class JaxTrainEngine(TrainEngine):
             tracing.count("train.ssm_resets", n_ssm_resets)
         if self.model_cfg.n_kda_layers:
             tracing.count("train.kda_cells", n_kda_cells)
+            from areal_tpu.ops.kda import _use_kernel
+
+            # those of them the forward's one kernel ran (`kda_fwd_rule`): all
+            # where the rule takes its kernels, none where it takes the plain form
+            tracing.count("train.kda_fwd_kernel_cells", n_kda_cells if _use_kernel(
+                self.model_cfg.kda.head_dim, self.mesh) else 0)
             tracing.count("train.kda_chunks", n_kda_chunks)
             tracing.count("train.kda_chunks_live", n_kda_live)
             tracing.count("train.kda_resets", n_kda_resets)

@@ -40,10 +40,23 @@ and 0 for the rest; a chunk hands on the state of the sequence its last
 cell belongs to (as `ops/ssm.chunked_scan`). Two steps: `intra` makes, for
 every chunk of a group at once, what does not depend on the state (W, U,
 Q exp(G), K exp(G_C - G), tril(P), exp(G_C), each with its mask folded
-in); the walk carries `S` over the group's chunks (the kernels of
-`ops/pallas/kda_chunk.py` on the chip, a `lax.scan` elsewhere:
-`states_scan`). `delta_rule` takes a row a group of chunks at a time, up
-to the group of its last token.
+in); the walk carries `S` over the group's chunks (`states_scan`, a
+`lax.scan`). `delta_rule` takes a row a group of chunks at a time, up to
+the group of its last token.
+
+**Which form runs where** (`_use_kernel`: a TPU backend, one device, heads
+of whole lane tiles; decided from what the code sees, no argument):
+
+- forward, on the chip: one kernel over the whole row, `kda_fwd_rule`
+  (`ops/pallas/kda_fwd.py`): the decay, `intra`'s formulas in `intra`'s
+  dtypes and the walk, a chunk a grid step with the state in VMEM; q, k,
+  v, f read and O written cells-major, nothing else of a chunk in HBM.
+  Under full remat both forward runs of a step take it.
+- backward loop, on the chip: group by group, `intra` under `jax.vjp` (the
+  plain `jnp` below), the chunks' states again by `kda_fwd_states` and the
+  walk backwards by `kda_bwd_states` (`ops/pallas/kda_chunk.py`).
+- the CPU, a mesh of several devices, toy heads: `intra` + `states_scan`
+  a group at a time forward, `states_scan` and `states_scan_bwd` backward.
 
 **No exponential of a positive number.** `exp(G_i - G_j)` is never split
 into `exp(G_i) exp(-G_j)` across a chunk (a decay of 0.2 a token over 64
@@ -371,17 +384,19 @@ def delta_rule(q, k, v, f, b, A, dt_bias, segment_ids, chunk: int, kernel):
     `unit(k)` under the decay `exp(A softplus(f + dt_bias))`: q, k, f [R,
     T, H, K], v [R, T, H, V], all 0 at padding; b [R, T, H] float32, 0 at
     padding; A [H] and dt_bias [H, K] float32; segment_ids [R, T]; T a
-    multiple of `chunk` -> o [R, T, H, V] in q's dtype. `kernel`: the walk over
-    chunks by the kernels of `ops/pallas/kda_chunk.py` (True; "interpret":
-    in interpret mode, a test's), or by `states_scan` (False).
+    multiple of `chunk` -> o [R, T, H, V] in q's dtype. `kernel`: the
+    forward by `ops/pallas/kda_fwd.py`'s one kernel and the backward
+    loop's walk by the kernels of `ops/pallas/kda_chunk.py` (True;
+    "interpret": in interpret mode, a test's), or the plain form (False).
 
-    A group of every row's chunks at a time (`_Groups`), up to the group
-    of the fullest row's last token (a loop whose trip count is a value of
-    the run): `intra` of the group, then the walk over its chunks from the
-    state the group before handed on. What stands in memory at once is a
-    group's; the forward rule keeps its inputs and the state each group
-    received, and the backward loop makes a group's `intra` and its
-    chunks' states again before it walks them backwards. One function
+    Plain: a group of every row's chunks at a time (`_Groups`), up to the
+    group of the fullest row's last token (a loop whose trip count is a
+    value of the run): `intra` of the group, then the walk over its chunks
+    from the state the group before handed on. What stands in memory at
+    once is a group's; the forward rule keeps its inputs and the state
+    each group received (the kernel writes the same), and the backward
+    loop makes a group's `intra` and its chunks' states again before it
+    walks them backwards. One function
     jitted at module level (as `ops/band_loop.stretch`): the layers of a
     stack that call it at one shape share a trace and a lowering of each
     loop."""
@@ -394,8 +409,26 @@ def _rule(q, k, v, f, b, A, dt_bias, segment_ids, chunk, kernel, cells):
 
 
 def _rule_fwd(q, k, v, f, b, A, dt_bias, segment_ids, chunk, kernel, cells):
+    if not kernel:
+        return _rule_fwd_groups(q, k, v, f, b, A, dt_bias, segment_ids, chunk, kernel, cells)
+    from areal_tpu.ops.pallas import kda_fwd
+
+    R, T = segment_ids.shape
+    res = (q, k, v, f, b, A, dt_bias, segment_ids)
+    o, bounds = kda_fwd.rule_fwd(*res, _live_chunks(segment_ids, chunk), chunk,
+                                 _group(R, T // chunk, chunk, cells),
+                                 interpret=kernel == "interpret")
+    return o, res + (bounds,)
+
+
+def _rule_fwd_groups(q, k, v, f, b, A, dt_bias, segment_ids, chunk, kernel, cells):
+    """The forward a group of chunks at a time, `intra` then the walk (by
+    `kernel`: `states_scan`, or `kda_fwd_states` as the backward loop
+    walks): the plain form's, and what `scripts/kda_probe.py` sets the one
+    kernel against."""
     R, T, H, K = q.shape
     V, cdt = v.shape[-1], q.dtype
+    res = (q, k, v, f, b, A, dt_bias, segment_ids)
     gr = _Groups(segment_ids, chunk, cells)
     args = tuple(gr.chunked(a) for a in (q, k, v, f, b))
 
@@ -412,7 +445,7 @@ def _rule_fwd(q, k, v, f, b, A, dt_bias, segment_ids, chunk, kernel, cells):
         jnp.zeros((R, H, V, K), jnp.float32), jnp.zeros((R, gr.N, H, chunk, V), cdt),
         jnp.zeros((gr.N // gr.gs, R, H, V, K), jnp.float32)))
     o = jnp.moveaxis(O, 2, 3).reshape(R, T, H, V)  # [R, N, H, C, V] -> cells
-    return o, (q, k, v, f, b, A, dt_bias, segment_ids, bounds)
+    return o, res + (bounds,)
 
 
 def _rule_bwd(chunk, kernel, cells, res, do):
@@ -449,9 +482,10 @@ _rule_jit = jax.jit(_rule, static_argnums=(8, 9, 10))
 
 
 def _use_kernel(K: int, mesh) -> bool:
-    """The kernels walk the chunks on the chip, one device's rows, heads of
-    whole lane tiles; the plain walk elsewhere (the CPU, a toy head, a
-    mesh of several devices: a kernel is opaque to the partitioner)."""
+    """The kernels (the forward's one, the backward loop's walk) on the
+    chip, one device's rows, heads of whole lane tiles; the plain form
+    elsewhere (the CPU, a toy head, a mesh of several devices: a kernel is
+    opaque to the partitioner)."""
     return (jax.default_backend() == "tpu" and (mesh is None or mesh.size == 1)
             and K % 128 == 0)
 

@@ -1,5 +1,9 @@
 """The delta rule's walk over a row's chunks (Pallas, TPU): the part of
-`ops/kda.delta_rule` that carries a state from chunk to chunk.
+`ops/kda.delta_rule` that carries a state from chunk to chunk, as the
+backward loop runs it: a group's `intra` under `jax.vjp`, then
+`kda_fwd_states` for the states its chunks received and `kda_bwd_states`
+backwards. (The forward is one kernel that holds `intra` and this walk,
+`ops/pallas/kda_fwd.py`; it shares `_heads` and the products here.)
 
 `ops/kda.intra` makes, for every chunk of a group at once, what does not
 depend on the state a chunk receives, masks folded in: `Wm`, `Qg`, `Kd`
@@ -36,8 +40,8 @@ from jax.experimental.pallas import tpu as pltpu
 HEADS = 4  # heads a grid step: independent chains that share a step's overhead
 
 
-def _heads(H: int) -> int:
-    hb = min(H, HEADS)
+def _heads(H: int, most: int = 0) -> int:
+    hb = min(H, most or HEADS)
     while H % hb:
         hb -= 1
     return hb

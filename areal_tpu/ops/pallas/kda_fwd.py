@@ -1,0 +1,267 @@
+"""The delta rule's forward as one kernel (Pallas, TPU): `ops/kda.decay`,
+`ops/kda.intra` and the walk over a row's chunks in one call, `kda_fwd_rule`.
+
+A grid step is one chunk of C cells of `HEADS` heads; the chunk axis is
+sequential, the state `[V, K]` float32 a head in VMEM scratch (transposed,
+as `kda_chunk`'s kernels hold it). q, k, v and f are read cells-major as
+the projections leave them (`[R, T, H K]`: a block `[C, HEADS K]` is
+lane-dense as it stands), `O` is written cells-major, and nothing of a
+chunk but `O` reaches HBM: no heads-first copy and no `[N, H, C, ...]`
+part. A step makes, by `intra`'s own formulas and in its dtypes:
+
+    g = A softplus(f + dt_bias), 0 at padding;  G = cumsum g   (log2 C
+        shifted adds down the rows, float32)
+    unit q K^-0.5, unit k
+    off-diagonal sub-blocks of 16: matrix products relative to the later
+        sub-block's first cell, operands in the compute dtype
+    diagonal sub-blocks cell by cell: exp(min(G_i - G_j, 0)) a cell j of
+        the sub-block, float32 (never the exponential of a positive
+        number; what stands above the diagonal is masked after)
+    (I + A)^-1 by squarings, float32;  W, U;  the masks of the sequence
+        that crossed in and of the one handed on
+    Vn = U - Wm S;  O = Qg S + Pm Vn;  S' = Diag(dec) S + Kd^T Vn
+
+Two heads' `[C, C]` matrices (A, P, the inverse) stand side by side in one
+`[C, 2 C]` array, full lanes at C = 64: a float32 product of the squarings
+(six passes of the MXU) then serves both heads, against the two blocks on a
+diagonal of `[2 C, 2 C]`. Every array carries the step's pairs on a leading
+axis (`[P, C, ...]`, the products batched): the pairs go through each stage
+together, so that one pair's chain of products fills the units while
+another's waits, and an operation is traced and lowered once for all of
+them (the body is host Python that no compile cache saves: unrolled over
+eight heads it cost 4-7 s of a warm set-up). By the probe (`PERF.md`
+section 6, PR 53), ms a call at 53 % fill: 10.5 a head at a time, 6.8 in
+pairs, 5.3 with four pairs staged, 4.85 with the pairs in front.
+
+A chunk past a row's last live one (`n_live`, a scalar the index maps
+read) fetches nothing new, computes nothing and writes zeros. Beside `O`
+the kernel writes the state every group of `group` chunks received
+(`bounds`, the one residual `ops/kda._rule_bwd` keeps beside its inputs;
+zeros for a group the row does not reach).
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from areal_tpu.ops.kda import SUB, unit
+from areal_tpu.ops.pallas.kda_chunk import _heads, _mm
+
+HEADS = 8  # heads a grid step: four pairs whose chains of products are independent
+
+_BNN = (((2,), (1,)), ((0,), (0,)))  # a pair at a time: a @ b
+_BNT = (((2,), (2,)), ((0,), (0,)))  # a @ b^T
+_BTN = (((1,), (1,)), ((0,), (0,)))  # a^T @ b
+
+
+def _mm32(a, b):
+    return lax.dot_general(a, b, _BNN, precision=lax.Precision.HIGHEST,
+                           preferred_element_type=jnp.float32)
+
+
+def _running_sum(g):
+    """Down the C rows of each of g `[P, C, K]` float32, in log2 C shifted
+    adds."""
+    P, C, K = g.shape
+    g = g.reshape(P * C, K)
+    row = lax.broadcasted_iota(jnp.int32, g.shape, 0) % C
+    s = 1
+    while s < C:
+        g = g + jnp.where(row >= s, pltpu.roll(g, s, 0), 0.0)
+        s *= 2
+    return g.reshape(P, C, K)
+
+
+def _chunk(sides, states, scratch, seg_row, before, last, cdt):
+    """A chunk of a grid step's heads, as P pairs: a pair's `[C, C]`
+    matrices stand side by side in `[C, 2 C]` (full lanes at C = 64; a
+    float32 product of the inverse then takes both heads at once, against
+    the two blocks on a diagonal), and every array has the pairs in front
+    (`[P, ...]`: an operation is traced once for all of them, and the
+    pairs' chains of products, which are independent, fill the units while
+    one another's wait). `sides`: the pairs' first heads and their second
+    heads, each q, k, v, f `[P, C, K]` in `cdt`, b_col `[P, C, 1]`, A and
+    dt_bias `[P, 1, K]`; `states` their states `[P, V, K]` float32;
+    `scratch` three `[2, P, C, K]` float32 refs (the running sum, unit k
+    and q: rows and sub-blocks of them are read back from there); seg_row
+    `[1, 2 C]` (the chunk's segment ids, twice), the sequences the chunk
+    before handed on and this one hands on (scalars) -> for each side O
+    `[P, C, V]` float32 and the states handed on."""
+    f32 = jnp.float32
+    P, C, K = sides[0][0].shape
+    n = C // SUB
+    G_s, k_s, q_s = scratch
+    row = lax.broadcasted_iota(jnp.int32, (C, 2 * C), 0)
+    lane = lax.broadcasted_iota(jnp.int32, (C, 2 * C), 1)
+    second = lane >= C  # the second head's half
+    col = jnp.where(second, lane - C, lane)
+    eye = row == col
+    seg_col = jnp.sum(jnp.where(eye & ~second, seg_row, 0), axis=1, keepdims=True)  # [C, 1]
+    seen = (seg_col == seg_row) & (row >= col)
+    cross = (seg_col == before) & (seg_col > 0)
+    lanes = lax.broadcasted_iota(jnp.int32, (1, SUB, 2 * C), 2)
+    earlier = lax.broadcasted_iota(jnp.int32, (C, 1), 0)
+    # a side's `[P, C, x]` operand under the pairs' `[P, C, 2 C]` matrices
+    under = lambda h, x: jnp.concatenate(
+        (jnp.zeros_like(x), x) if h else (x, jnp.zeros_like(x)), axis=1)
+
+    made = []
+    for h, (q, k, _, f, _, A, dt_bias) in enumerate(sides):
+        x = f.astype(f32) + dt_bias
+        softplus = jnp.maximum(x, 0.0) + jnp.log1p(jnp.exp(-jnp.abs(x)))
+        G_s[h] = G = _running_sum(jnp.where(seg_col > 0, A * softplus, 0.0))  # <= 0, falling
+        q_s[h] = qf = unit(q) * K ** -0.5
+        k_s[h] = kf = unit(k)
+        made.append((G, qf, kf))
+
+    kk_rows, p_rows = [], []
+    for i in range(n):
+        at = slice(i * SUB, (i + 1) * SUB)
+        acc_k = acc_q = jnp.zeros((P, SUB, 2 * C), f32)
+        for h, (G, _, kf) in enumerate(made):
+            Gs, ks, qs = G_s[h, :, at, :], k_s[h, :, at, :], q_s[h, :, at, :]
+            if i:  # the sub-blocks before: relative to this one's first cell
+                Gr = G_s[h, :, i * SUB:i * SUB + 1, :]
+                reach = jnp.exp(Gs - Gr)
+                k_cols = under(h, (kf * jnp.where(earlier < i * SUB, jnp.exp(
+                    jnp.minimum(Gr - G, 0.0)), 0.0)).astype(cdt))
+                acc_k = acc_k + _mm((ks * reach).astype(cdt), k_cols, _BNT)
+                acc_q = acc_q + _mm((qs * reach).astype(cdt), k_cols, _BNT)
+            for c in range(i * SUB, (i + 1) * SUB):  # its own cells, one at a time
+                ke = k_s[h, :, c:c + 1, :] * jnp.exp(
+                    jnp.minimum(Gs - G_s[h, :, c:c + 1, :], 0.0))
+                here = lanes == h * C + c
+                acc_k = jnp.where(here, jnp.sum(ks * ke, axis=2, keepdims=True), acc_k)
+                acc_q = jnp.where(here, jnp.sum(qs * ke, axis=2, keepdims=True), acc_q)
+        kk_rows.append(acc_k)
+        p_rows.append(acc_q)
+    kk = jnp.where(seen, jnp.concatenate(kk_rows, axis=1), 0.0)  # [P, C, 2 C]
+    Pc = jnp.where(seen, jnp.concatenate(p_rows, axis=1), 0.0).astype(cdt)
+    b_col = jnp.where(second, sides[1][4], sides[0][4])  # [P, C, 2 C]
+    b_row = jnp.sum(jnp.where(eye, b_col, 0.0), axis=1, keepdims=True)  # [P, 1, 2 C]
+
+    # the two heads' blocks on a diagonal of `[2 C, 2 C]`
+    blocks = lambda x: jnp.concatenate(
+        [jnp.where(second, 0.0, x), jnp.where(second, x, 0.0)], axis=1)
+    ident = eye.astype(f32)
+    power = jnp.where(eye, 0.0, kk) * b_col
+    inv, m = ident - power, 2
+    while m < C:  # (I + a)^-1 = (I - a)(I + a^2)(I + a^4)...
+        power = _mm32(power, blocks(power))
+        inv = _mm32(inv, blocks(ident + power))
+        m *= 2
+    Tc = (inv * b_row).astype(cdt)
+
+    outs = []
+    for h, (G, qf, kf) in enumerate(made):
+        v, s_t = sides[h][2], states[h]
+        eG = jnp.exp(G)
+        W = _mm(Tc, under(h, (kf * eG).astype(cdt)), _BNN)
+        U = _mm(Tc, under(h, v), _BNN).astype(cdt)
+        G_end = G_s[h, :, C - 1:C, :]
+        wm = jnp.where(cross, W, 0.0).astype(cdt)
+        qg = jnp.where(cross, qf * eG, 0.0).astype(cdt)
+        kd = jnp.where(seg_col == last, kf * jnp.exp(G_end - G), 0.0).astype(cdt)
+        dec = jnp.where((last == before) & (last > 0), jnp.exp(G_end), 0.0)
+        sc = s_t.astype(cdt)
+        vc = (U.astype(f32) - _mm(wm, sc, _BNT)).astype(cdt)
+        outs.append((_mm(qg, sc, _BNT) + _mm(Pc, under(h, vc), _BNN),
+                     dec * s_t + _mm(vc, kd, _BTN)))
+    return outs
+
+
+def _kernel(n_live_ref, ends_ref, q_ref, k_ref, v_ref, f_ref, b_ref, seg_ref, a_ref,
+            bias_ref, o_ref, bounds_ref, st, *scratch, group):
+    r, hg, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    N = pl.num_programs(2)
+    hb, V, K = bounds_ref.shape
+    # the heads two by two; a last odd one stands beside itself
+    pairs = [(j, min(j + 1, hb - 1)) for j in range(0, hb, 2)]
+    live = c < n_live_ref[r]
+
+    @pl.when(c == 0)
+    def _():
+        st[...] = jnp.zeros_like(st)
+
+    @pl.when(c % group == 0)
+    def _():
+        for j in range(hb):
+            bounds_ref[j] = jnp.where(live, st[j % 2, j // 2], 0.0)
+
+    @pl.when(live)
+    def _():
+        before = jnp.where(c > 0, ends_ref[r * N + jnp.maximum(c - 1, 0)], 0)
+        last = ends_ref[r * N + c]
+        b = b_ref[...]
+        which = lax.broadcasted_iota(jnp.int32, b.shape, 1)
+
+        def side(h):
+            js = [pair[h] for pair in pairs]
+            cut = lambda ref, w: jnp.stack([ref[:, j * w:(j + 1) * w] for j in js])
+            b_col = jnp.stack([jnp.sum(jnp.where(which == hg * hb + j, b, 0.0), axis=1,
+                                       keepdims=True) for j in js])
+            return (cut(q_ref, K), cut(k_ref, K), cut(v_ref, V), cut(f_ref, K), b_col,
+                    cut(a_ref, K), cut(bias_ref, K))
+
+        outs = _chunk([side(0), side(1)], [st[0], st[1]], scratch, seg_ref[...], before, last,
+                      q_ref.dtype)
+        for h, (o, s_t) in enumerate(outs):
+            st[h] = s_t
+            for p, pair in enumerate(pairs):
+                o_ref[:, pair[h] * V:(pair[h] + 1) * V] = o[p].astype(o_ref.dtype)
+
+    @pl.when(jnp.logical_not(live))
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "group", "interpret"))
+def rule_fwd(q, k, v, f, b, A, dt_bias, segment_ids, n_live, chunk: int, group: int,
+             interpret: bool = False):
+    """`ops/kda.delta_rule`'s forward: q, k, f `[R, T, H, K]`, v `[R, T, H,
+    V]`, b `[R, T, H]` float32, A `[H]`, dt_bias `[H, K]`, segment_ids
+    `[R, T]`, T a multiple of `chunk`, n_live `[R]` the chunks of a row up
+    to its last token's -> o `[R, T, H, V]` in q's dtype and the state
+    every `group` chunks received, `[N // group, R, H, V, K]`
+    float32. Device op `kda_fwd_rule`. Jitted here: the layers of a stack,
+    their forward and remat's, trace the kernel's body once a shape and
+    lower it once a program."""
+    R, T, H, K = q.shape
+    V, C, N = v.shape[-1], chunk, T // chunk
+    hb = _heads(H, HEADS)
+    seg = segment_ids.reshape(R, N, C)
+    # a chunk past the last live one fetches the last live one's blocks again
+    at = lambda r, c, n: jnp.minimum(c, jnp.maximum(n[r] - 1, 0))
+    cells = lambda w: pl.BlockSpec((None, C, w), lambda r, h, c, n, e: (r, at(r, c, n), h))
+    a_head = pl.BlockSpec((1, hb * K), lambda r, h, c, n, e: (0, h))
+    with jax.named_scope("kda_fwd_rule"):
+        o, bounds = pl.pallas_call(
+            functools.partial(_kernel, group=group),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2, grid=(R, H // hb, N),
+                in_specs=[cells(hb * K), cells(hb * K), cells(hb * V), cells(hb * K),
+                          pl.BlockSpec((None, C, H), lambda r, h, c, n, e: (r, at(r, c, n), 0)),
+                          pl.BlockSpec((None, None, 1, 2 * C),
+                                       lambda r, h, c, n, e: (r, at(r, c, n), 0, 0)),
+                          a_head, a_head],
+                out_specs=[pl.BlockSpec((None, C, hb * V), lambda r, h, c, n, e: (r, c, h)),
+                           pl.BlockSpec((None, None, hb, V, K),
+                                        lambda r, h, c, n, e: (c // group, r, h, 0, 0))],
+                scratch_shapes=[pltpu.VMEM((2, -(-hb // 2), V, K), jnp.float32)]
+                + [pltpu.VMEM((2, -(-hb // 2), C, K), jnp.float32)] * 3),
+            out_shape=[jax.ShapeDtypeStruct((R, T, H * V), q.dtype),
+                       jax.ShapeDtypeStruct((N // group, R, H, V, K), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            name="kda_fwd_rule", interpret=interpret,
+        )(n_live.astype(jnp.int32), seg[:, :, -1].reshape(R * N).astype(jnp.int32),
+          q.reshape(R, T, H * K), k.reshape(R, T, H * K), v.reshape(R, T, H * V),
+          f.reshape(R, T, H * K), b, jnp.tile(seg[:, :, None, :], (1, 1, 1, 2)),
+          jnp.repeat(A.astype(jnp.float32), K)[None], dt_bias.astype(jnp.float32).reshape(1, H * K))
+    return o.reshape(R, T, H, V), bounds

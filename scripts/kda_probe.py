@@ -2,15 +2,18 @@
 """The delta rule on the chip (`areal_tpu/ops/kda.py`): milliseconds a call
 of `delta_rule`, forward and forward + backward, at the shapes
 `kimilinear-d5e8-train-ppo-long` runs it (a row of 16,384 cells, 32 heads
-of 128, bf16) with a given share of the row holding tokens, the walk over
-chunks by the kernels of `ops/pallas/kda_chunk.py` and by the plain scan,
-at each chunk size, group of chunks and heads a grid step asked for.
+of 128, bf16, q, k, v and f cells-major `[1, T, H K]` as the projections
+leave them) with a given share of the row holding tokens, in three arms:
+`plain` (`intra` and the `lax.scan` walk a group at a time), `walk` (the
+same loop with the walk by `kda_fwd_states`: the chip's forward before
+PR 53) and `fused` (the forward one kernel, `kda_fwd_rule`); the last two
+share the backward loop. At each chunk size, group of chunks and heads a
+grid step asked for; `fused` beside its worst difference from `plain`.
 
     python scripts/kda_probe.py [--out chiprun_out/x.jsonl] [--chunks 64 128]
 """
 
 import argparse
-import functools
 import json
 import os
 import sys
@@ -60,29 +63,47 @@ def main():
     ap.add_argument("--out", default=None)
     ap.add_argument("--chunks", type=int, nargs="+", default=[64])
     ap.add_argument("--fill", type=float, nargs="+", default=[0.53, 1.0])
-    ap.add_argument("--heads", type=int, nargs="+", default=[4])
+    ap.add_argument("--heads", type=int, nargs="+", default=[4, 8])
     ap.add_argument("--groups", type=int, nargs="+", default=[1024])
     args = ap.parse_args()
     T, H, K = 16384, 32, 128
     rows = []
+    from areal_tpu.ops.pallas import kda_chunk, kda_fwd
+
+    # the group loop with the kernels' walk in the forward too
+    walk = jax.custom_vjp(
+        lambda *a: kda._rule_fwd_groups(*a)[0], nondiff_argnums=(8, 9, 10))
+    walk.defvjp(kda._rule_fwd_groups, kda._rule_bwd)
     for fill in args.fill:
         *xs, seg = inputs(T, H, K, int(T * fill))
+        xs = [a.reshape(1, T, H * K) if a.ndim == 4 else a for a in xs]
         w = jnp.asarray(np.random.default_rng(1).normal(size=(1, T, H, K)), jnp.float32)
         for chunk in args.chunks:
             for group in args.groups:
-                kda.GROUP_CELLS = group
-                for name, kernel, heads in [("plain", False, 0)] + [
-                        ("kernel", True, h) for h in args.heads]:
+                want = None
+                for name, heads in [("plain", 0)] + [
+                        (n, h) for h in args.heads for n in ("walk", "fused")]:
                     if heads:
-                        from areal_tpu.ops.pallas import kda_chunk
-                        kda_chunk.HEADS = heads
-                    rule = functools.partial(kda.delta_rule, chunk=chunk, kernel=kernel)
-                    fwd = jax.jit(lambda *a: rule(*a, seg))
+                        kda_chunk.HEADS = kda_fwd.HEADS = heads
+
+                    def rule(q, k, v, f, *rest, name=name):
+                        q, k, v, f = (a.reshape(1, T, H, K) for a in (q, k, v, f))
+                        if name == "walk":
+                            return walk(q, k, v, f, *rest, seg, chunk, True, group)
+                        return kda._rule(q, k, v, f, *rest, seg, chunk, name == "fused", group)
+
+                    fwd = jax.jit(rule)
                     both = jax.jit(jax.grad(
-                        lambda *a: jnp.sum(rule(*a, seg) * w), tuple(range(7))))
-                    row = dict(fill=fill, chunk=chunk, group=group, walk=name, heads=heads,
+                        lambda *a: jnp.sum(rule(*a) * w), tuple(range(7))))
+                    row = dict(fill=fill, chunk=chunk, group=group, arm=name, heads=heads,
                                fwd_ms=timed(fwd, xs) * 1e3,
                                fwd_bwd_ms=timed(both, xs) * 1e3)
+                    o = fwd(*xs).astype(jnp.float32)
+                    if name == "plain":
+                        want = o
+                    elif name == "fused":
+                        row["max_diff"] = float(jnp.abs(o - want).max())
+                        row["max_abs"] = float(jnp.abs(want).max())
                     rows.append(row)
                     print(json.dumps(row), flush=True)
                     jax.clear_caches()

@@ -1,9 +1,10 @@
 """The delta rule over packed rows (`areal_tpu/ops/kda.py`): the chunked
 form and its hand-written backward against the recurrence token by token
 (`benchmark/reference/kimi_linear.delta_rule`), decays small enough to
-underflow a chunk, the kernels of `ops/pallas/kda_chunk.py` in interpret
-mode, a packed row against each of its sequences alone, and the host's
-counts. CPU, float32, toy widths."""
+underflow a chunk, the kernels of `ops/pallas/kda_chunk.py` and the
+forward's one kernel (`ops/pallas/kda_fwd.py`) in interpret mode, a packed
+row against each of its sequences alone, and the host's counts. CPU,
+float32, toy widths."""
 
 import jax
 import jax.numpy as jnp
@@ -12,7 +13,7 @@ import pytest
 
 from areal_tpu.models.config import KDAConfig
 from areal_tpu.ops import kda
-from areal_tpu.ops.pallas import kda_chunk
+from areal_tpu.ops.pallas import kda_chunk, kda_fwd
 from benchmark.reference import kimi_linear as ref
 
 H, K = 2, 16
@@ -134,12 +135,14 @@ def test_intra_takes_no_exponential_of_a_positive_number(monkeypatch):
 @pytest.mark.parametrize("chunk", [16, 64])
 def test_the_kernels_walk_is_the_plain_walk(chunk, monkeypatch):
     """`kda_fwd_states` and `kda_bwd_states` in interpret mode against
-    `states_scan` and its transpose, through the whole rule: a row with
-    an empty tail (its dead chunks read zero) beside a full one."""
+    `states_scan` and its transpose, through the whole rule's backward
+    loop (the forward is the one kernel's, `kda_fwd_rule`): a row with an
+    empty tail (its dead chunks read zero) beside a full one."""
     ran = []
-    for name in ("states_fwd", "states_bwd"):
-        fn = getattr(kda_chunk, name)
-        monkeypatch.setattr(kda_chunk, name, lambda *a, _fn=fn, _n=name, **kw: (
+    for mod, name in ((kda_chunk, "states_fwd"), (kda_chunk, "states_bwd"),
+                      (kda_fwd, "rule_fwd")):
+        fn = getattr(mod, name)
+        monkeypatch.setattr(mod, name, lambda *a, _fn=fn, _n=name, **kw: (
             ran.append(_n) or _fn(*a, **kw)))
     jax.clear_caches()  # `delta_rule` is jitted at module level: trace it through the patches
     *args, seg = _inputs(rows=((50, 40), (100, 92)))
@@ -151,10 +154,82 @@ def test_the_kernels_walk_is_the_plain_walk(chunk, monkeypatch):
         np.testing.assert_allclose(np.asarray(got), np.asarray(plain(*args)), atol=1e-6)
         assert not np.asarray(got[0, 90:]).any()
         _assert_close(_grads(kernel, args, w), _grads(plain, args, w), 1e-6)
-    # a group a call: the forward, then the forward with its backward, which
-    # walks a group's chunks forwards again for their states
+    # the forward, then the forward with its backward: one call of the
+    # forward's kernel each; the backward loop walks a group's chunks
+    # forwards again for their states, then backwards, a group a call
     groups = 192 // chunk // kda._group(2, 192 // chunk, chunk, kda.GROUP_CELLS)
-    assert ran.count("states_fwd") == 3 * groups and ran.count("states_bwd") == groups
+    assert ran.count("rule_fwd") == 2
+    assert ran.count("states_fwd") == groups and ran.count("states_bwd") == groups
+
+
+# rows of 256 cells for the forward's one kernel: (a) sequences that start
+# in the middle of a chunk and of a sub-block of 16, (b) rows that end
+# before the row's last group, one before the other, (c) decays of 0.01 a
+# token, which underflow a chunk, (d) a row with no token beside a full one
+FWD_ROWS = {
+    "mid_starts": dict(rows=((50, 77, 30, 41), (100, 64, 92))),
+    "dead_groups": dict(rows=((50, 40), (150,))),
+    "underflow": dict(rows=((50, 77, 30), (100, 64)), g_max=4.7, g_min=4.5),
+    "empty_row": dict(rows=((), (100, 64, 92))),
+}
+
+
+@pytest.mark.parametrize("chunk", [16, 64])
+@pytest.mark.parametrize("case", list(FWD_ROWS))
+def test_the_forwards_one_kernel_is_intra_and_the_plain_walk(case, chunk, monkeypatch):
+    """`kda_fwd_rule` in interpret mode against `decay`, `intra` and
+    `states_scan` a group at a time: `O`, the state each group received
+    (`bounds`, zeros from a row's first dead group on: nothing reads them),
+    dead chunks zero; and `jax.grad` of the rule with the kernel's forward
+    is the plain rule's (the backward loop is shared, so this pins the
+    residuals)."""
+    monkeypatch.setattr(kda, "GROUP_CELLS", 128)  # groups of 64 cells of both rows
+    q, k, v, g, b, seg = _inputs(T=256, **FWD_ROWS[case])
+    f = jnp.where(g < 0, jnp.log(jnp.expm1(-jnp.where(g < 0, g, -1.0))), 0.0)
+    A, bias = -jnp.ones((H,)), jnp.zeros((H, K))
+    gs = kda._group(2, 256 // chunk, chunk, 128)
+    with jax.default_matmul_precision("highest"):
+        o, bounds = kda_fwd.rule_fwd(q, k, v, f, b, A, bias, seg, kda._live_chunks(seg, chunk),
+                                     chunk, gs, interpret=True)
+        want_o, res = kda._rule_fwd_groups(q, k, v, f, b, A, bias, seg, chunk, False, 128)
+    assert np.isfinite(np.asarray(o)).all() and np.isfinite(np.asarray(bounds)).all()
+    np.testing.assert_allclose(np.asarray(o), np.asarray(want_o), atol=2e-6)
+    live = np.asarray(kda._live_chunks(seg, chunk))  # [R]
+    want_b = np.asarray(res[-1])
+    assert bounds.shape == want_b.shape == (256 // chunk // gs, 2, H, K, K)
+    for r in range(2):
+        reached = -(-int(live[r]) // gs)
+        np.testing.assert_allclose(np.asarray(bounds[:reached, r]), want_b[:reached, r],
+                                   atol=2e-6, rtol=1e-5)
+        assert not np.asarray(bounds[reached:, r]).any()
+        assert not np.asarray(o[r, int(live[r]) * chunk:]).any()
+    args = (q, k, v, g, b)
+    w = jnp.asarray(np.random.default_rng(1).normal(size=v.shape), jnp.float32)
+    with jax.default_matmul_precision("highest"):
+        _assert_close(_grads(lambda *a: _rule(*a, seg, chunk, "interpret"), args, w),
+                      _grads(lambda *a: _rule(*a, seg, chunk, False), args, w), 2e-6)
+
+
+def test_the_forwards_one_kernel_takes_no_exponential_of_a_positive_number(monkeypatch):
+    """Every `exp` of the kernel's trace gets an argument that is at most 0
+    (`test_intra_takes_no_exponential_of_a_positive_number`'s meaning):
+    the diagonal sub-blocks cell by cell under `min(G_i - G_j, 0)`, the
+    others relative to the later sub-block's first cell."""
+    seen = []
+    exp = jnp.exp
+    monkeypatch.setattr(kda_fwd.jnp, "exp", lambda x: seen.append(float(jnp.max(x))) or exp(x))
+    monkeypatch.setattr(kda_fwd.pltpu, "roll", jnp.roll)  # the kernel's has no eager rule
+    q, k, v, g, b, seg = _inputs(g_max=4.7)
+    C = 64
+    f = jnp.where(g < 0, jnp.log(jnp.expm1(-jnp.where(g < 0, g, -1.0))), 0.0)
+    side = lambda h: (q[:1, :C, h], k[:1, :C, h], v[:1, :C, h], f[:1, :C, h], b[:1, :C, h:h + 1],
+                      -jnp.ones((1, 1, K)), jnp.zeros((1, 1, K)))
+    scratch = [np.zeros((2, 1, C, K), np.float32) for _ in range(3)]
+    with jax.disable_jit():
+        outs = kda_fwd._chunk([side(0), side(1)], [jnp.zeros((1, K, K))] * 2, scratch,
+                              jnp.tile(seg[:1, :C], (1, 2)), 0, int(seg[0, C - 1]), jnp.float32)
+    assert len(seen) >= 2 * (4 * 16 + 5) and max(seen) <= 0.0
+    assert all(np.isfinite(np.asarray(a)).all() for o_s in outs for a in o_s)
 
 
 def _mixer_inputs(D=32, T=64, lens=((20, 30, 10), (45, 11)), seed=0):

@@ -521,12 +521,14 @@ def test_an_accumulate_step_of_the_four_stream_stack_compiles_at_8k(one_chip, mo
 
 def test_the_delta_rules_kernels_compile_at_the_published_widths(one_chip):
     """`kimilinear-d5e8-train-ppo-long`'s delta rule over a row of 16,384
-    at 32 heads of 128 x 128, bf16 (`ops/kda.py`): a loop over groups of
-    16 chunks each way, its trip count a value of the run; the walk over a
-    group's chunks is a custom call, `kda_fwd_states` in the forward loop,
-    that again and `kda_bwd_states` in the backward loop, each with the
-    group's live chunks as a scalar the index maps read. What stands in
-    memory beside the inputs and their cotangents is a group's."""
+    at 32 heads of 128 x 128, bf16 (`ops/kda.py`): the forward is one
+    custom call over the whole row, `kda_fwd_rule` (decay, `intra` and the
+    walk, a chunk of four heads a grid step, the row's live chunks a
+    scalar the index maps read); the backward a loop over groups of 16
+    chunks whose trip count is a value of the run, the walk over a group's
+    chunks two custom calls in it, `kda_fwd_states` (the chunks' states
+    again) and `kda_bwd_states`. What stands in memory beside the inputs
+    and their cotangents is a group's."""
     from areal_tpu.ops import kda
 
     t, h, k = 16384, 32, 128
@@ -542,8 +544,9 @@ def test_the_delta_rules_kernels_compile_at_the_published_widths(one_chip):
         q, q, q, q, b, a, bias, seg).compile()
     text = compiled.as_text()
     assert text.count("tpu_custom_call") == 3
-    assert "kda_fwd_states" in text and "kda_bwd_states" in text
-    assert text.count(" while(") >= 2  # the groups, each way
+    for name in ("kda_fwd_rule", "kda_fwd_states", "kda_bwd_states"):
+        assert name in text, name
+    assert text.count(" while(") >= 1  # the groups, backwards
     assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
 
 
@@ -583,8 +586,10 @@ def test_an_accumulate_step_of_the_delta_rule_stack_compiles_at_16k(one_chip, mo
     model at its one shape `(1, 16384)`, full remat, the masked loss head:
     four delta-rule layers (one alone, a scan of two, one alone) and the
     latent layer between them, every layer's token-wise stretches over the
-    row's live bands, the rule's two kernels beside the pair kernels at
-    192 against 128 and the experts' row adds. The compiler's temporaries:
+    row's live bands, the rule's three kernels (`kda_fwd_rule` in the
+    forward and in remat's, `kda_fwd_states` and `kda_bwd_states` in the
+    backward loop) beside the pair kernels at 192 against 128 and the
+    experts' row adds. The compiler's temporaries:
     6.1 GB beside 8.43 GB of weights, gradient sums and moments (10.7 GB
     with the rule's parts and decays held a row at a time and the
     stretches over the whole row: PERF.md section 6, PR 50)."""
@@ -593,7 +598,8 @@ def test_an_accumulate_step_of_the_delta_rule_stack_compiles_at_16k(one_chip, mo
     cfg, compiled = _accumulate_step(one_chip, monkeypatch, "kimi-linear-d5-e8", 16384)
     assert looping_layers(cfg, 1, 16384) == 5
     text = compiled.as_text()
-    for name in ("kda_fwd_states", "kda_bwd_states", "splash_pairs_bwd", "moe_rows_add"):
+    for name in ("kda_fwd_rule", "kda_fwd_states", "kda_bwd_states", "splash_pairs_bwd",
+                 "moe_rows_add"):
         assert name in text, name
     assert compiled.memory_analysis().temp_size_in_bytes < 6.5e9
     _holds_dq_once(cfg, compiled, "kimi-linear-d5-e8", 16384)
