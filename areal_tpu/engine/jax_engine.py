@@ -144,7 +144,8 @@ def _kinds_label(cfg: TransformerConfig) -> str:
     layer that reads layer n's tensor `<n`, one that keeps its own `^`:
     `ssm+dense^`, `dense.diff.full.nope<5`, `gmu+dense<4`; latent
     attention says `latent.`, a delta-rule mixer beside an MLP `kda.` and
-    its chunk (`moe.kda.c64`), attention over the keys an indexer chooses
+    its chunk (`moe.kda.c64`; with one decay a head and h key heads
+    `moe.kda.head.k16.c64`), attention over the keys an indexer chooses
     `indexed.`, a layer over four residual streams starts with `hc4.`, and
     a prediction module after the stack ends the label with `+mtp`."""
     streams = f"hc{cfg.hyper.n}." if cfg.hyper is not None else ""
@@ -158,7 +159,8 @@ def _kinds_label(cfg: TransformerConfig) -> str:
         if k.block:
             return f"{streams}{k.mlp}.{attn}{keeps}{reads}"
         if k.mixer == "kda" and k.mlp is not None:
-            return f"{k.mlp}.kda.c{cfg.kda.chunk_size}"
+            form = "" if cfg.kda.decay == "channel" else f"head.k{cfg.kda.key_heads}."
+            return f"{k.mlp}.kda.{form}c{cfg.kda.chunk_size}"
         return (f"attn.{attn}{keeps}" if k.mixer == "attention" else k.parts) + reads
 
     names = [name(k) for k in cfg.kinds()]

@@ -67,6 +67,9 @@ class MoEConfig:
     # The shared MLP's width where the config states it; None = the
     # expert width times n_shared_experts.
     shared_intermediate_dim: Optional[int] = None
+    # The shared MLP's product times `sigmoid(x w_s)`, `w_s` hidden -> 1
+    # without a bias (Qwen's `shared_expert_gate`).
+    shared_gate: bool = False
     # (first, count): the contiguous range of experts whose weights this
     # chip holds, as one share of an expert-parallel layer. The router
     # keeps all `num_experts` outputs and its top-k; the layer computes
@@ -164,33 +167,91 @@ class SSMConfig:
 
 @dataclasses.dataclass
 class KDAConfig:
-    """A delta-rule mixer with a decay for every channel (Kimi Delta
-    Attention, arXiv:2510.26692; `ops/kda.py` has the equations):
-    `n_heads` heads whose keys and values are `head_dim` wide, a state of
-    `head_dim x head_dim` a head, q, k and v each through a causal
-    depthwise convolution of `conv_kernel` taps, the decay and the output
-    gate through low-rank products of `gate_rank`, the recurrence computed
-    in chunks of `chunk_size` positions. `dt_min`, `dt_max`, `dt_floor`:
-    the seeded draw of `dt_bias`, as `SSMConfig`'s."""
+    """A delta-rule mixer (`ops/kda.py` has the equations), in the two
+    published forms, side by side:
+
+    ====================  ==============================  ===============================
+    .                     Kimi Delta Attention            Gated DeltaNet
+                          (arXiv:2510.26692; defaults)    (arXiv:2412.06464, Qwen3-Next)
+    ====================  ==============================  ===============================
+    `decay`               "channel": g `[T, H, K]`        "head": g `[T, H]`
+    `decay_input`         "lowrank": `(h W_fa) W_fb`      "column": `h W_a`, a column a head
+    `n_key_heads`         None: as many as `n_heads`      divides `n_heads`: value head j
+                                                          reads key head `j // (H / Hk)`
+    `gate_rank`           an int: `(h W_ga) W_gb`         None: a full-rank `h W_g`
+    `gate_act`            "sigmoid"                       "silu"
+    state a value head    `Diag(exp(g_t))` S              `exp(g_t)` S
+    ====================  ==============================  ===============================
+
+    Both: `n_heads` (value) heads whose keys and values are `head_dim`
+    wide, a state of `head_dim x head_dim` a value head, q, k and v each
+    through a causal depthwise convolution of `conv_kernel` taps and silu,
+    q and k made unit a head, `g = -exp(A_log) softplus(x + dt_bias)`,
+    beta a sigmoid a value head, the recurrence computed in chunks of
+    `chunk_size` positions, an RMSNorm a head on the output under the
+    gate. `dt_min`, `dt_max`, `dt_floor`: the seeded draw of `dt_bias`, as
+    `SSMConfig`'s. A combination that has no code is refused here."""
 
     n_heads: int = 2
     head_dim: int = 16
     conv_kernel: int = 4
-    gate_rank: int = 16
+    gate_rank: Optional[int] = 16
     chunk_size: int = 64
     dt_min: float = 0.001
     dt_max: float = 0.1
     dt_floor: float = 1e-4
+    n_key_heads: Optional[int] = None
+    decay: str = "channel"  # channel | head
+    decay_input: str = "lowrank"  # lowrank | column
+    gate_act: str = "sigmoid"  # sigmoid | silu
 
     def __post_init__(self):
         if self.chunk_size % 16:
             raise ValueError(
                 f"KDAConfig.chunk_size must be a multiple of 16 (the sub-blocks "
                 f"inside which decays are taken cell by cell), got {self.chunk_size}")
+        if self.decay not in ("channel", "head") or self.gate_act not in ("sigmoid", "silu"):
+            raise ValueError(
+                f"KDAConfig: decay is 'channel' or 'head' and gate_act 'sigmoid' or "
+                f"'silu', got {self.decay!r} and {self.gate_act!r}")
+        if self.decay_input != {"channel": "lowrank", "head": "column"}[self.decay]:
+            raise NotImplementedError(
+                f"KDAConfig: a decay a {self.decay} from decay_input "
+                f"{self.decay_input!r}: models/transformer._kda_in makes a decay a "
+                "channel from the low-rank pair ('lowrank') and a decay a head from "
+                "a column of its own ('column'), and nothing else")
+        if self.n_heads % self.key_heads:
+            raise ValueError(
+                f"KDAConfig: {self.key_heads} key heads do not divide "
+                f"{self.n_heads} value heads")
+        if self.key_heads != self.n_heads and self.decay == "channel":
+            raise NotImplementedError(
+                "KDAConfig: fewer key heads than value heads under a decay a "
+                "channel: ops/kda.py's channel form scales a key by its own head's "
+                "decays before every product and has no shared key to read")
+        if self.gate_rank is None and self.decay_input == "lowrank":
+            raise NotImplementedError(
+                "KDAConfig: a full-rank output gate beside a low-rank decay is in no "
+                "published model; models/transformer._kda_in has no such pair")
+
+    @property
+    def key_heads(self) -> int:
+        return self.n_heads if self.n_key_heads is None else self.n_key_heads
 
     @property
     def d_inner(self) -> int:
+        """v's, the gate's and the output's width."""
         return self.n_heads * self.head_dim
+
+    @property
+    def d_key(self) -> int:
+        """q's and k's width."""
+        return self.key_heads * self.head_dim
+
+    @property
+    def d_decay(self) -> int:
+        """The decay's width: a channel of every value head, or a value head."""
+        return self.d_inner if self.decay == "channel" else self.n_heads
 
 
 @dataclasses.dataclass
@@ -488,6 +549,9 @@ class TransformerConfig:
     # original_max_position_embeddings), carried from the HF config.
     rotary_scaling_params: Optional[dict] = None
     rotary_interleaved: bool = False
+    # The share of a head's columns the rotary embedding turns, from the
+    # first (`partial_rotary_factor`): the rest are left as they are.
+    rotary_fraction: float = 1.0
 
     attn_bias: bool = False  # qwen2 uses qkv bias
     attn_out_bias: bool = False  # gpt2 also biases the output projection
@@ -620,6 +684,20 @@ class TransformerConfig:
                     "differential or neither")
         if any(k.diff for k in kinds) and (self.n_q_heads % 2 or self.n_kv_heads % 2):
             raise ValueError("differential attention pairs the heads: even counts")
+        if self.rotary_fraction != 1.0:
+            turned = self.head_dim * self.rotary_fraction
+            if (not 0.0 < self.rotary_fraction < 1.0 or turned != int(turned)
+                    or int(turned) % 2):
+                raise ValueError(
+                    f"rotary_fraction {self.rotary_fraction} of a head of "
+                    f"{self.head_dim} is no even number of columns")
+            if self.mla is not None or self.indexer is not None or any(
+                    k.diff for k in kinds):
+                raise NotImplementedError(
+                    "a partial rotation beside latent attention (its rope part is "
+                    "its own), an indexer or differential attention: "
+                    "models/transformer.py turns the first columns of a plain "
+                    "head's q and k and nothing else")
 
     @property
     def q_dim(self) -> int:
@@ -631,9 +709,13 @@ class TransformerConfig:
 
     @property
     def rotary_dim(self) -> int:
-        """The width the rotary embedding turns: a whole head, or latent
-        attention's rope part."""
-        return self.mla.rope_dim if self.mla is not None else self.head_dim
+        """The width the rotary embedding turns: a whole head (its first
+        `rotary_fraction`), or latent attention's rope part."""
+        if self.mla is not None:
+            return self.mla.rope_dim
+        if self.rotary_fraction == 1.0:
+            return self.head_dim
+        return int(self.head_dim * self.rotary_fraction)
 
     def kinds(self) -> Tuple[LayerKind, ...]:
         """The kind of every layer, in order."""
@@ -790,10 +872,11 @@ class TransformerConfig:
                 "none per layer): the layers here are "
                 f"{sorted({(k.mlp, k.window or 0, k.rotary) for k in kinds})}"
             )
-        if self.attn_gate or self.post_norms:
+        if self.attn_gate or self.post_norms or self.rotary_fraction != 1.0:
             missing.append(
-                "the attention output gate and the post-attention / post-MLP "
-                "norms in the decode layer"
+                "the attention output gate, the post-attention / post-MLP "
+                "norms and a rotation of part of a head (rotary_fraction "
+                f"{self.rotary_fraction}) in the decode layer"
             )
         if self.moe is not None and (
             self.moe.experts_held is not None or self.moe.score_func != "softmax"

@@ -575,10 +575,16 @@ def _held_experts(xt, mp, moe, act, cdt, choice_e, gate, token_mask,
 
 
 def _shared_expert(xt, sp, act, cdt):
-    """The MLP every token passes through, gated or plain."""
+    """The MLP every token passes through, gated or plain; times
+    `sigmoid(x w_s)` where it has a gate of its own (`w_s`, scope
+    `moe_shared_gate`)."""
     with jax.named_scope("moe_shared"):
         mats = ("w_in", "w_out") if "w_in" in sp else ("w_gate", "w_up", "w_down")
-        return _expert_ffn(xt.astype(cdt), tuple(sp[m].astype(cdt) for m in mats), act)
+        y = _expert_ffn(xt.astype(cdt), tuple(sp[m].astype(cdt) for m in mats), act)
+        if "w_s" in sp:
+            with jax.named_scope("moe_shared_gate"):
+                y = y * jax.nn.sigmoid(xt.astype(cdt) @ sp["w_s"].astype(cdt))
+        return y
 
 
 def moe_mlp(
@@ -779,4 +785,6 @@ def init_moe_params(cfg: TransformerConfig, dense_fn, keys, n_layers: int,
         mp["shared"] = {name: dense_fn(key, (L, D, Fs))
                         for name, key in zip(mats[:-1], ks)}
         mp["shared"][mats[-1]] = dense_fn(ks[2], (L, Fs, D))
+        if moe.shared_gate:
+            mp["shared"]["w_s"] = dense_fn(jax.random.fold_in(shared_key, 3), (L, D, 1))
     return mp

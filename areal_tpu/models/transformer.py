@@ -143,6 +143,11 @@ def _init_layer_stack(cfg: TransformerConfig, keys, n: int, kind: LayerKind,
             }
             if cfg.qk_norm:
                 attn["q_norm"] = attn["q_norm"] * _INDEXED_Q_GAIN
+        if cfg.qk_norm and cfg.rotary_fraction != 1.0:
+            # `_LATENT_Q_GAIN`'s reason: under unit scores a softmax over
+            # thousands of keys is flat, and no check of logprobs would see
+            # which of a head's columns were turned
+            attn["q_norm"] = attn["q_norm"] * _LATENT_Q_GAIN
         layers["attn"] = attn
     elif kind.mixer == "ssm" and cfg.ssm.form == "mamba1":
         from areal_tpu.ops.selective_scan import init_sscan_params
@@ -744,7 +749,9 @@ _ATTN_IN = ("wq", "wk", "wv", "bq", "bk", "bv", "q_norm", "k_norm", "wg", "index
 # Of a delta-rule mixer's, those its first step reads (the projections)
 # and its second (the head norm, the output projection); the rest are
 # what crosses tokens reads (`ops/kda.kda_mixer`).
-_KDA_IN = ("wq", "wk", "wv", "w_fa", "w_fb", "w_b", "w_ga", "w_gb")
+# The decay's input and the gate by the rule's form (`KDAConfig`): the
+# low-rank pairs, or a column a head `w_a` and a full-rank `w_g`.
+_KDA_IN = ("wq", "wk", "wv", "w_fa", "w_fb", "w_a", "w_b", "w_ga", "w_gb", "w_g")
 _KDA_OUT = ("o_norm", "wo")
 
 
@@ -752,7 +759,7 @@ def _mixer_weights(st: _Stretch, mp, first: bool):
     """The part of a mixer's parameters `mp` that its first step reads, or
     its second."""
     if st.kind.mixer == "kda":
-        return {n: mp[n] for n in (_KDA_IN if first else _KDA_OUT)}
+        return {n: mp[n] for n in (_KDA_IN if first else _KDA_OUT) if n in mp}
     first_names = set(mp) - {"wo"} if st.kind.latent else _ATTN_IN
     return {n: w for n, w in mp.items() if (n in first_names) == first}
 
@@ -787,25 +794,33 @@ def _hc_write(st: _Stretch, x, y, h_post=None, h_res=None):
 
 def _kda_in(h, mp, cdt):
     """A delta-rule mixer's projections of its normed input h `[R, T, D]`
-    (scope `kda_proj`; the two low-rank products under `kda_gate`): q, k,
-    v `[R, T, H K]` before their convolutions, the decay's `(h W_fa) W_fb`
-    `[R, T, H K]`, beta's `h W_b` `[R, T, H]`, the output gate's
-    `(h W_ga) W_gb` `[R, T, H K]`."""
+    (scope `kda_proj`; the decay's and the gate's under `kda_gate`): q, k
+    `[R, T, Hk K]` and v `[R, T, H K]` before their convolutions, the
+    decay's input (`(h W_fa) W_fb` `[R, T, H K]`, or a column a head `h
+    W_a` `[R, T, H]`), beta's `h W_b` `[R, T, H]`, the output gate's
+    (`(h W_ga) W_gb`, or the full-rank `h W_g`) `[R, T, H K]`."""
     with jax.named_scope("kda_proj"):
         q, k, v, b = (h @ mp[n].astype(cdt) for n in ("wq", "wk", "wv", "w_b"))
         with jax.named_scope("kda_gate"):
-            f = (h @ mp["w_fa"].astype(cdt)) @ mp["w_fb"].astype(cdt)
-            gate = (h @ mp["w_ga"].astype(cdt)) @ mp["w_gb"].astype(cdt)
+            if "w_a" in mp:
+                f = h @ mp["w_a"].astype(cdt)
+            else:
+                f = (h @ mp["w_fa"].astype(cdt)) @ mp["w_fb"].astype(cdt)
+            if "w_g" in mp:
+                gate = h @ mp["w_g"].astype(cdt)
+            else:
+                gate = (h @ mp["w_ga"].astype(cdt)) @ mp["w_gb"].astype(cdt)
     return q, k, v, f, b, gate
 
 
 def _kda_out(o, gate, mp, cfg, cdt):
     """The rule's output o `[R, T, H, K]` -> the mixer's `[R, T, D]`: an
-    RMSNorm a head, the sigmoid gate, the output projection (scope
-    `kda_out`)."""
+    RMSNorm a head, the gate (`KDAConfig.gate_act`: a sigmoid, or silu),
+    the output projection (scope `kda_out`)."""
     with jax.named_scope("kda_out"):
         o = rms_norm(o, mp["o_norm"], cfg.norm_eps).reshape(gate.shape)
-        return (o * jax.nn.sigmoid(gate)) @ mp["wo"].astype(cdt)
+        act = jax.nn.silu if cfg.kda.gate_act == "silu" else jax.nn.sigmoid
+        return (o * act(gate)) @ mp["wo"].astype(cdt)
 
 
 def _before_mixer(st: _Stretch, w, xs, side):

@@ -1,19 +1,32 @@
-"""A delta-rule mixer with a decay for every channel (Kimi Delta
-Attention, arXiv:2510.26692) over packed rows.
+"""A delta-rule mixer over packed rows, in the two published forms: a
+decay for every channel (Kimi Delta Attention, arXiv:2510.26692) and one
+decay a head under fewer key heads than value heads (Gated DeltaNet,
+arXiv:2412.06464, as Qwen3-Next runs it). `KDAConfig` says which.
 
-For a layer's normalised input `h` [R, T, D], with H heads whose keys and
-values are K = V = `head_dim` wide:
+For a layer's normalised input `h` [R, T, D], with H value heads and Hk key
+heads (Hk = H a decay a channel; Hk divides H a decay a head, value head j
+reading key head `j // (H / Hk)`) whose keys and values are K = V =
+`head_dim` wide, side by side:
 
-    q, k, v = silu(conv(h W_q)), silu(conv(h W_k)), silu(conv(h W_v))   [T, H, K]
-              conv: causal, depthwise, `conv_kernel` taps, no bias; a tap is
-              dropped unless its position lies in the token's own sequence
-    q, k    = q * rsqrt(sum q^2 + 1e-6), k likewise, a head;  q <- q * K^-0.5
-    g       = -exp(A_log)[H] * softplus((h W_fa) W_fb + dt_bias)   [T, H, K] float32, <= 0
-    b       = sigmoid(h W_b)                                        [T, H]
-    S_t     = (I - b_t k_t k_t^T) Diag(exp(g_t)) S_{t-1} + b_t k_t v_t^T   [K, V] a head,
-              float32, S = 0 before a sequence's first token
-    o_t     = S_t^T q_t
-    out     = (RMSNorm_head(o) * sigmoid((h W_ga) W_gb)) W_o
+    a decay a channel (`decay="channel"`)        | a decay a head (`decay="head"`)
+    q, k, v = silu(conv(h W_q)), .. W_k, .. W_v  | the same; q, k [T, Hk, K], v [T, H, K]
+      conv: causal, depthwise, `conv_kernel` taps, no bias; a tap is dropped
+      unless its position lies in the token's own sequence
+    q, k = q * rsqrt(sum q^2 + 1e-6), k likewise, a head;  q <- q * K^-0.5
+    g = -exp(A_log)[H] softplus((h W_fa) W_fb    | g = -exp(A_log)[H] softplus(h W_a
+        + dt_bias[H, K])    [T, H, K] float32    |     + dt_bias[H])        [T, H] float32
+    b = sigmoid(h W_b)                                                   [T, H]
+    S_t = (I - b_t k_t k_t^T) Diag(exp(g_t))     | S_t = exp(g_t) (I - b_t k_t k_t^T) S_{t-1}
+          S_{t-1} + b_t k_t v_t^T                |       + b_t k_t v_t^T
+      [K, V] a value head, float32, S = 0 before a sequence's first token
+    o_t = S_t^T q_t
+    out = (RMSNorm_head(o) * sigmoid((h W_ga)    | out = (RMSNorm_head(o) * silu(h W_g)) W_o
+          W_gb)) W_o                             |
+
+(The released code of the second decays the state first, `S <- exp(g_t) S`,
+then corrects: `d = b_t (v_t - S^T k_t)`, `S <- S + k_t d^T`. With a scalar
+decay that is the line above; with a decay a channel the first's order,
+`(I - b k k^T) Diag(a) S`, is the same statement.)
 
 What is token-wise (the projections before, the head norm, gate and `W_o`
 after) is `models/transformer.py`'s (`_before_mixer`, `_after_mixer`);
@@ -25,15 +38,34 @@ convolution start afresh at every sequence start; a padding cell has
 b = 0, g = 0 and q = k = v = 0, so it adds nothing to any state and its own
 result is 0.
 
-**The rule in chunks** of `chunk_size` C positions (`delta_rule`). With
-`G_i` the running sum of `g` inside a chunk (restarting nowhere: the masks
-do the restarting), a chunk that receives the state `S_0`:
+**The rule in chunks** of `chunk_size` C positions (`delta_rule`; one
+`custom_vjp`, one `_Groups`, one walk for both decays: the branch is on the
+decay's rank, known when the program is traced). With `G_i` the running sum
+of `g` inside a chunk (restarting nowhere: the masks do the restarting), a
+chunk that receives the state `S_0`, a decay a channel:
 
     A[i, j] = b_i sum_d k_i[d] k_j[d] exp(G_i[d] - G_j[d])   j < i of one sequence, else 0
     T = (I + A)^-1 Diag(b);   W = T (K * exp(G));   U = T V
     O = (Q * exp(G)) S_0' + tril(P) (U - W S_0'),
         P[i, j] = sum_d q_i[d] k_j[d] exp(G_i[d] - G_j[d]),  j <= i of one sequence
     S_C = Diag(exp(G_C)) S_0' + (K * exp(G_C - G))^T (U - W S_0')
+
+A decay a head (`_intra_head`): `G` is one number a position a value head
+and `D[i, j] = exp(G_i - G_j)` for `j <= i` a `[C, C]` matrix, at most 1, so
+
+    A = b (.) tril(K K^T, -1) (.) D;   T = (I + A)^-1 Diag(b)
+    W = T (K * exp(G));   U = T V
+    O = exp(G) * (Q S_0') + (tril(Q K^T) (.) D) (U - W S_0')
+    S_C = exp(G_C) S_0' + (K * exp(G_C - G))^T (U - W S_0')
+
+one product `K K^T` and one `Q K^T` a key head and one exponential a value
+head, every exponent at most 0 and no sub-blocks. **The decay a head is
+never laid out a channel in HBM**: `f`, `g` and their gradients are `[T,
+H]`; a kernel spreads them over lanes in VMEM, and of the parts only a
+chunk's `dec` holds a head's number K times (a chunk's, not a cell's: the
+walk then serves both). q and k stay a key head's: the forward kernel reads
+them through its blocks' index, the plain form spreads the `[C, C]` products
+over a key head's value heads, and their gradients sum over those.
 
 `S_0'` is `S_0` for the cells of the sequence that crossed into the chunk
 and 0 for the rest; a chunk hands on the state of the sequence its last
@@ -47,18 +79,23 @@ the group of its last token.
 **Which form runs where** (`_use_kernel`: a TPU backend, one device, heads
 of whole lane tiles; decided from what the code sees, no argument):
 
-- forward, on the chip: one kernel over the whole row, `kda_fwd_rule`
-  (`ops/pallas/kda_fwd.py`): the decay, `intra`'s formulas in `intra`'s
-  dtypes and the walk, a chunk a grid step with the state in VMEM; q, k,
-  v, f read and O written cells-major, nothing else of a chunk in HBM.
-  Under full remat both forward runs of a step take it.
+- forward, on the chip, both decays: one kernel over the whole row,
+  `kda_fwd_rule` (`ops/pallas/kda_fwd.py`): the decay, `intra`'s formulas
+  in `intra`'s dtypes (the channel form's sub-blocks, or the head form's
+  one `[C, 2 C]` exponential a pair of heads) and the walk, a chunk a grid
+  step with the state in VMEM; q, k, v, f read and O written cells-major,
+  nothing else of a chunk in HBM. Under full remat both forward runs of a
+  step take it. By the probe (`scripts/kda_probe.py`; PERF.md section 6,
+  PR 54), a row of 16,384 at 53 % fill, 32 value heads of 128, ms forward
+  / forward + backward: the channel form fed one decay K times 4.82 / 49.2,
+  the head form 3.48 / 22.7 and under 16 key heads 3.45 / 19.3: two forms.
 - backward loop, on the chip: group by group, `intra` under `jax.vjp` (the
   plain `jnp` below), the chunks' states again by `kda_fwd_states` and the
   walk backwards by `kda_bwd_states` (`ops/pallas/kda_chunk.py`).
 - the CPU, a mesh of several devices, toy heads: `intra` + `states_scan`
   a group at a time forward, `states_scan` and `states_scan_bwd` backward.
 
-**No exponential of a positive number.** `exp(G_i - G_j)` is never split
+**No exponential of a positive number.** A decay a channel: `exp(G_i - G_j)` is never split
 into `exp(G_i) exp(-G_j)` across a chunk (a decay of 0.2 a token over 64
 positions is 1e-45): a chunk is sub-blocks of 16; an off-diagonal
 sub-block is taken relative to the later sub-block's first position r
@@ -101,27 +138,39 @@ _mm32 = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
 def init_kda_params(kda: KDAConfig, hidden_dim: int, dense_fn, key, n_layers: int,
                     pdt) -> Dict[str, Any]:
     """`n_layers` mixers stacked on a leading axis. `A_log` (a head) and
-    `dt_bias` (a channel) as `ops/ssm.init_ssm_params` draws them: log of
-    a uniform draw from 1..16, the inverse softplus of a log-uniform step
-    in [dt_min, dt_max]: before the low-rank product moves it, a channel
-    forgets at 0.999 to 0.2 a token."""
-    L, D, d_in, r = n_layers, hidden_dim, kda.d_inner, kda.gate_rank
+    `dt_bias` (a channel, or a head where the decay is one) as
+    `ops/ssm.init_ssm_params` draws them: log of a uniform draw from
+    1..16, the inverse softplus of a log-uniform step in [dt_min,
+    dt_max]: before its input moves it, a channel (a head) forgets at
+    0.999 to 0.2 a token. The decay's input and the gate by the form:
+    the low-rank pairs `w_fa`, `w_fb` and `w_ga`, `w_gb`, or a column a
+    head `w_a` and a full-rank `w_g`."""
+    L, D, d_in, d_key, r = n_layers, hidden_dim, kda.d_inner, kda.d_key, kda.gate_rank
     ks = jax.random.split(key, 13)
-    dt = jnp.exp(jax.random.uniform(ks[0], (L, d_in), jnp.float32)
+    dt = jnp.exp(jax.random.uniform(ks[0], (L, kda.d_decay), jnp.float32)
                  * (math.log(kda.dt_max) - math.log(kda.dt_min))
                  + math.log(kda.dt_min))
     dt = jnp.maximum(dt, kda.dt_floor)
-    taps = lambda k: dense_fn(k, (L, kda.conv_kernel, d_in),
-                              1.0 / math.sqrt(kda.conv_kernel))
+    taps = lambda k, width: dense_fn(k, (L, kda.conv_kernel, width),
+                                     1.0 / math.sqrt(kda.conv_kernel))
+    if kda.decay_input == "lowrank":
+        decay_in = {"w_fa": dense_fn(ks[7], (L, D, r)), "w_fb": dense_fn(ks[8], (L, r, d_in))}
+    else:
+        decay_in = {"w_a": dense_fn(ks[7], (L, D, kda.n_heads))}
+    if r is None:
+        gate = {"w_g": dense_fn(ks[10], (L, D, d_in))}
+    else:
+        gate = {"w_ga": dense_fn(ks[10], (L, D, r)), "w_gb": dense_fn(ks[11], (L, r, d_in))}
     return {
-        "wq": dense_fn(ks[1], (L, D, d_in)),
-        "wk": dense_fn(ks[2], (L, D, d_in)),
+        "wq": dense_fn(ks[1], (L, D, d_key)),
+        "wk": dense_fn(ks[2], (L, D, d_key)),
         "wv": dense_fn(ks[3], (L, D, d_in)),
         # [taps, channels]: the last tap multiplies the position itself
-        "conv_q": taps(ks[4]), "conv_k": taps(ks[5]), "conv_v": taps(ks[6]),
-        "w_fa": dense_fn(ks[7], (L, D, r)), "w_fb": dense_fn(ks[8], (L, r, d_in)),
+        "conv_q": taps(ks[4], d_key), "conv_k": taps(ks[5], d_key),
+        "conv_v": taps(ks[6], d_in),
+        **decay_in,
         "w_b": dense_fn(ks[9], (L, D, kda.n_heads)),
-        "w_ga": dense_fn(ks[10], (L, D, r)), "w_gb": dense_fn(ks[11], (L, r, d_in)),
+        **gate,
         "A_log": jnp.log(jax.random.uniform(
             jax.random.fold_in(ks[0], 1), (L, kda.n_heads), jnp.float32, 1.0, 16.0)
         ).astype(pdt),
@@ -181,15 +230,75 @@ def unit(x):
 
 
 def decay(f, A, dt_bias, seg):
-    """The log-decay a channel: f [N, C, H, K] (the low-rank product), A
-    [H] float32 (`-exp(A_log)`), dt_bias [H, K] float32, seg [N, C] ->
-    `g = A softplus(f + dt_bias)` float32 <= 0, 0 at padding."""
-    g = A[:, None] * jax.nn.softplus(f.astype(jnp.float32) + dt_bias)
-    return jnp.where((seg > 0)[..., None, None], g, 0.0)
+    """The log-decay: f [N, C, H, K] (a channel: the low-rank product) or
+    [N, C, H] (a head: the projection's column), A [H] float32
+    (`-exp(A_log)`), dt_bias [H, K] or [H] float32, seg [N, C] ->
+    `g = A softplus(f + dt_bias)` float32 <= 0 in f's shape, 0 at padding."""
+    lift = (...,) + (None,) * (f.ndim - 3)  # a head's number over its channels, if any
+    g = A[lift] * jax.nn.softplus(f.astype(jnp.float32) + dt_bias)
+    return jnp.where((seg > 0)[lift + (None,)], g, 0.0)
 
 
 def intra(q, k, v, g, b, seg, before, cdt):
-    """What of a chunk does not depend on the state it receives. q, k
+    """What of a chunk does not depend on the state it receives, by the
+    decay's rank: `g` [N, C, H, K] a channel (`_intra_channel`) or [N, C,
+    H] a head (`_intra_head`). Both return, heads first and masks folded
+    in: Wm [N, H, C, K], U [N, H, C, V], Qg [N, H, C, K], Kd [N, H, C, K],
+    Pm [N, H, C, C] in `cdt`, dec [N, H, K] float32 (a head's decay over
+    the chunk stands K times there, a chunk's and not a cell's: the walk
+    then serves both)."""
+    return (_intra_head if g.ndim == 3 else _intra_channel)(q, k, v, g, b, seg, before, cdt)
+
+
+def _intra_head(q, k, v, g, b, seg, before, cdt):
+    """One decay a value head: q, k [N, C, Hk, K] (Hk key heads, value head
+    j reads key head `j // (H / Hk)`: never repeated, the products a key
+    head's and the `[C, C]` matrices spread over its value heads), v [N, C,
+    H, V], g, b [N, C, H] float32. `D[i, j] = exp(G_i - G_j)` is one number
+    a pair of positions, at most 1 for j <= i, so `A = b (.) tril(K K^T,
+    -1) (.) D` and `P = tril(Q K^T) (.) D` are one product a key head and
+    one exponential a value head: no sub-blocks."""
+    f32 = jnp.float32
+    N, C, Hk, K = q.shape
+    H = v.shape[2]
+    heads_first = lambda a: jnp.moveaxis(a, 2, 1)  # [N, C, H, ..] -> [N, H, C, ..]
+    q, k, v, g, b = (heads_first(a) for a in (q, k, v, g, b))
+    qf, kf = unit(q) * K ** -0.5, unit(k)
+    # a key head's array under each of its value heads: a broadcast, whose
+    # transpose sums the value heads' cotangents
+    per_v = lambda a: jnp.broadcast_to(
+        a[:, :, None], (N, Hk, H // Hk) + a.shape[2:]).reshape((N, H) + a.shape[2:])
+    G = jnp.cumsum(g, axis=2)  # [N, H, C] <= 0, falling
+
+    same = seg[:, :, None] == seg[:, None, :]  # [N, i, j]
+    seen = (same & jnp.tril(jnp.ones((C, C), bool)))[:, None]  # [N, 1, i, j]
+    D = jnp.exp(jnp.where(seen, G[..., :, None] - G[..., None, :], -jnp.inf))  # [N, H, i, j]
+    kc = kf.astype(cdt)
+    pairs = lambda x: per_v(jnp.einsum("ngik,ngjk->ngij", x.astype(cdt), kc,
+                                       preferred_element_type=f32)) * D
+    P = pairs(qf)
+    A = jnp.where(jnp.eye(C, dtype=bool), 0.0, pairs(kf)) * b[..., None]  # row i by b_i
+    T = _inverse_unit_lower(A) * b[:, :, None, :]  # (I + A)^-1 Diag(b)
+    Tc = T.astype(cdt)
+    eG = jnp.exp(G)[..., None]  # [N, H, C, 1]
+    kv_, qv_ = per_v(kf), per_v(qf)
+    W = jnp.einsum("nhij,nhjk->nhik", Tc, (kv_ * eG).astype(cdt), preferred_element_type=f32)
+    U = jnp.einsum("nhij,nhjv->nhiv", Tc, v.astype(cdt), preferred_element_type=f32)
+
+    last = seg[:, -1]
+    cross = ((seg == before[:, None]) & (seg > 0))[:, None, :, None]  # [N, 1, C, 1]
+    to_end = (seg == last[:, None])[:, None, :, None]
+    carry = ((last == before) & (last > 0))[:, None, None]  # [N, 1, 1]
+    G_end = G[:, :, -1:]  # [N, H, 1]
+    Wm = jnp.where(cross, W, 0.0).astype(cdt)
+    Qg = jnp.where(cross, qv_ * eG, 0.0).astype(cdt)
+    Kd = jnp.where(to_end, kv_ * jnp.exp(G_end - G)[..., None], 0.0).astype(cdt)
+    dec = jnp.broadcast_to(jnp.where(carry, jnp.exp(G_end), 0.0), (N, H, K))
+    return Wm, U.astype(cdt), Qg, Kd, P.astype(cdt), dec
+
+
+def _intra_channel(q, k, v, g, b, seg, before, cdt):
+    """A decay a channel. q, k
     [N, C, H, K] (as their convolutions left them: made unit a head, and q
     scaled by K^-0.5, here, in float32, a group of chunks at a time and
     not a row) and v [N, C, H, V] (N chunks of C cells), g [N, C, H, K]
@@ -381,10 +490,13 @@ class _Groups:
 
 def delta_rule(q, k, v, f, b, A, dt_bias, segment_ids, chunk: int, kernel):
     """The recurrence over packed rows, in chunks, of `unit(q) K^-0.5` and
-    `unit(k)` under the decay `exp(A softplus(f + dt_bias))`: q, k, f [R,
-    T, H, K], v [R, T, H, V], all 0 at padding; b [R, T, H] float32, 0 at
-    padding; A [H] and dt_bias [H, K] float32; segment_ids [R, T]; T a
-    multiple of `chunk` -> o [R, T, H, V] in q's dtype. `kernel`: the
+    `unit(k)` under the decay `exp(A softplus(f + dt_bias))`: q, k [R, T,
+    Hk, K] (Hk key heads that divide the H value heads), v [R, T, H, V], f
+    [R, T, H, K] with dt_bias [H, K] (a decay a channel; Hk = H) or f [R,
+    T, H] with dt_bias [H] (a decay a head: its rank is what tells the two
+    rules apart, known when the program is traced), all 0 at padding; b
+    [R, T, H] float32, 0 at padding; A [H] float32; segment_ids [R, T]; T
+    a multiple of `chunk` -> o [R, T, H, V] in q's dtype. `kernel`: the
     forward by `ops/pallas/kda_fwd.py`'s one kernel and the backward
     loop's walk by the kernels of `ops/pallas/kda_chunk.py` (True;
     "interpret": in interpret mode, a test's), or the plain form (False).
@@ -426,8 +538,8 @@ def _rule_fwd_groups(q, k, v, f, b, A, dt_bias, segment_ids, chunk, kernel, cell
     `kernel`: `states_scan`, or `kda_fwd_states` as the backward loop
     walks): the plain form's, and what `scripts/kda_probe.py` sets the one
     kernel against."""
-    R, T, H, K = q.shape
-    V, cdt = v.shape[-1], q.dtype
+    R, T, _, K = q.shape
+    H, V, cdt = v.shape[2], v.shape[-1], q.dtype
     res = (q, k, v, f, b, A, dt_bias, segment_ids)
     gr = _Groups(segment_ids, chunk, cells)
     args = tuple(gr.chunked(a) for a in (q, k, v, f, b))
@@ -450,8 +562,8 @@ def _rule_fwd_groups(q, k, v, f, b, A, dt_bias, segment_ids, chunk, kernel, cell
 
 def _rule_bwd(chunk, kernel, cells, res, do):
     q, k, v, f, b, A, dt_bias, segment_ids, bounds = res
-    R, T, H, K = q.shape
-    V, cdt = v.shape[-1], q.dtype
+    R, T, _, K = q.shape
+    H, V, cdt = v.shape[2], v.shape[-1], q.dtype
     gr = _Groups(segment_ids, chunk, cells)
     args = tuple(gr.chunked(a) for a in (q, k, v, f, b))
     dO = jnp.moveaxis(gr.chunked(do.astype(cdt)), 3, 2)  # [R, N, H, C, V]
@@ -492,12 +604,13 @@ def _use_kernel(K: int, mesh) -> bool:
 
 def kda_mixer(q, k, v, f, b, kp, kda: KDAConfig, segment_ids, cdt, mesh=None,
               kernel=None):
-    """What of the mixer crosses tokens. q, k, v [R, T, H K] (the three
-    projections), f [R, T, H K] (the decay's low-rank product), b [R, T,
-    H] (beta's projection), `kp` the layer's `conv_*`, `A_log`, `dt_bias`
-    -> o [R, T, H, K] in `cdt`, before the head norm."""
+    """What of the mixer crosses tokens. q, k [R, T, Hk K], v [R, T, H K]
+    (the three projections), f the decay's input ([R, T, H K] the low-rank
+    product, or [R, T, H] the projection's column where the decay is a
+    head's), b [R, T, H] (beta's projection), `kp` the layer's `conv_*`,
+    `A_log`, `dt_bias` -> o [R, T, H, K] in `cdt`, before the head norm."""
     R, T, _ = q.shape
-    H, K, C = kda.n_heads, kda.head_dim, kda.chunk_size
+    H, Hk, K, C = kda.n_heads, kda.key_heads, kda.head_dim, kda.chunk_size
     f32 = jnp.float32
     valid = segment_ids > 0
     # masked on the way in: whatever padding cells hold (the residual
@@ -505,12 +618,14 @@ def kda_mixer(q, k, v, f, b, kp, kda: KDAConfig, segment_ids, cdt, mesh=None,
     q, k, v, f, b = (jnp.where(valid[..., None], a, 0) for a in (q, k, v, f, b))
     with jax.named_scope("kda_taps"):
         conv = lambda x, w: causal_conv(x.astype(cdt), w.astype(cdt), None, segment_ids)
-        q, k, v = (conv(x, kp[n]).reshape(R, T, H, K)
-                   for x, n in ((q, "conv_q"), (k, "conv_k"), (v, "conv_v")))
+        q, k, v = (conv(x, kp[n]).reshape(R, T, h, K)
+                   for x, n, h in ((q, "conv_q", Hk), (k, "conv_k", Hk), (v, "conv_v", H)))
     with jax.named_scope("kda_gate"):
         A = -jnp.exp(kp["A_log"].astype(f32))  # [H]
-        dt_bias = kp["dt_bias"].astype(f32).reshape(H, K)
-        f = f.astype(cdt).reshape(R, T, H, K)
+        dt_bias = kp["dt_bias"].astype(f32)
+        f = f.astype(cdt)
+        if kda.decay == "channel":
+            dt_bias, f = dt_bias.reshape(H, K), f.reshape(R, T, H, K)
         beta = jnp.where(valid[..., None], jax.nn.sigmoid(b.astype(f32)), 0.0)
     with jax.named_scope("kda_chunk"):
         pad = -T % C

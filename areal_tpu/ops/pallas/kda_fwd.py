@@ -33,6 +33,17 @@ eight heads it cost 4-7 s of a warm set-up). By the probe (`PERF.md`
 section 6, PR 53), ms a call at 53 % fill: 10.5 a head at a time, 6.8 in
 pairs, 5.3 with four pairs staged, 4.85 with the pairs in front.
 
+Both decays (`ops/kda.py`): a decay a channel as above; one decay a head
+(f `[R, T, H]`, read a head's column a cell as beta is) is spread over the K
+lanes in VMEM for the running sum, and the sub-block work gives way to one
+`[C, 2 C]` exponential a pair (`exp(min(G_i - G_j, 0))`, rows against the
+diagonal's row) under one `K K^T` and one `Q K^T`, which the two value heads
+of a pair share where they read one key head. Key heads under value heads:
+the q and k blocks hold `heads / rep` key heads and a value head cuts its
+key head's columns; nothing is repeated in HBM. By the probe (PERF.md
+section 6, PR 54): 4.82 ms a call the channel form, 3.45 the head form at 16
+key heads.
+
 A chunk past a row's last live one (`n_live`, a scalar the index maps
 read) fetches nothing new, computes nothing and writes zeros. Beside `O`
 the kernel writes the state every group of `group` chunks received
@@ -78,48 +89,18 @@ def _running_sum(g):
     return g.reshape(P, C, K)
 
 
-def _chunk(sides, states, scratch, seg_row, before, last, cdt):
-    """A chunk of a grid step's heads, as P pairs: a pair's `[C, C]`
-    matrices stand side by side in `[C, 2 C]` (full lanes at C = 64; a
-    float32 product of the inverse then takes both heads at once, against
-    the two blocks on a diagonal), and every array has the pairs in front
-    (`[P, ...]`: an operation is traced once for all of them, and the
-    pairs' chains of products, which are independent, fill the units while
-    one another's wait). `sides`: the pairs' first heads and their second
-    heads, each q, k, v, f `[P, C, K]` in `cdt`, b_col `[P, C, 1]`, A and
-    dt_bias `[P, 1, K]`; `states` their states `[P, V, K]` float32;
-    `scratch` three `[2, P, C, K]` float32 refs (the running sum, unit k
-    and q: rows and sub-blocks of them are read back from there); seg_row
-    `[1, 2 C]` (the chunk's segment ids, twice), the sequences the chunk
-    before handed on and this one hands on (scalars) -> for each side O
-    `[P, C, V]` float32 and the states handed on."""
+def _channel_pairs(made, scratch, seen, cdt):
+    """A decay a channel: `kk` `[P, C, 2 C]` float32 and `P` in `cdt`, masked
+    by `seen`: sub-blocks of 16, an off-diagonal one a product relative to
+    the later sub-block's first cell, a diagonal one cell by cell."""
     f32 = jnp.float32
-    P, C, K = sides[0][0].shape
-    n = C // SUB
     G_s, k_s, q_s = scratch
-    row = lax.broadcasted_iota(jnp.int32, (C, 2 * C), 0)
-    lane = lax.broadcasted_iota(jnp.int32, (C, 2 * C), 1)
-    second = lane >= C  # the second head's half
-    col = jnp.where(second, lane - C, lane)
-    eye = row == col
-    seg_col = jnp.sum(jnp.where(eye & ~second, seg_row, 0), axis=1, keepdims=True)  # [C, 1]
-    seen = (seg_col == seg_row) & (row >= col)
-    cross = (seg_col == before) & (seg_col > 0)
+    P, C, K = made[0][0].shape
+    n = C // SUB
     lanes = lax.broadcasted_iota(jnp.int32, (1, SUB, 2 * C), 2)
     earlier = lax.broadcasted_iota(jnp.int32, (C, 1), 0)
-    # a side's `[P, C, x]` operand under the pairs' `[P, C, 2 C]` matrices
     under = lambda h, x: jnp.concatenate(
         (jnp.zeros_like(x), x) if h else (x, jnp.zeros_like(x)), axis=1)
-
-    made = []
-    for h, (q, k, _, f, _, A, dt_bias) in enumerate(sides):
-        x = f.astype(f32) + dt_bias
-        softplus = jnp.maximum(x, 0.0) + jnp.log1p(jnp.exp(-jnp.abs(x)))
-        G_s[h] = G = _running_sum(jnp.where(seg_col > 0, A * softplus, 0.0))  # <= 0, falling
-        q_s[h] = qf = unit(q) * K ** -0.5
-        k_s[h] = kf = unit(k)
-        made.append((G, qf, kf))
-
     kk_rows, p_rows = [], []
     for i in range(n):
         at = slice(i * SUB, (i + 1) * SUB)
@@ -142,7 +123,77 @@ def _chunk(sides, states, scratch, seg_row, before, last, cdt):
         kk_rows.append(acc_k)
         p_rows.append(acc_q)
     kk = jnp.where(seen, jnp.concatenate(kk_rows, axis=1), 0.0)  # [P, C, 2 C]
-    Pc = jnp.where(seen, jnp.concatenate(p_rows, axis=1), 0.0).astype(cdt)
+    return kk, jnp.where(seen, jnp.concatenate(p_rows, axis=1), 0.0).astype(cdt)
+
+
+def _chunk(sides, states, scratch, seg_row, before, last, cdt, scalar=False,
+           shared_key=False):
+    """A chunk of a grid step's heads, as P pairs: a pair's `[C, C]`
+    matrices stand side by side in `[C, 2 C]` (full lanes at C = 64; a
+    float32 product of the inverse then takes both heads at once, against
+    the two blocks on a diagonal), and every array has the pairs in front
+    (`[P, ...]`: an operation is traced once for all of them, and the
+    pairs' chains of products, which are independent, fill the units while
+    one another's wait). `sides`: the pairs' first heads and their second
+    heads, each q, k, v, f `[P, C, K]` in `cdt`, b_col `[P, C, 1]`, A and
+    dt_bias `[P, 1, K]`; `states` their states `[P, V, K]` float32;
+    `scratch` three `[2, P, C, K]` float32 refs (the running sum, unit k
+    and q: rows and sub-blocks of them are read back from there); seg_row
+    `[1, 2 C]` (the chunk's segment ids, twice), the sequences the chunk
+    before handed on and this one hands on (scalars) -> for each side O
+    `[P, C, V]` float32 and the states handed on.
+
+    A decay a head (`ops/kda._intra_head`): a side's f is `[P, C, 1]` and
+    its A and dt_bias `[P, 1, 1]`; the running sum is spread over the K
+    lanes here, in VMEM, and `exp(G_i - G_j)` is one `[C, 2 C]` matrix a
+    pair under one product `K K^T` and one `Q K^T` a side (one for both
+    where the pair's heads read one key head, `shared_key`): no
+    sub-blocks."""
+    f32 = jnp.float32
+    P, C, K = sides[0][0].shape
+    G_s, k_s, q_s = scratch
+    row = lax.broadcasted_iota(jnp.int32, (C, 2 * C), 0)
+    lane = lax.broadcasted_iota(jnp.int32, (C, 2 * C), 1)
+    second = lane >= C  # the second head's half
+    col = jnp.where(second, lane - C, lane)
+    eye = row == col
+    seg_col = jnp.sum(jnp.where(eye & ~second, seg_row, 0), axis=1, keepdims=True)  # [C, 1]
+    seen = (seg_col == seg_row) & (row >= col)
+    cross = (seg_col == before) & (seg_col > 0)
+    # a side's `[P, C, x]` operand under the pairs' `[P, C, 2 C]` matrices
+    under = lambda h, x: jnp.concatenate(
+        (jnp.zeros_like(x), x) if h else (x, jnp.zeros_like(x)), axis=1)
+
+    made = []
+    for h, (q, k, _, f, _, A, dt_bias) in enumerate(sides):
+        x = f.astype(f32) + dt_bias
+        softplus = jnp.maximum(x, 0.0) + jnp.log1p(jnp.exp(-jnp.abs(x)))
+        g = jnp.where(seg_col > 0, A * softplus, 0.0)
+        if scalar:  # one number a cell: over the lanes, in VMEM alone
+            g = jnp.broadcast_to(g, (P, C, K))
+        G_s[h] = G = _running_sum(g)  # <= 0, falling
+        qf, kf = unit(q) * K ** -0.5, unit(k)
+        if not scalar:  # the sub-blocks read rows of them back
+            q_s[h], k_s[h] = qf, kf
+        made.append((G, qf, kf))
+
+    if scalar:
+        wide = lambda x: jnp.broadcast_to(x[:, :, :1], (P, C, 2 * C))
+        G_col = jnp.where(second, wide(made[1][0]), wide(made[0][0]))
+        G_row = jnp.sum(jnp.where(eye, G_col, 0.0), axis=1, keepdims=True)  # [P, 1, 2 C]
+        D = jnp.exp(jnp.minimum(G_col - G_row, 0.0))
+        over = lambda x, kc: _mm(x.astype(cdt), kc, _BNT)  # [P, C, C]
+        kcs = [kf.astype(cdt) for _, _, kf in made]
+        if shared_key:
+            kk0, qk0 = over(made[0][2], kcs[0]), over(made[0][1], kcs[0])
+            kk_pair, qk_pair = (kk0, kk0), (qk0, qk0)
+        else:
+            kk_pair = tuple(over(kf, kc) for (_, _, kf), kc in zip(made, kcs))
+            qk_pair = tuple(over(qf, kc) for (_, qf, _), kc in zip(made, kcs))
+        kk = jnp.where(seen, jnp.concatenate(kk_pair, axis=2) * D, 0.0)  # [P, C, 2 C]
+        Pc = jnp.where(seen, jnp.concatenate(qk_pair, axis=2) * D, 0.0).astype(cdt)
+    else:
+        kk, Pc = _channel_pairs(made, scratch, seen, cdt)
     b_col = jnp.where(second, sides[1][4], sides[0][4])  # [P, C, 2 C]
     b_row = jnp.sum(jnp.where(eye, b_col, 0.0), axis=1, keepdims=True)  # [P, 1, 2 C]
 
@@ -177,10 +228,11 @@ def _chunk(sides, states, scratch, seg_row, before, last, cdt):
 
 
 def _kernel(n_live_ref, ends_ref, q_ref, k_ref, v_ref, f_ref, b_ref, seg_ref, a_ref,
-            bias_ref, o_ref, bounds_ref, st, *scratch, group):
+            bias_ref, o_ref, bounds_ref, st, *scratch, group, scalar):
     r, hg, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     N = pl.num_programs(2)
     hb, V, K = bounds_ref.shape
+    rep = hb * K // q_ref.shape[1]  # value heads a key head
     # the heads two by two; a last odd one stands beside itself
     pairs = [(j, min(j + 1, hb - 1)) for j in range(0, hb, 2)]
     live = c < n_live_ref[r]
@@ -200,17 +252,27 @@ def _kernel(n_live_ref, ends_ref, q_ref, k_ref, v_ref, f_ref, b_ref, seg_ref, a_
         last = ends_ref[r * N + c]
         b = b_ref[...]
         which = lax.broadcasted_iota(jnp.int32, b.shape, 1)
+        # a head's column of a `[., H]` block (b; a head's decay: f, A, dt_bias)
+        column = lambda x, j: jnp.sum(
+            jnp.where(which[:x.shape[0]] == hg * hb + j, x, 0.0), axis=1, keepdims=True)
 
         def side(h):
             js = [pair[h] for pair in pairs]
-            cut = lambda ref, w: jnp.stack([ref[:, j * w:(j + 1) * w] for j in js])
-            b_col = jnp.stack([jnp.sum(jnp.where(which == hg * hb + j, b, 0.0), axis=1,
-                                       keepdims=True) for j in js])
-            return (cut(q_ref, K), cut(k_ref, K), cut(v_ref, V), cut(f_ref, K), b_col,
-                    cut(a_ref, K), cut(bias_ref, K))
+            cut = lambda ref, w, at=lambda j: j: jnp.stack(
+                [ref[:, at(j) * w:(at(j) + 1) * w] for j in js])
+            key = lambda j: j // rep  # the key head a value head reads
+            b_col = jnp.stack([column(b, j) for j in js])
+            if scalar:
+                decay = tuple(jnp.stack([column(ref[...].astype(jnp.float32), j) for j in js])
+                              for ref in (f_ref, a_ref, bias_ref))
+            else:
+                decay = (cut(f_ref, K), cut(a_ref, K), cut(bias_ref, K))
+            return (cut(q_ref, K, key), cut(k_ref, K, key), cut(v_ref, V), decay[0], b_col,
+                    decay[1], decay[2])
 
         outs = _chunk([side(0), side(1)], [st[0], st[1]], scratch, seg_ref[...], before, last,
-                      q_ref.dtype)
+                      q_ref.dtype, scalar,
+                      shared_key=all(a // rep == b // rep for a, b in pairs))
         for h, (o, s_t) in enumerate(outs):
             st[h] = s_t
             for p, pair in enumerate(pairs):
@@ -224,32 +286,45 @@ def _kernel(n_live_ref, ends_ref, q_ref, k_ref, v_ref, f_ref, b_ref, seg_ref, a_
 @functools.partial(jax.jit, static_argnames=("chunk", "group", "interpret"))
 def rule_fwd(q, k, v, f, b, A, dt_bias, segment_ids, n_live, chunk: int, group: int,
              interpret: bool = False):
-    """`ops/kda.delta_rule`'s forward: q, k, f `[R, T, H, K]`, v `[R, T, H,
-    V]`, b `[R, T, H]` float32, A `[H]`, dt_bias `[H, K]`, segment_ids
-    `[R, T]`, T a multiple of `chunk`, n_live `[R]` the chunks of a row up
-    to its last token's -> o `[R, T, H, V]` in q's dtype and the state
-    every `group` chunks received, `[N // group, R, H, V, K]`
-    float32. Device op `kda_fwd_rule`. Jitted here: the layers of a stack,
-    their forward and remat's, trace the kernel's body once a shape and
-    lower it once a program."""
-    R, T, H, K = q.shape
-    V, C, N = v.shape[-1], chunk, T // chunk
-    hb = _heads(H, HEADS)
+    """`ops/kda.delta_rule`'s forward: q, k `[R, T, Hk, K]` (Hk key heads;
+    value head j reads key head `j // (H / Hk)` through the blocks' index:
+    nothing is repeated), v `[R, T, H, V]`, f `[R, T, H, K]` with dt_bias
+    `[H, K]` (a decay a channel) or f `[R, T, H]` with dt_bias `[H]` (a
+    decay a head: read a head a cell and spread over lanes in VMEM), b `[R,
+    T, H]` float32, A `[H]`, segment_ids `[R, T]`, T a multiple of `chunk`,
+    n_live `[R]` the chunks of a row up to its last token's -> o `[R, T, H,
+    V]` in q's dtype and the state every `group` chunks received, `[N //
+    group, R, H, V, K]` float32. Device op `kda_fwd_rule`. Jitted here: the
+    layers of a stack, their forward and remat's, trace the kernel's body
+    once a shape and lower it once a program."""
+    R, T, Hk, K = q.shape
+    H, V, C, N = v.shape[2], v.shape[-1], chunk, T // chunk
+    rep, scalar = H // Hk, f.ndim == 3
+    hb = rep * _heads(Hk, max(1, HEADS // rep))  # value heads a step: whole key heads
     seg = segment_ids.reshape(R, N, C)
     # a chunk past the last live one fetches the last live one's blocks again
     at = lambda r, c, n: jnp.minimum(c, jnp.maximum(n[r] - 1, 0))
     cells = lambda w: pl.BlockSpec((None, C, w), lambda r, h, c, n, e: (r, at(r, c, n), h))
-    a_head = pl.BlockSpec((1, hb * K), lambda r, h, c, n, e: (0, h))
+    a_cell = pl.BlockSpec((None, C, H), lambda r, h, c, n, e: (r, at(r, c, n), 0))
+    f32 = jnp.float32
+    if scalar:  # a head's decay: a column of `[., H]` blocks, as beta's
+        a_head = pl.BlockSpec((1, H), lambda r, h, c, n, e: (0, 0))
+        decay_specs, f_in = [a_cell, a_head, a_head], f
+        consts = (A.astype(f32)[None], dt_bias.astype(f32)[None])
+    else:
+        a_head = pl.BlockSpec((1, hb * K), lambda r, h, c, n, e: (0, h))
+        decay_specs, f_in = [cells(hb * K), a_head, a_head], f.reshape(R, T, H * K)
+        consts = (jnp.repeat(A.astype(f32), K)[None], dt_bias.astype(f32).reshape(1, H * K))
     with jax.named_scope("kda_fwd_rule"):
         o, bounds = pl.pallas_call(
-            functools.partial(_kernel, group=group),
+            functools.partial(_kernel, group=group, scalar=scalar),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2, grid=(R, H // hb, N),
-                in_specs=[cells(hb * K), cells(hb * K), cells(hb * V), cells(hb * K),
-                          pl.BlockSpec((None, C, H), lambda r, h, c, n, e: (r, at(r, c, n), 0)),
+                in_specs=[cells(hb // rep * K), cells(hb // rep * K), cells(hb * V),
+                          decay_specs[0], a_cell,
                           pl.BlockSpec((None, None, 1, 2 * C),
                                        lambda r, h, c, n, e: (r, at(r, c, n), 0, 0)),
-                          a_head, a_head],
+                          decay_specs[1], decay_specs[2]],
                 out_specs=[pl.BlockSpec((None, C, hb * V), lambda r, h, c, n, e: (r, c, h)),
                            pl.BlockSpec((None, None, hb, V, K),
                                         lambda r, h, c, n, e: (c // group, r, h, 0, 0))],
@@ -261,7 +336,6 @@ def rule_fwd(q, k, v, f, b, A, dt_bias, segment_ids, n_live, chunk: int, group: 
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             name="kda_fwd_rule", interpret=interpret,
         )(n_live.astype(jnp.int32), seg[:, :, -1].reshape(R * N).astype(jnp.int32),
-          q.reshape(R, T, H * K), k.reshape(R, T, H * K), v.reshape(R, T, H * V),
-          f.reshape(R, T, H * K), b, jnp.tile(seg[:, :, None, :], (1, 1, 1, 2)),
-          jnp.repeat(A.astype(jnp.float32), K)[None], dt_bias.astype(jnp.float32).reshape(1, H * K))
+          q.reshape(R, T, Hk * K), k.reshape(R, T, Hk * K), v.reshape(R, T, H * V),
+          f_in, b, jnp.tile(seg[:, :, None, :], (1, 1, 1, 2)), *consts)
     return o.reshape(R, T, H, V), bounds

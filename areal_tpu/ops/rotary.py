@@ -11,6 +11,7 @@ from __future__ import annotations
 import math
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -91,7 +92,17 @@ def apply_rotary(
 
     x: (..., n_heads, head_dim); cos/sin: (..., head_dim/2) broadcast over heads.
     Non-interleaved (HF neox style): pairs are (x[:d/2], x[d/2:]).
+    Tables narrower than half a head (a partial rotation,
+    `TransformerConfig.rotary_fraction`): the head's first `2 * width`
+    columns are turned, paired among themselves, and the rest left as
+    they are (scope `attn_rotary`).
     """
+    turned = 2 * cos.shape[-1]
+    if turned < x.shape[-1]:
+        with jax.named_scope("attn_rotary"):
+            return jnp.concatenate(
+                [apply_rotary(x[..., :turned], cos, sin, interleaved), x[..., turned:]],
+                axis=-1)
     dtype = x.dtype
     x = x.astype(jnp.float32)
     cos = cos[..., None, :]

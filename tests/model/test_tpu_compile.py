@@ -129,12 +129,14 @@ def test_splash_row_of_16k_at_32_over_4_heads_compiles_forward_and_backward(one_
 
 
 @pytest.mark.parametrize("hq,hkv,hd,hd_v,window", [
-    (32, 32, 192, 128, None), (16, 1, 128, 128, 2048), (12, 2, 128, 128, None)],
-    ids=["joyai_192_128", "group_16_window", "group_6"])
+    (32, 32, 192, 128, None), (16, 1, 128, 128, 2048), (12, 2, 128, 128, None),
+    (16, 2, 256, 256, None)],
+    ids=["joyai_192_128", "group_16_window", "group_6", "qwen3next_256_group_8"])
 def test_pair_kernels_compile_at_a_row_of_16k(one_chip, hq, hkv, hd, hd_v, window):
     """The list-walking kernels at the longest row, 16,384 at the blocks
     it runs at (512 x 1024): q and k of 192 against v of 128 (the joyai
-    cell's call), a group of 16 under a window, a group of 6."""
+    cell's call), a group of 16 under a window, a group of 6, and q, k and
+    v of 256 in a group of 8 (the qwen3next cell's attention layer)."""
     from areal_tpu.ops.attention import splash_packed_attention
 
     t = 16384
@@ -603,3 +605,55 @@ def test_an_accumulate_step_of_the_delta_rule_stack_compiles_at_16k(one_chip, mo
         assert name in text, name
     assert compiled.memory_analysis().temp_size_in_bytes < 6.5e9
     _holds_dq_once(cfg, compiled, "kimi-linear-d5-e8", 16384)
+
+
+def test_the_delta_rule_with_a_decay_a_head_compiles_at_the_published_widths(one_chip):
+    """`qwen3next-d4e32-train-ppo-long`'s delta rule over a row of 16,384:
+    16 key heads under 32 value heads of 128 x 128, one decay a value head,
+    bf16. The same three custom calls as the channel form's; q and k stand
+    `[T, 16, 128]` (no array of the program repeats them to 32 heads a row:
+    the kernel reads a key head through the block's index, and the backward
+    loop's repeat is a group's) and the decay `[T, 32]`: no float32 `[.,
+    32, 128]` of the row's length holds it a channel."""
+    from areal_tpu.ops import kda
+
+    t, hk, h, k = 16384, 16, 32, 128
+    q = _shape((1, t, hk, k), jnp.bfloat16, one_chip)
+    v = _shape((1, t, h, k), jnp.bfloat16, one_chip)
+    f = _shape((1, t, h), jnp.bfloat16, one_chip)
+    b = _shape((1, t, h), jnp.float32, one_chip)
+    a = _shape((h,), jnp.float32, one_chip)
+    seg = _shape((1, t), jnp.int32, one_chip)
+
+    def loss(q, k_, v, f, b, a, bias, seg):
+        return kda.delta_rule(q, k_, v, f, b, a, bias, seg, 64, True).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, tuple(range(7)))).lower(
+        q, q, v, f, b, a, a, seg).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 3
+    for name in ("kda_fwd_rule", "kda_fwd_states", "kda_bwd_states"):
+        assert name in text, name
+    assert f"f32[1,{t},{h},{k}]" not in text and f"f32[{t},{h},{k}]" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+
+
+def test_an_accumulate_step_of_the_gated_deltanet_stack_compiles_at_16k(one_chip, monkeypatch):
+    """A forward-backward micro-batch of `qwen3next-d4e32-train-ppo-long`'s
+    model at its one shape `(1, 16384)`, full remat, the masked loss head:
+    a scan of three Gated DeltaNet layers and the gated attention layer
+    after them, every layer's token-wise stretches over the row's live
+    bands, the rule's three kernels beside the pair kernels at heads of 256
+    and the experts' row adds. The compiler's temporaries beside 8.76 GB of
+    weights, gradient sums and moments."""
+    from areal_tpu.models.transformer import looping_layers
+
+    cfg, compiled = _accumulate_step(one_chip, monkeypatch, "qwen3-next-d4-e32", 16384)
+    assert looping_layers(cfg, 1, 16384) == 4
+    text = compiled.as_text()
+    for name in ("kda_fwd_rule", "kda_fwd_states", "kda_bwd_states", "splash_pairs_fwd",
+                 "splash_pairs_bwd", "moe_rows_add"):
+        assert name in text, name
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    print(f"temporaries {temp / 1e9:.2f} GB")
+    assert temp < 6.5e9
