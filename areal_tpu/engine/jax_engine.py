@@ -1381,7 +1381,8 @@ class JaxTrainEngine(TrainEngine):
         hyper-connections ran over the stack's sublayers and those of them
         inside a band loop (`_band_counts`, `_mhc_counts`), the positions
         the delta-rule layers' chunked rule walked (and, of them, those
-        whose forward the one kernel ran: `ops/kda._use_kernel`), its
+        whose forward and whose backward the kernels ran:
+        `ops/kda._use_kernel`), its
         chunks, those with a token and the sequence starts
         (`_kda_counts`)."""
         tracing.set_attrs(path=path, n_mbs=n_mbs, tokens=n_tok, cells=n_cells)
@@ -1422,10 +1423,13 @@ class JaxTrainEngine(TrainEngine):
             tracing.count("train.kda_cells", n_kda_cells)
             from areal_tpu.ops.kda import _use_kernel
 
-            # those of them the forward's one kernel ran (`kda_fwd_rule`): all
-            # where the rule takes its kernels, none where it takes the plain form
-            tracing.count("train.kda_fwd_kernel_cells", n_kda_cells if _use_kernel(
-                self.model_cfg.kda.head_dim, self.mesh) else 0)
+            # those of them the kernels ran, the forward's one (`kda_fwd_rule`)
+            # and the backward's (`kda_bwd_rule`): all where
+            # the rule takes its kernels, none where it takes the plain form
+            in_kernel = n_kda_cells if _use_kernel(
+                self.model_cfg.kda.head_dim, self.mesh) else 0
+            tracing.count("train.kda_fwd_kernel_cells", in_kernel)
+            tracing.count("train.kda_bwd_kernel_cells", in_kernel)
             tracing.count("train.kda_chunks", n_kda_chunks)
             tracing.count("train.kda_chunks_live", n_kda_live)
             tracing.count("train.kda_resets", n_kda_resets)

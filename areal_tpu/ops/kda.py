@@ -85,15 +85,27 @@ of whole lane tiles; decided from what the code sees, no argument):
   one `[C, 2 C]` exponential a pair of heads) and the walk, a chunk a grid
   step with the state in VMEM; q, k, v, f read and O written cells-major,
   nothing else of a chunk in HBM. Under full remat both forward runs of a
-  step take it. By the probe (`scripts/kda_probe.py`; PERF.md section 6,
-  PR 54), a row of 16,384 at 53 % fill, 32 value heads of 128, ms forward
-  / forward + backward: the channel form fed one decay K times 4.82 / 49.2,
-  the head form 3.48 / 22.7 and under 16 key heads 3.45 / 19.3: two forms.
-- backward loop, on the chip: group by group, `intra` under `jax.vjp` (the
-  plain `jnp` below), the chunks' states again by `kda_fwd_states` and the
-  walk backwards by `kda_bwd_states` (`ops/pallas/kda_chunk.py`).
+  step take it.
+- backward, on the chip, both decays: one kernel over the whole row,
+  `kda_bwd_rule` (`ops/pallas/kda_bwd.py`), a group of chunks at a time
+  from the row's last: the chunks' states again from the state the group
+  received (the forward kernel's chunk without q's half, the states in
+  VMEM), then the chunks backwards: `intra` again, the walk's transpose
+  with the state's cotangent in VMEM, and `intra`'s pullback by hand (the
+  inverse's rule in two float32 products, the pairs' cotangents under the
+  forward's own exponentials, the running sum's as `x (.) dx - k (.) dk`);
+  q, k, v, f, b and dO read and dq, dk, dv, df, db written cells-major,
+  dA and d dt_bias summed on the way. No loop of XLA's, no part of a chunk
+  and no cotangent of one in HBM.
 - the CPU, a mesh of several devices, toy heads: `intra` + `states_scan`
-  a group at a time forward, `states_scan` and `states_scan_bwd` backward.
+  a group at a time forward, `states_scan` and `states_scan_bwd` under
+  `jax.vjp` of `intra` backward: the plain form, and the tests' reference.
+
+By the probe (`scripts/kda_probe.py`; PERF.md section 6, PR 55), a row of
+16,384 at 53 % fill, 32 value heads of 128, bf16, ms forward / forward +
+backward: the channel form 4.84 / 18.64 (4.85 / 49.8 with the backward a
+loop of XLA's over groups, 14.01 / 59.0 the plain form), the head form under
+16 key heads 3.45 / 11.40 (3.45 / 19.3; 8.94 / 25.2): two forms.
 
 **No exponential of a positive number.** A decay a channel: `exp(G_i - G_j)` is never split
 into `exp(G_i) exp(-G_j)` across a chunk (a decay of 0.2 a token over 64
@@ -109,7 +121,8 @@ accumulate in float32.
 The backward pass (`delta_rule`'s `custom_vjp`) keeps the rule's inputs
 and the state each group received; group by group from the last, it makes
 `intra` and the chunks' states again, walks the chunks backwards for the
-state's part, and differentiates `intra` for the rest.
+state's part, and differentiates `intra` for the rest: the plain form by
+`jax.vjp` in a loop over groups, the kernel by hand inside its grid.
 """
 
 from __future__ import annotations
@@ -427,23 +440,6 @@ def _live_chunks(seg, C: int):
     return (last + C - 1) // C
 
 
-def _walk(parts, S_in, n_live, kernel):
-    if kernel:
-        from areal_tpu.ops.pallas import kda_chunk
-
-        return kda_chunk.states_fwd(*parts, S_in, n_live, interpret=kernel == "interpret")
-    return states_scan(*parts, S_in)
-
-
-def _walk_bwd(parts, S_all, dO, dS_in, n_live, kernel):
-    if kernel:
-        from areal_tpu.ops.pallas import kda_chunk
-
-        return kda_chunk.states_bwd(*parts, S_all, dO, dS_in, n_live,
-                                    interpret=kernel == "interpret")
-    return states_scan_bwd(*parts, S_all, dO, dS_in)
-
-
 class _Groups:
     """A call's rows cut into groups of chunks: `args` [R, N, ...] a group
     `i` of every row at a time (`take`), and a result put back (`put`)."""
@@ -467,10 +463,6 @@ class _Groups:
 
     def put(self, buf, a, i):
         return jax.lax.dynamic_update_slice_in_dim(buf, a.astype(buf.dtype), i * self.gs, axis=1)
-
-    def live_in(self, i):
-        """[R] a row's live chunks inside group i."""
-        return jnp.clip(self.n_live - i * self.gs, 0, self.gs)
 
     def intra(self, cdt, i):
         """`decay` and `intra` of group i's chunks, as a function of (q, k,
@@ -497,9 +489,9 @@ def delta_rule(q, k, v, f, b, A, dt_bias, segment_ids, chunk: int, kernel):
     rules apart, known when the program is traced), all 0 at padding; b
     [R, T, H] float32, 0 at padding; A [H] float32; segment_ids [R, T]; T
     a multiple of `chunk` -> o [R, T, H, V] in q's dtype. `kernel`: the
-    forward by `ops/pallas/kda_fwd.py`'s one kernel and the backward
-    loop's walk by the kernels of `ops/pallas/kda_chunk.py` (True;
-    "interpret": in interpret mode, a test's), or the plain form (False).
+    forward by `ops/pallas/kda_fwd.py`'s one kernel and the backward by
+    `ops/pallas/kda_bwd.py`'s (True; "interpret": in interpret mode, a
+    test's), or the plain form (False).
 
     Plain: a group of every row's chunks at a time (`_Groups`), up to the
     group of the fullest row's last token (a loop whose trip count is a
@@ -507,8 +499,9 @@ def delta_rule(q, k, v, f, b, A, dt_bias, segment_ids, chunk: int, kernel):
     from the state the group before handed on. What stands in memory at
     once is a group's; the forward rule keeps its inputs and the state
     each group received (the kernel writes the same), and the backward
-    loop makes a group's `intra` and its chunks' states again before it
-    walks them backwards. One function
+    makes a group's `intra` and its chunks' states again before it walks
+    them backwards (the kernel inside its grid, the plain form in a loop
+    like the forward's). One function
     jitted at module level (as `ops/band_loop.stretch`): the layers of a
     stack that call it at one shape share a trace and a lowering of each
     loop."""
@@ -522,7 +515,7 @@ def _rule(q, k, v, f, b, A, dt_bias, segment_ids, chunk, kernel, cells):
 
 def _rule_fwd(q, k, v, f, b, A, dt_bias, segment_ids, chunk, kernel, cells):
     if not kernel:
-        return _rule_fwd_groups(q, k, v, f, b, A, dt_bias, segment_ids, chunk, kernel, cells)
+        return _rule_fwd_groups(q, k, v, f, b, A, dt_bias, segment_ids, chunk, cells)
     from areal_tpu.ops.pallas import kda_fwd
 
     R, T = segment_ids.shape
@@ -533,11 +526,9 @@ def _rule_fwd(q, k, v, f, b, A, dt_bias, segment_ids, chunk, kernel, cells):
     return o, res + (bounds,)
 
 
-def _rule_fwd_groups(q, k, v, f, b, A, dt_bias, segment_ids, chunk, kernel, cells):
-    """The forward a group of chunks at a time, `intra` then the walk (by
-    `kernel`: `states_scan`, or `kda_fwd_states` as the backward loop
-    walks): the plain form's, and what `scripts/kda_probe.py` sets the one
-    kernel against."""
+def _rule_fwd_groups(q, k, v, f, b, A, dt_bias, segment_ids, chunk, cells):
+    """The plain form's forward, a group of chunks at a time: `intra`, then
+    `states_scan` over the group's chunks."""
     R, T, _, K = q.shape
     H, V, cdt = v.shape[2], v.shape[-1], q.dtype
     res = (q, k, v, f, b, A, dt_bias, segment_ids)
@@ -549,7 +540,7 @@ def _rule_fwd_groups(q, k, v, f, b, A, dt_bias, segment_ids, chunk, kernel, cell
         with jax.named_scope("kda_intra"):
             parts = gr.intra(cdt, i)(*(gr.take(a, i) for a in args), A, dt_bias)
         with jax.named_scope("kda_states"):
-            O_g, _, S_out = _walk(parts, S, gr.live_in(i), kernel)
+            O_g, _, S_out = states_scan(*parts, S)
         return (S_out, gr.put(O, O_g, i),
                 jax.lax.dynamic_update_slice_in_dim(bounds, S[None], i, axis=0))
 
@@ -561,6 +552,12 @@ def _rule_fwd_groups(q, k, v, f, b, A, dt_bias, segment_ids, chunk, kernel, cell
 
 
 def _rule_bwd(chunk, kernel, cells, res, do):
+    if kernel:
+        from areal_tpu.ops.pallas import kda_bwd
+
+        segment_ids, bounds = res[-2:]
+        return kda_bwd.rule_bwd(*res[:-1], _live_chunks(segment_ids, chunk), bounds, do,
+                                chunk, interpret=kernel == "interpret") + (None,)
     q, k, v, f, b, A, dt_bias, segment_ids, bounds = res
     R, T, _, K = q.shape
     H, V, cdt = v.shape[2], v.shape[-1], q.dtype
@@ -576,8 +573,8 @@ def _rule_bwd(chunk, kernel, cells, res, do):
                                   A, dt_bias)
         with jax.named_scope("kda_states"):
             S_in = jax.lax.dynamic_index_in_dim(bounds, i, 0, keepdims=False)
-            _, S_all, _ = _walk(parts, S_in, gr.live_in(i), kernel)
-            *d_parts, dS = _walk_bwd(parts, S_all, gr.take(dO, i), dS, gr.live_in(i), kernel)
+            _, S_all, _ = states_scan(*parts, S_in)
+            *d_parts, dS = states_scan_bwd(*parts, S_all, gr.take(dO, i), dS)
         with jax.named_scope("kda_intra"):
             *got, dA, d_bias = pull(tuple(d.astype(p.dtype) for d, p in zip(d_parts, parts)))
         return (dS, tuple(gr.put(buf, a, i) for buf, a in zip(grads, got)),
@@ -594,7 +591,7 @@ _rule_jit = jax.jit(_rule, static_argnums=(8, 9, 10))
 
 
 def _use_kernel(K: int, mesh) -> bool:
-    """The kernels (the forward's one, the backward loop's walk) on the
+    """The kernels (the forward's one, the backward's one) on the
     chip, one device's rows, heads of whole lane tiles; the plain form
     elsewhere (the CPU, a toy head, a mesh of several devices: a kernel is
     opaque to the partitioner)."""

@@ -7,15 +7,23 @@ leave them; `--decay channel`) and at those `qwen3next-d4e32-train-ppo-long`
 does (`--decay head --key-heads 16`: f `[1, T, H]`, one decay a value head,
 q and k `[1, T, Hk K]`; with `--key-heads 32` the same rule beside the
 channel form fed one decay K times) with a given share of the row holding
-tokens, in three arms:
-`plain` (`intra` and the `lax.scan` walk a group at a time), `walk` (the
-same loop with the walk by `kda_fwd_states`: the chip's forward before
-PR 53) and `fused` (the forward one kernel, `kda_fwd_rule`); the last two
-share the backward loop. At each chunk size, group of chunks and heads a
-grid step asked for; `fused` beside its worst difference from `plain`.
+tokens, in two arms: `plain` (`intra` and the `lax.scan` walk a group at a
+time, forward and backward: the CPU's and a mesh's form) and `fused` (the
+kernels: `kda_fwd_rule` forward, `kda_bwd_rule` backward). Forward +
+backward is the loss `sum(o * w)` with its seven gradients (the loss itself
+is returned too: the forward runs in both arms). At each chunk size and
+heads a grid step asked for; `fused` beside its worst
+differences from `plain`: of O (`max_diff` at values up to `max_abs`) and
+of each of the seven gradients (`grad_diff` at `grad_abs`); with `--exact`
+each arm's gradients also against the plain form's in float32 at the
+highest precision (`grad_err` the worst difference, `grad_err_rms` the root
+of the mean square: which of two bf16 computations that differ is the
+nearer). Every row says where it was made: the device, the shape, the dtype
+and whether the kernels ran in interpret mode (a rehearsal off the chip,
+whose milliseconds mean nothing).
 
     python scripts/kda_probe.py [--out chiprun_out/x.jsonl] [--chunks 64 128]
-        [--decay channel head] [--key-heads 32 16] [--arms plain fused]
+        [--decay channel head] [--key-heads 32 16] [--arms plain fused] [--exact]
 """
 
 import argparse
@@ -73,21 +81,23 @@ def main():
     ap.add_argument("--out", default=None)
     ap.add_argument("--chunks", type=int, nargs="+", default=[64])
     ap.add_argument("--fill", type=float, nargs="+", default=[0.53, 1.0])
-    ap.add_argument("--heads", type=int, nargs="+", default=[4, 8])
-    ap.add_argument("--groups", type=int, nargs="+", default=[1024])
+    ap.add_argument("--heads", type=int, nargs="+", default=[8],
+                    help="heads a grid step, the forward's and the backward's kernels")
     ap.add_argument("--decay", nargs="+", default=["channel"], choices=["channel", "head"])
     ap.add_argument("--key-heads", type=int, nargs="+", default=[32],
                     help="under --decay head (the channel form has a key a value head)")
-    ap.add_argument("--arms", nargs="+", default=["plain", "walk", "fused"])
+    ap.add_argument("--arms", nargs="+", default=["plain", "fused"])
+    ap.add_argument("--shape", type=int, nargs=3, default=[16384, 32, 128],
+                    help="T H K: a small one with --interpret rehearses the script off the chip")
+    ap.add_argument("--interpret", action="store_true")
+    ap.add_argument("--exact", action="store_true")
     args = ap.parse_args()
-    T, H, K = 16384, 32, 128
+    T, H, K = args.shape
+    kernel = "interpret" if args.interpret else True
     rows = []
-    from areal_tpu.ops.pallas import kda_chunk, kda_fwd
+    from areal_tpu.ops.pallas import kda_fwd
 
-    # the group loop with the kernels' walk in the forward too
-    walk = jax.custom_vjp(
-        lambda *a: kda._rule_fwd_groups(*a)[0], nondiff_argnums=(8, 9, 10))
-    walk.defvjp(kda._rule_fwd_groups, kda._rule_bwd)
+    names = ("q", "k", "v", "f", "b", "A", "dt_bias")
     forms = [(d, hk) for d in args.decay for hk in (args.key_heads if d == "head" else [H])]
     for fill, (decay, Hk) in ((fl, fm) for fl in args.fill for fm in forms):
         *xs, seg = inputs(T, H, K, int(T * fill), decay=decay, Hk=Hk)
@@ -95,35 +105,52 @@ def main():
         xs = [a.reshape(1, T, -1) if a.ndim == 4 else a for a in xs]
         w = jnp.asarray(np.random.default_rng(1).normal(size=(1, T, H, K)), jnp.float32)
         for chunk in args.chunks:
-            for group in args.groups:
-                want = None
-                for name, heads in [("plain", 0)] * ("plain" in args.arms) + [
-                        (n, h) for h in args.heads for n in ("walk", "fused") if n in args.arms]:
-                    if heads:
-                        kda_chunk.HEADS = kda_fwd.HEADS = heads
+            want = want_grads = exact = None
+            if args.exact:  # the plain form in float32: what both arms round
+                with jax.default_matmul_precision("highest"):
+                    exact = jax.jit(jax.grad(lambda *a: jnp.sum(kda._rule(
+                        *(x.astype(jnp.float32).reshape(sh) for x, sh in zip(a[:4], shapes)),
+                        *a[4:], seg, chunk, False, kda.GROUP_CELLS) * w), tuple(range(7))))(*xs)
+                jax.clear_caches()
+            for name, heads in [("plain", 0)] * ("plain" in args.arms) + [
+                    ("fused", h) for h in args.heads if "fused" in args.arms]:
+                if heads:
+                    kda_fwd.HEADS = heads
+                cut = lambda q, k, v, f: tuple(
+                    a.reshape(sh) for a, sh in zip((q, k, v, f), shapes))
 
-                    def rule(q, k, v, f, *rest, name=name):
-                        q, k, v, f = (a.reshape(sh) for a, sh in zip((q, k, v, f), shapes))
-                        if name == "walk":
-                            return walk(q, k, v, f, *rest, seg, chunk, True, group)
-                        return kda._rule(q, k, v, f, *rest, seg, chunk, name == "fused", group)
+                def rule(q, k, v, f, *rest, name=name):
+                    return kda._rule(*cut(q, k, v, f), *rest, seg, chunk,
+                                     name == "fused" and kernel, kda.GROUP_CELLS)
 
-                    fwd = jax.jit(rule)
-                    both = jax.jit(jax.grad(
-                        lambda *a: jnp.sum(rule(*a) * w), tuple(range(7))))
-                    row = dict(fill=fill, decay=decay, key_heads=Hk, chunk=chunk, group=group,
-                               arm=name, heads=heads,
-                               fwd_ms=timed(fwd, xs) * 1e3,
-                               fwd_bwd_ms=timed(both, xs) * 1e3)
-                    o = fwd(*xs).astype(jnp.float32)
-                    if name == "plain":
-                        want = o
-                    elif name == "fused" and want is not None:
+                fwd = jax.jit(rule)
+                both = jax.jit(jax.value_and_grad(
+                    lambda *a: jnp.sum(rule(*a) * w), tuple(range(7))))
+                row = dict(device=jax.devices()[0].device_kind, interpret=args.interpret,
+                           shape=[T, H, K], dtype=str(xs[0].dtype), fill=fill, decay=decay,
+                           key_heads=Hk, chunk=chunk, arm=name, heads=heads,
+                           fwd_ms=timed(fwd, xs) * 1e3, fwd_bwd_ms=timed(both, xs) * 1e3)
+                o = fwd(*xs).astype(jnp.float32)
+                grads = [g.astype(jnp.float32) for g in both(*xs)[1]]
+                if exact is not None:
+                    row["grad_err"] = {n: float(jnp.abs(g - t).max())
+                                       for n, g, t in zip(names, grads, exact)}
+                    row["grad_err_rms"] = {n: float(jnp.sqrt(jnp.mean(jnp.square(g - t))))
+                                           for n, g, t in zip(names, grads, exact)}
+                if name == "plain":
+                    want, want_grads = o, grads
+                else:
+                    if want is not None:
                         row["max_diff"] = float(jnp.abs(o - want).max())
                         row["max_abs"] = float(jnp.abs(want).max())
-                    rows.append(row)
-                    print(json.dumps(row), flush=True)
-                    jax.clear_caches()
+                        row["grad_diff"] = {n: float(jnp.abs(g - t).max())
+                                            for n, g, t in zip(names, grads, want_grads)}
+                        row["grad_abs"] = {n: float(jnp.abs(t).max())
+                                           for n, t in zip(names, want_grads)}
+                    row["finite"] = bool(all(jnp.isfinite(g).all() for g in grads))
+                rows.append(row)
+                print(json.dumps(row), flush=True)
+                jax.clear_caches()
     if args.out:
         os.makedirs(os.path.dirname(args.out), exist_ok=True)
         with open(args.out, "w") as f:

@@ -86,7 +86,8 @@ def test_a_train_step_moves_both_mixers_and_counts_what_the_rule_ran(depth):
     # (96 cells: two of 64) in each of the three delta-rule layers
     assert c["train.kda_cells"] == 3 * c["train.cells"] // 96 * 128 > 0
     assert c["train.kda_chunks"] * 64 == c["train.kda_cells"]
-    assert c["train.kda_fwd_kernel_cells"] == 0  # the CPU takes the plain form
+    # the CPU takes the plain form, forward and backward
+    assert c["train.kda_fwd_kernel_cells"] == c["train.kda_bwd_kernel_cells"] == 0
     assert 0 < c["train.kda_chunks_live"] <= c["train.kda_chunks"]
     assert c["train.kda_resets"] == 3 * len(lens)
     assert c["train.attn_cells"] == c["train.cells"]  # the one latent layer's alone
@@ -140,10 +141,11 @@ def test_the_family_runs_through_the_ppo_interface():
 
 
 @pytest.mark.parametrize("kernel", [False, True], ids=["plain", "kernel"])
-def test_the_forward_kernels_cells_are_the_rules_where_the_kernel_runs(kernel, monkeypatch):
-    """`train.kda_fwd_kernel_cells`: every position the rule walked where
-    `ops/kda._use_kernel` takes the kernels (one chip, heads of whole lane
-    tiles), none where the plain form runs; `train.kda_cells` either way."""
+def test_the_kernels_cells_are_the_rules_where_the_kernels_run(kernel, monkeypatch):
+    """`train.kda_fwd_kernel_cells` and `train.kda_bwd_kernel_cells`: every
+    position the rule walked where `ops/kda._use_kernel` takes the kernels
+    (one chip, heads of whole lane tiles), none where the plain form runs;
+    `train.kda_cells` either way."""
     _, eng = engine(0, row_len_multiple=256)
     seen = []
     monkeypatch.setattr(kda, "_use_kernel", lambda K, mesh: seen.append((K, mesh)) or kernel)
@@ -154,4 +156,5 @@ def test_the_forward_kernels_cells_are_the_rules_where_the_kernel_runs(kernel, m
         c = tracing.stop()["counters"]
     assert c["train.kda_cells"] == 768 and c["train.kda_chunks"] == 12
     assert c["train.kda_fwd_kernel_cells"] == (768 if kernel else 0)
+    assert c["train.kda_bwd_kernel_cells"] == (768 if kernel else 0)
     assert seen == [(eng.model_cfg.kda.head_dim, eng.mesh)]

@@ -523,14 +523,13 @@ def test_an_accumulate_step_of_the_four_stream_stack_compiles_at_8k(one_chip, mo
 
 def test_the_delta_rules_kernels_compile_at_the_published_widths(one_chip):
     """`kimilinear-d5e8-train-ppo-long`'s delta rule over a row of 16,384
-    at 32 heads of 128 x 128, bf16 (`ops/kda.py`): the forward is one
-    custom call over the whole row, `kda_fwd_rule` (decay, `intra` and the
-    walk, a chunk of four heads a grid step, the row's live chunks a
-    scalar the index maps read); the backward a loop over groups of 16
-    chunks whose trip count is a value of the run, the walk over a group's
-    chunks two custom calls in it, `kda_fwd_states` (the chunks' states
-    again) and `kda_bwd_states`. What stands in memory beside the inputs
-    and their cotangents is a group's."""
+    at 32 heads of 128 x 128, bf16 (`ops/kda.py`): forward and backward are
+    one custom call each over the whole row, `kda_fwd_rule` (decay, `intra`
+    and the walk, a chunk of eight heads a grid step, the row's live chunks
+    a scalar the index maps read) and `kda_bwd_rule` (a group's states
+    again, then its chunks backwards: `intra` again, the walk's transpose
+    and `intra`'s pullback, nothing of a chunk but the gradients in HBM).
+    No loop of XLA's is left in the rule, and no kernel of the walk alone."""
     from areal_tpu.ops import kda
 
     t, h, k = 16384, 32, 128
@@ -545,11 +544,27 @@ def test_the_delta_rules_kernels_compile_at_the_published_widths(one_chip):
     compiled = jax.jit(jax.grad(loss, tuple(range(7)))).lower(
         q, q, q, q, b, a, bias, seg).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 3
-    for name in ("kda_fwd_rule", "kda_fwd_states", "kda_bwd_states"):
-        assert name in text, name
-    assert text.count(" while(") >= 1  # the groups, backwards
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+    assert text.count("tpu_custom_call") == 2
+    assert "kda_fwd_rule" in text and "kda_bwd_rule" in text
+    assert "kda_fwd_states" not in text and "kda_bwd_states" not in text
+    assert " while(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
+# The accumulate step's temporaries at the parent of PR 55 (the rule's backward
+# a loop of XLA's over groups of chunks with two kernels of the walk in it),
+# bytes, by this file's own compile of that tree: the backward's kernel holds
+# no more.
+_TEMPORARIES_WITH_THE_BACKWARD_LOOP = {
+    "kimi-linear-d5-e8": 5_221_000_704, "qwen3-next-d4-e32": 3_309_406_208}
+
+
+def _rule_in_two_kernels(compiled, config):
+    text = compiled.as_text()
+    assert "kda_fwd_rule" in text and "kda_bwd_rule" in text
+    assert "kda_fwd_states" not in text and "kda_bwd_states" not in text
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= _TEMPORARIES_WITH_THE_BACKWARD_LOOP[config], temp
 
 
 # The step's temporaries at PR 50 (two kernels in the backward, dq bf16
@@ -588,33 +603,32 @@ def test_an_accumulate_step_of_the_delta_rule_stack_compiles_at_16k(one_chip, mo
     model at its one shape `(1, 16384)`, full remat, the masked loss head:
     four delta-rule layers (one alone, a scan of two, one alone) and the
     latent layer between them, every layer's token-wise stretches over the
-    row's live bands, the rule's three kernels (`kda_fwd_rule` in the
-    forward and in remat's, `kda_fwd_states` and `kda_bwd_states` in the
-    backward loop) beside the pair kernels at 192 against 128 and the
-    experts' row adds. The compiler's temporaries:
-    6.1 GB beside 8.43 GB of weights, gradient sums and moments (10.7 GB
-    with the rule's parts and decays held a row at a time and the
+    row's live bands, the rule's two kernels (`kda_fwd_rule` in the
+    forward and in remat's, `kda_bwd_rule` in the backward) beside the pair
+    kernels at 192 against 128 and the experts' row adds. The compiler's
+    temporaries: 5.20 GB beside 8.43 GB of weights, gradient sums and
+    moments, not above the 5.22 of the backward as a loop over groups
+    (10.7 GB with the rule's parts and decays held a row at a time and the
     stretches over the whole row: PERF.md section 6, PR 50)."""
     from areal_tpu.models.transformer import looping_layers
 
     cfg, compiled = _accumulate_step(one_chip, monkeypatch, "kimi-linear-d5-e8", 16384)
     assert looping_layers(cfg, 1, 16384) == 5
     text = compiled.as_text()
-    for name in ("kda_fwd_rule", "kda_fwd_states", "kda_bwd_states", "splash_pairs_bwd",
-                 "moe_rows_add"):
-        assert name in text, name
-    assert compiled.memory_analysis().temp_size_in_bytes < 6.5e9
+    assert "splash_pairs_bwd" in text and "moe_rows_add" in text
+    _rule_in_two_kernels(compiled, "kimi-linear-d5-e8")
     _holds_dq_once(cfg, compiled, "kimi-linear-d5-e8", 16384)
 
 
 def test_the_delta_rule_with_a_decay_a_head_compiles_at_the_published_widths(one_chip):
     """`qwen3next-d4e32-train-ppo-long`'s delta rule over a row of 16,384:
     16 key heads under 32 value heads of 128 x 128, one decay a value head,
-    bf16. The same three custom calls as the channel form's; q and k stand
+    bf16. The same two custom calls as the channel form's; q and k stand
     `[T, 16, 128]` (no array of the program repeats them to 32 heads a row:
-    the kernel reads a key head through the block's index, and the backward
-    loop's repeat is a group's) and the decay `[T, 32]`: no float32 `[.,
-    32, 128]` of the row's length holds it a channel."""
+    the kernels read a key head through the block's index, and the
+    backward's sums a key head's gradients inside its step) and the decay
+    `[T, 32]`: no float32 `[., 32, 128]` of the row's length holds it a
+    channel."""
     from areal_tpu.ops import kda
 
     t, hk, h, k = 16384, 16, 32, 128
@@ -631,11 +645,10 @@ def test_the_delta_rule_with_a_decay_a_head_compiles_at_the_published_widths(one
     compiled = jax.jit(jax.grad(loss, tuple(range(7)))).lower(
         q, q, v, f, b, a, a, seg).compile()
     text = compiled.as_text()
-    assert text.count("tpu_custom_call") == 3
-    for name in ("kda_fwd_rule", "kda_fwd_states", "kda_bwd_states"):
-        assert name in text, name
+    assert text.count("tpu_custom_call") == 2
+    assert "kda_fwd_rule" in text and "kda_bwd_rule" in text and " while(" not in text
     assert f"f32[1,{t},{h},{k}]" not in text and f"f32[{t},{h},{k}]" not in text
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.5e9
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
 
 
 def test_an_accumulate_step_of_the_gated_deltanet_stack_compiles_at_16k(one_chip, monkeypatch):
@@ -643,17 +656,15 @@ def test_an_accumulate_step_of_the_gated_deltanet_stack_compiles_at_16k(one_chip
     model at its one shape `(1, 16384)`, full remat, the masked loss head:
     a scan of three Gated DeltaNet layers and the gated attention layer
     after them, every layer's token-wise stretches over the row's live
-    bands, the rule's three kernels beside the pair kernels at heads of 256
-    and the experts' row adds. The compiler's temporaries beside 8.76 GB of
-    weights, gradient sums and moments."""
+    bands, the rule's two kernels beside the pair kernels at heads of 256
+    and the experts' row adds. The compiler's temporaries, 3.31 GB beside
+    8.76 GB of weights, gradient sums and moments, are not above those of
+    the backward as a loop over groups."""
     from areal_tpu.models.transformer import looping_layers
 
     cfg, compiled = _accumulate_step(one_chip, monkeypatch, "qwen3-next-d4-e32", 16384)
     assert looping_layers(cfg, 1, 16384) == 4
     text = compiled.as_text()
-    for name in ("kda_fwd_rule", "kda_fwd_states", "kda_bwd_states", "splash_pairs_fwd",
-                 "splash_pairs_bwd", "moe_rows_add"):
+    for name in ("splash_pairs_fwd", "splash_pairs_bwd", "moe_rows_add"):
         assert name in text, name
-    temp = compiled.memory_analysis().temp_size_in_bytes
-    print(f"temporaries {temp / 1e9:.2f} GB")
-    assert temp < 6.5e9
+    _rule_in_two_kernels(compiled, "qwen3-next-d4-e32")
