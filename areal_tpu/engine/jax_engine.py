@@ -147,14 +147,17 @@ def _kinds_label(cfg: TransformerConfig) -> str:
     its chunk (`moe.kda.c64`; with one decay a head and h key heads
     `moe.kda.head.k16.c64`), attention over the keys an indexer chooses
     `indexed.`, a layer over four residual streams starts with `hc4.`, and
-    a prediction module after the stack ends the label with `+mtp`."""
+    a prediction module after the stack ends the label with `+mtp`. A
+    layer that rotates by a named set of the stack's (`rotary_sets`) says
+    which: `moe.w1024.rope[sliding_attention] x3,moe.full.rope[full_attention]`."""
     streams = f"hc{cfg.hyper.n}." if cfg.hyper is not None else ""
 
     def name(k):
         attn = (f"{'diff.' if k.diff else ''}{'latent.' if k.latent else ''}"
                 f"{'indexed.' if k.indexed else ''}"
                 f"{'full' if k.window is None else 'w%d' % k.window}."
-                f"{'rope' if k.rotary else 'nope'}")
+                f"{'rope' if k.rotary else 'nope'}"
+                f"{'[%s]' % k.rotary_set if k.rotary_set else ''}")
         keeps, reads = "^" if k.keeps else "", "" if k.reads is None else f"<{k.reads}"
         if k.block:
             return f"{streams}{k.mlp}.{attn}{keeps}{reads}"
@@ -995,18 +998,18 @@ class JaxTrainEngine(TrainEngine):
             attn_attrs = {}
             if tracing.enabled():  # host passes whose only readers are spans and counters
                 attn = [self._attn_counts(rows["segment_ids"]) for rows in stacks]
-                counts = [a[1:-1] + self._head_counts(rows, scored_fn)
+                counts = [a[1:-2] + self._head_counts(rows, scored_fn)
                           + self._mtp_counts(rows, scored_fn)
                           + self._ssm_counts(rows["segment_ids"])
                           + self._index_counts(rows)
                           + self._band_counts(rows["segment_ids"])
                           + self._mhc_counts(rows["segment_ids"])
-                          + self._kda_counts(rows["segment_ids"])
+                          + self._kda_counts(rows["segment_ids"]) + a[-1:]
                           for a, rows in zip(attn, stacks)]
                 self._count_batch(
                     "fused", len(mbs), sum(b.n_rows == 1 for b, _ in built),
                     n_tok, n_cells, *(sum(c) for c in zip(*counts)))
-                attn_attrs = dict(attn_row_len=attn[big][0], width=attn[big][-1])
+                attn_attrs = dict(attn_row_len=attn[big][0], width=attn[big][-2])
 
             step = self._train_step_fn(
                 loss_name, loss_fn, tuple(sorted(stacks[0].keys())), len(mbs),
@@ -1068,14 +1071,14 @@ class JaxTrainEngine(TrainEngine):
                 tracing.set_attrs(tokens=batch.total_tokens, cells=cells)
                 attn_attrs, counts = {}, None
                 if tracing.enabled():  # their only readers are spans and counters
-                    run_len, *attn, width = self._attn_counts(rows["segment_ids"])
+                    run_len, *attn, width, n_window = self._attn_counts(rows["segment_ids"])
                     counts = (*attn, *self._head_counts(rows, scored_fn),
                               *self._mtp_counts(rows, scored_fn),
                               *self._ssm_counts(rows["segment_ids"]),
                               *self._index_counts(rows),
                               *self._band_counts(rows["segment_ids"]),
                               *self._mhc_counts(rows["segment_ids"]),
-                              *self._kda_counts(rows["segment_ids"]))
+                              *self._kda_counts(rows["segment_ids"]), n_window)
                     attn_attrs = dict(attn_row_len=run_len, width=width)
             return (rows_dev, denom, batch.total_tokens, cells, attn_attrs, counts)
 
@@ -1256,7 +1259,8 @@ class JaxTrainEngine(TrainEngine):
         one kernel), summed over rows, q heads and layers:
         ops/attention.attn_grid_steps; the widest forward grid, kv steps
         a q block, of any layer and row: of a row alone the pairs of its
-        fullest q block)."""
+        fullest q block; of the cells run, those of the layers that have
+        a window)."""
         cfg = self.model_cfg
         segment_ids = np.asarray(segment_ids)
         rows, row_len = segment_ids.shape[-2:]
@@ -1280,7 +1284,8 @@ class JaxTrainEngine(TrainEngine):
         return (run_len, len(mbs) * rows * run_len, total(0), total(1),
                 cfg.n_q_heads * total(2), cfg.n_q_heads * total(3),
                 cfg.n_q_heads * total(5),
-                max(mb[4] for counts in per.values() for mb in counts))
+                max(mb[4] for counts in per.values() for mb in counts),
+                int(sum(mb[0] for w in windows if w is not None for mb in per[w])))
 
     def _ssm_counts(self, segment_ids: np.ndarray) -> Tuple[int, int, int, int]:
         """What the state-space layers' chunked scan does with packed rows
@@ -1358,7 +1363,8 @@ class JaxTrainEngine(TrainEngine):
                      n_index_choosing: int = 0, n_band_cells: int = 0,
                      n_mhc_cells: int = 0, n_mhc_loop_cells: int = 0,
                      n_kda_cells: int = 0, n_kda_chunks: int = 0,
-                     n_kda_live: int = 0, n_kda_resets: int = 0):
+                     n_kda_live: int = 0, n_kda_resets: int = 0,
+                     n_attn_window: int = 0):
         """What one train_batch did, on its `train.batch` span and in the
         recorder's counters: the micro-batches and how many of them the
         packer made one row (what lets attention skip the block pairs
@@ -1384,7 +1390,8 @@ class JaxTrainEngine(TrainEngine):
         whose forward and whose backward the kernels ran:
         `ops/kda._use_kernel`), its
         chunks, those with a token and the sequence starts
-        (`_kda_counts`)."""
+        (`_kda_counts`), the window layers' part of the cells the attention
+        kernels' block pairs ran (the full layers' is the rest)."""
         tracing.set_attrs(path=path, n_mbs=n_mbs, tokens=n_tok, cells=n_cells)
         tracing.count("train.batches")
         tracing.count("train.micro_batches", n_mbs)
@@ -1397,6 +1404,8 @@ class JaxTrainEngine(TrainEngine):
             tracing.count("train.mhc_loop_cells", n_mhc_loop_cells)
         tracing.count("train.attn_cells", n_attn_cells)
         tracing.count("train.attn_active_cells", n_attn_active)
+        tracing.count("train.attn_window_cells", n_attn_window)
+        tracing.count("train.attn_full_cells", n_attn_active - n_attn_window)
         tracing.count("train.attn_causal_cells", n_attn_causal)
         tracing.count("train.attn_grid_steps", n_attn_steps)
         tracing.count("train.attn_live_steps", n_attn_live)

@@ -5,7 +5,8 @@ covering the same architecture space: GQA attention, rotary variants,
 RMS/LayerNorm, gated MLPs, optional MoE, actor (LM head) or critic (scalar
 head) outputs, tied embeddings, and qk-norm (qwen3); and, beyond it, a
 kind per layer (`LayerKind`: the parts a layer has: a mixer, attention
-with its window and rotary, differential or latent or neither, a
+with its window and rotary (the stack's one table or a named set of its
+own, `RotarySet`), differential or latent or neither, a
 state-space mixer in one of two forms, a delta-rule mixer (`KDAConfig`)
 or a gated memory unit, and an MLP,
 dense or expert, either of which may be absent; a layer may keep a tensor
@@ -385,6 +386,23 @@ class HyperConnConfig:
 
 
 @dataclasses.dataclass(frozen=True)
+class RotarySet:
+    """One rotary table's parameters, for a stack whose layers do not all
+    turn q and k by the same one (`TransformerConfig.rotary_sets`; a
+    `LayerKind` names its own): the base, the scaling as
+    `ops/rotary.rotary_inv_freq` takes it (`scaling_params` a dict), and
+    `attention_factor`: what the table's `cos` and `sin` are multiplied
+    by (HF's `attention_scaling` of a scaled table on a plain head; the
+    logits carry its square)."""
+
+    base: float = 10000.0
+    scaling: Optional[float] = None
+    scaling_type: Optional[str] = None
+    scaling_params: Optional[dict] = None
+    attention_factor: float = 1.0
+
+
+@dataclasses.dataclass(frozen=True)
 class LayerKind:
     """What one layer of the stack is, known when the program is traced:
     the parts it has, each under its own norm with its own residual. A
@@ -393,7 +411,9 @@ class LayerKind:
     have one. For attention: its mask (`window` = how many positions
     back a token sees, itself included; None = all of its sequence),
     whether q and k get the rotary embedding (False = no position
-    encoding in this layer), and `diff`: differential attention, two
+    encoding in this layer) and by which table (`rotary_set`: a name in
+    `TransformerConfig.rotary_sets`; None = the stack's one table), and
+    `diff`: differential attention, two
     softmaxes a pair of heads, the second subtracted from the first
     (`transformer._diff_combine`), and `latent`: q and k, v through
     low-rank projections (`MLAConfig`, `transformer._latent_in`),
@@ -419,6 +439,7 @@ class LayerKind:
     reads: Optional[int] = None
     latent: bool = False
     indexed: bool = False
+    rotary_set: Optional[str] = None
 
     def __post_init__(self):
         if self.mlp not in ("dense", "moe", None):
@@ -434,11 +455,19 @@ class LayerKind:
             raise ValueError(f"LayerKind.window must be >= 1, got {self.window}")
         if self.mixer != "attention" and (
                 self.window is not None or not self.rotary or self.diff
-                or self.latent or self.indexed):
+                or self.latent or self.indexed or self.rotary_set is not None):
             # one spelling a kind: layers without attention compare equal
             raise ValueError(
-                "window, rotary, diff, latent and indexed describe an attention "
-                "mixer")
+                "window, rotary, rotary_set, diff, latent and indexed describe an "
+                "attention mixer")
+        if self.rotary_set is not None and (
+                not self.rotary or self.diff or self.latent or self.indexed
+                or self.reads is not None):
+            raise NotImplementedError(
+                "a rotary set of a layer's own is a plain head's table: the layer "
+                "rotates, and is no differential, latent or indexed attention, "
+                "nor a reader of another layer's k and v (which come rotated by "
+                "that layer's table)")
         if self.indexed and (self.window is not None or self.diff or self.latent
                              or self.keeps or self.reads is not None):
             raise NotImplementedError(
@@ -487,6 +516,14 @@ class LayerKind:
     def block(self) -> bool:
         """A transformer block: attention, then an MLP."""
         return self.mixer == "attention" and self.mlp is not None
+
+    @property
+    def table(self):
+        """Which rotary table turns this layer's q and k: False = none,
+        True = the stack's one, a name = that set of
+        `TransformerConfig.rotary_sets`. With the window, what tells one
+        attention variant of a traced body from another."""
+        return self.rotary and (self.rotary_set or True)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -552,6 +589,10 @@ class TransformerConfig:
     # The share of a head's columns the rotary embedding turns, from the
     # first (`partial_rotary_factor`): the rest are left as they are.
     rotary_fraction: float = 1.0
+    # More than the one table above: name -> `RotarySet`, each layer's
+    # `LayerKind.rotary_set` naming its own (every rotating layer names
+    # one, and `rotary_base` .. `rotary_scaling_params` are then unused).
+    rotary_sets: Optional[Dict[str, RotarySet]] = None
 
     attn_bias: bool = False  # qwen2 uses qkv bias
     attn_out_bias: bool = False  # gpt2 also biases the output projection
@@ -618,6 +659,10 @@ class TransformerConfig:
             self.indexer = IndexerConfig(**self.indexer)
         if isinstance(self.hyper, dict):
             self.hyper = HyperConnConfig(**self.hyper)
+        if self.rotary_sets is not None:
+            self.rotary_sets = {
+                name: RotarySet(**rs) if isinstance(rs, dict) else rs
+                for name, rs in self.rotary_sets.items()}
         if self.activation not in ("silu", "gelu", "relu2"):
             raise ValueError(
                 f"activation must be 'silu', 'gelu' or 'relu2', got {self.activation!r}")
@@ -684,6 +729,22 @@ class TransformerConfig:
                     "differential or neither")
         if any(k.diff for k in kinds) and (self.n_q_heads % 2 or self.n_kv_heads % 2):
             raise ValueError("differential attention pairs the heads: even counts")
+        named = {k.rotary_set for k in kinds if k.mixer == "attention" and k.rotary}
+        if named != {None} and named:
+            if None in named or not named <= set(self.rotary_sets or {}):
+                raise ValueError(
+                    f"the layers' rotary sets {sorted(map(str, named))} are not all "
+                    f"among rotary_sets {sorted(self.rotary_sets or {})}: where a "
+                    "stack has sets, every rotating layer names one of them")
+            if (self.pos_emb != "rotary" or self.mla is not None
+                    or self.indexer is not None or self.mtp is not None):
+                raise NotImplementedError(
+                    "rotary sets beside learned positions, latent attention, an "
+                    "indexer or a prediction module: models/transformer.py builds "
+                    "the sets' tables for plain heads' q and k and nothing else")
+        elif self.rotary_sets:
+            raise ValueError(
+                f"rotary_sets {sorted(self.rotary_sets)} that no layer names")
         if self.rotary_fraction != 1.0:
             turned = self.head_dim * self.rotary_fraction
             if (not 0.0 < self.rotary_fraction < 1.0 or turned != int(turned)
@@ -799,6 +860,8 @@ class TransformerConfig:
         what is missing."""
         missing = []
         kinds = self.kinds()
+        sets = {k.rotary_set: self.rotary_sets[k.rotary_set]
+                for k in kinds if k.rotary_set is not None}
         if any(k.latent for k in kinds):
             missing.append(
                 "a latent cache: latent attention keeps, a token, one row of "
@@ -865,13 +928,29 @@ class TransformerConfig:
                 f"parts {sorted({k.parts for k in kinds})}, the cache paths run "
                 "attention and an MLP in every layer"
             )
-        elif not self.one_kind and not any(k.latent or k.indexed for k in kinds):
+        elif not any(k.latent or k.indexed for k in kinds) and (
+                len({dataclasses.replace(k, rotary_set=None) for k in kinds}) > 1
+                if sets else not self.one_kind):
             missing.append(
                 "a cache manager with a kind per layer (window layers keep "
                 "the last `window` positions, full layers all; rotary or "
                 "none per layer): the layers here are "
                 f"{sorted({(k.mlp, k.window or 0, k.rotary) for k in kinds})}"
             )
+        if len(sets) > 1:
+            missing.append(
+                f"a rotary table a kind of layer: the layers here turn q and k by "
+                f"{len(sets)} rotary sets {sorted(sets)}, the cache paths build one "
+                "table from rotary_base and its scaling and turn every layer's new "
+                "q and k by it")
+        factors = {n: rs.attention_factor for n, rs in sets.items()
+                   if rs.attention_factor != 1.0}
+        if factors:
+            missing.append(
+                f"a scaled table's attention factor on a plain head: rotary sets "
+                f"{sorted(factors)} multiply cos and sin by "
+                f"{sorted(factors.values())}, the decode layer rotates by a table "
+                "of unit amplitude and scores at head_dim^-0.5")
         if self.attn_gate or self.post_norms or self.rotary_fraction != 1.0:
             missing.append(
                 "the attention output gate, the post-attention / post-MLP "

@@ -26,9 +26,11 @@ real_llm_base.py (blocks) — redesigned for XLA rather than translated:
   run of a repeated unit of one or more layers is one scan over the
   unit, whose stacks are cut from their kinds' by `lax.split`; a layer
   that repeats nothing runs as it is. Layers of a scan that differ only
-  in their attention share one traced body: which (window, rotary) each
+  in their attention share one traced body: which (window, rotary table:
+  none, the stack's one, or a named set's, `config.RotarySet`) each
   has is an index scanned beside its parameters that switches the
-  attention call alone. What is traced grows with the runs of the
+  attention call alone and picks the layer's table. What is traced
+  grows with the runs of the
   pattern, not the depth; a stack of one kind is the plain scan.
 - **Packed rows**: a batch is [R, T] token streams; each row packs several
   variable-length sequences tagged by segment ids (0 = padding). No pad
@@ -143,10 +145,10 @@ def _init_layer_stack(cfg: TransformerConfig, keys, n: int, kind: LayerKind,
             }
             if cfg.qk_norm:
                 attn["q_norm"] = attn["q_norm"] * _INDEXED_Q_GAIN
-        if cfg.qk_norm and cfg.rotary_fraction != 1.0:
+        if cfg.qk_norm and (cfg.rotary_fraction != 1.0 or kind.rotary_set is not None):
             # `_LATENT_Q_GAIN`'s reason: under unit scores a softmax over
             # thousands of keys is flat, and no check of logprobs would see
-            # which of a head's columns were turned
+            # which of a head's columns were turned, nor by which table
             attn["q_norm"] = attn["q_norm"] * _LATENT_Q_GAIN
         layers["attn"] = attn
     elif kind.mixer == "ssm" and cfg.ssm.form == "mamba1":
@@ -334,7 +336,8 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Params:
     params: Params = {
         "embedding": {"weight": dense(keys[7], (V, D), scale=(
             _INDEXED_EMBED_SCALE
-            if (cfg.hyper is not None or any(k.indexed or k.mixer == "kda" for k in kinds))
+            if (cfg.hyper is not None or cfg.rotary_sets is not None
+                or any(k.indexed or k.mixer == "kda" for k in kinds))
             and not cfg.tied_embeddings else 0.02))},
         "final_norm": {"weight": jnp.ones((D,), pdt)},
     }
@@ -599,7 +602,8 @@ def _attn_out(out, gate, lp, cfg, cdt, l0=None):
 def _attn_core(q, k, v, cfg, cos, sin, segment_ids, positions, attn_impl, mesh,
                variants, variant_index, diff, index, ix, rotated=False):
     """What of an attention layer crosses tokens: rotary (unless q and k
-    come `rotated`) and the attention call of the (window, rotary)
+    come `rotated`; `cos`, `sin`: the layer's own table, `layer_body`
+    picks it) and the attention call of the (window, table)
     variant that runs, differential attention's split before it, the
     indexer's choice (`ix`: its three projections) inside it. Returns
     the call's output `[R, T, Hq, hd]`, k as attended, the layer's sums."""
@@ -1144,9 +1148,11 @@ def forward(
         if cfg.hyper is not None:  # every stream starts as the embedding
             x = act_c(jnp.concatenate([x] * cfg.hyper.n, axis=-1))
 
+    # One table a rotary set, built once: `LayerKind.table` -> (cos, sin).
+    tables: Dict[Any, Any] = {}
     if cfg.pos_emb == "learned":
         cos = sin = None
-    else:
+    elif cfg.rotary_sets is None:
         inv_freq = jnp.asarray(
             rotary_inv_freq(
                 cfg.rotary_dim, cfg.rotary_base, cfg.rotary_scaling,
@@ -1154,6 +1160,13 @@ def forward(
             )
         )
         cos, sin = rotary_cos_sin(positions, inv_freq)  # [R, T, rotary_dim/2]
+    else:
+        for name, rs in cfg.rotary_sets.items():
+            with jax.named_scope(f"rotary_table.{name}"):
+                tables[name] = rotary_cos_sin(positions, jnp.asarray(rotary_inv_freq(
+                    cfg.rotary_dim, rs.base, rs.scaling, rs.scaling_type,
+                    rs.scaling_params)), rs.attention_factor)
+        cos, sin = next(iter(tables.values()))
     index = None
     if cfg.indexer is not None:
         # the indexer's own tables: the same base over its head size
@@ -1247,8 +1260,9 @@ def forward(
         """carry, (one layer's parameters, which of `variants` it is),
         the tensor it reads -> carry, its (k, v) (for a layer that keeps:
         what it keeps): a layer with the parts of `kind` whose
-        attention is one of the (window, rotary) `variants`, under the
-        remat mode. Layers that differ only in their attention share the
+        attention is one of the (window, table) `variants`
+        (`LayerKind.table`), under the remat mode. Layers that differ
+        only in their attention share the
         one traced body: the switch is around the attention call alone
         (`_attn_core`), so what the backward pass keeps of a layer is one
         layer's, whatever its kind.
@@ -1265,9 +1279,17 @@ def forward(
         st = _Stretch(
             cfg, kind, cdt, route_in=banded, mlp_ckpt=remat_mode == "mlp" and not banded,
             rot_in=banded and not kind.latent and cos is not None
-            and all(rotary for _, rotary in variants),
+            and all(table for _, table in variants),
             hc_kernel=False if mesh is not None and mesh.size > 1 else None)
         hyper = cfg.hyper is not None
+        # The variants' tables (a stack of rotary sets): one, the body's own;
+        # several, stacked a variant, and a layer takes its own by its variant
+        # index before its first step, so the band loop's rotation and the
+        # attention call's alike read the one pair they are handed.
+        own = [tables.get(t, (cos, sin)) for _, t in variants]
+        several = len({t for _, t in variants if t in tables}) > 1
+        if several:
+            stacked = tuple(jnp.stack([pair[j] for pair in own]) for j in (0, 1))
 
         def res_err(h_res):
             """What Sinkhorn left undone of H_res, summed over real tokens."""
@@ -1291,6 +1313,10 @@ def forward(
             kv, terms, step, w = None, {}, _mlp_part, {}
             if kind.mixer == "attention":
                 mp = lp["attn"]
+                cos, sin = own[0]
+                if several:
+                    cos, sin = (jax.lax.dynamic_index_in_dim(a, variant_index, keepdims=False)
+                                for a in stacked)
                 side = (() if cos is None else (cos, sin)) + (
                     (index.cos, index.sin) if kind.indexed else ())
                 q, k, v, *mid = run(
@@ -1421,7 +1447,7 @@ def forward(
         for j in range(p):
             idx = range(seg.start + j, seg.start + p * seg.repeats, p)
             of_j = [kinds[i] for i in idx]
-            rest = [(k.window, k.rotary) for k in of_j]
+            rest = [(k.window, k.table) for k in of_j]
             variants = tuple(sorted(set(rest), key=rest.index))
             bodies.append(layer_body(of_j[0], variants, seg.repeats > 1))
             w = None if len(variants) == 1 else jnp.asarray(

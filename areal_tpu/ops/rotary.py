@@ -25,6 +25,21 @@ def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
     return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
 
 
+def yarn_attention_factor(factor: float, params: Optional[dict] = None) -> float:
+    """What a plain head's YaRN table multiplies `cos` and `sin` by, as
+    HF's `_compute_yarn_parameters` says it: `attention_factor` where the
+    config states one, else the quotient of the two temperatures where it
+    states both `mscale` and `mscale_all_dim`, else `yarn_mscale(factor)`.
+    (Latent attention puts its temperature on the softmax scale instead:
+    `MLAConfig.softmax_scale_factor`.)"""
+    p = params or {}
+    if p.get("attention_factor") is not None:
+        return float(p["attention_factor"])
+    if p.get("mscale") and p.get("mscale_all_dim"):
+        return yarn_mscale(factor, p["mscale"]) / yarn_mscale(factor, p["mscale_all_dim"])
+    return yarn_mscale(factor)
+
+
 def rotary_inv_freq(
     head_dim: int,
     base: float = 10000.0,
@@ -60,26 +75,35 @@ def rotary_inv_freq(
             wavelen < high_wl, inv_freq, np.where(wavelen > low_wl, scaled, smoothed)
         )
     elif scaling_type == "yarn" and scaling:
-        # YaRN (arXiv:2309.00071) as DeepSeek-V3 runs it: the dimensions
+        # YaRN (arXiv:2309.00071) as DeepSeek-V3 and HF's
+        # `_compute_yarn_parameters` run it: the dimensions
         # that turn more than `beta_fast` times over the original context
         # keep their frequency, those that turn fewer than `beta_slow`
-        # times are divided by the factor, a linear ramp between.
+        # times are divided by the factor, a linear ramp between whose
+        # ends are whole dimensions unless `truncate` is false.
         p = scaling_params or {}
         orig_ctx = p.get("original_max_position_embeddings", 4096)
         turns = lambda beta: head_dim * math.log(orig_ctx / (beta * 2 * math.pi)) / (
             2 * math.log(base))
-        low = max(math.floor(turns(p.get("beta_fast", 32))), 0)
-        high = min(math.ceil(turns(p.get("beta_slow", 1))), head_dim // 2 - 1)
+        low, high = turns(p.get("beta_fast") or 32), turns(p.get("beta_slow") or 1)
+        if p.get("truncate", True):
+            low, high = math.floor(low), math.ceil(high)
+        low, high = max(low, 0), min(high, head_dim - 1)
         ramp = np.clip((np.arange(head_dim // 2, dtype=np.float64) - low)
                        / max(high - low, 1e-3), 0.0, 1.0)
         inv_freq = inv_freq / scaling * ramp + inv_freq * (1.0 - ramp)
     return inv_freq.astype(np.float32)
 
 
-def rotary_cos_sin(positions: jnp.ndarray, inv_freq: jnp.ndarray):
-    """cos/sin of shape (*positions.shape, head_dim/2), fp32."""
+def rotary_cos_sin(positions: jnp.ndarray, inv_freq: jnp.ndarray,
+                   attention_factor: float = 1.0):
+    """cos/sin of shape (*positions.shape, head_dim/2), fp32, both times
+    `attention_factor` (a scaled table's temperature, HF's
+    `attention_scaling`: q and k each carry it, so the logits its square)."""
     freqs = positions.astype(jnp.float32)[..., None] * inv_freq[None, :]
-    return jnp.cos(freqs), jnp.sin(freqs)
+    if attention_factor == 1.0:
+        return jnp.cos(freqs), jnp.sin(freqs)
+    return jnp.cos(freqs) * attention_factor, jnp.sin(freqs) * attention_factor
 
 
 def apply_rotary(

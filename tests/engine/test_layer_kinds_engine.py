@@ -96,6 +96,9 @@ def test_a_train_step_updates_the_weights_and_leaves_the_buffer(depth):
     assert stats["t/moe_drop_rate"] == 0.0
     # the einsum reference runs every cell of a row whatever the mask
     assert c["train.attn_active_cells"] == c["train.attn_causal_cells"] > 0
+    # four window layers to one full layer: the split of the cells run
+    assert c["train.attn_window_cells"] == 4 * c["train.attn_full_cells"] > 0
+    assert c["train.attn_window_cells"] + c["train.attn_full_cells"] == c["train.attn_active_cells"]
     assert c["train.attn_cells"] == c["train.cells"]
     dispatch = [s["attrs"] for s in got["spans"] if s["name"] == "train.dispatch"]
     assert len(dispatch) == (N_MBS if depth else 1)

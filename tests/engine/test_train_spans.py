@@ -130,6 +130,8 @@ def test_attributes_and_counters_count_tokens_and_cells(recorded):
         "train.attn_cells": a["cells"],
         "train.attn_active_cells": all_cells,
         "train.attn_causal_cells": all_cells,
+        # no layer has a window: the split says none and the whole
+        "train.attn_window_cells": 0, "train.attn_full_cells": all_cells,
         # the einsum reference has no grid to walk
         "train.attn_grid_steps": 0, "train.attn_live_steps": 0,
         "train.attn_bwd_steps": 0,

@@ -668,3 +668,27 @@ def test_an_accumulate_step_of_the_gated_deltanet_stack_compiles_at_16k(one_chip
     for name in ("splash_pairs_fwd", "splash_pairs_bwd", "moe_rows_add"):
         assert name in text, name
     _rule_in_two_kernels(compiled, "qwen3-next-d4-e32")
+
+
+def test_an_accumulate_step_of_the_two_table_stack_compiles_at_16k(one_chip, monkeypatch):
+    """A forward-backward micro-batch of `mellum2-d4e16-train-ppo-long`'s
+    model at its one shape `(1, 16384)`, full remat, the masked loss head:
+    one scan of four layers, three through a window of 1,024 and one over
+    the whole sequence, whose body takes the layer's rotary table (plain,
+    or YaRN's with its attention factor) by its variant index before the
+    first stretch rotates q and k band by band, and whose switch picks the
+    mask alone; experts of 896 (seven lanes of 128) through the held
+    experts' tiles and the row adds. The compiler's temporaries stay under
+    5 GB beside 8.33 GB of weights, gradient sums and moments."""
+    from areal_tpu.models.transformer import looping_layers
+
+    cfg, compiled = _accumulate_step(one_chip, monkeypatch, "mellum2-d4-e16", 16384)
+    assert looping_layers(cfg, 1, 16384) == 4
+    assert [seg.repeats for seg in cfg.segments()] == [4]
+    text = compiled.as_text()
+    for name in ("splash_pairs_fwd", "splash_pairs_bwd", "moe_rows_add"):
+        assert name in text, name
+    assert "conditional" in text  # the window / full switch around the attention call
+    # both tables are built once, [1, 16384, 64] float32 each, and stacked for the scan
+    assert "rotary_table.sliding_attention" in text and "rotary_table.full_attention" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 5.0e9
