@@ -1004,7 +1004,8 @@ class JaxTrainEngine(TrainEngine):
                           + self._index_counts(rows)
                           + self._band_counts(rows["segment_ids"])
                           + self._mhc_counts(rows["segment_ids"])
-                          + self._kda_counts(rows["segment_ids"]) + a[-1:]
+                          + self._kda_counts(rows["segment_ids"])
+                          + self._kda_taps_counts(rows["segment_ids"]) + a[-1:]
                           for a, rows in zip(attn, stacks)]
                 self._count_batch(
                     "fused", len(mbs), sum(b.n_rows == 1 for b, _ in built),
@@ -1078,7 +1079,8 @@ class JaxTrainEngine(TrainEngine):
                               *self._index_counts(rows),
                               *self._band_counts(rows["segment_ids"]),
                               *self._mhc_counts(rows["segment_ids"]),
-                              *self._kda_counts(rows["segment_ids"]), n_window)
+                              *self._kda_counts(rows["segment_ids"]),
+                              *self._kda_taps_counts(rows["segment_ids"]), n_window)
                     attn_attrs = dict(attn_row_len=run_len, width=width)
             return (rows_dev, denom, batch.total_tokens, cells, attn_attrs, counts)
 
@@ -1318,6 +1320,24 @@ class JaxTrainEngine(TrainEngine):
         return tuple(n * c for c in chunk_counts(
             segment_ids, self.model_cfg.kda.chunk_size))
 
+    def _kda_taps_counts(self, segment_ids: np.ndarray) -> Tuple[int, int]:
+        """The cells the delta-rule layers' convolutions are asked for (a
+        call's R x T a layer: q's, k's and v's counted once; `segment_ids`
+        as `_kda_counts` takes them) and those of them that go through the
+        kernels (`kda_taps_fwd`, `kda_taps_bwd`): all where the rule takes
+        its own and the row's length and the widths fit
+        (`ops/kda.taps_in_kernel`), none where the plain form runs."""
+        n = self.model_cfg.n_kda_layers
+        if not n:
+            return 0, 0
+        from areal_tpu.ops import kda
+
+        cells = n * int(np.prod(np.shape(segment_ids)))
+        cfg = self.model_cfg.kda
+        in_kernel = kda.taps_in_kernel(cfg, np.shape(segment_ids)[-1],
+                                       kda._use_kernel(cfg.head_dim, self.mesh))
+        return cells, cells if in_kernel else 0
+
     def _head_counts(self, rows_np: Dict[str, np.ndarray],
                      scored_fn: Optional[ScoredFn], shift: int = 1) -> Tuple[int, int]:
         """What the loss head does with packed rows (on the host, before
@@ -1364,6 +1384,7 @@ class JaxTrainEngine(TrainEngine):
                      n_mhc_cells: int = 0, n_mhc_loop_cells: int = 0,
                      n_kda_cells: int = 0, n_kda_chunks: int = 0,
                      n_kda_live: int = 0, n_kda_resets: int = 0,
+                     n_kda_taps_cells: int = 0, n_kda_taps_kernel: int = 0,
                      n_attn_window: int = 0):
         """What one train_batch did, on its `train.batch` span and in the
         recorder's counters: the micro-batches and how many of them the
@@ -1390,7 +1411,8 @@ class JaxTrainEngine(TrainEngine):
         whose forward and whose backward the kernels ran:
         `ops/kda._use_kernel`), its
         chunks, those with a token and the sequence starts
-        (`_kda_counts`), the window layers' part of the cells the attention
+        (`_kda_counts`), the cells their convolutions were asked for and
+        those the taps' kernels took (`_kda_taps_counts`), the window layers' part of the cells the attention
         kernels' block pairs ran (the full layers' is the rest)."""
         tracing.set_attrs(path=path, n_mbs=n_mbs, tokens=n_tok, cells=n_cells)
         tracing.count("train.batches")
@@ -1442,6 +1464,8 @@ class JaxTrainEngine(TrainEngine):
             tracing.count("train.kda_chunks", n_kda_chunks)
             tracing.count("train.kda_chunks_live", n_kda_live)
             tracing.count("train.kda_resets", n_kda_resets)
+            tracing.count("train.kda_taps_cells", n_kda_taps_cells)
+            tracing.count("train.kda_taps_kernel_cells", n_kda_taps_kernel)
         if self._n_indexed:
             tracing.count("train.index_cells", n_index_cells)
             tracing.count("train.index_selected", n_index_selected)

@@ -97,6 +97,13 @@ of whole lane tiles; decided from what the code sees, no argument):
   q, k, v, f, b and dO read and dq, dk, dv, df, db written cells-major,
   dA and d dt_bias summed on the way. No loop of XLA's, no part of a chunk
   and no cotangent of one in HBM.
+- the mixer's way into the rule, on the chip, both decays: q's, k's and v's
+  mask and short convolution are a kernel pair, `kda_taps_fwd` /
+  `kda_taps_bwd` (`ops/pallas/kda_taps.py`: `ops/ssm.causal_conv`'s meaning
+  with the mask inside, an array read once and written once, the weights'
+  sums inside the backward), where the rule takes its kernels and a row is
+  whole blocks and the widths whole lane tiles (`taps_in_kernel`);
+  `causal_conv` after a `where` everywhere else.
 - the CPU, a mesh of several devices, toy heads: `intra` + `states_scan`
   a group at a time forward, `states_scan` and `states_scan_bwd` under
   `jax.vjp` of `intra` backward: the plain form, and the tests' reference.
@@ -599,22 +606,44 @@ def _use_kernel(K: int, mesh) -> bool:
             and K % 128 == 0)
 
 
+def taps_in_kernel(kda: KDAConfig, T: int, kernel) -> bool:
+    """Whether a row of T cells takes the taps' kernels (`ops/pallas/
+    kda_taps.py`) on its way into the rule: where the rule takes its own
+    (`kernel`, as `delta_rule` reads it) and q's, k's and v's shapes fit."""
+    from areal_tpu.ops.pallas import kda_taps
+
+    return bool(kernel) and all(kda_taps.fits(T, h * kda.head_dim, kda.conv_kernel)
+                                for h in (kda.key_heads, kda.n_heads))
+
+
 def kda_mixer(q, k, v, f, b, kp, kda: KDAConfig, segment_ids, cdt, mesh=None,
               kernel=None):
     """What of the mixer crosses tokens. q, k [R, T, Hk K], v [R, T, H K]
     (the three projections), f the decay's input ([R, T, H K] the low-rank
     product, or [R, T, H] the projection's column where the decay is a
     head's), b [R, T, H] (beta's projection), `kp` the layer's `conv_*`,
-    `A_log`, `dt_bias` -> o [R, T, H, K] in `cdt`, before the head norm."""
+    `A_log`, `dt_bias` -> o [R, T, H, K] in `cdt`, before the head norm.
+    `kernel` as `delta_rule` takes it (None: `_use_kernel`); the taps take
+    their kernels with the rule's, where the shapes fit (`taps_in_kernel`)."""
     R, T, _ = q.shape
     H, Hk, K, C = kda.n_heads, kda.key_heads, kda.head_dim, kda.chunk_size
     f32 = jnp.float32
     valid = segment_ids > 0
+    if kernel is None:
+        kernel = _use_kernel(K, mesh)
     # masked on the way in: whatever padding cells hold (the residual
     # stream carries them along) reaches neither a result nor a gradient
-    q, k, v, f, b = (jnp.where(valid[..., None], a, 0) for a in (q, k, v, f, b))
+    masked = lambda *xs: tuple(jnp.where(valid[..., None], a, 0) for a in xs)
+    f, b = masked(f, b)
     with jax.named_scope("kda_taps"):
-        conv = lambda x, w: causal_conv(x.astype(cdt), w.astype(cdt), None, segment_ids)
+        if taps_in_kernel(kda, T, kernel):  # q's, k's and v's mask is the kernels' own
+            from areal_tpu.ops.pallas import kda_taps
+
+            conv = lambda x, w: kda_taps.taps(x.astype(cdt), w.astype(cdt), None, segment_ids,
+                                              kernel == "interpret")
+        else:
+            q, k, v = masked(q, k, v)
+            conv = lambda x, w: causal_conv(x.astype(cdt), w.astype(cdt), None, segment_ids)
         q, k, v = (conv(x, kp[n]).reshape(R, T, h, K)
                    for x, n, h in ((q, "conv_q", Hk), (k, "conv_k", Hk), (v, "conv_v", H)))
     with jax.named_scope("kda_gate"):
@@ -630,8 +659,6 @@ def kda_mixer(q, k, v, f, b, kp, kda: KDAConfig, segment_ids, cdt, mesh=None,
             grow = lambda a: jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
             q, k, v, f, beta, segment_ids = (
                 grow(a) for a in (q, k, v, f, beta, segment_ids))
-        if kernel is None:
-            kernel = _use_kernel(K, mesh)
         o = delta_rule(q, k, v, f, beta, A, dt_bias, segment_ids, C, kernel)
     return o[:, :T]
 

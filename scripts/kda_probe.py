@@ -22,8 +22,21 @@ nearer). Every row says where it was made: the device, the shape, the dtype
 and whether the kernels ran in interpret mode (a rehearsal off the chip,
 whose milliseconds mean nothing).
 
+`--taps`: the mixers' way into the rule instead (`ops/pallas/kda_taps.py`):
+milliseconds a call of one operand's masked convolution, `[1, T, C]` bf16 at
+the widths the two cells have (`--widths`, 4,096 and 2,048 columns) under K
+taps, forward and forward + backward (the loss `sum(y * g)` with the
+gradients of x and w), in two arms: `plain` (`ops/ssm.causal_conv` after the
+`where` of the operand, XLA's) and `kernel` (`kda_taps_fwd`, `kda_taps_bwd`),
+the kernel's rows beside their worst differences from the plain arm's, at each
+block height (`--rows`) and chunk (`--chunk-cells`) asked for; with `--exact`
+each arm also against the plain form in float32 over the same operands (`err`
+the worst difference, `err_rms` the root of the mean square).
+
     python scripts/kda_probe.py [--out chiprun_out/x.jsonl] [--chunks 64 128]
         [--decay channel head] [--key-heads 32 16] [--arms plain fused] [--exact]
+    python scripts/kda_probe.py --taps [--out ...] [--widths 4096 2048] [--taps-k 4]
+        [--rows 256 128] [--chunk-cells 64 128] [--exact]
 """
 
 import argparse
@@ -76,6 +89,64 @@ def inputs(T, H, K, tokens, seed=0, decay="channel", Hk=None):
             jnp.zeros((H, K) if decay == "channel" else (H,), jnp.float32), jnp.asarray(seg))
 
 
+def taps_rows(args):
+    """`--taps`: a row a width, a fill, a block height and an arm."""
+    from areal_tpu.ops.pallas import kda_taps
+    from areal_tpu.ops.ssm import causal_conv
+
+    T, K = args.shape[0], args.taps_k
+    rng = np.random.default_rng(0)
+    rows = []
+    names = ("y", "dx", "dw")
+    for C, fill in ((c, fl) for c in args.widths for fl in args.fill):
+        seg = inputs(T, 1, 1, int(T * fill))[-1]
+        x, g = (jnp.asarray(rng.normal(size=(1, T, C)), jnp.bfloat16) for _ in range(2))
+        w = jnp.asarray(rng.normal(size=(K, C)) / 2, jnp.bfloat16)
+        plain = lambda x, w: causal_conv(jnp.where((seg > 0)[..., None], x, 0), w, None, seg)
+        kernel = lambda x, w: kda_taps.taps(x, w, None, seg, args.interpret)
+        three = lambda fn: jax.jit(jax.value_and_grad(
+            lambda x, w: jnp.sum(fn(x, w).astype(jnp.float32) * g), (0, 1)))
+        want = exact = None
+        if args.exact:  # the plain form in float32 over the same operands: what both arms round
+            f32 = lambda a: a.astype(jnp.float32)
+            exact = [jax.jit(plain)(f32(x), f32(w))] + list(three(plain)(f32(x), f32(w))[1])
+        for name, fn, height, chunk in [("plain", plain, 0, 0)] + [
+                ("kernel", kernel, h, c) for h in args.rows for c in args.chunk_cells]:
+            if height:
+                kda_taps.ROWS = height
+            if chunk:
+                kda_taps.CHUNK = chunk
+            fwd, both = jax.jit(fn), three(fn)
+            row = dict(device=jax.devices()[0].device_kind, interpret=args.interpret,
+                       shape=[1, T, C], dtype=str(x.dtype), taps=K, fill=fill, arm=name,
+                       rows=height, chunk=chunk, fwd_ms=timed(fwd, (x, w), 20) * 1e3,
+                       fwd_bwd_ms=timed(both, (x, w), 20) * 1e3)
+            got = [a.astype(jnp.float32) for a in (fwd(x, w),) + both(x, w)[1]]
+            if exact is not None:
+                row["err"] = {n: float(jnp.abs(a - t).max()) for n, a, t in zip(names, got, exact)}
+                row["err_rms"] = {n: float(jnp.sqrt(jnp.mean(jnp.square(a - t))))
+                                  for n, a, t in zip(names, got, exact)}
+            if want is None:
+                want = got
+            else:
+                row["max_diff"] = {n: float(jnp.abs(a - t).max())
+                                   for n, a, t in zip(names, got, want)}
+                row["max_abs"] = {n: float(jnp.abs(t).max()) for n, t in zip(names, want)}
+                row["finite"] = bool(all(jnp.isfinite(a).all() for a in got))
+            rows.append(row)
+            print(json.dumps(row), flush=True)
+            jax.clear_caches()
+    return rows
+
+
+def write(out, rows):
+    if out:
+        os.makedirs(os.path.dirname(out), exist_ok=True)
+        with open(out, "w") as f:
+            for r in rows:
+                f.write(json.dumps(r) + "\n")
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default=None)
@@ -91,9 +162,19 @@ def main():
                     help="T H K: a small one with --interpret rehearses the script off the chip")
     ap.add_argument("--interpret", action="store_true")
     ap.add_argument("--exact", action="store_true")
+    ap.add_argument("--taps", action="store_true",
+                    help="time the mixers' masked convolution instead of the rule")
+    ap.add_argument("--widths", type=int, nargs="+", default=[4096, 2048])
+    ap.add_argument("--taps-k", type=int, default=4)
+    ap.add_argument("--rows", type=int, nargs="+", default=[256],
+                    help="under --taps: cells a grid step of the kernels")
+    ap.add_argument("--chunk-cells", type=int, nargs="+", default=[64],
+                    help="under --taps: cells of a strip the kernels hold in registers")
     args = ap.parse_args()
     T, H, K = args.shape
     kernel = "interpret" if args.interpret else True
+    if args.taps:
+        return write(args.out, taps_rows(args))
     rows = []
     from areal_tpu.ops.pallas import kda_fwd
 
@@ -151,11 +232,7 @@ def main():
                 rows.append(row)
                 print(json.dumps(row), flush=True)
                 jax.clear_caches()
-    if args.out:
-        os.makedirs(os.path.dirname(args.out), exist_ok=True)
-        with open(args.out, "w") as f:
-            for r in rows:
-                f.write(json.dumps(r) + "\n")
+    write(args.out, rows)
 
 
 if __name__ == "__main__":

@@ -93,6 +93,8 @@ def test_a_train_step_moves_both_mixers_and_counts_what_the_rule_ran(depth):
     assert c["train.kda_chunks"] * 64 == c["train.kda_cells"]
     # the CPU takes the plain form, forward and backward
     assert c["train.kda_fwd_kernel_cells"] == c["train.kda_bwd_kernel_cells"] == 0
+    assert c["train.kda_taps_cells"] == 3 * c["train.cells"]  # the convolutions' cells
+    assert c["train.kda_taps_kernel_cells"] == 0
     assert 0 < c["train.kda_chunks_live"] <= c["train.kda_chunks"]
     assert c["train.kda_resets"] == 3 * len(lens)
     assert c["train.attn_cells"] == c["train.cells"]  # the one attention layer's alone

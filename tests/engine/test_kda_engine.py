@@ -88,6 +88,10 @@ def test_a_train_step_moves_both_mixers_and_counts_what_the_rule_ran(depth):
     assert c["train.kda_chunks"] * 64 == c["train.kda_cells"]
     # the CPU takes the plain form, forward and backward
     assert c["train.kda_fwd_kernel_cells"] == c["train.kda_bwd_kernel_cells"] == 0
+    # the convolutions of three layers were asked for every cell, and none of
+    # them went through the taps' kernels
+    assert c["train.kda_taps_cells"] == 3 * c["train.cells"]
+    assert c["train.kda_taps_kernel_cells"] == 0
     assert 0 < c["train.kda_chunks_live"] <= c["train.kda_chunks"]
     assert c["train.kda_resets"] == 3 * len(lens)
     assert c["train.attn_cells"] == c["train.cells"]  # the one latent layer's alone
@@ -158,3 +162,34 @@ def test_the_kernels_cells_are_the_rules_where_the_kernels_run(kernel, monkeypat
     assert c["train.kda_fwd_kernel_cells"] == (768 if kernel else 0)
     assert c["train.kda_bwd_kernel_cells"] == (768 if kernel else 0)
     assert seen == [(eng.model_cfg.kda.head_dim, eng.mesh)]
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["plain", "kernel"])
+@pytest.mark.parametrize("head_dim", [16, 128], ids=["toy_heads", "lane_tiles"])
+def test_the_taps_kernels_cells_are_the_convolutions_where_the_kernels_run(
+        kernel, head_dim, monkeypatch):
+    """`train.kda_taps_cells`: rows x row length x delta-rule layers, several
+    micro-batches summed; `train.kda_taps_kernel_cells`: all of them where
+    `ops/kda._use_kernel` holds and the shapes fit the taps' kernels (heads of
+    128: widths of whole strips; a row of whole blocks), 0 where the plain
+    form runs (the CPU; widths or a row's length that do not fit)."""
+    import dataclasses
+
+    _, eng = engine(0, row_len_multiple=256)
+    eng.model_cfg = dataclasses.replace(
+        eng.model_cfg, kda=dataclasses.replace(eng.model_cfg.kda, head_dim=head_dim))
+    monkeypatch.setattr(kda, "_use_kernel", lambda K, mesh: kernel)
+    seg = np.zeros((1, 256), np.int32)
+    seg[0, :70] = 1
+    took = kernel and head_dim == 128
+    assert eng._kda_taps_counts(seg) == (3 * 256, 3 * 256 if took else 0)
+    assert eng._kda_taps_counts(np.stack([seg, seg])) == (6 * 256, 6 * 256 if took else 0)
+    assert eng._kda_taps_counts(seg[:, :200]) == (3 * 200, 0)  # no whole blocks
+    tracing.start()
+    try:
+        eng._count_batch("fused", 1, 1, 70, 256, *([0] * 10), n_kda_cells=768,
+                         n_kda_taps_cells=768, n_kda_taps_kernel=768 if took else 0)
+    finally:
+        c = tracing.stop()["counters"]
+    assert c["train.kda_taps_cells"] == 768
+    assert c["train.kda_taps_kernel_cells"] == (768 if took else 0)

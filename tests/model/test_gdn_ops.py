@@ -6,7 +6,8 @@ enough to underflow a chunk, the scalar form against the channel form fed
 the same decay K times, the kernels in interpret mode (the forward's one
 kernel reading a key head through its blocks' index, the backward's summing
 a key head's gradients over its value heads) against the plain form and its
-`jax.vjp`, a row with an empty tail, and the host's counts. CPU, float32, toy
+`jax.vjp`, a row with an empty tail, the mixer under the rule's and the
+taps' kernels against the plain mixer, and the host's counts. CPU, float32, toy
 widths. (A packed row against each of its sequences alone: `recurrence`
 runs a sequence at a time, so every comparison with it is that.)"""
 
@@ -17,7 +18,7 @@ import pytest
 
 from areal_tpu.models.config import KDAConfig
 from areal_tpu.ops import kda
-from areal_tpu.ops.pallas import kda_bwd, kda_fwd
+from areal_tpu.ops.pallas import kda_bwd, kda_fwd, kda_taps
 from benchmark.reference import qwen3_next as ref
 
 HK, H, K = 2, 4, 16
@@ -298,6 +299,47 @@ def test_the_mixer_draws_and_runs_a_decay_a_head_over_grouped_keys():
                                   jnp.ones((1, n), jnp.int32), jnp.float32)
             np.testing.assert_allclose(np.asarray(packed[:, o:o + n]), np.asarray(alone),
                                        atol=2e-5, err_msg=f"sequence {s}")
+
+
+@pytest.mark.parametrize("hk", [1, 2], ids=["grouped_keys", "a_key_a_value_head"])
+def test_the_mixer_under_its_kernels_is_the_plain_mixer(hk, monkeypatch):
+    """`kda_mixer(..., kernel="interpret")` at heads of 128 (q and k of `hk`
+    key heads' columns, v of two value heads': the taps' kernels take widths
+    that differ), a packed row with NaN in its padding cells: the output and
+    the gradients of q, k, v, f, b, of `conv_q`, `conv_k`, `conv_v` and of the
+    decay's `A_log` and `dt_bias` against `kernel=False`, at the limits the
+    rule's kernels are held to."""
+    monkeypatch.setattr(kda_taps, "ROWS", 32)
+    cfg = KDAConfig(n_heads=2, n_key_heads=hk, head_dim=128, gate_rank=None, chunk_size=16,
+                    decay="head", decay_input="column", gate_act="silu")
+    dense = lambda key, shape, scale=None: jax.random.normal(key, shape) * (
+        scale if scale is not None else shape[-2] ** -0.5)
+    kp = {n: a[0] for n, a in kda.init_kda_params(
+        cfg, 32, dense, jax.random.PRNGKey(0), 1, jnp.float32).items()}
+    rng = np.random.default_rng(0)
+    seg = jnp.asarray(_segments(((20, 31, 9), (45, 11)), 64))
+    assert kda.taps_in_kernel(cfg, 64, "interpret")
+    ran = []
+    taps = kda_taps.taps
+    monkeypatch.setattr(kda_taps, "taps", lambda *a: ran.append(a[0].shape[-1]) or taps(*a))
+    xs = tuple(jnp.asarray(rng.normal(size=(2, 64, w)), jnp.float32)
+               for w in (hk * 128, hk * 128, 256, 2, 2))
+    nan = lambda a: jnp.where((seg > 0)[..., None], a, jnp.nan)
+    w = jnp.asarray(rng.normal(size=(2, 64, 2, 128)), jnp.float32) * (seg > 0)[..., None, None]
+    run = lambda kernel, xs: jax.value_and_grad(lambda xs, kp: (kda.kda_mixer(
+        *xs, kp, cfg, seg, jnp.float32, kernel=kernel) * w).sum(), (0, 1))(xs, kp)
+    with jax.default_matmul_precision("highest"):
+        (got_l, got), (want_l, want) = run("interpret", tuple(nan(a) for a in xs)), run(False, xs)
+    assert ran[:3] == [hk * 128, hk * 128, 256]
+    np.testing.assert_allclose(float(got_l), float(want_l), rtol=1e-5)
+    for name, a, t in zip(("q", "k", "v", "f", "b"), got[0], want[0]):
+        assert np.isfinite(np.asarray(a)).all(), name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(t), rtol=0, err_msg=f"d{name}",
+                                   atol=1e-5 * (float(jnp.abs(t).max()) + 1e-6))
+    for n in ("conv_q", "conv_k", "conv_v", "A_log", "dt_bias"):
+        np.testing.assert_allclose(np.asarray(got[1][n]), np.asarray(want[1][n]), rtol=0,
+                                   atol=4e-5 * (float(jnp.abs(want[1][n]).max()) + 1e-6),
+                                   err_msg=n)
 
 
 @pytest.mark.parametrize("kw,err", [

@@ -4,7 +4,9 @@ form and its hand-written backward against the recurrence token by token
 underflow a chunk, the forward's one kernel (`ops/pallas/kda_fwd.py`) and
 the backward's (`ops/pallas/kda_bwd.py`) in interpret mode against the
 plain form and its `jax.vjp`, a packed row against each of its sequences
-alone, and the host's counts. CPU, float32, toy widths."""
+alone, the host's counts, and the taps' kernels (`ops/pallas/kda_taps.py`)
+in interpret mode against `ops/ssm.causal_conv` after the mixer's `where`.
+CPU, float32, toy widths."""
 
 import jax
 import jax.numpy as jnp
@@ -13,7 +15,8 @@ import pytest
 
 from areal_tpu.models.config import KDAConfig
 from areal_tpu.ops import kda
-from areal_tpu.ops.pallas import kda_bwd, kda_fwd
+from areal_tpu.ops.pallas import kda_bwd, kda_fwd, kda_taps
+from areal_tpu.ops.ssm import causal_conv
 from benchmark.reference import kimi_linear as ref
 
 H, K = 2, 16
@@ -363,13 +366,13 @@ def test_the_forwards_one_kernel_takes_no_exponential_of_a_positive_number(monke
     assert all(np.isfinite(np.asarray(a)).all() for o_s in outs for a in o_s)
 
 
-def _mixer_inputs(D=32, T=64, lens=((20, 30, 10), (45, 11)), seed=0):
-    cfg = KDAConfig(n_heads=H, head_dim=K, gate_rank=8, chunk_size=16)
+def _mixer_inputs(D=32, T=64, lens=((20, 30, 10), (45, 11)), seed=0, heads=H, head_dim=K):
+    cfg = KDAConfig(n_heads=heads, head_dim=head_dim, gate_rank=8, chunk_size=16)
     dense = lambda k, s, scale=None: jax.random.normal(k, s) * (scale or s[-2] ** -0.5)
     kp = jax.tree_util.tree_map(lambda a: a[0], kda.init_kda_params(
         cfg, D, dense, jax.random.PRNGKey(seed), 1, jnp.float32))
     proj = lambda key, n: jax.random.normal(jax.random.PRNGKey(key), (len(lens), T, n))
-    xs = tuple(proj(i, H * K) for i in range(4)) + (proj(4, H),)
+    xs = tuple(proj(i, heads * head_dim) for i in range(4)) + (proj(4, heads),)
     return cfg, kp, xs, jnp.asarray(_segments(lens, T)), lens
 
 
@@ -449,3 +452,166 @@ def test_the_host_counts_chunks_by_the_devices_rule(monkeypatch):
     assert kda.chunk_counts(one, 16) == (128, 8, 5, 1)  # groups of 8 chunks
     live = np.asarray(kda._live_chunks(jnp.asarray(half), 64))
     assert live.tolist() == [2, 1]
+
+
+# The taps' kernels at blocks of 32 cells in chunks of 16, rows of 128: (a) sequences that
+# start and end inside blocks, (b) one that starts on a block's first row
+# (32), on its second (65) and on its third (row 1: 66), so that the cells
+# before come from the other block spec, and two that end on a block's last
+# row (31, 95), (c) a row whose last live cell is in the first block beside a
+# full one: every other block of it dead, (d) a row with no token
+TAPS_ROWS = {
+    "mid_starts": ((50, 41, 20), (100, 1, 13)),
+    "edge_starts": ((32, 33, 31), (66, 30)),
+    "first_block_only": ((20,), (128,)),
+    "empty_row": ((), (100,)),
+}
+
+
+def _taps_inputs(rows, T, C, K, dtype, bias, seed=0):
+    """x with NaN and inf written into its padding cells, w, b (or None),
+    the output's cotangent, the segment ids."""
+    rng = np.random.default_rng(seed)
+    seg = _segments(rows, T)
+    x = rng.normal(size=(len(rows), T, C))
+    x = np.where((seg > 0)[..., None], x, np.where(np.arange(C) % 2, np.nan, np.inf))
+    w, b, dy = rng.normal(size=(K, C)) / 2, rng.normal(size=(C,)), rng.normal(size=x.shape)
+    cast = lambda a: jnp.asarray(a, dtype)
+    return cast(x), cast(w), cast(b) if bias else None, cast(dy), jnp.asarray(seg)
+
+
+def _taps_both(x, w, b, dy, seg, ref_dtype=None):
+    """(y, dx, dw[, db]) of the kernels in interpret mode, and of
+    `causal_conv` after the `where` (in `ref_dtype` where one is given)."""
+    args = (x, w) + (() if b is None else (b,))
+    with_b = lambda fn: (lambda x, w, *b: fn(x, w, b[0] if b else None))
+    y, pull = jax.vjp(with_b(lambda x, w, b: kda_taps.taps(x, w, b, seg, True)), *args)
+    plain = with_b(lambda x, w, b: causal_conv(
+        jnp.where((seg > 0)[..., None], x, 0), w, b, seg))
+    up = (lambda a: a.astype(ref_dtype)) if ref_dtype else (lambda a: a)
+    want, want_pull = jax.vjp(plain, *(up(a) for a in args))
+    return (y,) + pull(dy), (want,) + want_pull(up(dy))
+
+
+@pytest.mark.parametrize("bias", [False, True], ids=["no_bias", "bias"])
+@pytest.mark.parametrize("K", [2, 4])
+@pytest.mark.parametrize("case", list(TAPS_ROWS))
+def test_the_taps_kernels_are_causal_conv_after_the_where(case, K, bias, monkeypatch):
+    """`kda_taps_fwd` and `kda_taps_bwd` in interpret mode against
+    `causal_conv(where(valid, x, 0))` and its `jax.vjp`, float32 to 1e-5:
+    values, dx, dw and db; NaN and inf in x's padding cells reach none of
+    them; a dead block's y and dx are zeros."""
+    monkeypatch.setattr(kda_taps, "ROWS", 32)
+    monkeypatch.setattr(kda_taps, "CHUNK", 16)  # two chunks a block
+    x, w, b, dy, seg = _taps_inputs(TAPS_ROWS[case], 128, 256, K, jnp.float32, bias)
+    assert kda_taps.fits(128, 256, K)
+    got, want = _taps_both(x, w, b, dy, seg)
+    assert len(got) == 3 + bias
+    for name, a, t in zip(("y", "dx", "dw", "db"), got, want):
+        assert a.shape == t.shape and a.dtype == t.dtype, name
+        assert np.isfinite(np.asarray(a)).all(), name
+        np.testing.assert_allclose(np.asarray(a), np.asarray(t), rtol=0, err_msg=name,
+                                   atol=1e-5 * (float(jnp.abs(t).max()) + 1e-6))
+    pad = np.asarray(seg) == 0
+    assert not np.asarray(got[0])[pad].any() and not np.asarray(got[1])[pad].any()
+
+
+def _assert_a_bf16_step_apart(got, want):
+    """Each element within one bf16 step of the float32 one (and float32's
+    own rounding of a sum of terms of the array's size, where they cancel)."""
+    for name, a, t in zip(("y", "dx", "dw", "db"), got, want):
+        assert a.dtype == jnp.bfloat16, name
+        a, t = np.asarray(a, np.float32), np.asarray(t)
+        step = 2.0 ** (np.floor(np.log2(np.maximum(np.abs(t), 1e-30))) - 7)
+        assert (np.abs(a - t) <= step + 2e-6 * np.abs(t).max()).all(), name
+
+
+@pytest.mark.parametrize("case", list(TAPS_ROWS))
+def test_the_taps_kernels_in_bf16_are_a_step_from_the_float32_result(case, monkeypatch):
+    """bf16 operands: sums, silu and the weights' sums over the row are
+    float32 inside, so y, dx, dw and db stand within one bf16 step of
+    `causal_conv` in float32 over the same (rounded) operands."""
+    monkeypatch.setattr(kda_taps, "ROWS", 32)
+    monkeypatch.setattr(kda_taps, "CHUNK", 16)
+    got, want = _taps_both(*_taps_inputs(TAPS_ROWS[case], 128, 256, 4, jnp.bfloat16, True),
+                           ref_dtype=jnp.float32)
+    _assert_a_bf16_step_apart(got, want)
+
+
+@pytest.mark.parametrize("C", [2048, 384])
+def test_the_taps_kernels_at_the_blocks_the_chip_runs(C):
+    """Blocks of `ROWS` cells as the module has them, the qwen3-next cell's
+    q and k of 2,048 columns (and a width of three strips): bf16, a row of
+    two blocks, a sequence across their edge."""
+    T = 2 * kda_taps.ROWS
+    rows = ((T // 2 - 3, 40), (T // 4,))
+    got, want = _taps_both(*_taps_inputs(rows, T, C, 4, jnp.bfloat16, False),
+                           ref_dtype=jnp.float32)
+    _assert_a_bf16_step_apart(got, want)
+
+
+def test_the_taps_kernels_take_a_packed_row_as_each_of_its_sequences_alone(monkeypatch):
+    """A sequence's y, and x's cotangent under it, are what the kernels give
+    for the sequence alone at the head of a row of its own; dw is the sum of
+    the sequences' own."""
+    monkeypatch.setattr(kda_taps, "ROWS", 32)
+    lens = (50, 41, 20)
+    x, w, b, dy, seg = _taps_inputs((lens,), 128, 128, 4, jnp.float32, True)
+    fn = lambda x, w, b, seg: kda_taps.taps(x, w, b, seg, True)
+    y, pull = jax.vjp(lambda x, w, b: fn(x, w, b, seg), x, w, b)
+    dx, dw, db = pull(dy)
+    o, dw_sum, db_sum = 0, 0.0, 0.0
+    for n in lens:
+        own = lambda a: jnp.pad(a[:, o:o + n], ((0, 0), (0, 128 - n), (0, 0)))
+        alone = jnp.asarray(_segments(((n,),), 128))
+        y1, pull1 = jax.vjp(lambda x, w, b: fn(x, w, b, alone), own(x), w, b)
+        dx1, dw1, db1 = pull1(own(dy))
+        np.testing.assert_allclose(np.asarray(y[0, o:o + n]), np.asarray(y1[0, :n]), atol=1e-6)
+        np.testing.assert_allclose(np.asarray(dx[0, o:o + n]), np.asarray(dx1[0, :n]), atol=1e-6)
+        dw_sum, db_sum, o = dw_sum + dw1, db_sum + db1, o + n
+    np.testing.assert_allclose(np.asarray(dw), np.asarray(dw_sum), atol=2e-5)
+    np.testing.assert_allclose(np.asarray(db), np.asarray(db_sum), atol=2e-5)
+
+
+def test_the_mixer_under_its_kernels_is_the_plain_mixer(monkeypatch):
+    """`kda_mixer(..., kernel="interpret")`: the taps' kernels follow the
+    rule's into interpret mode; the output and the gradients of q, k, v, f,
+    b, of the three convolutions' weights and of the decay's two against
+    `kernel=False`, at the limits the rule's kernels are held to."""
+    monkeypatch.setattr(kda_taps, "ROWS", 32)
+    cfg, kp, xs, seg, _ = _mixer_inputs(head_dim=128)  # heads of whole lane tiles
+    assert kda.taps_in_kernel(cfg, 64, "interpret") and not kda.taps_in_kernel(cfg, 64, False)
+    ran = []
+    taps = kda_taps.taps
+    monkeypatch.setattr(kda_taps, "taps", lambda *a: ran.append(a[0].shape) or taps(*a))
+    w = jax.random.normal(jax.random.PRNGKey(9), (2, 64, 2, 128)) * (seg > 0)[..., None, None]
+    both = lambda kernel: jax.value_and_grad(lambda xs, kp: (kda.kda_mixer(
+        *xs, kp, cfg, seg, jnp.float32, kernel=kernel) * w).sum(), (0, 1))(xs, kp)
+    out = lambda kernel: kda.kda_mixer(*xs, kp, cfg, seg, jnp.float32, kernel=kernel)
+    with jax.default_matmul_precision("highest"):
+        got_o, want_o = out("interpret"), out(False)
+        assert ran == [(2, 64, 256)] * 3
+        (_, got), (_, want) = both("interpret"), both(False)
+        assert len(ran) == 6  # the plain mixer took none
+    np.testing.assert_allclose(np.asarray(got_o), np.asarray(want_o), atol=2e-6)
+    _assert_seven(got[0], want[0], 1e-5)  # q's, k's, v's, f's and b's
+    for n in ("conv_q", "conv_k", "conv_v", "A_log", "dt_bias"):
+        scale = float(jnp.abs(want[1][n]).max()) + 1e-6
+        np.testing.assert_allclose(np.asarray(got[1][n]), np.asarray(want[1][n]), rtol=0,
+                                   atol=4e-5 * scale, err_msg=n)
+
+
+def test_a_width_no_column_block_divides_takes_the_plain_form(monkeypatch):
+    """The taps' kernels engage by the operands' shapes: a row no block of
+    cells divides, or a width that is no multiple of the column block (heads
+    of 64 here: 192 columns), runs `causal_conv` after the `where`."""
+    monkeypatch.setattr(kda_taps, "ROWS", 32)
+    monkeypatch.setattr(kda_taps, "taps", lambda *a: pytest.fail("the kernels ran"))
+    cfg, kp, xs, seg, _ = _mixer_inputs(lens=((40, 20),), heads=3, head_dim=64)
+    assert kda_taps.fits(64, 256, 4) and not kda_taps.fits(64, 192, 4)
+    assert not kda_taps.fits(48, 256, 4) and not kda_taps.fits(64, 256, 8)
+    assert not kda.taps_in_kernel(cfg, 64, "interpret")
+    wide = KDAConfig(n_heads=2, head_dim=128, gate_rank=8, chunk_size=16)
+    assert kda.taps_in_kernel(wide, 64, True) and not kda.taps_in_kernel(wide, 80, True)
+    o = kda.kda_mixer(*xs, kp, cfg, seg, jnp.float32, kernel=False)
+    assert o.shape == (1, 64, 3, 64) and np.isfinite(np.asarray(o)).all()

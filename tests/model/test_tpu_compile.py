@@ -567,6 +567,18 @@ def _rule_in_two_kernels(compiled, config):
     assert temp <= _TEMPORARIES_WITH_THE_BACKWARD_LOOP[config], temp
 
 
+def _taps_in_two_kernels(compiled):
+    """The mixers' way into the rule is the taps' kernels (`ops/pallas/
+    kda_taps.py`): both are in the program, and XLA's masked, shifted
+    products (`select_n` under the scope `kda_taps`) are not."""
+    import re
+
+    text = compiled.as_text()
+    assert "kda_taps_fwd" in text and "kda_taps_bwd" in text
+    under = re.findall(r'op_name="[^"]*kda_taps/[^"]*select_n[^"]*"', text)
+    assert not under, under[:3]
+
+
 # The step's temporaries at PR 50 (two kernels in the backward, dq bf16
 # `[heads, T, hd]` out of scratch), bytes, by this file's own compile:
 # the two cells with the least room beside their state.
@@ -617,6 +629,7 @@ def test_an_accumulate_step_of_the_delta_rule_stack_compiles_at_16k(one_chip, mo
     text = compiled.as_text()
     assert "splash_pairs_bwd" in text and "moe_rows_add" in text
     _rule_in_two_kernels(compiled, "kimi-linear-d5-e8")
+    _taps_in_two_kernels(compiled)
     _holds_dq_once(cfg, compiled, "kimi-linear-d5-e8", 16384)
 
 
@@ -668,6 +681,7 @@ def test_an_accumulate_step_of_the_gated_deltanet_stack_compiles_at_16k(one_chip
     for name in ("splash_pairs_fwd", "splash_pairs_bwd", "moe_rows_add"):
         assert name in text, name
     _rule_in_two_kernels(compiled, "qwen3-next-d4-e32")
+    _taps_in_two_kernels(compiled)
 
 
 def test_an_accumulate_step_of_the_two_table_stack_compiles_at_16k(one_chip, monkeypatch):
