@@ -198,13 +198,6 @@ _KNOBS: List[Knob] = [
        "Cross-entropy vocab-chunk size override (ops/loss.py); unset = "
        "heuristic. Snapshotted at first use per jit trace.",
        snapshot=True),
-    _k("AREAL_SPLASH_BQ", "int", 512,
-       "Splash-attention query block target (ops/attention.py); "
-       "pinned at engine construction.", snapshot=True),
-    _k("AREAL_SPLASH_BKV", "int", 1024,
-       "Splash-attention KV block target.", snapshot=True),
-    _k("AREAL_SPLASH_BKVC", "int", 512,
-       "Splash-attention KV-compute block target.", snapshot=True),
     _k("AREAL_GAE_IMPL", "str", "auto",
        "Trainer GAE implementation (ops/gae.packed_gae): 'auto' "
        "(associative scan), 'scan' (the serial lax.scan oracle), "

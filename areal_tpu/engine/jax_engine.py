@@ -27,11 +27,12 @@ broadcast across their span).
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import functools
 import operator
 import time
-from typing import Any, Callable, Dict, Iterable, List, Optional, Tuple
+from typing import Any, Callable, Dict, Iterable, List, Mapping, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -47,17 +48,13 @@ from areal_tpu.models.generation import generate_tokens
 from areal_tpu.models.packing import PackedBatch, pack_sequences
 from areal_tpu.models.transformer import forward as model_forward
 from areal_tpu.models.transformer import scan_stacked
-from areal_tpu.ops.attention import (
-    attn_block_cells,
-    attn_grid_steps,
-    attn_run_len,
-)
+from areal_tpu.ops import band_loop
 from areal_tpu.ops.loss import (
     fused_next_token_logprobs,
-    head_cells_run,
     response_scoring_mask,
     two_on,
 )
+from areal_tpu.engine import train_counts
 from areal_tpu.engine.optimizer import (
     OptimizerConfig,
     make_lr_schedule,
@@ -136,47 +133,6 @@ def with_buffers(new, old):
     return new
 
 
-def _kinds_label(cfg: TransformerConfig) -> str:
-    """The stack's layer kinds in order, runs of equal kinds folded:
-    `dense.w2048.rope,moe.w2048.rope x2,moe.full.nope` for transformer
-    blocks (named by their MLP); a layer of one part is `ssm`, `moe`,
-    `dense` or `attn.full.nope`. Differential attention says `diff.`, a
-    layer that reads layer n's tensor `<n`, one that keeps its own `^`:
-    `ssm+dense^`, `dense.diff.full.nope<5`, `gmu+dense<4`; latent
-    attention says `latent.`, a delta-rule mixer beside an MLP `kda.` and
-    its chunk (`moe.kda.c64`; with one decay a head and h key heads
-    `moe.kda.head.k16.c64`), attention over the keys an indexer chooses
-    `indexed.`, a layer over four residual streams starts with `hc4.`, and
-    a prediction module after the stack ends the label with `+mtp`. A
-    layer that rotates by a named set of the stack's (`rotary_sets`) says
-    which: `moe.w1024.rope[sliding_attention] x3,moe.full.rope[full_attention]`."""
-    streams = f"hc{cfg.hyper.n}." if cfg.hyper is not None else ""
-
-    def name(k):
-        attn = (f"{'diff.' if k.diff else ''}{'latent.' if k.latent else ''}"
-                f"{'indexed.' if k.indexed else ''}"
-                f"{'full' if k.window is None else 'w%d' % k.window}."
-                f"{'rope' if k.rotary else 'nope'}"
-                f"{'[%s]' % k.rotary_set if k.rotary_set else ''}")
-        keeps, reads = "^" if k.keeps else "", "" if k.reads is None else f"<{k.reads}"
-        if k.block:
-            return f"{streams}{k.mlp}.{attn}{keeps}{reads}"
-        if k.mixer == "kda" and k.mlp is not None:
-            form = "" if cfg.kda.decay == "channel" else f"head.k{cfg.kda.key_heads}."
-            return f"{k.mlp}.kda.{form}c{cfg.kda.chunk_size}"
-        return (f"attn.{attn}{keeps}" if k.mixer == "attention" else k.parts) + reads
-
-    names = [name(k) for k in cfg.kinds()]
-    out = []
-    for n in names:
-        if out and out[-1][0] == n:
-            out[-1][1] += 1
-        else:
-            out.append([n, 1])
-    return ",".join(n if c == 1 else f"{n} x{c}" for n, c in out) + (
-        "+mtp" if cfg.mtp is not None else "")
-
-
 @dataclasses.dataclass
 class EngineStats:
     """Host-side per-train_batch summary."""
@@ -214,32 +170,23 @@ class JaxTrainEngine(TrainEngine):
                 moe=dataclasses.replace(model_cfg.moe, dispatch=env_dispatch),
             )
         self.model_cfg = model_cfg
-        # What `train.dispatch` says of the stack it runs: nothing for a
-        # stack of one plain kind.
-        self._stack_attrs: Dict[str, Any] = {}
-        if (model_cfg.layer_kinds is not None or model_cfg.mla is not None
-                or model_cfg.indexer is not None or model_cfg.hyper is not None):
-            windows = sorted({k.window for k in model_cfg.kinds()
-                              if k.window is not None})
-            self._stack_attrs = dict(window=windows[0] if windows else None,
-                                     kinds=_kinds_label(model_cfg))
+        # What `train.dispatch` says of the stack it runs.
+        self._stack_attrs: Dict[str, Any] = train_counts.stack_attrs(model_cfg)
         # The prediction module's share of a train step (models/config
         # MTPConfig): the weight of its loss, 0 = the step skips its pass,
-        # and the layers a step then runs beside the stack's.
+        # and the expert layers a step then runs, its block among them.
         self._mtp_weight = (
             float(model_cfg.mtp.loss_weight) if model_cfg.mtp is not None else 0.0)
-        last = model_cfg.kinds()[-1]
         mtp_on = self._mtp_weight > 0
-        self._n_step_layers = model_cfg.n_layers + mtp_on  # the module's block is one
-        self._n_moe_layers = model_cfg.n_moe_layers + (mtp_on and last.mlp == "moe")
-        self._mtp_attn = mtp_on and last.mixer == "attention"
+        self._n_moe_layers = model_cfg.n_moe_layers + (
+            mtp_on and model_cfg.kinds()[-1].mlp == "moe")
         # The indexers' share of a train step (models/config IndexerConfig):
         # the weight of their KL loss, 0 = the step skips its pass.
         self._n_indexed = model_cfg.n_indexed_layers
         self._index_weight = (
             float(model_cfg.indexer.loss_weight) if self._n_indexed else 0.0)
-        # Pin AREAL_CE_CHUNK / AREAL_SPLASH_* now: retraces mid-run must
-        # not mix tuning settings, and bad values must fail at init.
+        # Pin AREAL_CE_CHUNK now: retraces mid-run must not mix tuning
+        # settings, and bad values must fail at init.
         from areal_tpu.ops import snapshot_env_tuning
 
         snapshot_env_tuning()
@@ -297,6 +244,11 @@ class JaxTrainEngine(TrainEngine):
         self.params = jax.device_put(params, self._param_shardings)
         self._batch_sharding = batch_sharding(self.mesh)
         self._n_row_multiple = int(np.prod(self.mesh.devices.shape[:2]))  # data*fsdp
+        # What a train step says of itself while tracing is on: the
+        # `train.*` counters and its spans' attributes (engine/train_counts.py).
+        self.counts = train_counts.TrainCounts(
+            model_cfg, self.mesh, attn_impl, row_len_multiple, self._n_row_multiple,
+            mtp=mtp_on, n_moe_layers=self._n_moe_layers)
         # XLA's in-process CPU collectives mismatch rendezvous when two
         # collective-bearing executables are in flight (async dispatch lets
         # e.g. the next step's program overlap the previous one); serialize
@@ -997,20 +949,17 @@ class JaxTrainEngine(TrainEngine):
             rows, row_len = stacks[big]["input_ids"].shape[-2:]
             attn_attrs = {}
             if tracing.enabled():  # host passes whose only readers are spans and counters
-                attn = [self._attn_counts(rows["segment_ids"]) for rows in stacks]
-                counts = [a[1:-2] + self._head_counts(rows, scored_fn)
-                          + self._mtp_counts(rows, scored_fn)
-                          + self._ssm_counts(rows["segment_ids"])
-                          + self._index_counts(rows)
-                          + self._band_counts(rows["segment_ids"])
-                          + self._mhc_counts(rows["segment_ids"])
-                          + self._kda_counts(rows["segment_ids"])
-                          + self._kda_taps_counts(rows["segment_ids"]) + a[-1:]
-                          for a, rows in zip(attn, stacks)]
-                self._count_batch(
-                    "fused", len(mbs), sum(b.n_rows == 1 for b, _ in built),
-                    n_tok, n_cells, *(sum(c) for c in zip(*counts)))
-                attn_attrs = dict(attn_row_len=attn[big][0], width=attn[big][-2])
+                tok_of = collections.Counter()  # a stack's real tokens, by its shape
+                for b, r in built:
+                    tok_of[r["input_ids"].shape] += b.total_tokens
+                said = [self.counts.of(r, tok_of[r["input_ids"].shape[-2:]], scored_fn)
+                        for r in stacks]
+                counts = collections.Counter()
+                for c, _ in said:
+                    counts.update(c)
+                self._count_batch("fused", len(mbs), sum(b.n_rows == 1 for b, _ in built),
+                                  n_tok, n_cells, counts)
+                attn_attrs = said[big][1]
 
             step = self._train_step_fn(
                 loss_name, loss_fn, tuple(sorted(stacks[0].keys())), len(mbs),
@@ -1070,18 +1019,9 @@ class JaxTrainEngine(TrainEngine):
                     }
                 cells = batch.n_rows * batch.row_len
                 tracing.set_attrs(tokens=batch.total_tokens, cells=cells)
-                attn_attrs, counts = {}, None
+                counts, attn_attrs = None, {}
                 if tracing.enabled():  # their only readers are spans and counters
-                    run_len, *attn, width, n_window = self._attn_counts(rows["segment_ids"])
-                    counts = (*attn, *self._head_counts(rows, scored_fn),
-                              *self._mtp_counts(rows, scored_fn),
-                              *self._ssm_counts(rows["segment_ids"]),
-                              *self._index_counts(rows),
-                              *self._band_counts(rows["segment_ids"]),
-                              *self._mhc_counts(rows["segment_ids"]),
-                              *self._kda_counts(rows["segment_ids"]),
-                              *self._kda_taps_counts(rows["segment_ids"]), n_window)
-                    attn_attrs = dict(attn_row_len=run_len, width=width)
+                    counts, attn_attrs = self.counts.of(rows, batch.total_tokens, scored_fn)
             return (rows_dev, denom, batch.total_tokens, cells, attn_attrs, counts)
 
         pf = HostPrefetcher(
@@ -1099,13 +1039,9 @@ class JaxTrainEngine(TrainEngine):
             g_acc = zero_sums(self.params)
         stats = None
         denom_sum, n_tok, n_cells, n_one_row = 0.0, 0, 0, 0
-        # attention's cells at the run length, run, causal, its grid steps
-        # walked, live; the head's positions read, cells run, and the
-        # prediction module's; the state-space scan's chunks, live, mixed,
-        # and its resets; the indexers' cells scored, kept, and queries that
-        # choose; the cells the layers' token-wise stretches run; counted
-        # while tracing is on (`n_counted` of the micro-batches)
-        n_counts, n_counted = None, 0  # as many as a stage counts: summed as they come
+        # what the micro-batches said of themselves, summed as they come
+        # while tracing is on (`n_counted` of them)
+        n_counts, n_counted = collections.Counter(), 0
         gaps_ms: List[float] = []
         if begin is not None:  # `train.begin`: up to the first `train.wait_input`
             begin.end()
@@ -1120,7 +1056,7 @@ class JaxTrainEngine(TrainEngine):
                 n_one_row += int(rows == 1)
                 if counts is not None:
                     n_counted += 1
-                    n_counts = [n + c for n, c in zip(n_counts or [0] * len(counts), counts)]
+                    n_counts.update(counts)
                 if mb_accum is None:
                     mb_accum = self._accum_step_fn(
                         loss_name, loss_fn, tuple(sorted(rows_dev.keys())),
@@ -1157,8 +1093,7 @@ class JaxTrainEngine(TrainEngine):
             tracing.fed("apply")
         self._grad_sums = g_acc  # the next minibatch's buffers
         if n_counts and n_counted == n_mbs:  # tracing was on for the whole batch
-            self._count_batch("overlapped", n_mbs, n_one_row, n_tok, n_cells,
-                              *n_counts)
+            self._count_batch("overlapped", n_mbs, n_one_row, n_tok, n_cells, n_counts)
         self.last_overlap = {
             "packing_efficiency": n_tok / max(n_cells, 1),
             "h2d_wait_ms": pf.wait_ms,
@@ -1179,297 +1114,30 @@ class JaxTrainEngine(TrainEngine):
             return jnp.asarray([1.0 / global_denom, 1.0 / max(n_tok, 1)], jnp.float32)
         return jnp.asarray(1.0 / global_denom, jnp.float32)
 
-    def _index_counts(self, rows_np: Dict[str, np.ndarray]) -> Tuple[int, int, int]:
-        """What the indexers do with packed rows (on the host, before the
-        transfer), summed over indexed layers: (cells scored, cells an
-        exact choice keeps, queries with more keys than `top_k`), by the
-        device's own rule (ops/indexer.index_counts)."""
-        if not self._n_indexed:
-            return 0, 0, 0
-        from areal_tpu.ops.indexer import index_counts
-
-        return tuple(self._n_indexed * int(c) for c in index_counts(
-            np.asarray(rows_np["positions"]).astype(np.int64),
-            np.asarray(rows_np["segment_ids"]), self.model_cfg.indexer.top_k))
-
     def _dead_bands(self, row_len: int) -> bool:
         """Whether a row of `row_len` cells, as `_build_rows` packs it, may
-        hold a band no token is in (what `forward(bands=)` is told): the
-        ladder's step up to that rung is longer than a band. At the
-        launcher's `row_len_multiple` of 128 a row of 16,384 pads under
-        1,024 cells and every band of it is live; at a multiple that is
-        the row, half of it may be empty."""
-        from areal_tpu.ops.band_loop import _BAND
+        hold a band no token is in: what `forward(bands=)` is told
+        (`ops/band_loop.dead_bands`)."""
+        return band_loop.dead_bands(row_len, self.row_len_multiple)
 
-        return datapack.ladder_step(row_len, self.row_len_multiple) > _BAND
-
-    def _stretch_cells(self, segment_ids: np.ndarray) -> List[Tuple[int, int]]:
-        """A micro-batch (`segment_ids` [R, T] of one or [n, R, T] of
-        several): the cells its layers' token-wise steps run, summed over
-        the layers a step runs (the stack's, and the prediction module's
-        block where the step runs the module), on the host before the
-        transfer by the device's own rule, as (those of the layers that
-        walk bands, those of the layers that run the row whole):
-        `ops/band_loop.band_cells_run` (the bands of `_BAND` cells up to
-        a row's last token, for one row alone of two bands or more) for
-        the first; a layer whose kind keeps the whole row
-        (`models/transformer.looping_layers`) counts every cell, and so
-        does every layer of a row the packer fills to the last band
-        (`_dead_bands`)."""
-        from areal_tpu.models.transformer import looping_layers
-        from areal_tpu.ops.band_loop import band_cells_run
-
-        seg = np.asarray(segment_ids)
-        mbs = seg.reshape((-1,) + seg.shape[-2:])
-        n = self._n_step_layers
-        loop = looping_layers(
-            self.model_cfg, *mbs.shape[1:], sharded=self.mesh.size > 1,
-            mtp=n > self.model_cfg.n_layers,
-        ) if self._dead_bands(mbs.shape[2]) else 0
-        return [(loop * band_cells_run(mb), (n - loop) * mb.size) for mb in mbs]
-
-    def _band_counts(self, segment_ids: np.ndarray) -> Tuple[int]:
-        """The cells the layers' token-wise stretches run
-        (`_stretch_cells`), a mean over those layers."""
-        return (sum((bands + whole) // self._n_step_layers
-                    for bands, whole in self._stretch_cells(segment_ids)),)
-
-    def _mhc_counts(self, segment_ids: np.ndarray) -> Tuple[int, int]:
-        """The cells the stream steps of hyper-connections run
-        (`models/transformer._hc_read`, `_hc_write`), summed over the two
-        sublayers of every layer by `_stretch_cells`' rule, and those of
-        them inside a layer that walks bands, whose backward loop makes a
-        band's forward once more (`ops/band_loop.py`); 0 for one stream."""
-        if self.model_cfg.hyper is None:
-            return (0, 0)
-        cells = self._stretch_cells(segment_ids)
-        return (2 * sum(bands + whole for bands, whole in cells),
-                2 * sum(bands for bands, _ in cells))
-
-    def _attn_counts(self, segment_ids: np.ndarray) -> Tuple[int, ...]:
-        """What the attention kernels do with packed rows (on the host,
-        before the transfer), `segment_ids` [R, T] of one micro-batch or
-        [n, R, T] of several: (the length they run a row at:
-        ops/attention.attn_run_len, splash pads a row to a length whose
-        blocks are large and any other implementation runs it as it is;
-        rows x that length; the cells of the block pairs they run, summed
-        over rows and layers: by the rows' own segment ids,
-        ops/attention.attn_block_cells; the cells a causal mask alone
-        would make them run; the grid steps the forward and backward
-        kernels walk, those whose pair runs, and the steps of the first
-        that the backward walks (a row alone: its live pairs, once, in
-        one kernel), summed over rows, q heads and layers:
-        ops/attention.attn_grid_steps; the widest forward grid, kv steps
-        a q block, of any layer and row: of a row alone the pairs of its
-        fullest q block; of the cells run, those of the layers that have
-        a window)."""
-        cfg = self.model_cfg
-        segment_ids = np.asarray(segment_ids)
-        rows, row_len = segment_ids.shape[-2:]
-        mbs = segment_ids.reshape(-1, rows, row_len)
-        shape = dict(
-            impl=self.attn_impl, hq=cfg.n_q_heads, hkv=cfg.n_kv_heads,
-            mesh=self.mesh if self.mesh.size > 1 else None,
-        )
-        run_len = attn_run_len(t=row_len, r=rows, **shape)
-        windows = [k.window for k in cfg.kinds() if k.mixer == "attention"]
-        if self._mtp_attn:  # the prediction module's block, the last kind's
-            windows.append(cfg.kinds()[-1].window)
-        # One count a window, not one a layer: (cells run, causal cells,
-        # steps walked, live steps, width, the backward's steps) a
-        # micro-batch.
-        per = {w: [attn_block_cells(segment_ids=mb, window=w, **shape)
-                   + attn_grid_steps(segment_ids=mb, window=w, **shape)
-                   for mb in mbs]
-               for w in set(windows)}
-        total = lambda i: int(sum(mb[i] for w in windows for mb in per[w]))
-        return (run_len, len(mbs) * rows * run_len, total(0), total(1),
-                cfg.n_q_heads * total(2), cfg.n_q_heads * total(3),
-                cfg.n_q_heads * total(5),
-                max(mb[4] for counts in per.values() for mb in counts),
-                int(sum(mb[0] for w in windows if w is not None for mb in per[w])))
-
-    def _ssm_counts(self, segment_ids: np.ndarray) -> Tuple[int, int, int, int]:
-        """What the state-space layers' chunked scan does with packed rows
-        (on the host, before the transfer; `segment_ids` [R, T] of one
-        micro-batch or [n, R, T] of several), by the device's own rule
-        (ops/ssm.chunk_counts; for the selective scan a chunk is the
-        kernel's block of time), summed over those layers: (the chunks it
-        runs, those that hold a token, those that hold a sequence start
-        after their first cell, sequence starts)."""
-        n = self.model_cfg.n_ssm_layers
-        if not n:
-            return 0, 0, 0, 0
-        from areal_tpu.ops.ssm import chunk_counts
-
-        return tuple(n * c for c in chunk_counts(
-            segment_ids, self.model_cfg.ssm.chunk_size))
-
-    def _kda_counts(self, segment_ids: np.ndarray) -> Tuple[int, int, int, int]:
-        """What the delta-rule layers' chunked rule does with packed rows
-        (on the host, before the transfer; `segment_ids` [R, T] of one
-        micro-batch or [n, R, T] of several), by the device's own rule
-        (ops/kda.chunk_counts), summed over those layers: (the positions it
-        walks, the chunks it runs, those that hold a token, sequence
-        starts)."""
-        n = self.model_cfg.n_kda_layers
-        if not n:
-            return 0, 0, 0, 0
-        from areal_tpu.ops.kda import chunk_counts
-
-        return tuple(n * c for c in chunk_counts(
-            segment_ids, self.model_cfg.kda.chunk_size))
-
-    def _kda_taps_counts(self, segment_ids: np.ndarray) -> Tuple[int, int]:
-        """The cells the delta-rule layers' convolutions are asked for (a
-        call's R x T a layer: q's, k's and v's counted once; `segment_ids`
-        as `_kda_counts` takes them) and those of them that go through the
-        kernels (`kda_taps_fwd`, `kda_taps_bwd`): all where the rule takes
-        its own and the row's length and the widths fit
-        (`ops/kda.taps_in_kernel`), none where the plain form runs."""
-        n = self.model_cfg.n_kda_layers
-        if not n:
-            return 0, 0
-        from areal_tpu.ops import kda
-
-        cells = n * int(np.prod(np.shape(segment_ids)))
-        cfg = self.model_cfg.kda
-        in_kernel = kda.taps_in_kernel(cfg, np.shape(segment_ids)[-1],
-                                       kda._use_kernel(cfg.head_dim, self.mesh))
-        return cells, cells if in_kernel else 0
-
-    def _head_counts(self, rows_np: Dict[str, np.ndarray],
-                     scored_fn: Optional[ScoredFn], shift: int = 1) -> Tuple[int, int]:
-        """What the loss head does with packed rows (on the host, before
-        the transfer; [R, T] arrays of one micro-batch or [n, R, T] of
-        several): (the positions whose logprob the loss reads, the cells
-        of the chunks the head runs its logits tile over), by the
-        device's own rule (ops/loss.head_cells_run). A critic has no
-        such head. `shift`: how many tokens on the labels lie (the
-        prediction module's run of the head: `_mtp_counts`)."""
-        if self.model_cfg.is_critic:
-            return 0, 0
-        seg = np.asarray(rows_np["segment_ids"])
-        mbs = seg.reshape((-1,) + seg.shape[-2:])
-        scored = ([None] * len(mbs) if scored_fn is None
-                  else np.asarray(scored_fn(rows_np)).reshape(mbs.shape))
-        counts = [head_cells_run(mb, s, self.model_cfg.vocab_size,
-                                 self._n_row_multiple, shift)
-                  for mb, s in zip(mbs, scored)]
-        return tuple(int(x) for x in np.sum(counts, axis=0))
-
-    def _mtp_counts(self, rows_np: Dict[str, np.ndarray],
-                    scored_fn: Optional[ScoredFn]) -> Tuple[int, int]:
-        """`_head_counts` of the prediction module's run of the head, over
-        the tokens two on that the caller's loss scores (`_mb_loss_fn`):
-        zeros where the step runs none."""
-        if not self._mtp_weight > 0:
-            return 0, 0
-        if scored_fn is None:
-            reads = lambda rows: np.ones(np.shape(rows["segment_ids"]), np.float32)
-        else:
-            reads = lambda rows: two_on(np.asarray(scored_fn(rows)))
-        return self._head_counts(rows_np, reads, shift=2)
-
-    def _count_batch(self, path: str, n_mbs: int, n_one_row: int, n_tok: int,
-                     n_cells: int,
-                     n_attn_cells: int, n_attn_active: int, n_attn_causal: int,
-                     n_attn_steps: int, n_attn_live: int, n_attn_bwd_steps: int,
-                     n_scored: int, n_head_cells: int,
-                     n_mtp_targets: int, n_mtp_head_cells: int,
-                     n_ssm_chunks: int = 0,
-                     n_ssm_live: int = 0, n_ssm_mixed: int = 0, n_ssm_resets: int = 0,
-                     n_index_cells: int = 0, n_index_selected: int = 0,
-                     n_index_choosing: int = 0, n_band_cells: int = 0,
-                     n_mhc_cells: int = 0, n_mhc_loop_cells: int = 0,
-                     n_kda_cells: int = 0, n_kda_chunks: int = 0,
-                     n_kda_live: int = 0, n_kda_resets: int = 0,
-                     n_kda_taps_cells: int = 0, n_kda_taps_kernel: int = 0,
-                     n_attn_window: int = 0):
+    @staticmethod
+    def _count_batch(path: str, n_mbs: int, n_one_row: int, n_tok: int, n_cells: int,
+                     counts: Mapping[str, int]):
         """What one train_batch did, on its `train.batch` span and in the
         recorder's counters: the micro-batches and how many of them the
         packer made one row (what lets attention skip the block pairs
         between sequences, from 2048 cells up: ops/attention._rows_skip),
         real tokens, the cells (rows x row length) they were padded to,
-        the cells the attention kernel ran (rows x the length it ran
-        them at), the cells of the block pairs it ran
-        against those of a causal mask alone, the grid steps its kernels
-        walked, those whose block pair ran and those the backward walked
-        (half the steps where one backward kernel walks a row's live
-        pairs once), the positions whose logprob
-        the loss reads and the cells of the chunks the loss head ran for
-        them, the same two of the prediction module's run of the head,
-        the (token, expert) pairs the routers of the expert
-        layers made, the chunks the state-space layers' scan ran
-        (the selective scan's also as positions: chunks x their length),
-        the cells the indexers scored, those an exact choice keeps and
-        the queries that had more keys than they keep, the cells the
-        layers' token-wise stretches ran, the cells the stream steps of
-        hyper-connections ran over the stack's sublayers and those of them
-        inside a band loop (`_band_counts`, `_mhc_counts`), the positions
-        the delta-rule layers' chunked rule walked (and, of them, those
-        whose forward and whose backward the kernels ran:
-        `ops/kda._use_kernel`), its
-        chunks, those with a token and the sequence starts
-        (`_kda_counts`), the cells their convolutions were asked for and
-        those the taps' kernels took (`_kda_taps_counts`), the window layers' part of the cells the attention
-        kernels' block pairs ran (the full layers' is the rest)."""
+        and what the micro-batches said of themselves
+        (`engine/train_counts.TrainCounts.of`, summed)."""
         tracing.set_attrs(path=path, n_mbs=n_mbs, tokens=n_tok, cells=n_cells)
         tracing.count("train.batches")
         tracing.count("train.micro_batches", n_mbs)
         tracing.count("train.one_row_batches", n_one_row)
         tracing.count("train.tokens", n_tok)
         tracing.count("train.cells", n_cells)
-        tracing.count("train.band_cells", n_band_cells)
-        if self.model_cfg.hyper is not None:
-            tracing.count("train.mhc_cells", n_mhc_cells)
-            tracing.count("train.mhc_loop_cells", n_mhc_loop_cells)
-        tracing.count("train.attn_cells", n_attn_cells)
-        tracing.count("train.attn_active_cells", n_attn_active)
-        tracing.count("train.attn_window_cells", n_attn_window)
-        tracing.count("train.attn_full_cells", n_attn_active - n_attn_window)
-        tracing.count("train.attn_causal_cells", n_attn_causal)
-        tracing.count("train.attn_grid_steps", n_attn_steps)
-        tracing.count("train.attn_live_steps", n_attn_live)
-        tracing.count("train.attn_bwd_steps", n_attn_bwd_steps)
-        if not self.model_cfg.is_critic:
-            tracing.count("train.scored_cells", n_scored)
-            tracing.count("train.head_cells", n_head_cells)
-        if self._mtp_weight > 0:
-            tracing.count("train.mtp_targets", n_mtp_targets)
-            tracing.count("train.mtp_head_cells", n_mtp_head_cells)
-        moe = self.model_cfg.moe
-        if moe is not None:
-            tracing.count("train.moe_pairs",
-                          moe.top_k * n_tok * self._n_moe_layers)
-        if self.model_cfg.n_ssm_layers:
-            ssm = self.model_cfg.ssm
-            if ssm.form == "mamba1":  # positions the scan kernel walks
-                tracing.count("train.sscan_cells", n_ssm_chunks * ssm.chunk_size)
-            tracing.count("train.ssm_chunks", n_ssm_chunks)
-            tracing.count("train.ssm_chunks_live", n_ssm_live)
-            tracing.count("train.ssm_chunks_mixed", n_ssm_mixed)
-            tracing.count("train.ssm_resets", n_ssm_resets)
-        if self.model_cfg.n_kda_layers:
-            tracing.count("train.kda_cells", n_kda_cells)
-            from areal_tpu.ops.kda import _use_kernel
-
-            # those of them the kernels ran, the forward's one (`kda_fwd_rule`)
-            # and the backward's (`kda_bwd_rule`): all where
-            # the rule takes its kernels, none where it takes the plain form
-            in_kernel = n_kda_cells if _use_kernel(
-                self.model_cfg.kda.head_dim, self.mesh) else 0
-            tracing.count("train.kda_fwd_kernel_cells", in_kernel)
-            tracing.count("train.kda_bwd_kernel_cells", in_kernel)
-            tracing.count("train.kda_chunks", n_kda_chunks)
-            tracing.count("train.kda_chunks_live", n_kda_live)
-            tracing.count("train.kda_resets", n_kda_resets)
-            tracing.count("train.kda_taps_cells", n_kda_taps_cells)
-            tracing.count("train.kda_taps_kernel_cells", n_kda_taps_kernel)
-        if self._n_indexed:
-            tracing.count("train.index_cells", n_index_cells)
-            tracing.count("train.index_selected", n_index_selected)
-            tracing.count("train.index_queries_choosing", n_index_choosing)
+        for name, n in counts.items():
+            tracing.count(name, n)
 
     def _record_overlap_stats(self):
         """Ship the last pipeline's telemetry through the stats tracker so

@@ -243,8 +243,8 @@ class ServingEngine:
     ):
         self.cfg = cfg
         cfg.require_plain_stack("engine/serving.py ServingEngine")
-        # Pin AREAL_CE_CHUNK / AREAL_SPLASH_* now: retraces mid-run must
-        # not mix tuning settings, and bad values must fail at init.
+        # Pin AREAL_CE_CHUNK now: retraces mid-run must not mix tuning
+        # settings, and bad values must fail at init.
         from areal_tpu.ops import snapshot_env_tuning
 
         snapshot_env_tuning()

@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from areal_tpu.base import env_registry
 
 NEG_INF = -2.0**30
 LANES = 128  # TPU lane width; splash blocks must be lane-aligned
@@ -105,40 +104,9 @@ def decode_attention(
 
 _SPLASH_MASK_CACHE = {}
 
-# AREAL_SPLASH_* snapshot: (bq, bkv, bkvc) targets once taken.
-_SPLASH_SNAP = None
-
-
-def snapshot_splash_blocks():
-    """Parse + validate the AREAL_SPLASH_BQ/BKV/BKVC block-size targets
-    and pin them for subsequent traces. Called at engine construction so
-    a mid-run retrace can't silently mix tuning settings and a bad value
-    fails at init instead of inside a jit trace; a fresh engine re-pins."""
-    global _SPLASH_SNAP
-
-    def check(name, v):
-        # Defaults live in the env registry, not here (the per-call-site
-        # default drift this registry exists to end); knob names stay
-        # literal at each get_int so the env-knob checker can see them.
-        if v < LANES:
-            raise ValueError(f"{name}={v}: splash block targets must be "
-                             f">= {LANES}")
-        return v
-
-    _SPLASH_SNAP = (
-        check("AREAL_SPLASH_BQ", env_registry.get_int("AREAL_SPLASH_BQ")),
-        check("AREAL_SPLASH_BKV", env_registry.get_int("AREAL_SPLASH_BKV")),
-        check("AREAL_SPLASH_BKVC",
-              env_registry.get_int("AREAL_SPLASH_BKVC")),
-    )
-    return _SPLASH_SNAP
-
-
-def _splash_block_targets():
-    if _SPLASH_SNAP is None:
-        # Direct ops use without an engine: snapshot lazily on first use.
-        return snapshot_splash_blocks()
-    return _SPLASH_SNAP
+# Upper targets for splash's blocks (bq, bkv, bkvc): the largest a run
+# shape may take (`splash_run_shape`).
+SPLASH_BLOCK_TARGETS = (512, 1024, 512)
 
 
 def _blocks_dividing(n: int, cap: int) -> list:
@@ -308,8 +276,8 @@ def _row_window(t: int, window: Optional[int]) -> Optional[int]:
 
 def splash_run_shape(t: int):
     """(t', bq, bkv, bkvc): the length the splash kernel runs a row of
-    length `t` at, and its blocks. A pure function of `t` (and the
-    AREAL_SPLASH_* upper targets), decided at trace time: a window layer
+    length `t` at, and its blocks. A pure function of `t` (and
+    `SPLASH_BLOCK_TARGETS`), decided at trace time: a window layer
     runs its rows at the same shape as a causal one. Its mask leaves
     fewer block pairs active, but on a v5e the shape picked here is
     also a window layer's fastest at four of five row lengths timed and
@@ -325,7 +293,7 @@ def splash_run_shape(t: int):
     `splash_cost`, if that is cheaper than the row as it is at its
     largest dividing blocks by more than the estimate's error; else the
     row stays as it is. So a padded length is never priced above `t`."""
-    return _cheapest_run_shape(t, *_splash_block_targets())
+    return _cheapest_run_shape(t, *SPLASH_BLOCK_TARGETS)
 
 
 def _splash_kernel(t: int, bq: int, bkv: int, bkvc: int, group: int,

@@ -42,6 +42,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from areal_tpu.base import datapack
+
 # Cells a band. A shorter band runs fewer empty cells in the last band a
 # row's tokens reach (8.6k tokens of 16,384 are 9 bands of 1,024 = 9.2k
 # cells, 5 of 2,048 = 10.2k) and reads the stretch's weights, and adds
@@ -52,6 +54,17 @@ import numpy as np
 # / 55.9 / 59.2; Qwen's layer 30.7 at 1,024 against 32.6 at 2,048; a full
 # row reads the same at all three. One constant for every stack.
 _BAND = 1024
+
+
+def dead_bands(row_len: int, row_len_multiple: int) -> bool:
+    """Whether a row of `row_len` cells, packed at a ladder of
+    `row_len_multiple` (`base/datapack.ladder_shape`), may hold a band no
+    token is in (what `forward(bands=)` is told, and what the host counts
+    by): the ladder's step up to that rung is longer than a band. At the
+    launcher's `row_len_multiple` of 128 a row of 16,384 pads under 1,024
+    cells and every band of it is live; at a multiple that is the row,
+    half of it may be empty."""
+    return datapack.ladder_step(row_len, row_len_multiple) > _BAND
 
 
 def loops(n_rows: int, row_len: int) -> bool:

@@ -76,7 +76,7 @@ in); the walk carries `S` over the group's chunks (`states_scan`, a
 `lax.scan`). `delta_rule` takes a row a group of chunks at a time, up to
 the group of its last token.
 
-**Which form runs where** (`_use_kernel`: a TPU backend, one device, heads
+**Which form runs where** (`use_kernel`: a TPU backend, one device, heads
 of whole lane tiles; decided from what the code sees, no argument):
 
 - forward, on the chip, both decays: one kernel over the whole row,
@@ -597,7 +597,7 @@ _rule.defvjp(_rule_fwd, _rule_bwd)
 _rule_jit = jax.jit(_rule, static_argnums=(8, 9, 10))
 
 
-def _use_kernel(K: int, mesh) -> bool:
+def use_kernel(K: int, mesh) -> bool:
     """The kernels (the forward's one, the backward's one) on the
     chip, one device's rows, heads of whole lane tiles; the plain form
     elsewhere (the CPU, a toy head, a mesh of several devices: a kernel is
@@ -623,14 +623,14 @@ def kda_mixer(q, k, v, f, b, kp, kda: KDAConfig, segment_ids, cdt, mesh=None,
     product, or [R, T, H] the projection's column where the decay is a
     head's), b [R, T, H] (beta's projection), `kp` the layer's `conv_*`,
     `A_log`, `dt_bias` -> o [R, T, H, K] in `cdt`, before the head norm.
-    `kernel` as `delta_rule` takes it (None: `_use_kernel`); the taps take
+    `kernel` as `delta_rule` takes it (None: `use_kernel`); the taps take
     their kernels with the rule's, where the shapes fit (`taps_in_kernel`)."""
     R, T, _ = q.shape
     H, Hk, K, C = kda.n_heads, kda.key_heads, kda.head_dim, kda.chunk_size
     f32 = jnp.float32
     valid = segment_ids > 0
     if kernel is None:
-        kernel = _use_kernel(K, mesh)
+        kernel = use_kernel(K, mesh)
     # masked on the way in: whatever padding cells hold (the residual
     # stream carries them along) reaches neither a result nor a gradient
     masked = lambda *xs: tuple(jnp.where(valid[..., None], a, 0) for a in xs)

@@ -60,7 +60,7 @@ def candidates(t):
     at the next compute block down."""
     from areal_tpu.ops import attention as A
 
-    tq, tkv, tkvc = A._splash_block_targets()
+    tq, tkv, tkvc = A.SPLASH_BLOCK_TARGETS
     out = []
     for t_run in A._run_lengths(t):
         bqs = A._blocks_dividing(t_run, tq)[:2]
@@ -76,7 +76,7 @@ def today(t):
     """What the parent ran: t' = t at the largest dividing blocks."""
     from areal_tpu.ops import attention as A
 
-    return A._plain_run_shape(t, *A._splash_block_targets())
+    return A._plain_run_shape(t, *A.SPLASH_BLOCK_TARGETS)
 
 
 _PAIRS = "areal_tpu.ops.pallas.splash_pairs"

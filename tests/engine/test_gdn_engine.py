@@ -12,7 +12,8 @@ import pytest
 
 from areal_tpu.api.data_api import MicroBatchSpec, SequenceSample
 from areal_tpu.base import tracing
-from areal_tpu.engine.jax_engine import JaxTrainEngine, _kinds_label
+from areal_tpu.engine.jax_engine import JaxTrainEngine
+from areal_tpu.engine.train_counts import kinds_label
 from areal_tpu.engine.optimizer import OptimizerConfig
 from areal_tpu.models import moe as moe_lib
 from areal_tpu.ops import kda
@@ -110,10 +111,12 @@ def test_the_host_counts_this_rules_chunks_as_it_counts_the_others(monkeypatch):
     cfg, eng = engine(0, row_len_multiple=256)
     seg = np.zeros((1, 256), np.int32)
     seg[0, :40], seg[0, 40:70] = 1, 2
-    assert eng._kda_counts(seg) == (3 * 256, 3 * 4, 3 * 2, 3 * 2)
+    names = ("train.kda_cells", "train.kda_chunks", "train.kda_chunks_live", "train.kda_resets")
+    rule = lambda seg: tuple(eng.counts.of({"segment_ids": seg}, 0)[0][n] for n in names)
+    assert rule(seg) == (3 * 256, 3 * 4, 3 * 2, 3 * 2)
     monkeypatch.setattr(kda, "GROUP_CELLS", 64)
-    assert eng._kda_counts(seg) == (3 * 128, 3 * 2, 3 * 2, 3 * 2)
-    assert _kinds_label(cfg) == KINDS
+    assert rule(seg) == (3 * 128, 3 * 2, 3 * 2, 3 * 2)
+    assert kinds_label(cfg) == KINDS
 
 
 def test_the_family_runs_through_the_ppo_interface():

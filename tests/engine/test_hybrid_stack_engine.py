@@ -4,15 +4,16 @@ decay, the selection bias is a buffer, the scan's chunks are counted on
 the host by the device's rule, attention's counters count attention
 layers only, and `train.dispatch` names the kinds."""
 
+import dataclasses
+
 import jax
 import numpy as np
 import pytest
 
 from areal_tpu.api.data_api import MicroBatchSpec
 from areal_tpu.base import tracing
-from areal_tpu.engine.jax_engine import (
-    BUFFER_LEAVES, JaxTrainEngine, _kinds_label, trainable,
-)
+from areal_tpu.engine.jax_engine import BUFFER_LEAVES, JaxTrainEngine, trainable
+from areal_tpu.engine.train_counts import kinds_label
 from areal_tpu.engine.optimizer import NO_DECAY_LEAVES, OptimizerConfig, _decay_mask
 from areal_tpu.models.transformer import init_params
 from areal_tpu.ops.ssm import chunk_counts
@@ -130,15 +131,19 @@ def test_a_train_step_updates_the_weights_and_counts_the_chunks(depth):
 
     # attention's cells are those of the attention layers: two count twice
     seg = eng._build_rows(mbs[0])[1]["segment_ids"]
-    one = eng._attn_counts(seg)
-    eng.model_cfg = _cfg(dict(HF, hybrid_override_pattern="M*MEM*EME"))
-    assert eng._attn_counts(seg)[2:] == tuple(2 * n for n in one[2:]) and one[3] > 0
+    one = eng.counts.of({"segment_ids": seg}, 0)[0]
+    twice = dataclasses.replace(
+        eng.counts, cfg=_cfg(dict(HF, hybrid_override_pattern="M*MEM*EME")))
+    two = twice.of({"segment_ids": seg}, 0)[0]
+    summed = [k for k in one if k.startswith("train.attn_") and k != "train.attn_cells"]
+    assert len(summed) == 7 and all(two[k] == 2 * one[k] for k in summed)
+    assert one["train.attn_causal_cells"] > 0
 
 
 def test_the_kinds_label_folds_runs_and_names_one_part_layers():
     from tests.model.test_layer_kinds import _cfg as afmoe_cfg
 
-    assert _kinds_label(afmoe_cfg()) == (
+    assert kinds_label(afmoe_cfg()) == (
         "dense.w8.rope,moe.w8.rope x2,moe.full.nope,moe.w8.rope")
     hf = dict(HF, num_hidden_layers=6, hybrid_override_pattern="MM-*EE")
-    assert _kinds_label(_cfg(hf)) == "ssm x2,dense,attn.full.nope,moe x2"
+    assert kinds_label(_cfg(hf)) == "ssm x2,dense,attn.full.nope,moe x2"

@@ -10,7 +10,8 @@ import pytest
 
 from areal_tpu.api.data_api import MicroBatchSpec, SequenceSample
 from areal_tpu.base import tracing
-from areal_tpu.engine.jax_engine import JaxTrainEngine, _kinds_label
+from areal_tpu.engine.jax_engine import JaxTrainEngine
+from areal_tpu.engine.train_counts import kinds_label
 from areal_tpu.engine.optimizer import OptimizerConfig
 from areal_tpu.models import moe as moe_lib
 from areal_tpu.ops import band_loop
@@ -110,18 +111,22 @@ def test_the_host_counts_the_stream_steps_cells_by_the_devices_rule(monkeypatch)
     cfg, eng = engine(0, row_len_multiple=128)
     seg = np.zeros((1, 128), np.int32)
     seg[0, :40] = 1
+    said = lambda eng, seg: eng.counts.of({"segment_ids": seg}, 0)[0]
+    mhc = lambda eng, seg: tuple(
+        said(eng, seg)[n] for n in ("train.mhc_cells", "train.mhc_loop_cells"))
     assert eng._dead_bands(128) and band_loop.band_cells_run(seg) == 48
-    assert eng._band_counts(seg) == ((2 * 48 + 128) // 3,)
+    assert said(eng, seg)["train.band_cells"] == (2 * 48 + 128) // 3
     # all of them, and those inside the two layers that walk bands (whose
     # backward loop makes a band's forward once more)
-    assert eng._mhc_counts(seg) == (2 * (2 * 48 + 128), 2 * 2 * 48)
-    assert eng._mhc_counts(np.stack([seg, seg])) == (4 * (2 * 48 + 128), 4 * 2 * 48)
+    assert mhc(eng, seg) == (2 * (2 * 48 + 128), 2 * 2 * 48)
+    assert mhc(eng, np.stack([seg, seg])) == (4 * (2 * 48 + 128), 4 * 2 * 48)
     _, plain = engine(0, hc_mult=1)
-    assert plain._band_counts(seg) == (48,) and plain._mhc_counts(seg) == (0, 0)
+    assert said(plain, seg)["train.band_cells"] == 48
+    assert not any("mhc" in name for name in said(plain, seg))
     # a row the packer fills to the last band runs whole in every layer
-    eng.row_len_multiple = 16
-    assert not eng._dead_bands(128) and eng._mhc_counts(seg) == (2 * 3 * 128, 0)
-    assert _kinds_label(plain.model_cfg) == "dense.latent.full.rope,moe.latent.full.rope x2"
+    eng.row_len_multiple = eng.counts.row_len_multiple = 16
+    assert not eng._dead_bands(128) and mhc(eng, seg) == (2 * 3 * 128, 0)
+    assert kinds_label(plain.model_cfg) == "dense.latent.full.rope,moe.latent.full.rope x2"
 
 
 def test_the_ppo_interface_reports_what_sinkhorn_left_undone():

@@ -15,7 +15,8 @@ import pytest
 
 from areal_tpu.api.data_api import MicroBatchSpec, SequenceSample
 from areal_tpu.base import tracing
-from areal_tpu.engine.jax_engine import JaxTrainEngine, _kinds_label
+from areal_tpu.engine.jax_engine import JaxTrainEngine
+from areal_tpu.engine.train_counts import kinds_label
 from areal_tpu.engine.optimizer import OptimizerConfig
 from areal_tpu.models import moe as moe_lib
 from areal_tpu.ops.loss import response_positions
@@ -232,7 +233,7 @@ def test_the_host_counts_what_the_indexers_score_and_keep(monkeypatch, tmp_path)
     tracing.reconfigure()
     lens, prompts = [120, 7, 90, 60, 12], [30, 2, 40, 10, 5]
     cfg, eng = engine()
-    assert _kinds_label(cfg) == "moe.indexed.full.rope x2"
+    assert kinds_label(cfg) == "moe.indexed.full.rope x2"
     tracing.start()
     stats = eng.train_batch(ppo_like_batch(lens, prompts), MicroBatchSpec(n_mbs=2),
                             response_loss, n_response, scored_fn=response_positions)

@@ -10,7 +10,8 @@ import pytest
 
 from areal_tpu.api.data_api import MicroBatchSpec, SequenceSample
 from areal_tpu.base import tracing
-from areal_tpu.engine.jax_engine import JaxTrainEngine, _kinds_label
+from areal_tpu.engine.jax_engine import JaxTrainEngine
+from areal_tpu.engine.train_counts import kinds_label
 from areal_tpu.engine.optimizer import OptimizerConfig
 from areal_tpu.models import moe as moe_lib
 from areal_tpu.ops import kda
@@ -33,6 +34,11 @@ def _tracing_off(monkeypatch):
     tracing.reconfigure()
     yield
     tracing.reconfigure()
+
+
+def _said(eng, seg):
+    """What the engine's counts say of rows of these segment ids."""
+    return eng.counts.of({"segment_ids": seg}, 0)[0]
 
 
 def engine(depth=2, row_len_multiple=32):
@@ -108,11 +114,13 @@ def test_the_host_counts_the_rules_chunks_by_the_devices_rule(monkeypatch):
     cfg, eng = engine(0, row_len_multiple=256)
     seg = np.zeros((1, 256), np.int32)
     seg[0, :40], seg[0, 40:70] = 1, 2
-    assert eng._kda_counts(seg) == (3 * 256, 3 * 4, 3 * 2, 3 * 2)
+    names = ("train.kda_cells", "train.kda_chunks", "train.kda_chunks_live", "train.kda_resets")
+    rule = lambda seg: tuple(_said(eng, seg)[n] for n in names)
+    assert rule(seg) == (3 * 256, 3 * 4, 3 * 2, 3 * 2)
     monkeypatch.setattr(kda, "GROUP_CELLS", 64)
-    assert eng._kda_counts(seg) == (3 * 128, 3 * 2, 3 * 2, 3 * 2)
-    assert eng._kda_counts(np.stack([seg, seg])) == (6 * 128, 6 * 2, 6 * 2, 6 * 2)
-    assert _kinds_label(cfg) == KINDS
+    assert rule(seg) == (3 * 128, 3 * 2, 3 * 2, 3 * 2)
+    assert rule(np.stack([seg, seg])) == (6 * 128, 6 * 2, 6 * 2, 6 * 2)
+    assert kinds_label(cfg) == KINDS
 
 
 def test_the_family_runs_through_the_ppo_interface():
@@ -147,15 +155,17 @@ def test_the_family_runs_through_the_ppo_interface():
 @pytest.mark.parametrize("kernel", [False, True], ids=["plain", "kernel"])
 def test_the_kernels_cells_are_the_rules_where_the_kernels_run(kernel, monkeypatch):
     """`train.kda_fwd_kernel_cells` and `train.kda_bwd_kernel_cells`: every
-    position the rule walked where `ops/kda._use_kernel` takes the kernels
+    position the rule walked where `ops/kda.use_kernel` takes the kernels
     (one chip, heads of whole lane tiles), none where the plain form runs;
     `train.kda_cells` either way."""
     _, eng = engine(0, row_len_multiple=256)
     seen = []
-    monkeypatch.setattr(kda, "_use_kernel", lambda K, mesh: seen.append((K, mesh)) or kernel)
+    monkeypatch.setattr(kda, "use_kernel", lambda K, mesh: seen.append((K, mesh)) or kernel)
+    seg = np.zeros((1, 256), np.int32)
+    seg[0, :70] = 1
     tracing.start()
     try:
-        eng._count_batch("fused", 1, 1, 70, 256, *([0] * 10), n_kda_cells=768, n_kda_chunks=12)
+        eng._count_batch("fused", 1, 1, 70, 256, _said(eng, seg))
     finally:
         c = tracing.stop()["counters"]
     assert c["train.kda_cells"] == 768 and c["train.kda_chunks"] == 12
@@ -170,25 +180,26 @@ def test_the_taps_kernels_cells_are_the_convolutions_where_the_kernels_run(
         kernel, head_dim, monkeypatch):
     """`train.kda_taps_cells`: rows x row length x delta-rule layers, several
     micro-batches summed; `train.kda_taps_kernel_cells`: all of them where
-    `ops/kda._use_kernel` holds and the shapes fit the taps' kernels (heads of
+    `ops/kda.use_kernel` holds and the shapes fit the taps' kernels (heads of
     128: widths of whole strips; a row of whole blocks), 0 where the plain
     form runs (the CPU; widths or a row's length that do not fit)."""
     import dataclasses
 
     _, eng = engine(0, row_len_multiple=256)
-    eng.model_cfg = dataclasses.replace(
-        eng.model_cfg, kda=dataclasses.replace(eng.model_cfg.kda, head_dim=head_dim))
-    monkeypatch.setattr(kda, "_use_kernel", lambda K, mesh: kernel)
+    eng.counts = dataclasses.replace(eng.counts, cfg=dataclasses.replace(
+        eng.model_cfg, kda=dataclasses.replace(eng.model_cfg.kda, head_dim=head_dim)))
+    monkeypatch.setattr(kda, "use_kernel", lambda K, mesh: kernel)
     seg = np.zeros((1, 256), np.int32)
     seg[0, :70] = 1
     took = kernel and head_dim == 128
-    assert eng._kda_taps_counts(seg) == (3 * 256, 3 * 256 if took else 0)
-    assert eng._kda_taps_counts(np.stack([seg, seg])) == (6 * 256, 6 * 256 if took else 0)
-    assert eng._kda_taps_counts(seg[:, :200]) == (3 * 200, 0)  # no whole blocks
+    taps = lambda seg: tuple(
+        _said(eng, seg)[n] for n in ("train.kda_taps_cells", "train.kda_taps_kernel_cells"))
+    assert taps(seg) == (3 * 256, 3 * 256 if took else 0)
+    assert taps(np.stack([seg, seg])) == (6 * 256, 6 * 256 if took else 0)
+    assert taps(seg[:, :200]) == (3 * 200, 0)  # no whole blocks
     tracing.start()
     try:
-        eng._count_batch("fused", 1, 1, 70, 256, *([0] * 10), n_kda_cells=768,
-                         n_kda_taps_cells=768, n_kda_taps_kernel=768 if took else 0)
+        eng._count_batch("fused", 1, 1, 70, 256, _said(eng, seg))
     finally:
         c = tracing.stop()["counters"]
     assert c["train.kda_taps_cells"] == 768

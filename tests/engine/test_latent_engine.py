@@ -14,7 +14,8 @@ import pytest
 
 from areal_tpu.api.data_api import MicroBatchSpec, SequenceSample
 from areal_tpu.base import tracing
-from areal_tpu.engine.jax_engine import JaxTrainEngine, _kinds_label
+from areal_tpu.engine.jax_engine import JaxTrainEngine
+from areal_tpu.engine.train_counts import kinds_label
 from areal_tpu.engine.optimizer import OptimizerConfig
 from areal_tpu.models import moe as moe_lib
 from areal_tpu.ops.loss import head_cells_run, response_positions, two_on
@@ -200,7 +201,7 @@ def test_a_train_step_trains_the_module_and_counts_what_it_ran(depth):
     # three layers of the stack and the module's: the reference runs every cell
     seg = rows[0]["segment_ids"]
     r, t = seg.shape
-    assert eng._attn_counts(seg)[2] == 4 * r * t * t
+    assert eng.counts.of({"segment_ids": seg}, 0)[0]["train.attn_active_cells"] == 4 * r * t * t
     # two expert layers of the stack and the module's
     assert c["train.moe_pairs"] == 4 * sum(lens) * 3
     assert 0 < c["train.moe_pairs_held"] < c["train.moe_pairs"]
@@ -223,8 +224,9 @@ def test_a_module_weighted_zero_is_skipped_and_counted_nowhere():
     assert "t/mtp_loss" not in stats and "train.mtp_targets" not in c
     assert c["train.moe_pairs"] == 4 * 99 * 2
     seg = eng._build_rows(batch)[1]["segment_ids"]
-    assert eng._attn_counts(seg)[2] == 3 * seg.shape[0] * seg.shape[1] ** 2
-    assert _kinds_label(_cfg(DENSE)) == "dense.latent.full.rope x2"
+    assert (eng.counts.of({"segment_ids": seg}, 0)[0]["train.attn_active_cells"]
+            == 3 * seg.shape[0] * seg.shape[1] ** 2)
+    assert kinds_label(_cfg(DENSE)) == "dense.latent.full.rope x2"
 
 
 def test_the_ppo_interface_reports_the_modules_loss_and_acceptance():
@@ -272,7 +274,7 @@ def test_a_layer_that_runs_once_walks_its_live_bands_as_the_scanned_ones_do(stac
         cfg, eng = afmoe_engine(0)
     else:
         cfg, eng = engine(0, mtp_weight=0.1 if stack == "module" else 0.0)
-    eng.row_len_multiple = 128
+    eng.row_len_multiple = eng.counts.row_len_multiple = 128
     _, rows = eng._build_rows(ppo_like_batch([24, 16], [10, 5]))
     assert rows["input_ids"].shape == (1, 128) and eng._dead_bands(128)
     seg = np.asarray(rows["segment_ids"])
@@ -281,8 +283,8 @@ def test_a_layer_that_runs_once_walks_its_live_bands_as_the_scanned_ones_do(stac
     layers = cfg.n_layers + (stack == "module")
     assert [s.repeats for s in cfg.segments()][0] == 1  # the leading layer: no scan
     assert looping_layers(cfg, 1, 128, mtp=stack == "module") == layers
-    assert live == 3 and eng._stretch_cells(seg) == [(layers * 16 * live, 0)]
-    assert eng._band_counts(seg) == (16 * live,)
+    # every layer walks the live bands, none runs the row whole
+    assert live == 3 and eng.counts.of({"segment_ids": seg}, 0)[0]["train.band_cells"] == 16 * live
     rows = {k: jnp.asarray(v) for k, v in rows.items()}
     step = lambda: jax.jit(jax.value_and_grad(
         eng._mb_loss_fn(response_loss, response_positions), has_aux=True))(eng.params, rows)

@@ -10,7 +10,8 @@ import pytest
 
 from areal_tpu.api.data_api import MicroBatchSpec, SequenceSample
 from areal_tpu.base import tracing
-from areal_tpu.engine.jax_engine import JaxTrainEngine, _kinds_label, trainable
+from areal_tpu.engine.jax_engine import JaxTrainEngine, trainable
+from areal_tpu.engine.train_counts import kinds_label
 from areal_tpu.engine.optimizer import NO_DECAY_LEAVES, OptimizerConfig, _decay_mask
 from areal_tpu.models.transformer import init_params
 from areal_tpu.ops.ssm import chunk_counts
@@ -124,7 +125,7 @@ def test_a_train_step_updates_every_weight_and_counts_the_scan(depth):
     assert c["train.attn_active_cells"] == c["train.attn_causal_cells"] > 0
     seg = eng._build_rows(mbs[0])[1]["segment_ids"]
     r, t = seg.shape
-    assert eng._attn_counts(seg)[2] == 4 * r * t * t
+    assert eng.counts.of({"segment_ids": seg}, 0)[0]["train.attn_active_cells"] == 4 * r * t * t
     assert "train.moe_pairs" not in c
     dispatch = [s["attrs"] for s in got["spans"] if s["name"] == "train.dispatch"]
     assert len(dispatch) == (N_MBS if depth else 1)
@@ -136,7 +137,7 @@ def test_a_train_step_updates_every_weight_and_counts_the_scan(depth):
 
 
 def test_the_kinds_label_folds_the_cross_decoders_units_apart():
-    assert _kinds_label(_cfg(num_hidden_layers=12)).endswith(
+    assert kinds_label(_cfg(num_hidden_layers=12)).endswith(
         "ssm+dense^,dense.diff.full.nope^,gmu+dense<6,dense.diff.full.nope<7,"
         "gmu+dense<6,dense.diff.full.nope<7")
 

@@ -139,9 +139,9 @@ def test_counters_say_what_the_head_ran(path, monkeypatch):
     model = _actor(PATHS[path])
     eng = model.module
     seen = []
-    inner = eng._head_counts
+    inner = eng.counts.of
     monkeypatch.setattr(
-        eng, "_head_counts", lambda rows, fn: seen.append(rows) or inner(rows, fn))
+        eng.counts, "of", lambda rows, tok, fn: seen.append(rows) or inner(rows, tok, fn))
     itf = PPOActorInterface(n_minibatches=2)
     itf.train_step(model, _sample(seed=0), MB_SPEC)  # warm
     del seen[:]
@@ -184,7 +184,8 @@ def test_no_mask_counts_every_chunk_and_a_critic_counts_no_head():
     critic = JaxTrainEngine(
         small_cfg(is_critic=True), init_params(small_cfg(is_critic=True), jax.random.PRNGKey(3)),
         optimizer_config=OptimizerConfig(lr=1e-3), total_train_steps=10, row_len_multiple=32)
-    assert critic._head_counts({"segment_ids": np.ones((2, 32), np.int32)}, None) == (0, 0)
+    said, _ = critic.counts.of({"segment_ids": np.ones((2, 32), np.int32)}, 64)
+    assert "train.attn_cells" in said and not any("head" in n or "scored" in n for n in said)
 
 
 @pytest.mark.parametrize("cell", ["q15d12-train-ppo", "q15d12-train-short",

@@ -260,7 +260,7 @@ def test_band_cells_are_the_cells_the_devices_loops_run(monkeypatch):
     jax.clear_caches()
     eng = mk_engine(init_params(small_cfg(), jax.random.PRNGKey(7)), depth=0,
                     attn_impl="reference")
-    eng.row_len_multiple = 640
+    eng.row_len_multiple = eng.counts.row_len_multiple = 640
     rng = np.random.RandomState(7)
 
     def counters(seqlens):
@@ -286,15 +286,16 @@ def test_band_cells_are_the_cells_the_devices_loops_run(monkeypatch):
     assert c["train.cells"] == 640 and c["train.tokens"] == 300
     # a ladder that steps by a band or less fills a row's every band: at a
     # multiple of 16 a row of 512 pads under 32 cells, and runs whole
-    eng.row_len_multiple = 16
+    eng.row_len_multiple = eng.counts.row_len_multiple = 16
     d, c = counters([300, 190])
     assert (d["rows"], d["row_len"]) == (1, 512) and band_loop.loops(1, 512)
     assert not eng._dead_bands(512) and c["train.band_cells"] == c["train.cells"] == 512
-    eng.row_len_multiple = 640
+    eng.row_len_multiple = eng.counts.row_len_multiple = 640
     # rows together, a row under two bands and micro-batches stacked: by shape
-    assert eng._band_counts(np.tile(seg, (2, 1))) == (1280,)
-    assert eng._band_counts(seg[:, :128]) == (128,)
-    assert eng._band_counts(np.stack([seg, np.roll(seg, 200, axis=1)])) == (384 + 512,)
+    bands = lambda seg: eng.counts.of({"segment_ids": seg}, 0)[0]["train.band_cells"]
+    assert bands(np.tile(seg, (2, 1))) == 1280
+    assert bands(seg[:, :128]) == 128
+    assert bands(np.stack([seg, np.roll(seg, 200, axis=1)])) == 384 + 512
 
 
 # ---------------------------------------------------------------------------
@@ -442,6 +443,7 @@ def test_one_forward_records_the_fwd_spans(depth):
 @pytest.mark.parametrize("path", sorted(PATHS))
 def test_tracing_off_runs_no_host_count_and_the_stats_are_bit_equal(path, monkeypatch):
     from areal_tpu.engine.jax_engine import JaxTrainEngine
+    from areal_tpu.engine.train_counts import TrainCounts
 
     params = init_params(small_cfg(), jax.random.PRNGKey(11))
     batch = make_batch(n=9, seed=11)
@@ -455,10 +457,9 @@ def test_tracing_off_runs_no_host_count_and_the_stats_are_bit_equal(path, monkey
     assert counters["train.attn_cells"] > 0 and counters["train.head_cells"] > 0
 
     called = []
-    for name in ("_attn_counts", "_head_counts", "_ssm_counts", "_count_batch"):
-        monkeypatch.setattr(
-            JaxTrainEngine, name,
-            lambda self, *a, _n=name, **k: called.append(_n) or (0,) * 8)
+    monkeypatch.setattr(TrainCounts, "of", lambda self, *a, **k: called.append("of") or ({}, {}))
+    monkeypatch.setattr(JaxTrainEngine, "_count_batch",
+                        lambda *a, **k: called.append("_count_batch"))
     off_eng = mk_engine(params, depth=PATHS[path]["depth"])
     off = [off_eng.train_batch(*args, loss_name="t") for _ in range(2)]
     assert called == [] and not tracing.enabled()

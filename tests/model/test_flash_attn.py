@@ -148,7 +148,7 @@ RUN_SHAPE_LENGTHS = [
 def test_splash_run_shape_is_lane_aligned_divisible_and_never_dearer(t):
     from areal_tpu.ops import attention as A
 
-    tq, tkv, tkvc = A._splash_block_targets()
+    tq, tkv, tkvc = A.SPLASH_BLOCK_TARGETS
     t_run, bq, bkv, bkvc = A.splash_run_shape(t)
     assert t <= t_run <= -(-t // 512) * 512 and t_run % 128 == 0
     for b, cap in ((bq, tq), (bkv, tkv), (bkvc, tkvc)):
@@ -172,7 +172,7 @@ def test_splash_run_shape_keeps_lengths_whose_blocks_are_large(t):
     from areal_tpu.ops import attention as A
 
     assert A.splash_run_shape(t) == A._plain_run_shape(
-        t, *A._splash_block_targets())
+        t, *A.SPLASH_BLOCK_TARGETS)
 
 
 @pytest.mark.parametrize("t", [5504, 3712, 4480, 3200])
