@@ -28,6 +28,7 @@ broadcast across their span).
 from __future__ import annotations
 
 import collections
+import collections.abc
 import dataclasses
 import functools
 import operator
@@ -57,7 +58,7 @@ from areal_tpu.ops.loss import (
 from areal_tpu.engine import train_counts
 from areal_tpu.engine.optimizer import (
     OptimizerConfig,
-    make_lr_schedule,
+    host_lr_schedule,
     make_optimizer,
 )
 from areal_tpu.parallel.mesh import single_device_mesh
@@ -141,6 +142,40 @@ class EngineStats:
     grad_norm: float = 0.0
     lr: float = 0.0
     n_tokens: float = 0.0
+
+
+class TrainStats(collections.abc.Mapping):
+    """What `train_batch` returns: a step's stats, read from the device
+    when first looked at (any key, `len`, iteration, `dict(st)`) and kept
+    from then on. A Mapping and no `dict` subclass: `dict(st)` on one of
+    those copies the underlying storage past every overridden method."""
+
+    def __init__(self, resolve: Optional[Callable[[], Dict[str, float]]] = None,
+                 stats: Optional[Dict[str, float]] = None):
+        """`resolve` makes the read, once; `stats` is a mapping born read."""
+        self._resolve, self._stats = resolve, stats
+
+    @property
+    def resolved(self) -> bool:
+        return self._stats is not None
+
+    def read(self) -> Dict[str, float]:
+        """The stats, read now if no look has read them yet."""
+        if self._stats is None:
+            self._stats, self._resolve = self._resolve(), None
+        return self._stats
+
+    def __getitem__(self, key):
+        return self.read()[key]
+
+    def __iter__(self):
+        return iter(self.read())
+
+    def __len__(self):
+        return len(self.read())
+
+    def __repr__(self):
+        return f"TrainStats({self._stats if self.resolved else 'on the device'})"
 
 
 class JaxTrainEngine(TrainEngine):
@@ -272,7 +307,7 @@ class JaxTrainEngine(TrainEngine):
             self.optimizer = make_optimizer(
                 optimizer_config, total_train_steps, external_lr=True
             )
-            self._lr_schedule = make_lr_schedule(
+            self._lr_schedule = host_lr_schedule(
                 optimizer_config, total_train_steps
             )
             weights = trainable(self.params)  # no moments for buffers
@@ -821,9 +856,10 @@ class JaxTrainEngine(TrainEngine):
         loss_name: str = "loss",
         dp_token_weights_fn=None,
         scored_fn: Optional[ScoredFn] = None,
-    ) -> Dict[str, float]:
+    ) -> TrainStats:
         """Forward+backward over micro-batches, one optimizer step, no
-        host sync until the single packed-stats fetch at the end. Two
+        host sync at all: the stats come back as a mapping that makes the
+        single packed-stats fetch when first looked at (`TrainStats`). Two
         equivalent input paths: the default overlapped pipeline (one
         accumulate program a micro-batch shape; pack+H2D of mb i+1 hidden
         behind mb i's compute — _train_batch_overlapped) and the fused path (one
@@ -878,7 +914,7 @@ class JaxTrainEngine(TrainEngine):
             begin = tracing.start_span("train.begin")
             lr_pos = self._lr_steps if version_steps is None else int(version_steps)
             self._lr_steps += 1
-            lr = float(self._lr_schedule(lr_pos))
+            lr = self._lr_schedule(lr_pos)  # a host number: nothing touches the device
             # The overlapped pipeline needs per-micro-batch programs; the
             # fused path keeps the single donated executable. 'dp' scope stays
             # fused (its per-shard denominators need every micro-batch's loss
@@ -974,7 +1010,7 @@ class JaxTrainEngine(TrainEngine):
                     self.params, self.opt_state,
                     rows_dev if len(mbs) > 1 else rows_dev[0],
                     self._inv_denom(global_denom, n_tok),
-                    jnp.asarray(lr, jnp.float32),
+                    np.float32(lr),
                 )
                 tracing.fed("fused_step")
             if self._serial_dispatch:
@@ -993,14 +1029,15 @@ class JaxTrainEngine(TrainEngine):
         lr: float,
         scored_fn: Optional[ScoredFn] = None,
         begin: Optional[tracing.ManualSpan] = None,
-    ) -> Dict[str, float]:
+    ) -> TrainStats:
         """Pipelined gradient accumulation: a background thread FFD-packs,
         pads-to-bucket and `device_put`s micro-batch i+1 while micro-batch
         i's accumulate program runs on device (engine/prefetch.py).
         Dispatch is non-blocking — no fetch or block_until_ready inside
-        the loop; the single packed-stats fetch happens once per batch
-        after the optimizer apply. The global denominator accumulates as
-        micro-batches stream through (it is only needed at the apply)."""
+        the loop or after it; the single packed-stats fetch is the
+        caller's first look at what comes back (`_fetch_train_stats`).
+        The global denominator accumulates as micro-batches stream
+        through (it is only needed at the apply)."""
         from areal_tpu.engine.prefetch import HostPrefetcher
 
         # The stage runs on the prefetcher's thread: hand it the batch's
@@ -1088,7 +1125,7 @@ class JaxTrainEngine(TrainEngine):
             self.params, self.opt_state, packed, aux = apply(
                 self.params, self.opt_state, (g_acc, *stats),
                 self._inv_denom(global_denom, n_tok),
-                jnp.asarray(lr, jnp.float32),
+                np.float32(lr),
             )
             tracing.fed("apply")
         self._grad_sums = g_acc  # the next minibatch's buffers
@@ -1109,10 +1146,13 @@ class JaxTrainEngine(TrainEngine):
         """What a step's gradient sums are divided by: one over the
         caller's count of loss-weighted tokens, and, where indexers train
         beside the caller's loss, one over the step's real tokens for
-        their parameters' (`_optimizer_apply`)."""
+        their parameters' (`_optimizer_apply`). A numpy value, as the
+        learning rate beside it: it goes to the device with the step's
+        own arguments (`jnp.asarray` of a Python number is a program of
+        its own on the device's queue)."""
         if self._index_weight > 0:
-            return jnp.asarray([1.0 / global_denom, 1.0 / max(n_tok, 1)], jnp.float32)
-        return jnp.asarray(1.0 / global_denom, jnp.float32)
+            return np.asarray([1.0 / global_denom, 1.0 / max(n_tok, 1)], np.float32)
+        return np.float32(1.0 / global_denom)
 
     def _dead_bands(self, row_len: int) -> bool:
         """Whether a row of `row_len` cells, as `_build_rows` packs it, may
@@ -1194,14 +1234,30 @@ class JaxTrainEngine(TrainEngine):
     def _fetch_train_stats(
         self, packed, aux, loss_name: str, global_denom: float, n_mbs: int,
         lr: float = 0.0,
-    ) -> Dict[str, float]:
-        """ONE host transfer for all scalars (each float() would be its own
-        device round trip). `aux`
-        stays on device; only its key structure is read.
+    ) -> TrainStats:
+        """A step's stats as a mapping that reads them when first looked
+        at: ONE host transfer for all scalars (each float() would be its
+        own device round trip), made by the caller's first access and not
+        here, so a caller that keeps the mapping and enqueues its next
+        `train_batch` first (the PPO loops: read under `ppo.stats`) never
+        waits for the device between two minibatches. `aux` stays on
+        device; only its key structure is read. A caller that reads at
+        once waits here as it always did. Where `_serial_dispatch` holds
+        the mapping comes back read: nothing may be in flight when the
+        next program is enqueued.
+
+        What the read leaves in the trace: the span `train.fetch_stats`
+        with `behind`, the `train_batch` calls enqueued after the one
+        read (3, 2, 1, 0 where a step of four is read at its end), and the
+        counter `train.stats_deferred` of the reads with `behind` > 0.
+        Only a read with nothing behind it has emptied the device's queue
+        and may tell `tracing.drained`. (A second engine on the same chip
+        that trains between two minibatches of this one now finds this
+        engine's work still queued; no launcher co-locates two.)
 
         Honors `stats_fetch_interval`: when > 1, only every Nth
         train_batch pays the round trip; the other calls return the last
-        fetched values (stats feed logging only) tagged
+        values read (stats feed logging only) tagged
         `<loss>/stats_stale` = 1 with host-side fields kept exact."""
         self._train_calls += 1
         if (
@@ -1219,13 +1275,30 @@ class JaxTrainEngine(TrainEngine):
             stats[f"{loss_name}/stats_stale"] = 1.0
             self._record_moe_stats(stats, loss_name)
             tracing.event("train.fetch_stats", stale=True)
-            return stats
-        aux_leaves, aux_treedef = jax.tree_util.tree_flatten(aux)
-        del aux_leaves
+            return TrainStats(stats=stats)
+        aux_treedef = jax.tree_util.tree_structure(aux)
+        # holds the packed vector and the host's fields, nothing else of
+        # the step; `_train_calls` now, so that the read counts the calls since
+        st = TrainStats(functools.partial(
+            self._read_train_stats, packed, aux_treedef, loss_name, global_denom,
+            n_mbs, lr, self._train_calls))
+        if self._serial_dispatch:
+            st.read()
+        return st
+
+    def _read_train_stats(
+        self, packed, aux_treedef, loss_name: str, global_denom: float,
+        n_mbs: int, lr: float, call: int,
+    ) -> Dict[str, float]:
+        """The read `_fetch_train_stats`' mapping makes, once."""
+        behind = self._train_calls - call
         # Where the host waits for the device: the step's one fetch.
-        with tracing.span("train.fetch_stats", stale=False):
+        with tracing.span("train.fetch_stats", stale=False, behind=behind):
             p = np.asarray(packed)
-        tracing.drained("train.fetch_stats")
+        if behind > 0:
+            tracing.count("train.stats_deferred")
+        else:
+            tracing.drained("train.fetch_stats")
         loss_sum, gnorm, unorm = float(p[0]), float(p[1]), float(p[2])
         aux_vals = jax.tree_util.tree_unflatten(aux_treedef, p[3:].tolist())
         stats = {

@@ -12,6 +12,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
+import numpy as np
 import optax
 
 
@@ -49,6 +50,49 @@ def make_lr_schedule(cfg: OptimizerConfig, total_train_steps: int):
     return optax.join_schedules(
         [optax.linear_schedule(cfg.lr / warmup, cfg.lr, warmup), after], [warmup]
     )
+
+
+def host_lr_schedule(cfg: OptimizerConfig, total_train_steps: int):
+    """`make_lr_schedule`'s value at a whole position as a host number:
+    `f(pos) -> float`, numpy in float32, operation for operation what the
+    optax schedule does when it is called eagerly with a Python int. No
+    jax computation runs, so a train step that asks for its learning rate
+    neither puts anything on the device's queue nor waits for what is
+    there (an eager schedule is a dozen one-op programs and a fetch).
+    Equal to `float(make_lr_schedule(...)(pos))` to the last bit for
+    `constant` and `linear`, and to one ulp of float32 for `cosine`
+    (numpy's cosine against XLA's): tests/engine/test_lr_on_host.py."""
+    f32 = np.float32
+    warmup = int(cfg.warmup_steps_proportion * total_train_steps)
+    decay_steps = max(1, total_train_steps - warmup)
+    kind = cfg.lr_scheduler_type
+    if kind not in ("constant", "linear", "cosine"):
+        raise ValueError(f"unknown lr_scheduler_type {kind!r}")
+
+    def line(init, end, steps, pos):  # optax.linear_schedule(init, end, steps)(pos)
+        frac = f32(1) - f32(min(max(pos, 0), steps)) / f32(steps)
+        return f32(init - end) * frac + f32(end)
+
+    def after(pos):
+        if kind == "constant":
+            return cfg.lr  # optax hands the Python number back as it is
+        if kind == "linear":
+            return line(cfg.lr, cfg.lr * cfg.min_lr_ratio, decay_steps, pos)
+        turn = f32(np.pi) * f32(min(pos, decay_steps)) / f32(decay_steps)
+        cosine_decay = f32(0.5) * (f32(1) + np.cos(turn, dtype=f32))
+        return f32(cfg.lr) * (
+            f32(1 - cfg.min_lr_ratio) * cosine_decay + f32(cfg.min_lr_ratio))
+
+    def schedule(pos: int) -> float:
+        pos = int(pos)
+        if warmup == 0:
+            return float(after(pos))
+        if pos < warmup:
+            return float(line(cfg.lr / warmup, cfg.lr, warmup, pos))
+        # `jnp.where` makes the constant schedule's Python number a float32
+        return float(f32(after(pos - warmup)))
+
+    return schedule
 
 
 # A state-space mixer's per-head and per-channel parameters (ops/ssm.py,

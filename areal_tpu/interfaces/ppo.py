@@ -392,6 +392,8 @@ class PPOActorInterface(ModelInterface):
                         version_steps=model.version, loss_name="ppo_actor",
                         scored_fn=response_positions,
                     )
+                # kept unread: the first look at `st` waits for the device,
+                # and the next minibatch is enqueued first (`ppo.stats` reads)
                 all_stats.append(st)
             with tracing.span("ppo.stats"):
                 model.inc_version()
@@ -443,7 +445,7 @@ def _run_prep(engine, prep, input_: SequenceSample, kl_coef: float):
         rows_dev = engine._device_rows(rows)
     tracing.build_site("ppo_prep", prep, batch.n_rows, batch.row_len)
     with tracing.span("ppo.prep.dispatch"):
-        out = prep(rows_dev, jnp.asarray(kl_coef, jnp.float32))
+        out = prep(rows_dev, np.float32(kl_coef))
         tracing.fed("ppo_prep")
     return batch, out
 
@@ -576,7 +578,7 @@ class PPOCriticInterface(ModelInterface):
                         token_normalize_scope=self.token_normalize_scope,
                         version_steps=model.version, loss_name="ppo_critic",
                     )
-                all_stats.append(st)
+                all_stats.append(st)  # unread until `ppo.stats`, as the actor's
             with tracing.span("ppo.stats"):
                 model.inc_version()
                 n_resp = float(np.sum(resp_flat))

@@ -50,7 +50,8 @@ class SFTInterface(ModelInterface):
         self, model: Model, input_: SequenceSample, mb_spec: MicroBatchSpec
     ) -> Dict:
         engine = model.module
-        stats = engine.train_batch(
+        # read at once: one update a step, nothing to enqueue behind it
+        stats = dict(engine.train_batch(
             input_,
             mb_spec,
             loss_fn=sft_row_loss,
@@ -59,7 +60,7 @@ class SFTInterface(ModelInterface):
             version_steps=model.version,
             loss_name="sft",
             scored_fn=response_positions,
-        )
+        ))
         model.inc_version()
         stats_tracker.scalar(**stats)
         return stats

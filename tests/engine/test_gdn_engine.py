@@ -73,8 +73,8 @@ def test_a_train_step_moves_both_mixers_and_counts_what_the_rule_ran(depth):
     batch = ppo_like_batch(lens, prompts)
     tracing.start()
     try:
-        stats = eng.train_batch(batch, MicroBatchSpec(n_mbs=N_MBS), response_loss,
-                                n_response, loss_name="t", scored_fn=response_positions)
+        stats = dict(eng.train_batch(batch, MicroBatchSpec(n_mbs=N_MBS), response_loss,
+                                     n_response, loss_name="t", scored_fn=response_positions))
     finally:
         got = tracing.stop()
     after = jax.tree_util.tree_map(np.asarray, eng.params)

@@ -95,8 +95,8 @@ def test_a_train_step_updates_the_weights_and_counts_the_chunks(depth):
     batch = make_batch(n=9, seed=5)
     tracing.start()
     try:
-        stats = eng.train_batch(batch, MicroBatchSpec(n_mbs=N_MBS),
-                                packed_loss, loss_weight, loss_name="t")
+        stats = dict(eng.train_batch(batch, MicroBatchSpec(n_mbs=N_MBS),
+                                     packed_loss, loss_weight, loss_name="t"))
     finally:
         got = tracing.stop()
     after = jax.tree_util.tree_map(np.asarray, eng.params)

@@ -177,8 +177,8 @@ def test_a_train_step_trains_the_module_and_counts_what_it_ran(depth):
     batch = ppo_like_batch(lens, prompts)
     tracing.start()
     try:
-        stats = eng.train_batch(batch, MicroBatchSpec(n_mbs=N_MBS), response_loss,
-                                n_response, loss_name="t", scored_fn=response_positions)
+        stats = dict(eng.train_batch(batch, MicroBatchSpec(n_mbs=N_MBS), response_loss,
+                                     n_response, loss_name="t", scored_fn=response_positions))
     finally:
         got = tracing.stop()
     after = jax.tree_util.tree_map(np.asarray, eng.params)
@@ -217,8 +217,8 @@ def test_a_module_weighted_zero_is_skipped_and_counted_nowhere():
     batch = ppo_like_batch([30, 44, 25], [10, 20, 24])
     tracing.start()
     try:
-        stats = eng.train_batch(batch, MicroBatchSpec(n_mbs=1), response_loss,
-                                n_response, loss_name="t", scored_fn=response_positions)
+        stats = dict(eng.train_batch(batch, MicroBatchSpec(n_mbs=1), response_loss,
+                                     n_response, loss_name="t", scored_fn=response_positions))
     finally:
         c = tracing.stop()["counters"]
     assert "t/mtp_loss" not in stats and "train.mtp_targets" not in c

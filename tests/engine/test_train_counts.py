@@ -104,8 +104,9 @@ def _traced_step(eng, scored_fn):
     batch = ppo_like_batch(LENS, PROMPTS)
     tracing.start()
     try:
-        eng.train_batch(batch, MicroBatchSpec(n_mbs=N_MBS), response_loss, n_response,
-                        loss_name="t", scored_fn=scored_fn)
+        # read inside the session: the `sum:` stats are counted by the read
+        dict(eng.train_batch(batch, MicroBatchSpec(n_mbs=N_MBS), response_loss, n_response,
+                             loss_name="t", scored_fn=scored_fn))
     finally:
         got = tracing.stop()
     return ({k: v for k, v in got["counters"].items() if k.startswith("train.")},

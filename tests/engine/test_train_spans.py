@@ -45,7 +45,8 @@ def _tracing_off(monkeypatch):
 
 @pytest.fixture(scope="module", params=sorted(PATHS))
 def recorded(request):
-    """One traced train_batch on each path (after an untraced warm one):
+    """One traced train_batch on each path (after an untraced warm one),
+    its stats read at once, as a caller with one update a step reads them:
     (path, what stop() returned, the engine's telemetry, the main thread)."""
     path = request.param
     tracing.reconfigure()
@@ -62,7 +63,7 @@ def recorded(request):
     off = dict(eng.last_overlap)
     tracing.start()
     try:
-        eng.train_batch(*args, loss_name="t")
+        dict(eng.train_batch(*args, loss_name="t"))
     finally:
         got = tracing.stop()
     # the (rows, row length) the engine's packer gives each micro-batch
@@ -83,10 +84,13 @@ def test_the_tree_hangs_under_one_train_batch(recorded):
     spans = got["spans"]
     by_id = {s["span"]: s for s in spans}
     [batch] = [s for s in spans if s["name"] == "train.batch"]
-    assert {s["trace"] for s in spans} == {batch["trace"]}
+    # the read is the caller's: after the batch has ended, under whatever
+    # span the caller is in (here none)
+    [fetch] = [s for s in spans if s["name"] == "train.fetch_stats"]
+    assert fetch["parent"] is None and fetch["start_ns"] >= batch["end_ns"]
+    assert {s["trace"] for s in spans if s is not fetch} == {batch["trace"]}
     parent_of = {s["name"]: by_id[s["parent"]]["name"] for s in spans if s["parent"]}
-    want = {"train.dispatch": "train.batch", "train.fetch_stats": "train.batch",
-            "train.begin": "train.batch"}
+    want = {"train.dispatch": "train.batch", "train.begin": "train.batch"}
     if path == "overlapped":
         want.update({"train.stage": "train.batch", "train.pack": "train.stage",
                      "train.h2d": "train.stage", "train.wait_input": "train.batch",
@@ -152,7 +156,7 @@ def test_attributes_and_counters_count_tokens_and_cells(recorded):
         if s["name"] == "train.stage":
             assert 0 < s["attrs"]["tokens"] <= s["attrs"]["cells"]
         if s["name"] == "train.fetch_stats":
-            assert s["attrs"] == {"stale": False}
+            assert s["attrs"] == {"stale": False, "behind": 0}
     if path == "overlapped":
         stages = [s["attrs"] for s in spans if s["name"] == "train.stage"]
         assert sum(x["tokens"] for x in stages) == a["tokens"]
@@ -174,9 +178,9 @@ def test_the_jit_cache_holds_three_entries_and_a_stale_fetch_is_marked_and_drain
         eng = mk_engine(init_params(small_cfg(), jax.random.PRNGKey(6)),
                         depth=2, stats_fetch_interval=2)
         batch = make_batch(n=9, seed=6)
-        for _ in range(4):
-            eng.train_batch(batch, MicroBatchSpec(n_mbs=N_MBS), packed_loss,
-                            loss_weight, loss_name="t")
+        for _ in range(4):  # each read at once
+            dict(eng.train_batch(batch, MicroBatchSpec(n_mbs=N_MBS), packed_loss,
+                                 loss_weight, loss_name="t"))
     finally:
         got = tracing.stop()
     # one entry for the accumulate program, one for the two beside it
@@ -197,6 +201,71 @@ def test_the_jit_cache_holds_three_entries_and_a_stale_fetch_is_marked_and_drain
     out = stats_tracker.export()
     assert {"perf/packing_efficiency", "perf/h2d_wait_ms",
             "perf/dispatch_gap_ms", "perf/overlap_events"} <= set(out)
+
+
+@pytest.mark.parametrize("path", sorted(PATHS))
+def test_only_a_read_of_the_newest_enqueued_programs_stats_marks_the_device_drained(path):
+    """Reading an older call's stats returns while the calls made since
+    are still queued: the device is not dry and no `device.starved` span
+    may follow. The newest call's read does empty the queue. So `n`
+    calls read at the end of each round leave one stretch a round (the
+    PPO step's two, with its prep's: tests/interfaces/test_ppo_spans.py)
+    where reading each at once leaves one a call."""
+    eng = mk_engine(init_params(small_cfg(), jax.random.PRNGKey(12)),
+                    depth=PATHS[path]["depth"])
+    args = (make_batch(n=9, seed=12), MicroBatchSpec(n_mbs=N_MBS), packed_loss, loss_weight)
+    dict(eng.train_batch(*args, loss_name="t"))
+    dict(eng.train_batch(*args, loss_name="t"))  # warm, untraced
+    until = "accum_step" if path == "overlapped" else "fused_step"
+    tracing.start()
+    try:
+        old = eng.train_batch(*args, loss_name="t")
+        new = eng.train_batch(*args, loss_name="t")
+        dict(old)  # one call behind it: marks nothing
+        eng.train_batch(*args, loss_name="t")  # so this enqueue ends no stretch
+        mid = tracing.stop()
+        tracing.start()
+        dict(new)  # still one behind (the third call)
+        newest = eng.train_batch(*args, loss_name="t")
+        dict(newest)  # nothing behind: the queue is empty when this returns
+        eng.train_batch(*args, loss_name="t")  # and this enqueue ends the stretch
+    finally:
+        got = tracing.stop()
+    assert [s for s in mid["spans"] if s["name"] == "device.starved"] == []
+    assert [s["attrs"]["behind"] for s in mid["spans"]
+            if s["name"] == "train.fetch_stats"] == [1]
+    fetches = sorted((s for s in got["spans"] if s["name"] == "train.fetch_stats"),
+                     key=lambda s: s["start_ns"])
+    assert [s["attrs"]["behind"] for s in fetches] == [1, 0]
+    [starved] = [s for s in got["spans"] if s["name"] == "device.starved"]
+    assert starved["attrs"] == {"after": "train.fetch_stats", "until": until}
+    assert fetches[1]["end_ns"] <= starved["start_ns"] < starved["end_ns"]
+    assert mid["counters"]["train.stats_deferred"] == got["counters"]["train.stats_deferred"] == 1
+
+
+@pytest.mark.parametrize("n", [2, 4])
+def test_n_calls_read_at_their_end_leave_one_stretch_where_reading_at_once_leaves_n(n):
+    eng = mk_engine(init_params(small_cfg(), jax.random.PRNGKey(13)), depth=2)
+    args = (make_batch(n=9, seed=13), MicroBatchSpec(n_mbs=N_MBS), packed_loss, loss_weight)
+    dict(eng.train_batch(*args, loss_name="t"))
+    dict(eng.train_batch(*args, loss_name="t"))  # warm, untraced
+
+    def rounds(at_once):
+        tracing.start()
+        try:
+            for _ in range(3):
+                sts = []
+                for _ in range(n):
+                    st = eng.train_batch(*args, loss_name="t")
+                    sts.append(dict(st) if at_once else st)
+                [dict(st) for st in sts]
+        finally:
+            got = tracing.stop()
+        return sum(s["name"] == "device.starved" for s in got["spans"])
+
+    # a session's first enqueue follows no mark, its last read feeds none
+    assert rounds(at_once=True) == 3 * n - 1
+    assert rounds(at_once=False) == 3 - 1
 
 
 @pytest.mark.parametrize("impl", ["splash", "reference"])

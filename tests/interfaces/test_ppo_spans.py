@@ -2,8 +2,10 @@
 `ppo.train_step` root a step, `ppo.prep` (its host parts as children),
 `ppo.advantages`, one `ppo.minibatch` a minibatch and `ppo.stats` under
 it, each minibatch's `train.batch` tree under that, the prefetcher's
-`train.stage` under the step's trace id, and a `device.starved` span for
-every stretch between a blocking read and the next enqueue. Actor and
+`train.stage` under the step's trace id, every minibatch's stats read
+under `ppo.stats` (no read between a step's first enqueue and its last),
+and a `device.starved` span for every stretch between a blocking read of
+the newest thing enqueued and the next enqueue: two a step. Actor and
 critic."""
 
 import threading
@@ -43,11 +45,12 @@ TREE = {
     "train.wait_input": (N_MINIBATCHES * (MBS_PER_MINIBATCH + 1), "train.batch"),
     "train.dispatch": (N_MINIBATCHES * MBS_PER_MINIBATCH, "train.batch"),
     "train.apply": (N_MINIBATCHES, "train.batch"),
-    "train.fetch_stats": (N_MINIBATCHES, "train.batch"),
-    # a session's first step: after the prep's read and after each
-    # minibatch's fetch but the last, each ended by a minibatch's first
-    # dispatch
-    "device.starved": (N_MINIBATCHES, "train.dispatch"),
+    # read at the step's end, the minibatches all enqueued
+    "train.fetch_stats": (N_MINIBATCHES, "ppo.stats"),
+    # a session's first step: after the prep's read alone, ended by the
+    # first minibatch's first dispatch (no minibatch's fetch comes before
+    # the next one's enqueue)
+    "device.starved": (1, "train.dispatch"),
 }
 
 
@@ -175,8 +178,11 @@ def test_a_first_step_builds_its_prep_under_ppo_prep_by_name_and_shape(critic):
 @pytest.mark.parametrize("critic", [False, True], ids=["actor", "critic"])
 def test_every_stretch_after_a_blocking_read_is_one_starved_span_of_the_step_that_fed(critic):
     """Three steps of one session: the first records a stretch after the
-    prep's read and after each minibatch's fetch but the last, whose mark
-    waits for the next step's prep; every later step one more."""
+    prep's read; the read of its last minibatch's stats, the only one with
+    nothing enqueued behind it, leaves a mark that waits for the next
+    step's prep, so every later step records two, whatever the number of
+    minibatches (n + 1 while each minibatch's stats were read before the
+    next was enqueued)."""
     tracing.reconfigure()
     model = _model(critic)
     itf = (PPOCriticInterface if critic else PPOActorInterface)(
@@ -200,8 +206,7 @@ def test_every_stretch_after_a_blocking_read_is_one_starved_span_of_the_step_tha
         untils = [s["attrs"]["until"] for s in mine]
         afters = [s["attrs"]["after"] for s in mine]
         tail = [] if i == 0 else [("train.fetch_stats", "ppo_prep")]
-        assert list(zip(afters, untils)) == tail + [("ppo.prep", "accum_step")] + [
-            ("train.fetch_stats", "accum_step")] * (N_MINIBATCHES - 1)
+        assert list(zip(afters, untils)) == tail + [("ppo.prep", "accum_step")]
         for s in mine:
             # it ends as an enqueue returns, inside the span that enqueued
             parent = by_id[s["parent"]]
@@ -218,4 +223,13 @@ def test_every_stretch_after_a_blocking_read_is_one_starved_span_of_the_step_tha
             assert not [m for m in main if last["end_ns"] < m["start_ns"] < s["start_ns"]]
         if tail:  # the waiting mark was set in the step before, after its last fetch
             assert roots[i - 1]["start_ns"] < mine[0]["start_ns"] < roots[i - 1]["end_ns"]
+        # the step's reads: under `ppo.stats`, oldest first, none before
+        # the last minibatch's apply was enqueued
+        fetches = [s for s in spans if s["name"] == "train.fetch_stats"
+                   and s["trace"] == root["trace"]]
+        assert [s["attrs"]["behind"] for s in fetches] == list(range(N_MINIBATCHES))[::-1]
+        last_apply = max(s["end_ns"] for s in spans if s["name"] == "train.apply"
+                         and s["trace"] == root["trace"])
+        assert all(s["start_ns"] >= last_apply for s in fetches)
+    assert got["counters"]["train.stats_deferred"] == 3 * (N_MINIBATCHES - 1)
     assert got["dropped"] == 0
