@@ -44,7 +44,9 @@ def kinds_label(cfg: TransformerConfig) -> str:
     `ssm+dense^`, `dense.diff.full.nope<5`, `gmu+dense<4`; latent
     attention says `latent.`, a delta-rule mixer beside an MLP `kda.` and
     its chunk (`moe.kda.c64`; with one decay a head and h key heads
-    `moe.kda.head.k16.c64`), attention over the keys an indexer chooses
+    `moe.kda.head.k16.c64`; with keys and values of two widths and a
+    doubled beta `dense.kda.head.k30.96x192.b2.c64`), attention over the
+    keys an indexer chooses
     `indexed.`, a layer over four residual streams starts with `hc4.`, and
     a prediction module after the stack ends the label with `+mtp`. A
     layer that rotates by a named set of the stack's (`rotary_sets`) says
@@ -62,6 +64,9 @@ def kinds_label(cfg: TransformerConfig) -> str:
             return f"{streams}{k.mlp}.{attn}{keeps}{reads}"
         if k.mixer == "kda" and k.mlp is not None:
             form = "" if cfg.kda.decay == "channel" else f"head.k{cfg.kda.key_heads}."
+            if cfg.kda.value_dim != cfg.kda.head_dim or cfg.kda.neg_eigval:
+                form += (f"{cfg.kda.head_dim}x{cfg.kda.value_dim}."
+                         f"{'b2.' if cfg.kda.neg_eigval else ''}")
             return f"{k.mlp}.kda.{form}c{cfg.kda.chunk_size}"
         return (f"attn.{attn}{keeps}" if k.mixer == "attention" else k.parts) + reads
 
@@ -279,7 +284,7 @@ class TrainCounts:
             return {}
         cfg = self.cfg.kda
         cells, chunks, live, resets = (n * c for c in kda.chunk_counts(mbs, cfg.chunk_size))
-        kernel = kda.use_kernel(cfg.head_dim, self.mesh)
+        kernel = kda.use_kernel(cfg, self.mesh)
         taps = n * mbs.size
         return {
             "train.kda_cells": cells,

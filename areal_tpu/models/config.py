@@ -168,29 +168,37 @@ class SSMConfig:
 
 @dataclasses.dataclass
 class KDAConfig:
-    """A delta-rule mixer (`ops/kda.py` has the equations), in the two
+    """A delta-rule mixer (`ops/kda.py` has the equations), in the three
     published forms, side by side:
 
-    ====================  ==============================  ===============================
-    .                     Kimi Delta Attention            Gated DeltaNet
-                          (arXiv:2510.26692; defaults)    (arXiv:2412.06464, Qwen3-Next)
-    ====================  ==============================  ===============================
-    `decay`               "channel": g `[T, H, K]`        "head": g `[T, H]`
-    `decay_input`         "lowrank": `(h W_fa) W_fb`      "column": `h W_a`, a column a head
-    `n_key_heads`         None: as many as `n_heads`      divides `n_heads`: value head j
-                                                          reads key head `j // (H / Hk)`
-    `gate_rank`           an int: `(h W_ga) W_gb`         None: a full-rank `h W_g`
-    `gate_act`            "sigmoid"                       "silu"
-    state a value head    `Diag(exp(g_t))` S              `exp(g_t)` S
-    ====================  ==============================  ===============================
+    ====================  =========================  ==========================  ==========================
+    .                     Kimi Delta Attention       Gated DeltaNet              Gated DeltaNet, expand_v 2
+                          (arXiv:2510.26692;         (arXiv:2412.06464,          (its authors' sizing with
+                          defaults)                  Qwen3-Next)                 arXiv:2411.12537's beta;
+                                                                                 Olmo-Hybrid)
+    ====================  =========================  ==========================  ==========================
+    `decay`               "channel": g `[T, H, K]`   "head": g `[T, H]`          "head"
+    `decay_input`         "lowrank": `(h W_fa) W_fb` "column": `h W_a`           "column"
+    `n_key_heads`         None: as many as `n_heads` divides `n_heads`: value    None
+                                                     head j reads key head
+                                                     `j // (H / Hk)`
+    `value_head_dim`      None: V = K = `head_dim`   None                        V = 2 K: a state `[K, V]`
+    `neg_eigval`          False: beta = sigmoid      False                       True: beta = 2 sigmoid
+    `gate_rank`           an int: `(h W_ga) W_gb`    None: a full-rank `h W_g`   None
+    `gate_act`            "sigmoid"                  "silu"                      "silu"
+    state a value head    `Diag(exp(g_t))` S         `exp(g_t)` S                `exp(g_t)` S
+    ====================  =========================  ==========================  ==========================
 
-    Both: `n_heads` (value) heads whose keys and values are `head_dim`
-    wide, a state of `head_dim x head_dim` a value head, q, k and v each
+    All: `n_heads` (value) heads whose keys are `head_dim` wide (K) and
+    whose values are `value_head_dim` wide (V; None: as the keys), a state
+    of K x V a value head, q, k and v each
     through a causal depthwise convolution of `conv_kernel` taps and silu,
     q and k made unit a head, `g = -exp(A_log) softplus(x + dt_bias)`,
-    beta a sigmoid a value head, the recurrence computed in chunks of
-    `chunk_size` positions, an RMSNorm a head on the output under the
-    gate. `dt_min`, `dt_max`, `dt_floor`: the seeded draw of `dt_bias`, as
+    beta a sigmoid a value head (doubled under `neg_eigval`: `I - beta k
+    k^T` then has an eigenvalue in (-1, 1) along k), the recurrence
+    computed in chunks of
+    `chunk_size` positions, an RMSNorm a head (V wide) on the output under
+    the gate. `dt_min`, `dt_max`, `dt_floor`: the seeded draw of `dt_bias`, as
     `SSMConfig`'s. A combination that has no code is refused here."""
 
     n_heads: int = 2
@@ -205,6 +213,8 @@ class KDAConfig:
     decay: str = "channel"  # channel | head
     decay_input: str = "lowrank"  # lowrank | column
     gate_act: str = "sigmoid"  # sigmoid | silu
+    value_head_dim: Optional[int] = None  # None: as wide as the keys
+    neg_eigval: bool = False  # beta = 2 sigmoid: in (0, 2)
 
     def __post_init__(self):
         if self.chunk_size % 16:
@@ -234,15 +244,30 @@ class KDAConfig:
             raise NotImplementedError(
                 "KDAConfig: a full-rank output gate beside a low-rank decay is in no "
                 "published model; models/transformer._kda_in has no such pair")
+        if self.decay == "channel" and (self.value_dim != self.head_dim or self.neg_eigval):
+            raise NotImplementedError(
+                "KDAConfig: values wider than keys, or a doubled beta, under a decay a "
+                "channel: the decay's low-rank product is d_inner wide and scales a "
+                "key's channels (models/transformer._kda_in, ops/kda._intra_channel), "
+                "so it has one width for both, and no published model doubles its beta")
 
     @property
     def key_heads(self) -> int:
         return self.n_heads if self.n_key_heads is None else self.n_key_heads
 
     @property
+    def value_dim(self) -> int:
+        """A value head's width V: the state a head is `[head_dim, V]`."""
+        return self.head_dim if self.value_head_dim is None else self.value_head_dim
+
+    @property
+    def beta_scale(self) -> float:
+        return 2.0 if self.neg_eigval else 1.0
+
+    @property
     def d_inner(self) -> int:
         """v's, the gate's and the output's width."""
-        return self.n_heads * self.head_dim
+        return self.n_heads * self.value_dim
 
     @property
     def d_key(self) -> int:
@@ -598,11 +623,19 @@ class TransformerConfig:
     attn_out_bias: bool = False  # gpt2 also biases the output projection
     mlp_bias: bool = False
     qk_norm: bool = False  # qwen3 per-head RMSNorm on q/k
+    # With `qk_norm`: "head" (one RMSNorm a head, weights `[head_dim]`) or
+    # "width" (Olmo 2's: one RMSNorm over the whole projected width of q and
+    # of k, before the split into heads, weights `[q_dim]` and `[kv_dim]`).
+    qk_norm_over: str = "head"
     # a = (softmax(qk)v) * sigmoid(h Wg) before the output projection.
     attn_gate: bool = False
     # Four norms a layer: the attention and MLP outputs are normalised
     # (ln1_post, ln2_post) before they join the residual stream.
     post_norms: bool = False
+    # The norms on the way into the mixer and the MLP (ln1, ln2). Without
+    # them and with `post_norms` a layer has output norms only (Olmo 2's
+    # block: `x + norm(mixer(x))`, `h + norm(mlp(h))`); with both, four.
+    pre_norms: bool = True
     tied_embeddings: bool = False
     embedding_multiplier: Optional[float] = None  # gemma normalizer
 
@@ -663,6 +696,14 @@ class TransformerConfig:
             self.rotary_sets = {
                 name: RotarySet(**rs) if isinstance(rs, dict) else rs
                 for name, rs in self.rotary_sets.items()}
+        if not (self.pre_norms or self.post_norms):
+            raise NotImplementedError(
+                "a layer with no norm at all (pre_norms and post_norms both off) is "
+                "in no published model; a layer norms on the way in, on the way "
+                "out, or both")
+        if self.qk_norm_over not in ("head", "width"):
+            raise ValueError(
+                f"qk_norm_over is 'head' or 'width', got {self.qk_norm_over!r}")
         if self.activation not in ("silu", "gelu", "relu2"):
             raise ValueError(
                 f"activation must be 'silu', 'gelu' or 'relu2', got {self.activation!r}")
@@ -745,6 +786,14 @@ class TransformerConfig:
         elif self.rotary_sets:
             raise ValueError(
                 f"rotary_sets {sorted(self.rotary_sets)} that no layer names")
+        if self.qk_norm and self.qk_norm_over == "width" and (
+                self.mla is not None or self.indexer is not None
+                or any(k.diff or k.reads is not None for k in kinds)):
+            raise NotImplementedError(
+                "q and k normed over their whole width beside latent attention, an "
+                "indexer, differential attention or a layer that reads another's k "
+                "and v: models/transformer._attn_in norms a plain layer's own "
+                "projections before the split into heads and nothing else")
         if self.rotary_fraction != 1.0:
             turned = self.head_dim * self.rotary_fraction
             if (not 0.0 < self.rotary_fraction < 1.0 or turned != int(turned)
@@ -902,7 +951,7 @@ class TransformerConfig:
             missing.append(
                 "a delta-rule state beside the KV pages: such a layer keeps, a "
                 f"sequence, its state [{self.kda.n_heads}, {self.kda.head_dim}, "
-                f"{self.kda.head_dim}] and the last conv_kernel - 1 inputs of its "
+                f"{self.kda.value_dim}] and the last conv_kernel - 1 inputs of its "
                 "three convolutions, which the cache manager has no slot for, no "
                 "snapshot of for an interrupted rollout to resume from, and no "
                 "decode step"
@@ -956,6 +1005,12 @@ class TransformerConfig:
                 "the attention output gate, the post-attention / post-MLP "
                 "norms and a rotation of part of a head (rotary_fraction "
                 f"{self.rotary_fraction}) in the decode layer"
+            )
+        if not self.pre_norms or (self.qk_norm and self.qk_norm_over == "width"):
+            missing.append(
+                "a block with output norms only (no norm on the way into the mixer "
+                "or the MLP) and one RMSNorm over the whole width of q and of k: "
+                "the decode layer norms its input and norms q and k a head"
             )
         if self.moe is not None and (
             self.moe.experts_held is not None or self.moe.score_func != "softmax"

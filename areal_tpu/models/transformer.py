@@ -128,7 +128,11 @@ def _init_layer_stack(cfg: TransformerConfig, keys, n: int, kind: LayerKind,
             attn["sub_norm"] = jnp.ones((L, 2 * cfg.head_dim), pdt)
         if cfg.attn_out_bias:
             attn["bo"] = jnp.zeros((L, D), pdt)
-        if cfg.qk_norm:
+        if cfg.qk_norm and cfg.qk_norm_over == "width":
+            # `_LATENT_Q_GAIN`'s reason: a softmax peaked as a trained model's
+            attn["q_norm"] = jnp.ones((L, cfg.q_dim), pdt) * _LATENT_Q_GAIN
+            attn["k_norm"] = jnp.ones((L, cfg.kv_dim), pdt)
+        elif cfg.qk_norm:
             attn["q_norm"] = jnp.ones((L, cfg.head_dim), pdt)
             attn["k_norm"] = jnp.ones((L, cfg.head_dim), pdt)
         if cfg.attn_gate:
@@ -168,7 +172,7 @@ def _init_layer_stack(cfg: TransformerConfig, keys, n: int, kind: LayerKind,
         layers["gmu"] = {"w_in": dense(k_in, (L, D, cfg.ssm.d_inner)),
                          "w_out": dense(k_out, (L, cfg.ssm.d_inner, D))}
     if kind.mixer is not None:
-        norms += ["ln1"] + (["ln1_post"] if cfg.post_norms else [])
+        norms += ["ln1"] * cfg.pre_norms + ["ln1_post"] * cfg.post_norms
 
     if kind.mlp == "moe":
         from areal_tpu.models.moe import init_moe_params
@@ -197,7 +201,7 @@ def _init_layer_stack(cfg: TransformerConfig, keys, n: int, kind: LayerKind,
             mlp["b_out"] = jnp.zeros((L, D), pdt)
     if kind.mlp is not None:
         layers["mlp"] = mlp
-        norms += ["ln2"] + (["ln2_post"] if cfg.post_norms else [])
+        norms += ["ln2"] * cfg.pre_norms + ["ln2_post"] * cfg.post_norms
 
     hy = cfg.hyper
     if hy is not None:  # a sublayer's hyper-connections: the mixer's, the MLP's
@@ -380,6 +384,8 @@ def init_params(cfg: TransformerConfig, rng: jax.Array) -> Params:
 
 
 def _norm(x, p, cfg):
+    if p is None:  # a layer without this norm (`pre_norms` off): x as it is
+        return x
     if cfg.norm_type == "rms":
         return rms_norm(x, p["weight"], cfg.norm_eps)
     return layer_norm(x, p["weight"], p.get("bias"), cfg.norm_eps)
@@ -565,6 +571,11 @@ def _attn_in(x, lp, cfg, cdt, kv=None):
             if kv is None:
                 k = k + lp["bk"].astype(cdt)
                 v = v + lp["bv"].astype(cdt)
+        over_width = cfg.qk_norm and cfg.qk_norm_over == "width"
+        if over_width:  # one norm over the projected width, before the heads
+            with jax.named_scope("attn_qk_wide_norm"):
+                q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
+                k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
         q = q.reshape(R, T, cfg.n_q_heads, cfg.head_dim)
         if kv is None:
             k = k.reshape(R, T, cfg.n_kv_heads, cfg.head_dim)
@@ -572,7 +583,7 @@ def _attn_in(x, lp, cfg, cdt, kv=None):
         else:
             k, v = kv
         own_kv = (k, v)
-        if cfg.qk_norm:
+        if cfg.qk_norm and not over_width:
             q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
             k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
         if "wg" in lp:
@@ -797,12 +808,13 @@ def _hc_write(st: _Stretch, x, y, h_post=None, h_res=None):
 
 
 def _kda_in(h, mp, cdt):
-    """A delta-rule mixer's projections of its normed input h `[R, T, D]`
+    """A delta-rule mixer's projections of its input h `[R, T, D]` (normed,
+    where the block norms on the way in)
     (scope `kda_proj`; the decay's and the gate's under `kda_gate`): q, k
-    `[R, T, Hk K]` and v `[R, T, H K]` before their convolutions, the
+    `[R, T, Hk K]` and v `[R, T, H V]` before their convolutions, the
     decay's input (`(h W_fa) W_fb` `[R, T, H K]`, or a column a head `h
     W_a` `[R, T, H]`), beta's `h W_b` `[R, T, H]`, the output gate's
-    (`(h W_ga) W_gb`, or the full-rank `h W_g`) `[R, T, H K]`."""
+    (`(h W_ga) W_gb`, or the full-rank `h W_g`) `[R, T, H V]`."""
     with jax.named_scope("kda_proj"):
         q, k, v, b = (h @ mp[n].astype(cdt) for n in ("wq", "wk", "wv", "w_b"))
         with jax.named_scope("kda_gate"):
@@ -818,8 +830,8 @@ def _kda_in(h, mp, cdt):
 
 
 def _kda_out(o, gate, mp, cfg, cdt):
-    """The rule's output o `[R, T, H, K]` -> the mixer's `[R, T, D]`: an
-    RMSNorm a head, the gate (`KDAConfig.gate_act`: a sigmoid, or silu),
+    """The rule's output o `[R, T, H, V]` -> the mixer's `[R, T, D]`: an
+    RMSNorm a head (V wide), the gate (`KDAConfig.gate_act`: a sigmoid, or silu),
     the output projection (scope `kda_out`)."""
     with jax.named_scope("kda_out"):
         o = rms_norm(o, mp["o_norm"], cfg.norm_eps).reshape(gate.shape)
@@ -842,11 +854,11 @@ def _before_mixer(st: _Stretch, w, xs, side):
     (x, *kept), mp = xs, w["mixer"]
     if kind.mixer == "kda":
         with jax.named_scope("kda_proj"):
-            h = _norm(x, w["ln1"], cfg)
+            h = _norm(x, w.get("ln1"), cfg)
         return _kda_in(h, mp, cdt)
     h, coefs = _hc_read(st, w.get("hc"), x)
     with jax.named_scope("attn_qkv"):
-        h = _norm(h, w["ln1"], cfg)
+        h = _norm(h, w.get("ln1"), cfg)
     if kind.latent:
         return _latent_in(h, mp, cfg, *side[:2], cdt, kind.rotary) + coefs
     q, k, v, gate, own_kv = _attn_in(h, mp, cfg, cdt, tuple(kept) or None)
@@ -864,7 +876,8 @@ def _mlp_joins(st: _Stretch, x, m, w, coefs=()):
     """The stream x plus the MLP's product m under the layer's
     `ln2_post`; several streams: m written to them by `coefs`."""
     if "ln2_post" in w:
-        m = _norm(m, w["ln2_post"], st.cfg)
+        with jax.named_scope("mlp_out_norm"):
+            m = _norm(m, w["ln2_post"], st.cfg)
     return _hc_write(st, x, m, *coefs)
 
 
@@ -883,7 +896,7 @@ def _mlp_part(st: _Stretch, w, xs, side=()):
         return (x,)
     h, coefs = _hc_read(st, w.get("hc"), x)
     with jax.named_scope("mlp"):
-        h = _norm(h, w["ln2"], cfg)
+        h = _norm(h, w.get("ln2"), cfg)
         if kind.mlp == "dense":
             mlp = jax.checkpoint(_mlp, static_argnums=(2, 3)) if st.mlp_ckpt else _mlp
             m = mlp(h, w["mlp"], cfg, cdt)
@@ -927,7 +940,8 @@ def _after_mixer(st: _Stretch, w, xs, side):
                       w.get("l0"))
     with jax.named_scope("kda_out" if kind.mixer == "kda" else "attn_out"):
         if "ln1_post" in w:
-            a = _norm(a, w["ln1_post"], cfg)
+            with jax.named_scope("mixer_out_norm"):
+                a = _norm(a, w["ln1_post"], cfg)
         x = _hc_write(st, x, a, *coefs)
     return _mlp_part(st, w, (x,))
 
@@ -1311,6 +1325,7 @@ def forward(
             lp, variant_index = xs
             x, aux_acc = carry
             kv, terms, step, w = None, {}, _mlp_part, {}
+            ln1 = {n: lp[n] for n in ("ln1",) if n in lp}  # none: output norms only
             if kind.mixer == "attention":
                 mp = lp["attn"]
                 cos, sin = own[0]
@@ -1321,7 +1336,7 @@ def forward(
                     (index.cos, index.sin) if kind.indexed else ())
                 q, k, v, *mid = run(
                     _before_mixer,
-                    {"ln1": lp["ln1"], "mixer": _mixer_weights(st, mp, True),
+                    {**ln1, "mixer": _mixer_weights(st, mp, True),
                      **({"hc": lp["hc1"]} if hyper else {})},
                     (x,) + (kept or ()), side)
                 step, w = _after_mixer, {"mixer": _mixer_weights(st, mp, False)}
@@ -1352,7 +1367,7 @@ def forward(
                 mp = lp["kda"]
                 *qkvfb, gate = run(
                     _before_mixer,
-                    {"ln1": lp["ln1"], "mixer": _mixer_weights(st, mp, True)}, (x,))
+                    {**ln1, "mixer": _mixer_weights(st, mp, True)}, (x,))
                 o = kda_mixer(*qkvfb, mp, cfg.kda, segment_ids, cdt, mesh=mesh)
                 step, w = _after_mixer, {"mixer": _mixer_weights(st, mp, False)}
                 got = (o, gate, x)
@@ -1362,20 +1377,20 @@ def forward(
                 from areal_tpu.ops.selective_scan import sscan_mixer
 
                 with jax.named_scope("sscan_in_proj"):
-                    h = _norm(x, lp["ln1"], cfg)
+                    h = _norm(x, lp.get("ln1"), cfg)
                 a, kv = sscan_mixer(h, lp["ssm"], cfg.ssm, segment_ids, cdt, mesh=mesh)
                 with jax.named_scope("sscan_out_proj"):
                     got = (x + a,)
             elif kind.mixer == "gmu":
                 with jax.named_scope("gmu"):
-                    h = _norm(x, lp["ln1"], cfg)
+                    h = _norm(x, lp.get("ln1"), cfg)
                     g = jax.nn.silu(h @ lp["gmu"]["w_in"].astype(cdt))
                     got = (x + (g * kept) @ lp["gmu"]["w_out"].astype(cdt),)
             elif kind.mixer == "ssm":
                 from areal_tpu.ops.ssm import ssm_mixer
 
                 with jax.named_scope("ssm_in_proj"):
-                    h = _norm(x, lp["ln1"], cfg)
+                    h = _norm(x, lp.get("ln1"), cfg)
                 a = ssm_mixer(h, lp["ssm"], cfg.ssm, segment_ids, cdt, cfg.norm_eps)
                 with jax.named_scope("ssm_out_proj"):
                     if "ln1_post" in lp:
@@ -1386,7 +1401,7 @@ def forward(
             if kind.mlp == "dense":
                 w.update({n: lp[n] for n in ("ln2", "ln2_post", "mlp") if n in lp})
             elif kind.mlp == "moe":  # the experts' own weights stay out of a stretch
-                w.update(ln2=lp["ln2"], mlp={n: lp["mlp"][n] for n in (
+                w.update({n: lp[n] for n in ("ln2",) if n in lp}, mlp={n: lp["mlp"][n] for n in (
                     "router", "expert_bias", "shared") if n in lp["mlp"]})
             if hyper and kind.mlp is not None:
                 w["hc"] = lp["hc2"]

@@ -1,12 +1,18 @@
-"""A delta-rule mixer over packed rows, in the two published forms: a
-decay for every channel (Kimi Delta Attention, arXiv:2510.26692) and one
+"""A delta-rule mixer over packed rows, in the three published forms: a
+decay for every channel (Kimi Delta Attention, arXiv:2510.26692), one
 decay a head under fewer key heads than value heads (Gated DeltaNet,
-arXiv:2412.06464, as Qwen3-Next runs it). `KDAConfig` says which.
+arXiv:2412.06464, as Qwen3-Next runs it), and one decay a head with values
+twice as wide as keys and a doubled beta (Gated DeltaNet at its authors'
+`expand_v` 2 with arXiv:2411.12537's negative eigenvalues, as Olmo-Hybrid
+runs it: the second column below with V != K and `b = 2 sigmoid`).
+`KDAConfig` says which.
 
-For a layer's normalised input `h` [R, T, D], with H value heads and Hk key
+For a layer's input `h` [R, T, D] (normalised, where the block norms on the
+way in), with H value heads and Hk key
 heads (Hk = H a decay a channel; Hk divides H a decay a head, value head j
-reading key head `j // (H / Hk)`) whose keys and values are K = V =
-`head_dim` wide, side by side:
+reading key head `j // (H / Hk)`) whose keys are K = `head_dim` wide and
+whose values are V = `value_dim` wide (V = K a decay a channel; any V a
+decay a head: the state a head is a rectangle `[K, V]`), side by side:
 
     a decay a channel (`decay="channel"`)        | a decay a head (`decay="head"`)
     q, k, v = silu(conv(h W_q)), .. W_k, .. W_v  | the same; q, k [T, Hk, K], v [T, H, K]
@@ -15,7 +21,7 @@ reading key head `j // (H / Hk)`) whose keys and values are K = V =
     q, k = q * rsqrt(sum q^2 + 1e-6), k likewise, a head;  q <- q * K^-0.5
     g = -exp(A_log)[H] softplus((h W_fa) W_fb    | g = -exp(A_log)[H] softplus(h W_a
         + dt_bias[H, K])    [T, H, K] float32    |     + dt_bias[H])        [T, H] float32
-    b = sigmoid(h W_b)                                                   [T, H]
+    b = sigmoid(h W_b), doubled under `neg_eigval` (a decay a head)     [T, H]
     S_t = (I - b_t k_t k_t^T) Diag(exp(g_t))     | S_t = exp(g_t) (I - b_t k_t k_t^T) S_{t-1}
           S_{t-1} + b_t k_t v_t^T                |       + b_t k_t v_t^T
       [K, V] a value head, float32, S = 0 before a sequence's first token
@@ -77,7 +83,23 @@ in); the walk carries `S` over the group's chunks (`states_scan`, a
 the group of its last token.
 
 **Which form runs where** (`use_kernel`: a TPU backend, one device, heads
-of whole lane tiles; decided from what the code sees, no argument):
+whose blocks are whole lane tiles; decided from what the code sees, no
+argument). **Keys that are no whole tile** (`key_lanes`: 96 of 128) go into
+the kernels widened to one with zero lanes, q's, k's and the convolutions'
+weights alike, in `kda_mixer` on the way into the taps: a zero lane moves no
+norm, no product and no state (the state stands `[V, 128]` there with 32
+columns of zeros), q's scale stays the keys' own `K^-0.5` (`RuleForm.key_dim`), and the
+zeros' transpose is a slice. They cross HBM: a third more of q's and k's
+bytes, a tenth of the rule's (counted in its roofline's bytes). By the
+compiler and the probe (`scripts/kda_layout_probe.py`; PERF.md section 6,
+PR 60): blocks of 96-wide heads as they stand are no whole tiles at any
+count of heads that divides 30 but all of them; the whole width a step (15
+pairs, keys at 96) compiles given 110 MB of VMEM and gives the same numbers
+to the bit, at 3.15 / 15.7 ms a call forward / forward + backward (a row of
+8,192, 70 % full, 30 heads of 96 x 192) and 13.6 / 57.1 s to build, where
+the widened keys, 6 heads a step, take 2.90 / 9.35 ms and 3.0 / 22.2 s; the
+plain form 6.37 / 23.3 ms. Values of 192 are a tile and a half: a step takes
+an even count of heads (`kda_fwd.step_heads`: 6 of 30).
 
 - forward, on the chip, both decays: one kernel over the whole row,
   `kda_fwd_rule` (`ops/pallas/kda_fwd.py`): the decay, `intra`'s formulas
@@ -102,8 +124,8 @@ of whole lane tiles; decided from what the code sees, no argument):
   `kda_taps_bwd` (`ops/pallas/kda_taps.py`: `ops/ssm.causal_conv`'s meaning
   with the mask inside, an array read once and written once, the weights'
   sums inside the backward), where the rule takes its kernels and a row is
-  whole blocks and the widths whole lane tiles (`taps_in_kernel`);
-  `causal_conv` after a `where` everywhere else.
+  whole blocks and the widths (the keys' as widened) whole lane tiles
+  (`taps_in_kernel`); `causal_conv` after a `where` everywhere else.
 - the CPU, a mesh of several devices, toy heads: `intra` + `states_scan`
   a group at a time forward, `states_scan` and `states_scan_bwd` under
   `jax.vjp` of `intra` backward: the plain form, and the tests' reference.
@@ -112,7 +134,7 @@ By the probe (`scripts/kda_probe.py`; PERF.md section 6, PR 55), a row of
 16,384 at 53 % fill, 32 value heads of 128, bf16, ms forward / forward +
 backward: the channel form 4.84 / 18.64 (4.85 / 49.8 with the backward a
 loop of XLA's over groups, 14.01 / 59.0 the plain form), the head form under
-16 key heads 3.45 / 11.40 (3.45 / 19.3; 8.94 / 25.2): two forms.
+16 key heads 3.45 / 11.40 (3.45 / 19.3; 8.94 / 25.2).
 
 **No exponential of a positive number.** A decay a channel: `exp(G_i - G_j)` is never split
 into `exp(G_i) exp(-G_j)` across a chunk (a decay of 0.2 a token over 64
@@ -121,7 +143,10 @@ sub-block is taken relative to the later sub-block's first position r
 (`exp(G_i - G_r)` and `exp(G_r - G_j)`, both at most 1) and is a matrix
 product; a diagonal sub-block is taken cell by cell. `A` is strictly
 lower triangular, so `(I + A)^-1 = (I - A)(I + A^2)(I + A^4)...`, `log2 C`
-squarings in float32 (backwards the inverse's own rule, two products). Decays, running sums, A, P and the inverse are
+squarings in float32 (backwards the inverse's own rule, two products); under
+a beta that reaches 2 (`RuleForm.doubling`) the same count of products
+doubles blocks on the diagonal and forms no power of A
+(`_inverse_unit_lower`). Decays, running sums, A, P and the inverse are
 float32; the other matrix products take operands in the compute dtype and
 accumulate in float32.
 
@@ -136,7 +161,7 @@ from __future__ import annotations
 
 import functools
 import math
-from typing import Any, Dict
+from typing import Any, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -146,6 +171,7 @@ from areal_tpu.models.config import KDAConfig
 from areal_tpu.ops.ssm import causal_conv
 
 SUB = 16  # a chunk's sub-blocks: decays inside one are taken cell by cell
+LANES = 128  # a lane tile: what a kernel's blocks are whole multiples of
 L2_EPS = 1e-6
 # The rule takes a call's rows this many cells at a time, `intra` and the
 # walk, forward and backward: what it holds at once is a group's, not a row's.
@@ -153,6 +179,20 @@ GROUP_CELLS = 1024
 
 
 _mm32 = functools.partial(jnp.matmul, precision=jax.lax.Precision.HIGHEST)
+
+
+class RuleForm(NamedTuple):
+    """What `delta_rule` is told of its operands beyond their shapes (static:
+    a program a form). `key_dim`: the keys' own width where q and k come
+    widened with zero lanes (`key_lanes`; the kernels' alone: q's scale is its
+    `^-0.5`), None: K as it stands. `doubling`: `(I + A)^-1` by doubling
+    blocks (`_inverse_unit_lower`), for a beta that reaches 2."""
+    key_dim: Optional[int] = None
+    doubling: bool = False
+
+    def q_scale(self, K: int) -> float:
+        """What unit q is scaled by, of keys that stand K wide."""
+        return (self.key_dim or K) ** -0.5
 
 
 def init_kda_params(kda: KDAConfig, hidden_dim: int, dense_fn, key, n_layers: int,
@@ -195,19 +235,40 @@ def init_kda_params(kda: KDAConfig, hidden_dim: int, dense_fn, key, n_layers: in
             jax.random.fold_in(ks[0], 1), (L, kda.n_heads), jnp.float32, 1.0, 16.0)
         ).astype(pdt),
         "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(pdt),
-        "o_norm": jnp.ones((L, kda.head_dim), pdt),
+        "o_norm": jnp.ones((L, kda.value_dim), pdt),
         "wo": dense_fn(ks[12], (L, d_in, D)),
     }
 
 
-@jax.custom_vjp
-def _inverse_unit_lower(a):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _inverse_unit_lower(a, doubling=False):
     """(I + a)^-1 for strictly lower triangular `a` [..., C, C] float32:
     (I - a)(I + a^2)(I + a^4)..., exact once the power reaches C. Its
     backward rule is the inverse's own, `da = -Y^T dY Y^T`: two products
-    where the squarings' transposes are twenty."""
+    where the squarings' transposes are twenty.
+
+    `doubling`: by blocks on the diagonal that double instead, as many
+    products (2 log2 C - 2): `X = I - a` is exact on blocks of 2, and with X
+    the inverse over blocks of s and `off` what `a` holds inside blocks of 2 s
+    and outside those of s, `X - X off X` is the inverse over blocks of 2 s
+    (`(off X)^2 = 0`: `off` maps a block's first half to its second). No power
+    of `a` is ever formed. The squarings hold A^32, whose entries reach 1e10
+    where a chunk's keys lie close together and beta nears 2 (they cancel in
+    the product, and float32 cannot follow: an error of 1e-5 on independent
+    keys, of 1e+3 on keys half alike, where doubling reads 2e-7 on both;
+    PERF.md section 6, PR 60); at beta <= 1 on a seeded model's keys they
+    hold, and the two forms that run so keep them (their programs as built)."""
     C = a.shape[-1]
     eye = jnp.eye(C, dtype=a.dtype)
+    if doubling:
+        at = jnp.arange(C)
+        same = lambda s: (at[:, None] // s) == (at[None, :] // s)
+        inv, s = eye - jnp.where(same(2), a, 0.0), 2
+        while s < C:
+            off = jnp.where(same(2 * s) & ~same(s), a, 0.0)
+            inv = inv - _mm32(_mm32(inv, off), inv)
+            s *= 2
+        return inv
     inv, power, n = eye - a, a, 2
     while n < C:
         power = _mm32(power, power)
@@ -216,12 +277,12 @@ def _inverse_unit_lower(a):
     return inv
 
 
-def _inverse_fwd(a):
-    y = _inverse_unit_lower(a)
+def _inverse_fwd(a, doubling):
+    y = _inverse_unit_lower(a, doubling)
     return y, y
 
 
-def _inverse_bwd(y, dy):
+def _inverse_bwd(doubling, y, dy):
     yt = jnp.swapaxes(y, -1, -2)
     return (-_mm32(_mm32(yt, dy), yt),)
 
@@ -259,18 +320,21 @@ def decay(f, A, dt_bias, seg):
     return jnp.where((seg > 0)[lift + (None,)], g, 0.0)
 
 
-def intra(q, k, v, g, b, seg, before, cdt):
+def intra(q, k, v, g, b, seg, before, cdt, doubling=False):
     """What of a chunk does not depend on the state it receives, by the
     decay's rank: `g` [N, C, H, K] a channel (`_intra_channel`) or [N, C,
     H] a head (`_intra_head`). Both return, heads first and masks folded
     in: Wm [N, H, C, K], U [N, H, C, V], Qg [N, H, C, K], Kd [N, H, C, K],
     Pm [N, H, C, C] in `cdt`, dec [N, H, K] float32 (a head's decay over
     the chunk stands K times there, a chunk's and not a cell's: the walk
-    then serves both)."""
-    return (_intra_head if g.ndim == 3 else _intra_channel)(q, k, v, g, b, seg, before, cdt)
+    then serves both). `doubling`: how a decay a head inverts `I + A`
+    (`_inverse_unit_lower`)."""
+    if g.ndim == 3:
+        return _intra_head(q, k, v, g, b, seg, before, cdt, doubling)
+    return _intra_channel(q, k, v, g, b, seg, before, cdt)
 
 
-def _intra_head(q, k, v, g, b, seg, before, cdt):
+def _intra_head(q, k, v, g, b, seg, before, cdt, doubling=False):
     """One decay a value head: q, k [N, C, Hk, K] (Hk key heads, value head
     j reads key head `j // (H / Hk)`: never repeated, the products a key
     head's and the `[C, C]` matrices spread over its value heads), v [N, C,
@@ -298,7 +362,7 @@ def _intra_head(q, k, v, g, b, seg, before, cdt):
                                        preferred_element_type=f32)) * D
     P = pairs(qf)
     A = jnp.where(jnp.eye(C, dtype=bool), 0.0, pairs(kf)) * b[..., None]  # row i by b_i
-    T = _inverse_unit_lower(A) * b[:, :, None, :]  # (I + A)^-1 Diag(b)
+    T = _inverse_unit_lower(A, doubling) * b[:, :, None, :]  # (I + A)^-1 Diag(b)
     Tc = T.astype(cdt)
     eG = jnp.exp(G)[..., None]  # [N, H, C, 1]
     kv_, qv_ = per_v(kf), per_v(qf)
@@ -471,7 +535,7 @@ class _Groups:
     def put(self, buf, a, i):
         return jax.lax.dynamic_update_slice_in_dim(buf, a.astype(buf.dtype), i * self.gs, axis=1)
 
-    def intra(self, cdt, i):
+    def intra(self, cdt, i, doubling=False):
         """`decay` and `intra` of group i's chunks, as a function of (q, k,
         v, f, b) `[R, gs, C, ...]`, A and dt_bias -> parts `[R, gs, H,
         ...]`: the float32 decays are a group's, never a row's."""
@@ -481,13 +545,14 @@ class _Groups:
 
         def fn(q, k, v, f, b, A, dt_bias):
             g = decay(flat(f), A, dt_bias, seg)
-            parts = intra(flat(q), flat(k), flat(v), g, flat(b), seg, before, cdt)
+            parts = intra(flat(q), flat(k), flat(v), g, flat(b), seg, before, cdt, doubling)
             return tuple(a.reshape((R, gs) + a.shape[1:]) for a in parts)
 
         return fn
 
 
-def delta_rule(q, k, v, f, b, A, dt_bias, segment_ids, chunk: int, kernel):
+def delta_rule(q, k, v, f, b, A, dt_bias, segment_ids, chunk: int, kernel,
+               form: RuleForm = RuleForm()):
     """The recurrence over packed rows, in chunks, of `unit(q) K^-0.5` and
     `unit(k)` under the decay `exp(A softplus(f + dt_bias))`: q, k [R, T,
     Hk, K] (Hk key heads that divide the H value heads), v [R, T, H, V], f
@@ -498,7 +563,7 @@ def delta_rule(q, k, v, f, b, A, dt_bias, segment_ids, chunk: int, kernel):
     a multiple of `chunk` -> o [R, T, H, V] in q's dtype. `kernel`: the
     forward by `ops/pallas/kda_fwd.py`'s one kernel and the backward by
     `ops/pallas/kda_bwd.py`'s (True; "interpret": in interpret mode, a
-    test's), or the plain form (False).
+    test's), or the plain form (False). `form`: `RuleForm`.
 
     Plain: a group of every row's chunks at a time (`_Groups`), up to the
     group of the fullest row's last token (a loop whose trip count is a
@@ -512,28 +577,31 @@ def delta_rule(q, k, v, f, b, A, dt_bias, segment_ids, chunk: int, kernel):
     jitted at module level (as `ops/band_loop.stretch`): the layers of a
     stack that call it at one shape share a trace and a lowering of each
     loop."""
-    return _rule_jit(q, k, v, f, b, A, dt_bias, segment_ids, chunk, kernel, GROUP_CELLS)
+    if form.key_dim is not None and not kernel:
+        raise ValueError("delta_rule: keys widened with zero lanes are the kernels' alone")
+    return _rule_jit(q, k, v, f, b, A, dt_bias, segment_ids, chunk, kernel, GROUP_CELLS, form)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10))
-def _rule(q, k, v, f, b, A, dt_bias, segment_ids, chunk, kernel, cells):
-    return _rule_fwd(q, k, v, f, b, A, dt_bias, segment_ids, chunk, kernel, cells)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9, 10, 11))
+def _rule(q, k, v, f, b, A, dt_bias, segment_ids, chunk, kernel, cells, form=RuleForm()):
+    return _rule_fwd(q, k, v, f, b, A, dt_bias, segment_ids, chunk, kernel, cells, form)[0]
 
 
-def _rule_fwd(q, k, v, f, b, A, dt_bias, segment_ids, chunk, kernel, cells):
+def _rule_fwd(q, k, v, f, b, A, dt_bias, segment_ids, chunk, kernel, cells, form):
     if not kernel:
-        return _rule_fwd_groups(q, k, v, f, b, A, dt_bias, segment_ids, chunk, cells)
+        return _rule_fwd_groups(q, k, v, f, b, A, dt_bias, segment_ids, chunk, cells,
+                                form.doubling)
     from areal_tpu.ops.pallas import kda_fwd
 
     R, T = segment_ids.shape
     res = (q, k, v, f, b, A, dt_bias, segment_ids)
     o, bounds = kda_fwd.rule_fwd(*res, _live_chunks(segment_ids, chunk), chunk,
                                  _group(R, T // chunk, chunk, cells),
-                                 interpret=kernel == "interpret")
+                                 interpret=kernel == "interpret", form=form)
     return o, res + (bounds,)
 
 
-def _rule_fwd_groups(q, k, v, f, b, A, dt_bias, segment_ids, chunk, cells):
+def _rule_fwd_groups(q, k, v, f, b, A, dt_bias, segment_ids, chunk, cells, doubling=False):
     """The plain form's forward, a group of chunks at a time: `intra`, then
     `states_scan` over the group's chunks."""
     R, T, _, K = q.shape
@@ -545,7 +613,7 @@ def _rule_fwd_groups(q, k, v, f, b, A, dt_bias, segment_ids, chunk, cells):
     def body(i, carry):
         S, O, bounds = carry
         with jax.named_scope("kda_intra"):
-            parts = gr.intra(cdt, i)(*(gr.take(a, i) for a in args), A, dt_bias)
+            parts = gr.intra(cdt, i, doubling)(*(gr.take(a, i) for a in args), A, dt_bias)
         with jax.named_scope("kda_states"):
             O_g, _, S_out = states_scan(*parts, S)
         return (S_out, gr.put(O, O_g, i),
@@ -558,13 +626,13 @@ def _rule_fwd_groups(q, k, v, f, b, A, dt_bias, segment_ids, chunk, cells):
     return o, res + (bounds,)
 
 
-def _rule_bwd(chunk, kernel, cells, res, do):
+def _rule_bwd(chunk, kernel, cells, form, res, do):
     if kernel:
         from areal_tpu.ops.pallas import kda_bwd
 
         segment_ids, bounds = res[-2:]
         return kda_bwd.rule_bwd(*res[:-1], _live_chunks(segment_ids, chunk), bounds, do,
-                                chunk, interpret=kernel == "interpret") + (None,)
+                                chunk, interpret=kernel == "interpret", form=form) + (None,)
     q, k, v, f, b, A, dt_bias, segment_ids, bounds = res
     R, T, _, K = q.shape
     H, V, cdt = v.shape[2], v.shape[-1], q.dtype
@@ -576,8 +644,8 @@ def _rule_bwd(chunk, kernel, cells, res, do):
         dS, grads, consts = carry
         i = gr.live - 1 - j
         with jax.named_scope("kda_intra"):
-            parts, pull = jax.vjp(gr.intra(cdt, i), *(gr.take(a, i) for a in args),
-                                  A, dt_bias)
+            parts, pull = jax.vjp(gr.intra(cdt, i, form.doubling),
+                                  *(gr.take(a, i) for a in args), A, dt_bias)
         with jax.named_scope("kda_states"):
             S_in = jax.lax.dynamic_index_in_dim(bounds, i, 0, keepdims=False)
             _, S_all, _ = states_scan(*parts, S_in)
@@ -594,43 +662,69 @@ def _rule_bwd(chunk, kernel, cells, res, do):
 
 
 _rule.defvjp(_rule_fwd, _rule_bwd)
-_rule_jit = jax.jit(_rule, static_argnums=(8, 9, 10))
+_rule_jit = jax.jit(_rule, static_argnums=(8, 9, 10, 11))
 
 
-def use_kernel(K: int, mesh) -> bool:
+def key_lanes(K: int) -> int:
+    """The width a key head of K stands at in the kernels: K where it is whole
+    lane tiles, the next whole tile where zeros up to it are at most a third
+    more (96 -> 128), else K as it is (a toy head: no kernel on the chip)."""
+    full = -(-K // LANES) * LANES
+    return full if 4 * K >= 3 * full else K
+
+
+def use_kernel(kda: KDAConfig, mesh) -> bool:
     """The kernels (the forward's one, the backward's one) on the
-    chip, one device's rows, heads of whole lane tiles; the plain form
+    chip, one device's rows, heads whose blocks a grid step are whole lane
+    tiles (`kda_fwd.step_heads`, the keys at `key_lanes`); the plain form
     elsewhere (the CPU, a toy head, a mesh of several devices: a kernel is
     opaque to the partitioner)."""
+    from areal_tpu.ops.pallas import kda_fwd
+
     return (jax.default_backend() == "tpu" and (mesh is None or mesh.size == 1)
-            and K % 128 == 0)
+            and kda_fwd.step_heads(kda.n_heads, kda.key_heads, key_lanes(kda.head_dim),
+                                   kda.value_dim)[1])
 
 
 def taps_in_kernel(kda: KDAConfig, T: int, kernel) -> bool:
     """Whether a row of T cells takes the taps' kernels (`ops/pallas/
     kda_taps.py`) on its way into the rule: where the rule takes its own
-    (`kernel`, as `delta_rule` reads it) and q's, k's and v's shapes fit."""
+    (`kernel`, as `delta_rule` reads it) and q's, k's and v's shapes fit, the
+    keys at the width the kernels take them (`key_lanes`)."""
     from areal_tpu.ops.pallas import kda_taps
 
-    return bool(kernel) and all(kda_taps.fits(T, h * kda.head_dim, kda.conv_kernel)
-                                for h in (kda.key_heads, kda.n_heads))
+    return bool(kernel) and all(kda_taps.fits(T, w, kda.conv_kernel) for w in (
+        kda.key_heads * key_lanes(kda.head_dim), kda.d_inner))
 
 
 def kda_mixer(q, k, v, f, b, kp, kda: KDAConfig, segment_ids, cdt, mesh=None,
               kernel=None):
-    """What of the mixer crosses tokens. q, k [R, T, Hk K], v [R, T, H K]
+    """What of the mixer crosses tokens. q, k [R, T, Hk K], v [R, T, H V]
     (the three projections), f the decay's input ([R, T, H K] the low-rank
     product, or [R, T, H] the projection's column where the decay is a
     head's), b [R, T, H] (beta's projection), `kp` the layer's `conv_*`,
-    `A_log`, `dt_bias` -> o [R, T, H, K] in `cdt`, before the head norm.
+    `A_log`, `dt_bias` -> o [R, T, H, V] in `cdt`, before the head norm.
     `kernel` as `delta_rule` takes it (None: `use_kernel`); the taps take
-    their kernels with the rule's, where the shapes fit (`taps_in_kernel`)."""
+    their kernels with the rule's, where the shapes fit (`taps_in_kernel`).
+    Keys that are no whole lane tile go into the kernels widened with zero
+    lanes (`key_lanes`; scope `kda_widen`), before the taps where those are
+    kernels too (their weights widened alike), after them where not."""
     R, T, _ = q.shape
-    H, Hk, K, C = kda.n_heads, kda.key_heads, kda.head_dim, kda.chunk_size
+    H, Hk, K, V, C = kda.n_heads, kda.key_heads, kda.head_dim, kda.value_dim, kda.chunk_size
     f32 = jnp.float32
     valid = segment_ids > 0
     if kernel is None:
-        kernel = use_kernel(K, mesh)
+        kernel = use_kernel(kda, mesh)
+    Kw = key_lanes(K) if kernel else K
+
+    def widen(a):
+        """Zero lanes after each key head's K of a's last axis, `Hk K` wide."""
+        if Kw == K:
+            return a
+        with jax.named_scope("kda_widen"):
+            heads = a.reshape(a.shape[:-1] + (Hk, K))
+            return jnp.pad(heads, ((0, 0),) * (heads.ndim - 1) + ((0, Kw - K),)).reshape(
+                a.shape[:-1] + (Hk * Kw,))
     # masked on the way in: whatever padding cells hold (the residual
     # stream carries them along) reaches neither a result nor a gradient
     masked = lambda *xs: tuple(jnp.where(valid[..., None], a, 0) for a in xs)
@@ -639,27 +733,34 @@ def kda_mixer(q, k, v, f, b, kp, kda: KDAConfig, segment_ids, cdt, mesh=None,
         if taps_in_kernel(kda, T, kernel):  # q's, k's and v's mask is the kernels' own
             from areal_tpu.ops.pallas import kda_taps
 
-            conv = lambda x, w: kda_taps.taps(x.astype(cdt), w.astype(cdt), None, segment_ids,
-                                              kernel == "interpret")
+            conv = lambda x, w, wide: kda_taps.taps(
+                wide(x.astype(cdt)), wide(w.astype(cdt)), None, segment_ids,
+                kernel == "interpret")
         else:
             q, k, v = masked(q, k, v)
-            conv = lambda x, w: causal_conv(x.astype(cdt), w.astype(cdt), None, segment_ids)
-        q, k, v = (conv(x, kp[n]).reshape(R, T, h, K)
-                   for x, n, h in ((q, "conv_q", Hk), (k, "conv_k", Hk), (v, "conv_v", H)))
+            conv = lambda x, w, wide: wide(
+                causal_conv(x.astype(cdt), w.astype(cdt), None, segment_ids))
+        q, k, v = (conv(x, kp[n], wide).reshape(R, T, h, w) for x, n, h, w, wide in (
+            (q, "conv_q", Hk, Kw, widen), (k, "conv_k", Hk, Kw, widen),
+            (v, "conv_v", H, V, lambda a: a)))
     with jax.named_scope("kda_gate"):
         A = -jnp.exp(kp["A_log"].astype(f32))  # [H]
         dt_bias = kp["dt_bias"].astype(f32)
         f = f.astype(cdt)
         if kda.decay == "channel":
             dt_bias, f = dt_bias.reshape(H, K), f.reshape(R, T, H, K)
-        beta = jnp.where(valid[..., None], jax.nn.sigmoid(b.astype(f32)), 0.0)
+        beta = jax.nn.sigmoid(b.astype(f32))
+        if kda.neg_eigval:  # in (0, 2): `I - beta k k^T` reflects past 1
+            beta = kda.beta_scale * beta
+        beta = jnp.where(valid[..., None], beta, 0.0)
     with jax.named_scope("kda_chunk"):
         pad = -T % C
         if pad:
             grow = lambda a: jnp.pad(a, ((0, 0), (0, pad)) + ((0, 0),) * (a.ndim - 2))
             q, k, v, f, beta, segment_ids = (
                 grow(a) for a in (q, k, v, f, beta, segment_ids))
-        o = delta_rule(q, k, v, f, beta, A, dt_bias, segment_ids, C, kernel)
+        o = delta_rule(q, k, v, f, beta, A, dt_bias, segment_ids, C, kernel,
+                       RuleForm(K if Kw != K else None, kda.neg_eigval))
     return o[:, :T]
 
 

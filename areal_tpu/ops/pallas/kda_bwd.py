@@ -78,11 +78,10 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from areal_tpu.ops.kda import L2_EPS, SUB
-from areal_tpu.ops.pallas import kda_fwd
+from areal_tpu.ops.kda import L2_EPS, SUB, RuleForm
 from areal_tpu.ops.pallas.kda_fwd import (
-    _BNN, _BNT, _BTN, _at, _chunk, _heads, _in_specs, _intra, _mm, _operands, _pairs,
-    _running_sum, _sides, _under)
+    _BNN, _BNT, _BTN, _at, _chunk, _in_specs, _intra, _mm, _operands, _pairs,
+    _running_sum, _sides, _under, step_heads)
 
 # A decay a channel: what a step holds at 8 heads of 128 x 128 in chunks of 64
 # (the blocks in and out twice, a group's states, the sub-blocks' scratch, the
@@ -160,7 +159,7 @@ def _channel_pairs_bwd(made, scratch, dkc_s, M, N, cdt):
 
 
 def _chunk_bwd(sides, S, dO, dS, scratch, dkc_s, seg_row, before, last, cdt, scalar=False,
-               shared_key=False):
+               shared_key=False, form=RuleForm()):
     """A chunk of a grid step's heads backwards. `sides`, `scratch`, the
     segment ids and the two sequences as `kda_fwd._intra` takes them; for
     each side S `[P, V, K]` in `cdt` (the state the chunk received), dO
@@ -171,7 +170,7 @@ def _chunk_bwd(sides, S, dO, dS, scratch, dkc_s, seg_row, before, last, cdt, sca
     head: their sums over cells are d dt_bias and dA), of b `[P, C, 1]`,
     all float32, and of the state the chunk received."""
     f32 = jnp.float32
-    m = _intra(sides, scratch, seg_row, before, last, cdt, scalar, shared_key)
+    m = _intra(sides, scratch, seg_row, before, last, cdt, scalar, shared_key, form=form)
     P, C, K = sides[0][1].shape
     half = lambda h, x: x[:, h * C:(h + 1) * C]  # side h's rows of a `[P, 2 C, .]` product
 
@@ -272,7 +271,7 @@ def _unit_bwd(x, xf, dxf, scale):
 
 def _kernel(n_live_ref, ends_ref, q_ref, k_ref, v_ref, f_ref, b_ref, seg_ref, a_ref,
             bias_ref, bounds_ref, do_ref, dq_ref, dk_ref, dv_ref, df_ref, db_ref, sums_ref,
-            st, sts, dst, G_s, k_s, q_s, dkc_s, *, gs, scalar):
+            st, sts, dst, G_s, k_s, q_s, dkc_s, *, gs, scalar, form):
     """A group of `gs` chunks a step of the third grid axis, from the row's
     last group to its first; the fourth axis walks the group twice: `gs`
     steps forwards from the state the group received (`bounds_ref`), which
@@ -316,7 +315,7 @@ def _kernel(n_live_ref, ends_ref, q_ref, k_ref, v_ref, f_ref, b_ref, seg_ref, a_
     def _():
         sides, shared_key, before, last = chunk_inputs()
         outs = _chunk(sides, [st[0], st[1]], scratch, seg_ref[...], before, last, cdt, scalar,
-                      shared_key, with_o=False)
+                      shared_key, with_o=False, form=form)
         for h, (_, s_t) in enumerate(outs):
             st[h] = s_t
 
@@ -328,7 +327,7 @@ def _kernel(n_live_ref, ends_ref, q_ref, k_ref, v_ref, f_ref, b_ref, seg_ref, a_
             sides, [of(h, lambda j: sts[at, j]) for h in range(2)],
             [of(h, lambda j: do_ref[:, j * V:(j + 1) * V]) for h in range(2)],
             [dst[0], dst[1]], scratch, dkc_s, seg_ref[...], before, last, cdt, scalar,
-            shared_key)
+            shared_key, form)
         C = seg_ref.shape[-1] // 2
         lying = (lax.broadcasted_iota(jnp.int32, (C, C), 0)
                  == lax.broadcasted_iota(jnp.int32, (C, C), 1))
@@ -365,7 +364,7 @@ def _kernel(n_live_ref, ends_ref, q_ref, k_ref, v_ref, f_ref, b_ref, seg_ref, a_
         keys = sorted(by_key)
         stack = lambda i: jnp.stack([by_key[kh][i] for kh in keys])
         cut = lambda ref: jnp.stack([ref[:, kh * K:(kh + 1) * K] for kh in keys])
-        dq = _unit_bwd(cut(q_ref), stack(0), stack(2), K ** -0.5)
+        dq = _unit_bwd(cut(q_ref), stack(0), stack(2), form.q_scale(K))
         dk = _unit_bwd(cut(k_ref), stack(1), stack(3), 1.0)
         for i, kh in enumerate(keys):
             dq_ref[:, kh * K:(kh + 1) * K] = dq[i].astype(dq_ref.dtype)
@@ -377,14 +376,15 @@ def _kernel(n_live_ref, ends_ref, q_ref, k_ref, v_ref, f_ref, b_ref, seg_ref, a_
             ref[...] = jnp.zeros_like(ref)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret", "form"))
 def rule_bwd(q, k, v, f, b, A, dt_bias, segment_ids, n_live, bounds, do, chunk: int,
-             interpret: bool = False):
+             interpret: bool = False, form: RuleForm = RuleForm()):
     """`ops/kda.delta_rule`'s transpose from its operands (as
     `kda_fwd.rule_fwd` takes them), the state every group of chunks
     received (`bounds` `[N // group, R, H, V, K]` float32, as `rule_fwd`
     wrote it) and `do` `[R, T, H, V]`: the cotangents of q, k, v, f (in
-    their dtypes), b, A and dt_bias (float32). Device op `kda_bwd_rule`.
+    their dtypes), b, A and dt_bias (float32); `form` as `rule_fwd` takes it.
+    Device op `kda_bwd_rule`.
     Jitted here, as the forward is."""
     R, T, Hk, K = q.shape
     H, V, C, N = v.shape[2], v.shape[-1], chunk, T // chunk
@@ -392,7 +392,7 @@ def rule_bwd(q, k, v, f, b, A, dt_bias, segment_ids, n_live, bounds, do, chunk: 
     G = bounds.shape[0]
     gs = N // G
     f32 = jnp.float32
-    hb = rep * _heads(Hk, max(1, kda_fwd.HEADS // rep))  # value heads a step, as the forward's
+    hb, _ = step_heads(H, Hk, K, V)  # value heads a step, as the forward's
 
     first = lambda gi: (G - 1 - gi) * gs  # groups from the row's last to its first
     reads = lambda r, gi, s, n, e: _at(
@@ -415,7 +415,7 @@ def rule_bwd(q, k, v, f, b, A, dt_bias, segment_ids, n_live, bounds, do, chunk: 
     half = -(-hb // 2)
     with jax.named_scope("kda_bwd_rule"):
         dq, dk, dv, df, db, sums = pl.pallas_call(
-            functools.partial(_kernel, gs=gs, scalar=scalar),
+            functools.partial(_kernel, gs=gs, scalar=scalar, form=form),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2, grid=(R, H // hb, G, 2 * gs),
                 in_specs=_in_specs(C, H, hb, rep, K, V, scalar, reads) + [

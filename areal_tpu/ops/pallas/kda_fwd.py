@@ -68,7 +68,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from areal_tpu.ops.kda import SUB, unit
+from areal_tpu.ops.kda import LANES, SUB, RuleForm, unit
 
 HEADS = 8  # heads a grid step: four pairs whose chains of products are independent
 
@@ -79,6 +79,22 @@ def _heads(H: int, most: int) -> int:
     while H % hb:
         hb -= 1
     return hb
+
+
+def step_heads(H: int, Hk: int, K: int, V: int):
+    """(value heads a grid step, whether its blocks are whole lane tiles):
+    whole key heads, at most `HEADS` value heads, the most whose blocks of q
+    and k (`heads / rep` keys of K) and of v and O (`heads` values of V) are
+    whole tiles of `LANES`; where no count's are (toy heads, which interpret
+    mode alone runs), the most. Heads of 128 x 128: 8, as ever. 30 heads of
+    128 (keys of 96 widened: `ops/kda.key_lanes`) x 192: 6, a block of v
+    nine tiles (5 would be seven and a half)."""
+    rep = H // Hk
+    most = _heads(Hk, max(1, HEADS // rep))
+    for hk in range(most, 0, -1):
+        if Hk % hk == 0 and hk * K % LANES == 0 and rep * hk * V % LANES == 0:
+            return rep * hk, True
+    return rep * most, False
 
 
 def _mm(a, b, dims):
@@ -160,7 +176,7 @@ def _channel_pairs(made, scratch, seen, cdt, with_q=True):
 
 
 def _intra(sides, scratch, seg_row, before, last, cdt, scalar=False, shared_key=False,
-           with_q=True):
+           with_q=True, form=RuleForm()):
     """What of a chunk does not depend on the state it receives
     (`ops/kda.intra`), for a grid step's heads as P pairs: a pair's `[C, C]`
     matrices stand side by side in `[C, 2 C]` (full lanes at C = 64; a
@@ -187,9 +203,18 @@ def _intra(sides, scratch, seg_row, before, last, cdt, scalar=False, shared_key=
     pair under one product `K K^T` and one `Q K^T` a side (one for both
     where the pair's heads read one key head, `shared_key`): no
     sub-blocks. Without `with_q` nothing of q is made (`Pc`, `Qg`: the
-    sweep that makes the chunks' states for the backward reads neither)."""
+    sweep that makes the chunks' states for the backward reads neither).
+
+    `form` (`ops/kda.RuleForm`): unit q is scaled by `K^-0.5` of the keys'
+    own width (keys widened to whole lane tiles with zeros, `ops/kda.key_lanes`,
+    keep their own: a zero lane moves no norm, no product and no state), and
+    under `doubling` the inverse is made by doubling blocks,
+    `ops/kda._inverse_unit_lower`'s other form: as many products and no power
+    of A (a beta that reaches 2). v and the state's other side are V wide,
+    whatever K is: nothing here takes the state for a square."""
     f32 = jnp.float32
     P, C, K = sides[0][1].shape
+    scale = form.q_scale(K)
     G_s, k_s, q_s = scratch
     m = types.SimpleNamespace()
     row = lax.broadcasted_iota(jnp.int32, (C, 2 * C), 0)
@@ -213,7 +238,7 @@ def _intra(sides, scratch, seg_row, before, last, cdt, scalar=False, shared_key=
         if scalar:  # one number a cell: over the lanes, in VMEM alone
             g = jnp.broadcast_to(g, (P, C, K))
         G_s[h] = G = _running_sum(g)  # <= 0, falling
-        qf = unit(q) * K ** -0.5 if with_q else None
+        qf = unit(q) * scale if with_q else None
         kf = unit(k)
         if not scalar:  # the sub-blocks read rows of them back
             k_s[h] = kf
@@ -256,11 +281,19 @@ def _intra(sides, scratch, seg_row, before, last, cdt, scalar=False, shared_key=
         [jnp.where(second, 0.0, x), jnp.where(second, x, 0.0)], axis=1)
     ident = eye.astype(f32)
     power = jnp.where(eye, 0.0, kk) * b_col
-    inv, n = ident - power, 2
-    while n < C:  # (I + a)^-1 = (I - a)(I + a^2)(I + a^4)...
-        power = _mm32(power, blocks(power))
-        inv = _mm32(inv, blocks(ident + power))
-        n *= 2
+    if form.doubling:  # exact on blocks of 2; X - X off X is the inverse over blocks twice as long
+        same = lambda s: (row // s) == (col // s)
+        inv, s = ident - jnp.where(same(2), power, 0.0), 2
+        while s < C:
+            off = jnp.where(same(2 * s) & ~same(s), power, 0.0)
+            inv = inv - _mm32(_mm32(inv, blocks(off)), blocks(inv))
+            s *= 2
+    else:
+        inv, n = ident - power, 2
+        while n < C:  # (I + a)^-1 = (I - a)(I + a^2)(I + a^4)...
+            power = _mm32(power, blocks(power))
+            inv = _mm32(inv, blocks(ident + power))
+            n *= 2
     m.inv = inv
     m.Tc = Tc = (inv * b_row).astype(cdt)
 
@@ -285,12 +318,12 @@ def _intra(sides, scratch, seg_row, before, last, cdt, scalar=False, shared_key=
 
 
 def _chunk(sides, states, scratch, seg_row, before, last, cdt, scalar=False,
-           shared_key=False, with_o=True):
+           shared_key=False, with_o=True, form=RuleForm()):
     """A chunk of a grid step's heads: `_intra`, then the walk's step from
     `states`, the sides' states `[P, V, K]` float32 -> for each side O
     `[P, C, V]` float32 (None without `with_o`) and the state handed on."""
     f32 = jnp.float32
-    m = _intra(sides, scratch, seg_row, before, last, cdt, scalar, shared_key, with_o)
+    m = _intra(sides, scratch, seg_row, before, last, cdt, scalar, shared_key, with_o, form)
     outs = []
     for h, s_t in enumerate(states):
         s = m.side(h)
@@ -335,7 +368,7 @@ def _sides(q_ref, k_ref, v_ref, f_ref, b_ref, a_ref, bias_ref, hg, hb, K, V, sca
 
 
 def _kernel(n_live_ref, ends_ref, q_ref, k_ref, v_ref, f_ref, b_ref, seg_ref, a_ref,
-            bias_ref, o_ref, bounds_ref, st, *scratch, group, scalar):
+            bias_ref, o_ref, bounds_ref, st, *scratch, group, scalar, form):
     r, hg, c = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     N = pl.num_programs(2)
     hb, V, K = bounds_ref.shape
@@ -358,7 +391,7 @@ def _kernel(n_live_ref, ends_ref, q_ref, k_ref, v_ref, f_ref, b_ref, seg_ref, a_
         sides, shared_key = _sides(q_ref, k_ref, v_ref, f_ref, b_ref, a_ref, bias_ref, hg, hb,
                                    K, V, scalar)
         outs = _chunk(sides, [st[0], st[1]], scratch, seg_ref[...], before, last,
-                      q_ref.dtype, scalar, shared_key)
+                      q_ref.dtype, scalar, shared_key, form=form)
         for h, (o, s_t) in enumerate(outs):
             st[h] = s_t
             for p, pair in enumerate(pairs):
@@ -407,9 +440,9 @@ def _operands(q, k, v, f, b, A, dt_bias, segment_ids, n_live, C):
             f_in, b, jnp.tile(seg[:, :, None, :], (1, 1, 1, 2)), *consts)
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "group", "interpret"))
+@functools.partial(jax.jit, static_argnames=("chunk", "group", "interpret", "form"))
 def rule_fwd(q, k, v, f, b, A, dt_bias, segment_ids, n_live, chunk: int, group: int,
-             interpret: bool = False):
+             interpret: bool = False, form: RuleForm = RuleForm()):
     """`ops/kda.delta_rule`'s forward: q, k `[R, T, Hk, K]` (Hk key heads;
     value head j reads key head `j // (H / Hk)` through the blocks' index:
     nothing is repeated), v `[R, T, H, V]`, f `[R, T, H, K]` with dt_bias
@@ -418,16 +451,18 @@ def rule_fwd(q, k, v, f, b, A, dt_bias, segment_ids, n_live, chunk: int, group: 
     T, H]` float32, A `[H]`, segment_ids `[R, T]`, T a multiple of `chunk`,
     n_live `[R]` the chunks of a row up to its last token's -> o `[R, T, H,
     V]` in q's dtype and the state every `group` chunks received, `[N //
-    group, R, H, V, K]` float32. Device op `kda_fwd_rule`. Jitted here: the
+    group, R, H, V, K]` float32. `form`: `ops/kda.RuleForm` (the keys' own
+    width where q and k come widened with zero lanes, and how `I + A` is
+    inverted). Device op `kda_fwd_rule`. Jitted here: the
     layers of a stack, their forward and remat's, trace the kernel's body
     once a shape and lower it once a program."""
     R, T, Hk, K = q.shape
     H, V, C, N = v.shape[2], v.shape[-1], chunk, T // chunk
     rep, scalar = H // Hk, f.ndim == 3
-    hb = rep * _heads(Hk, max(1, HEADS // rep))  # value heads a step: whole key heads
+    hb, _ = step_heads(H, Hk, K, V)  # value heads a step: whole key heads
     with jax.named_scope("kda_fwd_rule"):
         o, bounds = pl.pallas_call(
-            functools.partial(_kernel, group=group, scalar=scalar),
+            functools.partial(_kernel, group=group, scalar=scalar, form=form),
             grid_spec=pltpu.PrefetchScalarGridSpec(
                 num_scalar_prefetch=2, grid=(R, H // hb, N),
                 in_specs=_in_specs(C, H, hb, rep, K, V, scalar,

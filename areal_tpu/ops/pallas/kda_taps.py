@@ -88,6 +88,18 @@ def fits(T: int, C: int, K: int) -> bool:
     return T % ROWS == 0 and C % COLS == 0 and 2 <= K <= 7
 
 
+# What a step's blocks take of VMEM grows with the width: the backward's (x,
+# the cotangent and x's cotangent, each twice, 256 cells by all columns) are
+# 18.5 MB at 5,760 columns, past the 16 MiB a kernel gets unasked; up to 4,096
+# columns (12.6 MB) nothing is asked, and the programs there are as they were.
+WIDE_COLS = 4096
+WIDE_VMEM_BYTES = 32 * 2 ** 20
+
+
+def _vmem(C: int):
+    return None if C <= WIDE_COLS else WIDE_VMEM_BYTES
+
+
 def _codes(seg, K: int):
     """[R, T] -> [R, T, COLS] int32, a cell's number in every lane of a strip: bit 0 it
     holds a token; bit l (1..K-1) the cell l before it is of its sequence;
@@ -274,7 +286,7 @@ def taps_fwd(x, w, b, segment_ids, interpret: bool = False, rows: int = ROWS,
                 scratch_shapes=[pltpu.VMEM((K, rows, COLS), jnp.int32)]),
             out_shape=jax.ShapeDtypeStruct((R, T, C), x.dtype),
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
+                dimension_semantics=("parallel", "arbitrary"), vmem_limit_bytes=_vmem(C)),
             name="kda_taps_fwd", interpret=interpret,
         )(_live_blocks(segment_ids, rows), x, x, _codes(segment_ids, K), _weights(w, b))
 
@@ -301,7 +313,7 @@ def taps_bwd(x, w, b, segment_ids, dy, interpret: bool = False, rows: int = ROWS
             out_shape=[jax.ShapeDtypeStruct((R, T, C), x.dtype),
                        jax.ShapeDtypeStruct((R, 8, C), jnp.float32)],
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
+                dimension_semantics=("parallel", "arbitrary"), vmem_limit_bytes=_vmem(C)),
             name="kda_taps_bwd", interpret=interpret,
         )(_live_blocks(segment_ids, rows), x, x, _codes(segment_ids, K), _weights(w, b),
           dy.astype(x.dtype))

@@ -160,7 +160,7 @@ def test_the_kernels_cells_are_the_rules_where_the_kernels_run(kernel, monkeypat
     `train.kda_cells` either way."""
     _, eng = engine(0, row_len_multiple=256)
     seen = []
-    monkeypatch.setattr(kda, "use_kernel", lambda K, mesh: seen.append((K, mesh)) or kernel)
+    monkeypatch.setattr(kda, "use_kernel", lambda cfg, mesh: seen.append((cfg, mesh)) or kernel)
     seg = np.zeros((1, 256), np.int32)
     seg[0, :70] = 1
     tracing.start()
@@ -171,7 +171,7 @@ def test_the_kernels_cells_are_the_rules_where_the_kernels_run(kernel, monkeypat
     assert c["train.kda_cells"] == 768 and c["train.kda_chunks"] == 12
     assert c["train.kda_fwd_kernel_cells"] == (768 if kernel else 0)
     assert c["train.kda_bwd_kernel_cells"] == (768 if kernel else 0)
-    assert seen == [(eng.model_cfg.kda.head_dim, eng.mesh)]
+    assert seen == [(eng.model_cfg.kda, eng.mesh)]
 
 
 @pytest.mark.parametrize("kernel", [False, True], ids=["plain", "kernel"])
@@ -188,7 +188,7 @@ def test_the_taps_kernels_cells_are_the_convolutions_where_the_kernels_run(
     _, eng = engine(0, row_len_multiple=256)
     eng.counts = dataclasses.replace(eng.counts, cfg=dataclasses.replace(
         eng.model_cfg, kda=dataclasses.replace(eng.model_cfg.kda, head_dim=head_dim)))
-    monkeypatch.setattr(kda, "use_kernel", lambda K, mesh: kernel)
+    monkeypatch.setattr(kda, "use_kernel", lambda cfg, mesh: kernel)
     seg = np.zeros((1, 256), np.int32)
     seg[0, :70] = 1
     took = kernel and head_dim == 128

@@ -706,3 +706,62 @@ def test_an_accumulate_step_of_the_two_table_stack_compiles_at_16k(one_chip, mon
     # both tables are built once, [1, 16384, 64] float32 each, and stacked for the scan
     assert "rotary_table.sliding_attention" in text and "rotary_table.full_attention" in text
     assert compiled.memory_analysis().temp_size_in_bytes < 5.0e9
+
+
+def test_the_delta_rule_at_a_rectangle_compiles_at_the_published_widths(one_chip):
+    """`olmohybrid-d4-train-ppo-8k`'s delta rule over a row of 8,192: 30 heads
+    with a state of 96 x 192, one decay a head, bf16, `(I + A)^-1` by doubling
+    blocks. The keys stand widened to 128 lanes (`ops/kda.key_lanes`;
+    `RuleForm.key_dim` 96), a grid step takes 6 heads (a block of v nine lane
+    tiles): the same two custom calls as the square forms', no loop of XLA's.
+    As they stand at 96 the keys' blocks are no whole lane tiles at any count
+    of heads that divides 30 but all of them, and the compiler says so."""
+    from areal_tpu.ops import kda
+    from areal_tpu.ops.pallas import kda_fwd
+
+    t, h, k, v = 8192, 30, 96, 192
+    assert kda_fwd.step_heads(h, h, kda.key_lanes(k), v) == (6, True)
+    q = _shape((1, t, h, kda.key_lanes(k)), jnp.bfloat16, one_chip)
+    narrow = _shape((1, t, h, k), jnp.bfloat16, one_chip)
+    val = _shape((1, t, h, v), jnp.bfloat16, one_chip)
+    f = _shape((1, t, h), jnp.bfloat16, one_chip)
+    b = _shape((1, t, h), jnp.float32, one_chip)
+    a = _shape((h,), jnp.float32, one_chip)
+    seg = _shape((1, t), jnp.int32, one_chip)
+
+    def loss(form):
+        return lambda q, k_, v, f, b, a, bias, seg: kda.delta_rule(
+            q, k_, v, f, b, a, bias, seg, 64, True, form).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss(kda.RuleForm(k, True)), tuple(range(7)))).lower(
+        q, q, val, f, b, a, a, seg).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "kda_fwd_rule" in text and "kda_bwd_rule" in text and " while(" not in text
+    assert f"f32[1,{t},{h},{v}]" not in text  # no float32 copy of a row's values
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+    with pytest.raises(Exception, match="(?i)block|divisible|tile"):
+        jax.jit(jax.grad(loss(kda.RuleForm(None, True)), tuple(range(7)))).lower(
+            narrow, narrow, val, f, b, a, a, seg).compile()
+
+
+def test_an_accumulate_step_of_the_rectangle_stack_compiles_at_8k(one_chip, monkeypatch):
+    """A forward-backward micro-batch of `olmohybrid-d4-train-ppo-8k`'s model
+    at its one shape `(1, 8192)`, full remat, the masked loss head: a scan of
+    three Gated DeltaNet layers at 96 x 192 and the plain 30-head attention
+    layer after them, every layer's token-wise stretches (dense MLPs of 11,008
+    at hidden 3840 behind output norms) over the row's live bands, the rule's
+    two kernels and the taps' pair (v's 5,760 columns: the backward asks for
+    more VMEM than a kernel gets unasked, `kda_taps.WIDE_VMEM_BYTES`) beside
+    the pair kernels at 30 heads, group 1. The compiler's temporaries: 2.77 GB
+    beside 13.00 GB of weights, gradient sums and moments, of a chip that
+    gives a program 16.9."""
+    from areal_tpu.models.transformer import looping_layers
+
+    cfg, compiled = _accumulate_step(one_chip, monkeypatch, "olmo-hybrid-d4", 8192)
+    assert looping_layers(cfg, 1, 8192) == 4
+    text = compiled.as_text()
+    for name in ("splash_pairs_fwd", "splash_pairs_bwd", "kda_fwd_rule", "kda_bwd_rule"):
+        assert name in text, name
+    _taps_in_two_kernels(compiled)
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.0e9
