@@ -150,11 +150,18 @@ class TrainCounts:
         keeps the whole row (`models/transformer.looping_layers`) counts
         every cell, and so does every layer of a row the packer fills to
         the last band (`ops/band_loop.dead_bands`)."""
-        n = self.n_step_layers
-        loop = looping_layers(
-            self.cfg, *mbs.shape[1:], sharded=self.mesh.size > 1, mtp=self.mtp,
-        ) if band_loop.dead_bands(mbs.shape[2], self.row_len_multiple) else 0
+        n, loop = self.n_step_layers, self._looping(mbs)
         return [(loop * band_loop.band_cells_run(mb), (n - loop) * mb.size) for mb in mbs]
+
+    def _looping(self, mbs: np.ndarray, mixer: Optional[str] = None) -> int:
+        """The layers a step runs that walk the live bands of micro-batches
+        of this shape (`models/transformer.looping_layers`; of one `mixer`
+        where given): none where the packer fills every band
+        (`ops/band_loop.dead_bands`)."""
+        if not band_loop.dead_bands(mbs.shape[2], self.row_len_multiple):
+            return 0
+        return looping_layers(self.cfg, *mbs.shape[1:], sharded=self.mesh.size > 1,
+                              mtp=self.mtp, mixer=mixer)
 
     def _bands(self, stretch) -> Counts:
         """The cells the stretches run, a mean over the layers."""
@@ -257,13 +264,19 @@ class TrainCounts:
         the kernel's block of time), summed over those layers: the chunks
         it runs (the selective scan's also as positions the kernel
         walks), those that hold a token, those that hold a sequence start
-        after their first cell, sequence starts."""
+        after their first cell, sequence starts. A layer that walks its
+        row's live bands (`_looping`: the Mamba-2 form alone in its layer)
+        runs the chunks of those bands and no others."""
         n = self.cfg.n_ssm_layers
         if not n:
             return {}
         ssm = self.cfg.ssm
         chunks, live, mixed, resets = (
             n * c for c in ssm_ops.chunk_counts(mbs, ssm.chunk_size))
+        loop = self._looping(mbs, "ssm")
+        if loop:
+            chunks -= loop * (chunks // n - ssm_ops.chunk_counts(
+                mbs, ssm.chunk_size, band_loop._BAND)[0])
         walked = {"train.sscan_cells": chunks * ssm.chunk_size} if ssm.form == "mamba1" else {}
         return {**walked, "train.ssm_chunks": chunks, "train.ssm_chunks_live": live,
                 "train.ssm_chunks_mixed": mixed, "train.ssm_resets": resets}

@@ -946,27 +946,55 @@ def _after_mixer(st: _Stretch, w, xs, side):
     return _mlp_part(st, w, (x,))
 
 
-def _kind_loops(kind: LayerKind) -> bool:
-    """Whether a layer of `kind` runs as stretches over live bands where
-    the call's shape allows: a plain, latent or indexed attention mixer,
-    or a delta-rule mixer, with an MLP beside it (the delta-rule kinds by
-    memory first: a whole row of their stretches' float32 and MLP
-    temporaries is 1.8 GB of a 16,384-token step's 7.4, PERF.md section 6,
-    PR 50). By the probe (`scripts/band_loop_probe.py`;
-    PERF.md section 6, PR 45) such a layer takes 21-32 % off a half-empty
-    row and loses 0-3.5 % of a full one; a layer of one part (a
-    state-space mixer, experts or attention alone) and a scan or memory
-    unit with its MLP lose 7-14 % of a full row for 9-22 % off a
-    half-empty one, and differential attention's three kinds, each walked
-    outside a scan, are the most to trace in the stack with the least
-    set-up to spare. Where such a layer stands does not matter
+def _ssm_layer(st: _Stretch, w, xs, side, carry):
+    """A Mamba-2 layer alone, one band of it (`ops/band_loop.carried`): the
+    input norm, the mixer after what the cells before the band handed on
+    (`ops/ssm.band_mixer`: the state, the convolution's last cells),
+    `ln1_post` where the stack has one, the residual. `side`: the segment
+    ids. Returns the stream and what the band hands on."""
+    from areal_tpu.ops.ssm import band_mixer
+
+    cfg = st.cfg
+    (x,), (segment_ids,) = xs, side
+    with jax.named_scope("ssm_in_proj"):
+        h = _norm(x, w.get("ln1"), cfg)
+    a, carry = band_mixer(carry, h, w["mixer"], cfg.ssm, segment_ids, st.cdt, cfg.norm_eps)
+    with jax.named_scope("ssm_out_proj"):
+        if "ln1_post" in w:
+            a = _norm(a, w["ln1_post"], cfg)
+        return (x + a,), carry
+
+
+def _kind_loops(cfg: TransformerConfig, kind: LayerKind) -> bool:
+    """Whether a layer of `kind` runs over live bands where the call's
+    shape allows. As two stretches (`ops/band_loop.stretch`): a plain,
+    latent or indexed attention mixer, or a delta-rule mixer, with an MLP
+    beside it (the delta-rule kinds by memory first: a whole row of their
+    stretches' float32 and MLP temporaries is 1.8 GB of a 16,384-token
+    step's 7.4, PERF.md section 6, PR 50). By the probe
+    (`scripts/band_loop_probe.py`; PERF.md section 6, PR 45) such a layer
+    takes 21-32 % off a half-empty row and loses 0-3.5 % of a full one. As
+    one carried loop (`ops/band_loop.carried` around `_ssm_layer`; PR 61):
+    a Mamba-2 mixer alone in its layer, whose bands are whole chunks: the
+    layer's whole body runs a band at a time, so nothing `[T, in_proj_dim]`
+    is made, cut or put, and what crosses bands is the state, the taps'
+    last cells and a segment id (the probe's figures for it: PERF.md
+    section 6, PR 61; with a stretch before the mixer and one after, PR
+    45's form, the wide products still crossed HBM and the kind lost 7-14 %
+    of a full row for 9-22 % off a half-empty one). Experts or attention
+    alone in a layer, a selective scan or memory unit with its MLP, and
+    differential attention's three kinds (each walked outside a scan, the
+    most to trace in the stack with the least set-up to spare) keep the
+    whole row. Where such a layer stands does not matter
     (`looping_layers`): a leading dense layer and a prediction module's
     block, which run once outside a scan, loop as the scanned layers do
     since PR 48, but in a stack of several streams (`_lone_layer_loops`;
-    the set-up they cost is PERF.md section 6's, PR 48). The
-    figures of the kinds ruled out were taken with looping bodies that
-    went with this rule: the probe in the tree re-measures the kinds that
-    loop."""
+    the set-up they cost is PERF.md section 6's, PR 48). The figures of the
+    kinds still ruled out were taken with looping bodies that went with
+    PR 45's rule: the probe in the tree re-measures the kinds that loop."""
+    if kind.mixer == "ssm":
+        return (kind.mlp is None and cfg.ssm.form == "mamba2" and kind.reads is None
+                and not kind.keeps and band_loop._BAND % cfg.ssm.chunk_size == 0)
     return (kind.mixer in ("attention", "kda") and kind.mlp is not None
             and not kind.diff and kind.reads is None)
 
@@ -978,7 +1006,8 @@ def _scanned_layers(cfg: TransformerConfig) -> set:
 
 
 def looping_layers(cfg: TransformerConfig, n_rows: int, row_len: int,
-                   sharded: bool = False, mtp: bool = False) -> int:
+                   sharded: bool = False, mtp: bool = False,
+                   mixer: Optional[str] = None) -> int:
     """How many layers walk their live bands in a call of `n_rows` rows of
     `row_len` cells: none on a mesh that splits rows or the sequence
     (`sharded`), none for rows together or a row under two bands
@@ -986,12 +1015,14 @@ def looping_layers(cfg: TransformerConfig, n_rows: int, row_len: int,
     (`_kind_loops`), in a scan or alone (a leading dense layer), and with
     `mtp` the prediction module's block, a layer of the stack's last kind
     alone. One exception (`_lone_layer_loops`): a layer alone in a stack
-    of several residual streams keeps the whole row."""
+    of several residual streams keeps the whole row. `mixer`: count the
+    layers of that mixer alone ("ssm": those whose scan walks bands)."""
     if sharded or not band_loop.loops(n_rows, row_len):
         return 0
     kinds = cfg.kinds()
     scanned = _scanned_layers(cfg)
-    return sum(_kind_loops(k) and (i in scanned or _lone_layer_loops(cfg))
+    return sum(_kind_loops(cfg, k) and (i in scanned or _lone_layer_loops(cfg))
+               and mixer in (None, k.mixer)
                for i, k in enumerate(kinds + kinds[-1:] * mtp))
 
 
@@ -1117,7 +1148,8 @@ def forward(
     runs the token-wise stretches of every layer whose kind takes the loop
     (`looping_layers`: the stack's, in a scan or not, and the prediction
     module's block; a layer alone among several residual streams keeps the
-    whole row) over the bands its tokens reach. For the caller to
+    whole row) over the bands its tokens reach, and a Mamba-2 layer alone
+    whole, band after band, the state handed on (`_ssm_layer`). For the caller to
     say, who knows its packer: one whose ladder steps by a band or less at
     this row length
     (`base/datapack.ladder_step`) fills every band of such a row, and the
@@ -1287,8 +1319,10 @@ def forward(
         row's live bands (`ops/band_loop.stretch`) for a layer whose kind
         takes the loop (`_kind_loops`), in a scan or outside one
         (`_lone_layer_loops`), where the call walks bands at all
-        (`n_live`), a plain call otherwise."""
-        banded = (n_live is not None and _kind_loops(kind)
+        (`n_live`), a plain call otherwise. (A Mamba-2 mixer alone in its
+        layer, where `banded`, is no step of this kind: the whole layer is
+        one loop that hands a carry from band to band, `_ssm_layer`.)"""
+        banded = (n_live is not None and _kind_loops(cfg, kind)
                   and (scanned or _lone_layer_loops(cfg)))
         st = _Stretch(
             cfg, kind, cdt, route_in=banded, mlp_ckpt=remat_mode == "mlp" and not banded,
@@ -1386,6 +1420,16 @@ def forward(
                     h = _norm(x, lp.get("ln1"), cfg)
                     g = jax.nn.silu(h @ lp["gmu"]["w_in"].astype(cdt))
                     got = (x + (g * kept) @ lp["gmu"]["w_out"].astype(cdt),)
+            elif kind.mixer == "ssm" and banded:
+                # the whole layer a band at a time: one loop, and the state and
+                # the convolution's last cells handed from band to band
+                from areal_tpu.ops.ssm import start_carry
+
+                got = band_loop.carried(
+                    _ssm_layer, st,
+                    {**ln1, "mixer": lp["ssm"],
+                     **{n: lp[n] for n in ("ln1_post",) if n in lp}},
+                    (x,), (segment_ids,), start_carry(cfg.ssm, x.shape[0], cdt), n_live)
             elif kind.mixer == "ssm":
                 from areal_tpu.ops.ssm import ssm_mixer
 
@@ -1405,7 +1449,8 @@ def forward(
                     "router", "expert_bias", "shared") if n in lp["mlp"]})
             if hyper and kind.mlp is not None:
                 w["hc"] = lp["hc2"]
-            x, *rest = run(step, w, got)
+            # (a mixer alone in its layer has no step left: no loop over nothing)
+            x, *rest = got if kind.mlp is None and step is _mlp_part else run(step, w, got)
             coefs = ()
             if hyper and kind.mlp is not None:  # the MLP's read: H_res last, H_post before
                 *rest, h_res = rest

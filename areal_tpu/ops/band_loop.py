@@ -25,6 +25,13 @@ bought 0.25-0.46 % of `train_tokens_per_s` in the three cells that loop,
 every one of them under `remat` full, for a hundred lines that walked the
 band's jaxpr: PERF.md section 6, PR 45, after review.)
 
+`carried` is the same pair of loops for a function that is token-wise but
+for what one band hands the next (a state-space layer: the state, the
+convolution's last cells): the carry is threaded through the forward loop,
+each band's carry-in kept a band a slot, and the backward loop walks the
+bands last to first, handing the carry's cotangent backwards. It has its
+own bodies: `stretch`'s traced program is what eight cells' tests hold.
+
 One function, jitted at module level with the stretch's function and
 its static description as static arguments: the second layer of a kind,
 and the second and third program of a process (a train step is traced
@@ -114,6 +121,20 @@ def _row_zeros(avals, T):
     return tuple(jnp.zeros((1, T) + a.shape[2:], a.dtype) for a in avals)
 
 
+def _cotangents(avals, ds):
+    """A cotangent a leaf of `avals`: the next of `ds` for a floating leaf,
+    float0 zeros for an integer one (no one's to hand in)."""
+    ds = iter(ds)
+    return jax.tree_util.tree_map(
+        lambda a: next(ds) if jnp.issubdtype(a.dtype, jnp.inexact)
+        else np.zeros(a.shape, jax.dtypes.float0), avals)
+
+
+def _floating(tree):
+    """The floating leaves of `tree`, in order."""
+    return [a for a in jax.tree_util.tree_leaves(tree) if jnp.issubdtype(a.dtype, jnp.inexact)]
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
 def _stretch(fn, static, weights, xs, side, n_live):
     band = lambda i: tuple(fn(static, weights, _cut(xs, i), _cut(side, i)))
@@ -135,10 +156,7 @@ def _stretch_bwd(fn, static, res, d_outs):
         dws, dxs = carry
         s = _cut(side, i)
         outs, vjp = jax.vjp(lambda w, *x: tuple(fn(static, w, x, s)), weights, *_cut(xs, i))
-        d_band = iter(_cut(d_outs, i))
-        dw, *dx = vjp(tuple(
-            next(d_band) if jnp.issubdtype(a.dtype, jnp.inexact)
-            else np.zeros(a.shape, jax.dtypes.float0) for a in outs))
+        dw, *dx = vjp(_cotangents(outs, _cut(d_outs, i)))
         dws = jax.tree_util.tree_map(lambda a, g: a + g.astype(a.dtype), dws, dw)
         return dws, _put(dxs, dx, i)
 
@@ -159,6 +177,71 @@ _stretch.defvjp(_stretch_fwd, _stretch_bwd)
 _stretch_jit = jax.jit(_stretch, static_argnums=(0, 1))
 
 
+def _carried_loop(fn, static, weights, xs, side, carry, n_live, keep: bool):
+    """The forward loop of a carried stretch: (the results `[1, T, ...]`,
+    each band's carry-in a band a slot where `keep`, else None)."""
+    band = lambda i, c: fn(static, weights, _cut(xs, i), _cut(side, i), c)
+    T = xs[0].shape[1]
+    kept = jax.tree_util.tree_map(
+        lambda c: jnp.zeros((T // _BAND,) + c.shape, c.dtype), carry) if keep else None
+
+    def body(i, state):
+        outs, c, kept = state
+        if keep:
+            kept = jax.tree_util.tree_map(
+                lambda k, a: jax.lax.dynamic_update_index_in_dim(k, a, i, 0), kept, c)
+        band_outs, c = band(i, c)
+        return _put(outs, tuple(band_outs), i), c, kept
+
+    outs, _, kept = jax.lax.fori_loop(0, n_live, body, (
+        _row_zeros(jax.eval_shape(lambda: tuple(band(0, carry)[0])), T), carry, kept))
+    return outs, kept
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _carried(fn, static, weights, xs, side, carry, n_live):
+    return _carried_loop(fn, static, weights, xs, side, carry, n_live, False)[0]
+
+
+def _carried_fwd(fn, static, weights, xs, side, carry, n_live):
+    outs, kept = _carried_loop(fn, static, weights, xs, side, carry, n_live, True)
+    return outs, (weights, xs, side, kept, n_live)
+
+
+def _carried_bwd(fn, static, res, d_outs):
+    weights, xs, side, kept, n_live = res
+    d_outs = tuple(d for d in d_outs if d.dtype != jax.dtypes.float0)
+
+    def body(j, state):
+        dws, dxs, dc = state
+        i = n_live - 1 - j  # the last live band first: its carry's cotangent is zero
+        s = _cut(side, i)
+        c_in = jax.tree_util.tree_map(
+            lambda k: jax.lax.dynamic_index_in_dim(k, i, 0, keepdims=False), kept)
+
+        def band(w, c, *x):
+            outs, c = fn(static, w, x, s, c)
+            return tuple(outs), c
+
+        (outs, c_out), vjp = jax.vjp(band, weights, c_in, *_cut(xs, i))
+        dw, dc, *dx = vjp((_cotangents(outs, _cut(d_outs, i)), _cotangents(c_out, dc)))
+        dws = jax.tree_util.tree_map(lambda a, g: a + g.astype(a.dtype), dws, dw)
+        return dws, _put(dxs, dx, i), _floating(dc)
+
+    dws, dxs, _ = jax.lax.fori_loop(0, n_live, body, (
+        jax.tree_util.tree_map(lambda w: jnp.zeros(w.shape, jnp.float32), weights),
+        tuple(jnp.zeros_like(x) for x in xs),
+        [jnp.zeros(k.shape[1:], k.dtype) for k in _floating(kept)]))
+    dws, dxs = jax.lax.optimization_barrier(  # as `_stretch_bwd`: cast here and now
+        (jax.tree_util.tree_map(lambda a, w: a.astype(w.dtype), dws, weights), dxs))
+    return dws, dxs, None, None, None
+
+
+_carried.defvjp(_carried_fwd, _carried_bwd)
+
+_carried_jit = jax.jit(_carried, static_argnums=(0, 1))
+
+
 def stretch(fn: Callable, static: Any, weights: Any, xs: Sequence[jnp.ndarray],
             side: Sequence[jnp.ndarray], n_live) -> Tuple[jnp.ndarray, ...]:
     """`fn(static, weights, xs, side)` over the first `n_live` bands of the
@@ -171,3 +254,20 @@ def stretch(fn: Callable, static: Any, weights: Any, xs: Sequence[jnp.ndarray],
     band; summed in float32 across bands) and to `xs`; `side` gets none
     (positions' tables)."""
     return _stretch_jit(fn, static, weights, tuple(xs), tuple(side), n_live)
+
+
+def carried(fn: Callable, static: Any, weights: Any, xs: Sequence[jnp.ndarray],
+            side: Sequence[jnp.ndarray], carry: Any, n_live) -> Tuple[jnp.ndarray, ...]:
+    """`stretch` for a function that is token-wise but for what one band
+    hands the next: `fn(static, weights, xs, side, carry) -> (outs, carry)`
+    over the first `n_live` bands in order, `carry` (a pytree; integer leaves
+    ride along) what the first band receives. The result is `outs` as
+    `[1, T, ...]`, zero past the live bands; the last band's carry is no
+    one's. The forward rule keeps each band's carry-in a band a slot
+    (`[T / _BAND, ...]` a leaf) and nothing else of a band; the backward loop
+    walks the same bands last to first, makes a band's `jax.vjp` again from
+    its cells and its kept carry-in and hands the carry's cotangent
+    backwards. Gradients flow to `weights` (float32 sums across bands) and
+    `xs`; `side` and the first `carry` (a constant: zeros where a row
+    starts) get none."""
+    return _carried_jit(fn, static, weights, tuple(xs), tuple(side), carry, n_live)

@@ -30,12 +30,20 @@ that same sequence crossed the whole chunk; the state a chunk receives
 reaches, decayed by `exp(cum_i)`, only the cells of the sequence that
 crossed into it. Decays, softplus and running sums are float32; the
 matrix products run in the compute dtype and accumulate in float32.
+
+A row may also be run a band of whole chunks at a time (`band_mixer`
+under `ops/band_loop.carried`; `models/transformer._ssm_layer`): a band
+receives the state at the end of the cell before it, that cell's segment
+id, and the convolution's last `conv_kernel - 1` inputs with theirs
+(`MixerCarry`), and hands the same on. The lines are `ssm_mixer`'s own, so
+a band's first chunk receives exactly what the whole row's scan would
+have handed it; bands no token is in are not run.
 """
 
 from __future__ import annotations
 
 import math
-from typing import Any, Dict
+from typing import Any, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -74,13 +82,19 @@ def init_ssm_params(ssm: SSMConfig, hidden_dim: int, dense_fn, key, n_layers: in
     return sp
 
 
-def causal_conv(xbc, w, b, segment_ids):
+def causal_conv(xbc, w, b, segment_ids, tail=None):
     """xbc [R, T, C], w [K, C], b [C] or None, segment_ids [R, T] ->
     silu(b + sum_l w[K-1-l] xbc[t-l]) over the taps whose position lies
-    in t's own sequence; 0 at padding cells."""
+    in t's own sequence; 0 at padding cells. `tail`: the K - 1 cells
+    before the first, (xbc [R, K-1, C], segment ids [R, K-1]), where xbc is
+    a band of a longer row; without it nothing stands before cell 0."""
     K, T = w.shape[0], xbc.shape[1]
-    before = jnp.pad(xbc, ((0, 0), (K - 1, 0), (0, 0)))  # position t at t + K - 1
-    seg_before = jnp.pad(segment_ids, ((0, 0), (K - 1, 0)))
+    if tail is None:
+        before = jnp.pad(xbc, ((0, 0), (K - 1, 0), (0, 0)))  # position t at t + K - 1
+        seg_before = jnp.pad(segment_ids, ((0, 0), (K - 1, 0)))
+    else:
+        before = jnp.concatenate([tail[0], xbc], axis=1)
+        seg_before = jnp.concatenate([tail[1], segment_ids], axis=1)
     acc = xbc * w[K - 1]
     for lag in range(1, K):
         lo = K - 1 - lag
@@ -96,6 +110,16 @@ def chunked_scan(x, dt, A, B, C, segment_ids, chunk: int):
     padding), dt [R, T, H] float32 (0 at padding), A [H] float32 (< 0),
     B and C [R, T, G, N], segment_ids [R, T] -> y [R, T, H, P] float32,
     without the `D x` term."""
+    return scan_from(None, x, dt, A, B, C, segment_ids, chunk)[0]
+
+
+def scan_from(received, x, dt, A, B, C, segment_ids, chunk: int):
+    """`chunked_scan` over cells that follow others: `received` is (the
+    state at the end of the cell before the first, [R, H, P, N] float32,
+    that cell's segment id [R]), or None where nothing stands before cell 0
+    (zeros and 0). -> (y, the state at the end of the last chunk, which for
+    T a multiple of `chunk` is the last cell's: what the next cells
+    receive beside `segment_ids[:, -1]`)."""
     R, T, H, P = x.shape
     G, N = B.shape[2:]
     Q, K = chunk, H // G
@@ -141,7 +165,8 @@ def chunked_scan(x, dt, A, B, C, segment_ids, chunk: int):
             preferred_element_type=f32)  # [R, c, G, K, P, N]
         # the state received is handed on, decayed over the whole chunk,
         # if the sequence that crossed in is the one handed on
-        before = jnp.pad(last, ((0, 0), (1, 0)))[:, :-1]  # [R, c]
+        seg0 = jnp.zeros((R, 1), last.dtype) if received is None else received[1][:, None]
+        before = jnp.concatenate([seg0, last[:, :-1]], axis=1)  # [R, c]
         carry_on = jnp.where(((last == before) & (last > 0))[..., None, None],
                              jnp.exp(cum[..., -1]), 0.0)  # [R, c, G, K]
 
@@ -149,9 +174,10 @@ def chunked_scan(x, dt, A, B, C, segment_ids, chunk: int):
             keep, add = inp
             return keep[..., None, None] * state + add, state
 
-        _, received = jax.lax.scan(
-            step, jnp.zeros((R, G, K, P, N), f32),
-            (jnp.moveaxis(carry_on, 1, 0), jnp.moveaxis(local, 1, 0)))
+        state0 = (jnp.zeros((R, G, K, P, N), f32) if received is None
+                  else received[0].reshape(R, G, K, P, N))
+        state, received = jax.lax.scan(
+            step, state0, (jnp.moveaxis(carry_on, 1, 0), jnp.moveaxis(local, 1, 0)))
         received = jnp.moveaxis(received, 0, 1)  # [R, c, G, K, P, N]
         # ... and reaches the cells of the sequence that crossed in
         from_start = jnp.where(
@@ -160,7 +186,7 @@ def chunked_scan(x, dt, A, B, C, segment_ids, chunk: int):
         y = y + by_cell(from_start) * jnp.einsum(
             "rcign,rcgkpn->rcigkp", C, received.astype(cdt),
             preferred_element_type=f32)
-    return y.reshape(R, nc * Q, H, P)[:, :T]
+    return y.reshape(R, nc * Q, H, P)[:, :T], state.reshape(R, H, P, N)
 
 
 def ssm_mixer(h, sp, ssm: SSMConfig, segment_ids, cdt, eps: float,
@@ -168,6 +194,42 @@ def ssm_mixer(h, sp, ssm: SSMConfig, segment_ids, cdt, eps: float,
     """h [R, T, D] (the layer's input after its norm) -> the mixer's
     output [R, T, D]; `sp` one layer's parameters (`init_ssm_params`
     without the leading axis)."""
+    return _mixer(None, h, sp, ssm, segment_ids, cdt, eps,
+                  lambda received, *a: (scan(*a), None))[0]
+
+
+class MixerCarry(NamedTuple):
+    """What the cells of a row up to a band's first hand the band
+    (`band_mixer`): the state at the end of the last of them `[R, H, P, N]`
+    float32, and the convolution's reach back, the last `conv_kernel - 1`
+    cells' `xBC` before the taps `[R, K-1, conv_dim]` and segment ids
+    `[R, K-1]` (the last of which is the cell the state is of)."""
+    state: Any
+    xbc: Any
+    seg: Any
+
+
+def start_carry(ssm: SSMConfig, n_rows: int, cdt) -> MixerCarry:
+    """What a row's first band receives: no state, no cell before it."""
+    k = ssm.conv_kernel - 1
+    return MixerCarry(
+        jnp.zeros((n_rows, ssm.n_heads, ssm.head_dim, ssm.state_dim), jnp.float32),
+        jnp.zeros((n_rows, k, ssm.conv_dim), cdt), jnp.zeros((n_rows, k), jnp.int32))
+
+
+def band_mixer(carry: MixerCarry, h, sp, ssm: SSMConfig, segment_ids, cdt, eps: float):
+    """`ssm_mixer` over a band of a row, whole chunks of it: h `[R, band, D]`
+    and what the cells before it handed on -> (the mixer's output for the
+    band, what it hands on). `ssm_mixer`'s own lines in its dtypes: band
+    after band from `start_carry` is the whole row's arithmetic in the whole
+    row's order (`ops/band_loop.carried` walks a row so)."""
+    assert h.shape[1] % ssm.chunk_size == 0 and h.shape[1] >= ssm.conv_kernel - 1
+    return _mixer(carry, h, sp, ssm, segment_ids, cdt, eps, scan_from)
+
+
+def _mixer(carry, h, sp, ssm: SSMConfig, segment_ids, cdt, eps: float, scan):
+    """The mixer over h [R, T, D] after `carry` (None: from a row's first
+    cell) -> (its output, the carry after h's last cell)."""
     R, T, _ = h.shape
     H, P, G, N = ssm.n_heads, ssm.head_dim, ssm.n_groups, ssm.state_dim
     d_in = ssm.d_inner
@@ -178,17 +240,22 @@ def ssm_mixer(h, sp, ssm: SSMConfig, segment_ids, cdt, eps: float,
         zxbcdt = h @ sp["in_proj"].astype(cdt)
         z, xbc, dt = jnp.split(zxbcdt, [d_in, d_in + ssm.conv_dim], axis=-1)
     with jax.named_scope("ssm_taps"):
+        k = ssm.conv_kernel - 1
+        handed = (xbc[:, T - k:], segment_ids[:, T - k:])
         xbc = causal_conv(
             xbc, sp["conv_w"].astype(cdt),
-            sp["conv_b"].astype(cdt) if "conv_b" in sp else None, segment_ids)
+            sp["conv_b"].astype(cdt) if "conv_b" in sp else None, segment_ids,
+            None if carry is None else (carry.xbc, carry.seg))
         x, B, C = jnp.split(xbc, [d_in, d_in + G * N], axis=-1)
         x = x.reshape(R, T, H, P)
     with jax.named_scope("ssm_scan"):
         dt = jax.nn.softplus(dt.astype(f32) + sp["dt_bias"].astype(f32))
         dt = jnp.where(valid[..., None], dt, 0.0)
         A = -jnp.exp(sp["A_log"].astype(f32))
-        y = scan(x, dt, A, B.reshape(R, T, G, N), C.reshape(R, T, G, N),
-                 segment_ids, ssm.chunk_size)
+        y, state = scan(
+            None if carry is None else (carry.state, carry.seg[:, -1]),
+            x, dt, A, B.reshape(R, T, G, N), C.reshape(R, T, G, N),
+            segment_ids, ssm.chunk_size)
         y = y + sp["D"].astype(f32)[:, None] * x.astype(f32)
     with jax.named_scope("ssm_gate_norm"):
         y = y.reshape(R, T, d_in) * jax.nn.silu(z.astype(f32))
@@ -197,14 +264,16 @@ def ssm_mixer(h, sp, ssm: SSMConfig, segment_ids, cdt, eps: float,
         yg = yg * jax.lax.rsqrt(jnp.mean(yg * yg, axis=-1, keepdims=True) + eps)
         y = (yg.reshape(R, T, d_in) * sp["norm"].astype(f32)).astype(cdt)
     with jax.named_scope("ssm_out_proj"):
-        return y @ sp["out_proj"].astype(cdt)
+        return y @ sp["out_proj"].astype(cdt), MixerCarry(state, *handed)
 
 
-def chunk_counts(segment_ids: np.ndarray, chunk: int):
+def chunk_counts(segment_ids: np.ndarray, chunk: int, band: Optional[int] = None):
     """What `chunked_scan` does with packed rows, counted on the host by
     its own rule; `segment_ids` [..., T]: (chunks it runs, those that
     hold a token, those that hold a sequence start after their first
-    cell, sequence starts)."""
+    cell, sequence starts). `band`: the mixer walks each row's live bands
+    of that many cells (`band_mixer` under `ops/band_loop.carried`), and
+    runs the chunks of the bands up to the row's last token, no others."""
     seg = np.asarray(segment_ids)
     seg = seg.reshape(-1, seg.shape[-1])
     pad = -seg.shape[1] % chunk
@@ -212,7 +281,11 @@ def chunk_counts(segment_ids: np.ndarray, chunk: int):
     start = (seg != np.pad(seg, ((0, 0), (1, 0)))[:, :-1]) & (seg > 0)
     chunks = seg.reshape(seg.shape[0], -1, chunk)
     starts = start.reshape(chunks.shape)
-    return (int(chunks.shape[0] * chunks.shape[1]),
+    run = chunks.shape[0] * chunks.shape[1]
+    if band is not None:
+        tokens = np.where(seg > 0, np.arange(1, seg.shape[1] + 1), 0).max(-1)  # to the last
+        run = (-(-tokens // band) * (band // chunk)).sum()
+    return (int(run),
             int((chunks > 0).any(-1).sum()),
             int(starts[:, :, 1:].any(-1).sum()),
             int(start.sum()))

@@ -24,13 +24,18 @@ probed every kind alone) and PR 48's own (the leading dense layers of the
 trinity and joyai stacks and joyai's module block, with the seconds to
 trace and lower each; the xing4 stack's row was taken before
 `_lone_layer_loops` ruled that layer out, and reads the same in both modes
-now). **It cannot regenerate PR 45's rows for
-the kinds that were ruled out** (nemotron's `M`, `E`, `*`, phi4flash's
+now), and **PR 61's for nemotron's `M`** (a Mamba-2 mixer alone in its
+layer: `whole` against the carried loop, `ops/band_loop.carried` around
+`transformer._ssm_layer`, the layer's whole body a band at a time with the
+state and the taps' last cells handed on; PR 45's row for this kind was a
+stretch before and one after the mixer, a body that went with that PR's
+rule). **It cannot regenerate PR 45's rows for
+the other kinds that were ruled out** (nemotron's `E` and `*`, phi4flash's
 scan, memory unit and differential layers): those were taken one layer a
 kind by the code of that PR's calls 1 and 2, which had a looping body for
 every kind; the bodies of the kinds ruled out went with the rule, and a
-re-run needs them written again (a stretch before and after each mixer
-in `ops/ssm.py` and `ops/selective_scan.py`, and for a layer of one
+re-run needs them written again (a stretch before and after the mixer in
+`ops/selective_scan.py`, and for a layer of one
 part). A line a (config, kind, mode, tokens): forward
 and forward + backward milliseconds (the median of `--reps` calls, each
 ended by `block_until_ready`), the seconds jax spent tracing and lowering
@@ -41,6 +46,8 @@ the forward + backward program, the cells the stretches ran, and with
     python scripts/band_loop_probe.py --config trinity-mini-d5-e16 \\
         --row-len 16384 --tokens 8600,16384 --modes whole,loop@512,loop@1024,loop@2048 \\
         [--kinds 0,1] [--ops] [--out chiprun_out/x.jsonl]
+    python scripts/band_loop_probe.py --config nemotron-3-nano-d9-e8 --kinds 0 \\
+        --tokens 8600,16384 --modes whole,loop@1024 --ops   # the `M` kind, PR 61
 
 `--toy` walks it on the CPU at the configuration's rehearsal widths: the
 plumbing, no time.

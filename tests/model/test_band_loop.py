@@ -150,3 +150,105 @@ def test_weight_gradients_are_summed_in_float32():
         err = lambda g: float(jnp.max(jnp.abs(g[name].astype(jnp.float32) - g_f32[name])))
         assert g_loop[name].dtype == jnp.bfloat16
         assert err(g_loop) <= 2 * err(g_whole) + 1e-3, name
+
+
+def running(static, w, xs, side, carry):
+    """Token-wise but for what it is handed: a running sum of every cell
+    so far, the cell before (a tap), and how many cells came before (an
+    integer that rides along and numbers the cells)."""
+    CALLS.append(static)
+    (x,), (c,) = xs, side
+    total, last, seen = carry
+    h = jnp.tanh((x * c) @ w["gate"])
+    sums = total[:, None] + jnp.cumsum(h, axis=1)
+    before = jnp.concatenate([last, h[:, :-1]], axis=1)
+    y = x + (static * sums + before * jax.nn.silu(h)) @ w["down"]
+    number = seen[:, None] + jnp.arange(x.shape[1], dtype=jnp.int32)[None]
+    return (y, number), (sums[:, -1], h[:, -1:], seen + x.shape[1])
+
+
+def start():
+    return (jnp.zeros((1, F)), jnp.zeros((1, 1, F)), jnp.zeros((1,), jnp.int32))
+
+
+@pytest.mark.parametrize("tokens", [0, 5, 8, 9, 20, 40, 47, 48], ids=[
+    "no_live_band", "one_band_half_full", "one_band", "a_band_and_a_cell",
+    "last_band_half_full", "all_but_one_band", "all_but_one_cell", "every_band"])
+def test_a_carried_stretch_matches_the_whole_row(tokens):
+    """`carried`: band after band, each handed what the one before it left,
+    against the same function over the live cells at once from the same
+    start: values, the integer result, and every gradient (weights and x,
+    through the carry from the last live band back to the first)."""
+    w, x, c = operands()
+    w = {n: w[n] for n in ("gate", "down")}
+    seg = (jnp.arange(T) < tokens).astype(jnp.int32)[None]
+    n_live = band_loop.live_bands(seg)
+    cells = int(n_live) * BAND
+    cot = jax.random.normal(jax.random.PRNGKey(11), (1, T, D))
+
+    def loss(run):
+        def f(w, x):
+            y, number = run(w, x)
+            return jnp.sum(y[:, :cells] * cot[:, :cells]), (y, number)
+        return jax.jit(jax.value_and_grad(f, (0, 1), has_aux=True))
+
+    looped = loss(lambda w, x: band_loop.carried(running, 0.5, w, (x,), (c,), start(), n_live))
+    # the whole row: the live cells as one band of their own
+    plain = loss(lambda w, x: tuple(jnp.pad(a, ((0, 0), (0, T - cells)) + ((0, 0),) * (a.ndim - 2))
+                                    for a in running(0.5, w, (x[:, :cells],), (c[:, :cells],),
+                                                     start())[0]))
+    (l1, (y1, n1)), g1 = looped(w, x)
+    if not cells:
+        assert not np.asarray(y1).any() and not float(l1)
+        assert not any(np.asarray(g).any() for g in jax.tree_util.tree_leaves(g1))
+        return
+    (l0, (y0, n0)), g0 = plain(w, x)
+    np.testing.assert_allclose(l1, l0, rtol=1e-5)
+    np.testing.assert_allclose(y1, y0, rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(n1, n0)
+    assert not np.asarray(y1[:, cells:]).any(), "a dead band's results are zeros"
+    for a, b in zip(jax.tree_util.tree_leaves(g1), jax.tree_util.tree_leaves(g0)):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
+    assert not np.asarray(g1[1][:, cells:]).any(), "nothing flows into a dead band"
+
+
+def test_a_carried_stretch_is_traced_once_for_two_counts_and_a_second_layer():
+    """The trip count is a value of the run and the jit is the module's:
+    two counts, two layers of one kind and a second program run the
+    function's Python once each way (forward rule, backward rule)."""
+    w, x, c = operands()
+    w = {n: w[n] for n in ("gate", "down")}
+
+    def program(layers):
+        def loss(w, y, n):
+            for _ in range(layers):
+                y = band_loop.carried(running, 2.0, w, (y,), (c,), start(), n)[0]
+            return jnp.sum(y ** 2)
+
+        return jax.jit(jax.grad(loss, (0, 1)))
+
+    del CALLS[:]
+    one = program(1)
+    one(w, x, jnp.int32(2))
+    traced = len(CALLS)
+    one(w, x, jnp.int32(5))
+    program(2)(w, x, jnp.int32(3))
+    assert 0 < traced == len(CALLS)
+
+
+def test_a_carried_stretch_sums_weight_gradients_in_float32():
+    w, x, c = operands(3)
+    w = {n: w[n] for n in ("gate", "down")}
+    to16 = lambda t: jax.tree_util.tree_map(lambda a: a.astype(jnp.bfloat16), t)
+    carry16 = lambda: to16(start()[:2]) + start()[2:]
+    n = T // BAND - 1
+    total = lambda y: jnp.sum(y[:, :n * BAND].astype(jnp.float32))
+    g_loop = jax.grad(lambda w: total(band_loop.carried(
+        running, 1.0, w, (to16(x),), (to16(c),), carry16(), jnp.int32(n))[0]))(to16(w))
+    g_f32 = jax.grad(lambda w: total(running(1.0, w, (x,), (c,), start())[0][0]))(w)
+    g_whole = jax.grad(lambda w: total(
+        running(1.0, w, (to16(x),), (to16(c),), carry16())[0][0]))(to16(w))
+    for name in w:
+        err = lambda g: float(jnp.max(jnp.abs(g[name].astype(jnp.float32) - g_f32[name])))
+        assert g_loop[name].dtype == jnp.bfloat16
+        assert err(g_loop) <= 2 * err(g_whole) + 2e-2 * float(jnp.abs(g_f32[name]).max()), name

@@ -129,7 +129,7 @@ def test_a_gated_deltanet_layer_walks_its_live_bands(monkeypatch):
         p, cfg, ids, seg, pos, seqs, remat="full", bands=True))
     want, g_want = jax.jit(jax.value_and_grad(whole))(params)
     ran = small_bands(monkeypatch)
-    assert all(transformer._kind_loops(k) for k in cfg.kinds())
+    assert all(transformer._kind_loops(cfg, k) for k in cfg.kinds())
     assert looping_layers(cfg, 1, 96) == 4
     assert looping_layers(cfg, 1, 96, sharded=True) == 0
     got, g_got = jax.jit(jax.value_and_grad(whole))(params)

@@ -124,31 +124,164 @@ def test_the_stack_matches_the_reference_through_a_ppo_step(remat, monkeypatch):
 
 
 @pytest.mark.parametrize("remat", ["none", "full", "mlp"])
-def test_a_half_empty_row_of_one_part_layers_keeps_the_whole_row(remat, monkeypatch):
-    """One row alone, 37 tokens in 96 cells at bands of 16: a state-space
-    mixer, experts or attention alone in a layer run no loop
-    (`transformer._kind_loops`: a full row would lose more than a
-    half-empty one gains), and the logprobs, the PPO loss and every
-    gradient are the plain reference's, as two rows' are."""
+@pytest.mark.parametrize("pattern,loops,ran_want", [
+    ("MEMEM*EME", 4, ["_ssm_layer"] * 3), ("E*EE*", 0, [])],
+    ids=["M_walks_its_bands", "E_and_attention_keep_the_row"])
+def test_a_half_empty_row_of_one_part_layers(pattern, loops, ran_want, remat, monkeypatch):
+    """One row alone, 37 tokens in 96 cells at bands of 16. A Mamba-2
+    mixer alone in its layer walks the three live bands as one loop that
+    hands state and taps from band to band (`transformer._ssm_layer`
+    under `band_loop.carried`: the scan's one body and the two layers
+    that run alone); experts or attention alone in a layer run no loop
+    (`transformer._kind_loops`). Either way the logprobs, the PPO loss and
+    every gradient are the plain reference's, as two rows' are."""
     from areal_tpu.models.transformer import looping_layers
 
     monkeypatch.setattr(moe_lib, "_HELD_ROW_TILE", 8)
     ran = small_bands(monkeypatch)
-    cfg = _cfg()
-    assert looping_layers(cfg, 1, 96) == 0 and looping_layers(_afmoe_cfg(), 1, 96) == 5
+    hf = dict(HF, num_hidden_layers=len(pattern), hybrid_override_pattern=pattern)
+    cfg = _cfg(hf)
+    assert looping_layers(cfg, 1, 96) == loops and looping_layers(_afmoe_cfg(), 1, 96) == 5
+    assert looping_layers(cfg, 1, 96, mixer="ssm") == loops
+    assert looping_layers(cfg, 2, 96) == looping_layers(cfg, 1, 96, sharded=True) == 0
     params = _params(cfg)
     ids, seg, pos, seqs = _packed(rows=[[24, 13]], row_len=96)
     got = _program_logprobs(params, cfg, ids, seg, pos, seqs, remat=remat, bands=True)
-    assert not ran
-    want = _reference_logprobs(params, HF, seqs)
+    assert ran == ran_want
+    want = _reference_logprobs(params, hf, seqs)
     for g, w in zip(got, want):
         np.testing.assert_allclose(np.asarray(g), np.asarray(w), atol=2e-5)
     prog = lambda p: _ppo_loss(_program_logprobs(
         p, cfg, ids, seg, pos, seqs, remat=remat, bands=True))
-    plain = lambda p: _ppo_loss(_reference_logprobs(p, HF, seqs))
+    plain = lambda p: _ppo_loss(_reference_logprobs(p, hf, seqs))
     (l_prog, g_prog), (l_ref, g_ref) = (jax.value_and_grad(f)(params) for f in (prog, plain))
     np.testing.assert_allclose(float(l_prog), float(l_ref), atol=2e-5)
     _assert_trees_close(g_prog, _no_bias_grad(g_prog, g_ref), rtol=1e-4)
+
+
+def _band_layer_operands(lens, T=64, seed=0):
+    """A toy Mamba-2 layer's weights (`ln1` and the mixer, a bias on the
+    taps), a packed row of `lens` in `T` cells and a stream to run."""
+    cfg = _cfg()
+    lp = jax.tree_util.tree_map(lambda a: a[0], _params(cfg, seed)["stacks"]["ssm"])
+    lp["ssm"]["conv_b"] = 0.1 * jax.random.normal(
+        jax.random.PRNGKey(seed + 5), lp["ssm"]["conv_b"].shape)
+    seg = np.zeros((1, T), np.int32)
+    o = 0
+    for j, n in enumerate(lens):
+        seg[0, o:o + n] = j + 1
+        o += n
+    x = jax.random.normal(jax.random.PRNGKey(seed + 1), (1, T, cfg.hidden_dim))
+    return cfg, {"ln1": lp["ln1"], "mixer": lp["ssm"]}, jnp.asarray(seg), x
+
+
+def _layer_over_bands(cfg, w, x, seg):
+    """The layer as `forward` runs it where a row walks its bands."""
+    from areal_tpu.models import transformer as tf
+    from areal_tpu.ops import band_loop
+
+    st = tf._Stretch(cfg, LayerKind(mixer="ssm", mlp=None), jnp.float32)
+    return band_loop.carried(
+        tf._ssm_layer, st, w, (x,), (seg,), ssm_lib.start_carry(cfg.ssm, 1, jnp.float32),
+        band_loop.live_bands(seg))[0]
+
+
+def _layer_whole(cfg, w, x, seg):
+    from areal_tpu.models.transformer import _norm
+
+    return x + ssm_lib.ssm_mixer(_norm(x, w["ln1"], cfg), w["mixer"], cfg.ssm, seg,
+                                 jnp.float32, cfg.norm_eps)
+
+
+@pytest.mark.parametrize("lens", [
+    [20, 17], [16, 20], [14, 2, 20], [30, 10], [40, 24], [3], []], ids=[
+    "a_sequence_crosses_a_boundary", "one_starts_on_a_bands_first_cell",
+    "one_of_two_cells_before_a_boundary", "a_dead_last_band", "a_full_row",
+    "one_band_of_four", "no_token"])
+def test_a_mamba2_layer_over_its_live_bands_is_the_whole_rows(lens, monkeypatch):
+    """`transformer._ssm_layer` band after band (`band_loop.carried`: the
+    state, the taps' last three cells and their segment ids handed on)
+    against `ssm_mixer` over the whole row, float32: the result and every
+    gradient to 1e-5; a band no token is in reads zeros and sends its
+    cells no gradient."""
+    small_bands(monkeypatch)
+    cfg, w, seg, x = _band_layer_operands(lens)
+    assert cfg.ssm.chunk_size == 16 and cfg.ssm.conv_kernel == 4
+    cells = -(-sum(lens) // 16) * 16
+    cot = jax.random.normal(jax.random.PRNGKey(9), x.shape)
+    live = (jnp.arange(x.shape[1]) < cells)[None, :, None]  # a dead band is no one's to read
+    loss = lambda run: lambda w, x: jnp.sum(jnp.where(live, run(cfg, w, x, seg) * cot, 0))
+    with jax.default_matmul_precision("highest"):
+        got, want = _layer_over_bands(cfg, w, x, seg), _layer_whole(cfg, w, x, seg)
+        g_got = jax.grad(loss(_layer_over_bands), (0, 1))(w, x)
+        g_want = jax.grad(loss(_layer_whole), (0, 1))(w, x)
+    np.testing.assert_allclose(np.asarray(got[:, :cells]), np.asarray(want[:, :cells]),
+                               rtol=1e-5, atol=1e-5)
+    assert not np.asarray(got[:, cells:]).any(), "a dead band's results are zeros"
+    _assert_trees_close(g_got, g_want, rtol=1e-5)
+    assert not np.asarray(g_got[1][:, cells:]).any(), "nothing flows into a dead band"
+
+
+def test_a_packed_row_over_its_bands_is_each_of_its_sequences_alone(monkeypatch):
+    """State and taps start afresh at every sequence start wherever it
+    falls in a band, and what one band hands the next is its own
+    sequence's: each sequence of a row that walks its bands reads what it
+    reads alone in a row of its own, from its first cell."""
+    small_bands(monkeypatch)
+    lens = [14, 2, 20, 9, 3]  # starts at 0, 14, 16, 36 and 45; 48 cells of 64 live
+    cfg, w, seg, x = _band_layer_operands(lens, seed=3)
+    with jax.default_matmul_precision("highest"):
+        packed = _layer_over_bands(cfg, w, x, seg)
+        o = 0
+        for n in lens:
+            alone = _layer_whole(cfg, w, x[:, o:o + n], jnp.ones((1, n), jnp.int32))
+            np.testing.assert_allclose(np.asarray(packed[0, o:o + n]), np.asarray(alone[0]),
+                                       atol=2e-5)
+            o += n
+        x_nan = jnp.where((seg > 0)[..., None], x, jnp.nan)  # padding reaches nothing
+        np.testing.assert_array_equal(
+            np.asarray(_layer_over_bands(cfg, w, x_nan, seg)[0, :o]), np.asarray(packed[0, :o]))
+
+
+def test_the_host_counts_the_chunks_and_cells_the_devices_loop_runs(monkeypatch):
+    """`train.ssm_chunks` and `train.band_cells` (`engine/train_counts.py`)
+    against what the device ran, counted where it runs: a callback in the
+    scan, a call a band. One row of 128 cells with 40 tokens at bands of
+    16 and chunks of 8: the four `M` layers run three bands of two chunks
+    each, the other five layers the whole row; rows together, a mesh that
+    splits them and a packer that fills every band run every chunk."""
+    from areal_tpu.engine.train_counts import TrainCounts
+
+    small_bands(monkeypatch)
+    hf = dict(HF, chunk_size=8)
+    cfg = _cfg(hf)
+    params = _params(cfg)
+    ids, seg, pos, _ = _packed(rows=[[24, 16]], row_len=128)
+    ran, scan_from = [], ssm_lib.scan_from
+
+    def counted(received, x, *a):
+        jax.debug.callback(lambda: ran.append(x.shape[1] // cfg.ssm.chunk_size))
+        return scan_from(received, x, *a)
+
+    monkeypatch.setattr(ssm_lib, "scan_from", counted)
+    forward(params, cfg, ids, seg, pos, attn_impl="reference", bands=True).block_until_ready()
+    jax.effects_barrier()
+    assert ran == [2] * 12  # four layers, three live bands each
+
+    mesh = jax.sharding.Mesh(np.array(jax.devices()[:1]), ("data",))
+    counts = TrainCounts(cfg, mesh, "reference", 128, 1, False, cfg.n_moe_layers)
+    said = lambda c, s: c.of({"segment_ids": np.asarray(s)}, 40)[0]
+    c = said(counts, seg)
+    assert c["train.ssm_chunks"] == sum(ran) == 24
+    assert c["train.ssm_chunks_live"] == 4 * 5 and c["train.ssm_resets"] == 4 * 2
+    assert c["train.band_cells"] == (sum(ran) * 8 + 5 * 128) // 9
+    whole = 4 * 128 // 8
+    assert said(counts, np.concatenate([seg, seg]))["train.ssm_chunks"] == 2 * whole
+    filled = dataclasses.replace(counts, row_len_multiple=16)
+    assert said(filled, seg)["train.ssm_chunks"] == whole
+    assert said(filled, seg)["train.band_cells"] == 128
+    assert ssm_lib.chunk_counts(np.asarray(seg), 8, band=16)[0] == 6
+    assert ssm_lib.chunk_counts(np.zeros((1, 128), np.int32), 8, band=16)[0] == 0
 
 
 def test_a_dense_mlp_layer_and_a_unit_of_three_run_as_the_reference_does():

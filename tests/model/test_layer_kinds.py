@@ -153,11 +153,14 @@ def test_the_whole_stack_matches_the_reference(remat, monkeypatch):
 def small_bands(monkeypatch, band=16):
     """Bands of `band` cells, so that a toy row walks them, and a list
     that grows by one for every stretch a program runs through the
-    loop (`ops/band_loop.stretch`)."""
+    loop (`ops/band_loop.stretch`, `carried`)."""
     monkeypatch.setattr(band_loop, "_BAND", band)
     jax.clear_caches()  # a trace made at another band length is no one's to find
-    ran, stretch = [], band_loop.stretch
-    monkeypatch.setattr(band_loop, "stretch", lambda fn, *a: ran.append(fn.__name__) or stretch(fn, *a))
+    ran = []
+    for name in ("stretch", "carried"):
+        loop = getattr(band_loop, name)
+        monkeypatch.setattr(band_loop, name, lambda fn, *a, loop=loop: (
+            ran.append(fn.__name__) or loop(fn, *a)))
     return ran
 
 
