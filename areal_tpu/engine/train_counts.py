@@ -27,7 +27,12 @@ from areal_tpu.models.config import TransformerConfig
 from areal_tpu.models.transformer import looping_layers
 from areal_tpu.ops import band_loop, kda
 from areal_tpu.ops import ssm as ssm_ops
-from areal_tpu.ops.attention import attn_block_cells, attn_grid_steps, attn_run_len
+from areal_tpu.ops.attention import (
+    attn_block_cells,
+    attn_grid_steps,
+    attn_in_place,
+    attn_run_len,
+)
 from areal_tpu.ops.indexer import index_counts
 from areal_tpu.ops.loss import head_cells_run, two_on
 
@@ -114,8 +119,13 @@ class TrainCounts:
         kinds = self.cfg.kinds() + self.cfg.kinds()[-1:] * self.mtp
         self.n_step_layers = len(kinds)
         # a window an attention layer a step runs
-        self.windows: List[Optional[int]] = [
-            k.window for k in kinds if k.mixer == "attention"]
+        attention = [k for k in kinds if k.mixer == "attention"]
+        self.windows: List[Optional[int]] = [k.window for k in attention]
+        # q's and k's head size an attention layer a step runs; None where
+        # the kernels take an indexer's choice, a mask operand, head-first
+        self.qk_dims: List[Optional[int]] = [
+            None if k.indexed else self.cfg.mla.qk_dim if k.latent else self.cfg.head_dim
+            for k in attention]
 
     def of(self, rows: Rows, n_tokens: int,
            scored_fn: Optional[Callable[[Rows], np.ndarray]] = None,
@@ -191,8 +201,11 @@ class TrainCounts:
         alone would make them run; the grid steps the forward and
         backward kernels walk, those whose pair runs and those the
         backward walks, summed over rows, q heads and layers
-        (`attn_grid_steps`). The attributes: that length, and the widest
-        forward grid, kv steps a q block, of any layer and row."""
+        (`attn_grid_steps`); of `train.attn_cells`, a mean over the
+        attention layers, those whose kernels read q, k and v where the
+        projections left them (`attn_in_place`). The attributes: that
+        length, the widest forward grid, kv steps a q block, of any layer
+        and row, and the attention layers that read in place."""
         cfg = self.cfg
         n, rows, row_len = mbs.shape
         shape = dict(
@@ -209,9 +222,13 @@ class TrainCounts:
                for w in set(self.windows)}
         total = lambda i: int(sum(mb[i] for w in self.windows for mb in per[w]))
         active = total(0)
+        in_place = sum(hd is not None and attn_in_place(t=row_len, r=rows, hd=hd, **shape)
+                       for hd in self.qk_dims)
         window = int(sum(mb[0] for w in self.windows if w is not None for mb in per[w]))
         return {
             "train.attn_cells": n * rows * run_len,
+            "train.attn_cells_in_place":
+                n * rows * run_len * in_place // max(len(self.qk_dims), 1),
             "train.attn_active_cells": active,
             "train.attn_window_cells": window,
             "train.attn_full_cells": active - window,
@@ -220,7 +237,8 @@ class TrainCounts:
             "train.attn_live_steps": cfg.n_q_heads * total(3),
             "train.attn_bwd_steps": cfg.n_q_heads * total(5),
         }, dict(attn_row_len=run_len,
-                width=max(mb[4] for counts in per.values() for mb in counts))
+                width=max(mb[4] for counts in per.values() for mb in counts),
+                in_place=in_place)
 
     # -- the loss head ---------------------------------------------------
 

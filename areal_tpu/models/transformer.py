@@ -712,23 +712,31 @@ def _latent_in(x, lp, cfg, cos, sin, cdt, rotary=True):
 
 
 def _latent_out(out, lp, cdt):
-    """The attention call's output `[R, T, H, v_dim]` through latent
-    attention's output projection (scope `attn_out`)."""
+    """The attention call's output, sequence-minor as `_latent_core` hands
+    it over, `[R, H, v_dim, T]`, through latent attention's output
+    projection (scope `attn_out`) -> `[R, T, D]`."""
+    _, H, V, _ = out.shape
     with jax.named_scope("attn_out"):
-        return out.reshape(out.shape[:2] + (-1,)) @ lp["wo"].astype(cdt)
+        return jnp.einsum("rhvt,hvd->rtd", out, lp["wo"].astype(cdt).reshape(H, V, -1))
 
 
 def _latent_core(q, k, v, cfg, segment_ids, positions, attn_impl, mesh):
     """Latent attention's kernel call (scope `attn_kernel`): every head
     has its own k and v; the softmax scale is `MLAConfig.softmax_scale`
-    (the head size's times YaRN's `mscale^2` where the table is scaled)."""
+    (the head size's times YaRN's `mscale^2` where the table is scaled).
+    Returns the output sequence-minor, `[R, H, v_dim, T]`: where the pair
+    kernels read and write in place (`ops/attention._rows_in_place`: a
+    long row alone) that is the kernel's own output and this
+    transposition undoes the call's, so that the output reaches the
+    output projection's stretch, and its cotangent the kernel, as they
+    lie."""
     from areal_tpu.ops.attention import resolve_attn_impl
 
     H = cfg.n_q_heads
     impl = resolve_attn_impl(attn_impl, q.shape[1], H, H, mesh=mesh, r=q.shape[0])
     with jax.named_scope("attn_kernel"):
         return _attention_kernel(q, k, v, segment_ids, positions, impl, cfg, mesh, None,
-                                 cfg.mla.softmax_scale)
+                                 cfg.mla.softmax_scale).transpose(0, 2, 3, 1)
 
 
 class _Stretch(NamedTuple):
@@ -1350,10 +1358,10 @@ def forward(
             # have the same mixer (PERF.md section 6, PR 48: 1.5 s of the
             # joyai stack's tracing on the chip's host).
             st_in = st._replace(kind=dataclasses.replace(kind, mlp=None))
-            run = lambda fn, w, xs, side=(): band_loop.stretch(
-                fn, st_in if fn is _before_mixer else st, w, xs, side, n_live)
+            run = lambda fn, w, xs, side=(), minor=(): band_loop.stretch(
+                fn, st_in if fn is _before_mixer else st, w, xs, side, n_live, minor)
         else:
-            run = lambda fn, w, xs, side=(): fn(st, w, xs, side)
+            run = lambda fn, w, xs, side=(), minor=(): fn(st, w, xs, side)
 
         def body(carry, xs, kept=None):
             lp, variant_index = xs
@@ -1450,7 +1458,9 @@ def forward(
             if hyper and kind.mlp is not None:
                 w["hc"] = lp["hc2"]
             # (a mixer alone in its layer has no step left: no loop over nothing)
-            x, *rest = got if kind.mlp is None and step is _mlp_part else run(step, w, got)
+            # (latent attention's output comes sequence-minor: `_latent_core`)
+            x, *rest = got if kind.mlp is None and step is _mlp_part else run(
+                step, w, got, minor=(0,) if kind.latent else ())
             coefs = ()
             if hyper and kind.mlp is not None:  # the MLP's read: H_res last, H_post before
                 *rest, h_res = rest

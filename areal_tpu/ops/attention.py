@@ -415,6 +415,21 @@ def _rows_skip(rows: int, t_run: int) -> bool:
     return rows == 1 and t_run >= _SKIP_MIN_LEN
 
 
+def _rows_in_place(skip: bool, hd: int) -> bool:
+    """Whether the pair kernels of a row that walks its own pairs
+    (`skip`: `_rows_skip`) read q, k and v, and write the output and the
+    three gradients, sequence-minor, `[H, hd, T]`: where q's and k's head
+    size is more than a lane tile and no whole number of them (192: latent
+    attention). XLA's products write such operands with the sequence in
+    lanes, and a head-first kernel has them relaid first, a real transpose
+    of q, k, v and the output a layer forward, and of `do`, dq, dk and dv
+    backward (PERF.md section 6, PR 62); the kernels' step is the same
+    products either way (`ops/pallas/splash_pairs.py`). A head of whole
+    lane tiles keeps the head-first kernels, and so does one under a
+    tile (64: not priced)."""
+    return skip and hd > LANES and hd % LANES != 0
+
+
 def splash_packed_attention(
     q: jnp.ndarray,  # [T, Hq, hd], or packed rows: [R, T, Hq, hd]
     k: jnp.ndarray,  # [T, Hkv, hd]
@@ -425,6 +440,7 @@ def splash_packed_attention(
     interpret: Optional[bool] = None,
     _run_shape: Optional[tuple] = None,
     window: Optional[int] = None,
+    _in_place: Optional[bool] = None,
 ) -> jnp.ndarray:
     """Packed GQA attention on jax's splash kernel (one MQA call per kv
     head, GQA group as the q-head axis). Pad tokens (segment 0) attend
@@ -434,7 +450,8 @@ def splash_packed_attention(
     The kernel runs at `splash_run_shape(T)`: the row is padded with
     zeros in segment 0 up to a length whose blocks are large, and the
     first T positions come back. `_run_shape` overrides that choice
-    (tests, scripts/splash_shape_sweep.py).
+    (tests, scripts/splash_shape_sweep.py), `_in_place` that of
+    `_rows_in_place` for a row that walks its own pairs.
 
     Of the block pairs the row's causal or window mask leaves, the
     kernels of a long row alone (`_rows_skip`) walk those that
@@ -452,10 +469,11 @@ def splash_packed_attention(
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     run_shape = _run_shape or splash_run_shape(t)
+    skip = q.ndim == 4 and _rows_skip(q.shape[0], run_shape[0])
     one = functools.partial(
         _splash_row, run_shape=run_shape, interpret=bool(interpret),
-        window=_row_window(t, window),
-        skip=q.ndim == 4 and _rows_skip(q.shape[0], run_shape[0]),
+        window=_row_window(t, window), skip=skip,
+        in_place=_rows_in_place(skip, hd) if _in_place is None else skip and _in_place,
         scale=float(softmax_scale) if softmax_scale is not None else hd ** -0.5)
     rows = (q, k, v, segment_ids)
     if q.ndim == 3:
@@ -466,7 +484,7 @@ def splash_packed_attention(
 
 
 def _splash_row(q, k, v, segment_ids, *, run_shape, interpret, window, skip,
-                scale):
+                in_place, scale):
     """One packed row of `splash_packed_attention`, its choices made."""
     from jax.experimental.pallas.ops.tpu.splash_attention import (
         splash_attention_kernel as sk,
@@ -486,6 +504,16 @@ def _splash_row(q, k, v, segment_ids, *, run_shape, interpret, window, skip,
         pad = ((0, t_run - t), (0, 0), (0, 0))
         q, k, v = jnp.pad(q, pad), jnp.pad(k, pad), jnp.pad(v, pad)
         segment_ids = jnp.pad(segment_ids, (0, t_run - t))
+    if in_place:
+        from areal_tpu.ops.pallas.splash_pairs import Blocks, pair_attention
+
+        # [T', H, hd] -> [H, hd, T'], which is how XLA's products leave
+        # them: these transposes, and the output's, move nothing
+        out = pair_attention(
+            *(a.transpose(1, 2, 0) for a in (q, k, v)), segment_ids,
+            _pair_lists(segment_ids, bq, bkv, window), Blocks(bq, bkv, bkvc), window,
+            SPLASH_RESIDUAL_NAME, interpret, True)
+        return out.transpose(2, 0, 1)[:t].astype(q.dtype)
     # [T', H, hd] -> [H, T', hd]; the static kernel takes q as
     # [Hkv, group, T', hd], an MQA problem a kv head
     qh = q.transpose(1, 0, 2)
@@ -664,6 +692,12 @@ def attn_run_len(
     return splash_run_shape(t)[0] if ran == "splash" else t
 
 
+def _host_rows_skip(r: int, t_run: int, mesh) -> bool:
+    """`_rows_skip` of the `r` rows of one micro-batch as the host sees
+    them: a sharded mesh runs each shard's rows in a call of their own."""
+    return _rows_skip(r // (cp_axes(mesh)[0] if mesh is not None else 1), t_run)
+
+
 def _host_block_pairs(impl, segment_ids, hq, hkv, mesh, window):
     """What the host's counters count of the packed rows `segment_ids`
     [R, T] of one micro-batch: None where an implementation without
@@ -677,12 +711,23 @@ def _host_block_pairs(impl, segment_ids, hq, hkv, mesh, window):
     shape = splash_run_shape(t)
     t_run, bq, bkv, _ = shape
     static = _static_block_pairs(t_run, bq, bkv, _row_window(t, window))
-    # A sharded mesh runs each shard's rows in a call of their own.
-    if not _rows_skip(r // (cp_axes(mesh)[0] if mesh is not None else 1), t_run):
+    if not _host_rows_skip(r, t_run, mesh):
         return shape, static, None
     live = live_block_pairs(
         np.pad(segment_ids, ((0, 0), (0, t_run - t))), bq, bkv)
     return shape, static, live & static
+
+
+def attn_in_place(
+    impl: str, r: int, t: int, hq: int, hkv: int, hd: int, mesh=None,
+) -> bool:
+    """Whether the attention kernels of `r` packed rows of `t` in one
+    micro-batch, q and k at a head size of `hd`, read their operands
+    where the projections left them (`_rows_in_place`: the pair kernels
+    of a long row alone, sequence-minor). For host-side counters."""
+    ran, _ = _choose_attn_impl(impl, t, hq, hkv, mesh, r)
+    return _rows_in_place(
+        ran == "splash" and _host_rows_skip(r, splash_run_shape(t)[0], mesh), hd)
 
 
 def attn_block_cells(

@@ -102,15 +102,23 @@ def band_cells_run(segment_ids: np.ndarray) -> int:
     return int(-(-(live[-1] + 1) // _BAND) * _BAND) if live.size else 0
 
 
-def _cut(arrays, i):
-    """Band i of each `[1, T, ...]` array."""
-    return tuple(jax.lax.dynamic_slice_in_dim(a, i * _BAND, _BAND, axis=1) for a in arrays)
+def _axes(arrays, minor=()):
+    """The axis each array's bands lie along: 1 of a `[1, T, ...]` array,
+    the last of one of `minor` (indices into `arrays`), `[1, ..., T]`."""
+    return tuple(a.ndim - 1 if j in minor else 1 for j, a in enumerate(arrays))
 
 
-def _put(bufs, bands, i):
-    """Each `[1, T, ...]` buffer with its band written as band i."""
-    return tuple(jax.lax.dynamic_update_slice_in_dim(b, a, i * _BAND, axis=1)
-                 for b, a in zip(bufs, bands))
+def _cut(arrays, i, minor=()):
+    """Band i of each `[1, T, ...]` array (of `minor`'s, `[1, ..., T]`)."""
+    return tuple(jax.lax.dynamic_slice_in_dim(a, i * _BAND, _BAND, axis=axis)
+                 for a, axis in zip(arrays, _axes(arrays, minor)))
+
+
+def _put(bufs, bands, i, minor=()):
+    """Each `[1, T, ...]` buffer (of `minor`'s, `[1, ..., T]`) with its band
+    written as band i."""
+    return tuple(jax.lax.dynamic_update_slice_in_dim(b, a, i * _BAND, axis=axis)
+                 for b, a, axis in zip(bufs, bands, _axes(bufs, minor)))
 
 
 def _row_zeros(avals, T):
@@ -135,19 +143,20 @@ def _floating(tree):
     return [a for a in jax.tree_util.tree_leaves(tree) if jnp.issubdtype(a.dtype, jnp.inexact)]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
-def _stretch(fn, static, weights, xs, side, n_live):
-    band = lambda i: tuple(fn(static, weights, _cut(xs, i), _cut(side, i)))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2))
+def _stretch(fn, static, minor, weights, xs, side, n_live):
+    band = lambda i: tuple(fn(static, weights, _cut(xs, i, minor), _cut(side, i)))
     return jax.lax.fori_loop(
         0, n_live, lambda i, outs: _put(outs, band(i), i),
-        _row_zeros(jax.eval_shape(band, 0), xs[0].shape[1]))
+        _row_zeros(jax.eval_shape(band, 0), xs[0].shape[_axes(xs, minor)[0]]))
 
 
-def _stretch_fwd(fn, static, weights, xs, side, n_live):
-    return _stretch(fn, static, weights, xs, side, n_live), (weights, xs, side, n_live)
+def _stretch_fwd(fn, static, minor, weights, xs, side, n_live):
+    return (_stretch(fn, static, minor, weights, xs, side, n_live),
+            (weights, xs, side, n_live))
 
 
-def _stretch_bwd(fn, static, res, d_outs):
+def _stretch_bwd(fn, static, minor, res, d_outs):
     weights, xs, side, n_live = res
     # an integer output's cotangent is float0: no one's to hand in
     d_outs = tuple(d for d in d_outs if d.dtype != jax.dtypes.float0)
@@ -155,10 +164,11 @@ def _stretch_bwd(fn, static, res, d_outs):
     def body(i, carry):
         dws, dxs = carry
         s = _cut(side, i)
-        outs, vjp = jax.vjp(lambda w, *x: tuple(fn(static, w, x, s)), weights, *_cut(xs, i))
+        outs, vjp = jax.vjp(lambda w, *x: tuple(fn(static, w, x, s)), weights,
+                            *_cut(xs, i, minor))
         dw, *dx = vjp(_cotangents(outs, _cut(d_outs, i)))
         dws = jax.tree_util.tree_map(lambda a, g: a + g.astype(a.dtype), dws, dw)
-        return dws, _put(dxs, dx, i)
+        return dws, _put(dxs, dx, i, minor)
 
     dws, dxs = jax.lax.fori_loop(0, n_live, body, (
         jax.tree_util.tree_map(lambda w: jnp.zeros(w.shape, jnp.float32), weights),
@@ -174,7 +184,7 @@ def _stretch_bwd(fn, static, res, d_outs):
 
 _stretch.defvjp(_stretch_fwd, _stretch_bwd)
 
-_stretch_jit = jax.jit(_stretch, static_argnums=(0, 1))
+_stretch_jit = jax.jit(_stretch, static_argnums=(0, 1, 2))
 
 
 def _carried_loop(fn, static, weights, xs, side, carry, n_live, keep: bool):
@@ -243,17 +253,22 @@ _carried_jit = jax.jit(_carried, static_argnums=(0, 1))
 
 
 def stretch(fn: Callable, static: Any, weights: Any, xs: Sequence[jnp.ndarray],
-            side: Sequence[jnp.ndarray], n_live) -> Tuple[jnp.ndarray, ...]:
+            side: Sequence[jnp.ndarray], n_live,
+            minor: Tuple[int, ...] = ()) -> Tuple[jnp.ndarray, ...]:
     """`fn(static, weights, xs, side)` over the first `n_live` bands of the
     one row: `xs` and `side` are tuples of `[1, T, ...]` arrays of which
     `fn` sees a band `[1, _BAND, ...]` each, and returns a tuple of such
     arrays; the result is those as `[1, T, ...]`, zero past the live
-    bands. `fn` and `static` are hashable (a module-level function and a
+    bands. The `xs` that `minor` names by index lie sequence-minor, `[1,
+    ..., T]`, and `fn` sees `[1, ..., _BAND]` of them (an attention
+    kernel's output as the kernel wrote it: the loop's buffers, this one
+    and its cotangent's, are then the kernel's own layout, and nothing
+    relays the row on its way in or out). `fn` and `static` are hashable (a module-level function and a
     tuple of what it does not trace): they key the one trace. Gradients
     flow to `weights` (a pytree of floating arrays, the same for every
     band; summed in float32 across bands) and to `xs`; `side` gets none
     (positions' tables)."""
-    return _stretch_jit(fn, static, weights, tuple(xs), tuple(side), n_live)
+    return _stretch_jit(fn, static, tuple(minor), weights, tuple(xs), tuple(side), n_live)
 
 
 def carried(fn: Callable, static: Any, weights: Any, xs: Sequence[jnp.ndarray],

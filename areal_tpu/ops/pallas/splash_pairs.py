@@ -17,7 +17,20 @@ Inside a step the arithmetic is splash's
 dq): online softmax over `bkvc` sub-blocks, float32 sums, the mask by
 place in the row and segment id. Operands are head-first: q `[Hq, T,
 hd]`, k `[Hkv, T, hd]`, v `[Hkv, T, hd_v]`; the kv head of a q head is in
-the index maps (`h // group`).
+the index maps (`h // group`). Or, `seq_minor`, sequence-minor: q, k `[H,
+hd, T]`, v `[H, hd_v, T]`, and so the output, `do`, dq, dk and dv, which
+is how XLA's products write latent attention's q, k and v (the sequence
+in lanes) and read its gradients, so that nothing relays a row between a
+projection and a kernel (`ops/attention._rows_in_place` says when; PERF.md
+section 6, PR 62). The walk, the lists, the flags and a step's arithmetic
+are the same, each product the same shape with its operands read the other
+way round (NN for NT and NT for NN); what is turned is a block that stays
+for a run of steps, once a run: in the forward the q block on its way in
+and the output block on its way out, in the backward k and v on their way
+in (k's transpose for dq is then the block as it lies) and dk and dv on
+their way out; dq leaves as the block of its float32 sum stands. On a v5e
+that costs the forward 2.3-3.3 % and the backward -0.1 to +1.1 % (the
+probe, PERF.md section 6, PR 62).
 
 The backward is one kernel, five products a pair (`s = k q^T`, `dp = v
 do^T`, `dv = p^T do`, `dk = ds^T q`, `dq^T = k^T ds`), where a dq kernel
@@ -148,11 +161,16 @@ def _keep(q_at, k_at, shape, q_ids, kv_ids, window, k_in_lanes, chosen=None):
 
 
 def _fwd_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, qseg_ref,
-                kvseg_ref, *rest, blocks, window, masked=False):
+                kvseg_ref, *rest, blocks, window, masked=False, seq_minor=False):
     mask_ref, (o_ref, *rest) = (rest[0], rest[1:]) if masked else (None, rest)
+    # Sequence-minor blocks lie `[hd, bq]`, `[hd, bkv]`, `[hd_v, bkv]`: the
+    # q block is turned once a run of steps, the output block once at its end,
+    # and a step's two products are today's with k and v read as they lie.
+    q_sc, rest = (rest[-1], rest[:-1]) if seq_minor else (None, rest)
     # The logsumexp is an output only where the backward will want it.
     lse_ref, (m_sc, l_sc, o_sc) = (rest[0], rest[1:]) if len(rest) == 4 else (None, rest)
     bq, bkv, bkvc = blocks
+    over_hd, over_kv = (_NN, _NT) if seq_minor else (_NT, _NN)
     s = pl.program_id(1)
     flags = flags_ref[s]
     v_repeats = pl.cdiv(o_sc.shape[-1], _LANES)
@@ -162,14 +180,18 @@ def _fwd_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, qseg_ref,
         o_sc[...] = jnp.zeros_like(o_sc)
         m_sc[...] = jnp.full_like(m_sc, _MASK_VALUE)
         l_sc[...] = jnp.zeros_like(l_sc)
+        if seq_minor:
+            q_sc[...] = q_ref[...].T
 
     q_at, k_at = qi_ref[s] * bq, ki_ref[s] * bkv
+    kv_cols = (lambda ref, cols: ref[:, cols]) if seq_minor else (
+        lambda ref, cols: ref[cols, :])
 
     def sub_block(c, _):
         cols = pl.ds(c * bkvc, bkvc)
         m_prev, l_prev = m_sc[...], l_sc[...]
-        qk = lax.dot_general(q_ref[...], k_ref[cols, :], _NT,
-                             preferred_element_type=jnp.float32)
+        qk = lax.dot_general((q_sc if seq_minor else q_ref)[...], kv_cols(k_ref, cols),
+                             over_hd, preferred_element_type=jnp.float32)
         keep = _keep(q_at, k_at + c * bkvc, qk.shape,
                      jnp.tile(qseg_ref[...], (1, bkvc // _LANES)),
                      kvseg_ref[:1, cols], window, True,
@@ -181,7 +203,7 @@ def _fwd_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, qseg_ref,
         l_curr = lax.broadcast_in_dim(s_curr.sum(axis=-1), l_prev.shape, (0,))
         alpha = jnp.exp(m_prev - m_next)
         m_sc[...], l_sc[...] = m_next, l_curr + alpha * l_prev
-        o_curr = lax.dot_general(s_curr, v_ref[cols, :].astype(jnp.float32), _NN)
+        o_curr = lax.dot_general(s_curr, kv_cols(v_ref, cols).astype(jnp.float32), over_kv)
         alpha_o = jnp.tile(alpha, (1, v_repeats))[..., :o_sc.shape[-1]]
         o_sc[...] = alpha_o * o_sc[...] + o_curr
 
@@ -191,7 +213,8 @@ def _fwd_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, qseg_ref,
     def end():
         l = l_sc[...]
         l_inv = jnp.tile(1.0 / l, (1, v_repeats))[..., :o_sc.shape[-1]]
-        o_ref[...] = (o_sc[...] * l_inv).astype(o_ref.dtype)
+        o = o_sc[...] * l_inv
+        o_ref[...] = (o.T if seq_minor else o).astype(o_ref.dtype)
         if lse_ref is not None:
             # One row of bq, not splash's bq x 128 of equal lanes (a 128th
             # of the bytes, and no relayout around the call to slice it).
@@ -199,11 +222,16 @@ def _fwd_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, qseg_ref,
 
 
 def _bwd_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, qseg_ref,
-                kvseg_ref, *rest, blocks, window, group, masked=False):
+                kvseg_ref, *rest, blocks, window, group, masked=False, seq_minor=False):
     mask_ref, rest = (rest[0], rest[1:]) if masked else (None, rest)
+    # `k_sc`: the kv block's k the other way round than it lies; `v_sc`
+    # (sequence-minor operands alone): v likewise.
     (lse_ref, do_ref, di_ref, dq_ref, sum_ref, dk_ref, dv_ref,
-     dk_sc, dv_sc, kt_sc, dq_sc, dq_io, dq_out, sems, on_its_way) = rest
+     dk_sc, dv_sc, k_sc, *v_sc, dq_sc, dq_io, dq_out, sems, on_its_way) = rest
     bq, bkv, bkvc = blocks
+    # A product with a q block's operand (q, do) runs over the head size
+    # or over the block's cells: which axis that is follows the layout.
+    over_hd, over_q = (_NN, _NT) if seq_minor else (_NT, _NN)
     h, s, g = (pl.program_id(i) for i in range(3))
     kv_heads, n = pl.num_programs(0), pl.num_programs(1)
     flags = flags_ref[s]
@@ -220,7 +248,11 @@ def _bwd_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, qseg_ref,
         dv_sc[...] = jnp.zeros_like(dv_sc)
         # dq is summed transposed, `[hd, bq]` = k^T ds: the transpose is
         # of the kv block's k, once a run of steps, not of a step's ds.
-        kt_sc[...] = k_ref[...].T
+        # Sequence-minor blocks are that transpose as they lie, and what
+        # is turned once a run is what the other products read by rows.
+        k_sc[...] = k_ref[...].T
+        if seq_minor:
+            v_sc[0][...] = v_ref[...].T
 
     # The q block's sum lives in HBM between its visits (`sum_ref`, the
     # whole `[Hq, hd, T]`, float32): in at the step's start, added to at
@@ -233,7 +265,9 @@ def _bwd_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, qseg_ref,
     sum_at = sum_ref.at[h * group + g, :, rows_of_q]
     read = pltpu.make_async_copy(sum_at, mine, sems.at[2])
     write = pltpu.make_async_copy(mine, sum_at, sems.at[half])
-    out = pltpu.make_async_copy(dq_out, dq_ref.at[h * group + g, rows_of_q], sems.at[3])
+    dq_at = (dq_ref.at[h * group + g, :, rows_of_q] if seq_minor else
+             dq_ref.at[h * group + g, rows_of_q])
+    out = pltpu.make_async_copy(dq_out, dq_at, sems.at[3])
     handed = on_its_way[_KEPT] != 0
     summed = flags & NEW == 0  # an earlier step left this block a sum
     fetched = summed & jnp.logical_not(handed)
@@ -251,21 +285,23 @@ def _bwd_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, qseg_ref,
     def sum_in():
         read.start()
 
+    k_rows, k_cols, v_rows = (k_sc, k_ref, *v_sc) if seq_minor else (k_ref, k_sc, v_ref)
     for c in range(bkv // bkvc):
         rows = pl.ds(c * bkvc, bkvc)
-        q, k, v, do = q_ref[...], k_ref[rows, :], v_ref[rows, :], do_ref[...]
-        qk = lax.dot_general(k, q, _NT, preferred_element_type=jnp.float32)
+        q, k, v, do = q_ref[...], k_rows[rows, :], v_rows[rows, :], do_ref[...]
+        qk = lax.dot_general(k, q, over_hd, preferred_element_type=jnp.float32)
         keep = _keep(q_at, k_at + c * bkvc, qk.shape, qseg_ref[:1, :],
                      jnp.tile(kvseg_ref[rows, :], (1, bq // _LANES)), window, False,
                      None if mask_ref is None else mask_ref[rows, :])
         p = jnp.exp(jnp.where(keep, qk, _MASK_VALUE) - lse_ref[:1, :])
-        dv = lax.dot(p.astype(do.dtype), do, preferred_element_type=jnp.float32)
+        dv = lax.dot_general(p.astype(do.dtype), do, over_q,
+                             preferred_element_type=jnp.float32)
         dv_sc[rows, :] = dv + dv_sc[rows, :]
-        dp = lax.dot_general(v, do, _NT, preferred_element_type=jnp.float32)
+        dp = lax.dot_general(v, do, over_hd, preferred_element_type=jnp.float32)
         ds = ((dp - di_ref[:1, :]) * p).astype(do.dtype)
-        dk = lax.dot_general(ds, q, _NN, preferred_element_type=jnp.float32)
+        dk = lax.dot_general(ds, q, over_q, preferred_element_type=jnp.float32)
         dk_sc[rows, :] = dk + dk_sc[rows, :]
-        dq = lax.dot(kt_sc[:, rows], ds, preferred_element_type=jnp.float32)
+        dq = lax.dot(k_cols[:, rows], ds, preferred_element_type=jnp.float32)
         dq_sc[...] = dq if c == 0 else dq + dq_sc[...]
 
     @pl.when(fetched)
@@ -284,12 +320,15 @@ def _bwd_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, qseg_ref,
     on_its_way[_KEPT] = keeps.astype(jnp.int32)
 
     @pl.when(last_visit)
-    def block_out():  # the one rounding, and dq as the model has it: [bq, hd]
+    def block_out():  # the one rounding, and dq as the model has it
         @pl.when(on_its_way[_OUT] != 0)
         def stage_free():
             out.wait()
 
-        dq_out[:, :mine.shape[0]] = mine[...].T.astype(dq_out.dtype)
+        if seq_minor:  # `[hd, bq]`, the sum as it stands
+            dq_out[...] = mine[...].astype(dq_out.dtype)
+        else:  # `[bq, hd]`
+            dq_out[:, :mine.shape[0]] = mine[...].T.astype(dq_out.dtype)
         out.start()
         on_its_way[_OUT] = 1
 
@@ -300,8 +339,8 @@ def _bwd_kernel(qi_ref, ki_ref, flags_ref, q_ref, k_ref, v_ref, qseg_ref,
 
     @pl.when((flags & LAST != 0) & (g == group - 1))
     def end():
-        dk_ref[...] = dk_sc[...].astype(dk_ref.dtype)
-        dv_ref[...] = dv_sc[...].astype(dv_ref.dtype)
+        for ref, sc in ((dk_ref, dk_sc), (dv_ref, dv_sc)):
+            ref[...] = (sc[...].T if seq_minor else sc[...]).astype(ref.dtype)
 
     @pl.when((h == kv_heads - 1) & (s == n - 1) & (g == group - 1))
     def drain():
@@ -337,19 +376,31 @@ def _segment_operands(segment_ids, q_in_lanes):
     return (flat, wide) if q_in_lanes else (wide, flat)
 
 
-def _q_major(q, k, v, blocks, mask=None):
-    """(index map of a q head's blocks, in_specs of q, k, v, the segment
-    ids and, where there is one, the mask operand `[T / bkvc, T, bkvc]`)
-    for the kernels that walk the q-major list."""
-    hq, _, hd = q.shape
-    hkv, hd_v = k.shape[0], v.shape[-1]
+def _block(rows, hd, at, seq_minor):
+    """The block of `rows` cells that `at` names, as (head, block), of a
+    head-first operand `[H, T, hd]` or of a sequence-minor one `[H, hd,
+    T]`."""
+
+    def index(*grid):
+        h, i = at(*grid)
+        return (h, 0, i) if seq_minor else (h, i, 0)
+
+    return pl.BlockSpec((None, hd, rows) if seq_minor else (None, rows, hd), index)
+
+
+def _q_major(q, k, v, blocks, mask=None, seq_minor=False):
+    """((head, block) of a q head's blocks, in_specs of q, k, v, the
+    segment ids and, where there is one, the mask operand `[T / bkvc, T,
+    bkvc]`) for the kernels that walk the q-major list."""
+    hq, hkv = q.shape[0], k.shape[0]
+    hd, hd_v = (q.shape[1], v.shape[1]) if seq_minor else (q.shape[2], v.shape[2])
     bq, bkv, bkvc = blocks
-    on_q = lambda h, s, qi, ki, fl: (h, qi[s], 0)
-    on_kv = lambda h, s, qi, ki, fl: (h // (hq // hkv), ki[s], 0)
+    on_q = lambda h, s, qi, ki, fl: (h, qi[s])
+    on_kv = lambda h, s, qi, ki, fl: (h // (hq // hkv), ki[s])
     specs = [
-        pl.BlockSpec((None, bq, hd), on_q),
-        pl.BlockSpec((None, bkv, hd), on_kv),
-        pl.BlockSpec((None, bkv, hd_v), on_kv),
+        _block(bq, hd, on_q, seq_minor),
+        _block(bkv, hd, on_kv, seq_minor),
+        _block(bkv, hd_v, on_kv, seq_minor),
         pl.BlockSpec((bq, _LANES), lambda h, s, qi, ki, fl: (qi[s], 0)),
         pl.BlockSpec((_SUBLANES, bkv), lambda h, s, qi, ki, fl: (0, ki[s])),
     ]
@@ -366,24 +417,26 @@ def _masked(*operands):
 
 
 def _forward(q, k, v, segment_ids, lists, blocks, window, interpret, residuals,
-             mask=None):
-    hq, t, _ = q.shape
-    bq, hd_v = blocks.bq, v.shape[-1]
-    on_q, in_specs = _q_major(q, k, v, blocks, mask)
-    out_shape = [jax.ShapeDtypeStruct((hq, t, hd_v), q.dtype)]
-    out_specs = [pl.BlockSpec((None, bq, hd_v), on_q)]
+             mask=None, seq_minor=False):
+    assert mask is None or not seq_minor
+    hq, bq = q.shape[0], blocks.bq
+    (hd, t), hd_v = (q.shape[1:], v.shape[1]) if seq_minor else (q.shape[:0:-1], v.shape[2])
+    on_q, in_specs = _q_major(q, k, v, blocks, mask, seq_minor)
+    out_shape = [jax.ShapeDtypeStruct((hq, hd_v, t) if seq_minor else (hq, t, hd_v), q.dtype)]
+    out_specs = [_block(bq, hd_v, on_q, seq_minor)]
     if residuals:
         out_shape.append(jax.ShapeDtypeStruct((hq, 1, t), jnp.float32))
         out_specs.append(pl.BlockSpec(
             (None, 1, bq), lambda h, s, qi, ki, fl: (h, 0, qi[s])))
     out = _call(
         functools.partial(_fwd_kernel, blocks=blocks, window=window,
-                          masked=mask is not None),
+                          masked=mask is not None, seq_minor=seq_minor),
         "splash_pairs_fwd", (hq, lists.n), lists.q_major, in_specs=in_specs,
         out_specs=out_specs, out_shape=out_shape,
         scratch=[pltpu.VMEM((bq, _LANES), jnp.float32),
                  pltpu.VMEM((bq, _LANES), jnp.float32),
-                 pltpu.VMEM((bq, hd_v), jnp.float32)],
+                 pltpu.VMEM((bq, hd_v), jnp.float32),
+                 *([pltpu.VMEM((bq, hd), q.dtype)] if seq_minor else [])],
         semantics=("parallel", "arbitrary"), interpret=interpret,
         operands=_masked(q, k, v, *_segment_operands(segment_ids, q_in_lanes=False),
                          mask))
@@ -391,17 +444,19 @@ def _forward(q, k, v, segment_ids, lists, blocks, window, interpret, residuals,
 
 
 def _backward(q, k, v, segment_ids, lists, lse, do, di, blocks, window,
-              interpret, mask_t=None):
-    """(dq, dk, dv) from one kernel over the kv-major list. dq's minor
-    dimension is a multiple of the lanes in the kernel's own copies (192
-    in 256): sliced here."""
-    hq, t, hd = q.shape
-    hkv, hd_v = k.shape[0], v.shape[-1]
+              interpret, mask_t=None, seq_minor=False):
+    """(dq, dk, dv) from one kernel over the kv-major list, laid as q, k
+    and v are. A head-first dq's minor dimension is a multiple of the
+    lanes in the kernel's own copies (192 in 256): sliced here. A
+    sequence-minor dq is the block of the sum as it stands."""
+    assert mask_t is None or not seq_minor
+    hq, hkv = q.shape[0], k.shape[0]
+    (hd, t), hd_v = (q.shape[1:], v.shape[1]) if seq_minor else (q.shape[:0:-1], v.shape[2])
     group = hq // hkv
     bq, bkv, _ = blocks
-    hd_out = -(-hd // _LANES) * _LANES
-    on_q = lambda h, s, g, qi, ki, fl: (h * group + g, qi[s], 0)
-    on_kv = lambda h, s, g, qi, ki, fl: (h, ki[s], 0)
+    hd_out = hd if seq_minor else -(-hd // _LANES) * _LANES
+    on_q = lambda h, s, g, qi, ki, fl: (h * group + g, qi[s])
+    on_kv = lambda h, s, g, qi, ki, fl: (h, ki[s])
     # Sublane-broadcast, as splash does it: Mosaic has no retiling of a
     # single row yet.
     q_rows = pl.BlockSpec((None, _SUBLANES, bq),
@@ -413,69 +468,73 @@ def _backward(q, k, v, segment_ids, lists, lse, do, di, blocks, window,
         (None, bkv, bq), lambda h, s, g, qi, ki, fl: (qi[s], ki[s], 0)),)
     dq, _, dk, dv = _call(
         functools.partial(_bwd_kernel, blocks=blocks, window=window, group=group,
-                          masked=mask_t is not None),
+                          masked=mask_t is not None, seq_minor=seq_minor),
         "splash_pairs_bwd", (hkv, lists.n, group), lists.kv_major,
         in_specs=[
-            pl.BlockSpec((None, bq, hd), on_q),
-            pl.BlockSpec((None, bkv, hd), on_kv),
-            pl.BlockSpec((None, bkv, hd_v), on_kv),
+            _block(bq, hd, on_q, seq_minor),
+            _block(bkv, hd, on_kv, seq_minor),
+            _block(bkv, hd_v, on_kv, seq_minor),
             pl.BlockSpec((_SUBLANES, bq), lambda h, s, g, qi, ki, fl: (0, qi[s])),
             pl.BlockSpec((bkv, _LANES), lambda h, s, g, qi, ki, fl: (ki[s], 0)),
             *mask_spec,
             q_rows,
-            pl.BlockSpec((None, bq, hd_v), on_q),
+            _block(bq, hd_v, on_q, seq_minor),
             q_rows,
         ],
         out_specs=[pl.BlockSpec(memory_space=pl.ANY),
                    pl.BlockSpec(memory_space=pl.ANY),
-                   pl.BlockSpec((None, bkv, hd), on_kv),
-                   pl.BlockSpec((None, bkv, hd_v), on_kv)],
-        out_shape=[jax.ShapeDtypeStruct((hq, t, hd_out), q.dtype),
+                   _block(bkv, hd, on_kv, seq_minor),
+                   _block(bkv, hd_v, on_kv, seq_minor)],
+        out_shape=[jax.ShapeDtypeStruct((hq, hd, t) if seq_minor else (hq, t, hd_out), q.dtype),
                    jax.ShapeDtypeStruct((hq, hd, t), jnp.float32),  # the sums, in passing
                    jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch=[pltpu.VMEM((bkv, hd), jnp.float32),
                  pltpu.VMEM((bkv, hd_v), jnp.float32),
-                 pltpu.VMEM((hd, bkv), k.dtype),
+                 *([pltpu.VMEM((bkv, hd), k.dtype), pltpu.VMEM((bkv, hd_v), v.dtype)]
+                   if seq_minor else [pltpu.VMEM((hd, bkv), k.dtype)]),
                  pltpu.VMEM((hd, bq), jnp.float32),
                  pltpu.VMEM((2, hd, bq), jnp.float32),
-                 pltpu.VMEM((bq, hd_out), q.dtype),
+                 pltpu.VMEM((hd, bq) if seq_minor else (bq, hd_out), q.dtype),
                  pltpu.SemaphoreType.DMA((4,)), pltpu.SMEM((4,), jnp.int32)],
         semantics=("arbitrary", "arbitrary", "arbitrary"), interpret=interpret,
         operands=_masked(q, k, v, *_segment_operands(segment_ids, q_in_lanes=True),
                          mask_t, rows(lse), do, rows(di)))
-    return dq[..., :hd], dk, dv
+    return (dq if seq_minor else dq[..., :hd]), dk, dv
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7, 8, 9))
 def pair_attention(q, k, v, segment_ids, lists: PairLists, blocks: Blocks,
-                   window: Optional[int], residual_name: str, interpret: bool):
+                   window: Optional[int], residual_name: str, interpret: bool,
+                   seq_minor: bool = False):
     """Attention of one packed row over the block pairs `lists` names:
     q `[Hq, T, hd]` (already scaled), k `[Hkv, T, hd]`, v `[Hkv, T,
-    hd_v]`, `segment_ids` `[T]` -> `[Hq, T, hd_v]`. A q block must have
+    hd_v]`, `segment_ids` `[T]` -> `[Hq, T, hd_v]`; `seq_minor`: every
+    one of them, and each gradient, `[H, hd, T]`. A q block must have
     a pair, or its output block is never written. The output and the
     logsumexp the backward keeps are `checkpoint_name`d `residual_name`,
     so a remat policy can keep them and the backward not run the forward
     kernel again."""
     out, _ = _forward(q, k, v, segment_ids, lists, blocks, window, interpret,
-                      residuals=False)
+                      residuals=False, seq_minor=seq_minor)
     return checkpoint_name(out, residual_name)
 
 
 def _pair_attention_fwd(q, k, v, segment_ids, lists, blocks, window,
-                        residual_name, interpret):
+                        residual_name, interpret, seq_minor=False):
     out, lse = _forward(q, k, v, segment_ids, lists, blocks, window, interpret,
-                        residuals=True)
+                        residuals=True, seq_minor=seq_minor)
     out, lse = (checkpoint_name(x, residual_name) for x in (out, lse))
     return out, (q, k, v, segment_ids, lists, out, lse)
 
 
-def _pair_attention_bwd(blocks, window, residual_name, interpret, res, do):
+def _pair_attention_bwd(blocks, window, residual_name, interpret, seq_minor, res, do):
     del residual_name
     q, k, v, segment_ids, lists, out, lse = res
-    di = jnp.einsum("hsd,hsd->hs", out.astype(jnp.float32), do.astype(jnp.float32))
+    di = jnp.einsum("hdt,hdt->ht" if seq_minor else "hsd,hsd->hs",
+                    out.astype(jnp.float32), do.astype(jnp.float32))
     dq, dk, dv = _backward(q, k, v, segment_ids, lists, lse, do, di, blocks, window,
-                           interpret)
+                           interpret, seq_minor=seq_minor)
     return dq, dk, dv, None, None
 
 

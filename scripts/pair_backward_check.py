@@ -3,6 +3,7 @@
 checkout on the same inputs, on the chip, at one row of 16,384.
 
     python scripts/pair_backward_check.py [--parent _parent] [--out chiprun_out/x.jsonl]
+        [--shapes group_1_at_192_128 ..]
 
 `--parent DIR` is a `git archive` of the commit to compare with. A line a
 shape x layout: the largest difference of each gradient as a share of its
@@ -58,6 +59,7 @@ def main():
     ap.add_argument("--parent", default=os.path.join(ROOT, "_parent"))
     ap.add_argument("--out", default=None)
     ap.add_argument("--toy", action="store_true")
+    ap.add_argument("--shapes", nargs="+", choices=sorted(SHAPES), default=sorted(SHAPES))
     args = ap.parse_args()
 
     import jax
@@ -66,7 +68,7 @@ def main():
 
     import splash_shape_sweep as sweep
 
-    t, shapes = 16384, SHAPES
+    t, shapes = 16384, {name: SHAPES[name] for name in args.shapes}
     if args.toy:
         t, shapes = 2048, {"toy": (4, 2, 32, 32, None)}
     elif jax.default_backend() != "tpu":

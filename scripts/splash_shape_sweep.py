@@ -37,6 +37,10 @@ around them) and the heaviest other ops, with the grid steps a q head
 walks and those whose pair runs;
 `--parent DIR` runs every line for the checkout at DIR as well (`git
 archive` of the commit to compare with), on the same inputs.
+`--layout` says how a row alone hands its kernels q, k and v:
+`head-first` (`[H, T, hd]`), `seq-minor` (`[H, hd, T]`, as XLA's
+products leave them: `ops/attention._rows_in_place`), `both` (a line
+each) or, the default, what the call picks for its head size.
 """
 
 from __future__ import annotations
@@ -175,16 +179,26 @@ def inputs(rows, t, hq, hkv, hd, v_dim, seed):
                  for h, d in ((hq, hd), (hkv, hd), (hkv, v_dim)))
 
 
-def chained(A, run_shape, layers, window):
+# `_in_place` of `splash_packed_attention` by its name here, and what
+# `--layout` runs
+LAYOUT_NAMES = {None: "auto", False: "head-first", True: "seq-minor"}
+LAYOUTS = {**{name: [value] for value, name in LAYOUT_NAMES.items()},
+           "both": [False, True]}
+
+
+def chained(A, run_shape, layers, window, in_place=None):
     """(q, k, v, seg, pos) -> a scalar through `layers` chained calls of
-    `A.splash_packed_attention`, rows whole as the model gives them."""
+    `A.splash_packed_attention`, rows whole as the model gives them;
+    `in_place`: the kernels' layout (None: the call's own choice)."""
     import jax
     import jax.numpy as jnp
 
+    layout = {} if in_place is None else {"_in_place": in_place}
+
     def chain(q, k, v, seg, pos):
         def body(x, _):
-            out = A.splash_packed_attention(x, k, v, seg, pos,
-                                            _run_shape=run_shape, window=window)
+            out = A.splash_packed_attention(x, k, v, seg, pos, _run_shape=run_shape,
+                                            window=window, **layout)
             # v's head size may differ from q's
             d = x.shape[-1] - out.shape[-1]
             out = jnp.pad(out, ((0, 0),) * 3 + ((0, d),)) if d > 0 else out[..., :x.shape[-1]]
@@ -196,11 +210,11 @@ def chained(A, run_shape, layers, window):
     return chain
 
 
-def time_shape(A, qkv, ids, run_shape, layers, window=None):
+def time_shape(A, qkv, ids, run_shape, layers, window=None, in_place=None):
     """(fwd ms, fwd+bwd ms) of `layers` chained attention calls."""
     import jax
 
-    chain = chained(A, run_shape, layers, window)
+    chain = chained(A, run_shape, layers, window, in_place)
 
     def clock(fn):
         jax.block_until_ready(fn(*qkv, *ids))  # compiles
@@ -226,7 +240,7 @@ def backward_ms(ops_ms):
     return sum(ms for name, ms in ops_ms.items() if not name.endswith("fwd"))
 
 
-def kernel_ops(A, qkv, run_shape, layers, window=None, reps=3):
+def kernel_ops(A, qkv, run_shape, layers, window=None, reps=3, in_place=None):
     """ids -> ({attention kernel's name: ms a layer}, the device's busy
     ms a layer: the kernels and what XLA runs around them, {the six
     heaviest other ops: ms a layer}) from a trace of `reps` calls of
@@ -238,7 +252,7 @@ def kernel_ops(A, qkv, run_shape, layers, window=None, reps=3):
 
     from benchmark import trace_reduce
 
-    fn = jax.jit(jax.grad(chained(A, run_shape, layers, window), (0, 1, 2)))
+    fn = jax.jit(jax.grad(chained(A, run_shape, layers, window, in_place), (0, 1, 2)))
 
     def traced(ids):
         jax.block_until_ready(fn(*qkv, *ids))
@@ -267,7 +281,7 @@ def walked(A, ids, hq, hkv, window):
     return int(steps), int(live)
 
 
-def check_shape(A, qkv, ids, run_shape, window=None):
+def check_shape(A, qkv, ids, run_shape, window=None, in_place=None):
     """Largest error of the call's output and of its q, k, v gradients
     against `reference_packed_attention`'s (float32 from the same bf16
     inputs), each as a share of the reference's largest value."""
@@ -289,8 +303,9 @@ def check_shape(A, qkv, ids, run_shape, window=None):
             loss, (0, 1, 2), has_aux=True))(*qkv)
         return [np.asarray(a, np.float32) for a in (out, *grads)]
 
+    layout = {} if in_place is None else {"_in_place": in_place}
     got = run(lambda q, k, v: A.splash_packed_attention(
-        q, k, v, seg, pos, _run_shape=run_shape, window=window))
+        q, k, v, seg, pos, _run_shape=run_shape, window=window, **layout))
     want = run(lambda q, k, v: jax.vmap(
         lambda q, k, v, s, p: A.reference_packed_attention(
             q, k, v, s, p, window=window))(q, k, v, seg, pos))
@@ -338,19 +353,24 @@ def sweep(args):
             else:
                 pool = [packed_rows(rows, t, args.seq_len)]
             pool = [tuple(jnp.asarray(a) for a in ids) for ids in pool]
-            for tree, A, pairs in trees:
+            # the parent has one layout: its own
+            for tree, A, pairs, in_place in [
+                    (*tr, lay) for tr in trees
+                    for lay in (LAYOUTS[args.layout] if tr[0] == "here" else [None])]:
                 use_tree(pairs)
-                ops = kernel_ops(A, qkv, c, args.layers, args.window) if args.ops else None
+                ops = (kernel_ops(A, qkv, c, args.layers, args.window, in_place=in_place)
+                       if args.ops else None)
                 for at, ids in enumerate(pool):
                     row = dict(rows=rows, t=t, t_run=c[0], bq=c[1], bkv=c[2], bkvc=c[3],
                                hq=args.hq, hkv=hkv, hd=args.hd, v_dim=v_dim,
                                layers=args.layers, window=args.window,
                                device=jax.devices()[0].device_kind, tree=tree,
                                static=args.static,
+                               layout=LAYOUT_NAMES[in_place],
                                seq_len=args.seq_len, rows_from=args.rows_from, row=at)
                     try:
                         if args.check:
-                            row["rel_err"] = check_shape(A, qkv, ids, c, args.window)
+                            row["rel_err"] = check_shape(A, qkv, ids, c, args.window, in_place)
                         elif args.ops:
                             row["steps"], row["live"] = walked(
                                 A, ids, args.hq, hkv, args.window)
@@ -358,7 +378,7 @@ def sweep(args):
                             row["backward_ms"] = backward_ms(row["ops_ms"])
                         else:
                             row["fwd_ms"], row["grad_ms"] = time_shape(
-                                A, qkv, ids, c, args.layers, args.window)
+                                A, qkv, ids, c, args.layers, args.window, in_place)
                     except Exception as e:  # a block the compiler refuses is a result
                         row["error"] = f"{type(e).__name__}: {str(e)[:300]}"
                     f.write(json.dumps(row) + "\n")
@@ -424,6 +444,8 @@ def main():
                     help="a traced call's attention kernels, ms a layer each")
     ap.add_argument("--parent", metavar="DIR", default=None,
                     help="time the checkout at DIR as well")
+    ap.add_argument("--layout", choices=sorted(LAYOUTS), default="auto",
+                    help="how a row alone hands its kernels q, k and v")
     ap.add_argument("--seq-len", type=int, default=None,
                     help="pack the rows with sequences this long")
     ap.add_argument("--check", action="store_true",

@@ -32,7 +32,8 @@ N_MBS = 3
 BATCH = {"batches", "micro_batches", "one_row_batches", "tokens", "cells"}
 BANDS = {"band_cells"}
 ATTN = {"attn_cells", "attn_active_cells", "attn_window_cells", "attn_full_cells",
-        "attn_causal_cells", "attn_grid_steps", "attn_live_steps", "attn_bwd_steps"}
+        "attn_causal_cells", "attn_grid_steps", "attn_live_steps", "attn_bwd_steps",
+        "attn_cells_in_place"}
 HEAD = {"scored_cells", "head_cells"}
 MTP = {"mtp_targets", "mtp_head_cells"}
 # `moe_pairs` by the host; the rest the step's own statistics, fetched
@@ -151,4 +152,4 @@ def test_the_counts_of_micro_batches_add_up_to_their_stacks():
              for i, n in enumerate((70, 100))]
     assert whole == {k: parts[0][0][k] + parts[1][0][k] for k in whole}
     assert whole["train.moe_pairs"] > 0 < whole["train.scored_cells"]
-    assert attrs == parts[0][1] == parts[1][1] == dict(attn_row_len=128, width=0)
+    assert attrs == parts[0][1] == parts[1][1] == dict(attn_row_len=128, width=0, in_place=0)

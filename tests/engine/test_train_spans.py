@@ -132,6 +132,8 @@ def test_attributes_and_counters_count_tokens_and_cells(recorded):
         "train.one_row_batches": sum(r == 1 for r, _ in shapes),
         "train.tokens": a["tokens"], "train.cells": a["cells"],
         "train.attn_cells": a["cells"],
+        # the einsum reference reads no operand where a projection left it
+        "train.attn_cells_in_place": 0,
         "train.attn_active_cells": all_cells,
         "train.attn_causal_cells": all_cells,
         # no layer has a window: the split says none and the whole

@@ -77,6 +77,42 @@ def test_stretch_matches_the_whole_row(tokens):
     assert not np.any(np.asarray(g1[1][:, cells:])), "nothing flows into a dead band"
 
 
+def heads_out(static, w, xs, side):
+    """An attention layer's way out: the kernel's output as the kernel
+    wrote it, sequence-minor `[1, H, V, cells]`, through the output
+    projection onto the residual `[1, cells, D]`."""
+    (a, x), _ = xs, side
+    return (x + static * jnp.einsum("rhvt,hvd->rtd", a, w["wo"]),)
+
+
+@pytest.mark.parametrize("tokens", [0, 5, 9, 40, 48], ids=[
+    "no_live_band", "one_band_half_full", "a_band_and_a_cell", "all_but_one_band", "every_band"])
+def test_a_sequence_minor_input_is_cut_and_its_cotangent_put_along_its_last_axis(tokens):
+    """`stretch(minor=(0,))`: the first input lies `[1, H, V, T]` and the
+    function sees `[1, H, V, band]` of it; values, the weight's gradient
+    and both inputs' against the whole row, and the minor input's
+    cotangent comes back `[1, H, V, T]`, zero past the live bands."""
+    H, V = 3, 4
+    k = jax.random.split(jax.random.PRNGKey(3), 3)
+    w = {"wo": jax.random.normal(k[0], (H, V, D)) / 3}
+    a, x = jax.random.normal(k[1], (1, H, V, T)), jax.random.normal(k[2], (1, T, D))
+    n_live = band_loop.live_bands((jnp.arange(T) < tokens).astype(jnp.int32)[None])
+    cells = int(n_live) * BAND
+    live = (jnp.arange(T) < tokens)[None, :, None]
+    loss = lambda run: lambda w, a, x: jnp.sum(jnp.where(live, jnp.sin(run(w, a, x)[0]), 0))
+    looped = loss(lambda w, a, x: band_loop.stretch(heads_out, 0.5, w, (a, x), (), n_live,
+                                                    minor=(0,)))
+    plain = loss(lambda w, a, x: whole(heads_out, 0.5, w, (a, x)))
+    l0, g0 = jax.jit(jax.value_and_grad(plain, (0, 1, 2)))(w, a, x)
+    l1, g1 = jax.jit(jax.value_and_grad(looped, (0, 1, 2)))(w, a, x)
+    np.testing.assert_allclose(l1, l0, rtol=1e-5)
+    for got, want in zip(jax.tree_util.tree_leaves(g1), jax.tree_util.tree_leaves(g0)):
+        assert got.shape == want.shape
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+    assert g1[1].shape == (1, H, V, T) and not np.any(np.asarray(g1[1][..., cells:]))
+    assert not np.any(np.asarray(g1[2][:, cells:]))
+
+
 def test_two_rows_with_different_counts_share_one_trace():
     """The count is a value of the run: one compiled program, two rows."""
     w, x, c = operands()

@@ -767,6 +767,116 @@ def test_a_shard_with_one_long_row_walks_its_live_pairs(monkeypatch):
         + A.attn_grid_steps("splash", seg[1:], hq, hkv)[0],) * 2
 
 
+# lens, (bq, bkv, bkvc), window: rows of 1,024 over which the sequence-minor
+# kernels are held to the head-first ones
+SEQ_MINOR = {
+    "three_sequences_and_padding": ([400, 300, 200], (128, 256, 128), None),
+    "ends_mid_block": ([333, 479], (128, 256, 128), None),
+    "one_sequence_to_the_end": ([1024], (256, 256, 128), None),
+    "window": ([900], (128, 256, 128), 300),
+    "window_over_three": ([300, 400, 250], (128, 128, 128), 200),
+    "twelve_short": ([80] * 12, (128, 256, 128), None),
+    # a q block visited in two steps running: its sum stays in VMEM
+    "revisit_one_step_later": ([200, 400, 300], (128, 128, 128), None),
+    "all_padding": ([], (128, 128, 128), None),
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", SEQ_MINOR)
+def test_sequence_minor_kernels_are_the_head_first_kernels_to_the_bit(case, dtype):
+    """`pair_attention(seq_minor=True)`, q, k, v and the cotangent `[H, hd,
+    T]` as XLA's products leave latent attention's, at 192 / 128 and a
+    group of 1: the output `[H, hd_v, T]` and dq, dk, dv are the head-first
+    kernels' transposed, **to the bit** in interpret mode, float32 and
+    bf16 (the same products over the same values, an operand read the other
+    way round; on the chip `scripts/pair_backward_check.py` says what the
+    MXU's order of summing leaves: PERF.md section 6, PR 62), over several
+    sequences, padding, a row that ends inside a block, a window, and a q
+    block's sum kept in VMEM."""
+    from areal_tpu.ops import attention as A
+    from areal_tpu.ops.pallas.splash_pairs import Blocks, pair_attention
+
+    lens, blocks, window = SEQ_MINOR[case]
+    t, h, hd, hd_v, blocks = 1024, 2, 192, 128, Blocks(*blocks)
+    seg = jnp.asarray(_row(t, lens))
+    lists = A._pair_lists(seg, blocks.bq, blocks.bkv, window)
+    rng = np.random.RandomState(11)
+    q, k, v, dout = (jnp.asarray(rng.randn(h, t, d), dtype) for d in (hd, hd, hd_v, hd_v))
+    q = q * jnp.asarray(hd ** -0.5, q.dtype)
+
+    def run(seq_minor):
+        turn = (lambda a: a.transpose(0, 2, 1)) if seq_minor else (lambda a: a)
+        out, vjp = jax.vjp(
+            lambda q, k, v: pair_attention(q, k, v, seg, lists, blocks, window, "x", True,
+                                           seq_minor), *map(turn, (q, k, v)))
+        return [np.asarray(turn(a), np.float32) for a in (out, *vjp(turn(dout)))]
+
+    for a, b, name in zip(run(True), run(False), ("out", "dq", "dk", "dv")):
+        assert a.shape == b.shape and np.isfinite(a).all(), name
+        np.testing.assert_array_equal(a, b, err_msg=name)
+
+
+@pytest.mark.parametrize("skip,hd,want", [
+    (True, 192, True), (True, 128, False), (True, 256, False), (True, 64, False),
+    (True, 320, True), (False, 192, False)])
+def test_only_a_row_alone_at_a_head_of_no_whole_lane_tiles_reads_in_place(skip, hd, want):
+    """The one rule (`ops/attention._rows_in_place`), and what the call
+    traces by it: at 192 the pair kernels' operands are `[H, 192, T]`, at
+    any other head size here and for rows together they are as they were."""
+    from areal_tpu.ops import attention as A
+
+    assert A._rows_in_place(skip, hd) == want
+    rows, t, h = (1 if skip else 3), 2048, 2
+    qk = jax.ShapeDtypeStruct((rows, t, h, hd), jnp.bfloat16)
+    v = jax.ShapeDtypeStruct((rows, t, h, 128), jnp.bfloat16)
+    ids = jax.ShapeDtypeStruct((rows, t), jnp.int32)
+
+    def loss(q, k, v, seg, pos):
+        return A.splash_packed_attention(q, k, v, seg, pos, interpret=True).sum()
+
+    text = str(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(qk, qk, v, ids, ids))
+    assert (f"bf16[{h},{hd},{t}]" in text) == want
+    assert ("splash_pairs_bwd" in text) == skip
+    assert A.attn_in_place("splash", rows, t, h, h, hd) == want
+    assert not A.attn_in_place("reference", rows, t, h, h, hd)
+
+
+# sha256 of str(jaxpr) of the backward pass of one call of a long row
+# alone, taken at the commit before the sequence-minor kernels (PR 61):
+# (t, hq, hkv, head size of q and k, of v, window)
+ROW_ALONE_JAXPR = {
+    (2048, 4, 2, 128, 128, None): "e2b81492edd2f9a81ea08b8fcf9b09a9962e7a1da4e13bdfb275d2c3fa227f71",
+    (2048, 2, 2, 128, 128, 512): "331d84a3ba1ad9a0c839e9b2294e38cb5bb919c628a1819ebb03c94ceddfe0ef",
+    (4096, 4, 1, 256, 256, None): "ffd4693b10c8fa31f18ac3ebc6544366e7aa62a338184366ab8fd52583b36808",
+    (2048, 4, 2, 64, 128, None): "8ac1f4a47a7608a2076ce69d7b4d61d43935ad084b9bae731d7d46f0bec941e5",
+}
+
+
+@pytest.mark.parametrize("t,hq,hkv,hd,hd_v,window", sorted(ROW_ALONE_JAXPR, key=str))
+def test_a_row_alone_at_whole_lane_tiles_traces_the_program_it_did(t, hq, hkv, hd, hd_v, window):
+    """A long row alone whose heads are whole lane tiles (128 in a group
+    of 2, under a window, 256 in a group of 4) or under one (64 against
+    128) keeps the head-first pair kernels: the jaxpr of its backward
+    pass, the kernels' bodies in it, is the parent commit's to the
+    character."""
+    import hashlib
+
+    from areal_tpu.ops.attention import splash_packed_attention
+
+    q = jax.ShapeDtypeStruct((1, t, hq, hd), jnp.float32)
+    k = jax.ShapeDtypeStruct((1, t, hkv, hd), jnp.float32)
+    v = jax.ShapeDtypeStruct((1, t, hkv, hd_v), jnp.float32)
+    ids = jax.ShapeDtypeStruct((1, t), jnp.int32)
+
+    def loss(q, k, v, seg, pos):
+        return splash_packed_attention(q, k, v, seg, pos, interpret=True, window=window).sum()
+
+    text = str(jax.make_jaxpr(jax.grad(loss, (0, 1, 2)))(q, k, v, ids, ids))
+    assert hashlib.sha256(text.encode()).hexdigest() == ROW_ALONE_JAXPR[t, hq, hkv, hd, hd_v, window]
+    assert "splash_pairs_bwd" in text and "splash_mqa" not in text
+
+
 # sha256 of str(jaxpr) of the backward pass of one call, taken at the
 # commit before the compacted tables (PR 34): (rows, t, window)
 STATIC_JAXPR = {

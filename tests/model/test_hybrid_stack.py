@@ -680,10 +680,15 @@ def test_a_stack_of_blocks_traces_the_program_it_did_before_segments(remat):
 # 371185f2..., which this tree still gives with `banded` asking `scanned`
 # again: nothing else of the program moved). "indexed" (every layer in one
 # scan) and "dense" (one kind, one scan) have no layer outside a scan and
-# are kept.
+# are kept. "latent" was taken again at PR 62 (405ab41b... before it): the
+# attention call's output reaches the `attn_out` stretch sequence-minor,
+# `[1, H, v_dim, T]` (a transposition after the call, bands cut along the
+# last axis: `band_loop.stretch(minor=(0,))`), and the output projection is
+# the einsum `rhvt,hvd->rtd` over it; the other three, which hold every
+# other line of `stretch` and `layer_body`, are as they were.
 LOOPING_JAXPR = {
     "afmoe": "8579ee6c452e5a5184b9f3ceb217dfc27a19c3775d9e1d51cb2fa2b05899b852",
-    "latent": "405ab41b217f922375a5abf54cc7220ccd538f05ce40f4bcc89f5d8473dacfbd",
+    "latent": "095fc3edb86906423a0d60e78405f984f4156f0d21f1a5ce18e1ac540e0d93b3",
     "indexed": "f8414a0388ce27ce05d56210d5cb72a45b59e3d2341255a35b89c0772c8d8774",
     "dense": "0154f30c09a2fff80afea6c6d94735dadef447ee9a2428d4a3193e05505657dc",
 }

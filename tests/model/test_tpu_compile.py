@@ -54,6 +54,35 @@ def _no_partial_of_dq(text, hq, t, hd):
             assert math.prod(dims[:-3]) == 1, f"a partial of dq: {dims}"
 
 
+def _reads_in_place(text, t, heads=32, qk=192, v=128):
+    """The pair kernels of the compiled step `text` take latent
+    attention's operands where XLA's products leave them: their custom
+    calls' q, k, v, output and gradients are `[heads, hd, t]`, and nothing
+    relays a `[1, t, heads, 192]` array (q, k, dq, dk: the parent copied
+    each, 201 MB at 16,384, twice forward and once backward a layer) nor
+    transposes one in a fusion; of the `[1, t, heads, 128]` ones (v, the
+    output, `do`, dv: the parent's four copies a layer) the one copy left
+    is `do` on its way out of the `attn_out` stretch's backward loop, whose
+    product writes its buffer head-minor whatever reads it: at most one an
+    attention call in the backward (the output itself goes into that
+    stretch sequence-minor, `[1, heads, 128, t]`: `band_loop.stretch`'s
+    `minor`)."""
+    import re
+
+    calls = [(line.split(" custom-call(")[0], name) for line, name in re.findall(
+        r"^(.* custom-call\(.*/splash_pairs_(fwd|bwd)/pallas_call.*)$", text, re.M)]
+    assert calls and {name for _, name in calls} == {"fwd", "bwd"}
+    for outs, name in calls:
+        assert f"bf16[{heads},{v if name == 'fwd' else qk},{t}]" in outs, (name, outs[:200])
+        assert f"[{heads},{t}," not in outs
+    relaid = lambda hd: re.findall(
+        rf"= bf16\[(?:1,{t},{heads},{hd}|1,{heads},{hd},{t}|{heads},{t},{hd}|{heads},{hd},{t})\]\S* "
+        r"(?:copy|transpose)\(", text)
+    backward_calls = sum(name == "bwd" for _, name in calls)
+    assert not relaid(qk) and len(relaid(v)) <= backward_calls, (relaid(qk), relaid(v))
+    assert not re.search(rf"%transpose\S* = bf16\[[\d,]*{t}[\d,]*{qk}[\d,]*\]", text)
+
+
 def _accumulate_step(one_chip, monkeypatch, config, t):
     """(the configuration, the compiled forward-backward micro-batch of
     its model at one row of `t`: full remat, the masked loss head, the
@@ -536,6 +565,7 @@ def test_an_accumulate_step_of_the_four_stream_stack_compiles_at_8k(one_chip, mo
                  "splash_pairs_bwd", "moe_rows_add"):
         assert name in text, name
     assert compiled.memory_analysis().temp_size_in_bytes < 7.0e9
+    _reads_in_place(text, 8192)
 
 
 def test_the_delta_rules_kernels_compile_at_the_published_widths(one_chip):
@@ -627,6 +657,27 @@ def test_an_accumulate_step_of_the_sambay_stack_holds_dq_once(one_chip, monkeypa
     _holds_dq_once(cfg, compiled, "phi-4-mini-flash-d8", 8192)
 
 
+def test_an_accumulate_step_of_the_latent_stack_reads_q_k_and_v_in_place(one_chip, monkeypatch):
+    """A forward-backward micro-batch of `joyai-d6e16-train-ppo-long`'s model
+    at its one shape `(1, 16384)`, full remat, the masked loss head: the
+    pair kernels of its latent layers (the leading dense layer alone, the
+    five scanned expert layers) take q, k and v sequence-minor, `[32, hd,
+    T]`, and hand back the output, dq, dk and dv likewise
+    (`ops/attention._rows_in_place`), so that of the parent's twelve
+    relayouts a layer (PERF.md section 6, PR 62) no `copy` or `transpose`
+    of q, k, dq or dk is left and of v's, the output's, `do`'s and dv's
+    one, `do`'s, in the backward; and the compiler's peak and temporaries
+    are no higher than with the head-first kernels (5.71 and 3.78 GB
+    there, 5.44 and 3.57 here)."""
+    cfg, compiled = _accumulate_step(one_chip, monkeypatch, "joyai-llm-flash-d6-e16", 16384)
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") > 4 and "moe_rows_add" in text
+    _reads_in_place(text, 16384)
+    _no_partial_of_dq(text, cfg.n_q_heads, 16384, cfg.mla.qk_dim)
+    memory = compiled.memory_analysis()
+    assert memory.peak_memory_in_bytes <= 5.71e9 and memory.temp_size_in_bytes <= 3.78e9
+
+
 def test_an_accumulate_step_of_the_delta_rule_stack_compiles_at_16k(one_chip, monkeypatch):
     """A forward-backward micro-batch of `kimilinear-d5e8-train-ppo-long`'s
     model at its one shape `(1, 16384)`, full remat, the masked loss head:
@@ -648,6 +699,7 @@ def test_an_accumulate_step_of_the_delta_rule_stack_compiles_at_16k(one_chip, mo
     _rule_in_two_kernels(compiled, "kimi-linear-d5-e8")
     _taps_in_two_kernels(compiled)
     _holds_dq_once(cfg, compiled, "kimi-linear-d5-e8", 16384)
+    _reads_in_place(text, 16384)
 
 
 def test_the_delta_rule_with_a_decay_a_head_compiles_at_the_published_widths(one_chip):
