@@ -47,7 +47,8 @@ def kinds_label(cfg: TransformerConfig) -> str:
     `dense` or `attn.full.nope`. Differential attention says `diff.`, a
     layer that reads layer n's tensor `<n`, one that keeps its own `^`:
     `ssm+dense^`, `dense.diff.full.nope<5`, `gmu+dense<4`; latent
-    attention says `latent.`, a delta-rule mixer beside an MLP `kda.` and
+    attention says `latent.`, a gated short convolution beside an MLP `conv.`
+    and its taps (`moe.conv.k3`), a delta-rule mixer beside an MLP `kda.` and
     its chunk (`moe.kda.c64`; with one decay a head and h key heads
     `moe.kda.head.k16.c64`; with keys and values of two widths and a
     doubled beta `dense.kda.head.k30.96x192.b2.c64`), attention over the
@@ -67,6 +68,8 @@ def kinds_label(cfg: TransformerConfig) -> str:
         keeps, reads = "^" if k.keeps else "", "" if k.reads is None else f"<{k.reads}"
         if k.block:
             return f"{streams}{k.mlp}.{attn}{keeps}{reads}"
+        if k.mixer == "conv" and k.mlp is not None:
+            return f"{k.mlp}.conv.k{cfg.conv.kernel}"
         if k.mixer == "kda" and k.mlp is not None:
             form = "" if cfg.kda.decay == "channel" else f"head.k{cfg.kda.key_heads}."
             if cfg.kda.value_dim != cfg.kda.head_dim or cfg.kda.neg_eigval:
@@ -147,7 +150,7 @@ class TrainCounts:
             **self._bands(stretch), **self._streams(stretch), **attn,
             **self._head(mbs, scored), **self._mtp_head(mbs, scored),
             **self._experts(n_tokens), **self._ssm(mbs), **self._kda(mbs),
-            **self._indexers(rows),
+            **self._conv(mbs), **self._indexers(rows),
         }, attrs
 
     # -- the layers' token-wise stretches -------------------------------
@@ -327,6 +330,27 @@ class TrainCounts:
             "train.kda_taps_cells": taps,
             "train.kda_taps_kernel_cells":
                 taps if kda.taps_in_kernel(cfg, mbs.shape[-1], kernel) else 0,
+        }
+
+    def _conv(self, mbs) -> Counts:
+        """The cells the gated short-convolution mixers run, summed over
+        those layers (a layer that walks its row's live bands, `_looping`,
+        runs the bands up to the row's last token, any other every cell),
+        those of them that hold a token, and those that go through the
+        convolution's kernels: the whole rows', where they take them
+        (`ops/ssm.conv_in_kernel`)."""
+        n = self.cfg.n_conv_layers
+        if not n:
+            return {}
+        cfg, loop = self.cfg, self._looping(mbs, "conv")
+        whole = (n - loop) * mbs.size
+        kernel = ssm_ops.conv_in_kernel(
+            mbs.shape[2], cfg.hidden_dim, cfg.conv.kernel, cfg.conv.bias, False,
+            False if self.mesh.size > 1 else None)
+        return {
+            "train.conv_cells": whole + sum(loop * band_loop.band_cells_run(mb) for mb in mbs),
+            "train.conv_live_cells": n * int((mbs > 0).sum()),
+            "train.conv_kernel_cells": whole if kernel else 0,
         }
 
     def _indexers(self, rows: Rows) -> Counts:

@@ -7,8 +7,8 @@ head) outputs, tied embeddings, and qk-norm (qwen3); and, beyond it, a
 kind per layer (`LayerKind`: the parts a layer has: a mixer, attention
 with its window and rotary (the stack's one table or a named set of its
 own, `RotarySet`), differential or latent or neither, a
-state-space mixer in one of two forms, a delta-rule mixer (`KDAConfig`)
-or a gated memory unit, and an MLP,
+state-space mixer in one of two forms, a delta-rule mixer (`KDAConfig`),
+a gated short convolution (`ConvConfig`) or a gated memory unit, and an MLP,
 dense or expert, either of which may be absent; a layer may keep a tensor
 that later layers read), an attention output gate,
 post-norms, a sigmoid router with a selection bias, shared experts, a
@@ -60,6 +60,9 @@ class MoEConfig:
     # when `route_norm`, times `routed_scaling_factor`.
     score_func: str = "softmax"
     route_norm: bool = True
+    # What the sigmoid router adds to the chosen scores' sum before it
+    # divides by it (`route_norm`): LFM2's modelling code says 1e-6.
+    route_norm_eps: float = 1e-20
     router_bias: bool = False
     # Shared experts: one gated MLP of width n_shared_experts *
     # expert width that every token passes through, added to the routed
@@ -164,6 +167,21 @@ class SSMConfig:
     def in_proj_dim(self) -> int:
         """[z | xBC | dt]."""
         return self.d_inner + self.conv_dim + self.n_heads
+
+
+@dataclasses.dataclass
+class ConvConfig:
+    """A gated short convolution as a layer's mixer (LFM2's, `ops/ssm.py`
+    `gated_conv_mixer`): `[B | C | x] = u W_in`, three parts of the hidden
+    size each; `z_t = sum_j w_j (B * x)_{t-j}`, depthwise over `kernel`
+    taps within a sequence, no activation; `(C * z) W_out`."""
+
+    kernel: int = 3
+    bias: bool = False
+
+    def __post_init__(self):
+        if self.kernel < 2:
+            raise ValueError(f"ConvConfig.kernel must be >= 2 taps, got {self.kernel}")
 
 
 @dataclasses.dataclass
@@ -431,7 +449,7 @@ class RotarySet:
 class LayerKind:
     """What one layer of the stack is, known when the program is traced:
     the parts it has, each under its own norm with its own residual. A
-    mixer (`mixer`: "attention", "ssm", "kda", "gmu" or None) and an MLP (`mlp`:
+    mixer (`mixer`: "attention", "ssm", "kda", "conv", "gmu" or None) and an MLP (`mlp`:
     "dense", "moe" or None); a transformer block has both, a layer may
     have one. For attention: its mask (`window` = how many positions
     back a token sees, itself included; None = all of its sequence),
@@ -470,10 +488,10 @@ class LayerKind:
         if self.mlp not in ("dense", "moe", None):
             raise ValueError(
                 f"LayerKind.mlp must be 'dense', 'moe' or None, got {self.mlp!r}")
-        if self.mixer not in ("attention", "ssm", "kda", "gmu", None):
+        if self.mixer not in ("attention", "ssm", "kda", "conv", "gmu", None):
             raise ValueError(
-                "LayerKind.mixer must be 'attention', 'ssm', 'kda', 'gmu' or None, "
-                f"got {self.mixer!r}")
+                "LayerKind.mixer must be 'attention', 'ssm', 'kda', 'conv', 'gmu' or "
+                f"None, got {self.mixer!r}")
         if self.mixer is None and self.mlp is None:
             raise ValueError("a LayerKind needs a mixer or an MLP")
         if self.window is not None and self.window < 1:
@@ -519,7 +537,7 @@ class LayerKind:
     def parts(self) -> str:
         """The layer's parts, which decide its parameters' structure and
         the stack they live in: layers with the same parts share a
-        stack. "attention+moe", "ssm", "moe", "kda+moe", "diffattention+dense",
+        stack. "attention+moe", "ssm", "moe", "kda+moe", "conv+moe", "diffattention+dense",
         "xdiffattention+dense" (x: q and the output projection only),
         "latentattention+moe", "indexedattention+moe" (the indexer's
         parameters beside attention's), "gmu+dense", ...; a layer that
@@ -644,6 +662,8 @@ class TransformerConfig:
     ssm: Optional[SSMConfig] = None
     # The delta-rule mixer's sizes, for layers whose mixer is "kda".
     kda: Optional[KDAConfig] = None
+    # The gated short convolution's taps, for layers whose mixer is "conv".
+    conv: Optional[ConvConfig] = None
     # Latent attention's sizes; with them and no `layer_kinds`, every
     # layer's attention is latent. `head_dim` is then a head's q and k.
     mla: Optional[MLAConfig] = None
@@ -684,6 +704,8 @@ class TransformerConfig:
             self.ssm = SSMConfig(**self.ssm)
         if isinstance(self.kda, dict):
             self.kda = KDAConfig(**self.kda)
+        if isinstance(self.conv, dict):
+            self.conv = ConvConfig(**self.conv)
         if isinstance(self.mla, dict):
             self.mla = MLAConfig(**self.mla)
         if isinstance(self.mtp, dict):
@@ -725,6 +747,8 @@ class TransformerConfig:
                 "a layer with an 'ssm' or 'gmu' mixer needs TransformerConfig.ssm")
         if any(k.mixer == "kda" for k in kinds) and self.kda is None:
             raise ValueError("a layer with a 'kda' mixer needs TransformerConfig.kda")
+        if any(k.mixer == "conv" for k in kinds) and self.conv is None:
+            raise ValueError("a layer with a 'conv' mixer needs TransformerConfig.conv")
         if any(k.latent for k in kinds):
             if self.mla is None:
                 raise ValueError("a latent attention layer needs TransformerConfig.mla")
@@ -894,6 +918,10 @@ class TransformerConfig:
         return sum(k.mixer == "kda" for k in self.kinds())
 
     @property
+    def n_conv_layers(self) -> int:
+        return sum(k.mixer == "conv" for k in self.kinds())
+
+    @property
     def one_kind(self) -> bool:
         """Every layer the same transformer block over one residual
         stream, full causal attention, rotary as `pos_emb` says: what the
@@ -955,6 +983,15 @@ class TransformerConfig:
                 "three convolutions, which the cache manager has no slot for, no "
                 "snapshot of for an interrupted rollout to resume from, and no "
                 "decode step"
+            )
+        if any(k.mixer == "conv" for k in kinds):
+            missing.append(
+                "a convolution's reach back beside the KV pages: a gated "
+                f"short-convolution layer keeps, a sequence, the last "
+                f"{self.conv.kernel - 1} gated inputs B * x of {self.hidden_dim} "
+                "values each and no KV page, which the cache manager has no slot "
+                "for, no snapshot of for an interrupted rollout to resume from, and "
+                "no decode step"
             )
         if any(k.reads is not None or k.keeps for k in kinds):
             missing.append(

@@ -163,3 +163,4 @@ from areal_tpu.models.hf import kimi_linear as _kimi_linear  # noqa: E402,F401
 from areal_tpu.models.hf import qwen3_next as _qwen3_next  # noqa: E402,F401
 from areal_tpu.models.hf import mellum as _mellum  # noqa: E402,F401
 from areal_tpu.models.hf import olmo_hybrid as _olmo_hybrid  # noqa: E402,F401
+from areal_tpu.models.hf import lfm2_moe as _lfm2_moe  # noqa: E402,F401

@@ -151,7 +151,7 @@ def _router(xt, router_w, moe, expert_bias=None):
         _, top_e = jax.lax.top_k(select, moe.top_k)  # [T, k]
         top_p = jnp.take_along_axis(scores, top_e, axis=-1)
         if moe.route_norm:
-            top_p = top_p / (top_p.sum(-1, keepdims=True) + 1e-20)
+            top_p = top_p / (top_p.sum(-1, keepdims=True) + moe.route_norm_eps)
         return logits, scores, top_p * moe.routed_scaling_factor, top_e
     probs = jax.nn.softmax(logits, axis=-1)
     top_p, top_e = jax.lax.top_k(probs, moe.top_k)  # [T, k]
@@ -762,12 +762,26 @@ def after_router(xt, mp, cfg, cdt, routed, token_mask=None, mesh=None,
     }
 
 
+# The standard deviation of a seeded selection bias (`init_moe_params`'
+# `bias_key`): a sigmoid router's scores over seeded weights have one near
+# 0.2, and its k-th largest of E stands where their density is thin, so a
+# bias of this size moves an expert's load by about half and every second
+# token's choice: a check of logprobs sees whether the experts were chosen on
+# score + bias or on the score.
+_SEEDED_BIAS_STD = 0.05
+
+
 def init_moe_params(cfg: TransformerConfig, dense_fn, keys, n_layers: int,
-                    shared_key=None) -> Dict[str, Any]:
+                    shared_key=None, bias_key=None) -> Dict[str, Any]:
     """Stacked per-layer MoE params (L leading dim, matching the scan):
     the router over all experts, the weights of the experts held here,
-    and where the config has them the selection bias (zeros) and the
-    shared expert."""
+    and where the config has them the selection bias and the shared expert.
+    The bias is zeros, or with `bias_key` a seeded draw at
+    `_SEEDED_BIAS_STD`, the same draw for the second half of the experts as
+    for the first: the two halves of an expert-parallel layer then see one
+    load between them whatever the seed, as under a trained bias, whose
+    purpose that is (a free draw moved a half's pairs, and a step's seconds,
+    by several percent from seed to seed)."""
     moe = cfg.moe
     L, D, E, H = n_layers, cfg.hidden_dim, moe.num_experts, moe.n_held
     F = moe.expert_intermediate_dim or cfg.intermediate_dim
@@ -777,7 +791,10 @@ def init_moe_params(cfg: TransformerConfig, dense_fn, keys, n_layers: int,
     for name, key in zip(mats[:-1], keys[1:]):
         mp[name] = dense_fn(key, (L, H, D, F))
     mp[mats[-1]] = dense_fn(keys[3], (L, H, F, D))
-    if moe.router_bias:
+    if moe.router_bias and bias_key is not None and E % 2 == 0:
+        half = jax.random.normal(bias_key, (L, E // 2), jnp.float32) * _SEEDED_BIAS_STD
+        mp["expert_bias"] = jnp.concatenate([half, half], axis=-1)
+    elif moe.router_bias:
         mp["expert_bias"] = jnp.zeros((L, E), jnp.float32)
     if moe.n_shared_experts:
         Fs = moe.shared_intermediate_dim or F * moe.n_shared_experts

@@ -13,7 +13,8 @@ real_llm_base.py (blocks) — redesigned for XLA rather than translated:
   differential or latent (low-rank q and kv projections,
   `_latent_attention_block`) or neither, a state-space mixer,
   `ops/ssm.py` or `ops/selective_scan.py`, a delta-rule mixer,
-  `ops/kda.py`, or a gated memory unit; and
+  `ops/kda.py`, a gated short convolution (`ops/ssm.gated_conv_mixer`), or
+  a gated memory unit; and
   an MLP, dense or expert; all static). A layer may keep a tensor (its
   scan's output, its
   k and v) that later layers read: it travels beside the residual
@@ -149,10 +150,12 @@ def _init_layer_stack(cfg: TransformerConfig, keys, n: int, kind: LayerKind,
             }
             if cfg.qk_norm:
                 attn["q_norm"] = attn["q_norm"] * _INDEXED_Q_GAIN
-        if cfg.qk_norm and (cfg.rotary_fraction != 1.0 or kind.rotary_set is not None):
+        if cfg.qk_norm and (cfg.rotary_fraction != 1.0 or kind.rotary_set is not None
+                            or cfg.n_conv_layers):
             # `_LATENT_Q_GAIN`'s reason: under unit scores a softmax over
             # thousands of keys is flat, and no check of logprobs would see
-            # which of a head's columns were turned, nor by which table
+            # which of a head's columns were turned, nor by which table (nor,
+            # among convolution mixers, the one attention layer of four at all)
             attn["q_norm"] = attn["q_norm"] * _LATENT_Q_GAIN
         layers["attn"] = attn
     elif kind.mixer == "ssm" and cfg.ssm.form == "mamba1":
@@ -167,6 +170,10 @@ def _init_layer_stack(cfg: TransformerConfig, keys, n: int, kind: LayerKind,
         from areal_tpu.ops.kda import init_kda_params
 
         layers["kda"] = init_kda_params(cfg.kda, D, dense, keys[13], L, pdt)
+    elif kind.mixer == "conv":
+        from areal_tpu.ops.ssm import init_conv_params
+
+        layers["conv"] = init_conv_params(cfg.conv, D, dense, keys[13], L, pdt)
     elif kind.mixer == "gmu":
         k_in, k_out = jax.random.split(jax.random.fold_in(keys[15], 8))
         layers["gmu"] = {"w_in": dense(k_in, (L, D, cfg.ssm.d_inner)),
@@ -178,7 +185,8 @@ def _init_layer_stack(cfg: TransformerConfig, keys, n: int, kind: LayerKind,
         from areal_tpu.models.moe import init_moe_params
 
         mlp = init_moe_params(
-            cfg, dense, jax.random.split(keys[4], 4), L, shared_key=keys[11]
+            cfg, dense, jax.random.split(keys[4], 4), L, shared_key=keys[11],
+            bias_key=jax.random.fold_in(keys[15], 128) if cfg.n_conv_layers else None,
         )
     elif kind.mlp == "dense" and cfg.mlp_type == "gated":
         mlp = {
@@ -760,7 +768,8 @@ class _Stretch(NamedTuple):
     # remat "mlp": the dense MLP under a checkpoint of its own. (No loop
     # has it: a stretch's backward rule keeps nothing of a band.)
     mlp_ckpt: bool = False
-    # The stream steps' kernels (`ops/pallas/stream_mix.py`, `sinkhorn.py`):
+    # The stream steps' kernels (`ops/pallas/stream_mix.py`, `sinkhorn.py`) and
+    # a gated short convolution's (`ops/pallas/conv_gate.py`):
     # None = on the chip where the shapes allow; False = the plain forms,
     # on a mesh of several devices (a kernel is opaque to the partitioner).
     hc_kernel: Optional[bool] = None
@@ -973,6 +982,28 @@ def _ssm_layer(st: _Stretch, w, xs, side, carry):
         return (x + a,), carry
 
 
+def _conv_layer(st: _Stretch, w, xs, side, carry):
+    """A gated short-convolution layer up to its MLP's product or the routed
+    experts' doorstep, the whole row (`carry` None) or one band of it
+    (`ops/band_loop.carried`): the input norm, the mixer after what the
+    cells before the band handed on (`ops/ssm.gated_conv_mixer`: the last
+    gated inputs `B * x` and their segment ids), `ln1_post` where the stack
+    has one, the residual, then `_mlp_part`. `side`: the segment ids.
+    Returns `_mlp_part`'s and what the band hands on."""
+    from areal_tpu.ops.ssm import gated_conv_mixer
+
+    cfg = st.cfg
+    (x,), (segment_ids,) = xs, side
+    with jax.named_scope("conv_in_proj"):
+        h = _norm(x, w.get("ln1"), cfg)
+    a, carry = gated_conv_mixer(carry, h, w["mixer"], segment_ids, st.cdt, st.hc_kernel)
+    with jax.named_scope("conv_out_proj"):
+        if "ln1_post" in w:
+            a = _norm(a, w["ln1_post"], cfg)
+        x = x + a
+    return _mlp_part(st, w, (x,)), carry
+
+
 def _kind_loops(cfg: TransformerConfig, kind: LayerKind) -> bool:
     """Whether a layer of `kind` runs over live bands where the call's
     shape allows. As two stretches (`ops/band_loop.stretch`): a plain,
@@ -989,7 +1020,12 @@ def _kind_loops(cfg: TransformerConfig, kind: LayerKind) -> bool:
     last cells and a segment id (the probe's figures for it: PERF.md
     section 6, PR 61; with a stretch before the mixer and one after, PR
     45's form, the wide products still crossed HBM and the kind lost 7-14 %
-    of a full row for 9-22 % off a half-empty one). Experts or attention
+    of a full row for 9-22 % off a half-empty one). As one carried loop too
+    (around `_conv_layer`; PR 63): a gated short convolution under a dense MLP,
+    whose bands hand on the taps' last two gated inputs; under experts the same
+    mixer keeps the whole row: its token-wise part is two light projections and
+    a router, and by the probe the loop's float32 sums and copies cost 2-9 %
+    more than the dead cells save (PERF.md section 6, PR 63). Experts or attention
     alone in a layer, a selective scan or memory unit with its MLP, and
     differential attention's three kinds (each walked outside a scan, the
     most to trace in the stack with the least set-up to spare) keep the
@@ -1003,6 +1039,8 @@ def _kind_loops(cfg: TransformerConfig, kind: LayerKind) -> bool:
     if kind.mixer == "ssm":
         return (kind.mlp is None and cfg.ssm.form == "mamba2" and kind.reads is None
                 and not kind.keeps and band_loop._BAND % cfg.ssm.chunk_size == 0)
+    if kind.mixer == "conv":
+        return kind.mlp == "dense"
     return (kind.mixer in ("attention", "kda") and kind.mlp is not None
             and not kind.diff and kind.reads is None)
 
@@ -1428,6 +1466,12 @@ def forward(
                     h = _norm(x, lp.get("ln1"), cfg)
                     g = jax.nn.silu(h @ lp["gmu"]["w_in"].astype(cdt))
                     got = (x + (g * kept) @ lp["gmu"]["w_out"].astype(cdt),)
+            elif kind.mixer == "conv":
+                # one step from the stream to the MLP's product (the experts'
+                # doorstep); banded, one loop that hands the taps' reach back on
+                step, got = _conv_layer, (x,)
+                w = {**ln1, "mixer": lp["conv"],
+                     **{n: lp[n] for n in ("ln1_post",) if n in lp}}
             elif kind.mixer == "ssm" and banded:
                 # the whole layer a band at a time: one loop, and the state and
                 # the convolution's last cells handed from band to band
@@ -1459,8 +1503,16 @@ def forward(
                 w["hc"] = lp["hc2"]
             # (a mixer alone in its layer has no step left: no loop over nothing)
             # (latent attention's output comes sequence-minor: `_latent_core`)
-            x, *rest = got if kind.mlp is None and step is _mlp_part else run(
-                step, w, got, minor=(0,) if kind.latent else ())
+            if step is _conv_layer:  # a band hands the next its last cells
+                from areal_tpu.ops.ssm import conv_start_carry
+
+                x, *rest = band_loop.carried(
+                    step, st, w, got, (segment_ids,),
+                    conv_start_carry(cfg.conv, x.shape[0], cfg.hidden_dim, cdt), n_live,
+                ) if banded else step(st, w, got, (segment_ids,), None)[0]
+            else:
+                x, *rest = got if kind.mlp is None and step is _mlp_part else run(
+                    step, w, got, minor=(0,) if kind.latent else ())
             coefs = ()
             if hyper and kind.mlp is not None:  # the MLP's read: H_res last, H_post before
                 *rest, h_res = rest

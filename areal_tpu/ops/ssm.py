@@ -49,7 +49,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from areal_tpu.models.config import SSMConfig
+from areal_tpu.models.config import ConvConfig, SSMConfig
 
 
 def init_ssm_params(ssm: SSMConfig, hidden_dim: int, dense_fn, key, n_layers: int,
@@ -82,10 +82,11 @@ def init_ssm_params(ssm: SSMConfig, hidden_dim: int, dense_fn, key, n_layers: in
     return sp
 
 
-def causal_conv(xbc, w, b, segment_ids, tail=None):
+def causal_conv(xbc, w, b, segment_ids, tail=None, act=jax.nn.silu):
     """xbc [R, T, C], w [K, C], b [C] or None, segment_ids [R, T] ->
-    silu(b + sum_l w[K-1-l] xbc[t-l]) over the taps whose position lies
-    in t's own sequence; 0 at padding cells. `tail`: the K - 1 cells
+    act(b + sum_l w[K-1-l] xbc[t-l]) over the taps whose position lies
+    in t's own sequence (`act` None: the sum as it is); 0 at padding cells.
+    `tail`: the K - 1 cells
     before the first, (xbc [R, K-1, C], segment ids [R, K-1]), where xbc is
     a band of a longer row; without it nothing stands before cell 0."""
     K, T = w.shape[0], xbc.shape[1]
@@ -102,7 +103,7 @@ def causal_conv(xbc, w, b, segment_ids, tail=None):
         acc = acc + jnp.where(same[..., None], before[:, lo: lo + T], 0) * w[lo]
     if b is not None:
         acc = acc + b
-    return jnp.where((segment_ids > 0)[..., None], jax.nn.silu(acc), 0)
+    return jnp.where((segment_ids > 0)[..., None], acc if act is None else act(acc), 0)
 
 
 def chunked_scan(x, dt, A, B, C, segment_ids, chunk: int):
@@ -289,3 +290,98 @@ def chunk_counts(segment_ids: np.ndarray, chunk: int, band: Optional[int] = None
             int((chunks > 0).any(-1).sum()),
             int(starts[:, :, 1:].any(-1).sum()),
             int(start.sum()))
+
+
+# ---------------------------------------------------------------------------
+# A gated short convolution as a layer's whole mixer (LFM2)
+# ---------------------------------------------------------------------------
+
+
+def init_conv_params(conv: ConvConfig, hidden_dim: int, dense_fn, key, n_layers: int,
+                     pdt) -> Dict[str, Any]:
+    """`n_layers` gated short convolutions stacked on a leading axis:
+    `in_proj` to `[B | C | x]`, the taps `[K, D]` (tap K-1 multiplies the
+    position itself, as `init_ssm_params`'), `out_proj`."""
+    L, D = n_layers, hidden_dim
+    k_in, k_conv, k_out = jax.random.split(key, 3)
+    cp: Dict[str, Any] = {
+        "in_proj": dense_fn(k_in, (L, D, 3 * D)),
+        "conv_w": dense_fn(k_conv, (L, conv.kernel, D), 1.0 / math.sqrt(conv.kernel)),
+        "out_proj": dense_fn(k_out, (L, D, D)),
+    }
+    if conv.bias:
+        cp["conv_b"] = jnp.zeros((L, D), pdt)
+    return cp
+
+
+class ConvCarry(NamedTuple):
+    """What the cells of a row up to a band's first hand the band
+    (`gated_conv_mixer`): the last `kernel - 1` cells' gated inputs `B * x`
+    `[R, K-1, D]` and their segment ids `[R, K-1]`."""
+    bx: Any
+    seg: Any
+
+
+def conv_start_carry(conv: ConvConfig, n_rows: int, hidden_dim: int, cdt) -> ConvCarry:
+    """What a row's first band receives: no cell before it."""
+    k = conv.kernel - 1
+    return ConvCarry(jnp.zeros((n_rows, k, hidden_dim), cdt),
+                     jnp.zeros((n_rows, k), jnp.int32))
+
+
+def conv_in_kernel(T: int, D: int, K: int, bias: bool, tail: bool,
+                   kernel: Optional[bool] = None) -> bool:
+    """Whether `gated_conv` takes its kernels (`ops/pallas/conv_gate.py`) for a
+    call of these shapes: on the chip (`kernel` None; True: anywhere, in
+    interpret mode off the chip; False: nowhere, a mesh of several devices),
+    a whole row of whole blocks and lane tiles, no bias, nothing handed in."""
+    from areal_tpu.ops.pallas import conv_gate
+
+    if kernel is None:
+        kernel = jax.default_backend() == "tpu"
+    return bool(kernel) and not bias and not tail and conv_gate.fits(T, D, K)
+
+
+def gated_conv(bcx, w, b, segment_ids, tail=None, kernel: Optional[bool] = None):
+    """bcx `[R, T, 3 D]` = `[B | C | x]` -> (`C * conv(B * x)` `[R, T, D]`,
+    the last `K - 1` cells' gated input `B * x`, what the cells after would
+    be handed): `causal_conv` without an activation over the product, under
+    the second gate. Over a whole row on the chip a kernel pair
+    (`conv_in_kernel`); otherwise plain `jax.numpy`, whose forward the
+    compiler fuses into one pass at three quarters of the memory's rate and
+    whose backward it does not (a fifth: `scripts/conv_probe.py`, PERF.md
+    section 6, PR 63)."""
+    K, D = w.shape
+    B, C, x = jnp.split(bcx, 3, axis=-1)
+    last = slice(-(K - 1), None)
+    if conv_in_kernel(bcx.shape[1], D, K, b is not None, tail is not None, kernel):
+        from areal_tpu.ops.pallas import conv_gate
+
+        return (conv_gate.conv_gate(bcx, w, segment_ids, jax.default_backend() != "tpu"),
+                B[:, last] * x[:, last])
+    bx = B * x
+    z = causal_conv(bx, w, b, segment_ids, tail, act=None)
+    # (masked once more after the gate: what a padding cell's C holds is no one's)
+    return jnp.where((segment_ids > 0)[..., None], C * z, 0), bx[:, last]
+
+
+def gated_conv_mixer(carry: Optional[ConvCarry], u, cp, segment_ids, cdt,
+                     kernel: Optional[bool] = None):
+    """u `[R, T, D]` (the layer's input after its norm) -> (the mixer's
+    output `[R, T, D]`, what its last cells hand on); `cp` one layer's
+    parameters. `carry`: what the cells before u's first handed on where u
+    is a band of a longer row (`ops/band_loop.carried`), None where nothing
+    stands before cell 0. A padding cell's result is 0 and no tap reaches
+    out of a sequence, so what padding cells hold reaches no token.
+    `kernel`: `conv_in_kernel`'s."""
+    k = cp["conv_w"].shape[0] - 1
+    with jax.named_scope("conv_in_proj"):
+        bcx = u.astype(cdt) @ cp["in_proj"].astype(cdt)
+    with jax.named_scope("conv_taps"):
+        y, last = gated_conv(
+            bcx, cp["conv_w"].astype(cdt),
+            cp["conv_b"].astype(cdt) if "conv_b" in cp else None, segment_ids,
+            None if carry is None else tuple(carry), kernel)
+        handed = ConvCarry(last, segment_ids[:, -k:])
+    with jax.named_scope("conv_out_proj"):
+        return y @ cp["out_proj"].astype(cdt), handed

@@ -834,3 +834,60 @@ def test_an_accumulate_step_of_the_rectangle_stack_compiles_at_8k(one_chip, monk
         assert name in text, name
     _taps_in_two_kernels(compiled)
     assert compiled.memory_analysis().temp_size_in_bytes < 3.0e9
+
+
+def test_an_accumulate_step_of_the_short_convolution_stack_compiles_at_8k(one_chip, monkeypatch):
+    """A forward-backward micro-batch of `lfm2-d5e16-train-ppo-8k`'s model at
+    its one shape `(1, 8192)`, full remat, the masked loss head over the tied
+    embedding: a dense gated short-convolution layer and an attention layer
+    over held experts, each alone, then a scan of three convolution layers
+    over held experts, which keep the whole row; the dense convolution layer
+    one carried loop over the row's live bands (`band_loop.carried` around
+    `transformer._conv_layer`: the taps' last two gated inputs handed from
+    band to band), the attention layer its two stretches, the pair kernels at
+    heads of 64, group 4. What
+    the step holds besides: 893.7 M parameters at 14 bytes, 12.51 GB, of
+    which the program's arguments are the bf16 weights; its peak, with the
+    float32 gradient sums and the two moments it does not see, stands under
+    the 15.75 GB a chip's allocator gives."""
+    from areal_tpu.models.transformer import looping_layers
+
+    cfg, compiled = _accumulate_step(one_chip, monkeypatch, "lfm2-8b-a1b-d5-e16", 8192)
+    assert looping_layers(cfg, 1, 8192) == 2
+    assert looping_layers(cfg, 1, 8192, mixer="conv") == 1
+    text = compiled.as_text()
+    for name in ("splash_pairs_fwd", "splash_pairs_bwd", "moe_rows_add", "conv_gate_fwd",
+                 "conv_gate_bwd"):  # the whole rows' convolutions in their kernels
+        assert name in text, name
+    m = compiled.memory_analysis()
+    n_params = m.argument_size_in_bytes // 2  # bf16 weights; the three int rows are nothing
+    assert abs(n_params - 893.7e6) < 0.1e6, n_params
+    # the weights' bf16 gradients are this program's results, where the engine's
+    # step adds into its float32 sums: the sums (4 bytes a parameter) stand in
+    # their place, beside the two moments (8)
+    held = m.peak_memory_in_bytes - m.output_size_in_bytes + 12 * n_params
+    assert 12.51e9 < held < 15.75e9, (held, m.peak_memory_in_bytes, m.temp_size_in_bytes)
+
+
+def test_the_gated_convolutions_kernels_compile_at_the_published_widths(one_chip):
+    """`ops/pallas/conv_gate.py` over `lfm2-d5e16-train-ppo-8k`'s row: `[B | C |
+    x]` `[1, 8192, 6144]` bf16 under three taps, forward and transpose: one
+    custom call each, no loop of XLA's and no array of the row's size beside
+    the operands and results."""
+    from areal_tpu.ops.pallas import conv_gate
+
+    t, d, k = 8192, 2048, 3
+    bcx = _shape((1, t, 3 * d), jnp.bfloat16, one_chip)
+    w = _shape((k, d), jnp.bfloat16, one_chip)
+    dy = _shape((1, t, d), jnp.bfloat16, one_chip)
+    seg = _shape((1, t), jnp.int32, one_chip)
+    fwd = jax.jit(lambda bcx, w, seg: conv_gate.conv_gate(bcx, w, seg)).lower(
+        bcx, w, seg).compile()
+    bwd = jax.jit(lambda bcx, w, dy, seg: jax.vjp(
+        lambda a, b: conv_gate.conv_gate(a, b, seg), bcx, w)[1](dy)).lower(
+        bcx, w, dy, seg).compile()
+    for compiled, name in ((fwd, "conv_gate_fwd"), (bwd, "conv_gate_bwd")):
+        text = compiled.as_text()
+        assert text.count("tpu_custom_call") == 1 and name in text and " while(" not in text
+        # the cells' numbers `[1, t, 128]` int32 and nothing else of a row's size
+        assert compiled.memory_analysis().temp_size_in_bytes < 8.0e6
